@@ -4,8 +4,11 @@ through the overlapped training loop (ray_tpu/train/loop.py).
 Prints ONE JSON line: {"metric", "value", "unit", "vs_baseline"} plus
 "checkpoint_overhead_pct", "mfu", "step_breakdown" (host step-time
 shares from TrainLoop.last_breakdown: prefetch / dispatch / metrics /
-checkpoint / publish) and "retraces_unexpected" (retrace-sentinel
-violations of the fused dispatch's compile-once pin — must be 0).
+checkpoint / publish), "retraces_unexpected" (retrace-sentinel
+violations of the fused dispatch's compile-once pin — must be 0), and
+the device it ran on: "platform", "device_kind", "device_count". Off a
+TPU it runs a toy model so tier-1 can check the contract; "mfu" and
+"vs_baseline" are then 0.0 (not measured).
 
 Methodology (changed in PR 2): earlier rounds re-dispatched one jitted
 step per Python iteration on a single pre-sharded device batch, so the
@@ -14,8 +17,7 @@ generates a FRESH host batch every step and streams it through the
 double-buffered prefetcher with fused multi-step dispatch, so tokens/s is
 an honest end-to-end figure — host feed, transfer, dispatch, compute and
 the (ring-buffered, every-K-steps) metrics fetch all inside the timed
-region. The overlap work keeps it at or above the r5 fixed-batch number
-(61.6k tok/s on v5e).
+region. On this round's chip: not measured.
 
 Knobs (env vars, platform-tuned defaults below):
   RAY_TPU_BENCH_ACCUM     gradient-accumulation microbatches per step
@@ -47,25 +49,7 @@ import time
 import jax
 import numpy as np
 
-# bf16 peak FLOPs per chip by device kind (jax device_kind substrings).
-_PEAK_FLOPS = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),   # v5 litepod
-    ("v5", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
 _BASELINE_MFU = 0.40
-
-
-def peak_flops(device) -> float:
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in _PEAK_FLOPS:
-        if key in kind:
-            return val
-    return 197e12
 
 
 def _env_int(name: str, default: int) -> int:
@@ -76,28 +60,27 @@ def main():
     from ray_tpu.models import gpt
     from ray_tpu.parallel import MeshSpec
     from ray_tpu.train import loop, spmd
+    from ray_tpu.util import telemetry
+    from ray_tpu.util.compile_cache import enable_compile_cache
 
     devices = jax.devices()
     on_tpu = devices[0].platform == "tpu"
     if on_tpu:
+        enable_compile_cache()
+        peak = telemetry.device_peak_flops(devices[0])  # unknown: raise
         cfg = gpt.GPTConfig(vocab_size=50304, d_model=1024, n_layers=12,
                             n_heads=16, d_ff=4096, max_seq_len=1024,
                             attn_impl="flash", logits_dtype="bfloat16",
                             remat_policy="dots", loss_impl="fused")
-        # bf16 unembed output (loss upcasts before logsumexp): halves
-        # the HBM traffic of the biggest activation; measured +2.3%
-        # tok/s on v5e at loss parity to 3 decimals (57.6k -> 59.0k)
-        # Batch swept on v5e: 8 -> 55.2k tok/s (0.468 MFU), 16 -> 58.4k
-        # (0.495), 32 -> 58.5k (plateau; remat required above 8 anyway).
-        # remat_policy swept on v5e at B=16 (r5): save-nothing 58.2k,
-        # attn_out 58.0k, dots 61.6k (+5.8%, loss parity to 4 decimals).
+        # None of these choices has been measured on this round's chip
+        # (bf16 unembed output, remat_policy="dots", the batch).
         # loss_impl="fused" (ops/fused_xent.py) streams the unembed in
-        # vocab chunks so the [B, T, V] logits tensor never exists; that
-        # is what reopened B>16 (r5 runs B=24).
-        # accum=1: B=24 fits, so accumulation is off on the bench; flip
-        # RAY_TPU_BENCH_ACCUM to trade peak activations for scan steps
-        # when sweeping B beyond HBM. unroll=4 amortizes one Python
-        # dispatch over 4 steps; prefetch=2 double-buffers the host feed.
+        # vocab chunks so the [B, T, V] logits tensor never exists.
+        # B=24: the compiler's memory analysis puts the step alone at
+        # 15.75 GiB of the 16 GB chip (B=16: 9.27 GiB of temporaries),
+        # so expect to lower RAY_TPU_BENCH_BATCH or raise
+        # RAY_TPU_BENCH_ACCUM. unroll=4 amortizes one Python dispatch
+        # over 4 steps; prefetch=2 double-buffers the host feed.
         batch_size, steps, warmup = 24, 20, 4
         accum, unroll, prefetch, interval = 1, 4, 2, 10
     else:   # CPU smoke mode so the benchmark is runnable anywhere.
@@ -195,8 +178,9 @@ def main():
                   "publish")}
 
     tok_s = tokens_per_step * steps / dt
-    mfu = tok_s * flops_tok / (peak_flops(devices[0]) * len(devices))
-    vs_baseline = mfu / _BASELINE_MFU if on_tpu else 0.0
+    # a CPU rate over a chip's peak is not a utilization: 0.0 off-TPU
+    mfu = tok_s * flops_tok / (peak * len(devices)) if on_tpu else 0.0
+    vs_baseline = mfu / _BASELINE_MFU
 
     print(json.dumps({
         "metric": "gpt_train_tokens_per_sec",
@@ -207,6 +191,9 @@ def main():
         "mfu": round(mfu, 4),
         "step_breakdown": step_breakdown,
         "retraces_unexpected": train.sentinel.retraces_unexpected,
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
     }))
 
 

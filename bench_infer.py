@@ -149,27 +149,6 @@ import time
 import jax
 import numpy as np
 
-# HBM bandwidth per chip, bytes/s, by device kind substring (same probe
-# idiom as bench.py's _PEAK_FLOPS).
-_HBM_BW = (
-    ("v6", 1638e9),
-    ("v5p", 2765e9),
-    ("v5e", 819e9),
-    ("v5", 819e9),
-    ("v4", 1228e9),
-    ("v3", 900e9),
-    ("v2", 700e9),
-)
-
-
-def hbm_bandwidth(device) -> float:
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in _HBM_BW:
-        if key in kind:
-            return val
-    return 819e9
-
-
 def _env_int(name: str, default: int) -> int:
     return int(os.environ.get(name, default))
 
@@ -198,7 +177,9 @@ def decode_roofline_tokens_per_sec(cfg, slots: int, mean_ctx: float,
     kv_bytes = slots * mean_ctx * 2 * kv_row
     bytes_per_step = (full_params * bpe + matmul_params * w_bpe
                       + kv_bytes)
-    return hbm_bandwidth(device) * slots / bytes_per_step
+    from ray_tpu.util import telemetry
+    return (telemetry.device_peaks(device)["hbm_bytes_per_s"] * slots
+            / bytes_per_step)
 
 
 def main():
@@ -208,6 +189,10 @@ def main():
     devices = jax.devices()
     on_tpu = devices[0].platform == "tpu"
     if on_tpu:
+        from ray_tpu.util import telemetry
+        from ray_tpu.util.compile_cache import enable_compile_cache
+        enable_compile_cache()
+        telemetry.device_peaks(devices[0])    # unknown kind: raise now
         cfg = gpt.GPTConfig(vocab_size=50304, d_model=1024, n_layers=12,
                             n_heads=16, d_ff=4096, max_seq_len=1024)
         slots, max_len, prompt_len, new_tokens, requests = \
@@ -560,6 +545,9 @@ def main():
         "value": round(decode_tok_s, 1),
         "unit": "tokens/s",
         "vs_baseline": round(vs_baseline, 3),
+        "platform": devices[0].platform,
+        "device_kind": devices[0].device_kind,
+        "device_count": len(devices),
         "prefill_tokens_per_sec": round(prefill_tok_s, 1),
         "decode_tokens_per_sec": round(decode_tok_s, 1),
         "p50_token_latency_ms": round(s["p50_token_latency_ms"], 3),

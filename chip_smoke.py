@@ -1,0 +1,650 @@
+"""The quickest proof that both TPU paths still start on the chip.
+
+    python chip_smoke.py            # one chip: serve phase, then train phase
+    python chip_smoke.py --chips 4  # one four-chip host: sharded train step
+                                    # against one chip, then two replicas
+
+Drives the serving path (`serve.run` of an `InferenceReplica` that asked
+for a chip, concurrent `handle.stream` calls) and the training path
+(`spmd.make_gpt_trainer` + `loop.TrainLoop` with the prefetcher) once at
+the full width of the repo's bench model, random weights from `--seed`,
+and checks what comes out against references computed the plain way.
+
+A chip belongs to one process at a time, so this process never
+initialises a JAX backend: every phase runs in one process of its own
+that holds the chip, one after the other. Each phase prints one JSON
+line; the last line is `{"ok": ..., "device": {...}}` with the device as
+JAX reports it. Any failed check raises, and the exit code is then not 0.
+Without an accelerator the script fails within seconds and prints no
+result.
+"""
+
+from __future__ import annotations
+
+import argparse
+import dataclasses
+import glob
+import json
+import logging
+import os
+import re
+import signal
+import subprocess
+import sys
+import threading
+import time
+
+import numpy as np
+
+import ray_tpu
+from ray_tpu import serve
+from ray_tpu._private import native
+from ray_tpu.serve.engine import InferenceReplica
+
+# The repo's bench model (bench.py, bench_infer.py): published-GPT-2-medium
+# -like widths, all 12 layers.
+WIDTHS = dict(vocab_size=50304, d_model=1024, n_layers=12, n_heads=16,
+              d_ff=4096, max_seq_len=1024)
+TRAIN_CFG = dict(WIDTHS, attn_impl="flash", logits_dtype="bfloat16",
+                 remat_policy="dots", loss_impl="fused")
+# Engine logprobs come from bf16 activations and weights cast to bf16 at
+# use, the reference from float32 at the highest matmul precision. bf16
+# rounds at 2^-9 relative; over the 24 residual adds of 12 layers that
+# is about 1% of the final activations, i.e. ~1e-2 on logits of spread
+# 0.64 (1024 dims x embed scale 0.02). First chip run (PR 21): max
+# 3.02e-2 over 64 tokens. A wrong mask or scale in a kernel moves
+# logprobs by tenths.
+LOGPROB_MAX_TOL = 6e-2
+LOGPROB_MEAN_TOL = 2e-2
+LOSS_TOL = 2e-2
+PHASE_TIMEOUT_S = 900
+
+
+def emit(record: dict) -> None:
+    print(json.dumps(record), flush=True)
+
+
+def check(ok: bool, what: str) -> None:
+    if not ok:
+        raise AssertionError(what)
+
+
+# ---------------------------------------------------------------------------
+# helpers that run inside a process that owns the chip
+# ---------------------------------------------------------------------------
+
+class _Records(logging.Handler):
+    def __init__(self):
+        super().__init__(logging.WARNING)
+        self.messages: list[str] = []
+
+    def emit(self, record):
+        self.messages.append(record.getMessage())
+
+
+def watch_op_fallbacks() -> list[str]:
+    """Messages `ops.backend.note_fallback` logs from here on: each is an
+    `impl="auto"` that found no kernel plan on a TPU backend."""
+    handler = _Records()
+    logging.getLogger("ray_tpu.ops").addHandler(handler)
+    return handler.messages
+
+
+class CompileWatch:
+    """Counts what JAX compiles and what its persistent cache answers,
+    from the events JAX itself records."""
+
+    def __init__(self):
+        import jax
+        self.compile_s = 0.0
+        self.compiles = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._dur)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _dur(self, event: str, secs: float, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            self.compile_s += secs
+            self.compiles += 1
+
+    def _event(self, event: str, **_):
+        if event == "/jax/compilation_cache/cache_hits":
+            self.cache_hits += 1
+        elif event == "/jax/compilation_cache/cache_misses":
+            self.cache_misses += 1
+
+    def report(self) -> dict:
+        return {"compile_s": round(self.compile_s, 2),
+                "compiles": self.compiles,
+                "cache_hits": self.cache_hits,
+                "cache_misses": self.cache_misses}
+
+
+def device_report() -> dict:
+    import jax
+    devices = jax.devices()
+    return {"platform": devices[0].platform,
+            "kind": devices[0].device_kind, "count": len(devices)}
+
+
+def kernels_of(jitted, *args):
+    """(names of the Pallas kernels in `jitted` lowered for `args`,
+    number of `tpu_custom_call`s left in the compiled program, the
+    executable)."""
+    lowered = jitted.lower(*args)
+    names = sorted(set(re.findall(r'kernel_name = "([^"]+)"',
+                                  lowered.as_text())))
+    compiled = lowered.compile()
+    return names, compiled.as_text().count(
+        'custom_call_target="tpu_custom_call"'), compiled
+
+
+def peak_bytes() -> int | None:
+    import jax
+    stats = jax.devices()[0].memory_stats() or {}
+    return stats.get("peak_bytes_in_use")
+
+
+# ---------------------------------------------------------------------------
+# serve phase
+# ---------------------------------------------------------------------------
+
+class SmokeReplica(InferenceReplica):
+    """`InferenceReplica` plus the one method that has to run inside the
+    process that owns the chip; the program itself grows no API."""
+
+    def __init__(self, *args, **kwargs):
+        from ray_tpu.util.compile_cache import enable_compile_cache
+        self._cache_dir = enable_compile_cache()
+        self._fallbacks = watch_op_fallbacks()
+        self._compiles = CompileWatch()
+        t0 = time.perf_counter()
+        super().__init__(*args, **kwargs)
+        self._init_s = time.perf_counter() - t0
+
+    def inspect(self, prompt, tokens, logprobs) -> dict:
+        """Stats, the kernels in the paged forwards at this engine's
+        shapes, and how far the logprobs the engine streamed for
+        `prompt` -> `tokens` are from a float32 teacher-forced forward
+        over the same tokens on the XLA attention path."""
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import gpt
+        eng = self.engine
+        stats = self.stats()
+
+        cfg32 = dataclasses.replace(eng.cfg, dtype="float32",
+                                    attn_impl="xla")
+        seq = jnp.asarray(np.concatenate([prompt, tokens])[None],
+                          jnp.int32)
+        with jax.default_matmul_precision("highest"):
+            ref = jax.jit(
+                lambda p, s: gpt.completion_logprobs(
+                    p, s, jnp.asarray([len(prompt)]), len(tokens), cfg32)
+            )(eng.params, seq)
+        diff = np.abs(np.asarray(ref[0], np.float64)
+                      - np.asarray(logprobs, np.float64))
+
+        params, cache = jax.tree.map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            (eng.params, eng.cache))
+
+        def i32(*shape):
+            return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+        slots, mb = eng.num_slots, eng.max_blocks
+        decode_k, decode_n, _ = kernels_of(
+            jax.jit(lambda p, t, c, pos, tb: gpt.decode_step_paged(
+                p, t, c, pos, tb, eng.cfg)),
+            params, i32(slots), cache, i32(slots), i32(slots, mb))
+        chunk = eng.chunk_buckets[-1]
+        prefill_k, prefill_n, _ = kernels_of(
+            jax.jit(lambda p, t, c, tb, st, ln: gpt.prefill_paged(
+                p, t, c, eng.cfg, block_table=tb, start=st, length=ln)),
+            params, i32(1, chunk), cache, i32(mb), i32(), i32())
+        return {
+            "pid": os.getpid(),
+            "stats": {k: stats[k] for k in (
+                "platform", "device_kind", "device_count",
+                "visible_chips", "decode_traces", "prefill_traces",
+                "retraces_unexpected", "decode_tokens", "prefill_tokens",
+                "p50_token_latency_ms", "p99_token_latency_ms",
+                "ttft_ms_p50")},
+            "logprob_max_abs_diff": float(diff.max()),
+            "logprob_mean_abs_diff": float(diff.mean()),
+            "logprob_mean": float(np.mean(logprobs)),
+            "kernels": {"decode_step_paged": decode_k,
+                        "prefill_paged": prefill_k},
+            "tpu_custom_calls": {"decode_step_paged": decode_n,
+                                 "prefill_paged": prefill_n},
+            "op_fallbacks": list(self._fallbacks),
+            "replica_init_s": round(self._init_s, 2),
+            "cache_dir": self._cache_dir,
+            **self._compiles.report(),
+            "peak_bytes_in_use": peak_bytes(),
+        }
+
+
+def _ray_tpu_processes():
+    """(pid, parent pid, command line) of every worker-side process of
+    ray_tpu on this host: `worker_main` execs, the fork factory and the
+    workers it forked (which keep its command line)."""
+    for path in glob.glob("/proc/[0-9]*"):
+        try:
+            with open(path + "/cmdline", "rb") as f:
+                cmd = f.read().replace(b"\0", b" ").decode(errors="replace")
+            with open(path + "/stat", "rb") as f:
+                ppid = int(f.read().rsplit(b")", 1)[1].split()[1])
+        except (OSError, ValueError, IndexError):
+            continue
+        if ("ray_tpu._private.worker_main" in cmd
+                or "ray_tpu._private.forkserver" in cmd):
+            yield int(os.path.basename(path)), ppid, cmd.strip()
+
+
+def stop_workers_or_fail() -> None:
+    """After `ray_tpu.shutdown()`: no worker may be left (a replica left
+    alive still holds the chip). The fork factory is this process's own
+    child, kept warm between sessions by design; stop it too, so that
+    the script leaves nothing running."""
+    deadline = time.monotonic() + 15
+    while True:
+        procs = list(_ray_tpu_processes())
+        factories = [pid for pid, ppid, cmd in procs
+                     if ppid == os.getpid() and "forkserver" in cmd]
+        workers = [f"{pid}: {cmd}" for pid, _, cmd in procs
+                   if pid not in factories]
+        if not workers or time.monotonic() > deadline:
+            break
+        time.sleep(0.2)
+    for pid in factories:
+        os.kill(pid, signal.SIGTERM)
+        os.waitpid(pid, 0)
+    check(not workers, f"workers left after shutdown: {workers}")
+
+
+def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
+                streams: int, prompt_lens: tuple[int, int],
+                new_tokens: int, slots: int, max_len: int,
+                seed: int) -> None:
+    """`serve.run` of `replicas` chip-holding replicas behind one
+    handle, `streams` concurrent greedy `handle.stream` calls, then the
+    in-replica checks of every replica. Prints what it measured, then
+    holds it to the checks."""
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg_kwargs["vocab_size"],
+                            int(rng.integers(prompt_lens[0],
+                                             prompt_lens[1] + 1))
+                            ).astype(np.int32) for _ in range(streams)]
+    ray_tpu.init()
+    try:
+        t0 = time.perf_counter()
+        app = serve.deployment(
+            SmokeReplica, num_replicas=replicas,
+            ray_actor_options={"num_tpus": 1},
+        ).bind(cfg_kwargs, slots=slots, max_len=max_len, seed=seed)
+        handle = serve.run(app, name="smoke")
+
+        outs: list = [None] * streams
+        errors: list = []
+
+        def consume(i):
+            try:
+                outs[i] = list(handle.stream(prompts[i], new_tokens,
+                                             timeout=PHASE_TIMEOUT_S))
+            except BaseException as e:       # re-raised below
+                errors.append(e)
+
+        threads = [threading.Thread(target=consume, args=(i,))
+                   for i in range(streams)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(PHASE_TIMEOUT_S)
+        check(not any(t.is_alive() for t in threads),
+              "a stream did not finish")
+        if errors:
+            raise errors[0]
+        wall_s = time.perf_counter() - t0
+        for i, out in enumerate(outs):
+            check(len(out) == new_tokens,
+                  f"stream {i} returned {len(out)} of {new_tokens} tokens")
+            check(all(0 <= int(t) < cfg_kwargs["vocab_size"] for t in out),
+                  f"stream {i} returned a token outside the vocabulary")
+
+        # A worker that asked for no chip must stay off the TPU runtime
+        # while the replicas hold it.
+        @ray_tpu.remote
+        def cpu_worker_platform():
+            import jax
+            return jax.devices()[0].platform
+
+        cpu_platform = ray_tpu.get(cpu_worker_platform.remote(),
+                                   timeout=120)
+        check(cpu_platform == "cpu",
+              f"a worker without num_tpus runs on {cpu_platform}")
+
+        handle._refresh(force=True)
+        reports = [
+            ray_tpu.get(r.handle_method.remote(
+                "inspect", (prompts[0], [int(t) for t in outs[0]],
+                            [t.logprob for t in outs[0]]), {}),
+                timeout=PHASE_TIMEOUT_S)
+            for r in handle._replicas]
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+        stop_workers_or_fail()
+
+    emit({"phase": "serve", "replicas": reports, "streams": streams,
+          "new_tokens": new_tokens,
+          "prompt_lens": [len(p) for p in prompts],
+          "wall_s": round(wall_s, 2), "cpu_worker_platform": cpu_platform})
+    check(len(reports) == replicas, f"{len(reports)} replicas answered")
+    for rep in reports:
+        st = rep["stats"]
+        check(st["platform"] == platform,
+              f"replica runs on {st['platform']}, not {platform}")
+        check(st["decode_tokens"] > 0,
+              f"replica {rep['pid']} served no stream")
+        check(st["decode_traces"] == 1 and st["retraces_unexpected"] == 0,
+              f"compile-once broke: {st}")
+        check(rep["logprob_max_abs_diff"] <= LOGPROB_MAX_TOL
+              and rep["logprob_mean_abs_diff"] <= LOGPROB_MEAN_TOL,
+              f"engine logprobs are {rep['logprob_max_abs_diff']} (max) / "
+              f"{rep['logprob_mean_abs_diff']} (mean) from the float32 "
+              f"recompute (tolerances {LOGPROB_MAX_TOL} / "
+              f"{LOGPROB_MEAN_TOL})")
+        check(not rep["op_fallbacks"],
+              f"ops fell back to pure JAX: {rep['op_fallbacks']}")
+        if platform == "tpu":
+            check("_paged_kernel" in rep["kernels"]["decode_step_paged"]
+                  and rep["tpu_custom_calls"]["decode_step_paged"] > 0,
+                  f"no paged decode kernel: {rep['kernels']}")
+            check("_paged_mq_kernel" in rep["kernels"]["prefill_paged"]
+                  and rep["tpu_custom_calls"]["prefill_paged"] > 0,
+                  f"no paged prefill kernel: {rep['kernels']}")
+    scoped = [rep["stats"]["visible_chips"] for rep in reports]
+    check(len({rep["pid"] for rep in reports}) == replicas
+          and len(set(scoped)) == replicas,
+          f"replicas share a process or a chip: chips {scoped}")
+
+
+# ---------------------------------------------------------------------------
+# train phase
+# ---------------------------------------------------------------------------
+
+def seeded_batch(cfg, batch: int, seed: int) -> dict:
+    toks = np.random.default_rng(seed).integers(
+        0, cfg.vocab_size, (batch, cfg.max_seq_len + 1), np.int32)
+    return {"inputs": toks[:, :-1], "targets": toks[:, 1:]}
+
+
+def train_run(cfg, mesh_spec, devices, *, batch: int, steps: int,
+              unroll: int, seed: int, watch: CompileWatch) -> dict:
+    """`steps` steps of `cfg` on a mesh over `devices` through
+    `TrainLoop` with the prefetcher; returns what it measured for
+    `check_train_run`. The generator yields the same seeded batch every
+    step, so the loss has to fall strictly (fresh random tokens cannot
+    go below ln(vocab))."""
+    import jax
+
+    from ray_tpu.train import loop, spmd
+    mesh = mesh_spec.build(devices)
+    state, step_fn, _ = spmd.make_gpt_trainer(
+        cfg, mesh, rng=jax.random.key(seed),
+        optimizer=spmd.default_optimizer(warmup_steps=0))
+    host_batch = seeded_batch(cfg, batch, seed)
+
+    def host_batches():
+        while True:
+            yield host_batch
+
+    batches = loop.DevicePrefetcher(
+        host_batches(), loop.make_placer(mesh, stacked=True), depth=2,
+        group=unroll)
+    weight = state.params["layers"]["w_up"]
+    shard_devices = sorted(s.device.id for s in weight.addressable_shards)
+
+    # The fused dispatch `TrainLoop` builds, lowered here to read it. The
+    # loop's own compile of the same program is the second in-call run
+    # of the step: it must be answered by the persistent cache.
+    first = next(batches)
+    t0 = time.perf_counter()
+    kernels, n_calls, compiled = kernels_of(
+        loop.fuse_steps(step_fn, unroll), state, first)
+    lower_compile_s = time.perf_counter() - t0
+    text = compiled.as_text()
+    collectives = {op: text.count(f" {op}(") + text.count(f" {op}-start(")
+                   for op in ("all-reduce", "all-gather", "reduce-scatter",
+                              "all-to-all", "collective-permute")}
+    mem = compiled.memory_analysis()
+    hits_before = watch.cache_hits
+
+    train = loop.TrainLoop(step_fn, unroll=unroll, metrics_interval=unroll)
+
+    def chain(head, rest):
+        yield head
+        yield from rest
+
+    state, warm = train.run(state, chain(first, batches), num_steps=unroll)
+    t0 = time.perf_counter()
+    state, timed = train.run(state, batches, num_steps=steps - unroll)
+    step_s = (time.perf_counter() - t0) / (steps - unroll)   # run() drains
+    return {
+        "mesh": {k: v for k, v in mesh.shape.items() if v > 1},
+        "batch": batch, "unroll": unroll, "steps": steps,
+        "losses": [float(m["loss"]) for m in warm + timed],
+        "dispatch_traces": train.dispatch_traces,
+        "retraces_unexpected": train.stats()["retraces_unexpected"],
+        "kernels": kernels, "tpu_custom_calls": n_calls,
+        "collectives": collectives,
+        "weight_shard_devices": shard_devices,
+        "lower_compile_s": round(lower_compile_s, 2),
+        "cache_hits_on_second_compile": watch.cache_hits - hits_before,
+        "step_s": step_s,
+        "program_bytes": {"arguments": mem.argument_size_in_bytes,
+                          "temporaries": mem.temp_size_in_bytes},
+    }
+
+
+def check_train_run(run: dict, expect_kernels: set) -> None:
+    losses = run["losses"]
+    check(len(losses) == run["steps"],
+          f"{len(losses)} of {run['steps']} steps ran")
+    check(all(np.isfinite(losses)), f"non-finite loss: {losses}")
+    check(all(b < a for a, b in zip(losses, losses[1:])),
+          f"loss is not strictly falling: {losses}")
+    check(run["retraces_unexpected"] == 0 and run["dispatch_traces"] == 1,
+          f"the fused dispatch traced {run['dispatch_traces']} times")
+    check(expect_kernels <= set(run["kernels"])
+          and run["tpu_custom_calls"] >= len(expect_kernels),
+          f"kernels {run['kernels']} ({run['tpu_custom_calls']} custom "
+          f"calls), expected {sorted(expect_kernels)}")
+
+
+def first_loss_xla_dense(cfg, devices, *, batch: int, seed: int) -> float:
+    """Loss of the first step of the same model, seed and batch built
+    with XLA attention and the dense loss: what the kernels must match."""
+    import jax
+
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import spmd
+    ref_cfg = dataclasses.replace(cfg, attn_impl="xla", loss_impl="dense")
+    mesh = MeshSpec(data=1).build(devices[:1])
+    state, step_fn, shard = spmd.make_gpt_trainer(
+        ref_cfg, mesh, rng=jax.random.key(seed),
+        optimizer=spmd.default_optimizer(warmup_steps=0))
+    _, metrics = step_fn(state, shard(seeded_batch(cfg, batch, seed)))
+    return float(metrics["loss"])
+
+
+TRAIN_KERNELS = {"_flash_kernel", "_dq_kernel", "_dkv_kernel"}
+XENT_KERNELS = {"_fwd_kernel", "_dx_kernel", "_de_kernel"}
+
+
+def train_phase(cfg_kwargs: dict, *, platform: str, batch: int,
+                steps: int, seed: int) -> None:
+    """One chip: the bench.py configuration through the training loop,
+    checked against the XLA/dense step."""
+    import jax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.util.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    fallbacks = watch_op_fallbacks()
+    watch = CompileWatch()
+    device = device_report()
+    check(device["platform"] == platform,
+          f"train phase runs on {device['platform']}, not {platform}")
+    cfg = gpt.GPTConfig(**cfg_kwargs)
+    devices = jax.devices()[:1]
+    ref_loss = first_loss_xla_dense(cfg, devices, batch=batch, seed=seed)
+    run = train_run(cfg, MeshSpec(data=1), devices, batch=batch,
+                    steps=steps, unroll=4, seed=seed, watch=watch)
+    emit({"phase": "train", "device": device, **run,
+          "first_loss_xla_dense": ref_loss, "op_fallbacks": fallbacks,
+          "cache_dir": cache_dir, **watch.report(),
+          "peak_bytes_in_use": peak_bytes()})
+    on_tpu = platform == "tpu"
+    check_train_run(run, TRAIN_KERNELS | XENT_KERNELS if on_tpu else set())
+    check(abs(run["losses"][0] - ref_loss) <= LOSS_TOL,
+          f"first loss {run['losses'][0]} against {ref_loss} from the "
+          f"XLA/dense step (tolerance {LOSS_TOL})")
+    check(not fallbacks, f"ops fell back to pure JAX: {fallbacks}")
+    if on_tpu:
+        check(run["cache_hits_on_second_compile"] >= 1,
+              "the loop's compile of the dispatch missed the persistent "
+              "cache: its key moves")
+
+
+def train4_phase(cfg_kwargs: dict, *, platform: str, batch: int,
+                 steps: int, seed: int) -> None:
+    """Four chips: the same step on `MeshSpec(fsdp=2, tensor=2)` and on
+    one chip of the same host, same seed and batch."""
+    import jax
+
+    from ray_tpu.models import gpt
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.util.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    fallbacks = watch_op_fallbacks()
+    watch = CompileWatch()
+    device = device_report()
+    check(device["platform"] == platform and device["count"] == 4,
+          f"four-chip train phase sees {device}")
+    cfg = gpt.GPTConfig(**cfg_kwargs)
+    common = dict(batch=batch, steps=steps, unroll=4, seed=seed,
+                  watch=watch)
+    one = train_run(cfg, MeshSpec(data=1), jax.devices()[:1], **common)
+    one_fallbacks = list(fallbacks)
+    # Under tensor=2 each shard holds vocab/2 = 25152 embedding rows,
+    # which no 128-multiple block divides: the fused loss then has no
+    # kernel plan and says so (expected here, and printed).
+    four = train_run(cfg, MeshSpec(data=1, fsdp=2, tensor=2),
+                     jax.devices(), **common)
+    four_fallbacks = fallbacks[len(one_fallbacks):]
+    gaps = [abs(a - b) for a, b in zip(one["losses"], four["losses"])]
+    emit({"phase": "train4", "device": device, "one_chip": one,
+          "four_chips": four, "loss_gaps": gaps,
+          "op_fallbacks_one_chip": one_fallbacks,
+          "op_fallbacks_four_chips": four_fallbacks,
+          "cache_dir": cache_dir, **watch.report(),
+          "peak_bytes_in_use": peak_bytes()})
+    on_tpu = platform == "tpu"
+    check_train_run(one, TRAIN_KERNELS | XENT_KERNELS if on_tpu else set())
+    check_train_run(four, TRAIN_KERNELS if on_tpu else set())
+    check(not one_fallbacks, f"ops fell back on one chip: {one_fallbacks}")
+    check(max(gaps[:4]) <= LOSS_TOL,
+          f"four-chip losses {four['losses'][:4]} against one chip "
+          f"{one['losses'][:4]} (tolerance {LOSS_TOL})")
+    check(four["weight_shard_devices"] == sorted(
+        d.id for d in jax.devices()),
+        f"a weight's shards sit on {four['weight_shard_devices']}")
+    check(four["collectives"]["all-reduce"] > 0
+          and four["collectives"]["all-gather"] > 0,
+          f"expected collectives are missing: {four['collectives']}")
+
+
+# ---------------------------------------------------------------------------
+# the parent: owns no chip
+# ---------------------------------------------------------------------------
+
+PREFLIGHT = """
+import json, jax
+d = jax.devices()
+print(json.dumps({"platform": d[0].platform, "kind": d[0].device_kind,
+                  "count": len(d)}))
+"""
+
+
+def run_phase_child(phase: str, seed: int) -> None:
+    proc = subprocess.run(
+        [sys.executable, os.path.abspath(__file__), "--phase", phase,
+         "--seed", str(seed)], timeout=PHASE_TIMEOUT_S)
+    check(proc.returncode == 0,
+          f"{phase} phase exited with code {proc.returncode}")
+
+
+def main() -> int:
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
+    ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--phase", choices=("train", "train4"),
+                    help=argparse.SUPPRESS)   # how a phase child is started
+    args = ap.parse_args()
+
+    if args.phase == "train":
+        train_phase(TRAIN_CFG, platform="tpu", batch=8, steps=12,
+                    seed=args.seed)
+        return 0
+    if args.phase == "train4":
+        train4_phase(TRAIN_CFG, platform="tpu", batch=8, steps=8,
+                     seed=args.seed)
+        return 0
+
+    # Which device JAX finds, asked in a process that exits again.
+    found = subprocess.run([sys.executable, "-c", PREFLIGHT],
+                           stdout=subprocess.PIPE, text=True,
+                           timeout=PHASE_TIMEOUT_S)
+    if found.returncode != 0:
+        print("chip_smoke: JAX could not start", file=sys.stderr)
+        return 2
+    device = json.loads(found.stdout.strip().splitlines()[-1])
+    if device["platform"] != "tpu" or device["count"] != args.chips:
+        print(f"chip_smoke: needs {args.chips} TPU chip(s), JAX found "
+              f"{device}", file=sys.stderr)
+        return 2
+
+    so_path = os.path.join(os.path.dirname(native.__file__), "libstore.so")
+    had_so = os.path.exists(so_path)
+    emit({"phase": "host", "dev_accel": glob.glob("/dev/accel*"),
+          "dev_vfio": glob.glob("/dev/vfio/*"),
+          "chips_detected": ray_tpu._detect_tpu_chips(),
+          "native_arena": native.build_extension("store") is not None,
+          "native_arena_built_now": not had_so,
+          "JAX_COMPILATION_CACHE_DIR":
+              os.environ.get("JAX_COMPILATION_CACHE_DIR")})
+    try:
+        if args.chips == 1:
+            serve_phase(WIDTHS, platform="tpu", replicas=1, streams=6,
+                        prompt_lens=(128, 512), new_tokens=64, slots=8,
+                        max_len=1024, seed=args.seed)
+            run_phase_child("train", args.seed)
+        else:
+            run_phase_child("train4", args.seed)
+            serve_phase(WIDTHS, platform="tpu", replicas=2, streams=6,
+                        prompt_lens=(128, 512), new_tokens=64, slots=8,
+                        max_len=1024, seed=args.seed)
+    except BaseException:
+        emit({"ok": False, "device": device})
+        raise
+    emit({"ok": True, "device": device})
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

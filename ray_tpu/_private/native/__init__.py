@@ -8,9 +8,12 @@ Set RAY_TPU_DISABLE_NATIVE=1 to force the pure-Python fallbacks.
 
 from __future__ import annotations
 
+import logging
 import os
 import subprocess
 import threading
+
+logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _BUILD_LOCK = threading.Lock()
@@ -22,7 +25,8 @@ def native_disabled() -> bool:
 
 def build_extension(name: str) -> str | None:
     """Compile native/<name>.cc -> native/lib<name>.so if stale; return the
-    .so path, or None if native is disabled or the toolchain fails.
+    .so path, or None if native is disabled or the toolchain fails (the
+    failure is logged: callers then run their pure-Python fallbacks).
 
     RAY_TPU_SANITIZE=thread|address builds a separate sanitizer-
     instrumented library (lib<name>.tsan.so / .asan.so) — the stress
@@ -50,5 +54,9 @@ def build_extension(name: str) -> str | None:
                 check=True, capture_output=True, timeout=120)
             os.replace(tmp, out)  # atomic: concurrent builders race safely
             return out
-        except (OSError, subprocess.SubprocessError):
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = getattr(e, "stderr", None) or b""
+            logger.warning(
+                "native extension %s did not build, running without it: "
+                "%s %s", name, e, stderr[-400:].decode(errors="replace"))
             return None

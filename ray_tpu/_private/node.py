@@ -2882,6 +2882,8 @@ class NodeServer:
         candidates += [nid for nid, node in self.nodes.items()
                        if node_fits(node)]
         if not candidates:
+            if self._tpu_request_unmeetable(n_tpu):
+                return "__infeasible__"
             return None
         if strategy == "SPREAD":
             self._spread_rr += 1
@@ -2900,6 +2902,24 @@ class NodeServer:
             if arg_bytes.get(best, 0) > 0:
                 return best
         return candidates[0]
+
+    def _tpu_request_unmeetable(self, n_tpu: int) -> bool:
+        """True when a request for `n_tpu` chips would wait forever: a
+        driver-mode session is one host, and with no daemon registered
+        and no autoscaler attached nothing can ever add chips to it."""
+        return (n_tpu > self.total_resources.get("TPU", 0)
+                and not self.standalone and not self.nodes
+                and getattr(self, "_autoscaler", None) is None)
+
+    def _infeasible_reason(self, spec) -> str:
+        n_tpu = int(spec.resources.get("TPU", 0))
+        if self._tpu_request_unmeetable(n_tpu):
+            return (f"asks for {n_tpu} TPU chip(s) but this host has "
+                    f"{int(self.total_resources.get('TPU', 0))} (chips are "
+                    "counted from /dev/accel* or /dev/vfio/* at init(); "
+                    "pass init(num_tpus=...) or RAY_TPU_NUM_TPUS if that "
+                    "count is wrong)")
+        return "has hard node affinity to a dead or unknown node"
 
     def _needs_localize_locked(self, t: _TaskState) -> bool:
         """Head-local dispatch needs every ref arg readable in the head's
@@ -3013,8 +3033,8 @@ class NodeServer:
             self._store_error(
                 t.spec.return_ids,
                 SchedulingError(
-                    f"task {t.spec.function_desc} has hard node "
-                    "affinity to a dead or unknown node"),
+                    f"task {t.spec.function_desc} "
+                    + self._infeasible_reason(t.spec)),
                 spec=t.spec)
             return True     # consumed: removed from pending as failed
         if target != "head":
@@ -3119,7 +3139,7 @@ class NodeServer:
             return False
         if target == "__infeasible__":
             self._fail_actor(
-                a, "actor has hard node affinity to a dead or unknown node")
+                a, "actor " + self._infeasible_reason(t.spec))
             return True         # consumed: removed from pending as failed
         if target != "head":
             a.tpu_chips = self._debit_target(target, idx, req, n_tpu, pg)

@@ -49,6 +49,7 @@ import threading
 import time
 
 from ray_tpu._private import constants
+from ray_tpu._private.spawn import CHIP_SCOPE_VARS
 from ray_tpu.exceptions import RuntimeEnvSetupError
 
 from ray_tpu._private.constants import (
@@ -198,8 +199,8 @@ class RuntimeEnvManager:
             cmd_prefix = self._container_prefix(
                 container, runtime_env.get("env_vars") or {})
         if pypath:
-            # spawn.propagate_pythonpath places these first (after the
-            # worker sitecustomize) so the env wins over inherited paths
+            # spawn.propagate_pythonpath places these first so the env
+            # wins over inherited paths
             env["RAY_TPU_RUNTIME_ENV_PATHS"] = os.pathsep.join(pypath)
         return env, cwd, python_exe, cmd_prefix
 
@@ -390,9 +391,8 @@ class RuntimeEnvManager:
                   "-v", "/dev/shm:/dev/shm",
                   "-v", f"{pkg_root}:{pkg_root}:ro"]
         forward = ["RAY_TPU_AUTHKEY", "PYTHONPATH", "RAY_TPU_WORKER",
-                   "RAY_TPU_WORKER_FORCE_CPU", "JAX_PLATFORMS",
-                   "RAY_TPU_NODE_ID", "RAY_TPU_RUNTIME_ENV_PATHS",
-                   constants.TPU_VISIBLE_CHIPS_ENV, "TPU_PROCESS_BOUNDS"]
+                   "JAX_PLATFORMS", "RAY_TPU_NODE_ID",
+                   "RAY_TPU_RUNTIME_ENV_PATHS", *CHIP_SCOPE_VARS]
         forward += [str(k) for k in (env_vars or {})]
         for name in forward:
             prefix += ["--env", name]

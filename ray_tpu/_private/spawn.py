@@ -17,31 +17,52 @@ import time as _time
 from ray_tpu._private import constants
 
 
+# What scopes a worker to its chips; a container runtime env forwards these.
+CHIP_SCOPE_VARS = (constants.TPU_VISIBLE_CHIPS_ENV,
+                   "TPU_CHIPS_PER_PROCESS_BOUNDS", "TPU_PROCESS_BOUNDS")
+
+
+# TPU_CHIPS_PER_PROCESS_BOUNDS by chip count, as tried on a v5e 2x2 host
+# with libtpu 0.0.34 (PR 21): "1,2,1" was tried for chips 0,1 only, and
+# "2,1,1" is refused there.
+_CHIP_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+
+
+def chip_scope_env(chips) -> dict:
+    """Variables under which libtpu gives a process exactly `chips` of
+    this host (the reference scopes GPUs with CUDA_VISIBLE_DEVICES the
+    same way). Two chips of four also need the process bounds: with the
+    visible chips alone libtpu still expects the whole host's topology
+    ("expected 4, actual: 2"). Several one-chip processes run side by
+    side this way, each seeing its chip as device 0."""
+    env = {constants.TPU_VISIBLE_CHIPS_ENV: ",".join(map(str, chips))}
+    bounds = _CHIP_BOUNDS.get(len(chips))
+    if bounds:
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = bounds
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+    return env
+
+
 def worker_env(chips=None, runtime_env=None) -> dict:
     env = dict(os.environ)
     env["RAY_TPU_WORKER"] = "1"
     # Per-task/actor env overrides first (reference: runtime_env env_vars,
-    # _private/runtime_env/) so an explicit JAX_PLATFORMS override is
-    # visible to the FORCE_CPU decision below.
+    # _private/runtime_env/) so an explicit JAX_PLATFORMS override wins
+    # over the CPU default below.
     overrides = {
         str(k): str(v)
         for k, v in ((runtime_env or {}).get("env_vars") or {}).items()
     }
     env.update(overrides)
     if chips:
-        env[constants.TPU_VISIBLE_CHIPS_ENV] = ",".join(map(str, chips))
-        env["TPU_PROCESS_BOUNDS"] = ""
-    else:
+        env.update(chip_scope_env(chips))
+    elif "JAX_PLATFORMS" not in overrides:
         # Workers must not grab the host's TPU runtime by default: only
-        # tasks that requested TPU resources see chips (the reference hides
-        # GPUs the same way via CUDA_VISIBLE_DEVICES="").
-        # RAY_TPU_WORKER_FORCE_CPU drives worker_site/sitecustomize.py,
-        # which blocks accelerator plugin registration pre-jax-import.
-        if "JAX_PLATFORMS" not in overrides:
-            env["JAX_PLATFORMS"] = env.get(
-                "RAY_TPU_WORKER_JAX_PLATFORMS", "cpu")
-        if env["JAX_PLATFORMS"] == "cpu":
-            env["RAY_TPU_WORKER_FORCE_CPU"] = "1"
+        # tasks that requested TPU resources see chips (the reference
+        # hides GPUs the same way via CUDA_VISIBLE_DEVICES=""). A chip
+        # belongs to one process, so a CPU worker that initialised the
+        # TPU backend would take it from the worker it was given to.
+        env["JAX_PLATFORMS"] = "cpu"
     return env
 
 
@@ -53,16 +74,14 @@ def propagate_pythonpath(env: dict) -> dict:
     working_dir runtime env, services.py).
 
     Runtime-env paths (RAY_TPU_RUNTIME_ENV_PATHS: working_dir, py_modules,
-    pip-venv site-packages) go FIRST, right after the worker sitecustomize
-    — a runtime env must be able to shadow the parent's installed
-    packages, or pip:["pkg==2.0"] silently resolves to the base image's
-    pkg 1.0."""
+    pip-venv site-packages) go FIRST — a runtime env must be able to
+    shadow the parent's installed packages, or pip:["pkg==2.0"] silently
+    resolves to the base image's pkg 1.0."""
     pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))))
-    worker_site = os.path.join(pkg_root, "ray_tpu", "_private", "worker_site")
     rt_paths = [p for p in env.get(
         "RAY_TPU_RUNTIME_ENV_PATHS", "").split(os.pathsep) if p]
-    entries = [worker_site] + rt_paths + [pkg_root]
+    entries = rt_paths + [pkg_root]
     entries += [p for p in sys.path if p]
     pypath = env.get("PYTHONPATH", "")
     entries += [p for p in pypath.split(os.pathsep) if p]
@@ -166,9 +185,7 @@ class _ForkServerClient:
                             f"ray_tpu_fs_{os.getpid()}.sock")
         env = propagate_pythonpath(dict(os.environ))
         env["RAY_TPU_AUTHKEY"] = authkey.hex()
-        # the factory itself is a CPU process; the worker site hook keeps
-        # platform plugins (and their 2s jax import) out of it
-        env["RAY_TPU_WORKER_FORCE_CPU"] = "1"
+        # the factory itself is a CPU process
         env["JAX_PLATFORMS"] = "cpu"
         try:
             # stdio INHERITED (not piped): forked children without a log
@@ -226,9 +243,9 @@ def _fork_eligible(env: dict, python_exe, cwd,
                    cmd_prefix=None) -> bool:
     """Fork only the common case: CPU worker, default interpreter, no
     runtime-env path/cwd overrides, no container wrapper. TPU workers
-    must gate plugin registration before ANY import (env decides at
-    exec time), and venv/conda/container workers need their own
-    interpreter/command line."""
+    get their chip scoping from the environment at exec time, and
+    venv/conda/container workers need their own interpreter/command
+    line."""
     return (python_exe is None and cwd is None and cmd_prefix is None
             and not env.get("RAY_TPU_RUNTIME_ENV_PATHS")
             and constants.TPU_VISIBLE_CHIPS_ENV not in env

@@ -598,6 +598,10 @@ def run(address: str, worker_id: str):
     forkserver child (forkserver.py) — the child passes args directly
     instead of re-parsing argv."""
     authkey = bytes.fromhex(os.environ["RAY_TPU_AUTHKEY"])
+    if os.environ.get("TPU_VISIBLE_CHIPS"):
+        # a chip worker compiles for its chip: keep what it compiles
+        from ray_tpu.util.compile_cache import enable_compile_cache
+        enable_compile_cache()
     rt = WorkerRuntime(address, worker_id, authkey)
     _tracing.set_process_label(f"worker:{worker_id}")
     rt.send(protocol.RegisterWorker(worker_id, os.getpid()))

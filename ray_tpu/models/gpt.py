@@ -191,7 +191,23 @@ def _attention(q, k, v, cfg: GPTConfig, mesh: Mesh | None):
         out = ring_attention(q, k, v, mesh, causal=True)
     elif impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
-        out = flash_attention(q, k, v, causal=True)
+        attend = partial(flash_attention, causal=True)
+        if mesh is not None and mesh.size > 1:
+            # The compiler cannot partition a Pallas kernel; attention
+            # is independent per (sequence, head), so each device runs
+            # the kernel on its own batch rows and heads.
+            from ray_tpu.parallel.sharding import (
+                logical_to_spec,
+                shard_map,
+                valid_spec_for,
+            )
+            # a dim its mesh axes do not divide stays whole per device
+            spec = valid_spec_for(
+                mesh, logical_to_spec(("batch", None, "heads", None),
+                                      mesh=mesh), q.shape)
+            attend = shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
+                               out_specs=spec, check_vma=False)
+        out = attend(q, k, v)
     else:
         out = reference_attention(q, k, v, causal=True)
     # Named for the remat policy: saving attention outputs means the bwd
@@ -261,11 +277,9 @@ def forward_features(params, tokens, cfg: GPTConfig,
         x, kv = jax.lax.scan(scan_body_kv, x, params["layers"])
         return _rms_norm(x, params["final_ln_scale"].astype(adt)), kv
     if cfg.remat:
-        # Measured on v5e (B=16, T=1024 bench shape): save-nothing beats
-        # save_only_these_names("attn_out") and no remat — the recomputed
-        # forward overlaps with backward HBM traffic, so saving
-        # activations often only adds bandwidth. remat_policy exposes the
-        # alternatives for shapes where recompute dominates instead.
+        # Which policy is fastest is not measured on this round's chip:
+        # save-nothing recomputes the forward under the backward's HBM
+        # traffic, the others trade memory for skipped recompute.
         policy = None
         if cfg.remat_policy == "dots":
             policy = jax.checkpoint_policies \

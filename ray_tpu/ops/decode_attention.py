@@ -18,8 +18,8 @@ decode-specific twists:
 
 - **position masking**: each sequence attends to cache positions
   ``<= pos[b]`` (its current token's position — the caller writes the new
-  K/V at ``pos`` *before* attending). ``pos`` rides in as a per-row
-  [BH, 128] i32 tile (the fused_xent `_rows128` idiom).
+  K/V at ``pos`` *before* attending). ``pos [B]`` rides in as scalar
+  prefetch, like the paged kernels' tables.
 - **data-dependent block skip**: kv blocks strictly past ``pos`` are
   predicated away with ``pl.when(k_start <= pos)`` — a *runtime* branch,
   unlike flash's static causal predicate — so short sequences in a long
@@ -72,14 +72,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ray_tpu.ops import backend
 from ray_tpu.ops.flash_attention import (
-    _CompilerParams,
     _head_pad_target,
     _pad_heads,
     _pick_block,
 )
 
 NEG_INF = -1e30
+
+
+def _auto_impl(op: str, has_plan: bool, why: str) -> str:
+    """Resolve ``impl="auto"``: the kernel on a TPU backend, the
+    pure-JAX path elsewhere. A TPU shape with no plan is recorded."""
+    if not has_plan:
+        backend.note_fallback(op, why)
+    return "pallas" if backend.on_tpu() and has_plan else "jax"
 
 
 # ---------------------------------------------------------------------------
@@ -107,9 +115,9 @@ def reference_decode_attention(q, k, v, pos):
 # pallas kernel
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
+def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
                    m_scr, l_scr, acc_scr, *, sm_scale: float,
-                   block_kv: int):
+                   block_kv: int, n_heads: int):
     ki = pl.program_id(1)
 
     @pl.when(ki == 0)
@@ -118,7 +126,7 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
-    pos = pos_ref[0, 0]
+    pos = pos_ref[pl.program_id(0) // n_heads]
     k_start = ki * block_kv
 
     # Runtime predicate: blocks wholly past this row's position contribute
@@ -158,31 +166,33 @@ def _decode_kernel(q_ref, k_ref, v_ref, pos_ref, o_ref,
 
 
 def _decode_bhsd(q, k, v, pos, *, sm_scale: float, block_kv: int,
-                 interpret: bool):
-    """q [BH, 1, D]; k, v [BH, S, D]; pos [BH, 128] i32 -> [BH, 1, D]."""
+                 n_heads: int, interpret: bool):
+    """q [BH, 1, D]; k, v [BH, S, D]; pos [B] i32 -> [BH, 1, D]."""
     bh, s, d = k.shape
-    grid = (bh, s // block_kv)
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale,
-                          block_kv=block_kv),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        grid=grid,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(bh, s // block_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j: (b, j, 0)),
-            pl.BlockSpec((1, 128), lambda b, j: (b, 0)),
+            pl.BlockSpec((1, 1, d), lambda b, j, ps: (b, 0, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, j, ps: (b, j, 0)),
+            pl.BlockSpec((1, block_kv, d), lambda b, j, ps: (b, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, j: (b, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, d), lambda b, j, ps: (b, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((8, 128), jnp.float32),    # m (cell [0, 0] used)
             pltpu.VMEM((8, 128), jnp.float32),    # l
             pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 used)
         ],
-        compiler_params=_CompilerParams(
+    )
+    return pl.pallas_call(
+        functools.partial(_decode_kernel, sm_scale=sm_scale,
+                          block_kv=block_kv, n_heads=n_heads),
+        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+        grid_spec=grid_spec,
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(q, k, v, pos)
+    )(pos, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -206,8 +216,8 @@ def decode_attention(q, k, v, pos, *, impl: str = "auto",
     b, s, h, d = k.shape
     bkv = _pick_block(s, block_kv)
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and bkv is not None) else "jax"
+        impl = _auto_impl("decode_attention", bkv is not None,
+                          f"cache length {s}")
     if impl == "jax":
         return reference_decode_attention(q, k, v, pos)
     if impl != "pallas":
@@ -217,7 +227,7 @@ def decode_attention(q, k, v, pos, *, impl: str = "auto",
     if bkv is None:
         raise ValueError(
             f"cache length {s} has no pallas block plan; use impl='jax'")
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     # [B, S, H, D] -> [B*H, S, D]: (S, D) become the trailing tile per
     # row. On TPU this is one cache-sized transpose per call — the price
@@ -226,11 +236,9 @@ def decode_attention(q, k, v, pos, *, impl: str = "auto",
     kt = _pad_heads(k, d_pad).transpose(0, 2, 1, 3).reshape(b * h, s, d_pad)
     vt = _pad_heads(v, d_pad).transpose(0, 2, 1, 3).reshape(b * h, s, d_pad)
     qt = _pad_heads(q, d_pad).reshape(b * h, 1, d_pad)
-    pos_rows = jnp.broadcast_to(
-        pos.astype(jnp.int32).reshape(b, 1, 1), (b, h, 128)
-    ).reshape(b * h, 128)
-    out = _decode_bhsd(qt, kt, vt, pos_rows, sm_scale=d ** -0.5,
-                       block_kv=bkv, interpret=interpret)
+    out = _decode_bhsd(qt, kt, vt, pos.astype(jnp.int32),
+                       sm_scale=d ** -0.5, block_kv=bkv, n_heads=h,
+                       interpret=interpret)
     return out.reshape(b, h, d_pad)[..., :d]
 
 
@@ -279,6 +287,25 @@ def reference_paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     return reference_decode_attention(q, k_seq, v_seq, pos)
 
 
+def _scale_spec(n_heads: int, bs: int):
+    """BlockSpec of an int8 pool's scales, head-major [n_blocks, H, bs]:
+    every head's row of one physical block, fetched through the same
+    table dereference as the payload. Mosaic wants a block's last two
+    dims (8, 128)-divisible or equal to the array's, which (H, bs) is
+    and a single head's (1, bs) row is not."""
+    return pl.BlockSpec((1, n_heads, bs),
+                        lambda i, j, tbl, ps: (tbl[i // n_heads, j], 0, 0))
+
+
+def _scale_row(scale_ref, head):
+    """This head's [1, bs] scale row of a `_scale_spec` block. A row,
+    not a column: a per-position K scale multiplies the score columns
+    (``q . (k_j * s_j) == (q . k_j) * s_j``) and a V scale the
+    probabilities (``p @ (v * s) == (p * s) @ v``), so dequantization
+    needs no lane-to-sublane relayout and no [bs, D] multiply."""
+    return scale_ref[0, pl.ds(head, 1), :].astype(jnp.float32)
+
+
 def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                   sm_scale: float, block_size: int, n_heads: int,
                   quantized: bool):
@@ -296,6 +323,7 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     pos = pos_ref[pl.program_id(0) // n_heads]
+    head = pl.program_id(0) % n_heads
     k_start = ji * block_size     # LOGICAL position of this kv block --
     # the BlockSpec index maps already dereferenced tbl_ref, so k_ref
     # holds the right physical block; masking stays in logical space.
@@ -304,14 +332,12 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
     def _body():
         q = q_ref[0].astype(jnp.float32)            # [1, D]
         k = k_ref[0, 0].astype(jnp.float32)         # [bs, D]
-        if quantized:
-            # Per-row dequant in VMEM: the int8 payload and its f32
-            # scale column rode the same table-dereferenced DMA.
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q * sm_scale, k,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)     # [1, bs]
+        if quantized:
+            s = s * _scale_row(ks_ref, head)
         col = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         s = jnp.where(col <= pos, s, NEG_INF)
         m_prev = m_scr[:1, :1]
@@ -323,8 +349,8 @@ def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         m_scr[:1, :1] = m_new
         v = v_ref[0, 0]
         if quantized:
-            v = v.astype(jnp.float32) * \
-                vs_ref[0, 0].astype(jnp.float32)[:, None]
+            p = p * _scale_row(vs_ref, head)
+            v = v.astype(jnp.float32)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -362,12 +388,7 @@ def _paged_bhsd(q, k, v, tables, pos, *, sm_scale: float, n_heads: int,
     ]
     operands = [tables, pos, q, k, v]
     if quantized:
-        # The scale column rides the same table-dereferenced schedule as
-        # its payload block, one [bs] row per (block, head).
-        scale_spec = pl.BlockSpec((1, 1, bs),
-                                  lambda i, j, tbl, ps: (tbl[i // h, j],
-                                                         i % h, 0))
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(h, bs)] * 2
         operands += [ks, vs]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -386,7 +407,7 @@ def _paged_bhsd(q, k, v, tables, pos, *, sm_scale: float, n_heads: int,
                           quantized=quantized),
         out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -442,18 +463,19 @@ def _paged_mq_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         acc_scr[:] = jnp.zeros_like(acc_scr)
 
     pos = pos_ref[pl.program_id(0) // n_heads]
+    head = pl.program_id(0) % n_heads
     k_start = ji * block_size
 
     @pl.when(k_start <= pos + w_real - 1)
     def _body():
         q = q_ref[0].astype(jnp.float32)            # [Wp, D]
         k = k_ref[0, 0].astype(jnp.float32)         # [bs, D]
-        if quantized:
-            k = k * ks_ref[0, 0].astype(jnp.float32)[:, None]
         s = jax.lax.dot_general(
             q * sm_scale, k,
             dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)     # [Wp, bs]
+        if quantized:
+            s = s * _scale_row(ks_ref, head)
         col = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
         # Padded q rows (>= w_real) reuse the last real row's mask so
         # they keep >= 1 live column (l stays nonzero); their output is
@@ -470,8 +492,8 @@ def _paged_mq_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
         m_scr[:, :1] = m_new
         v = v_ref[0, 0]
         if quantized:
-            v = v.astype(jnp.float32) * \
-                vs_ref[0, 0].astype(jnp.float32)[:, None]
+            p = p * _scale_row(vs_ref, head)
+            v = v.astype(jnp.float32)
         pv = jax.lax.dot_general(
             p.astype(v.dtype), v,
             dimension_numbers=(((1,), (0,)), ((), ())),
@@ -507,10 +529,7 @@ def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
     ]
     operands = [tables, pos, q, k, v]
     if quantized:
-        scale_spec = pl.BlockSpec((1, 1, bs),
-                                  lambda i, j, tbl, ps: (tbl[i // h, j],
-                                                         i % h, 0))
-        in_specs += [scale_spec, scale_spec]
+        in_specs += [_scale_spec(h, bs)] * 2
         operands += [ks, vs]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
@@ -530,7 +549,7 @@ def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
                           quantized=quantized),
         out_shape=jax.ShapeDtypeStruct((bh, wp, d), q.dtype),
         grid_spec=grid_spec,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(*operands)
@@ -576,8 +595,8 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
     b, w, h, d = q.shape
     bs = k_pool.shape[1]
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and bs % 8 == 0) else "jax"
+        impl = _auto_impl("paged_verify_attention", bs % 8 == 0,
+                          f"block_size {bs}")
     if impl == "jax":
         return reference_paged_verify_attention(
             q, k_pool, v_pool, tables, pos,
@@ -589,7 +608,7 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
     if bs % 8 != 0:
         raise ValueError(
             f"block_size {bs} is not a multiple of 8; use impl='jax'")
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     wp = max(8, ((w + 7) // 8) * 8)
     kt = _pad_heads(k_pool, d_pad).transpose(0, 2, 1, 3)
@@ -637,8 +656,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     b, h, d = q.shape
     bs = k_pool.shape[1]
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and bs % 8 == 0) else "jax"
+        impl = _auto_impl("paged_decode_attention", bs % 8 == 0,
+                          f"block_size {bs}")
     if impl == "jax":
         return reference_paged_decode_attention(
             q, k_pool, v_pool, tables, pos,
@@ -650,7 +669,7 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     if bs % 8 != 0:
         raise ValueError(
             f"block_size {bs} is not a multiple of 8; use impl='jax'")
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     # [n_blocks, bs, H, D] -> head-major [n_blocks, H, bs, D]: the
     # kernel's per-(row, block) tile is (bs, D) for one head.
@@ -735,8 +754,8 @@ def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
     c, h, d = q.shape
     bs = k_pool.shape[1]
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and bs % 8 == 0) else "jax"
+        impl = _auto_impl("paged_prefill_attention", bs % 8 == 0,
+                          f"block_size {bs}")
     if impl == "jax":
         return reference_paged_prefill_attention(
             q, k_pool, v_pool, table, start,
@@ -748,7 +767,7 @@ def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
     if bs % 8 != 0:
         raise ValueError(
             f"block_size {bs} is not a multiple of 8; use impl='jax'")
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     wp = max(8, ((c + 7) // 8) * 8)
     kt = _pad_heads(k_pool, d_pad).transpose(0, 2, 1, 3)

@@ -41,12 +41,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x ships the TPU params dataclass as TPUCompilerParams; newer
-# releases renamed it CompilerParams. Resolve once so the kernels run
-# on both.
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
-
+from ray_tpu.ops import backend
 from ray_tpu.parallel.ring_attention import reference_attention
 
 NEG_INF = -1e30
@@ -148,7 +143,7 @@ def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, block_q: int,
             pltpu.VMEM((block_q, 128), jnp.float32),   # l
             pltpu.VMEM((block_q, d), jnp.float32),     # acc
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -270,7 +265,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
         in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -288,7 +283,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
         out_specs=(kspec2, kspec2),
         scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                         pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v, do, lse, delta)
@@ -341,11 +336,11 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 1024,
     TPU-unfriendly shapes. Fully differentiable: both directions are
     Pallas kernels (backward = dQ + dKV kernels over saved lse).
 
-    Default blocks measured on v5e at the bench shape (B=8, T=1024, H=16,
-    D=64): 1024/1024 > 512/1024 > 512/512 ≈ 128/128 on full train-step
-    throughput (55.1k vs 51.3k vs 28.2k tok/s for the pre-backward-kernel
-    XLA-recompute path). Blocks shrink to the largest divisor of T, so
-    ragged sequence lengths stay on the kernel path."""
+    The default blocks have not been measured on this round's chip.
+    Blocks shrink to the largest divisor of T, so ragged sequence
+    lengths stay on the kernel path. The kernels cannot be partitioned
+    by the compiler: on a mesh of more than one device the caller wraps
+    this in a shard_map (`models.gpt._attention` does)."""
     out, _ = _flash_forward_impl(q, k, v, causal, block_q, block_kv,
                                  with_lse=False)
     return out
@@ -358,9 +353,10 @@ def _flash_forward_impl(q, k, v, causal, block_q, block_kv, with_lse):
     b, t, h, d = q.shape
     plan = _plan_blocks(t, block_q, block_kv)
     if plan is None:
+        backend.note_fallback("flash_attention", f"T={t}")
         return reference_attention(q, k, v, causal=causal), None
     block_q, block_kv = plan
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     bhtd = lambda x: (_pad_heads(x, d_pad)
                       .transpose(0, 2, 1, 3).reshape(b * h, t, d_pad))
@@ -378,11 +374,11 @@ def _flash_fwd(q, k, v, causal, block_q, block_kv):
     if lse is None:
         return out, (q, k, v, None, None)
     # The residual keeps the kernel's broadcast [BH, T, 128] lse layout.
-    # Slicing to [BH, T] and re-broadcasting in bwd costs ~3% step time
-    # (two extra 64 MB passes per layer at bench shape, measured 55.1k ->
-    # 53.5k tok/s); under the default per-layer remat the residual only
-    # lives within one layer's backward, so the 128x is transient. A
-    # no-remat long-T config that can't afford it should slice here.
+    # Slicing to [BH, T] and re-broadcasting in bwd would add two
+    # passes per layer (64 MB each at bench shape; cost not measured);
+    # under the default per-layer remat the residual only lives within
+    # one layer's backward, so the 128x is transient. A no-remat long-T
+    # config that can't afford it should slice here.
     return out, (q, k, v, out, lse)
 
 
@@ -396,7 +392,7 @@ def _flash_bwd(causal, block_q, block_kv, res, g):
 
     b, t, h, d = q.shape
     block_q, block_kv = _plan_blocks(t, block_q, block_kv)
-    interpret = jax.default_backend() != "tpu"
+    interpret = backend.interpret()
     d_pad = _head_pad_target(d)
     # delta_i = rowsum(dO_i * O_i) — O(T*D) traffic, fine in XLA.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),

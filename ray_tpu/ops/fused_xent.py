@@ -25,15 +25,16 @@ Two implementations share that math:
   blocks, per-row m/l/target-logit accumulators in VMEM scratch),
   mirroring flash_attention.py's structure.
 - **scan**: a pure-JAX `lax.scan` over vocab chunks — the
-  everywhere-correct fallback that CPU CI and `bench.py --smoke` run.
+  everywhere-correct path, and what `impl="auto"` picks off a TPU.
 
-Vocab-sharded (tensor-parallel) embeddings compose through a
-`shard_map` wrapper: each shard reduces its *local* vocab rows to a
-partial logsumexp and partial target logit, then one psum over the
-vocab mesh axis combines them (`parallel/sharding.fused_xent_specs`
-derives the specs from the rule table). The collective moves two
-``[B, T]`` f32 arrays — vs. the dense path's vocab-sharded logits
-gather/reduction over ``[B, T, V]``.
+On a mesh of more than one device the op runs under `shard_map` (the
+compiler cannot partition a Pallas kernel): tokens split over the batch
+axes, and a vocab-sharded (tensor-parallel) embedding stays sharded —
+each shard reduces its *local* vocab rows to a partial logsumexp and
+partial target logit, then one psum over the vocab mesh axis combines
+them (`parallel/sharding.fused_xent_specs` derives the specs from the
+rule table). The collective moves two ``[B, T]`` f32 arrays — vs. the
+dense path's vocab-sharded logits gather/reduction over ``[B, T, V]``.
 """
 
 from __future__ import annotations
@@ -46,10 +47,7 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# TPUCompilerParams (jax 0.4.x) vs CompilerParams (newer) — same
-# resolve-once shim as flash_attention.py
-_CompilerParams = getattr(pltpu, "CompilerParams", None) \
-    or pltpu.TPUCompilerParams
+from ray_tpu.ops import backend
 
 _NEG = -1e30   # finite -inf stand-in: exp(_NEG - m) underflows to 0
 
@@ -246,7 +244,7 @@ def _lse_tgt_pallas(x, embed, targets, block_n, block_v, interpret):
         ],
         out_specs=(row_spec, row_spec),
         scratch_shapes=[pltpu.VMEM((block_n, 128), jnp.float32)] * 3,
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x.reshape(n, d), embed, _rows128(targets.astype(jnp.int32), n))
@@ -276,7 +274,7 @@ def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, block_n, block_v,
         ],
         out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
         scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x2, embed, t2, lse2, cl2, ct2)
@@ -295,7 +293,7 @@ def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, block_n, block_v,
         ],
         out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
         scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(x2, embed, t2, lse2, cl2, ct2)
@@ -328,8 +326,11 @@ def _resolve_impl(impl: str, n: int, v: int, chunk: int):
     doubles as the preferred pallas vocab block."""
     plan = _plan(n, v, block_n=256, block_v=max(chunk, 128))
     if impl == "auto":
-        impl = "pallas" if (jax.default_backend() == "tpu"
-                            and plan is not None) else "scan"
+        if plan is None:
+            backend.note_fallback("fused_softmax_xent",
+                                  f"rows={n}, vocab rows={v}")
+        impl = "pallas" if backend.on_tpu() and plan is not None \
+            else "scan"
     if impl == "scan":
         return "scan", chunk
     if impl == "pallas":
@@ -349,7 +350,7 @@ def _lse_tgt_impl(x, embed, targets, chunk, impl):
     if kind == "scan":
         return _lse_tgt_scan(x, embed, targets, arg)
     return _lse_tgt_pallas(x, embed, targets, *arg,
-                           interpret=jax.default_backend() != "tpu")
+                           interpret=backend.interpret())
 
 
 def _bwd_impl(x, embed, targets, lse, c_lse, c_tgt, chunk, impl):
@@ -360,7 +361,7 @@ def _bwd_impl(x, embed, targets, lse, c_lse, c_tgt, chunk, impl):
     if kind == "scan":
         return _bwd_scan(x, embed, targets, lse, c_lse, c_tgt, arg)
     return _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, *arg,
-                       interpret=jax.default_backend() != "tpu")
+                       interpret=backend.interpret())
 
 
 def _int_zero(targets):
@@ -395,8 +396,12 @@ _lse_and_target.defvjp(_lse_and_target_fwd, _lse_and_target_bwd)
 
 
 # ---------------------------------------------------------------------------
-# vocab-sharded (tensor-parallel) composition
+# sharded composition: any mesh of more than one device
 # ---------------------------------------------------------------------------
+# The compiler cannot partition a Pallas kernel, so on a mesh the loss
+# runs under shard_map whatever the axes: tokens split over the batch
+# axes, and the embedding over the vocab axes when the rules shard it
+# (tensor parallelism), with one psum of the partial terms.
 
 def _flat_axes(spec):
     out = []
@@ -407,22 +412,31 @@ def _flat_axes(spec):
     return tuple(out)
 
 
-def _tp_nll_and_lse(x, embed, targets, mesh, specs, vocab_axis, chunk,
-                    impl):
+def _local_targets(ts, es, vocab_axes):
+    """Target ids relative to this shard's vocab rows (out of range on
+    every shard but the owner, which the kernels treat as no hit)."""
+    if not vocab_axes:
+        return ts
+    return ts - jax.lax.axis_index(vocab_axes) * es.shape[0]
+
+
+def _sharded_nll_and_lse(x, embed, targets, mesh, specs, chunk, impl):
     from ray_tpu.parallel.sharding import shard_map
     x_spec, e_spec, t_spec = specs
+    vocab_axes = _flat_axes(e_spec[:1])
 
     def fwd(xs, es, ts):
-        vloc = es.shape[0]
-        base = jax.lax.axis_index(vocab_axis) * vloc
-        lse_p, tgt_p = _lse_tgt_impl(xs, es, ts - base, chunk, impl)
-        # psum of the partial log-sum-exp terms over the vocab axis,
-        # max-shifted for stability; the partial target logit is nonzero
-        # on exactly the shard owning the id, so a plain psum recovers it
-        mg = jax.lax.pmax(lse_p, vocab_axis)
-        lse = mg + jnp.log(
-            jax.lax.psum(jnp.exp(lse_p - mg), vocab_axis))
-        tgt = jax.lax.psum(tgt_p, vocab_axis)
+        lse, tgt = _lse_tgt_impl(
+            xs, es, _local_targets(ts, es, vocab_axes), chunk, impl)
+        if vocab_axes:
+            # psum of the partial log-sum-exp terms over the vocab
+            # axes, max-shifted for stability; the partial target logit
+            # is nonzero on exactly the shard owning the id, so a plain
+            # psum recovers it
+            mg = jax.lax.pmax(lse, vocab_axes)
+            lse = mg + jnp.log(
+                jax.lax.psum(jnp.exp(lse - mg), vocab_axes))
+            tgt = jax.lax.psum(tgt, vocab_axes)
         return lse - tgt, lse
 
     f = shard_map(fwd, mesh=mesh, in_specs=(x_spec, e_spec, t_spec),
@@ -430,35 +444,33 @@ def _tp_nll_and_lse(x, embed, targets, mesh, specs, vocab_axis, chunk,
     return f(x, embed, targets)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _fused_xent_tp(x, embed, targets, mesh, specs, vocab_axis, chunk,
-                   impl):
-    nll, _ = _tp_nll_and_lse(x, embed, targets, mesh, specs, vocab_axis,
-                             chunk, impl)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
+def _fused_xent_sharded(x, embed, targets, mesh, specs, chunk, impl):
+    nll, _ = _sharded_nll_and_lse(x, embed, targets, mesh, specs, chunk,
+                                  impl)
     return nll
 
 
-def _fused_xent_tp_fwd(x, embed, targets, mesh, specs, vocab_axis, chunk,
-                       impl):
-    nll, lse = _tp_nll_and_lse(x, embed, targets, mesh, specs, vocab_axis,
-                               chunk, impl)
+def _fused_xent_sharded_fwd(x, embed, targets, mesh, specs, chunk, impl):
+    nll, lse = _sharded_nll_and_lse(x, embed, targets, mesh, specs,
+                                    chunk, impl)
     return nll, (x, embed, targets, lse)
 
 
-def _fused_xent_tp_bwd(mesh, specs, vocab_axis, chunk, impl, res, g):
+def _fused_xent_sharded_bwd(mesh, specs, chunk, impl, res, g):
     from ray_tpu.parallel.sharding import shard_map
     x, embed, targets, lse = res
     x_spec, e_spec, t_spec = specs
+    vocab_axes = _flat_axes(e_spec[:1])
     # dembed sums over every axis that shards tokens (its batch
     # reduction); dx sums the per-vocab-shard partials
     batch_axes = _flat_axes(t_spec)
 
     def bwd(xs, es, ts, lse_s, gs):
-        vloc = es.shape[0]
-        base = jax.lax.axis_index(vocab_axis) * vloc
-        dx_p, de = _bwd_impl(xs, es, ts - base, lse_s, gs, -gs, chunk,
-                             impl)
-        dx = jax.lax.psum(dx_p, vocab_axis)
+        dx, de = _bwd_impl(xs, es, _local_targets(ts, es, vocab_axes),
+                           lse_s, gs, -gs, chunk, impl)
+        if vocab_axes:
+            dx = jax.lax.psum(dx, vocab_axes)
         if batch_axes:
             de = jax.lax.psum(de, batch_axes)
         return dx.astype(xs.dtype), de.astype(es.dtype)
@@ -471,7 +483,7 @@ def _fused_xent_tp_bwd(mesh, specs, vocab_axis, chunk, impl, res, g):
     return dx, de, _int_zero(targets)
 
 
-_fused_xent_tp.defvjp(_fused_xent_tp_fwd, _fused_xent_tp_bwd)
+_fused_xent_sharded.defvjp(_fused_xent_sharded_fwd, _fused_xent_sharded_bwd)
 
 
 # ---------------------------------------------------------------------------
@@ -489,23 +501,26 @@ def fused_softmax_xent(x, embed, targets, *, vocab_chunk: int = 512,
     activations, ``embed [V, d_model]`` the tied embedding, and the
     implied logits are ``x @ embed.T`` accumulated in f32.
 
-    With a `mesh` whose vocab rule axis (default ``tensor``) is >1-way,
-    the embedding stays vocab-sharded: each shard reduces its local rows
-    and one psum of the partial log-sum-exp / target-logit terms over
-    that axis combines them (see `parallel.sharding.fused_xent_specs`).
+    With a `mesh` of more than one device the op runs under shard_map
+    (`parallel.sharding.fused_xent_specs`): tokens split over the batch
+    axes, and where the rules shard the vocab (default: over ``tensor``)
+    each shard reduces its local rows and one psum of the partial
+    log-sum-exp / target-logit terms combines them.
     """
     if x.ndim != 3 or embed.ndim != 2:
         raise ValueError(
             f"fused_softmax_xent wants x [B, T, D] and embed [V, D]; got "
             f"{x.shape} and {embed.shape}")
-    if mesh is not None:
-        from ray_tpu.parallel.sharding import fused_xent_specs
-        specs = fused_xent_specs(mesh, rules)
-        vocab_axis = specs[1][0]
-        if (isinstance(vocab_axis, str)
-                and mesh.shape.get(vocab_axis, 1) > 1
-                and embed.shape[0] % mesh.shape[vocab_axis] == 0):
-            return _fused_xent_tp(x, embed, targets, mesh, specs,
-                                  vocab_axis, vocab_chunk, impl)
+    if mesh is not None and mesh.size > 1:
+        from ray_tpu.parallel.sharding import (
+            fused_xent_specs,
+            valid_spec_for,
+        )
+        # a dim its mesh axes do not divide stays whole on every shard
+        specs = tuple(
+            valid_spec_for(mesh, spec, a.shape) for spec, a in zip(
+                fused_xent_specs(mesh, rules), (x, embed, targets)))
+        return _fused_xent_sharded(x, embed, targets, mesh, specs,
+                                   vocab_chunk, impl)
     lse, tgt = _lse_and_target(x, embed, targets, vocab_chunk, impl)
     return lse - tgt

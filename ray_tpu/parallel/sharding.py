@@ -31,18 +31,8 @@ DEFAULT_RULES: dict[str, object] = {
 }
 
 
-def shard_map(f, *, mesh: Mesh, in_specs, out_specs, check_vma=True):
-    """shard_map across jax versions: newer releases expose
-    ``jax.shard_map(..., check_vma=)``; 0.4.x has
-    ``jax.experimental.shard_map.shard_map(..., check_rep=)`` (the same
-    knob under its old name). Every shard_map in the tree goes through
-    here so the version probe lives in one place."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
+# Every shard_map in the tree is imported from here.
+shard_map = jax.shard_map
 
 
 def _mesh_axes(mesh: Mesh) -> set:

@@ -114,12 +114,8 @@ class PPO(Algorithm):
             n = int(cfg.num_learner_devices or 0)
             if n > 1:
                 from jax.sharding import Mesh, PartitionSpec as P
-                try:
-                    from jax import shard_map
-                    _rep_kw = {"check_vma": False}
-                except ImportError:      # pre-0.8 jax, old signature
-                    from jax.experimental.shard_map import shard_map
-                    _rep_kw = {"check_rep": False}
+
+                from ray_tpu.parallel.sharding import shard_map
                 if cfg.num_envs_per_worker % n:
                     raise ValueError(
                         f"num_envs_per_worker={cfg.num_envs_per_worker} "
@@ -136,7 +132,7 @@ class PPO(Algorithm):
                     in_specs=(P(), P(), P("data"), P()),
                     out_specs=(P(), P(), P("data"), P(),
                                P(None, "data")),
-                    **_rep_kw)
+                    check_vma=False)
                 self._train_fn = jax.jit(fn)
             else:
                 self._train_fn = jax.jit(self._fused_iteration)

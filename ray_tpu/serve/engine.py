@@ -83,6 +83,7 @@ from __future__ import annotations
 
 import collections
 import logging
+import os
 import threading
 import time
 from dataclasses import dataclass, field
@@ -2677,6 +2678,10 @@ class InferenceReplica:
     initialized on the replica from `seed`, so nothing heavyweight rides
     the deployment's pickled init args. Real deployments would load
     checkpointed params here instead.
+
+    A worker sees a chip only if it asked for one: bind the deployment
+    with ``ray_actor_options={"num_tpus": 1}``, or the replica serves
+    from the CPU (`stats()["platform"]` says which).
     """
 
     def __init__(self, cfg_kwargs: dict | None = None, *,
@@ -2697,6 +2702,16 @@ class InferenceReplica:
                 jax.random.PRNGKey(seed + 1), dcfg)
         self.engine = InferenceEngine(
             params, cfg, slots=slots, max_len=max_len, **ek)
+        # Where this replica's process actually runs the model: a
+        # deployment bound without `num_tpus` gets a CPU worker, and
+        # nothing else in stats() or the stream would say so.
+        devices = jax.devices()
+        self._device_info = {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "device_count": len(devices),
+            "visible_chips": os.environ.get("TPU_VISIBLE_CHIPS", ""),
+        }
 
     def __call__(self, prompt, max_new_tokens: int = 8,
                  temperature: float = 0.0, priority: int | None = None):
@@ -2723,4 +2738,8 @@ class InferenceReplica:
                                          draft_params=draft_params)
 
     def stats(self) -> dict:
-        return self.engine.stats()
+        """The engine's stats plus the device this process holds:
+        ``platform`` / ``device_kind`` / ``device_count`` as JAX reports
+        them here, and ``visible_chips``, the host chips the scheduler
+        scoped this worker to ("" for a CPU worker)."""
+        return {**self.engine.stats(), **self._device_info}

@@ -365,7 +365,10 @@ class TrainLoop:
         # device dispatch (i.e. not stalled on data, checkpoint or
         # metrics plumbing). MFU needs the model's flop estimate.
         self.last_goodput = dispatch_s / denom
-        if self.flops_per_step and steps_run:
+        # MFU is a device metric: off an accelerator it stays 0.0 (not
+        # measured) instead of a CPU rate over a chip's peak.
+        if (self.flops_per_step and steps_run
+                and jax.default_backend() != "cpu"):
             self.last_mfu = _telemetry.mfu(
                 self.flops_per_step * steps_run / denom)
         return state, out

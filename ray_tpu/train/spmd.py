@@ -50,6 +50,17 @@ def default_optimizer(learning_rate: float = 3e-4,
     )
 
 
+def opt_state_shardings(optimizer: optax.GradientTransformation, params,
+                        param_shardings, mesh: Mesh):
+    """Shardings of `optimizer.init(params)`: moments take their
+    parameter's sharding, everything else (step counts) sits replicated
+    on the mesh. `params` may be arrays or shapes."""
+    return optax.tree_map_params(
+        optimizer, lambda _, sharding: sharding,
+        jax.eval_shape(optimizer.init, params), param_shardings,
+        transform_non_params=lambda _: replicated(mesh))
+
+
 def create_sharded_state(init_fn: Callable[[jax.Array], Any],
                          param_logical_axes,
                          mesh: Mesh,
@@ -58,14 +69,19 @@ def create_sharded_state(init_fn: Callable[[jax.Array], Any],
                          rules: dict | None = None) -> tuple[TrainState, Any]:
     """Initialize params + optimizer state directly into their shardings.
 
-    Params are materialized *sharded* (jit with out_shardings), so a model
-    too big for one device's HBM never exists unsharded anywhere. Optimizer
-    moments inherit the param shardings through XLA propagation
-    (zeros_like preserves sharding).
+    Params and optimizer state are materialized *sharded* (jit with
+    out_shardings), so a model too big for one device's HBM never exists
+    unsharded anywhere.
     """
     param_shardings = tree_shardings(mesh, param_logical_axes, rules)
     params = jax.jit(init_fn, out_shardings=param_shardings)(rng)
-    opt_state = jax.jit(optimizer.init)(params)
+    # The optimizer state is placed as the train step returns it. Left
+    # to propagation it comes back replicated (or, on a one-device mesh,
+    # uncommitted and off the mesh), and jax keys a trace on each
+    # argument's mesh and an executable on its sharding: the step would
+    # compile once for the first call and again for every later one.
+    opt_state = jax.jit(optimizer.init, out_shardings=opt_state_shardings(
+        optimizer, params, param_shardings, mesh))(params)
     step = jax.device_put(jnp.zeros((), jnp.int32), replicated(mesh))
     return TrainState(params, opt_state, step), param_shardings
 

@@ -651,29 +651,35 @@ def _publish_one(name: str, mname: str, key: str, num: float,
 # MFU helpers
 # ---------------------------------------------------------------------------
 
-# bf16 peak FLOPs per chip by jax device_kind substring (the table
-# bench.py established; first match wins).
-PEAK_FLOPS = (
-    ("v6", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),   # v5 litepod
-    ("v5", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
+# Published peaks of one chip, keyed by `jax.Device.device_kind` as the
+# runtime reports it (a v5e chip says "TPU v5 lite"; "TPU v5e" is jax's
+# other name for the same part). Source: Google Cloud documentation,
+# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip. This is
+# the one table the benches and the MFU gauge read; a device that is not
+# in it is an error, never a default — add a row with its source.
+DEVICE_PEAKS = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+    "TPU v5e": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
+}
 
 
-def device_peak_flops(device=None) -> float:
-    """Peak bf16 FLOPs/s of `device` (default: jax.devices()[0])."""
+def device_peaks(device=None) -> dict:
+    """`DEVICE_PEAKS` row of `device` (default: jax.devices()[0])."""
     if device is None:
         import jax
         device = jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
-    for key, val in PEAK_FLOPS:
-        if key in kind:
-            return val
-    return 197e12
+    kind = getattr(device, "device_kind", None)
+    if kind not in DEVICE_PEAKS:
+        raise ValueError(
+            f"no published peaks for device_kind {kind!r} in "
+            f"telemetry.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); a "
+            "utilization against a guessed peak is not a measurement")
+    return DEVICE_PEAKS[kind]
+
+
+def device_peak_flops(device=None) -> float:
+    """Peak bf16 FLOPs/s of `device`; raises on an unknown kind."""
+    return device_peaks(device)["bf16_flops"]
 
 
 def mfu(flops_per_sec: float, n_devices: int | None = None,

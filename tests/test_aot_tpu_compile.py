@@ -1,0 +1,168 @@
+"""The chip's compiler, asked without a chip: every Pallas kernel of the
+serve and train paths is AOT-compiled for a described `v5e:2x2` at the
+widths of the repo's bench model (d_model 1024, 16 heads x 64, vocab
+50304, block_size 16). Interpret mode accepts BlockSpecs and kernels that
+Mosaic refuses, so these are what guard the kernels between chip runs.
+
+A compile that passes is not a chip run: it says nothing about results
+or times. `chip_smoke.py` is the run.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or it logs under /tmp
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental import topologies
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
+
+from ray_tpu.models import gpt
+from ray_tpu.ops import decode_attention as da
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.fused_xent import fused_softmax_xent
+from ray_tpu.parallel import MeshSpec
+
+SLOTS, H, D, BS, NB, MB = 8, 16, 64, 16, 513, 64
+BF16, I32 = jnp.bfloat16, jnp.int32
+
+
+@pytest.fixture(scope="module")
+def topo():
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:      # no TPU compiler in this installation
+        pytest.skip(f"cannot describe a v5e:2x2 topology here: {e!r}")
+
+
+@pytest.fixture(autouse=True)
+def compile_as_on_tpu(monkeypatch):
+    """The ops ask `jax.default_backend()` whether to compile or
+    interpret; here it answers for the described chip. A described
+    chip's executables cannot be read back from the persistent cache, so
+    the cache is off around these compiles."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def compile_for(topo, fn, *args):
+    """Compile `fn` for one described chip; returns how many Mosaic
+    kernels the executable holds."""
+    one = SingleDeviceSharding(topo.devices[0])
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+            for shape, dtype in args]
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return text.count('custom_call_target="tpu_custom_call"')
+
+
+def pool_args(kv_dtype):
+    """(pool, pool[, k_scale, v_scale]) shapes of a bf16 or int8 pool."""
+    pools = [((NB, BS, H, D), kv_dtype)] * 2
+    if kv_dtype == jnp.int8:
+        pools += [((NB, BS, H), jnp.float32)] * 2
+    return pools
+
+
+def with_scales(op):
+    def call(q, k, v, tables, pos, *scales):
+        kw = dict(zip(("k_scale", "v_scale"), scales))
+        return op(q, k, v, tables, pos, impl="pallas", **kw)
+    return call
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_decode_kernel_compiles(topo, kv_dtype):
+    k, v, *scales = pool_args(kv_dtype)
+    assert compile_for(
+        topo, with_scales(da.paged_decode_attention),
+        ((SLOTS, H, D), BF16), k, v, ((SLOTS, MB), I32), ((SLOTS,), I32),
+        *scales) == 1
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_verify_kernel_compiles(topo, kv_dtype):
+    k, v, *scales = pool_args(kv_dtype)
+    assert compile_for(
+        topo, with_scales(da.paged_verify_attention),
+        ((SLOTS, 5, H, D), BF16), k, v, ((SLOTS, MB), I32),
+        ((SLOTS,), I32), *scales) == 1
+
+
+@pytest.mark.parametrize("chunk,kv_dtype", [
+    (32, BF16), (128, BF16), (512, BF16), (128, jnp.int8)],
+    ids=["C32-bf16", "C128-bf16", "C512-bf16", "C128-int8"])
+def test_paged_prefill_kernel_compiles(topo, chunk, kv_dtype):
+    k, v, *scales = pool_args(kv_dtype)
+    assert compile_for(
+        topo, with_scales(da.paged_prefill_attention),
+        ((chunk, H, D), BF16), k, v, ((MB,), I32), ((), I32),
+        *scales) == 1
+
+
+def test_unpaged_decode_kernel_compiles(topo):
+    assert compile_for(
+        topo, lambda q, k, v, pos: da.decode_attention(
+            q, k, v, pos, impl="pallas"),
+        ((SLOTS, H, D), BF16), ((SLOTS, 1024, H, D), BF16),
+        ((SLOTS, 1024, H, D), BF16), ((SLOTS,), I32)) == 1
+
+
+def test_flash_attention_fwd_bwd_compiles(topo):
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, causal=True)
+                       .astype(jnp.float32))
+    qkv = ((24, 1024, H, D), BF16)
+    assert compile_for(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                       qkv, qkv, qkv) == 3     # forward, dQ, dK/dV
+
+
+def test_fused_xent_fwd_bwd_compiles(topo):
+    def loss(x, embed, targets):
+        return jnp.mean(fused_softmax_xent(x, embed, targets,
+                                           impl="pallas"))
+    assert compile_for(
+        topo, jax.grad(loss, argnums=(0, 1)), ((8, 1024, 1024), BF16),
+        ((50304, 1024), BF16), ((8, 1024), I32)) == 3   # lse, dx, dembed
+
+
+def test_flash_attention_compiles_on_a_four_device_mesh(topo):
+    """The compiler cannot partition a Mosaic kernel by itself
+    ("Mosaic kernels cannot be automatically partitioned"); the model's
+    attention call site shard_maps it over batch rows and heads."""
+    mesh = MeshSpec(data=1, fsdp=2, tensor=2).build(topo.devices)
+    cfg = gpt.GPTConfig(n_heads=H, d_model=H * D, attn_impl="flash")
+    sharding = NamedSharding(
+        mesh, PartitionSpec(("data", "fsdp"), None, "tensor", None))
+    qkv = jax.ShapeDtypeStruct((8, 1024, H, D), BF16, sharding=sharding)
+    text = jax.jit(
+        lambda q, k, v: gpt._attention(q, k, v, cfg, mesh)
+    ).lower(qkv, qkv, qkv).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 1
+
+
+def test_fused_xent_compiles_on_a_four_device_mesh(topo):
+    """Same for the loss: on a data-parallel mesh the kernels run under
+    shard_map over the batch axes, the whole vocab on every shard."""
+    mesh = MeshSpec(data=2, fsdp=2).build(topo.devices)
+
+    def loss(x, embed, targets):
+        return jnp.mean(fused_softmax_xent(x, embed, targets,
+                                           impl="pallas", mesh=mesh))
+    batch = PartitionSpec(("data", "fsdp"))
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for shape, dtype, spec in (
+                ((8, 1024, 1024), BF16, batch),
+                ((50304, 1024), BF16, PartitionSpec()),
+                ((8, 1024), I32, batch))]
+    text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
+        *args).compile().as_text()
+    assert text.count('custom_call_target="tpu_custom_call"') == 3

@@ -1,0 +1,113 @@
+"""Rehearsals of `chip_smoke.py` that cost no chip time (guide
+`on-chip-measurement`, section 2), each in a process of its own: the
+phases at a tiny size on the CPU backend, the four-chip phase on four
+virtual devices, and the whole train step AOT-compiled at the real
+widths for a described `v5e:2x2`. Run them before sending the smoke to
+the chip: `pytest -m slow tests/test_chip_smoke_rehearsal.py`.
+"""
+
+import os
+import subprocess
+import sys
+
+import pytest
+
+pytestmark = pytest.mark.slow
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+TINY = ("dict(vocab_size=512, d_model=128, n_layers=2, n_heads=4, "
+        "d_ff=512, max_seq_len=128)")
+TINY_TRAIN = (f"dict({TINY}, attn_impl='flash', logits_dtype='bfloat16', "
+              "remat_policy='dots', loss_impl='fused')")
+
+
+def run(code: str, **env) -> str:
+    out = subprocess.run(
+        [sys.executable, "-c", "import chip_smoke as cs\n" + code],
+        cwd=REPO, capture_output=True, text=True, timeout=900,
+        env={**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": REPO,
+             **env})
+    assert out.returncode == 0, out.stdout[-2000:] + out.stderr[-4000:]
+    return out.stdout
+
+
+@pytest.mark.parametrize("replicas", [1, 2])
+def test_serve_phase_tiny_on_cpu(replicas):
+    # RAY_TPU_NUM_TPUS stands in for the chips the replicas ask for
+    out = run(f"cs.serve_phase({TINY}, platform='cpu', "
+              f"replicas={replicas}, streams=6, prompt_lens=(16, 60), "
+              "new_tokens=8, slots=4, max_len=128, seed=0)",
+              RAY_TPU_NUM_TPUS="2")
+    assert '"phase": "serve"' in out
+
+
+def test_train_phase_tiny_on_cpu():
+    out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
+              "steps=12, seed=0)")
+    assert '"phase": "train"' in out
+
+
+def test_four_chip_train_phase_on_virtual_devices():
+    out = run(f"cs.train4_phase({TINY_TRAIN}, platform='cpu', batch=4, "
+              "steps=8, seed=0)",
+              XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    assert '"weight_shard_devices": [0, 1, 2, 3]' in out
+
+
+AOT_TRAIN_STEP = '''
+import os
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+from unittest import mock
+import jax, jax.numpy as jnp
+from jax.experimental import topologies
+from jax.sharding import NamedSharding, PartitionSpec as P
+from ray_tpu.models import gpt
+from ray_tpu.parallel import MeshSpec
+from ray_tpu.parallel.sharding import (
+    logical_to_spec, replicated, tree_shardings)
+from ray_tpu.train import loop, spmd
+
+jax.config.update("jax_enable_compilation_cache", False)
+topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+cfg = gpt.GPTConfig(**cs.TRAIN_CFG)
+mesh = MeshSpec(**MESH).build(topo.devices[:N])
+opt = spmd.default_optimizer(warmup_steps=0)
+_, step_fn, _ = spmd.make_gpt_trainer(cfg, mesh, optimizer=opt,
+                                      init_state=False)
+
+def abstract(shapes, shardings):
+    return jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        shapes, shardings)
+
+p_sh = tree_shardings(mesh, gpt.param_logical_axes(cfg))
+p_shape = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
+                         jax.random.key(0))
+state = spmd.TrainState(
+    abstract(p_shape, p_sh),
+    abstract(jax.eval_shape(opt.init, p_shape),
+             spmd.opt_state_shardings(opt, p_shape, p_sh, mesh)),
+    jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated(mesh)))
+tok = jax.ShapeDtypeStruct(
+    (4, 8, cfg.max_seq_len), jnp.int32, sharding=NamedSharding(
+        mesh, P(None, *logical_to_spec(("batch",), None, mesh), None)))
+with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+    names, calls, compiled = cs.kernels_of(
+        loop.fuse_steps(step_fn, 4), state, {"inputs": tok, "targets": tok})
+mem = compiled.memory_analysis()
+print("KERNELS", names, calls)
+print("GIB", (mem.argument_size_in_bytes + mem.temp_size_in_bytes) / 2**30)
+assert cs.TRAIN_KERNELS <= set(names), names
+assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) < 15 * 2**30
+'''
+
+
+@pytest.mark.parametrize("n,mesh", [
+    (1, "dict(data=1)"), (4, "dict(data=1, fsdp=2, tensor=2)")],
+    ids=["one-chip", "fsdp2-tensor2"])
+def test_fused_train_dispatch_compiles_for_v5e(n, mesh):
+    """The program the train phases run, at the real widths and batch,
+    through the chip's compiler: kernels present, fits the 16 GB chip."""
+    out = run(f"N, MESH = {n}, {mesh}\n" + AOT_TRAIN_STEP)
+    assert "KERNELS" in out
