@@ -3,12 +3,18 @@
     python chip_smoke.py            # one chip: serve phase, then train phase
     python chip_smoke.py --chips 4  # one four-chip host: sharded train step
                                     # against one chip, then two replicas
+    python chip_smoke.py --phase serve-load   # one chip: 16 closed-loop
+                                    # streams on the olmo-1b serve block,
+                                    # a profiler trace from inside the replica
 
 Drives the serving path (`serve.run` of an `InferenceReplica` that asked
 for a chip, concurrent `handle.stream` calls) and the training path
 (`spmd.make_gpt_trainer` + `loop.TrainLoop` with the prefetcher) once at
 the full width of the repo's bench model, random weights from `--seed`,
 and checks what comes out against references computed the plain way.
+The serve phase runs its streams a second time under a `jax.profiler`
+trace taken inside the replica, Python tracer off, and prints what the
+program's own spans (`engine/*`, `stream/*`) and named kernels say.
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -40,6 +46,7 @@ import ray_tpu
 from ray_tpu import serve
 from ray_tpu._private import native
 from ray_tpu.serve.engine import InferenceReplica
+from ray_tpu.util import tracing
 
 # The repo's bench model (bench.py, bench_infer.py): published-GPT-2-medium
 # -like widths, all 12 layers.
@@ -163,6 +170,68 @@ class SmokeReplica(InferenceReplica):
         super().__init__(*args, **kwargs)
         self._init_s = time.perf_counter() - t0
 
+    def warm(self, prompt_lens, new_tokens: int) -> int:
+        """One request per length, drained here in the replica, so that
+        every program a window will run is compiled before it opens;
+        then the engine's counts start from zero."""
+        rng = np.random.default_rng(0)
+        for n in prompt_lens:
+            self.engine.generate(
+                rng.integers(0, self.engine.cfg.vocab_size, int(n)
+                             ).astype(np.int32), max_new_tokens=new_tokens)
+        self.engine.reset_stats()
+        return self._compiles.compiles
+
+    def trace_start(self) -> bool:
+        """Open a profiler session in this process, the one that holds
+        the chip. Python tracer off: the program's own spans name what
+        the host does, and in a process with tens of threads the tracer
+        cut the device's part of the trace short (PERF.md, PR 23)."""
+        import shutil
+
+        import jax
+        self._trace_dir = os.path.join(
+            os.environ.get("TMPDIR", "/tmp"), f"smoke_trace_{os.getpid()}")
+        shutil.rmtree(self._trace_dir, ignore_errors=True)
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
+        options.host_tracer_level = 2
+        self.engine.reset_stats()
+        self._compiles_at_start = self._compiles.compiles
+        self._trace_t0 = time.perf_counter()
+        jax.profiler.start_trace(self._trace_dir, profiler_options=options)
+        return True
+
+    def trace_stop(self) -> dict:
+        """Close the session and reduce its trace here (the file stays
+        on this machine): the named kernels, the program's spans, who
+        owned the chip's idle gaps, and the engine's own numbers for the
+        same window."""
+        import shutil
+
+        import jax
+        # counts first: closing the session takes seconds, and under
+        # load the engine goes on ticking meanwhile
+        traced_s = time.perf_counter() - self._trace_t0
+        stats = self.stats()
+        jax.profiler.stop_trace()
+        report = reduce_serve_trace(self._trace_dir)
+        shutil.rmtree(self._trace_dir, ignore_errors=True)
+        report.update(
+            traced_s=traced_s,
+            compiles_in_window=(self._compiles.compiles
+                                - self._compiles_at_start),
+            stats={k: stats[k] for k in (
+                "ticks", "tick_s", "admit_s", "decode_build_s",
+                "decode_dispatch_s", "token_sync_s", "emit_s",
+                "prefill_time_s", "decode_time_s", "decode_steps",
+                "decode_tokens", "prefill_tokens", "prefill_chunks",
+                "pump_lock_waits", "pump_lock_wait_s", "slot_occupancy",
+                "p50_token_latency_ms", "ttft_ms_p50", "ttft_ms_p99",
+                "deliver_wait_ms_p50", "deliver_wait_ms_p99",
+                "queue_depth")})
+        return report
+
     def inspect(self, prompt, tokens, logprobs) -> dict:
         """Stats, the kernels in the paged forwards at this engine's
         shapes, and how far the logprobs the engine streamed for
@@ -227,6 +296,107 @@ class SmokeReplica(InferenceReplica):
         }
 
 
+def pct(values, p: float) -> float | None:
+    values = sorted(values)
+    return values[min(len(values) - 1, int(p / 100 * len(values)))] \
+        if values else None
+
+
+def request_split() -> dict:
+    """From the flight recorder's spans, where a request's time to its
+    first token went inside the replica: queue (submit to a slot),
+    prefill (slot to first token made), deliver (made to handed to the
+    stream's consumer). Milliseconds. A worker's recorder is drained
+    with every reply it sends, into the head's tracing ring: read here,
+    in the driver."""
+    by_rid: dict = {}
+    for s in tracing.get_spans():
+        if s.get("cat") == "request":
+            by_rid.setdefault((s.get("lane"), s["attributes"].get("rid")),
+                              {}).setdefault(s["name"], s)
+    parts: dict = {"queue_ms": [], "prefill_ms": [], "deliver_ms": [],
+                   "ticks_made_to_yielded": []}
+    for r in by_rid.values():
+        if not {"queue_wait", "first_token", "first_yield"} <= set(r):
+            continue
+        q, made, out = r["queue_wait"], r["first_token"], r["first_yield"]
+        parts["queue_ms"].append((q["end_ns"] - q["start_ns"]) / 1e6)
+        parts["prefill_ms"].append((made["start_ns"] - q["end_ns"]) / 1e6)
+        parts["deliver_ms"].append(
+            (out["start_ns"] - made["start_ns"]) / 1e6)
+        parts["ticks_made_to_yielded"].append(
+            out["attributes"]["tick"] - made["attributes"]["tick"])
+    return {"requests": len(parts["queue_ms"]),
+            **{f"{k}_p{p}": pct(v, p) for k, v in parts.items()
+               for p in (50, 99)}}
+
+
+def reduce_serve_trace(trace_dir: str) -> dict:
+    """What a trace taken inside a serving replica holds. The tables are
+    the benchmark's own reductions (`benchmarks/harness`), so a serving
+    cell will read the same numbers the same way."""
+    from jax.profiler import ProfileData
+
+    from benchmarks.harness import spans as spans_mod
+    from benchmarks.harness import trace as trace_mod
+    path = trace_mod.find_xplane(trace_dir)
+    by_shape = trace_mod.reduce(path)
+    named = spans_mod.reduce(path)
+    # Do the device planes last as long as the host's spans? And what a
+    # reply carried: the spans' attributes, which the tables drop.
+    device, host, reply_tokens = [], [], []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            if trace_mod.DEVICE_PLANE.match(plane.name) \
+                    and line.name == trace_mod.OPS_LINE:
+                device += [(e.start_ns, e.start_ns + e.duration_ns)
+                           for e in line.events]
+            elif plane.name == "/host:CPU":
+                for e in line.events:
+                    if spans_mod.SPAN.match(e.name):
+                        host.append((e.start_ns,
+                                     e.start_ns + e.duration_ns))
+                        if e.name == "stream/reply":
+                            reply_tokens.append(
+                                dict(e.stats).get("tokens", 0))
+    extent = {}
+    for name, evs in (("device_ops", device), ("program_spans", host)):
+        if evs:
+            extent[name] = [len(evs), min(s for s, _ in evs) * 1e-9,
+                            max(e for _, e in evs) * 1e-9]
+    sp = named["spans"]
+
+    def total(*names):
+        return sum(sp[n][1] for n in names if n in sp)
+
+    tick_s = total("engine/tick")
+    return {
+        "xplane_bytes": os.path.getsize(path),
+        "extent_s": extent,         # [events, first s, last s] of each
+        "window_s": by_shape["window_s"], "busy_s": by_shape["busy_s"],
+        "idle_share": (1 - by_shape["busy_s"] / by_shape["window_s"]
+                       if by_shape["window_s"] else None),
+        "modules": by_shape["modules"],
+        "kernels": named["kernels"],
+        "spans": sp,                # {name: [count, total s, median s]}
+        "tick_outside_dispatch_and_sync_share": (
+            1 - total("engine/decode_dispatch", "engine/verify_dispatch",
+                      "engine/token_sync") / tick_s if tick_s else None),
+        "idle_s": named["idle_s"], "idle_owners": named["idle_owners"],
+        "reply_tokens": {"replies": len(reply_tokens),
+                         "mean": (float(np.mean(reply_tokens))
+                                  if reply_tokens else None),
+                         "p50": pct(reply_tokens, 50),
+                         "max": max(reply_tokens, default=None)},
+    }
+
+
+def in_replica(replica, method: str, *args):
+    """Call a method of the deployment's callable inside `replica`."""
+    return ray_tpu.get(replica.handle_method.remote(method, args, {}),
+                       timeout=PHASE_TIMEOUT_S)
+
+
 def _ray_tpu_processes():
     """(pid, parent pid, command line) of every worker-side process of
     ray_tpu on this host: `worker_main` execs, the fork factory and the
@@ -287,26 +457,30 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
         ).bind(cfg_kwargs, slots=slots, max_len=max_len, seed=seed)
         handle = serve.run(app, name="smoke")
 
-        outs: list = [None] * streams
-        errors: list = []
+        def run_streams(prompts) -> list:
+            outs: list = [None] * streams
+            errors: list = []
 
-        def consume(i):
-            try:
-                outs[i] = list(handle.stream(prompts[i], new_tokens,
-                                             timeout=PHASE_TIMEOUT_S))
-            except BaseException as e:       # re-raised below
-                errors.append(e)
+            def consume(i):
+                try:
+                    outs[i] = list(handle.stream(prompts[i], new_tokens,
+                                                 timeout=PHASE_TIMEOUT_S))
+                except BaseException as e:       # re-raised below
+                    errors.append(e)
 
-        threads = [threading.Thread(target=consume, args=(i,))
-                   for i in range(streams)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(PHASE_TIMEOUT_S)
-        check(not any(t.is_alive() for t in threads),
-              "a stream did not finish")
-        if errors:
-            raise errors[0]
+            threads = [threading.Thread(target=consume, args=(i,))
+                       for i in range(streams)]
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(PHASE_TIMEOUT_S)
+            check(not any(t.is_alive() for t in threads),
+                  "a stream did not finish")
+            if errors:
+                raise errors[0]
+            return outs
+
+        outs = run_streams(prompts)
         wall_s = time.perf_counter() - t0
         for i, out in enumerate(outs):
             check(len(out) == new_tokens,
@@ -326,18 +500,31 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
         check(cpu_platform == "cpu",
               f"a worker without num_tpus runs on {cpu_platform}")
 
+        # Streams of the same lengths again (other tokens: no prefix
+        # hits), every program compiled by now, under a profiler trace
+        # taken inside the first replica.
         handle._refresh(force=True)
+        traced = handle._replicas[0]
+        tracing.clear_spans()       # the split is the traced pass's
+        in_replica(traced, "trace_start")
+        again = run_streams([rng.integers(
+            0, cfg_kwargs["vocab_size"], len(p)).astype(np.int32)
+            for p in prompts])
+        trace_report = in_replica(traced, "trace_stop")
+        trace_report["requests"] = request_split()
+        check(all(len(o) == new_tokens for o in again),
+              "a traced stream came back short")
+
         reports = [
-            ray_tpu.get(r.handle_method.remote(
-                "inspect", (prompts[0], [int(t) for t in outs[0]],
-                            [t.logprob for t in outs[0]]), {}),
-                timeout=PHASE_TIMEOUT_S)
+            in_replica(r, "inspect", prompts[0], [int(t) for t in outs[0]],
+                       [t.logprob for t in outs[0]])
             for r in handle._replicas]
     finally:
         serve.shutdown()
         ray_tpu.shutdown()
         stop_workers_or_fail()
 
+    emit({"phase": "serve_trace", **trace_report})
     emit({"phase": "serve", "replicas": reports, "streams": streams,
           "new_tokens": new_tokens,
           "prompt_lens": [len(p) for p in prompts],
@@ -360,16 +547,180 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
         check(not rep["op_fallbacks"],
               f"ops fell back to pure JAX: {rep['op_fallbacks']}")
         if platform == "tpu":
-            check("_paged_kernel" in rep["kernels"]["decode_step_paged"]
+            check("paged_decode" in rep["kernels"]["decode_step_paged"]
                   and rep["tpu_custom_calls"]["decode_step_paged"] > 0,
                   f"no paged decode kernel: {rep['kernels']}")
-            check("_paged_mq_kernel" in rep["kernels"]["prefill_paged"]
+            check("paged_mq" in rep["kernels"]["prefill_paged"]
                   and rep["tpu_custom_calls"]["prefill_paged"] > 0,
                   f"no paged prefill kernel: {rep['kernels']}")
+    check_serve_trace(trace_report, on_tpu=platform == "tpu",
+                      replicas=replicas)
     scoped = [rep["stats"]["visible_chips"] for rep in reports]
     check(len({rep["pid"] for rep in reports}) == replicas
           and len(set(scoped)) == replicas,
           f"replicas share a process or a chip: chips {scoped}")
+
+
+def check_serve_trace(rep: dict, *, on_tpu: bool, replicas: int) -> None:
+    """The traced pass: the program's spans are in the trace with the
+    Python tracer off, nothing compiled under it, and on a chip the
+    device planes last as long as the spans and the kernels go by
+    their names. The tables count the spans that lie inside the window
+    (first to last device op), so the trace holds nearly all the ticks
+    the engine counted, not one more."""
+    spans = rep["spans"]
+    if replicas > 1 and "engine/tick" not in spans:
+        return      # the router sent this replica none of the streams
+    check(rep["compiles_in_window"] == 0,
+          f"{rep['compiles_in_window']} programs compiled under the trace")
+    wanted = {"engine/tick", "engine/admit", "engine/prefill_chunk",
+              "engine/decode_build", "engine/decode_dispatch",
+              "engine/token_sync", "engine/emit", "stream/lock_wait",
+              "stream/reply"}
+    check(wanted <= set(spans), f"spans missing from the trace: "
+          f"{sorted(wanted - set(spans))}")
+    st = rep["stats"]
+    ticks, waits = spans["engine/tick"][0], spans["stream/lock_wait"][0]
+    check(0.9 * st["ticks"] - 2 <= ticks <= st["ticks"]
+          and waits <= st["pump_lock_waits"],
+          f"the trace holds {ticks} ticks and {waits} lock waits, the "
+          f"engine counted {st['ticks']} and {st['pump_lock_waits']}")
+    if replicas == 1:       # with more, the streams spread over them
+        check(rep["requests"]["requests"] > 0
+              and st["deliver_wait_ms_p99"] > 0,
+              f"no request's first yield was recorded: {rep['requests']}")
+    if on_tpu:
+        check({"paged_decode", "paged_mq"} <= set(rep["kernels"]),
+              f"kernels in the trace: {sorted(rep['kernels'])}")
+        dev, host = rep["extent_s"]["device_ops"], \
+            rep["extent_s"]["program_spans"]
+        check(dev[2] >= host[2] - 1.0,
+              f"the device planes end at {dev[2]:.3f} s, the program's "
+              f"spans at {host[2]:.3f} s")
+
+
+# ---------------------------------------------------------------------------
+# serve under load: where a client's time to the first token goes
+# ---------------------------------------------------------------------------
+
+def lognormal_length(rng, median: float, sigma: float, lo: int,
+                     hi: int) -> int:
+    return int(np.clip(np.exp(rng.normal(np.log(median), sigma)), lo, hi))
+
+
+LOAD_CONFIG = os.path.join(os.path.dirname(os.path.abspath(__file__)),
+                           "benchmarks", "configs", "olmo-1b.json")
+# every prefill bucket of the olmo-1b block once, then a prompt that
+# shares a block and a half with the last (the prefix-copy program)
+LOAD_WARM_LENS = (16, 40, 100, 200, 400, 800, 1500, 1500)
+
+
+def serve_load_phase(config: dict, *, platform: str, clients: int,
+                     seconds: float, trace_s: float, seed: int,
+                     warm_lens=LOAD_WARM_LENS, prompt_median: int = 256,
+                     output_median: int = 96) -> None:
+    """The load of PERF.md's finding 3 (PR 23): one replica of a
+    benchmark configuration (`benchmarks/configs/olmo-1b.json`) with its
+    `serve` block, `clients` closed-loop streams (each sends its next
+    request when the last one ends; prompts lognormal median 256,
+    outputs median 96). A profiler trace of `trace_s` is taken inside
+    the replica once the load is steady. Prints the client's view beside
+    the program's own split of the time to the first token."""
+    from benchmarks.harness.common import gpt_kwargs
+    block = config["program"]["serve"]
+    top = block["max_len"]
+    cfg_kwargs = {**gpt_kwargs(config), **config["program"]["model"]}
+    ray_tpu.init()
+    try:
+        app = serve.deployment(
+            SmokeReplica, num_replicas=1,
+            ray_actor_options={"num_tpus": 1},
+            max_concurrent_queries=block["max_concurrent_queries"],
+        ).bind(cfg_kwargs, slots=block["slots"], max_len=block["max_len"],
+               seed=seed, engine_kwargs=block["engine_kwargs"])
+        handle = serve.run(app, name="smoke-load")
+        handle._refresh(force=True)
+        replica = handle._replicas[0]
+        compiled = in_replica(replica, "warm", list(warm_lens), 4)
+        stop_at = [None]
+        done: list = []          # (t_call, ttft_s, total_s, tokens)
+        errors: list = []
+
+        def client(i):
+            crng = np.random.default_rng([seed, i])
+            try:
+                while time.perf_counter() < stop_at[0]:
+                    n_in = lognormal_length(
+                        crng, prompt_median, 0.8, prompt_median // 8,
+                        top * 3 // 4)
+                    n_out = lognormal_length(
+                        crng, output_median, 0.7, output_median // 12,
+                        top * 7 // 32)
+                    prompt = crng.integers(
+                        0, cfg_kwargs["vocab_size"], n_in).astype(np.int32)
+                    t_call = time.perf_counter()
+                    first, n = None, 0
+                    for _ in handle.stream(prompt, n_out,
+                                           timeout=PHASE_TIMEOUT_S):
+                        if first is None:
+                            first = time.perf_counter() - t_call
+                        n += 1
+                    done.append((t_call, first,
+                                 time.perf_counter() - t_call, n))
+            except BaseException as e:
+                errors.append(e)
+
+        t0 = time.perf_counter()
+        stop_at[0] = t0 + seconds
+        threads = [threading.Thread(target=client, args=(i,))
+                   for i in range(clients)]
+        for t in threads:
+            t.start()
+        time.sleep(max(0.0, seconds - trace_s) * 0.6)    # let it fill
+        tracing.clear_spans()       # the split is of the steady load
+        in_replica(replica, "trace_start")
+        t_trace = time.perf_counter()
+        time.sleep(trace_s)
+        trace_report = in_replica(replica, "trace_stop")
+        t_trace_end = time.perf_counter()
+        for t in threads:
+            t.join(PHASE_TIMEOUT_S)
+        check(not any(t.is_alive() for t in threads),
+              "a client did not finish")
+        if errors:
+            raise errors[0]
+        wall_s = time.perf_counter() - t0
+        trace_report["requests"] = request_split()
+        stats = in_replica(replica, "stats")
+        device = {k: stats[k]
+                  for k in ("platform", "device_kind", "device_count")}
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+        stop_workers_or_fail()
+
+    # the recorder's split is of the requests that ended from the
+    # trace's start on: the client's view of the same ones beside it
+    late = [d[1] * 1e3 for d in done
+            if d[0] + d[2] >= t_trace and d[1] is not None]
+    ttft = [d[1] for d in done if d[1] is not None]
+    emit({"phase": "serve_load", "device": device, "clients": clients,
+          "seconds": seconds, "wall_s": wall_s,
+          "programs_compiled_in_warm_up": compiled,
+          "requests_ended": len(done),
+          "traced_from_s": t_trace - t0, "traced_to_s": t_trace_end - t0,
+          "requests_ended_since_trace_start": len(late),
+          "client_ttft_ms_p50_of_those": pct(late, 50),
+          "client_ttft_ms_p99_of_those": pct(late, 99),
+          "client_tokens_per_s": sum(d[3] for d in done) / wall_s,
+          "client_ttft_ms_p50": pct([t * 1e3 for t in ttft], 50),
+          "client_ttft_ms_p99": pct([t * 1e3 for t in ttft], 99),
+          "client_request_s_p50": pct([d[2] for d in done], 50),
+          "trace": trace_report})
+    check(device["platform"] == platform,
+          f"replica runs on {device['platform']}, not {platform}")
+    check(len(done) > 0, "no request ended")
+    check_serve_trace(trace_report, on_tpu=platform == "tpu", replicas=1)
 
 
 # ---------------------------------------------------------------------------
@@ -481,8 +832,8 @@ def first_loss_xla_dense(cfg, devices, *, batch: int, seed: int) -> float:
     return float(metrics["loss"])
 
 
-TRAIN_KERNELS = {"_flash_kernel", "_dq_kernel", "_dkv_kernel"}
-XENT_KERNELS = {"_fwd_kernel", "_dx_kernel", "_de_kernel"}
+TRAIN_KERNELS = {"flash_fwd", "flash_dq", "flash_dkv"}
+XENT_KERNELS = {"xent_fwd", "xent_dx", "xent_de"}
 
 
 def train_phase(cfg_kwargs: dict, *, platform: str, batch: int,
@@ -593,8 +944,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", choices=("train", "train4"),
-                    help=argparse.SUPPRESS)   # how a phase child is started
+    ap.add_argument("--phase", choices=("train", "train4", "serve-load"),
+                    help="serve-load: see the top of this file; train and "
+                    "train4 are how a phase child is started")
     args = ap.parse_args()
 
     if args.phase == "train":
@@ -629,7 +981,11 @@ def main() -> int:
           "JAX_COMPILATION_CACHE_DIR":
               os.environ.get("JAX_COMPILATION_CACHE_DIR")})
     try:
-        if args.chips == 1:
+        if args.phase == "serve-load":
+            with open(LOAD_CONFIG) as f:
+                serve_load_phase(json.load(f), platform="tpu", clients=16,
+                                 seconds=75.0, trace_s=10.0, seed=args.seed)
+        elif args.chips == 1:
             serve_phase(WIDTHS, platform="tpu", replicas=1, streams=6,
                         prompt_lens=(128, 512), new_tokens=64, slots=8,
                         max_len=1024, seed=args.seed)

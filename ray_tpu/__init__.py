@@ -97,6 +97,11 @@ def init(num_cpus: int | None = None,
         constants.SHM_ROOT,
         constants.SESSION_PREFIX + ids.new_node_id())
     os.makedirs(session_dir, exist_ok=True)
+    # Claim the directory at once: NodeServer writes the same pidfile
+    # only after it has opened the object store, and a session starting
+    # beside this one meanwhile must not collect the directory as stale.
+    with open(os.path.join(session_dir, "driver.pid"), "w") as f:
+        f.write(str(os.getpid()))
     node = NodeServer(total, session_dir, num_tpu_chips=int(num_tpus or 0))
     client = _worker.connect_driver_mode(node)
     if log_to_driver is None:
@@ -173,6 +178,14 @@ def _connect_client(address: str, ignore_reinit_error: bool = False,
     return client
 
 
+def _younger_than(path: str, seconds: float) -> bool:
+    import time
+    try:
+        return time.time() - os.stat(path).st_mtime < seconds
+    except OSError:
+        return False
+
+
 def _gc_stale_sessions():
     """Remove session dirs whose driver process is gone (crash leftovers)."""
     import shutil
@@ -183,7 +196,10 @@ def _gc_stale_sessions():
             with open(pidfile) as f:
                 pid = int(f.read().strip())
             os.kill(pid, 0)       # raises if the driver is dead
-        except (FileNotFoundError, ValueError, ProcessLookupError):
+        except (FileNotFoundError, ValueError, ProcessLookupError) as e:
+            if not isinstance(e, ProcessLookupError) and _younger_than(
+                    d, 60.0):
+                continue          # being created right now, not stale
             for sub in glob.glob(os.path.join(d, "nodes", "*")):
                 shutil.rmtree(
                     os.path.join(constants.OBJECT_SPILL_ROOT,

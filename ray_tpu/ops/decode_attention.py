@@ -81,6 +81,13 @@ from ray_tpu.ops.flash_attention import (
 
 NEG_INF = -1e30
 
+# Kernel names in the compiled program and the profiler's trace
+# (`%paged_decode.N = ... custom-call`); PERF.md, section 3, lists them.
+# Each call sits in a `named_scope` of its own name: see flash_attention.py.
+# Verify and the fused prefill share `paged_mq`.
+DECODE_UNPAGED, PAGED_DECODE, PAGED_MQ = (
+    "decode_unpaged", "paged_decode", "paged_mq")
+
 
 def _auto_impl(op: str, has_plan: bool, why: str) -> str:
     """Resolve ``impl="auto"``: the kernel on a TPU backend, the
@@ -184,15 +191,17 @@ def _decode_bhsd(q, k, v, pos, *, sm_scale: float, block_kv: int,
             pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 used)
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_decode_kernel, sm_scale=sm_scale,
-                          block_kv=block_kv, n_heads=n_heads),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(pos, q, k, v)
+    with jax.named_scope(DECODE_UNPAGED):
+        return pl.pallas_call(
+            functools.partial(_decode_kernel, sm_scale=sm_scale,
+                              block_kv=block_kv, n_heads=n_heads),
+            name=DECODE_UNPAGED,
+            out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(pos, q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -401,16 +410,18 @@ def _paged_bhsd(q, k, v, tables, pos, *, sm_scale: float, n_heads: int,
             pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 used)
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_kernel, sm_scale=sm_scale,
-                          block_size=bs, n_heads=n_heads,
-                          quantized=quantized),
-        out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope(PAGED_DECODE):
+        return pl.pallas_call(
+            functools.partial(_paged_kernel, sm_scale=sm_scale,
+                              block_size=bs, n_heads=n_heads,
+                              quantized=quantized),
+            name=PAGED_DECODE,
+            out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(*operands)
 
 
 def reference_paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
@@ -543,16 +554,18 @@ def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
             pltpu.VMEM((wp, d), jnp.float32),     # acc
         ],
     )
-    return pl.pallas_call(
-        functools.partial(_paged_mq_kernel, sm_scale=sm_scale,
-                          block_size=bs, n_heads=n_heads, w_real=w_real,
-                          quantized=quantized),
-        out_shape=jax.ShapeDtypeStruct((bh, wp, d), q.dtype),
-        grid_spec=grid_spec,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(*operands)
+    with jax.named_scope(PAGED_MQ):
+        return pl.pallas_call(
+            functools.partial(_paged_mq_kernel, sm_scale=sm_scale,
+                              block_size=bs, n_heads=n_heads, w_real=w_real,
+                              quantized=quantized),
+            name=PAGED_MQ,
+            out_shape=jax.ShapeDtypeStruct((bh, wp, d), q.dtype),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(*operands)
 
 
 def _check_scales(k_scale, v_scale, k_pool, op: str):

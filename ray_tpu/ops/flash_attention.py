@@ -46,6 +46,14 @@ from ray_tpu.parallel.ring_attention import reference_attention
 
 NEG_INF = -1e30
 
+# Kernel names: what each `pallas_call` is called in the compiled program
+# (`%flash_fwd.N = ... custom-call`) and so in a profiler trace's `XLA Ops`
+# line. Readers learn them from PERF.md, section 3. The instruction is
+# named after the innermost scope around the call, and a transform wraps
+# the first scope it meets (`jvp(xent_fwd)` -> `%jvp_xent_fwd_.N`), so
+# every call sits in a `named_scope` of its own name that takes the wrap.
+FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
+
 
 def _causal_mask(s, q_start, k_start, block_q, block_kv):
     qpos = q_start + jax.lax.broadcasted_iota(
@@ -128,25 +136,27 @@ def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, block_q: int,
         out_shape.append(jax.ShapeDtypeStruct((bh, t, 128), jnp.float32))
         out_specs.append(
             pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)))
-    res = pl.pallas_call(
-        kernel,
-        out_shape=tuple(out_shape),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
-        ],
-        out_specs=tuple(out_specs),
-        scratch_shapes=[
-            pltpu.VMEM((block_q, 128), jnp.float32),   # m (col 0 used)
-            pltpu.VMEM((block_q, 128), jnp.float32),   # l
-            pltpu.VMEM((block_q, d), jnp.float32),     # acc
-        ],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v)
+    with jax.named_scope(FLASH_FWD):
+        res = pl.pallas_call(
+            kernel,
+            name=FLASH_FWD,
+            out_shape=tuple(out_shape),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
+                pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
+            ],
+            out_specs=tuple(out_specs),
+            scratch_shapes=[
+                pltpu.VMEM((block_q, 128), jnp.float32),   # m (col 0 used)
+                pltpu.VMEM((block_q, 128), jnp.float32),   # l
+                pltpu.VMEM((block_q, d), jnp.float32),     # acc
+            ],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v)
     return (res[0], res[1]) if with_lse else (res[0], None)
 
 
@@ -258,35 +268,39 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
     rowq = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    dq = pl.pallas_call(
-        functools.partial(_dq_kernel, **common),
-        out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-        grid=(bh, t // block_q, t // block_kv),
-        in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
-        out_specs=qspec,
-        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope(FLASH_DQ):
+        dq = pl.pallas_call(
+            functools.partial(_dq_kernel, **common),
+            name=FLASH_DQ,
+            out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
+            grid=(bh, t // block_q, t // block_kv),
+            in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+            out_specs=qspec,
+            scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
 
     # dKV grid: kv blocks parallel, q blocks innermost/sequential.
     qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, block_kv, d), lambda b, j, i: (b, j, 0))
     rowq2 = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
-    dk, dv = pl.pallas_call(
-        functools.partial(_dkv_kernel, **common),
-        out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                   jax.ShapeDtypeStruct((bh, t, d), v.dtype)),
-        grid=(bh, t // block_kv, t // block_q),
-        in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-        out_specs=(kspec2, kspec2),
-        scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
-                        pltpu.VMEM((block_kv, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
-        interpret=interpret,
-    )(q, k, v, do, lse, delta)
+    with jax.named_scope(FLASH_DKV):
+        dk, dv = pl.pallas_call(
+            functools.partial(_dkv_kernel, **common),
+            name=FLASH_DKV,
+            out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
+                       jax.ShapeDtypeStruct((bh, t, d), v.dtype)),
+            grid=(bh, t // block_kv, t // block_q),
+            in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
+            out_specs=(kspec2, kspec2),
+            scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
+                            pltpu.VMEM((block_kv, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+        )(q, k, v, do, lse, delta)
     return dq, dk, dv
 
 

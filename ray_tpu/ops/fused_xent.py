@@ -51,6 +51,11 @@ from ray_tpu.ops import backend
 
 _NEG = -1e30   # finite -inf stand-in: exp(_NEG - m) underflows to 0
 
+# Kernel names in the compiled program and the profiler's trace
+# (`%xent_fwd.N = ... custom-call`); PERF.md, section 3, lists them.
+# Each call sits in a `named_scope` of its own name: see flash_attention.py.
+XENT_FWD, XENT_DX, XENT_DE = "xent_fwd", "xent_dx", "xent_de"
+
 
 # ---------------------------------------------------------------------------
 # scan implementation (the everywhere-correct fallback)
@@ -233,21 +238,23 @@ def _lse_tgt_pallas(x, embed, targets, block_n, block_v, interpret):
     v = embed.shape[0]
     grid = (n // block_n, v // block_v)
     row_spec = pl.BlockSpec((block_n, 128), lambda i, j: (i, 0))
-    lse2, tgt2 = pl.pallas_call(
-        functools.partial(_fwd_kernel, block_v=block_v),
-        out_shape=(jax.ShapeDtypeStruct((n, 128), jnp.float32),) * 2,
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
-            row_spec,
-        ],
-        out_specs=(row_spec, row_spec),
-        scratch_shapes=[pltpu.VMEM((block_n, 128), jnp.float32)] * 3,
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(x.reshape(n, d), embed, _rows128(targets.astype(jnp.int32), n))
+    with jax.named_scope(XENT_FWD):
+        lse2, tgt2 = pl.pallas_call(
+            functools.partial(_fwd_kernel, block_v=block_v),
+            name=XENT_FWD,
+            out_shape=(jax.ShapeDtypeStruct((n, 128), jnp.float32),) * 2,
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
+                row_spec,
+            ],
+            out_specs=(row_spec, row_spec),
+            scratch_shapes=[pltpu.VMEM((block_n, 128), jnp.float32)] * 3,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(x.reshape(n, d), embed, _rows128(targets.astype(jnp.int32), n))
     return lse2[:, 0].reshape(b, t), tgt2[:, 0].reshape(b, t)
 
 
@@ -263,40 +270,44 @@ def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, block_n, block_v,
     ct2 = _rows128(c_tgt.astype(jnp.float32), n)
     row_spec = pl.BlockSpec((block_n, 128), lambda i, j: (i, 0))
 
-    dx = pl.pallas_call(
-        functools.partial(_dx_kernel, block_v=block_v),
-        out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
-        grid=(n // block_n, v // block_v),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
-            row_spec, row_spec, row_spec, row_spec,
-        ],
-        out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-        scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(x2, embed, t2, lse2, cl2, ct2)
+    with jax.named_scope(XENT_DX):
+        dx = pl.pallas_call(
+            functools.partial(_dx_kernel, block_v=block_v),
+            name=XENT_DX,
+            out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
+            grid=(n // block_n, v // block_v),
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
+                row_spec, row_spec, row_spec, row_spec,
+            ],
+            out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
+            scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(x2, embed, t2, lse2, cl2, ct2)
 
     # swapped grid: each vocab block streams every row block through its
     # accumulator
     row_spec_t = pl.BlockSpec((block_n, 128), lambda j, i: (i, 0))
-    de = pl.pallas_call(
-        functools.partial(_de_kernel, block_v=block_v),
-        out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
-        grid=(v // block_v, n // block_n),
-        in_specs=[
-            pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
-            pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
-            row_spec_t, row_spec_t, row_spec_t, row_spec_t,
-        ],
-        out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
-        scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary")),
-        interpret=interpret,
-    )(x2, embed, t2, lse2, cl2, ct2)
+    with jax.named_scope(XENT_DE):
+        de = pl.pallas_call(
+            functools.partial(_de_kernel, block_v=block_v),
+            name=XENT_DE,
+            out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
+            grid=(v // block_v, n // block_n),
+            in_specs=[
+                pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
+                pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
+                row_spec_t, row_spec_t, row_spec_t, row_spec_t,
+            ],
+            out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
+            scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "arbitrary")),
+            interpret=interpret,
+        )(x2, embed, t2, lse2, cl2, ct2)
     return dx.reshape(b, t, d), de
 
 

@@ -842,8 +842,6 @@ class InferenceEngine:
         self._block_util = collections.deque(maxlen=512)
         self._prefill_tokens = 0
         self._decode_tokens = 0
-        self._prefill_time = 0.0
-        self._decode_time = 0.0
         self._prefill_chunks = 0
         self._prefix_hit_tokens = 0
         self._prompt_tokens = 0
@@ -914,6 +912,10 @@ class InferenceEngine:
         self.name = _telemetry.next_name("engine")
         self._recorder = _telemetry.FlightRecorder(
             self.name, sample=telemetry_sample)
+        # Program spans of the tick and the pump (`engine/*`,
+        # `stream/lock_wait`): profiler annotations whose totals feed
+        # stats(). Every one closes under `_lock`.
+        self._phases = _telemetry.Phases()
         self._sentinel = _telemetry.RetraceSentinel(self.name)
         self._sentinel.watch("decode", lambda: self.decode_traces, cap=1,
                              registered=True)
@@ -1174,13 +1176,19 @@ class InferenceEngine:
         drive one continuously-batched device loop. Abandoning the
         generator (break / close / GC) cancels the request and releases
         its cache blocks."""
+        first = True
         try:
             while True:
                 tok = None
                 fin = False
+                # asked for the lock here, held where the span closes:
+                # what a pump waits behind every other stream's pump
+                wait = self._phases.phase("stream/lock_wait")
+                wait.__enter__()
                 with self._lock:   # pop under the lock, yield OUTSIDE
                     # it — a generator suspends at yield, and a
                     # suspended holder would block every consumer's pump.
+                    wait.__exit__(None, None, None)
                     q = self._out.get(rid)
                     if q is None:
                         return
@@ -1202,6 +1210,9 @@ class InferenceEngine:
                         self.step()
                     if q:
                         tok = q.popleft()
+                        if first:
+                            first = False
+                            self._recorder.on_first_yield(rid)
                     if rid in self._done and not q:
                         self._done.discard(rid)
                         del self._out[rid]
@@ -1911,17 +1922,17 @@ class InferenceEngine:
             cap = self._chunk_bucket_for(clen)
             toks = np.zeros((1, cap), np.int32)
             toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
-            t0 = time.perf_counter()
-            tok, lp, self.cache = self._prefill_fn(
-                self.params, jnp.asarray(toks), self.cache,
-                jnp.asarray(s.table), np.int32(s.filled),
-                np.int32(clen), np.float32(s.temperature),
-                self._base_key, np.int32(self._decode_steps))
-            # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
-            tok = int(tok)    # device sync, so the timing is honest
-            dt = time.perf_counter() - t0
-            self._prefill_time += dt
-            self._recorder.on_prefill_chunk(s.rid, clen, cap, dt)
+            with self._phases.phase("engine/prefill_chunk", tokens=clen,
+                                    bucket=cap) as chunk:
+                tok, lp, self.cache = self._prefill_fn(
+                    self.params, jnp.asarray(toks), self.cache,
+                    jnp.asarray(s.table), np.int32(s.filled),
+                    np.int32(clen), np.float32(s.temperature),
+                    self._base_key, np.int32(self._decode_steps))
+                # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
+                tok = int(tok)    # device sync, so the timing is honest
+            self._recorder.on_prefill_chunk(s.rid, clen, cap,
+                                            chunk.seconds)
             self._prefill_tokens += clen
             self._prefill_chunks += 1
             s.filled += clen
@@ -1945,12 +1956,12 @@ class InferenceEngine:
             dtoks = np.zeros((1, dcap), np.int32)
             dtoks[0, :dclen] = s.prompt[
                 s.draft_filled:s.draft_filled + dclen]
-            t0 = time.perf_counter()
-            self.draft_cache = self._draft_prefill_fn(
-                self.draft_params, jnp.asarray(dtoks), self.draft_cache,
-                jnp.asarray(s.draft_table), np.int32(s.draft_filled),
-                np.int32(dclen))
-            self._prefill_time += time.perf_counter() - t0
+            with self._phases.phase("engine/draft_prefill_chunk",
+                                    tokens=dclen, bucket=dcap):
+                self.draft_cache = self._draft_prefill_fn(
+                    self.draft_params, jnp.asarray(dtoks),
+                    self.draft_cache, jnp.asarray(s.draft_table),
+                    np.int32(s.draft_filled), np.int32(dclen))
             s.draft_filled += dclen
         if s.filled < s.prompt.size or (
                 self._draft_alloc is not None
@@ -2050,43 +2061,53 @@ class InferenceEngine:
             # finally — a fault-failed tick must not read as stuck forever
             self._tick_seq += 1
             self._tick_started = t_tick
+            # the recorder tags its events with the tick they fall in:
+            # that number joins them to this `engine/tick` annotation
+            self._recorder.tick = self._tick_seq
+            phase = self._phases.phase
             try:
-                # fault site: 'fail' surfaces FaultInjected to the
-                # pumping consumer; 'delay' wedges the tick (what the
-                # watchdog exists to catch)
-                _faults.check("engine.tick")
-                # fault site: 'fail' forces preemption of the lowest-
-                # class active stream this tick (absorbed — consumers
-                # see only the token-identical resume)
-                try:
-                    _faults.check("engine.preempt")
-                except _faults.FaultInjected:
-                    self._force_preempt()
-                had_decoders = any(
-                    s.phase == "decode" for s in self._slots)
-                imported = self._admit_imports()
-                admitted = self._admit_pending() or imported
-                chunked = self._prefill_tick(had_decoders)
-                if had_decoders and (admitted or chunked):
-                    self._max_admission_stall = max(
-                        self._max_admission_stall,
-                        time.perf_counter() - t_tick)
-                active = [i for i, s in enumerate(self._slots)
-                          if s.active]
-                self._occupancy.append(len(active) / self.num_slots)
-                self._block_util.append(
-                    self._alloc.used / max(self.cache_blocks, 1))
-                decoding = [i for i, s in enumerate(self._slots)
-                            if s.phase == "decode"]
-                if not decoding:  # idle, or admissions finished early
+                with phase("engine/tick", tick=self._tick_seq) as tick:
+                    # fault site: 'fail' surfaces FaultInjected to the
+                    # pumping consumer; 'delay' wedges the tick (what the
+                    # watchdog exists to catch)
+                    _faults.check("engine.tick")
+                    # fault site: 'fail' forces preemption of the lowest-
+                    # class active stream this tick (absorbed — consumers
+                    # see only the token-identical resume)
+                    try:
+                        _faults.check("engine.preempt")
+                    except _faults.FaultInjected:
+                        self._force_preempt()
+                    had_decoders = any(
+                        s.phase == "decode" for s in self._slots)
+                    with phase("engine/admit") as admit:
+                        seq = self._admit_seq
+                        imported = self._admit_imports()
+                        admitted = self._admit_pending() or imported
+                        admit.set(admitted=self._admit_seq - seq)
+                    chunked = self._prefill_tick(had_decoders)
+                    if had_decoders and (admitted or chunked):
+                        self._max_admission_stall = max(
+                            self._max_admission_stall,
+                            time.perf_counter() - t_tick)
+                    active = [i for i, s in enumerate(self._slots)
+                              if s.active]
+                    self._occupancy.append(len(active) / self.num_slots)
+                    self._block_util.append(
+                        self._alloc.used / max(self.cache_blocks, 1))
+                    decoding = [i for i, s in enumerate(self._slots)
+                                if s.phase == "decode"]
+                    tick.set(decoding=len(decoding),
+                             prefilling=len(active) - len(decoding))
+                    if not decoding:  # idle, or admissions finished early
+                        self._sentinel.check()
+                        return admitted or chunked
+                    if self.spec is not None:
+                        self._spec_tick(decoding)
+                    else:
+                        self._decode_tick(decoding)
                     self._sentinel.check()
-                    return admitted or chunked
-                if self.spec is not None:
-                    self._spec_tick(decoding)
-                else:
-                    self._decode_tick(decoding)
-                self._sentinel.check()
-                return True
+                    return True
             finally:
                 self._tick_started = None
 
@@ -2117,30 +2138,41 @@ class InferenceEngine:
                          np.float32)
         return tokens, pos, tables, temps
 
-    def _decode_tick(self, decoding: list):
-        tokens, pos, tables, temps = self._batch_arrays()
-        t0 = time.perf_counter()
-        nxt, lps, self.cache = self._decode_fn(
-            self.params, self.cache, self._dev("tokens", tokens),
-            self._dev("pos", pos), self._dev("tables", tables),
-            self._dev("temps", temps), self._base_key,
-            np.int32(self._decode_steps))
-        # graftlint: disable-next-line=R001,R004 the decode tick IS the scheduler's unit of work: it must sync on the sampled tokens to route them, and the lock is held for exactly one tick by design
-        nxt = np.asarray(nxt)    # device sync
-        # graftlint: disable-next-line=R001,R004 same sync as nxt above — lps arrives in the same device batch, so this is a no-cost host view
-        lps = np.asarray(lps)
-        dt = time.perf_counter() - t0
+    def _decode_inputs(self):
+        """`_batch_arrays` and its four device puts, as one span: the
+        host's part of a tick before the dispatch."""
+        with self._phases.phase("engine/decode_build"):
+            tokens, pos, tables, temps = host = self._batch_arrays()
+            return host, (self._dev("tokens", tokens),
+                          self._dev("pos", pos),
+                          self._dev("tables", tables),
+                          self._dev("temps", temps))
+
+    def _decode_tick(self, decoding: list, inputs=None):
+        phase = self._phases.phase
+        if inputs is None:      # the spec tick's fallback built them
+            _, inputs = self._decode_inputs()
+        with phase("engine/decode_dispatch") as dispatch:
+            nxt, lps, self.cache = self._decode_fn(
+                self.params, self.cache, *inputs, self._base_key,
+                np.int32(self._decode_steps))
+        with phase("engine/token_sync") as sync:
+            # graftlint: disable-next-line=R001,R004 the decode tick IS the scheduler's unit of work: it must sync on the sampled tokens to route them, and the lock is held for exactly one tick by design
+            nxt = np.asarray(nxt)    # device sync
+            # graftlint: disable-next-line=R001,R004 same sync as nxt above — lps arrives in the same device batch, so this is a no-cost host view
+            lps = np.asarray(lps)
+        dt = dispatch.seconds + sync.seconds
         self._step_times.append(dt)
-        self._decode_time += dt
         self._decode_steps += 1
         self._decode_tokens += len(decoding)
         self._decode_slot_steps += len(decoding)
         self._tok_window.append((dt, len(decoding)))
-        for i in decoding:
-            s = self._slots[i]
-            s.token, s.pos = int(nxt[i]), s.pos + 1
-            s.remaining -= 1
-            self._emit(s, i, s.token, float(lps[i]))
+        with phase("engine/emit", tokens=len(decoding)):
+            for i in decoding:
+                s = self._slots[i]
+                s.token, s.pos = int(nxt[i]), s.pos + 1
+                s.remaining -= 1
+                self._emit(s, i, s.token, float(lps[i]))
 
     def _ngram_propose(self, s: _Slot) -> list | None:
         """Prompt-lookup proposal: find the longest n-gram (ngram_max
@@ -2163,75 +2195,79 @@ class InferenceEngine:
         Falls back to the plain decode step when nothing is worth
         speculating on, so both paths stay compiled-exactly-once."""
         W = self.spec_window
+        phase = self._phases.phase
         # Slots one token from retiring can't use speculation (and, for
         # the draft backend, retire before their stale draft cache
         # could ever be consulted again).
         worth = [i for i in decoding
                  if self._slots[i].remaining >= 2]
         proposals: dict[int, list] = {}
-        tokens, pos, tables, temps = self._batch_arrays()
-        t0 = time.perf_counter()
-        if self.spec == "ngram":
-            for i in worth:
-                prop = self._ngram_propose(self._slots[i])
-                if prop is not None:
-                    proposals[i] = prop
-            if not proposals:
-                self._decode_tick(decoding)
-                return
-            # Junk default (repeat the current token) for rows without
-            # a proposal; any accidental accepts are still exact.
-            drafts = np.repeat(tokens[:, None], W - 1, axis=1)
-            for i, prop in proposals.items():
-                drafts[i, :] = (prop + [prop[-1]] * (W - 1))[:W - 1]
-        else:
-            if not worth:
-                self._decode_tick(decoding)
-                return
-            zeros = np.zeros((self.max_blocks,), np.int32)
-            dtables = np.stack(
-                [s.draft_table if s.phase == "decode" else zeros
-                 for s in self._slots])
-            dj, self.draft_cache = self._propose_fn(
-                self.draft_params, self.draft_cache,
-                self._dev("tokens", tokens), self._dev("pos", pos),
-                self._dev("tables", dtables), self._dev("temps", temps),
-                self._base_key, np.int32(self._decode_steps))
-            # graftlint: disable-next-line=R001,R004 draft proposals must reach the host to build the verify window; one sync per spec tick, same budget as the plain decode tick's
-            drafts = np.asarray(dj)
-            for i in worth:
-                proposals[i] = drafts[i].tolist()
+        host, inputs = self._decode_inputs()
+        tokens = host[0]
+        d_tokens, d_pos, d_tables, d_temps = inputs
+        with phase("engine/propose") as propose:
+            if self.spec == "ngram":
+                for i in worth:
+                    prop = self._ngram_propose(self._slots[i])
+                    if prop is not None:
+                        proposals[i] = prop
+                if proposals:
+                    # Junk default (repeat the current token) for rows
+                    # without a proposal; any accidental accepts are
+                    # still exact.
+                    drafts = np.repeat(tokens[:, None], W - 1, axis=1)
+                    for i, prop in proposals.items():
+                        drafts[i, :] = (
+                            prop + [prop[-1]] * (W - 1))[:W - 1]
+            elif worth:
+                zeros = np.zeros((self.max_blocks,), np.int32)
+                dtables = np.stack(
+                    [s.draft_table if s.phase == "decode" else zeros
+                     for s in self._slots])
+                dj, self.draft_cache = self._propose_fn(
+                    self.draft_params, self.draft_cache, d_tokens, d_pos,
+                    self._dev("tables", dtables), d_temps,
+                    self._base_key, np.int32(self._decode_steps))
+                # graftlint: disable-next-line=R001,R004 draft proposals must reach the host to build the verify window; one sync per spec tick, same budget as the plain decode tick's
+                drafts = np.asarray(dj)
+                for i in worth:
+                    proposals[i] = drafts[i].tolist()
+        if not proposals:
+            self._decode_tick(decoding, inputs)
+            return
         window = np.concatenate([tokens[:, None], drafts], axis=1)
-        out, out_lp, acc, self.cache = self._verify_fn(
-            self.params, self.cache, self._dev("window", window),
-            self._dev("pos", pos), self._dev("tables", tables),
-            self._dev("temps", temps), self._base_key,
-            np.int32(self._decode_steps))
-        # graftlint: disable-next-line=R001,R004 the spec tick's one deliberate sync: accepted tokens must reach the host to emit; replaces W plain-tick syncs
-        out, acc = np.asarray(out), np.asarray(acc)   # device sync
-        # graftlint: disable-next-line=R001,R004 same device batch as out/acc above — already materialized, no extra round-trip
-        out_lp = np.asarray(out_lp)
-        dt = time.perf_counter() - t0
+        with phase("engine/verify_dispatch") as verify:
+            out, out_lp, acc, self.cache = self._verify_fn(
+                self.params, self.cache, self._dev("window", window),
+                d_pos, d_tables, d_temps, self._base_key,
+                np.int32(self._decode_steps))
+        with phase("engine/token_sync") as sync:
+            # graftlint: disable-next-line=R001,R004 the spec tick's one deliberate sync: accepted tokens must reach the host to emit; replaces W plain-tick syncs
+            out, acc = np.asarray(out), np.asarray(acc)   # device sync
+            # graftlint: disable-next-line=R001,R004 same device batch as out/acc above — already materialized, no extra round-trip
+            out_lp = np.asarray(out_lp)
+        dt = propose.seconds + verify.seconds + sync.seconds
         self._step_times.append(dt)
-        self._decode_time += dt
         self._decode_steps += 1
         self._spec_steps += 1
         self._decode_slot_steps += len(decoding)
         emitted = 0
-        for i in decoding:
-            s = self._slots[i]
-            if i in proposals:
-                self._spec_proposed += W - 1
-                self._spec_accepted += int(acc[i])
-            for j in range(int(acc[i]) + 1):
-                if self._slots[i] is not s:
-                    break   # slot retired mid-window (eos/len/budget)
-                tok = int(out[i, j])
-                s.token, s.pos = tok, s.pos + 1
-                s.remaining -= 1
-                self._decode_tokens += 1
-                emitted += 1
-                self._emit(s, i, tok, float(out_lp[i, j]))
+        with phase("engine/emit") as emit:
+            for i in decoding:
+                s = self._slots[i]
+                if i in proposals:
+                    self._spec_proposed += W - 1
+                    self._spec_accepted += int(acc[i])
+                for j in range(int(acc[i]) + 1):
+                    if self._slots[i] is not s:
+                        break   # slot retired mid-window (eos/len/budget)
+                    tok = int(out[i, j])
+                    s.token, s.pos = tok, s.pos + 1
+                    s.remaining -= 1
+                    self._decode_tokens += 1
+                    emitted += 1
+                    self._emit(s, i, tok, float(out_lp[i, j]))
+            emit.set(tokens=emitted)
         self._tok_window.append((dt, emitted))
 
     def run_until_idle(self):
@@ -2349,7 +2385,8 @@ class InferenceEngine:
         with self._lock:
             self._decode_steps = 0
             self._prefill_tokens = self._decode_tokens = 0
-            self._prefill_time = self._decode_time = 0.0
+            self._phases.clear()
+            self._recorder.deliver_waits.clear()
             self._prefill_chunks = 0
             self._prefix_hit_tokens = self._prompt_tokens = 0
             self._cow_copies = self._evicted_blocks = 0
@@ -2398,7 +2435,9 @@ class InferenceEngine:
           ``decode_steps`` — device decode/verify ticks since reset.
           ``prefill_tokens`` / ``decode_tokens`` — tokens absorbed /
           emitted since reset; ``prefill_time_s`` / ``decode_time_s``
-          the device time attributed to each.
+          the device time attributed to each: the totals of the
+          prefill-chunk spans, and of the decode/verify dispatch,
+          propose and token-sync spans (below).
           ``prefill_chunks`` — chunked-admission device calls.
           ``slot_occupancy`` — mean fraction of slots active per tick.
           ``p50_token_latency_ms`` / ``p99_token_latency_ms`` — decode
@@ -2450,6 +2489,30 @@ class InferenceEngine:
           compile-once guarantee broke at runtime — each violation also
           logs one WARN).
 
+          ``deliver_wait_ms_p50`` / ``deliver_wait_ms_p99`` — first
+          token made (the recorder's first_token) to first token handed
+          to the stream's consumer by `tokens_for` (its first_yield),
+          over the last 512 sampled requests: what a stream waits for
+          the scheduler lock after its token exists. ``ttft_ms_*`` stops
+          at the engine's edge; this is the step past it.
+
+        Program spans (util.telemetry.Phases; each is also an annotation
+        of the same name in a `jax.profiler` trace, on the device
+        planes' clock — PERF.md, section 3; seconds since reset):
+          ``ticks`` / ``tick_s`` — `engine/tick`: scheduler ticks and
+          their wall time under the lock.
+          ``admit_s`` — `engine/admit`: import and pending admission.
+          ``decode_build_s`` — `engine/decode_build`: the per-slot input
+          arrays and their device puts.
+          ``decode_dispatch_s`` — `engine/decode_dispatch` and
+          `engine/verify_dispatch`: enqueueing the decode/verify step.
+          ``token_sync_s`` — `engine/token_sync`: waiting for the
+          sampled tokens on the host (the device's time shows here).
+          ``emit_s`` — `engine/emit`: routing tokens to their streams.
+          ``pump_lock_waits`` / ``pump_lock_wait_s`` —
+          `stream/lock_wait`: times a `tokens_for` pump asked for the
+          scheduler lock, and the total it waited for it.
+
         Speculative decoding:
           ``spec`` / ``spec_k`` — backend ('' when off) and window.
           ``spec_steps`` — verify ticks; ``acceptance_rate`` — accepted
@@ -2475,9 +2538,9 @@ class InferenceEngine:
           watchdog disabled). Each stall also logs one WARN.
 
         Disaggregated prefill/decode (role-specialized serving):
-          ``role`` — this engine's role: ``colocated`` (default) /
-          ``prefill`` (chunked prefill only, exports KV handoffs) /
-          ``decode`` (colocated behavior + import target; the tag
+          ``role`` — this engine's role: 'colocated' (default) /
+          'prefill' (chunked prefill only, exports KV handoffs) /
+          'decode' (colocated behavior + import target; the tag
           drives role-aware routing and per-role autoscaling).
           ``handoffs`` — prompts prefilled and exported as KV blobs
           since reset; ``imports`` — handoffs adopted into this pool.
@@ -2512,11 +2575,12 @@ class InferenceEngine:
           whose queue wait exceeded the per-class aging bound and were
           admitted ahead of stride order.
           ``per_class`` — dict keyed by class id (str) with per-class
-          ``submitted`` / ``completed`` / ``sheds`` / ``preemptions`` /
-          ``decode_tokens`` counters plus ``pending`` / ``active``
-          occupancy and ``queue_wait_ms_p50`` / ``queue_wait_ms_p99``
-          over a 256-request window — the fairness/usage series the
-          telemetry bridge fans out as class-tagged gauges.
+          'submitted' / 'completed' / 'sheds' / 'preemptions' /
+          'decode_tokens' counters plus 'pending' / 'active' occupancy
+          and 'queue_wait_ms_p50' / 'queue_wait_ms_p99' over a
+          256-request window — the fairness/usage series the telemetry
+          bridge fans out as class-tagged gauges. (Double backticks are
+          for this dict's own keys only: the contract test reads them.)
         """
         with self._lock:
             self._sentinel.check()   # surface retraces since last tick
@@ -2559,6 +2623,15 @@ class InferenceEngine:
                 return waits[min(len(waits) - 1,
                                  int(p / 100 * len(waits)))] * 1e3
 
+            ph = self._phases
+            delivered = sorted(self._recorder.deliver_waits)
+
+            def dpct(p):
+                if not delivered:
+                    return 0.0
+                return delivered[min(len(delivered) - 1,
+                                     int(p / 100 * len(delivered)))]
+
             exp_ms = sorted(self._kv_export_ms)
             imp_ms = sorted(self._kv_import_ms)
 
@@ -2580,8 +2653,11 @@ class InferenceEngine:
                 "decode_steps": self._decode_steps,
                 "prefill_tokens": self._prefill_tokens,
                 "decode_tokens": self._decode_tokens,
-                "prefill_time_s": self._prefill_time,
-                "decode_time_s": self._decode_time,
+                "prefill_time_s": ph.seconds(
+                    "engine/prefill_chunk", "engine/draft_prefill_chunk"),
+                "decode_time_s": ph.seconds(
+                    "engine/decode_dispatch", "engine/verify_dispatch",
+                    "engine/propose", "engine/token_sync"),
                 "prefill_traces": self.prefill_traces,
                 "decode_traces": self.decode_traces,
                 "prefill_chunks": self._prefill_chunks,
@@ -2617,6 +2693,19 @@ class InferenceEngine:
                 "ttft_ms_p50": wpct(50),
                 "ttft_ms_p99": wpct(99),
                 "retraces_unexpected": self._sentinel.retraces_unexpected,
+                "deliver_wait_ms_p50": dpct(50),
+                "deliver_wait_ms_p99": dpct(99),
+                # program spans
+                "ticks": ph.count("engine/tick"),
+                "tick_s": ph.seconds("engine/tick"),
+                "admit_s": ph.seconds("engine/admit"),
+                "decode_build_s": ph.seconds("engine/decode_build"),
+                "decode_dispatch_s": ph.seconds(
+                    "engine/decode_dispatch", "engine/verify_dispatch"),
+                "token_sync_s": ph.seconds("engine/token_sync"),
+                "emit_s": ph.seconds("engine/emit"),
+                "pump_lock_waits": ph.count("stream/lock_wait"),
+                "pump_lock_wait_s": ph.seconds("stream/lock_wait"),
                 # speculative decoding
                 "spec": self.spec or "",
                 "spec_k": self.spec_k if self.spec else 0,

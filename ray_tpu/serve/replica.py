@@ -89,6 +89,8 @@ class Replica:
         # /metrics, tagged by a per-replica source id. Worker-resident
         # replicas reach the driver scrape via the metrics flusher.
         from ray_tpu.util import telemetry as _telemetry
+        # `stream/reply`: one span per batch of chunks handed back
+        self._phases = _telemetry.Phases()
         self._telemetry_name = _telemetry.register_stats_source(
             _telemetry.next_name(f"replica:{self.deployment_name}#"),
             self, kind="replica")
@@ -235,24 +237,26 @@ class Replica:
             None, self._next_chunks_sync, stream_id, max_chunks)
 
     def _next_chunks_sync(self, stream_id: int, max_chunks: int):
-        with self._lock:
-            entry = self._streams.get(stream_id)
-            if entry is not None:
-                entry[1] = time.time()
-        if entry is None:
-            return None, True
-        it = entry[0]
-        chunks = []
-        done = False
-        try:
-            for _ in range(max_chunks):
-                chunks.append(next(it))
-        except StopIteration:
-            done = True
-        if done:
+        with self._phases.phase("stream/reply") as reply:
             with self._lock:
-                self._streams.pop(stream_id, None)
-        return chunks, done
+                entry = self._streams.get(stream_id)
+                if entry is not None:
+                    entry[1] = time.time()
+            if entry is None:
+                return None, True
+            it = entry[0]
+            chunks = []
+            done = False
+            try:
+                for _ in range(max_chunks):
+                    chunks.append(next(it))
+            except StopIteration:
+                done = True
+            if done:
+                with self._lock:
+                    self._streams.pop(stream_id, None)
+            reply.set(tokens=len(chunks))
+            return chunks, done
 
     def cancel_stream(self, stream_id: int) -> bool:
         with self._lock:
@@ -276,11 +280,16 @@ class Replica:
         after handles abandon/time out). Engine-backed deployments also
         merge the fault-tolerance counters (``sheds``,
         ``watchdog_stalls`` — see `InferenceEngine.stats`), which the
-        telemetry bridge republishes as `replica_*` series."""
+        telemetry bridge republishes as `replica_*` series. `replies` /
+        `reply_s` count the `stream/reply` spans: `next_chunks` calls
+        answered and the time they held a reply thread (a
+        `jax.profiler` trace of the replica shows each one)."""
         with self._lock:
             out = {"inflight": self._inflight, "total": self._total,
                    "streams": len(self._streams),
-                   "uptime_s": time.time() - self._started}
+                   "uptime_s": time.time() - self._started,
+                   "replies": self._phases.count("stream/reply"),
+                   "reply_s": self._phases.seconds("stream/reply")}
         fn = getattr(self.callable, "stats", None)
         if callable(fn) and not self._is_function:
             try:

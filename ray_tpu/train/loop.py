@@ -98,15 +98,21 @@ class DevicePrefetcher:
         self._exhausted = False
         self.issued = 0         # transfers dispatched (observability)
         self.skipped_ragged = 0  # host batches dropped in a ragged tail
+        # `train/host_batch` (the data source's `next`) and `train/place`
+        # (the transfer's enqueue): the buffer fills on the consumer's
+        # thread inside `__next__`, so in a trace both lie inside the
+        # loop's `train/next_batch`.
+        self.phases = _telemetry.Phases()
 
     def _next_host_batch(self):
-        if self._group == 1:
-            return next(self._host)
-        parts = list(itertools.islice(self._host, self._group))
-        if len(parts) < self._group:
-            self.skipped_ragged += len(parts)
-            raise StopIteration
-        return jax.tree.map(lambda *xs: np.stack(xs), *parts)
+        with self.phases.phase("train/host_batch"):
+            if self._group == 1:
+                return next(self._host)
+            parts = list(itertools.islice(self._host, self._group))
+            if len(parts) < self._group:
+                self.skipped_ragged += len(parts)
+                raise StopIteration
+            return jax.tree.map(lambda *xs: np.stack(xs), *parts)
 
     def __iter__(self) -> Iterator:
         return self
@@ -115,7 +121,9 @@ class DevicePrefetcher:
         while (not self._exhausted and self._err is None
                 and len(self._buf) < self._depth):
             try:
-                self._buf.append(self._place(self._next_host_batch()))
+                host = self._next_host_batch()
+                with self.phases.phase("train/place"):
+                    self._buf.append(self._place(host))
                 self.issued += 1
             except StopIteration:
                 self._exhausted = True
@@ -145,6 +153,7 @@ class MetricsRing:
         self._pending: collections.deque = collections.deque()
         self.history: list = []
         self.fetches = 0        # host syncs performed (tests assert this)
+        self.phases = _telemetry.Phases()    # `train/metrics_fetch`
         self._steps_pushed = 0
         self._last_sync = 0
 
@@ -165,8 +174,9 @@ class MetricsRing:
         if take <= 0:
             return
         items = [self._pending.popleft() for _ in range(take)]
-        # graftlint: disable-next-line=R001 intentional lagged fetch: fires at most every `interval` pushed steps and only for entries >= `lag` dispatches old, so the device queue is never drained behind the live dispatch
-        hosts = _device_get([m for _, m in items])
+        with self.phases.phase("train/metrics_fetch", entries=take):
+            # graftlint: disable-next-line=R001 intentional lagged fetch: fires at most every `interval` pushed steps and only for entries >= `lag` dispatches old, so the device queue is never drained behind the live dispatch
+            hosts = _device_get([m for _, m in items])
         self.fetches += 1
         for (count, _), host in zip(items, hosts):
             if count == 1:
@@ -249,9 +259,11 @@ class TrainLoop:
                           else fuse_steps(step_fn, self.unroll, donate,
                                           on_trace=_count_trace))
         self.last_ring: MetricsRing | None = None
-        # Step-time breakdown of the last run (host-side perf_counter
-        # timers only — no device syncs beyond the ones already there),
-        # MFU/goodput derived from it, and the retrace sentinel.
+        # Step-time breakdown of the last run (the totals of the loop's
+        # program spans, `self.phases` — host-side timers only, no device
+        # syncs beyond the ones already there), MFU/goodput derived from
+        # it, and the retrace sentinel.
+        self.phases = _telemetry.Phases()
         self.last_breakdown: dict = {}
         self.flops_per_step = flops_per_step
         self.last_mfu = 0.0
@@ -295,58 +307,57 @@ class TrainLoop:
         self.last_ring = ring
         ckpt = self.checkpointer
         done = int(start_step)
-        # Host-side step-time breakdown: perf_counter around each host
-        # activity of the loop. These time where the HOST thread waits
-        # (the overlap design's whole point is keeping these small) and
-        # add no device syncs — the no-host-sync tests monkeypatch
-        # `_device_get` and still see only the ring's lagged fetches.
+        # Host-side step-time breakdown: one program span around each
+        # host activity of the loop (`telemetry.Phases`: a profiler
+        # annotation and a perf_counter total). These time where the
+        # HOST thread waits (the overlap design's whole point is keeping
+        # these small) and add no device syncs — the no-host-sync tests
+        # monkeypatch `_device_get` and still see only the ring's lagged
+        # fetches, which count in this run's totals too.
+        phases = ring.phases = self.phases
+        phases.clear()
+        phase = phases.phase
         pc = time.perf_counter
-        prefetch_s = dispatch_s = metrics_s = 0.0
-        checkpoint_s = publish_s = 0.0
         t_run = pc()
         it = iter(device_batches)
         while True:
-            t0 = pc()
             try:
-                batch = next(it)
+                with phase("train/next_batch"):
+                    batch = next(it)
             except StopIteration:
-                prefetch_s += pc() - t0
                 break
-            t1 = pc()
-            state, metrics = self._dispatch(state, batch)
-            t2 = pc()
-            ring.push(metrics, count=self.unroll)
-            t3 = pc()
+            with phase("train/dispatch", step=done):
+                state, metrics = self._dispatch(state, batch)
+            with phase("train/metrics"):
+                ring.push(metrics, count=self.unroll)
             done += self.unroll
             # Snapshot/publish BEFORE the next dispatch donates these
             # buffers: both hooks device-copy what they keep, which is
             # the donation-safety seam (ft.AsyncCheckpointer docstring;
             # engine.update_params copies into its own buffers).
             if ckpt is not None:
-                ckpt.maybe_snapshot(state, done)
-            t4 = pc()
+                with phase("train/checkpoint"):
+                    ckpt.maybe_snapshot(state, done)
             if self.publisher is not None:
-                self.publisher(state, done)
-            t5 = pc()
-            prefetch_s += t1 - t0
-            dispatch_s += t2 - t1
-            metrics_s += t3 - t2
-            checkpoint_s += t4 - t3
-            publish_s += t5 - t4
+                with phase("train/publish"):
+                    self.publisher(state, done)
             if self.unroll > 1:
                 self.sentinel.check()
             if num_steps is not None and done >= num_steps:
                 break
         if ckpt is not None:
-            t0 = pc()
-            ckpt.flush()
-            checkpoint_s += pc() - t0
-        t0 = pc()
-        out = ring.drain()
-        metrics_s += pc() - t0
+            with phase("train/checkpoint"):
+                ckpt.flush()
+        with phase("train/metrics"):
+            out = ring.drain()
         total_s = pc() - t_run
         steps_run = done - int(start_step)
         denom = max(total_s, 1e-12)
+        prefetch_s = phases.seconds("train/next_batch")
+        dispatch_s = phases.seconds("train/dispatch")
+        metrics_s = phases.seconds("train/metrics")
+        checkpoint_s = phases.seconds("train/checkpoint")
+        publish_s = phases.seconds("train/publish")
         self.last_breakdown = {
             "steps": steps_run,
             "total_s": total_s,

@@ -6,13 +6,23 @@ private telemetry (`InferenceEngine.stats()`, `MetricsRing`,
 `weight_swap_ms`), none of which reached the plane the core ships — the
 `util.metrics` Prometheus registry, `util.tracing` spans, the
 dashboard's `/metrics` and `/api/timeline`. This module is the bridge,
-built from four pieces:
+built from five pieces:
+
+  * `Phases` — program spans on the profiler's clock: an owner's
+    `phase(name, **attrs)` is a `jax.profiler.TraceAnnotation` plus a
+    `{name: [count, seconds]}` total the owner hands out in `stats()`.
+    The train loop, the prefetcher, the engine's tick and pump and the
+    serve replica's replies time themselves with it, so a profiler
+    trace shows what the host did on the clock of the device planes
+    without the Python tracer. The recorder below keeps the epoch's
+    clock (the merged timeline's) and joins a profile by tick number.
 
   * `FlightRecorder` — per-request lifecycle tracing for an engine:
     submit → queue wait → each prefill chunk (prefix-hit/COW annotated)
-    → decode → first token → finish/cancel/swap-crossing, recorded as
-    `util.tracing`-shaped span dicts in a bounded ring (evictions
-    counted, never silent). Sampled per request
+    → decode → first token → first yield to the stream's consumer →
+    finish/cancel/swap-crossing, recorded as `util.tracing`-shaped span
+    dicts in a bounded ring (evictions counted, never silent), each
+    tagged with the engine tick it fell in. Sampled per request
     (`RAY_TPU_TELEMETRY_SAMPLE`, default 1.0) and cheap enough to leave
     on: the per-token hook is one dict lookup + an int increment, and an
     unsampled request costs a single failed lookup per hook.
@@ -55,6 +65,7 @@ import logging
 import os
 import random
 import re
+import sys
 import threading
 import time
 import uuid
@@ -88,6 +99,116 @@ def next_name(kind: str) -> str:
 
 def _now_ns() -> int:
     return time.time_ns()
+
+
+# ---------------------------------------------------------------------------
+# program spans on the profiler's clock
+# ---------------------------------------------------------------------------
+
+class _HostPhase:
+    """The span in a process that has not loaded jax (a serve replica of
+    a plain callable): no profiler can be tracing it, so it is the
+    total alone."""
+
+    __slots__ = ("_total", "_t0", "seconds")
+
+    def __init__(self, total: list, name: str, attrs: dict):
+        self._total = total
+        self.seconds = 0.0
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __exit__(self, *exc):
+        self.seconds = dt = time.perf_counter() - self._t0
+        total = self._total
+        total[0] += 1
+        total[1] += dt
+        return False
+
+
+_phase_type = None
+
+
+def _phase_class():
+    """`jax.profiler.TraceAnnotation` that also times itself into its
+    owner's totals. Built once jax is loaded: this module imports no
+    jax, and a process that never loaded it is not made to."""
+    global _phase_type
+    if _phase_type is None:
+        if "jax" not in sys.modules:
+            return _HostPhase
+        from jax.profiler import TraceAnnotation
+
+        class Phase(TraceAnnotation):
+            __slots__ = ("_total", "_t0", "seconds")
+
+            def __init__(self, total: list, name: str, attrs: dict):
+                super().__init__(name, **attrs)
+                self._total = total
+                self.seconds = 0.0
+
+            def __enter__(self):
+                super().__enter__()
+                self._t0 = time.perf_counter()
+                return self
+
+            def set(self, **attrs) -> None:
+                """Attributes known only at the end (tokens emitted,
+                requests admitted); dropped when no session is on."""
+                if self.is_enabled():
+                    self.set_metadata(**attrs)
+
+            def __exit__(self, *exc):
+                self.seconds = dt = time.perf_counter() - self._t0
+                total = self._total
+                total[0] += 1
+                total[1] += dt
+                return super().__exit__(*exc)
+
+        _phase_type = Phase
+    return _phase_type
+
+
+class Phases:
+    """One owner's program spans: `phase(name, **attrs)` is a context
+    manager that is a `jax.profiler.TraceAnnotation` — so the span lies
+    in a profiler trace's `/host:CPU` plane on the clock of the device
+    planes, and with no session on is a flag test in C++ — and adds
+    `(1, elapsed perf_counter seconds)` to `totals[name]`, which the
+    owner hands out in its `stats()`. Nothing else: no ring, no export.
+    After exit the span's `seconds` is what it added.
+
+    Each update is a list item's `+=` with no Python call between load
+    and store, so threads that share an owner lose no count under the
+    GIL. Names: PERF.md, section 3.
+    """
+
+    __slots__ = ("totals",)
+
+    def __init__(self):
+        self.totals: dict[str, list] = {}    # name -> [count, seconds]
+
+    def phase(self, name: str, **attrs):
+        total = self.totals.get(name)
+        if total is None:
+            total = self.totals.setdefault(name, [0, 0.0])
+        return _phase_class()(total, name, attrs)
+
+    def count(self, name: str) -> int:
+        return self.totals.get(name, (0, 0.0))[0]
+
+    def seconds(self, *names: str) -> float:
+        return sum(self.totals.get(n, (0, 0.0))[1] for n in names)
+
+    def clear(self) -> None:
+        """Zero in place: a span open across the call still lands."""
+        for total in self.totals.values():
+            total[0], total[1] = 0, 0.0
 
 
 # ---------------------------------------------------------------------------
@@ -146,6 +267,19 @@ class FlightRecorder:
         self._spans: collections.deque = collections.deque()
         self._live: dict[int, dict] = {}
         self._rng = random.Random(0x5EED ^ hash(self.name))
+        # The engine's tick number, set by `InferenceEngine.step`: every
+        # event carries it, and it is what joins a request's events to
+        # the `engine/tick` annotations of a profiler trace (this
+        # recorder's clock is the epoch's, the profiler's its own).
+        self.tick = 0
+        # rid -> (first-token ns, trace id, root span id) of requests
+        # whose first token exists and has not reached its consumer;
+        # bounded like the ring, oldest first out (a request that is
+        # never consumed through `tokens_for` would stay).
+        self._await_yield: dict[int, tuple] = {}
+        # first token made -> first token yielded, ms (engine stats)
+        self.deliver_waits: collections.deque = collections.deque(
+            maxlen=512)
         self.dropped_spans = 0
         self.requests_seen = 0
         self.requests_traced = 0
@@ -214,11 +348,37 @@ class FlightRecorder:
             return
         tr["first_ns"] = _now_ns()
         tr["extra"].append(self._instant(tr, "first_token", rid))
+        if len(self._await_yield) >= self.max_spans:
+            del self._await_yield[next(iter(self._await_yield))]
+        root = tr["root"]
+        self._await_yield[rid] = (tr["first_ns"], root["trace_id"],
+                                  root["span_id"])
         h = _metric(_metrics.Histogram, "engine_ttft_ms",
                     "submit -> first token, ms",
                     boundaries=_MS_BOUNDARIES)
         if h is not None:
             h.observe(wait_s * 1e3, tags={"source": self.name})
+
+    def on_first_yield(self, rid: int) -> None:
+        """`tokens_for` is handing the request's first token to its
+        consumer (under the engine's lock, like every hook): the instant
+        past the engine's edge. The request may have finished already —
+        other streams' pumps made all its tokens — and then the instant
+        joins its spans in the ring."""
+        made = self._await_yield.pop(rid, None)
+        if made is None:
+            return
+        first_ns, trace_id, root_sid = made
+        now = _now_ns()
+        self.deliver_waits.append((now - first_ns) / 1e6)
+        s = self._span("first_yield", trace_id, root_sid, now,
+                       {"rid": rid})
+        s["end_ns"] = now
+        tr = self._live.get(rid)
+        if tr is not None:
+            tr["extra"].append(s)
+        else:
+            self._push(s)
 
     def on_token(self, rid: int) -> None:
         tr = self._live.get(rid)
@@ -282,6 +442,8 @@ class FlightRecorder:
 
     def on_finish(self, rid: int, outcome: str) -> None:
         tr = self._live.pop(rid, None)
+        if outcome in ("cancelled", "handoff"):   # nobody to yield to
+            self._await_yield.pop(rid, None)
         if tr is None:
             return
         now = _now_ns()
@@ -307,14 +469,18 @@ class FlightRecorder:
                     h.observe((now - first) / 1e6 / (tr["tokens"] - 1),
                               tags={"source": self.name})
         for s in spans:
-            if len(self._spans) >= self.max_spans:
-                self._spans.popleft()
-                self.dropped_spans += 1
-            self._spans.append(s)
+            self._push(s)
 
     # -- internals ----------------------------------------------------
 
+    def _push(self, s: dict) -> None:
+        if len(self._spans) >= self.max_spans:
+            self._spans.popleft()
+            self.dropped_spans += 1
+        self._spans.append(s)
+
     def _span(self, name, trace_id, parent, start_ns, attrs) -> dict:
+        attrs["tick"] = self.tick
         return {"name": name, "trace_id": trace_id,
                 "span_id": uuid.uuid4().hex[:16],
                 "parent_span_id": parent, "start_ns": start_ns,
@@ -388,6 +554,7 @@ class FlightRecorder:
         assert self.requests_traced <= self.requests_seen
         for tr in self._live.values():
             assert len(tr["extra"]) <= MAX_CHUNKS_PER_REQUEST + 8
+        assert len(self._await_yield) <= self.max_spans
 
 
 # ---------------------------------------------------------------------------

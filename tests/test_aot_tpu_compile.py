@@ -53,14 +53,26 @@ def compile_as_on_tpu(monkeypatch):
     compilation_cache.reset_cache()
 
 
-def compile_for(topo, fn, *args):
-    """Compile `fn` for one described chip; returns how many Mosaic
-    kernels the executable holds."""
+def compiled_text(topo, fn, *args) -> str:
+    """The program `fn` compiles to for one described chip."""
     one = SingleDeviceSharding(topo.devices[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
             for shape, dtype in args]
-    text = jax.jit(fn).lower(*args).compile().as_text()
-    return text.count('custom_call_target="tpu_custom_call"')
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+def kernel_names(text: str) -> list[str]:
+    """Instruction names, `.N` cut, of the Mosaic kernels in a program."""
+    return [line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+            for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def compile_for(topo, fn, *args):
+    """Compile `fn` for one described chip; returns how many Mosaic
+    kernels the executable holds."""
+    return compiled_text(topo, fn, *args).count(
+        'custom_call_target="tpu_custom_call"')
 
 
 def pool_args(kv_dtype):
@@ -166,3 +178,70 @@ def test_fused_xent_compiles_on_a_four_device_mesh(topo):
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         *args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+def _flash_grad(q, k, v):
+    return jax.grad(lambda q, k, v: jnp.sum(
+        flash_attention(q, k, v, causal=True).astype(jnp.float32)),
+        argnums=(0, 1, 2))(q, k, v)
+
+
+def _xent_grad(x, embed, targets):
+    return jax.grad(lambda x, e: jnp.mean(
+        fused_softmax_xent(x, e, targets, impl="pallas")),
+        argnums=(0, 1))(x, embed)
+
+
+_QKV = ((8, 1024, H, D), BF16)
+_POOL = pool_args(BF16)
+NAMED = {
+    "flash": (_flash_grad, [_QKV] * 3,
+              ("flash_fwd", "flash_dq", "flash_dkv")),
+    "fused_xent": (_xent_grad, [((8, 1024, 1024), BF16),
+                                ((50304, 1024), BF16), ((8, 1024), I32)],
+                   ("xent_fwd", "xent_dx", "xent_de")),
+    "paged_decode": (with_scales(da.paged_decode_attention),
+                     [((SLOTS, H, D), BF16), *_POOL, ((SLOTS, MB), I32),
+                      ((SLOTS,), I32)], ("paged_decode",)),
+    "paged_verify": (with_scales(da.paged_verify_attention),
+                     [((SLOTS, 5, H, D), BF16), *_POOL, ((SLOTS, MB), I32),
+                      ((SLOTS,), I32)], ("paged_mq",)),
+    "paged_prefill": (with_scales(da.paged_prefill_attention),
+                      [((128, H, D), BF16), *_POOL, ((MB,), I32),
+                       ((), I32)], ("paged_mq",)),
+    "decode_unpaged": (lambda q, k, v, pos: da.decode_attention(
+        q, k, v, pos, impl="pallas"),
+        [((SLOTS, H, D), BF16), ((SLOTS, 1024, H, D), BF16),
+         ((SLOTS, 1024, H, D), BF16), ((SLOTS,), I32)],
+        ("decode_unpaged",)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(NAMED))
+def test_kernels_carry_their_names(topo, case):
+    """A kernel's `name=` is its instruction's name in the compiled
+    program (`%flash_fwd.N = ... custom-call`), which is what a profiler
+    trace's `XLA Ops` line shows: readers match it, not shapes."""
+    fn, args, names = NAMED[case]
+    text = compiled_text(topo, fn, *args)
+    assert sorted(kernel_names(text)) == sorted(names)
+    for name in names:
+        assert f"%{name}." in text
+
+
+def test_rematerialised_forward_keeps_the_forward_kernels_name(topo):
+    """Under `lax.scan` + `jax.checkpoint` the second run of the forward
+    kernel is `%flash_fwd.N` too: a trace counts both under one name,
+    and `flash_fwd` calls / `flash_dq` calls reads 2.0."""
+    def loss(q, k, v):
+        def layer(x, _):
+            y = jax.checkpoint(lambda x: flash_attention(
+                x, k, v, causal=True))(x)
+            return y, None
+        out, _ = jax.lax.scan(layer, q, None, length=2)
+        return jnp.sum(out.astype(jnp.float32))
+    text = compiled_text(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                         _QKV, _QKV, _QKV)
+    kernels = kernel_names(text)
+    assert kernels.count("flash_fwd") == 2 * kernels.count("flash_dq") == 2
+    assert kernels.count("flash_dkv") == 1

@@ -1,0 +1,324 @@
+"""Program spans on the profiler's clock (`util.telemetry.Phases`): the
+accumulator, the spans a CPU `jax.profiler` trace holds for the train
+loop and the engine tick with the Python tracer off, and the request's
+step past the engine's edge (`first_yield`, `deliver_wait_ms_*`)."""
+
+import glob
+import os
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import gpt
+from ray_tpu.serve.engine import InferenceEngine
+from ray_tpu.train import loop
+from ray_tpu.util import telemetry
+
+TRAIN_SPANS = {"train/next_batch", "train/host_batch", "train/place",
+               "train/dispatch", "train/metrics", "train/metrics_fetch"}
+TICK_SPANS = {"engine/tick", "engine/admit", "engine/prefill_chunk",
+              "engine/decode_build", "engine/decode_dispatch",
+              "engine/token_sync", "engine/emit", "stream/lock_wait"}
+
+
+def tiny_engine(**kw):
+    cfg = gpt.GPTConfig(vocab_size=64, d_model=32, n_layers=1, n_heads=2,
+                        d_ff=64, max_seq_len=32, dtype="float32")
+    params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+    return InferenceEngine(params, cfg, slots=2, max_len=32,
+                           prefill_buckets=(8, 16), **kw)
+
+
+def train_run(dispatches=3, unroll=2, **loop_kw):
+    """`dispatches` fused dispatches through the prefetcher, to the end
+    of the host iterator."""
+    def step_fn(state, batch):
+        return state + jnp.sum(batch["x"]), {"loss": jnp.mean(batch["x"])}
+
+    host = ({"x": np.full((2, 4), i, np.float32)}
+            for i in range(dispatches * unroll))
+    batches = loop.DevicePrefetcher(host, lambda t: jax.tree.map(
+        jnp.asarray, t), depth=2, group=unroll)
+    tl = loop.TrainLoop(step_fn, unroll=unroll, metrics_interval=unroll,
+                        **loop_kw)
+    state, metrics = tl.run(jnp.float32(0), batches)
+    return tl, batches, metrics
+
+
+# -- the primitive -----------------------------------------------------------
+
+def test_phase_counts_and_nests():
+    ph = telemetry.Phases()
+    for _ in range(3):
+        with ph.phase("outer", step=1) as outer:
+            with ph.phase("inner") as inner:
+                time.sleep(1e-3)
+    assert ph.count("outer") == ph.count("inner") == 3
+    assert ph.seconds("outer") >= ph.seconds("inner") >= 3e-3
+    assert outer.seconds >= inner.seconds >= 1e-3
+    assert ph.seconds("outer", "inner") == \
+        ph.seconds("outer") + ph.seconds("inner")
+    assert ph.count("never") == 0 and ph.seconds("never") == 0.0
+
+
+def test_phase_with_no_session_touches_the_accumulator_only():
+    """No profiler session: the span is its total and nothing else — no
+    ring, no list that grows with calls; `set` drops its attributes."""
+    ph = telemetry.Phases()
+    with ph.phase("a", tokens=3) as a:
+        a.set(admitted=2)
+    before = {k: list(v) for k, v in ph.totals.items()}
+    for i in range(1000):
+        with ph.phase("a", step=i):
+            pass
+    assert set(ph.totals) == set(before) == {"a"}
+    assert ph.totals["a"][0] == before["a"][0] + 1000
+    assert vars(telemetry.Phases).get("__slots__") == ("totals",)
+
+
+def test_phase_clear_zeroes_in_place_and_an_error_still_counts():
+    ph = telemetry.Phases()
+    with ph.phase("a") as open_span:
+        ph.clear()                      # e.g. reset_stats() mid-tick
+    assert ph.totals["a"] == [1, open_span.seconds]
+    with pytest.raises(KeyError):
+        with ph.phase("a"):
+            raise KeyError("x")
+    assert ph.count("a") == 2
+
+
+def test_a_process_without_jax_times_without_annotating():
+    total = [0, 0.0]
+    with telemetry._HostPhase(total, "stream/reply", {}) as span:
+        span.set(tokens=4)
+    assert total[0] == 1 and total[1] == span.seconds > 0
+
+
+# -- the train loop's totals ---------------------------------------------------
+
+def test_last_breakdown_keeps_its_keys_and_reads_the_spans():
+    tl, batches, metrics = train_run(dispatches=3, unroll=2)
+    bd = tl.last_breakdown
+    assert set(bd) == {
+        "steps", "total_s", "prefetch_s", "dispatch_s", "metrics_s",
+        "checkpoint_s", "publish_s", "prefetch_share", "dispatch_share",
+        "metrics_share", "checkpoint_share", "publish_share"}
+    assert bd["steps"] == 6 and len(metrics) == 6
+    ph = tl.phases
+    assert bd["prefetch_s"] == ph.seconds("train/next_batch")
+    assert bd["prefetch_share"] == \
+        ph.seconds("train/next_batch") / bd["total_s"]
+    assert bd["dispatch_s"] == ph.seconds("train/dispatch")
+    assert ph.count("train/dispatch") == 3
+    assert ph.count("train/next_batch") == 4     # the end of the iterator
+    assert ph.count("train/metrics_fetch") == tl.last_ring.fetches
+    assert bd["checkpoint_s"] == bd["publish_s"] == 0.0
+    assert "train/checkpoint" not in ph.totals      # no hook, no span
+    # the prefetcher keeps its own, beside `issued`
+    assert batches.phases.count("train/place") == batches.issued == 3
+    assert batches.phases.count("train/host_batch") == 4
+
+
+def test_hooks_open_their_spans_only_when_set():
+    class Ckpt:
+        def maybe_snapshot(self, state, step):
+            pass
+
+        def flush(self):
+            pass
+
+    tl, _, _ = train_run(dispatches=2, checkpointer=Ckpt(),
+                         publisher=lambda state, step: None)
+    assert tl.phases.count("train/checkpoint") == 3     # 2 + flush
+    assert tl.phases.count("train/publish") == 2
+    assert tl.last_breakdown["checkpoint_s"] == \
+        tl.phases.seconds("train/checkpoint")
+    tl.run(jnp.float32(0), iter(()))        # a run starts from zero
+    assert tl.phases.count("train/dispatch") == 0
+
+
+# -- what a profiler trace holds, Python tracer off -----------------------------
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One CPU `jax.profiler` session over a train run and an engine
+    run: {span name: [(thread, start_ns, end_ns, stats), ...]}."""
+    from jax.profiler import ProfileData
+    eng = tiny_engine()
+    warm = eng.submit([5, 9, 3], max_new_tokens=2)      # compile outside
+    list(eng.tokens_for(warm))
+    eng.reset_stats()
+    out = str(tmp_path_factory.mktemp("trace"))
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(out, profiler_options=options)
+    try:
+        tl, batches, _ = train_run(dispatches=3, unroll=2)
+        rid = eng.submit([7, 1, 2, 4], max_new_tokens=4)
+        tokens = list(eng.tokens_for(rid))
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(out, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name != "/host:CPU":
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if re.match(r"(train|engine|stream)/", ev.name):
+                    events.setdefault(ev.name, []).append(
+                        (line.name, ev.start_ns,
+                         ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return {"events": events, "engine": eng, "loop": tl, "tokens": tokens,
+            "path": path}
+
+
+def inside(child, parents):
+    thread, s, e, _ = child
+    return any(t == thread and ps <= s and e <= pe
+               for t, ps, pe, _ in parents)
+
+
+def test_trace_holds_one_span_per_train_dispatch(traced):
+    ev = traced["events"]
+    assert TRAIN_SPANS <= set(ev)
+    assert len(ev["train/dispatch"]) == 3
+    assert len(ev["train/next_batch"]) == 3 + 1     # + end of iterator
+    assert [d[3]["step"] for d in sorted(ev["train/dispatch"],
+                                         key=lambda d: d[1])] == [0, 2, 4]
+    assert all(f[3]["entries"] >= 1 for f in ev["train/metrics_fetch"])
+
+
+def test_prefetcher_spans_are_children_of_next_batch(traced):
+    ev = traced["events"]
+    for name in ("train/host_batch", "train/place"):
+        assert ev[name] and all(inside(c, ev["train/next_batch"])
+                                for c in ev[name])
+    assert all(inside(c, ev["train/metrics"])
+               for c in ev["train/metrics_fetch"])
+
+
+def test_trace_holds_one_tick_per_step_with_the_sync_inside(traced):
+    ev, eng = traced["events"], traced["engine"]
+    assert TICK_SPANS <= set(ev)
+    st = eng.stats()
+    assert len(ev["engine/tick"]) == st["ticks"] >= 3
+    ticks = [t[3]["tick"] for t in ev["engine/tick"]]
+    assert sorted(ticks) == list(range(min(ticks), min(ticks) + len(ticks)))
+    assert len(ev["engine/token_sync"]) == st["decode_steps"]
+    for name in ("engine/token_sync", "engine/decode_dispatch",
+                 "engine/decode_build", "engine/emit", "engine/admit",
+                 "engine/prefill_chunk"):
+        assert all(inside(c, ev["engine/tick"]) for c in ev[name]), name
+    assert all({"decoding", "prefilling"} <= set(t[3])
+               for t in ev["engine/tick"])
+    chunk, = ev["engine/prefill_chunk"]
+    assert chunk[3]["tokens"] == 4 and chunk[3]["bucket"] == 8
+    assert sum(a[3]["admitted"] for a in ev["engine/admit"]) == 1
+    assert sum(e[3]["tokens"] for e in ev["engine/emit"]) == \
+        len(traced["tokens"]) - 1       # the first comes from the prefill
+    # a pump asks for the lock once per tick it drives, and more
+    assert len(ev["stream/lock_wait"]) == st["pump_lock_waits"] >= \
+        len(ev["engine/tick"])
+
+
+def test_the_benchmarks_reducer_reads_the_same_trace(traced):
+    """`benchmarks/harness/spans.py` over a CPU trace: the spans, and no
+    kernel, chip or idle time to report."""
+    from benchmarks.harness import spans
+    s = spans.reduce(traced["path"])
+    assert s["chips"] == 0 and s["kernels"] == {} and s["idle_owners"] == {}
+    count, total, median = s["spans"]["train/dispatch"]
+    assert count == 3 and 0 < median <= total
+    assert s["spans"]["engine/tick"][0] == len(
+        traced["events"]["engine/tick"])
+
+
+# -- the engine's totals and the request's step past its edge -----------------------
+
+def test_engine_times_come_from_the_spans():
+    eng = tiny_engine()
+    rid = eng.submit([5, 9, 3], max_new_tokens=4)
+    assert len(list(eng.tokens_for(rid))) == 4
+    st, ph = eng.stats(), eng._phases
+    assert st["prefill_time_s"] == ph.seconds("engine/prefill_chunk") > 0
+    assert st["decode_time_s"] == ph.seconds(
+        "engine/decode_dispatch", "engine/token_sync") > 0
+    assert st["ticks"] == ph.count("engine/tick") > 0
+    assert st["tick_s"] >= st["admit_s"] + st["decode_build_s"] \
+        + st["decode_dispatch_s"] + st["token_sync_s"] + st["emit_s"]
+    assert st["pump_lock_wait_s"] == ph.seconds("stream/lock_wait") > 0
+    assert st["p50_token_latency_ms"] > 0
+    eng.reset_stats()
+    st = eng.stats()
+    assert st["ticks"] == st["pump_lock_waits"] == 0
+    assert st["decode_time_s"] == st["prefill_time_s"] == 0.0
+    assert st["deliver_wait_ms_p99"] == 0.0
+
+
+@pytest.mark.parametrize("drain_first", [False, True],
+                         ids=["streamed", "finished-before-first-yield"])
+def test_first_yield_follows_first_token_and_events_carry_a_tick(
+        drain_first):
+    """`first_yield` is the instant `tokens_for` hands the first token
+    on; other streams' pumps may have finished the request by then."""
+    eng = tiny_engine()
+    rid = eng.submit([5, 9, 3], max_new_tokens=3)
+    if drain_first:
+        eng.run_until_idle()
+    assert len(list(eng.tokens_for(rid))) == 3
+    spans = [s for s in eng._recorder.get_spans()
+             if s["attributes"]["rid"] == rid]
+    by_name = {s["name"]: s for s in spans}
+    assert {"engine.request", "first_token", "first_yield"} <= set(by_name)
+    first, yielded = by_name["first_token"], by_name["first_yield"]
+    assert first["start_ns"] <= yielded["start_ns"]
+    assert yielded["trace_id"] == by_name["engine.request"]["trace_id"]
+    assert all(isinstance(s["attributes"]["tick"], int) for s in spans)
+    assert 1 <= first["attributes"]["tick"] <= yielded["attributes"]["tick"]
+    assert yielded["attributes"]["tick"] <= eng.stats()["ticks"]
+    st = eng.stats()
+    waited_ms = (yielded["start_ns"] - first["start_ns"]) / 1e6
+    assert st["deliver_wait_ms_p50"] == st["deliver_wait_ms_p99"] \
+        == pytest.approx(waited_ms, abs=0.5)
+    assert not eng._recorder._await_yield
+    eng._recorder.check_invariants()
+
+
+def test_deliver_wait_is_documented_and_cancel_forgets_the_wait():
+    doc = InferenceEngine.stats.__doc__
+    for key in ("deliver_wait_ms_p50", "deliver_wait_ms_p99",
+                "pump_lock_wait_s", "tick_s"):
+        assert f"``{key}``" in doc
+    eng = tiny_engine()
+    rid = eng.submit([5, 9, 3], max_new_tokens=3)
+    eng.run_until_idle()                # first token made, never yielded
+    assert rid in eng._recorder._await_yield
+    gen = eng.tokens_for(rid)
+    gen.close()                         # never started: no finally runs
+    eng.cancel(rid)
+    assert not eng._recorder._await_yield
+    assert eng.stats()["deliver_wait_ms_p50"] == 0.0
+
+
+def test_replica_counts_its_replies():
+    from ray_tpu.serve.replica import Replica
+
+    def chunks():
+        yield from range(5)
+
+    rep = Replica({"callable": chunks, "deployment_name": "phases-test"})
+    sid = rep._register_stream(chunks())
+    got, done = rep._next_chunks_sync(sid, 3)
+    assert (got, done) == ([0, 1, 2], False)
+    got, done = rep._next_chunks_sync(sid, 3)
+    assert (got, done) == ([3, 4], True)
+    assert rep._next_chunks_sync(sid, 3) == (None, True)
+    st = rep.stats()
+    assert st["replies"] == 3 and st["reply_s"] > 0
