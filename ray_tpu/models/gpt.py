@@ -45,10 +45,12 @@ class GPTConfig:
     remat: bool = True
     # What the layer-scan checkpoint saves for backward:
     #   "nothing"  - recompute the whole block (min HBM, max recompute)
-    #   "dots"     - save matmul/attention outputs, recompute elementwise
-    #                (jax.checkpoint_policies.checkpoint_dots_with_no_
-    #                batch_dims; bwd skips re-running the big einsums)
-    #   "attn_out" - save only the attention-kernel outputs
+    #   "dots"     - save matmul outputs (jax.checkpoint_policies.
+    #                checkpoint_dots_with_no_batch_dims) and what the flash
+    #                forward kernel made (output and row logsumexp):
+    #                recompute elementwise only, bwd re-runs neither the
+    #                big einsums nor the attention forward
+    #   "attn_out" - save only what the flash forward kernel made
     remat_policy: str = "nothing"
     attn_impl: str = "auto"        # auto | ring | flash | xla
     # Output dtype of the block einsums. MXU accumulation is f32 either
@@ -187,9 +189,7 @@ def _attention(q, k, v, cfg: GPTConfig, mesh: Mesh | None):
             impl = "ring"
         else:
             impl = "flash"
-    if impl == "ring":
-        out = ring_attention(q, k, v, mesh, causal=True)
-    elif impl == "flash":
+    if impl == "flash":
         from ray_tpu.ops.flash_attention import flash_attention
         attend = partial(flash_attention, causal=True)
         if mesh is not None and mesh.size > 1:
@@ -207,11 +207,16 @@ def _attention(q, k, v, cfg: GPTConfig, mesh: Mesh | None):
                                       mesh=mesh), q.shape)
             attend = shard_map(attend, mesh=mesh, in_specs=(spec,) * 3,
                                out_specs=spec, check_vma=False)
-        out = attend(q, k, v)
-    else:
-        out = reference_attention(q, k, v, causal=True)
-    # Named for the remat policy: saving attention outputs means the bwd
-    # pass re-runs only cheap matmuls/norms, never the attention kernel.
+        # The kernel's forward rule names what it keeps for backward
+        # (`flash_attention.SAVED_NAMES`), inside its custom_vjp where
+        # the remat policy finds them: with both saved the bwd pass goes
+        # straight to the dQ and dK/dV kernels.
+        return attend(q, k, v)
+    # Ring and XLA attention are plain autodiff: their backward recomputes
+    # their forward whatever is saved, and the name spares that recompute
+    # only its last p @ v.
+    out = (ring_attention(q, k, v, mesh, causal=True) if impl == "ring"
+           else reference_attention(q, k, v, causal=True))
     return checkpoint_name(out, "attn_out")
 
 
@@ -277,16 +282,18 @@ def forward_features(params, tokens, cfg: GPTConfig,
         x, kv = jax.lax.scan(scan_body_kv, x, params["layers"])
         return _rms_norm(x, params["final_ln_scale"].astype(adt)), kv
     if cfg.remat:
-        # Which policy is fastest is not measured on this round's chip:
-        # save-nothing recomputes the forward under the backward's HBM
-        # traffic, the others trade memory for skipped recompute.
+        # Measured on the v5e (PERF.md, PR 25): under "dots", saving the
+        # flash forward's output and lse halves `flash_fwd`'s time a step
+        # for one more activation a layer. "nothing" and "attn_out" are
+        # not measured.
+        policies = jax.checkpoint_policies
         policy = None
-        if cfg.remat_policy == "dots":
-            policy = jax.checkpoint_policies \
-                .checkpoint_dots_with_no_batch_dims
-        elif cfg.remat_policy == "attn_out":
-            policy = jax.checkpoint_policies.save_only_these_names(
-                "attn_out")
+        if cfg.remat_policy in ("dots", "attn_out"):
+            from ray_tpu.ops.flash_attention import SAVED_NAMES
+            policy = policies.save_only_these_names(*SAVED_NAMES)
+            if cfg.remat_policy == "dots":
+                policy = policies.save_from_both_policies(
+                    policies.checkpoint_dots_with_no_batch_dims, policy)
         elif cfg.remat_policy != "nothing":
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r} "

@@ -26,10 +26,11 @@ strictly above the diagonal are predicated away in all three kernels.
 
 The forward-only (inference) path compiles a kernel variant with no lse
 output, so serving never pays the lse write; the lse variant runs only
-under autodiff.  lse/delta live as [BH, T, 128] f32 — broadcast across the
-128-lane tile — because Mosaic requires output block last dims of 128 (a
-[BH, T] row vector with (1, block_q) blocks fails its tiling check); the
-stock JAX TPU flash kernel stores its lse the same way.
+under autodiff.  The kernels read and write lse/delta as [BH, T, 128] f32 —
+broadcast across the 128-lane tile — because Mosaic requires output block
+last dims of 128 (a [BH, T] row vector with (1, block_q) blocks fails its
+tiling check); the stock JAX TPU flash kernel stores its lse the same way.
+Between the forward and the backward the lse is kept as [BH, T].
 """
 
 from __future__ import annotations
@@ -38,6 +39,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -53,6 +55,13 @@ NEG_INF = -1e30
 # the first scope it meets (`jvp(xent_fwd)` -> `%jvp_xent_fwd_.N`), so
 # every call sits in a `named_scope` of its own name that takes the wrap.
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
+
+# `checkpoint_name`s of the two things only the forward kernel can make:
+# its output and its per-row logsumexp. A `jax.checkpoint` policy that
+# saves both (`models/gpt.py`: "dots", "attn_out") goes straight to the
+# dQ and dK/dV kernels in its backward; one that saves neither runs the
+# forward kernel a second time to get them back.
+SAVED_NAMES = ("attn_out", "attn_lse")
 
 
 def _causal_mask(s, q_start, k_start, block_q, block_kv):
@@ -387,12 +396,12 @@ def _flash_fwd(q, k, v, causal, block_q, block_kv):
                                    with_lse=True)
     if lse is None:
         return out, (q, k, v, None, None)
-    # The residual keeps the kernel's broadcast [BH, T, 128] lse layout.
-    # Slicing to [BH, T] and re-broadcasting in bwd would add two
-    # passes per layer (64 MB each at bench shape; cost not measured);
-    # under the default per-layer remat the residual only lives within
-    # one layer's backward, so the 128x is transient. A no-remat long-T
-    # config that can't afford it should slice here.
+    # The kernel writes its lse across a 128-lane tile; one lane of it is
+    # kept ([BH, T] f32, 1/128 of the bytes), so a policy can afford to
+    # save it for every layer. `_flash_bwd` broadcasts it back, as it
+    # does delta.
+    out = checkpoint_name(out, SAVED_NAMES[0])
+    lse = checkpoint_name(lse[..., 0], SAVED_NAMES[1])
     return out, (q, k, v, out, lse)
 
 
@@ -413,6 +422,7 @@ def _flash_bwd(causal, block_q, block_kv, res, g):
                     axis=-1)                          # [B, T, H]
     delta = delta.transpose(0, 2, 1).reshape(b * h, t)
     delta = jnp.broadcast_to(delta[..., None], (b * h, t, 128))
+    lse = jnp.broadcast_to(lse[..., None], (b * h, t, 128))
     bhtd = lambda x: (_pad_heads(x, d_pad)
                       .transpose(0, 2, 1, 3).reshape(b * h, t, d_pad))
     dq, dk, dv = _flash_bwd_bhtd(

@@ -245,3 +245,24 @@ def test_rematerialised_forward_keeps_the_forward_kernels_name(topo):
     kernels = kernel_names(text)
     assert kernels.count("flash_fwd") == 2 * kernels.count("flash_dq") == 2
     assert kernels.count("flash_dkv") == 1
+
+
+def test_gpt_dots_policy_runs_the_forward_kernel_once(topo):
+    """The twin of the test above, through `gpt`'s own layer checkpoint:
+    "dots" saves the forward kernel's output and lse, so the compiled
+    backward holds as many `flash_fwd` as `flash_dq`."""
+    cfg = gpt.GPTConfig(vocab_size=512, d_model=H * D, n_layers=2,
+                        n_heads=H, d_ff=1024, max_seq_len=512,
+                        attn_impl="flash", remat_policy="dots")
+    params = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
+                            jax.random.key(0))
+    one = SingleDeviceSharding(topo.devices[0])
+    abstract = lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=one)
+    text = jax.jit(jax.grad(
+        lambda p, tokens: gpt.loss_fn(p, {"tokens": tokens}, cfg))).lower(
+            jax.tree.map(abstract, params),
+            jax.ShapeDtypeStruct((2, 513), I32, sharding=one)
+        ).compile().as_text()
+    kernels = kernel_names(text)
+    assert (kernels.count("flash_fwd") == kernels.count("flash_dq")
+            == kernels.count("flash_dkv") == 1)
