@@ -353,7 +353,7 @@ def main():
                 peng.submit(make_prompt(), max_new_tokens=new_tokens,
                             priority=cls)
             for _ in range(200):
-                if not peng._pending:
+                if not peng.stats()["pending"]:
                     break
                 peng.step()
         peng.run_until_idle()

@@ -374,7 +374,8 @@ SERVE_HANDLE_REFRESH_S = define(
 
 SERVE_STREAM_BATCH = define(
     "SERVE_STREAM_BATCH", int, 16,
-    "Streaming responses ship this many chunks per proxy round-trip.")
+    "Streaming responses ship at most this many chunks per proxy "
+    "round-trip (a reply goes out with what is ready).")
 
 SERVE_STREAM_IDLE_TTL_S = define(
     "SERVE_STREAM_IDLE_TTL_S", float, 300.0,

@@ -77,11 +77,19 @@ before. `step()` is the one scheduler tick (admit, chunk, decode);
 `submit()` / `tokens_for()` / `cancel()` are the request-side API. A
 consumer that stops iterating `tokens_for` releases its request's
 blocks and queues automatically (generator finalization cancels it).
+
+Three locks. The scheduler lock (`_lock`) is a tick's, for its whole
+length. The delivery lock (`_delivery`, a condition) holds what crosses
+between a tick and its consumers — rids, `submit`'s inbox, the output
+queues — for a few dict operations at a time. The pump mutex (`_pump`)
+names the one consumer that runs the next tick; the others sleep on the
+condition (`_await`). Nothing waits for `_lock` to submit or to pop.
 """
 
 from __future__ import annotations
 
 import collections
+import contextlib
 import logging
 import os
 import threading
@@ -397,6 +405,11 @@ class RadixTree:
 # ---------------------------------------------------------------------------
 # engine
 # ---------------------------------------------------------------------------
+
+# what `tokens_for`'s pop returns at the end of a stream (None means
+# "nothing yet", and a token id can be 0)
+_END = object()
+
 
 @dataclass
 class _Pending:
@@ -774,8 +787,21 @@ class InferenceEngine:
 
         self._slots = [_Slot() for _ in range(slots)]
         self._pending: collections.deque[_Pending] = collections.deque()
-        self._rid = 0
         self._admit_seq = 0
+        # The scheduler lock: slots, `_pending`, the allocator, the
+        # tree, the counters. A tick holds it for its whole length, the
+        # device round trip included.
+        self._lock = threading.RLock()
+        # The delivery lock, and the condition consumers sleep on: rids,
+        # the inbox, and what a tick hands to its streams (`_out`,
+        # `_done`, `_errors`, `_handoffs`). Held for a few dict and
+        # deque operations, never across device work; taken after
+        # `_lock` where both are held, never before it.
+        self._delivery = threading.Condition()
+        self._rid = 0
+        # submitted, not yet seen by a tick: `_take_inbox` (under
+        # `_lock`) moves it into `_pending`
+        self._inbox: collections.deque[_Pending] = collections.deque()
         # rid -> deque of emitted token ids; rid dropped when done AND
         # drained (tokens_for pops, then deletes) or cancelled.
         self._out: dict[int, collections.deque] = {}
@@ -783,7 +809,11 @@ class InferenceEngine:
         # rid -> exception for requests terminated while QUEUED (class-
         # ordered shedding): tokens_for raises it to the consumer.
         self._errors: dict[int, Exception] = {}
-        self._lock = threading.RLock()
+        # Held by the one consumer that runs the next tick (`_await`);
+        # only ever tried, never waited for.
+        self._pump = threading.Lock()
+        # threads asking for `_lock` that run no tick (`_before_pump`)
+        self._lock_waiters = 0
 
         # --- disaggregated prefill/decode handoff state ---------------
         # Export side (role="prefill"): rid -> host-side KV blob parked
@@ -912,9 +942,9 @@ class InferenceEngine:
         self.name = _telemetry.next_name("engine")
         self._recorder = _telemetry.FlightRecorder(
             self.name, sample=telemetry_sample)
-        # Program spans of the tick and the pump (`engine/*`,
-        # `stream/lock_wait`): profiler annotations whose totals feed
-        # stats(). Every one closes under `_lock`.
+        # Program spans of the tick, of `submit` and of a consumer's
+        # wait for a tick it does not run (`engine/*`, `stream/wait`):
+        # profiler annotations whose totals feed stats().
         self._phases = _telemetry.Phases()
         self._sentinel = _telemetry.RetraceSentinel(self.name)
         self._sentinel.watch("decode", lambda: self.decode_traces, cap=1,
@@ -1031,63 +1061,102 @@ class InferenceEngine:
         prompts are absorbed in chunks, so there is no per-bucket prompt
         length limit, only the cache-capacity ones.
 
+        Does not wait for a tick: under the delivery lock it takes its
+        rid, makes its output queue and leaves the request in an inbox,
+        which the next `step()` moves to the admission queue. Only an
+        engine with shedding configured (`max_queue` /
+        `shed_high_water`) takes the scheduler lock here, because the
+        verdict reads the queue and the pool: such a submit can wait
+        for the tick in progress.
+
         `priority` is the request's class (0 = lowest, up to
         ``priority_classes - 1``): higher classes get proportionally
         more admission share, shed last, and may preempt strictly-lower
         active streams under block pressure."""
-        prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prompt.size == 0:
-            raise ValueError("empty prompt")
-        priority = int(priority)
-        if not 0 <= priority < self.priority_classes:
-            raise ValueError(
-                f"priority {priority} outside "
-                f"[0, {self.priority_classes})")
-        if prompt.size + max_new_tokens > self.max_len:
-            raise ValueError(
-                f"prompt {prompt.size} + max_new_tokens {max_new_tokens} "
-                f"exceeds cache max_len {self.max_len}")
-        if self._slot_blocks_for(prompt.size, max_new_tokens) > \
-                self.cache_blocks:
-            raise ValueError(
-                f"request footprint "
-                f"{self._slot_blocks_for(prompt.size, max_new_tokens)} "
-                f"blocks exceeds cache blocks {self.cache_blocks}")
-        if self._draft_alloc is not None and \
-                self._slot_blocks_for(prompt.size, max_new_tokens) > \
-                self.draft_cache_blocks:
-            raise ValueError(
-                f"request footprint exceeds draft cache blocks "
-                f"{self.draft_cache_blocks}")
-        with self._lock:
-            if self.max_queue is not None or \
-                    self.shed_high_water is not None:
-                reason = self._shed_verdict(
-                    self._slot_blocks_for(prompt.size, max_new_tokens))
+        with self._phases.phase("engine/submit"):
+            prompt = np.asarray(prompt, np.int32).reshape(-1)
+            if prompt.size == 0:
+                raise ValueError("empty prompt")
+            priority = int(priority)
+            if not 0 <= priority < self.priority_classes:
+                raise ValueError(
+                    f"priority {priority} outside "
+                    f"[0, {self.priority_classes})")
+            if prompt.size + max_new_tokens > self.max_len:
+                raise ValueError(
+                    f"prompt {prompt.size} + max_new_tokens "
+                    f"{max_new_tokens} exceeds cache max_len "
+                    f"{self.max_len}")
+            n_blocks = self._slot_blocks_for(prompt.size, max_new_tokens)
+            if n_blocks > self.cache_blocks:
+                raise ValueError(
+                    f"request footprint {n_blocks} blocks exceeds cache "
+                    f"blocks {self.cache_blocks}")
+            if self._draft_alloc is not None and \
+                    n_blocks > self.draft_cache_blocks:
+                raise ValueError(
+                    f"request footprint exceeds draft cache blocks "
+                    f"{self.draft_cache_blocks}")
+            req = _Pending(0, prompt, max_new_tokens, temperature, eos_id,
+                           time.perf_counter(), priority=priority)
+            if self.max_queue is None and self.shed_high_water is None:
+                return self._enqueue(req)
+            with self._before_pump(), self._lock:
+                self._take_inbox()
+                reason = self._shed_verdict(n_blocks)
                 # Class-ordered shedding: pressure evicts the lowest-
                 # class QUEUED request first; the incoming request is
                 # only shed when nothing queued ranks below it (so an
                 # all-one-class engine behaves exactly as before).
                 while reason is not None and \
                         self._shed_lowest_below(priority):
-                    reason = self._shed_verdict(
-                        self._slot_blocks_for(prompt.size,
-                                              max_new_tokens))
+                    reason = self._shed_verdict(n_blocks)
                 if reason is not None:
                     self._sheds += 1
                     self._class_counter(priority)["sheds"] += 1
                     raise OverloadedError(
                         f"engine overloaded, request shed: {reason}")
-            rid = self._rid
+                return self._enqueue(req)
+
+    def _enqueue(self, req: _Pending) -> int:
+        """`submit`'s one step under the delivery lock: the rid, the
+        output queue, the inbox."""
+        with self._delivery:
+            rid = req.rid = self._rid
             self._rid += 1
             self._out[rid] = collections.deque()
-            self._pending.append(_Pending(rid, prompt, max_new_tokens,
-                                          temperature, eos_id,
-                                          time.perf_counter(),
-                                          priority=priority))
-            self._class_counter(priority)["submitted"] += 1
-            self._recorder.on_submit(rid, prompt.size)
+            self._inbox.append(req)
+            self._recorder.on_submit(rid, req.prompt.size)
         return rid
+
+    def _take_inbox(self) -> None:
+        """Under `_lock`: what `submit` left in the inbox joins the
+        admission queue, in order. Everything that reads the backlog
+        (`step`, `stats`, `cancel`, a shedding `submit`) calls this
+        first. Only holders of `_lock` pop the inbox, and a deque's
+        `popleft` is atomic beside `submit`'s `append`."""
+        while self._inbox:
+            req = self._inbox.popleft()
+            self._pending.append(req)
+            self._class_counter(req.priority)["submitted"] += 1
+
+    @contextlib.contextmanager
+    def _before_pump(self):
+        """Around `with self._lock:` in every thread that runs no tick
+        (`stats`, `cancel`, `update_params`, `import_handoff`,
+        `reset_stats`, a shedding `submit`). A thread that releases an
+        `RLock` and asks for it again wins it, so consumers that tick
+        back to back would keep these waiting for seconds; instead the
+        pump lets every thread counted here go first (`_await`)."""
+        with self._delivery:
+            self._lock_waiters += 1
+        try:
+            yield
+        finally:
+            with self._delivery:
+                self._lock_waiters -= 1
+                if not self._lock_waiters:
+                    self._delivery.notify_all()
 
     def _shed_lowest_below(self, priority: int) -> bool:
         """Shed the lowest-class queued request strictly below
@@ -1110,9 +1179,10 @@ class InferenceEngine:
             return False
         victim = self._pending[victim_i]
         del self._pending[victim_i]
-        self._errors[victim.rid] = OverloadedError(
-            f"engine overloaded: request (class {victim.priority}) "
-            f"shed from the queue for a class-{priority} admission")
+        with self._delivery:
+            self._errors[victim.rid] = OverloadedError(
+                f"engine overloaded: request (class {victim.priority}) "
+                f"shed from the queue for a class-{priority} admission")
         self._sheds += 1
         self._class_counter(victim.priority)["sheds"] += 1
         self._recorder.on_finish(victim.rid, "shed")
@@ -1129,11 +1199,12 @@ class InferenceEngine:
         return d
 
     def cancel(self, rid: int) -> bool:
-        """Abort a request wherever it is — pending, mid-prefill,
-        decoding, or finished-but-undrained — releasing its cache
-        blocks and output queue. Idempotent; returns True if anything
-        was released."""
-        with self._lock:
+        """Abort a request wherever it is — in the inbox, pending,
+        mid-prefill, decoding, or finished-but-undrained — releasing its
+        cache blocks and output queue. Idempotent; returns True if
+        anything was released."""
+        with self._before_pump(), self._lock:
+            self._take_inbox()
             hit = False
             for i, req in enumerate(self._pending):
                 if req.rid == rid:
@@ -1145,12 +1216,6 @@ class InferenceEngine:
                     self._release(i)
                     hit = True
                     break
-            if self._handoffs.pop(rid, None) is not None:
-                # an exported-but-never-collected prefill: the device
-                # blocks were already freed at export, so abandoning
-                # only drops the host blob
-                self._handoffs_abandoned += 1
-                hit = True
             if rid in self._import_rids:
                 self._import_rids.discard(rid)
                 for i, (irid, _) in enumerate(self._imports):
@@ -1158,69 +1223,100 @@ class InferenceEngine:
                         del self._imports[i]
                         break
                 hit = True
-            hit |= self._out.pop(rid, None) is not None
-            hit |= self._errors.pop(rid, None) is not None
-            self._done.discard(rid)
+            with self._delivery:
+                if self._handoffs.pop(rid, None) is not None:
+                    # an exported-but-never-collected prefill: the
+                    # device blocks were already freed at export, so
+                    # abandoning only drops the host blob
+                    self._handoffs_abandoned += 1
+                    hit = True
+                hit |= self._out.pop(rid, None) is not None
+                hit |= self._errors.pop(rid, None) is not None
+                self._done.discard(rid)
             if hit:
                 self._cancelled += 1
                 self._recorder.on_finish(rid, "cancelled")
             return hit
 
+    def _await(self, take):
+        """What `tokens_for` and `handoff_for` block in. `take()` runs
+        under the delivery lock and returns what its request has ready,
+        or None. With nothing ready the caller either becomes the pump
+        (it gets the pump mutex without waiting, and runs ONE `step()`,
+        which takes `_lock` itself) or sleeps on the condition until
+        the pump has ticked. Nobody queues to become the pump and
+        nobody asks for `_lock` in order to pop, so N consumers cost a
+        tick nothing; a lone consumer is always the pump, and sees the
+        ticks it would see calling `step()` itself. A tick that raises
+        does so in the consumer that ran it, and in no other.
+
+        The mutex is given up under the delivery lock, and the
+        sleepers woken in the same hold: one that found the mutex taken
+        is already waiting by then, and cannot miss the call. Before a
+        tick the pump lets `_before_pump`'s threads have `_lock`."""
+        while True:
+            with self._delivery:
+                got = take()
+                if got is not None:
+                    return got
+                if not self._pump.acquire(blocking=False):
+                    with self._phases.phase("stream/wait"):
+                        self._delivery.wait()
+                    continue
+                while self._lock_waiters:
+                    self._delivery.wait()
+            try:
+                self.step()
+            finally:
+                with self._delivery:
+                    self._pump.release()
+                    self._delivery.notify_all()
+
     def tokens_for(self, rid: int):
         """Generator of generated tokens for one request — each yielded
         value is a `TokenEvent`: an ``int`` (token id) that also carries
         ``.logprob`` (natural log pi(token|prefix) under the weights it
-        was sampled with) and ``.params_version``. Pumps the
-        shared engine: each next() ticks `step()` (under the lock) until
-        this request has output, so N concurrent consumers collectively
-        drive one continuously-batched device loop. Abandoning the
-        generator (break / close / GC) cancels the request and releases
-        its cache blocks."""
+        was sampled with) and ``.params_version``. Each next() pops
+        this request's next token under the delivery lock; when there
+        is none it waits in `_await`: one consumer at a time runs
+        `step()` and the others sleep until that tick has handed its
+        tokens over, so N concurrent consumers collectively drive one
+        continuously-batched device loop, and a lone one drives it
+        alone. Abandoning the generator (break / close / GC) cancels
+        the request and releases its cache blocks."""
         first = True
+
+        def take():
+            nonlocal first
+            q = self._out.get(rid)
+            if q is None:               # cancelled, or never submitted
+                return _END
+            err = self._errors.pop(rid, None)
+            if err is not None:
+                # terminated while queued (class-ordered shed): surface
+                # the typed error to this consumer
+                del self._out[rid]
+                self._done.discard(rid)
+                raise err
+            fin = rid in self._done
+            if not q:
+                if not fin:
+                    return None
+                tok = _END
+            else:
+                tok = q.popleft()
+                if first:
+                    first = False
+                    self._recorder.on_first_yield(rid)
+            if fin and not q:
+                self._done.discard(rid)
+                del self._out[rid]
+            return tok
+
         try:
-            while True:
-                tok = None
-                fin = False
-                # asked for the lock here, held where the span closes:
-                # what a pump waits behind every other stream's pump
-                wait = self._phases.phase("stream/lock_wait")
-                wait.__enter__()
-                with self._lock:   # pop under the lock, yield OUTSIDE
-                    # it — a generator suspends at yield, and a
-                    # suspended holder would block every consumer's pump.
-                    wait.__exit__(None, None, None)
-                    q = self._out.get(rid)
-                    if q is None:
-                        return
-                    err = self._errors.pop(rid, None)
-                    if err is not None:
-                        # terminated while queued (class-ordered shed):
-                        # surface the typed error to this consumer
-                        del self._out[rid]
-                        self._done.discard(rid)
-                        raise err
-                    if not q and rid not in self._done:
-                        # ONE tick per lock hold, not a hold-until-token
-                        # loop: releasing between ticks lets submit()/
-                        # stats()/cancel() interleave with saturated
-                        # pumps. (Observed: the serve controller's
-                        # autoscaling scrape starving seconds behind 8
-                        # pumping consumers and reading post-drain
-                        # queue depths — the scale-up signal vanished.)
-                        self.step()
-                    if q:
-                        tok = q.popleft()
-                        if first:
-                            first = False
-                            self._recorder.on_first_yield(rid)
-                    if rid in self._done and not q:
-                        self._done.discard(rid)
-                        del self._out[rid]
-                        fin = True
-                if tok is not None:
-                    yield tok
-                elif fin:
-                    return
+            # yield OUTSIDE every lock: a generator suspends at yield
+            while (tok := self._await(take)) is not _END:
+                yield tok
         finally:
             self.cancel(rid)
 
@@ -1282,7 +1378,6 @@ class InferenceEngine:
             "draft_payload": draft_payload,
             "kv_bytes": int(kv_bytes),
         }
-        self._handoffs[s.rid] = blob
         self._handoffs_exported += 1
         self._kv_blocks_exported += n_written * (
             2 if draft_payload is not None else 1)
@@ -1290,44 +1385,49 @@ class InferenceEngine:
         self._kv_export_ms.append(dt * 1e3)
         self._recorder.on_kv_export(s.rid, n_written, kv_bytes, dt)
         self._recorder.on_finish(s.rid, "handoff")
-        # No token consumer on a prefill engine: drop the output queue
-        # now (handoff_for polls `_handoffs`, not `_out`) and release
+        # No token consumer on a prefill engine: park the blob and drop
+        # the output queue in one hold (handoff_for reads `_handoffs`
+        # first, and takes a missing queue for a cancel), and release
         # the device blocks — the prompt's full blocks live on in the
         # radix tree for shared-prefix admissions, everything else is
         # host-side in the blob.
-        self._out.pop(s.rid, None)
-        self._done.discard(s.rid)
+        with self._delivery:
+            self._handoffs[s.rid] = blob
+            self._out.pop(s.rid, None)
+            self._done.discard(s.rid)
         self._release(slot_idx)
 
     def handoff_for(self, rid: int) -> dict:
-        """Pump the scheduler until `rid`'s prefill completes, then pop
-        and return its handoff blob — the prefill-role analogue of
+        """Wait in `_await` (running the scheduler, or sleeping while
+        another consumer runs it) until `rid`'s prefill completes, then
+        pop and return its handoff blob — the prefill-role analogue of
         draining `tokens_for`. Raises the parked error for a request
         shed from the queue, KeyError for an unknown/cancelled rid."""
         if self.role != "prefill":
             raise RuntimeError(
                 "handoff_for is only available on a prefill-role "
                 f"engine (this engine is {self.role!r})")
-        while True:
-            with self._lock:
-                blob = self._handoffs.pop(rid, None)
-                if blob is not None:
-                    return blob
-                err = self._errors.pop(rid, None)
-                if err is not None:
-                    self._out.pop(rid, None)
-                    self._done.discard(rid)
-                    raise err
-                if rid not in self._out:
-                    raise KeyError(
-                        f"unknown or cancelled handoff rid {rid}")
-                # one tick per lock hold, same contract as tokens_for
-                self.step()
+
+        def take():
+            blob = self._handoffs.pop(rid, None)
+            if blob is not None:
+                return blob
+            err = self._errors.pop(rid, None)
+            if err is not None:
+                self._out.pop(rid, None)
+                self._done.discard(rid)
+                raise err
+            if rid not in self._out:
+                raise KeyError(
+                    f"unknown or cancelled handoff rid {rid}")
+            return None
+
+        return self._await(take)
 
     def take_handoff(self, rid: int) -> dict | None:
         """Non-blocking collect: pop `rid`'s parked blob if its prefill
         already completed, else None."""
-        with self._lock:
+        with self._delivery:
             return self._handoffs.pop(rid, None)
 
     def import_handoff(self, blob: dict) -> int:
@@ -1375,14 +1475,15 @@ class InferenceEngine:
             raise ValueError(
                 f"handoff priority {priority} outside "
                 f"[0, {self.priority_classes})")
-        with self._lock:
-            rid = self._rid
-            self._rid += 1
-            self._out[rid] = collections.deque()
+        with self._before_pump(), self._lock:
+            with self._delivery:
+                rid = self._rid
+                self._rid += 1
+                self._out[rid] = collections.deque()
+                self._recorder.on_submit(rid, p)
             self._imports.append((rid, blob))
             self._import_rids.add(rid)
             self._class_counter(priority)["submitted"] += 1
-            self._recorder.on_submit(rid, p)
         return rid
 
     def _admit_imports(self) -> bool:
@@ -1595,7 +1696,7 @@ class InferenceEngine:
         # mutex is declared blocking_ok for exactly this).
         with self._swap_mutex:
             t0 = time.perf_counter()
-            with self._lock:
+            with self._before_pump(), self._lock:
                 old = self.params
                 old_draft = self.draft_params
             if draft_params is not None and old_draft is None:
@@ -1616,7 +1717,7 @@ class InferenceEngine:
             placed_draft = (
                 self._place_tree(old_draft, draft_params, "draft_params")
                 if draft_params is not None else None)
-            with self._lock:
+            with self._before_pump(), self._lock:
                 self.params = self._swap_fn(old, placed)
                 if placed_draft is not None:
                     self.draft_params = self._swap_fn(
@@ -2035,17 +2136,21 @@ class InferenceEngine:
                                   - self._swap_pending_ts) * 1e3
             self._swap_pending_ts = None
             self._recorder.on_swap_crossing(s.rid)
-        self._out[s.rid].append(ev)
+        hit_eos = s.eos_id is not None and tok == s.eos_id
+        # pos of the *next* token; it must still fit in the cache row.
+        finished = s.remaining <= 0 or hit_eos or s.pos + 1 >= self.max_len
+        # the hand-over: a stream's last token and its end in one hold
+        with self._delivery:
+            self._out[s.rid].append(ev)
+            if finished:
+                self._done.add(s.rid)
         s.emitted.append(int(tok))
         cc = self._class_counter(s.priority)
         cc["decode_tokens"] += 1
         self._recorder.on_token(s.rid)
         if self.spec == "ngram":
             s.history.append(tok)
-        hit_eos = s.eos_id is not None and tok == s.eos_id
-        # pos of the *next* token; it must still fit in the cache row.
-        if s.remaining <= 0 or hit_eos or s.pos + 1 >= self.max_len:
-            self._done.add(s.rid)
+        if finished:
             cc["completed"] += 1
             self._release(slot_idx)
             self._recorder.on_finish(s.rid, "finished")
@@ -2081,6 +2186,7 @@ class InferenceEngine:
                     had_decoders = any(
                         s.phase == "decode" for s in self._slots)
                     with phase("engine/admit") as admit:
+                        self._take_inbox()
                         seq = self._admit_seq
                         imported = self._admit_imports()
                         admitted = self._admit_pending() or imported
@@ -2274,7 +2380,7 @@ class InferenceEngine:
         """Drive the scheduler until every submitted request finished."""
         while True:
             with self._lock:
-                busy = self._pending or any(
+                busy = self._inbox or self._pending or any(
                     s.active for s in self._slots)
                 if not busy:
                     return
@@ -2332,7 +2438,8 @@ class InferenceEngine:
         # every output queue must still be owned by someone — a leaked
         # `_out` deque (or an errored rid still scheduled) would pin
         # consumer state forever.
-        pend_rids = [q.rid for q in self._pending]
+        queued = list(self._pending) + list(self._inbox)
+        pend_rids = [q.rid for q in queued]
         assert len(pend_rids) == len(set(pend_rids)), \
             f"duplicate pending rids: {pend_rids}"
         slot_rids = [s.rid for s in self._slots if s.active]
@@ -2369,7 +2476,7 @@ class InferenceEngine:
             | set(self._errors) | import_rids
         for rid in self._out:
             assert rid in owners, f"orphaned output queue for rid {rid}"
-        for q in self._pending:
+        for q in queued:
             assert 0 <= q.priority < self.priority_classes
             assert q.max_new_tokens >= 1, \
                 f"rid {q.rid} requeued with no token budget"
@@ -2382,7 +2489,7 @@ class InferenceEngine:
         rate; a learner correlating trajectory tags against
         `stats()["params_version"]` must not see it rewind. The windowed
         `swaps` counter and `weight_swap_ms` DO reset."""
-        with self._lock:
+        with self._before_pump(), self._lock:
             self._decode_steps = 0
             self._prefill_tokens = self._decode_tokens = 0
             self._phases.clear()
@@ -2431,7 +2538,8 @@ class InferenceEngine:
 
         Scheduler/throughput:
           ``slots`` / ``active`` / ``pending`` — slot capacity, occupied
-          slots, queued (unadmitted) requests.
+          slots, queued (unadmitted) requests, those no tick has seen
+          yet included.
           ``decode_steps`` — device decode/verify ticks since reset.
           ``prefill_tokens`` / ``decode_tokens`` — tokens absorbed /
           emitted since reset; ``prefill_time_s`` / ``decode_time_s``
@@ -2492,9 +2600,10 @@ class InferenceEngine:
           ``deliver_wait_ms_p50`` / ``deliver_wait_ms_p99`` — first
           token made (the recorder's first_token) to first token handed
           to the stream's consumer by `tokens_for` (its first_yield),
-          over the last 512 sampled requests: what a stream waits for
-          the scheduler lock after its token exists. ``ttft_ms_*`` stops
-          at the engine's edge; this is the step past it.
+          over the last 512 sampled requests: what a stream waits, once
+          its token exists, for the tick that made it to end and for its
+          consumer to wake. ``ttft_ms_*`` stops at the engine's edge;
+          this is the step past it.
 
         Program spans (util.telemetry.Phases; each is also an annotation
         of the same name in a `jax.profiler` trace, on the device
@@ -2509,9 +2618,12 @@ class InferenceEngine:
           ``token_sync_s`` — `engine/token_sync`: waiting for the
           sampled tokens on the host (the device's time shows here).
           ``emit_s`` — `engine/emit`: routing tokens to their streams.
-          ``pump_lock_waits`` / ``pump_lock_wait_s`` —
-          `stream/lock_wait`: times a `tokens_for` pump asked for the
-          scheduler lock, and the total it waited for it.
+          ``stream_waits`` / ``stream_wait_s`` — `stream/wait`: times a
+          consumer (`tokens_for`, `handoff_for`) slept until a tick that
+          another consumer ran had ended, and the total it slept:
+          consumers asleep, not contending.
+          ``submits`` / ``submit_s`` — `engine/submit`: calls of
+          `submit`, validation and refusals included, and their time.
 
         Speculative decoding:
           ``spec`` / ``spec_k`` — backend ('' when off) and window.
@@ -2582,8 +2694,9 @@ class InferenceEngine:
           bridge fans out as class-tagged gauges. (Double backticks are
           for this dict's own keys only: the contract test reads them.)
         """
-        with self._lock:
+        with self._before_pump(), self._lock:
             self._sentinel.check()   # surface retraces since last tick
+            self._take_inbox()
             per_class = {}
             pend_by = collections.Counter(q.priority for q in self._pending)
             act_by = collections.Counter(
@@ -2704,8 +2817,10 @@ class InferenceEngine:
                     "engine/decode_dispatch", "engine/verify_dispatch"),
                 "token_sync_s": ph.seconds("engine/token_sync"),
                 "emit_s": ph.seconds("engine/emit"),
-                "pump_lock_waits": ph.count("stream/lock_wait"),
-                "pump_lock_wait_s": ph.seconds("stream/lock_wait"),
+                "stream_waits": ph.count("stream/wait"),
+                "stream_wait_s": ph.seconds("stream/wait"),
+                "submits": ph.count("engine/submit"),
+                "submit_s": ph.seconds("engine/submit"),
                 # speculative decoding
                 "spec": self.spec or "",
                 "spec_k": self.spec_k if self.spec else 0,
@@ -2758,10 +2873,13 @@ class InferenceReplica:
     """Serve deployment hosting one InferenceEngine; `__call__` returns
     a generator of token ids, which `serve.replica` automatically turns
     into a `next_chunks` stream — so `handle.stream(prompt)` yields
-    tokens as they are decoded, and concurrent requests continuously
-    batch into the shared engine's slots. A client that walks away
-    mid-stream closes the generator, which cancels the request and
-    frees its cache blocks.
+    tokens as they are decoded (a reply carries the tokens that are
+    ready, not a full batch), and concurrent requests continuously
+    batch into the shared engine's slots: each stream's reply thread is
+    a consumer of `tokens_for`, one of them at a time runs the tick and
+    the rest sleep until it ends. A client that walks away mid-stream
+    closes the generator, which cancels the request and frees its cache
+    blocks.
 
     Construction takes *config kwargs*, not arrays: params are
     initialized on the replica from `seed`, so nothing heavyweight rides

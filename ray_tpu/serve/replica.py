@@ -23,6 +23,12 @@ from ray_tpu._private.constants import (
     SERVE_STREAM_IDLE_TTL_S as _STREAM_IDLE_TTL_S,
 )
 
+# How long `_next_chunks_sync` goes on collecting once it holds a chunk.
+# Well under an engine tick and well over a list's `next()`: a generator
+# that makes a token a tick answers a token a call, a fast one still
+# fills `max_chunks` in one round trip.
+_REPLY_COLLECT_S = 0.02
+
 
 class StreamingResponse:
     """Deployment return type for streamed HTTP bodies (reference:
@@ -73,11 +79,14 @@ class Replica:
         # pin the generator + its closure for the replica's lifetime)
         self._streams: dict[int, list] = {}
         self._stream_ids = itertools.count(1)
-        # Sync handlers get a dedicated pool sized to the concurrency the
-        # deployment declared: the default asyncio executor caps at
+        # Sync handlers and `next_chunks` pulls get a dedicated pool
+        # sized to the concurrency the deployment declared (the actor
+        # admits no more calls than that at once, so no pull waits for
+        # a thread): the default asyncio executor caps at
         # ~min(32, cpus+4) threads, which would throttle sync-handler
-        # concurrency below max_concurrent_queries and can deadlock a
-        # deployment whose sync handlers call back into itself.
+        # and stream concurrency below max_concurrent_queries and can
+        # deadlock a deployment whose sync handlers call back into
+        # itself.
         from concurrent.futures import ThreadPoolExecutor
         self._executor = ThreadPoolExecutor(
             max_workers=max(1, int(
@@ -224,17 +233,20 @@ class Replica:
 
     async def next_chunks(self, stream_id: int,
                           max_chunks: int = _STREAM_BATCH):
-        """Pull the next batch of chunks from a registered stream.
-        Returns (chunks, done); the stream is dropped when done. An
-        unknown/TTL-reaped id returns (None, True) — consumers must treat
-        that as an ERROR, not a clean EOF, or a reaped stream looks like
-        a complete (truncated) response. Async wrapper: the user's
-        generator may block per chunk (inference, I/O), which must not
-        stall the replica's event loop."""
+        """Pull the next batch of chunks from a registered stream: what
+        is ready, up to `max_chunks` — the reply goes out once it holds
+        that many, or holds at least one and has been collecting for
+        `_REPLY_COLLECT_S`. Returns (chunks, done); the stream is
+        dropped when done. An unknown/TTL-reaped id returns
+        (None, True) — consumers must treat that as an ERROR, not a
+        clean EOF, or a reaped stream looks like a complete (truncated)
+        response. Async wrapper: the user's generator may block per
+        chunk (inference, I/O), which must not stall the replica's
+        event loop; it runs on the replica's own executor."""
         import asyncio
         loop = asyncio.get_running_loop()
         return await loop.run_in_executor(
-            None, self._next_chunks_sync, stream_id, max_chunks)
+            self._executor, self._next_chunks_sync, stream_id, max_chunks)
 
     def _next_chunks_sync(self, stream_id: int, max_chunks: int):
         with self._phases.phase("stream/reply") as reply:
@@ -247,9 +259,12 @@ class Replica:
             it = entry[0]
             chunks = []
             done = False
+            deadline = time.perf_counter() + _REPLY_COLLECT_S
             try:
-                for _ in range(max_chunks):
+                while len(chunks) < max_chunks:
                     chunks.append(next(it))
+                    if time.perf_counter() > deadline:
+                        break
             except StopIteration:
                 done = True
             if done:

@@ -38,6 +38,9 @@ HOT_SCOPES: dict[str, frozenset[str]] = {
     ENGINE: frozenset({
         "InferenceEngine.step",
         "InferenceEngine.tokens_for",
+        # the one wait-or-pump loop `tokens_for` and `handoff_for`
+        # block in: a host sync here holds up whoever pumps next
+        "InferenceEngine._await",
         "InferenceEngine._try_admit",
         "InferenceEngine._admit_pending",
         "InferenceEngine._batch_arrays",
@@ -165,6 +168,14 @@ LOCKS: dict[str, dict[str, LockSpec]] = {
     ENGINE: {
         "self._lock": LockSpec("engine.scheduler"),
         "self._swap_mutex": LockSpec("engine.swap", blocking_ok=True),
+        # delivery condition: rids, the inbox and what a tick hands to
+        # its streams; consumers wait under it for a tick they do not
+        # run. Held for dict/deque operations only, never device work.
+        "self._delivery": LockSpec("engine.delivery", blocking_ok=True),
+        # held by the one consumer running the next tick (`_await`);
+        # tried without blocking and never waited for, so it adds no
+        # edge: its holder goes on to take engine.scheduler in step()
+        "self._pump": LockSpec("engine.pump", blocking_ok=True),
     },
     CONTROLLER: {
         "self._lock": LockSpec("serve.controller"),
@@ -232,6 +243,11 @@ LOCKS: dict[str, dict[str, LockSpec]] = {
 # declared and observed edges are findings.
 LOCK_ORDER: frozenset[tuple[str, str]] = frozenset({
     ("engine.swap", "engine.scheduler"),
+    # a tick hands over tokens, ends and errors; never the other way
+    ("engine.scheduler", "engine.delivery"),
+    # update_params counts itself among the threads a pump lets go
+    # first (`_before_pump`) while it holds the swap mutex
+    ("engine.swap", "engine.delivery"),
     ("engine.scheduler", "telemetry.registry"),
     ("telemetry.registry", "metrics.registry"),
     ("metrics.registry", "metrics.series"),

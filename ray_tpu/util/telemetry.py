@@ -248,8 +248,11 @@ def _metric(cls, name: str, desc: str = "", boundaries=None,
 class FlightRecorder:
     """Sampled per-request lifecycle tracer for one engine.
 
-    The engine calls the `on_*` hooks from inside its scheduler (under
-    its own lock, so no recorder state races); every hook for an
+    The engine calls the `on_*` hooks from inside its scheduler, under
+    its scheduler lock, but for two that its request side calls under
+    its delivery lock: `on_submit` and `on_first_yield`. Those two meet
+    the scheduler's hooks in `_live`, `_await_yield` and the ring only,
+    one atomic dict or deque operation at a time. Every hook for an
     unsampled request is one dict miss. Spans use the `util.tracing`
     dict shape (epoch-ns timestamps, so they interleave with task events
     on the merged timeline) and land in a bounded ring on finish —
@@ -276,7 +279,8 @@ class FlightRecorder:
         # whose first token exists and has not reached its consumer;
         # bounded like the ring, oldest first out (a request that is
         # never consumed through `tokens_for` would stay).
-        self._await_yield: dict[int, tuple] = {}
+        self._await_yield: collections.OrderedDict[int, tuple] = \
+            collections.OrderedDict()
         # first token made -> first token yielded, ms (engine stats)
         self.deliver_waits: collections.deque = collections.deque(
             maxlen=512)
@@ -349,7 +353,10 @@ class FlightRecorder:
         tr["first_ns"] = _now_ns()
         tr["extra"].append(self._instant(tr, "first_token", rid))
         if len(self._await_yield) >= self.max_spans:
-            del self._await_yield[next(iter(self._await_yield))]
+            try:        # one atomic step: a consumer may pop beside it
+                self._await_yield.popitem(last=False)
+            except KeyError:
+                pass
         root = tr["root"]
         self._await_yield[rid] = (tr["first_ns"], root["trace_id"],
                                   root["span_id"])
@@ -361,10 +368,11 @@ class FlightRecorder:
 
     def on_first_yield(self, rid: int) -> None:
         """`tokens_for` is handing the request's first token to its
-        consumer (under the engine's lock, like every hook): the instant
-        past the engine's edge. The request may have finished already —
-        other streams' pumps made all its tokens — and then the instant
-        joins its spans in the ring."""
+        consumer (under the engine's delivery lock; a tick may be
+        running): the instant past the engine's edge. It goes to the
+        ring at once, ahead of the request's other spans if the request
+        is still live: the tick that finishes the request may be
+        collecting those right now."""
         made = self._await_yield.pop(rid, None)
         if made is None:
             return
@@ -374,11 +382,7 @@ class FlightRecorder:
         s = self._span("first_yield", trace_id, root_sid, now,
                        {"rid": rid})
         s["end_ns"] = now
-        tr = self._live.get(rid)
-        if tr is not None:
-            tr["extra"].append(s)
-        else:
-            self._push(s)
+        self._push(s)
 
     def on_token(self, rid: int) -> None:
         tr = self._live.get(rid)

@@ -22,7 +22,7 @@ TRAIN_SPANS = {"train/next_batch", "train/host_batch", "train/place",
                "train/dispatch", "train/metrics", "train/metrics_fetch"}
 TICK_SPANS = {"engine/tick", "engine/admit", "engine/prefill_chunk",
               "engine/decode_build", "engine/decode_dispatch",
-              "engine/token_sync", "engine/emit", "stream/lock_wait"}
+              "engine/token_sync", "engine/emit", "engine/submit"}
 
 
 def tiny_engine(**kw):
@@ -223,9 +223,10 @@ def test_trace_holds_one_tick_per_step_with_the_sync_inside(traced):
     assert sum(a[3]["admitted"] for a in ev["engine/admit"]) == 1
     assert sum(e[3]["tokens"] for e in ev["engine/emit"]) == \
         len(traced["tokens"]) - 1       # the first comes from the prefill
-    # a pump asks for the lock once per tick it drives, and more
-    assert len(ev["stream/lock_wait"]) == st["pump_lock_waits"] >= \
-        len(ev["engine/tick"])
+    # one submit; a lone consumer runs every tick itself and never
+    # sleeps through one (tests/test_engine_entry.py has the sleepers)
+    assert len(ev["engine/submit"]) == st["submits"] == 1
+    assert len(ev.get("stream/wait", ())) == st["stream_waits"] == 0
 
 
 def test_the_benchmarks_reducer_reads_the_same_trace(traced):
@@ -253,11 +254,12 @@ def test_engine_times_come_from_the_spans():
     assert st["ticks"] == ph.count("engine/tick") > 0
     assert st["tick_s"] >= st["admit_s"] + st["decode_build_s"] \
         + st["decode_dispatch_s"] + st["token_sync_s"] + st["emit_s"]
-    assert st["pump_lock_wait_s"] == ph.seconds("stream/lock_wait") > 0
+    assert st["submit_s"] == ph.seconds("engine/submit") > 0
+    assert st["stream_wait_s"] == ph.seconds("stream/wait") == 0.0
     assert st["p50_token_latency_ms"] > 0
     eng.reset_stats()
     st = eng.stats()
-    assert st["ticks"] == st["pump_lock_waits"] == 0
+    assert st["ticks"] == st["submits"] == st["stream_waits"] == 0
     assert st["decode_time_s"] == st["prefill_time_s"] == 0.0
     assert st["deliver_wait_ms_p99"] == 0.0
 
@@ -294,7 +296,7 @@ def test_first_yield_follows_first_token_and_events_carry_a_tick(
 def test_deliver_wait_is_documented_and_cancel_forgets_the_wait():
     doc = InferenceEngine.stats.__doc__
     for key in ("deliver_wait_ms_p50", "deliver_wait_ms_p99",
-                "pump_lock_wait_s", "tick_s"):
+                "stream_wait_s", "submit_s", "tick_s"):
         assert f"``{key}``" in doc
     eng = tiny_engine()
     rid = eng.submit([5, 9, 3], max_new_tokens=3)
