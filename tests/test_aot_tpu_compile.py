@@ -127,13 +127,26 @@ def test_unpaged_decode_kernel_compiles(topo):
         ((SLOTS, 1024, H, D), BF16), ((SLOTS,), I32)) == 1
 
 
-def test_flash_attention_fwd_bwd_compiles(topo):
+@pytest.mark.parametrize(
+    "shape", [(24, 1024, H, D), (8, 2048, 16, 64), (4, 2048, 16, 128),
+              (2, 100, 4, 64), (2, 20, 4, 64), (2, 127, 4, 128)],
+    ids=["bench-model", "datadecide-300m", "olmo-1b-a-chip",
+         "T100", "T20", "T127"])
+def test_flash_attention_fwd_bwd_compiles(topo, shape):
+    """The bench model's layer, and what one chip runs a layer in each
+    benchmark cell, with the plan the code picks (a head's K and V
+    resident whole, 512-wide tiles walked inside). More scoped VMEM than
+    a kernel may use is refused here and nowhere before the chip. So is
+    a short T that is no multiple of the 8 sublanes (one tile, indexed
+    statically): interpret mode on the CPU takes any index."""
     def loss(q, k, v):
         return jnp.sum(flash_attention(q, k, v, causal=True)
                        .astype(jnp.float32))
-    qkv = ((24, 1024, H, D), BF16)
-    assert compile_for(topo, jax.grad(loss, argnums=(0, 1, 2)),
-                       qkv, qkv, qkv) == 3     # forward, dQ, dK/dV
+    qkv = (shape, BF16)
+    text = compiled_text(topo, jax.grad(loss, argnums=(0, 1, 2)),
+                         qkv, qkv, qkv)
+    assert sorted(kernel_names(text)) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
 
 
 def test_fused_xent_fwd_bwd_compiles(topo):
