@@ -80,10 +80,10 @@ class GPTConfig:
     # is [B, T, loss_chunk]; smaller chunks mean less live memory and
     # more loop steps.
     loss_chunk: int = 512
-    # Attention implementation for single-token decode over the KV cache
-    # (decode_step). "auto" picks the Pallas decode kernel on TPU and the
-    # pure-JAX fallback elsewhere; both share the same math
-    # (ops/decode_attention.py).
+    # Attention implementation for paged decode and verify over the block
+    # pool (decode_step_paged, verify_step_paged). "auto" picks the
+    # Pallas kernels on TPU and the pure-JAX fallback elsewhere; both
+    # share the same math (ops/decode_attention.py).
     decode_attn_impl: str = "auto"   # auto | pallas | jax
     # Paged KV pool element type. "f32" keeps the pool in the activation
     # dtype (full precision — the bitwise-default path); "int8" stores
@@ -94,7 +94,7 @@ class GPTConfig:
     # dtype. ~3-4x KV bytes/token vs an f32 pool (2x vs bf16).
     kv_dtype: str = "f32"            # f32 | int8
     # Weight precision for the paged inference forwards (prefill/decode/
-    # verify — training and the unpaged path always run full precision).
+    # verify — training always runs full precision).
     # "int8" expects params through `quantize_params` (per-output-channel
     # scales; dequant folds into each matmul's rhs read, accumulation
     # stays f32 via preferred_element_type).
@@ -182,8 +182,9 @@ def _rms_norm(x, scale):
 # forward
 # ---------------------------------------------------------------------------
 
-def _attention(q, k, v, cfg: GPTConfig, mesh: Mesh | None):
-    impl = cfg.attn_impl
+def _attention(q, k, v, impl: str, mesh: Mesh | None):
+    """Causal self-attention on [B, T, H, Dh] by `impl`, a config's
+    `attn_impl`."""
     if impl == "auto":
         if mesh is not None and mesh.shape.get("seq", 1) > 1:
             impl = "ring"
@@ -220,67 +221,95 @@ def _attention(q, k, v, cfg: GPTConfig, mesh: Mesh | None):
     return checkpoint_name(out, "attn_out")
 
 
-def _block(x, lp, cfg: GPTConfig, mesh: Mesh | None, with_kv: bool = False):
-    """One transformer block. x: [B, T, D] activations in cfg.dtype;
-    lp: this layer's param slice (f32, cast here). With ``with_kv`` the
-    block also returns this layer's (k, v) [B, T, H, Dh] — exactly what a
-    KV cache stores — so prefill reuses the training forward verbatim."""
-    adt = cfg.activation_dtype()
-    pet = (jnp.float32 if cfg.matmul_out == "float32" else adt)
-    b, t, d = x.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
+def _w(lp, name, adt):
+    """Resolve one per-layer matmul weight: dequantize (f32 scale per
+    output channel, then cast to the activation dtype) when the layer
+    dict carries a ``"<name>_scale"`` sibling, plain cast otherwise —
+    a static dict-key check, so f32 configs trace byte-identical code."""
+    w = lp[name]
+    s = lp.get(name + "_scale")
+    if s is None:
+        return w.astype(adt)
+    return (w.astype(jnp.float32) * s[..., None, :]).astype(adt)
 
-    h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-    q = jnp.einsum("btd,dh->bth", h, lp["wq"].astype(adt),
-                   preferred_element_type=pet).astype(adt)
-    k = jnp.einsum("btd,dh->bth", h, lp["wk"].astype(adt),
-                   preferred_element_type=pet).astype(adt)
-    v = jnp.einsum("btd,dh->bth", h, lp["wv"].astype(adt),
-                   preferred_element_type=pet).astype(adt)
-    q = q.reshape(b, t, nh, hd)
-    k = k.reshape(b, t, nh, hd)
-    v = v.reshape(b, t, nh, hd)
-    att = _attention(q, k, v, cfg, mesh).reshape(b, t, nh * hd)
-    att = jnp.einsum("bth,hd->btd", att, lp["wo"].astype(adt),
-                     preferred_element_type=pet).astype(adt)
-    x = x + att
 
-    h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-    up = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(adt),
+def _matmul_out(cfg: GPTConfig):
+    """Element type the layer's einsums emit (`cfg.matmul_out`)."""
+    return (jnp.float32 if cfg.matmul_out == "float32"
+            else cfg.activation_dtype())
+
+
+def _gated_mlp(h, lp, adt, pet):
+    """SwiGLU feed-forward on normed activations h [..., D], the layer's
+    default: -> (out [..., D], None)."""
+    up = jnp.einsum("...d,df->...f", h, _w(lp, "w_up", adt),
                     preferred_element_type=pet).astype(adt)
-    gate = jnp.einsum("btd,df->btf", h, lp["w_gate"].astype(adt),
+    gate = jnp.einsum("...d,df->...f", h, _w(lp, "w_gate", adt),
                       preferred_element_type=pet).astype(adt)
     ff = jax.nn.silu(gate) * up
-    down = jnp.einsum("btf,fd->btd", ff, lp["w_down"].astype(adt),
-                      preferred_element_type=pet).astype(adt)
-    if with_kv:
-        return x + down, (k, v)
-    return x + down
+    return jnp.einsum("...f,fd->...d", ff, _w(lp, "w_down", adt),
+                      preferred_element_type=pet).astype(adt), None
+
+
+def _layer(x, lp, cfg, pet, attend, ffn=None):
+    """One transformer layer, the only spelling of it: norm, q/k/v,
+    attention, output projection, residual, norm, feed-forward, residual.
+
+    x: activations [..., D] in cfg.dtype, any leading dims ([B, T, D]
+    training, [C, D] chunked prefill, [B, D] decode, [B, W, D] verify).
+    lp: this layer's param slice (f32 masters cast here; int8 leaves of
+    `quantize_params` dequantized by `_w`). cfg: any config with
+    `n_heads`, `head_dim` and `activation_dtype()`. pet: the einsums'
+    output element type, the caller's choice (`_matmul_out`).
+
+    The two parts that vary come in as arguments and return
+    ``(output, kept)``, where `kept` is whatever the part makes besides
+    its output (the layer's updated pool slice, the experts' aux loss,
+    None) and is handed back as it came:
+    ``attend(q, k, v)`` on [..., H, Dh] -> ([..., H, Dh], kept);
+    ``ffn(h, lp)`` on the normed [..., D] -> ([..., D], kept), the gated
+    MLP unless given. Returns ``(x, attend's kept, ffn's kept)``."""
+    adt = cfg.activation_dtype()
+    lead = x.shape[:-1]
+    h = _rms_norm(x, lp["ln1_scale"].astype(adt))
+    q, k, v = (jnp.einsum("...d,dh->...h", h, _w(lp, name, adt),
+                          preferred_element_type=pet).astype(adt)
+               for name in ("wq", "wk", "wv"))
+    q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
+               for a in (q, k, v))
+    att, attend_kept = attend(q, k, v)
+    att = jnp.einsum("...h,hd->...d",
+                     att.reshape(*lead, cfg.n_heads * cfg.head_dim),
+                     _w(lp, "wo", adt),
+                     preferred_element_type=pet).astype(adt)
+    x = x + att
+    h = _rms_norm(x, lp["ln2_scale"].astype(adt))
+    if ffn is None:
+        ffn = partial(_gated_mlp, adt=adt, pet=pet)
+    ff, ffn_kept = ffn(h, lp)
+    return x + ff, attend_kept, ffn_kept
+
+
+def _block(x, lp, cfg: GPTConfig, mesh: Mesh | None):
+    """One training block. x: [B, T, D] activations in cfg.dtype; lp:
+    this layer's param slice."""
+    x, _, _ = _layer(
+        x, lp, cfg, _matmul_out(cfg),
+        lambda q, k, v: (_attention(q, k, v, cfg.attn_impl, mesh), None))
+    return x
 
 
 def forward_features(params, tokens, cfg: GPTConfig,
-                     mesh: Mesh | None = None, *, with_kv: bool = False):
+                     mesh: Mesh | None = None):
     """tokens [B, T] int32 -> final-norm activations [B, T, d_model] in
     cfg.dtype — everything except the unembed matmul. The fused loss
-    consumes these directly so [B, T, vocab] logits never exist.
-
-    With ``with_kv`` (the prefill path) additionally returns the
-    per-layer attention keys/values stacked over layers:
-    ``(x, (k [L, B, T, H, Dh], v [L, B, T, H, Dh]))`` — the scan's ys
-    stacking produces the KV-cache layout directly. No remat is applied
-    in this mode (prefill has no backward pass to save memory for)."""
+    consumes these directly so [B, T, vocab] logits never exist."""
     adt = cfg.activation_dtype()
     t = tokens.shape[1]
     x = params["embed"].astype(adt)[tokens]
     x = x + params["pos_embed"].astype(adt)[:t][None]
 
     block = partial(_block, cfg=cfg, mesh=mesh)
-    if with_kv:
-        def scan_body_kv(x, lp):
-            return block(x, lp, with_kv=True)
-
-        x, kv = jax.lax.scan(scan_body_kv, x, params["layers"])
-        return _rms_norm(x, params["final_ln_scale"].astype(adt)), kv
     if cfg.remat:
         # Measured on the v5e (PERF.md, PR 25): under "dots", saving the
         # flash forward's output and lse halves `flash_fwd`'s time a step
@@ -380,164 +409,18 @@ def completion_logprobs(params, tokens, start, width, cfg: GPTConfig,
 
 
 # ---------------------------------------------------------------------------
-# autoregressive inference: KV cache, prefill, single-token decode
-# ---------------------------------------------------------------------------
-# The Podracer recipe (Hessel et al., 2104.06272) applied to serving: device
-# shapes are static and resident. The cache is allocated ONCE at
-# [L, slots, max_len, H, Dh]; sequences stream through fixed slots
-# (serve/engine.py), so prefill compiles once per length bucket and
-# decode_step compiles exactly once for the engine's lifetime.
-
-def kv_cache_logical_axes():
-    """Logical-axis tuples for the KV cache pytree (layer stack and cache
-    length replicated; batch over the data axes, heads tensor-parallel —
-    matching the wq/wk/wv column split, so each tensor shard owns its own
-    heads' cache rows)."""
-    axes = (None, "batch", None, "heads", None)
-    return {"k": axes, "v": axes}
-
-
-def init_kv_cache(cfg: GPTConfig, batch: int, max_len: int,
-                  mesh: Mesh | None = None):
-    """Preallocated ring cache {"k", "v"} of [L, batch, max_len, H, Dh]
-    in cfg.dtype, zero-filled, placed with its sharding annotation when a
-    mesh is given. `batch` is the number of resident decode slots, NOT a
-    per-request batch — the engine multiplexes requests into it."""
-    if max_len > cfg.max_seq_len:
-        raise ValueError(
-            f"max_len {max_len} exceeds cfg.max_seq_len "
-            f"{cfg.max_seq_len} (pos_embed table size)")
-    shape = (cfg.n_layers, batch, max_len, cfg.n_heads, cfg.head_dim)
-    cache = {"k": jnp.zeros(shape, cfg.activation_dtype()),
-             "v": jnp.zeros(shape, cfg.activation_dtype())}
-    if mesh is not None:
-        from ray_tpu.parallel.sharding import kv_cache_shardings
-        sh = kv_cache_shardings(mesh)
-        cache = {name: jax.device_put(arr, sh[name])
-                 for name, arr in cache.items()}
-    return cache
-
-
-def prefill(params, tokens, cache, cfg: GPTConfig,
-            mesh: Mesh | None = None, *, lengths=None, slot=None):
-    """Process prompt tokens in one full-sequence forward, write their
-    K/V into the cache, and return ``(last_logits [B, vocab] f32,
-    cache)`` — the [B, T, vocab] logits tensor is never materialized
-    (only the last/`lengths-1` position is unembedded).
-
-    tokens: [B, T] int32, right-padded to the bucket length. `lengths`
-    [B] gives each row's true prompt length (defaults to T); under causal
-    attention right-padding cannot influence positions < length, and the
-    pad garbage written to the cache tail is masked away by decode's
-    position mask.
-
-    `slot` (traced scalar ok): tokens must then be [1, T] and the
-    sequence lands in cache row `slot` — the continuous-batching
-    admission path, which therefore never retraces per slot. Without
-    `slot`, tokens rows map 1:1 onto cache rows."""
-    b, t = tokens.shape
-    cache_b = cache["k"].shape[1]
-    if slot is None and b != cache_b:
-        raise ValueError(
-            f"prefill batch {b} != cache slots {cache_b}; pass slot= to "
-            "target one slot")
-    if slot is not None and b != 1:
-        raise ValueError(f"slot-targeted prefill wants tokens [1, T], "
-                         f"got batch {b}")
-    if t > cache["k"].shape[2]:
-        raise ValueError(
-            f"prompt length {t} exceeds cache max_len "
-            f"{cache['k'].shape[2]}")
-    x, (ks, vs) = forward_features(params, tokens, cfg, mesh,
-                                   with_kv=True)
-    if lengths is None:
-        last = x[:, -1]
-    else:
-        last = jnp.take_along_axis(
-            x, (lengths.astype(jnp.int32) - 1)[:, None, None], axis=1
-        )[:, 0]
-    logits = jnp.einsum(
-        "bd,vd->bv", last, params["embed"].astype(cfg.activation_dtype()),
-        preferred_element_type=jnp.float32)
-    start = (0, 0 if slot is None else slot, 0, 0, 0)
-    dt = cache["k"].dtype
-    cache = {
-        "k": jax.lax.dynamic_update_slice(cache["k"], ks.astype(dt),
-                                          start),
-        "v": jax.lax.dynamic_update_slice(cache["v"], vs.astype(dt),
-                                          start),
-    }
-    return logits, cache
-
-
-def decode_step(params, tokens, cache, pos, cfg: GPTConfig,
-                mesh: Mesh | None = None):
-    """One autoregressive step for every cache slot: ``tokens [B]`` int32
-    (each slot's current token) at positions ``pos [B]`` int32. Writes
-    each token's K/V at ``pos`` and attends over cache positions
-    ``<= pos``, so no prefix is ever re-run. Returns
-    ``(logits [B, vocab] f32, cache)``.
-
-    All shapes are static — B is the slot count, the cache length is the
-    preallocated max — so the engine's jitted wrapper compiles exactly
-    once. Donate the cache argument at the jit boundary: XLA then aliases
-    the cache in/out and the update is in-place in HBM."""
-    from ray_tpu.ops.decode_attention import decode_attention
-    adt = cfg.activation_dtype()
-    pet = (jnp.float32 if cfg.matmul_out == "float32" else adt)
-    b = tokens.shape[0]
-    nh, hd = cfg.n_heads, cfg.head_dim
-    rows = jnp.arange(b)
-    pos = pos.astype(jnp.int32)
-    x = params["embed"].astype(adt)[tokens]
-    x = x + params["pos_embed"].astype(adt)[pos]
-
-    def body(x, layer):
-        lp, kc, vc = layer                      # kc/vc [B, S, H, Dh]
-        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-        q = jnp.einsum("bd,dh->bh", h, lp["wq"].astype(adt),
-                       preferred_element_type=pet).astype(adt)
-        k = jnp.einsum("bd,dh->bh", h, lp["wk"].astype(adt),
-                       preferred_element_type=pet).astype(adt)
-        v = jnp.einsum("bd,dh->bh", h, lp["wv"].astype(adt),
-                       preferred_element_type=pet).astype(adt)
-        q = q.reshape(b, nh, hd)
-        kc = kc.at[rows, pos].set(k.reshape(b, nh, hd).astype(kc.dtype))
-        vc = vc.at[rows, pos].set(v.reshape(b, nh, hd).astype(vc.dtype))
-        att = decode_attention(q, kc, vc, pos,
-                               impl=cfg.decode_attn_impl)
-        att = jnp.einsum("bh,hd->bd", att.reshape(b, nh * hd),
-                         lp["wo"].astype(adt),
-                         preferred_element_type=pet).astype(adt)
-        x = x + att
-        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-        up = jnp.einsum("bd,df->bf", h, lp["w_up"].astype(adt),
-                        preferred_element_type=pet).astype(adt)
-        gate = jnp.einsum("bd,df->bf", h, lp["w_gate"].astype(adt),
-                          preferred_element_type=pet).astype(adt)
-        ff = jax.nn.silu(gate) * up
-        down = jnp.einsum("bf,fd->bd", ff, lp["w_down"].astype(adt),
-                          preferred_element_type=pet).astype(adt)
-        return x + down, (kc, vc)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x, (params["layers"], cache["k"], cache["v"]))
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
-    logits = jnp.einsum("bd,vd->bv", x, params["embed"].astype(adt),
-                        preferred_element_type=jnp.float32)
-    return logits, {"k": k_new, "v": v_new}
-
-
-# ---------------------------------------------------------------------------
 # paged KV cache: block pool + block tables
 # ---------------------------------------------------------------------------
-# Paging is the Podracer philosophy scaled to ragged traffic: the device
-# allocation is still ONE static pool, but its unit is a block of
-# `block_size` positions instead of a full max_len row. Sequences name
-# their blocks through an int32 block table [B, max_blocks] that rides
-# into the jits as data — shapes never change, so decode still compiles
-# exactly once, while the host (serve/engine.py) is free to share,
-# copy-on-write, and recycle blocks between requests.
+# The Podracer recipe (Hessel et al., 2104.06272) applied to ragged
+# serving traffic: device shapes are static and resident. The allocation
+# is ONE static pool whose unit is a block of `block_size` positions.
+# Sequences name their blocks through an int32 block table
+# [B, max_blocks] that rides into the jits as data — shapes never change,
+# so prefill compiles once per chunk bucket and decode exactly once,
+# while the host (serve/engine.py) is free to share, copy-on-write, and
+# recycle blocks between requests. The three forwards below take `mesh`
+# for their callers' signature and do not read it: params and pool
+# arrive placed.
 
 def check_quant_cfg(cfg: GPTConfig) -> bool:
     """Trace-time validation of the quantization knobs (the
@@ -582,27 +465,15 @@ def quantize_params(params):
     return {**params, "layers": layers}
 
 
-def _w(lp, name, adt):
-    """Resolve one per-layer matmul weight: dequantize (f32 scale per
-    output channel, then cast to the activation dtype) when the layer
-    dict carries a ``"<name>_scale"`` sibling, plain cast otherwise —
-    a static dict-key check, so f32 configs trace byte-identical code."""
-    w = lp[name]
-    s = lp.get(name + "_scale")
-    if s is None:
-        return w.astype(adt)
-    return (w.astype(jnp.float32) * s[..., None, :]).astype(adt)
-
-
 def kv_pool_logical_axes(quantized: bool = False):
     """Logical-axis tuples for the paged block pool {"k", "v"} of
     [L, n_blocks, block_size, H, Dh]. Heads stay tensor-parallel
-    (matching the wq/wk/wv column split, exactly like the unpaged
-    cache); the block axis is replicated — any block must be assignable
-    to any sequence, so it cannot ride the data axes the way dedicated
-    slot rows could. With ``quantized`` the dict grows
-    {"k_scale", "v_scale"} of [L, n_blocks, block_size, H] — heads
-    sharded with their payload rows, blocks replicated the same way."""
+    (matching the wq/wk/wv column split, so each tensor shard owns its
+    own heads' rows); the block axis is replicated — any block must be
+    assignable to any sequence, so it cannot ride the data axes. With
+    ``quantized`` the dict grows {"k_scale", "v_scale"} of
+    [L, n_blocks, block_size, H] — heads sharded with their payload
+    rows, blocks replicated the same way."""
     axes = (None, None, None, "heads", None)
     pool = {"k": axes, "v": axes}
     if quantized:
@@ -685,8 +556,9 @@ def scatter_block(cache, block, idx):
 
 
 def _scatter_kv(lc, k, v, widx):
-    """Write `k`/`v` [N, H, Dh] (activation dtype) into one layer's pool
-    slice `lc` at flat indices ``widx [N]`` (out-of-bounds rows drop —
+    """Write `k`/`v` [..., H, Dh] (activation dtype; N rows over the
+    leading dims) into one layer's pool slice `lc` at flat indices
+    ``widx [N]`` (out-of-bounds rows drop —
     the padded-tail / past-table convention every paged writer shares).
     An int8 pool (``"k_scale" in lc`` — a static check) quantizes at the
     write: payload rows and their (position, head) scale cells scatter
@@ -695,6 +567,7 @@ def _scatter_kv(lc, k, v, widx):
     inputs (`ops.quant`'s determinism contract). Returns the layer's new
     cache dict."""
     nb, bs, nh, hd = lc["k"].shape
+    k, v = k.reshape(-1, nh, hd), v.reshape(-1, nh, hd)
     kf = lc["k"].reshape(nb * bs, nh, hd)
     vf = lc["v"].reshape(nb * bs, nh, hd)
     if "k_scale" in lc:
@@ -717,6 +590,29 @@ def _scatter_kv(lc, k, v, widx):
         "v": vf.at[widx].set(v.astype(vf.dtype), mode="drop").reshape(
             nb, bs, nh, hd),
     }
+
+
+def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
+    """Scan activations x [..., D] through the layer stack, each layer
+    with its slice `lc` of the pool: the layer's K/V rows are written at
+    flat indices `widx` first (`_scatter_kv`), then ``attend(q, lc)``
+    attends over the written slice. Returns (final-norm activations,
+    the updated pool)."""
+    adt = cfg.activation_dtype()
+    pet = _matmul_out(cfg)
+
+    def body(x, layer):
+        lp, lc = layer                  # lc["k"/"v"]: [nb, bs, H, Dh]
+
+        def write_then_attend(q, k, v):
+            written = _scatter_kv(lc, k, v, widx)
+            return attend(q, written), written
+
+        x, lc, _ = _layer(x, lp, cfg, pet, write_then_attend)
+        return x, lc
+
+    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
+    return _rms_norm(x, params["final_ln_scale"].astype(adt)), cache
 
 
 def prefill_paged(params, tokens, cache, cfg: GPTConfig,
@@ -755,8 +651,6 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
     if start is None:
         raise ValueError("prefill_paged needs start=")
     adt = cfg.activation_dtype()
-    pet = (jnp.float32 if cfg.matmul_out == "float32" else adt)
-    nh, hd = cfg.n_heads, cfg.head_dim
     start = jnp.asarray(start, jnp.int32)
     length = jnp.asarray(c if length is None else length, jnp.int32)
     table = jnp.asarray(block_table, jnp.int32)
@@ -772,37 +666,13 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
     x = params["embed"].astype(adt)[tokens[0]]
     x = x + params["pos_embed"].astype(adt)[positions]      # [C, D]
 
-    def body(x, layer):
-        lp, lc = layer                  # lc["k"/"v"]: [nb, bs, H, Dh]
-        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-        q = jnp.einsum("td,dh->th", h, _w(lp, "wq", adt),
-                       preferred_element_type=pet).astype(adt)
-        k = jnp.einsum("td,dh->th", h, _w(lp, "wk", adt),
-                       preferred_element_type=pet).astype(adt)
-        v = jnp.einsum("td,dh->th", h, _w(lp, "wv", adt),
-                       preferred_element_type=pet).astype(adt)
-        q = q.reshape(c, nh, hd)
-        lc = _scatter_kv(lc, k.reshape(c, nh, hd),
-                         v.reshape(c, nh, hd), widx)
-        att = paged_prefill_attention(
+    def attend(q, lc):                  # q: [C, H, Dh]
+        return paged_prefill_attention(
             q, lc["k"], lc["v"], table, start,
             k_scale=lc.get("k_scale"), v_scale=lc.get("v_scale"),
-            impl=cfg.prefill_attn_impl).reshape(c, nh * hd)
-        att = jnp.einsum("th,hd->td", att, _w(lp, "wo", adt),
-                         preferred_element_type=pet).astype(adt)
-        x = x + att
-        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-        up = jnp.einsum("td,df->tf", h, _w(lp, "w_up", adt),
-                        preferred_element_type=pet).astype(adt)
-        gate = jnp.einsum("td,df->tf", h, _w(lp, "w_gate", adt),
-                          preferred_element_type=pet).astype(adt)
-        ff = jax.nn.silu(gate) * up
-        down = jnp.einsum("tf,fd->td", ff, _w(lp, "w_down", adt),
-                          preferred_element_type=pet).astype(adt)
-        return x + down, lc
+            impl=cfg.prefill_attn_impl)
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
+    x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
     last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
     logits = jnp.einsum("td,vd->tv", last, params["embed"].astype(adt),
                         preferred_element_type=jnp.float32)
@@ -829,11 +699,8 @@ def decode_step_paged(params, tokens, cache, pos, tables,
     check_quant_cfg(cfg)
     from ray_tpu.ops.decode_attention import paged_decode_attention
     adt = cfg.activation_dtype()
-    pet = (jnp.float32 if cfg.matmul_out == "float32" else adt)
-    b = tokens.shape[0]
     nb, bs = cache["k"].shape[1], cache["k"].shape[2]
     mb = tables.shape[1]
-    nh, hd = cfg.n_heads, cfg.head_dim
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
     # Positions past the table's reach (speculative draft steps can run a
@@ -847,38 +714,13 @@ def decode_step_paged(params, tokens, cache, pos, tables,
     x = x + params["pos_embed"].astype(adt)[
         jnp.minimum(pos, cfg.max_seq_len - 1)]
 
-    def body(x, layer):
-        lp, lc = layer                  # lc["k"/"v"]: [nb, bs, H, Dh]
-        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-        q = jnp.einsum("bd,dh->bh", h, _w(lp, "wq", adt),
-                       preferred_element_type=pet).astype(adt)
-        k = jnp.einsum("bd,dh->bh", h, _w(lp, "wk", adt),
-                       preferred_element_type=pet).astype(adt)
-        v = jnp.einsum("bd,dh->bh", h, _w(lp, "wv", adt),
-                       preferred_element_type=pet).astype(adt)
-        q = q.reshape(b, nh, hd)
-        lc = _scatter_kv(lc, k.reshape(b, nh, hd),
-                         v.reshape(b, nh, hd), widx)
-        att = paged_decode_attention(q, lc["k"], lc["v"], tables, pos,
-                                     k_scale=lc.get("k_scale"),
-                                     v_scale=lc.get("v_scale"),
-                                     impl=cfg.decode_attn_impl)
-        att = jnp.einsum("bh,hd->bd", att.reshape(b, nh * hd),
-                         _w(lp, "wo", adt),
-                         preferred_element_type=pet).astype(adt)
-        x = x + att
-        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-        up = jnp.einsum("bd,df->bf", h, _w(lp, "w_up", adt),
-                        preferred_element_type=pet).astype(adt)
-        gate = jnp.einsum("bd,df->bf", h, _w(lp, "w_gate", adt),
-                          preferred_element_type=pet).astype(adt)
-        ff = jax.nn.silu(gate) * up
-        down = jnp.einsum("bf,fd->bd", ff, _w(lp, "w_down", adt),
-                          preferred_element_type=pet).astype(adt)
-        return x + down, lc
+    def attend(q, lc):                  # q: [B, H, Dh]
+        return paged_decode_attention(q, lc["k"], lc["v"], tables, pos,
+                                      k_scale=lc.get("k_scale"),
+                                      v_scale=lc.get("v_scale"),
+                                      impl=cfg.decode_attn_impl)
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
+    x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
     logits = jnp.einsum("bd,vd->bv", x, params["embed"].astype(adt),
                         preferred_element_type=jnp.float32)
     return logits, cache
@@ -915,11 +757,9 @@ def verify_step_paged(params, tokens, cache, pos, tables,
     check_quant_cfg(cfg)
     from ray_tpu.ops.decode_attention import paged_verify_attention
     adt = cfg.activation_dtype()
-    pet = (jnp.float32 if cfg.matmul_out == "float32" else adt)
-    b, w = tokens.shape
+    w = tokens.shape[1]
     nb, bs = cache["k"].shape[1], cache["k"].shape[2]
     mb = tables.shape[1]
-    nh, hd = cfg.n_heads, cfg.head_dim
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
     positions = pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
@@ -932,38 +772,13 @@ def verify_step_paged(params, tokens, cache, pos, tables,
     x = x + params["pos_embed"].astype(adt)[
         jnp.minimum(positions, cfg.max_seq_len - 1)]
 
-    def body(x, layer):
-        lp, lc = layer                  # lc["k"/"v"]: [nb, bs, H, Dh]
-        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-        q = jnp.einsum("bwd,dh->bwh", h, _w(lp, "wq", adt),
-                       preferred_element_type=pet).astype(adt)
-        k = jnp.einsum("bwd,dh->bwh", h, _w(lp, "wk", adt),
-                       preferred_element_type=pet).astype(adt)
-        v = jnp.einsum("bwd,dh->bwh", h, _w(lp, "wv", adt),
-                       preferred_element_type=pet).astype(adt)
-        q = q.reshape(b, w, nh, hd)
-        lc = _scatter_kv(lc, k.reshape(b * w, nh, hd),
-                         v.reshape(b * w, nh, hd), widx)
-        att = paged_verify_attention(q, lc["k"], lc["v"], tables, pos,
-                                     k_scale=lc.get("k_scale"),
-                                     v_scale=lc.get("v_scale"),
-                                     impl=cfg.decode_attn_impl)
-        att = jnp.einsum("bwh,hd->bwd", att.reshape(b, w, nh * hd),
-                         _w(lp, "wo", adt),
-                         preferred_element_type=pet).astype(adt)
-        x = x + att
-        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-        up = jnp.einsum("bwd,df->bwf", h, _w(lp, "w_up", adt),
-                        preferred_element_type=pet).astype(adt)
-        gate = jnp.einsum("bwd,df->bwf", h, _w(lp, "w_gate", adt),
-                          preferred_element_type=pet).astype(adt)
-        ff = jax.nn.silu(gate) * up
-        down = jnp.einsum("bwf,fd->bwd", ff, _w(lp, "w_down", adt),
-                          preferred_element_type=pet).astype(adt)
-        return x + down, lc
+    def attend(q, lc):                  # q: [B, W, H, Dh]
+        return paged_verify_attention(q, lc["k"], lc["v"], tables, pos,
+                                      k_scale=lc.get("k_scale"),
+                                      v_scale=lc.get("v_scale"),
+                                      impl=cfg.decode_attn_impl)
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
+    x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
     logits = jnp.einsum("bwd,vd->bwv", x, params["embed"].astype(adt),
                         preferred_element_type=jnp.float32)
     return logits, cache

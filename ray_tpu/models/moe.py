@@ -30,8 +30,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.models import gpt as gpt_mod
-from ray_tpu.models.gpt import _attention, _rms_norm
+from ray_tpu.models.gpt import _attention, _layer, _rms_norm
 
 
 @dataclass(frozen=True)
@@ -189,30 +188,14 @@ def _moe_ffn(x, lp, cfg: MoEConfig):
 
 
 def _block(x, lp, cfg: MoEConfig, mesh: Mesh | None):
-    adt = cfg.activation_dtype()
-    b, t, d = x.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
-
-    h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-    q = jnp.einsum("btd,dh->bth", h, lp["wq"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    k = jnp.einsum("btd,dh->bth", h, lp["wk"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    v = jnp.einsum("btd,dh->bth", h, lp["wv"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    gpt_cfg = gpt_mod.GPTConfig(
-        d_model=cfg.d_model, n_heads=cfg.n_heads, dtype=cfg.dtype,
-        attn_impl=cfg.attn_impl)
-    att = _attention(q.reshape(b, t, nh, hd), k.reshape(b, t, nh, hd),
-                     v.reshape(b, t, nh, hd), gpt_cfg,
-                     mesh).reshape(b, t, nh * hd)
-    att = jnp.einsum("bth,hd->btd", att, lp["wo"].astype(adt),
-                     preferred_element_type=jnp.float32).astype(adt)
-    x = x + att
-
-    h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-    ff, aux = _moe_ffn(h, lp, cfg)
-    return x + ff, aux
+    """`gpt._layer` with the experts as its feed-forward; every einsum
+    emits float32 (this model never got `matmul_out`: ROADMAP D6).
+    -> (x, this layer's aux loss)."""
+    x, _, aux = _layer(
+        x, lp, cfg, jnp.float32,
+        lambda q, k, v: (_attention(q, k, v, cfg.attn_impl, mesh), None),
+        lambda h, lp: _moe_ffn(h, lp, cfg))
+    return x, aux
 
 
 def forward(params, tokens, cfg: MoEConfig, mesh: Mesh | None = None):

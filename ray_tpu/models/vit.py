@@ -19,7 +19,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.models.gpt import _rms_norm
+from ray_tpu.models.gpt import _layer, _rms_norm
 
 
 @dataclass(frozen=True)
@@ -118,40 +118,34 @@ def _patchify(images, cfg: ViTConfig):
     return x.reshape(b, (hgt // p) * (wid // p), p * p * c)
 
 
-def _block(x, lp, cfg: ViTConfig):
-    adt = cfg.activation_dtype()
-    b, t, d = x.shape
-    nh, hd = cfg.n_heads, cfg.head_dim
-
-    h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-    q = jnp.einsum("btd,dh->bth", h, lp["wq"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    k = jnp.einsum("btd,dh->bth", h, lp["wk"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    v = jnp.einsum("btd,dh->bth", h, lp["wv"].astype(adt),
-                   preferred_element_type=jnp.float32).astype(adt)
-    q = q.reshape(b, t, nh, hd)
-    k = k.reshape(b, t, nh, hd)
-    v = v.reshape(b, t, nh, hd)
-    # bidirectional attention — XLA fuses this softmax chain well at ViT
-    # sequence lengths (<= ~1k patches), no flash kernel needed
+def _attend(q, k, v):
+    """Bidirectional attention on [B, T, H, Dh] — XLA fuses this softmax
+    chain well at ViT sequence lengths (<= ~1k patches), no flash kernel
+    needed."""
+    adt = q.dtype
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k,
                         preferred_element_type=jnp.float32)
-    probs = jax.nn.softmax(scores / np.sqrt(hd), axis=-1).astype(adt)
-    att = jnp.einsum("bhqk,bkhd->bqhd", probs, v,
-                     preferred_element_type=jnp.float32).astype(adt)
-    att = att.reshape(b, t, nh * hd)
-    att = jnp.einsum("bth,hd->btd", att, lp["wo"].astype(adt),
-                     preferred_element_type=jnp.float32).astype(adt)
-    x = x + att
+    probs = jax.nn.softmax(scores / np.sqrt(q.shape[-1]),
+                           axis=-1).astype(adt)
+    return jnp.einsum("bhqk,bkhd->bqhd", probs, v,
+                      preferred_element_type=jnp.float32).astype(adt), None
 
-    h = _rms_norm(x, lp["ln2_scale"].astype(adt))
+
+def _gelu_mlp(h, lp):
+    """Ungated GELU feed-forward on normed [B, T, D]."""
+    adt = h.dtype
     up = jnp.einsum("btd,df->btf", h, lp["w_up"].astype(adt),
                     preferred_element_type=jnp.float32).astype(adt)
-    ff = jax.nn.gelu(up)
-    down = jnp.einsum("btf,fd->btd", ff, lp["w_down"].astype(adt),
-                      preferred_element_type=jnp.float32).astype(adt)
-    return x + down
+    return jnp.einsum("btf,fd->btd", jax.nn.gelu(up),
+                      lp["w_down"].astype(adt),
+                      preferred_element_type=jnp.float32).astype(adt), None
+
+
+def _block(x, lp, cfg: ViTConfig):
+    """`gpt._layer` with bidirectional attention and an ungated GELU
+    feed-forward; every einsum emits float32."""
+    x, _, _ = _layer(x, lp, cfg, jnp.float32, _attend, _gelu_mlp)
+    return x
 
 
 def forward(params, images, cfg: ViTConfig, mesh: Mesh | None = None):

@@ -1,49 +1,40 @@
-"""Single-token decode attention over a resident KV cache — Pallas TPU
-kernel plus a pure-JAX fallback with identical math.
+"""Decode attention over a paged KV pool — Pallas TPU kernels plus
+pure-JAX fallbacks with identical math.
 
 The autoregressive hot path: one new query per sequence attends over that
 sequence's cached keys/values. There is no O(T^2) score matrix here — per
 (batch, head) the work is a [1, D] x [D, S] matvec — so the op is purely
-HBM-bandwidth-bound (arithmetic intensity ~1 flop/byte). What the kernel
-buys over the XLA fallback is the same thing flash_attention buys the
+HBM-bandwidth-bound (arithmetic intensity ~1 flop/byte). What the kernels
+buy over the XLA fallback is the same thing flash_attention buys the
 training path: the masked scores, softmax statistics and weighted sum all
 live in VMEM while K/V blocks stream through, so the [B, H, S] score
 tensor is never written to HBM and the per-position mask costs no extra
 pass.
 
-Structure mirrors `ops/flash_attention.py`: grid (B*H, S/block_kv) with
-the kv dimension innermost/sequential, per-row running (m, l, acc)
-softmax statistics in VMEM scratch, finalize on the last kv block. Two
-decode-specific twists:
-
-- **position masking**: each sequence attends to cache positions
-  ``<= pos[b]`` (its current token's position — the caller writes the new
-  K/V at ``pos`` *before* attending). ``pos [B]`` rides in as scalar
-  prefetch, like the paged kernels' tables.
-- **data-dependent block skip**: kv blocks strictly past ``pos`` are
-  predicated away with ``pl.when(k_start <= pos)`` — a *runtime* branch,
-  unlike flash's static causal predicate — so short sequences in a long
-  preallocated cache don't pay for the empty tail.
-
-Layout: the public cache layout is ``[B, S, H, D]`` (matching
-`models.gpt.init_kv_cache`'s ``[L, B, S, H, D]``); the kernel wants
-(S, D) as the trailing tile per (b, h), so the wrapper transposes K/V to
-``[B*H, S, D]`` on entry. The fallback consumes ``[B, S, H, D]``
-directly.
-
-**Paged variant** (`paged_decode_attention`): K/V live in a shared block
+**Paged decode** (`paged_decode_attention`): K/V live in a shared block
 pool ``[n_blocks, block_size, H, D]`` and each sequence names its blocks
 through an int32 block table ``[B, max_blocks]`` (logical block j of
-sequence b is physical block ``tables[b, j]``). The Pallas kernel rides
-the same online-softmax structure with the kv grid dimension walking
-*logical* blocks; the block table and positions arrive as scalar
-prefetch (`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index
-maps dereference the table and the DMA engine fetches exactly the
-blocks the sequence owns — the pool is never materialized per sequence.
-The JAX fallback gathers ``pool[tables]`` and reuses
-`reference_decode_attention`; both paths mask logical positions
-``> pos[b]``, so stale data in partially-filled tail blocks never
-contributes.
+sequence b is physical block ``tables[b, j]``). The structure mirrors
+`ops/flash_attention.py`: grid (B*H, max_blocks) with the kv dimension
+innermost/sequential walking *logical* blocks, per-row running
+(m, l, acc) softmax statistics in VMEM scratch, finalize on the last kv
+block. The block table and positions arrive as scalar prefetch
+(`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps
+dereference the table and the DMA engine fetches exactly the blocks the
+sequence owns — the pool is never materialized per sequence. Two
+decode-specific twists:
+
+- **position masking**: each sequence attends to logical positions
+  ``<= pos[b]`` (its current token's position — the caller writes the new
+  K/V at ``pos`` *before* attending), so stale data in partially-filled
+  tail blocks never contributes.
+- **data-dependent block skip**: kv blocks strictly past ``pos`` are
+  predicated away with ``pl.when(k_start <= pos)`` — a *runtime* branch,
+  unlike flash's static causal predicate — so short sequences behind a
+  long table don't pay for the empty tail.
+
+The JAX fallback gathers ``pool[tables]`` and attends with
+`reference_decode_attention`, the same masking and f32 accumulation.
 
 **Int8 pools** (`ops/quant.py`): every paged op takes optional
 ``k_scale`` / ``v_scale`` arrays ``[n_blocks, bs, H]`` f32 — one scale
@@ -73,11 +64,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import backend
-from ray_tpu.ops.flash_attention import (
-    _head_pad_target,
-    _pad_heads,
-    _pick_block,
-)
+from ray_tpu.ops.flash_attention import _head_pad_target, _pad_heads
 
 NEG_INF = -1e30
 
@@ -85,8 +72,7 @@ NEG_INF = -1e30
 # (`%paged_decode.N = ... custom-call`); PERF.md, section 3, lists them.
 # Each call sits in a `named_scope` of its own name: see flash_attention.py.
 # Verify and the fused prefill share `paged_mq`.
-DECODE_UNPAGED, PAGED_DECODE, PAGED_MQ = (
-    "decode_unpaged", "paged_decode", "paged_mq")
+PAGED_DECODE, PAGED_MQ = "paged_decode", "paged_mq"
 
 
 def _auto_impl(op: str, has_plan: bool, why: str) -> str:
@@ -119,140 +105,7 @@ def reference_decode_attention(q, k, v, pos):
 
 
 # ---------------------------------------------------------------------------
-# pallas kernel
-# ---------------------------------------------------------------------------
-
-def _decode_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_scr, l_scr, acc_scr, *, sm_scale: float,
-                   block_kv: int, n_heads: int):
-    ki = pl.program_id(1)
-
-    @pl.when(ki == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = pos_ref[pl.program_id(0) // n_heads]
-    k_start = ki * block_kv
-
-    # Runtime predicate: blocks wholly past this row's position contribute
-    # nothing — skip them (pos is data, so this is a dynamic branch, not
-    # flash's static causal one).
-    @pl.when(k_start <= pos)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [1, D]
-        k = k_ref[0].astype(jnp.float32)            # [bkv, D]
-        s = jax.lax.dot_general(
-            q * sm_scale, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [1, bkv]
-        col = k_start + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1)
-        s = jnp.where(col <= pos, s, NEG_INF)
-        m_prev = m_scr[:1, :1]                      # [1, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                      # [1, bkv]
-        l_scr[:1, :1] = l_scr[:1, :1] * corr + jnp.sum(
-            p, axis=1, keepdims=True)
-        m_scr[:1, :1] = m_new
-        v = v_ref[0]
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [1, D]
-        acc_scr[:1] = acc_scr[:1] * corr + pv
-
-    # Finalize unconditionally at the last block: the last kv block may
-    # itself be dead (pos early in the cache), but the output write must
-    # still happen (flash's _finalize structure).
-    @pl.when(ki == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:1] / l_scr[:1, :1]).astype(o_ref.dtype)
-
-
-def _decode_bhsd(q, k, v, pos, *, sm_scale: float, block_kv: int,
-                 n_heads: int, interpret: bool):
-    """q [BH, 1, D]; k, v [BH, S, D]; pos [B] i32 -> [BH, 1, D]."""
-    bh, s, d = k.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bh, s // block_kv),
-        in_specs=[
-            pl.BlockSpec((1, 1, d), lambda b, j, ps: (b, 0, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j, ps: (b, j, 0)),
-            pl.BlockSpec((1, block_kv, d), lambda b, j, ps: (b, j, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, d), lambda b, j, ps: (b, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),    # m (cell [0, 0] used)
-            pltpu.VMEM((8, 128), jnp.float32),    # l
-            pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 used)
-        ],
-    )
-    with jax.named_scope(DECODE_UNPAGED):
-        return pl.pallas_call(
-            functools.partial(_decode_kernel, sm_scale=sm_scale,
-                              block_kv=block_kv, n_heads=n_heads),
-            name=DECODE_UNPAGED,
-            out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
-            grid_spec=grid_spec,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(pos, q, k, v)
-
-
-# ---------------------------------------------------------------------------
-# public API
-# ---------------------------------------------------------------------------
-
-def decode_attention(q, k, v, pos, *, impl: str = "auto",
-                     block_kv: int = 512):
-    """Decode-step attention: ``q [B, H, D]`` against a KV cache
-    ``k, v [B, S, H, D]``, attending to positions ``<= pos[b]``
-    (``pos [B]`` i32, the position of the token q was computed from).
-    Returns ``[B, H, D]`` in q.dtype.
-
-    impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax". The two paths share the same masking/accumulation math and
-    agree to f32 tolerance."""
-    if q.ndim != 3 or k.ndim != 4:
-        raise ValueError(
-            f"decode_attention wants q [B, H, D] and k/v [B, S, H, D]; "
-            f"got {q.shape} and {k.shape}")
-    b, s, h, d = k.shape
-    bkv = _pick_block(s, block_kv)
-    if impl == "auto":
-        impl = _auto_impl("decode_attention", bkv is not None,
-                          f"cache length {s}")
-    if impl == "jax":
-        return reference_decode_attention(q, k, v, pos)
-    if impl != "pallas":
-        raise ValueError(
-            f"unknown decode_attention impl {impl!r} "
-            "(expected 'auto' | 'pallas' | 'jax')")
-    if bkv is None:
-        raise ValueError(
-            f"cache length {s} has no pallas block plan; use impl='jax'")
-    interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
-    # [B, S, H, D] -> [B*H, S, D]: (S, D) become the trailing tile per
-    # row. On TPU this is one cache-sized transpose per call — the price
-    # of keeping the public cache layout sequence-major; a head-major
-    # resident cache is the follow-up that removes it.
-    kt = _pad_heads(k, d_pad).transpose(0, 2, 1, 3).reshape(b * h, s, d_pad)
-    vt = _pad_heads(v, d_pad).transpose(0, 2, 1, 3).reshape(b * h, s, d_pad)
-    qt = _pad_heads(q, d_pad).reshape(b * h, 1, d_pad)
-    out = _decode_bhsd(qt, kt, vt, pos.astype(jnp.int32),
-                       sm_scale=d ** -0.5, block_kv=bkv, n_heads=h,
-                       interpret=interpret)
-    return out.reshape(b, h, d_pad)[..., :d]
-
-
-# ---------------------------------------------------------------------------
-# paged variant: K/V behind a block table
+# K/V behind a block table
 # ---------------------------------------------------------------------------
 
 def gather_kv_pages(pool, tables):
@@ -657,8 +510,8 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     pallas, post-gather for jax — so HBM traffic stays int8.
 
     impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax". Paths share masking/accumulation math exactly like
-    `decode_attention`."""
+    "jax". The two paths share the same masking/accumulation math and
+    agree to f32 tolerance."""
     if q.ndim != 3 or k_pool.ndim != 4 or tables.ndim != 2:
         raise ValueError(
             "paged_decode_attention wants q [B, H, D], pools "

@@ -16,8 +16,6 @@ from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     constrain,
     global_from_local,
-    kv_cache_shardings,
-    kv_cache_specs,
     logical_to_spec,
     named_sharding,
     replicate_tree,
@@ -31,7 +29,7 @@ from ray_tpu.parallel.pipeline import pipeline_apply, stack_stage_params
 __all__ = [
     "AXIS_ORDER", "BATCH_AXES", "MeshSpec", "dp_mesh", "single_device_mesh",
     "DEFAULT_RULES", "constrain", "global_from_local",
-    "kv_cache_shardings", "kv_cache_specs", "logical_to_spec",
+    "logical_to_spec",
     "named_sharding", "replicate_tree", "replicated", "shard_batch",
     "tree_shardings",
     "reference_attention", "ring_attention",

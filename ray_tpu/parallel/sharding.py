@@ -122,35 +122,15 @@ def fused_xent_specs(mesh: Mesh, rules: dict | None = None
     return x_spec, e_spec, t_spec
 
 
-def kv_cache_specs(mesh: Mesh, rules: dict | None = None):
-    """PartitionSpec pytree for a decode KV cache {"k", "v"} of
-    [L, slots, max_len, H, D]: slots ride the data axes (each data shard
-    serves its own sequences), heads ride the tensor axis (matching the
-    wq/wk/wv column split, so the cache rows a tensor shard writes are
-    the rows it attends over — no cross-shard traffic in decode). Layer
-    stack, cache length and head_dim stay replicated."""
-    from ray_tpu.models.gpt import kv_cache_logical_axes
-    return {name: logical_to_spec(axes, rules, mesh)
-            for name, axes in kv_cache_logical_axes().items()}
-
-
-def kv_cache_shardings(mesh: Mesh, rules: dict | None = None
-                       ) -> dict[str, NamedSharding]:
-    """NamedShardings for `kv_cache_specs` — what
-    `models.gpt.init_kv_cache(mesh=...)` places the cache with."""
-    return {name: NamedSharding(mesh, spec)
-            for name, spec in kv_cache_specs(mesh, rules).items()}
-
-
 def kv_pool_specs(mesh: Mesh, rules: dict | None = None, *,
                   quantized: bool = False):
     """PartitionSpec pytree for a paged KV block pool {"k", "v"} of
-    [L, n_blocks, block_size, H, D]: heads ride the tensor axis (same
-    wq/wk/wv column-split alignment as the unpaged cache — the blocks a
-    tensor shard writes hold the heads it attends over). The block axis
-    is replicated: the allocator hands any physical block to any
-    sequence, so blocks cannot be pinned to data shards the way whole
-    slot rows were. With ``quantized`` (an int8 pool) the pytree grows
+    [L, n_blocks, block_size, H, D]: heads ride the tensor axis
+    (matching the wq/wk/wv column split — the blocks a tensor shard
+    writes hold the heads it attends over, no cross-shard traffic in
+    decode). The block axis is replicated: the allocator hands any
+    physical block to any sequence, so blocks cannot be pinned to data
+    shards. With ``quantized`` (an int8 pool) the pytree grows
     {"k_scale", "v_scale"} of [L, n_blocks, block_size, H]: the head
     axis shards with its payload rows — each tensor shard dequantizes
     from scales it already owns — and blocks stay replicated."""

@@ -119,14 +119,6 @@ def test_paged_prefill_kernel_compiles(topo, chunk, kv_dtype):
         *scales) == 1
 
 
-def test_unpaged_decode_kernel_compiles(topo):
-    assert compile_for(
-        topo, lambda q, k, v, pos: da.decode_attention(
-            q, k, v, pos, impl="pallas"),
-        ((SLOTS, H, D), BF16), ((SLOTS, 1024, H, D), BF16),
-        ((SLOTS, 1024, H, D), BF16), ((SLOTS,), I32)) == 1
-
-
 @pytest.mark.parametrize(
     "shape", [(24, 1024, H, D), (8, 2048, 16, 64), (4, 2048, 16, 128),
               (2, 100, 4, 64), (2, 20, 4, 64), (2, 127, 4, 128)],
@@ -168,7 +160,7 @@ def test_flash_attention_compiles_on_a_four_device_mesh(topo):
         mesh, PartitionSpec(("data", "fsdp"), None, "tensor", None))
     qkv = jax.ShapeDtypeStruct((8, 1024, H, D), BF16, sharding=sharding)
     text = jax.jit(
-        lambda q, k, v: gpt._attention(q, k, v, cfg, mesh)
+        lambda q, k, v: gpt._attention(q, k, v, cfg.attn_impl, mesh)
     ).lower(qkv, qkv, qkv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 
@@ -222,11 +214,6 @@ NAMED = {
     "paged_prefill": (with_scales(da.paged_prefill_attention),
                       [((128, H, D), BF16), *_POOL, ((MB,), I32),
                        ((), I32)], ("paged_mq",)),
-    "decode_unpaged": (lambda q, k, v, pos: da.decode_attention(
-        q, k, v, pos, impl="pallas"),
-        [((SLOTS, H, D), BF16), ((SLOTS, 1024, H, D), BF16),
-         ((SLOTS, 1024, H, D), BF16), ((SLOTS,), I32)],
-        ("decode_unpaged",)),
 }
 
 

@@ -1,7 +1,7 @@
-"""Inference-engine tests: decode-attention kernel parity, KV-cache
-prefill/decode vs. full forward, cache donation, compile-once semantics,
-and continuous batching (slot reuse / late join) through the engine and
-through Serve streaming."""
+"""Inference-engine tests: the decode-attention reference's position
+mask, compile-once semantics, and continuous batching (slot reuse / late
+join) through the engine and through Serve streaming. The paged model
+path's parity with the full forward is in test_paged_cache.py."""
 
 import threading
 
@@ -52,159 +52,6 @@ class TestDecodeAttention:
         v2 = v.at[0, 4:].set(-1e4)
         out2 = da.reference_decode_attention(q, k2, v2, pos)
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
-
-    def test_pallas_matches_reference_f32(self):
-        q, k, v = self._rand(2, 256, 2, 64)
-        pos = jnp.array([0, 200], jnp.int32)
-        ref = da.decode_attention(q, k, v, pos, impl="jax")
-        out = da.decode_attention(q, k, v, pos, impl="pallas")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_pallas_matches_reference_bf16(self):
-        q, k, v = self._rand(1, 128, 2, 64, jnp.bfloat16)
-        pos = jnp.array([77], jnp.int32)
-        ref = da.decode_attention(q, k, v, pos, impl="jax")
-        out = da.decode_attention(q, k, v, pos, impl="pallas")
-        np.testing.assert_allclose(
-            np.asarray(out, np.float32), np.asarray(ref, np.float32),
-            atol=2e-2, rtol=2e-2)
-
-    def test_pallas_padded_head_dim(self):
-        """head_dim not a multiple of 8 goes through _pad_heads."""
-        q, k, v = self._rand(1, 128, 2, 20)
-        pos = jnp.array([64], jnp.int32)
-        ref = da.decode_attention(q, k, v, pos, impl="jax")
-        out = da.decode_attention(q, k, v, pos, impl="pallas")
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_auto_on_cpu_is_jax(self):
-        q, k, v = self._rand(1, 64, 2, 16)
-        pos = jnp.array([10], jnp.int32)
-        auto = da.decode_attention(q, k, v, pos, impl="auto")
-        ref = da.decode_attention(q, k, v, pos, impl="jax")
-        np.testing.assert_array_equal(np.asarray(auto), np.asarray(ref))
-
-    def test_bad_impl_and_shapes(self):
-        q, k, v = self._rand(1, 16, 2, 8)
-        pos = jnp.array([1], jnp.int32)
-        with pytest.raises(ValueError, match="unknown"):
-            da.decode_attention(q, k, v, pos, impl="nope")
-        with pytest.raises(ValueError, match="wants q"):
-            da.decode_attention(k, k, v, pos)
-
-
-# ---------------------------------------------------------------------------
-# KV-cache model path
-# ---------------------------------------------------------------------------
-
-class TestPrefillDecode:
-    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
-                                            ("bfloat16", 5e-2)])
-    def test_matches_full_forward_token_for_token(self, dtype, atol):
-        """prefill(prompt) + decode_step per token reproduces the
-        full-forward logits at every position."""
-        cfg = tiny_cfg(dtype=dtype)
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        B, T, P = 2, 10, 4
-        toks = jax.random.randint(jax.random.PRNGKey(1), (B, T), 0,
-                                  cfg.vocab_size)
-        full = gpt.forward(params, toks, cfg)          # [B, T, V]
-        cache = gpt.init_kv_cache(cfg, B, 16)
-        logits, cache = gpt.prefill(params, toks[:, :P], cache, cfg)
-        np.testing.assert_allclose(np.asarray(logits),
-                                   np.asarray(full[:, P - 1], np.float32),
-                                   atol=atol, rtol=atol)
-        for t in range(P, T):
-            pos = jnp.full((B,), t, jnp.int32)
-            logits, cache = gpt.decode_step(params, toks[:, t], cache,
-                                            pos, cfg)
-            np.testing.assert_allclose(
-                np.asarray(logits), np.asarray(full[:, t], np.float32),
-                atol=atol, rtol=atol)
-
-    def test_prefill_ragged_lengths(self):
-        """lengths= picks each row's own last-token logits; the padded
-        tail cannot leak into them (causal masking)."""
-        cfg = tiny_cfg()
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        toks = jax.random.randint(jax.random.PRNGKey(2), (2, 9), 0,
-                                  cfg.vocab_size)
-        full = gpt.forward(params, toks, cfg)
-        cache = gpt.init_kv_cache(cfg, 2, 16)
-        lens = jnp.array([5, 9], jnp.int32)
-        logits, _ = gpt.prefill(params, toks, cache, cfg, lengths=lens)
-        ref = jnp.stack([full[0, 4], full[1, 8]])
-        np.testing.assert_allclose(np.asarray(logits), np.asarray(ref),
-                                   atol=1e-5, rtol=1e-5)
-
-    def test_slot_targeted_prefill(self):
-        """slot= lands a [1, T] prompt in one cache row and decode picks
-        it up there, ignoring garbage in other slots."""
-        cfg = tiny_cfg()
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        toks = jax.random.randint(jax.random.PRNGKey(3), (1, 6), 0,
-                                  cfg.vocab_size)
-        full = gpt.forward(params, toks, cfg)
-        cache = gpt.init_kv_cache(cfg, 4, 16)
-        logits, cache = gpt.prefill(params, toks, cache, cfg,
-                                    slot=np.int32(2))
-        np.testing.assert_allclose(np.asarray(logits[0]),
-                                   np.asarray(full[0, -1]),
-                                   atol=1e-5, rtol=1e-5)
-        nxt = jnp.argmax(full[0, -1]).astype(jnp.int32)
-        ext = gpt.forward(
-            params, jnp.concatenate([toks, nxt[None, None]], 1), cfg)
-        dtoks = jnp.zeros((4,), jnp.int32).at[2].set(nxt)
-        dpos = jnp.zeros((4,), jnp.int32).at[2].set(6)
-        dl, _ = gpt.decode_step(params, dtoks, cache, dpos, cfg)
-        np.testing.assert_allclose(np.asarray(dl[2]),
-                                   np.asarray(ext[0, -1]),
-                                   atol=1e-4, rtol=1e-4)
-
-    def test_validation_errors(self):
-        cfg = tiny_cfg()
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        with pytest.raises(ValueError, match="max_seq_len"):
-            gpt.init_kv_cache(cfg, 2, cfg.max_seq_len + 1)
-        cache = gpt.init_kv_cache(cfg, 2, 8)
-        toks = jnp.zeros((2, 9), jnp.int32)
-        with pytest.raises(ValueError, match="exceeds cache"):
-            gpt.prefill(params, toks, cache, cfg)
-        with pytest.raises(ValueError, match="pass slot"):
-            gpt.prefill(params, jnp.zeros((3, 4), jnp.int32), cache, cfg)
-        with pytest.raises(ValueError, match="tokens \\[1, T\\]"):
-            gpt.prefill(params, toks, cache, cfg, slot=np.int32(0))
-
-    def test_decode_step_cache_donation(self):
-        """Under jit(donate_argnums=cache) the compiled step aliases the
-        cache input to its output (in-place HBM update) and the donated
-        buffers are consumed."""
-        cfg = tiny_cfg()
-        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
-        cache = gpt.init_kv_cache(cfg, 2, 16)
-        toks = jnp.array([3, 5], jnp.int32)
-        pos = jnp.array([0, 0], jnp.int32)
-
-        step = jax.jit(
-            lambda p, t, c, q: gpt.decode_step(p, t, c, q, cfg),
-            donate_argnums=(2,))
-        hlo = step.lower(params, toks, cache, pos).compile().as_text()
-        assert "input_output_alias" in hlo
-        _, new_cache = step(params, toks, cache, pos)
-        assert cache["k"].is_deleted() and cache["v"].is_deleted()
-        assert not new_cache["k"].is_deleted()
-
-    def test_cache_sharding_specs(self):
-        from ray_tpu.parallel import MeshSpec
-        from ray_tpu.parallel.sharding import kv_cache_specs
-        mesh = MeshSpec(data=-1).build(jax.devices())
-        specs = kv_cache_specs(mesh)
-        assert set(specs) == {"k", "v"}
-        cfg = tiny_cfg(n_layers=1)
-        cache = gpt.init_kv_cache(cfg, 8, 8, mesh=mesh)
-        assert cache["k"].sharding.spec == specs["k"]
 
 
 # ---------------------------------------------------------------------------
@@ -312,8 +159,12 @@ class TestInferenceEngine:
         cfg, params = engine_setup
         toks = solo.generate([5, 9, 3, 7], max_new_tokens=8)
         eos = toks[2]
+        # a greedy rollout may repeat itself: the stream ends at the
+        # FIRST occurrence of the end-of-sequence token, wherever that is
+        first = toks.index(eos)
         got = solo.generate([5, 9, 3, 7], max_new_tokens=8, eos_id=eos)
-        assert got == toks[:3]             # emits eos, then stops
+        assert got == toks[:first + 1]     # emits eos, then stops
+        assert len(got) < len(toks)
 
     def test_concurrent_consumers(self, engine_setup, solo):
         """N threads each pumping their own request drive one shared

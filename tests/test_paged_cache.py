@@ -82,7 +82,7 @@ class TestPagedAttention:
         """Attention through a scrambled block table == attention over
         the contiguous cache it encodes."""
         q, k, v, kp, vp, tables, pos = self._paged(2, 32, 2, 8, 8)
-        ref = da.decode_attention(q, k, v, pos, impl="jax")
+        ref = da.reference_decode_attention(q, k, v, pos)
         out = da.paged_decode_attention(q, kp, vp, tables, pos,
                                         impl="jax")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
@@ -151,6 +151,128 @@ class TestPagedModelPath:
                 jnp.asarray([t], jnp.int32), tables, cfg)
             cur = int(jnp.argmax(logits[0]))
         assert toks_out == rollout_reference(params, prompt, cfg, 6)
+
+    @pytest.mark.parametrize("dtype,atol", [("float32", 1e-4),
+                                            ("bfloat16", 5e-2)])
+    def test_prefill_and_decode_logits_match_full_forward(self, dtype,
+                                                          atol):
+        """Chunked prefill, then one decode step a token: the logits at
+        every position are the full forward's, in f32 and in bf16."""
+        cfg = tiny_cfg(dtype=dtype)
+        params = gpt.init_params(jax.random.PRNGKey(0), cfg)
+        bs, T, P = 8, 14, 10
+        toks = np.asarray(jax.random.randint(
+            jax.random.PRNGKey(1), (T,), 0, cfg.vocab_size))
+        full = np.asarray(gpt.forward(params, jnp.asarray(toks[None]),
+                                      cfg)[0], np.float32)   # [T, V]
+        pool = gpt.init_kv_pool(cfg, 8, bs)
+        table = jnp.asarray([5, 2, 7, 1], jnp.int32)
+        for start, clen in ((0, 8), (8, P - 8)):
+            chunk = np.zeros((1, 8), np.int32)
+            chunk[0, :clen] = toks[start:start + clen]
+            logits, pool = gpt.prefill_paged(
+                params, jnp.asarray(chunk), pool, cfg, block_table=table,
+                start=start, length=jnp.int32(clen))
+            np.testing.assert_allclose(
+                np.asarray(logits[0]), full[start + clen - 1],
+                atol=atol, rtol=atol)
+        for t in range(P, T):
+            logits, pool = gpt.decode_step_paged(
+                params, jnp.asarray(toks[t:t + 1]), pool,
+                jnp.asarray([t], jnp.int32), table[None], cfg)
+            np.testing.assert_allclose(np.asarray(logits[0]), full[t],
+                                       atol=atol, rtol=atol)
+
+    def test_ragged_length_returns_last_real_positions_logits(self,
+                                                              setup):
+        """`length` < C: the logits are position `start + length - 1`'s,
+        whatever the padded tail holds, and the tail writes nothing."""
+        cfg, params = setup
+        bs = 8
+        toks = np.asarray(jax.random.randint(
+            jax.random.PRNGKey(2), (1, 8), 1, cfg.vocab_size))
+        full = gpt.forward(params, jnp.asarray(toks[:, :5]), cfg)
+        table = jnp.asarray([3, 1], jnp.int32)
+
+        def run(tail):
+            chunk = toks.copy()
+            chunk[0, 5:] = tail
+            return gpt.prefill_paged(
+                params, jnp.asarray(chunk), gpt.init_kv_pool(cfg, 4, bs),
+                cfg, block_table=table, start=0, length=jnp.int32(5))
+
+        logits, pool = run(0)
+        np.testing.assert_allclose(np.asarray(logits[0]),
+                                   np.asarray(full[0, 4]),
+                                   atol=1e-5, rtol=1e-5)
+        logits2, _ = run(7)
+        np.testing.assert_array_equal(np.asarray(logits),
+                                      np.asarray(logits2))
+        k = np.asarray(pool["k"])               # [L, nb, bs, H, Dh]
+        assert np.any(k[:, 3, :5] != 0) and not np.any(k[:, 3, 5:])
+        assert not np.any(k[:, [0, 1, 2]])
+
+    def test_prefill_rejects_a_batch_and_a_missing_start(self, setup):
+        cfg, params = setup
+        pool = gpt.init_kv_pool(cfg, 4, 8)
+        table = jnp.asarray([1, 2], jnp.int32)
+        with pytest.raises(ValueError, match="tokens \\[1, C\\]"):
+            gpt.prefill_paged(params, jnp.zeros((2, 8), jnp.int32), pool,
+                              cfg, block_table=table, start=0)
+        with pytest.raises(ValueError, match="needs start"):
+            gpt.prefill_paged(params, jnp.zeros((1, 8), jnp.int32), pool,
+                              cfg, block_table=table, start=None)
+
+    def test_writes_past_the_table_drop(self, setup):
+        """A position past `max_blocks * block_size` (a speculative step
+        near max_len) writes nothing: clamping would land it inside the
+        slot's own last block. The pool is whatever size the caller
+        asks; nothing ties it to `max_seq_len`."""
+        cfg, params = setup
+        bs = 8
+        pool = gpt.init_kv_pool(cfg, cfg.max_seq_len // bs + 3, bs)
+        assert pool["k"].shape[1:3] == (cfg.max_seq_len // bs + 3, bs)
+        tables = jnp.asarray([[1, 2]], jnp.int32)      # reach: 16
+        tok = jnp.asarray([5], jnp.int32)
+        _, inside = gpt.decode_step_paged(
+            params, tok, pool, jnp.asarray([15], jnp.int32), tables, cfg)
+        assert np.any(np.asarray(inside["k"][:, 2, 7]))
+        for write in (
+            lambda pos: gpt.decode_step_paged(
+                params, tok, pool, jnp.asarray([pos], jnp.int32), tables,
+                cfg),
+            lambda pos: gpt.verify_step_paged(
+                params, jnp.asarray([[5, 6]], jnp.int32), pool,
+                jnp.asarray([pos], jnp.int32), tables, cfg),
+        ):
+            _, past = write(16)
+            for name in pool:
+                assert not np.any(np.asarray(past[name])), name
+
+    def test_decode_step_paged_pool_donation(self, setup):
+        """Under jit(donate_argnums=pool) the compiled step aliases the
+        pool's input to its output (an in-place update in HBM), the
+        donated buffers are consumed, and a second step with other
+        positions and tables does not trace again."""
+        cfg, params = setup
+        pool = gpt.init_kv_pool(cfg, 6, 8)
+        toks = jnp.array([3, 5], jnp.int32)
+        tables = jnp.asarray([[1, 2], [3, 4]], jnp.int32)
+        traces = []
+
+        def fn(p, t, c, pos, tbl):
+            traces.append(1)
+            return gpt.decode_step_paged(p, t, c, pos, tbl, cfg)
+
+        step = jax.jit(fn, donate_argnums=(2,))
+        pos = jnp.array([0, 0], jnp.int32)
+        hlo = step.lower(params, toks, pool, pos, tables).compile().as_text()
+        assert "input_output_alias" in hlo
+        _, new_pool = step(params, toks, pool, pos, tables)
+        assert pool["k"].is_deleted() and pool["v"].is_deleted()
+        assert not new_pool["k"].is_deleted()
+        step(params, toks, new_pool, pos + 1, tables[::-1])
+        assert len(traces) == 1         # the lowering's; the calls reuse it
 
     def test_copy_block(self, setup):
         cfg, params = setup

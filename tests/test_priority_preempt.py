@@ -67,6 +67,17 @@ PROMPT = np.arange(1, 9, dtype=np.int32)          # 8 tokens = 2 blocks
 # token-identical resume
 # ---------------------------------------------------------------------------
 
+def assert_same_stream(got, base):
+    """Tokens exactly; logprobs within 1e-5. A resumed stream re-prefills
+    positions the baseline decoded one at a time: two programs that trace
+    different matmul shapes and round differently (measured 5e-7 on
+    logprobs near -4.6). 1e-5 is twenty times that and far under what a
+    wrong mask or a lost block does (1e-2 and up)."""
+    assert [t for t, _ in got] == [t for t, _ in base]
+    np.testing.assert_allclose([lp for _, lp in got],
+                               [lp for _, lp in base], rtol=0, atol=1e-5)
+
+
 class TestTokenIdenticalResume:
     def _baseline(self, cfg, params, prompt, n, **kw):
         eng = make_engine(cfg, params, **kw)
@@ -95,7 +106,7 @@ class TestTokenIdenticalResume:
         assert s["preemptions"] >= 1
         assert s["per_class"]["0"]["preemptions"] >= 1
         got = [(int(t), t.logprob) for t in eng.tokens_for(ra)]
-        assert got == base
+        assert_same_stream(got, base)
         assert len(drain(eng, rb)) == 6
         eng.check_invariants()
 
@@ -110,7 +121,8 @@ class TestTokenIdenticalResume:
         rid = eng.submit(PROMPT, max_new_tokens=6, priority=0)
         run_all(eng)
         assert eng.stats()["preemptions"] == 1
-        assert [(int(t), t.logprob) for t in eng.tokens_for(rid)] == base
+        assert_same_stream(
+            [(int(t), t.logprob) for t in eng.tokens_for(rid)], base)
         eng.check_invariants()
 
     def test_shared_prefix_cow_preempt_token_identical(self, setup):
@@ -135,8 +147,10 @@ class TestTokenIdenticalResume:
         run_all(eng)
         assert eng.stats()["preemptions"] == 1
         # the class-0 stream was the victim; both match their baselines
-        assert [(int(t), t.logprob) for t in eng.tokens_for(ra)] == base_a
-        assert [(int(t), t.logprob) for t in eng.tokens_for(rb)] == base_b
+        assert_same_stream(
+            [(int(t), t.logprob) for t in eng.tokens_for(ra)], base_a)
+        assert_same_stream(
+            [(int(t), t.logprob) for t in eng.tokens_for(rb)], base_b)
         eng.check_invariants()
 
     @pytest.mark.parametrize("spec", ["ngram", "draft"])
@@ -157,7 +171,8 @@ class TestTokenIdenticalResume:
         rid = eng.submit(motif, max_new_tokens=8, priority=0)
         run_all(eng)
         assert eng.stats()["preemptions"] == 1
-        assert [(int(t), t.logprob) for t in eng.tokens_for(rid)] == base
+        assert_same_stream(
+            [(int(t), t.logprob) for t in eng.tokens_for(rid)], base)
         eng.check_invariants()
 
     def test_mid_prefill_preempt_token_identical(self, setup):
@@ -174,7 +189,8 @@ class TestTokenIdenticalResume:
         rid = eng.submit(long_prompt, max_new_tokens=4, priority=0)
         run_all(eng)
         assert eng.stats()["preemptions"] == 1
-        assert [(int(t), t.logprob) for t in eng.tokens_for(rid)] == base
+        assert_same_stream(
+            [(int(t), t.logprob) for t in eng.tokens_for(rid)], base)
         eng.check_invariants()
 
 
