@@ -75,10 +75,12 @@ class GPTConfig:
     #             batch size at 16. Accumulation is f32 either way;
     #             fused vs dense agrees to ~1e-6 with f32 logits.
     loss_impl: str = "dense"        # dense | fused
-    # Vocab rows per online-softmax step of the fused loss (also its
-    # preferred Pallas vocab block). The loss's transient logits block
-    # is [B, T, loss_chunk]; smaller chunks mean less live memory and
-    # more loop steps.
+    # Vocab rows per online-softmax step of the fused loss's scan path
+    # (off a TPU, or a vocab shard no lane tile divides): its transient
+    # logits block is [B, T, loss_chunk]; smaller chunks mean less live
+    # memory and more loop steps. The Pallas kernels do not read it:
+    # their blocks come from the shapes and the chip's VMEM
+    # (ops/fused_xent._plan).
     loss_chunk: int = 512
     # Attention implementation for paged decode and verify over the block
     # pool (decode_step_paged, verify_step_paged). "auto" picks the

@@ -21,11 +21,33 @@ targets, lse) — again no ``[B, T, V]`` anywhere:
 
 Two implementations share that math:
 
-- **pallas**: TPU forward + backward kernels (grid = rows x vocab
-  blocks, per-row m/l/target-logit accumulators in VMEM scratch),
-  mirroring flash_attention.py's structure.
-- **scan**: a pure-JAX `lax.scan` over vocab chunks — the
-  everywhere-correct path, and what `impl="auto"` picks off a TPU.
+- **pallas**: three TPU kernels (`xent_fwd`, `xent_dx`, `xent_de`),
+  each a grid of row blocks x vocab blocks over one MXU pass per score
+  tile, bf16 operands as stored, f32 everything else. What a grid step
+  holds is `_plan`'s: forward and dX keep a block of `x` rows (and, for
+  dX, its f32 result block, which is its own accumulator) while the
+  whole embedding streams past 384 rows at a time at V = 50304; dE
+  keeps 384 embedding rows and their f32 result while `x` streams past.
+  A body walks its row block in tiles of `sub_n` rows with a static
+  loop. Both shapes the benchmark runs sat at the HBM ridge with the
+  blocks a rounded-down `vocab_chunk` gave them (256 rows x 384 or 128
+  vocab rows: the embedding read once per 256 rows); the table under
+  `fused_softmax_xent` is the sweep the plan was written from.
+- **scan**: a pure-JAX `lax.scan` over vocab chunks of `vocab_chunk`
+  rows — the everywhere-correct path, and what `impl="auto"` picks off
+  a TPU or for a shape with no plan.
+
+**Row statistics are lane-dense.** The forward keeps its running max,
+sum and target logit as `[rows, 128]` with one value *per lane*: lane c
+of a row owns the vocab columns = c (mod 128), so a score tile is
+folded in slab by slab (128 columns) with elementwise `max`, `exp`,
+add and select only. Nothing crosses lanes until `_finalize`, which
+reduces 128 lanes once a row block. A `[rows, 1]` column costs a vreg
+per 8 rows all the same, each use of it a lane broadcast, and each tile
+two cross-lane reductions and a third for the target: at these narrow
+tiles that, not the MXU, was most of the forward. The backward's lse,
+targets and cotangents arrive `[N, 128]` with every lane alike and are
+used whole against each slab.
 
 On a mesh of more than one device the op runs under `shard_map` (the
 compiler cannot partition a Pallas kernel): tokens split over the batch
@@ -40,6 +62,7 @@ dense path's vocab-sharded logits gather/reduction over ``[B, T, V]``.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -138,8 +161,50 @@ def _bwd_scan(x, embed, targets, lse, c_lse, c_tgt, chunk):
 # pallas kernels (TPU)
 # ---------------------------------------------------------------------------
 
+class _Plan(NamedTuple):
+    """What a call's three kernels run at (`_plan` chooses it).
+    `block_n`: the rows of `x` a grid step of `xent_fwd` / `xent_dx`
+    keeps in VMEM while the whole embedding streams past in blocks of
+    `block_v` rows; `xent_de` turns that round: `block_v` embedding
+    rows and their f32 dE block stay while `x` streams past in blocks
+    of `block_n` rows. `sub_n`: the rows of the score tile
+    `[sub_n, block_v]` a kernel body builds at a time, walking its row
+    block. `vmem_limit`: what the hungriest of the three working sets
+    asks of `CompilerParams(vmem_limit_bytes=)`, None where the
+    compiler's default scope holds it."""
+    block_n: int
+    block_v: int
+    sub_n: int
+    vmem_limit: int | None
+
+
+def _walk(plan: _Plan, rows):
+    """`rows(slice)` for each `sub_n` rows of the resident row block:
+    a static loop, so every start is a constant and the scheduler may
+    run one tile's matmul under its neighbour's exp (as a
+    `lax.fori_loop` the same walk cost 1.4-1.5 ms a kernel: the table
+    under `fused_softmax_xent`)."""
+    for r in range(plan.block_n // plan.sub_n):
+        rows(pl.ds(r * plan.sub_n, plan.sub_n))
+
+
+def _slabs(s):
+    """[rows, block_v] -> its 128-column slabs, one lane tile each."""
+    return [s[:, k * 128:(k + 1) * 128] for k in range(s.shape[1] // 128)]
+
+
+def _scores(x, e):
+    """One score tile on the MXU, the operands as stored (one type, or
+    the wider of two): [sn, D] x [bv, D] -> f32 [sn, bv]."""
+    ct = jnp.promote_types(x.dtype, e.dtype)
+    return jax.lax.dot_general(
+        x.astype(ct), e.astype(ct),
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+
+
 def _fwd_kernel(x_ref, e_ref, t_ref, lse_ref, tgt_ref,
-                m_scr, l_scr, t_scr, *, block_v):
+                m_scr, l_scr, t_scr, *, plan: _Plan):
     ji = pl.program_id(1)
 
     @pl.when(ji == 0)
@@ -148,83 +213,102 @@ def _fwd_kernel(x_ref, e_ref, t_ref, lse_ref, tgt_ref,
         l_scr[:] = jnp.zeros_like(l_scr)
         t_scr[:] = jnp.zeros_like(t_scr)
 
-    x = x_ref[...].astype(jnp.float32)              # [bn, D]
-    e = e_ref[...].astype(jnp.float32)              # [bv, D]
-    s = jax.lax.dot_general(
-        x, e, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [bn, bv]
-    col = ji * block_v + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    m_prev = m_scr[:, :1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    l_scr[:, :1] = (l_scr[:, :1] * jnp.exp(m_prev - m_new)
-                    + jnp.sum(jnp.exp(s - m_new), axis=1, keepdims=True))
-    m_scr[:, :1] = m_new
-    hit = col == t_ref[:, :1]
-    t_scr[:, :1] += jnp.sum(jnp.where(hit, s, 0.0), axis=1, keepdims=True)
+    e = e_ref[...]                                      # [bv, D]
+    lane = jax.lax.broadcasted_iota(jnp.int32, (plan.sub_n, 128), 1)
+
+    def rows(sl):
+        slabs = _slabs(_scores(x_ref[sl, :], e))        # f32 [sn, 128] each
+        # lane c of a row keeps the running max, sum and target logit
+        # of the vocab columns = c (mod 128): elementwise work only
+        m_prev = m_scr[sl, :]
+        m_new = functools.reduce(jnp.maximum, slabs, m_prev)
+        l = l_scr[sl, :] * jnp.exp(m_prev - m_new)
+        tg = t_scr[sl, :]
+        tloc = t_ref[sl, :] - ji * plan.block_v         # lanes alike
+        for k, sk in enumerate(slabs):
+            l = l + jnp.exp(sk - m_new)
+            tg = tg + jnp.where(lane + k * 128 == tloc, sk, 0.0)
+        m_scr[sl, :] = m_new
+        l_scr[sl, :] = l
+        t_scr[sl, :] = tg
+
+    _walk(plan, rows)
 
     @pl.when(ji == pl.num_programs(1) - 1)
     def _finalize():
-        lse = m_scr[:, :1] + jnp.log(l_scr[:, :1])
-        # broadcast across the 128-lane tile (TPU min tile width)
-        lse_ref[...] = jnp.broadcast_to(lse, lse_ref.shape)
-        tgt_ref[...] = jnp.broadcast_to(t_scr[:, :1], tgt_ref.shape)
+        # the one cross-lane pass of a row block: a row's 128 maxima,
+        # sums and target terms become one, broadcast across the
+        # 128-lane tile (TPU min tile width)
+        m = m_scr[:]
+        row_m = jnp.max(m, axis=1, keepdims=True)
+        row_l = jnp.sum(l_scr[:] * jnp.exp(m - row_m), axis=1,
+                        keepdims=True)
+        lse_ref[...] = jnp.broadcast_to(row_m + jnp.log(row_l),
+                                        lse_ref.shape)
+        tgt_ref[...] = jnp.broadcast_to(
+            jnp.sum(t_scr[:], axis=1, keepdims=True), tgt_ref.shape)
 
 
-def _recompute_dlog(x_ref, e_ref, t_ref, lse_ref, cl_ref, ct_ref,
-                    v_start):
-    """Rebuild one logits block from the saved lse and form dlogits —
-    shared by the dx and dembed kernels so the masking/softmax math can
-    never diverge between them (flash_attention._recompute_p_ds idiom)."""
-    x = x_ref[...].astype(jnp.float32)              # [bn, D]
-    e = e_ref[...].astype(jnp.float32)              # [bv, D]
-    s = jax.lax.dot_general(
-        x, e, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [bn, bv]
-    col = v_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-    p = jnp.exp(s - lse_ref[:, :1])
-    hit = (col == t_ref[:, :1]).astype(jnp.float32)
-    dlog = cl_ref[:, :1] * p + ct_ref[:, :1] * hit  # [bn, bv]
-    return x, e, dlog
+def _dlog(x, e, t, lse, cl, ct, v_start):
+    """Rebuild one score tile from the saved lse and form dlogits in
+    f32 — shared by the dx and dembed kernels so the masking/softmax
+    math can never diverge between them
+    (flash_attention._recompute_p_ds idiom). `t`, `lse`, `cl`, `ct`:
+    [sn, 128] with every lane alike, as they arrive, used whole against
+    each 128-column slab."""
+    tloc = t - v_start
+    lane = jax.lax.broadcasted_iota(jnp.int32, t.shape, 1)
+    parts = []
+    for k, sk in enumerate(_slabs(_scores(x, e))):
+        p = cl * jnp.exp(sk - lse)
+        parts.append(jnp.where(lane + k * 128 == tloc, p + ct, p))
+    return jnp.concatenate(parts, axis=1)
 
 
-def _dx_kernel(x_ref, e_ref, t_ref, lse_ref, cl_ref, ct_ref, dx_ref,
-               dx_scr, *, block_v):
+def _dx_kernel(x_ref, e_ref, t_ref, lse_ref, cl_ref, ct_ref, dx_ref, *,
+               plan: _Plan):
     ji = pl.program_id(1)
 
+    # the f32 dx block stays while the vocab blocks go by: it is its
+    # own accumulator
     @pl.when(ji == 0)
     def _init():
-        dx_scr[:] = jnp.zeros_like(dx_scr)
+        dx_ref[...] = jnp.zeros_like(dx_ref)
 
-    _, e, dlog = _recompute_dlog(x_ref, e_ref, t_ref, lse_ref, cl_ref,
-                                 ct_ref, ji * block_v)
-    dx_scr[:] += jax.lax.dot_general(
-        dlog, e, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [bn, D]
+    e = e_ref[...]
 
-    @pl.when(ji == pl.num_programs(1) - 1)
-    def _finalize():
-        dx_ref[...] = dx_scr[:]
+    def rows(sl):
+        dlog = _dlog(x_ref[sl, :], e, t_ref[sl, :], lse_ref[sl, :],
+                     cl_ref[sl, :], ct_ref[sl, :], ji * plan.block_v)
+        dx_ref[sl, :] += jax.lax.dot_general(
+            dlog.astype(e.dtype), e,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [sn, D]
+
+    _walk(plan, rows)
 
 
-def _de_kernel(x_ref, e_ref, t_ref, lse_ref, cl_ref, ct_ref, de_ref,
-               de_scr, *, block_v):
+def _de_kernel(x_ref, e_ref, t_ref, lse_ref, cl_ref, ct_ref, de_ref, *,
+               plan: _Plan):
     # grid is (vocab blocks, row blocks): rows are the inner sequential
-    # dim so the dembed accumulator lives in scratch across them
-    ii = pl.program_id(1)
-
-    @pl.when(ii == 0)
+    # dim, so the f32 dembed block accumulates across them in place
+    @pl.when(pl.program_id(1) == 0)
     def _init():
-        de_scr[:] = jnp.zeros_like(de_scr)
+        de_ref[...] = jnp.zeros_like(de_ref)
 
-    x, _, dlog = _recompute_dlog(x_ref, e_ref, t_ref, lse_ref, cl_ref,
-                                 ct_ref, pl.program_id(0) * block_v)
-    de_scr[:] += jax.lax.dot_general(
-        dlog, x, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)         # [bv, D]
+    e = e_ref[...]
+    v_start = pl.program_id(0) * plan.block_v
 
-    @pl.when(ii == pl.num_programs(1) - 1)
-    def _finalize():
-        de_ref[...] = de_scr[:]
+    def rows(sl):
+        x = x_ref[sl, :]
+        dlog = _dlog(x, e, t_ref[sl, :], lse_ref[sl, :], cl_ref[sl, :],
+                     ct_ref[sl, :], v_start)
+        de_ref[...] += jax.lax.dot_general(
+            dlog.astype(x.dtype), x,
+            dimension_numbers=(((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [bv, D]
+
+    _walk(plan, rows)
 
 
 def _rows128(a, n):
@@ -232,88 +316,108 @@ def _rows128(a, n):
     return jnp.broadcast_to(a.reshape(n, 1), (n, 128))
 
 
-def _lse_tgt_pallas(x, embed, targets, block_n, block_v, interpret):
+def _params(plan: _Plan):
+    return pltpu.CompilerParams(
+        dimension_semantics=("parallel", "arbitrary"),
+        vmem_limit_bytes=plan.vmem_limit)
+
+
+def _lse_tgt_pallas(x, embed, targets, plan: _Plan, interpret):
     b, t, d = x.shape
     n = b * t
     v = embed.shape[0]
-    grid = (n // block_n, v // block_v)
-    row_spec = pl.BlockSpec((block_n, 128), lambda i, j: (i, 0))
+    bn, bv = plan.block_n, plan.block_v
+    row_spec = pl.BlockSpec((bn, 128), lambda i, j: (i, 0))
     with jax.named_scope(XENT_FWD):
         lse2, tgt2 = pl.pallas_call(
-            functools.partial(_fwd_kernel, block_v=block_v),
+            functools.partial(_fwd_kernel, plan=plan),
             name=XENT_FWD,
             out_shape=(jax.ShapeDtypeStruct((n, 128), jnp.float32),) * 2,
-            grid=grid,
+            grid=(n // bn, v // bv),
             in_specs=[
-                pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bv, d), lambda i, j: (j, 0)),
                 row_spec,
             ],
             out_specs=(row_spec, row_spec),
-            scratch_shapes=[pltpu.VMEM((block_n, 128), jnp.float32)] * 3,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            # running max, sum and target logit, one of each per lane
+            scratch_shapes=[pltpu.VMEM((bn, 128), jnp.float32)] * 3,
+            compiler_params=_params(plan),
             interpret=interpret,
         )(x.reshape(n, d), embed, _rows128(targets.astype(jnp.int32), n))
     return lse2[:, 0].reshape(b, t), tgt2[:, 0].reshape(b, t)
 
 
-def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, block_n, block_v,
+def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, plan: _Plan,
                 interpret):
     b, t, d = x.shape
     n = b * t
     v = embed.shape[0]
+    bn, bv = plan.block_n, plan.block_v
     x2 = x.reshape(n, d)
     t2 = _rows128(targets.astype(jnp.int32), n)
     lse2 = _rows128(lse.astype(jnp.float32), n)
     cl2 = _rows128(c_lse.astype(jnp.float32), n)
     ct2 = _rows128(c_tgt.astype(jnp.float32), n)
-    row_spec = pl.BlockSpec((block_n, 128), lambda i, j: (i, 0))
+    row_spec = pl.BlockSpec((bn, 128), lambda i, j: (i, 0))
 
     with jax.named_scope(XENT_DX):
         dx = pl.pallas_call(
-            functools.partial(_dx_kernel, block_v=block_v),
+            functools.partial(_dx_kernel, plan=plan),
             name=XENT_DX,
             out_shape=jax.ShapeDtypeStruct((n, d), jnp.float32),
-            grid=(n // block_n, v // block_v),
+            grid=(n // bn, v // bv),
             in_specs=[
-                pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-                pl.BlockSpec((block_v, d), lambda i, j: (j, 0)),
+                pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+                pl.BlockSpec((bv, d), lambda i, j: (j, 0)),
                 row_spec, row_spec, row_spec, row_spec,
             ],
-            out_specs=pl.BlockSpec((block_n, d), lambda i, j: (i, 0)),
-            scratch_shapes=[pltpu.VMEM((block_n, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            out_specs=pl.BlockSpec((bn, d), lambda i, j: (i, 0)),
+            compiler_params=_params(plan),
             interpret=interpret,
         )(x2, embed, t2, lse2, cl2, ct2)
 
     # swapped grid: each vocab block streams every row block through its
     # accumulator
-    row_spec_t = pl.BlockSpec((block_n, 128), lambda j, i: (i, 0))
+    row_spec_t = pl.BlockSpec((bn, 128), lambda j, i: (i, 0))
     with jax.named_scope(XENT_DE):
         de = pl.pallas_call(
-            functools.partial(_de_kernel, block_v=block_v),
+            functools.partial(_de_kernel, plan=plan),
             name=XENT_DE,
             out_shape=jax.ShapeDtypeStruct((v, d), jnp.float32),
-            grid=(v // block_v, n // block_n),
+            grid=(v // bv, n // bn),
             in_specs=[
-                pl.BlockSpec((block_n, d), lambda j, i: (i, 0)),
-                pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
+                pl.BlockSpec((bn, d), lambda j, i: (i, 0)),
+                pl.BlockSpec((bv, d), lambda j, i: (j, 0)),
                 row_spec_t, row_spec_t, row_spec_t, row_spec_t,
             ],
-            out_specs=pl.BlockSpec((block_v, d), lambda j, i: (j, 0)),
-            scratch_shapes=[pltpu.VMEM((block_v, d), jnp.float32)],
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+            out_specs=pl.BlockSpec((bv, d), lambda j, i: (j, 0)),
+            compiler_params=_params(plan),
             interpret=interpret,
         )(x2, embed, t2, lse2, cl2, ct2)
     return dx.reshape(b, t, d), de
 
 
 # ---------------------------------------------------------------------------
-# implementation dispatch
+# the step plan and the implementation dispatch
 # ---------------------------------------------------------------------------
+
+_MIB = 1 << 20
+_SCOPED_DEFAULT = 16 * _MIB     # Mosaic's scoped VMEM when no limit is asked
+_ROW_BLOCKS = (2048, 1024, 512, 256)    # resident row blocks looked for
+_BLOCK_V = 512                  # the widest vocab block looked for
+_SUB_N = 512                    # rows of a score tile, where they divide
+
+
+def _vmem_capacity() -> int:
+    """The VMEM of the core the kernels compile for. Off a TPU (the
+    interpreter; a compile for a described chip) the v5e's 128 MiB, the
+    chip this repo's cells and AOT tests describe."""
+    try:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    except ValueError:
+        return 128 * _MIB
+
 
 def _pick(t: int, pref: int, step: int) -> int | None:
     """Largest step-aligned block <= pref that divides t (the
@@ -327,15 +431,63 @@ def _pick(t: int, pref: int, step: int) -> int | None:
     return None
 
 
-def _plan(n: int, v: int, block_n: int, block_v: int):
-    bn, bv = _pick(n, block_n, 8), _pick(v, block_v, 128)
-    return (bn, bv) if bn and bv else None
+def _working_set(bn: int, bv: int, sn: int, d: int, xb: int,
+                 eb: int) -> int:
+    """Bytes of VMEM the hungriest of the three kernels holds in a grid
+    step: every operand and result block twice (the pipeline's two
+    buffers), scratch once, and the body's temporaries (four of the
+    score tile for it and what is made of it, and the second matmul's
+    result on its way to the accumulator)."""
+    rows = bn * 128 * 4                         # one [bn, 128] f32 block
+    blocks = 2 * (bn * d * xb + bv * d * eb)    # x and embedding blocks
+    tile = 4 * sn * bv * 4
+    fwd = blocks + 2 * 3 * rows + 3 * rows + tile
+    dx = blocks + 2 * (4 * rows + bn * d * 4) + tile + sn * d * 4
+    de = blocks + 2 * (4 * rows + bv * d * 4) + tile + bv * d * 4
+    return max(fwd, dx, de)
 
 
-def _resolve_impl(impl: str, n: int, v: int, chunk: int):
-    """-> ("scan", chunk) | ("pallas", (block_n, block_v)). `chunk`
-    doubles as the preferred pallas vocab block."""
-    plan = _plan(n, v, block_n=256, block_v=max(chunk, 128))
+def _plan(n: int, v: int, d: int, x_bytes: int = 2, e_bytes: int = 2,
+          vmem: int | None = None) -> _Plan | None:
+    """The step plan for `n` rows against `v` embedding rows of width
+    `d`, from the shapes, the element sizes and the chip's VMEM alone:
+    the widest lane-aligned vocab block that divides `v`, and of the
+    row blocks of `_ROW_BLOCKS` that divide `n` the largest whose
+    working set fits the compiler's default scope, which asks nothing;
+    where none does, the largest that fits half the VMEM, and the ask.
+    (An ask is not free outside the kernels: XLA keeps buffers of its
+    own in VMEM, and with any `vmem_limit_bytes` on these three calls
+    it moved 134 MB of the one-chip cell's temporaries to HBM.) A row
+    count none of them divides runs one tile a block. None where `n`
+    has no sublane-aligned divisor or `v` no lane-aligned one: the
+    caller takes the scan path."""
+    bv = _pick(v, _BLOCK_V, 128)
+    ragged = _pick(n, _ROW_BLOCKS[-1], 8)
+    if bv is None or ragged is None:
+        return None
+    fits = []                   # (working set, block_n, sub_n), largest first
+    for bn in (*(b for b in _ROW_BLOCKS if n % b == 0), ragged):
+        sn = _SUB_N if bn % _SUB_N == 0 else bn
+        fits.append((_working_set(bn, bv, sn, d, x_bytes, e_bytes), bn, sn))
+
+    def largest_in(limit):
+        return next((f for f in fits if f[0] <= limit), None)
+
+    fit = largest_in(_SCOPED_DEFAULT)
+    if fit is not None:
+        return _Plan(fit[1], bv, fit[2], None)
+    need, bn, sn = (largest_in((vmem or _vmem_capacity()) // 2)
+                    or fits[-1])
+    # the estimate and a quarter for what it cannot see
+    return _Plan(bn, bv, sn, -(-(need + need // 4) // _MIB) * _MIB)
+
+
+def _resolve_impl(impl: str, x, embed, chunk: int):
+    """-> ("scan", chunk) | ("pallas", _Plan). `chunk` is the scan
+    path's; the kernels' blocks come from the shapes."""
+    b, t, d = x.shape
+    n, v = b * t, embed.shape[0]
+    plan = _plan(n, v, d, x.dtype.itemsize, embed.dtype.itemsize)
     if impl == "auto":
         if plan is None:
             backend.note_fallback("fused_softmax_xent",
@@ -356,22 +508,20 @@ def _resolve_impl(impl: str, n: int, v: int, chunk: int):
 
 
 def _lse_tgt_impl(x, embed, targets, chunk, impl):
-    b, t, _ = x.shape
-    kind, arg = _resolve_impl(impl, b * t, embed.shape[0], chunk)
+    kind, arg = _resolve_impl(impl, x, embed, chunk)
     if kind == "scan":
         return _lse_tgt_scan(x, embed, targets, arg)
-    return _lse_tgt_pallas(x, embed, targets, *arg,
+    return _lse_tgt_pallas(x, embed, targets, arg,
                            interpret=backend.interpret())
 
 
 def _bwd_impl(x, embed, targets, lse, c_lse, c_tgt, chunk, impl):
     """f32 (dx, dembed); callers cast at the custom_vjp boundary (and
     the TP path psums in f32 first)."""
-    b, t, _ = x.shape
-    kind, arg = _resolve_impl(impl, b * t, embed.shape[0], chunk)
+    kind, arg = _resolve_impl(impl, x, embed, chunk)
     if kind == "scan":
         return _bwd_scan(x, embed, targets, lse, c_lse, c_tgt, arg)
-    return _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, *arg,
+    return _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, arg,
                        interpret=backend.interpret())
 
 
@@ -517,6 +667,42 @@ def fused_softmax_xent(x, embed, targets, *, vocab_chunk: int = 512,
     axes, and where the rules shard the vocab (default: over ``tensor``)
     each shard reduces its local rows and one psum of the partial
     log-sum-exp / target-logit terms combines them.
+
+    `vocab_chunk` is the scan path's chunk. The kernels' blocks are
+    `_plan(N, V, D, element sizes, VMEM)`'s, written from this sweep
+    (one v5e, bf16, each kernel alone in its own jit, device ms a call
+    by kernel name from a profiler trace, 5 calls; `(N, V, D)` =
+    `(16384, 50304, 1024)` / `(8192, 50304, 2048)`, the benchmark's two
+    cells a chip; one logits matmul is 8.57 ms at the MXU's peak, the
+    forward does one, dX and dE two each):
+
+    ====================================  ===========  ===========  ===========
+    rows x vocab rows a step; body        xent_fwd     xent_dx      xent_de
+    ====================================  ===========  ===========  ===========
+    256 x 384 / 256 x 128; f32 upcast,    14.53/17.59  18.78/19.66  18.75/23.08
+    `[rows, 1]` statistics (before)
+    the same body, 1024 x 384             12.67/10.63  17.68/17.45  18.13/18.02
+    the same body, 1024 x 128             20.81/14.71  18.51/17.86  22.35/19.17
+    lanes alike, 1024 x 384                9.31/ 8.95  as below     as below
+    per lane, 256 x 384                   11.23/ 9.98  18.64/18.01  18.68/18.27
+    per lane, 512 x 384                    9.37/ 8.99  17.88/17.58  18.00/17.68
+    per lane, 1024 x 384                   8.98/ 8.79  17.54/17.40  17.56/17.44
+    per lane, 1024 x 128                   9.77/ 9.18  18.30/17.82  21.72/20.14
+    per lane, 2048 x 384                   8.80/ 8.70  17.38/17.33  17.39/17.36
+    per lane, 1024 x 384, `fori_loop`     10.45/ 9.79  18.92/18.35  19.02/18.45
+    per lane, 1024 x 384, f32 upcast       8.98/ 8.79  17.55/17.40  17.59/17.47
+    ====================================  ===========  ===========  ===========
+
+    (Per-lane rows: tiles of 256 or 512 rows walked with a static loop;
+    128-row tiles cost dX 0.2-0.4 ms, one tile of the whole block dE
+    0.4.) Row blocks past 512 need more than the compiler's default 16
+    MiB of scoped VMEM. The plan asks for it only where no row block
+    fits the default, as at D = 2048 (1024 rows, 48 MiB asked), and
+    takes 512 rows at D = 1024: an ask on these calls made XLA move
+    134 MB of the one-chip cell's temporaries out of VMEM into HBM,
+    for the 1.5 ms between the 512 and 2048 rows of the table.
+    Operands as stored against an f32 upcast measured nothing; the
+    stored type is what the MXU ran either way.
     """
     if x.ndim != 3 or embed.ndim != 2:
         raise ValueError(

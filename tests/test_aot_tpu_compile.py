@@ -9,6 +9,7 @@ or times. `chip_smoke.py` is the run.
 """
 
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")   # or it logs under /tmp
 
@@ -21,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from ray_tpu.models import gpt
 from ray_tpu.ops import decode_attention as da
+from ray_tpu.ops import fused_xent
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.fused_xent import fused_softmax_xent
 from ray_tpu.parallel import MeshSpec
@@ -183,6 +185,65 @@ def test_fused_xent_compiles_on_a_four_device_mesh(topo):
     text = jax.jit(jax.grad(loss, argnums=(0, 1))).lower(
         *args).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 3
+
+
+# (B, T, d_model) a chip of the benchmark's two cells
+# (`datadecide-300m.pretrain-2k`, `olmo-1b.pretrain-2k-fsdp4`), vocab 50304.
+# PR 23 compiled only (8, 1024, 1024), and a 0.5 MiB refusal at d_model 2048
+# became a configuration's `loss_chunk`.
+CELL_LOSS_SHAPES = {"datadecide-300m": (8, 2048, 1024),
+                    "olmo-1b": (4, 2048, 2048)}
+V5E_VMEM = 128 << 20
+
+
+_SCOPED = r'"%sscoped_memory_configs":\[\{"memory_space":"1","offset":"\d+",' \
+          r'"size":"(\d+)"'
+
+
+def _assert_loss_kernels(text, n, d):
+    """Three kernels by name; each compiled call carries the scoped VMEM
+    the plan asked for (none: the compiler's default of 16 MiB), uses no
+    more, and an ask is under the chip's."""
+    assert sorted(kernel_names(text)) == ["xent_de", "xent_dx", "xent_fwd"]
+    plan = fused_xent._plan(n, 50304, d)
+    assert plan.block_v >= 384
+    calls = "\n".join(line for line in text.splitlines()
+                      if 'custom_call_target="tpu_custom_call"' in line)
+    asked = [int(m) for m in re.findall(_SCOPED % "", calls)]
+    used = [int(m) for m in re.findall(_SCOPED % "used_", calls)]
+    assert asked == ([plan.vmem_limit] * 3 if plan.vmem_limit else [])
+    assert len(used) == 3
+    assert max(used) <= (plan.vmem_limit or 16 << 20) < V5E_VMEM
+
+
+@pytest.mark.parametrize("cell", sorted(CELL_LOSS_SHAPES))
+def test_fused_xent_compiles_at_the_cells_shapes(topo, cell):
+    """The loss's forward and gradient at each cell's shape a chip."""
+    b, t, d = CELL_LOSS_SHAPES[cell]
+    text = compiled_text(topo, _xent_grad, ((b, t, d), BF16),
+                         ((50304, d), BF16), ((b, t), I32))
+    _assert_loss_kernels(text, b * t, d)
+
+
+def test_fused_xent_compiles_at_olmo_1b_under_fsdp4(topo):
+    """`olmo-1b.pretrain-2k-fsdp4`'s loss as the cell runs it: B=16 over
+    `MeshSpec(fsdp=4)`, the embedding whole on every chip."""
+    mesh = MeshSpec(fsdp=4).build(topo.devices)
+    b, t, d = CELL_LOSS_SHAPES["olmo-1b"]
+
+    def grad(x, embed, targets):
+        return jax.grad(lambda x, e: jnp.mean(fused_softmax_xent(
+            x, e, targets, impl="pallas", mesh=mesh)),
+            argnums=(0, 1))(x, embed)
+    batch = PartitionSpec(("data", "fsdp"))
+    args = [jax.ShapeDtypeStruct(shape, dtype,
+                                 sharding=NamedSharding(mesh, spec))
+            for shape, dtype, spec in (
+                ((4 * b, t, d), BF16, batch),
+                ((50304, d), BF16, PartitionSpec()),
+                ((4 * b, t), I32, batch))]
+    text = jax.jit(grad).lower(*args).compile().as_text()
+    _assert_loss_kernels(text, b * t, d)
 
 
 def _flash_grad(q, k, v):
