@@ -2,6 +2,7 @@
 
 from __future__ import annotations
 
+import importlib
 import os
 
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -12,15 +13,19 @@ class BenchFailure(RuntimeError):
     """The run cannot produce a result: exit non-zero, print none."""
 
 
-def gpt_kwargs(config: dict) -> dict:
-    """The configuration file's published keys as `GPTConfig` arguments."""
+def gpt_kwargs(config: dict, driver: str = "train") -> dict:
+    """The configuration file's published keys as `GPTConfig` arguments,
+    for a driver that is about to build one; what `models/gpt.py` cannot
+    run is that driver's `BenchFailure`. (The arithmetic of a cell does
+    not pass through here: a configuration names its own, `run.collect`.)"""
     heads = config["num_attention_heads"]
+    cannot = f"the {driver} driver builds a models/gpt.py GPTConfig, which "
     if config["num_key_value_heads"] != heads:
-        raise BenchFailure("the program has full multi-head attention only")
+        raise BenchFailure(cannot + "has full multi-head attention only")
     if config["head_dim"] * heads != config["hidden_size"]:
-        raise BenchFailure("the program derives head_dim as d_model/heads")
+        raise BenchFailure(cannot + "derives head_dim as d_model/heads")
     if not config["tie_word_embeddings"]:
-        raise BenchFailure("the program ties its embedding")
+        raise BenchFailure(cannot + "ties its embedding")
     return {"vocab_size": config["vocab_size"],
             "d_model": config["hidden_size"],
             "n_layers": config["num_hidden_layers"],
@@ -29,8 +34,42 @@ def gpt_kwargs(config: dict) -> dict:
             "max_seq_len": config["max_position_embeddings"]}
 
 
-def widths_for_arith(config: dict) -> dict:
-    return {**gpt_kwargs(config), "head_dim": config["head_dim"]}
+def merged(base: dict, over: dict) -> dict:
+    """`base` with `over` laid on it, nested dicts key by key: how a
+    configuration's `tiny` and `control` blocks and a mix's `tiny` block
+    apply."""
+    out = dict(base)
+    for k, v in over.items():
+        out[k] = (merged(out[k], v)
+                  if isinstance(v, dict) and isinstance(out.get(k), dict)
+                  else v)
+    return out
+
+
+ENTRY = {"config": "models.gpt:GPTConfig",
+         "trainer": "train.spmd:make_gpt_trainer",
+         "loss": "train.spmd:gpt_loss_fn"}
+
+
+def entry_point(config: dict, what: str):
+    """The program's `what` ("config", "trainer", "loss") for this
+    configuration: `module:attribute` under `ray_tpu`, named by the file's
+    `program.entry` where it is not `models/gpt.py`'s."""
+    module, _, attr = config["program"].get("entry", {}).get(
+        what, ENTRY[what]).partition(":")
+    return getattr(importlib.import_module(f"ray_tpu.{module}"), attr)
+
+
+def model_config(config: dict, driver: str, **more):
+    """The program's model configuration object: the entry point
+    `config`, called with the arguments the file's `program.constructor`
+    maps from its published keys ({argument: key}); without one,
+    `gpt_kwargs`, which refuses what `models/gpt.py` cannot run."""
+    mapping = config["program"].get("constructor")
+    kwargs = (gpt_kwargs(config, driver) if mapping is None
+              else {arg: config[key] for arg, key in mapping.items()})
+    return entry_point(config, "config")(
+        **kwargs, **config["program"]["model"], **more)
 
 
 def program_seed(seed: int) -> int:

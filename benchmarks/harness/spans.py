@@ -14,9 +14,10 @@ program itself wrote into the profiler's trace (PERF.md, section 3).
 
 `trace.reduce` keeps its job (busy time, ops by shape, modules,
 collectives); the readers of the named metrics call `summary(ctx)` here.
-The driver does not hand the trace's path on, so it is found where
-`train_cell.py` writes it: `<checkout>/.bench_scratch/<pid>/trace`, which
-`run.py` keeps until every metric is read. Parsed once a process.
+The trace is found where the drivers write it (`train_cell.py` in this
+process, `serve_cell.py`'s replica in its own):
+`<checkout>/.bench_scratch/<pid of run.py>/trace`, which `run.py` keeps
+until every metric is read. Parsed once a process.
 
 A trace of a program without names or spans (the parent of the PR that
 added them) gives empty tables, and the readers then return nothing.
@@ -121,7 +122,7 @@ def reduce(path: str) -> dict:
 
 
 def trace_dir() -> str:
-    """Where `train_cell.py` writes this process's trace."""
+    """Where the drivers write this run's trace."""
     return os.path.join(common.ROOT, ".bench_scratch", str(os.getpid()),
                         "trace")
 
@@ -140,10 +141,21 @@ def summary(ctx: dict) -> dict | None:
     return _cache[path]
 
 
+def idle_gaps(ctx: dict, top: int = 10) -> list:
+    """`breakdown.idle_gaps`: chip 0's idle seconds by the program span
+    that owned them, the largest first."""
+    s = summary(ctx)
+    if not s:
+        return []
+    return [[n, v] for n, v in sorted(s["idle_owners"].items(),
+                                      key=lambda kv: -kv[1])[:top]]
+
+
 def kernel_seconds(s: dict, names) -> tuple[int, float] | None:
-    """(calls, self seconds) over the named kernels; None unless every
-    one of them ran (a program without the names has none of them)."""
-    if not all(n in s["kernels"] for n in names):
+    """(calls, self seconds) over those of the named kernels that ran;
+    None where none of them did (a fused kernel may stand for two of the
+    names: whichever ran are the whole)."""
+    ran = [s["kernels"][n] for n in names if n in s["kernels"]]
+    if not ran:
         return None
-    return (sum(s["kernels"][n][0] for n in names),
-            sum(s["kernels"][n][1] for n in names))
+    return sum(k[0] for k in ran), sum(k[1] for k in ran)

@@ -1,7 +1,8 @@
 """From a profiler trace (`.xplane.pb`) to numbers: device busy time, time
-per operation, idle gaps named by what the host was doing. Kept with the
-benchmark so every PR reduces its trace the same way; checked against a
-small recorded v5e trace in `benchmarks/tests/`.
+per operation and per program, collectives. Kept with the benchmark so
+every PR reduces its trace the same way; checked against small recorded
+v5e traces in `benchmarks/tests/`. Who owned the idle gaps is
+`spans.py`'s: the program's own spans name them.
 
 What a v5e trace holds (looked at by hand, PR 23): one plane per chip,
 `/device:TPU:<n>`. Its line `XLA Modules` has one event per executed
@@ -14,8 +15,8 @@ times. A Pallas kernel is a `custom-call` with
 kernel's Python name, so a kernel is known by its shapes:
 `pallas <results> <- <operands>`. `Async XLA Ops` holds copies and
 collectives that overlap compute. The plane `/host:CPU` has one line per
-host thread, among them `python`: spans `$file.py:line function` from the
-Python tracer, and any `TraceAnnotation`. One clock, nanoseconds.
+host thread holding every `TraceAnnotation` (and, were the Python tracer
+on, spans `$file.py:line function`). One clock, nanoseconds.
 """
 
 from __future__ import annotations
@@ -27,28 +28,24 @@ import os
 import re
 import shutil
 
-import numpy as np
 
 DEVICE_PLANE = re.compile(r"^/device:TPU:(\d+)$")
 OPS_LINE, ASYNC_LINE, MODULES_LINE = "XLA Ops", "Async XLA Ops", "XLA Modules"
 WINDOW_SPAN = "bench/window"
 COLLECTIVE = re.compile(
     r"^(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)")
-HOST_SPAN = re.compile(r"^(?:\$(?:\S*/)?(\w+\.py):\d+ (\w+)|bench/(\S+))$")
-# Files whose functions may own a device-idle gap: the program's hot
-# paths and the benchmark's drivers.
-OWN_FILES = ("loop.py", "spmd.py", "train_cell.py", "traffic.py", "gpt.py")
 SHORT_GAP_NS = 20_000
 _SHAPE = re.compile(r"(\w+\[[\d,]*\])")
 
 
 def start(trace_dir: str) -> None:
-    """With the Python tracer on: its spans name the idle gaps by the
-    program's functions."""
+    """Python tracer off: the program's own spans own every idle gap
+    (`idle_owned_share` 100 % in both training cells, ledger PR 24-29),
+    and the tracer slows the host it watches."""
     import jax
     shutil.rmtree(trace_dir, ignore_errors=True)
     options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 1
+    options.python_tracer_level = 0
     options.host_tracer_level = 2
     jax.profiler.start_trace(trace_dir, profiler_options=options)
 
@@ -181,26 +178,18 @@ def reduce(path: str, top: int = 10) -> dict:
     collective_s, collective_exposed_s   per chip: collective time, and the
                    part of it during which no other op ran on that chip
     device_ops     the `top` ops by self seconds: [[name, seconds], ...]
-    idle_gaps      the `top` host owners of chip 0's idle time
     """
     from jax.profiler import ProfileData
     data = ProfileData.from_file(path)
     planes = {p.name: p for p in data.planes}
     chips = sorted((int(DEVICE_PLANE.match(n).group(1)), p)
                    for n, p in planes.items() if DEVICE_PLANE.match(n))
-    host_spans, window = [], None
+    window = None
     if "/host:CPU" in planes:
         for line in planes["/host:CPU"].lines:
             for ev in line.events:
-                m = HOST_SPAN.match(ev.name)
-                if not m:
-                    continue
-                s, e = ev.start_ns, ev.start_ns + ev.duration_ns
                 if ev.name == WINDOW_SPAN:
-                    window = (s, e)
-                elif m.group(3) or m.group(1) in OWN_FILES:
-                    host_spans.append(
-                        (s, e, m.group(3) or f"{m.group(1)}:{m.group(2)}"))
+                    window = (ev.start_ns, ev.start_ns + ev.duration_ns)
 
     per_chip, inventory = [], {}
     for _, plane in chips:
@@ -220,7 +209,7 @@ def reduce(path: str, top: int = 10) -> dict:
     empty = {"chips": len(per_chip), "lines": inventory,
              "window_s": 0.0, "busy_s": 0.0,
              "collective_s": 0.0, "collective_exposed_s": 0.0, "ops": {},
-             "modules": {}, "device_ops": [], "idle_gaps": []}
+             "modules": {}, "device_ops": []}
     if not any(ops for ops, _, _ in per_chip):
         return empty
     if window is None:
@@ -264,20 +253,6 @@ def reduce(path: str, top: int = 10) -> dict:
                 mod_runs[n].append((e - s) * ns)
     n_chips = len(per_chip)
 
-    owners = collections.defaultdict(float)
-    ops0 = [(s, e) for s, e, _ in _clip(per_chip[0][0], lo, hi)]
-    host_spans.sort(key=lambda t: t[1] - t[0])        # innermost first
-    starts = np.array([t[0] for t in host_spans], np.float64)
-    ends = np.array([t[1] for t in host_spans], np.float64)
-    for s, e in _gaps(ops0, lo, hi):
-        if e - s < SHORT_GAP_NS:
-            owners["between queued ops (<20us)"] += (e - s) * ns
-            continue
-        mid = (s + e) / 2
-        hit = np.nonzero((starts <= mid) & (ends > mid))[0]
-        owners[host_spans[hit[0]][2] if hit.size
-               else "no span of the program"] += (e - s) * ns
-
     def med(xs):
         return sorted(xs)[len(xs) // 2]
 
@@ -293,8 +268,6 @@ def reduce(path: str, top: int = 10) -> dict:
         "modules": {n: [len(v), sum(v), med(v)]
                     for n, v in mod_runs.items()},
         "device_ops": [[n, v[1]] for n, v in ranked[:top]],
-        "idle_gaps": [[n, v] for n, v in sorted(
-            owners.items(), key=lambda kv: -kv[1])[:top]],
     }
 
 

@@ -1,29 +1,28 @@
 """Flash attention's share of its compute roofline, from the trace.
 
-The kernels are known by their shapes (the trace carries no kernel
-name): every operand of a flash call is `bf16[B*H, T, D']` with B the
-sequences on one chip and D' the head size the kernel pads to. Forward
-takes 3 operands; dQ takes 6 and returns one array, dK/dV returns two.
-Required work is counted per dQ call (one per layer per step per chip);
-the time is that of every flash call, the forward's rematerialised
-second run included.
-"""
+The time is that of every call of the kernels named `flash_fwd`,
+`flash_dq` and `flash_dkv` (whichever of them ran: a fused backward
+kernel keeps one of the names; a rematerialised forward is `flash_fwd`
+too). The required work is one causal forward + dQ + dK/dV pass per
+layer per optimizer step per chip: the fused dispatch's runs on all
+chips x steps a dispatch x layers. Neither depends on an operand's
+layout or on how many heads a kernel block holds."""
 
-from benchmarks.harness import arith, trace as trace_mod
+from benchmarks.harness import spans
+
+KERNELS = ("flash_fwd", "flash_dq", "flash_dkv")
 
 
-def read(ctx):
-    trace, mix, w = ctx.get("trace"), ctx["traffic"], ctx["widths"]
-    if not trace:
+def read(ctx, module: str = "jit_multi"):
+    s, mix, w = spans.summary(ctx), ctx["traffic"], ctx["widths"]
+    if not s or module not in ctx["trace"]["modules"]:
         return None
-    local = mix["batch"] // ctx["cell"]["chips"]
-    dims = rf"bf16\[{local * w['n_heads']},{mix['seq_len']},\d+\]"
-    flash = rf"/pallas [^<]*<- {dims},{dims},{dims}(,|$)"
-    dq = rf"/pallas {dims} <- ({dims},){{4}}"
-    _, seconds = trace_mod.op_seconds(trace, flash)
-    dq_calls, _ = trace_mod.op_seconds(trace, dq)
-    if not dq_calls or seconds <= 0:
+    found = spans.kernel_seconds(s, KERNELS)
+    if found is None or found[1] <= 0:
         return None
-    need = dq_calls * arith.flash_attention_flops(
-        local, mix["seq_len"], w["n_heads"], w["head_dim"], layers=1)
-    return 100.0 * need / ctx["peaks"]["flops_per_s"] / seconds
+    passes = (ctx["trace"]["modules"][module][0] * mix["unroll"]
+              * w["n_layers"])
+    need = passes * ctx["arith"].flash_attention_flops(
+        mix["batch"] // ctx["cell"]["chips"], mix["seq_len"], w["n_heads"],
+        w["head_dim"], layers=1)
+    return 100.0 * need / ctx["peaks"]["flops_per_s"] / found[1]

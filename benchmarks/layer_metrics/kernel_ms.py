@@ -1,13 +1,16 @@
-"""Self time of named Pallas kernels per optimizer step per chip, on the
-basis of `train_step_device_ms`: the kernels' seconds on all chips over
-the fused dispatch's runs on all chips x steps a dispatch."""
+"""Self time of named Pallas kernels per run of a program per chip: the
+kernels' seconds on all chips over the program's runs on all chips x
+steps a run (`train_step_device_ms`'s basis for the fused train
+dispatch; a decode step for `jit__decode`). Whichever of the listed
+kernels ran are summed: a fused kernel that stands for two of the names
+still gives the metric; nothing only where none of them ran."""
 
 from benchmarks.harness import spans
 from benchmarks.layer_metrics._stats import lookup
 
 
 def read(ctx, kernels: list, module: str = "jit_multi",
-         steps: str = "traffic.unroll"):
+         steps: str | int = "traffic.unroll"):
     s = spans.summary(ctx)
     if not s or module not in ctx["trace"]["modules"]:
         return None
@@ -15,4 +18,5 @@ def read(ctx, kernels: list, module: str = "jit_multi",
     if found is None:
         return None
     runs = ctx["trace"]["modules"][module][0]
-    return found[1] * 1e3 / (runs * lookup(ctx, steps))
+    per_run = lookup(ctx, steps) if isinstance(steps, str) else steps
+    return found[1] * 1e3 / (runs * per_run)

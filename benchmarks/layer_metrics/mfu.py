@@ -1,12 +1,11 @@
 """Model FLOP/s utilization of the untraced timed window."""
 
-from benchmarks.harness import arith
-
 
 def read(ctx):
     train = ctx["stats"].get("train")
     if not train:
         return None
-    need = arith.train_flops_per_token(ctx["widths"], train["seq_len"])
+    need = ctx["arith"].train_flops_per_token(ctx["widths"],
+                                              train["seq_len"])
     return (100.0 * train["train_tokens_per_s"] * need
             / (train["chips"] * ctx["peaks"]["flops_per_s"]))

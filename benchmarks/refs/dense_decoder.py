@@ -13,6 +13,11 @@ multiplication and both the program's (see the configuration files):
 learned positions instead of rotary ones, RMSNorm with a scale instead
 of the non-parametric LayerNorm.
 
+Every function takes the configuration file's data and reads the sizes
+it needs from the published keys; `init_params` makes seeded weights in
+that layout (the scales of `gpt.init_params`, copied), so a serving cell
+compares the program with nothing the program made.
+
 On a TPU a float32 matmul runs in reduced precision unless the highest
 precision is asked for, so callers wrap these in
 `jax.default_matmul_precision("highest")`.
@@ -30,6 +35,36 @@ def rms_norm(x, scale):
     return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + EPS) * scale
 
 
+def init_params(key, config: dict):
+    """float32 weights from `key`, in one traceable call."""
+    d, f = config["hidden_size"], config["intermediate_size"]
+    h = config["num_attention_heads"] * config["head_dim"]
+    n = config["num_hidden_layers"]
+    ks = jax.random.split(key, 9)
+
+    def normal(k, shape, scale):
+        return jax.random.normal(k, shape, jnp.float32) * scale
+
+    residual = 1.0 / jnp.sqrt(2.0 * n)
+    return {
+        "embed": normal(ks[0], (config["vocab_size"], d), 0.02),
+        "pos_embed": normal(ks[1], (config["max_position_embeddings"], d),
+                            0.01),
+        "final_ln_scale": jnp.ones((d,), jnp.float32),
+        "layers": {
+            "ln1_scale": jnp.ones((n, d), jnp.float32),
+            "ln2_scale": jnp.ones((n, d), jnp.float32),
+            "wq": normal(ks[2], (n, d, h), d ** -0.5),
+            "wk": normal(ks[3], (n, d, h), d ** -0.5),
+            "wv": normal(ks[4], (n, d, h), d ** -0.5),
+            "wo": normal(ks[5], (n, h, d), h ** -0.5 * residual),
+            "w_up": normal(ks[6], (n, d, f), d ** -0.5),
+            "w_gate": normal(ks[7], (n, d, f), d ** -0.5),
+            "w_down": normal(ks[8], (n, f, d), f ** -0.5 * residual),
+        },
+    }
+
+
 def block(x, lp, n_heads: int):
     b, t, d = x.shape
     h = rms_norm(x, lp["ln1_scale"])
@@ -45,8 +80,9 @@ def block(x, lp, n_heads: int):
     return x + (jax.nn.silu(h @ lp["w_gate"]) * (h @ lp["w_up"])) @ lp["w_down"]
 
 
-def logits(params, tokens, n_heads: int):
+def logits(params, tokens, config: dict):
     """tokens [B, T] -> float32 logits [B, T, V]."""
+    n_heads = config["num_attention_heads"]
     p = jax.tree.map(lambda a: a.astype(jnp.float32), params)
     t = tokens.shape[1]
     x = p["embed"][tokens] + p["pos_embed"][:t][None]
@@ -59,13 +95,19 @@ def logits(params, tokens, n_heads: int):
     return x @ p["embed"].T
 
 
-def token_logprobs(params, tokens, n_heads: int):
+def token_logprobs(params, tokens, config: dict):
     """log p(tokens[:, i+1] | tokens[:, :i+1]) for every i: [B, T-1]."""
-    lp = jax.nn.log_softmax(logits(params, tokens[:, :-1], n_heads), -1)
+    lp = jax.nn.log_softmax(logits(params, tokens[:, :-1], config), -1)
     return jnp.take_along_axis(lp, tokens[:, 1:, None], -1)[..., 0]
 
 
-def loss(params, inputs, targets, n_heads: int):
+def sequence_losses(params, inputs, targets, config: dict):
+    """Mean next-token cross entropy of each sequence: [B]."""
+    lp = jax.nn.log_softmax(logits(params, inputs, config), -1)
+    return -jnp.mean(jnp.take_along_axis(lp, targets[..., None], -1)[..., 0],
+                     axis=-1)
+
+
+def loss(params, inputs, targets, config: dict):
     """Mean next-token cross entropy over pre-shifted inputs/targets."""
-    lp = jax.nn.log_softmax(logits(params, inputs, n_heads), -1)
-    return -jnp.mean(jnp.take_along_axis(lp, targets[..., None], -1))
+    return jnp.mean(sequence_losses(params, inputs, targets, config))

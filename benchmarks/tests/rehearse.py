@@ -8,12 +8,11 @@ import sys
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+os.environ.setdefault("RAY_TPU_NUM_TPUS", "1")      # a serving cell's chip
 ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 sys.path.insert(0, ROOT)
 os.environ["PYTHONPATH"] = ROOT + os.pathsep + os.environ.get("PYTHONPATH", "")
-
-import importlib  # noqa: E402
 
 from benchmarks import run as bench_run  # noqa: E402
 
@@ -22,17 +21,17 @@ def main() -> int:
     with open(sys.argv[1]) as f:
         spec = json.load(f)
     cell, config, mix = spec["cell"], spec["config"], spec["mix"]
-    driver = importlib.import_module(
-        f"benchmarks.harness.{mix['driver']}_cell")
-    out = driver.run(cell, config, mix, seed=2**31 + 5, seconds=4.0,
-                     trace=spec["trace"], platform="cpu",
-                     scratch=spec["scratch"])
+    out = bench_run.drive(cell, config, mix, seed=2**31 + 5, seconds=4.0,
+                          trace=spec["trace"], platform="cpu",
+                          scratch=spec["scratch"])
     metrics = bench_run.collect(
         spec["bench"], cell, config, mix, out, seconds=4.0,
         trace=spec["trace"], setup_s=1.0,
         peak={"flops_per_s": 1.0, "hbm_bytes_per_s": 1.0})
     result = {k: out[k] for k in ("correct", "attempted", "failed",
-                                  "device", "problems")}
+                                  "device", "problems", "checks")}
+    result["stats"] = {k: v for k, v in out["stats"].items()
+                       if k != "setup_parts"}
     print(json.dumps({"result": result, "metrics": metrics}))
     return 0
 

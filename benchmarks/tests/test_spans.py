@@ -119,6 +119,110 @@ def test_readers_on_the_named_trace(monkeypatch):
     assert 0 < got["host_batch_share"] < 100
 
 
+def test_flash_roofline_by_name_reads_what_the_shapes_read(monkeypatch):
+    """`flash_roofline` takes its seconds by kernel name and its passes
+    from the dispatch's runs x steps x layers; on a program whose
+    operands still have the shapes PR 23's reader matched, both give the
+    same number."""
+    from benchmarks.harness import arith
+    ctx = ctx_for(NAMED, monkeypatch)
+    ctx.update(traffic={"unroll": UNROLL, "batch": 2, "seq_len": 512},
+               widths={"n_heads": 2, "head_dim": 128, "n_layers": LAYERS},
+               arith=arith, peaks={"flops_per_s": 197e12})
+    dims = r"bf16\[4,512,128\]"
+    _, seconds = trace.op_seconds(
+        ctx["trace"], rf"/pallas [^<]*<- {dims},{dims},{dims}(,|$)")
+    dq_calls, _ = trace.op_seconds(
+        ctx["trace"], rf"/pallas {dims} <- ({dims},){{4}}")
+    by_shape = 100.0 * dq_calls * arith.flash_attention_flops(
+        2, 512, 2, 128, layers=1) / 197e12 / seconds
+    got = bench_run.read_layer_metric("flash_roofline", ctx)
+    assert got == pytest.approx(by_shape, rel=1e-9) and 0 < got < 100
+
+
+def test_kernel_ms_sums_whichever_of_its_kernels_ran(monkeypatch):
+    """A fused dX + dE kernel would keep one of the names: the metric is
+    what ran, and nothing only where none of the names did."""
+    ctx = ctx_for(NAMED, monkeypatch)
+    from benchmarks.layer_metrics import kernel_ms
+    three = kernel_ms.read(ctx, ["xent_fwd", "xent_dx", "xent_de"])
+    two = kernel_ms.read(ctx, ["xent_fwd", "xent_dx", "xent_dx_de_fused"])
+    assert 0 < two < three
+    assert kernel_ms.read(ctx, ["xent_dx_de_fused"]) is None
+    assert kernel_ms.read(ctx, ["xent_fwd"], steps=1) == pytest.approx(
+        UNROLL * kernel_ms.read(ctx, ["xent_fwd"]))
+
+
+def test_idle_gaps_are_named_by_the_program_s_spans(monkeypatch):
+    monkeypatch.setattr(spans, "summary", lambda ctx: spans.reduce(NAMED))
+    gaps = spans.idle_gaps({"trace": True})
+    assert gaps and len(gaps) <= 10
+    assert [g[1] for g in gaps] == sorted((g[1] for g in gaps),
+                                          reverse=True)
+    assert {g[0] for g in gaps} <= set(TRAIN_SPANS) | {spans.NO_SPAN,
+                                                       spans.SHORT}
+
+
+# -- a trace recorded inside a serving replica -------------------------------
+
+SERVE = os.path.join(HERE, "data", "v5e_serve.xplane.pb")
+SERVE_LAYERS = 2
+ENGINE_SPANS = {"engine/tick", "engine/admit", "engine/prefill_chunk",
+                "engine/decode_build", "engine/decode_dispatch",
+                "engine/token_sync", "engine/emit", "stream/wait",
+                "stream/reply"}
+
+
+def test_serving_readers_on_a_trace_recorded_inside_a_replica(monkeypatch):
+    """`data/v5e_serve.xplane.pb`: `benchmarks/tools/record_trace.py
+    --workload olmo-1b.chat-closed64` on a v5e in PR 30: the cell's driver
+    at the size of the `tiny` blocks (2 layers, d_model 64, 4 slots, 8
+    closed-loop clients), 0.08 s traced inside the replica's process,
+    Python tracer off. Every serving metric read from a trace finds its
+    spans, programs and kernels there."""
+    from benchmarks.harness import arith, peaks
+    named = spans.reduce(SERVE)
+    assert ENGINE_SPANS <= set(named["spans"])
+    assert {"paged_decode", "paged_mq"} <= set(named["kernels"])
+    ctx = ctx_for(SERVE, monkeypatch)
+    modules = ctx["trace"]["modules"]
+    assert {"jit__decode", "jit__prefill"} <= set(modules)
+    # one kernel call a layer a decode step (steps cut by the window's
+    # edges are in the kernels' count and not in the modules')
+    steps = modules["jit__decode"][0]
+    assert 0 <= named["kernels"]["paged_decode"][0] \
+        - SERVE_LAYERS * steps <= 2 * SERVE_LAYERS
+    ctx.update(stats={"serve": {"decoding_context_tokens": 120.0},
+                      "engine": {"kv_bytes_per_token": 1024.0}},
+               arith=arith, peaks=peaks.peaks_for("TPU v5 lite"))
+    by_trace = [m["name"] for m in BENCH["per_layer"]
+                if "olmo-1b.chat-closed64" in m.get("workloads", ())
+                and m["source"] == "device_trace"]
+    got = {n: bench_run.read_layer_metric(n, ctx) for n in by_trace}
+    assert len(got) >= 9 and all(v is not None for v in got.values()), got
+    assert 0 < got["paged_decode_ms"] < got["decode_step_device_ms"] \
+        < got["engine_tick_ms"]
+    assert got["prefill_chunk_device_ms"] > 0
+    for share in ("tick_host_share", "prefill_tick_share",
+                  "serve_idle_owned_share", "paged_decode_roofline"):
+        assert 0 < got[share] <= 100, (share, got[share])
+    assert got["stream_wait_ms"] > 0
+    # the bytes are the benchmark's own count
+    step_s = named["kernels"]["paged_decode"][1] / steps
+    assert got["paged_decode_roofline"] == pytest.approx(
+        100 * 120.0 * 1024.0 / 819e9 / step_s)
+    gaps = spans.idle_gaps(ctx)
+    assert gaps and {g[0] for g in gaps} <= ENGINE_SPANS | {
+        "engine/submit", spans.NO_SPAN, spans.SHORT}
+    # a training trace has none of it: every serving reader returns nothing
+    ctx = ctx_for(NAMED, monkeypatch)
+    ctx.update(stats={}, arith=arith, peaks={"hbm_bytes_per_s": 819e9})
+    left = {n: bench_run.read_layer_metric(n, ctx) for n in by_trace}
+    assert left == dict.fromkeys(by_trace) or set(
+        n for n, v in left.items() if v is not None) <= {
+            "serve_idle_owned_share"}
+
+
 def test_readers_return_nothing_for_a_program_without_names(monkeypatch):
     """The trace PR 23 recorded: kernels named after their scopes, no
     program span. Every new reader leaves its metric out; none raises."""
