@@ -1,0 +1,39 @@
+"""The interface between `serve/engine.py` and the model families it can
+serve. A family's module builds one `ServingFamily` from its own functions
+and its configuration object returns it as `cfg.family`; the engine and
+every family import this module and none imports another family for it."""
+
+from __future__ import annotations
+
+from typing import Callable, NamedTuple
+
+
+class ServingFamily(NamedTuple):
+    """What `serve/engine.py` asks of a model family, found as the
+    `family` of the configuration object it is given. The pool is a dict
+    of arrays, any number of kinds, with the blocks on axis 1 of each.
+
+    init_pool(cfg, n_blocks, block_size, mesh) -> pool
+    prefill(params, tokens [1, C], pool, cfg, mesh, *, block_table,
+            start, length) -> (logits [1, V] f32, pool, counts)
+    decode(params, tokens [B], pool, pos, tables, cfg, mesh)
+            -> (logits [B, V] f32, pool, counts)
+    copy_block(pool, src, dst), gather_block(pool, idx),
+    scatter_block(pool, block, idx): over every array of the pool
+    verify(params, tokens [B, W], pool, pos, tables, cfg, mesh)
+            -> (logits [B, W, V] f32, pool): speculative decoding; a
+            family without it cannot be given `spec=`
+    quantize(params) -> params, for `cfg.weight_dtype == "int8"`
+    counts(cfg, totals) -> {name: number}: what the int32 vector that
+            prefill and decode return third, summed over a window, adds
+            to `stats()`; None where they return None
+    """
+    init_pool: Callable
+    prefill: Callable
+    decode: Callable
+    copy_block: Callable
+    gather_block: Callable
+    scatter_block: Callable
+    verify: Callable | None = None
+    quantize: Callable | None = None
+    counts: Callable | None = None

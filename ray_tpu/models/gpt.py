@@ -30,6 +30,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from ray_tpu.models.family import ServingFamily
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention
 
 
@@ -114,6 +115,10 @@ class GPTConfig:
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
 
+    @property
+    def family(self) -> ServingFamily:
+        return FAMILY
+
 
 def small(**kw) -> GPTConfig:
     return GPTConfig(**{**dict(vocab_size=512, d_model=128, n_layers=2,
@@ -175,9 +180,9 @@ def init_params(rng, cfg: GPTConfig):
     }
 
 
-def _rms_norm(x, scale):
+def _rms_norm(x, scale, eps: float = 1e-6):
     var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
+    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
 # ---------------------------------------------------------------------------
@@ -784,6 +789,21 @@ def verify_step_paged(params, tokens, cache, pos, tables,
     logits = jnp.einsum("bwd,vd->bwv", x, params["embed"].astype(adt),
                         preferred_element_type=jnp.float32)
     return logits, cache
+
+
+def _prefill_family(*args, **kw):
+    return (*prefill_paged(*args, **kw), None)
+
+
+def _decode_family(*args, **kw):
+    return (*decode_step_paged(*args, **kw), None)
+
+
+FAMILY = ServingFamily(
+    init_pool=init_kv_pool, prefill=_prefill_family, decode=_decode_family,
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, verify=verify_step_paged,
+    quantize=quantize_params)
 
 
 def num_params(params) -> int:

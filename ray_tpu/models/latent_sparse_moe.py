@@ -1,0 +1,672 @@
+"""Decoder with latent attention, learned sparse selection and routed
+experts, on the serving path.
+
+Three mechanisms in one layer, none of which `models/gpt.py` has:
+
+- **Latent attention.** Queries pass through a low-rank bottleneck; keys
+  and values are rebuilt per head from one compressed vector a token
+  (`c_kv`, `kv_rank` wide) plus one rotary key shared by all heads
+  (`k_rope`). The cache holds that row, `kv_rank + rope_dim` values a
+  token a layer, and nothing per head. Prefill rebuilds keys and values
+  from the rows (expanded heads); a decode step never does: the query's
+  no-position part goes through the key up-projection once
+  (`q_nope W_uk`), is scored against `c_kv` itself, and the weighted sum
+  of `c_kv` rows goes through the value up-projection (absorbed form: the
+  row is read once for all heads).
+- **Learned sparse attention.** A layer that owns an indexer
+  (`indexer_types` "full") scores every earlier position with a small
+  multi-head ReLU scorer over a cached index key (`index_dim` values a
+  token) and attention reads the `index_topk` best only, exactly. A
+  "shared" layer reads the selection the nearest "full" layer before it
+  made in the same step; a selection is never recomputed and never kept
+  across steps.
+- **Routed experts, this chip's share.** A sigmoid router over the
+  published width chooses `experts_per_token`; the chip holds experts
+  `held_from .. held_from + held_count - 1` and computes their part,
+  dropless, plus the shared expert every chip computes alike. What the
+  absent experts would add is left out (no code stands in for them).
+
+The pool is two kinds of state under one block table: `"latent"` uint32
+`[L, n_blocks, block_size, 1, words]` (`ops/sparse_latent.py`'s row format)
+for every layer and `"index"` `[L_full, n_blocks, block_size, index_dim]`
+for the layers that own an indexer. Blocks are axis 1 of both, so
+`gpt.copy_block` / `gather_block` / `scatter_block` move them together.
+
+Rotary positions are interleaved pairs. The dense layer and the shared
+expert are `gpt._gated_mlp`; the norms `gpt._rms_norm`. Parameters: the
+tree `benchmarks/refs/latent_sparse_moe.py` documents (a list of layer
+dicts; layers differ, so they are not stacked), leaves of any float type,
+cast at use.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import gpt
+from ray_tpu.models.family import ServingFamily
+from ray_tpu.ops import grouped_experts, sparse_latent
+
+NEG_INF = sparse_latent.NEG_INF
+CONTEXT_BLOCK = 1024        # cached positions a step of the prefill loops
+
+# what the prefill and decode programs count, in the order of the int32
+# vector they return beside the logits; the held experts' loads follow
+COUNTS = ("index_scanned_tokens", "index_selected_tokens",
+          "index_layer_runs", "index_layer_reuses",
+          "expert_tokens_here", "expert_tokens_routed")
+
+
+@dataclass(frozen=True)
+class LatentSparseMoEConfig:
+    vocab_size: int = 512
+    d_model: int = 64
+    n_layers: int = 3
+    n_heads: int = 4
+    q_rank: int = 32
+    kv_rank: int = 32
+    nope_dim: int = 16
+    rope_dim: int = 8
+    v_dim: int = 16
+    index_heads: int = 4
+    index_dim: int = 16
+    index_topk: int = 16
+    # one entry a published layer; the layers that run are
+    # [first_layer, first_layer + n_layers)
+    indexer_types: tuple = ("full", "shared", "full")
+    mlp_types: tuple = ("dense", "sparse", "sparse")
+    first_layer: int = 0
+    d_ff: int = 128
+    expert_ff: int = 32
+    shared_experts: int = 1
+    router_width: int = 8
+    experts_per_token: int = 2
+    held_from: int = 0
+    held_count: int = 8
+    routed_scale: float = 2.5
+    norm_topk: bool = True
+    rope_theta: float = 10000.0
+    eps: float = 1e-5
+    max_seq_len: int = 128
+    dtype: str = "bfloat16"
+    # test-only, for the benchmark's control: "int8" rounds a cache row,
+    # as it is written, to the int8 grid of its own largest magnitude
+    # (`ops.quant.quantize_rows`) and keeps that value in the pool's own
+    # element type. An int8 pool's numbers without its bytes: it saves
+    # nothing, and this family has no `kv_dtype` for that reason
+    cache_round: str = "none"        # none | int8
+    sparse_impl: str = "auto"        # auto | pallas | jax (the three ops)
+
+    def __post_init__(self):
+        for name in ("indexer_types", "mlp_types"):
+            object.__setattr__(self, name, tuple(getattr(self, name)))
+        if self.kinds[0][1] != "full":
+            raise ValueError("the first layer run must own an indexer")
+        if self.cache_round not in ("none", "int8"):
+            raise ValueError(f"unknown cache_round {self.cache_round!r}")
+
+    @property
+    def kinds(self) -> tuple:
+        """((mlp type, indexer type), ...) of the layers that run."""
+        lo, hi = self.first_layer, self.first_layer + self.n_layers
+        return tuple(zip(self.mlp_types[lo:hi], self.indexer_types[lo:hi]))
+
+    @property
+    def row_values(self) -> int:
+        return self.kv_rank + self.rope_dim
+
+    @property
+    def row_words(self) -> int:
+        return sparse_latent.row_words(self.row_values,
+                                       self.activation_dtype())
+
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def family(self):
+        return FAMILY
+
+
+def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
+                   q_lora_rank, kv_lora_rank, qk_nope_head_dim,
+                   qk_rope_head_dim, v_head_dim, index_n_heads,
+                   index_head_dim, mlp_layer_types, intermediate_size,
+                   moe_intermediate_size, n_shared_experts, n_routed_experts,
+                   num_experts_per_tok, routed_scaling_factor,
+                   norm_topk_prob, rms_norm_eps, max_position_embeddings,
+                   layers_from=0, experts_held_from=0, published=None,
+                   **same) -> LatentSparseMoEConfig:
+    """The configuration from a published `config.json`'s own keys
+    (`glm_moe_dsa`'s names). `n_routed_experts` is how many experts are
+    held here; the router's width is `published["n_routed_experts"]`
+    where a share is run, else the same number. Keys this module spells
+    as the source does (`vocab_size`, `index_topk`, `indexer_types`,
+    `rope_theta`, `dtype`, ...) pass through."""
+    return LatentSparseMoEConfig(
+        d_model=hidden_size, n_layers=num_hidden_layers,
+        n_heads=num_attention_heads, q_rank=q_lora_rank,
+        kv_rank=kv_lora_rank, nope_dim=qk_nope_head_dim,
+        rope_dim=qk_rope_head_dim, v_dim=v_head_dim,
+        index_heads=index_n_heads, index_dim=index_head_dim,
+        mlp_types=mlp_layer_types, first_layer=layers_from,
+        d_ff=intermediate_size, expert_ff=moe_intermediate_size,
+        shared_experts=n_shared_experts,
+        router_width=(published or {}).get("n_routed_experts",
+                                           n_routed_experts),
+        experts_per_token=num_experts_per_tok, held_from=experts_held_from,
+        held_count=n_routed_experts, routed_scale=routed_scaling_factor,
+        norm_topk=norm_topk_prob, eps=rms_norm_eps,
+        max_seq_len=max_position_embeddings, **same)
+
+
+# ---------------------------------------------------------------------------
+# the pool
+# ---------------------------------------------------------------------------
+
+def init_pool(cfg: LatentSparseMoEConfig, n_blocks: int, block_size: int,
+              mesh=None):
+    """{"latent", "index"}, zero-filled; blocks on axis 1 of both."""
+    if mesh is not None:
+        raise ValueError("this family's pool is not sharded over a mesh")
+    n_full = sum(ix == "full" for _, ix in cfg.kinds)
+    return {
+        "latent": jnp.zeros((cfg.n_layers, n_blocks, block_size, 1,
+                             cfg.row_words), jnp.uint32),
+        "index": jnp.zeros((n_full, n_blocks, block_size, cfg.index_dim),
+                           cfg.activation_dtype()),
+    }
+
+
+def _stored(x, cfg):
+    """A cache row as the pool keeps it (`cfg.cache_round`)."""
+    if cfg.cache_round == "int8":
+        from ray_tpu.ops import quant
+        q, s = quant.quantize_rows(x[..., None, :])
+        x = (q.astype(jnp.float32) * s[..., None])[..., 0, :].astype(x.dtype)
+    return x
+
+
+def _flat_at(pool, layer: int, widx):
+    """A layer's flat indices widx (nb * bs and beyond: dropped) as row
+    numbers of the whole pool, layers and blocks flattened. The pool is
+    written and read as one array of rows, in place when it is donated; a
+    layer is never sliced out of it."""
+    n_layers, nb, bs = pool.shape[:3]
+    return jnp.where(widx < nb * bs, layer * nb * bs + widx,
+                     n_layers * nb * bs)
+
+
+def _write_latent(latent, layer: int, row, widx, cfg):
+    """Cache rows row [N, kv_rank + rope] into a layer of the latent
+    pool."""
+    words = latent.shape[-1]
+    return sparse_latent.write_rows(
+        latent.reshape(-1, 1, words),
+        sparse_latent.pack_rows(row, words), _flat_at(latent, layer, widx),
+        impl=cfg.sparse_impl).reshape(latent.shape)
+
+
+def _write_index(index, layer: int, keys, widx):
+    """Index keys [N, Di] into a layer of the index pool."""
+    di = index.shape[-1]
+    return index.reshape(-1, di).at[_flat_at(index, layer, widx)].set(
+        keys.astype(index.dtype), mode="drop").reshape(index.shape)
+
+
+# ---------------------------------------------------------------------------
+# pieces of the layer
+# ---------------------------------------------------------------------------
+
+def _mm(x, w, adt):
+    return jnp.einsum("...d,df->...f", x, w.astype(adt),
+                      preferred_element_type=jnp.float32).astype(adt)
+
+
+def rope(x, pos, theta: float):
+    """Rotary embedding on the last axis of x [N, ..., d] at positions
+    pos [N], interleaved pairs; float32 inside."""
+    d = x.shape[-1]
+    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
+    ang = pos.astype(jnp.float32)[:, None] * inv
+    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., 0::2], xf[..., 1::2]
+    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
+                     -1).reshape(x.shape).astype(x.dtype)
+
+
+def _norm(x, scale, cfg):
+    return gpt._rms_norm(x, scale.astype(x.dtype), cfg.eps)
+
+
+def _layer_norm(x, scale, bias, cfg):
+    xf = x.astype(jnp.float32)
+    mu = jnp.mean(xf, -1, keepdims=True)
+    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
+    return ((xf - mu) * jax.lax.rsqrt(var + cfg.eps)
+            * scale.astype(jnp.float32)
+            + bias.astype(jnp.float32)).astype(x.dtype)
+
+
+def _project(h, lp, pos, cfg):
+    """Normed h [N, D] at positions pos [N] -> (q_nope [N, H, nope],
+    q_rope [N, H, rope], row [N, kv_rank + rope]: the cache row
+    `[c_kv | k_rope]`)."""
+    adt = cfg.activation_dtype()
+    n = h.shape[0]
+    c_q = _norm(_mm(h, lp["wq_a"], adt), lp["q_norm_scale"], cfg)
+    q = _mm(c_q, lp["wq_b"], adt).reshape(n, cfg.n_heads, -1)
+    q_nope = q[..., :cfg.nope_dim]
+    q_rope = rope(q[..., cfg.nope_dim:], pos, cfg.rope_theta)
+    kv = _mm(h, lp["wkv_a"], adt)
+    c_kv = _norm(kv[:, :cfg.kv_rank], lp["kv_norm_scale"], cfg)
+    k_rope = rope(kv[:, cfg.kv_rank:], pos, cfg.rope_theta)
+    return q_nope, q_rope, _stored(
+        jnp.concatenate([c_kv, k_rope], -1), cfg)
+
+
+def _index_parts(x, lp, pos, cfg):
+    """The indexer of a "full" layer from the residual x [N, D]: -> (q_I
+    [N, J, Di] f32, k_I [N, Di] (the cached index key, in the pool's
+    type), w [N, J] f32). In float32 throughout, from its own norm of x
+    and its own low-rank query: which positions are selected is a
+    discrete choice, and the activations' 8 bits would flip it at the
+    threshold far more often than float32 does."""
+    f32 = jnp.float32
+    n, rp = x.shape[0], cfg.rope_dim
+
+    def mm(a, w):
+        # three bfloat16 passes on the MXU: 2^-16, far under the cached
+        # key's own rounding
+        return jnp.einsum("...d,df->...f", a, w.astype(f32),
+                          precision=jax.lax.Precision.HIGH)
+
+    def turned(a):
+        return jnp.concatenate([rope(a[..., :rp], pos, cfg.rope_theta),
+                                a[..., rp:]], -1)
+
+    h = gpt._rms_norm(x.astype(f32), lp["attn_norm_scale"].astype(f32), cfg.eps)
+    c_q = gpt._rms_norm(mm(h, lp["wq_a"]), lp["q_norm_scale"].astype(f32),
+                        cfg.eps)
+    q_i = turned(mm(c_q, lp["wi_q"]).reshape(
+        n, cfg.index_heads, cfg.index_dim))
+    k_i = turned(_layer_norm(mm(h, lp["wi_k"]), lp["ik_norm_scale"],
+                             lp["ik_norm_bias"], cfg))
+    w = mm(h, lp["wi_w"]) * (cfg.index_heads ** -0.5 * cfg.index_dim ** -0.5)
+    return q_i, _stored(k_i, cfg).astype(cfg.activation_dtype()), w
+
+
+def _kv_up(lp, cfg, adt):
+    """W_kvb as [kv_rank, H, nope + v]."""
+    return lp["wkv_b"].astype(adt).reshape(
+        cfg.kv_rank, cfg.n_heads, cfg.nope_dim + cfg.v_dim)
+
+
+def _sm_scale(cfg) -> float:
+    return (cfg.nope_dim + cfg.rope_dim) ** -0.5
+
+
+def routing(h2, lp, cfg):
+    """-> (chosen [N, k] i32, weights [N, k] f32), in float32: a choice
+    between two near-equal scores should not turn on the activations'
+    rounding more than it must."""
+    g = jax.nn.sigmoid(jnp.einsum(
+        "nd,de->ne", h2.astype(jnp.float32),
+        lp["router"].astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(g + lp["router_bias"].astype(jnp.float32),
+                              cfg.experts_per_token)
+    weights = jnp.take_along_axis(g, chosen, -1)
+    if cfg.norm_topk:
+        weights = weights / jnp.sum(weights, -1, keepdims=True)
+    return chosen.astype(jnp.int32), weights * cfg.routed_scale
+
+
+def expert_layer(h2, lp, cfg, live=None,
+                 kernel: str = grouped_experts.EXPERTS_GROUPED):
+    """A sparse layer's two parts on normed h2 [N, D]: -> (routed: what
+    the held experts add, shared: the shared expert's, counts
+    [2 + held_count] i32: pairs routed here, pairs routed anywhere, pairs
+    each held expert got; rows where `live` is false count nothing)."""
+    adt = cfg.activation_dtype()
+    chosen, weights = routing(h2, lp, cfg)
+    if live is not None:
+        chosen = jnp.where(live[:, None], chosen, -1)
+    routed, load = grouped_experts.experts_grouped(
+        h2, chosen, weights, lp["we_gate"], lp["we_up"], lp["we_down"],
+        held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
+    shared, _ = gpt._gated_mlp(
+        h2, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
+             "w_down": lp["ws_down"]}, adt, jnp.float32)
+    counts = jnp.concatenate([
+        jnp.stack([jnp.sum(load), jnp.sum(chosen >= 0, dtype=jnp.int32)]),
+        load])
+    return routed.astype(adt), shared, counts
+
+
+def _feed_forward(x, lp, cfg, live=None,
+                  kernel: str = grouped_experts.EXPERTS_GROUPED):
+    """x += the layer's feed-forward; -> (x, expert counts or None)."""
+    adt = cfg.activation_dtype()
+    h2 = _norm(x, lp["ffn_norm_scale"], cfg)
+    if "router" in lp:
+        routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel)
+        return x + routed + shared, counts
+    return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
+
+
+def _counts(cfg, pos, live, expert_counts):
+    """The int32 vector a program returns: `COUNTS`, then the held
+    experts' loads. pos [N]: each query's position; live [N]: the rows
+    that count."""
+    n_full = sum(ix == "full" for _, ix in cfg.kinds)
+    scanned = jnp.sum(jnp.where(live, pos + 1, 0))
+    selected = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, cfg.index_topk),
+                                 0))
+    experts = sum(expert_counts) if expert_counts else jnp.zeros(
+        (2 + cfg.held_count,), jnp.int32)
+    return jnp.concatenate([
+        jnp.stack([scanned * n_full, selected * n_full,
+                   jnp.int32(n_full), jnp.int32(cfg.n_layers - n_full),
+                   experts[0], experts[1]]).astype(jnp.int32),
+        experts[2:].astype(jnp.int32)])
+
+
+def summarize(cfg, totals) -> dict:
+    """`COUNTS` summed over a window (None: nothing ran yet) -> the
+    engine's `stats()` entries."""
+    if totals is None:
+        totals = [0] * (len(COUNTS) + cfg.held_count)
+    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
+    load = [int(v) for v in totals[len(COUNTS):]]
+    mean = sum(load) / max(len(load), 1)
+    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
+    return out
+
+
+def _unembed(x, params, cfg):
+    return jnp.einsum("...d,vd->...v", x,
+                      params["head"].astype(cfg.activation_dtype()),
+                      preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# whole sequence (tests, and the share test)
+# ---------------------------------------------------------------------------
+
+def _select_dense(scores, valid, k: int):
+    """scores [N, S] f32 with -inf where not `valid` -> bool [N, S]: the
+    k largest of each row (all valid ones while there are no more). Of
+    equal scores at the threshold the earliest positions are taken, as
+    `jax.lax.top_k` takes them in a decode step: the same set either
+    way."""
+    k = min(k, scores.shape[1])
+    kth = jax.lax.top_k(scores, k)[0][:, -1:]
+    above, ties = scores > kth, scores == kth
+    need = k - jnp.sum(above, -1, keepdims=True)
+    return valid & (above | (ties & (jnp.cumsum(ties, -1) <= need)))
+
+
+def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
+    """tokens [B, T] -> float32 logits [B, T, V], no cache: every layer
+    dense over its own sequence, masked to the selection. With
+    `selections`, a list, each "full" layer's bool [T, T] is appended."""
+    adt = cfg.activation_dtype()
+
+    def one(seq):
+        t = seq.shape[0]
+        pos = jnp.arange(t, dtype=jnp.int32)
+        causal = pos[None, :] <= pos[:, None]
+        x = params["embed"].astype(adt)[seq]
+        selected = None
+        for lp in params["layers"]:
+            h = _norm(x, lp["attn_norm_scale"], cfg)
+            q_nope, q_rope, row = _project(h, lp, pos, cfg)
+            if "wi_q" in lp:
+                q_i, k_i, w = _index_parts(x, lp, pos, cfg)
+                s = sparse_latent.index_dots(q_i, k_i, "qjd,kd->qjk")
+                scores = jnp.where(causal, jnp.einsum(
+                    "qj,qjk->qk", w, jax.nn.relu(s)), -jnp.inf)
+                selected = _select_dense(scores, causal, cfg.index_topk)
+                if selections is not None:
+                    selections.append(selected)
+            c_kv, k_rope = row[:, :cfg.kv_rank], row[:, cfg.kv_rank:]
+            kv = jnp.einsum("sc,chd->shd", c_kv, _kv_up(lp, cfg, adt),
+                            preferred_element_type=jnp.float32).astype(adt)
+            s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :cfg.nope_dim],
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("qhd,kd->hqk", q_rope, k_rope,
+                              preferred_element_type=jnp.float32))
+            p = jax.nn.softmax(jnp.where(selected[None], s * _sm_scale(cfg),
+                                         NEG_INF), -1)
+            att = jnp.einsum("hqk,khd->qhd", p.astype(adt),
+                             kv[..., cfg.nope_dim:],
+                             preferred_element_type=jnp.float32).astype(adt)
+            x = x + _mm(att.reshape(t, -1), lp["w_out"], adt)
+            x, _ = _feed_forward(x, lp, cfg)
+        return _unembed(_norm(x, params["final_ln_scale"], cfg), params, cfg)
+
+    if selections is not None:          # the list is filled outside a map
+        return jnp.stack([one(seq) for seq in tokens])
+    return jax.lax.map(one, tokens)
+
+
+# ---------------------------------------------------------------------------
+# paged prefill of one chunk
+# ---------------------------------------------------------------------------
+
+def _context_block(s: int, bs: int) -> int:
+    b = max(bs, min(s, CONTEXT_BLOCK) // bs * bs)
+    while s % b:
+        b -= bs
+    return b
+
+
+def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
+    """A chunk's selection over the cached context: q_i [C, J, Di],
+    w [C, J], index [L_full, nb, bs, Di] -> bool [C, S]. Plain
+    `jax.numpy`: the context's keys gathered through the table, scored a
+    block of positions at a time, only as far as the chunk's last
+    position."""
+    from ray_tpu.ops.decode_attention import gather_kv_pages
+    _, nb, bs, di = index.shape
+    keys = gather_kv_pages(index.reshape(-1, bs, di),
+                           table[None] + layer * nb)[0]     # [S, Di]
+    c, s = q_i.shape[0], keys.shape[0]
+    sb = _context_block(s, bs)
+    last = jnp.max(jnp.where(valid, positions, 0))
+
+    def block(j, scores):
+        ks = jax.lax.dynamic_slice_in_dim(keys, j * sb, sb)
+        dots = sparse_latent.index_dots(q_i, ks, "qjd,sd->qjs")
+        return jax.lax.dynamic_update_slice_in_dim(
+            scores, jnp.einsum("qj,qjs->qs", w, jax.nn.relu(dots)),
+            j * sb, axis=1)
+
+    scores = jax.lax.fori_loop(0, last // sb + 1, block,
+                               jnp.full((c, s), -jnp.inf, jnp.float32))
+    live = (jnp.arange(s, dtype=jnp.int32)[None, :] <= positions[:, None]) \
+        & valid[:, None]
+    return _select_dense(jnp.where(live, scores, -jnp.inf), live,
+                         cfg.index_topk)
+
+
+def _prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
+                    valid, selected, lp, cfg):
+    """Expanded-head attention of a chunk's queries over the selected
+    positions of the cached context: keys and values rebuilt from the
+    latent rows a block of positions at a time (online softmax), only as
+    far as the chunk's last position. -> [C, H * v]."""
+    adt = cfg.activation_dtype()
+    _, nb, bs, _, words = latent.shape
+    flat = latent.reshape(-1, 1, words)
+    c, nh = q_nope.shape[0], cfg.n_heads
+    s = table.shape[0] * bs
+    sb = _context_block(s, bs)
+    up = _kv_up(lp, cfg, adt)
+    last = jnp.max(jnp.where(valid, positions, 0))
+    q_nope = q_nope * jnp.asarray(_sm_scale(cfg), adt)
+    q_rope = q_rope * jnp.asarray(_sm_scale(cfg), adt)
+
+    def block(j, carry):
+        m, l, acc = carry
+        at = j * sb + jnp.arange(sb, dtype=jnp.int32)
+        rows = sparse_latent.unpack_rows(sparse_latent.gather_rows(
+            flat, (table[at // bs] + layer * nb) * bs + at % bs,
+            impl=cfg.sparse_impl), cfg.row_values, adt)
+        kv = jnp.einsum("sc,chd->shd", rows[:, :cfg.kv_rank], up,
+                        preferred_element_type=jnp.float32).astype(adt)
+        sc = (jnp.einsum("qhd,shd->hqs", q_nope, kv[..., :cfg.nope_dim],
+                         preferred_element_type=jnp.float32)
+              + jnp.einsum("qhd,sd->hqs", q_rope, rows[:, cfg.kv_rank:],
+                           preferred_element_type=jnp.float32))
+        live = jax.lax.dynamic_slice_in_dim(selected, j * sb, sb, axis=1)
+        sc = jnp.where(live[None], sc, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, -1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new[..., None])
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hqs,shd->hqd", p.astype(adt), kv[..., cfg.nope_dim:],
+            preferred_element_type=jnp.float32)
+        return m_new, l * corr + jnp.sum(p, -1), acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, last // sb + 1, block,
+        (jnp.full((nh, c), NEG_INF, jnp.float32),
+         jnp.zeros((nh, c), jnp.float32),
+         jnp.zeros((nh, c, cfg.v_dim), jnp.float32)))
+    att = acc / jnp.maximum(l, 1e-30)[..., None]
+    return att.astype(adt).transpose(1, 0, 2).reshape(c, nh * cfg.v_dim)
+
+
+def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
+            block_table, start, length=None):
+    """One chunk of paged prefill of one sequence (`gpt.prefill_paged`'s
+    contract): tokens [1, C] at positions start .. start + length - 1;
+    every layer's cache row, and a "full" layer's index key, is written
+    through `block_table` before the layer attends. -> (logits [1, V] f32
+    of the chunk's last real position, cache, counts)."""
+    c = tokens.shape[1]
+    if tokens.shape[0] != 1:
+        raise ValueError(f"paged prefill wants tokens [1, C], got batch "
+                         f"{tokens.shape[0]}")
+    adt = cfg.activation_dtype()
+    nb, bs = cache["latent"].shape[1], cache["latent"].shape[2]
+    start = jnp.asarray(start, jnp.int32)
+    length = jnp.asarray(c if length is None else length, jnp.int32)
+    table = jnp.asarray(block_table, jnp.int32)
+    offs = jnp.arange(c, dtype=jnp.int32)
+    positions = start + offs
+    valid = offs < length
+    widx = jnp.where(valid, table[positions // bs] * bs + positions % bs,
+                     nb * bs)
+    latent, index = cache["latent"], cache["index"]
+    x = params["embed"].astype(adt)[tokens[0]]
+    selected, full, expert_counts = None, 0, []
+    for i, lp in enumerate(params["layers"]):
+        h = _norm(x, lp["attn_norm_scale"], cfg)
+        q_nope, q_rope, row = _project(h, lp, positions, cfg)
+        latent = _write_latent(latent, i, row, widx, cfg)
+        if "wi_q" in lp:
+            q_i, k_i, w = _index_parts(x, lp, positions, cfg)
+            index = _write_index(index, full, k_i, widx)
+            selected = _prefill_select(q_i, w, index, full, table,
+                                       positions, valid, cfg)
+            full += 1
+        att = _prefill_attend(q_nope, q_rope, latent, i, table, positions,
+                              valid, selected, lp, cfg)
+        x = x + _mm(att, lp["w_out"], adt)
+        x, counts = _feed_forward(
+            x, lp, cfg, valid, grouped_experts.EXPERTS_GROUPED_PREFILL)
+        if counts is not None:
+            expert_counts.append(counts)
+    x = _norm(x, params["final_ln_scale"], cfg)
+    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+    return (_unembed(last, params, cfg),
+            {"latent": latent, "index": index},
+            _counts(cfg, positions, valid, expert_counts))
+
+
+# ---------------------------------------------------------------------------
+# paged decode step
+# ---------------------------------------------------------------------------
+
+def select_rows(scores, tables, pos, cfg, block_size: int):
+    """A decode step's selection from the indexer's scores [B, S] (-inf
+    past pos): -> (rows [B, K] i32 physical row numbers, the live ones
+    first and 0 after; count [B] i32; idx [B, K] the logical positions)."""
+    k = min(cfg.index_topk, scores.shape[1])
+    _, idx = jax.lax.top_k(scores, k)
+    count = jnp.minimum(pos + 1, k).astype(jnp.int32)
+    live = jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None]
+    blocks = jnp.take_along_axis(tables, idx // block_size, axis=1)
+    rows = jnp.where(live, blocks * block_size + idx % block_size, 0)
+    return rows.astype(jnp.int32), count, jnp.where(live, idx, -1)
+
+
+def decode(params, tokens, cache, pos, tables,
+           cfg: LatentSparseMoEConfig, mesh=None, selections=None):
+    """One token for every slot (`gpt.decode_step_paged`'s contract):
+    tokens [B] at positions pos [B], blocks named by tables
+    [B, max_blocks]. Absorbed attention over the rows a "full" layer's
+    indexer selected in this step. Idle rows point their table at the
+    trash block; they compute garbage nobody reads and count nothing.
+    -> (logits [B, V] f32, cache, counts)."""
+    adt = cfg.activation_dtype()
+    nb, bs = cache["latent"].shape[1], cache["latent"].shape[2]
+    mb = tables.shape[1]
+    b = tokens.shape[0]
+    pos = pos.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    blk = jnp.take_along_axis(
+        tables, jnp.minimum(pos // bs, mb - 1)[:, None], axis=1)[:, 0]
+    widx = jnp.where(pos < mb * bs, blk * bs + pos % bs, nb * bs)
+    live = tables[:, 0] > 0
+    latent, index = cache["latent"], cache["index"]
+    x = params["embed"].astype(adt)[tokens]
+    rows = count = None
+    full, expert_counts = 0, []
+    for i, lp in enumerate(params["layers"]):
+        h = _norm(x, lp["attn_norm_scale"], cfg)
+        q_nope, q_rope, row = _project(h, lp, pos, cfg)
+        latent = _write_latent(latent, i, row, widx, cfg)
+        if "wi_q" in lp:
+            q_i, k_i, w = _index_parts(x, lp, pos, cfg)
+            index = _write_index(index, full, k_i, widx)
+            scores = sparse_latent.index_scores(
+                q_i, w, index.reshape(-1, bs, cfg.index_dim),
+                tables + full * nb, pos, impl=cfg.sparse_impl)
+            rows, count, idx = select_rows(scores, tables, pos, cfg, bs)
+            if selections is not None:
+                selections.append(idx)
+            full += 1
+        up = _kv_up(lp, cfg, adt)
+        q_abs = jnp.einsum("bhd,chd->bhc", q_nope, up[..., :cfg.nope_dim],
+                           preferred_element_type=jnp.float32).astype(adt)
+        q = jnp.concatenate([q_abs, q_rope], -1) * jnp.asarray(
+            _sm_scale(cfg), adt)
+        out = sparse_latent.sparse_latent_decode(
+            sparse_latent.split_query(q, cfg.row_words),
+            latent.reshape(-1, 1, cfg.row_words), rows + i * nb * bs, count,
+            dtype=adt, impl=cfg.sparse_impl)
+        mixed = sparse_latent.join_parts(out, cfg.kv_rank).astype(adt)
+        att = jnp.einsum("bhc,chd->bhd", mixed, up[..., cfg.nope_dim:],
+                         preferred_element_type=jnp.float32).astype(adt)
+        x = x + _mm(att.reshape(b, -1), lp["w_out"], adt)
+        x, counts = _feed_forward(x, lp, cfg, live)
+        if counts is not None:
+            expert_counts.append(counts)
+    x = _norm(x, params["final_ln_scale"], cfg)
+    return (_unembed(x, params, cfg), {"latent": latent, "index": index},
+            _counts(cfg, pos, live, expert_counts))
+
+
+FAMILY = ServingFamily(
+    init_pool=init_pool, prefill=prefill, decode=decode,
+    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
+    scatter_block=gpt.scatter_block, counts=summarize)

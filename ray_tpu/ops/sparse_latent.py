@@ -1,0 +1,475 @@
+"""Sparse attention over a paged latent cache: the two decode kernels and
+the row format they read, with pure-JAX paths of identical math.
+
+A latent-attention model caches one row a token a layer: the compressed
+key-value vector and the shared rotary key (`[c_kv | k_rope]`). A learned
+indexer scores every cached position of a stream, the `top_k` best are
+selected, and attention reads those rows only. Both steps run over the
+engine's block pool through its block tables (block 0 the trash block,
+`ops/decode_attention.py`'s conventions).
+
+**The row format.** A selected row is fetched from HBM by a DMA of its
+own. A DMA out of an array tiled (8, 128) moves eight rows at the least,
+so the latent pool is an array of uint32 words with a unit axis before the
+words, `[n_blocks, block_size, 1, words]`, `words` a multiple of 128: the
+compiler tiles it (1, 128), a row is `words * 4` contiguous bytes, and
+one DMA moves exactly one. XLA's own gather and scatter would first copy
+the whole pool into the (8, 128) tiling, so on a TPU rows are also written
+(`write_rows`) and read back for prefill (`gather_rows`) by DMA, HBM to
+HBM. A row of 16-bit values is split into a first and a second half, and
+word j holds first[j] in its low and second[j] in its high 16 bits
+(`pack_rows`; the kernel unpacks with a shift and a mask, and a bfloat16
+widened to float32 is those 16 bits shifted left); a row of float32
+values is its words as they are. Either way a row is `parts` arrays of
+`words` lanes, and the query is laid out the same way (`split_query`).
+
+**`index_scores`** (decode): for every stream the indexer's score of each
+cached position, `I[b, s] = sum_j w[b, j] ReLU(q[b, j] . k[s])` in
+float32 (a float32 query against 16-bit keys goes as a high and a low
+part), over the paged index keys `[n_blocks, block_size, Di]` of the stream's whole
+context; positions past `pos[b]` come out as -inf. The grid walks
+(stream, group of blocks); a step fetches its blocks through the table
+with one DMA a block and is skipped past the stream's position.
+
+**`sparse_latent_decode`**: absorbed latent attention of one query a
+stream over its selected rows, `rows[b, :count[b]]` (physical row numbers,
+the live ones first): scores against the whole row (the no-position part
+of the query already multiplied through the key up-projection), online
+softmax, and the weighted sum of the rows themselves, whose leading
+`kv_rank` values the caller takes through the value up-projection. The
+rows come in chunks, double-buffered, one DMA a row; chunks past
+`count[b]` are never fetched, so the bytes scale with min(context, top_k)
+and never with the context.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from ray_tpu.ops import backend
+
+NEG_INF = -1e30
+
+# Kernel names in the compiled program and the profiler's trace; PERF.md,
+# section 3, lists them. Each call sits in a `named_scope` of its own
+# name: see flash_attention.py.
+SPARSE_LATENT_DECODE, INDEX_SCORES = "sparse_latent_decode", "index_scores"
+
+LANES = 128
+ROW_CHUNK = 256         # selected rows a chunk of `sparse_latent_decode`
+INDEX_STEP_TOKENS = 512  # cached positions a grid step of `index_scores`
+
+
+def _up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+def resolve_impl(impl: str) -> str:
+    if impl not in ("auto", "pallas", "jax"):
+        raise ValueError(f"unknown impl {impl!r} (auto | pallas | jax)")
+    if impl == "auto":
+        return "pallas" if backend.on_tpu() else "jax"
+    return impl
+
+
+# ---------------------------------------------------------------------------
+# the row format
+# ---------------------------------------------------------------------------
+
+def row_words(values: int, dtype) -> int:
+    """uint32 words of a pool row that holds `values` numbers of `dtype`."""
+    if jnp.dtype(dtype).itemsize == 2:
+        return _up(-(-values // 2), LANES)
+    return _up(values, LANES)
+
+
+def row_parts(dtype) -> int:
+    return 2 if jnp.dtype(dtype).itemsize == 2 else 1
+
+
+def pack_rows(x, words: int):
+    """x [..., R] (bfloat16 or float32) -> uint32 [..., words]."""
+    parts = row_parts(x.dtype)
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1)
+                + [(0, parts * words - x.shape[-1])])
+    if parts == 1:
+        return jax.lax.bitcast_convert_type(x, jnp.uint32)
+    bits = jax.lax.bitcast_convert_type(x, jnp.uint16).astype(jnp.uint32)
+    return bits[..., :words] | (bits[..., words:] << 16)
+
+
+def unpack_rows(words_arr, values: int, dtype):
+    """uint32 [..., words] -> [..., values] of `dtype`."""
+    return jnp.concatenate(_parts_of(words_arr, dtype), -1)[
+        ..., :values].astype(dtype)
+
+
+def _parts_of(w, dtype):
+    """The row's part arrays, float32 [..., words] each (in a kernel or
+    out of one)."""
+    if row_parts(dtype) == 1:
+        return [jax.lax.bitcast_convert_type(w, jnp.float32)]
+    return [jax.lax.bitcast_convert_type(w << 16, jnp.float32),
+            jax.lax.bitcast_convert_type(w & jnp.uint32(0xFFFF0000),
+                                         jnp.float32)]
+
+
+def split_query(q, words: int):
+    """q [..., R] in the row's dtype -> [parts, ..., words], laid out as
+    `pack_rows` lays out a row."""
+    parts = row_parts(q.dtype)
+    q = jnp.pad(q, [(0, 0)] * (q.ndim - 1)
+                + [(0, parts * words - q.shape[-1])])
+    return jnp.stack([q[..., i * words:(i + 1) * words]
+                      for i in range(parts)])
+
+
+def join_parts(o, values: int):
+    """[parts, ..., words] -> [..., values]: the weighted sum of rows back
+    in the row's own order."""
+    return jnp.concatenate(list(o), -1)[..., :values]
+
+
+# ---------------------------------------------------------------------------
+# rows in and out of the pool
+# ---------------------------------------------------------------------------
+
+ROW_WRITE, ROW_GATHER = "latent_row_write", "latent_row_gather"
+
+
+def _row_copies(n: int, copy):
+    """Start `n` row DMAs on one semaphore, then wait for them all."""
+    def start(i, _):
+        copy(i).start()
+        return _
+
+    def wait(i, _):
+        copy(i).wait()
+        return _
+
+    jax.lax.fori_loop(0, n, start, 0)
+    jax.lax.fori_loop(0, n, wait, 0)
+
+
+def _write_kernel(at_ref, rows_ref, pool_ref, o_ref, sem, *, n: int,
+                  n_rows: int):
+    del pool_ref                # the same buffer as o_ref
+
+    def copy(i):
+        # a dropped row is written to row 0 of the trash block instead
+        at = at_ref[i]
+        return pltpu.make_async_copy(
+            rows_ref.at[i], o_ref.at[jnp.where(at < n_rows, at, 0)], sem)
+
+    _row_copies(n, copy)
+
+
+def write_rows(pool, rows, at, *, impl: str = "auto"):
+    """rows [N, words] uint32 into pool [n_rows, 1, words] at row numbers
+    at [N] i32 (n_rows and beyond: dropped), in place when the pool is
+    donated. Row 0 belongs to the trash block: on a TPU a dropped row
+    lands there."""
+    n_rows, _, words = pool.shape
+    if resolve_impl(impl) != "pallas":
+        return pool.at[at, 0].set(rows, mode="drop")
+    n = rows.shape[0]
+    with jax.named_scope(ROW_WRITE):
+        return pl.pallas_call(
+            functools.partial(_write_kernel, n=n, n_rows=n_rows),
+            name=ROW_WRITE,
+            out_shape=jax.ShapeDtypeStruct(pool.shape, pool.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+            input_output_aliases={2: 0},
+            interpret=backend.interpret(),
+        )(at.astype(jnp.int32), rows[:, None, :], pool)
+
+
+def _gather_kernel(at_ref, pool_ref, o_ref, sem, *, n: int):
+    _row_copies(n, lambda i: pltpu.make_async_copy(
+        pool_ref.at[at_ref[i]], o_ref.at[i], sem))
+
+
+def gather_rows(pool, at, *, impl: str = "auto"):
+    """pool [n_rows, 1, words] uint32, at [N] i32 -> [N, words]."""
+    if resolve_impl(impl) != "pallas":
+        return pool[at, 0]
+    n = at.shape[0]
+    with jax.named_scope(ROW_GATHER):
+        return pl.pallas_call(
+            functools.partial(_gather_kernel, n=n), name=ROW_GATHER,
+            out_shape=jax.ShapeDtypeStruct((n, 1, pool.shape[-1]),
+                                           pool.dtype),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=1, grid=(1,),
+                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+                out_specs=pl.BlockSpec(memory_space=pl.ANY),
+                scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
+            interpret=backend.interpret(),
+        )(at.astype(jnp.int32), pool)[:, 0]
+
+
+# ---------------------------------------------------------------------------
+# index scores (decode)
+# ---------------------------------------------------------------------------
+
+def query_parts(q, key_dtype):
+    """A float32 query as the parts that multiply keys of `key_dtype`
+    without losing its low bits: itself against float32 keys; against
+    16-bit keys a high and a low 16-bit part (`q = hi + lo` to 2^-17),
+    whose dots add up. [..., J, Di] -> [..., parts * J, Di]."""
+    q = q.astype(jnp.float32)
+    if jnp.dtype(key_dtype).itemsize == 4:
+        return q
+    hi = q.astype(key_dtype)
+    lo = (q - hi.astype(jnp.float32)).astype(key_dtype)
+    return jnp.concatenate([hi, lo], axis=-2)
+
+
+def index_dots(q, keys, eq: str):
+    """The indexer's q . k per head in float32 (`eq` an einsum over
+    q [..., J, Di] and keys [..., Di], heads on the output's axis -2):
+    the selection is a discrete choice, so the query keeps its float32
+    bits whatever type the cached keys have."""
+    j = q.shape[-2]
+    dots = jnp.einsum(eq, query_parts(q, keys.dtype), keys,
+                      preferred_element_type=jnp.float32,
+                      precision=jax.lax.Precision.HIGHEST)
+    return dots if dots.shape[-2] == j else \
+        dots[..., :j, :] + dots[..., j:, :]
+
+
+def reference_index_scores(q, w, pool, tables, pos):
+    """q [B, J, Di] f32, w [B, J] f32, pool [n_blocks, bs, Di], tables
+    [B, max_blocks], pos [B] -> f32 [B, max_blocks * bs], -inf past pos."""
+    from ray_tpu.ops.decode_attention import gather_kv_pages
+    keys = gather_kv_pages(pool, tables)                    # [B, S, Di]
+    s = index_dots(q, keys, "bjd,bsd->bjs")
+    scores = jnp.einsum("bj,bjs->bs", w.astype(jnp.float32),
+                        jax.nn.relu(s))
+    live = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :] \
+        <= pos.astype(jnp.int32)[:, None]
+    return jnp.where(live, scores, -jnp.inf)
+
+
+def _index_kernel(tbl_ref, pos_ref, q_ref, w_ref, pool_ref, o_ref, buf, sem,
+                  *, group: int, block_size: int, heads: int):
+    b, g = pl.program_id(0), pl.program_id(1)
+    pos = pos_ref[b]
+    base = g * group * block_size
+
+    @pl.when(base > pos)
+    def _skip():
+        o_ref[...] = jnp.full_like(o_ref, -jnp.inf)
+
+    @pl.when(base <= pos)
+    def _body():
+        def copy(i):
+            return pltpu.make_async_copy(
+                pool_ref.at[tbl_ref[b, g * group + i]],
+                buf.at[pl.ds(i * block_size, block_size)], sem)
+
+        for i in range(group):
+            copy(i).start()
+        for i in range(group):
+            copy(i).wait()
+        s = jax.lax.dot_general(
+            q_ref[0], buf[...],
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)     # [parts * J, tokens]
+        if s.shape[0] != heads:         # the query's high and low parts
+            s = s[:heads] + s[heads:]
+        scores = jnp.sum(jax.nn.relu(s) * w_ref[0], axis=0, keepdims=True)
+        col = base + jax.lax.broadcasted_iota(jnp.int32, scores.shape, 1)
+        o_ref[0] = jnp.where(col <= pos, scores, -jnp.inf)
+
+
+def _index_scores_pallas(q, w, pool, tables, pos):
+    heads = q.shape[1]
+    q = query_parts(q, pool.dtype)
+    b, j, di = q.shape
+    nb, bs, _ = pool.shape
+    mb = tables.shape[1]
+    group = max(1, min(mb, INDEX_STEP_TOKENS // bs))
+    while mb % group:
+        group -= 1
+    step = group * bs
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b, mb // group),
+        in_specs=[
+            pl.BlockSpec((1, j, di), lambda i, g, tbl, ps: (i, 0, 0)),
+            pl.BlockSpec((1, heads, 1), lambda i, g, tbl, ps: (i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((1, 1, step), lambda i, g, tbl, ps: (i, 0, g)),
+        scratch_shapes=[pltpu.VMEM((step, di), pool.dtype),
+                        pltpu.SemaphoreType.DMA(())],
+    )
+    with jax.named_scope(INDEX_SCORES):
+        out = pl.pallas_call(
+            functools.partial(_index_kernel, group=group, block_size=bs,
+                              heads=heads),
+            name=INDEX_SCORES,
+            out_shape=jax.ShapeDtypeStruct((b, 1, mb * bs), jnp.float32),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary", "arbitrary")),
+            interpret=backend.interpret(),
+        )(tables.astype(jnp.int32), pos.astype(jnp.int32),
+          q, w.astype(jnp.float32)[..., None], pool)
+    return out[:, 0]
+
+
+def index_scores(q, w, pool, tables, pos, *, impl: str = "auto"):
+    """The indexer's scores of every cached position of every stream:
+    q [B, J, Di] f32 (`query_parts` keeps its bits against 16-bit keys),
+    w [B, J], pool [n_blocks, bs, Di] (the paged index keys), tables
+    [B, max_blocks] i32, pos [B] i32 -> f32 [B, max_blocks * bs], -inf at
+    positions past pos[b]."""
+    if resolve_impl(impl) == "pallas":
+        return _index_scores_pallas(q, w, pool, tables, pos)
+    return reference_index_scores(q, w, pool, tables, pos)
+
+
+# ---------------------------------------------------------------------------
+# sparse latent decode
+# ---------------------------------------------------------------------------
+
+def reference_sparse_latent_decode(q, pool, rows, count, dtype):
+    """q [parts, B, H, words] (the scale folded in), pool
+    [n_rows, 1, words] uint32, rows [B, K] i32, count [B] i32 ->
+    f32 [parts, B, H, words]."""
+    k = rows.shape[1]
+    picked = jnp.stack(_parts_of(pool[rows, 0], dtype))  # [parts,B,K,words]
+    s = jnp.einsum("pbhw,pbkw->bhk", q.astype(jnp.float32), picked,
+                   preferred_element_type=jnp.float32)
+    live = jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None]
+    p = jax.nn.softmax(jnp.where(live[:, None], s, NEG_INF), axis=-1)
+    return jnp.einsum("bhk,pbkw->pbhw", p, picked,
+                      preferred_element_type=jnp.float32)
+
+
+def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
+                   m_scr, l_scr, acc_scr, *, chunk: int, dtype):
+    b = pl.program_id(0)
+    n = count_ref[b]
+    n_chunks = (n + chunk - 1) // chunk
+    compute = jnp.bfloat16 if row_parts(dtype) == 2 else jnp.float32
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    def issue(c, slot):
+        def eight(g, _):
+            for i in range(8):
+                at = g * 8 + i
+                pltpu.make_async_copy(
+                    pool_ref.at[rows_ref[b, c * chunk + at]],
+                    buf.at[slot, at], sem.at[slot]).start()
+            return _
+        jax.lax.fori_loop(0, chunk // 8, eight, 0)
+
+    def wait(slot):
+        # the semaphore counts bytes: one wait for the chunk's rows
+        pltpu.make_async_copy(pool_ref.at[pl.ds(0, chunk)], buf.at[slot],
+                              sem.at[slot]).wait()
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        issue(0, 0)
+
+    def step(c, _):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            issue(c + 1, 1 - slot)
+
+        wait(slot)
+        words = buf[slot].reshape(chunk, buf.shape[-1])
+        parts = [p.astype(compute) for p in _parts_of(words, dtype)]
+        s = sum(jax.lax.dot_general(
+            q_ref[i, 0].astype(compute), part,
+            dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+            for i, part in enumerate(parts))                # [H, chunk]
+        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        s = jnp.where(col < n, s, NEG_INF)
+        m_prev = m_scr[:, :1]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        corr = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=1,
+                                                     keepdims=True)
+        m_scr[:, :1] = m_new
+        for i, part in enumerate(parts):
+            acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
+                p.astype(compute), part,
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)         # [H, words]
+        return _
+
+    jax.lax.fori_loop(0, n_chunks, step, 0)
+    for i in range(acc_scr.shape[0]):
+        o_ref[i, 0] = acc_scr[i] / jnp.maximum(l_scr[:, :1], 1e-30)
+
+
+def _sparse_latent_decode_pallas(q, pool, rows, count, dtype):
+    parts, b, h, words = q.shape
+    k = rows.shape[1]
+    chunk = min(ROW_CHUNK, _up(k, 8))
+    if k % chunk:           # whole chunks: the tail points at row 0
+        rows = jnp.pad(rows, ((0, 0), (0, _up(k, chunk) - k)))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(b,),
+        in_specs=[
+            pl.BlockSpec((parts, 1, h, words),
+                         lambda i, rw, ct: (0, i, 0, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec((parts, 1, h, words),
+                               lambda i, rw, ct: (0, i, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, chunk, 1, words), jnp.uint32),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.VMEM((h, LANES), jnp.float32),          # m (col 0 used)
+            pltpu.VMEM((h, LANES), jnp.float32),          # l
+            pltpu.VMEM((parts, h, words), jnp.float32),   # acc
+        ],
+    )
+    with jax.named_scope(SPARSE_LATENT_DECODE):
+        return pl.pallas_call(
+            functools.partial(_sparse_kernel, chunk=chunk, dtype=dtype),
+            name=SPARSE_LATENT_DECODE,
+            out_shape=jax.ShapeDtypeStruct((parts, b, h, words),
+                                           jnp.float32),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=backend.interpret(),
+        )(rows.astype(jnp.int32), count.astype(jnp.int32), q, pool)
+
+
+def sparse_latent_decode(q, pool, rows, count, *, dtype,
+                         impl: str = "auto"):
+    """One query a stream over its selected rows of the latent pool.
+
+    q [parts, B, H, words]: `split_query` of the absorbed query, the
+    softmax scale folded in. pool [n_rows, 1, words] uint32: the latent
+    rows (`pack_rows` of values of `dtype`), layers and blocks flattened.
+    rows [B, K] i32: physical row numbers, the `count[b]` live ones first.
+    -> f32 [parts, B, H, words]: softmax-weighted sum of the rows
+    (`join_parts` puts it back in the row's order)."""
+    if resolve_impl(impl) == "pallas":
+        return _sparse_latent_decode_pallas(q, pool, rows, count, dtype)
+    return reference_sparse_latent_decode(q, pool, rows, count, dtype)

@@ -3,9 +3,13 @@ cache.
 
 The Podracer serving recipe (Hessel et al., 2104.06272): device shapes
 are STATIC and the model stays resident. The engine owns one fixed block
-pool (`models.gpt.init_kv_pool`, ``[L, n_blocks, block_size, H, Dh]``)
-and streams ragged traffic through it via int32 block tables — the only
-thing that changes between steps is *data*, never shapes:
+pool, made by the model family it serves (`models.family.ServingFamily`,
+found as `cfg.family`: a dict of arrays with the blocks on axis 1 of
+each; for `models/gpt.py` ``{"k", "v"}`` of
+``[L, n_blocks, block_size, H, Dh]``), and streams ragged traffic through
+it via int32 block tables — the only thing that changes between steps is
+*data*, never shapes. Prefill, decode, verify and the block moves named
+below are that family's; `gpt.`'s names stand for them:
 
 - **paged allocation**: each request holds exactly the blocks its
   prompt + generation footprint needs (a 100-token chat no longer pins a
@@ -468,10 +472,11 @@ class _Slot:
 
 
 class InferenceEngine:
-    """Slot-based continuous-batching scheduler over one GPT model with
-    a paged, prefix-shared KV cache.
+    """Slot-based continuous-batching scheduler over one model with a
+    paged, prefix-shared cache.
 
-    params/cfg are the `models.gpt` pytree and config; `slots` is the
+    params/cfg are a model family's pytree and config (`cfg.family` is
+    its `models.family.ServingFamily`); `slots` is the
     resident decode batch, `max_len` the per-sequence logical capacity
     (prompt + generated). `block_size` sets the paging granule and
     `cache_blocks` the pool's usable block count (default: enough for
@@ -502,10 +507,12 @@ class InferenceEngine:
                  role: str = "colocated"):
         import jax
         import jax.numpy as jnp
-        from ray_tpu.models import gpt
         self._jax = jax
-        self._gpt = gpt
         self.cfg = cfg
+        # Everything the engine asks of the model goes through its
+        # family (`models.family.ServingFamily`): the pool, prefill, decode,
+        # the block moves, and optionally verify and quantize.
+        fam = self._family = cfg.family
         # Disaggregated serving role. "prefill": this engine runs
         # chunked prefill only — a completed prompt's KV blocks are
         # gathered to host and parked as a handoff blob for a decode
@@ -540,8 +547,8 @@ class InferenceEngine:
             {b for b in self.buckets if b < self.prefill_chunk}
             | {self.prefill_chunk}))
         # +1: physical block 0 is the trash block (idle rows write there).
-        self.cache = gpt.init_kv_pool(cfg, self.cache_blocks + 1,
-                                      block_size, mesh)
+        self.cache = fam.init_pool(cfg, self.cache_blocks + 1, block_size,
+                                   mesh)
         self._alloc = BlockAllocator(self.cache_blocks + 1)
         self._tree = (RadixTree(block_size, self._alloc)
                       if prefix_cache else None)
@@ -552,6 +559,11 @@ class InferenceEngine:
             raise ValueError(f"unknown spec backend {spec!r}")
         if spec is not None and spec_k < 1:
             raise ValueError("spec_k must be >= 1")
+        if spec is not None and fam.verify is None:
+            raise ValueError(
+                f"spec={spec!r}: {type(cfg).__name__}'s family has no "
+                "verify step, so it cannot be served with speculative "
+                "decoding")
         self.spec = spec
         self.spec_k = int(spec_k)
         # Verify window: [current token, k speculated tokens].
@@ -567,7 +579,7 @@ class InferenceEngine:
             self.draft_cache_blocks = (
                 self.cache_blocks if draft_cache_blocks is None
                 else draft_cache_blocks)
-            self.draft_cache = gpt.init_kv_pool(
+            self.draft_cache = draft_cfg.family.init_pool(
                 draft_cfg, self.draft_cache_blocks + 1, block_size, mesh)
             self._draft_alloc = BlockAllocator(self.draft_cache_blocks + 1)
         else:
@@ -600,9 +612,10 @@ class InferenceEngine:
         # and draft each at most once, ever.
         def _quantize(p):
             self.quantize_traces += 1
-            return gpt.quantize_params(p)
+            return fam.quantize(p)
 
-        self._quant_target = cfg.weight_dtype == "int8"
+        self._quant_target = (fam.quantize is not None
+                              and cfg.weight_dtype == "int8")
         self._quant_draft = (spec == "draft"
                              and draft_cfg.weight_dtype == "int8")
         self._quantize_fn = (jax.jit(_quantize)
@@ -643,19 +656,19 @@ class InferenceEngine:
         def _prefill(params, tokens, cache, table, start, length, temp,
                      key, step):
             self.prefill_traces += 1
-            logits, cache = gpt.prefill_paged(
+            logits, cache, counts = fam.prefill(
                 params, tokens, cache, cfg, mesh, block_table=table,
                 start=start, length=length)
             tok, logp = _sample(logits, temp[None], key, step)
-            return tok[0], logp[0], cache
+            return tok[0], logp[0], cache, counts
 
         def _decode(params, cache, tokens, pos, tables, temps, key,
                     step):
             self.decode_traces += 1
-            logits, cache = gpt.decode_step_paged(
+            logits, cache, counts = fam.decode(
                 params, tokens, cache, pos, tables, cfg, mesh)
             tok, logp = _sample(logits, temps, key, step)
-            return tok, logp, cache
+            return tok, logp, cache, counts
 
         def _verify(params, cache, tokens, pos, tables, temps, key,
                     step):
@@ -671,7 +684,7 @@ class InferenceEngine:
             future writes overwrite the stale K/V before any read.
             """
             self.verify_traces += 1
-            logits, cache = gpt.verify_step_paged(
+            logits, cache = fam.verify(
                 params, tokens, cache, pos, tables, cfg, mesh)
             b, w = tokens.shape
             drafts = tokens[:, 1:]                       # [B, W-1]
@@ -719,7 +732,7 @@ class InferenceEngine:
         # output so every step updates the pool in place in HBM.
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(2,))
         self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
-        self._copy_fn = jax.jit(gpt.copy_block, donate_argnums=(0,))
+        self._copy_fn = jax.jit(fam.copy_block, donate_argnums=(0,))
         self._verify_fn = (jax.jit(_verify, donate_argnums=(1,))
                            if spec is not None else None)
 
@@ -734,11 +747,11 @@ class InferenceEngine:
 
         def _gather(cache, idx):
             self.kv_gather_traces += 1
-            return gpt.gather_block(cache, idx)
+            return fam.gather_block(cache, idx)
 
         def _scatter_blk(cache, block, idx):
             self.kv_scatter_traces += 1
-            return gpt.scatter_block(cache, block, idx)
+            return fam.scatter_block(cache, block, idx)
 
         self._gather_fn = jax.jit(_gather)
         self._scatter_block_fn = jax.jit(_scatter_blk,
@@ -759,7 +772,7 @@ class InferenceEngine:
 
                 def body(carry, i):
                     tok, cache = carry
-                    logits, cache = gpt.decode_step_paged(
+                    logits, cache, _ = draft_cfg.family.decode(
                         dparams, tok, cache, pos + i, tables,
                         draft_cfg, mesh)
                     nxt, _ = _sample(logits, temps, k, i)
@@ -773,7 +786,7 @@ class InferenceEngine:
             def _draft_prefill(dparams, tokens, dcache, table, start,
                                length):
                 self.draft_prefill_traces += 1
-                _, dcache = gpt.prefill_paged(
+                _, dcache, _ = draft_cfg.family.prefill(
                     dparams, tokens, dcache, draft_cfg, mesh,
                     block_table=table, start=start, length=length)
                 return dcache
@@ -867,6 +880,9 @@ class InferenceEngine:
         # host->device upload in _place_tree happens OUTSIDE _lock.
         self._swap_mutex = threading.Lock()
         self._decode_steps = 0
+        # what the family's programs count (`ServingFamily.counts`),
+        # summed over the window; None until a program returns some
+        self._model_counts = None
         self._step_times = collections.deque(maxlen=512)
         self._occupancy = collections.deque(maxlen=512)
         self._block_util = collections.deque(maxlen=512)
@@ -2025,13 +2041,14 @@ class InferenceEngine:
             toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
             with self._phases.phase("engine/prefill_chunk", tokens=clen,
                                     bucket=cap) as chunk:
-                tok, lp, self.cache = self._prefill_fn(
+                tok, lp, self.cache, counts = self._prefill_fn(
                     self.params, jnp.asarray(toks), self.cache,
                     jnp.asarray(s.table), np.int32(s.filled),
                     np.int32(clen), np.float32(s.temperature),
                     self._base_key, np.int32(self._decode_steps))
                 # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
                 tok = int(tok)    # device sync, so the timing is honest
+            self._add_counts(counts)
             self._recorder.on_prefill_chunk(s.rid, clen, cap,
                                             chunk.seconds)
             self._prefill_tokens += clen
@@ -2259,7 +2276,7 @@ class InferenceEngine:
         if inputs is None:      # the spec tick's fallback built them
             _, inputs = self._decode_inputs()
         with phase("engine/decode_dispatch") as dispatch:
-            nxt, lps, self.cache = self._decode_fn(
+            nxt, lps, self.cache, counts = self._decode_fn(
                 self.params, self.cache, *inputs, self._base_key,
                 np.int32(self._decode_steps))
         with phase("engine/token_sync") as sync:
@@ -2267,6 +2284,7 @@ class InferenceEngine:
             nxt = np.asarray(nxt)    # device sync
             # graftlint: disable-next-line=R001,R004 same sync as nxt above — lps arrives in the same device batch, so this is a no-cost host view
             lps = np.asarray(lps)
+            self._add_counts(counts)
         dt = dispatch.seconds + sync.seconds
         self._step_times.append(dt)
         self._decode_steps += 1
@@ -2279,6 +2297,16 @@ class InferenceEngine:
                 s.token, s.pos = int(nxt[i]), s.pos + 1
                 s.remaining -= 1
                 self._emit(s, i, s.token, float(lps[i]))
+
+    def _add_counts(self, counts) -> None:
+        """Sum what a prefill or decode program counted (after the
+        tick's own sync: the vector came with the token)."""
+        if counts is None:
+            return
+        # graftlint: disable-next-line=R001,R004 a few int32 that arrived with the token this tick already waited for
+        counts = np.asarray(counts, np.int64)
+        self._model_counts = (counts if self._model_counts is None
+                              else self._model_counts + counts)
 
     def _ngram_propose(self, s: _Slot) -> list | None:
         """Prompt-lookup proposal: find the longest n-gram (ngram_max
@@ -2491,6 +2519,7 @@ class InferenceEngine:
         `swaps` counter and `weight_swap_ms` DO reset."""
         with self._before_pump(), self._lock:
             self._decode_steps = 0
+            self._model_counts = None
             self._prefill_tokens = self._decode_tokens = 0
             self._phases.clear()
             self._recorder.deliver_waits.clear()
@@ -2866,6 +2895,9 @@ class InferenceEngine:
                 "reprefill_blocks": self._reprefill_blocks,
                 "aging_promotions": self._aging_promotions,
                 "per_class": per_class,
+                # what the model family's programs counted
+                **(self._family.counts(self.cfg, self._model_counts)
+                   if self._family.counts is not None else {}),
             }
 
 

@@ -22,7 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from ray_tpu.models import gpt
 from ray_tpu.ops import decode_attention as da
-from ray_tpu.ops import fused_xent
+from ray_tpu.ops import fused_xent, grouped_experts, sparse_latent
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.fused_xent import fused_softmax_xent
 from ray_tpu.parallel import MeshSpec
@@ -65,7 +65,8 @@ def compiled_text(topo, fn, *args) -> str:
 
 def kernel_names(text: str) -> list[str]:
     """Instruction names, `.N` cut, of the Mosaic kernels in a program."""
-    return [line.split(" = ")[0].strip().lstrip("%").rsplit(".", 1)[0]
+    return [line.split(" = ")[0].strip().removeprefix("ROOT ").lstrip("%")
+            .rsplit(".", 1)[0]
             for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
 
@@ -327,3 +328,99 @@ def test_gpt_dots_policy_runs_the_forward_kernel_once(topo):
     kernels = kernel_names(text)
     assert (kernels.count("flash_fwd") == kernels.count("flash_dq")
             == kernels.count("flash_dkv") == 1)
+
+
+# -- the latent / sparse / routed-expert family at glm-5.2.docqa-closed24's
+# shapes: 16 slots, 16,385 blocks of 16, chunk 512, 64 heads, rows of 576
+# values (384 words), index keys of 128, top 2048, 16 experts of 2048 x 6144
+
+def _glm():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import latent_sparse_moe as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "glm-5.2.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+LS, LNB, LMB, LK = 16, 16385, 1024, 2048
+U32, F32 = jnp.uint32, jnp.float32
+LATENT_KERNELS = {
+    "sparse_latent_decode": (
+        lambda q, pool, rows, count: sparse_latent.sparse_latent_decode(
+            q, pool, rows, count, dtype=BF16, impl="pallas"),
+        [((2, LS, 64, 384), BF16), ((6 * LNB * BS, 1, 384), U32),
+         ((LS, LK), I32), ((LS,), I32)], ("sparse_latent_decode",)),
+    "index_scores": (
+        lambda q, w, pool, tables, pos: sparse_latent.index_scores(
+            q, w, pool, tables, pos, impl="pallas"),
+        [((LS, 32, 128), BF16), ((LS, 32), F32),
+         ((2 * LNB, BS, 128), BF16), ((LS, LMB), I32), ((LS,), I32)],
+        ("index_scores",)),
+    "experts_grouped": (
+        lambda x, c, w, g, u, d: grouped_experts.experts_grouped(
+            x, c, w, g, u, d, held_from=0, impl="pallas"),
+        [((LS, 6144), BF16), ((LS, 8), I32), ((LS, 8), F32)]
+        + [((16, 2048, 6144), BF16)] * 3, ("experts_grouped",)),
+    "experts_grouped_prefill": (
+        lambda x, c, w, g, u, d: grouped_experts.experts_grouped(
+            x, c, w, g, u, d, held_from=0, impl="pallas",
+            name=grouped_experts.EXPERTS_GROUPED_PREFILL),
+        [((512, 6144), BF16), ((512, 8), I32), ((512, 8), F32)]
+        + [((16, 2048, 6144), BF16)] * 3, ("experts_grouped_prefill",)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(LATENT_KERNELS))
+def test_latent_family_kernels_compile_under_their_names(topo, case):
+    fn, args, names = LATENT_KERNELS[case]
+    text = compiled_text(topo, fn, *args)
+    assert sorted(kernel_names(text)) == sorted(names)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and a 512-token prefill chunk of
+    `benchmarks/configs/glm-5.2.json` as the engine jits them (the pool
+    donated): every kernel is there under its name, the pool is updated
+    in place (no copy of it among the temporaries), and weights, pool and
+    temporaries fit the chip."""
+    from ray_tpu.models import latent_sparse_moe as lsm
+    config, cfg, ref = _glm()
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(lambda: lsm.init_pool(cfg, LNB, BS)))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: lsm.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((LS,)), arg((LS,)), arg((LS, LMB))).compile()
+        want = {"latent_row_write": 6, "index_scores": 2,
+                "sparse_latent_decode": 6, "experts_grouped": 5}
+    else:
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: lsm.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, 512)), pool, arg((LMB,)), arg(()),
+            arg(())).compile()
+        want = {"latent_row_write": 6, "latent_row_gather": 6,
+                "experts_grouped_prefill": 5}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 1e9                 # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
