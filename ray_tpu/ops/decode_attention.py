@@ -14,24 +14,35 @@ pass.
 **Paged decode** (`paged_decode_attention`): K/V live in a shared block
 pool ``[n_blocks, block_size, H, D]`` and each sequence names its blocks
 through an int32 block table ``[B, max_blocks]`` (logical block j of
-sequence b is physical block ``tables[b, j]``). The structure mirrors
-`ops/flash_attention.py`: grid (B*H, max_blocks) with the kv dimension
-innermost/sequential walking *logical* blocks, per-row running
-(m, l, acc) softmax statistics in VMEM scratch, finalize on the last kv
-block. The block table and positions arrive as scalar prefetch
-(`pltpu.PrefetchScalarGridSpec`), so the K/V BlockSpec index maps
-dereference the table and the DMA engine fetches exactly the blocks the
-sequence owns — the pool is never materialized per sequence. Two
-decode-specific twists:
+sequence b is physical block ``tables[b, j]``). The kernel
+(`paged_decode`) runs ``grid = (B,)``: a program is one stream, and its
+loop's trip count is the stream's own, ``pos[b] // block_size + 1`` live
+pages walked in turns of several pages. The pools stay in HBM in the
+layout the model writes; the block table and positions arrive as scalar
+prefetch, a page is one DMA of a block named by the table, and a turn's
+pages land in one of two buffers while the turn before is computed, so
+what is read follows what is live and nothing else does. A turn scores
+every head of its pages at once with two plain matmuls over the page as
+stored and a mask that keeps each head its own rows; running (m, l, acc)
+softmax statistics live in VMEM scratch and the output is written once.
+`_decode_plan` chooses pages a turn and the scoped VMEM from the shapes,
+the pool's dtype and the chip's VMEM; `_paged_decode_kernel` says what a
+dead page costs (nothing is fetched for it; the V rows it leaves in a
+buffer are zeroed). Two things follow from decode:
 
 - **position masking**: each sequence attends to logical positions
   ``<= pos[b]`` (its current token's position — the caller writes the new
   K/V at ``pos`` *before* attending), so stale data in partially-filled
   tail blocks never contributes.
-- **data-dependent block skip**: kv blocks strictly past ``pos`` are
-  predicated away with ``pl.when(k_start <= pos)`` — a *runtime* branch,
-  unlike flash's static causal predicate — so short sequences behind a
-  long table don't pay for the empty tail.
+- **idle rows are nearly free**: a row with ``pos = 0`` and a table of
+  zeros (how the engine marks a slot that is not decoding) is one page
+  and one turn, whatever the table's width.
+
+Verify and the fused prefill (`paged_mq`) keep the older structure:
+grid (B*H, max_blocks) over a head-major copy of the pool, one
+``(block_size, D)`` tile a step through BlockSpec index maps that
+dereference the table, blocks past the last query's horizon predicated
+away with ``pl.when``.
 
 The JAX fallback gathers ``pool[tables]`` and attends with
 `reference_decode_attention`, the same masking and f32 accumulation.
@@ -39,8 +50,8 @@ The JAX fallback gathers ``pool[tables]`` and attends with
 **Int8 pools** (`ops/quant.py`): every paged op takes optional
 ``k_scale`` / ``v_scale`` arrays ``[n_blocks, bs, H]`` f32 — one scale
 per (position, head) row of an int8 pool. Dequantization happens
-*inside* the kernels (the scale tile rides the same table-dereferenced
-DMA schedule as its payload block) and inside the fallbacks (gathered
+*inside* the kernels (a page's scales are fetched through the same
+table entry as its payload) and inside the fallbacks (gathered
 through the same `gather_kv_pages`), so HBM reads stay int8 and the
 block-table machinery above never sees the dtype. Scales absent ==
 full-precision pool, bit-for-bit the pre-quantization math.
@@ -57,6 +68,7 @@ VMEM instead of round-tripping through HBM.
 from __future__ import annotations
 
 import functools
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -168,111 +180,247 @@ def _scale_row(scale_ref, head):
     return scale_ref[0, pl.ds(head, 1), :].astype(jnp.float32)
 
 
-def _paged_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                  sm_scale: float, block_size: int, n_heads: int,
-                  quantized: bool):
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
+# ---------------------------------------------------------------------------
+# paged decode: a program a stream over its own pages
+# ---------------------------------------------------------------------------
+
+_TURN_TOKENS = 128              # cached positions a turn, where they fit
+
+
+def _up(n: int, to: int) -> int:
+    return -(-n // to) * to
+
+
+class _DecodePlan(NamedTuple):
+    """What `paged_decode` runs at (`_decode_plan` chooses it). `pack`:
+    heads side by side in a row of lanes, 1 where the pool goes to the
+    kernel as the model stores it. `pages`: pages a turn of a stream's
+    loop. `vmem_limit`: what the working set asks of
+    `CompilerParams(vmem_limit_bytes=)`, None where the compiler's
+    default scope holds it."""
+    pack: int
+    pages: int
+    vmem_limit: int | None
+
+
+def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
+                 vmem: int | None = None) -> _DecodePlan | None:
+    """The plan for pools `[n_blocks, bs, h, d]` of `dtype`, from the
+    shapes, the element size and the chip's VMEM alone.
+
+    A page reaches VMEM by one DMA, and Mosaic moves by DMA only slices
+    whose lanes fill whole 128-lane tiles. A head size that is a multiple
+    of 128 does as stored: `pack` 1, no operand touched. A smaller one
+    that divides 128 is stored by XLA padded to 128 lanes, so the wrapper
+    lays `pack = 128 // d` heads side by side (`[n_blocks, bs * h / pack,
+    128]`, one copy of the pool). Pages a turn: `_TURN_TOKENS`
+    positions, halved until two turns of K and V, what the body makes of
+    one and its score tiles fit the default scope; one page that does not fit asks for what it needs,
+    up to half the VMEM. None where no row of lanes can be made (`d`
+    neither a multiple nor a divisor of 128, heads that do not fill
+    rows or sublanes) or an int8 pool's scale rows are not whole lane
+    tiles: the caller takes the JAX path."""
+    item = jnp.dtype(dtype).itemsize
+    sub = 8 * 4 // item                             # sublanes of a tile
+    pack = 1 if d % 128 == 0 else 128 // d
+    rows = bs * h // pack                           # score columns a page
+    if pack == 1:
+        ok = h % 8 == 0
+        page = bs * _up(h, sub) * d * item          # as VMEM holds it
     else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    ji = pl.program_id(1)
+        ok = pack * d == 128 and h % pack == 0 and rows % sub == 0
+        page = rows * 128 * item
+    if not ok or (quantized and rows % 128):
+        return None
 
-    @pl.when(ji == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = pos_ref[pl.program_id(0) // n_heads]
-    head = pl.program_id(0) % n_heads
-    k_start = ji * block_size     # LOGICAL position of this kv block --
-    # the BlockSpec index maps already dereferenced tbl_ref, so k_ref
-    # holds the right physical block; masking stays in logical space.
-
-    @pl.when(k_start <= pos)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [1, D]
-        k = k_ref[0, 0].astype(jnp.float32)         # [bs, D]
-        s = jax.lax.dot_general(
-            q * sm_scale, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [1, bs]
+    def working_set(pages):
+        cols = pages * rows
+        bufs = 2 * 2 * pages * page                 # K and V, two turns
         if quantized:
-            s = s * _scale_row(ks_ref, head)
-        col = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col <= pos, s, NEG_INF)
-        m_prev = m_scr[:1, :1]
+            bufs += 2 * 2 * 8 * cols * 4            # their scale rows
+        operands = 2 * cols * max(d, 128) * 4       # K, V as the MXU gets them
+        scores = 4 * _up(h, 8) * cols * 4           # the mask, s, p, a spare
+        return bufs + operands + scores
+
+    pages = max(1, _TURN_TOKENS // bs)
+    while pages > 1 and working_set(pages) > backend.SCOPED_VMEM_DEFAULT:
+        pages //= 2
+    need = working_set(pages)
+    if need <= backend.SCOPED_VMEM_DEFAULT:
+        return _DecodePlan(pack, pages, None)
+    if need > (vmem or backend.vmem_capacity()) // 2:
+        return None
+    # the estimate and a quarter for what it cannot see
+    return _DecodePlan(pack, pages, _up(need + need // 4, 1 << 20))
+
+
+def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
+                         sm_scale: float, pack: int, pages: int,
+                         block_size: int, quantized: bool):
+    """One stream: its live pages in turns of `pages`, online softmax
+    over a turn's every head at once.
+
+    A turn's K is `[cols, lanes]`: row `c` holds position `c // hr` of
+    the turn and the `pack` heads from `c % hr * pack` on, `hr = H /
+    pack` rows a position (with `pack` 1, a page as the model wrote it:
+    `[bs, H, D]` is `[bs * H, D]`). `q [H, lanes] . K^T` scores every
+    head against every row, `[H, cols]`; a row of scores keeps the
+    columns that hold its own head and are at or before `pos` (`ahead`),
+    the rest are masked like a dead position, and `p . V` then sums a
+    head's own rows only. The MXU does `hr` times the useful
+    multiplications on 128 x 128 tiles it would otherwise leave idle; no
+    head is ever moved out of the layout it was stored in.
+
+    Dead pages: a turn issues and awaits DMAs for its live pages only
+    (page `j` is live while `j <= pos // bs`; an idle row, `pos` 0, is
+    one page, one turn), so no table entry past the length is read and
+    no byte of a dead page is moved. The last turn's dead pages are
+    whatever the buffer held: their scores are masked by `ahead`, and
+    their V rows (and V scales) are zeroed before `p . V`, because zero
+    times a NaN is a NaN. What is read beyond the live positions is the
+    rest of each stream's last live page: `bs - 1 - pos % bs` positions,
+    under one page of K and one of V a stream a layer."""
+    if quantized:
+        (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
+         m_scr, l_scr, acc_scr) = rest
+    else:
+        o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr = rest
+    b = pl.program_id(0)
+    bs, mb = block_size, tbl_ref.shape[1]
+    _, h, lanes = q_ref.shape
+    hr = h // pack                      # rows of lanes a cached position
+    rows = bs * hr                      # score columns a page
+    cols = pages * rows
+    size = kbuf.shape[1] // pages       # a page along the buffer's rows
+    pos = pos_ref[b]
+    n_pages = jnp.minimum(pos // bs + 1, mb)
+    n_turns = (n_pages + pages - 1) // pages
+
+    def copies(blk, slot, i):
+        """Page `i` of a turn: block `blk` into buffer `slot`."""
+        page, scales = pl.ds(i * size, size), pl.ds(i * rows, rows)
+        pairs = [(k_hbm, kbuf.at[slot, page]), (v_hbm, vbuf.at[slot, page])]
+        if quantized:
+            pairs += [(ks_hbm, ksbuf.at[slot, :, scales]),
+                      (vs_hbm, vsbuf.at[slot, :, scales])]
+        return [pltpu.make_async_copy(src.at[blk], dst, sem.at[slot])
+                for src, dst in pairs]
+
+    def issue(c, slot):
+        for i in range(pages):
+            @pl.when(c * pages + i < n_pages)
+            def _start():
+                for cp in copies(tbl_ref[b, c * pages + i], slot, i):
+                    cp.start()
+
+    def land(c, slot):
+        for i in range(pages):
+            live = c * pages + i < n_pages
+
+            @pl.when(live)
+            def _wait():        # the semaphore counts bytes, not blocks
+                for cp in copies(0, slot, i):
+                    cp.wait()
+
+            @pl.when(jnp.logical_not(live))
+            def _zero():
+                vbuf[slot, pl.ds(i * size, size)] = jnp.zeros(
+                    (size,) + vbuf.shape[2:], vbuf.dtype)
+                if quantized:
+                    vsbuf[slot, :, pl.ds(i * rows, rows)] = jnp.zeros(
+                        (pack, rows), jnp.float32)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    q = q_ref[0].astype(jnp.float32) * sm_scale             # [H, lanes]
+    row = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
+    # a column's position within the turn where it holds the row's head,
+    # past every position where it does not
+    ahead = jnp.where(col % hr == row // pack, col // hr, mb * bs)
+    issue(0, 0)
+
+    def step(c, _):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_turns)
+        def _next():
+            issue(c + 1, 1 - slot)
+
+        land(c, slot)
+        k = kbuf[slot].astype(jnp.float32).reshape(cols, lanes)
+        v = vbuf[slot]
+        if quantized:
+            v = v.astype(jnp.float32)
+        s = jax.lax.dot_general(
+            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)             # [H, cols]
+        if quantized:   # row h takes the scales of head h: h % pack here
+            s = s * jnp.tile(ksbuf[slot], (hr, 1))
+        s = jnp.where(ahead <= pos - c * pages * bs, s, NEG_INF)
+        m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
-        l_scr[:1, :1] = l_scr[:1, :1] * corr + jnp.sum(
-            p, axis=1, keepdims=True)
-        m_scr[:1, :1] = m_new
-        v = v_ref[0, 0]
+        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=1,
+                                                     keepdims=True)
+        m_scr[:, :1] = m_new
         if quantized:
-            p = p * _scale_row(vs_ref, head)
-            v = v.astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v,
+            p = p * jnp.tile(vsbuf[slot], (hr, 1))
+        acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
+            p.astype(v.dtype), v.reshape(cols, lanes),
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [1, D]
-        acc_scr[:1] = acc_scr[:1] * corr + pv
+            preferred_element_type=jnp.float32)             # [H, lanes]
+        return _
 
-    @pl.when(ji == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:1] / l_scr[:1, :1]).astype(o_ref.dtype)
+    jax.lax.fori_loop(0, n_turns, step, 0)
+    o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_bhsd(q, k, v, tables, pos, *, sm_scale: float, n_heads: int,
-                interpret: bool, ks=None, vs=None):
-    """q [BH, 1, D]; k, v [n_blocks, H, bs, D] head-major pool; tables
-    [B, max_blocks]; pos [B] i32 -> [BH, 1, D]. Grid walks (row, logical
-    block); the physical block index comes out of the scalar-prefetched
-    table inside the BlockSpec index maps — paging lives entirely in the
-    DMA schedule, the kernel body is the stock online softmax. With
-    ``ks``/``vs`` [n_blocks, H, bs] (head-major per-row scales) the
-    pools are int8 and dequantized in VMEM."""
-    bh, _, d = q.shape
-    mb = tables.shape[1]
-    bs = k.shape[2]
-    grid = (bh, mb)
-    h = n_heads
+def _paged_decode(q, k_pool, v_pool, tables, pos, *, plan: _DecodePlan,
+                  block_size: int, sm_scale: float, interpret: bool,
+                  ks=None, vs=None):
+    """q [B, H, lanes]; k_pool, v_pool in HBM, `[n_blocks, bs, H, D]` as
+    stored (`plan.pack` 1) or `[n_blocks, bs * H / pack, 128]`; tables
+    [B, max_blocks], pos [B] i32, scalar-prefetched -> [B, H, lanes].
+    `grid = (B,)`: a program is a stream, its loop's trip count the
+    stream's own live pages, each page one DMA named by the table, a
+    turn's pages landing while the turn before is computed. ``ks``/``vs``
+    `[n_blocks, pack, bs * H / pack]` f32 mark int8 pools: row `j` holds
+    the scales of heads `j, j + pack, ..` in the order of a page's rows,
+    fetched page by page beside the payload and applied to the scores
+    and the probabilities (`_scale_row` says why there)."""
+    b, h, lanes = q.shape
     quantized = ks is not None
-
-    pool_spec = pl.BlockSpec((1, 1, bs, d),
-                             lambda i, j, tbl, ps: (tbl[i // h, j],
-                                                    i % h, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, 1, d), lambda i, j, tbl, ps: (i, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [tables, pos, q, k, v]
+    hbm = pl.BlockSpec(memory_space=pl.ANY)
+    row = pl.BlockSpec((1, h, lanes), lambda i, tbl, ps: (i, 0, 0))
+    operands = [tables, pos, q, k_pool, v_pool]
+    turn = (2, plan.pages * k_pool.shape[1]) + k_pool.shape[2:]
+    scratch = [pltpu.VMEM(turn, k_pool.dtype)] * 2
     if quantized:
-        in_specs += [_scale_spec(h, bs)] * 2
         operands += [ks, vs]
+        scratch += [pltpu.VMEM((2, plan.pack, plan.pages * ks.shape[2]),
+                               jnp.float32)] * 2
+    scratch += [pltpu.SemaphoreType.DMA((2,)),
+                pltpu.VMEM((h, 128), jnp.float32),    # m (column 0 used)
+                pltpu.VMEM((h, 128), jnp.float32),    # l
+                pltpu.VMEM((h, lanes), jnp.float32)]  # acc
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=grid,
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, d), lambda i, j, tbl, ps: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((8, 128), jnp.float32),    # m (cell [0, 0] used)
-            pltpu.VMEM((8, 128), jnp.float32),    # l
-            pltpu.VMEM((8, d), jnp.float32),      # acc (row 0 used)
-        ],
-    )
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[row] + [hbm] * (len(operands) - 3),
+        out_specs=row, scratch_shapes=scratch)
     with jax.named_scope(PAGED_DECODE):
         return pl.pallas_call(
-            functools.partial(_paged_kernel, sm_scale=sm_scale,
-                              block_size=bs, n_heads=n_heads,
-                              quantized=quantized),
+            functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
+                              pack=plan.pack, pages=plan.pages,
+                              block_size=block_size, quantized=quantized),
             name=PAGED_DECODE,
-            out_shape=jax.ShapeDtypeStruct((bh, 1, d), q.dtype),
+            out_shape=jax.ShapeDtypeStruct((b, h, lanes), q.dtype),
             grid_spec=grid_spec,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
+                dimension_semantics=("parallel",),   # streams share nothing
+                vmem_limit_bytes=plan.vmem_limit),
             interpret=interpret,
         )(*operands)
 
@@ -309,10 +457,10 @@ def reference_paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
 def _paged_mq_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
                      sm_scale: float, block_size: int, n_heads: int,
                      w_real: int, quantized: bool):
-    """`_paged_kernel` generalized to W query rows per (b, h): the online
-    softmax statistics become per-row vectors, the mask becomes the
-    staircase ``col <= pos + row``, and the runtime block skip widens to
-    the LAST query row's horizon (``pos + w_real - 1``)."""
+    """W query rows of one (b, h) against one `(block_size, D)` tile of
+    that head a grid step: stock online softmax with per-row statistics,
+    the staircase mask ``col <= pos + row``, and blocks past the LAST
+    query row's horizon (``pos + w_real - 1``) skipped at run time."""
     if quantized:
         ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
     else:
@@ -374,9 +522,11 @@ def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
                    ks=None, vs=None):
     """q [BH, Wp, D] (Wp = W padded to a sublane multiple); k, v
     [n_blocks, H, bs, D] head-major pool; tables [B, max_blocks]; pos
-    [B] i32 -> [BH, Wp, D]. Same DMA schedule as `_paged_bhsd` — only
-    the q/o tile grows from one row to Wp. ``ks``/``vs``
-    [n_blocks, H, bs] mark int8 pools (dequantized in VMEM)."""
+    [B] i32 -> [BH, Wp, D]. Grid walks (row, logical block); the
+    physical block index comes out of the scalar-prefetched table inside
+    the BlockSpec index maps, so paging lives in the DMA schedule.
+    ``ks``/``vs`` [n_blocks, H, bs] mark int8 pools (dequantized in
+    VMEM)."""
     bh, wp, d = q.shape
     mb = tables.shape[1]
     bs = k.shape[2]
@@ -501,17 +651,21 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     against a block pool ``k_pool, v_pool [n_blocks, block_size, H, D]``
     indexed by ``tables [B, max_blocks]`` i32 (logical block j of row b
     is physical block ``tables[b, j]``; entries past the allocated
-    length may be any valid block — they are masked). Attends to logical
-    positions ``<= pos[b]`` and returns ``[B, H, D]`` in q.dtype.
+    length may be any valid block — the kernel never reads them, the
+    JAX path masks them). Attends to logical positions ``<= pos[b]`` and
+    returns ``[B, H, D]`` in q.dtype.
 
     With ``k_scale``/``v_scale`` ``[n_blocks, bs, H]`` f32 the pools
     hold int8 payloads (`ops.quant.quantize_rows` convention, one scale
     per position-head row); both impls dequantize at read — in VMEM for
     pallas, post-gather for jax — so HBM traffic stays int8.
 
-    impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax". The two paths share the same masking/accumulation math and
-    agree to f32 tolerance."""
+    impl: "auto" (pallas on a TPU where `_decode_plan` has a plan, else
+    jax) | "pallas" | "jax". The two paths share the same
+    masking/accumulation math and agree to f32 tolerance. The kernel
+    takes a pool whose head size is a multiple of 128 as it is stored;
+    at a smaller one the pool (which XLA stores padded to 128 lanes) and
+    the scales are laid out for it here, one copy each."""
     if q.ndim != 3 or k_pool.ndim != 4 or tables.ndim != 2:
         raise ValueError(
             "paged_decode_attention wants q [B, H, D], pools "
@@ -520,10 +674,12 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_decode_attention")
     b, h, d = q.shape
-    bs = k_pool.shape[1]
+    nb, bs = k_pool.shape[:2]
+    plan = _decode_plan(bs, h, d, k_pool.dtype, quantized)
     if impl == "auto":
-        impl = _auto_impl("paged_decode_attention", bs % 8 == 0,
-                          f"block_size {bs}")
+        impl = _auto_impl("paged_decode_attention", plan is not None,
+                          f"block_size {bs}, {h} heads of {d}, "
+                          f"{k_pool.dtype} pool")
     if impl == "jax":
         return reference_paged_decode_attention(
             q, k_pool, v_pool, tables, pos,
@@ -532,24 +688,35 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
         raise ValueError(
             f"unknown paged_decode_attention impl {impl!r} "
             "(expected 'auto' | 'pallas' | 'jax')")
-    if bs % 8 != 0:
-        raise ValueError(
-            f"block_size {bs} is not a multiple of 8; use impl='jax'")
     interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
-    # [n_blocks, bs, H, D] -> head-major [n_blocks, H, bs, D]: the
-    # kernel's per-(row, block) tile is (bs, D) for one head.
-    kt = _pad_heads(k_pool, d_pad).transpose(0, 2, 1, 3)
-    vt = _pad_heads(v_pool, d_pad).transpose(0, 2, 1, 3)
-    qt = _pad_heads(q, d_pad).reshape(b * h, 1, d_pad)
+    if plan is None:
+        if not interpret:
+            raise ValueError(
+                f"no paged_decode plan for block_size {bs}, {h} heads of "
+                f"{d}, {k_pool.dtype} pool; use impl='jax'")
+        # the interpreter has no tiles to align: any shape, as stored
+        plan = _DecodePlan(1, max(1, _TURN_TOKENS // bs), None)
+    pack = plan.pack
     ks = vs = None
+    if pack > 1:
+        # `pack` heads side by side in a row of 128 lanes; row h of the
+        # query and of the output keeps the lanes of its own head
+        own = (jnp.arange(h)[:, None] % pack
+               == jnp.arange(pack * d)[None, :] // d)       # [H, lanes]
+        q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
+        k_pool = k_pool.reshape(nb, bs * h // pack, pack * d)
+        v_pool = v_pool.reshape(nb, bs * h // pack, pack * d)
     if quantized:
-        ks = k_scale.transpose(0, 2, 1)
-        vs = v_scale.transpose(0, 2, 1)
-    out = _paged_bhsd(qt, kt, vt, tables.astype(jnp.int32),
-                      pos.astype(jnp.int32), sm_scale=d ** -0.5,
-                      n_heads=h, interpret=interpret, ks=ks, vs=vs)
-    return out.reshape(b, h, d_pad)[..., :d]
+        lay = lambda sc: sc.reshape(nb, bs, h // pack, pack).transpose(
+            0, 3, 1, 2).reshape(nb, pack, bs * h // pack)
+        ks, vs = lay(k_scale), lay(v_scale)
+    out = _paged_decode(q, k_pool, v_pool, tables.astype(jnp.int32),
+                        pos.astype(jnp.int32), plan=plan, block_size=bs,
+                        sm_scale=d ** -0.5, interpret=interpret,
+                        ks=ks, vs=vs)
+    if pack > 1:
+        out = jnp.sum(jnp.where(own, out, 0).reshape(b, h, pack, d), axis=2)
+    return out
 
 
 # ---------------------------------------------------------------------------

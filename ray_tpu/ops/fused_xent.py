@@ -403,20 +403,9 @@ def _bwd_pallas(x, embed, targets, lse, c_lse, c_tgt, plan: _Plan,
 # ---------------------------------------------------------------------------
 
 _MIB = 1 << 20
-_SCOPED_DEFAULT = 16 * _MIB     # Mosaic's scoped VMEM when no limit is asked
 _ROW_BLOCKS = (2048, 1024, 512, 256)    # resident row blocks looked for
 _BLOCK_V = 512                  # the widest vocab block looked for
 _SUB_N = 512                    # rows of a score tile, where they divide
-
-
-def _vmem_capacity() -> int:
-    """The VMEM of the core the kernels compile for. Off a TPU (the
-    interpreter; a compile for a described chip) the v5e's 128 MiB, the
-    chip this repo's cells and AOT tests describe."""
-    try:
-        return pltpu.get_tpu_info().vmem_capacity_bytes
-    except ValueError:
-        return 128 * _MIB
 
 
 def _pick(t: int, pref: int, step: int) -> int | None:
@@ -473,10 +462,10 @@ def _plan(n: int, v: int, d: int, x_bytes: int = 2, e_bytes: int = 2,
     def largest_in(limit):
         return next((f for f in fits if f[0] <= limit), None)
 
-    fit = largest_in(_SCOPED_DEFAULT)
+    fit = largest_in(backend.SCOPED_VMEM_DEFAULT)
     if fit is not None:
         return _Plan(fit[1], bv, fit[2], None)
-    need, bn, sn = (largest_in((vmem or _vmem_capacity()) // 2)
+    need, bn, sn = (largest_in((vmem or backend.vmem_capacity()) // 2)
                     or fits[-1])
     # the estimate and a quarter for what it cannot see
     return _Plan(bn, bv, sn, -(-(need + need // 4) // _MIB) * _MIB)

@@ -8,6 +8,7 @@ A compile that passes is not a chip run: it says nothing about results
 or times. `chip_smoke.py` is the run.
 """
 
+import math
 import os
 import re
 
@@ -55,12 +56,17 @@ def compile_as_on_tpu(monkeypatch):
     compilation_cache.reset_cache()
 
 
-def compiled_text(topo, fn, *args) -> str:
-    """The program `fn` compiles to for one described chip."""
+def compiled_for(topo, fn, *args):
+    """`fn` compiled for one described chip."""
     one = SingleDeviceSharding(topo.devices[0])
     args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one)
             for shape, dtype in args]
-    return jax.jit(fn).lower(*args).compile().as_text()
+    return jax.jit(fn).lower(*args).compile()
+
+
+def compiled_text(topo, fn, *args) -> str:
+    """The program `fn` compiles to for one described chip."""
+    return compiled_for(topo, fn, *args).as_text()
 
 
 def kernel_names(text: str) -> list[str]:
@@ -100,6 +106,39 @@ def test_paged_decode_kernel_compiles(topo, kv_dtype):
         topo, with_scales(da.paged_decode_attention),
         ((SLOTS, H, D), BF16), k, v, ((SLOTS, MB), I32), ((SLOTS,), I32),
         *scales) == 1
+
+
+# `olmo-1b.chat-closed64` / `olmo-1b.chat-steady`: 32 slots, 16 heads of
+# 128, 2049 blocks of 16, tables of 128
+CELL_SLOTS, CELL_D, CELL_NB, CELL_MB = 32, 128, 2049, 128
+
+
+@pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8], ids=["bf16", "int8"])
+def test_paged_decode_takes_the_cells_pool_as_stored(topo, kv_dtype):
+    """The serving cells' own shape: one Mosaic kernel named
+    `paged_decode`, and the pool reaches it as the program's parameter:
+    nothing else in the program makes an array of the pool's size (no
+    copy, transpose or fusion of it at a head size of 128; at 64, above,
+    XLA stores the pool padded and the wrapper lays it out once)."""
+    pool = (CELL_NB, BS, H, CELL_D)
+    args = [((CELL_SLOTS, H, CELL_D), BF16), (pool, kv_dtype),
+            (pool, kv_dtype), ((CELL_SLOTS, CELL_MB), I32),
+            ((CELL_SLOTS,), I32)]
+    if kv_dtype == jnp.int8:
+        args += [(pool[:3], jnp.float32)] * 2
+    compiled = compiled_for(topo, with_scales(da.paged_decode_attention),
+                            *args)
+    text = compiled.as_text()
+    assert kernel_names(text) == ["paged_decode"]
+    name = {BF16: "bf16", jnp.int8: "s8"}[kv_dtype]
+    size = CELL_NB * BS * H * CELL_D
+    made = [line.strip() for line in text.splitlines()
+            for m in [re.search(rf" = {name}\[([\d,]+)\]", line)]
+            if m and " parameter(" not in line
+            and math.prod(map(int, m.group(1).split(","))) >= size]
+    assert made == []
+    # int8: the two scale arrays are laid out for the kernel, 2 MiB each
+    assert compiled.memory_analysis().temp_size_in_bytes < size // 8
 
 
 @pytest.mark.parametrize("kv_dtype", [BF16, jnp.int8], ids=["bf16", "int8"])

@@ -50,6 +50,45 @@ def make_engine(cfg, params, **kw):
 # paged decode attention
 # ---------------------------------------------------------------------------
 
+# (heads, head size, block size): the cells' own, `datadecide-300m`'s
+# head size, and one whose heads fill a single row of lanes
+KERNEL_SHAPES = [(16, 128, 16), (16, 64, 16), (4, 32, 8)]
+
+
+def edge_batch(h, d, bs, dtype, seed=0):
+    """Six rows against one scrambled pool, `turn` the kernel's pages a
+    turn: an idle row (pos 0, a table of zeros), one ending on a block's
+    last position, one on a block's first, one filling the whole table
+    (2 turns and 3 pages), one a page past a turn, one of exactly a
+    turn. Entries past a row's length point at other rows' blocks.
+    -> (q, k_pool, v_pool, tables, pos)."""
+    turn = 128 // bs
+    mb = 2 * turn + 3
+    pos = np.array([0, 3 * bs - 1, 3 * bs, mb * bs - 1,
+                    (turn + 1) * bs - 5, turn * bs - 1], np.int32)
+    rng = np.random.default_rng(seed)
+    live = pos // bs + 1
+    live[0] = 0
+    nb = 1 + int(live.sum())
+    blocks = iter(rng.permutation(nb - 1) + 1)
+    tables = rng.integers(1, nb, size=(len(pos), mb)).astype(np.int32)
+    tables[0] = 0
+    for i, n in enumerate(live):
+        tables[i, :n] = [next(blocks) for _ in range(n)]
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
+    pool = lambda key: jax.random.normal(key, (nb, bs, h, d)).astype(dtype)
+    q = jax.random.normal(kq, (len(pos), h, d)).astype(dtype)
+    return q, pool(kk), pool(kv), jnp.asarray(tables), jnp.asarray(pos)
+
+
+def dead_blocks(nb, bs, tables, pos):
+    """Blocks that no decoding row (row 0 is idle) reads at its pos."""
+    tables, pos = np.asarray(tables), np.asarray(pos)
+    read = {int(b) for i in range(1, len(pos))
+            for b in tables[i, :pos[i] // bs + 1]}
+    return jnp.asarray(sorted(set(range(nb)) - read))
+
+
 class TestPagedAttention:
     def _paged(self, b, s, h, d, bs, seed=0):
         """Random contiguous K/V scattered into a scrambled block pool;
@@ -116,6 +155,53 @@ class TestPagedAttention:
                                          jnp.asarray(vp2), tables, pos,
                                          impl="jax")
         np.testing.assert_array_equal(np.asarray(out), np.asarray(out2))
+
+
+    @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+    @pytest.mark.parametrize("h,d,bs", KERNEL_SHAPES)
+    def test_kernel_matches_reference_on_the_edges(self, h, d, bs, dtype):
+        """`paged_decode` (interpret mode) against the gather-then-attend
+        reference on `edge_batch`'s rows, whole pages and ragged ones."""
+        q, kp, vp, tables, pos = edge_batch(h, d, bs, dtype)
+        ref = da.reference_paged_decode_attention(q, kp, vp, tables, pos)
+        out = da.paged_decode_attention(q, kp, vp, tables, pos,
+                                        impl="pallas")
+        assert out.dtype == q.dtype and out.shape == q.shape
+        tol = 2e-5 if dtype == "float32" else 2e-2
+        np.testing.assert_allclose(np.asarray(out, np.float32),
+                                   np.asarray(ref, np.float32),
+                                   atol=tol, rtol=tol)
+
+    @pytest.mark.parametrize("h,d,bs", KERNEL_SHAPES)
+    def test_kernel_never_reads_a_dead_block(self, h, d, bs):
+        """NaN in every block no decoding row's live table entry names
+        (the trash block and the blocks that entries past a length point
+        at among them): the decoding rows get the clean pool's answer."""
+        q, kp, vp, tables, pos = edge_batch(h, d, bs, "float32")
+        ref = da.reference_paged_decode_attention(q, kp, vp, tables, pos)
+        dead = dead_blocks(kp.shape[0], bs, tables, pos)
+        out = da.paged_decode_attention(
+            q, kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan),
+            tables, pos, impl="pallas")
+        np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("shape,kw,want", [
+        ((16, 16, 128, "bfloat16"), {}, (1, 8, None)),      # the cells'
+        ((16, 16, 64, "bfloat16"), {}, (2, 8, None)),       # two heads a row
+        ((8, 4, 32, "float32"), {}, (4, 16, None)),
+        ((16, 16, 128, "int8"), {"quantized": True}, (1, 8, None)),
+        ((16, 12, 96, "bfloat16"), {}, None),       # no row of 128 lanes
+        ((16, 2, 128, "float32"), {}, None),        # heads under a tile
+        ((8, 4, 32, "int8"), {"quantized": True}, None),    # scale rows
+        ((256, 64, 128, "bfloat16"), {}, (1, 1, 60 << 20)),  # asks VMEM
+        ((256, 64, 128, "bfloat16"), {"vmem": 32 << 20}, None),
+    ])
+    def test_the_plan_comes_from_the_shapes(self, shape, kw, want):
+        bs, h, d, dtype = shape
+        kw = {"quantized": False, **kw}
+        plan = da._decode_plan(bs, h, d, jnp.dtype(dtype), **kw)
+        assert plan == (want and da._DecodePlan(*want))
 
 
 # ---------------------------------------------------------------------------
@@ -799,6 +885,38 @@ class TestQuantizedPagedAttention:
             q, kq, vq, tables, pos, k_scale=ksc, v_scale=vsc,
             impl="pallas")
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    @pytest.mark.parametrize("h,d,bs", KERNEL_SHAPES)
+    def test_kernel_matches_reference_on_the_edges(self, h, d, bs):
+        """`edge_batch` on an int8 pool: payload and scales page by page,
+        same rounding as the gather-then-dequantize reference."""
+        q, kp, vp, tables, pos = edge_batch(h, d, bs, "float32", seed=2)
+        kq, ksc = quant.quantize_rows(kp)
+        vq, vsc = quant.quantize_rows(vp)
+        ref = da.reference_paged_decode_attention(
+            q, kq, vq, tables, pos, k_scale=ksc, v_scale=vsc)
+        out = da.paged_decode_attention(
+            q, kq, vq, tables, pos, k_scale=ksc, v_scale=vsc,
+            impl="pallas")
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
+    def test_kernel_never_reads_a_dead_scale(self):
+        """An int8 payload cannot hold a NaN, its scales can: NaN scales
+        in every block no decoding row reads, garbage payload there."""
+        h, d, bs = KERNEL_SHAPES[0]
+        q, kp, vp, tables, pos = edge_batch(h, d, bs, "float32", seed=3)
+        kq, ksc = quant.quantize_rows(kp)
+        vq, vsc = quant.quantize_rows(vp)
+        ref = da.reference_paged_decode_attention(
+            q, kq, vq, tables, pos, k_scale=ksc, v_scale=vsc)
+        dead = dead_blocks(kp.shape[0], bs, tables, pos)
+        out = da.paged_decode_attention(
+            q, kq.at[dead].set(127), vq.at[dead].set(-128), tables, pos,
+            k_scale=ksc.at[dead].set(jnp.nan),
+            v_scale=vsc.at[dead].set(jnp.nan), impl="pallas")
+        np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
                                    atol=2e-5, rtol=2e-5)
 
     def test_quantized_close_to_f32(self):
