@@ -1,6 +1,7 @@
 """The quickest proof that both TPU paths still start on the chip.
 
-    python chip_smoke.py            # one chip: serve phase, then train phase
+    python chip_smoke.py            # one chip: serve phase (both families),
+                                    # then train phase
     python chip_smoke.py --chips 4  # one four-chip host: sharded train step
                                     # against one chip, then two replicas
     python chip_smoke.py --phase serve-load   # one chip: 16 closed-loop
@@ -14,7 +15,11 @@ the full width of the repo's bench model, random weights from `--seed`,
 and checks what comes out against references computed the plain way.
 The serve phase runs its streams a second time under a `jax.profiler`
 trace taken inside the replica, Python tracer off, and prints what the
-program's own spans (`engine/*`, `stream/*`) and named kernels say.
+program's own spans (`engine/*`, `stream/*`) and named kernels say. It
+then serves the power-retention family (`models/retention.py`: a state of
+fixed size a sequence) from an engine in a process of its own, at the
+published head size so that both of its kernels run, and fails on a
+fallback of either as the first part does for the paged kernels.
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -613,6 +618,122 @@ def check_serve_trace(rep: dict, *, on_tpu: bool, replicas: int) -> None:
 
 
 # ---------------------------------------------------------------------------
+# serve phase, second family: a state of fixed size a sequence
+# ---------------------------------------------------------------------------
+
+# `models/retention.py` at the published head (128 dims, so both kernels
+# have a plan) and otherwise tiny: 6 query heads over 2 key-value heads, a
+# state block of 19 MB
+RETENTION_CFG = dict(vocab_size=512, d_model=256, n_layers=2, n_heads=6,
+                     n_kv_heads=2, head_dim=128, d_ff=512, max_seq_len=1024)
+# bfloat16 activations against the float32 definition, two layers; a squared
+# score doubles a relative error (first chip run, PR 34: see PERF.md)
+RETENTION_LOGPROB_MAX_TOL = 6e-2
+RETENTION_LOGPROB_MEAN_TOL = 2e-2
+
+
+def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
+                          prompt_lens: tuple[int, int], new_tokens: int,
+                          slots: int, seed: int) -> None:
+    """The engine over the power-retention family, in this process:
+    `streams` greedy requests over `slots` state blocks (so blocks are
+    reused), chunked prefill in both buckets and then steps. Holds the
+    streamed logprobs to the float32 definition over the same tokens, the
+    two programs to their kernels by name, and `ops.backend.note_fallback`
+    to silence, as the first serve phase holds the paged kernels."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import retention
+    from ray_tpu.serve.engine import InferenceEngine
+    fallbacks = watch_op_fallbacks()
+    compiles = CompileWatch()
+    cfg = retention.RetentionConfig(**cfg_kwargs)
+    params = retention.init_params(jax.random.key(seed), cfg)
+    eng = InferenceEngine(params, cfg, slots=slots, max_len=1024,
+                          prefill_chunk=512, prefill_buckets=(128, 512),
+                          prefix_cache=False, seed=seed)
+    rng = np.random.default_rng(seed)
+    prompts = [rng.integers(0, cfg.vocab_size, int(rng.integers(
+        prompt_lens[0], prompt_lens[1] + 1))).astype(np.int32)
+        for _ in range(streams)]
+    t0 = time.perf_counter()
+    rids = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
+    outs = [list(eng.tokens_for(r)) for r in rids]
+    wall_s = time.perf_counter() - t0
+    cfg32 = dataclasses.replace(cfg, dtype="float32", retention_impl="jax")
+    diffs = []
+    with jax.default_matmul_precision("highest"):
+        for p, out in zip(prompts, outs):
+            seq = np.concatenate([p, [int(t) for t in out]])
+            logits = retention.forward(params, jnp.asarray(seq[None],
+                                                           jnp.int32),
+                                       cfg32)[0, len(p) - 1:-1]
+            want = jnp.take_along_axis(
+                jax.nn.log_softmax(logits, -1),
+                jnp.asarray(seq[len(p):, None], jnp.int32), -1)[:, 0]
+            diffs.append(np.abs(np.asarray(want, np.float64) - np.asarray(
+                [t.logprob for t in out], np.float64)))
+    diff = np.concatenate(diffs)
+    abstract = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+        (params, eng.cache))
+
+    def i32(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    decode_k, decode_n, _ = kernels_of(
+        jax.jit(lambda p, t, c, pos, tb: retention.decode(
+            p, t, c, pos, tb, cfg)[:2]),
+        abstract[0], i32(slots), abstract[1], i32(slots), i32(slots, 1))
+    prefill_k, prefill_n, _ = kernels_of(
+        jax.jit(lambda p, t, c, tb, st, ln: retention.prefill(
+            p, t, c, cfg, block_table=tb, start=st, length=ln)),
+        abstract[0], i32(1, 512), abstract[1], i32(1), i32(), i32())
+    stats, device = eng.stats(), device_report()
+    emit({"phase": "serve_retention", "streams": streams,
+          "new_tokens": new_tokens,
+          "prompt_lens": [len(p) for p in prompts],
+          "wall_s": round(wall_s, 2),
+          "logprob_max_abs_diff": float(diff.max()),
+          "logprob_mean_abs_diff": float(diff.mean()),
+          "kernels": {"decode": decode_k, "prefill": prefill_k},
+          "tpu_custom_calls": {"decode": decode_n, "prefill": prefill_n},
+          "op_fallbacks": list(fallbacks),
+          "stats": {k: stats[k] for k in (
+              "decode_traces", "prefill_traces", "retraces_unexpected",
+              "decode_tokens", "prefill_tokens", "prefill_chunks",
+              "state_resets", "retention_tokens_live",
+              "retention_tokens_padded", "cache_blocks", "pool_bytes",
+              "p50_token_latency_ms")},
+          **compiles.report(), **device,
+          "peak_bytes_in_use": peak_bytes()})
+    check(device["platform"] == platform,
+          f"the engine runs on {device['platform']}")
+    check(all(len(o) == new_tokens for o in outs),
+          "a stream came back short")
+    check(stats["decode_traces"] == 1 and stats["retraces_unexpected"] == 0
+          and stats["prefill_traces"] == 2,
+          f"compile-once broke: {stats['decode_traces']} decode, "
+          f"{stats['prefill_traces']} prefill traces")
+    check(stats["state_resets"] == streams,
+          f"{stats['state_resets']} first chunks for {streams} requests")
+    check(diff.max() <= RETENTION_LOGPROB_MAX_TOL
+          and diff.mean() <= RETENTION_LOGPROB_MEAN_TOL,
+          f"engine logprobs are {diff.max()} (max) / {diff.mean()} (mean) "
+          f"from the float32 definition (tolerances "
+          f"{RETENTION_LOGPROB_MAX_TOL} / {RETENTION_LOGPROB_MEAN_TOL})")
+    check(not fallbacks, f"ops fell back to pure JAX: {list(fallbacks)}")
+    if platform == "tpu":
+        check(decode_k == ["retention_step"]
+              and decode_n == cfg.n_layers,
+              f"no step kernel a layer: {decode_k} x {decode_n}")
+        check(prefill_k == ["retention_chunk"]
+              and prefill_n == cfg.n_layers,
+              f"no chunk kernel a layer: {prefill_k} x {prefill_n}")
+
+
+# ---------------------------------------------------------------------------
 # serve under load: where a client's time to the first token goes
 # ---------------------------------------------------------------------------
 
@@ -967,9 +1088,11 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", choices=("train", "train4", "serve-load"),
-                    help="serve-load: see the top of this file; train and "
-                    "train4 are how a phase child is started")
+    ap.add_argument("--phase", choices=("train", "train4", "serve-load",
+                                        "serve-retention"),
+                    help="serve-load: see the top of this file; train, "
+                    "train4 and serve-retention are how a phase child is "
+                    "started")
     args = ap.parse_args()
 
     if args.phase == "train":
@@ -979,6 +1102,11 @@ def main() -> int:
     if args.phase == "train4":
         train4_phase(TRAIN_CFG, platform="tpu", batch=8, steps=8,
                      seed=args.seed)
+        return 0
+    if args.phase == "serve-retention":
+        serve_retention_phase(RETENTION_CFG, platform="tpu", streams=6,
+                              prompt_lens=(100, 700), new_tokens=32,
+                              slots=4, seed=args.seed)
         return 0
 
     # Which device JAX finds, asked in a process that exits again.
@@ -1012,6 +1140,7 @@ def main() -> int:
             serve_phase(WIDTHS, platform="tpu", replicas=1, streams=6,
                         prompt_lens=(128, 512), new_tokens=64, slots=8,
                         max_len=1024, seed=args.seed)
+            run_phase_child("serve-retention", args.seed)
             run_phase_child("train", args.seed)
         else:
             run_phase_child("train4", args.seed)

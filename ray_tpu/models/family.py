@@ -27,6 +27,14 @@ class ServingFamily(NamedTuple):
     counts(cfg, totals) -> {name: number}: what the int32 vector that
             prefill and decode return third, summed over a window, adds
             to `stats()`; None where they return None
+    state_blocks: None for a cache that grows, a block a range of
+            `block_size` tokens written once. A number (1) for a family
+            whose block is a sequence's whole state, of fixed size and
+            rewritten by every token: a request then holds that many
+            blocks whatever its length, its table has that width, and
+            the engine keeps no prefix tree (a block's content names no
+            range of tokens) and re-prefills a preempted stream from its
+            first token
     """
     init_pool: Callable
     prefill: Callable
@@ -37,3 +45,4 @@ class ServingFamily(NamedTuple):
     verify: Callable | None = None
     quantize: Callable | None = None
     counts: Callable | None = None
+    state_blocks: int | None = None

@@ -1,0 +1,300 @@
+"""A decoder whose sequence mixer is power retention: the layer that
+`model_type: brumby` (Brumby-14B-Base) names, served from a state of
+fixed size a sequence instead of a cache that grows.
+
+The layer (`n = RMSNorm(x)` with a learned scale; 40 query heads over 8
+key-value heads at the published widths, five a group):
+
+    q^i = rot(norm_q(W_q^i n)),  k^j = rot(norm_k(W_k^j n)),  v^j = W_v^j n
+    log g^j_t = log sigmoid(w_g^j . n_t + b_g^j)          (<= 0, a scalar)
+    a^i[t, s] = (q^i_t . k^j_s)^2 / d  x  exp(sum_{r=s+1..t} log g^j_r)
+    o^i_t = sum_{s<=t} a^i[t, s] v^j_s / (sum_{s<=t} a^i[t, s] + eps)
+    h = x + W_o concat_i(o^i);   y = h + W_down(silu(W_gate m) * W_up m),
+    m = RMSNorm(h)
+
+`norm_q`, `norm_k`: RMSNorm over a head's dims with a learned scale;
+`rot`: rotary in halves (the Qwen3 layout). `ops/power_retention.py` has
+the state form of the same numbers, the two kernels and the layout.
+
+**What the engine holds for this family**: one block a sequence, the
+state of every layer and key-value head (`s [L, blocks, Hkv, d, D]`,
+`z [L, blocks, Hkv, 1, D]`, float32), rewritten by every token. The
+family says so through `ServingFamily.state_blocks`; the engine then
+gives a request one block whatever its length and keeps no prefix tree.
+Prefill resets the block on a sequence's first chunk (`start == 0`),
+leaves it untouched by a chunk bucket's padding, and decode's idle rows
+(table 0) rewrite the trash block.
+
+`forward` is the whole-sequence form for tests; `prefill` and `decode`
+are what `ServingFamily` asks.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import gpt
+from ray_tpu.models.family import ServingFamily
+from ray_tpu.ops import power_retention
+
+# what the prefill program counts, in the order of the int32 vector it
+# returns beside the logits (the decode program counts nothing)
+COUNTS = ("retention_tokens_live", "retention_tokens_padded", "state_resets")
+
+
+@dataclass(frozen=True)
+class RetentionConfig:
+    vocab_size: int = 512
+    d_model: int = 64
+    n_layers: int = 2
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    d_ff: int = 128
+    rope_theta: float = 1e6
+    eps: float = 1e-6               # RMSNorm's
+    retention_eps: float = 1e-6     # the normaliser's
+    max_seq_len: int = 128
+    dtype: str = "bfloat16"
+    # test-only, for the benchmark's control: "bfloat16" rounds the state
+    # to bfloat16 at every write and keeps float32 bytes
+    state_round: str = "none"       # none | bfloat16
+    retention_impl: str = "auto"    # auto | pallas | jax (both ops)
+
+    def __post_init__(self):
+        if self.n_heads % self.n_kv_heads:
+            raise ValueError(f"{self.n_heads} query heads over "
+                             f"{self.n_kv_heads} key-value heads")
+        if self.state_round not in ("none", "bfloat16"):
+            raise ValueError(f"unknown state_round {self.state_round!r}")
+        power_retention.feature_dim(self.head_dim)
+
+    @property
+    def feature_dim(self) -> int:
+        return power_retention.feature_dim(self.head_dim)
+
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def family(self):
+        return FAMILY
+
+
+def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
+                   num_key_value_heads, intermediate_size, rms_norm_eps,
+                   max_position_embeddings, **same):
+    """The configuration file's published keys -> `RetentionConfig`
+    (`benchmarks/configs/brumby-14b.json`, `program.constructor`)."""
+    return RetentionConfig(
+        d_model=hidden_size, n_layers=num_hidden_layers,
+        n_heads=num_attention_heads, n_kv_heads=num_key_value_heads,
+        d_ff=intermediate_size, eps=rms_norm_eps,
+        max_seq_len=max_position_embeddings, **same)
+
+
+def init_params(key, cfg: RetentionConfig):
+    """Float32 leaves, for tests; the tree `benchmarks/refs/
+    retention_decoder.py` documents. Gate biases spread over the heads so
+    that a head remembers from tens to thousands of positions."""
+    d, hd = cfg.d_model, cfg.head_dim
+    hq, hkv, f = cfg.n_heads, cfg.n_kv_heads, cfg.d_ff
+    residual = (2.0 * cfg.n_layers) ** -0.5
+    keys = iter(jax.random.split(key, 2 + 8 * cfg.n_layers))
+
+    def normal(shape, scale):
+        return jax.random.normal(next(keys), shape, jnp.float32) * scale
+
+    layers = [{
+        "mix_norm_scale": jnp.ones((d,)), "mlp_norm_scale": jnp.ones((d,)),
+        "w_q": normal((d, hq * hd), d ** -0.5),
+        "w_k": normal((d, hkv * hd), d ** -0.5),
+        "w_v": normal((d, hkv * hd), d ** -0.5),
+        "q_norm_scale": jnp.ones((hd,)), "k_norm_scale": jnp.ones((hd,)),
+        "w_g": normal((d, hkv), d ** -0.5),
+        "b_g": jnp.linspace(4.0, 8.0, hkv),
+        "w_o": normal((hq * hd, d), (hq * hd) ** -0.5 * residual),
+        "w_gate": normal((d, f), d ** -0.5),
+        "w_up": normal((d, f), d ** -0.5),
+        "w_down": normal((f, d), f ** -0.5 * residual),
+    } for _ in range(cfg.n_layers)]
+    return {"embed": normal((cfg.vocab_size, d), 0.02),
+            "head": normal((cfg.vocab_size, d), d ** -0.5),
+            "final_norm_scale": jnp.ones((d,)), "layers": layers}
+
+
+# ---------------------------------------------------------------------------
+# the pool
+# ---------------------------------------------------------------------------
+
+def init_pool(cfg: RetentionConfig, n_blocks: int, block_size: int,
+              mesh=None):
+    """{"s", "z"}, zero-filled float32; blocks on axis 1 of both, a block
+    one sequence's state. `block_size` (tokens a block of a paged family)
+    sizes nothing here."""
+    if mesh is not None:
+        raise ValueError("this family's pool is not sharded over a mesh")
+    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads)
+    return {"s": jnp.zeros(shape + (cfg.head_dim, cfg.feature_dim),
+                           jnp.float32),
+            "z": jnp.zeros(shape + (1, cfg.feature_dim), jnp.float32)}
+
+
+# ---------------------------------------------------------------------------
+# pieces of the layer
+# ---------------------------------------------------------------------------
+
+def _mm(x, w, adt):
+    return jnp.einsum("...d,df->...f", x, w.astype(adt),
+                      preferred_element_type=jnp.float32).astype(adt)
+
+
+def _norm(x, scale, cfg):
+    return gpt._rms_norm(x, scale.astype(x.dtype), cfg.eps)
+
+
+def rope(x, pos, theta: float):
+    """Rotary embedding on the last axis of x [N, H, d] at positions pos
+    [N], in halves: (x[i], x[i + d/2]) turned by pos * theta^(-2i/d);
+    float32 inside."""
+    half = x.shape[-1] // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
+    ang = pos.astype(jnp.float32)[:, None, None] * inv
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    xf = x.astype(jnp.float32)
+    x1, x2 = xf[..., :half], xf[..., half:]
+    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
+                           -1).astype(x.dtype)
+
+
+def _project(h, lp, pos, cfg):
+    """Normed h [N, D] at positions pos [N] -> (q [N, Hq, d], k, v
+    [N, Hkv, d], log g [N, Hkv] float32)."""
+    adt = cfg.activation_dtype()
+    n = h.shape[0]
+    q = _mm(h, lp["w_q"], adt).reshape(n, cfg.n_heads, cfg.head_dim)
+    k = _mm(h, lp["w_k"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+    v = _mm(h, lp["w_v"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+    q = rope(_norm(q, lp["q_norm_scale"], cfg), pos, cfg.rope_theta)
+    k = rope(_norm(k, lp["k_norm_scale"], cfg), pos, cfg.rope_theta)
+    gate = jnp.einsum("nd,dj->nj", h, lp["w_g"].astype(adt),
+                      preferred_element_type=jnp.float32)
+    return q, k, v, jax.nn.log_sigmoid(gate + lp["b_g"].astype(jnp.float32))
+
+
+def _mlp(x, lp, cfg):
+    adt = cfg.activation_dtype()
+    m = _norm(x, lp["mlp_norm_scale"], cfg)
+    hidden = jax.nn.silu(_mm(m, lp["w_gate"], adt)) * _mm(m, lp["w_up"], adt)
+    return x + _mm(hidden, lp["w_down"], adt)
+
+
+def _mixed(x, o, lp, cfg):
+    """The residual after the retention's output o [N, Hq, d] f32."""
+    adt = cfg.activation_dtype()
+    return x + _mm(o.astype(adt).reshape(o.shape[0], -1), lp["w_o"], adt)
+
+
+def _unembed(x, params, cfg):
+    return jnp.einsum("...d,vd->...v", x,
+                      params["head"].astype(cfg.activation_dtype()),
+                      preferred_element_type=jnp.float32)
+
+
+def summarize(cfg, totals) -> dict:
+    """`COUNTS` summed over a window (None: nothing ran yet) -> the
+    engine's `stats()` entries."""
+    if totals is None:
+        totals = [0] * len(COUNTS)
+    return {name: int(totals[i]) for i, name in enumerate(COUNTS)}
+
+
+# ---------------------------------------------------------------------------
+# whole sequence (tests)
+# ---------------------------------------------------------------------------
+
+def forward(params, tokens, cfg: RetentionConfig):
+    """tokens [B, T] -> logits [B, T, V] f32, by the definition (the
+    masked square over the whole sequence; no state)."""
+    adt = cfg.activation_dtype()
+
+    def one(seq):
+        pos = jnp.arange(seq.shape[0])
+        x = params["embed"].astype(adt)[seq]
+        for lp in params["layers"]:
+            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
+                                     lp, pos, cfg)
+            o = power_retention.retention_quadratic(
+                q, k, v, logg, eps=cfg.retention_eps)
+            x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
+        return _unembed(_norm(x, params["final_norm_scale"], cfg), params,
+                        cfg)
+
+    return jax.lax.map(one, tokens)
+
+
+# ---------------------------------------------------------------------------
+# what the engine calls
+# ---------------------------------------------------------------------------
+
+def prefill(params, tokens, cache, cfg: RetentionConfig, mesh=None, *,
+            block_table, start, length=None):
+    """One chunk of one sequence (`gpt.prefill_paged`'s contract): tokens
+    [1, C] at positions start .. start + length - 1, against the state in
+    block `block_table[0]`; a chunk that starts the sequence resets it.
+    -> (logits [1, V] f32 of the chunk's last real position, cache,
+    counts)."""
+    c = tokens.shape[1]
+    if tokens.shape[0] != 1:
+        raise ValueError(f"prefill wants tokens [1, C], got batch "
+                         f"{tokens.shape[0]}")
+    adt = cfg.activation_dtype()
+    start = jnp.asarray(start, jnp.int32)
+    length = jnp.asarray(c if length is None else length, jnp.int32)
+    block = jnp.asarray(block_table, jnp.int32)[0]
+    first = start == 0
+    positions = start + jnp.arange(c, dtype=jnp.int32)
+    s, z = cache["s"], cache["z"]
+    x = params["embed"].astype(adt)[tokens[0]]
+    for i, lp in enumerate(params["layers"]):
+        q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg), lp,
+                                 positions, cfg)
+        o, s, z = power_retention.retention_chunk(
+            q, k, v, logg, s, z, i, block, first, length,
+            eps=cfg.retention_eps, state_round=cfg.state_round,
+            impl=cfg.retention_impl)
+        x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
+    x = _norm(x, params["final_norm_scale"], cfg)
+    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+    counts = jnp.stack([length, c - length, first.astype(jnp.int32)])
+    return _unembed(last, params, cfg), {"s": s, "z": z}, counts
+
+
+def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
+           mesh=None):
+    """One token for every slot (`gpt.decode_step_paged`'s contract):
+    tokens [B] at positions pos [B], each row's state in block
+    `tables[:, 0]`. Idle rows name the trash block and rewrite it.
+    -> (logits [B, V] f32, cache, None)."""
+    adt = cfg.activation_dtype()
+    blocks = tables.astype(jnp.int32)[:, 0]
+    s, z = cache["s"], cache["z"]
+    x = params["embed"].astype(adt)[tokens]
+    for i, lp in enumerate(params["layers"]):
+        q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg), lp,
+                                 pos.astype(jnp.int32), cfg)
+        o, s, z = power_retention.retention_step(
+            q, k, v, logg, s, z, i, blocks, eps=cfg.retention_eps,
+            state_round=cfg.state_round, impl=cfg.retention_impl)
+        x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
+    x = _norm(x, params["final_norm_scale"], cfg)
+    return _unembed(x, params, cfg), {"s": s, "z": z}, None
+
+
+FAMILY = ServingFamily(
+    init_pool=init_pool, prefill=prefill, decode=decode,
+    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
+    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1)

@@ -14,7 +14,11 @@ below are that family's; `gpt.`'s names stand for them:
 - **paged allocation**: each request holds exactly the blocks its
   prompt + generation footprint needs (a 100-token chat no longer pins a
   4k-token row). `BlockAllocator` refcounts physical blocks; block 0 is
-  the trash block idle decode rows scatter into.
+  the trash block idle decode rows scatter into. A family may instead
+  keep a state of fixed size a sequence (`ServingFamily.state_blocks`,
+  `models/retention.py`): a request then holds that many blocks whatever
+  its length, under the same allocator, release, cancel and hand-off,
+  and the two points below do not apply to it.
 - **radix prefix sharing**: a host-side `RadixTree` maps token prefixes
   to cached blocks at block granularity. A repeated system prompt is
   prefilled ONCE; later requests admit by taking references on the
@@ -531,7 +535,18 @@ class InferenceEngine:
         self.num_slots = slots
         self.max_len = cfg.max_seq_len if max_len is None else max_len
         self.block_size = block_size
-        self.max_blocks = -(-self.max_len // block_size)
+        # A family whose block is a sequence's whole state says how many
+        # a sequence holds (`ServingFamily.state_blocks`); a block is
+        # then no range of tokens, and `block_size` sizes nothing.
+        self._state_blocks = fam.state_blocks
+        if self._state_blocks and prefix_cache:
+            raise ValueError(
+                f"prefix_cache=True: a block of {type(cfg).__name__}'s "
+                "family is a sequence's whole state, rewritten by every "
+                "token, so no block can be shared as a prefix; pass "
+                "prefix_cache=False")
+        self.max_blocks = self._state_blocks \
+            or -(-self.max_len // block_size)
         self.cache_blocks = (slots * self.max_blocks
                              if cache_blocks is None else cache_blocks)
         self.buckets = tuple(sorted(
@@ -634,9 +649,13 @@ class InferenceEngine:
         if self.draft_cache is not None:
             self._pool_bytes += sum(
                 int(arr.nbytes) for arr in self.draft_cache.values())
+        # tokens a block stands for: a state block's share of a
+        # sequence of up to max_len
+        block_tokens = (self.max_len / self._state_blocks
+                        if self._state_blocks else block_size)
         self._kv_bytes_per_token = (
             sum(int(arr.nbytes) for arr in self.cache.values())
-            / ((self.cache_blocks + 1) * block_size))
+            / ((self.cache_blocks + 1) * block_tokens))
 
         def _sample(logits, temps, key, step):
             """Sample one token per row; also return the model's NATURAL
@@ -1050,12 +1069,16 @@ class InferenceEngine:
     # request side
     # ------------------------------------------------------------------
 
+    def _written_blocks(self, n: int) -> int:
+        """Blocks that hold a sequence's first `n` tokens: for a family
+        of state blocks the same whatever `n`."""
+        return self._state_blocks or (n - 1) // self.block_size + 1
+
     def _blocks_for(self, p: int, max_new: int) -> int:
         """Blocks a request's full footprint needs: prefill writes
         positions 0..p-1, decode writes p..p+max_new-2 (the final
         sampled token is never written)."""
-        highest = p - 1 + max(max_new - 1, 0)
-        return highest // self.block_size + 1
+        return self._written_blocks(p + max(max_new - 1, 0))
 
     def _slot_blocks_for(self, p: int, max_new: int) -> int:
         """Blocks THIS engine must hold for a request. A prefill-role
@@ -1065,7 +1088,7 @@ class InferenceEngine:
         engine's problem. Every other role needs the full
         prompt+generation footprint (`_blocks_for`)."""
         if self.role == "prefill":
-            return (p - 1) // self.block_size + 1
+            return self._written_blocks(p)
         return self._blocks_for(p, max_new)
 
     def submit(self, prompt, max_new_tokens: int = 16,
@@ -1357,7 +1380,7 @@ class InferenceEngine:
         version the KV was computed under."""
         s = self._slots[slot_idx]
         p = s.prompt.size
-        n_written = (p - 1) // self.block_size + 1
+        n_written = self._written_blocks(p)
         t0 = time.perf_counter()
 
         def _dump(pool, blocks):
@@ -1464,7 +1487,7 @@ class InferenceEngine:
                 f"handoff block_size {blob['block_size']} != engine "
                 f"block_size {self.block_size} — prefill and decode "
                 f"pools must share the paging granule")
-        n_written = (p - 1) // self.block_size + 1
+        n_written = self._written_blocks(p)
         if len(blob["payload"]) != n_written:
             raise ValueError(
                 f"handoff payload has {len(blob['payload'])} blocks, "
@@ -1839,7 +1862,7 @@ class InferenceEngine:
         if req.resumed:
             # blocks' worth of KV this resume recomputes (the radix
             # match absorbed the rest for free)
-            self._reprefill_blocks += -(-(p - matched) // bs)
+            self._reprefill_blocks += self._written_blocks(p - matched)
         s.history = req.prompt.tolist() if self.spec == "ngram" else []
         if self._draft_alloc is not None:
             dblocks = [self._draft_alloc.alloc() for _ in range(total)]
@@ -1917,7 +1940,10 @@ class InferenceEngine:
         queue. The consumer keeps iterating `tokens_for` unaware; a
         greedy stream resumes token-identical because the re-prefilled
         KV is bit-identical to the KV released (prefill and decode
-        share the paged attention math)."""
+        share the paged attention math). With no tree (a family of
+        state blocks keeps none: its block is rewritten by every token)
+        nothing is published and the resume re-prefills from its first
+        token; the first chunk resets the block it is given."""
         s = self._slots[slot_idx]
         seq = [int(t) for t in s.prompt.tolist()] \
             + [int(t) for t in s.emitted]
@@ -2594,6 +2620,16 @@ class InferenceEngine:
         Paged cache:
           ``block_size`` / ``cache_blocks`` / ``blocks_in_use`` /
           ``blocks_free`` — pool geometry and live allocation.
+          For a family of state blocks (`ServingFamily.state_blocks`)
+          a block is one sequence's whole state: ``block_size`` is the
+          constructor's argument and sizes nothing, ``cache_blocks`` is
+          the sequences the pool holds, ``cache_block_utilization`` the
+          share of them in use, and ``kv_bytes_per_token`` the pool's
+          bytes over the `max_len` tokens a block may stand for (what
+          a token of capacity costs; a sequence costs a whole block at
+          any length).
+          ``prefix_cache`` — whether a radix tree is kept (never for a
+          family of state blocks).
           ``cached_prefix_blocks`` — blocks the radix tree holds.
           ``cache_block_utilization`` — mean pool utilization per tick.
           ``prefix_hit_rate`` / ``prefix_hit_tokens`` — prompt tokens
@@ -2811,6 +2847,7 @@ class InferenceEngine:
                 "cache_blocks": self.cache_blocks,
                 "blocks_in_use": self._alloc.used,
                 "blocks_free": self._alloc.free,
+                "prefix_cache": self._tree is not None,
                 "cached_prefix_blocks": (self._tree.n_blocks()
                                          if self._tree else 0),
                 "cache_block_utilization": (sum(util) / len(util)

@@ -463,3 +463,100 @@ def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
     assert mem.temp_size_in_bytes < 1e9                 # and never copied
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- the power-retention family at brumby-14b.docgen-closed24's shapes: 16
+# slots and the trash block, 8 layers, 40 query heads over 8 key-value heads
+# of 128, D = 9216, chunks of 512 and 128, vocabulary 151,936
+
+def _brumby():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import retention_decoder as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "brumby-14b.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+RB, RL, RNB, RHQ, RHKV, RD, RBIG = 16, 8, 17, 40, 8, 128, 9216
+RPOOL = [((RL, RNB, RHKV, RD, RBIG), F32), ((RL, RNB, RHKV, 1, RBIG), F32)]
+
+
+def _retention_chunk_case(c):
+    from ray_tpu.ops import power_retention as pr
+    return (lambda q, k, v, g, s, z, block, first, n: pr.retention_chunk(
+        q, k, v, g, s, z, 3, block, first, n, eps=1e-6, impl="pallas"),
+        [((c, RHQ, RD), BF16), ((c, RHKV, RD), BF16), ((c, RHKV, RD), BF16),
+         ((c, RHKV), F32)] + RPOOL + [((), I32)] * 3)
+
+
+def _retention_step_case():
+    from ray_tpu.ops import power_retention as pr
+    return (lambda q, k, v, g, s, z, blocks: pr.retention_step(
+        q, k, v, g, s, z, 3, blocks, eps=1e-6, impl="pallas"),
+        [((RB, RHQ, RD), BF16), ((RB, RHKV, RD), BF16),
+         ((RB, RHKV, RD), BF16), ((RB, RHKV), F32)] + RPOOL
+        + [((RB,), I32)])
+
+
+RETENTION_KERNELS = {
+    "retention_chunk_512": (_retention_chunk_case(512), "retention_chunk"),
+    "retention_chunk_128": (_retention_chunk_case(128), "retention_chunk"),
+    "retention_step": (_retention_step_case(), "retention_step"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RETENTION_KERNELS))
+def test_retention_kernels_compile_under_their_names(topo, case):
+    (fn, args), name = RETENTION_KERNELS[case]
+    assert kernel_names(compiled_text(topo, fn, *args)) == [name]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_retention_family_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/brumby-14b.json` as the engine jits them (the
+    pool donated): one kernel a layer under its name, every sequence's
+    state updated in place (no copy of the pool among the temporaries),
+    and weights, pool and temporaries fit the chip."""
+    from ray_tpu.models import retention
+    config, cfg, ref = _brumby()
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(
+        lambda: retention.init_pool(cfg, RNB, 16)))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: retention.decode(
+                p, tok, cache, pos, tab, cfg)[:2],
+            donate_argnums=(1,)).lower(
+            params, pool, arg((RB,)), arg((RB,)), arg((RB, 1))).compile()
+        want = {"retention_step": RL}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: retention.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((1,)), arg(()),
+            arg(())).compile()
+        want = {"retention_chunk": RL}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 1e9                 # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)

@@ -42,6 +42,16 @@ def test_serve_phase_tiny_on_cpu(replicas):
     assert '"phase": "serve"' in out
 
 
+def test_serve_retention_phase_tiny_on_cpu():
+    """The second family's part of the serve phase: an engine over
+    `models/retention.py` in the phase's own process, float32 on the CPU
+    (the plain paths), held to the definition."""
+    out = run("cs.serve_retention_phase(dict(cs.RETENTION_CFG, "
+              "head_dim=32, dtype='float32'), platform='cpu', streams=5, "
+              "prompt_lens=(100, 300), new_tokens=6, slots=3, seed=0)")
+    assert '"phase": "serve_retention"' in out
+
+
 def test_serve_load_phase_tiny_on_cpu():
     """16 closed-loop streams for a few seconds on a tiny model, with
     the profiler trace taken inside the replica."""
