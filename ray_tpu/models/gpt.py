@@ -271,8 +271,8 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
 
     The two parts that vary come in as arguments and return
     ``(output, kept)``, where `kept` is whatever the part makes besides
-    its output (the layer's updated pool slice, the experts' aux loss,
-    None) and is handed back as it came:
+    its output (the pool with the layer's rows written, the experts' aux
+    loss, None) and is handed back as it came:
     ``attend(q, k, v)`` on [..., H, Dh] -> ([..., H, Dh], kept);
     ``ffn(h, lp)`` on the normed [..., D] -> ([..., D], kept), the gated
     MLP unless given. Returns ``(x, attend's kept, ffn's kept)``."""
@@ -562,63 +562,81 @@ def scatter_block(cache, block, idx):
             for name in cache}
 
 
-def _scatter_kv(lc, k, v, widx):
+def _scatter_kv(cache, layer, k, v, widx):
     """Write `k`/`v` [..., H, Dh] (activation dtype; N rows over the
-    leading dims) into one layer's pool slice `lc` at flat indices
-    ``widx [N]`` (out-of-bounds rows drop —
-    the padded-tail / past-table convention every paged writer shares).
-    An int8 pool (``"k_scale" in lc`` — a static check) quantizes at the
-    write: payload rows and their (position, head) scale cells scatter
-    through the SAME indices, so single-token appends, chunked prefill
-    and W-token verify all land byte-identical int8 for identical f32
-    inputs (`ops.quant`'s determinism contract). Returns the layer's new
-    cache dict."""
-    nb, bs, nh, hd = lc["k"].shape
-    k, v = k.reshape(-1, nh, hd), v.reshape(-1, nh, hd)
-    kf = lc["k"].reshape(nb * bs, nh, hd)
-    vf = lc["v"].reshape(nb * bs, nh, hd)
-    if "k_scale" in lc:
+    leading dims) into layer `layer` (a traced scalar) of the stacked
+    pool `cache` at that layer's flat row numbers ``widx [N]``. The
+    scatter takes three indices, ``(layer, widx // bs, widx % bs)``, into
+    the pool as it is stored (no reshape: merging the block and offset
+    axes is a copy wherever XLA tiles or orders them its own way, an
+    int8 pool's scales for one). A row whose ``widx`` is ``n_blocks *
+    bs`` or more is out of bounds on the block axis and drops — the
+    padded-tail / past-table convention every paged writer shares. (One
+    flat index into the stacked pool would turn that number into row 0
+    of block 0 of the next layer.) An int8 pool (``"k_scale" in cache``
+    — a static check) quantizes at the write: payload rows and their
+    (position, head) scale cells scatter through the SAME indices, so
+    single-token appends, chunked prefill and W-token verify all land
+    byte-identical int8 for identical f32 inputs (`ops.quant`'s
+    determinism contract). Returns the new stacked cache dict; on a pool
+    that is a loop's carry or a donated argument XLA writes the rows
+    where the pool lies."""
+    bs, nh, hd = cache["k"].shape[2:]
+    rows = {"k": k.reshape(-1, nh, hd), "v": v.reshape(-1, nh, hd)}
+    if "k_scale" in cache:
         from ray_tpu.ops import quant
-        qk, ks = quant.quantize_rows(k)
-        qv, vs = quant.quantize_rows(v)
-        return {
-            "k": kf.at[widx].set(qk, mode="drop").reshape(
-                nb, bs, nh, hd),
-            "v": vf.at[widx].set(qv, mode="drop").reshape(
-                nb, bs, nh, hd),
-            "k_scale": lc["k_scale"].reshape(nb * bs, nh)
-                .at[widx].set(ks, mode="drop").reshape(nb, bs, nh),
-            "v_scale": lc["v_scale"].reshape(nb * bs, nh)
-                .at[widx].set(vs, mode="drop").reshape(nb, bs, nh),
-        }
-    return {
-        "k": kf.at[widx].set(k.astype(kf.dtype), mode="drop").reshape(
-            nb, bs, nh, hd),
-        "v": vf.at[widx].set(v.astype(vf.dtype), mode="drop").reshape(
-            nb, bs, nh, hd),
-    }
+        rows["k"], rows["k_scale"] = quant.quantize_rows(rows["k"])
+        rows["v"], rows["v_scale"] = quant.quantize_rows(rows["v"])
+    return {name: pool.at[layer, widx // bs, widx % bs].set(
+                rows[name].astype(pool.dtype), mode="drop")
+            for name, pool in cache.items()}
 
 
 def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
-    """Scan activations x [..., D] through the layer stack, each layer
-    with its slice `lc` of the pool: the layer's K/V rows are written at
-    flat indices `widx` first (`_scatter_kv`), then ``attend(q, lc)``
-    attends over the written slice. Returns (final-norm activations,
-    the updated pool)."""
+    """Scan activations x [..., D] through the layer stack with the
+    stacked pool `cache` as a carry beside them, so that within a step
+    the pool never leaves the buffer it was donated in: layer `li`'s K/V
+    rows are written into the stacked arrays at ``(li, widx)`` first
+    (`_scatter_kv`), then ``attend(q, cache, li)`` attends over layer
+    `li` of the written pool. (Scanning over the pool instead makes XLA
+    slice every layer out, stack the written layers into a new buffer
+    and copy that onto the donated one: three passes over K and over V a
+    step.)
+
+    A head size under 128 is the exception, told by the decode kernel's
+    own plan (`reads_pool_where_it_lies`): XLA stores such a pool in a
+    layout of its own, and rows can only be written into, and pages read
+    from, a lay-out of it. That lay-out has to be one layer's, so there
+    a layer is taken out of the carry, written and read as a stack of
+    one, and put back where it was; the carry still never moves.
+    Returns (final-norm activations, the updated pool)."""
+    from ray_tpu.ops.decode_attention import reads_pool_where_it_lies
     adt = cfg.activation_dtype()
     pet = _matmul_out(cfg)
+    whole = reads_pool_where_it_lies(*cache["k"].shape[2:], cache["k"].dtype,
+                                     "k_scale" in cache)
 
-    def body(x, layer):
-        lp, lc = layer                  # lc["k"/"v"]: [nb, bs, H, Dh]
+    def body(carry, layer):
+        x, cache = carry                # cache["k"/"v"]: [L, nb, bs, H, Dh]
+        lp, li = layer
 
         def write_then_attend(q, k, v):
-            written = _scatter_kv(lc, k, v, widx)
-            return attend(q, written), written
+            if whole:
+                written = _scatter_kv(cache, li, k, v, widx)
+                return attend(q, written, li), written
+            one = {name: jax.lax.dynamic_slice_in_dim(pool, li, 1)
+                   for name, pool in cache.items()}
+            one = _scatter_kv(one, 0, k, v, widx)
+            return attend(q, one, 0), {
+                name: jax.lax.dynamic_update_slice_in_dim(
+                    cache[name], one[name], li, 0) for name in cache}
 
-        x, lc, _ = _layer(x, lp, cfg, pet, write_then_attend)
-        return x, lc
+        x, cache, _ = _layer(x, lp, cfg, pet, write_then_attend)
+        return (x, cache), None
 
-    x, cache = jax.lax.scan(body, x, (params["layers"], cache))
+    (x, cache), _ = jax.lax.scan(
+        body, (x, cache),
+        (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     return _rms_norm(x, params["final_ln_scale"].astype(adt)), cache
 
 
@@ -673,11 +691,11 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
     x = params["embed"].astype(adt)[tokens[0]]
     x = x + params["pos_embed"].astype(adt)[positions]      # [C, D]
 
-    def attend(q, lc):                  # q: [C, H, Dh]
+    def attend(q, cache, layer):        # q: [C, H, Dh]
         return paged_prefill_attention(
-            q, lc["k"], lc["v"], table, start,
-            k_scale=lc.get("k_scale"), v_scale=lc.get("v_scale"),
-            impl=cfg.prefill_attn_impl)
+            q, cache["k"], cache["v"], table, start,
+            k_scale=cache.get("k_scale"), v_scale=cache.get("v_scale"),
+            layer=layer, impl=cfg.prefill_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
     last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
@@ -721,10 +739,11 @@ def decode_step_paged(params, tokens, cache, pos, tables,
     x = x + params["pos_embed"].astype(adt)[
         jnp.minimum(pos, cfg.max_seq_len - 1)]
 
-    def attend(q, lc):                  # q: [B, H, Dh]
-        return paged_decode_attention(q, lc["k"], lc["v"], tables, pos,
-                                      k_scale=lc.get("k_scale"),
-                                      v_scale=lc.get("v_scale"),
+    def attend(q, cache, layer):        # q: [B, H, Dh]
+        return paged_decode_attention(q, cache["k"], cache["v"], tables,
+                                      pos, k_scale=cache.get("k_scale"),
+                                      v_scale=cache.get("v_scale"),
+                                      layer=layer,
                                       impl=cfg.decode_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
@@ -779,10 +798,11 @@ def verify_step_paged(params, tokens, cache, pos, tables,
     x = x + params["pos_embed"].astype(adt)[
         jnp.minimum(positions, cfg.max_seq_len - 1)]
 
-    def attend(q, lc):                  # q: [B, W, H, Dh]
-        return paged_verify_attention(q, lc["k"], lc["v"], tables, pos,
-                                      k_scale=lc.get("k_scale"),
-                                      v_scale=lc.get("v_scale"),
+    def attend(q, cache, layer):        # q: [B, W, H, Dh]
+        return paged_verify_attention(q, cache["k"], cache["v"], tables,
+                                      pos, k_scale=cache.get("k_scale"),
+                                      v_scale=cache.get("v_scale"),
+                                      layer=layer,
                                       impl=cfg.decode_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)

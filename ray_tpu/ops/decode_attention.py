@@ -18,10 +18,14 @@ sequence b is physical block ``tables[b, j]``). The kernel
 (`paged_decode`) runs ``grid = (B,)``: a program is one stream, and its
 loop's trip count is the stream's own, ``pos[b] // block_size + 1`` live
 pages walked in turns of several pages. The pools stay in HBM in the
-layout the model writes; the block table and positions arrive as scalar
-prefetch, a page is one DMA of a block named by the table, and a turn's
-pages land in one of two buffers while the turn before is computed, so
-what is read follows what is live and nothing else does. A turn scores
+layout the model writes, and where it keeps them: the model's whole
+stacked cache ``[L, n_blocks, block_size, H, D]`` may be handed over with
+the layer to read (a pool of one layer is a stack of one). The layer,
+the block table and positions arrive as scalar prefetch, a page is one
+DMA of a block named by the layer and the table, and a turn's pages land
+in one of two buffers while the turn before is computed, so what is read
+follows what is live and nothing else does: a layer scan never slices
+the pool. A turn scores
 every head of its pages at once with two plain matmuls over the page as
 stored and a mask that keeps each head its own rows; running (m, l, acc)
 softmax statistics live in VMEM scratch and the output is written once.
@@ -42,7 +46,8 @@ Verify and the fused prefill (`paged_mq`) keep the older structure:
 grid (B*H, max_blocks) over a head-major copy of the pool, one
 ``(block_size, D)`` tile a step through BlockSpec index maps that
 dereference the table, blocks past the last query's horizon predicated
-away with ``pl.when``.
+away with ``pl.when``. Given the stacked cache and a layer they slice
+that layer out inside the copy they make anyway.
 
 The JAX fallback gathers ``pool[tables]`` and attends with
 `reference_decode_attention`, the same masking and f32 accumulation.
@@ -211,9 +216,9 @@ def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
     A page reaches VMEM by one DMA, and Mosaic moves by DMA only slices
     whose lanes fill whole 128-lane tiles. A head size that is a multiple
     of 128 does as stored: `pack` 1, no operand touched. A smaller one
-    that divides 128 is stored by XLA padded to 128 lanes, so the wrapper
+    that divides 128 XLA stores in a layout of its own, so the wrapper
     lays `pack = 128 // d` heads side by side (`[n_blocks, bs * h / pack,
-    128]`, one copy of the pool). Pages a turn: `_TURN_TOKENS`
+    128]`, one copy of the layer read). Pages a turn: `_TURN_TOKENS`
     positions, halved until two turns of K and V, what the body makes of
     one and its score tiles fit the default scope; one page that does not fit asks for what it needs,
     up to half the VMEM. None where no row of lanes can be made (`d`
@@ -254,11 +259,27 @@ def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
     return _DecodePlan(pack, pages, _up(need + need // 4, 1 << 20))
 
 
-def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
-                         sm_scale: float, pack: int, pages: int,
+def reads_pool_where_it_lies(bs: int, h: int, d: int, dtype,
+                             quantized: bool) -> bool:
+    """Whether `paged_decode` takes pools `[L, n_blocks, bs, h, d]` of
+    `dtype` as the model stores them (`_decode_plan`'s `pack` 1: a head
+    fills its 128 lanes), so that a layer loop can keep the stacked pool
+    in one buffer and hand it over whole. At a smaller head size XLA
+    stores the pool in a layout of its own and every reader and writer
+    of rows works on a lay-out of it: a layer loop should then take one
+    layer out at a time, or that lay-out is the whole pool's."""
+    plan = _decode_plan(bs, h, d, dtype, quantized)
+    return plan is None or plan.pack == 1
+
+
+def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
+                         *rest, sm_scale: float, pack: int, pages: int,
                          block_size: int, quantized: bool):
     """One stream: its live pages in turns of `pages`, online softmax
-    over a turn's every head at once.
+    over a turn's every head at once. The pools are stacked, `[L,
+    n_blocks, ...]`, and every page's DMA reads layer `layer_ref[0]` of
+    them: the layer is one more number in the copy's index, never a
+    slice of the pool.
 
     A turn's K is `[cols, lanes]`: row `c` holds position `c // hr` of
     the turn and the `pack` heads from `c % hr * pack` on, `hr = H /
@@ -287,6 +308,7 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
         o_ref, kbuf, vbuf, sem, m_scr, l_scr, acc_scr = rest
     b = pl.program_id(0)
     bs, mb = block_size, tbl_ref.shape[1]
+    layer = layer_ref[0]
     _, h, lanes = q_ref.shape
     hr = h // pack                      # rows of lanes a cached position
     rows = bs * hr                      # score columns a page
@@ -299,11 +321,12 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     def copies(blk, slot, i):
         """Page `i` of a turn: block `blk` into buffer `slot`."""
         page, scales = pl.ds(i * size, size), pl.ds(i * rows, rows)
-        pairs = [(k_hbm, kbuf.at[slot, page]), (v_hbm, vbuf.at[slot, page])]
-        if quantized:
-            pairs += [(ks_hbm, ksbuf.at[slot, :, scales]),
-                      (vs_hbm, vsbuf.at[slot, :, scales])]
-        return [pltpu.make_async_copy(src.at[blk], dst, sem.at[slot])
+        pairs = [(k_hbm.at[layer, blk], kbuf.at[slot, page]),
+                 (v_hbm.at[layer, blk], vbuf.at[slot, page])]
+        if quantized:       # the scales come laid out, one layer's
+            pairs += [(ks_hbm.at[blk], ksbuf.at[slot, :, scales]),
+                      (vs_hbm.at[blk], vsbuf.at[slot, :, scales])]
+        return [pltpu.make_async_copy(src, dst, sem.at[slot])
                 for src, dst in pairs]
 
     def issue(c, slot):
@@ -378,25 +401,26 @@ def _paged_decode_kernel(tbl_ref, pos_ref, q_ref, k_hbm, v_hbm, *rest,
     o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_decode(q, k_pool, v_pool, tables, pos, *, plan: _DecodePlan,
-                  block_size: int, sm_scale: float, interpret: bool,
-                  ks=None, vs=None):
-    """q [B, H, lanes]; k_pool, v_pool in HBM, `[n_blocks, bs, H, D]` as
-    stored (`plan.pack` 1) or `[n_blocks, bs * H / pack, 128]`; tables
-    [B, max_blocks], pos [B] i32, scalar-prefetched -> [B, H, lanes].
-    `grid = (B,)`: a program is a stream, its loop's trip count the
-    stream's own live pages, each page one DMA named by the table, a
-    turn's pages landing while the turn before is computed. ``ks``/``vs``
-    `[n_blocks, pack, bs * H / pack]` f32 mark int8 pools: row `j` holds
-    the scales of heads `j, j + pack, ..` in the order of a page's rows,
+def _paged_decode(q, k_pool, v_pool, tables, pos, layer, *,
+                  plan: _DecodePlan, block_size: int, sm_scale: float,
+                  interpret: bool, ks=None, vs=None):
+    """q [B, H, lanes]; k_pool, v_pool in HBM, stacked: `[L, n_blocks,
+    bs, H, D]` as stored (`plan.pack` 1) or `[1, n_blocks, bs * H / pack,
+    128]`; tables [B, max_blocks], pos [B] and layer [1] i32,
+    scalar-prefetched -> [B, H, lanes]. `grid = (B,)`: a program is a
+    stream, its loop's trip count the stream's own live pages, each page
+    one DMA named by the layer and the table, a turn's pages landing
+    while the turn before is computed. ``ks``/``vs`` `[n_blocks, pack,
+    bs * H / pack]` f32, one layer's, mark int8 pools: row `j` holds the
+    scales of heads `j, j + pack, ..` in the order of a page's rows,
     fetched page by page beside the payload and applied to the scores
     and the probabilities (`_scale_row` says why there)."""
     b, h, lanes = q.shape
     quantized = ks is not None
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    row = pl.BlockSpec((1, h, lanes), lambda i, tbl, ps: (i, 0, 0))
-    operands = [tables, pos, q, k_pool, v_pool]
-    turn = (2, plan.pages * k_pool.shape[1]) + k_pool.shape[2:]
+    row = pl.BlockSpec((1, h, lanes), lambda i, tbl, ps, ly: (i, 0, 0))
+    operands = [tables, pos, layer, q, k_pool, v_pool]
+    turn = (2, plan.pages * k_pool.shape[2]) + k_pool.shape[3:]
     scratch = [pltpu.VMEM(turn, k_pool.dtype)] * 2
     if quantized:
         operands += [ks, vs]
@@ -407,8 +431,8 @@ def _paged_decode(q, k_pool, v_pool, tables, pos, *, plan: _DecodePlan,
                 pltpu.VMEM((h, 128), jnp.float32),    # l
                 pltpu.VMEM((h, lanes), jnp.float32)]  # acc
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2, grid=(b,),
-        in_specs=[row] + [hbm] * (len(operands) - 3),
+        num_scalar_prefetch=3, grid=(b,),
+        in_specs=[row] + [hbm] * (len(operands) - 4),
         out_specs=row, scratch_shapes=scratch)
     with jax.named_scope(PAGED_DECODE):
         return pl.pallas_call(
@@ -571,6 +595,32 @@ def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
         )(*operands)
 
 
+def _layer_of(pool, layer):
+    """Layer `layer` (may be traced), `[n_blocks, ...]`, of a stacked
+    pool `[L, n_blocks, ...]`: one layer's bytes, for a reader that
+    copies or gathers what it reads anyway. A pool given without a layer
+    (or no pool at all: absent scales) is returned as it came."""
+    if pool is None or layer is None:
+        return pool
+    return jax.lax.dynamic_index_in_dim(pool, layer, 0, keepdims=False)
+
+
+def _check_pools(op: str, q, q_form: str, k_pool, tables, tables_form: str,
+                 layer):
+    """Rank check shared by the paged wrappers: a pool is one layer's
+    `[n_blocks, bs, H, D]`, or stacked `[L, n_blocks, bs, H, D]` with
+    the `layer` to read."""
+    stacked = layer is not None
+    if (q.ndim != len(q_form.split(",")) or k_pool.ndim != 4 + stacked
+            or tables.ndim != len(tables_form.split(","))):
+        raise ValueError(
+            f"{op} wants q [{q_form}], pools [n_blocks, bs, H, D] (or "
+            f"[L, n_blocks, bs, H, D] with layer=) and tables "
+            f"[{tables_form}]; got {q.shape}, {k_pool.shape}, "
+            f"{tables.shape}, layer "
+            f"{'given' if stacked else None}")
+
+
 def _check_scales(k_scale, v_scale, k_pool, op: str):
     """Both-or-neither scale validation shared by the paged wrappers;
     returns True when the pool is quantized."""
@@ -581,15 +631,16 @@ def _check_scales(k_scale, v_scale, k_pool, op: str):
             f"v_scale={'set' if v_scale is not None else None}")
     if k_scale is None:
         return False
-    if k_scale.shape != k_pool.shape[:3]:
+    if k_scale.shape != k_pool.shape[:-1]:
         raise ValueError(
             f"{op} scale shape {k_scale.shape} != pool row shape "
-            f"{k_pool.shape[:3]} ([n_blocks, bs, H])")
+            f"{k_pool.shape[:-1]} ([n_blocks, bs, H], stacked like the "
+            "pool)")
     return True
 
 
 def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
-                           k_scale=None, v_scale=None,
+                           k_scale=None, v_scale=None, layer=None,
                            impl: str = "auto"):
     """Masked multi-query attention through the paged cache — the verify
     half of speculative decoding. ``q [B, W, H, D]`` holds W query tokens
@@ -597,17 +648,19 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
     of row b sits at logical position ``pos[b] + i`` and attends to cache
     positions ``<= pos[b] + i``. Pools/tables as in
     `paged_decode_attention`, including the int8 ``k_scale``/``v_scale``
-    contract. Returns ``[B, W, H, D]`` in q.dtype.
+    contract and the stacked form with ``layer``. Returns
+    ``[B, W, H, D]`` in q.dtype.
 
     impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax"; the paths share masking/accumulation math."""
-    if q.ndim != 4 or k_pool.ndim != 4 or tables.ndim != 2:
-        raise ValueError(
-            "paged_verify_attention wants q [B, W, H, D], pools "
-            f"[n_blocks, bs, H, D] and tables [B, max_blocks]; got "
-            f"{q.shape}, {k_pool.shape}, {tables.shape}")
+    "jax"; the paths share masking/accumulation math. Both read one
+    layer's copy of the pool (the kernel a head-major one, the jax path
+    a gather), so a stacked pool's layer is sliced inside that copy."""
+    _check_pools("paged_verify_attention", q, "B, W, H, D", k_pool,
+                 tables, "B, max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_verify_attention")
+    k_pool, v_pool, k_scale, v_scale = (
+        _layer_of(a, layer) for a in (k_pool, v_pool, k_scale, v_scale))
     b, w, h, d = q.shape
     bs = k_pool.shape[1]
     if impl == "auto":
@@ -645,7 +698,7 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
-                           k_scale=None, v_scale=None,
+                           k_scale=None, v_scale=None, layer=None,
                            impl: str = "auto"):
     """Decode-step attention through a paged KV cache: ``q [B, H, D]``
     against a block pool ``k_pool, v_pool [n_blocks, block_size, H, D]``
@@ -655,35 +708,43 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     JAX path masks them). Attends to logical positions ``<= pos[b]`` and
     returns ``[B, H, D]`` in q.dtype.
 
-    With ``k_scale``/``v_scale`` ``[n_blocks, bs, H]`` f32 the pools
-    hold int8 payloads (`ops.quant.quantize_rows` convention, one scale
-    per position-head row); both impls dequantize at read — in VMEM for
-    pallas, post-gather for jax — so HBM traffic stays int8.
+    The pools may be the model's whole stacked cache ``[L, n_blocks,
+    block_size, H, D]`` with ``layer`` (a scalar, traced in a layer
+    scan) the layer to read: the form a layer loop wants, because the
+    kernel then reads the layer where it lies and the loop never slices
+    the pool.
+
+    With ``k_scale``/``v_scale`` ``[n_blocks, bs, H]`` f32 (stacked like
+    the pools) the pools hold int8 payloads (`ops.quant.quantize_rows`
+    convention, one scale per position-head row); both impls dequantize
+    at read — in VMEM for pallas, post-gather for jax — so HBM traffic
+    stays int8.
 
     impl: "auto" (pallas on a TPU where `_decode_plan` has a plan, else
     jax) | "pallas" | "jax". The two paths share the same
     masking/accumulation math and agree to f32 tolerance. The kernel
-    takes a pool whose head size is a multiple of 128 as it is stored;
-    at a smaller one the pool (which XLA stores padded to 128 lanes) and
-    the scales are laid out for it here, one copy each."""
-    if q.ndim != 3 or k_pool.ndim != 4 or tables.ndim != 2:
-        raise ValueError(
-            "paged_decode_attention wants q [B, H, D], pools "
-            f"[n_blocks, bs, H, D] and tables [B, max_blocks]; got "
-            f"{q.shape}, {k_pool.shape}, {tables.shape}")
+    takes a pool whose head size is a multiple of 128 where and as it is
+    stored, stacked or not: the layer is a number in each page's DMA. At
+    a smaller head size XLA stores the pool padded to 128 lanes, and the
+    one layer read is sliced out and laid out for the kernel here, one
+    copy of a layer a call; an int8 pool's scales are laid out the same
+    way, a layer's at a time."""
+    _check_pools("paged_decode_attention", q, "B, H, D", k_pool, tables,
+                 "B, max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_decode_attention")
     b, h, d = q.shape
-    nb, bs = k_pool.shape[:2]
+    nb, bs = k_pool.shape[-4:-2]
     plan = _decode_plan(bs, h, d, k_pool.dtype, quantized)
     if impl == "auto":
         impl = _auto_impl("paged_decode_attention", plan is not None,
                           f"block_size {bs}, {h} heads of {d}, "
                           f"{k_pool.dtype} pool")
     if impl == "jax":
-        return reference_paged_decode_attention(
-            q, k_pool, v_pool, tables, pos,
-            k_scale=k_scale, v_scale=v_scale)
+        k, v, ks, vs = (_layer_of(a, layer)
+                        for a in (k_pool, v_pool, k_scale, v_scale))
+        return reference_paged_decode_attention(q, k, v, tables, pos,
+                                                k_scale=ks, v_scale=vs)
     if impl != "pallas":
         raise ValueError(
             f"unknown paged_decode_attention impl {impl!r} "
@@ -698,22 +759,31 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
         plan = _DecodePlan(1, max(1, _TURN_TOKENS // bs), None)
     pack = plan.pack
     ks = vs = None
+    if quantized:           # laid out for the kernel, one layer's scales
+        lay = lambda sc: _layer_of(sc, layer).reshape(
+            nb, bs, h // pack, pack).transpose(0, 3, 1, 2).reshape(
+            nb, pack, bs * h // pack)
+        ks, vs = lay(k_scale), lay(v_scale)
     if pack > 1:
         # `pack` heads side by side in a row of 128 lanes; row h of the
         # query and of the output keeps the lanes of its own head
         own = (jnp.arange(h)[:, None] % pack
                == jnp.arange(pack * d)[None, :] // d)       # [H, lanes]
         q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
-        k_pool = k_pool.reshape(nb, bs * h // pack, pack * d)
-        v_pool = v_pool.reshape(nb, bs * h // pack, pack * d)
-    if quantized:
-        lay = lambda sc: sc.reshape(nb, bs, h // pack, pack).transpose(
-            0, 3, 1, 2).reshape(nb, pack, bs * h // pack)
-        ks, vs = lay(k_scale), lay(v_scale)
+        # the lay-out is a copy, so it is a layer's: slice, then reshape
+        # (a reshape of the stacked pool would copy every layer, and in a
+        # layer scan do so once a layer)
+        k_pool, v_pool = (
+            _layer_of(pool, layer).reshape(nb, bs * h // pack, pack * d)
+            for pool in (k_pool, v_pool))
+        layer = None
+    if layer is None:       # one layer is a stack of one: a free reshape
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
     out = _paged_decode(q, k_pool, v_pool, tables.astype(jnp.int32),
-                        pos.astype(jnp.int32), plan=plan, block_size=bs,
-                        sm_scale=d ** -0.5, interpret=interpret,
-                        ks=ks, vs=vs)
+                        pos.astype(jnp.int32),
+                        jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
+                        block_size=bs, sm_scale=d ** -0.5,
+                        interpret=interpret, ks=ks, vs=vs)
     if pack > 1:
         out = jnp.sum(jnp.where(own, out, 0).reshape(b, h, pack, d), axis=2)
     return out
@@ -756,7 +826,7 @@ def reference_paged_prefill_attention(q, k_pool, v_pool, table, start, *,
 
 
 def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
-                            k_scale=None, v_scale=None,
+                            k_scale=None, v_scale=None, layer=None,
                             impl: str = "auto"):
     """Chunked-prefill attention for one sequence through the paged
     pool: ``q [C, H, D]`` (chunk token t at absolute position
@@ -773,17 +843,19 @@ def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
     (`reference_paged_prefill_attention`) — bit-identical to the
     pre-fused inline math, which keeps ``impl="jax"`` the bitwise
     default on CPU. ``k_scale``/``v_scale`` [n_blocks, bs, H] mark int8
-    pools, dequantized at read on both paths.
+    pools, dequantized at read on both paths. Pools and scales may be
+    stacked ``[L, n_blocks, ...]`` with ``layer`` the one to read, as in
+    `paged_decode_attention`; both paths copy what they read, so the
+    layer is sliced inside that copy.
 
     impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
     "jax". Returns ``[C, H, D]`` in q.dtype."""
-    if q.ndim != 3 or k_pool.ndim != 4 or table.ndim != 1:
-        raise ValueError(
-            "paged_prefill_attention wants q [C, H, D], pools "
-            f"[n_blocks, bs, H, D] and table [max_blocks]; got "
-            f"{q.shape}, {k_pool.shape}, {table.shape}")
+    _check_pools("paged_prefill_attention", q, "C, H, D", k_pool, table,
+                 "max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_prefill_attention")
+    k_pool, v_pool, k_scale, v_scale = (
+        _layer_of(a, layer) for a in (k_pool, v_pool, k_scale, v_scale))
     c, h, d = q.shape
     bs = k_pool.shape[1]
     if impl == "auto":

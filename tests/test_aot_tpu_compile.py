@@ -77,6 +77,33 @@ def kernel_names(text: str) -> list[str]:
             if 'custom_call_target="tpu_custom_call"' in line]
 
 
+def arrays_made(text: str, dtype, size: int) -> list[str]:
+    """The instructions of a program that make an array of `dtype` with
+    `size` elements or more: not its parameters, and not the views of
+    them that cost nothing (a bitcast, an element of a tuple)."""
+    name = {BF16: "bf16", jnp.int8: "s8"}[dtype]
+    return [line.strip() for line in text.splitlines()
+            for m in [re.search(rf" = {name}\[([\d,]+)\]", line)]
+            if m and not re.search(
+                r" (parameter|get-tuple-element|bitcast)\(", line)
+            and math.prod(map(int, m.group(1).split(","))) >= size]
+
+
+def describers(topo):
+    """(described, arg) for one described chip: `described(tree)` gives a
+    pytree's arrays (or `eval_shape`'s shapes) as arguments placed on it,
+    `arg(shape, dtype=int32)` one such argument."""
+    one = SingleDeviceSharding(topo.devices[0])
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    def described(tree):
+        return jax.tree.map(lambda a: arg(a.shape, a.dtype), tree)
+
+    return described, arg
+
+
 def compile_for(topo, fn, *args):
     """Compile `fn` for one described chip; returns how many Mosaic
     kernels the executable holds."""
@@ -130,13 +157,8 @@ def test_paged_decode_takes_the_cells_pool_as_stored(topo, kv_dtype):
                             *args)
     text = compiled.as_text()
     assert kernel_names(text) == ["paged_decode"]
-    name = {BF16: "bf16", jnp.int8: "s8"}[kv_dtype]
     size = CELL_NB * BS * H * CELL_D
-    made = [line.strip() for line in text.splitlines()
-            for m in [re.search(rf" = {name}\[([\d,]+)\]", line)]
-            if m and " parameter(" not in line
-            and math.prod(map(int, m.group(1).split(","))) >= size]
-    assert made == []
+    assert arrays_made(text, kv_dtype, size) == []
     # int8: the two scale arrays are laid out for the kernel, 2 MiB each
     assert compiled.memory_analysis().temp_size_in_bytes < size // 8
 
@@ -369,6 +391,98 @@ def test_gpt_dots_policy_runs_the_forward_kernel_once(topo):
             == kernels.count("flash_dkv") == 1)
 
 
+# -- the dense family at the olmo-1b serving cells' shapes: 32 slots, 16
+# layers, 16 heads of 128, 2049 blocks of 16, tables of 128, chunk 64, a
+# verify window of 5 (`spec_k` 4), bf16 activations over f32 weights
+
+def _olmo(**more):
+    import json
+    from benchmarks.harness import common
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "olmo-1b.json")) as f:
+        return common.model_config(json.load(f), "serve", **more)
+
+
+DENSE_KERNEL = {"decode": "paged_decode", "prefill": "paged_mq",
+                "verify": "paged_mq"}
+
+
+def _dense_program(topo, program, cfg):
+    """`program` of `models/gpt.py` at the cells' shapes as the engine
+    jits it (the cache donated), compiled for one described chip;
+    returns (compiled, params, pool) with the arguments as described."""
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: gpt.init_params(k, cfg), jax.random.key(0)))
+    pool = described(jax.eval_shape(
+        lambda: gpt.init_kv_pool(cfg, CELL_NB, BS)))
+    if program == "prefill":
+        lowered = jax.jit(
+            lambda p, tok, cache, tab, start, n: gpt.prefill_paged(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, 64)), pool, arg((CELL_MB,)), arg(()), arg(()))
+    else:
+        step = {"decode": gpt.decode_step_paged,
+                "verify": gpt.verify_step_paged}[program]
+        tok = (CELL_SLOTS,) if program == "decode" else (CELL_SLOTS, 5)
+        lowered = jax.jit(
+            lambda p, cache, tok, pos, tab: step(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg(tok), arg((CELL_SLOTS,)),
+            arg((CELL_SLOTS, CELL_MB)))
+    return lowered.compile(), params, pool
+
+
+def _nbytes(tree):
+    return sum(a.size * a.dtype.itemsize for a in jax.tree.leaves(tree))
+
+
+@pytest.mark.parametrize("kv_dtype", ["bf16", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+def test_dense_family_programs_update_the_pool_in_place(topo, program,
+                                                        kv_dtype):
+    """The decode step, a 64-token prefill chunk and a verify step of
+    `benchmarks/configs/olmo-1b.json`: the kernel is there under its
+    name (the scan's body holds it once), the donated pool is the
+    program's output buffer, and nothing of the pool's size is among the
+    temporaries. What is there is the bf16 copy XLA makes of the f32
+    weights each step (ROADMAP S12: bf16 weights made once at load),
+    and, for `paged_mq`, one layer of K and of V sliced out and laid
+    head-major. The decode step makes no array of even a layer's size
+    that is not the pool itself, written in place."""
+    cfg = _olmo(kv_dtype={"bf16": "f32", "int8": "int8"}[kv_dtype])
+    compiled, params, pool = _dense_program(topo, program, cfg)
+    text = compiled.as_text()
+    assert kernel_names(text) == [DENSE_KERNEL[program]]
+    mem = compiled.memory_analysis()
+    converted = _nbytes(params) // 2        # f32 weights, once more in bf16
+    assert mem.alias_size_in_bytes >= _nbytes(pool)     # updated in place
+    assert mem.temp_size_in_bytes < converted + (
+        1e8 if program == "decode" else 1e9)            # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    if program == "decode":     # a layer of the pool, or more: the writes
+        made = [line for line in arrays_made(
+            text, {"bf16": BF16, "int8": jnp.int8}[kv_dtype],
+            CELL_NB * BS * H * CELL_D) if f",{H},{CELL_D}]" in line]
+        assert made and all("scatter" in line for line in made), made
+
+
+def test_dense_family_lays_out_one_layer_at_head_size_64(topo):
+    """Sixteen heads of 64 (`datadecide-300m`'s head size): XLA stores
+    that pool in a layout of its own, so rows are written into, and pages
+    read from, a lay-out of it. The temporaries hold one layer's (K and
+    V, there and back: under 1 GB), not the sixteen layers' (4.3 GB) that
+    a scatter or a reshape of the whole carried pool costs."""
+    import dataclasses
+    cfg = dataclasses.replace(_olmo(), d_model=H * D)
+    compiled, params, pool = _dense_program(topo, "decode", cfg)
+    assert kernel_names(compiled.as_text()) == ["paged_decode"]
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes >= _nbytes(pool)
+    assert mem.temp_size_in_bytes < _nbytes(params) // 2 + 1e9
+
+
 # -- the latent / sparse / routed-expert family at glm-5.2.docqa-closed24's
 # shapes: 16 slots, 16,385 blocks of 16, chunk 512, 64 heads, rows of 576
 # values (384 words), index keys of 128, top 2048, 16 experts of 2048 x 6144
@@ -427,15 +541,7 @@ def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     temporaries fit the chip."""
     from ray_tpu.models import latent_sparse_moe as lsm
     config, cfg, ref = _glm()
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def described(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    def arg(shape, dtype=I32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
+    described, arg = describers(topo)
     params = described(jax.eval_shape(
         lambda k: ref.init_params(k, config), jax.random.key(0)))
     pool = described(jax.eval_shape(lambda: lsm.init_pool(cfg, LNB, BS)))
@@ -522,15 +628,7 @@ def test_retention_family_programs_compile_at_the_cells_shapes(topo, program):
     and weights, pool and temporaries fit the chip."""
     from ray_tpu.models import retention
     config, cfg, ref = _brumby()
-    one = SingleDeviceSharding(topo.devices[0])
-
-    def described(tree):
-        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-            a.shape, a.dtype, sharding=one), tree)
-
-    def arg(shape, dtype=I32):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
-
+    described, arg = describers(topo)
     params = described(jax.eval_shape(
         lambda k: ref.init_params(k, config), jax.random.key(0)))
     pool = described(jax.eval_shape(
