@@ -981,6 +981,14 @@ class InferenceEngine:
         # wait for a tick it does not run (`engine/*`, `stream/wait`):
         # profiler annotations whose totals feed stats().
         self._phases = _telemetry.Phases()
+        # The gap between two ticks, taken at a tick's two ends
+        # (`_note_tick_gap`): when the previous one ended, whether it
+        # left work behind, and which thread ran it.
+        self._tick_ended: float | None = None
+        self._tick_carried = False
+        self._tick_thread: int | None = None
+        self._tick_gaps = self._pump_handoffs = 0
+        self._tick_gap_s = self._tick_gap_max_s = 0.0
         self._sentinel = _telemetry.RetraceSentinel(self.name)
         self._sentinel.watch("decode", lambda: self.decode_traces, cap=1,
                              registered=True)
@@ -2063,17 +2071,25 @@ class InferenceEngine:
         if s.filled < s.prompt.size:
             clen = min(self.prefill_chunk, s.prompt.size - s.filled)
             cap = self._chunk_bucket_for(clen)
-            toks = np.zeros((1, cap), np.int32)
-            toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
-            with self._phases.phase("engine/prefill_chunk", tokens=clen,
-                                    bucket=cap) as chunk:
-                tok, lp, self.cache, counts = self._prefill_fn(
-                    self.params, jnp.asarray(toks), self.cache,
-                    jnp.asarray(s.table), np.int32(s.filled),
-                    np.int32(clen), np.float32(s.temperature),
-                    self._base_key, np.int32(self._decode_steps))
-                # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
-                tok = int(tok)    # device sync, so the timing is honest
+            phase = self._phases.phase
+            # build, enqueue and wait tile the chunk's span: the host's
+            # work before the program can start, the call, and the host
+            # blocked until the chunk's token is back
+            with phase("engine/prefill_chunk", tokens=clen,
+                       bucket=cap) as chunk:
+                with phase("engine/prefill_build"):
+                    toks = np.zeros((1, cap), np.int32)
+                    toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
+                    toks, table = jnp.asarray(toks), jnp.asarray(s.table)
+                    scalars = (np.int32(s.filled), np.int32(clen),
+                               np.float32(s.temperature), self._base_key,
+                               np.int32(self._decode_steps))
+                with phase("engine/prefill_dispatch"):
+                    tok, lp, self.cache, counts = self._prefill_fn(
+                        self.params, toks, self.cache, table, *scalars)
+                with phase("engine/prefill_sync"):
+                    # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
+                    tok = int(tok)    # device sync, so the timing is honest
             self._add_counts(counts)
             self._recorder.on_prefill_chunk(s.rid, clen, cap,
                                             chunk.seconds)
@@ -2213,8 +2229,10 @@ class InferenceEngine:
             # that number joins them to this `engine/tick` annotation
             self._recorder.tick = self._tick_seq
             phase = self._phases.phase
+            gap_us, carried = self._note_tick_gap(t_tick)
             try:
-                with phase("engine/tick", tick=self._tick_seq) as tick:
+                with phase("engine/tick", tick=self._tick_seq,
+                           gap_us=gap_us, carried=carried) as tick:
                     # fault site: 'fail' surfaces FaultInjected to the
                     # pumping consumer; 'delay' wedges the tick (what the
                     # watchdog exists to catch)
@@ -2259,6 +2277,29 @@ class InferenceEngine:
                     return True
             finally:
                 self._tick_started = None
+                self._tick_carried = bool(
+                    self._inbox or self._pending or self._imports
+                    or any(s.active for s in self._slots))
+                self._tick_ended = time.perf_counter()
+
+    def _note_tick_gap(self, t_tick: float) -> tuple[int, int]:
+        """(`gap_us`, `carried`) of the tick that starts at `t_tick`:
+        the time since the previous tick ended, and whether that tick
+        left work behind (a slot active, a request pending or in the
+        inbox). Only a carried gap is the engine's to answer for: it is
+        counted into `tick_gap_s`, and into `pump_handoffs` where
+        another thread than the previous tick's runs this one. A gap
+        after a tick that left nothing is demand that was not there."""
+        ident = threading.get_ident()
+        ended, carried = self._tick_ended, int(self._tick_carried)
+        gap = t_tick - ended if ended is not None else 0.0
+        if carried:
+            self._tick_gaps += 1
+            self._tick_gap_s += gap
+            self._tick_gap_max_s = max(self._tick_gap_max_s, gap)
+            self._pump_handoffs += ident != self._tick_thread
+        self._tick_thread = ident
+        return int(gap * 1e6), carried
 
     def _dev(self, name: str, arr):
         """Host array -> device, through the replicated per-step input
@@ -2289,13 +2330,16 @@ class InferenceEngine:
 
     def _decode_inputs(self):
         """`_batch_arrays` and its four device puts, as one span: the
-        host's part of a tick before the dispatch."""
-        with self._phases.phase("engine/decode_build"):
+        host's part of a tick before the dispatch. The puts are a child
+        span of their own."""
+        phase = self._phases.phase
+        with phase("engine/decode_build"):
             tokens, pos, tables, temps = host = self._batch_arrays()
-            return host, (self._dev("tokens", tokens),
-                          self._dev("pos", pos),
-                          self._dev("tables", tables),
-                          self._dev("temps", temps))
+            with phase("engine/decode_put"):
+                return host, (self._dev("tokens", tokens),
+                              self._dev("pos", pos),
+                              self._dev("tables", tables),
+                              self._dev("temps", temps))
 
     def _decode_tick(self, decoding: list, inputs=None):
         phase = self._phases.phase
@@ -2548,6 +2592,10 @@ class InferenceEngine:
             self._model_counts = None
             self._prefill_tokens = self._decode_tokens = 0
             self._phases.clear()
+            # the next tick starts a new count: no gap before it
+            self._tick_ended, self._tick_carried = None, False
+            self._tick_gaps = self._pump_handoffs = 0
+            self._tick_gap_s = self._tick_gap_max_s = 0.0
             self._recorder.deliver_waits.clear()
             self._prefill_chunks = 0
             self._prefix_hit_tokens = self._prompt_tokens = 0
@@ -2677,7 +2725,15 @@ class InferenceEngine:
           their wall time under the lock.
           ``admit_s`` — `engine/admit`: import and pending admission.
           ``decode_build_s`` — `engine/decode_build`: the per-slot input
-          arrays and their device puts.
+          arrays and their device puts; ``decode_put_s`` —
+          `engine/decode_put`, inside it: the four puts alone.
+          ``prefill_build_s`` / ``prefill_dispatch_s`` /
+          ``prefill_sync_s`` — `engine/prefill_build`,
+          `engine/prefill_dispatch`, `engine/prefill_sync`: the three
+          parts that tile `engine/prefill_chunk` (``prefill_time_s``):
+          the chunk's host arrays and their puts, enqueueing the
+          program, and the host blocked until the chunk's token is back
+          (the device's time shows here).
           ``decode_dispatch_s`` — `engine/decode_dispatch` and
           `engine/verify_dispatch`: enqueueing the decode/verify step.
           ``token_sync_s`` — `engine/token_sync`: waiting for the
@@ -2689,6 +2745,23 @@ class InferenceEngine:
           consumers asleep, not contending.
           ``submits`` / ``submit_s`` — `engine/submit`: calls of
           `submit`, validation and refusals included, and their time.
+
+        The gap between two ticks (taken by `step()` itself; each
+        `engine/tick` annotation carries its own as `gap_us` and
+        `carried`):
+          ``tick_gaps`` / ``tick_gap_s`` / ``tick_gap_max_s`` — ticks
+          that began after a tick that left work behind (a slot active,
+          a request pending or in the inbox), and the time from that
+          tick's end to their start, in total and at the most: the
+          engine standing still with work to do, while one consumer
+          hands the pump to the next. A gap after a tick that left
+          nothing is counted nowhere. The mean is ``tick_gap_s`` /
+          ``tick_gaps``; beside ``tick_s`` / ``ticks`` it says what
+          share of a token's gap no tick was running.
+          ``pump_handoffs`` — of those ticks, the ones run by another
+          thread than the tick before: near ``tick_gaps`` where many
+          consumers take turns at the pump, near 0 where one stream's
+          consumer runs every tick.
 
         Speculative decoding:
           ``spec`` / ``spec_k`` — backend ('' when off) and window.
@@ -2879,6 +2952,15 @@ class InferenceEngine:
                 "tick_s": ph.seconds("engine/tick"),
                 "admit_s": ph.seconds("engine/admit"),
                 "decode_build_s": ph.seconds("engine/decode_build"),
+                "decode_put_s": ph.seconds("engine/decode_put"),
+                "prefill_build_s": ph.seconds("engine/prefill_build"),
+                "prefill_dispatch_s": ph.seconds(
+                    "engine/prefill_dispatch"),
+                "prefill_sync_s": ph.seconds("engine/prefill_sync"),
+                "tick_gaps": self._tick_gaps,
+                "tick_gap_s": self._tick_gap_s,
+                "tick_gap_max_s": self._tick_gap_max_s,
+                "pump_handoffs": self._pump_handoffs,
                 "decode_dispatch_s": ph.seconds(
                     "engine/decode_dispatch", "engine/verify_dispatch"),
                 "token_sync_s": ph.seconds("engine/token_sync"),

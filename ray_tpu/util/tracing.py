@@ -21,7 +21,7 @@ and the replica's context flows into the engine caller thread via
 `FlightRecorder` parents its request spans under it. Workers drain
 their span rings back to the head — piggybacked on `TaskDone` and on
 the periodic metrics flush — and the head `ingest()`s them into its own
-ring, so `export_json` / the node's "timeline" verb emit ONE merged
+ring, so the node's "timeline" verb emits ONE merged
 cluster trace instead of per-process fragments.
 
 The active-span slot is a `contextvars.ContextVar`: it flows into
@@ -34,7 +34,6 @@ from __future__ import annotations
 import collections
 import contextlib
 import contextvars
-import json
 import os
 import threading
 import time
@@ -293,37 +292,6 @@ def clear_spans() -> None:
     with _lock:
         _spans.clear()
         _dropped = 0
-
-
-def export_json(path: str) -> int:
-    """Write this process's spans as a JSON list; returns the count. On
-    the head, workers' drained spans are already merged into the ring,
-    so this is the whole-cluster trace."""
-    spans = get_spans()
-    with open(path, "w") as f:
-        json.dump(spans, f)
-    return len(spans)
-
-
-def probe_disabled_overhead_ns(iters: int = 20_000) -> float:
-    """Per-call cost (ns) of the tracing-OFF hot path: `span()` with
-    recording disabled. scale_bench compares this against measured task
-    latency to assert the always-compiled-in instrumentation costs <1%."""
-    global _enabled
-    prev_enabled, prev_env = _enabled, os.environ.pop("RAY_TPU_TRACING",
-                                                      None)
-    _enabled = False
-    try:
-        t0 = time.perf_counter_ns()
-        for _ in range(iters):
-            with span("overhead-probe"):
-                pass
-        dt = time.perf_counter_ns() - t0
-    finally:
-        _enabled = prev_enabled
-        if prev_env is not None:
-            os.environ["RAY_TPU_TRACING"] = prev_env
-    return dt / max(1, iters)
 
 
 def spans_to_chrome_trace(spans: Optional[List[dict]] = None) -> List[dict]:

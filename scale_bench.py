@@ -310,32 +310,6 @@ def bench_broadcast(ray_tpu, cluster, gib: float = 1.0,
             "aggregate_gb_per_s": round(total_bytes / dt / 1e9, 2)}
 
 
-def bench_tracing_overhead(ray_tpu, n: int = 2000) -> dict:
-    """Cost of the always-compiled-in tracing instrumentation with
-    recording OFF, relative to the measured per-task latency. The task
-    path has two disabled-path touch points (the submit-side TaskSpec
-    stamp and the worker-side span check), each no more expensive than
-    one full `span()` call; <1% of a no-op task is the contract."""
-    from ray_tpu.util import tracing
-    ns_per_call = tracing.probe_disabled_overhead_ns()
-
-    @ray_tpu.remote
-    def nop():
-        return None
-
-    ray_tpu.get([nop.remote() for _ in range(20)])
-    t0 = time.perf_counter()
-    ray_tpu.get([nop.remote() for _ in range(n)])
-    task_ns = (time.perf_counter() - t0) / n * 1e9
-    overhead_pct = 100.0 * 2 * ns_per_call / task_ns
-    return {
-        "span_disabled_ns": round(ns_per_call, 1),
-        "task_ns": round(task_ns, 1),
-        "overhead_pct": round(overhead_pct, 4),
-        "under_1pct": bool(overhead_pct < 1.0),
-    }
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--queued", type=int, default=100_000)
@@ -374,7 +348,6 @@ def main():
     _settle(ray_tpu)
     results["broadcast_1gib"] = bench_broadcast(
         ray_tpu, cluster, args.broadcast_gib, args.broadcast_nodes)
-    results["tracing_overhead"] = bench_tracing_overhead(ray_tpu)
     # last: spawns its own fresh sessions in subprocesses, so the
     # parent cluster must be idle while they run
     results["batched_vs_unbatched"] = bench_batched_vs_unbatched()

@@ -160,6 +160,23 @@ def test_eight_streams_one_in_step_and_each_served_every_tick(slow_ticks):
     eng.check_invariants()
 
 
+def test_a_tick_run_by_another_thread_than_the_last_is_a_handoff():
+    """`pump_handoffs`: the short stream's consumer runs every tick
+    until its stream ends, with the long stream decoding beside it; the
+    long stream's consumer then takes the pump over, once."""
+    eng = tiny_engine(slots=2)
+    short = eng.submit(prompt(1), max_new_tokens=3)
+    long_ = eng.submit(prompt(2), max_new_tokens=9)
+    run_threads([lambda: drain(eng, short)])
+    st = eng.stats()
+    assert st["pump_handoffs"] == 0 and st["tick_gaps"] == st["ticks"] - 1
+    assert len(drain(eng, long_)) == 9
+    st = eng.stats()
+    assert st["pump_handoffs"] == 1
+    assert st["tick_gaps"] == st["ticks"] - 1
+    assert 0 < st["tick_gap_max_s"] <= st["tick_gap_s"]
+
+
 def test_submit_under_back_to_back_ticks_takes_under_half_a_tick(
         slow_ticks):
     eng = tiny_engine()

@@ -21,7 +21,9 @@ from ray_tpu.util import telemetry
 TRAIN_SPANS = {"train/next_batch", "train/host_batch", "train/place",
                "train/dispatch", "train/metrics", "train/metrics_fetch"}
 TICK_SPANS = {"engine/tick", "engine/admit", "engine/prefill_chunk",
-              "engine/decode_build", "engine/decode_dispatch",
+              "engine/prefill_build", "engine/prefill_dispatch",
+              "engine/prefill_sync", "engine/decode_build",
+              "engine/decode_put", "engine/decode_dispatch",
               "engine/token_sync", "engine/emit", "engine/submit"}
 
 
@@ -229,6 +231,107 @@ def test_trace_holds_one_tick_per_step_with_the_sync_inside(traced):
     assert len(ev.get("stream/wait", ())) == st["stream_waits"] == 0
 
 
+def test_the_chunk_is_tiled_by_three_spans_and_the_puts_lie_in_the_build(
+        traced):
+    ev = traced["events"]
+    chunk, = ev["engine/prefill_chunk"]
+    parts = [ev[f"engine/prefill_{p}"] for p in ("build", "dispatch",
+                                                 "sync")]
+    assert all(len(p) == 1 and inside(p[0], [chunk]) for p in parts)
+    (build,), (dispatch,), (sync,) = parts
+    assert chunk[1] <= build[1] and build[2] <= dispatch[1] \
+        and dispatch[2] <= sync[1] and sync[2] <= chunk[2]
+    covered = sum(p[2] - p[1] for p in (build, dispatch, sync))
+    assert covered >= 0.9 * (chunk[2] - chunk[1])
+    puts, builds = ev["engine/decode_put"], ev["engine/decode_build"]
+    assert len(puts) == len(builds) == traced["engine"].stats()[
+        "decode_steps"]
+    assert all(inside(p, builds) for p in puts)
+
+
+def test_every_tick_says_how_long_after_the_last_it_began(traced):
+    """`gap_us` and `carried` on each `engine/tick`: the engine's own
+    reading of the time since the previous tick ended is the distance
+    between the two events in the trace, and the first tick after
+    `reset_stats()` follows nothing."""
+    ticks = sorted(traced["events"]["engine/tick"], key=lambda t: t[1])
+    assert all({"gap_us", "carried"} <= set(t[3]) for t in ticks)
+    assert ticks[0][3]["carried"] == 0 and ticks[0][3]["gap_us"] == 0
+    assert len(ticks) >= 3
+    for before, after in zip(ticks, ticks[1:]):
+        assert after[3]["carried"] == 1     # the stream was still open
+        assert after[3]["gap_us"] * 1e3 == pytest.approx(
+            after[1] - before[2], abs=0.5e6)
+    st = traced["engine"].stats()
+    assert st["tick_gaps"] == len(ticks) - 1
+    assert st["tick_gap_s"] == pytest.approx(
+        sum(t[3]["gap_us"] for t in ticks) / 1e6, abs=1e-5 * len(ticks))
+
+
+READERS = {"tick_gap_ms": True, "tick_host_ms": True,
+           "prefill_host_ms": True, "decode_put_ms": True,
+           "idle_in_tick_ms": False, "idle_between_ticks_ms": False}
+
+
+@pytest.mark.parametrize("name", READERS)
+def test_the_tick_s_readers_on_the_same_trace(traced, monkeypatch, name):
+    """The benchmark's readers of the new spans and attributes, over the
+    CPU trace, against the engine's own totals; with no device plane the
+    two idle readers have nothing to read."""
+    from benchmarks import run as bench_run
+    from benchmarks.harness import spans
+    from benchmarks.layer_metrics import tick_events
+    monkeypatch.setattr(spans, "summary",
+                        lambda ctx: spans.reduce(traced["path"]))
+    monkeypatch.setattr(tick_events, "find", lambda ctx: traced["path"])
+    got = bench_run.read_layer_metric(name, {"trace": {"modules": {}}})
+    if not READERS[name]:
+        assert got is None
+        return
+    st = traced["engine"].stats()
+    want = {
+        "tick_gap_ms": 1e3 * st["tick_gap_s"] / st["tick_gaps"],
+        "tick_host_ms": 1e3 * (st["tick_s"] - st["token_sync_s"]
+                               - st["prefill_sync_s"]) / st["ticks"],
+        "prefill_host_ms": 1e3 * (st["prefill_build_s"]
+                                  + st["prefill_dispatch_s"])
+        / st["prefill_chunks"],
+        "decode_put_ms": 1e3 * st["decode_put_s"] / st["decode_steps"],
+    }[name]
+    # a mean from perf_counter beside a median or a mean from the
+    # trace's clock, over three or four ticks
+    assert 0 < got < 4 * want and want < 4 * got
+
+
+SPLITS = {      # idle, ticks (start, end, gap, carried) -> inside, between
+    "inside-a-tick": ([(12, 18)], [(10, 20, 0, 0)], (6, 0)),
+    "between-two-ticks": (
+        [(21, 29)], [(10, 20, 0, 0), (30, 40, 10, 1)], (0, 8)),
+    # midpoint 21: the midpoint rule gives all 12 to the hand-off
+    "straddles-a-tick-s-end": (
+        [(15, 27)], [(10, 20, 0, 0), (30, 40, 10, 1)], (5, 7)),
+    "before-an-uncarried-tick": (
+        [(22, 28)], [(10, 20, 0, 0), (30, 40, 10, 0)], (0, 0)),
+    "one-gap-over-two-ticks": (
+        [(5, 45)], [(10, 20, 3, 1), (30, 40, 10, 1)], (20, 13)),
+    "the-gap-is-the-engine-s-not-the-events": (
+        [(20, 30)], [(10, 20, 0, 0), (30, 40, 6, 1)], (0, 6)),
+    "many-short-gaps": (
+        [(11, 12), (13, 14), (19, 22), (28, 31)],
+        [(10, 20, 0, 0), (30, 40, 10, 1)], (4, 4)),
+    "no-idle-time": ([], [(10, 20, 0, 0), (30, 40, 10, 1)], (0, 0)),
+}
+
+
+@pytest.mark.parametrize("case", SPLITS)
+def test_an_idle_gap_is_split_where_a_tick_ends(case):
+    from benchmarks.layer_metrics import tick_events
+    idle, ticks, want = SPLITS[case]
+    assert tick_events.split(idle, ticks) == want
+    # the parts never exceed the whole
+    assert sum(want) <= sum(e - s for s, e in idle)
+
+
 def test_the_benchmarks_reducer_reads_the_same_trace(traced):
     """`benchmarks/harness/spans.py` over a CPU trace: the spans, and no
     kernel, chip or idle time to report."""
@@ -257,11 +360,48 @@ def test_engine_times_come_from_the_spans():
     assert st["submit_s"] == ph.seconds("engine/submit") > 0
     assert st["stream_wait_s"] == ph.seconds("stream/wait") == 0.0
     assert st["p50_token_latency_ms"] > 0
+    # the chunk's three parts and the build's puts lie inside their spans
+    assert st["prefill_time_s"] >= st["prefill_build_s"] \
+        + st["prefill_dispatch_s"] + st["prefill_sync_s"] > 0
+    assert min(st["prefill_build_s"], st["prefill_dispatch_s"],
+               st["prefill_sync_s"]) > 0
+    assert st["decode_build_s"] >= st["decode_put_s"] > 0
+    # a lone consumer runs every tick itself, each after one that left
+    # its stream open, but the first
+    assert st["tick_gaps"] == st["ticks"] - 1 > 0
+    assert 0 < st["tick_gap_max_s"] <= st["tick_gap_s"] < st["tick_s"]
+    assert st["pump_handoffs"] == 0
     eng.reset_stats()
     st = eng.stats()
     assert st["ticks"] == st["submits"] == st["stream_waits"] == 0
     assert st["decode_time_s"] == st["prefill_time_s"] == 0.0
     assert st["deliver_wait_ms_p99"] == 0.0
+    new = ("prefill_build_s", "prefill_dispatch_s", "prefill_sync_s",
+           "decode_put_s", "tick_gaps", "tick_gap_s", "tick_gap_max_s",
+           "pump_handoffs")
+    assert [st[k] for k in new] == [0] * len(new)
+    assert all(f"``{k}``" in InferenceEngine.stats.__doc__ for k in new)
+
+
+def test_time_with_nothing_to_do_is_no_gap():
+    """A gap counts after a tick that left work behind. The sleep
+    between two requests follows a tick that left none, and so does the
+    first tick after `reset_stats()`, whatever came before it."""
+    eng = tiny_engine()
+    for i in range(2):
+        rid = eng.submit([5, 9, 3], max_new_tokens=3)
+        assert len(list(eng.tokens_for(rid))) == 3
+        time.sleep(0.1)
+    st = eng.stats()
+    assert st["tick_gaps"] == st["ticks"] - 2
+    assert st["tick_gap_max_s"] <= st["tick_gap_s"] < 0.1
+    rid = eng.submit([5, 9, 3], max_new_tokens=3)
+    eng.step()                          # leaves the stream open
+    eng.reset_stats()
+    time.sleep(0.1)
+    assert len(list(eng.tokens_for(rid))) == 3
+    st = eng.stats()
+    assert st["tick_gaps"] == st["ticks"] - 1 and st["tick_gap_s"] < 0.1
 
 
 @pytest.mark.parametrize("drain_first", [False, True],
