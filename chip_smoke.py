@@ -171,6 +171,7 @@ class SmokeReplica(InferenceReplica):
         self._cache_dir = enable_compile_cache()
         self._fallbacks = watch_op_fallbacks()
         self._compiles = CompileWatch()
+        self._seed = kwargs.get("seed", 0)
         t0 = time.perf_counter()
         super().__init__(*args, **kwargs)
         self._init_s = time.perf_counter() - t0
@@ -254,11 +255,15 @@ class SmokeReplica(InferenceReplica):
                                     attn_impl="xla")
         seq = jnp.asarray(np.concatenate([prompt, tokens])[None],
                           jnp.int32)
+        # the f32 masters this replica was made from: the engine keeps
+        # the tree its steps read, not them
+        masters = gpt.init_params(jax.random.PRNGKey(self._seed), eng.cfg)
         with jax.default_matmul_precision("highest"):
             ref = jax.jit(
                 lambda p, s: gpt.completion_logprobs(
                     p, s, jnp.asarray([len(prompt)]), len(tokens), cfg32)
-            )(eng.params, seq)
+            )(masters, seq)
+        del masters
         diff = np.abs(np.asarray(ref[0], np.float64)
                       - np.asarray(logprobs, np.float64))
 

@@ -23,7 +23,16 @@ class ServingFamily(NamedTuple):
     verify(params, tokens [B, W], pool, pos, tables, cfg, mesh)
             -> (logits [B, W, V] f32, pool): speculative decoding; a
             family without it cannot be given `spec=`
-    quantize(params) -> params, for `cfg.weight_dtype == "int8"`
+    load(params, cfg) -> params: the family's one load-time function,
+            published masters in, the tree that prefill, decode and
+            verify read out: every leaf in the dtype the steps would
+            cast it to at use, for every `cfg.weight_dtype`, so that no
+            step converts a weight. Pure; the engine jits it, runs it
+            at construction and on every `update_params`, on the target
+            and on a draft model, and runs nothing where it would hand
+            a tree back as it is. A family without it (its weights are
+            stored in the dtype its steps read) is given its tree as
+            published
     counts(cfg, totals) -> {name: number}: what the int32 vector that
             prefill and decode return third, summed over a window, adds
             to `stats()`; None where they return None
@@ -43,6 +52,6 @@ class ServingFamily(NamedTuple):
     gather_block: Callable
     scatter_block: Callable
     verify: Callable | None = None
-    quantize: Callable | None = None
+    load: Callable | None = None
     counts: Callable | None = None
     state_blocks: int | None = None

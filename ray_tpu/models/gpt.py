@@ -97,10 +97,12 @@ class GPTConfig:
     # dtype. ~3-4x KV bytes/token vs an f32 pool (2x vs bf16).
     kv_dtype: str = "f32"            # f32 | int8
     # Weight precision for the paged inference forwards (prefill/decode/
-    # verify — training always runs full precision).
-    # "int8" expects params through `quantize_params` (per-output-channel
-    # scales; dequant folds into each matmul's rhs read, accumulation
-    # stays f32 via preferred_element_type).
+    # verify — training always runs full precision). Both expect params
+    # through `serving_params`, the engine's load-time function: "f32"
+    # is the published values, each rounded to `dtype` once where a step
+    # would round it at use; "int8" quantizes the matmul stacks besides
+    # (per-output-channel scales; dequant folds into each matmul's rhs
+    # read, accumulation stays f32 via preferred_element_type).
     weight_dtype: str = "f32"        # f32 | int8
     # Attention implementation for chunked paged prefill. "auto" picks
     # the fused Pallas multi-query kernel on TPU (chunk scores stay
@@ -264,7 +266,8 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
 
     x: activations [..., D] in cfg.dtype, any leading dims ([B, T, D]
     training, [C, D] chunked prefill, [B, D] decode, [B, W, D] verify).
-    lp: this layer's param slice (f32 masters cast here; int8 leaves of
+    lp: this layer's param slice (f32 masters cast here, leaves that
+    `serving_params` cast read as they are; int8 leaves of
     `quantize_params` dequantized by `_w`). cfg: any config with
     `n_heads`, `head_dim` and `activation_dtype()`. pet: the einsums'
     output element type, the caller's choice (`_matmul_out`).
@@ -449,8 +452,8 @@ def check_quant_cfg(cfg: GPTConfig) -> bool:
 
 
 # The per-layer matmul weights the int8 weight-only path quantizes.
-# Norm scales, embed and pos_embed stay f32 — they are O(d) reads, not
-# the bandwidth, and the unembed shares `embed`.
+# Norm scales, embed and pos_embed are not quantized — they are O(d)
+# reads, not the bandwidth, and the unembed shares `embed`.
 QUANTIZED_WEIGHTS = ("wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down")
 
 
@@ -460,9 +463,8 @@ def quantize_params(params):
     leaf ``[L, In, Out]`` becomes an int8 leaf plus an
     ``"<name>_scale"`` f32 ``[L, Out]`` sibling
     (`ops.quant.quantize_channels`). Embed/pos_embed/norm scales pass
-    through untouched. Pure and jittable — the engine wraps it in a
-    donating jit so the RL flywheel's swap path republishes f32 masters
-    and quantization rides the swap."""
+    through untouched. Pure and jittable: the int8 half of
+    `serving_params`."""
     from ray_tpu.ops import quant
     layers = dict(params["layers"])
     for name in QUANTIZED_WEIGHTS:
@@ -470,6 +472,42 @@ def quantize_params(params):
         layers[name] = q
         layers[name + "_scale"] = s
     return {**params, "layers": layers}
+
+
+def serving_params(params, cfg: GPTConfig):
+    """The dense family's load-time function (`ServingFamily.load`):
+    published masters in, the tree that `prefill_paged`,
+    `decode_step_paged` and `verify_step_paged` read out.
+
+    `weight_dtype="int8"` quantizes the `QUANTIZED_WEIGHTS`
+    (`quantize_params`); their scales stay f32, since `_w` multiplies
+    in f32 before its cast. For every `weight_dtype`, every other
+    floating leaf (`embed`, `pos_embed`, the norm scales and, with
+    `weight_dtype="f32"`, the matmul stacks) takes
+    `cfg.activation_dtype()`, the dtype each step casts it to at use:
+    the same rounding of the same numbers, done once, so the matmuls
+    see bit-identical operands and a compiled step's `astype` of a leaf
+    to its own dtype is no op. (Cast at use, XLA hoists the casts out
+    of the layer loop and every run of a step converts the whole tree.)
+    A leaf already in that dtype is returned as it is, so a
+    `dtype="float32"` tree and a tree published in bf16 come back leaf
+    for leaf. Pure and jittable; the engine runs it at construction and
+    on every `update_params`, so trainers go on publishing f32 masters.
+    Training calls the model functions with the masters themselves and
+    traces what it traced."""
+    adt = cfg.activation_dtype()
+    if cfg.weight_dtype == "int8":
+        params = quantize_params(params)
+    scales = {name + "_scale" for name in QUANTIZED_WEIGHTS}
+
+    def cast(x):
+        return (x.astype(adt) if jnp.issubdtype(x.dtype, jnp.floating)
+                else x)
+
+    layers = {name: leaf if name in scales else cast(leaf)
+              for name, leaf in params["layers"].items()}
+    return {**{name: cast(leaf) for name, leaf in params.items()
+               if name != "layers"}, "layers": layers}
 
 
 def kv_pool_logical_axes(quantized: bool = False):
@@ -823,7 +861,7 @@ FAMILY = ServingFamily(
     init_pool=init_kv_pool, prefill=_prefill_family, decode=_decode_family,
     copy_block=copy_block, gather_block=gather_block,
     scatter_block=scatter_block, verify=verify_step_paged,
-    quantize=quantize_params)
+    load=serving_params)
 
 
 def num_params(params) -> int:

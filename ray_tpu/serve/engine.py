@@ -98,6 +98,7 @@ from __future__ import annotations
 
 import collections
 import contextlib
+import functools
 import logging
 import os
 import threading
@@ -515,7 +516,7 @@ class InferenceEngine:
         self.cfg = cfg
         # Everything the engine asks of the model goes through its
         # family (`models.family.ServingFamily`): the pool, prefill, decode,
-        # the block moves, and optionally verify and quantize.
+        # the block moves, and optionally verify and load.
         fam = self._family = cfg.family
         # Disaggregated serving role. "prefill": this engine runs
         # chunked prefill only — a completed prompt's KV blocks are
@@ -616,30 +617,47 @@ class InferenceEngine:
         self.verify_traces = 0
         self.draft_traces = 0
         self.draft_prefill_traces = 0
-        self.quantize_traces = 0
+        self.load_traces = 0
 
-        # --- int8 weight-only path (cfg.weight_dtype="int8") ----------
-        # Quantize once at construction; update_params re-runs the same
-        # jitted fn on every swap, so trainers keep publishing f32
-        # masters and quantization rides the swap (zero decode/verify
-        # retraces — the tree the compiled paths close over keeps its
-        # shapes and dtypes). One trace per distinct tree shape: target
-        # and draft each at most once, ever.
-        def _quantize(p):
-            self.quantize_traces += 1
-            return fam.quantize(p)
+        # --- load time: masters in, the tree the steps read out -------
+        # A family's `load` (`models.family.ServingFamily`) casts, and
+        # for cfg.weight_dtype="int8" quantizes, the published masters
+        # once, so that no compiled step converts a weight. It runs
+        # here and, the same jitted fn, on every update_params, so
+        # trainers keep publishing f32 masters and the cast rides the
+        # swap (zero decode/verify retraces — the tree the compiled
+        # paths close over keeps its shapes and dtypes). One trace per
+        # distinct tree: target and draft each at most once, ever. The
+        # engine keeps no reference to the masters.
+        def _loader(cfg_, tree):
+            """`cfg_.family.load` jitted and counted, or None where
+            the family has none or it would hand `tree` back leaf for
+            leaf (told from the shapes and dtypes `eval_shape` gives
+            it): the engine then holds the caller's buffers and runs
+            nothing, where a `jit` would copy the whole tree."""
+            if cfg_.family.load is None:
+                return None
+            load = functools.partial(cfg_.family.load, cfg=cfg_)
 
-        self._quant_target = (fam.quantize is not None
-                              and cfg.weight_dtype == "int8")
-        self._quant_draft = (spec == "draft"
-                             and draft_cfg.weight_dtype == "int8")
-        self._quantize_fn = (jax.jit(_quantize)
-                             if self._quant_target or self._quant_draft
-                             else None)
-        if self._quant_target:
-            self.params = self._quantize_fn(self.params)
-        if self._quant_draft:
-            self.draft_params = self._quantize_fn(self.draft_params)
+            def signature(t):
+                return jax.tree.map(lambda a: (a.shape, a.dtype), t)
+
+            if signature(jax.eval_shape(load, tree)) == signature(tree):
+                return None
+
+            def _load(p):
+                self.load_traces += 1
+                return load(p)
+
+            return jax.jit(_load)
+
+        self._load_target = _loader(cfg, params)
+        self._load_draft = (_loader(draft_cfg, draft_params)
+                            if spec == "draft" else None)
+        if self._load_target is not None:
+            self.params = self._load_target(self.params)
+        if self._load_draft is not None:
+            self.draft_params = self._load_draft(self.draft_params)
 
         # Capacity gauges: total device bytes of the block pool(s) and
         # the bytes one cached position costs — the lever kv_dtype
@@ -649,6 +667,11 @@ class InferenceEngine:
         if self.draft_cache is not None:
             self._pool_bytes += sum(
                 int(arr.nbytes) for arr in self.draft_cache.values())
+        # the tree(s) the steps read; a swap keeps shapes and dtypes
+        self._weight_bytes = sum(
+            int(leaf.nbytes) for leaf in jax.tree.leaves(
+                (self.params, self.draft_params if spec == "draft"
+                 else None)))
         # tokens a block stands for: a state block's share of a
         # sequence of up to max_len
         block_tokens = (self.max_len / self._state_blocks
@@ -995,11 +1018,11 @@ class InferenceEngine:
         self._sentinel.watch("swap", lambda: self.swap_traces,
                              cap=2 if spec == "draft" else 1,
                              registered=True)
-        if self._quantize_fn is not None:
-            self._sentinel.watch(
-                "quantize", lambda: self.quantize_traces,
-                cap=int(self._quant_target) + int(self._quant_draft),
-                registered=True)
+        loaders = sum(fn is not None
+                      for fn in (self._load_target, self._load_draft))
+        if loaders:
+            self._sentinel.watch("load", lambda: self.load_traces,
+                                 cap=loaders, registered=True)
         if spec is not None:
             self._sentinel.watch("verify", lambda: self.verify_traces,
                                  cap=1, registered=True)
@@ -1718,10 +1741,15 @@ class InferenceEngine:
           construction (or returned by a previous swap) is invalidated
           by donation. `new_params` itself is NOT donated — a trainer
           can keep training on the same state it published.
-        - `weight_dtype="int8"` engines still take f32 masters here:
-          the same jitted quantization that ran at construction re-runs
-          on the published tree before validation/placement, so the RL
-          flywheel never handles int8 and the swap stays retrace-free.
+        - Publish what was given at construction, the f32 masters:
+          where the family's load-time function ran there (it casts
+          the masters to the activation dtype and, for
+          `weight_dtype="int8"`, quantizes them), the same jitted
+          function re-runs on the published tree before
+          validation/placement, so the RL flywheel never handles bf16
+          or int8 and the swap stays retrace-free. Where it did not
+          run (no such function, or a tree already in the dtype the
+          steps read), the published tree is placed as it is.
         - The radix prefix cache is flushed: cached K/V was computed
           under the old weights and must not be shared into post-swap
           admissions. In-flight sequences keep their already-written
@@ -1750,16 +1778,16 @@ class InferenceEngine:
                 raise ValueError(
                     "update_params: draft_params given but the "
                     "engine has no draft model")
-            # Int8 weight-only engines hold quantized trees: quantize
+            # The engine holds loaded trees (cast, or quantized): load
             # the published f32 masters BEFORE validation, so the
-            # leaf-for-leaf check compares quantized against quantized
-            # and the donated swap copies int8+scales. Shapes repeat, so
-            # this hits the cached _quantize trace (quantize_traces is
+            # leaf-for-leaf check compares like with like and the
+            # donated swap copies what the steps read. Shapes repeat, so
+            # this hits the cached _load trace (load_traces is
             # sentinel-pinned).
-            if self._quant_target:
-                new_params = self._quantize_fn(new_params)
-            if self._quant_draft and draft_params is not None:
-                draft_params = self._quantize_fn(draft_params)
+            if self._load_target is not None:
+                new_params = self._load_target(new_params)
+            if self._load_draft is not None and draft_params is not None:
+                draft_params = self._load_draft(draft_params)
             placed = self._place_tree(old, new_params, "params")
             placed_draft = (
                 self._place_tree(old_draft, draft_params, "draft_params")
@@ -2660,10 +2688,12 @@ class InferenceEngine:
           each jitted path; tests pin decode/verify to 1 per lifetime.
           ``swap_traces`` — traces of the hot-swap copy fn (once per
           distinct pytree: target and draft each trace once, ever).
-          ``quantize_traces`` — traces of the int8 weight-quantize fn
-          (0 for f32-weight engines; else once per distinct tree shape
-          — target and quantized draft each at most once, however many
-          hot-swaps re-run it).
+          ``load_traces`` — traces of the family's load-time fn, which
+          casts the masters to the activation dtype and quantizes the
+          int8 ones (0 where nothing ran: no such fn, or a tree given
+          in the dtype the steps read; else once per distinct tree —
+          target and draft each at most once, however many hot-swaps
+          re-run it).
 
         Paged cache:
           ``block_size`` / ``cache_blocks`` / ``blocks_in_use`` /
@@ -2690,6 +2720,9 @@ class InferenceEngine:
           ``pool_bytes`` — total device bytes of the preallocated block
           pool(s), payload plus any int8 scale arrays (draft pool
           included); fixed at construction.
+          ``weight_bytes`` — device bytes of the tree(s) the steps read
+          (a draft model's included), after the load-time fn: half the
+          f32 masters' for bf16 activations; fixed at construction.
           ``kv_bytes_per_token`` — main-pool bytes one cached position
           costs (all layers, K+V, scales included) — the capacity
           lever `kv_dtype="int8"` pulls (~4x down vs an f32 pool).
@@ -2934,6 +2967,7 @@ class InferenceEngine:
                 "cancelled": self._cancelled,
                 "max_admission_stall_ms": self._max_admission_stall * 1e3,
                 "pool_bytes": self._pool_bytes,
+                "weight_bytes": self._weight_bytes,
                 "kv_bytes_per_token": self._kv_bytes_per_token,
                 # load stats the autoscaler consumes (queued imports
                 # are demand exactly like queued prompts)
@@ -2987,7 +3021,7 @@ class InferenceEngine:
                 "swaps": self._swaps,
                 "weight_swap_ms": self._last_swap_ms,
                 "swap_traces": self.swap_traces,
-                "quantize_traces": self.quantize_traces,
+                "load_traces": self.load_traces,
                 # fault tolerance
                 "sheds": self._sheds,
                 "watchdog_stalls": self._watchdog_stalls,

@@ -109,7 +109,7 @@ COMPILE_ONCE_JITS: dict[str, dict[str, str | None]] = {
         "self._propose_fn": "draft",
         "self._draft_prefill_fn": "draft_prefill",
         "self._swap_fn": "swap",
-        "self._quantize_fn": "quantize",  # int8 weight-only path
+        "_loader": "load",              # factory: the family's load-time fn
         # disaggregated prefill/decode block transport (one trace per
         # pool geometry: target + optional draft pool)
         "self._gather_fn": "kv_gather",

@@ -1152,10 +1152,10 @@ class TestQuantizedEngine:
         assert list(a) == list(b)           # peaked logits: same argmax
         deltas = [abs(x.logprob - y.logprob) for x, y in zip(a, b)]
         assert max(deltas) < 0.05
-        assert eng_w.quantize_traces == 1
-        assert eng_w.stats()["quantize_traces"] == 1
+        assert eng_w.load_traces == 1
+        assert eng_w.stats()["load_traces"] == 1
         eng_w.update_params(params)         # same shapes: no retrace
-        assert eng_w.quantize_traces == 1
+        assert eng_w.load_traces == 1
         assert list(eng_w.generate(prompt, max_new_tokens=8)) == list(b)
         eng_w.check_invariants()
 
