@@ -1,5 +1,13 @@
-"""Decoder with latent attention, learned sparse selection and routed
-experts, on the serving path.
+"""Decoder with latent attention, routed experts and, where a layer has
+one, a learned sparse selection of the keys: served and trained.
+
+One family, two published shapes of its layer. With an indexer and a query
+bottleneck (`glm_moe_dsa`: GLM-5.2) it is served through the engine
+(`prefill`, `decode`, the paged pool). Without either (`deepseek_v3`:
+Kanana-2; `indexer_types` all "none", `q_rank` None) every query attends
+to every earlier position; that layer is trained (`forward_features`,
+`train.spmd.make_latent_moe_trainer`), and the serving entry points
+refuse it until a dense latent decode kernel exists.
 
 Three mechanisms in one layer, none of which `models/gpt.py` has:
 
@@ -26,6 +34,13 @@ Three mechanisms in one layer, none of which `models/gpt.py` has:
   dropless, plus the shared expert every chip computes alike. What the
   absent experts would add is left out (no code stands in for them).
 
+Training (`forward_features`) runs the expanded-head form through
+`ops.flash_attention` (keys `nope_dim + rope_dim` wide, values `v_dim`),
+the held experts through `ops.grouped_experts`' kernels and their
+backward pass, and rematerialises layer by layer. `router_bias` takes no
+gradient: `update_router_bias` moves it after a step by the step's own
+expert counts.
+
 The pool is two kinds of state under one block table: `"latent"` uint32
 `[L, n_blocks, block_size, 1, words]` (`ops/sparse_latent.py`'s row format)
 for every layer and `"index"` `[L_full, n_blocks, block_size, index_dim]`
@@ -36,7 +51,8 @@ Rotary positions are interleaved pairs. The dense layer and the shared
 expert are `gpt._gated_mlp`; the norms `gpt._rms_norm`. Parameters: the
 tree `benchmarks/refs/latent_sparse_moe.py` documents (a list of layer
 dicts; layers differ, so they are not stacked), leaves of any float type,
-cast at use.
+cast at use. A layer without the bottleneck has `w_q` [D, H*(nope+rope)]
+in place of `wq_a`, `q_norm_scale` and `wq_b`.
 """
 
 from __future__ import annotations
@@ -59,6 +75,14 @@ COUNTS = ("index_scanned_tokens", "index_selected_tokens",
           "index_layer_runs", "index_layer_reuses",
           "expert_tokens_here", "expert_tokens_routed")
 
+# training. The embedding's scale in `init_params`: not the 0.02 of the
+# other leaves' family, because a residual that starts that small is soon
+# the mean value vector of the sequence's earlier positions, alike for
+# every token, and the router then sends them all to the same experts
+# (PERF.md, PR 38: one expert of 80 got every pair).
+EMBED_INIT = 1.0
+BIAS_UPDATE_RATE = 0.001    # gamma of `update_router_bias`
+
 
 @dataclass(frozen=True)
 class LatentSparseMoEConfig:
@@ -66,16 +90,17 @@ class LatentSparseMoEConfig:
     d_model: int = 64
     n_layers: int = 3
     n_heads: int = 4
-    q_rank: int = 32
+    q_rank: int | None = 32      # None: q = h W_q, no bottleneck
     kv_rank: int = 32
     nope_dim: int = 16
     rope_dim: int = 8
     v_dim: int = 16
     index_heads: int = 4
     index_dim: int = 16
-    index_topk: int = 16
+    index_topk: int | None = 16
     # one entry a published layer; the layers that run are
-    # [first_layer, first_layer + n_layers)
+    # [first_layer, first_layer + n_layers). "none": the layer has no
+    # indexer and attends to every earlier position
     indexer_types: tuple = ("full", "shared", "full")
     mlp_types: tuple = ("dense", "sparse", "sparse")
     first_layer: int = 0
@@ -99,14 +124,36 @@ class LatentSparseMoEConfig:
     # nothing, and this family has no `kv_dtype` for that reason
     cache_round: str = "none"        # none | int8
     sparse_impl: str = "auto"        # auto | pallas | jax (the three ops)
+    # training (`forward_features`): q and kv rows a grid step of the flash
+    # kernels, at most (the sweep at keys 192, values 128, T 8192 is
+    # PERF.md's, PR 38; 2048 x 2048 does not fit VMEM)
+    flash_block_q: int = 1024
+    flash_block_kv: int = 2048
+    # test-only, for the benchmark's control: the routed experts' inputs
+    # and matrices rounded to this type's grid before their matmuls
+    expert_round: str = "none"       # none | float8_e4m3fn
 
     def __post_init__(self):
         for name in ("indexer_types", "mlp_types"):
             object.__setattr__(self, name, tuple(getattr(self, name)))
-        if self.kinds[0][1] != "full":
+        indexers = {ix for _, ix in self.kinds}
+        if "none" in indexers:
+            if indexers != {"none"}:
+                raise ValueError("layers with and without an indexer do "
+                                 "not mix")
+        elif self.kinds[0][1] != "full":
             raise ValueError("the first layer run must own an indexer")
+        elif self.q_rank is None or self.index_topk is None:
+            raise ValueError("an indexer reads the query bottleneck: "
+                             "q_rank and index_topk are numbers")
         if self.cache_round not in ("none", "int8"):
             raise ValueError(f"unknown cache_round {self.cache_round!r}")
+        if self.expert_round not in ("none", "float8_e4m3fn"):
+            raise ValueError(f"unknown expert_round {self.expert_round!r}")
+
+    @property
+    def has_indexer(self) -> bool:
+        return self.kinds[0][1] != "none"
 
     @property
     def kinds(self) -> tuple:
@@ -133,25 +180,36 @@ class LatentSparseMoEConfig:
 
 def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
                    q_lora_rank, kv_lora_rank, qk_nope_head_dim,
-                   qk_rope_head_dim, v_head_dim, index_n_heads,
-                   index_head_dim, mlp_layer_types, intermediate_size,
+                   qk_rope_head_dim, v_head_dim, intermediate_size,
                    moe_intermediate_size, n_shared_experts, n_routed_experts,
                    num_experts_per_tok, routed_scaling_factor,
                    norm_topk_prob, rms_norm_eps, max_position_embeddings,
-                   layers_from=0, experts_held_from=0, published=None,
+                   index_n_heads=0, index_head_dim=0, index_topk=None,
+                   indexer_types=None, mlp_layer_types=None,
+                   first_k_dense_replace=None, layers_from=0,
+                   experts_held_from=0, published=None,
                    **same) -> LatentSparseMoEConfig:
     """The configuration from a published `config.json`'s own keys
-    (`glm_moe_dsa`'s names). `n_routed_experts` is how many experts are
-    held here; the router's width is `published["n_routed_experts"]`
+    (`glm_moe_dsa`'s names, or `deepseek_v3`'s, which has no indexer: no
+    `indexer_types`, and `first_k_dense_replace` leading dense layers in
+    place of `mlp_layer_types`). `n_routed_experts` is how many experts
+    are held here; the router's width is `published["n_routed_experts"]`
     where a share is run, else the same number. Keys this module spells
-    as the source does (`vocab_size`, `index_topk`, `indexer_types`,
-    `rope_theta`, `dtype`, ...) pass through."""
+    as the source does (`vocab_size`, `rope_theta`, `dtype`, ...) pass
+    through."""
+    n_published = layers_from + num_hidden_layers
+    if mlp_layer_types is None:
+        mlp_layer_types = ["dense" if i < first_k_dense_replace else "sparse"
+                           for i in range(n_published)]
+    if indexer_types is None:
+        indexer_types = ["none"] * n_published
     return LatentSparseMoEConfig(
         d_model=hidden_size, n_layers=num_hidden_layers,
         n_heads=num_attention_heads, q_rank=q_lora_rank,
         kv_rank=kv_lora_rank, nope_dim=qk_nope_head_dim,
         rope_dim=qk_rope_head_dim, v_dim=v_head_dim,
         index_heads=index_n_heads, index_dim=index_head_dim,
+        index_topk=index_topk, indexer_types=indexer_types,
         mlp_types=mlp_layer_types, first_layer=layers_from,
         d_ff=intermediate_size, expert_ff=moe_intermediate_size,
         shared_experts=n_shared_experts,
@@ -167,9 +225,17 @@ def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
 # the pool
 # ---------------------------------------------------------------------------
 
+def _served(cfg) -> None:
+    if not cfg.has_indexer:
+        raise NotImplementedError(
+            "layers without an indexer are trained, not served: this "
+            "family has no dense latent decode kernel yet")
+
+
 def init_pool(cfg: LatentSparseMoEConfig, n_blocks: int, block_size: int,
               mesh=None):
     """{"latent", "index"}, zero-filled; blocks on axis 1 of both."""
+    _served(cfg)
     if mesh is not None:
         raise ValueError("this family's pool is not sharded over a mesh")
     n_full = sum(ix == "full" for _, ix in cfg.kinds)
@@ -259,8 +325,12 @@ def _project(h, lp, pos, cfg):
     `[c_kv | k_rope]`)."""
     adt = cfg.activation_dtype()
     n = h.shape[0]
-    c_q = _norm(_mm(h, lp["wq_a"], adt), lp["q_norm_scale"], cfg)
-    q = _mm(c_q, lp["wq_b"], adt).reshape(n, cfg.n_heads, -1)
+    if "w_q" in lp:
+        q = _mm(h, lp["w_q"], adt)
+    else:
+        c_q = _norm(_mm(h, lp["wq_a"], adt), lp["q_norm_scale"], cfg)
+        q = _mm(c_q, lp["wq_b"], adt)
+    q = q.reshape(n, cfg.n_heads, -1)
     q_nope = q[..., :cfg.nope_dim]
     q_rope = rope(q[..., cfg.nope_dim:], pos, cfg.rope_theta)
     kv = _mm(h, lp["wkv_a"], adt)
@@ -327,35 +397,62 @@ def routing(h2, lp, cfg):
     return chosen.astype(jnp.int32), weights * cfg.routed_scale
 
 
+def _rounded(a, cfg):
+    """`cfg.expert_round`'s grid (float8_e4m3fn: three bits of mantissa,
+    at most 448; ties to even, the small exponents' coarser steps left
+    out), in a's own type; the gradient passes as through a cast. By
+    arithmetic on the bits and not by a cast there and back: the TPU
+    compiler drops a round trip through a type its chip has no unit for,
+    and the control then rounds nothing (PERF.md, PR 38)."""
+    if cfg.expert_round == "none":
+        return a
+    drop = 23 - 3                       # float32 mantissa bits to lose
+    bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
+    bits = (bits + jnp.uint32((1 << (drop - 1)) - 1)
+            + ((bits >> drop) & jnp.uint32(1))) & jnp.uint32(
+                ~((1 << drop) - 1) & 0xFFFFFFFF)
+    grid = jnp.clip(jax.lax.bitcast_convert_type(bits, jnp.float32),
+                    -448.0, 448.0).astype(a.dtype)
+    return a + jax.lax.stop_gradient(grid - a)
+
+
 def expert_layer(h2, lp, cfg, live=None,
-                 kernel: str = grouped_experts.EXPERTS_GROUPED):
+                 kernel: str = grouped_experts.EXPERTS_GROUPED,
+                 every_load: bool = False):
     """A sparse layer's two parts on normed h2 [N, D]: -> (routed: what
-    the held experts add, shared: the shared expert's, counts
-    [2 + held_count] i32: pairs routed here, pairs routed anywhere, pairs
-    each held expert got; rows where `live` is false count nothing)."""
+    the held experts add, shared: the shared expert's, counts i32: pairs
+    routed here, pairs routed anywhere, then the pairs each held expert
+    got, or with `every_load` each expert of the router's whole width,
+    held or not; rows where `live` is false count nothing)."""
     adt = cfg.activation_dtype()
     chosen, weights = routing(h2, lp, cfg)
     if live is not None:
         chosen = jnp.where(live[:, None], chosen, -1)
     routed, load = grouped_experts.experts_grouped(
-        h2, chosen, weights, lp["we_gate"], lp["we_up"], lp["we_down"],
+        _rounded(h2, cfg), chosen, weights, _rounded(lp["we_gate"], cfg),
+        _rounded(lp["we_up"], cfg), _rounded(lp["we_down"], cfg),
         held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
     shared, _ = gpt._gated_mlp(
         h2, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
              "w_down": lp["ws_down"]}, adt, jnp.float32)
+    here = jnp.sum(load)
+    if every_load:
+        load = jnp.sum(chosen[..., None] == jnp.arange(cfg.router_width),
+                       (0, 1), dtype=jnp.int32)
     counts = jnp.concatenate([
-        jnp.stack([jnp.sum(load), jnp.sum(chosen >= 0, dtype=jnp.int32)]),
-        load])
+        jnp.stack([here, jnp.sum(chosen >= 0, dtype=jnp.int32)]), load])
     return routed.astype(adt), shared, counts
 
 
 def _feed_forward(x, lp, cfg, live=None,
-                  kernel: str = grouped_experts.EXPERTS_GROUPED):
+                  kernel: str = grouped_experts.EXPERTS_GROUPED,
+                  every_load: bool = False):
     """x += the layer's feed-forward; -> (x, expert counts or None)."""
     adt = cfg.activation_dtype()
     h2 = _norm(x, lp["ffn_norm_scale"], cfg)
     if "router" in lp:
-        routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel)
+        routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel,
+                                              every_load)
         return x + routed + shared, counts
     return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
 
@@ -423,7 +520,7 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
         pos = jnp.arange(t, dtype=jnp.int32)
         causal = pos[None, :] <= pos[:, None]
         x = params["embed"].astype(adt)[seq]
-        selected = None
+        selected = None if cfg.has_indexer else causal
         for lp in params["layers"]:
             h = _norm(x, lp["attn_norm_scale"], cfg)
             q_nope, q_rope, row = _project(h, lp, pos, cfg)
@@ -454,6 +551,179 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
     if selections is not None:          # the list is filled outside a map
         return jnp.stack([one(seq) for seq in tokens])
     return jax.lax.map(one, tokens)
+
+
+# ---------------------------------------------------------------------------
+# training: layers without an indexer, whole sequences, expanded heads
+# ---------------------------------------------------------------------------
+
+def init_params(key, cfg: LatentSparseMoEConfig):
+    """float32 master parameters of the layers that run, cast at use:
+    normal, fan-in^-1/2, residual outputs x (2 x layers)^-1/2, embedding
+    `EMBED_INIT`, norm scales 1, `router_bias` 0 (the tree of the module's
+    header; a layer with an indexer is served from stored weights and is
+    not made here)."""
+    if cfg.has_indexer:
+        raise NotImplementedError("init_params makes layers without an "
+                                  "indexer (the ones that are trained)")
+    d, nh, rkv = cfg.d_model, cfg.n_heads, cfg.kv_rank
+    qk = cfg.nope_dim + cfg.rope_dim
+    fs = cfg.expert_ff * cfg.shared_experts
+    residual = (2.0 * cfg.n_layers) ** -0.5
+    keys = iter(jax.random.split(key, 2 + 12 * cfg.n_layers))
+
+    def normal(shape, scale):
+        return jax.random.normal(next(keys), shape, jnp.float32) * scale
+
+    def ones(n):
+        return jnp.ones((n,), jnp.float32)
+
+    layers = []
+    for mlp, _ in cfg.kinds:
+        lp = {"attn_norm_scale": ones(d), "ffn_norm_scale": ones(d),
+              "wkv_a": normal((d, rkv + cfg.rope_dim), d ** -0.5),
+              "kv_norm_scale": ones(rkv),
+              "wkv_b": normal((rkv, nh * (cfg.nope_dim + cfg.v_dim)),
+                              rkv ** -0.5),
+              "w_out": normal((nh * cfg.v_dim, d),
+                              (nh * cfg.v_dim) ** -0.5 * residual)}
+        if cfg.q_rank is None:
+            lp["w_q"] = normal((d, nh * qk), d ** -0.5)
+        else:
+            lp.update(wq_a=normal((d, cfg.q_rank), d ** -0.5),
+                      q_norm_scale=ones(cfg.q_rank),
+                      wq_b=normal((cfg.q_rank, nh * qk), cfg.q_rank ** -0.5))
+        if mlp == "dense":
+            lp.update(w_gate=normal((d, cfg.d_ff), d ** -0.5),
+                      w_up=normal((d, cfg.d_ff), d ** -0.5),
+                      w_down=normal((cfg.d_ff, d),
+                                    cfg.d_ff ** -0.5 * residual))
+        else:
+            experts = (cfg.held_count, cfg.expert_ff, d)
+            lp.update(
+                router=normal((d, cfg.router_width), d ** -0.5),
+                router_bias=jnp.zeros((cfg.router_width,), jnp.float32),
+                we_gate=normal(experts, d ** -0.5),
+                we_up=normal(experts, d ** -0.5),
+                we_down=normal(experts, cfg.expert_ff ** -0.5 * residual),
+                ws_gate=normal((d, fs), d ** -0.5),
+                ws_up=normal((d, fs), d ** -0.5),
+                ws_down=normal((fs, d), fs ** -0.5 * residual))
+        layers.append(lp)
+    return {"embed": normal((cfg.vocab_size, d), EMBED_INIT),
+            "head": normal((cfg.vocab_size, d), d ** -0.5),
+            "final_ln_scale": ones(d), "layers": layers}
+
+
+def param_logical_axes(cfg: LatentSparseMoEConfig):
+    """Every leaf whole on every device: one chip's share is trained on
+    one chip (the expert exchange over chips is not built)."""
+    shapes = jax.eval_shape(lambda: init_params(jax.random.key(0), cfg))
+    return jax.tree.map(lambda a: (None,) * a.ndim, shapes)
+
+
+def is_router_bias(params):
+    """The tree of bools that is true at the `router_bias` leaves: the
+    ones no optimizer moves."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: getattr(path[-1], "key", None) == "router_bias",
+        params)
+
+
+def _train_attention(h, lp, pos, cfg):
+    """Latent attention of normed h [B, T, D] over each sequence's
+    earlier positions, keys and values rebuilt for every head, through
+    W_o: -> [B, T, D]."""
+    from ray_tpu.ops.flash_attention import flash_attention
+    adt = cfg.activation_dtype()
+    b, t, d = h.shape
+    nh = cfg.n_heads
+    q_nope, q_rope, row = _project(h.reshape(b * t, d), lp, pos, cfg)
+    kv = jnp.einsum("sc,chd->shd", row[:, :cfg.kv_rank], _kv_up(lp, cfg, adt),
+                    preferred_element_type=jnp.float32).astype(adt)
+    k_rope = jnp.broadcast_to(row[:, None, cfg.kv_rank:],
+                              (b * t, nh, cfg.rope_dim))
+    q = jnp.concatenate([q_nope, q_rope], -1).reshape(b, t, nh, -1)
+    k = jnp.concatenate([kv[..., :cfg.nope_dim], k_rope],
+                        -1).reshape(b, t, nh, -1)
+    v = kv[..., cfg.nope_dim:].reshape(b, t, nh, cfg.v_dim)
+    att = flash_attention(q, k, v, True, cfg.flash_block_q,
+                          cfg.flash_block_kv)
+    return _mm(att.reshape(b, t, nh * cfg.v_dim), lp["w_out"], adt)
+
+
+def _train_layer(x, lp, pos, cfg):
+    """-> (x [B, T, D], the layer's expert counts [2 + router_width] i32,
+    None for a dense layer)."""
+    b, t, d = x.shape
+    x = x + _train_attention(_norm(x, lp["attn_norm_scale"], cfg), lp, pos,
+                             cfg)
+    x, counts = _feed_forward(
+        x.reshape(b * t, d), lp, cfg,
+        kernel=grouped_experts.EXPERTS_GROUPED_TRAIN, every_load=True)
+    return x.reshape(b, t, d), counts
+
+
+def forward_features(params, tokens, cfg: LatentSparseMoEConfig, mesh=None):
+    """tokens [B, T] -> (final-normed activations [B, T, D] in the
+    activation type: everything but the head, which the fused loss folds
+    in; counts [sparse layers, 2 + router_width] i32: each sparse layer's
+    pairs routed here, pairs routed anywhere, and every expert's load).
+    Each layer is a `jax.checkpoint` of its own that keeps its input and
+    the flash forward's output and logsumexp, so that its backward runs
+    the dQ and dK/dV kernels without the forward again; everything else of
+    the layer is made again (at the cell's size 4.5 GB are too little for
+    the matmuls' outputs)."""
+    if cfg.has_indexer:
+        raise NotImplementedError("training a layer with an indexer is "
+                                  "not built: the selection has no "
+                                  "backward pass")
+    if mesh is not None and mesh.size > 1:
+        raise NotImplementedError("one chip's share is trained on one "
+                                  "chip: the expert exchange over chips "
+                                  "is not built")
+    from ray_tpu.ops.flash_attention import SAVED_NAMES
+    adt = cfg.activation_dtype()
+    b, t = tokens.shape
+    pos = jnp.tile(jnp.arange(t, dtype=jnp.int32), b)
+    layer = jax.checkpoint(
+        lambda x, lp: _train_layer(x, lp, pos, cfg),
+        policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
+    x = params["embed"].astype(adt)[tokens]
+    counts = []
+    for lp in params["layers"]:
+        x, c = layer(x, lp)
+        if c is not None:
+            counts.append(c)
+    counts = jnp.stack(counts) if counts else jnp.zeros(
+        (0, 2 + cfg.router_width), jnp.int32)
+    return _norm(x, params["final_ln_scale"], cfg), counts
+
+
+def update_router_bias(params, counts, cfg: LatentSparseMoEConfig):
+    """The step's own part of the router: every sparse layer's
+    `router_bias` moves by `BIAS_UPDATE_RATE` x sign(mean load - load)
+    over the router's whole width (the auxiliary-loss-free balancing of
+    the DeepSeek-V3 report), from `forward_features`' counts. -> (params,
+    the step's expert metrics)."""
+    layers, row = [], 0
+    for lp in params["layers"]:
+        if "router" in lp:
+            load = counts[row, 2:].astype(jnp.float32)
+            bias = lp["router_bias"] + BIAS_UPDATE_RATE * jnp.sign(
+                jnp.mean(load) - load).astype(lp["router_bias"].dtype)
+            lp, row = {**lp, "router_bias": bias}, row + 1
+        layers.append(lp)
+    held = counts[:, 2 + cfg.held_from:2 + cfg.held_from + cfg.held_count]
+    return {**params, "layers": layers}, {
+        "expert_pairs_here": jnp.sum(counts[:, 0]),
+        "expert_pairs_routed": jnp.sum(counts[:, 1]),
+        "expert_load_max": jnp.max(held),
+        "expert_load_mean": jnp.mean(held.astype(jnp.float32)),
+        "router_bias_abs_max": jnp.max(jnp.stack(
+            [jnp.max(jnp.abs(lp["router_bias"])) for lp in layers
+             if "router" in lp])),
+    }
 
 
 # ---------------------------------------------------------------------------
@@ -552,6 +822,7 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     through `block_table` before the layer attends. -> (logits [1, V] f32
     of the chunk's last real position, cache, counts)."""
     c = tokens.shape[1]
+    _served(cfg)
     if tokens.shape[0] != 1:
         raise ValueError(f"paged prefill wants tokens [1, C], got batch "
                          f"{tokens.shape[0]}")
@@ -617,6 +888,7 @@ def decode(params, tokens, cache, pos, tables,
     indexer selected in this step. Idle rows point their table at the
     trash block; they compute garbage nobody reads and count nothing.
     -> (logits [B, V] f32, cache, counts)."""
+    _served(cfg)
     adt = cfg.activation_dtype()
     nb, bs = cache["latent"].shape[1], cache["latent"].shape[2]
     mb = tables.shape[1]

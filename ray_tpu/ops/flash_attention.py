@@ -243,19 +243,21 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
 
 def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, plan: _Plan,
                 interpret: bool, with_lse: bool):
-    """q,k,v: [BH, T, D] with T divisible by both block sizes.
+    """q, k: [BH, T, D], v: [BH, T, Dv] with T divisible by both block
+    sizes.
 
-    Returns (out [BH, T, D], lse) where lse is [BH, T, 128] f32 (per-row
+    Returns (out [BH, T, Dv], lse) where lse is [BH, T, 128] f32 (per-row
     logsumexp broadcast across the lane tile) when with_lse, else None."""
     bh, t, d = q.shape
+    dv = v.shape[-1]
     block_q, block_kv = plan.block_q, plan.block_kv
     grid = (bh, t // block_q, t // block_kv)
 
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, plan=plan,
         with_lse=with_lse)
-    out_shape = [jax.ShapeDtypeStruct((bh, t, d), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))]
+    out_shape = [jax.ShapeDtypeStruct((bh, t, dv), q.dtype)]
+    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((bh, t, 128), jnp.float32))
         out_specs.append(
@@ -269,13 +271,13 @@ def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, plan: _Plan,
             in_specs=[
                 pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
                 pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
+                pl.BlockSpec((1, block_kv, dv), lambda b, i, j: (b, j, 0)),
             ],
             out_specs=tuple(out_specs),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),   # m, lanes alike
                 pltpu.VMEM((block_q, 128), jnp.float32),   # l
-                pltpu.VMEM((block_q, d), jnp.float32),     # acc
+                pltpu.VMEM((block_q, dv), jnp.float32),    # acc
             ],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
@@ -399,13 +401,17 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
                     causal: bool, plan: _Plan, interpret: bool):
-    """All inputs [BH, T, D] (lse/delta [BH, T, 128] f32) -> (dq, dk, dv)."""
+    """q, k [BH, T, D]; v, do [BH, T, Dv] (lse/delta [BH, T, 128] f32)
+    -> (dq, dk, dv)."""
     bh, t, d = q.shape
+    dv = v.shape[-1]
     block_q, block_kv = plan.block_q, plan.block_kv
     common = dict(sm_scale=sm_scale, causal=causal, plan=plan)
 
     qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
     kspec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
+    vspec = pl.BlockSpec((1, block_kv, dv), lambda b, i, j: (b, j, 0))
+    dospec = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
     rowq = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
     with jax.named_scope(FLASH_DQ):
         dq = pl.pallas_call(
@@ -413,7 +419,7 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
             name=FLASH_DQ,
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
             grid=(bh, t // block_q, t // block_kv),
-            in_specs=[qspec, kspec, kspec, qspec, rowq, rowq],
+            in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
             out_specs=qspec,
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
@@ -424,18 +430,20 @@ def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
     # dKV grid: kv blocks parallel, q blocks innermost/sequential.
     qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
     kspec2 = pl.BlockSpec((1, block_kv, d), lambda b, j, i: (b, j, 0))
+    vspec2 = pl.BlockSpec((1, block_kv, dv), lambda b, j, i: (b, j, 0))
+    dospec2 = pl.BlockSpec((1, block_q, dv), lambda b, j, i: (b, i, 0))
     rowq2 = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
     with jax.named_scope(FLASH_DKV):
         dk, dv = pl.pallas_call(
             functools.partial(_dkv_kernel, **common),
             name=FLASH_DKV,
             out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, t, d), v.dtype)),
+                       jax.ShapeDtypeStruct((bh, t, dv), v.dtype)),
             grid=(bh, t // block_kv, t // block_q),
-            in_specs=[qspec2, kspec2, kspec2, qspec2, rowq2, rowq2],
-            out_specs=(kspec2, kspec2),
+            in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
+            out_specs=(kspec2, vspec2),
             scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
-                            pltpu.VMEM((block_kv, d), jnp.float32)],
+                            pltpu.VMEM((block_kv, dv), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
@@ -491,12 +499,27 @@ def _head_pad_target(d: int) -> int:
     return d if d % 8 == 0 else -(-d // 8) * 8
 
 
+def _bhtd(x):
+    """[B, T, H, D] -> [B * H, T, D padded to whole sublanes]."""
+    b, t, h, d = x.shape
+    d_pad = _head_pad_target(d)
+    return _pad_heads(x, d_pad).transpose(0, 2, 1, 3).reshape(b * h, t, d_pad)
+
+
+def _unbhtd(x, b: int, h: int, d: int):
+    """`_bhtd` undone: [B * H, T, D padded] -> [B, T, H, d]."""
+    return x.reshape(b, h, *x.shape[1:]).transpose(0, 2, 1, 3)[..., :d]
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 2048,
                     block_kv: int = 2048):
     """[B, T, H, D] attention; falls back to the XLA path on
     TPU-unfriendly shapes. Fully differentiable: both directions are
-    Pallas kernels (backward = dQ + dKV kernels over saved lse).
+    Pallas kernels (backward = dQ + dKV kernels over saved lse). v may
+    have another width than q and k (latent attention: 192 against 128);
+    the scale is that of q's width, the output has v's, and neither is
+    padded to the other.
 
     `block_q` / `block_kv` are upper bounds: blocks shrink to the largest
     divisor of T, so ragged sequence lengths stay on the kernel path. The
@@ -521,7 +544,26 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 2048,
     the square (0.5625 against 0.625) and loses more to what every tile
     costs whatever its size: per-row statistics, accumulator read-modify-
     writes, MXU weight loads. Whole-T blocks beat 1024 because a grid
-    step above the diagonal still fetches its K and V."""
+    step above the diagonal still fetches its K and V.
+
+    Keys wider than values (latent attention), swept the same way
+    (`benchmarks/tools/flash_sweep.py`, PERF.md, PR 38) at (BH, T, d_qk,
+    d_v) = (64, 8192, 192, 128), block_q x block_kv, tile:
+
+        blocks, tile             forward   dQ        dK/dV
+        2048 x 2048, 512 x 512   refused: scoped VMEM (a 192-wide block
+                                 is stored 256 lanes wide)
+        2048 x 1024, 512 x 512   12.672    19.464    24.292
+        1024 x 2048, 512 x 512   13.640    19.846    22.576
+        1024 x 1024, 512 x 512   14.147    20.813    25.272
+        1024 x  512, 512 x 512   14.689    22.349    30.206
+         512 x 1024, 512 x 512   16.609    23.214    25.905
+         512 x  512, 512 x 512   17.504    25.039    31.481
+        1024 x 1024, 256 x 256   23.166    23.859    32.286
+
+    The caller passes the bounds (`models/latent_sparse_moe.py`: 1024 x
+    2048, 56.1 ms the three together against 56.4 and 60.2); the default
+    bounds and the (d, d) plan above are as they were."""
     out, _ = _flash_forward_impl(q, k, v, causal, block_q, block_kv,
                                  with_lse=False)
     return out
@@ -532,19 +574,16 @@ def _flash_forward_impl(q, k, v, causal, block_q, block_kv, with_lse):
     when with_lse=False (the inference variant, which skips the lse
     write entirely)."""
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     plan = _plan_blocks(t, block_q, block_kv)
     if plan is None:
         backend.note_fallback("flash_attention", f"T={t}")
         return reference_attention(q, k, v, causal=causal), None
     interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
-    bhtd = lambda x: (_pad_heads(x, d_pad)
-                      .transpose(0, 2, 1, 3).reshape(b * h, t, d_pad))
-    out, lse = _flash_bhtd(bhtd(q), bhtd(k), bhtd(v), sm_scale=d ** -0.5,
+    out, lse = _flash_bhtd(_bhtd(q), _bhtd(k), _bhtd(v), sm_scale=d ** -0.5,
                            causal=causal, plan=plan, interpret=interpret,
                            with_lse=with_lse)
-    out = out.reshape(b, h, t, d_pad).transpose(0, 2, 1, 3)
-    return out[..., :d], lse
+    return _unbhtd(out, b, h, dv), lse
 
 
 def _flash_fwd(q, k, v, causal, block_q, block_kv):
@@ -570,23 +609,20 @@ def _flash_bwd(causal, block_q, block_kv, res, g):
         return vjp(g)
 
     b, t, h, d = q.shape
+    dv = v.shape[-1]
     plan = _plan_blocks(t, block_q, block_kv)
     interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
     # delta_i = rowsum(dO_i * O_i) — O(T*D) traffic, fine in XLA.
     delta = jnp.sum(g.astype(jnp.float32) * out.astype(jnp.float32),
                     axis=-1)                          # [B, T, H]
     delta = delta.transpose(0, 2, 1).reshape(b * h, t)
     delta = jnp.broadcast_to(delta[..., None], (b * h, t, 128))
     lse = jnp.broadcast_to(lse[..., None], (b * h, t, 128))
-    bhtd = lambda x: (_pad_heads(x, d_pad)
-                      .transpose(0, 2, 1, 3).reshape(b * h, t, d_pad))
-    dq, dk, dv = _flash_bwd_bhtd(
-        bhtd(q), bhtd(k), bhtd(v), bhtd(g), lse, delta,
+    dq, dk, dv_ = _flash_bwd_bhtd(
+        _bhtd(q), _bhtd(k), _bhtd(v), _bhtd(g), lse, delta,
         sm_scale=d ** -0.5, causal=causal, plan=plan, interpret=interpret)
-    unbhtd = lambda x: (x.reshape(b, h, t, d_pad)
-                        .transpose(0, 2, 1, 3)[..., :d])
-    return unbhtd(dq), unbhtd(dk), unbhtd(dv)
+    return (_unbhtd(dq, b, h, d), _unbhtd(dk, b, h, d),
+            _unbhtd(dv_, b, h, dv))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

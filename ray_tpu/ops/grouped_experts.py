@@ -18,10 +18,24 @@ expert's three matrices are stored `[held, F, D]`, width first, so that a
 slice of the width is whole rows of D.
 
 The pure-JAX path is the plain loop over the held experts, each over every
-token.
+token; it differentiates as it is, and is what the kernels are compared
+with.
+
+The kernel path differentiates through a `custom_vjp` over the same group
+layout (training: `EXPERTS_GROUPED_TRAIN` names the forward). Nothing of
+the forward is kept but its inputs. `experts_grouped_dx` walks the row
+tiles as the forward does, makes gate and up again, and gives each row's
+dX, the gradient to the row's routing weight, and the three [tile, slice]
+factors the weights' gradients are made of; `experts_grouped_dw` walks
+the tiles once a slice of the width and sums each group's rows into its
+expert's dW_gate, dW_up and dW_down, one reduction a group. Tiles past
+the last group cost nothing in either, as in the forward; an expert that
+got no token gets zeros.
 """
 
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -37,9 +51,17 @@ from ray_tpu.ops.sparse_latent import resolve_impl
 # the decode step's few tokens an expert from the chunk's many.
 EXPERTS_GROUPED = "experts_grouped"
 EXPERTS_GROUPED_PREFILL = "experts_grouped_prefill"
+EXPERTS_GROUPED_TRAIN = "experts_grouped_train"
+EXPERTS_GROUPED_DX = "experts_grouped_dx"
+EXPERTS_GROUPED_DW = "experts_grouped_dw"
 
 WIDTH_SLICE = 256           # rows of the expert width a grid step
 VMEM_LIMIT = 64 * 1024 * 1024
+
+# `dot_general` dimension numbers
+_NT = (((1,), (1,)), ((), ()))      # [a, c] x [b, c] -> [a, b]
+_NN = (((1,), (0,)), ((), ()))      # [a, c] x [c, b] -> [a, b]
+_TN = (((0,), (0,)), ((), ()))      # [c, a] x [c, b] -> [a, b]
 
 
 def reference_experts_grouped(x, chosen, weights, w_gate, w_up, w_down,
@@ -122,37 +144,42 @@ def _experts_kernel(expert_ref, block_ref, live_ref, x_ref, wg_ref, wu_ref,
             acc[...] = jnp.zeros_like(acc)
 
         x = x_ref[...]
-        nt = (((1,), (1,)), ((), ()))
-        gate = jax.lax.dot_general(x, wg_ref[0], nt,
+        gate = jax.lax.dot_general(x, wg_ref[0], _NT,
                                    preferred_element_type=jnp.float32)
-        up = jax.lax.dot_general(x, wu_ref[0], nt,
+        up = jax.lax.dot_general(x, wu_ref[0], _NT,
                                  preferred_element_type=jnp.float32)
         hidden = (jax.nn.silu(gate) * up).astype(x.dtype)   # [tile, slice]
         acc[...] += jax.lax.dot_general(
-            hidden, wd_ref[0], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            hidden, wd_ref[0], _NN, preferred_element_type=jnp.float32)
 
         @pl.when(f == pl.num_programs(1) - 1)
         def _store():
             o_ref[...] = acc[...].astype(o_ref.dtype)
 
 
-def _grouped_pallas(xs, tile_expert, tile_block, n_live, w_gate, w_up,
-                    w_down, tile: int, name: str):
-    m, d = xs.shape
-    _, width, _ = w_gate.shape
+def _width_slice(width: int) -> tuple[int, int]:
+    """(rows of the expert width a grid step, steps)."""
     fs = min(WIDTH_SLICE, width)
     while width % fs:
         fs -= 1
-    nf = width // fs
+    return fs, width // fs
 
+
+def _held_slice(nf: int):
+    """Index map of a [held, F, D] matrix's block for grid step (t, f):
+    past the last group it stays on the block the last live step read."""
     def weight_map(t, f, ex, blk, live):
-        # past the last group: stay on the block the last live step read
         on = (t < live[0]).astype(jnp.int32)
         return ex[t], f * on + (nf - 1) * (1 - on), 0
+    return weight_map
 
+
+def _grouped_pallas(xs, tile_expert, tile_block, n_live, w_gate, w_up,
+                    w_down, tile: int, name: str):
+    m, d = xs.shape
+    fs, nf = _width_slice(w_gate.shape[1])
     rows = pl.BlockSpec((tile, d), lambda t, f, ex, blk, live: (blk[t], 0))
-    weight = pl.BlockSpec((1, fs, d), weight_map)
+    weight = pl.BlockSpec((1, fs, d), _held_slice(nf))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(m // tile, nf),
@@ -172,22 +199,231 @@ def _grouped_pallas(xs, tile_expert, tile_block, n_live, w_gate, w_up,
         )(tile_expert, tile_block, n_live[None], xs, w_gate, w_up, w_down)
 
 
+# ---------------------------------------------------------------------------
+# backward kernels
+# ---------------------------------------------------------------------------
+
+
+def _dx_kernel(expert_ref, block_ref, live_ref, x_ref, dy_ref, rw_ref,
+               wg_ref, wu_ref, wd_ref, dx_ref, drw_ref, dg_ref, du_ref,
+               hs_ref, dx_acc, drw_acc):
+    """One row tile against one slice of its expert's width: gate and up
+    made again, then the slice's part of dX and of the rows' weight
+    gradient, and its [tile, slice] factors of the three dW."""
+    t, f = pl.program_id(0), pl.program_id(1)
+
+    @pl.when(t < live_ref[0])
+    def _body():
+        @pl.when(f == 0)
+        def _init():
+            dx_acc[...] = jnp.zeros_like(dx_acc)
+            drw_acc[...] = jnp.zeros_like(drw_acc)
+
+        x, dy = x_ref[...], dy_ref[...]
+        f32 = jnp.float32
+        gate = jax.lax.dot_general(x, wg_ref[0], _NT,
+                                   preferred_element_type=f32)
+        up = jax.lax.dot_general(x, wu_ref[0], _NT,
+                                 preferred_element_type=f32)
+        sig = jax.nn.sigmoid(gate)
+        silu = gate * sig
+        hidden = silu * up                                   # [tile, slice]
+        # d hidden of the unweighted row; the row's weight scales it
+        dh = jax.lax.dot_general(dy, wd_ref[0], _NT,
+                                 preferred_element_type=f32)
+        drw_acc[...] += jnp.sum(hidden * dh, axis=1, keepdims=True)
+        rw = rw_ref[...][:, :1]                              # [tile, 1]
+        dh = dh * rw
+        d_up = (dh * silu).astype(x.dtype)
+        d_gate = (dh * up * sig * (1.0 + gate * (1.0 - sig))).astype(x.dtype)
+        dg_ref[...] = d_gate
+        du_ref[...] = d_up
+        hs_ref[...] = (hidden * rw).astype(x.dtype)
+        dx_acc[...] += (
+            jax.lax.dot_general(d_gate, wg_ref[0], _NN,
+                                preferred_element_type=f32)
+            + jax.lax.dot_general(d_up, wu_ref[0], _NN,
+                                  preferred_element_type=f32))
+
+        @pl.when(f == pl.num_programs(1) - 1)
+        def _store():
+            dx_ref[...] = dx_acc[...].astype(dx_ref.dtype)
+            drw_ref[...] = drw_acc[...]
+
+
+def _dw_kernel(expert_ref, block_ref, live_ref, x_ref, dy_ref, dg_ref,
+               du_ref, hs_ref, dwg_ref, dwu_ref, dwd_ref):
+    """One slice of the width against one row tile: the tile's rows summed
+    into its expert's three gradients, which stay in VMEM while the walk
+    is inside the group."""
+    t = pl.program_id(1)
+
+    @pl.when(t < live_ref[0])
+    def _body():
+        first = (t == 0) | (expert_ref[jnp.maximum(t - 1, 0)]
+                            != expert_ref[t])
+
+        @pl.when(first)
+        def _init():
+            dwg_ref[...] = jnp.zeros_like(dwg_ref)
+            dwu_ref[...] = jnp.zeros_like(dwu_ref)
+            dwd_ref[...] = jnp.zeros_like(dwd_ref)
+
+        f32 = jnp.float32
+        x, dy = x_ref[...], dy_ref[...]
+        dwg_ref[0] += jax.lax.dot_general(dg_ref[...], x, _TN,
+                                          preferred_element_type=f32)
+        dwu_ref[0] += jax.lax.dot_general(du_ref[...], x, _TN,
+                                          preferred_element_type=f32)
+        dwd_ref[0] += jax.lax.dot_general(hs_ref[...], dy, _TN,
+                                          preferred_element_type=f32)
+
+
+def _grouped_backward(xs, dys, row_w, tile_expert, tile_block, n_live,
+                      w_gate, w_up, w_down, tile: int):
+    """xs, dys [M, D]: each row's input and the gradient of its token's
+    result (unweighted); row_w [M] f32: the row's routing weight, 0 on a
+    padding row -> (dxs [M, D], d row_w [M] f32, dW_gate, dW_up, dW_down
+    [held, F, D] f32; garbage where no tile is live: rows past the last
+    group, experts without a token)."""
+    m, d = xs.shape
+    held, width, _ = w_gate.shape
+    fs, nf = _width_slice(width)
+    lanes = jnp.broadcast_to(row_w[:, None], (m, 128))
+    scalars = (tile_expert, tile_block, n_live[None])
+    params = pltpu.CompilerParams(
+        dimension_semantics=("arbitrary", "arbitrary"),
+        vmem_limit_bytes=VMEM_LIMIT)
+
+    held_slice = _held_slice(nf)
+    rows = pl.BlockSpec((tile, d), lambda t, f, ex, blk, live: (blk[t], 0))
+    row_lanes = pl.BlockSpec((tile, 128),
+                             lambda t, f, ex, blk, live: (blk[t], 0))
+    factor = pl.BlockSpec(
+        (tile, fs),
+        lambda t, f, ex, blk, live: (blk[t], held_slice(t, f, ex, blk,
+                                                        live)[1]))
+    weight = pl.BlockSpec((1, fs, d), held_slice)
+    factor_shape = jax.ShapeDtypeStruct((m, width), xs.dtype)
+    with jax.named_scope(EXPERTS_GROUPED_DX):
+        dxs, drw, d_gate, d_up, hidden = pl.pallas_call(
+            _dx_kernel, name=EXPERTS_GROUPED_DX,
+            out_shape=(jax.ShapeDtypeStruct((m, d), xs.dtype),
+                       jax.ShapeDtypeStruct((m, 128), jnp.float32),
+                       factor_shape, factor_shape, factor_shape),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(m // tile, nf),
+                in_specs=[rows, rows, row_lanes, weight, weight, weight],
+                out_specs=(rows, row_lanes, factor, factor, factor),
+                scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32),
+                                pltpu.VMEM((tile, 128), jnp.float32)]),
+            compiler_params=params, interpret=backend.interpret(),
+        )(*scalars, xs, dys, lanes, w_gate, w_up, w_down)
+
+    # the width outermost, so that a group's tiles follow one another and
+    # its expert's block of the result is written once
+    rows2 = pl.BlockSpec((tile, d), lambda f, t, ex, blk, live: (blk[t], 0))
+    factor2 = pl.BlockSpec((tile, fs),
+                           lambda f, t, ex, blk, live: (blk[t], f))
+    weight2 = pl.BlockSpec((1, fs, d),
+                           lambda f, t, ex, blk, live: (ex[t], f, 0))
+    grad = jax.ShapeDtypeStruct((held, width, d), jnp.float32)
+    with jax.named_scope(EXPERTS_GROUPED_DW):
+        dwg, dwu, dwd = pl.pallas_call(
+            _dw_kernel, name=EXPERTS_GROUPED_DW,
+            out_shape=(grad, grad, grad),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=3, grid=(nf, m // tile),
+                in_specs=[rows2, rows2, factor2, factor2, factor2],
+                out_specs=(weight2, weight2, weight2)),
+            compiler_params=params, interpret=backend.interpret(),
+        )(*scalars, xs, dys, d_gate, d_up, hidden)
+    return dxs, drw[:, 0], dwg, dwu, dwd
+
+
+# ---------------------------------------------------------------------------
+# the op
+# ---------------------------------------------------------------------------
+
 def row_tile(n_pairs: int) -> int:
     """Rows a tile: 128 where a chunk of a prompt is routed, the 16 of a
     packed sublane tile for a decode step's few pairs."""
     return 128 if n_pairs >= 1024 else 16
 
 
+def _pick_rows(rows, dest):
+    """rows [M, ...] -> [N, k, ...]: each pair's row, zeros where its
+    expert is not held."""
+    picked = rows[jnp.maximum(dest, 0)]
+    return jnp.where((dest >= 0).reshape(dest.shape + (1,) * (rows.ndim - 1)),
+                     picked, 0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _grouped(name: str, tile: int, x, weights, w_gate, w_up, w_down, layout):
+    """The held experts' part on the kernel path; layout = (dest, src,
+    tile_expert, tile_block, n_live, load) of `group_layout`."""
+    dest, src, tile_expert, tile_block, n_live, _ = layout
+    ys = _grouped_pallas(x[src], tile_expert, tile_block, n_live,
+                         w_gate.astype(x.dtype), w_up.astype(x.dtype),
+                         w_down.astype(x.dtype), tile, name)
+    # op for op the forward as it was before it had a backward: the
+    # serving programs lower to what they were
+    picked = ys[jnp.maximum(dest, 0)].astype(jnp.float32)   # [N, k, D]
+    picked = jnp.where((dest >= 0)[..., None], picked, 0.0)
+    return jnp.einsum("nk,nkd->nd", weights.astype(jnp.float32), picked)
+
+
+def _grouped_fwd(name, tile, x, weights, w_gate, w_up, w_down, layout):
+    """The forward where a backward follows (training). The same values
+    as `_grouped`; the rows are picked in their own type and widened
+    inside the sum, which at 16,384 tokens x 6 keeps an [N, k, D] float32
+    array out of HBM (8 ms a step of `kanana-2-30b-a3b.pretrain-8k`:
+    PERF.md, PR 38)."""
+    dest, src, tile_expert, tile_block, n_live, _ = layout
+    ys = _grouped_pallas(x[src], tile_expert, tile_block, n_live,
+                         w_gate.astype(x.dtype), w_up.astype(x.dtype),
+                         w_down.astype(x.dtype), tile, name)
+    out = jnp.einsum("nk,nkd->nd", weights.astype(jnp.float32),
+                     _pick_rows(ys, dest).astype(jnp.float32))
+    return out, (x, weights, w_gate, w_up, w_down, layout)
+
+
+def _grouped_bwd(name, tile, res, dy):
+    x, weights, w_gate, w_up, w_down, layout = res
+    dest, src, tile_expert, tile_block, n_live, load = layout
+    n, k = dest.shape
+    m = src.shape[0]
+    # each row's routing weight, by the row (0 on padding rows)
+    row_w = jnp.zeros((m,), jnp.float32).at[
+        jnp.where(dest >= 0, dest, m).reshape(-1)].set(
+            weights.astype(jnp.float32).reshape(-1), mode="drop")
+    dxs, drw, dwg, dwu, dwd = _grouped_backward(
+        x[src], dy.astype(x.dtype)[src], row_w, tile_expert, tile_block,
+        n_live, w_gate.astype(x.dtype), w_up.astype(x.dtype),
+        w_down.astype(x.dtype), tile)
+    dx = jnp.sum(_pick_rows(dxs, dest).astype(jnp.float32), 1)
+    got = (load > 0)[:, None, None]
+    return (dx.astype(x.dtype), _pick_rows(drw, dest).astype(weights.dtype),
+            jnp.where(got, dwg, 0.0).astype(w_gate.dtype),
+            jnp.where(got, dwu, 0.0).astype(w_up.dtype),
+            jnp.where(got, dwd, 0.0).astype(w_down.dtype), None)
+
+
+_grouped.defvjp(_grouped_fwd, _grouped_bwd)
+
+
 def experts_grouped(x, chosen, weights, w_gate, w_up, w_down, *,
                     held_from: int, impl: str = "auto",
                     name: str = EXPERTS_GROUPED):
-    """The held experts' part of a routed layer's result.
+    """The held experts' part of a routed layer's result; differentiable
+    in x, weights and the three matrices on either path.
 
     x [N, D] normed activations; chosen [N, k] i32: each token's experts
     (ids over the router's whole width); weights [N, k] f32: their
     weights; w_gate, w_up, w_down [held, F, D]: experts `held_from` ..
-    `held_from + held - 1`; name: the kernel's. -> ([N, D] f32, load
-    [held] i32: the pairs each held expert got)."""
+    `held_from + held - 1`; name: the forward kernel's. -> ([N, D] f32,
+    load [held] i32: the pairs each held expert got)."""
     held = w_gate.shape[0]
     if resolve_impl(impl) != "pallas":
         return reference_experts_grouped(
@@ -195,12 +431,6 @@ def experts_grouped(x, chosen, weights, w_gate, w_up, w_down, *,
             held_from), held_pairs(chosen, held_from, held)[1]
     n, k = chosen.shape
     tile = row_tile(n * k)
-    dest, src, tile_expert, tile_block, n_live, load = group_layout(
-        chosen, held_from, held, tile)
-    ys = _grouped_pallas(x[src], tile_expert, tile_block, n_live,
-                         w_gate.astype(x.dtype), w_up.astype(x.dtype),
-                         w_down.astype(x.dtype), tile, name)
-    picked = ys[jnp.maximum(dest, 0)].astype(jnp.float32)   # [N, k, D]
-    picked = jnp.where((dest >= 0)[..., None], picked, 0.0)
-    return jnp.einsum("nk,nkd->nd", weights.astype(jnp.float32),
-                      picked), load
+    layout = group_layout(chosen, held_from, held, tile)
+    return _grouped(name, tile, x, weights, w_gate, w_up, w_down,
+                    layout), layout[-1]

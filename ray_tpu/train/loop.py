@@ -43,6 +43,31 @@ from ray_tpu.util import telemetry as _telemetry
 # host. Tests monkeypatch it to assert the no-per-step-sync property.
 _device_get = jax.device_get
 
+# What every step's metrics hold (`spmd.make_train_step`). Whatever other
+# scalars a trainer's steps carry, `TrainLoop.stats()` totals.
+STEP_BASICS = ("loss", "grad_norm", "step")
+
+
+def step_totals(history: list) -> dict:
+    """What a run's steps carried beside `STEP_BASICS`, by the names the
+    trainer gave: {name: the total over the run's steps, ...,
+    "first_step": {name: its value at the first step}, "last_step": the
+    same at the last}. A count's total is a count; a level (a maximum, a
+    mean) is read at the two steps, or as one total over another. Nothing
+    where the steps carry nothing more."""
+    first = history[0] if history and isinstance(history[0], dict) else {}
+    names = [k for k, v in first.items()
+             if k not in STEP_BASICS and np.ndim(v) == 0]
+    if not names:
+        return {}
+
+    def row(m) -> dict:
+        return {k: np.asarray(m[k]).item() for k in names}
+
+    return {**{k: sum(np.asarray(m[k]).item() for m in history)
+               for k in names},
+            "first_step": row(history[0]), "last_step": row(history[-1])}
+
 
 def make_placer(mesh: Mesh, rules: dict | None = None,
                 stacked: bool = False) -> Callable[[Any], Any]:
@@ -265,6 +290,7 @@ class TrainLoop:
         # it, and the retrace sentinel.
         self.phases = _telemetry.Phases()
         self.last_breakdown: dict = {}
+        self.last_step_totals: dict = {}
         self.flops_per_step = flops_per_step
         self.last_mfu = 0.0
         self.last_goodput = 0.0
@@ -350,6 +376,7 @@ class TrainLoop:
                 ckpt.flush()
         with phase("train/metrics"):
             out = ring.drain()
+        self.last_step_totals = step_totals(out)
         total_s = pc() - t_run
         steps_run = done - int(start_step)
         denom = max(total_s, 1e-12)
@@ -387,9 +414,11 @@ class TrainLoop:
     def stats(self) -> dict:
         """Telemetry-bridge stats dict (util.telemetry republishes these
         as train_* gauges at every /metrics scrape): the last run's
-        step-time breakdown plus MFU/goodput and the fused-dispatch
-        compile-once accounting."""
+        step-time breakdown plus MFU/goodput, the fused-dispatch
+        compile-once accounting, and under their own names the totals of
+        what its steps carried beside the loss (`step_totals`)."""
         return {
+            **self.last_step_totals,
             "dispatch_traces": self.dispatch_traces,
             "retraces_unexpected": self.sentinel.retraces_unexpected,
             "unroll": self.unroll,

@@ -61,27 +61,42 @@ def opt_state_shardings(optimizer: optax.GradientTransformation, params,
         transform_non_params=lambda _: replicated(mesh))
 
 
+def _trainable(tree, frozen: Callable | None):
+    """`tree` (parameters, their gradients or their shardings) as the
+    optimizer sees it: None, a node without leaves, where `frozen(tree)`
+    is true."""
+    if frozen is None:
+        return tree
+    return jax.tree.map(lambda leaf, off: None if off else leaf, tree,
+                        frozen(tree))
+
+
 def create_sharded_state(init_fn: Callable[[jax.Array], Any],
                          param_logical_axes,
                          mesh: Mesh,
                          rng,
                          optimizer: optax.GradientTransformation,
-                         rules: dict | None = None) -> tuple[TrainState, Any]:
+                         rules: dict | None = None,
+                         frozen: Callable | None = None
+                         ) -> tuple[TrainState, Any]:
     """Initialize params + optimizer state directly into their shardings.
 
     Params and optimizer state are materialized *sharded* (jit with
     out_shardings), so a model too big for one device's HBM never exists
-    unsharded anywhere.
+    unsharded anywhere. `frozen(tree)` -> a tree of bools by the leaves'
+    places: leaves that are no part of the optimizer's state
+    (`make_train_step`).
     """
     param_shardings = tree_shardings(mesh, param_logical_axes, rules)
     params = jax.jit(init_fn, out_shardings=param_shardings)(rng)
+    seen = _trainable(params, frozen)
     # The optimizer state is placed as the train step returns it. Left
     # to propagation it comes back replicated (or, on a one-device mesh,
     # uncommitted and off the mesh), and jax keys a trace on each
     # argument's mesh and an executable on its sharding: the step would
     # compile once for the first call and again for every later one.
     opt_state = jax.jit(optimizer.init, out_shardings=opt_state_shardings(
-        optimizer, params, param_shardings, mesh))(params)
+        optimizer, seen, _trainable(param_shardings, frozen), mesh))(seen)
     step = jax.device_put(jnp.zeros((), jnp.int32), replicated(mesh))
     return TrainState(params, opt_state, step), param_shardings
 
@@ -92,7 +107,9 @@ def make_train_step(loss_fn: Callable,
                     donate: bool = True,
                     accum: int = 1,
                     rules: dict | None = None,
-                    jit: bool = True):
+                    jit: bool = True,
+                    aux_update: Callable | None = None,
+                    frozen: Callable | None = None):
     """Build the jitted (state, batch) -> (state, metrics) step.
 
     loss_fn(params, batch) -> scalar loss. The batch is a pytree of global
@@ -110,10 +127,20 @@ def make_train_step(loss_fn: Callable,
     up to summation-order float error (~1e-6 f32); with a padding mask
     the per-microbatch normalization means exact parity only holds when
     mask counts are equal across microbatches.
+
+    With `aux_update`, loss_fn returns (loss, aux) and the step ends with
+    `aux_update(params, aux) -> (params, metrics)` on the optimizer's new
+    parameters: the part of a model that moves by what the step counted
+    and not by a gradient (a router's balancing bias). Its metrics join
+    the step's. `frozen(params)` -> a tree of bools, true at the leaves
+    the optimizer neither sees nor moves nor keeps state for.
     """
     accum = int(accum)
     if accum < 1:
         raise ValueError(f"accum must be >= 1, got {accum}")
+    if aux_update is not None and accum != 1:
+        raise ValueError("aux_update wants accum=1: a step's counts are "
+                         "not accumulated over microbatches")
     micro_spec = logical_to_spec(("batch",), rules, mesh)
 
     def split_micro(batch):
@@ -131,7 +158,8 @@ def make_train_step(loss_fn: Callable,
 
     def value_and_mean_grad(params, batch):
         if accum == 1:
-            return jax.value_and_grad(loss_fn)(params, batch)
+            return jax.value_and_grad(
+                loss_fn, has_aux=aux_update is not None)(params, batch)
 
         def micro_step(carry, mb):
             i, loss_mean, gmean = carry
@@ -158,13 +186,24 @@ def make_train_step(loss_fn: Callable,
 
     def step(state: TrainState, batch):
         loss, grads = value_and_mean_grad(state.params, batch)
+        more = {}
+        if aux_update is not None:
+            loss, aux = loss
         updates, opt_state = optimizer.update(
-            grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
+            _trainable(grads, frozen), state.opt_state,
+            _trainable(state.params, frozen))
+        if frozen is None:
+            params = optax.apply_updates(state.params, updates)
+        else:
+            params = jax.tree.map(
+                lambda p, u: p if u is None else (p + u).astype(p.dtype),
+                state.params, updates, is_leaf=lambda x: x is None)
+        if aux_update is not None:
+            params, more = aux_update(params, aux)
         gnorm = optax.global_norm(grads)
         new_state = TrainState(params, opt_state, state.step + 1)
         return new_state, {"loss": loss, "grad_norm": gnorm,
-                           "step": new_state.step}
+                           "step": new_state.step, **more}
 
     if not jit:
         return step
@@ -257,16 +296,20 @@ def moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None):
 
 def _make_lm_trainer(init_fn, logical_axes, loss_fn, mesh: Mesh, rng,
                      optimizer, rules, accum: int = 1,
-                     init_state: bool = True):
-    """Shared assembly behind make_gpt_trainer / make_moe_trainer."""
+                     init_state: bool = True,
+                     aux_update: Callable | None = None,
+                     frozen: Callable | None = None):
+    """Shared assembly behind make_gpt_trainer / make_moe_trainer /
+    make_latent_moe_trainer."""
     rng = jax.random.key(0) if rng is None else rng
     optimizer = optimizer or default_optimizer()
     state = None
     if init_state:
         state, _ = create_sharded_state(
-            init_fn, logical_axes, mesh, rng, optimizer, rules)
+            init_fn, logical_axes, mesh, rng, optimizer, rules, frozen)
     step_fn = make_train_step(loss_fn, optimizer, mesh, accum=accum,
-                              rules=rules)
+                              rules=rules, aux_update=aux_update,
+                              frozen=frozen)
 
     tok_spec = logical_to_spec(("batch", "length"), rules, mesh)
     tok_sharding = NamedSharding(mesh, tok_spec)
@@ -345,6 +388,49 @@ def make_moe_trainer(cfg, mesh: Mesh, rng=None,
         lambda key: moe.init_params(key, cfg), moe.param_logical_axes(cfg),
         partial(moe_loss_fn, cfg=cfg, mesh=mesh), mesh, rng, optimizer,
         rules, accum=accum, init_state=init_state)
+
+
+def latent_moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
+                       with_counts: bool = False):
+    """Mean negative log-likelihood of pre-shifted inputs/targets [B, T]
+    under `models.latent_sparse_moe` (layers without an indexer), over
+    the rows of the vocabulary held, untied head, through
+    `fused_softmax_xent`; no auxiliary loss. With `with_counts`, (loss, the
+    forward's expert counts)."""
+    from ray_tpu.models import latent_sparse_moe as lsm
+    from ray_tpu.ops.fused_xent import fused_softmax_xent
+
+    x, counts = lsm.forward_features(params, batch["inputs"], cfg, mesh)
+    nll = fused_softmax_xent(
+        x, params["head"].astype(cfg.activation_dtype()), batch["targets"],
+        mesh=mesh)
+    mask = batch.get("mask")
+    loss = (jnp.mean(nll) if mask is None
+            else jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))
+    return (loss, counts) if with_counts else loss
+
+
+def make_latent_moe_trainer(cfg, mesh: Mesh, rng=None,
+                            optimizer: optax.GradientTransformation | None
+                            = None,
+                            rules: dict | None = None,
+                            init_state: bool = True):
+    """`make_gpt_trainer`'s assembly for `models.latent_sparse_moe`
+    (layers without an indexer: latent attention through the flash
+    kernels, this chip's share of the routed experts with their backward
+    kernels). `router_bias` is no leaf of the optimizer, whose state has
+    nothing for it: the step moves it after the optimizer's update, by
+    the step's own expert counts (`update_router_bias`), and its metrics
+    carry `expert_pairs_here`, `expert_pairs_routed`, `expert_load_max`,
+    `expert_load_mean` and `router_bias_abs_max`."""
+    from ray_tpu.models import latent_sparse_moe as lsm
+
+    return _make_lm_trainer(
+        lambda key: lsm.init_params(key, cfg), lsm.param_logical_axes(cfg),
+        partial(latent_moe_loss_fn, cfg=cfg, mesh=mesh, with_counts=True),
+        mesh, rng, optimizer, rules, init_state=init_state,
+        aux_update=partial(lsm.update_router_bias, cfg=cfg),
+        frozen=lsm.is_router_bias)
 
 
 def train_flops_per_token(cfg, seq_len: int) -> float:

@@ -566,6 +566,35 @@ def test_latent_family_kernels_compile_under_their_names(topo, case):
     assert sorted(kernel_names(text)) == sorted(names)
 
 
+def test_flash_attention_compiles_at_keys_192_values_128(topo):
+    """`kanana-2-30b-a3b.pretrain-8k`'s layer: BH 64, T 8192, keys 192
+    wide and values 128, blocks of 1024 q rows and 2048 kv rows (2048 x
+    2048 asks for more scoped VMEM than a kernel may use: a 192-wide
+    block is stored 256 lanes wide)."""
+    def loss(q, k, v):
+        return jnp.sum(flash_attention(q, k, v, True, 1024, 2048)
+                       .astype(jnp.float32))
+    qk, v = ((2, 8192, 32, 192), BF16), ((2, 8192, 32, 128), BF16)
+    text = compiled_text(topo, jax.grad(loss, argnums=(0, 1, 2)), qk, qk, v)
+    assert sorted(kernel_names(text)) == [
+        "flash_dkv", "flash_dq", "flash_fwd"]
+
+
+def test_experts_grouped_train_kernels_compile_under_their_names(topo):
+    """The same cell's expert layer, forward and backward: 16,384 tokens,
+    6 of 128 experts each, 16 held at 768 x 2048, float32 masters."""
+    def loss(x, w, g, u, d, c):
+        return jnp.sum(grouped_experts.experts_grouped(
+            x, c, w, g, u, d, held_from=0, impl="pallas",
+            name=grouped_experts.EXPERTS_GROUPED_TRAIN)[0])
+    text = compiled_text(
+        topo, jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+        ((16384, 2048), BF16), ((16384, 6), F32),
+        *[((16, 768, 2048), F32)] * 3, ((16384, 6), I32))
+    assert sorted(kernel_names(text)) == [
+        "experts_grouped_dw", "experts_grouped_dx", "experts_grouped_train"]
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     """The decode step and a 512-token prefill chunk of

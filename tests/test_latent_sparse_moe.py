@@ -201,11 +201,14 @@ def test_selection_takes_every_position_while_there_are_no_more(params):
 
 # -- (d) the share test ------------------------------------------------------
 
-def test_the_shares_add_up_to_the_uncut_layer():
-    """Every share's routed part, plus the shared expert counted once, is
-    the uncut reference's whole expert layer: 8 experts in shares of
-    4 + 4 and of 2 + 6 (a ragged cut)."""
-    uncut = {**TINY, "n_routed_experts": 8, "experts_held_from": 0}
+@pytest.mark.parametrize("n_shared", [1, 2])
+def test_the_shares_add_up_to_the_uncut_layer(n_shared):
+    """Every share's routed part, plus the shared experts counted once
+    (two of them are one gated MLP of twice the width), is the uncut
+    reference's whole expert layer: 8 experts in shares of 4 + 4 and of
+    2 + 6 (a ragged cut)."""
+    uncut = {**TINY, "n_routed_experts": 8, "experts_held_from": 0,
+             "n_shared_experts": n_shared}
     lp = ref.init_params(jax.random.key(7), uncut)["layers"][1]
     h2 = jax.random.normal(jax.random.key(8), (48, TINY["hidden_size"]))
     want = ref.feed_forward(h2, lp, uncut)
@@ -215,7 +218,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
             share = {**lp, **{n: lp[n][lo:hi]
                               for n in ("we_gate", "we_up", "we_down")}}
             cfg = config("pallas", n_routed_experts=hi - lo,
-                         experts_held_from=lo)
+                         experts_held_from=lo, n_shared_experts=n_shared)
+            assert share["ws_gate"].shape[1] == 32 * n_shared
             routed, shared, counts = lsm.expert_layer(h2, share, cfg)
             np.testing.assert_allclose(shared, ref.shared_part(h2, lp),
                                        rtol=0, atol=TOL)
