@@ -636,12 +636,15 @@ def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
     the pool never leaves the buffer it was donated in: layer `li`'s K/V
     rows are written into the stacked arrays at ``(li, widx)`` first
     (`_scatter_kv`), then ``attend(q, cache, li)`` attends over layer
-    `li` of the written pool. (Scanning over the pool instead makes XLA
+    `li` of the written pool: the three paged kernels (decode, verify,
+    the prefill chunk) take the stacked pool and the layer's number and
+    fetch the live pages themselves, so no step slices or copies a
+    layer. (Scanning over the pool instead makes XLA
     slice every layer out, stack the written layers into a new buffer
     and copy that onto the donated one: three passes over K and over V a
     step.)
 
-    A head size under 128 is the exception, told by the decode kernel's
+    A head size under 128 is the exception, told by the paged kernel's
     own plan (`reads_pool_where_it_lies`): XLA stores such a pool in a
     layout of its own, and rows can only be written into, and pages read
     from, a lay-out of it. That lay-out has to be one layer's, so there
@@ -701,7 +704,9 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
     `ops.decode_attention.paged_prefill_attention`
     (`cfg.prefill_attn_impl`): the "jax" path is the dense gather+einsum
     this function used to inline, bit-identical; "pallas" (or "auto" on
-    TPU) runs the fused kernel whose chunk scores never round-trip HBM.
+    TPU) runs the fused kernel (`paged_mq`), whose chunk scores never
+    round-trip HBM and which reads the sequence's live pages of the
+    layer out of the carried pool where it lies.
     An int8 pool (`cfg.kv_dtype="int8"`) quantizes K/V inside the
     scatter and the attention op dequantizes blockwise inside."""
     check_quant_cfg(cfg)

@@ -15,7 +15,7 @@ pass.
 pool ``[n_blocks, block_size, H, D]`` and each sequence names its blocks
 through an int32 block table ``[B, max_blocks]`` (logical block j of
 sequence b is physical block ``tables[b, j]``). The kernel
-(`paged_decode`) runs ``grid = (B,)``: a program is one stream, and its
+(`paged_decode`) runs ``grid = (B, 1)``: a program is one stream, and its
 loop's trip count is the stream's own, ``pos[b] // block_size + 1`` live
 pages walked in turns of several pages. The pools stay in HBM in the
 layout the model writes, and where it keeps them: the model's whole
@@ -30,7 +30,7 @@ every head of its pages at once with two plain matmuls over the page as
 stored and a mask that keeps each head its own rows; running (m, l, acc)
 softmax statistics live in VMEM scratch and the output is written once.
 `_decode_plan` chooses pages a turn and the scoped VMEM from the shapes,
-the pool's dtype and the chip's VMEM; `_paged_decode_kernel` says what a
+the pool's dtype and the chip's VMEM; `_paged_kernel` says what a
 dead page costs (nothing is fetched for it; the V rows it leaves in a
 buffer are zeroed). Two things follow from decode:
 
@@ -42,12 +42,17 @@ buffer are zeroed). Two things follow from decode:
   zeros (how the engine marks a slot that is not decoding) is one page
   and one turn, whatever the table's width.
 
-Verify and the fused prefill (`paged_mq`) keep the older structure:
-grid (B*H, max_blocks) over a head-major copy of the pool, one
-``(block_size, D)`` tile a step through BlockSpec index maps that
-dereference the table, blocks past the last query's horizon predicated
-away with ``pl.when``. Given the stacked cache and a layer they slice
-that layer out inside the copy they make anyway.
+**Verify and the fused prefill** (`paged_mq`) are the same kernel body
+with W query rows a head where decode has one: the queries go in as the
+model made them, ``[W * H, D]`` (row ``w * H + h``), a row of scores
+keeps its own head's columns at or before its own position (the
+staircase ``col <= pos + w``), and the trip count is the last query's,
+``(pos + W - 1) // block_size + 1`` pages. A verify step is ``grid =
+(B, 1)`` with W = ``spec_k + 1``; a prefill chunk is one stream with W
+the chunk's bucket, in programs of `_PROGRAM_ROWS` score rows along the
+grid's second axis where one does not hold them (64 queries at 16
+heads). Neither copies, slices or transposes a layer: the same DMAs
+from the same stacked pool.
 
 The JAX fallback gathers ``pool[tables]`` and attends with
 `reference_decode_attention`, the same masking and f32 accumulation.
@@ -64,10 +69,10 @@ full-precision pool, bit-for-bit the pre-quantization math.
 **Fused paged prefill** (`paged_prefill_attention`): chunked-prefill
 attention for one sequence over the same paged pool — the dense-math
 JAX path is exactly the gather+einsum that used to live inline in
-`models.gpt.prefill_paged`, and the Pallas path reuses the multi-query
-verify kernel (the prefill staircase ``col <= start + row`` IS the
+`models.gpt.prefill_paged`, and the Pallas path is the multi-query
+kernel above (the prefill staircase ``col <= start + row`` IS the
 verify mask with ``pos = start``), so the [C, S] score matrix stays in
-VMEM instead of round-tripping through HBM.
+VMEM, a turn of pages at a time, instead of round-tripping through HBM.
 """
 
 from __future__ import annotations
@@ -81,23 +86,16 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from ray_tpu.ops import backend
-from ray_tpu.ops.flash_attention import _head_pad_target, _pad_heads
 
 NEG_INF = -1e30
 
 # Kernel names in the compiled program and the profiler's trace
 # (`%paged_decode.N = ... custom-call`); PERF.md, section 3, lists them.
 # Each call sits in a `named_scope` of its own name: see flash_attention.py.
-# Verify and the fused prefill share `paged_mq`.
+# One kernel body (`_paged_kernel`) under two names: the decode step's one
+# query a head is `paged_decode`; verify and the fused prefill share
+# `paged_mq`.
 PAGED_DECODE, PAGED_MQ = "paged_decode", "paged_mq"
-
-
-def _auto_impl(op: str, has_plan: bool, why: str) -> str:
-    """Resolve ``impl="auto"``: the kernel on a TPU backend, the
-    pure-JAX path elsewhere. A TPU shape with no plan is recorded."""
-    if not has_plan:
-        backend.note_fallback(op, why)
-    return "pallas" if backend.on_tpu() and has_plan else "jax"
 
 
 # ---------------------------------------------------------------------------
@@ -166,38 +164,31 @@ def reference_paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     return reference_decode_attention(q, k_seq, v_seq, pos)
 
 
-def _scale_spec(n_heads: int, bs: int):
-    """BlockSpec of an int8 pool's scales, head-major [n_blocks, H, bs]:
-    every head's row of one physical block, fetched through the same
-    table dereference as the payload. Mosaic wants a block's last two
-    dims (8, 128)-divisible or equal to the array's, which (H, bs) is
-    and a single head's (1, bs) row is not."""
-    return pl.BlockSpec((1, n_heads, bs),
-                        lambda i, j, tbl, ps: (tbl[i // n_heads, j], 0, 0))
-
-
-def _scale_row(scale_ref, head):
-    """This head's [1, bs] scale row of a `_scale_spec` block. A row,
-    not a column: a per-position K scale multiplies the score columns
-    (``q . (k_j * s_j) == (q . k_j) * s_j``) and a V scale the
-    probabilities (``p @ (v * s) == (p * s) @ v``), so dequantization
-    needs no lane-to-sublane relayout and no [bs, D] multiply."""
-    return scale_ref[0, pl.ds(head, 1), :].astype(jnp.float32)
-
-
 # ---------------------------------------------------------------------------
-# paged decode: a program a stream over its own pages
+# the paged kernel: a program a stream (and a tile of its query rows) over
+# its own pages
 # ---------------------------------------------------------------------------
 
 _TURN_TOKENS = 128              # cached positions a turn, where they fit
+_PROGRAM_ROWS = 1024            # score rows (queries x heads) a program
+_FAR = 1 << 30                  # a position no query ever reaches
 
 
 def _up(n: int, to: int) -> int:
     return -(-n // to) * to
 
 
+def _query_tile(w: int, h: int) -> int:
+    """Queries a head that one program of the kernel scores: all `w` of a
+    stream where their `w * h` score rows are `_PROGRAM_ROWS` or fewer
+    (the decode step's one, a verify step's few, a 64-token chunk at 16
+    heads), else as many as fill `_PROGRAM_ROWS`, the rest in further
+    programs of the grid's second axis."""
+    return min(w, max(1, _PROGRAM_ROWS // h))
+
+
 class _DecodePlan(NamedTuple):
-    """What `paged_decode` runs at (`_decode_plan` chooses it). `pack`:
+    """What the paged kernel runs at (`_decode_plan` chooses it). `pack`:
     heads side by side in a row of lanes, 1 where the pool goes to the
     kernel as the model stores it. `pages`: pages a turn of a stream's
     loop. `vmem_limit`: what the working set asks of
@@ -208,10 +199,11 @@ class _DecodePlan(NamedTuple):
     vmem_limit: int | None
 
 
-def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
+def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool, w: int,
                  vmem: int | None = None) -> _DecodePlan | None:
-    """The plan for pools `[n_blocks, bs, h, d]` of `dtype`, from the
-    shapes, the element size and the chip's VMEM alone.
+    """The plan for pools `[n_blocks, bs, h, d]` of `dtype` and `w`
+    queries a head a program (1: the decode step), from the shapes, the
+    element size and the chip's VMEM alone.
 
     A page reaches VMEM by one DMA, and Mosaic moves by DMA only slices
     whose lanes fill whole 128-lane tiles. A head size that is a multiple
@@ -220,7 +212,8 @@ def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
     lays `pack = 128 // d` heads side by side (`[n_blocks, bs * h / pack,
     128]`, one copy of the layer read). Pages a turn: `_TURN_TOKENS`
     positions, halved until two turns of K and V, what the body makes of
-    one and its score tiles fit the default scope; one page that does not fit asks for what it needs,
+    one, its `w * h` rows of score tiles and of running state fit the
+    default scope; one page that does not fit asks for what it needs,
     up to half the VMEM. None where no row of lanes can be made (`d`
     neither a multiple nor a divisor of 128, heads that do not fill
     rows or sublanes) or an int8 pool's scale rows are not whole lane
@@ -237,15 +230,26 @@ def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
         page = rows * 128 * item
     if not ok or (quantized and rows % 128):
         return None
+    qrows, lanes = _up(w * h, 8), max(d, 128)
+    # q and the output as they come and go (two buffers each, in the
+    # activations' dtype: the pool's, or bf16 over an int8 pool), q as it
+    # is scaled, acc, m and l
+    state = qrows * (lanes * (4 * max(item, 2) + 4 + 4) + 128 * 4 * 2)
 
     def working_set(pages):
         cols = pages * rows
         bufs = 2 * 2 * pages * page                 # K and V, two turns
         if quantized:
             bufs += 2 * 2 * 8 * cols * 4            # their scale rows
-        operands = 2 * cols * max(d, 128) * 4       # K, V as the MXU gets them
-        scores = 4 * _up(h, 8) * cols * 4           # the mask, s, p, a spare
-        return bufs + operands + scores
+        operands = 2 * cols * lanes * 4             # K, V as the MXU gets them
+        # the mask, s and p: the compiler keeps two and a half of them
+        # alive (AOT for a v5e, 1024 rows: 5.4 / 7.3 / 13.6 / 22.3 MB of
+        # scoped VMEM used at 256 / 512 / 1024 / 2048 columns; this
+        # estimate 6.0 / 9.0 / 15.0 / 27.0)
+        scores = 5 * qrows * cols * 2
+        if quantized and pack > 1:      # the scales' rows tiled over the
+            scores += qrows * cols * 4  # queries' (pack 1: a broadcast)
+        return bufs + operands + scores + state
 
     pages = max(1, _TURN_TOKENS // bs)
     while pages > 1 and working_set(pages) > backend.SCOPED_VMEM_DEFAULT:
@@ -261,46 +265,56 @@ def _decode_plan(bs: int, h: int, d: int, dtype, quantized: bool,
 
 def reads_pool_where_it_lies(bs: int, h: int, d: int, dtype,
                              quantized: bool) -> bool:
-    """Whether `paged_decode` takes pools `[L, n_blocks, bs, h, d]` of
+    """Whether the paged kernel takes pools `[L, n_blocks, bs, h, d]` of
     `dtype` as the model stores them (`_decode_plan`'s `pack` 1: a head
     fills its 128 lanes), so that a layer loop can keep the stacked pool
     in one buffer and hand it over whole. At a smaller head size XLA
     stores the pool in a layout of its own and every reader and writer
     of rows works on a lay-out of it: a layer loop should then take one
-    layer out at a time, or that lay-out is the whole pool's."""
-    plan = _decode_plan(bs, h, d, dtype, quantized)
+    layer out at a time, or that lay-out is the whole pool's. (`pack`
+    follows the head size alone, whatever the queries a program.)"""
+    plan = _decode_plan(bs, h, d, dtype, quantized, 1)
     return plan is None or plan.pack == 1
 
 
-def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
-                         *rest, sm_scale: float, pack: int, pages: int,
-                         block_size: int, quantized: bool):
-    """One stream: its live pages in turns of `pages`, online softmax
-    over a turn's every head at once. The pools are stacked, `[L,
-    n_blocks, ...]`, and every page's DMA reads layer `layer_ref[0]` of
-    them: the layer is one more number in the copy's index, never a
-    slice of the pool.
+def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
+                  sm_scale: float, pack: int, pages: int, block_size: int,
+                  heads: int, queries: int, quantized: bool):
+    """One stream's queries, `wt` of them a head a program (all `queries`
+    of them in one where the grid's second axis is 1): the stream's live
+    pages in turns of `pages`, online softmax over a turn's every head
+    at once. The decode step is `queries` 1; a verify step and a prefill
+    chunk (`paged_mq`) are the same body with more rows. The pools are
+    stacked, `[L, n_blocks, ...]`, and every page's DMA reads layer
+    `layer_ref[0]` of them: the layer is one more number in the copy's
+    index, never a slice of the pool.
 
     A turn's K is `[cols, lanes]`: row `c` holds position `c // hr` of
     the turn and the `pack` heads from `c % hr * pack` on, `hr = H /
     pack` rows a position (with `pack` 1, a page as the model wrote it:
-    `[bs, H, D]` is `[bs * H, D]`). `q [H, lanes] . K^T` scores every
-    head against every row, `[H, cols]`; a row of scores keeps the
-    columns that hold its own head and are at or before `pos` (`ahead`),
-    the rest are masked like a dead position, and `p . V` then sums a
-    head's own rows only. The MXU does `hr` times the useful
+    `[bs, H, D]` is `[bs * H, D]`). The queries are `[wt * H, lanes]`,
+    as the model made them: row `r` is query `r // H` of head `r % H`,
+    at position `pos + r // H`. `q . K^T` scores every row against every
+    column, `[wt * H, cols]`; a row of scores keeps the columns that
+    hold its own head and are at or before its own position (`ahead`:
+    the staircase `col <= pos + row` of a chunk, one step of it a
+    query), the rest are masked like a dead position, and `p . V` then
+    sums a head's own rows only. The MXU does `hr` times the useful
     multiplications on 128 x 128 tiles it would otherwise leave idle; no
     head is ever moved out of the layout it was stored in.
 
     Dead pages: a turn issues and awaits DMAs for its live pages only
-    (page `j` is live while `j <= pos // bs`; an idle row, `pos` 0, is
-    one page, one turn), so no table entry past the length is read and
-    no byte of a dead page is moved. The last turn's dead pages are
-    whatever the buffer held: their scores are masked by `ahead`, and
-    their V rows (and V scales) are zeroed before `p . V`, because zero
-    times a NaN is a NaN. What is read beyond the live positions is the
-    rest of each stream's last live page: `bs - 1 - pos % bs` positions,
-    under one page of K and one of V a stream a layer."""
+    (page `j` is live while `j <= last // bs`, `last` the position of
+    the program's last query; an idle row, `pos` 0, is one page, one
+    turn), so no table entry past the length is read and no byte of a
+    dead page is moved. The last turn's dead pages are whatever the
+    buffer held: their scores are masked by `ahead`, and their V rows
+    (and V scales) are zeroed before `p . V`, because zero times a NaN
+    is a NaN. What is read beyond the live positions is the rest of each
+    stream's last live page: `bs - 1 - last % bs` positions, under one
+    page of K and one of V a stream a layer. A query row past `queries`
+    (the last program's padding) scores what the real ones fetched and
+    is cut away by the wrapper."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
          m_scr, l_scr, acc_scr) = rest
@@ -309,13 +323,17 @@ def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
     b = pl.program_id(0)
     bs, mb = block_size, tbl_ref.shape[1]
     layer = layer_ref[0]
-    _, h, lanes = q_ref.shape
+    _, qrows, lanes = q_ref.shape
+    h = heads
+    wt = qrows // h                     # queries a head here
     hr = h // pack                      # rows of lanes a cached position
     rows = bs * hr                      # score columns a page
     cols = pages * rows
     size = kbuf.shape[1] // pages       # a page along the buffer's rows
-    pos = pos_ref[b]
-    n_pages = jnp.minimum(pos // bs + 1, mb)
+    t = pl.program_id(1)
+    first = pos_ref[b] + t * wt         # this program's first query's position
+    last = pos_ref[b] + jnp.minimum((t + 1) * wt, queries) - 1  # last real
+    n_pages = jnp.minimum(last // bs + 1, mb)
     n_turns = (n_pages + pages - 1) // pages
 
     def copies(blk, slot, i):
@@ -356,12 +374,16 @@ def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    q = q_ref[0].astype(jnp.float32) * sm_scale             # [H, lanes]
-    row = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 0)
-    col = jax.lax.broadcasted_iota(jnp.int32, (h, cols), 1)
-    # a column's position within the turn where it holds the row's head,
-    # past every position where it does not
-    ahead = jnp.where(col % hr == row // pack, col // hr, mb * bs)
+    q = q_ref[0].astype(jnp.float32) * sm_scale             # [wt * H, lanes]
+    row = jax.lax.broadcasted_iota(jnp.int32, (qrows, cols), 0)
+    col = jax.lax.broadcasted_iota(jnp.int32, (qrows, cols), 1)
+    head, query = row % h, row // h
+    # how far a column's position within the turn lies ahead of the row's
+    # query where it holds the row's head, past every query where not. A
+    # query past the table's reach (a draft step near the longest length)
+    # sees what the table's last position sees: no page holds more
+    query = jnp.minimum(query, mb * bs - 1 - first)
+    ahead = jnp.where(col % hr == head // pack, col // hr - query, _FAR)
     issue(0, 0)
 
     def step(c, _):
@@ -378,10 +400,10 @@ def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
             v = v.astype(jnp.float32)
         s = jax.lax.dot_general(
             q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [H, cols]
-        if quantized:   # row h takes the scales of head h: h % pack here
-            s = s * jnp.tile(ksbuf[slot], (hr, 1))
-        s = jnp.where(ahead <= pos - c * pages * bs, s, NEG_INF)
+            preferred_element_type=jnp.float32)             # [wt * H, cols]
+        if quantized:   # row r takes the scales of head r % H: r % pack here
+            s = s * jnp.tile(ksbuf[slot], (qrows // pack, 1))
+        s = jnp.where(ahead <= first - c * pages * bs, s, NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -390,35 +412,40 @@ def _paged_decode_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm,
                                                      keepdims=True)
         m_scr[:, :1] = m_new
         if quantized:
-            p = p * jnp.tile(vsbuf[slot], (hr, 1))
+            p = p * jnp.tile(vsbuf[slot], (qrows // pack, 1))
         acc_scr[...] = acc_scr[...] * corr + jax.lax.dot_general(
             p.astype(v.dtype), v.reshape(cols, lanes),
             dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)             # [H, lanes]
+            preferred_element_type=jnp.float32)             # [wt * H, lanes]
         return _
 
     jax.lax.fori_loop(0, n_turns, step, 0)
     o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
 
 
-def _paged_decode(q, k_pool, v_pool, tables, pos, layer, *,
-                  plan: _DecodePlan, block_size: int, sm_scale: float,
-                  interpret: bool, ks=None, vs=None):
-    """q [B, H, lanes]; k_pool, v_pool in HBM, stacked: `[L, n_blocks,
-    bs, H, D]` as stored (`plan.pack` 1) or `[1, n_blocks, bs * H / pack,
+def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
+                plan: _DecodePlan, block_size: int, heads: int, queries: int,
+                sm_scale: float, interpret: bool, ks=None, vs=None):
+    """q [B, tiles * wt * H, lanes], a stream's queries as the model
+    made them (`_paged_kernel` has the order; `queries` of the `tiles *
+    wt` are real); k_pool, v_pool in HBM, stacked: `[L, n_blocks, bs, H,
+    D]` as stored (`plan.pack` 1) or `[1, n_blocks, bs * H / pack,
     128]`; tables [B, max_blocks], pos [B] and layer [1] i32,
-    scalar-prefetched -> [B, H, lanes]. `grid = (B,)`: a program is a
-    stream, its loop's trip count the stream's own live pages, each page
-    one DMA named by the layer and the table, a turn's pages landing
-    while the turn before is computed. ``ks``/``vs`` `[n_blocks, pack,
-    bs * H / pack]` f32, one layer's, mark int8 pools: row `j` holds the
-    scales of heads `j, j + pack, ..` in the order of a page's rows,
-    fetched page by page beside the payload and applied to the scores
-    and the probabilities (`_scale_row` says why there)."""
-    b, h, lanes = q.shape
+    scalar-prefetched -> q's shape. `grid = (B, tiles)`: a program is a
+    stream (and `wt` of its queries a head), its loop's trip count its
+    own live pages, each page one DMA named by the layer and the table,
+    a turn's pages landing while the turn before is computed.
+    ``ks``/``vs`` `[n_blocks, pack, bs * H / pack]` f32, one layer's,
+    mark int8 pools: row `j` holds the scales of heads `j, j + pack, ..`
+    in the order of a page's rows, fetched page by page beside the
+    payload and applied, a row of them to the score columns and to the
+    probabilities (``q . (k_j * s_j) == (q . k_j) * s_j``, ``p @ (v * s)
+    == (p * s) @ v``: no `[cols, D]` multiply and no relayout)."""
+    b, total, lanes = q.shape
+    qrows = _query_tile(queries, heads) * heads
     quantized = ks is not None
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    row = pl.BlockSpec((1, h, lanes), lambda i, tbl, ps, ly: (i, 0, 0))
+    row = pl.BlockSpec((1, qrows, lanes), lambda i, t, tbl, ps, ly: (i, t, 0))
     operands = [tables, pos, layer, q, k_pool, v_pool]
     turn = (2, plan.pages * k_pool.shape[2]) + k_pool.shape[3:]
     scratch = [pltpu.VMEM(turn, k_pool.dtype)] * 2
@@ -427,23 +454,25 @@ def _paged_decode(q, k_pool, v_pool, tables, pos, layer, *,
         scratch += [pltpu.VMEM((2, plan.pack, plan.pages * ks.shape[2]),
                                jnp.float32)] * 2
     scratch += [pltpu.SemaphoreType.DMA((2,)),
-                pltpu.VMEM((h, 128), jnp.float32),    # m (column 0 used)
-                pltpu.VMEM((h, 128), jnp.float32),    # l
-                pltpu.VMEM((h, lanes), jnp.float32)]  # acc
+                pltpu.VMEM((qrows, 128), jnp.float32),    # m (column 0 used)
+                pltpu.VMEM((qrows, 128), jnp.float32),    # l
+                pltpu.VMEM((qrows, lanes), jnp.float32)]  # acc
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(b,),
+        num_scalar_prefetch=3, grid=(b, total // qrows),
         in_specs=[row] + [hbm] * (len(operands) - 4),
         out_specs=row, scratch_shapes=scratch)
-    with jax.named_scope(PAGED_DECODE):
+    with jax.named_scope(name):
         return pl.pallas_call(
-            functools.partial(_paged_decode_kernel, sm_scale=sm_scale,
+            functools.partial(_paged_kernel, sm_scale=sm_scale,
                               pack=plan.pack, pages=plan.pages,
-                              block_size=block_size, quantized=quantized),
-            name=PAGED_DECODE,
-            out_shape=jax.ShapeDtypeStruct((b, h, lanes), q.dtype),
+                              block_size=block_size, heads=heads,
+                              queries=queries, quantized=quantized),
+            name=name,
+            out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             grid_spec=grid_spec,
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel",),   # streams share nothing
+                # streams, and a stream's tiles of queries, share nothing
+                dimension_semantics=("parallel", "parallel"),
                 vmem_limit_bytes=plan.vmem_limit),
             interpret=interpret,
         )(*operands)
@@ -476,123 +505,6 @@ def reference_paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
     out = jnp.einsum("bhws,bshd->bwhd", p, v_seq.astype(jnp.float32),
                      preferred_element_type=jnp.float32)
     return out.astype(q.dtype)
-
-
-def _paged_mq_kernel(tbl_ref, pos_ref, q_ref, k_ref, v_ref, *rest,
-                     sm_scale: float, block_size: int, n_heads: int,
-                     w_real: int, quantized: bool):
-    """W query rows of one (b, h) against one `(block_size, D)` tile of
-    that head a grid step: stock online softmax with per-row statistics,
-    the staircase mask ``col <= pos + row``, and blocks past the LAST
-    query row's horizon (``pos + w_real - 1``) skipped at run time."""
-    if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    ji = pl.program_id(1)
-
-    @pl.when(ji == 0)
-    def _init():
-        m_scr[:] = jnp.full_like(m_scr, NEG_INF)
-        l_scr[:] = jnp.zeros_like(l_scr)
-        acc_scr[:] = jnp.zeros_like(acc_scr)
-
-    pos = pos_ref[pl.program_id(0) // n_heads]
-    head = pl.program_id(0) % n_heads
-    k_start = ji * block_size
-
-    @pl.when(k_start <= pos + w_real - 1)
-    def _body():
-        q = q_ref[0].astype(jnp.float32)            # [Wp, D]
-        k = k_ref[0, 0].astype(jnp.float32)         # [bs, D]
-        s = jax.lax.dot_general(
-            q * sm_scale, k,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [Wp, bs]
-        if quantized:
-            s = s * _scale_row(ks_ref, head)
-        col = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        # Padded q rows (>= w_real) reuse the last real row's mask so
-        # they keep >= 1 live column (l stays nonzero); their output is
-        # sliced away by the wrapper.
-        row = jnp.minimum(
-            jax.lax.broadcasted_iota(jnp.int32, s.shape, 0), w_real - 1)
-        s = jnp.where(col <= pos + row, s, NEG_INF)
-        m_prev = m_scr[:, :1]                       # [Wp, 1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)                      # [Wp, bs]
-        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(
-            p, axis=1, keepdims=True)
-        m_scr[:, :1] = m_new
-        v = v_ref[0, 0]
-        if quantized:
-            p = p * _scale_row(vs_ref, head)
-            v = v.astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)     # [Wp, D]
-        acc_scr[:] = acc_scr[:] * corr + pv
-
-    @pl.when(ji == pl.num_programs(1) - 1)
-    def _finalize():
-        o_ref[0] = (acc_scr[:] / l_scr[:, :1]).astype(o_ref.dtype)
-
-
-def _paged_mq_bhsd(q, k, v, tables, pos, *, sm_scale: float,
-                   n_heads: int, w_real: int, interpret: bool,
-                   ks=None, vs=None):
-    """q [BH, Wp, D] (Wp = W padded to a sublane multiple); k, v
-    [n_blocks, H, bs, D] head-major pool; tables [B, max_blocks]; pos
-    [B] i32 -> [BH, Wp, D]. Grid walks (row, logical block); the
-    physical block index comes out of the scalar-prefetched table inside
-    the BlockSpec index maps, so paging lives in the DMA schedule.
-    ``ks``/``vs`` [n_blocks, H, bs] mark int8 pools (dequantized in
-    VMEM)."""
-    bh, wp, d = q.shape
-    mb = tables.shape[1]
-    bs = k.shape[2]
-    h = n_heads
-    quantized = ks is not None
-
-    pool_spec = pl.BlockSpec((1, 1, bs, d),
-                             lambda i, j, tbl, ps: (tbl[i // h, j],
-                                                    i % h, 0, 0))
-    in_specs = [
-        pl.BlockSpec((1, wp, d), lambda i, j, tbl, ps: (i, 0, 0)),
-        pool_spec,
-        pool_spec,
-    ]
-    operands = [tables, pos, q, k, v]
-    if quantized:
-        in_specs += [_scale_spec(h, bs)] * 2
-        operands += [ks, vs]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(bh, mb),
-        in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, wp, d),
-                               lambda i, j, tbl, ps: (i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((wp, 128), jnp.float32),   # m (col 0 used)
-            pltpu.VMEM((wp, 128), jnp.float32),   # l
-            pltpu.VMEM((wp, d), jnp.float32),     # acc
-        ],
-    )
-    with jax.named_scope(PAGED_MQ):
-        return pl.pallas_call(
-            functools.partial(_paged_mq_kernel, sm_scale=sm_scale,
-                              block_size=bs, n_heads=n_heads, w_real=w_real,
-                              quantized=quantized),
-            name=PAGED_MQ,
-            out_shape=jax.ShapeDtypeStruct((bh, wp, d), q.dtype),
-            grid_spec=grid_spec,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")),
-            interpret=interpret,
-        )(*operands)
 
 
 def _layer_of(pool, layer):
@@ -639,6 +551,89 @@ def _check_scales(k_scale, v_scale, k_pool, op: str):
     return True
 
 
+def _kernel_plan(op: str, impl: str, q, k_pool, quantized: bool):
+    """Resolve a paged wrapper's ``impl`` for queries ``q [B, W, H, D]``:
+    the plan its kernel runs at, or None for the JAX path. ``"auto"`` is
+    the kernel on a TPU where `_decode_plan` has a plan (a shape without
+    one is recorded), the JAX path elsewhere."""
+    _, w, h, d = q.shape
+    bs = k_pool.shape[-3]
+    plan = _decode_plan(bs, h, d, k_pool.dtype, quantized,
+                        _query_tile(w, h))
+    shape = (f"block_size {bs}, {w} queries of {h} heads of {d}, "
+             f"{k_pool.dtype} pool")
+    if impl == "auto":
+        if plan is None:
+            backend.note_fallback(op, shape)
+        impl = "pallas" if backend.on_tpu() and plan is not None else "jax"
+    if impl == "jax":
+        return None
+    if impl != "pallas":
+        raise ValueError(
+            f"unknown {op} impl {impl!r} (expected 'auto' | 'pallas' | "
+            "'jax')")
+    if plan is None:
+        if not backend.interpret():
+            raise ValueError(
+                f"no paged kernel plan for {shape}; use impl='jax'")
+        # the interpreter has no tiles to align: any shape, as stored
+        plan = _DecodePlan(1, max(1, _TURN_TOKENS // bs), None)
+    return plan
+
+
+def _paged_attend(name: str, q, k_pool, v_pool, tables, pos, k_scale,
+                  v_scale, layer, plan: _DecodePlan):
+    """The kernel path of the three wrappers: ``q [B, W, H, D]``, query
+    `i` of stream `b` at position ``pos[b] + i``, against the pools where
+    and as they are stored -> ``[B, W, H, D]``. The queries go to the
+    kernel in the order the model made them (``[B, W * H, D]`` is a free
+    reshape), padded to whole programs where one does not hold them all.
+
+    A head size that fills its lanes (`plan.pack` 1) leaves the pools
+    untouched, stacked or not: the layer is a number in each page's DMA.
+    At a smaller one XLA stores the pool padded to 128 lanes, and the
+    one layer read is sliced out and laid out for the kernel here, one
+    copy of a layer a call; an int8 pool's scales are laid out the same
+    way, a layer's at a time (2 MB at the cells' shapes)."""
+    b, w, h, d = q.shape
+    nb, bs = k_pool.shape[-4:-2]
+    pack = plan.pack
+    wt = _query_tile(w, h)
+    if w % wt:
+        q = jnp.pad(q, ((0, 0), (0, -w % wt), (0, 0), (0, 0)))
+    q = q.reshape(b, -1, d)                         # [B, tiles * wt * H, D]
+    ks = vs = None
+    if k_scale is not None:     # laid out for the kernel, one layer's scales
+        lay = lambda sc: _layer_of(sc, layer).reshape(
+            nb, bs, h // pack, pack).transpose(0, 3, 1, 2).reshape(
+            nb, pack, bs * h // pack)
+        ks, vs = lay(k_scale), lay(v_scale)
+    if pack > 1:
+        # `pack` heads side by side in a row of 128 lanes; a row of the
+        # queries and of the output keeps the lanes of its own head
+        # (row r is head r % H, and `pack` divides H)
+        own = (jnp.arange(q.shape[1])[:, None] % pack
+               == jnp.arange(pack * d)[None, :] // d)       # [rows, lanes]
+        q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
+        # the lay-out is a copy, so it is a layer's: slice, then reshape
+        # (a reshape of the stacked pool would copy every layer, and in a
+        # layer scan do so once a layer)
+        k_pool, v_pool = (
+            _layer_of(pool, layer).reshape(nb, bs * h // pack, pack * d)
+            for pool in (k_pool, v_pool))
+        layer = None
+    if layer is None:       # one layer is a stack of one: a free reshape
+        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
+    out = _paged_call(name, q, k_pool, v_pool, tables.astype(jnp.int32),
+                      pos.astype(jnp.int32),
+                      jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
+                      block_size=bs, heads=h, queries=w, sm_scale=d ** -0.5,
+                      interpret=backend.interpret(), ks=ks, vs=vs)
+    if pack > 1:
+        out = jnp.sum(jnp.where(own, out, 0).reshape(b, -1, pack, d), axis=2)
+    return out.reshape(b, -1, h, d)[:, :w]
+
+
 def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
                            k_scale=None, v_scale=None, layer=None,
                            impl: str = "auto"):
@@ -651,50 +646,24 @@ def paged_verify_attention(q, k_pool, v_pool, tables, pos, *,
     contract and the stacked form with ``layer``. Returns
     ``[B, W, H, D]`` in q.dtype.
 
-    impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax"; the paths share masking/accumulation math. Both read one
-    layer's copy of the pool (the kernel a head-major one, the jax path
-    a gather), so a stacked pool's layer is sliced inside that copy."""
+    impl: "auto" (pallas on a TPU where `_decode_plan` has a plan, else
+    jax) | "pallas" | "jax"; the paths share masking/accumulation math.
+    The kernel (`paged_mq`) is the decode step's with W rows a head: it
+    reads each stream's live pages of the layer where the pool lies
+    (`_paged_attend`); the jax path gathers one layer's slice."""
     _check_pools("paged_verify_attention", q, "B, W, H, D", k_pool,
                  tables, "B, max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_verify_attention")
-    k_pool, v_pool, k_scale, v_scale = (
-        _layer_of(a, layer) for a in (k_pool, v_pool, k_scale, v_scale))
-    b, w, h, d = q.shape
-    bs = k_pool.shape[1]
-    if impl == "auto":
-        impl = _auto_impl("paged_verify_attention", bs % 8 == 0,
-                          f"block_size {bs}")
-    if impl == "jax":
-        return reference_paged_verify_attention(
-            q, k_pool, v_pool, tables, pos,
-            k_scale=k_scale, v_scale=v_scale)
-    if impl != "pallas":
-        raise ValueError(
-            f"unknown paged_verify_attention impl {impl!r} "
-            "(expected 'auto' | 'pallas' | 'jax')")
-    if bs % 8 != 0:
-        raise ValueError(
-            f"block_size {bs} is not a multiple of 8; use impl='jax'")
-    interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
-    wp = max(8, ((w + 7) // 8) * 8)
-    kt = _pad_heads(k_pool, d_pad).transpose(0, 2, 1, 3)
-    vt = _pad_heads(v_pool, d_pad).transpose(0, 2, 1, 3)
-    qt = _pad_heads(q, d_pad).transpose(0, 2, 1, 3).reshape(
-        b * h, w, d_pad)
-    qt = jnp.pad(qt, ((0, 0), (0, wp - w), (0, 0)))
-    ks = vs = None
-    if quantized:
-        ks = k_scale.transpose(0, 2, 1)      # head-major [nb, H, bs]
-        vs = v_scale.transpose(0, 2, 1)
-    out = _paged_mq_bhsd(qt, kt, vt, tables.astype(jnp.int32),
-                         pos.astype(jnp.int32), sm_scale=d ** -0.5,
-                         n_heads=h, w_real=w, interpret=interpret,
-                         ks=ks, vs=vs)
-    return out.reshape(b, h, wp, d_pad)[:, :, :w, :d].transpose(
-        0, 2, 1, 3)
+    plan = _kernel_plan("paged_verify_attention", impl, q, k_pool,
+                        quantized)
+    if plan is None:
+        k, v, ks, vs = (_layer_of(a, layer)
+                        for a in (k_pool, v_pool, k_scale, v_scale))
+        return reference_paged_verify_attention(q, k, v, tables, pos,
+                                                k_scale=ks, v_scale=vs)
+    return _paged_attend(PAGED_MQ, q, k_pool, v_pool, tables, pos,
+                         k_scale, v_scale, layer, plan)
 
 
 def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
@@ -723,70 +692,22 @@ def paged_decode_attention(q, k_pool, v_pool, tables, pos, *,
     impl: "auto" (pallas on a TPU where `_decode_plan` has a plan, else
     jax) | "pallas" | "jax". The two paths share the same
     masking/accumulation math and agree to f32 tolerance. The kernel
-    takes a pool whose head size is a multiple of 128 where and as it is
-    stored, stacked or not: the layer is a number in each page's DMA. At
-    a smaller head size XLA stores the pool padded to 128 lanes, and the
-    one layer read is sliced out and laid out for the kernel here, one
-    copy of a layer a call; an int8 pool's scales are laid out the same
-    way, a layer's at a time."""
+    (`paged_decode`) is `_paged_attend`'s with one query a head: what it
+    takes as stored and what it lays out is said there."""
     _check_pools("paged_decode_attention", q, "B, H, D", k_pool, tables,
                  "B, max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_decode_attention")
-    b, h, d = q.shape
-    nb, bs = k_pool.shape[-4:-2]
-    plan = _decode_plan(bs, h, d, k_pool.dtype, quantized)
-    if impl == "auto":
-        impl = _auto_impl("paged_decode_attention", plan is not None,
-                          f"block_size {bs}, {h} heads of {d}, "
-                          f"{k_pool.dtype} pool")
-    if impl == "jax":
+    rows = q[:, None]                   # one query a head: W = 1
+    plan = _kernel_plan("paged_decode_attention", impl, rows, k_pool,
+                        quantized)
+    if plan is None:
         k, v, ks, vs = (_layer_of(a, layer)
                         for a in (k_pool, v_pool, k_scale, v_scale))
         return reference_paged_decode_attention(q, k, v, tables, pos,
                                                 k_scale=ks, v_scale=vs)
-    if impl != "pallas":
-        raise ValueError(
-            f"unknown paged_decode_attention impl {impl!r} "
-            "(expected 'auto' | 'pallas' | 'jax')")
-    interpret = backend.interpret()
-    if plan is None:
-        if not interpret:
-            raise ValueError(
-                f"no paged_decode plan for block_size {bs}, {h} heads of "
-                f"{d}, {k_pool.dtype} pool; use impl='jax'")
-        # the interpreter has no tiles to align: any shape, as stored
-        plan = _DecodePlan(1, max(1, _TURN_TOKENS // bs), None)
-    pack = plan.pack
-    ks = vs = None
-    if quantized:           # laid out for the kernel, one layer's scales
-        lay = lambda sc: _layer_of(sc, layer).reshape(
-            nb, bs, h // pack, pack).transpose(0, 3, 1, 2).reshape(
-            nb, pack, bs * h // pack)
-        ks, vs = lay(k_scale), lay(v_scale)
-    if pack > 1:
-        # `pack` heads side by side in a row of 128 lanes; row h of the
-        # query and of the output keeps the lanes of its own head
-        own = (jnp.arange(h)[:, None] % pack
-               == jnp.arange(pack * d)[None, :] // d)       # [H, lanes]
-        q = jnp.where(own, jnp.tile(q, (1, 1, pack)), 0)
-        # the lay-out is a copy, so it is a layer's: slice, then reshape
-        # (a reshape of the stacked pool would copy every layer, and in a
-        # layer scan do so once a layer)
-        k_pool, v_pool = (
-            _layer_of(pool, layer).reshape(nb, bs * h // pack, pack * d)
-            for pool in (k_pool, v_pool))
-        layer = None
-    if layer is None:       # one layer is a stack of one: a free reshape
-        k_pool, v_pool, layer = k_pool[None], v_pool[None], 0
-    out = _paged_decode(q, k_pool, v_pool, tables.astype(jnp.int32),
-                        pos.astype(jnp.int32),
-                        jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
-                        block_size=bs, sm_scale=d ** -0.5,
-                        interpret=interpret, ks=ks, vs=vs)
-    if pack > 1:
-        out = jnp.sum(jnp.where(own, out, 0).reshape(b, h, pack, d), axis=2)
-    return out
+    return _paged_attend(PAGED_DECODE, rows, k_pool, v_pool, tables, pos,
+                         k_scale, v_scale, layer, plan)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -834,62 +755,35 @@ def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
     the caller scatters the chunk's K/V into the pool FIRST, exactly as
     `models.gpt.prefill_paged` always has.
 
-    The pallas path reuses the multi-query verify kernel: the prefill
+    The pallas path is the verify kernel's (`paged_mq`): the prefill
     staircase (token t sees positions ``<= start + t``) is the verify
-    mask with ``pos = start`` and ``W = C``, so the [C, S] score matrix
-    lives blockwise in VMEM instead of round-tripping through HBM, and
-    the runtime block skip prunes pool blocks past ``start + C - 1``.
+    mask with ``pos = start``, ``W = C`` and one stream, so the [C, S]
+    score matrix lives a turn of pages at a time in VMEM instead of
+    round-tripping through HBM, and only the pages up to ``start + C -
+    1`` are fetched, from the pool where it lies (`_paged_attend`).
     The jax path is the legacy dense gather+einsum
     (`reference_paged_prefill_attention`) — bit-identical to the
     pre-fused inline math, which keeps ``impl="jax"`` the bitwise
     default on CPU. ``k_scale``/``v_scale`` [n_blocks, bs, H] mark int8
     pools, dequantized at read on both paths. Pools and scales may be
     stacked ``[L, n_blocks, ...]`` with ``layer`` the one to read, as in
-    `paged_decode_attention`; both paths copy what they read, so the
-    layer is sliced inside that copy.
+    `paged_decode_attention`: the kernel reads that layer's live pages
+    in place, the jax path gathers from its slice.
 
-    impl: "auto" (pallas on TPU-friendly shapes, else jax) | "pallas" |
-    "jax". Returns ``[C, H, D]`` in q.dtype."""
+    impl: "auto" (pallas on a TPU where `_decode_plan` has a plan, else
+    jax) | "pallas" | "jax". Returns ``[C, H, D]`` in q.dtype."""
     _check_pools("paged_prefill_attention", q, "C, H, D", k_pool, table,
                  "max_blocks", layer)
     quantized = _check_scales(k_scale, v_scale, k_pool,
                               "paged_prefill_attention")
-    k_pool, v_pool, k_scale, v_scale = (
-        _layer_of(a, layer) for a in (k_pool, v_pool, k_scale, v_scale))
-    c, h, d = q.shape
-    bs = k_pool.shape[1]
-    if impl == "auto":
-        impl = _auto_impl("paged_prefill_attention", bs % 8 == 0,
-                          f"block_size {bs}")
-    if impl == "jax":
-        return reference_paged_prefill_attention(
-            q, k_pool, v_pool, table, start,
-            k_scale=k_scale, v_scale=v_scale)
-    if impl != "pallas":
-        raise ValueError(
-            f"unknown paged_prefill_attention impl {impl!r} "
-            "(expected 'auto' | 'pallas' | 'jax')")
-    if bs % 8 != 0:
-        raise ValueError(
-            f"block_size {bs} is not a multiple of 8; use impl='jax'")
-    interpret = backend.interpret()
-    d_pad = _head_pad_target(d)
-    wp = max(8, ((c + 7) // 8) * 8)
-    kt = _pad_heads(k_pool, d_pad).transpose(0, 2, 1, 3)
-    vt = _pad_heads(v_pool, d_pad).transpose(0, 2, 1, 3)
-    # One sequence == one batch row of the mq kernel: B=1, W=C,
-    # pos=start. Padded q rows (>= C) compute a discarded garbage row —
-    # the same thing the dense path's padded chunk tail does.
-    qt = q.transpose(1, 0, 2)                      # [H, C, D]
-    qt = _pad_heads(qt, d_pad)
-    qt = jnp.pad(qt, ((0, 0), (0, wp - c), (0, 0)))
-    ks = vs = None
-    if quantized:
-        ks = k_scale.transpose(0, 2, 1)
-        vs = v_scale.transpose(0, 2, 1)
-    tables = table.astype(jnp.int32)[None]
-    pos = jnp.asarray(start, jnp.int32).reshape(1)
-    out = _paged_mq_bhsd(qt, kt, vt, tables, pos, sm_scale=d ** -0.5,
-                         n_heads=h, w_real=c, interpret=interpret,
-                         ks=ks, vs=vs)
-    return out[:, :c, :d].transpose(1, 0, 2)
+    plan = _kernel_plan("paged_prefill_attention", impl, q[None], k_pool,
+                        quantized)
+    if plan is None:
+        k, v, ks, vs = (_layer_of(a, layer)
+                        for a in (k_pool, v_pool, k_scale, v_scale))
+        return reference_paged_prefill_attention(q, k, v, table, start,
+                                                 k_scale=ks, v_scale=vs)
+    # one sequence is one stream of the kernel: B = 1, W = C, pos = start
+    return _paged_attend(PAGED_MQ, q[None], k_pool, v_pool, table[None],
+                         jnp.asarray(start).reshape(1), k_scale, v_scale,
+                         layer, plan)[0]

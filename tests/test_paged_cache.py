@@ -55,19 +55,21 @@ def make_engine(cfg, params, **kw):
 KERNEL_SHAPES = [(16, 128, 16), (16, 64, 16), (4, 32, 8)]
 
 
-def edge_batch(h, d, bs, dtype, seed=0):
+def edge_batch(h, d, bs, dtype, seed=0, w=None):
     """Six rows against one scrambled pool, `turn` the kernel's pages a
     turn: an idle row (pos 0, a table of zeros), one ending on a block's
     last position, one on a block's first, one filling the whole table
     (2 turns and 3 pages), one a page past a turn, one of exactly a
-    turn. Entries past a row's length point at other rows' blocks.
+    turn. Entries past a row's length point at other rows' blocks. With
+    `w`, rows of `w` queries (a verify step's: query `i` at `pos + i`,
+    its page the row's own where the table reaches it).
     -> (q, k_pool, v_pool, tables, pos)."""
     turn = 128 // bs
     mb = 2 * turn + 3
     pos = np.array([0, 3 * bs - 1, 3 * bs, mb * bs - 1,
                     (turn + 1) * bs - 5, turn * bs - 1], np.int32)
     rng = np.random.default_rng(seed)
-    live = pos // bs + 1
+    live = np.minimum((pos + (w or 1) - 1) // bs + 1, mb)
     live[0] = 0
     nb = 1 + int(live.sum())
     blocks = iter(rng.permutation(nb - 1) + 1)
@@ -77,16 +79,29 @@ def edge_batch(h, d, bs, dtype, seed=0):
         tables[i, :n] = [next(blocks) for _ in range(n)]
     kq, kk, kv = jax.random.split(jax.random.PRNGKey(seed), 3)
     pool = lambda key: jax.random.normal(key, (nb, bs, h, d)).astype(dtype)
-    q = jax.random.normal(kq, (len(pos), h, d)).astype(dtype)
+    rows = (len(pos), h, d) if w is None else (len(pos), w, h, d)
+    q = jax.random.normal(kq, rows).astype(dtype)
     return q, pool(kk), pool(kv), jnp.asarray(tables), jnp.asarray(pos)
 
 
-def dead_blocks(nb, bs, tables, pos):
-    """Blocks that no decoding row (row 0 is idle) reads at its pos."""
+def dead_blocks(nb, bs, tables, pos, w=1):
+    """Blocks that no decoding row (row 0 is idle) reads at its pos, or
+    for its `w` queries from there on."""
     tables, pos = np.asarray(tables), np.asarray(pos)
     read = {int(b) for i in range(1, len(pos))
-            for b in tables[i, :pos[i] // bs + 1]}
+            for b in tables[i, :(pos[i] + w - 1) // bs + 1]}
     return jnp.asarray(sorted(set(range(nb)) - read))
+
+
+def with_dead_nan(kp, vp, ksc, vsc, dead):
+    """The pools with NaN in every `dead` block: in the payload, or for
+    an int8 pool (which cannot hold one) in the scales, over a payload
+    of garbage."""
+    if ksc is None:
+        return kp.at[dead].set(jnp.nan), vp.at[dead].set(jnp.nan), {}
+    return kp.at[dead].set(127), vp.at[dead].set(-128), {
+        "k_scale": ksc.at[dead].set(jnp.nan),
+        "v_scale": vsc.at[dead].set(jnp.nan)}
 
 
 class TestPagedAttention:
@@ -194,12 +209,19 @@ class TestPagedAttention:
         ((16, 12, 96, "bfloat16"), {}, None),       # no row of 128 lanes
         ((16, 2, 128, "float32"), {}, None),        # heads under a tile
         ((8, 4, 32, "int8"), {"quantized": True}, None),    # scale rows
-        ((256, 64, 128, "bfloat16"), {}, (1, 1, 60 << 20)),  # asks VMEM
+        ((256, 64, 128, "bfloat16"), {}, (1, 1, 53 << 20)),  # asks VMEM
         ((256, 64, 128, "bfloat16"), {"vmem": 32 << 20}, None),
+        # `paged_mq`: the cells' chunk of 64 (1024 score rows a program),
+        # a verify step of 5, a chunk at two heads a row, an f32 pool
+        ((16, 16, 128, "bfloat16"), {"w": 64}, (1, 4, None)),
+        ((16, 16, 128, "bfloat16"), {"w": 5}, (1, 8, None)),
+        ((16, 16, 128, "int8"), {"w": 64, "quantized": True}, (1, 4, None)),
+        ((16, 16, 64, "bfloat16"), {"w": 64}, (2, 8, None)),
+        ((16, 16, 128, "float32"), {"w": 64}, (1, 2, None)),
     ])
     def test_the_plan_comes_from_the_shapes(self, shape, kw, want):
         bs, h, d, dtype = shape
-        kw = {"quantized": False, **kw}
+        kw = {"quantized": False, "w": 1, **kw}
         plan = da._decode_plan(bs, h, d, jnp.dtype(dtype), **kw)
         assert plan == (want and da._DecodePlan(*want))
 
@@ -1008,11 +1030,74 @@ class TestFusedPrefill:
         np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
                                    atol=2e-5, rtol=2e-5)
 
+    @pytest.mark.parametrize("dead", [False, True],
+                             ids=["clean", "dead-pages-nan"])
+    @pytest.mark.parametrize("kv", ["f32", "int8"])
+    @pytest.mark.parametrize("start,c", [(0, 24), (19, 17), (40, 80)],
+                             ids=["from-0", "ends-mid-page", "C80"])
+    @pytest.mark.parametrize("h,d,bs", KERNEL_SHAPES)
+    def test_kernel_reads_the_live_pages_alone(self, h, d, bs, start, c,
+                                               kv, dead):
+        """`paged_mq` (interpret mode) as a prefill chunk on the kernel
+        shapes (16 heads: 80 queries are two programs, the second padded)
+        against the dense reference: a chunk from position 0, one whose
+        last query sits mid-page, and with `dead` NaN in every block past
+        the chunk's last page, those that table entries past the length
+        name among them (an int8 pool: NaN scales)."""
+        mb = (start + c - 1) // bs + 3
+        q, kp, vp, ksc, vsc, table = self._seq(
+            mb * bs, h, d, bs, seed=5, quantize=kv == "int8")
+        q = q[start:start + c]
+        ref = da.reference_paged_prefill_attention(
+            q, kp, vp, table, start, k_scale=ksc, v_scale=vsc)
+        scales = {} if ksc is None else {"k_scale": ksc, "v_scale": vsc}
+        if dead:
+            live = np.asarray(table)[:(start + c - 1) // bs + 1]
+            gone = jnp.asarray(sorted(set(range(mb + 1)) - set(live.tolist())))
+            kp, vp, scales = with_dead_nan(kp, vp, ksc, vsc, gone)
+        out = da.paged_prefill_attention(q, kp, vp, table, start,
+                                         impl="pallas", **scales)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                                   atol=2e-5, rtol=2e-5)
+
     def test_rejects_bad_rank(self):
         with pytest.raises(ValueError, match="paged_prefill_attention"):
             da.paged_prefill_attention(
                 jnp.zeros((4, 16)), jnp.zeros((4, 8, 2, 16)),
                 jnp.zeros((4, 8, 2, 16)), jnp.zeros((4,), jnp.int32), 0)
+
+
+class TestVerifyKernel:
+    @pytest.mark.parametrize("dead", [False, True],
+                             ids=["clean", "dead-pages-nan"])
+    @pytest.mark.parametrize("kv", ["f32", "int8"])
+    @pytest.mark.parametrize("h,d,bs", KERNEL_SHAPES)
+    def test_kernel_reads_the_live_pages_alone(self, h, d, bs, kv, dead):
+        """`paged_mq` (interpret mode) as a verify step of 5 on the decode
+        kernel's shapes and `edge_batch`'s rows (one runs past the
+        table's reach) against the gather-then-attend reference; with
+        `dead`, NaN in every block no row's five queries reach (an int8
+        pool: NaN scales over garbage)."""
+        w = 5
+        q, kp, vp, tables, pos = edge_batch(h, d, bs, "float32", seed=4,
+                                            w=w)
+        ksc = vsc = None
+        if kv == "int8":
+            kp, ksc = quant.quantize_rows(kp)
+            vp, vsc = quant.quantize_rows(vp)
+        scales = {} if ksc is None else {"k_scale": ksc, "v_scale": vsc}
+        ref = da.reference_paged_verify_attention(q, kp, vp, tables, pos,
+                                                  **scales)
+        if dead:
+            kp, vp, scales = with_dead_nan(
+                kp, vp, ksc, vsc, dead_blocks(kp.shape[0], bs, tables, pos,
+                                              w))
+        out = da.paged_verify_attention(q, kp, vp, tables, pos,
+                                        impl="pallas", **scales)
+        assert out.shape == q.shape and out.dtype == q.dtype
+        np.testing.assert_allclose(np.asarray(out)[1:], np.asarray(ref)[1:],
+                                   atol=2e-5, rtol=2e-5)
 
 
 class TestQuantizedModelPath:

@@ -146,7 +146,12 @@ def test_dropped_rows_drop_in_every_layer(monkeypatch, program, loop):
 # it lies, four heads to a row of lanes are laid out a layer at a time
 STACKED = [("decode", 8, 128, "f32"), ("decode", 4, 32, "int8"),
            ("decode", 4, 32, "f32"), ("verify", 8, 128, "int8"),
-           ("prefill", 4, 32, "f32")]
+           ("prefill", 4, 32, "f32"),
+           # `paged_mq` with the decode kernel's operands: two heads to
+           # a row of lanes, and an int8 pool's scales a layer at a time
+           ("prefill", 16, 64, "f32"), ("prefill", 16, 64, "int8"),
+           ("prefill", 8, 128, "int8"), ("verify", 16, 64, "f32"),
+           ("verify", 16, 64, "int8"), ("verify", 8, 128, "f32")]
 
 
 @pytest.mark.parametrize("op,h,d,kv", STACKED,
