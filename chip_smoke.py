@@ -16,10 +16,13 @@ and checks what comes out against references computed the plain way.
 The serve phase runs its streams a second time under a `jax.profiler`
 trace taken inside the replica, Python tracer off, and prints what the
 program's own spans (`engine/*`, `stream/*`) and named kernels say. It
-then serves the power-retention family (`models/retention.py`: a state of
-fixed size a sequence) from an engine in a process of its own, at the
-published head size so that both of its kernels run, and fails on a
-fallback of either as the first part does for the paged kernels.
+then serves the two families that keep a state a sequence, each from an
+engine in a process of its own at the published head size so that its
+kernels run, and fails on a fallback of any as the first part does for
+the paged kernels: the power-retention family (`models/retention.py`,
+`--phase serve-retention`: a state block and no pages) and the KDA-and-
+latent family (`models/linear_latent.py`, `--phase serve-hybrid`: a state
+block and growing latent pages in one pool, experts chosen by groups).
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -636,28 +639,93 @@ RETENTION_CFG = dict(vocab_size=512, d_model=256, n_layers=2, n_heads=6,
 RETENTION_LOGPROB_MAX_TOL = 6e-2
 RETENTION_LOGPROB_MEAN_TOL = 2e-2
 
+# `models/linear_latent.py` at the published head (128 x 128 states, so
+# both KDA kernels have a plan), latent rows of 160 values in pages of 128,
+# and otherwise tiny: layers 1-7 of a period of six (a dense layer, five
+# KDA layers around one latent layer), 8 held experts of a 32-wide router
+# in 4 groups
+HYBRID_CFG = dict(
+    vocab_size=512, d_model=256, n_layers=7, first_layer=1, n_heads=2,
+    kda_head_dim=128, kv_rank=128, nope_dim=64, rope_dim=32, v_dim=64,
+    indexer_types=("none",) * 8,
+    mixer_types=("kda",) * 5 + ("latent", "kda", "kda"),
+    mlp_types=("dense", "dense") + ("sparse",) * 6, d_ff=512, expert_ff=128,
+    router_width=32, held_count=8, experts_per_token=4, n_group=4,
+    topk_group=2, eps=1e-6, rope_theta=6e6, max_seq_len=1024)
+# bfloat16 activations against the float32 definition, seven layers
+# (chip runs, PR 40: 0.184 largest, 0.0106 mean; the largest are tokens whose
+# expert choice the activations' rounding flipped; see PERF.md)
+HYBRID_LOGPROB_MAX_TOL = 5e-1
+HYBRID_LOGPROB_MEAN_TOL = 3e-2
 
-def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
-                          prompt_lens: tuple[int, int], new_tokens: int,
-                          slots: int, seed: int) -> None:
-    """The engine over the power-retention family, in this process:
-    `streams` greedy requests over `slots` state blocks (so blocks are
-    reused), chunked prefill in both buckets and then steps. Holds the
-    streamed logprobs to the float32 definition over the same tokens, the
-    two programs to their kernels by name, and `ops.backend.note_fallback`
+
+def retention_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import retention
+    cfg = retention.RetentionConfig(**cfg_kwargs)
+    return {
+        "phase": "serve_retention", "family": retention, "cfg": cfg,
+        "params": retention.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32",
+                                     retention_impl="jax"),
+        "engine": {}, "table": 1,
+        "decode_kernels": {"retention_step": cfg.n_layers},
+        "prefill_kernels": {"retention_chunk": cfg.n_layers},
+        "counters": ("state_resets", "retention_tokens_live",
+                     "retention_tokens_padded"),
+        "tolerances": (RETENTION_LOGPROB_MAX_TOL,
+                       RETENTION_LOGPROB_MEAN_TOL)}
+
+
+def hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import linear_latent
+    cfg = linear_latent.LinearLatentConfig(**cfg_kwargs)
+    n_kda = cfg.mixers.count("kda")
+    n_latent, n_sparse = cfg.n_layers - n_kda, sum(
+        mlp == "sparse" for mlp, _ in cfg.kinds)
+    return {
+        "phase": "serve_hybrid", "family": linear_latent, "cfg": cfg,
+        "params": linear_latent.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32", kda_impl="jax",
+                                     sparse_impl="jax"),
+        "engine": {"block_size": 128}, "table": 1 + 1024 // 128,
+        "decode_kernels": {"kda_step": n_kda, "latent_row_write": n_latent,
+                           "latent_decode": n_latent,
+                           "experts_grouped": n_sparse},
+        "prefill_kernels": {"kda_chunk": n_kda,
+                            "latent_row_write": n_latent,
+                            "latent_row_gather": n_latent,
+                            "experts_grouped_prefill": n_sparse},
+        "counters": ("state_resets", "kda_tokens_live", "kda_tokens_padded",
+                     "latent_rows_read", "expert_tokens_here",
+                     "expert_tokens_routed", "expert_groups_kept_here",
+                     "expert_load_max_over_mean", "state_blocks"),
+        "tolerances": (HYBRID_LOGPROB_MAX_TOL, HYBRID_LOGPROB_MEAN_TOL)}
+
+
+def serve_family_phase(case: dict, *, platform: str, streams: int,
+                       prompt_lens: tuple[int, int], new_tokens: int,
+                       slots: int, seed: int) -> None:
+    """The engine over a family that keeps a state a sequence
+    (`retention_case`, `hybrid_case`), in this process: `streams` greedy
+    requests over `slots` state blocks (so blocks are reused), chunked
+    prefill in both buckets and then steps. Holds the streamed logprobs to
+    the family's float32 definition over the same tokens, the two programs
+    to their kernels by name and number, and `ops.backend.note_fallback`
     to silence, as the first serve phase holds the paged kernels."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import retention
     from ray_tpu.serve.engine import InferenceEngine
     fallbacks = watch_op_fallbacks()
     compiles = CompileWatch()
-    cfg = retention.RetentionConfig(**cfg_kwargs)
-    params = retention.init_params(jax.random.key(seed), cfg)
+    family, cfg, params = case["family"], case["cfg"], case["params"]
     eng = InferenceEngine(params, cfg, slots=slots, max_len=1024,
                           prefill_chunk=512, prefill_buckets=(128, 512),
-                          prefix_cache=False, seed=seed)
+                          prefix_cache=False, seed=seed, **case["engine"])
     rng = np.random.default_rng(seed)
     prompts = [rng.integers(0, cfg.vocab_size, int(rng.integers(
         prompt_lens[0], prompt_lens[1] + 1))).astype(np.int32)
@@ -666,14 +734,12 @@ def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
     rids = [eng.submit(p, max_new_tokens=new_tokens) for p in prompts]
     outs = [list(eng.tokens_for(r)) for r in rids]
     wall_s = time.perf_counter() - t0
-    cfg32 = dataclasses.replace(cfg, dtype="float32", retention_impl="jax")
     diffs = []
     with jax.default_matmul_precision("highest"):
         for p, out in zip(prompts, outs):
             seq = np.concatenate([p, [int(t) for t in out]])
-            logits = retention.forward(params, jnp.asarray(seq[None],
-                                                           jnp.int32),
-                                       cfg32)[0, len(p) - 1:-1]
+            logits = family.forward(params, jnp.asarray(seq[None], jnp.int32),
+                                    case["plain"])[0, len(p) - 1:-1]
             want = jnp.take_along_axis(
                 jax.nn.log_softmax(logits, -1),
                 jnp.asarray(seq[len(p):, None], jnp.int32), -1)[:, 0]
@@ -687,16 +753,17 @@ def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)
 
+    width = case["table"]
     decode_k, decode_n, _ = kernels_of(
-        jax.jit(lambda p, t, c, pos, tb: retention.decode(
+        jax.jit(lambda p, t, c, pos, tb: family.decode(
             p, t, c, pos, tb, cfg)[:2]),
-        abstract[0], i32(slots), abstract[1], i32(slots), i32(slots, 1))
+        abstract[0], i32(slots), abstract[1], i32(slots), i32(slots, width))
     prefill_k, prefill_n, _ = kernels_of(
-        jax.jit(lambda p, t, c, tb, st, ln: retention.prefill(
+        jax.jit(lambda p, t, c, tb, st, ln: family.prefill(
             p, t, c, cfg, block_table=tb, start=st, length=ln)),
-        abstract[0], i32(1, 512), abstract[1], i32(1), i32(), i32())
+        abstract[0], i32(1, 512), abstract[1], i32(width), i32(), i32())
     stats, device = eng.stats(), device_report()
-    emit({"phase": "serve_retention", "streams": streams,
+    emit({"phase": case["phase"], "streams": streams,
           "new_tokens": new_tokens,
           "prompt_lens": [len(p) for p in prompts],
           "wall_s": round(wall_s, 2),
@@ -708,9 +775,8 @@ def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
           "stats": {k: stats[k] for k in (
               "decode_traces", "prefill_traces", "retraces_unexpected",
               "decode_tokens", "prefill_tokens", "prefill_chunks",
-              "state_resets", "retention_tokens_live",
-              "retention_tokens_padded", "cache_blocks", "pool_bytes",
-              "p50_token_latency_ms")},
+              "cache_blocks", "pool_bytes", "p50_token_latency_ms")
+              + case["counters"]},
           **compiles.report(), **device,
           "peak_bytes_in_use": peak_bytes()})
     check(device["platform"] == platform,
@@ -723,19 +789,18 @@ def serve_retention_phase(cfg_kwargs: dict, *, platform: str, streams: int,
           f"{stats['prefill_traces']} prefill traces")
     check(stats["state_resets"] == streams,
           f"{stats['state_resets']} first chunks for {streams} requests")
-    check(diff.max() <= RETENTION_LOGPROB_MAX_TOL
-          and diff.mean() <= RETENTION_LOGPROB_MEAN_TOL,
+    max_tol, mean_tol = case["tolerances"]
+    check(diff.max() <= max_tol and diff.mean() <= mean_tol,
           f"engine logprobs are {diff.max()} (max) / {diff.mean()} (mean) "
-          f"from the float32 definition (tolerances "
-          f"{RETENTION_LOGPROB_MAX_TOL} / {RETENTION_LOGPROB_MEAN_TOL})")
+          f"from the float32 definition (tolerances {max_tol} / {mean_tol})")
     check(not fallbacks, f"ops fell back to pure JAX: {list(fallbacks)}")
     if platform == "tpu":
-        check(decode_k == ["retention_step"]
-              and decode_n == cfg.n_layers,
-              f"no step kernel a layer: {decode_k} x {decode_n}")
-        check(prefill_k == ["retention_chunk"]
-              and prefill_n == cfg.n_layers,
-              f"no chunk kernel a layer: {prefill_k} x {prefill_n}")
+        for what, names, n, want in (
+                ("decode", decode_k, decode_n, case["decode_kernels"]),
+                ("prefill", prefill_k, prefill_n, case["prefill_kernels"])):
+            check(names == sorted(want) and n == sum(want.values()),
+                  f"the {what} program's kernels are {names} x {n}, "
+                  f"wanted {want}")
 
 
 # ---------------------------------------------------------------------------
@@ -1094,10 +1159,10 @@ def main() -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", choices=("train", "train4", "serve-load",
-                                        "serve-retention"),
+                                        "serve-retention", "serve-hybrid"),
                     help="serve-load: see the top of this file; train, "
-                    "train4 and serve-retention are how a phase child is "
-                    "started")
+                    "train4, serve-retention and serve-hybrid are how a "
+                    "phase child is started")
     args = ap.parse_args()
 
     if args.phase == "train":
@@ -1108,10 +1173,13 @@ def main() -> int:
         train4_phase(TRAIN_CFG, platform="tpu", batch=8, steps=8,
                      seed=args.seed)
         return 0
-    if args.phase == "serve-retention":
-        serve_retention_phase(RETENTION_CFG, platform="tpu", streams=6,
-                              prompt_lens=(100, 700), new_tokens=32,
-                              slots=4, seed=args.seed)
+    if args.phase in ("serve-retention", "serve-hybrid"):
+        case = (retention_case(RETENTION_CFG, args.seed)
+                if args.phase == "serve-retention"
+                else hybrid_case(HYBRID_CFG, args.seed))
+        serve_family_phase(case, platform="tpu", streams=6,
+                           prompt_lens=(100, 700), new_tokens=32, slots=4,
+                           seed=args.seed)
         return 0
 
     # Which device JAX finds, asked in a process that exits again.
@@ -1146,6 +1214,7 @@ def main() -> int:
                         prompt_lens=(128, 512), new_tokens=64, slots=8,
                         max_len=1024, seed=args.seed)
             run_phase_child("serve-retention", args.seed)
+            run_phase_child("serve-hybrid", args.seed)
             run_phase_child("train", args.seed)
         else:
             run_phase_child("train4", args.seed)

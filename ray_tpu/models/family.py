@@ -11,9 +11,9 @@ from typing import Callable, NamedTuple
 class ServingFamily(NamedTuple):
     """What `serve/engine.py` asks of a model family, found as the
     `family` of the configuration object it is given. The pool is a dict
-    of arrays, any number of kinds, with the blocks on axis 1 of each.
+    of arrays with the blocks on axis 1 of each, of one or two kinds.
 
-    init_pool(cfg, n_blocks, block_size, mesh) -> pool
+    init_pool(cfg, n_blocks, block_size, mesh[, state_blocks=]) -> pool
     prefill(params, tokens [1, C], pool, cfg, mesh, *, block_table,
             start, length) -> (logits [1, V] f32, pool, counts)
     decode(params, tokens [B], pool, pos, tables, cfg, mesh)
@@ -36,13 +36,25 @@ class ServingFamily(NamedTuple):
     counts(cfg, totals) -> {name: number}: what the int32 vector that
             prefill and decode return third, summed over a window, adds
             to `stats()`; None where they return None
-    state_blocks: None for a cache that grows, a block a range of
-            `block_size` tokens written once. A number (1) for a family
-            whose block is a sequence's whole state, of fixed size and
-            rewritten by every token: a request then holds that many
-            blocks whatever its length, its table has that width, and
-            the engine keeps no prefix tree (a block's content names no
-            range of tokens) and re-prefills a preempted stream from its
+    What a request holds of the pool (one contract for every family,
+    one footprint arithmetic in the engine): `state_blocks` blocks of
+    fixed size whatever its length, and, where `paged`, one page a
+    `block_size` tokens of its prompt and output, which grow with it. Its
+    block table is `state_blocks` columns of state blocks, then the
+    columns of its pages in order (0: the trash block of that kind).
+    `models/gpt.py` is (0, paged): pages alone. `models/retention.py` is
+    (1, not paged): a sequence's whole state, rewritten by every token.
+    `models/linear_latent.py` is (1, paged): both.
+
+    state_blocks: how many blocks of fixed size a request holds
+    paged: whether it also holds pages that grow
+    state_keys: the pool's arrays whose axis 1 counts state blocks; every
+            other array's axis 1 counts pages. Block 0 of each kind is
+            the trash block. A family with state blocks is given their
+            number as `init_pool(..., state_blocks=n)` beside the pages'
+            `n_blocks`; a state names no range of tokens, so the engine
+            keeps no prefix tree for such a family (`prefix_cache=True`
+            is refused) and re-prefills a preempted stream from its
             first token
     """
     init_pool: Callable
@@ -54,4 +66,6 @@ class ServingFamily(NamedTuple):
     verify: Callable | None = None
     load: Callable | None = None
     counts: Callable | None = None
-    state_blocks: int | None = None
+    state_blocks: int = 0
+    paged: bool = True
+    state_keys: tuple = ()

@@ -6,8 +6,10 @@ bottleneck (`glm_moe_dsa`: GLM-5.2) it is served through the engine
 (`prefill`, `decode`, the paged pool). Without either (`deepseek_v3`:
 Kanana-2; `indexer_types` all "none", `q_rank` None) every query attends
 to every earlier position; that layer is trained (`forward_features`,
-`train.spmd.make_latent_moe_trainer`), and the serving entry points
-refuse it until a dense latent decode kernel exists.
+`train.spmd.make_latent_moe_trainer`) and served through the same entry
+points: a decode step streams a stream's live pages whole against the
+absorbed query (`ops.sparse_latent.latent_decode`), a prefill chunk
+attends to every cached row, and the pool has no index keys.
 
 Three mechanisms in one layer, none of which `models/gpt.py` has:
 
@@ -113,6 +115,10 @@ class LatentSparseMoEConfig:
     held_count: int = 8
     routed_scale: float = 2.5
     norm_topk: bool = True
+    # group-limited choice (the published keys): the router's width in
+    # `n_group` equal groups, experts chosen inside the `topk_group` best
+    n_group: int = 1
+    topk_group: int = 1
     rope_theta: float = 10000.0
     eps: float = 1e-5
     max_seq_len: int = 128
@@ -146,6 +152,11 @@ class LatentSparseMoEConfig:
         elif self.q_rank is None or self.index_topk is None:
             raise ValueError("an indexer reads the query bottleneck: "
                              "q_rank and index_topk are numbers")
+        if self.router_width % self.n_group \
+                or not 1 <= self.topk_group <= self.n_group:
+            raise ValueError(
+                f"{self.topk_group} of {self.n_group} groups over a router "
+                f"{self.router_width} wide")
         if self.cache_round not in ("none", "int8"):
             raise ValueError(f"unknown cache_round {self.cache_round!r}")
         if self.expert_round not in ("none", "float8_e4m3fn"):
@@ -187,8 +198,8 @@ def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
                    index_n_heads=0, index_head_dim=0, index_topk=None,
                    indexer_types=None, mlp_layer_types=None,
                    first_k_dense_replace=None, layers_from=0,
-                   experts_held_from=0, published=None,
-                   **same) -> LatentSparseMoEConfig:
+                   experts_held_from=0, published=None, n_group=1,
+                   topk_group=1, **same) -> LatentSparseMoEConfig:
     """The configuration from a published `config.json`'s own keys
     (`glm_moe_dsa`'s names, or `deepseek_v3`'s, which has no indexer: no
     `indexer_types`, and `first_k_dense_replace` leading dense layers in
@@ -217,34 +228,33 @@ def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
                                            n_routed_experts),
         experts_per_token=num_experts_per_tok, held_from=experts_held_from,
         held_count=n_routed_experts, routed_scale=routed_scaling_factor,
-        norm_topk=norm_topk_prob, eps=rms_norm_eps,
-        max_seq_len=max_position_embeddings, **same)
+        norm_topk=norm_topk_prob, n_group=n_group, topk_group=topk_group,
+        eps=rms_norm_eps, max_seq_len=max_position_embeddings, **same)
 
 
 # ---------------------------------------------------------------------------
 # the pool
 # ---------------------------------------------------------------------------
 
-def _served(cfg) -> None:
-    if not cfg.has_indexer:
-        raise NotImplementedError(
-            "layers without an indexer are trained, not served: this "
-            "family has no dense latent decode kernel yet")
+def latent_pool(cfg, n_layers: int, n_blocks: int, block_size: int):
+    """The latent rows of `n_layers` layers, zero-filled."""
+    return jnp.zeros((n_layers, n_blocks, block_size, 1, cfg.row_words),
+                     jnp.uint32)
 
 
 def init_pool(cfg: LatentSparseMoEConfig, n_blocks: int, block_size: int,
               mesh=None):
-    """{"latent", "index"}, zero-filled; blocks on axis 1 of both."""
-    _served(cfg)
+    """{"latent"} and, where layers own an indexer, {"index"}, zero-filled;
+    blocks on axis 1 of both."""
     if mesh is not None:
         raise ValueError("this family's pool is not sharded over a mesh")
-    n_full = sum(ix == "full" for _, ix in cfg.kinds)
-    return {
-        "latent": jnp.zeros((cfg.n_layers, n_blocks, block_size, 1,
-                             cfg.row_words), jnp.uint32),
-        "index": jnp.zeros((n_full, n_blocks, block_size, cfg.index_dim),
-                           cfg.activation_dtype()),
-    }
+    pool = {"latent": latent_pool(cfg, cfg.n_layers, n_blocks, block_size)}
+    if cfg.has_indexer:
+        n_full = sum(ix == "full" for _, ix in cfg.kinds)
+        pool["index"] = jnp.zeros(
+            (n_full, n_blocks, block_size, cfg.index_dim),
+            cfg.activation_dtype())
+    return pool
 
 
 def _stored(x, cfg):
@@ -381,16 +391,35 @@ def _sm_scale(cfg) -> float:
     return (cfg.nope_dim + cfg.rope_dim) ** -0.5
 
 
-def routing(h2, lp, cfg):
-    """-> (chosen [N, k] i32, weights [N, k] f32), in float32: a choice
-    between two near-equal scores should not turn on the activations'
-    rounding more than it must."""
+def kept_groups(biased, cfg):
+    """The group-limited choice's first half: biased scores [N, E] ->
+    bool [N, n_group], true at the `topk_group` groups whose two largest
+    scores sum highest."""
+    n = biased.shape[0]
+    best = jax.lax.top_k(biased.reshape(n, cfg.n_group, -1), 2)[0]
+    _, keep = jax.lax.top_k(jnp.sum(best, -1), cfg.topk_group)
+    return jnp.any(keep[..., None] == jnp.arange(cfg.n_group), axis=1)
+
+
+def router_scores(h2, lp):
+    """-> (sigmoid scores [N, E], scores + the expert bias), float32."""
     g = jax.nn.sigmoid(jnp.einsum(
         "nd,de->ne", h2.astype(jnp.float32),
         lp["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(g + lp["router_bias"].astype(jnp.float32),
-                              cfg.experts_per_token)
+    return g, g + lp["router_bias"].astype(jnp.float32)
+
+
+def routing(h2, lp, cfg):
+    """-> (chosen [N, k] i32, weights [N, k] f32), in float32: a choice
+    between two near-equal scores should not turn on the activations'
+    rounding more than it must."""
+    g, biased = router_scores(h2, lp)
+    if cfg.n_group > 1:
+        biased = jnp.where(jnp.repeat(
+            kept_groups(biased, cfg), cfg.router_width // cfg.n_group, 1),
+            biased, -jnp.inf)
+    _, chosen = jax.lax.top_k(biased, cfg.experts_per_token)
     weights = jnp.take_along_axis(g, chosen, -1)
     if cfg.norm_topk:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
@@ -463,8 +492,8 @@ def _counts(cfg, pos, live, expert_counts):
     that count."""
     n_full = sum(ix == "full" for _, ix in cfg.kinds)
     scanned = jnp.sum(jnp.where(live, pos + 1, 0))
-    selected = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, cfg.index_topk),
-                                 0))
+    selected = jnp.sum(jnp.where(
+        live, jnp.minimum(pos + 1, cfg.index_topk or 0), 0))
     experts = sum(expert_counts) if expert_counts else jnp.zeros(
         (2 + cfg.held_count,), jnp.int32)
     return jnp.concatenate([
@@ -509,6 +538,24 @@ def _select_dense(scores, valid, k: int):
     return valid & (above | (ties & (jnp.cumsum(ties, -1) <= need)))
 
 
+def attend_full(q_nope, q_rope, row, selected, lp, cfg):
+    """Expanded-head latent attention of a whole sequence over its own
+    rows [T, kv_rank + rope], masked to `selected` bool [T, T]:
+    -> [T, H, v] in the activation type."""
+    adt = cfg.activation_dtype()
+    c_kv, k_rope = row[:, :cfg.kv_rank], row[:, cfg.kv_rank:]
+    kv = jnp.einsum("sc,chd->shd", c_kv, _kv_up(lp, cfg, adt),
+                    preferred_element_type=jnp.float32).astype(adt)
+    s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :cfg.nope_dim],
+                    preferred_element_type=jnp.float32)
+         + jnp.einsum("qhd,kd->hqk", q_rope, k_rope,
+                      preferred_element_type=jnp.float32))
+    p = jax.nn.softmax(jnp.where(selected[None], s * _sm_scale(cfg),
+                                 NEG_INF), -1)
+    return jnp.einsum("hqk,khd->qhd", p.astype(adt), kv[..., cfg.nope_dim:],
+                      preferred_element_type=jnp.float32).astype(adt)
+
+
 def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
     """tokens [B, T] -> float32 logits [B, T, V], no cache: every layer
     dense over its own sequence, masked to the selection. With
@@ -532,18 +579,7 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
                 selected = _select_dense(scores, causal, cfg.index_topk)
                 if selections is not None:
                     selections.append(selected)
-            c_kv, k_rope = row[:, :cfg.kv_rank], row[:, cfg.kv_rank:]
-            kv = jnp.einsum("sc,chd->shd", c_kv, _kv_up(lp, cfg, adt),
-                            preferred_element_type=jnp.float32).astype(adt)
-            s = (jnp.einsum("qhd,khd->hqk", q_nope, kv[..., :cfg.nope_dim],
-                            preferred_element_type=jnp.float32)
-                 + jnp.einsum("qhd,kd->hqk", q_rope, k_rope,
-                              preferred_element_type=jnp.float32))
-            p = jax.nn.softmax(jnp.where(selected[None], s * _sm_scale(cfg),
-                                         NEG_INF), -1)
-            att = jnp.einsum("hqk,khd->qhd", p.astype(adt),
-                             kv[..., cfg.nope_dim:],
-                             preferred_element_type=jnp.float32).astype(adt)
+            att = attend_full(q_nope, q_rope, row, selected, lp, cfg)
             x = x + _mm(att.reshape(t, -1), lp["w_out"], adt)
             x, _ = _feed_forward(x, lp, cfg)
         return _unembed(_norm(x, params["final_ln_scale"], cfg), params, cfg)
@@ -814,6 +850,18 @@ def _prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
     return att.astype(adt).transpose(1, 0, 2).reshape(c, nh * cfg.v_dim)
 
 
+def every_earlier(positions, valid, s: int):
+    """The selection of a layer without an indexer: bool [C, S], a live
+    query's row true at every position up to its own."""
+    return (jnp.arange(s, dtype=jnp.int32)[None, :] <= positions[:, None]) \
+        & valid[:, None]
+
+
+def _pool_of(latent, index):
+    return {"latent": latent} if index is None else {
+        "latent": latent, "index": index}
+
+
 def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
             block_table, start, length=None):
     """One chunk of paged prefill of one sequence (`gpt.prefill_paged`'s
@@ -822,7 +870,6 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     through `block_table` before the layer attends. -> (logits [1, V] f32
     of the chunk's last real position, cache, counts)."""
     c = tokens.shape[1]
-    _served(cfg)
     if tokens.shape[0] != 1:
         raise ValueError(f"paged prefill wants tokens [1, C], got batch "
                          f"{tokens.shape[0]}")
@@ -836,9 +883,11 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     valid = offs < length
     widx = jnp.where(valid, table[positions // bs] * bs + positions % bs,
                      nb * bs)
-    latent, index = cache["latent"], cache["index"]
+    latent, index = cache["latent"], cache.get("index")
     x = params["embed"].astype(adt)[tokens[0]]
-    selected, full, expert_counts = None, 0, []
+    selected = None if cfg.has_indexer else every_earlier(
+        positions, valid, table.shape[0] * bs)
+    full, expert_counts = 0, []
     for i, lp in enumerate(params["layers"]):
         h = _norm(x, lp["attn_norm_scale"], cfg)
         q_nope, q_rope, row = _project(h, lp, positions, cfg)
@@ -858,8 +907,7 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
             expert_counts.append(counts)
     x = _norm(x, params["final_ln_scale"], cfg)
     last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    return (_unembed(last, params, cfg),
-            {"latent": latent, "index": index},
+    return (_unembed(last, params, cfg), _pool_of(latent, index),
             _counts(cfg, positions, valid, expert_counts))
 
 
@@ -880,6 +928,45 @@ def select_rows(scores, tables, pos, cfg, block_size: int):
     return rows.astype(jnp.int32), count, jnp.where(live, idx, -1)
 
 
+def decode_write_index(latent, tables, pos):
+    """Where a decode step's rows go: each stream's flat index of position
+    pos[b] in a layer of the latent pool through tables [B, max_blocks]
+    (past the table: dropped)."""
+    nb, bs = latent.shape[1], latent.shape[2]
+    mb = tables.shape[1]
+    blk = jnp.take_along_axis(
+        tables, jnp.minimum(pos // bs, mb - 1)[:, None], axis=1)[:, 0]
+    return jnp.where(pos < mb * bs, blk * bs + pos % bs, nb * bs)
+
+
+def decode_attend(q_nope, q_rope, latent, layer: int, tables, pos, lp, cfg,
+                  rows=None, count=None):
+    """Absorbed latent attention of one query a stream, q_nope [B, H, nope]
+    and q_rope [B, H, rope], over layer `layer` of the latent pool: over
+    the selected rows `rows[b, :count[b]]` (a layer's numbers within its
+    own layer), or with none given over every cached row of the stream's
+    pages up to pos[b]. -> [B, H, v] in the activation type."""
+    adt = cfg.activation_dtype()
+    nb, bs = latent.shape[1], latent.shape[2]
+    up = _kv_up(lp, cfg, adt)
+    q_abs = jnp.einsum("bhd,chd->bhc", q_nope, up[..., :cfg.nope_dim],
+                       preferred_element_type=jnp.float32).astype(adt)
+    q = sparse_latent.split_query(
+        jnp.concatenate([q_abs, q_rope], -1) * jnp.asarray(
+            _sm_scale(cfg), adt), cfg.row_words)
+    if rows is None:
+        out = sparse_latent.latent_decode(
+            q, latent, layer, tables, pos + 1, dtype=adt,
+            impl=cfg.sparse_impl)
+    else:
+        out = sparse_latent.sparse_latent_decode(
+            q, latent.reshape(-1, 1, cfg.row_words), rows + layer * nb * bs,
+            count, dtype=adt, impl=cfg.sparse_impl)
+    mixed = sparse_latent.join_parts(out, cfg.kv_rank).astype(adt)
+    return jnp.einsum("bhc,chd->bhd", mixed, up[..., cfg.nope_dim:],
+                      preferred_element_type=jnp.float32).astype(adt)
+
+
 def decode(params, tokens, cache, pos, tables,
            cfg: LatentSparseMoEConfig, mesh=None, selections=None):
     """One token for every slot (`gpt.decode_step_paged`'s contract):
@@ -888,18 +975,14 @@ def decode(params, tokens, cache, pos, tables,
     indexer selected in this step. Idle rows point their table at the
     trash block; they compute garbage nobody reads and count nothing.
     -> (logits [B, V] f32, cache, counts)."""
-    _served(cfg)
     adt = cfg.activation_dtype()
     nb, bs = cache["latent"].shape[1], cache["latent"].shape[2]
-    mb = tables.shape[1]
     b = tokens.shape[0]
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
-    blk = jnp.take_along_axis(
-        tables, jnp.minimum(pos // bs, mb - 1)[:, None], axis=1)[:, 0]
-    widx = jnp.where(pos < mb * bs, blk * bs + pos % bs, nb * bs)
+    widx = decode_write_index(cache["latent"], tables, pos)
     live = tables[:, 0] > 0
-    latent, index = cache["latent"], cache["index"]
+    latent, index = cache["latent"], cache.get("index")
     x = params["embed"].astype(adt)[tokens]
     rows = count = None
     full, expert_counts = 0, []
@@ -917,24 +1000,14 @@ def decode(params, tokens, cache, pos, tables,
             if selections is not None:
                 selections.append(idx)
             full += 1
-        up = _kv_up(lp, cfg, adt)
-        q_abs = jnp.einsum("bhd,chd->bhc", q_nope, up[..., :cfg.nope_dim],
-                           preferred_element_type=jnp.float32).astype(adt)
-        q = jnp.concatenate([q_abs, q_rope], -1) * jnp.asarray(
-            _sm_scale(cfg), adt)
-        out = sparse_latent.sparse_latent_decode(
-            sparse_latent.split_query(q, cfg.row_words),
-            latent.reshape(-1, 1, cfg.row_words), rows + i * nb * bs, count,
-            dtype=adt, impl=cfg.sparse_impl)
-        mixed = sparse_latent.join_parts(out, cfg.kv_rank).astype(adt)
-        att = jnp.einsum("bhc,chd->bhd", mixed, up[..., cfg.nope_dim:],
-                         preferred_element_type=jnp.float32).astype(adt)
+        att = decode_attend(q_nope, q_rope, latent, i, tables, pos, lp, cfg,
+                            rows, count)
         x = x + _mm(att.reshape(b, -1), lp["w_out"], adt)
         x, counts = _feed_forward(x, lp, cfg, live)
         if counts is not None:
             expert_counts.append(counts)
     x = _norm(x, params["final_ln_scale"], cfg)
-    return (_unembed(x, params, cfg), {"latent": latent, "index": index},
+    return (_unembed(x, params, cfg), _pool_of(latent, index),
             _counts(cfg, pos, live, expert_counts))
 
 

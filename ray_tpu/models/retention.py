@@ -19,8 +19,9 @@ the state form of the same numbers, the two kernels and the layout.
 **What the engine holds for this family**: one block a sequence, the
 state of every layer and key-value head (`s [L, blocks, Hkv, d, D]`,
 `z [L, blocks, Hkv, 1, D]`, float32), rewritten by every token. The
-family says so through `ServingFamily.state_blocks`; the engine then
-gives a request one block whatever its length and keeps no prefix tree.
+family says so through `ServingFamily.state_blocks` (1, and not
+`paged`); the engine then gives a request one block whatever its length
+and keeps no prefix tree.
 Prefill resets the block on a sequence's first chunk (`start == 0`),
 leaves it untouched by a chunk bucket's padding, and decode's idle rows
 (table 0) rewrite the trash block.
@@ -131,13 +132,14 @@ def init_params(key, cfg: RetentionConfig):
 # ---------------------------------------------------------------------------
 
 def init_pool(cfg: RetentionConfig, n_blocks: int, block_size: int,
-              mesh=None):
+              mesh=None, *, state_blocks: int | None = None):
     """{"s", "z"}, zero-filled float32; blocks on axis 1 of both, a block
-    one sequence's state. `block_size` (tokens a block of a paged family)
-    sizes nothing here."""
+    one sequence's state. The family holds no pages, so its one count is
+    of state blocks: `state_blocks` as the engine names it, `n_blocks`
+    for a caller that names no other; `block_size` sizes nothing."""
     if mesh is not None:
         raise ValueError("this family's pool is not sharded over a mesh")
-    shape = (cfg.n_layers, n_blocks, cfg.n_kv_heads)
+    shape = (cfg.n_layers, state_blocks or n_blocks, cfg.n_kv_heads)
     return {"s": jnp.zeros(shape + (cfg.head_dim, cfg.feature_dim),
                            jnp.float32),
             "z": jnp.zeros(shape + (1, cfg.feature_dim), jnp.float32)}
@@ -297,4 +299,5 @@ def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
     copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1)
+    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
+    paged=False, state_keys=("s", "z"))

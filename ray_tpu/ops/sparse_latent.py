@@ -59,9 +59,10 @@ NEG_INF = -1e30
 # section 3, lists them. Each call sits in a `named_scope` of its own
 # name: see flash_attention.py.
 SPARSE_LATENT_DECODE, INDEX_SCORES = "sparse_latent_decode", "index_scores"
+LATENT_DECODE = "latent_decode"
 
 LANES = 128
-ROW_CHUNK = 256         # selected rows a chunk of `sparse_latent_decode`
+ROW_CHUNK = 256         # cached rows a chunk of the two decode kernels
 INDEX_STEP_TOKENS = 512  # cached positions a grid step of `index_scores`
 
 
@@ -358,15 +359,69 @@ def reference_sparse_latent_decode(q, pool, rows, count, dtype):
                       preferred_element_type=jnp.float32)
 
 
-def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
-                   m_scr, l_scr, acc_scr, *, chunk: int, dtype):
-    b = pl.program_id(0)
-    n = count_ref[b]
-    n_chunks = (n + chunk - 1) // chunk
+def _attend_chunk(q_ref, words, c, n, m_scr, l_scr, acc_scr, dtype):
+    """One chunk of cached rows (uint32 `words` [chunk, words], the c-th
+    chunk of a stream's `n` live rows) into the online softmax that both
+    decode kernels keep: scores of every head against whole rows, the
+    running maximum and sum, and the weighted sum of the rows."""
+    chunk = words.shape[0]
     compute = jnp.bfloat16 if row_parts(dtype) == 2 else jnp.float32
+    parts = [p.astype(compute) for p in _parts_of(words, dtype)]
+    s = sum(jax.lax.dot_general(
+        q_ref[i, 0].astype(compute), part,
+        dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)
+        for i, part in enumerate(parts))                # [H, chunk]
+    col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    s = jnp.where(col < n, s, NEG_INF)
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    corr = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
+    m_scr[:, :1] = m_new
+    for i, part in enumerate(parts):
+        acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
+            p.astype(compute), part,
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)         # [H, words]
+
+
+def _online_decode(n, chunk, issue, wait, q_ref, o_ref, buf, m_scr, l_scr,
+                   acc_scr, dtype):
+    """The loop both decode kernels run for one stream: its `n` live rows
+    in chunks of `chunk`, double-buffered (`issue(c, slot)` starts chunk
+    c's DMAs into `buf[slot]`, `wait(slot)` waits for them), through the
+    online softmax, then the weighted sum of rows out."""
+    n_chunks = (n + chunk - 1) // chunk
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    @pl.when(n_chunks > 0)
+    def _first():
+        issue(0, 0)
+
+    def step(c, _):
+        slot = c % 2
+
+        @pl.when(c + 1 < n_chunks)
+        def _next():
+            issue(c + 1, 1 - slot)
+
+        wait(slot)
+        _attend_chunk(q_ref, buf[slot].reshape(chunk, buf.shape[-1]), c, n,
+                      m_scr, l_scr, acc_scr, dtype)
+        return _
+
+    jax.lax.fori_loop(0, n_chunks, step, 0)
+    for i in range(acc_scr.shape[0]):
+        o_ref[i, 0] = acc_scr[i] / jnp.maximum(l_scr[:, :1], 1e-30)
+
+
+def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
+                   m_scr, l_scr, acc_scr, *, chunk: int, dtype):
+    b = pl.program_id(0)
 
     def issue(c, slot):
         def eight(g, _):
@@ -383,44 +438,21 @@ def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
         pltpu.make_async_copy(pool_ref.at[pl.ds(0, chunk)], buf.at[slot],
                               sem.at[slot]).wait()
 
-    @pl.when(n_chunks > 0)
-    def _first():
-        issue(0, 0)
+    _online_decode(count_ref[b], chunk, issue, wait, q_ref, o_ref, buf,
+                   m_scr, l_scr, acc_scr, dtype)
 
-    def step(c, _):
-        slot = c % 2
 
-        @pl.when(c + 1 < n_chunks)
-        def _next():
-            issue(c + 1, 1 - slot)
-
-        wait(slot)
-        words = buf[slot].reshape(chunk, buf.shape[-1])
-        parts = [p.astype(compute) for p in _parts_of(words, dtype)]
-        s = sum(jax.lax.dot_general(
-            q_ref[i, 0].astype(compute), part,
-            dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-            for i, part in enumerate(parts))                # [H, chunk]
-        col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(col < n, s, NEG_INF)
-        m_prev = m_scr[:, :1]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new)
-        l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=1,
-                                                     keepdims=True)
-        m_scr[:, :1] = m_new
-        for i, part in enumerate(parts):
-            acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
-                p.astype(compute), part,
-                dimension_numbers=(((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)         # [H, words]
-        return _
-
-    jax.lax.fori_loop(0, n_chunks, step, 0)
-    for i in range(acc_scr.shape[0]):
-        o_ref[i, 0] = acc_scr[i] / jnp.maximum(l_scr[:, :1], 1e-30)
+def _decode_specs(parts: int, h: int, words: int, chunk: int):
+    """(the query's and the output's BlockSpec, the scratch) both decode
+    kernels take, after two prefetched scalars."""
+    block = pl.BlockSpec((parts, 1, h, words), lambda i, *_: (0, i, 0, 0))
+    return block, [
+        pltpu.VMEM((2, chunk, 1, words), jnp.uint32),
+        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((h, LANES), jnp.float32),          # m (col 0 used)
+        pltpu.VMEM((h, LANES), jnp.float32),          # l
+        pltpu.VMEM((parts, h, words), jnp.float32),   # acc
+    ]
 
 
 def _sparse_latent_decode_pallas(q, pool, rows, count, dtype):
@@ -429,24 +461,11 @@ def _sparse_latent_decode_pallas(q, pool, rows, count, dtype):
     chunk = min(ROW_CHUNK, _up(k, 8))
     if k % chunk:           # whole chunks: the tail points at row 0
         rows = jnp.pad(rows, ((0, 0), (0, _up(k, chunk) - k)))
+    block, scratch = _decode_specs(parts, h, words, chunk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec((parts, 1, h, words),
-                         lambda i, rw, ct: (0, i, 0, 0)),
-            pl.BlockSpec(memory_space=pl.ANY),
-        ],
-        out_specs=pl.BlockSpec((parts, 1, h, words),
-                               lambda i, rw, ct: (0, i, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((2, chunk, 1, words), jnp.uint32),
-            pltpu.SemaphoreType.DMA((2,)),
-            pltpu.VMEM((h, LANES), jnp.float32),          # m (col 0 used)
-            pltpu.VMEM((h, LANES), jnp.float32),          # l
-            pltpu.VMEM((parts, h, words), jnp.float32),   # acc
-        ],
-    )
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[block, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=block, scratch_shapes=scratch)
     with jax.named_scope(SPARSE_LATENT_DECODE):
         return pl.pallas_call(
             functools.partial(_sparse_kernel, chunk=chunk, dtype=dtype),
@@ -473,3 +492,86 @@ def sparse_latent_decode(q, pool, rows, count, *, dtype,
     if resolve_impl(impl) == "pallas":
         return _sparse_latent_decode_pallas(q, pool, rows, count, dtype)
     return reference_sparse_latent_decode(q, pool, rows, count, dtype)
+
+
+# ---------------------------------------------------------------------------
+# dense latent decode
+# ---------------------------------------------------------------------------
+
+def reference_latent_decode(q, pool, layer: int, tables, count, dtype):
+    """q [parts, B, H, words], pool [L, n_blocks, bs, 1, words] uint32,
+    tables [B, max_blocks] i32, count [B] i32 -> f32 [parts, B, H, words]:
+    `reference_sparse_latent_decode` over every row of the stream's
+    pages, the first `count[b]` live."""
+    bs = pool.shape[2]
+    at = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
+    rows = jnp.take(tables, at // bs, axis=1) * bs + at % bs
+    return reference_sparse_latent_decode(
+        q, pool[layer].reshape(-1, 1, pool.shape[-1]), rows, count, dtype)
+
+
+def _latent_kernel(tbl_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
+                   m_scr, l_scr, acc_scr, *, layer: int, pages: int,
+                   block_size: int, dtype):
+    b = pl.program_id(0)
+
+    def copy(c, slot, i):
+        # a page where it lies: its rows are contiguous, one DMA
+        return pltpu.make_async_copy(
+            pool_ref.at[layer, tbl_ref[b, c * pages + i]],
+            buf.at[slot, pl.ds(i * block_size, block_size)], sem.at[slot])
+
+    def issue(c, slot):
+        for i in range(pages):
+            copy(c, slot, i).start()
+
+    def wait(slot):
+        for i in range(pages):
+            copy(0, slot, i).wait()
+
+    _online_decode(count_ref[b], pages * block_size, issue, wait, q_ref,
+                   o_ref, buf, m_scr, l_scr, acc_scr, dtype)
+
+
+def _latent_decode_pallas(q, pool, layer: int, tables, count, dtype):
+    parts, b, h, words = q.shape
+    bs = pool.shape[2]
+    pages = max(1, ROW_CHUNK // bs)
+    tables = jnp.pad(tables, ((0, 0), (0, -tables.shape[1] % pages)))
+    block, scratch = _decode_specs(parts, h, words, pages * bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(b,),
+        in_specs=[block, pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=block, scratch_shapes=scratch)
+    with jax.named_scope(LATENT_DECODE):
+        return pl.pallas_call(
+            functools.partial(_latent_kernel, layer=layer, pages=pages,
+                              block_size=bs, dtype=dtype),
+            name=LATENT_DECODE,
+            out_shape=jax.ShapeDtypeStruct((parts, b, h, words),
+                                           jnp.float32),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",)),
+            interpret=backend.interpret(),
+        )(tables.astype(jnp.int32), count.astype(jnp.int32), q, pool)
+
+
+def latent_decode(q, pool, layer: int, tables, count, *, dtype,
+                  impl: str = "auto"):
+    """One query a stream over every cached row of its context: dense
+    absorbed latent attention.
+
+    q [parts, B, H, words]: `split_query` of the absorbed query, the
+    softmax scale folded in. pool [L, n_blocks, bs, 1, words] uint32: the
+    latent pool where it lies; `layer` (static) the layer read. tables
+    [B, max_blocks] i32: each stream's pages in order (0: the trash
+    block). count [B] i32: the stream's live rows, its first `count[b]`
+    positions. A stream's live pages are streamed whole by DMA, a chunk of
+    pages ahead of the one being scored, so the bytes scale with its
+    context; pages past it are never fetched.
+    -> f32 [parts, B, H, words] (`join_parts` puts it back in the row's
+    order)."""
+    if resolve_impl(impl) == "pallas":
+        return _latent_decode_pallas(q, pool, layer, tables, count, dtype)
+    return reference_latent_decode(q, pool, layer, tables, count, dtype)

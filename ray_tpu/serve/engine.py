@@ -14,11 +14,14 @@ below are that family's; `gpt.`'s names stand for them:
 - **paged allocation**: each request holds exactly the blocks its
   prompt + generation footprint needs (a 100-token chat no longer pins a
   4k-token row). `BlockAllocator` refcounts physical blocks; block 0 is
-  the trash block idle decode rows scatter into. A family may instead
-  keep a state of fixed size a sequence (`ServingFamily.state_blocks`,
-  `models/retention.py`): a request then holds that many blocks whatever
-  its length, under the same allocator, release, cancel and hand-off,
-  and the two points below do not apply to it.
+  the trash block idle decode rows scatter into. A family may keep a
+  state of fixed size a sequence instead of pages, or beside them
+  (`ServingFamily.state_blocks`, `models/retention.py`,
+  `models/linear_latent.py`): a request then holds that many state
+  blocks whatever its length and, where the family is `paged`, its pages
+  as well, under the same allocator, release, cancel and hand-off
+  (`_written_blocks` is the one footprint arithmetic); the two points
+  below do not apply to such a family.
 - **radix prefix sharing**: a host-side `RadixTree` maps token prefixes
   to cached blocks at block granularity. A repeated system prompt is
   prefilled ONCE; later requests admit by taking references on the
@@ -167,30 +170,44 @@ class BlockAllocator:
     (never handed out — idle decode rows scatter there), so a pool of
     ``n_blocks`` has ``n_blocks - 1`` usable blocks.
 
+    One space of ids for the two kinds of block a family may keep
+    (`models.family.ServingFamily`): ids ``1 .. n_state`` are state
+    blocks, the ids after them pages, each kind with a free list of its
+    own. A page's id less ``n_state`` is its index on axis 1 of the
+    pool's paged arrays; a state block's id is its index in the state
+    arrays.
+
     Invariants (asserted by `check()` and the fuzz tests): a block is
     either free with refcount 0 or allocated with refcount >= 1;
-    used + free == n_blocks - 1; decref of a free block (double free)
-    raises."""
+    used + free + free_state == n_blocks - 1; decref of a free block
+    (double free) raises."""
 
-    def __init__(self, n_blocks: int):
+    def __init__(self, n_blocks: int, n_state: int = 0):
         if n_blocks < 2:
             raise ValueError("need at least one usable block")
-        self.n_blocks = n_blocks
-        self._free = list(range(n_blocks - 1, 0, -1))   # pop() -> 1, 2…
+        self.n_blocks, self.n_state = n_blocks, n_state
+        self._free = list(range(n_blocks - 1, n_state, -1))  # pop() -> 1, 2…
+        self._free_state = list(range(n_state, 0, -1))
         self._ref = [0] * n_blocks
 
     @property
     def free(self) -> int:
+        """Free pages."""
         return len(self._free)
 
     @property
-    def used(self) -> int:
-        return (self.n_blocks - 1) - len(self._free)
+    def free_state(self) -> int:
+        return len(self._free_state)
 
-    def alloc(self) -> int:
-        if not self._free:
+    @property
+    def used(self) -> int:
+        return (self.n_blocks - 1) - len(self._free) - len(self._free_state)
+
+    def alloc(self, state: bool = False) -> int:
+        free = self._free_state if state else self._free
+        if not free:
             raise RuntimeError("out of KV cache blocks")
-        b = self._free.pop()
+        b = free.pop()
         self._ref[b] = 1
         return b
 
@@ -204,15 +221,17 @@ class BlockAllocator:
             raise RuntimeError(f"double free of block {block}")
         self._ref[block] -= 1
         if self._ref[block] == 0:
-            self._free.append(block)
+            (self._free_state if block <= self.n_state
+             else self._free).append(block)
 
     def refcount(self, block: int) -> int:
         return self._ref[block]
 
     def check(self):
-        assert self.used + self.free == self.n_blocks - 1
-        free = set(self._free)
-        assert len(free) == len(self._free), "free-list duplicate"
+        assert self.used + self.free + self.free_state == self.n_blocks - 1
+        free = set(self._free) | set(self._free_state)
+        assert len(free) == self.free + self.free_state, \
+            "free-list duplicate"
         for b in range(1, self.n_blocks):
             if b in free:
                 assert self._ref[b] == 0, f"free block {b} has refs"
@@ -484,9 +503,11 @@ class InferenceEngine:
     its `models.family.ServingFamily`); `slots` is the
     resident decode batch, `max_len` the per-sequence logical capacity
     (prompt + generated). `block_size` sets the paging granule and
-    `cache_blocks` the pool's usable block count (default: enough for
-    every slot at full length — shrink it to trade HBM for prefix-cache
-    churn). `prefill_chunk` caps prompt tokens absorbed per scheduler
+    `cache_blocks` the pool's usable pages (default: enough for every
+    slot at full length — shrink it to trade HBM for prefix-cache
+    churn); a family that also keeps state blocks gets one set of them
+    a slot besides, and one that keeps nothing else has `cache_blocks`
+    count those. `prefill_chunk` caps prompt tokens absorbed per scheduler
     tick while anything is decoding; `prefix_cache=False` disables the
     radix tree. All device work happens in `step()`."""
 
@@ -536,20 +557,32 @@ class InferenceEngine:
         self.num_slots = slots
         self.max_len = cfg.max_seq_len if max_len is None else max_len
         self.block_size = block_size
-        # A family whose block is a sequence's whole state says how many
-        # a sequence holds (`ServingFamily.state_blocks`); a block is
-        # then no range of tokens, and `block_size` sizes nothing.
+        # What a request holds (`ServingFamily`): `state_blocks` blocks
+        # of fixed size, a sequence's state, and where the family is
+        # `paged` a page a `block_size` tokens. A slot never needs more
+        # state blocks than its own, so there are slots x state_blocks
+        # of them; `cache_blocks` counts the pages (for a family without
+        # pages the state blocks, its only kind).
         self._state_blocks = fam.state_blocks
         if self._state_blocks and prefix_cache:
             raise ValueError(
-                f"prefix_cache=True: a block of {type(cfg).__name__}'s "
-                "family is a sequence's whole state, rewritten by every "
-                "token, so no block can be shared as a prefix; pass "
-                "prefix_cache=False")
-        self.max_blocks = self._state_blocks \
-            or -(-self.max_len // block_size)
-        self.cache_blocks = (slots * self.max_blocks
-                             if cache_blocks is None else cache_blocks)
+                f"prefix_cache=True: a request of {type(cfg).__name__}'s "
+                "family holds a sequence's state, rewritten by every "
+                "token, which names no range of tokens to share as a "
+                "prefix; pass prefix_cache=False")
+        max_pages = -(-self.max_len // block_size) if fam.paged else 0
+        self.max_blocks = self._state_blocks + max_pages
+        n_state = slots * self._state_blocks
+        if not fam.paged:
+            n_state, n_pages = (n_state if cache_blocks is None
+                                else cache_blocks), 0
+        else:
+            n_pages = (slots * max_pages if cache_blocks is None
+                       else cache_blocks)
+        self.cache_blocks = n_state + n_pages
+        # the largest footprint that can ever be admitted: a request's
+        # own state blocks and every page
+        self._max_footprint = self._state_blocks + n_pages
         self.buckets = tuple(sorted(
             b for b in (prefill_buckets or _default_buckets(self.max_len))
             if b <= self.max_len))
@@ -563,9 +596,10 @@ class InferenceEngine:
             {b for b in self.buckets if b < self.prefill_chunk}
             | {self.prefill_chunk}))
         # +1: physical block 0 is the trash block (idle rows write there).
-        self.cache = fam.init_pool(cfg, self.cache_blocks + 1, block_size,
-                                   mesh)
-        self._alloc = BlockAllocator(self.cache_blocks + 1)
+        self.cache = fam.init_pool(
+            cfg, n_pages + 1, block_size, mesh,
+            **({"state_blocks": n_state + 1} if n_state else {}))
+        self._alloc = BlockAllocator(self.cache_blocks + 1, n_state)
         self._tree = (RadixTree(block_size, self._alloc)
                       if prefix_cache else None)
         self._base_key = jax.random.PRNGKey(seed)
@@ -672,13 +706,13 @@ class InferenceEngine:
             int(leaf.nbytes) for leaf in jax.tree.leaves(
                 (self.params, self.draft_params if spec == "draft"
                  else None)))
-        # tokens a block stands for: a state block's share of a
-        # sequence of up to max_len
-        block_tokens = (self.max_len / self._state_blocks
-                        if self._state_blocks else block_size)
-        self._kv_bytes_per_token = (
-            sum(int(arr.nbytes) for arr in self.cache.values())
-            / ((self.cache_blocks + 1) * block_tokens))
+        # a page's bytes over its tokens, and a sequence's state blocks'
+        # over a sequence of up to max_len
+        self._kv_bytes_per_token = sum(
+            int(arr.nbytes) / arr.shape[1]
+            * (self._state_blocks / self.max_len
+               if key in fam.state_keys else 1 / block_size)
+            for key, arr in self.cache.items())
 
         def _sample(logits, temps, key, step):
             """Sample one token per row; also return the model's NATURAL
@@ -1101,9 +1135,42 @@ class InferenceEngine:
     # ------------------------------------------------------------------
 
     def _written_blocks(self, n: int) -> int:
-        """Blocks that hold a sequence's first `n` tokens: for a family
-        of state blocks the same whatever `n`."""
-        return self._state_blocks or (n - 1) // self.block_size + 1
+        """Blocks that hold a sequence's first `n` tokens: the family's
+        state blocks, the same whatever `n`, and where it is paged the
+        pages of `n` tokens. Every footprint is made of this."""
+        pages = (n - 1) // self.block_size + 1 if self._family.paged else 0
+        return self._state_blocks + pages
+
+    def _block_at(self, pool: dict, block: int):
+        """Block id -> (the pool's arrays of its kind, its index on
+        their axis 1): what the block moves are given."""
+        n_state = self._alloc.n_state
+        keys = self._family.state_keys
+        if block <= n_state:
+            return {k: pool[k] for k in keys}, block
+        return ({k: a for k, a in pool.items() if k not in keys},
+                block - n_state)
+
+    def _take_blocks(self, total: int, held: int = 0):
+        """`total - held` fresh blocks for a footprint of `total` of which
+        `held` pages are already in hand: the state blocks first, then
+        pages; None where either kind is short."""
+        ns = self._state_blocks
+        if self._alloc.free_state < ns or \
+                self._alloc.free < total - ns - held:
+            return None
+        return ([self._alloc.alloc(state=True) for _ in range(ns)]
+                + [self._alloc.alloc() for _ in range(total - ns - held)])
+
+    def _table_of(self, blocks: list) -> np.ndarray:
+        """A request's block table: its state blocks' ids, then each
+        page's index among the pages; 0 past its footprint."""
+        ns = self._state_blocks
+        table = np.zeros((self.max_blocks,), np.int32)
+        table[:ns] = blocks[:ns]
+        n_state = self._alloc.n_state
+        table[ns:len(blocks)] = [b - n_state for b in blocks[ns:]]
+        return table
 
     def _blocks_for(self, p: int, max_new: int) -> int:
         """Blocks a request's full footprint needs: prefill writes
@@ -1158,7 +1225,7 @@ class InferenceEngine:
                     f"{max_new_tokens} exceeds cache max_len "
                     f"{self.max_len}")
             n_blocks = self._slot_blocks_for(prompt.size, max_new_tokens)
-            if n_blocks > self.cache_blocks:
+            if n_blocks > self._max_footprint:
                 raise ValueError(
                     f"request footprint {n_blocks} blocks exceeds cache "
                     f"blocks {self.cache_blocks}")
@@ -1417,7 +1484,8 @@ class InferenceEngine:
         def _dump(pool, blocks):
             out = []
             for b in blocks[:n_written]:
-                blk = self._gather_fn(pool, np.int32(b))
+                arrays, at = self._block_at(pool, b)
+                blk = self._gather_fn(arrays, np.int32(at))
                 # graftlint: disable-next-line=R001,R004 the export IS the handoff's one deliberate device->host pull: the blob must be host bytes before it can ride netaddr to the decode replica
                 out.append({k: np.asarray(v) for k, v in blk.items()})
             return out
@@ -1527,7 +1595,7 @@ class InferenceEngine:
             raise ValueError(
                 f"handoff prompt {p} + max_new_tokens {max_new} "
                 f"exceeds cache max_len {self.max_len}")
-        if self._blocks_for(p, max_new) > self.cache_blocks:
+        if self._blocks_for(p, max_new) > self._max_footprint:
             raise ValueError(
                 f"handoff footprint {self._blocks_for(p, max_new)} "
                 f"blocks exceeds cache blocks {self.cache_blocks}")
@@ -1621,23 +1689,23 @@ class InferenceEngine:
         if self._alloc.free < fresh_needed and self._tree is not None:
             self._evicted_blocks += self._tree.evict(
                 fresh_needed - self._alloc.free)
-        if self._alloc.free < fresh_needed:
+        fresh = self._take_blocks(total, n_full)
+        if fresh is None:
             for b in blocks[:n_full]:
                 self._alloc.decref(b)
             return False
-        fresh = [self._alloc.alloc() for _ in range(fresh_needed)]
         slot_blocks = blocks[:n_full] + fresh
         jnp = self._jax.numpy
         t0 = time.perf_counter()
         scattered = 0
         for j in range(n_full, n_written):
-            self.cache = self._scatter_block_fn(
-                self.cache,
+            arrays, at = self._block_at(self.cache, slot_blocks[j])
+            self.cache = {**self.cache, **self._scatter_block_fn(
+                arrays,
                 {k: jnp.asarray(v) for k, v in payload[j].items()},
-                np.int32(slot_blocks[j]))
+                np.int32(at))}
             scattered += 1
-        table = np.zeros((self.max_blocks,), np.int32)
-        table[:len(slot_blocks)] = slot_blocks
+        table = self._table_of(slot_blocks)
         s = self._slots[slot_idx]
         s.rid, s.phase = rid, "decode"
         s.prompt, s.filled = prompt, p
@@ -1865,11 +1933,11 @@ class InferenceEngine:
         if self._alloc.free < fresh_needed and self._tree is not None:
             self._evicted_blocks += self._tree.evict(
                 fresh_needed - self._alloc.free)
-        if self._alloc.free < fresh_needed:
+        fresh = self._take_blocks(total, n_full)
+        if fresh is None:
             for b in blocks:
                 self._alloc.decref(b)
             return False
-        fresh = [self._alloc.alloc() for _ in range(fresh_needed)]
         slot_blocks = blocks[:n_full] + fresh
         if partial:
             # Copy-on-write: the matched prefix ends inside a shared
@@ -1880,8 +1948,7 @@ class InferenceEngine:
                                        np.int32(dst))
             self._cow_copies += 1
             self._alloc.decref(src)
-        table = np.zeros((self.max_blocks,), np.int32)
-        table[:len(slot_blocks)] = slot_blocks
+        table = self._table_of(slot_blocks)
         s = self._slots[slot_idx]
         s.rid, s.phase = req.rid, "prefill"
         s.prompt, s.filled = req.prompt, matched
@@ -2698,14 +2765,15 @@ class InferenceEngine:
         Paged cache:
           ``block_size`` / ``cache_blocks`` / ``blocks_in_use`` /
           ``blocks_free`` — pool geometry and live allocation.
-          For a family of state blocks (`ServingFamily.state_blocks`)
-          a block is one sequence's whole state: ``block_size`` is the
-          constructor's argument and sizes nothing, ``cache_blocks`` is
-          the sequences the pool holds, ``cache_block_utilization`` the
-          share of them in use, and ``kv_bytes_per_token`` the pool's
-          bytes over the `max_len` tokens a block may stand for (what
-          a token of capacity costs; a sequence costs a whole block at
-          any length).
+          For a family with state blocks (`ServingFamily.state_blocks`)
+          these count both kinds together, a sequence's state blocks and
+          its pages; ``state_blocks`` / ``state_blocks_in_use`` are the
+          state blocks' part (``blocks_free`` counts pages, or for a
+          family without pages the state blocks). A state block costs
+          the same at any length: ``kv_bytes_per_token`` is a page's
+          bytes a token plus a sequence's state over the `max_len`
+          tokens it may stand for. Without pages ``block_size`` is the
+          constructor's argument and sizes nothing.
           ``prefix_cache`` — whether a radix tree is kept (never for a
           family of state blocks).
           ``cached_prefix_blocks`` — blocks the radix tree holds.
@@ -2952,7 +3020,11 @@ class InferenceEngine:
                 "block_size": self.block_size,
                 "cache_blocks": self.cache_blocks,
                 "blocks_in_use": self._alloc.used,
-                "blocks_free": self._alloc.free,
+                "blocks_free": (self._alloc.free if self._family.paged
+                                else self._alloc.free_state),
+                "state_blocks": self._alloc.n_state,
+                "state_blocks_in_use": (self._alloc.n_state
+                                        - self._alloc.free_state),
                 "prefix_cache": self._tree is not None,
                 "cached_prefix_blocks": (self._tree.n_blocks()
                                          if self._tree else 0),

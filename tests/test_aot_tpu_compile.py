@@ -724,3 +724,104 @@ def test_retention_family_programs_compile_at_the_cells_shapes(topo, program):
     assert mem.temp_size_in_bytes < 1e9                 # and never copied
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# -- the KDA-and-latent family at ling-3.0-flash-vl.reason-closed96's
+# shapes: 64 slots and the trash block, six KDA layers of 32 heads of 128
+# around one latent layer with rows of 576 values in pages of 128, chunks
+# of 512 and 128, 128 held experts of a 512-wide router, vocabulary 39,296
+
+def _ling():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import linear_latent as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "ling-3.0-flash-vl.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+KB, KL, KNS, KH, KD = 64, 6, 65, 32, 128
+KPAGES, KBS, KMB, KWORDS = 5697, 128, 128, 384
+KSTATE = [((KL, KNS, KH, KD, KD), F32)]
+
+
+def _kda_chunk_case(c):
+    from ray_tpu.ops import kda
+    return (lambda q, k, v, g, beta, s, block, first, n: kda.kda_chunk(
+        q, k, v, g, beta, s, 3, block, first, n, impl="pallas"),
+        [((c, KH, KD), BF16)] * 3 + [((c, KH, KD), F32), ((c, KH), F32)]
+        + KSTATE + [((), I32)] * 3)
+
+
+def _kda_step_case():
+    from ray_tpu.ops import kda
+    return (lambda q, k, v, g, beta, s, blocks: kda.kda_step(
+        q, k, v, g, beta, s, 3, blocks, impl="pallas"),
+        [((KB, KH, KD), BF16)] * 3 + [((KB, KH, KD), F32), ((KB, KH), F32)]
+        + KSTATE + [((KB,), I32)])
+
+
+def _latent_decode_case():
+    return (lambda q, pool, tables, count: sparse_latent.latent_decode(
+        q, pool, 0, tables, count, dtype=BF16, impl="pallas"),
+        [((2, KB, KH, KWORDS), BF16), ((1, KPAGES, KBS, 1, KWORDS),
+                                       jnp.uint32),
+         ((KB, KMB), I32), ((KB,), I32)])
+
+
+HYBRID_KERNELS = {
+    "kda_chunk_512": (_kda_chunk_case(512), "kda_chunk"),
+    "kda_chunk_128": (_kda_chunk_case(128), "kda_chunk"),
+    "kda_step": (_kda_step_case(), "kda_step"),
+    "latent_decode": (_latent_decode_case(), "latent_decode"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(HYBRID_KERNELS))
+def test_hybrid_kernels_compile_under_their_names(topo, case):
+    (fn, args), name = HYBRID_KERNELS[case]
+    assert kernel_names(compiled_text(topo, fn, *args)) == [name]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/ling-3.0-flash-vl.json` as the engine jits them
+    (the pool donated): every kernel is there under its name, states,
+    tails and pages are updated in place (no copy of the pool among the
+    temporaries), and weights, pool and temporaries fit the chip."""
+    from ray_tpu.models import linear_latent
+    config, cfg, ref = _ling()
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(lambda: linear_latent.init_pool(
+        cfg, KPAGES, KBS, state_blocks=KNS)))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: linear_latent.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((KB,)), arg((KB,)),
+            arg((KB, 1 + KMB))).compile()
+        want = {"kda_step": KL, "latent_row_write": 1, "latent_decode": 1,
+                "experts_grouped": 6}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: linear_latent.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((1 + KMB,)), arg(()),
+            arg(())).compile()
+        want = {"kda_chunk": KL, "latent_row_write": 1,
+                "latent_row_gather": 1, "experts_grouped_prefill": 6}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 1e9                 # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)

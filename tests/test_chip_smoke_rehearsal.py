@@ -46,10 +46,22 @@ def test_serve_retention_phase_tiny_on_cpu():
     """The second family's part of the serve phase: an engine over
     `models/retention.py` in the phase's own process, float32 on the CPU
     (the plain paths), held to the definition."""
-    out = run("cs.serve_retention_phase(dict(cs.RETENTION_CFG, "
-              "head_dim=32, dtype='float32'), platform='cpu', streams=5, "
-              "prompt_lens=(100, 300), new_tokens=6, slots=3, seed=0)")
+    out = run("cs.serve_family_phase(cs.retention_case(dict("
+              "cs.RETENTION_CFG, head_dim=32, dtype='float32'), 0), "
+              "platform='cpu', streams=5, prompt_lens=(100, 300), "
+              "new_tokens=6, slots=3, seed=0)")
     assert '"phase": "serve_retention"' in out
+
+
+def test_serve_hybrid_phase_tiny_on_cpu():
+    """The third family's part: an engine over `models/linear_latent.py`
+    (a state block and latent pages a request) in the phase's own process,
+    float32 on the CPU (the plain paths), held to the definition."""
+    out = run("cs.serve_family_phase(cs.hybrid_case(dict(cs.HYBRID_CFG, "
+              "kda_head_dim=32, dtype='float32'), 0), platform='cpu', "
+              "streams=5, prompt_lens=(100, 300), new_tokens=6, slots=3, "
+              "seed=0)")
+    assert '"phase": "serve_hybrid"' in out
 
 
 def test_serve_load_phase_tiny_on_cpu():

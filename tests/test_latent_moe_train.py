@@ -230,12 +230,12 @@ def test_the_fused_dispatch_carries_the_counters_into_stats():
     assert stats["last_step"]["router_bias_abs_max"] == pytest.approx(0.004)
 
 
-def test_the_serving_entry_points_refuse_a_layer_without_an_indexer(params):
+def test_a_layer_without_an_indexer_has_a_pool_of_latent_rows_alone(params):
+    """Served since the dense latent decode exists
+    (`tests/test_linear_latent.py` streams it through the engine): its
+    pool holds no index keys."""
     cfg = config()
-    with pytest.raises(NotImplementedError, match="trained, not served"):
-        lsm.init_pool(cfg, 8, 16)
-    with pytest.raises(NotImplementedError, match="trained, not served"):
-        lsm.decode(params, None, None, None, None, cfg)
+    assert set(lsm.init_pool(cfg, 8, 16)) == {"latent"}
     with pytest.raises(ValueError, match="do not mix"):
         lsm.LatentSparseMoEConfig(indexer_types=("full", "none", "none"))
     with pytest.raises(ValueError, match="unknown expert_round"):

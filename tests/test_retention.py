@@ -300,10 +300,14 @@ def test_a_request_holds_one_block_and_the_counters_say_so(params):
 
 def test_only_a_family_of_state_blocks_says_so():
     from ray_tpu.models import gpt, latent_sparse_moe
-    assert retention.FAMILY.state_blocks == 1
-    assert gpt.GPTConfig().family.state_blocks is None
-    assert latent_sparse_moe.FAMILY.state_blocks is None
-    assert ServingFamily._fields[-1] == "state_blocks"
+    fam = retention.FAMILY
+    assert (fam.state_blocks, fam.paged, fam.state_keys) == (
+        1, False, ("s", "z"))
+    for other in (gpt.GPTConfig().family, latent_sparse_moe.FAMILY):
+        assert (other.state_blocks, other.paged, other.state_keys) == (
+            0, True, ())
+    assert ServingFamily._fields[-3:] == ("state_blocks", "paged",
+                                          "state_keys")
 
 
 def test_a_prefix_cache_is_refused(params):
