@@ -34,7 +34,12 @@ below are that family's; `gpt.`'s names stand for them:
   chunks (bucketized, one compile per chunk bucket) interleaved between
   decode steps — when any sequence is decoding, a tick runs at most ONE
   chunk, so a long admission never stalls in-flight streams for more
-  than one chunk's worth of work.
+  than one chunk's worth of work. That chunk goes into the device's
+  queue BEHIND the tick's decode step: the host builds it while the
+  device decodes and emits the step's tokens while the device prefills,
+  and reads nothing before both programs are in flight. The sequence
+  whose prompt ends in such a chunk emits its first token in that tick
+  and takes its first decode step in the next.
 - **decode** advances ALL slots one token per call through a single
   jitted, pool-donating wrapper around `gpt.decode_step_paged` —
   compiled exactly once for the engine's lifetime (asserted in tests
@@ -84,7 +89,8 @@ below are that family's; `gpt.`'s names stand for them:
   across shared-prefix/COW admissions and both spec-decode backends.
 
 Sampling (greedy + temperature) runs inside the jitted functions, as
-before. `step()` is the one scheduler tick (admit, chunk, decode);
+before. `step()` is the one scheduler tick (admit, then decode step and
+chunk overlapped where the tick holds both, else chunks, then the step);
 `submit()` / `tokens_for()` / `cancel()` are the request-side API. A
 consumer that stops iterating `tokens_for` releases its request's
 blocks and queues automatically (generator finalization cancels it).
@@ -493,6 +499,19 @@ class _Slot:
     @property
     def active(self) -> bool:
         return self.phase != "idle"
+
+
+@dataclass
+class _ChunkInFlight:
+    """A prompt chunk between `_start_chunk` and `_finish_chunk`."""
+    tok: object                   # device scalar; an int once read
+    lp: object
+    counts: object
+    tokens: int
+    bucket: int
+    span: object                  # its `engine/prefill_chunk`
+    overlapped: bool              # enqueued behind a decode step
+    enqueued: float = 0.0         # perf_counter at the span's end
 
 
 class InferenceEngine:
@@ -965,6 +984,10 @@ class InferenceEngine:
         self._prefill_tokens = 0
         self._decode_tokens = 0
         self._prefill_chunks = 0
+        # chunks enqueued behind a decode step of their tick, and their
+        # time from the end of `engine/prefill_chunk` to the token's read
+        self._chunks_overlapped = 0
+        self._chunk_tail_s = 0.0
         self._prefix_hit_tokens = 0
         self._prompt_tokens = 0
         self._cow_copies = 0
@@ -1467,7 +1490,7 @@ class InferenceEngine:
 
     def _export_handoff(self, slot_idx: int):
         """Prefill-role endgame for one slot (under the lock, called
-        from `_run_prefill_chunk` the tick the prompt completes): gather
+        from `_finish_chunk` the tick the prompt completes): gather
         every written KV block — payload and any int8 scale rows travel
         together, block-aligned — to host, park the blob for collection,
         and free the device blocks. The blob carries everything a
@@ -2160,45 +2183,42 @@ class InferenceEngine:
             del self._class_pass[c]
         return admitted
 
-    def _run_prefill_chunk(self, slot_idx: int):
+    def _start_chunk(self, slot_idx: int,
+                     overlapped: bool = False) -> "_ChunkInFlight | None":
+        """Build and enqueue the next chunk of a slot's prompt (and the
+        draft pool's, where there is one). Alone in the tick
+        (`overlapped` false) the chunk's token is read here, inside
+        `engine/prefill_chunk`, which build, enqueue and wait then tile;
+        behind a decode step of the same tick the span ends with the
+        enqueue and `_finish_chunk` does the reading, once the step's
+        tokens are out. None where the main pool holds the whole prompt
+        already and only the draft pool catches up."""
         jnp = self._jax.numpy
         s = self._slots[slot_idx]
+        flight = None
         if s.filled < s.prompt.size:
             clen = min(self.prefill_chunk, s.prompt.size - s.filled)
             cap = self._chunk_bucket_for(clen)
             phase = self._phases.phase
-            # build, enqueue and wait tile the chunk's span: the host's
-            # work before the program can start, the call, and the host
-            # blocked until the chunk's token is back
-            with phase("engine/prefill_chunk", tokens=clen,
-                       bucket=cap) as chunk:
+            with phase("engine/prefill_chunk", tokens=clen, bucket=cap,
+                       overlapped=int(overlapped)) as chunk:
                 with phase("engine/prefill_build"):
                     toks = np.zeros((1, cap), np.int32)
                     toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
                     toks, table = jnp.asarray(toks), jnp.asarray(s.table)
+                    # keyed by the count before this tick's decode step,
+                    # wherever in the tick the chunk is enqueued
                     scalars = (np.int32(s.filled), np.int32(clen),
                                np.float32(s.temperature), self._base_key,
                                np.int32(self._decode_steps))
                 with phase("engine/prefill_dispatch"):
                     tok, lp, self.cache, counts = self._prefill_fn(
                         self.params, toks, self.cache, table, *scalars)
-                with phase("engine/prefill_sync"):
-                    # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: the first token must reach the host to park on the slot, and syncing here keeps the prefill timing honest
-                    tok = int(tok)    # device sync, so the timing is honest
-            self._add_counts(counts)
-            self._recorder.on_prefill_chunk(s.rid, clen, cap,
-                                            chunk.seconds)
-            self._prefill_tokens += clen
-            self._prefill_chunks += 1
-            s.filled += clen
-            if s.filled >= s.prompt.size:
-                # Park the first generated token (with its logprob and
-                # compute-time version) until the draft cache (if any)
-                # catches up and the slot joins decode.
-                s.token = tok
-                # graftlint: disable-next-line=R001,R004 lp is already on host after the int(tok) sync above; float() here is a cast, not a new device round-trip
-                s.token_logp = float(lp)
-                s.token_ver = self._params_version
+                flight = _ChunkInFlight(tok, lp, counts, clen, cap, chunk,
+                                        overlapped)
+                if not overlapped:
+                    self._read_chunk_token(flight)
+            flight.enqueued = time.perf_counter()
         # Draft-model backend: the draft pool has no prefix sharing, so
         # it absorbs the FULL prompt through its own chunk loop — one
         # draft chunk per tick, alongside the main chunk. No host sync:
@@ -2218,6 +2238,45 @@ class InferenceEngine:
                     self.draft_cache, jnp.asarray(s.draft_table),
                     np.int32(s.draft_filled), np.int32(dclen))
             s.draft_filled += dclen
+        return flight
+
+    def _read_chunk_token(self, flight: "_ChunkInFlight") -> None:
+        with self._phases.phase("engine/prefill_sync"):
+            # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: its token must reach the host to park on the slot, and the tick ends with no result unread. A chunk alone in its tick waits at once, inside engine/prefill_chunk, which keeps the prefill timing honest; a chunk behind a decode step waits after engine/emit, so its build and the emit lie under device time and the tick's two waits never overlap
+            flight.tok = int(np.asarray(flight.tok))
+
+    def _finish_chunk(self, slot_idx: int,
+                      flight: "_ChunkInFlight | None") -> None:
+        """The rest of a chunk `_start_chunk` enqueued: the wait for its
+        token where that is still out, the counts, `filled`, and at the
+        prompt's end the tree insert, the slot's turn to `decode` (or
+        its hand-off) and the first token's `_emit`."""
+        s = self._slots[slot_idx]
+        if flight is not None:
+            seconds = flight.span.seconds
+            if flight.overlapped:
+                self._read_chunk_token(flight)
+                # the chunk's time runs on past its span, to this read
+                tail = time.perf_counter() - flight.enqueued
+                self._chunk_tail_s += tail
+                seconds += tail
+                self._chunks_overlapped += 1
+            clen = flight.tokens
+            self._add_counts(flight.counts)
+            self._recorder.on_prefill_chunk(s.rid, clen, flight.bucket,
+                                            seconds)
+            self._prefill_tokens += clen
+            self._prefill_chunks += 1
+            s.filled += clen
+            if s.filled >= s.prompt.size:
+                # Park the first generated token (with its logprob and
+                # compute-time version) until the draft cache (if any)
+                # catches up and the slot joins decode.
+                s.token = flight.tok
+                # lp came with the token `_read_chunk_token` waited
+                # for: a cast, not a second round-trip
+                s.token_logp = float(flight.lp)
+                s.token_ver = self._params_version
         if s.filled < s.prompt.size or (
                 self._draft_alloc is not None
                 and s.draft_filled < s.prompt.size):
@@ -2256,21 +2315,56 @@ class InferenceEngine:
             self._recorder.on_first_token(s.rid, wait)
         self._emit(s, slot_idx, s.token, s.token_logp, s.token_ver)
 
+    def _decoding(self) -> list:
+        return [i for i, s in enumerate(self._slots)
+                if s.phase == "decode"]
+
+    def _next_prefilling(self) -> int | None:
+        """The prefilling slot admitted first: whose chunk runs next."""
+        return min((i for i, s in enumerate(self._slots)
+                    if s.phase == "prefill"),
+                   key=lambda i: self._slots[i].order, default=None)
+
     def _prefill_tick(self, had_decoders: bool) -> bool:
-        """Run prefill chunks: at most ONE while anything is decoding
-        (the per-tick admission budget that bounds decode stall); drain
-        freely when the engine is otherwise idle — nobody is waiting."""
+        """The chunks of a tick whose decode step, if it has one, comes
+        after them: each is built, enqueued and waited for before the
+        next. With nothing decoding they drain freely (the ramp, an idle
+        engine, a `role="prefill"` engine: nobody is waiting); at most
+        ONE where the tick began with decoders and admission took them
+        all. A tick that holds decoders and a prefilling slot does not
+        come here: `_decode_with_chunk`."""
         did = False
-        while True:
-            prefilling = [i for i, s in enumerate(self._slots)
-                          if s.phase == "prefill"]
-            if not prefilling:
-                return did
-            prefilling.sort(key=lambda i: self._slots[i].order)
-            self._run_prefill_chunk(prefilling[0])
+        while (slot_idx := self._next_prefilling()) is not None:
+            self._finish_chunk(slot_idx, self._start_chunk(slot_idx))
             did = True
             if had_decoders:
-                return did
+                break
+        return did
+
+    def _decode_with_chunk(self, decoding: list, slot_idx: int) -> float:
+        """The tick that holds decoders and a prefilling slot: the decode
+        step (or the speculative tick's programs) is enqueued first, the
+        slot's chunk is built and enqueued while the device runs it, and
+        only then is anything read: the step's tokens, which are emitted
+        while the device runs the chunk, then the chunk's token. Both
+        programs donate and return `self.cache`, so the device runs them
+        in that order with no gap; nothing of the step reads what the
+        chunk writes, so the order changes no stream's tokens. A slot
+        whose prompt ends here emits its first token in this tick and
+        joins the decode batch of the next. Returns the seconds the
+        chunk kept the tick open past the step's emit."""
+        flights = []
+        tick = self._decode_tick if self.spec is None else self._spec_tick
+        try:
+            tick(decoding, enqueued=lambda: flights.append(
+                self._start_chunk(slot_idx, overlapped=True)))
+        finally:
+            # whatever the emit raised (fault site `engine.emit`), no
+            # program's result is left unread when the tick ends
+            t_emitted = time.perf_counter()
+            if flights:
+                self._finish_chunk(slot_idx, flights[0])
+        return time.perf_counter() - t_emitted
 
     def _emit(self, s: _Slot, slot_idx: int, tok: int,
               logp: float = 0.0, ver: int | None = None):
@@ -2311,9 +2405,18 @@ class InferenceEngine:
 
     def step(self) -> bool:
         """One scheduler tick: admit pending requests into free slots,
-        run at most one prefill chunk if anything is decoding (all
-        pending prefill work otherwise), then one decode step for every
-        resident sequence. Returns True if any device work happened."""
+        then the tick's device work, in the order that what it holds
+        allows. With sequences decoding and a prompt to absorb, the
+        decode step is enqueued first, ONE prefill chunk is built and
+        enqueued while the device runs it, and only then are the step's
+        tokens waited for and emitted, and after them the chunk's
+        (`_decode_with_chunk`). With nothing decoding, every pending
+        chunk runs, each waited for, and the sequences that thereby
+        start decoding take one step in the same tick; with nothing to
+        prefill, the step alone. Whatever the order, no program's result
+        is unread when the tick ends: `update_params`, `cancel`,
+        preemption and the watchdog find the engine between ticks with
+        nothing in flight. Returns True if any device work happened."""
         with self._lock:
             t_tick = time.perf_counter()
             # watchdog window: seq first, then start ts, cleared in the
@@ -2347,24 +2450,41 @@ class InferenceEngine:
                         imported = self._admit_imports()
                         admitted = self._admit_pending() or imported
                         admit.set(admitted=self._admit_seq - seq)
-                    chunked = self._prefill_tick(had_decoders)
-                    if had_decoders and (admitted or chunked):
-                        self._max_admission_stall = max(
-                            self._max_admission_stall,
-                            time.perf_counter() - t_tick)
-                    active = [i for i, s in enumerate(self._slots)
-                              if s.active]
-                    self._occupancy.append(len(active) / self.num_slots)
+                    t_admitted = time.perf_counter()
+                    decoding = self._decoding()
+                    slot_idx = self._next_prefilling()
+                    # the order comes from what the tick holds: with
+                    # decoders and a prompt to absorb, the step goes
+                    # first and the chunk behind it; else as ever,
+                    # chunks then the step
+                    overlap = bool(had_decoders and decoding
+                                   and slot_idx is not None)
+                    chunked = overlap or self._prefill_tick(had_decoders)
+                    if not overlap:
+                        # a prompt that ended in a chunk already waited
+                        # for joins this tick's step
+                        decoding = self._decoding()
+                        if had_decoders and (admitted or chunked):
+                            self._max_admission_stall = max(
+                                self._max_admission_stall,
+                                time.perf_counter() - t_tick)
+                    active = sum(s.active for s in self._slots)
+                    self._occupancy.append(active / self.num_slots)
                     self._block_util.append(
                         self._alloc.used / max(self.cache_blocks, 1))
-                    decoding = [i for i, s in enumerate(self._slots)
-                                if s.phase == "decode"]
                     tick.set(decoding=len(decoding),
-                             prefilling=len(active) - len(decoding))
+                             prefilling=active - len(decoding))
                     if not decoding:  # idle, or admissions finished early
                         self._sentinel.check()
                         return admitted or chunked
-                    if self.spec is not None:
+                    if overlap:
+                        # admission held this step up, the chunk's end
+                        # the next
+                        self._max_admission_stall = max(
+                            self._max_admission_stall,
+                            t_admitted - t_tick
+                            + self._decode_with_chunk(decoding, slot_idx))
+                    elif self.spec is not None:
                         self._spec_tick(decoding)
                     else:
                         self._decode_tick(decoding)
@@ -2436,7 +2556,10 @@ class InferenceEngine:
                               self._dev("tables", tables),
                               self._dev("temps", temps))
 
-    def _decode_tick(self, decoding: list, inputs=None):
+    def _decode_tick(self, decoding: list, inputs=None, enqueued=None):
+        """One decode step for every slot. `enqueued`, where given, is
+        called once the step is in the device's queue and before its
+        tokens are waited for (`_decode_with_chunk`)."""
         phase = self._phases.phase
         if inputs is None:      # the spec tick's fallback built them
             _, inputs = self._decode_inputs()
@@ -2444,6 +2567,8 @@ class InferenceEngine:
             nxt, lps, self.cache, counts = self._decode_fn(
                 self.params, self.cache, *inputs, self._base_key,
                 np.int32(self._decode_steps))
+        if enqueued is not None:
+            enqueued()
         with phase("engine/token_sync") as sync:
             # graftlint: disable-next-line=R001,R004 the decode tick IS the scheduler's unit of work: it must sync on the sampled tokens to route them, and the lock is held for exactly one tick by design
             nxt = np.asarray(nxt)    # device sync
@@ -2487,12 +2612,14 @@ class InferenceEngine:
                     return h[i + n:i + n + self.spec_k]
         return None
 
-    def _spec_tick(self, decoding: list):
+    def _spec_tick(self, decoding: list, enqueued=None):
         """One speculative device step: propose (n-gram host lookup or
         one jitted draft-model scan), verify the whole window in ONE
         batched target forward, emit `accepted + 1` tokens per slot.
         Falls back to the plain decode step when nothing is worth
-        speculating on, so both paths stay compiled-exactly-once."""
+        speculating on, so both paths stay compiled-exactly-once.
+        `enqueued` as in `_decode_tick`: called once verify (or the
+        fallback's step) is in the device's queue."""
         W = self.spec_window
         phase = self._phases.phase
         # Slots one token from retiring can't use speculation (and, for
@@ -2532,7 +2659,7 @@ class InferenceEngine:
                 for i in worth:
                     proposals[i] = drafts[i].tolist()
         if not proposals:
-            self._decode_tick(decoding, inputs)
+            self._decode_tick(decoding, inputs, enqueued)
             return
         window = np.concatenate([tokens[:, None], drafts], axis=1)
         with phase("engine/verify_dispatch") as verify:
@@ -2540,6 +2667,8 @@ class InferenceEngine:
                 self.params, self.cache, self._dev("window", window),
                 d_pos, d_tables, d_temps, self._base_key,
                 np.int32(self._decode_steps))
+        if enqueued is not None:
+            enqueued()
         with phase("engine/token_sync") as sync:
             # graftlint: disable-next-line=R001,R004 the spec tick's one deliberate sync: accepted tokens must reach the host to emit; replaces W plain-tick syncs
             out, acc = np.asarray(out), np.asarray(acc)   # device sync
@@ -2692,7 +2821,8 @@ class InferenceEngine:
             self._tick_gaps = self._pump_handoffs = 0
             self._tick_gap_s = self._tick_gap_max_s = 0.0
             self._recorder.deliver_waits.clear()
-            self._prefill_chunks = 0
+            self._prefill_chunks = self._chunks_overlapped = 0
+            self._chunk_tail_s = 0.0
             self._prefix_hit_tokens = self._prompt_tokens = 0
             self._cow_copies = self._evicted_blocks = 0
             self._cancelled = 0
@@ -2741,10 +2871,18 @@ class InferenceEngine:
           ``decode_steps`` — device decode/verify ticks since reset.
           ``prefill_tokens`` / ``decode_tokens`` — tokens absorbed /
           emitted since reset; ``prefill_time_s`` / ``decode_time_s``
-          the device time attributed to each: the totals of the
-          prefill-chunk spans, and of the decode/verify dispatch,
-          propose and token-sync spans (below).
-          ``prefill_chunks`` — chunked-admission device calls.
+          the device time attributed to each: what the recorder is told
+          each chunk took, from the start of its build to the read of
+          its token (the prefill-chunk spans, and for a chunk enqueued
+          behind a decode step the time from its span's end to that
+          read, which the step's wait and emit share), and the totals of
+          the decode/verify dispatch, propose and token-sync spans
+          (below).
+          ``prefill_chunks`` — chunked-admission device calls;
+          ``chunks_overlapped`` — those enqueued behind a decode step of
+          the same tick and built while the device ran it (their
+          `engine/prefill_chunk` carries ``overlapped=1``): every chunk
+          of a tick that held a decoder, none of a tick that held none.
           ``slot_occupancy`` — mean fraction of slots active per tick.
           ``p50_token_latency_ms`` / ``p99_token_latency_ms`` — decode
           step-time percentiles over a 512-tick window.
@@ -2830,11 +2968,13 @@ class InferenceEngine:
           `engine/decode_put`, inside it: the four puts alone.
           ``prefill_build_s`` / ``prefill_dispatch_s`` /
           ``prefill_sync_s`` — `engine/prefill_build`,
-          `engine/prefill_dispatch`, `engine/prefill_sync`: the three
-          parts that tile `engine/prefill_chunk` (``prefill_time_s``):
-          the chunk's host arrays and their puts, enqueueing the
-          program, and the host blocked until the chunk's token is back
-          (the device's time shows here).
+          `engine/prefill_dispatch`, `engine/prefill_sync`: the chunk's
+          host arrays and their puts, enqueueing the program, and the
+          host blocked until the chunk's token is back (the device's
+          time shows here). Alone in its tick the three tile
+          `engine/prefill_chunk`; behind a decode step the span holds
+          the first two and the wait comes after `engine/emit`, so the
+          two waits of a tick never overlap.
           ``decode_dispatch_s`` — `engine/decode_dispatch` and
           `engine/verify_dispatch`: enqueueing the decode/verify step.
           ``token_sync_s`` — `engine/token_sync`: waiting for the
@@ -3006,13 +3146,15 @@ class InferenceEngine:
                 "prefill_tokens": self._prefill_tokens,
                 "decode_tokens": self._decode_tokens,
                 "prefill_time_s": ph.seconds(
-                    "engine/prefill_chunk", "engine/draft_prefill_chunk"),
+                    "engine/prefill_chunk", "engine/draft_prefill_chunk")
+                + self._chunk_tail_s,
                 "decode_time_s": ph.seconds(
                     "engine/decode_dispatch", "engine/verify_dispatch",
                     "engine/propose", "engine/token_sync"),
                 "prefill_traces": self.prefill_traces,
                 "decode_traces": self.decode_traces,
                 "prefill_chunks": self._prefill_chunks,
+                "chunks_overlapped": self._chunks_overlapped,
                 "slot_occupancy": (sum(occ) / len(occ)) if occ else 0.0,
                 "p50_token_latency_ms": pct(50),
                 "p99_token_latency_ms": pct(99),

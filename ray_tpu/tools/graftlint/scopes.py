@@ -44,8 +44,11 @@ HOT_SCOPES: dict[str, frozenset[str]] = {
         "InferenceEngine._try_admit",
         "InferenceEngine._admit_pending",
         "InferenceEngine._batch_arrays",
-        "InferenceEngine._run_prefill_chunk",
+        "InferenceEngine._start_chunk",
+        "InferenceEngine._read_chunk_token",
+        "InferenceEngine._finish_chunk",
         "InferenceEngine._prefill_tick",
+        "InferenceEngine._decode_with_chunk",
         "InferenceEngine._decode_tick",
         "InferenceEngine._spec_tick",
         "InferenceEngine._emit",

@@ -145,26 +145,10 @@ def test_hooks_open_their_spans_only_when_set():
 
 # -- what a profiler trace holds, Python tracer off -----------------------------
 
-@pytest.fixture(scope="module")
-def traced(tmp_path_factory):
-    """One CPU `jax.profiler` session over a train run and an engine
-    run: {span name: [(thread, start_ns, end_ns, stats), ...]}."""
+def program_spans(out):
+    """The trace under `out` and its program spans:
+    {span name: [(thread, start_ns, end_ns, stats), ...]}."""
     from jax.profiler import ProfileData
-    eng = tiny_engine()
-    warm = eng.submit([5, 9, 3], max_new_tokens=2)      # compile outside
-    list(eng.tokens_for(warm))
-    eng.reset_stats()
-    out = str(tmp_path_factory.mktemp("trace"))
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    options.host_tracer_level = 2
-    jax.profiler.start_trace(out, profiler_options=options)
-    try:
-        tl, batches, _ = train_run(dispatches=3, unroll=2)
-        rid = eng.submit([7, 1, 2, 4], max_new_tokens=4)
-        tokens = list(eng.tokens_for(rid))
-    finally:
-        jax.profiler.stop_trace()
     path, = glob.glob(os.path.join(out, "plugins", "profile", "*",
                                    "*.xplane.pb"))
     events = {}
@@ -177,6 +161,33 @@ def traced(tmp_path_factory):
                     events.setdefault(ev.name, []).append(
                         (line.name, ev.start_ns,
                          ev.start_ns + ev.duration_ns, dict(ev.stats)))
+    return path, events
+
+
+def start_trace(out):
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    options.host_tracer_level = 2
+    jax.profiler.start_trace(out, profiler_options=options)
+
+
+@pytest.fixture(scope="module")
+def traced(tmp_path_factory):
+    """One CPU `jax.profiler` session over a train run and an engine
+    run: {span name: [(thread, start_ns, end_ns, stats), ...]}."""
+    eng = tiny_engine()
+    warm = eng.submit([5, 9, 3], max_new_tokens=2)      # compile outside
+    list(eng.tokens_for(warm))
+    eng.reset_stats()
+    out = str(tmp_path_factory.mktemp("trace"))
+    start_trace(out)
+    try:
+        tl, batches, _ = train_run(dispatches=3, unroll=2)
+        rid = eng.submit([7, 1, 2, 4], max_new_tokens=4)
+        tokens = list(eng.tokens_for(rid))
+    finally:
+        jax.profiler.stop_trace()
+    path, events = program_spans(out)
     return {"events": events, "engine": eng, "loop": tl, "tokens": tokens,
             "path": path}
 
@@ -247,6 +258,71 @@ def test_the_chunk_is_tiled_by_three_spans_and_the_puts_lie_in_the_build(
     assert len(puts) == len(builds) == traced["engine"].stats()[
         "decode_steps"]
     assert all(inside(p, builds) for p in puts)
+
+
+@pytest.fixture(scope="module")
+def traced_beside_a_decoder(tmp_path_factory):
+    """A second session: a prompt of three chunks absorbed while another
+    stream decodes, so every chunk shares its tick with a decode step."""
+    eng = tiny_engine(prefill_chunk=8)
+    warm = eng.submit(list(range(1, 12)), max_new_tokens=2)  # both buckets
+    list(eng.tokens_for(warm))
+    resident = eng.submit([5, 9, 3], max_new_tokens=12)
+    eng.step()
+    eng.step()
+    eng.reset_stats()
+    out = str(tmp_path_factory.mktemp("trace-overlap"))
+    start_trace(out)
+    try:
+        rid = eng.submit(list(range(20, 40)), max_new_tokens=3)
+        tokens = list(eng.tokens_for(rid))
+    finally:
+        jax.profiler.stop_trace()
+    stats = eng.stats()
+    eng.cancel(resident)
+    return {"events": program_spans(out)[1], "stats": stats,
+            "tokens": tokens}
+
+
+def test_a_tick_with_a_decoder_enqueues_the_step_then_the_chunk_and_reads_after(
+        traced_beside_a_decoder):
+    """decode (build, put, enqueue), chunk (build, enqueue), the step's
+    tokens, the emit, the chunk's token: in that order inside one
+    `engine/tick`, every span under its old name, the chunk marked."""
+    ev, st = (traced_beside_a_decoder[k] for k in ("events", "stats"))
+    chunks = sorted(ev["engine/prefill_chunk"], key=lambda c: c[1])
+    assert [c[3]["tokens"] for c in chunks] == [8, 8, 4]
+    assert all(c[3]["overlapped"] == 1 for c in chunks)
+    assert st["chunks_overlapped"] == st["prefill_chunks"] == 3
+
+    def within(name, tick):
+        got = [e for e in ev[name] if inside(e, [tick])]
+        assert len(got) == 1, (name, len(got))
+        return got[0]
+
+    ticks = [t for t in ev["engine/tick"] if any(inside(c, [t])
+                                                 for c in chunks)]
+    assert len(ticks) == 3
+    for tick in ticks:
+        chunk, build, dispatch, sync, d_build, d_put, d_dispatch, \
+            token_sync, emit = (within(f"engine/{n}", tick) for n in (
+                "prefill_chunk", "prefill_build", "prefill_dispatch",
+                "prefill_sync", "decode_build", "decode_put",
+                "decode_dispatch", "token_sync", "emit"))
+        assert tick[3]["decoding"] == 1 and tick[3]["prefilling"] == 1
+        assert inside(d_put, [d_build]) and d_build[2] <= d_dispatch[1]
+        assert d_dispatch[2] <= build[1]        # the step is enqueued first
+        # the span holds the build and the enqueue, and ends before a wait
+        assert inside(build, [chunk]) and inside(dispatch, [chunk])
+        assert build[2] <= dispatch[1] and chunk[2] <= token_sync[1]
+        assert dispatch[2] <= token_sync[2]     # both in flight, then read
+        assert token_sync[2] <= emit[1] and emit[2] <= sync[1]
+        assert not inside(sync, [chunk])
+    # the prompt was in none of the three ticks' decode batches: each
+    # emitted the resident's token alone
+    assert [e[3]["tokens"] for e in sorted(ev["engine/emit"],
+                                           key=lambda e: e[1])][:3] == [1] * 3
+    assert len(traced_beside_a_decoder["tokens"]) == 3
 
 
 def test_every_tick_says_how_long_after_the_last_it_began(traced):
