@@ -154,16 +154,16 @@ def replicated(mesh: Mesh):
 
 
 def engine_io_shardings(mesh: Mesh) -> dict[str, NamedSharding]:
-    """Shardings for the inference engine's per-step host inputs
-    (current tokens, speculation windows, positions, block tables,
-    temperatures). All replicated: they are tiny int32/f32 vectors the
-    scheduler rebuilds every tick, and every shard of the paged pool
-    needs the full batch's tables — but routing them through explicit
+    """Sharding for the inference engine's per-program host input: the
+    one packed int32 array a decode, verify, propose or prefill program
+    takes (`serve.engine.pack_rows` / `pack_chunk`: tokens or the
+    speculation window, positions, temperatures' bits, block tables, the
+    step counter). Replicated: it is a tiny vector the scheduler
+    rebuilds for every program, and every shard of the paged pool needs
+    the full batch's tables — but routing it through an explicit
     device_put keeps each step's transfer off XLA's implicit-transfer
     path and makes the engine's placement auditable."""
-    rep = NamedSharding(mesh, PartitionSpec())
-    return {name: rep
-            for name in ("tokens", "window", "pos", "tables", "temps")}
+    return {"inputs": NamedSharding(mesh, PartitionSpec())}
 
 
 # -- PartitionSpec (de)serialization for checkpoint manifests ---------------

@@ -514,6 +514,71 @@ class _ChunkInFlight:
     enqueued: float = 0.0         # perf_counter at the span's end
 
 
+# --- a program's host-built input: one int32 array, one transfer --------
+#
+# What the host builds for a device program of the tick crosses to the
+# device as ONE flat int32 array: a transfer's price is fixed, not by
+# the byte, and each one is a point where the pump's thread gives the
+# interpreter lock up. A temperature rides as its own bits
+# (`float32.view(int32)`), so what the program reads back is the host's
+# float32 to the bit. The layouts follow from shapes the engine fixes at
+# construction (slots, max_blocks, the verify window, the chunk bucket).
+
+def pack_rows(tokens, pos, temps, tables, step) -> np.ndarray:
+    """A decode, verify or propose step's input: a row a slot of
+    ``[tokens (1 or W) | pos | temperature bits | block table]``, the
+    rows flattened, then the step counter `_sample` folds into the key.
+    `tokens` is ``[S]`` (decode, propose) or ``[S, W]`` (verify)."""
+    slots = len(pos)
+    tokens = tokens.reshape(slots, -1)
+    w = tokens.shape[1]
+    packed = np.empty(slots * (w + 2 + tables.shape[1]) + 1, np.int32)
+    rows = packed[:-1].reshape(slots, -1)
+    rows[:, :w] = tokens
+    rows[:, w] = pos
+    rows[:, w + 1] = temps.view(np.int32)    # float32 [S], to the bit
+    rows[:, w + 2:] = tables
+    packed[-1] = step
+    return packed
+
+
+def unpack_rows(packed, slots: int, window: int | None = None):
+    """`pack_rows` undone inside a jitted program: ``(tokens, pos,
+    temps, tables, step)``; `tokens` is ``[S]`` without `window`, else
+    ``[S, window]``."""
+    from jax import lax
+    w = 1 if window is None else window
+    rows = packed[:-1].reshape(slots, -1)
+    tokens = rows[:, 0] if window is None else rows[:, :w]
+    temps = lax.bitcast_convert_type(rows[:, w + 1], np.float32)
+    return tokens, rows[:, w], temps, rows[:, w + 2:], packed[-1]
+
+
+def pack_chunk(tokens, cap: int, table, start, temp, step) -> np.ndarray:
+    """A prompt chunk's input: ``[tokens, zero-padded to the bucket
+    `cap` | block table | start | length | temperature bits | step
+    counter]``; `length` is `tokens`' own. One layout a chunk bucket, so
+    the program compiles once a bucket."""
+    length, blocks = len(tokens), len(table)
+    packed = np.zeros(cap + blocks + 4, np.int32)
+    packed[:length] = tokens
+    packed[cap:cap + blocks] = table
+    packed[cap + blocks:] = (start, length,
+                             np.float32(temp).view(np.int32), step)
+    return packed
+
+
+def unpack_chunk(packed, max_blocks: int):
+    """`pack_chunk` undone inside a jitted program: ``(tokens [1, cap],
+    table, start, length, temp, step)``."""
+    from jax import lax
+    cap = packed.shape[0] - max_blocks - 4
+    start, length, temp, step = (packed[cap + max_blocks + i]
+                                 for i in range(4))
+    return (packed[None, :cap], packed[cap:cap + max_blocks], start,
+            length, lax.bitcast_convert_type(temp, np.float32), step)
+
+
 class InferenceEngine:
     """Slot-based continuous-batching scheduler over one model with a
     paged, prefix-shared cache.
@@ -657,7 +722,7 @@ class InferenceEngine:
             self._draft_alloc = None
         if mesh is not None:
             from ray_tpu.parallel.sharding import engine_io_shardings
-            self._io_sh = engine_io_shardings(mesh)
+            self._io_sh = engine_io_shardings(mesh)["inputs"]
         else:
             self._io_sh = None
 
@@ -748,25 +813,27 @@ class InferenceEngine:
             logp = jnp.take_along_axis(nat, tok[:, None], axis=-1)[:, 0]
             return tok, logp
 
-        def _prefill(params, tokens, cache, table, start, length, temp,
-                     key, step):
+        max_blocks = self.max_blocks
+
+        def _prefill(params, inputs, cache, key):
             self.prefill_traces += 1
+            tokens, table, start, length, temp, step = unpack_chunk(
+                inputs, max_blocks)
             logits, cache, counts = fam.prefill(
                 params, tokens, cache, cfg, mesh, block_table=table,
                 start=start, length=length)
             tok, logp = _sample(logits, temp[None], key, step)
             return tok[0], logp[0], cache, counts
 
-        def _decode(params, cache, tokens, pos, tables, temps, key,
-                    step):
+        def _decode(params, cache, inputs, key):
             self.decode_traces += 1
+            tokens, pos, temps, tables, step = unpack_rows(inputs, slots)
             logits, cache, counts = fam.decode(
                 params, tokens, cache, pos, tables, cfg, mesh)
             tok, logp = _sample(logits, temps, key, step)
             return tok, logp, cache, counts
 
-        def _verify(params, cache, tokens, pos, tables, temps, key,
-                    step):
+        def _verify(params, cache, inputs, key):
             """One batched W-token forward + in-jit accept/correct.
 
             `tokens[:, 0]` is each slot's current token, `tokens[:, 1:]`
@@ -779,6 +846,8 @@ class InferenceEngine:
             future writes overwrite the stale K/V before any read.
             """
             self.verify_traces += 1
+            tokens, pos, temps, tables, step = unpack_rows(
+                inputs, slots, self.spec_window)
             logits, cache = fam.verify(
                 params, tokens, cache, pos, tables, cfg, mesh)
             b, w = tokens.shape
@@ -855,14 +924,15 @@ class InferenceEngine:
         if spec == "draft":
             W = self.spec_window
 
-            def _propose(dparams, dcache, tokens, pos, tables, temps,
-                         key, step):
+            def _propose(dparams, dcache, inputs, key):
                 """W draft decode steps as one jitted scan: consume
                 c_0..c_{W-1}, write their K/V at pos..pos+W-1, sample
                 c_1..c_W; the first W-1 samples are the proposal (the
                 last scan step exists only to write d_{k}'s K/V so the
                 draft cache stays lockstep with the target's)."""
                 self.draft_traces += 1
+                tokens, pos, temps, tables, step = unpack_rows(
+                    inputs, slots)
                 k = jax.random.fold_in(jax.random.fold_in(key, step), 3)
 
                 def body(carry, i):
@@ -878,9 +948,10 @@ class InferenceEngine:
                     jnp.arange(W, dtype=jnp.int32))
                 return outs[:-1].T, dcache               # [B, W-1]
 
-            def _draft_prefill(dparams, tokens, dcache, table, start,
-                               length):
+            def _draft_prefill(dparams, inputs, dcache):
                 self.draft_prefill_traces += 1
+                tokens, table, start, length, _, _ = unpack_chunk(
+                    inputs, max_blocks)
                 _, dcache, _ = draft_cfg.family.prefill(
                     dparams, tokens, dcache, draft_cfg, mesh,
                     block_table=table, start=start, length=length)
@@ -975,6 +1046,8 @@ class InferenceEngine:
         # host->device upload in _place_tree happens OUTSIDE _lock.
         self._swap_mutex = threading.Lock()
         self._decode_steps = 0
+        # host-to-device transfers made for the programs' inputs
+        self._host_puts = 0
         # what the family's programs count (`ServingFamily.counts`),
         # summed over the window; None until a program returns some
         self._model_counts = None
@@ -2193,7 +2266,6 @@ class InferenceEngine:
         enqueue and `_finish_chunk` does the reading, once the step's
         tokens are out. None where the main pool holds the whole prompt
         already and only the draft pool catches up."""
-        jnp = self._jax.numpy
         s = self._slots[slot_idx]
         flight = None
         if s.filled < s.prompt.size:
@@ -2202,18 +2274,15 @@ class InferenceEngine:
             phase = self._phases.phase
             with phase("engine/prefill_chunk", tokens=clen, bucket=cap,
                        overlapped=int(overlapped)) as chunk:
-                with phase("engine/prefill_build"):
-                    toks = np.zeros((1, cap), np.int32)
-                    toks[0, :clen] = s.prompt[s.filled:s.filled + clen]
-                    toks, table = jnp.asarray(toks), jnp.asarray(s.table)
+                with phase("engine/prefill_build", puts=1):
                     # keyed by the count before this tick's decode step,
                     # wherever in the tick the chunk is enqueued
-                    scalars = (np.int32(s.filled), np.int32(clen),
-                               np.float32(s.temperature), self._base_key,
-                               np.int32(self._decode_steps))
+                    inputs = self._dev(pack_chunk(
+                        s.prompt[s.filled:s.filled + clen], cap, s.table,
+                        s.filled, s.temperature, self._decode_steps))
                 with phase("engine/prefill_dispatch"):
                     tok, lp, self.cache, counts = self._prefill_fn(
-                        self.params, toks, self.cache, table, *scalars)
+                        self.params, inputs, self.cache, self._base_key)
                 flight = _ChunkInFlight(tok, lp, counts, clen, cap, chunk,
                                         overlapped)
                 if not overlapped:
@@ -2228,15 +2297,14 @@ class InferenceEngine:
             dclen = min(self.prefill_chunk,
                         s.prompt.size - s.draft_filled)
             dcap = self._chunk_bucket_for(dclen)
-            dtoks = np.zeros((1, dcap), np.int32)
-            dtoks[0, :dclen] = s.prompt[
-                s.draft_filled:s.draft_filled + dclen]
             with self._phases.phase("engine/draft_prefill_chunk",
                                     tokens=dclen, bucket=dcap):
+                # the chunk's layout, its temperature and step unread
+                inputs = self._dev(pack_chunk(
+                    s.prompt[s.draft_filled:s.draft_filled + dclen], dcap,
+                    s.draft_table, s.draft_filled, 0.0, 0))
                 self.draft_cache = self._draft_prefill_fn(
-                    self.draft_params, jnp.asarray(dtoks),
-                    self.draft_cache, jnp.asarray(s.draft_table),
-                    np.int32(s.draft_filled), np.int32(dclen))
+                    self.draft_params, inputs, self.draft_cache)
             s.draft_filled += dclen
         return flight
 
@@ -2516,13 +2584,13 @@ class InferenceEngine:
         self._tick_thread = ident
         return int(gap * 1e6), carried
 
-    def _dev(self, name: str, arr):
-        """Host array -> device, through the replicated per-step input
-        shardings when the engine runs on a mesh."""
-        if self._io_sh is None:
-            return self._jax.numpy.asarray(arr)
-        # graftlint: disable-next-line=R004 µs-scale host->device placement of tiny per-tick inputs; placing outside the lock would race slot state, and the transfer is async (no sync back)
-        return self._jax.device_put(arr, self._io_sh[name])
+    def _dev(self, packed: np.ndarray):
+        """A program's packed input (`pack_rows`, `pack_chunk`), host ->
+        device in one transfer, counted in `host_puts`; replicated over
+        the mesh when the engine runs on one."""
+        self._host_puts += 1
+        # graftlint: disable-next-line=R004 µs-scale host->device placement of one tiny per-program input; placing outside the lock would race slot state, and the transfer is async (no sync back)
+        return self._jax.device_put(packed, self._io_sh)
 
     def _batch_arrays(self):
         """Per-slot decode inputs. Rows not decoding (idle or
@@ -2543,30 +2611,34 @@ class InferenceEngine:
                          np.float32)
         return tokens, pos, tables, temps
 
-    def _decode_inputs(self):
-        """`_batch_arrays` and its four device puts, as one span: the
-        host's part of a tick before the dispatch. The puts are a child
-        span of their own."""
+    def _decode_inputs(self, host=None, *, window=None, tables=None):
+        """A step's packed input on the device (`pack_rows`), as one
+        span: the host's part of a step before its dispatch, its one
+        put a child span of its own. `host` is what `_batch_arrays`
+        returned where the caller built it already (the speculative
+        tick); `window` takes the tokens' place for verify, `tables` the
+        target pool's for the draft pool's propose."""
         phase = self._phases.phase
         with phase("engine/decode_build"):
-            tokens, pos, tables, temps = host = self._batch_arrays()
-            with phase("engine/decode_put"):
-                return host, (self._dev("tokens", tokens),
-                              self._dev("pos", pos),
-                              self._dev("tables", tables),
-                              self._dev("temps", temps))
+            tokens, pos, own_tables, temps = host or self._batch_arrays()
+            packed = pack_rows(
+                tokens if window is None else window, pos, temps,
+                own_tables if tables is None else tables,
+                self._decode_steps)
+            with phase("engine/decode_put", puts=1):
+                return self._dev(packed)
 
-    def _decode_tick(self, decoding: list, inputs=None, enqueued=None):
-        """One decode step for every slot. `enqueued`, where given, is
-        called once the step is in the device's queue and before its
-        tokens are waited for (`_decode_with_chunk`)."""
+    def _decode_tick(self, decoding: list, host=None, enqueued=None):
+        """One decode step for every slot. `host` is `_batch_arrays`'
+        result where the speculative tick, falling back, built it
+        already. `enqueued`, where given, is called once the step is in
+        the device's queue and before its tokens are waited for
+        (`_decode_with_chunk`)."""
         phase = self._phases.phase
-        if inputs is None:      # the spec tick's fallback built them
-            _, inputs = self._decode_inputs()
+        inputs = self._decode_inputs(host)
         with phase("engine/decode_dispatch") as dispatch:
             nxt, lps, self.cache, counts = self._decode_fn(
-                self.params, self.cache, *inputs, self._base_key,
-                np.int32(self._decode_steps))
+                self.params, self.cache, inputs, self._base_key)
         if enqueued is not None:
             enqueued()
         with phase("engine/token_sync") as sync:
@@ -2628,9 +2700,8 @@ class InferenceEngine:
         worth = [i for i in decoding
                  if self._slots[i].remaining >= 2]
         proposals: dict[int, list] = {}
-        host, inputs = self._decode_inputs()
-        tokens = host[0]
-        d_tokens, d_pos, d_tables, d_temps = inputs
+        with phase("engine/decode_build"):
+            tokens, *_ = host = self._batch_arrays()
         with phase("engine/propose") as propose:
             if self.spec == "ngram":
                 for i in worth:
@@ -2651,22 +2722,21 @@ class InferenceEngine:
                     [s.draft_table if s.phase == "decode" else zeros
                      for s in self._slots])
                 dj, self.draft_cache = self._propose_fn(
-                    self.draft_params, self.draft_cache, d_tokens, d_pos,
-                    self._dev("tables", dtables), d_temps,
-                    self._base_key, np.int32(self._decode_steps))
+                    self.draft_params, self.draft_cache,
+                    self._decode_inputs(host, tables=dtables),
+                    self._base_key)
                 # graftlint: disable-next-line=R001,R004 draft proposals must reach the host to build the verify window; one sync per spec tick, same budget as the plain decode tick's
                 drafts = np.asarray(dj)
                 for i in worth:
                     proposals[i] = drafts[i].tolist()
         if not proposals:
-            self._decode_tick(decoding, inputs, enqueued)
+            self._decode_tick(decoding, host, enqueued)
             return
         window = np.concatenate([tokens[:, None], drafts], axis=1)
+        inputs = self._decode_inputs(host, window=window)
         with phase("engine/verify_dispatch") as verify:
             out, out_lp, acc, self.cache = self._verify_fn(
-                self.params, self.cache, self._dev("window", window),
-                d_pos, d_tables, d_temps, self._base_key,
-                np.int32(self._decode_steps))
+                self.params, self.cache, inputs, self._base_key)
         if enqueued is not None:
             enqueued()
         with phase("engine/token_sync") as sync:
@@ -2812,7 +2882,7 @@ class InferenceEngine:
         `stats()["params_version"]` must not see it rewind. The windowed
         `swaps` counter and `weight_swap_ms` DO reset."""
         with self._before_pump(), self._lock:
-            self._decode_steps = 0
+            self._decode_steps = self._host_puts = 0
             self._model_counts = None
             self._prefill_tokens = self._decode_tokens = 0
             self._phases.clear()
@@ -2869,6 +2939,11 @@ class InferenceEngine:
           slots, queued (unadmitted) requests, those no tick has seen
           yet included.
           ``decode_steps`` — device decode/verify ticks since reset.
+          ``host_puts`` — host-to-device transfers made for the
+          programs' inputs since reset: one a decode, verify, propose or
+          prefill program (`pack_rows`, `pack_chunk`), so
+          ``decode_steps + prefill_chunks`` where nothing speculates
+          with a draft model.
           ``prefill_tokens`` / ``decode_tokens`` — tokens absorbed /
           emitted since reset; ``prefill_time_s`` / ``decode_time_s``
           the device time attributed to each: what the recorder is told
@@ -2964,12 +3039,13 @@ class InferenceEngine:
           their wall time under the lock.
           ``admit_s`` — `engine/admit`: import and pending admission.
           ``decode_build_s`` — `engine/decode_build`: the per-slot input
-          arrays and their device puts; ``decode_put_s`` —
-          `engine/decode_put`, inside it: the four puts alone.
+          arrays, their packing and the put; ``decode_put_s`` —
+          `engine/decode_put`, inside it: the one put alone (``puts=1``
+          on the span).
           ``prefill_build_s`` / ``prefill_dispatch_s`` /
           ``prefill_sync_s`` — `engine/prefill_build`,
           `engine/prefill_dispatch`, `engine/prefill_sync`: the chunk's
-          host arrays and their puts, enqueueing the program, and the
+          packed input and its one put, enqueueing the program, and the
           host blocked until the chunk's token is back (the device's
           time shows here). Alone in its tick the three tile
           `engine/prefill_chunk`; behind a decode step the span holds
@@ -3143,6 +3219,7 @@ class InferenceEngine:
                 "active": sum(s.active for s in self._slots),
                 "pending": len(self._pending),
                 "decode_steps": self._decode_steps,
+                "host_puts": self._host_puts,
                 "prefill_tokens": self._prefill_tokens,
                 "decode_tokens": self._decode_tokens,
                 "prefill_time_s": ph.seconds(

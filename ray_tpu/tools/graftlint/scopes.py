@@ -44,6 +44,13 @@ HOT_SCOPES: dict[str, frozenset[str]] = {
         "InferenceEngine._try_admit",
         "InferenceEngine._admit_pending",
         "InferenceEngine._batch_arrays",
+        # a program's host-built input, packed into one int32 array and
+        # put once (`pack_rows` / `pack_chunk`, then `_dev`): a sync on
+        # the way would hold the tick's first program back
+        "pack_rows",
+        "pack_chunk",
+        "InferenceEngine._dev",
+        "InferenceEngine._decode_inputs",
         "InferenceEngine._start_chunk",
         "InferenceEngine._read_chunk_token",
         "InferenceEngine._finish_chunk",

@@ -18,7 +18,8 @@ from benchmarks.refs import latent_sparse_moe as ref
 from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
 from ray_tpu.ops import grouped_experts, sparse_latent
-from ray_tpu.serve.engine import InferenceEngine
+from ray_tpu.serve.engine import (InferenceEngine, pack_chunk, pack_rows,
+                                  unpack_chunk, unpack_rows)
 from ray_tpu.util import faults
 
 # the published keys at a tiny size: two layers own an indexer, the top-k
@@ -473,15 +474,17 @@ def test_gpt_lowers_to_the_programs_it_lowered_to_before():
         logp = jnp.take_along_axis(nat, tok[:, None], axis=-1)[:, 0]
         return tok, logp
 
-    def _prefill(params, tokens, cache, table, start, length, temp, key,
-                 step):
+    def _prefill(params, inputs, cache, key):
+        tokens, table, start, length, temp, step = unpack_chunk(
+            inputs, eng.max_blocks)
         logits, cache = gpt.prefill_paged(
             params, tokens, cache, cfg, None, block_table=table,
             start=start, length=length)
         tok, logp = _sample(logits, temp[None], key, step)
         return tok[0], logp[0], cache
 
-    def _decode(params, cache, tokens, pos, tables, temps, key, step):
+    def _decode(params, cache, inputs, key):
+        tokens, pos, temps, tables, step = unpack_rows(inputs, 4)
         logits, cache = gpt.decode_step_paged(
             params, tokens, cache, pos, tables, cfg, None)
         tok, logp = _sample(logits, temps, key, step)
@@ -489,14 +492,16 @@ def test_gpt_lowers_to_the_programs_it_lowered_to_before():
 
     i32, f32 = np.int32, np.float32
     key = jax.random.PRNGKey(0)
-    decode_args = (p, eng.cache, np.zeros(4, i32), np.zeros(4, i32),
-                   np.zeros((4, eng.max_blocks), i32), np.zeros(4, f32),
-                   key, i32(0))
+    # each program's host-built input is one packed int32 array
+    # (tests/test_packed_inputs.py holds the layouts to the bit)
+    decode_args = (p, eng.cache, pack_rows(
+        np.zeros(4, i32), np.zeros(4, i32), np.zeros(4, f32),
+        np.zeros((4, eng.max_blocks), i32), 0), key)
     assert _sha(eng._decode_fn, *decode_args) == _sha(
         jax.jit(_decode, donate_argnums=(1,)), *decode_args)
-    prefill_args = (p, np.zeros((1, 16), i32), eng.cache,
-                    np.zeros(eng.max_blocks, i32), i32(0), i32(16), f32(0),
-                    key, i32(0))
+    prefill_args = (p, pack_chunk(
+        np.zeros(16, i32), 16, np.zeros(eng.max_blocks, i32), 0, 0.0, 0),
+        eng.cache, key)
     assert _sha(eng._prefill_fn, *prefill_args) == _sha(
         jax.jit(_prefill, donate_argnums=(2,)), *prefill_args)
     # and its streams do not change: the family adds nothing to stats()

@@ -325,6 +325,50 @@ def test_a_tick_with_a_decoder_enqueues_the_step_then_the_chunk_and_reads_after(
     assert len(traced_beside_a_decoder["tokens"]) == 3
 
 
+@pytest.mark.parametrize("with_chunk", [False, True],
+                         ids=["decode-only", "with-a-chunk"])
+def test_a_tick_makes_one_input_transfer_a_program(with_chunk):
+    """`host_puts` over one tick: one for a decode step alone, two where
+    a prompt chunk is enqueued behind it (each program's input is one
+    packed array: `serve.engine.pack_rows`, `pack_chunk`)."""
+    eng = tiny_engine(prefill_chunk=8)
+    eng.submit([5, 9, 3], max_new_tokens=20)
+    eng.step()
+    eng.step()
+    if with_chunk:
+        eng.submit(list(range(20, 40)), max_new_tokens=3)
+    before = eng.stats()
+    eng.step()
+    after = eng.stats()
+    moved = {k: after[k] - before[k] for k in (
+        "decode_steps", "prefill_chunks", "chunks_overlapped", "host_puts")}
+    assert moved == {"decode_steps": 1, "prefill_chunks": int(with_chunk),
+                     "chunks_overlapped": int(with_chunk),
+                     "host_puts": 1 + int(with_chunk)}
+    eng.reset_stats()
+    assert eng.stats()["host_puts"] == 0
+    assert "``host_puts``" in InferenceEngine.stats.__doc__
+
+
+@pytest.mark.parametrize("session", ["traced", "traced_beside_a_decoder"])
+def test_the_put_spans_say_one_put_each(session, request):
+    """`puts=1` on every `engine/decode_put` and `engine/prefill_build`
+    of a trace, alone in the tick and overlapped, and the spans' sum is
+    the engine's own count."""
+    got = request.getfixturevalue(session)
+    ev = got["events"]
+    st = got["stats"] if "stats" in got else got["engine"].stats()
+    spans = ev["engine/decode_put"] + ev["engine/prefill_build"]
+    assert all(e[3]["puts"] == 1 for e in spans)
+    assert len(ev["engine/decode_put"]) == st["decode_steps"]
+    assert len(ev["engine/prefill_build"]) == st["prefill_chunks"]
+    assert sum(e[3]["puts"] for e in spans) == st["host_puts"]
+    for tick in ev["engine/tick"]:
+        inside_tick = [e for e in spans if inside(e, [tick])]
+        assert len(inside_tick) == (tick[3]["decoding"] > 0) + sum(
+            inside(c, [tick]) for c in ev["engine/prefill_chunk"])
+
+
 def test_every_tick_says_how_long_after_the_last_it_began(traced):
     """`gap_us` and `carried` on each `engine/tick`: the engine's own
     reading of the time since the previous tick ended is the distance
