@@ -21,13 +21,12 @@ trace-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_tracing_distributed.py \
 		-q -k 'merged or proxy'
 
-# Quantization CPU parity + JSON-contract subset: int8 KV token
-# identity vs f32 (incl. COW / spec-decode), kernel dequant parity,
-# fused-prefill parity, the quantized fuzz tier, and the bench fields
-# (capacity_vs_f32, quality_logprob_delta) pinned end to end.
+# Quantization CPU parity subset: int8 KV token identity vs f32 (incl.
+# COW / spec-decode), kernel dequant parity, fused-prefill parity and
+# the quantized fuzz tier.
 quant-smoke:
 	JAX_PLATFORMS=cpu $(PY) -m pytest tests/test_paged_cache.py \
-		tests/test_spec_decode.py tests/test_bench_infer_smoke.py \
+		tests/test_spec_decode.py \
 		-q -m 'not slow' -k 'quant or Quant or FusedPrefill'
 
 # Disaggregated prefill/decode smoke: token identity vs colocated

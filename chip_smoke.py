@@ -4,15 +4,15 @@
                                     # then train phase
     python chip_smoke.py --chips 4  # one four-chip host: sharded train step
                                     # against one chip, then two replicas
-    python chip_smoke.py --phase serve-load   # one chip: 16 closed-loop
-                                    # streams on the olmo-1b serve block,
-                                    # a profiler trace from inside the replica
 
 Drives the serving path (`serve.run` of an `InferenceReplica` that asked
 for a chip, concurrent `handle.stream` calls) and the training path
 (`spmd.make_gpt_trainer` + `loop.TrainLoop` with the prefetcher) once at
-the full width of the repo's bench model, random weights from `--seed`,
-and checks what comes out against references computed the plain way.
+the widths of `WIDTHS`, random weights from `--seed`, and checks what
+comes out against references computed the plain way. What a cell of the
+benchmark proves on every PR (rates and latencies under load:
+`benchmarks/run.py`) is not repeated here; the helpers the two share are
+the benchmark's (`benchmarks/harness`), imported.
 The serve phase runs its streams a second time under a `jax.profiler`
 trace taken inside the replica, Python tracer off, and prints what the
 program's own spans (`engine/*`, `stream/*`) and named kernels say. It
@@ -42,7 +42,7 @@ import json
 import logging
 import os
 import re
-import signal
+import shutil
 import subprocess
 import sys
 import threading
@@ -51,13 +51,18 @@ import time
 import numpy as np
 
 import ray_tpu
+from benchmarks.harness import serve_cell
+from benchmarks.harness import spans as spans_mod
+from benchmarks.harness import trace as trace_mod
+from benchmarks.harness.common import CompileWatch, device_report
+from benchmarks.harness.serve_replica import BenchReplica
 from ray_tpu import serve
 from ray_tpu._private import native
 from ray_tpu.serve.engine import InferenceReplica
-from ray_tpu.util import tracing
 
-# The repo's bench model (bench.py, bench_infer.py): published-GPT-2-medium
-# -like widths, all 12 layers.
+# Published-GPT-2-medium-like widths, all 12 layers: a dense model that
+# fits one chip with its optimizer state and leaves the flash, fused-loss
+# and paged kernels a plan each.
 WIDTHS = dict(vocab_size=50304, d_model=1024, n_layers=12, n_heads=16,
               d_ff=4096, max_seq_len=1024)
 TRAIN_CFG = dict(WIDTHS, attn_impl="flash", logits_dtype="bfloat16",
@@ -105,42 +110,9 @@ def watch_op_fallbacks() -> list[str]:
     return handler.messages
 
 
-class CompileWatch:
-    """Counts what JAX compiles and what its persistent cache answers,
-    from the events JAX itself records."""
-
-    def __init__(self):
-        import jax
-        self.compile_s = 0.0
-        self.compiles = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._dur)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _dur(self, event: str, secs: float, **_):
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += secs
-            self.compiles += 1
-
-    def _event(self, event: str, **_):
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def report(self) -> dict:
-        return {"compile_s": round(self.compile_s, 2),
-                "compiles": self.compiles,
-                "cache_hits": self.cache_hits,
-                "cache_misses": self.cache_misses}
-
-
-def device_report() -> dict:
-    import jax
-    devices = jax.devices()
-    return {"platform": devices[0].platform,
-            "kind": devices[0].device_kind, "count": len(devices)}
+def compile_report(watch: CompileWatch) -> dict:
+    return {"compile_s": round(watch.compile_s, 2),
+            "compiles": watch.compiles, "cache_hits": watch.cache_hits}
 
 
 def kernels_of(jitted, *args):
@@ -155,19 +127,13 @@ def kernels_of(jitted, *args):
         'custom_call_target="tpu_custom_call"'), compiled
 
 
-def peak_bytes() -> int | None:
-    import jax
-    stats = jax.devices()[0].memory_stats() or {}
-    return stats.get("peak_bytes_in_use")
-
-
 # ---------------------------------------------------------------------------
 # serve phase
 # ---------------------------------------------------------------------------
 
 class SmokeReplica(InferenceReplica):
-    """`InferenceReplica` plus the one method that has to run inside the
-    process that owns the chip; the program itself grows no API."""
+    """`InferenceReplica` plus what has to run inside the process that
+    owns the chip; the program itself grows no API."""
 
     def __init__(self, *args, **kwargs):
         from ray_tpu.util.compile_cache import enable_compile_cache
@@ -179,68 +145,12 @@ class SmokeReplica(InferenceReplica):
         super().__init__(*args, **kwargs)
         self._init_s = time.perf_counter() - t0
 
-    def warm(self, prompt_lens, new_tokens: int) -> int:
-        """One request per length, drained here in the replica, so that
-        every program a window will run is compiled before it opens;
-        then the engine's counts start from zero."""
-        rng = np.random.default_rng(0)
-        for n in prompt_lens:
-            self.engine.generate(
-                rng.integers(0, self.engine.cfg.vocab_size, int(n)
-                             ).astype(np.int32), max_new_tokens=new_tokens)
-        self.engine.reset_stats()
-        return self._compiles.compiles
-
-    def trace_start(self) -> bool:
-        """Open a profiler session in this process, the one that holds
-        the chip. Python tracer off: the program's own spans name what
-        the host does, and in a process with tens of threads the tracer
-        cut the device's part of the trace short (PERF.md, PR 23)."""
-        import shutil
-
-        import jax
-        self._trace_dir = os.path.join(
-            os.environ.get("TMPDIR", "/tmp"), f"smoke_trace_{os.getpid()}")
-        shutil.rmtree(self._trace_dir, ignore_errors=True)
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        options.host_tracer_level = 2
-        self.engine.reset_stats()
-        self._compiles_at_start = self._compiles.compiles
-        self._trace_t0 = time.perf_counter()
-        jax.profiler.start_trace(self._trace_dir, profiler_options=options)
-        return True
-
-    def trace_stop(self) -> dict:
-        """Close the session and reduce its trace here (the file stays
-        on this machine): the named kernels, the program's spans, who
-        owned the chip's idle gaps, and the engine's own numbers for the
-        same window."""
-        import shutil
-
-        import jax
-        # counts first: closing the session takes seconds, and under
-        # load the engine goes on ticking meanwhile
-        traced_s = time.perf_counter() - self._trace_t0
-        stats = self.stats()
-        jax.profiler.stop_trace()
-        report = reduce_serve_trace(self._trace_dir)
-        shutil.rmtree(self._trace_dir, ignore_errors=True)
-        report.update(
-            traced_s=traced_s,
-            compiles_in_window=(self._compiles.compiles
-                                - self._compiles_at_start),
-            stats={k: stats[k] for k in (
-                "ticks", "tick_s", "admit_s", "decode_build_s",
-                "decode_dispatch_s", "token_sync_s", "emit_s",
-                "prefill_time_s", "decode_time_s", "decode_steps",
-                "decode_tokens", "prefill_tokens", "prefill_chunks",
-                "stream_waits", "stream_wait_s", "submits", "submit_s",
-                "slot_occupancy",
-                "p50_token_latency_ms", "ttft_ms_p50", "ttft_ms_p99",
-                "deliver_wait_ms_p50", "deliver_wait_ms_p99",
-                "queue_depth")})
-        return report
+    # The traced window is the benchmark's: the engine's counts from
+    # zero, a profiler session in this process (Python tracer off), and
+    # at its end the counts of `serve_replica.ENGINE_STATS` with the
+    # programs made meanwhile.
+    window_start = BenchReplica.window_start
+    window_stop = BenchReplica.window_stop
 
     def inspect(self, prompt, tokens, logprobs) -> dict:
         """Stats, the kernels in the paged forwards at this engine's
@@ -305,104 +215,19 @@ class SmokeReplica(InferenceReplica):
             "op_fallbacks": list(self._fallbacks),
             "replica_init_s": round(self._init_s, 2),
             "cache_dir": self._cache_dir,
-            **self._compiles.report(),
-            "peak_bytes_in_use": peak_bytes(),
+            **compile_report(self._compiles),
+            "memory_peak_bytes": device_report()["memory_peak_bytes"],
         }
 
 
-def pct(values, p: float) -> float | None:
-    values = sorted(values)
-    return values[min(len(values) - 1, int(p / 100 * len(values)))] \
-        if values else None
-
-
-def request_split() -> dict:
-    """From the flight recorder's spans, where a request's time to its
-    first token went inside the replica: queue (submit to a slot),
-    prefill (slot to first token made), deliver (made to handed to the
-    stream's consumer). Milliseconds. A worker's recorder is drained
-    with every reply it sends, into the head's tracing ring: read here,
-    in the driver."""
-    by_rid: dict = {}
-    for s in tracing.get_spans():
-        if s.get("cat") == "request":
-            by_rid.setdefault((s.get("lane"), s["attributes"].get("rid")),
-                              {}).setdefault(s["name"], s)
-    parts: dict = {"queue_ms": [], "prefill_ms": [], "deliver_ms": [],
-                   "ticks_made_to_yielded": []}
-    for r in by_rid.values():
-        if not {"queue_wait", "first_token", "first_yield"} <= set(r):
-            continue
-        q, made, out = r["queue_wait"], r["first_token"], r["first_yield"]
-        parts["queue_ms"].append((q["end_ns"] - q["start_ns"]) / 1e6)
-        parts["prefill_ms"].append((made["start_ns"] - q["end_ns"]) / 1e6)
-        parts["deliver_ms"].append(
-            (out["start_ns"] - made["start_ns"]) / 1e6)
-        parts["ticks_made_to_yielded"].append(
-            out["attributes"]["tick"] - made["attributes"]["tick"])
-    return {"requests": len(parts["queue_ms"]),
-            **{f"{k}_p{p}": pct(v, p) for k, v in parts.items()
-               for p in (50, 99)}}
-
-
-def reduce_serve_trace(trace_dir: str) -> dict:
-    """What a trace taken inside a serving replica holds. The tables are
-    the benchmark's own reductions (`benchmarks/harness`), so a serving
-    cell will read the same numbers the same way."""
-    from jax.profiler import ProfileData
-
-    from benchmarks.harness import spans as spans_mod
-    from benchmarks.harness import trace as trace_mod
+def traced_window(window: dict, trace_dir: str) -> dict:
+    """What the benchmark's two reductions (`harness/spans.py` by name,
+    `harness/trace.py` by shape) say of the trace the replica wrote into
+    `trace_dir`, beside the replica's own counts for the same window."""
     path = trace_mod.find_xplane(trace_dir)
     by_shape = trace_mod.reduce(path)
-    named = spans_mod.reduce(path)
-    # Do the device planes last as long as the host's spans? And what a
-    # reply carried: the spans' attributes, which the tables drop.
-    device, host, reply_tokens = [], [], []
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            if trace_mod.DEVICE_PLANE.match(plane.name) \
-                    and line.name == trace_mod.OPS_LINE:
-                device += [(e.start_ns, e.start_ns + e.duration_ns)
-                           for e in line.events]
-            elif plane.name == "/host:CPU":
-                for e in line.events:
-                    if spans_mod.SPAN.match(e.name):
-                        host.append((e.start_ns,
-                                     e.start_ns + e.duration_ns))
-                        if e.name == "stream/reply":
-                            reply_tokens.append(
-                                dict(e.stats).get("tokens", 0))
-    extent = {}
-    for name, evs in (("device_ops", device), ("program_spans", host)):
-        if evs:
-            extent[name] = [len(evs), min(s for s, _ in evs) * 1e-9,
-                            max(e for _, e in evs) * 1e-9]
-    sp = named["spans"]
-
-    def total(*names):
-        return sum(sp[n][1] for n in names if n in sp)
-
-    tick_s = total("engine/tick")
-    return {
-        "xplane_bytes": os.path.getsize(path),
-        "extent_s": extent,         # [events, first s, last s] of each
-        "window_s": by_shape["window_s"], "busy_s": by_shape["busy_s"],
-        "idle_share": (1 - by_shape["busy_s"] / by_shape["window_s"]
-                       if by_shape["window_s"] else None),
-        "modules": by_shape["modules"],
-        "kernels": named["kernels"],
-        "spans": sp,                # {name: [count, total s, median s]}
-        "tick_outside_dispatch_and_sync_share": (
-            1 - total("engine/decode_dispatch", "engine/verify_dispatch",
-                      "engine/token_sync") / tick_s if tick_s else None),
-        "idle_s": named["idle_s"], "idle_owners": named["idle_owners"],
-        "reply_tokens": {"replies": len(reply_tokens),
-                         "mean": (float(np.mean(reply_tokens))
-                                  if reply_tokens else None),
-                         "p50": pct(reply_tokens, 50),
-                         "max": max(reply_tokens, default=None)},
-    }
+    return {**window, **spans_mod.reduce(path),
+            **{k: by_shape[k] for k in ("busy_s", "modules", "lines")}}
 
 
 def in_replica(replica, method: str, *args):
@@ -411,42 +236,12 @@ def in_replica(replica, method: str, *args):
                        timeout=PHASE_TIMEOUT_S)
 
 
-def _ray_tpu_processes():
-    """(pid, parent pid, command line) of every worker-side process of
-    ray_tpu on this host: `worker_main` execs, the fork factory and the
-    workers it forked (which keep its command line)."""
-    for path in glob.glob("/proc/[0-9]*"):
-        try:
-            with open(path + "/cmdline", "rb") as f:
-                cmd = f.read().replace(b"\0", b" ").decode(errors="replace")
-            with open(path + "/stat", "rb") as f:
-                ppid = int(f.read().rsplit(b")", 1)[1].split()[1])
-        except (OSError, ValueError, IndexError):
-            continue
-        if ("ray_tpu._private.worker_main" in cmd
-                or "ray_tpu._private.forkserver" in cmd):
-            yield int(os.path.basename(path)), ppid, cmd.strip()
-
-
 def stop_workers_or_fail() -> None:
     """After `ray_tpu.shutdown()`: no worker may be left (a replica left
-    alive still holds the chip). The fork factory is this process's own
-    child, kept warm between sessions by design; stop it too, so that
-    the script leaves nothing running."""
-    deadline = time.monotonic() + 15
-    while True:
-        procs = list(_ray_tpu_processes())
-        factories = [pid for pid, ppid, cmd in procs
-                     if ppid == os.getpid() and "forkserver" in cmd]
-        workers = [f"{pid}: {cmd}" for pid, _, cmd in procs
-                   if pid not in factories]
-        if not workers or time.monotonic() > deadline:
-            break
-        time.sleep(0.2)
-    for pid in factories:
-        os.kill(pid, signal.SIGTERM)
-        os.waitpid(pid, 0)
-    check(not workers, f"workers left after shutdown: {workers}")
+    alive still holds the chip), and the fork factory is stopped too, so
+    that the script leaves nothing running."""
+    left = serve_cell.stop_workers()
+    check(not left, f"workers left after shutdown: {left}")
 
 
 def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
@@ -519,13 +314,12 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
         # taken inside the first replica.
         handle._refresh(force=True)
         traced = handle._replicas[0]
-        tracing.clear_spans()       # the split is the traced pass's
-        in_replica(traced, "trace_start")
+        trace_dir = spans_mod.trace_dir()
+        in_replica(traced, "window_start", trace_dir)
         again = run_streams([rng.integers(
             0, cfg_kwargs["vocab_size"], len(p)).astype(np.int32)
             for p in prompts])
-        trace_report = in_replica(traced, "trace_stop")
-        trace_report["requests"] = request_split()
+        window = in_replica(traced, "window_stop")
         check(all(len(o) == new_tokens for o in again),
               "a traced stream came back short")
 
@@ -538,6 +332,8 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
         ray_tpu.shutdown()
         stop_workers_or_fail()
 
+    trace_report = traced_window(window, trace_dir)
+    shutil.rmtree(os.path.dirname(trace_dir), ignore_errors=True)
     emit({"phase": "serve_trace", **trace_report})
     emit({"phase": "serve", "replicas": reports, "streams": streams,
           "new_tokens": new_tokens,
@@ -569,13 +365,12 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
                   f"no paged prefill kernel: {rep['kernels']}")
     check_serve_trace(trace_report, on_tpu=platform == "tpu",
                       replicas=replicas)
-    # every stream here starts under the trace (a window of the load
-    # phase may hold no first token: one long prompt's chunks fill it)
+    # every stream here starts inside the window
     check(replicas > 1
-          or (trace_report["stats"]["deliver_wait_ms_p99"] > 0
-              and trace_report["stats"]["submits"] == streams),
+          or (trace_report["engine"]["deliver_wait_ms_p99"] > 0
+              and trace_report["engine"]["submits"] == streams),
           f"first yields or submits are missing from the window's "
-          f"stats: {trace_report['stats']}")
+          f"stats: {trace_report['engine']}")
     scoped = [rep["stats"]["visible_chips"] for rep in reports]
     check(len({rep["pid"] for rep in reports}) == replicas
           and len(set(scoped)) == replicas,
@@ -584,16 +379,16 @@ def serve_phase(cfg_kwargs: dict, *, platform: str, replicas: int,
 
 def check_serve_trace(rep: dict, *, on_tpu: bool, replicas: int) -> None:
     """The traced pass: the program's spans are in the trace with the
-    Python tracer off, nothing compiled under it, and on a chip the
-    device planes last as long as the spans and the kernels go by
-    their names. The tables count the spans that lie inside the window
-    (first to last device op), so the trace holds nearly all the ticks
-    the engine counted, not one more."""
+    Python tracer off, no program was made under it, and on a chip the
+    kernels go by their names. The tables count the spans that lie
+    inside the window (first to last device op), so the trace holds
+    nearly all the ticks the engine counted, not one more: device planes
+    that ended before the spans did would leave most of them outside."""
     spans = rep["spans"]
     if replicas > 1 and "engine/tick" not in spans:
         return      # the router sent this replica none of the streams
-    check(rep["compiles_in_window"] == 0,
-          f"{rep['compiles_in_window']} programs compiled under the trace")
+    check(rep["programs_in_window"] == 0,
+          f"programs compiled or loaded under the trace: {rep['compiled']}")
     wanted = {"engine/tick", "engine/admit", "engine/prefill_chunk",
               "engine/decode_build", "engine/decode_dispatch",
               "engine/token_sync", "engine/emit", "stream/reply"}
@@ -601,7 +396,7 @@ def check_serve_trace(rep: dict, *, on_tpu: bool, replicas: int) -> None:
         wanted.add("stream/wait")
     check(wanted <= set(spans), f"spans missing from the trace: "
           f"{sorted(wanted - set(spans))}")
-    st = rep["stats"]
+    st = rep["engine"]
     ticks = spans["engine/tick"][0]
     waits = spans.get("stream/wait", [0])[0]
     # the smoke's submits come before the window's first device op
@@ -612,17 +407,9 @@ def check_serve_trace(rep: dict, *, on_tpu: bool, replicas: int) -> None:
           f"the trace holds {ticks} ticks, {waits} stream waits and "
           f"{submits} submits, the engine counted {st['ticks']}, "
           f"{st['stream_waits']} and {st['submits']}")
-    if replicas == 1:       # with more, the streams spread over them
-        check(rep["requests"]["requests"] > 0,
-              f"no request's first yield was recorded: {rep['requests']}")
     if on_tpu:
         check({"paged_decode", "paged_mq"} <= set(rep["kernels"]),
               f"kernels in the trace: {sorted(rep['kernels'])}")
-        dev, host = rep["extent_s"]["device_ops"], \
-            rep["extent_s"]["program_spans"]
-        check(dev[2] >= host[2] - 1.0,
-              f"the device planes end at {dev[2]:.3f} s, the program's "
-              f"spans at {host[2]:.3f} s")
 
 
 # ---------------------------------------------------------------------------
@@ -777,8 +564,7 @@ def serve_family_phase(case: dict, *, platform: str, streams: int,
               "decode_tokens", "prefill_tokens", "prefill_chunks",
               "cache_blocks", "pool_bytes", "p50_token_latency_ms")
               + case["counters"]},
-          **compiles.report(), **device,
-          "peak_bytes_in_use": peak_bytes()})
+          **compile_report(compiles), **device})
     check(device["platform"] == platform,
           f"the engine runs on {device['platform']}")
     check(all(len(o) == new_tokens for o in outs),
@@ -801,140 +587,6 @@ def serve_family_phase(case: dict, *, platform: str, streams: int,
             check(names == sorted(want) and n == sum(want.values()),
                   f"the {what} program's kernels are {names} x {n}, "
                   f"wanted {want}")
-
-
-# ---------------------------------------------------------------------------
-# serve under load: where a client's time to the first token goes
-# ---------------------------------------------------------------------------
-
-def lognormal_length(rng, median: float, sigma: float, lo: int,
-                     hi: int) -> int:
-    return int(np.clip(np.exp(rng.normal(np.log(median), sigma)), lo, hi))
-
-
-LOAD_CONFIG = os.path.join(os.path.dirname(os.path.abspath(__file__)),
-                           "benchmarks", "configs", "olmo-1b.json")
-# every prefill bucket of the olmo-1b block once, then a prompt that
-# shares a block and a half with the last (the prefix-copy program)
-LOAD_WARM_LENS = (16, 40, 100, 200, 400, 800, 1500, 1500)
-
-
-def serve_load_phase(config: dict, *, platform: str, clients: int,
-                     seconds: float, trace_s: float, seed: int,
-                     warm_lens=LOAD_WARM_LENS, prompt_median: int = 256,
-                     output_median: int = 96) -> None:
-    """The load of PERF.md's finding 3 (PR 23): one replica of a
-    benchmark configuration (`benchmarks/configs/olmo-1b.json`) with its
-    `serve` block, `clients` closed-loop streams (each sends its next
-    request when the last one ends; prompts lognormal median 256,
-    outputs median 96). A profiler trace of `trace_s` is taken inside
-    the replica once the load is steady. Prints the client's view beside
-    the program's own split of the time to the first token."""
-    from benchmarks.harness.common import gpt_kwargs
-    block = config["program"]["serve"]
-    top = block["max_len"]
-    cfg_kwargs = {**gpt_kwargs(config), **config["program"]["model"]}
-    ray_tpu.init()
-    try:
-        app = serve.deployment(
-            SmokeReplica, num_replicas=1,
-            ray_actor_options={"num_tpus": 1},
-            max_concurrent_queries=block["max_concurrent_queries"],
-        ).bind(cfg_kwargs, slots=block["slots"], max_len=block["max_len"],
-               seed=seed, engine_kwargs=block["engine_kwargs"])
-        handle = serve.run(app, name="smoke-load")
-        handle._refresh(force=True)
-        replica = handle._replicas[0]
-        compiled = in_replica(replica, "warm", list(warm_lens), 4)
-        stop_at = [None]
-        done: list = []          # (t_call, ttft_s, total_s, tokens)
-        arrivals: list = []      # every token's time at its client
-        errors: list = []
-
-        def client(i):
-            crng = np.random.default_rng([seed, i])
-            try:
-                while time.perf_counter() < stop_at[0]:
-                    n_in = lognormal_length(
-                        crng, prompt_median, 0.8, prompt_median // 8,
-                        top * 3 // 4)
-                    n_out = lognormal_length(
-                        crng, output_median, 0.7, output_median // 12,
-                        top * 7 // 32)
-                    prompt = crng.integers(
-                        0, cfg_kwargs["vocab_size"], n_in).astype(np.int32)
-                    t_call = time.perf_counter()
-                    first, n = None, 0
-                    for _ in handle.stream(prompt, n_out,
-                                           timeout=PHASE_TIMEOUT_S):
-                        arrivals.append(time.perf_counter())
-                        if first is None:
-                            first = arrivals[-1] - t_call
-                        n += 1
-                    done.append((t_call, first,
-                                 time.perf_counter() - t_call, n))
-            except BaseException as e:
-                errors.append(e)
-
-        t0 = time.perf_counter()
-        stop_at[0] = t0 + seconds
-        threads = [threading.Thread(target=client, args=(i,))
-                   for i in range(clients)]
-        for t in threads:
-            t.start()
-        time.sleep(max(0.0, seconds - trace_s) * 0.6)    # let it fill
-        tracing.clear_spans()       # the split is of the steady load
-        in_replica(replica, "trace_start")
-        t_trace = time.perf_counter()
-        time.sleep(trace_s)
-        trace_report = in_replica(replica, "trace_stop")
-        t_trace_end = time.perf_counter()
-        for t in threads:
-            t.join(PHASE_TIMEOUT_S)
-        check(not any(t.is_alive() for t in threads),
-              "a client did not finish")
-        if errors:
-            raise errors[0]
-        wall_s = time.perf_counter() - t0
-        trace_report["requests"] = request_split()
-        stats = in_replica(replica, "stats")
-        device = {k: stats[k]
-                  for k in ("platform", "device_kind", "device_count")}
-    finally:
-        serve.shutdown()
-        ray_tpu.shutdown()
-        stop_workers_or_fail()
-
-    # the recorder's split is of the requests that ended from the
-    # trace's start on: the client's view of the same ones beside it
-    late = [d[1] * 1e3 for d in done
-            if d[0] + d[2] >= t_trace and d[1] is not None]
-    ttft = [d[1] for d in done if d[1] is not None]
-    emit({"phase": "serve_load", "device": device, "clients": clients,
-          "seconds": seconds, "wall_s": wall_s,
-          "programs_compiled_in_warm_up": compiled,
-          "requests_ended": len(done),
-          "traced_from_s": t_trace - t0, "traced_to_s": t_trace_end - t0,
-          "requests_ended_since_trace_start": len(late),
-          "client_ttft_ms_p50_of_those": pct(late, 50),
-          "client_ttft_ms_p99_of_those": pct(late, 99),
-          "client_tokens_per_s": sum(d[3] for d in done) / wall_s,
-          # the load's second half: no ramp, and not the last requests'
-          # tail, which `wall_s` above waits out
-          "client_tokens_per_s_steady": sum(
-              t0 + seconds / 2 <= t < t0 + seconds
-              for t in arrivals) / (seconds / 2),
-          "client_ttft_ms_p50": pct([t * 1e3 for t in ttft], 50),
-          "client_ttft_ms_p99": pct([t * 1e3 for t in ttft], 99),
-          "client_request_s_p50": pct([d[2] for d in done], 50),
-          # mean over the traced window's ticks
-          "occupied_slots_mean": (trace_report["stats"]["slot_occupancy"]
-                                  * block["slots"]),
-          "trace": trace_report})
-    check(device["platform"] == platform,
-          f"replica runs on {device['platform']}, not {platform}")
-    check(len(done) > 0, "no request ended")
-    check_serve_trace(trace_report, on_tpu=platform == "tpu", replicas=1)
 
 
 # ---------------------------------------------------------------------------
@@ -1052,8 +704,8 @@ XENT_KERNELS = {"xent_fwd", "xent_dx", "xent_de"}
 
 def train_phase(cfg_kwargs: dict, *, platform: str, batch: int,
                 steps: int, seed: int) -> None:
-    """One chip: the bench.py configuration through the training loop,
-    checked against the XLA/dense step."""
+    """One chip: `TRAIN_CFG` through the training loop, checked against
+    the XLA/dense step."""
     import jax
 
     from ray_tpu.models import gpt
@@ -1070,10 +722,9 @@ def train_phase(cfg_kwargs: dict, *, platform: str, batch: int,
     ref_loss = first_loss_xla_dense(cfg, devices, batch=batch, seed=seed)
     run = train_run(cfg, MeshSpec(data=1), devices, batch=batch,
                     steps=steps, unroll=4, seed=seed, watch=watch)
-    emit({"phase": "train", "device": device, **run,
+    emit({"phase": "train", "device": device_report(), **run,
           "first_loss_xla_dense": ref_loss, "op_fallbacks": fallbacks,
-          "cache_dir": cache_dir, **watch.report(),
-          "peak_bytes_in_use": peak_bytes()})
+          "cache_dir": cache_dir, **compile_report(watch)})
     on_tpu = platform == "tpu"
     check_train_run(run, TRAIN_KERNELS | XENT_KERNELS if on_tpu else set())
     check(abs(run["losses"][0] - ref_loss) <= LOSS_TOL,
@@ -1113,12 +764,11 @@ def train4_phase(cfg_kwargs: dict, *, platform: str, batch: int,
                      jax.devices(), **common)
     four_fallbacks = fallbacks[len(one_fallbacks):]
     gaps = [abs(a - b) for a, b in zip(one["losses"], four["losses"])]
-    emit({"phase": "train4", "device": device, "one_chip": one,
+    emit({"phase": "train4", "device": device_report(), "one_chip": one,
           "four_chips": four, "loss_gaps": gaps,
           "op_fallbacks_one_chip": one_fallbacks,
           "op_fallbacks_four_chips": four_fallbacks,
-          "cache_dir": cache_dir, **watch.report(),
-          "peak_bytes_in_use": peak_bytes()})
+          "cache_dir": cache_dir, **compile_report(watch)})
     on_tpu = platform == "tpu"
     check_train_run(one, TRAIN_KERNELS | XENT_KERNELS if on_tpu else set())
     check_train_run(four, TRAIN_KERNELS if on_tpu else set())
@@ -1158,11 +808,9 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", choices=("train", "train4", "serve-load",
+    ap.add_argument("--phase", choices=("train", "train4",
                                         "serve-retention", "serve-hybrid"),
-                    help="serve-load: see the top of this file; train, "
-                    "train4, serve-retention and serve-hybrid are how a "
-                    "phase child is started")
+                    help="how a phase child is started")
     args = ap.parse_args()
 
     if args.phase == "train":
@@ -1205,11 +853,7 @@ def main() -> int:
           "JAX_COMPILATION_CACHE_DIR":
               os.environ.get("JAX_COMPILATION_CACHE_DIR")})
     try:
-        if args.phase == "serve-load":
-            with open(LOAD_CONFIG) as f:
-                serve_load_phase(json.load(f), platform="tpu", clients=16,
-                                 seconds=75.0, trace_s=10.0, seed=args.seed)
-        elif args.chips == 1:
+        if args.chips == 1:
             serve_phase(WIDTHS, platform="tpu", replicas=1, streams=6,
                         prompt_lens=(128, 512), new_tokens=64, slots=8,
                         max_len=1024, seed=args.seed)

@@ -60,7 +60,7 @@ class EngineSampler:
         self.temperature = float(temperature)
         self.eos_id = eos_id
         self.pad_to = int(pad_to) if pad_to is not None else engine.max_len
-        # last-rollout throughput (bench_infer's rollout_tok_s probe)
+        # last-rollout throughput (the flywheel reports it: rl/flywheel.py)
         self.last_rollout_tok_s = 0.0
         self.last_rollout_tokens = 0
 

@@ -1,7 +1,7 @@
 """Recurrent policy support: LSTM Q-module + stateful in-graph sampler.
 
-The structural piece VERDICT r3 flagged missing: nothing in rollout.py
-carried policy state. TPU-first design: the recurrent state is just
+Nothing in rollout.py carries policy state; this module does.
+TPU-first design: the recurrent state is just
 another pytree in the scan carry — the whole rollout (env vmap + LSTM
 step + epsilon-greedy) stays one compiled `lax.scan`, and the sampler
 emits fixed-length fragments WITH the state snapshot at fragment start.

@@ -779,7 +779,7 @@ class InferenceEngine:
 
         # Capacity gauges: total device bytes of the block pool(s) and
         # the bytes one cached position costs — the lever kv_dtype
-        # pulls (stats()/bench_infer surface both).
+        # pulls (`stats()` gives both: `pool_bytes`, `kv_bytes_per_token`).
         self._pool_bytes = sum(
             int(arr.nbytes) for arr in self.cache.values())
         if self.draft_cache is not None:
@@ -2931,8 +2931,9 @@ class InferenceEngine:
     def stats(self) -> dict:
         """The engine's one stats contract — this dict feeds the serve
         autoscaler (`autoscaler.load_metrics.
-        replica_demands_from_engine_stats`), `bench_infer.py`'s JSON,
-        and the RL flywheel's staleness accounting. Keys:
+        replica_demands_from_engine_stats`), the benchmark's serving
+        cells (`benchmarks/harness/serve_replica.ENGINE_STATS`), and the
+        RL flywheel's staleness accounting. Keys:
 
         Scheduler/throughput:
           ``slots`` / ``active`` / ``pending`` — slot capacity, occupied

@@ -21,8 +21,9 @@ SPMD trainers:
     on a host sync.
 
 `ray_tpu.data.Dataset.iter_device_batches` bridges `iter_batches` into a
-`DevicePrefetcher`, and `bench.py` streams fresh host batches through the
-whole thing.
+`DevicePrefetcher`; the benchmark's training cells
+(`benchmarks/harness/train_cell.py`) stream fresh host batches through
+the whole thing.
 """
 
 from __future__ import annotations

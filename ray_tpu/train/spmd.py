@@ -431,14 +431,3 @@ def make_latent_moe_trainer(cfg, mesh: Mesh, rng=None,
         mesh, rng, optimizer, rules, init_state=init_state,
         aux_update=partial(lsm.update_router_bias, cfg=cfg),
         frozen=lsm.is_router_bias)
-
-
-def train_flops_per_token(cfg, seq_len: int) -> float:
-    """Approximate model FLOPs per trained token (fwd+bwd ≈ 3x fwd), for
-    MFU reporting."""
-    d, f, L = cfg.d_model, cfg.d_ff, cfg.n_layers
-    h = cfg.n_heads * cfg.head_dim
-    matmuls = 2 * (3 * d * h + h * d + 3 * d * f)      # qkv+o+glu-mlp
-    attn = 2 * 2 * seq_len * h                         # scores + p@v
-    embed = 2 * d * cfg.vocab_size                     # logits matmul
-    return 3.0 * (L * (matmuls + attn) + embed)        # fwd + 2x bwd

@@ -18,7 +18,7 @@ _CHECKOUT = os.path.dirname(os.path.dirname(os.path.dirname(
 
 def enable_compile_cache() -> str:
     """Call before the first jit of a process that compiles for a chip
-    (chip workers, the benches, `chip_smoke.py`'s children). Returns the
+    (chip workers, `chip_smoke.py`'s children). Returns the
     directory in use."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:

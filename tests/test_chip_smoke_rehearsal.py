@@ -64,26 +64,6 @@ def test_serve_hybrid_phase_tiny_on_cpu():
     assert '"phase": "serve_hybrid"' in out
 
 
-def test_serve_load_phase_tiny_on_cpu():
-    """16 closed-loop streams for a few seconds on a tiny model, with
-    the profiler trace taken inside the replica."""
-    out = run(
-        "import json\n"
-        "cfg = json.load(open(cs.LOAD_CONFIG))\n"
-        "cfg.update(hidden_size=128, num_hidden_layers=2, "
-        "num_attention_heads=4, num_key_value_heads=4, head_dim=32, "
-        "intermediate_size=512, vocab_size=512, "
-        "max_position_embeddings=128)\n"
-        "cfg['program']['model']['dtype'] = 'float32'\n"
-        "cfg['program']['serve'] = dict(slots=4, max_len=128, "
-        "engine_kwargs=dict(cache_blocks=64), max_concurrent_queries=64)\n"
-        "cs.serve_load_phase(cfg, platform='cpu', clients=16, seconds=6.0, "
-        "trace_s=2.0, seed=0, warm_lens=(8, 20, 40, 90, 90), "
-        "prompt_median=32, output_median=12)",
-        RAY_TPU_NUM_TPUS="1")
-    assert '"phase": "serve_load"' in out
-
-
 def test_train_phase_tiny_on_cpu():
     out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
               "steps=12, seed=0)")

@@ -40,8 +40,7 @@ def test_multi_agent_env_contract():
 
 def test_mappo_learns_cooperative_toy():
     """Shared-reward coordination: MAPPO with per-agent policies reaches
-    >=12 of the optimal 16 episode reward (the VERDICT acceptance
-    criterion: multi-agent PPO learns a cooperative toy env)."""
+    >=12 of the optimal 16 episode reward."""
     result = run_tuned_example(
         [p for p in list_tuned_examples() if "coopmatch-mappo" in p][0],
         verbose=False)

@@ -85,9 +85,8 @@ def test_py_modules(ray_session, tmp_path):
 
 @pytest.mark.slow
 def test_pip_wheel_in_actor(ray_session, tmp_path):
-    """An actor imports a pip package the driver doesn't have — the
-    VERDICT's acceptance criterion for runtime envs (venv created with
-    --system-site-packages, wheel installed offline)."""
+    """An actor imports a pip package the driver doesn't have (venv created
+    with --system-site-packages, wheel installed offline)."""
     wheel = _make_wheel(tmp_path)
 
     @ray_tpu.remote(runtime_env={"pip": [wheel]})

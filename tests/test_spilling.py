@@ -72,7 +72,7 @@ def test_arena_overflow_and_proactive_spill(tmp_path):
 
 def test_data_pipeline_4x_arena_completes(tmp_path):
     """A Data pipeline whose working set is ~4x the arena finishes with
-    bounded shm usage (the VERDICT churn criterion): blocks overflow to
+    bounded shm usage: blocks overflow to
     the disk spill dir, never to tmpfs fallback files."""
     script = textwrap.dedent(f"""
         import sys; sys.path.insert(0, {REPO!r})
