@@ -23,6 +23,9 @@ the paged kernels: the power-retention family (`models/retention.py`,
 `--phase serve-retention`: a state block and no pages) and the KDA-and-
 latent family (`models/linear_latent.py`, `--phase serve-hybrid`: a state
 block and growing latent pages in one pool, experts chosen by groups).
+Last of the serving families, the window-and-full one
+(`models/window_moe.py`, `--phase serve-window`: grouped key-value heads,
+pages that grow beside a ring of pages that do not, four `gqa_*` kernels).
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -446,6 +449,21 @@ HYBRID_LOGPROB_MAX_TOL = 5e-1
 HYBRID_LOGPROB_MEAN_TOL = 3e-2
 
 
+# `models/window_moe.py` at the published head (128 dims, pages of 128, so
+# the four `gqa_*` kernels have a plan) and otherwise tiny: one period
+# (window x 3, full), 16 query heads over 2 key-value heads, a window of
+# 256 positions so that the longer prompts pass it and their ring of 7
+# pages is written again in place, 8 held experts of a 32-wide router
+WINDOW_CFG = dict(
+    vocab_size=512, d_model=256, n_layers=4, n_heads=16, n_kv_heads=2,
+    head_dim=128, window=256, expert_ff=128, shared_experts=4,
+    router_width=32, held_count=8, experts_per_token=4, max_seq_len=1024)
+# bfloat16 activations against the float32 definition, four layers
+# (chip run, PR 44: 0.222 largest, 0.0152 mean over six streams)
+WINDOW_LOGPROB_MAX_TOL = 5e-1
+WINDOW_LOGPROB_MEAN_TOL = 5e-2
+
+
 def retention_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
@@ -493,12 +511,39 @@ def hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
         "tolerances": (HYBRID_LOGPROB_MAX_TOL, HYBRID_LOGPROB_MEAN_TOL)}
 
 
+def window_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import window_moe
+    cfg = window_moe.WindowMoEConfig(**cfg_kwargs)
+    n_window = cfg.kinds.count("window")
+    n_full = cfg.n_layers - n_window
+    return {
+        "phase": "serve_window", "family": window_moe, "cfg": cfg,
+        "params": window_moe.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32", attn_impl="jax",
+                                     sparse_impl="jax"),
+        "engine": {"block_size": 128}, "table": 2 * (1024 // 128),
+        "decode_kernels": {"gqa_window_decode": n_window,
+                           "gqa_full_decode": n_full,
+                           "experts_grouped": cfg.n_layers},
+        "prefill_kernels": {"gqa_window_chunk": n_window,
+                            "gqa_full_chunk": n_full,
+                            "experts_grouped_prefill": cfg.n_layers},
+        "counters": ("window_rows_read", "full_rows_read",
+                     "expert_tokens_here", "expert_tokens_routed",
+                     "expert_load_max_over_mean", "bounded_blocks",
+                     "bounded_ring", "bounded_pages_reused"),
+        "tolerances": (WINDOW_LOGPROB_MAX_TOL, WINDOW_LOGPROB_MEAN_TOL)}
+
+
 def serve_family_phase(case: dict, *, platform: str, streams: int,
                        prompt_lens: tuple[int, int], new_tokens: int,
                        slots: int, seed: int) -> None:
-    """The engine over a family that keeps a state a sequence
-    (`retention_case`, `hybrid_case`), in this process: `streams` greedy
-    requests over `slots` state blocks (so blocks are reused), chunked
+    """The engine over a family that keeps more than pages that grow
+    (`retention_case`, `hybrid_case`: a state a sequence; `window_case`:
+    a ring of window pages), in this process: `streams` greedy
+    requests over `slots` slots (so blocks are reused), chunked
     prefill in both buckets and then steps. Holds the streamed logprobs to
     the family's float32 definition over the same tokens, the two programs
     to their kernels by name and number, and `ops.backend.note_fallback`
@@ -573,8 +618,15 @@ def serve_family_phase(case: dict, *, platform: str, streams: int,
           and stats["prefill_traces"] == 2,
           f"compile-once broke: {stats['decode_traces']} decode, "
           f"{stats['prefill_traces']} prefill traces")
-    check(stats["state_resets"] == streams,
-          f"{stats['state_resets']} first chunks for {streams} requests")
+    if "state_resets" in case["counters"]:
+        check(stats["state_resets"] == streams,
+              f"{stats['state_resets']} first chunks for {streams} requests")
+    if "window_rows_read" in case["counters"]:
+        n_window = case["cfg"].kinds.count("window")
+        check(0 < stats["window_rows_read"]
+              < stats["full_rows_read"] * n_window,
+              f"no prompt passed the window: {stats['window_rows_read']} "
+              f"window rows, {stats['full_rows_read']} full rows")
     max_tol, mean_tol = case["tolerances"]
     check(diff.max() <= max_tol and diff.mean() <= mean_tol,
           f"engine logprobs are {diff.max()} (max) / {diff.mean()} (mean) "
@@ -809,7 +861,8 @@ def main() -> int:
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", choices=("train", "train4",
-                                        "serve-retention", "serve-hybrid"),
+                                        "serve-retention", "serve-hybrid",
+                                        "serve-window"),
                     help="how a phase child is started")
     args = ap.parse_args()
 
@@ -821,10 +874,12 @@ def main() -> int:
         train4_phase(TRAIN_CFG, platform="tpu", batch=8, steps=8,
                      seed=args.seed)
         return 0
-    if args.phase in ("serve-retention", "serve-hybrid"):
-        case = (retention_case(RETENTION_CFG, args.seed)
-                if args.phase == "serve-retention"
-                else hybrid_case(HYBRID_CFG, args.seed))
+    cases = {"serve-retention": (retention_case, RETENTION_CFG),
+             "serve-hybrid": (hybrid_case, HYBRID_CFG),
+             "serve-window": (window_case, WINDOW_CFG)}
+    if args.phase in cases:
+        make, cfg_kwargs = cases[args.phase]
+        case = make(cfg_kwargs, args.seed)
         serve_family_phase(case, platform="tpu", streams=6,
                            prompt_lens=(100, 700), new_tokens=32, slots=4,
                            seed=args.seed)
@@ -859,6 +914,7 @@ def main() -> int:
                         max_len=1024, seed=args.seed)
             run_phase_child("serve-retention", args.seed)
             run_phase_child("serve-hybrid", args.seed)
+            run_phase_child("serve-window", args.seed)
             run_phase_child("train", args.seed)
         else:
             run_phase_child("train4", args.seed)

@@ -11,9 +11,10 @@ from typing import Callable, NamedTuple
 class ServingFamily(NamedTuple):
     """What `serve/engine.py` asks of a model family, found as the
     `family` of the configuration object it is given. The pool is a dict
-    of arrays with the blocks on axis 1 of each, of one or two kinds.
+    of arrays with the blocks on axis 1 of each, of up to three kinds.
 
-    init_pool(cfg, n_blocks, block_size, mesh[, state_blocks=]) -> pool
+    init_pool(cfg, n_blocks, block_size, mesh[, state_blocks=]
+              [, bounded_blocks=]) -> pool
     prefill(params, tokens [1, C], pool, cfg, mesh, *, block_table,
             start, length) -> (logits [1, V] f32, pool, counts)
     decode(params, tokens [B], pool, pos, tables, cfg, mesh)
@@ -36,15 +37,29 @@ class ServingFamily(NamedTuple):
     counts(cfg, totals) -> {name: number}: what the int32 vector that
             prefill and decode return third, summed over a window, adds
             to `stats()`; None where they return None
-    What a request holds of the pool (one contract for every family,
-    one footprint arithmetic in the engine): `state_blocks` blocks of
-    fixed size whatever its length, and, where `paged`, one page a
-    `block_size` tokens of its prompt and output, which grow with it. Its
-    block table is `state_blocks` columns of state blocks, then the
-    columns of its pages in order (0: the trash block of that kind).
+    What a request holds of the pool: three things, one contract for
+    every family and one footprint arithmetic in the engine
+    (`InferenceEngine._footprint`).
+      1. `state_blocks` blocks of fixed size whatever its length: a
+         sequence's state, rewritten by every token.
+      2. Where `paged`, pages that grow: one a `block_size` tokens of
+         its prompt and output, kept to the end.
+      3. Where `bounded_tokens`, pages that grow up to a bound: one a
+         `block_size` tokens while the sequence is short, and never more
+         than hold `bounded_tokens` positions and the longest prefill
+         chunk at once. Past that the engine reuses them in place, as a
+         ring: the page that the last `bounded_tokens` positions have
+         left whole takes the next positions.
+    Its block table is `state_blocks` columns of state blocks, then one
+    column a page of `max_len` for the pages that grow, then as many
+    again for the bounded ones (0: the trash block of that kind). Column
+    `j` of either run names the page that holds positions `j * block_size
+    ..`; in a ring several columns name one page, and only the latest of
+    them is true. A family reads no column that its own bound has left.
     `models/gpt.py` is (0, paged): pages alone. `models/retention.py` is
-    (1, not paged): a sequence's whole state, rewritten by every token.
-    `models/linear_latent.py` is (1, paged): both.
+    (1, not paged): a state alone. `models/linear_latent.py` is (1,
+    paged): both. `models/window_moe.py` is (0, paged, bounded): full
+    layers' pages that grow and window layers' pages that do not.
 
     state_blocks: how many blocks of fixed size a request holds
     paged: whether it also holds pages that grow
@@ -56,6 +71,11 @@ class ServingFamily(NamedTuple):
             keeps no prefix tree for such a family (`prefix_cache=True`
             is refused) and re-prefills a preempted stream from its
             first token
+    bounded_keys: the pool's arrays whose axis 1 counts bounded pages,
+            given as `init_pool(..., bounded_blocks=n)`; a page that is
+            overwritten names no lasting range of tokens either, so the
+            same two things hold for such a family
+    bounded_tokens: the bound, in positions (a window); 0: no such kind
     """
     init_pool: Callable
     prefill: Callable
@@ -69,3 +89,5 @@ class ServingFamily(NamedTuple):
     state_blocks: int = 0
     paged: bool = True
     state_keys: tuple = ()
+    bounded_keys: tuple = ()
+    bounded_tokens: int = 0

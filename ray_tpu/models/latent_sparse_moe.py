@@ -402,20 +402,26 @@ def kept_groups(biased, cfg):
 
 
 def router_scores(h2, lp):
-    """-> (sigmoid scores [N, E], scores + the expert bias), float32."""
+    """-> (sigmoid scores [N, E], scores + the expert bias where the
+    layer has one), float32."""
     g = jax.nn.sigmoid(jnp.einsum(
         "nd,de->ne", h2.astype(jnp.float32),
         lp["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
+    if "router_bias" not in lp:
+        return g, g
     return g, g + lp["router_bias"].astype(jnp.float32)
 
 
 def routing(h2, lp, cfg):
     """-> (chosen [N, k] i32, weights [N, k] f32), in float32: a choice
     between two near-equal scores should not turn on the activations'
-    rounding more than it must."""
+    rounding more than it must. Of `cfg` it reads `router_width`,
+    `experts_per_token` and `norm_topk`, and, where the family's router
+    has them, `n_group` with `topk_group` (else one group) and
+    `routed_scale` (else 1)."""
     g, biased = router_scores(h2, lp)
-    if cfg.n_group > 1:
+    if getattr(cfg, "n_group", 1) > 1:
         biased = jnp.where(jnp.repeat(
             kept_groups(biased, cfg), cfg.router_width // cfg.n_group, 1),
             biased, -jnp.inf)
@@ -423,7 +429,8 @@ def routing(h2, lp, cfg):
     weights = jnp.take_along_axis(g, chosen, -1)
     if cfg.norm_topk:
         weights = weights / jnp.sum(weights, -1, keepdims=True)
-    return chosen.astype(jnp.int32), weights * cfg.routed_scale
+    return chosen.astype(jnp.int32), weights * getattr(
+        cfg, "routed_scale", 1.0)
 
 
 def _rounded(a, cfg):

@@ -96,6 +96,12 @@ NEG_INF = -1e30
 # query a head is `paged_decode`; verify and the fused prefill share
 # `paged_mq`.
 PAGED_DECODE, PAGED_MQ = "paged_decode", "paged_mq"
+# The same body over pages that hold one key-value head each
+# (`gqa_decode_attention`, `gqa_chunk_attention`): a window layer's call
+# and a full layer's, the decode step's and a prompt chunk's, so that a
+# trace parts the four.
+GQA_WINDOW_DECODE, GQA_FULL_DECODE = "gqa_window_decode", "gqa_full_decode"
+GQA_WINDOW_CHUNK, GQA_FULL_CHUNK = "gqa_window_chunk", "gqa_full_chunk"
 
 
 # ---------------------------------------------------------------------------
@@ -279,7 +285,8 @@ def reads_pool_where_it_lies(bs: int, h: int, d: int, dtype,
 
 def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
                   sm_scale: float, pack: int, pages: int, block_size: int,
-                  heads: int, queries: int, quantized: bool):
+                  heads: int, queries: int, quantized: bool,
+                  window: int | None = None, head_major: bool = False):
     """One stream's queries, `wt` of them a head a program (all `queries`
     of them in one where the grid's second axis is 1): the stream's live
     pages in turns of `pages`, online softmax over a turn's every head
@@ -314,7 +321,28 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     stream's last live page: `bs - 1 - last % bs` positions, under one
     page of K and one of V a stream a layer. A query row past `queries`
     (the last program's padding) scores what the real ones fetched and
-    is cut away by the wrapper."""
+    is cut away by the wrapper.
+
+    `window`: a query at position `i` keeps the positions `i - window <
+    j <= i` only. The pages wholly before the window of the program's
+    first query are not fetched: the loop starts at the page that holds
+    position `first - window + 1`, whatever column of the table that is,
+    and the mask cuts the rest of that page and, for the later queries
+    of a chunk, what has left their own window. A row that a turn masks
+    whole before any of its scores was kept adds `exp(0)` a column to
+    sums that the first kept score's correction, `exp(NEG_INF - m)`,
+    turns to exactly zero; every row keeps its own position at least.
+
+    `head_major`: the pools are `[L, n_blocks, Hkv, bs, D]`, a page of
+    one key-value head a contiguous `[bs, D]`, and the grid is `(B, Hkv,
+    tiles)`: a program is a stream, one key-value head and `wt` queries
+    of each of the `heads` query heads that read it (`heads` is then the
+    group, 16 of Command A+'s 128 over 8). Its page is fetched once for
+    all of them and every column holds its rows' own head, so no
+    multiplication is masked away; q and K go to the MXU in the type
+    they are stored in and the scale is applied to the float32 scores
+    (the products of two bfloat16 values are exact in float32, so this
+    is the float32 matmul's result at the bfloat16 rate)."""
     if quantized:
         (ks_hbm, vs_hbm, o_ref, kbuf, vbuf, ksbuf, vsbuf, sem,
          m_scr, l_scr, acc_scr) = rest
@@ -323,24 +351,43 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     b = pl.program_id(0)
     bs, mb = block_size, tbl_ref.shape[1]
     layer = layer_ref[0]
-    _, qrows, lanes = q_ref.shape
+    qrows, lanes = q_ref.shape[-2:]
     h = heads
     wt = qrows // h                     # queries a head here
-    hr = h // pack                      # rows of lanes a cached position
+    # rows of lanes a cached position: a page of one head has one
+    hr = 1 if head_major else h // pack
     rows = bs * hr                      # score columns a page
     cols = pages * rows
     size = kbuf.shape[1] // pages       # a page along the buffer's rows
-    t = pl.program_id(1)
+    t = pl.program_id(2 if head_major else 1)
     first = pos_ref[b] + t * wt         # this program's first query's position
     last = pos_ref[b] + jnp.minimum((t + 1) * wt, queries) - 1  # last real
     n_pages = jnp.minimum(last // bs + 1, mb)
-    n_turns = (n_pages + pages - 1) // pages
+    if window is None:
+        n_turns = (n_pages + pages - 1) // pages
+
+        def page_of(c, i=None):         # page `i` of turn `c`
+            return c * pages if i is None else c * pages + i
+    else:
+        page0 = jnp.maximum(first - (window - 1), 0) // bs
+        n_turns = (n_pages - page0 + pages - 1) // pages
+
+        def page_of(c, i=0):
+            return page0 + c * pages + i
+
+    kv_head = pl.program_id(1) if head_major else None
+
+    def at(pool, blk):
+        """Block `blk` of the layer, as one DMA reads it."""
+        if head_major:
+            return pool.at[layer, blk, kv_head]
+        return pool.at[layer, blk]
 
     def copies(blk, slot, i):
         """Page `i` of a turn: block `blk` into buffer `slot`."""
         page, scales = pl.ds(i * size, size), pl.ds(i * rows, rows)
-        pairs = [(k_hbm.at[layer, blk], kbuf.at[slot, page]),
-                 (v_hbm.at[layer, blk], vbuf.at[slot, page])]
+        pairs = [(at(k_hbm, blk), kbuf.at[slot, page]),
+                 (at(v_hbm, blk), vbuf.at[slot, page])]
         if quantized:       # the scales come laid out, one layer's
             pairs += [(ks_hbm.at[blk], ksbuf.at[slot, :, scales]),
                       (vs_hbm.at[blk], vsbuf.at[slot, :, scales])]
@@ -349,14 +396,14 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
 
     def issue(c, slot):
         for i in range(pages):
-            @pl.when(c * pages + i < n_pages)
+            @pl.when(page_of(c, i) < n_pages)
             def _start():
-                for cp in copies(tbl_ref[b, c * pages + i], slot, i):
+                for cp in copies(tbl_ref[b, page_of(c, i)], slot, i):
                     cp.start()
 
     def land(c, slot):
         for i in range(pages):
-            live = c * pages + i < n_pages
+            live = page_of(c, i) < n_pages
 
             @pl.when(live)
             def _wait():        # the semaphore counts bytes, not blocks
@@ -374,7 +421,10 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
-    q = q_ref[0].astype(jnp.float32) * sm_scale             # [wt * H, lanes]
+    if head_major:
+        q = q_ref[0, 0]                                     # [wt * g, lanes]
+    else:
+        q = q_ref[0].astype(jnp.float32) * sm_scale         # [wt * H, lanes]
     row = jax.lax.broadcasted_iota(jnp.int32, (qrows, cols), 0)
     col = jax.lax.broadcasted_iota(jnp.int32, (qrows, cols), 1)
     head, query = row % h, row // h
@@ -383,7 +433,10 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
     # query past the table's reach (a draft step near the longest length)
     # sees what the table's last position sees: no page holds more
     query = jnp.minimum(query, mb * bs - 1 - first)
-    ahead = jnp.where(col % hr == head // pack, col // hr - query, _FAR)
+    if head_major:      # every column holds the rows' own key-value head
+        ahead = col - query
+    else:
+        ahead = jnp.where(col % hr == head // pack, col // hr - query, _FAR)
     issue(0, 0)
 
     def step(c, _):
@@ -394,16 +447,27 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
             issue(c + 1, 1 - slot)
 
         land(c, slot)
-        k = kbuf[slot].astype(jnp.float32).reshape(cols, lanes)
+        if head_major:
+            k = kbuf[slot]
+            both = jnp.promote_types(q.dtype, k.dtype)
+            q_, k = q.astype(both), k.astype(both)
+        else:
+            q_, k = q, kbuf[slot].astype(jnp.float32).reshape(cols, lanes)
         v = vbuf[slot]
         if quantized:
             v = v.astype(jnp.float32)
         s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+            q_, k, dimension_numbers=(((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32)             # [wt * H, cols]
+        if head_major:
+            s = s * sm_scale
         if quantized:   # row r takes the scales of head r % H: r % pack here
             s = s * jnp.tile(ksbuf[slot], (qrows // pack, 1))
-        s = jnp.where(ahead <= first - c * pages * bs, s, NEG_INF)
+        reach = first - page_of(c) * bs     # of the program's first query
+        keep = ahead <= reach
+        if window is not None:
+            keep = keep & (ahead > reach - window)
+        s = jnp.where(keep, s, NEG_INF)
         m_prev = m_scr[:, :1]
         m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
         corr = jnp.exp(m_prev - m_new)
@@ -420,12 +484,17 @@ def _paged_kernel(tbl_ref, pos_ref, layer_ref, q_ref, k_hbm, v_hbm, *rest,
         return _
 
     jax.lax.fori_loop(0, n_turns, step, 0)
-    o_ref[0] = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+    out = (acc_scr[...] / l_scr[:, :1]).astype(o_ref.dtype)
+    if head_major:
+        o_ref[0, 0] = out
+    else:
+        o_ref[0] = out
 
 
 def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
                 plan: _DecodePlan, block_size: int, heads: int, queries: int,
-                sm_scale: float, interpret: bool, ks=None, vs=None):
+                sm_scale: float, interpret: bool, ks=None, vs=None,
+                window: int | None = None, head_major: bool = False):
     """q [B, tiles * wt * H, lanes], a stream's queries as the model
     made them (`_paged_kernel` has the order; `queries` of the `tiles *
     wt` are real); k_pool, v_pool in HBM, stacked: `[L, n_blocks, bs, H,
@@ -440,14 +509,29 @@ def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
     in the order of a page's rows, fetched page by page beside the
     payload and applied, a row of them to the score columns and to the
     probabilities (``q . (k_j * s_j) == (q . k_j) * s_j``, ``p @ (v * s)
-    == (p * s) @ v``: no `[cols, D]` multiply and no relayout)."""
-    b, total, lanes = q.shape
+    == (p * s) @ v``: no `[cols, D]` multiply and no relayout).
+
+    `head_major`: pools `[L, n_blocks, Hkv, bs, D]`, q `[B, Hkv, tiles *
+    wt * g, lanes]` (a key-value head's `heads` = g query heads side by
+    side, row `w * g + j`), `grid = (B, Hkv, tiles)`; `window` as
+    `_paged_kernel` says."""
+    b, total, lanes = q.shape[0], q.shape[-2], q.shape[-1]
     qrows = _query_tile(queries, heads) * heads
     quantized = ks is not None
     hbm = pl.BlockSpec(memory_space=pl.ANY)
-    row = pl.BlockSpec((1, qrows, lanes), lambda i, t, tbl, ps, ly: (i, t, 0))
+    if head_major:
+        grid = (b, q.shape[1], total // qrows)
+        row = pl.BlockSpec((1, 1, qrows, lanes),
+                           lambda i, j, t, tbl, ps, ly: (i, j, t, 0))
+        turn = (2, plan.pages * block_size, lanes)
+    else:
+        grid = (b, total // qrows)
+        row = pl.BlockSpec((1, qrows, lanes),
+                           lambda i, t, tbl, ps, ly: (i, t, 0))
+        turn = (2, plan.pages * k_pool.shape[2]) + k_pool.shape[3:]
+    more = {} if window is None and not head_major else {
+        "window": window, "head_major": head_major}
     operands = [tables, pos, layer, q, k_pool, v_pool]
-    turn = (2, plan.pages * k_pool.shape[2]) + k_pool.shape[3:]
     scratch = [pltpu.VMEM(turn, k_pool.dtype)] * 2
     if quantized:
         operands += [ks, vs]
@@ -458,7 +542,7 @@ def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
                 pltpu.VMEM((qrows, 128), jnp.float32),    # l
                 pltpu.VMEM((qrows, lanes), jnp.float32)]  # acc
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3, grid=(b, total // qrows),
+        num_scalar_prefetch=3, grid=grid,
         in_specs=[row] + [hbm] * (len(operands) - 4),
         out_specs=row, scratch_shapes=scratch)
     with jax.named_scope(name):
@@ -466,13 +550,13 @@ def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
             functools.partial(_paged_kernel, sm_scale=sm_scale,
                               pack=plan.pack, pages=plan.pages,
                               block_size=block_size, heads=heads,
-                              queries=queries, quantized=quantized),
+                              queries=queries, quantized=quantized, **more),
             name=name,
             out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
             grid_spec=grid_spec,
             compiler_params=pltpu.CompilerParams(
                 # streams, and a stream's tiles of queries, share nothing
-                dimension_semantics=("parallel", "parallel"),
+                dimension_semantics=("parallel",) * len(grid),
                 vmem_limit_bytes=plan.vmem_limit),
             interpret=interpret,
         )(*operands)
@@ -787,3 +871,161 @@ def paged_prefill_attention(q, k_pool, v_pool, table, start, *,
     return _paged_attend(PAGED_MQ, q[None], k_pool, v_pool, table[None],
                          jnp.asarray(start).reshape(1), k_scale, v_scale,
                          layer, plan)[0]
+
+
+# ---------------------------------------------------------------------------
+# grouped key-value heads over head-major pages, with or without a window
+# ---------------------------------------------------------------------------
+
+def reference_gqa_attention(q, k, v, pos, window=None):
+    """q [B, W, Hq, D], query `i` of row `b` at position ``pos[b] + i``;
+    k, v [B, S, Hkv, D]; query head `h` reads key-value head ``h // (Hq
+    // Hkv)`` at the positions ``j <= pos[b] + i`` and, with `window`,
+    ``j > pos[b] + i - window``. -> [B, W, Hq, D] in q.dtype, float32
+    inside: the definition the kernels below are held to."""
+    b, w, hq, d = q.shape
+    s, hkv = k.shape[1:3]
+    qg = q.astype(jnp.float32).reshape(b, w, hkv, hq // hkv, d)
+    scores = jnp.einsum("bwkgd,bskd->bkgws", qg, k.astype(jnp.float32),
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    at = (pos.astype(jnp.int32)[:, None]
+          + jnp.arange(w, dtype=jnp.int32))[:, :, None]       # [B, W, 1]
+    cols = jnp.arange(s, dtype=jnp.int32)
+    live = cols <= at
+    if window is not None:
+        live &= cols > at - window
+    p = jax.nn.softmax(jnp.where(live[:, None, None], scores, NEG_INF), -1)
+    out = jnp.einsum("bkgws,bskd->bwkgd", p, v.astype(jnp.float32),
+                     preferred_element_type=jnp.float32)
+    return out.reshape(b, w, hq, d).astype(q.dtype)
+
+
+def gather_head_major(pool, tables):
+    """`pool [n_blocks, Hkv, bs, D]` through `tables [B, max_blocks]` ->
+    `[B, max_blocks * bs, Hkv, D]`: logical position `p` of row `b` is
+    `pool[tables[b, p // bs], :, p % bs]`."""
+    b, mb = tables.shape
+    _, hkv, bs, d = pool.shape
+    return pool[tables.astype(jnp.int32)].transpose(0, 1, 3, 2, 4).reshape(
+        b, mb * bs, hkv, d)
+
+
+def reference_gqa_paged_attention(q, k_pool, v_pool, tables, pos,
+                                  window=None):
+    """`reference_gqa_attention` over one layer's head-major pools
+    `[n_blocks, Hkv, bs, D]` gathered through `tables`."""
+    return reference_gqa_attention(q, gather_head_major(k_pool, tables),
+                                   gather_head_major(v_pool, tables), pos,
+                                   window)
+
+
+_DECODE_TURN, _CHUNK_TURN = 1024, 512   # cached positions a turn, at most
+
+
+def _gqa_plan(bs: int, g: int, d: int, dtype, w: int,
+              vmem: int | None = None) -> _DecodePlan | None:
+    """`_decode_plan` for head-major pools `[.., Hkv, bs, d]` and `w`
+    queries of each of `g` heads a program: pages a turn from `_DECODE_TURN`
+    (one query a head: the turn is all DMA) or `_CHUNK_TURN` positions,
+    halved until two turns of K and V, the score tiles and the running
+    state fit the default scope. None where a page is not whole tiles."""
+    item = jnp.dtype(dtype).itemsize
+    if d % 128 or bs % (8 * 4 // item) or (w * g) % 8:
+        return None
+    qrows = w * g
+    state = qrows * (d * (4 * max(item, 2) + 4) + 128 * 4 * 2)
+
+    def working_set(pages):
+        cols = pages * bs
+        return (2 * 2 * cols * d * item             # K and V, two turns
+                + 5 * qrows * cols * 2 + state)     # mask, s, p; see above
+
+    pages = max(1, (_DECODE_TURN if w == 1 else _CHUNK_TURN) // bs)
+    while pages > 1 and working_set(pages) > backend.SCOPED_VMEM_DEFAULT:
+        pages //= 2
+    need = working_set(pages)
+    if need <= backend.SCOPED_VMEM_DEFAULT:
+        return _DecodePlan(1, pages, None)
+    if need > (vmem or backend.vmem_capacity()) // 2:
+        return None
+    return _DecodePlan(1, pages, _up(need + need // 4, 1 << 20))
+
+
+def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
+                  window: int | None = None, impl: str = "auto"):
+    """Attention of ``q [B, W, Hq, D]`` (query `i` of stream `b` at
+    position ``pos[b] + i``, written to the pool before this call) over
+    stacked head-major pools ``[L, n_blocks, Hkv, bs, D]``, layer
+    `layer` of them, through ``tables [B, max_blocks]`` whose column `j`
+    names the page of positions ``j * bs ..`` wherever the engine keeps
+    it (a window layer's pages may be a ring: a column the window has
+    left names a page that holds later positions by now, and is never
+    read). Query head `h` reads key-value head ``h // (Hq // Hkv)``;
+    with `window` the positions ``pos[b] + i - window < j <= pos[b] + i``.
+    -> ``[B, W, Hq, D]`` in q.dtype.
+
+    The kernel is `_paged_kernel` under `name`, `head_major`: a program
+    is a stream, a key-value head and a tile of queries; its pages are
+    fetched once for the head's whole group and, with a window, from the
+    first page the window reaches. The jax path gathers the layer
+    through the whole table (`reference_gqa_paged_attention`)."""
+    b, w, hq, d = q.shape
+    if k_pool.ndim != 5 or tables.ndim != 2 or hq % k_pool.shape[2]:
+        raise ValueError(
+            f"gqa_attention wants q [B, W, Hq, D], pools [L, n_blocks, "
+            f"Hkv, bs, D] and tables [B, max_blocks]; got {q.shape}, "
+            f"{k_pool.shape}, {tables.shape}")
+    hkv, bs = k_pool.shape[2:4]
+    g = hq // hkv
+    wt = _query_tile(w, g)
+    plan = _gqa_plan(bs, g, d, k_pool.dtype, wt)
+    if impl == "auto":
+        if plan is None:
+            backend.note_fallback(name, f"block_size {bs}, {w} queries of "
+                                  f"{hq} heads over {hkv} of {d}")
+        impl = "pallas" if backend.on_tpu() and plan is not None else "jax"
+    if impl == "jax":
+        return reference_gqa_paged_attention(
+            q, _layer_of(k_pool, layer), _layer_of(v_pool, layer), tables,
+            pos, window)
+    if impl != "pallas":
+        raise ValueError(f"unknown {name} impl {impl!r}")
+    if plan is None:
+        if not backend.interpret():
+            raise ValueError(f"no kernel plan for {name} at {q.shape} over "
+                             f"{k_pool.shape}; use impl='jax'")
+        plan = _DecodePlan(1, max(1, _TURN_TOKENS // bs), None)
+    if w % wt:
+        q = jnp.pad(q, ((0, 0), (0, -w % wt), (0, 0), (0, 0)))
+    # a key-value head's g query heads side by side: [B, Hkv, W * g, D]
+    rows = q.reshape(b, -1, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, hkv, -1, d)
+    out = _paged_call(name, rows, k_pool, v_pool, tables.astype(jnp.int32),
+                      pos.astype(jnp.int32),
+                      jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
+                      block_size=bs, heads=g, queries=w, sm_scale=d ** -0.5,
+                      interpret=backend.interpret(), window=window,
+                      head_major=True)
+    return out.reshape(b, hkv, -1, g, d).transpose(0, 2, 1, 3, 4).reshape(
+        b, -1, hq, d)[:, :w]
+
+
+def gqa_decode_attention(q, k_pool, v_pool, tables, pos, *, layer,
+                         window: int | None = None, impl: str = "auto"):
+    """The decode step's form: ``q [B, Hq, D]`` at ``pos [B]``
+    (`gqa_window_decode` with a window, `gqa_full_decode` without)."""
+    name = GQA_FULL_DECODE if window is None else GQA_WINDOW_DECODE
+    return gqa_attention(name, q[:, None], k_pool, v_pool, tables, pos,
+                         layer=layer, window=window, impl=impl)[:, 0]
+
+
+def gqa_chunk_attention(q, k_pool, v_pool, table, start, *, layer,
+                        window: int | None = None, impl: str = "auto"):
+    """A prompt chunk's form: ``q [C, Hq, D]`` of one sequence, token `t`
+    at position ``start + t``, ``table [max_blocks]`` (`gqa_window_chunk`
+    with a window, `gqa_full_chunk` without). A window layer's call
+    fetches the pages that intersect ``[start - window + 1, start + C)``."""
+    name = GQA_FULL_CHUNK if window is None else GQA_WINDOW_CHUNK
+    return gqa_attention(name, q[None], k_pool, v_pool, table[None],
+                         jnp.asarray(start).reshape(1), layer=layer,
+                         window=window, impl=impl)[0]

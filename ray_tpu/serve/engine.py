@@ -176,23 +176,27 @@ class BlockAllocator:
     (never handed out — idle decode rows scatter there), so a pool of
     ``n_blocks`` has ``n_blocks - 1`` usable blocks.
 
-    One space of ids for the two kinds of block a family may keep
+    One space of ids for the three kinds of block a family may keep
     (`models.family.ServingFamily`): ids ``1 .. n_state`` are state
-    blocks, the ids after them pages, each kind with a free list of its
-    own. A page's id less ``n_state`` is its index on axis 1 of the
-    pool's paged arrays; a state block's id is its index in the state
-    arrays.
+    blocks, the next ``n_bounded`` ids bounded pages, the ids after them
+    pages that grow, each kind with a free list of its own. A block's
+    id less the ids of the kinds before it is its index on axis 1 of
+    the pool's arrays of its kind (`index_of`).
 
     Invariants (asserted by `check()` and the fuzz tests): a block is
     either free with refcount 0 or allocated with refcount >= 1;
-    used + free + free_state == n_blocks - 1; decref of a free block
-    (double free) raises."""
+    used + free + free_state + free_bounded == n_blocks - 1, and a
+    block freed returns to the list of its own kind; decref of a free
+    block (double free) raises."""
 
-    def __init__(self, n_blocks: int, n_state: int = 0):
+    def __init__(self, n_blocks: int, n_state: int = 0, n_bounded: int = 0):
         if n_blocks < 2:
             raise ValueError("need at least one usable block")
         self.n_blocks, self.n_state = n_blocks, n_state
-        self._free = list(range(n_blocks - 1, n_state, -1))  # pop() -> 1, 2…
+        self.n_bounded = n_bounded
+        first_page = n_state + n_bounded
+        self._free = list(range(n_blocks - 1, first_page, -1))  # pop() -> 1, 2…
+        self._free_bounded = list(range(first_page, n_state, -1))
         self._free_state = list(range(n_state, 0, -1))
         self._ref = [0] * n_blocks
 
@@ -206,11 +210,33 @@ class BlockAllocator:
         return len(self._free_state)
 
     @property
-    def used(self) -> int:
-        return (self.n_blocks - 1) - len(self._free) - len(self._free_state)
+    def free_bounded(self) -> int:
+        return len(self._free_bounded)
 
-    def alloc(self, state: bool = False) -> int:
-        free = self._free_state if state else self._free
+    @property
+    def used(self) -> int:
+        return ((self.n_blocks - 1) - len(self._free)
+                - len(self._free_state) - len(self._free_bounded))
+
+    def kind_of(self, block: int) -> str:
+        """"state", "bounded" or "page", by the block's id."""
+        if block <= self.n_state:
+            return "state"
+        return ("bounded" if block <= self.n_state + self.n_bounded
+                else "page")
+
+    def index_of(self, block: int) -> int:
+        """The block's index on axis 1 of the pool's arrays of its kind."""
+        return block - {"state": 0, "bounded": self.n_state,
+                        "page": self.n_state + self.n_bounded}[
+                            self.kind_of(block)]
+
+    def _free_list(self, kind: str) -> list:
+        return {"state": self._free_state, "bounded": self._free_bounded,
+                "page": self._free}[kind]
+
+    def alloc(self, kind: str = "page") -> int:
+        free = self._free_list(kind)
         if not free:
             raise RuntimeError("out of KV cache blocks")
         b = free.pop()
@@ -227,17 +253,21 @@ class BlockAllocator:
             raise RuntimeError(f"double free of block {block}")
         self._ref[block] -= 1
         if self._ref[block] == 0:
-            (self._free_state if block <= self.n_state
-             else self._free).append(block)
+            self._free_list(self.kind_of(block)).append(block)
 
     def refcount(self, block: int) -> int:
         return self._ref[block]
 
     def check(self):
-        assert self.used + self.free + self.free_state == self.n_blocks - 1
-        free = set(self._free) | set(self._free_state)
-        assert len(free) == self.free + self.free_state, \
-            "free-list duplicate"
+        n_free = self.free + self.free_state + self.free_bounded
+        assert self.used + n_free == self.n_blocks - 1
+        free = (set(self._free) | set(self._free_state)
+                | set(self._free_bounded))
+        assert len(free) == n_free, "free-list duplicate"
+        for kind in ("state", "bounded", "page"):
+            assert all(self.kind_of(b) == kind
+                       for b in self._free_list(kind)), \
+                f"a block of another kind among the free {kind} blocks"
         for b in range(1, self.n_blocks):
             if b in free:
                 assert self._ref[b] == 0, f"free block {b} has refs"
@@ -600,6 +630,7 @@ class InferenceEngine:
                  prefill_buckets: tuple[int, ...] | None = None,
                  block_size: int = 16,
                  cache_blocks: int | None = None,
+                 bounded_blocks: int | None = None,
                  prefill_chunk: int | None = None,
                  prefix_cache: bool = True,
                  spec: str | None = None, spec_k: int = 4,
@@ -648,14 +679,14 @@ class InferenceEngine:
         # of them; `cache_blocks` counts the pages (for a family without
         # pages the state blocks, its only kind).
         self._state_blocks = fam.state_blocks
-        if self._state_blocks and prefix_cache:
+        if (self._state_blocks or fam.bounded_tokens) and prefix_cache:
             raise ValueError(
                 f"prefix_cache=True: a request of {type(cfg).__name__}'s "
                 "family holds a sequence's state, rewritten by every "
-                "token, which names no range of tokens to share as a "
-                "prefix; pass prefix_cache=False")
+                "token, or pages that are written again once a window has "
+                "left them; neither names a lasting range of tokens to "
+                "share as a prefix; pass prefix_cache=False")
         max_pages = -(-self.max_len // block_size) if fam.paged else 0
-        self.max_blocks = self._state_blocks + max_pages
         n_state = slots * self._state_blocks
         if not fam.paged:
             n_state, n_pages = (n_state if cache_blocks is None
@@ -663,10 +694,6 @@ class InferenceEngine:
         else:
             n_pages = (slots * max_pages if cache_blocks is None
                        else cache_blocks)
-        self.cache_blocks = n_state + n_pages
-        # the largest footprint that can ever be admitted: a request's
-        # own state blocks and every page
-        self._max_footprint = self._state_blocks + n_pages
         self.buckets = tuple(sorted(
             b for b in (prefill_buckets or _default_buckets(self.max_len))
             if b <= self.max_len))
@@ -679,11 +706,42 @@ class InferenceEngine:
         self.chunk_buckets = tuple(sorted(
             {b for b in self.buckets if b < self.prefill_chunk}
             | {self.prefill_chunk}))
+        # Bounded pages (`ServingFamily.bounded_tokens`, a window): a
+        # request holds them as a ring of at most `_ring` pages, what the
+        # window and the longest chunk can have live at once (a chunk at
+        # `n` writes up to page `(n + C - 1) // bs` while its first query
+        # still reads page `(n - window + 1) // bs`). Logical page `j`
+        # lies in ring page `j % _ring`; the table spells that out a
+        # column a logical page, so the family never sees the ring.
+        if fam.bounded_tokens:
+            if not fam.paged:
+                raise ValueError("bounded pages go beside pages that grow")
+            self._ring = min(max_pages, -(-(
+                fam.bounded_tokens + self.prefill_chunk - 2) // block_size)
+                + 1)
+            n_bounded = (slots * self._ring if bounded_blocks is None
+                         else bounded_blocks)
+            if n_bounded < self._ring:
+                raise ValueError(
+                    f"bounded_blocks {n_bounded} holds no request's ring "
+                    f"of {self._ring} pages")
+        else:
+            self._ring = n_bounded = 0
+        self.bounded_blocks = n_bounded
+        self.max_blocks = self._state_blocks + max_pages * (
+            2 if self._ring else 1)
+        self.cache_blocks = n_state + n_bounded + n_pages
+        # the largest footprint that can ever be admitted: a request's
+        # own state blocks, every page, and a ring
+        self._max_footprint = (self._state_blocks + n_pages
+                               + min(n_pages, self._ring))
         # +1: physical block 0 is the trash block (idle rows write there).
         self.cache = fam.init_pool(
             cfg, n_pages + 1, block_size, mesh,
-            **({"state_blocks": n_state + 1} if n_state else {}))
-        self._alloc = BlockAllocator(self.cache_blocks + 1, n_state)
+            **({"state_blocks": n_state + 1} if n_state else {}),
+            **({"bounded_blocks": n_bounded + 1} if n_bounded else {}))
+        self._alloc = BlockAllocator(self.cache_blocks + 1, n_state,
+                                     n_bounded)
         self._tree = (RadixTree(block_size, self._alloc)
                       if prefix_cache else None)
         self._base_key = jax.random.PRNGKey(seed)
@@ -1065,6 +1123,7 @@ class InferenceEngine:
         self._prompt_tokens = 0
         self._cow_copies = 0
         self._evicted_blocks = 0
+        self._bounded_reused = 0
         self._cancelled = 0
         self._max_admission_stall = 0.0
         # Windowed / speculative accounting (all reset_stats-covered).
@@ -1230,49 +1289,80 @@ class InferenceEngine:
     # request side
     # ------------------------------------------------------------------
 
-    def _written_blocks(self, n: int) -> int:
-        """Blocks that hold a sequence's first `n` tokens: the family's
-        state blocks, the same whatever `n`, and where it is paged the
-        pages of `n` tokens. Every footprint is made of this."""
+    def _footprint(self, n: int) -> tuple:
+        """(state blocks, bounded pages, pages) that hold a sequence's
+        first `n` tokens: the family's state blocks, the same whatever
+        `n`; where it is paged the pages of `n` tokens; and where it
+        keeps bounded pages as many again, up to a ring. Every footprint
+        is made of this."""
         pages = (n - 1) // self.block_size + 1 if self._family.paged else 0
-        return self._state_blocks + pages
+        return self._state_blocks, min(pages, self._ring), pages
+
+    def _written_blocks(self, n: int) -> int:
+        return sum(self._footprint(n))
 
     def _block_at(self, pool: dict, block: int):
         """Block id -> (the pool's arrays of its kind, its index on
         their axis 1): what the block moves are given."""
-        n_state = self._alloc.n_state
-        keys = self._family.state_keys
-        if block <= n_state:
-            return {k: pool[k] for k in keys}, block
-        return ({k: a for k, a in pool.items() if k not in keys},
-                block - n_state)
+        fam = self._family
+        kind = self._alloc.kind_of(block)
+        keys = {"state": fam.state_keys, "bounded": fam.bounded_keys}.get(
+            kind)
+        if keys is None:
+            keys = [k for k in pool
+                    if k not in fam.state_keys + fam.bounded_keys]
+        return {k: pool[k] for k in keys}, self._alloc.index_of(block)
 
-    def _take_blocks(self, total: int, held: int = 0):
-        """`total - held` fresh blocks for a footprint of `total` of which
-        `held` pages are already in hand: the state blocks first, then
-        pages; None where either kind is short."""
-        ns = self._state_blocks
-        if self._alloc.free_state < ns or \
-                self._alloc.free < total - ns - held:
+    def _take_blocks(self, n: int, held: int = 0):
+        """Fresh blocks for the footprint of `n` tokens of which `held`
+        pages are already in hand: the state blocks first, then the
+        bounded pages, then pages; None where any kind is short."""
+        ns, nb, pages = self._footprint(n)
+        alloc = self._alloc
+        if alloc.free_state < ns or alloc.free_bounded < nb \
+                or alloc.free < pages - held:
             return None
-        return ([self._alloc.alloc(state=True) for _ in range(ns)]
-                + [self._alloc.alloc() for _ in range(total - ns - held)])
+        return ([alloc.alloc(kind="state") for _ in range(ns)]
+                + [alloc.alloc(kind="bounded") for _ in range(nb)]
+                + [alloc.alloc() for _ in range(pages - held)])
+
+    def _written_of(self, blocks: list, n: int) -> list:
+        """Of a request's `blocks`, those that hold its first `n`
+        tokens, in the list's order (state, bounded, pages): what a
+        hand-off carries."""
+        ns, nb, pages = self._footprint(n)
+        held_b = sum(self._alloc.kind_of(b) == "bounded" for b in blocks)
+        return (blocks[:ns + nb]
+                + blocks[ns + held_b:ns + held_b + pages])
 
     def _table_of(self, blocks: list) -> np.ndarray:
-        """A request's block table: its state blocks' ids, then each
-        page's index among the pages; 0 past its footprint."""
+        """A request's block table: its state blocks' ids, then a column
+        a logical page with the page's index among its kind: the pages
+        that grow in order, then (a family with bounded pages) as many
+        columns again that walk the request's ring, column `j` naming
+        ring page `j % ring`; 0 past its footprint."""
         ns = self._state_blocks
+        index = self._alloc.index_of
         table = np.zeros((self.max_blocks,), np.int32)
         table[:ns] = blocks[:ns]
-        n_state = self._alloc.n_state
-        table[ns:len(blocks)] = [b - n_state for b in blocks[ns:]]
+        ring = [index(b) for b in blocks[ns:]
+                if self._alloc.kind_of(b) == "bounded"]
+        pages = [index(b) for b in blocks[ns + len(ring):]]
+        table[ns:ns + len(pages)] = pages
+        if ring:
+            half = ns + (self.max_blocks - ns) // 2
+            table[half:half + len(pages)] = np.resize(ring, len(pages))
         return table
 
+    @staticmethod
+    def _tokens_written(p: int, max_new: int) -> int:
+        """Positions a request writes: prefill 0..p-1, decode
+        p..p+max_new-2 (the final sampled token is never written)."""
+        return p + max(max_new - 1, 0)
+
     def _blocks_for(self, p: int, max_new: int) -> int:
-        """Blocks a request's full footprint needs: prefill writes
-        positions 0..p-1, decode writes p..p+max_new-2 (the final
-        sampled token is never written)."""
-        return self._written_blocks(p + max(max_new - 1, 0))
+        """Blocks a request's full footprint needs."""
+        return self._written_blocks(self._tokens_written(p, max_new))
 
     def _slot_blocks_for(self, p: int, max_new: int) -> int:
         """Blocks THIS engine must hold for a request. A prefill-role
@@ -1281,9 +1371,12 @@ class InferenceEngine:
         blocks alone — the generation footprint is the importing
         engine's problem. Every other role needs the full
         prompt+generation footprint (`_blocks_for`)."""
-        if self.role == "prefill":
-            return self._written_blocks(p)
-        return self._blocks_for(p, max_new)
+        return self._written_blocks(self._slot_tokens(p, max_new))
+
+    def _slot_tokens(self, p: int, max_new: int) -> int:
+        """The positions `_slot_blocks_for` counts."""
+        return p if self.role == "prefill" else self._tokens_written(
+            p, max_new)
 
     def submit(self, prompt, max_new_tokens: int = 16,
                temperature: float = 0.0,
@@ -1579,7 +1672,8 @@ class InferenceEngine:
 
         def _dump(pool, blocks):
             out = []
-            for b in blocks[:n_written]:
+            for b in (blocks[:n_written] if pool is self.draft_cache
+                      else self._written_of(blocks, p)):
                 arrays, at = self._block_at(pool, b)
                 blk = self._gather_fn(arrays, np.int32(at))
                 # graftlint: disable-next-line=R001,R004 the export IS the handoff's one deliberate device->host pull: the blob must be host bytes before it can ride netaddr to the decode replica
@@ -1785,7 +1879,7 @@ class InferenceEngine:
         if self._alloc.free < fresh_needed and self._tree is not None:
             self._evicted_blocks += self._tree.evict(
                 fresh_needed - self._alloc.free)
-        fresh = self._take_blocks(total, n_full)
+        fresh = self._take_blocks(self._tokens_written(p, max_new), n_full)
         if fresh is None:
             for b in blocks[:n_full]:
                 self._alloc.decref(b)
@@ -1794,8 +1888,9 @@ class InferenceEngine:
         jnp = self._jax.numpy
         t0 = time.perf_counter()
         scattered = 0
+        written = self._written_of(slot_blocks, p)
         for j in range(n_full, n_written):
-            arrays, at = self._block_at(self.cache, slot_blocks[j])
+            arrays, at = self._block_at(self.cache, written[j])
             self.cache = {**self.cache, **self._scatter_block_fn(
                 arrays,
                 {k: jnp.asarray(v) for k, v in payload[j].items()},
@@ -1984,6 +2079,12 @@ class InferenceEngine:
 
     def _release(self, slot_idx: int):
         s = self._slots[slot_idx]
+        if self._ring and s.blocks:
+            # the pages of what it wrote that lay past its ring took a
+            # ring page written before, in place
+            written = s.pos if s.phase == "decode" else s.filled
+            self._bounded_reused += max(
+                0, -(-written // self.block_size) - self._ring)
         for b in s.blocks:
             self._alloc.decref(b)
         for b in s.draft_blocks:
@@ -2029,7 +2130,8 @@ class InferenceEngine:
         if self._alloc.free < fresh_needed and self._tree is not None:
             self._evicted_blocks += self._tree.evict(
                 fresh_needed - self._alloc.free)
-        fresh = self._take_blocks(total, n_full)
+        fresh = self._take_blocks(
+            self._slot_tokens(p, req.max_new_tokens), n_full)
         if fresh is None:
             for b in blocks:
                 self._alloc.decref(b)
@@ -2273,7 +2375,7 @@ class InferenceEngine:
             cap = self._chunk_bucket_for(clen)
             phase = self._phases.phase
             with phase("engine/prefill_chunk", tokens=clen, bucket=cap,
-                       overlapped=int(overlapped)) as chunk:
+                       overlapped=int(overlapped), start=s.filled) as chunk:
                 with phase("engine/prefill_build", puts=1):
                     # keyed by the count before this tick's decode step,
                     # wherever in the tick the chunk is enqueued
@@ -2639,6 +2741,13 @@ class InferenceEngine:
         with phase("engine/decode_dispatch") as dispatch:
             nxt, lps, self.cache, counts = self._decode_fn(
                 self.params, self.cache, inputs, self._base_key)
+            bound = self._family.bounded_tokens
+            if bound and dispatch.is_enabled():
+                # what the step's bounded pages hold of each decoding
+                # stream: min(context, bound), which no sum of contexts
+                # gives; summed only where a profiler session reads it
+                dispatch.set(bounded_rows=sum(
+                    min(self._slots[i].pos + 1, bound) for i in decoding))
         if enqueued is not None:
             enqueued()
         with phase("engine/token_sync") as sync:
@@ -2809,6 +2918,10 @@ class InferenceEngine:
         holds = collections.Counter()
         for s in self._slots:
             holds.update(s.blocks)
+            ring = sum(self._alloc.kind_of(b) == "bounded"
+                       for b in s.blocks)
+            assert ring <= self._ring, \
+                f"rid {s.rid} holds {ring} bounded pages, bound {self._ring}"
         if self._tree is not None:
             for nd in self._tree._nodes():
                 holds.update(nd.blocks)
@@ -2895,6 +3008,7 @@ class InferenceEngine:
             self._chunk_tail_s = 0.0
             self._prefix_hit_tokens = self._prompt_tokens = 0
             self._cow_copies = self._evicted_blocks = 0
+            self._bounded_reused = 0
             self._cancelled = 0
             self._max_admission_stall = 0.0
             self._step_times.clear()
@@ -2988,6 +3102,17 @@ class InferenceEngine:
           bytes a token plus a sequence's state over the `max_len`
           tokens it may stand for. Without pages ``block_size`` is the
           constructor's argument and sizes nothing.
+          For a family with bounded pages (`ServingFamily.bounded_tokens`,
+          a window layer's) ``bounded_blocks`` / ``bounded_blocks_in_use``
+          are that kind's pages held by the pool and by requests,
+          ``bounded_ring`` the most one request holds (the window and a
+          chunk, in pages); ``blocks_free`` then counts the pages that
+          grow alone, and ``kv_bytes_per_token`` counts a bounded page's
+          bytes like a page's, which a sequence past the bound no longer
+          pays. ``bounded_pages_reused`` counts the logical pages past
+          their request's ring, each of which took a ring page written
+          before, in place; a request adds its own when it leaves its
+          slot (finished, cancelled or preempted).
           ``prefix_cache`` — whether a radix tree is kept (never for a
           family of state blocks).
           ``cached_prefix_blocks`` — blocks the radix tree holds.
@@ -3245,6 +3370,11 @@ class InferenceEngine:
                 "state_blocks": self._alloc.n_state,
                 "state_blocks_in_use": (self._alloc.n_state
                                         - self._alloc.free_state),
+                "bounded_blocks": self._alloc.n_bounded,
+                "bounded_blocks_in_use": (self._alloc.n_bounded
+                                          - self._alloc.free_bounded),
+                "bounded_ring": self._ring,
+                "bounded_pages_reused": self._bounded_reused,
                 "prefix_cache": self._tree is not None,
                 "cached_prefix_blocks": (self._tree.n_blocks()
                                          if self._tree else 0),

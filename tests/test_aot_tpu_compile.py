@@ -825,3 +825,155 @@ def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
     assert mem.temp_size_in_bytes < 1e9                 # and never copied
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# -- the window-and-full family at command-a-plus.mixed-closed24's shapes:
+# 16 slots, 128 query heads over 8 key-value heads of 128, one full layer
+# of 3,712 pages of 128 and three window layers of 592 (a ring of 37 a
+# slot), tables of 256 columns a kind, chunks of 512 and 128, 16 held
+# experts of a 128-wide router, vocabulary 32,768
+
+def _command_a():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import window_moe as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "command-a-plus.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+WB, WHQ, WHKV, WD, WBS, WMB, WWIN = 16, 128, 8, 128, 128, 256, 4096
+WFULL = ((1, 3713, WHKV, WBS, WD), BF16)
+WRING = ((3, 593, WHKV, WBS, WD), BF16)
+
+
+def _gqa_case(form, window):
+    pool = WRING if window else WFULL
+    if form == "decode":
+        return (lambda q, k, v, t, p: da.gqa_decode_attention(
+            q, k, v, t, p, layer=0, window=window, impl="pallas"),
+            [((WB, WHQ, WD), BF16), pool, pool, ((WB, WMB), I32),
+             ((WB,), I32)])
+    return (lambda q, k, v, t, p: da.gqa_chunk_attention(
+        q, k, v, t, p, layer=0, window=window, impl="pallas"),
+        [((form, WHQ, WD), BF16), pool, pool, ((WMB,), I32), ((), I32)])
+
+
+GQA_KERNELS = {
+    "window_decode": (_gqa_case("decode", WWIN), "gqa_window_decode"),
+    "full_decode": (_gqa_case("decode", None), "gqa_full_decode"),
+    "window_chunk_512": (_gqa_case(512, WWIN), "gqa_window_chunk"),
+    "window_chunk_128": (_gqa_case(128, WWIN), "gqa_window_chunk"),
+    "full_chunk_512": (_gqa_case(512, None), "gqa_full_chunk"),
+    "full_chunk_128": (_gqa_case(128, None), "gqa_full_chunk"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GQA_KERNELS))
+def test_gqa_kernels_compile_under_their_names(topo, case):
+    """One kernel a call, under its own name, and the pool reaches it as
+    the program's parameter: nothing makes an array of a pool's size."""
+    (fn, args), name = GQA_KERNELS[case]
+    compiled = compiled_for(topo, fn, *args)
+    text = compiled.as_text()
+    assert kernel_names(text) == [name]
+    pool = args[1][0]
+    assert arrays_made(text, BF16, math.prod(pool[1:])) == []
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+# sha256 (first 16 hex digits) of the jaxpr of the paged wrappers at
+# `olmo-1b`'s serving shapes (32 slots, 16 heads of 128, 16 layers of 2,049
+# blocks of 16, tables of 128), taken on the commit before the kernel body
+# was given grouped heads and a window (PR 43's tree). A PR that means to
+# change what those two cells run replaces them and says so. A jaxpr's
+# text is this JAX's: under another version the test skips, and whoever
+# upgrades takes the digests anew on the tree before the upgrade's first
+# change to `ops/decode_attention.py`
+OLMO_PAGED_JAX = "0.9.0"
+OLMO_PAGED = {"decode": "94733b9694d53a0a", "mq_512": "cbd56ac3bf4816ea",
+              "mq_128": "bd50bd7197c932a9"}
+
+
+@pytest.mark.parametrize("case", sorted(OLMO_PAGED))
+def test_paged_kernels_at_olmo_1b_trace_to_what_they_did(case):
+    """`g = 1` without a window is the program `olmo-1b`'s two cells
+    run: the wrapper and the kernel body trace, equation for equation,
+    to what they did before the body took grouped heads and a window
+    (the kernel's serialized Mosaic module is not the same from one
+    compile to the next, so the jaxpr is what is held)."""
+    import hashlib
+    if jax.__version__ != OLMO_PAGED_JAX:
+        pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
+    pool = jax.ShapeDtypeStruct((16, CELL_NB, BS, H, CELL_D), BF16)
+    scalar = jax.ShapeDtypeStruct((), I32)
+
+    def fingerprint(fn, *args):
+        text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    if case == "decode":
+        args = (jax.ShapeDtypeStruct((CELL_SLOTS, H, CELL_D), BF16), pool,
+                pool, jax.ShapeDtypeStruct((CELL_SLOTS, CELL_MB), I32),
+                jax.ShapeDtypeStruct((CELL_SLOTS,), I32), scalar)
+
+        def fn(q, k, v, t, p, layer):
+            return da.paged_decode_attention(q, k, v, t, p, layer=layer,
+                                             impl="pallas")
+    else:
+        args = (jax.ShapeDtypeStruct((int(case[3:]), H, CELL_D), BF16),
+                pool, pool, jax.ShapeDtypeStruct((CELL_MB,), I32), scalar,
+                scalar)
+
+        def fn(q, k, v, t, p, layer):
+            return da.paged_prefill_attention(q, k, v, t, p, layer=layer,
+                                              impl="pallas")
+    assert fingerprint(fn, *args) == OLMO_PAGED[case]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_window_family_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/command-a-plus.json` as the engine jits them (the
+    pool donated): every kernel is there under its name, a call a layer
+    of its kind; both kinds of page are updated in place (no copy of a
+    pool among the temporaries); weights, pool and temporaries fit the
+    chip."""
+    from ray_tpu.models import window_moe
+    config, cfg, ref = _command_a()
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(lambda: window_moe.init_pool(
+        cfg, WFULL[0][1], WBS, bounded_blocks=WRING[0][1])))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: window_moe.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((WB,)), arg((WB,)),
+            arg((WB, 2 * WMB))).compile()
+        want = {"gqa_window_decode": 3, "gqa_full_decode": 1,
+                "experts_grouped": 4}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: window_moe.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((2 * WMB,)), arg(()),
+            arg(())).compile()
+        want = {"gqa_window_chunk": 3, "gqa_full_chunk": 1,
+                "experts_grouped_prefill": 4}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    # under one window array (466 MB), the smallest of the pool's: none
+    # of them is copied or laid out anew around its writes (a scatter
+    # whose window is not contiguous in a head-major page did both)
+    assert mem.temp_size_in_bytes < 256 << 20
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)

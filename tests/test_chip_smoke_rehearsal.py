@@ -64,6 +64,18 @@ def test_serve_hybrid_phase_tiny_on_cpu():
     assert '"phase": "serve_hybrid"' in out
 
 
+def test_serve_window_phase_tiny_on_cpu():
+    """The fourth family's part: an engine over `models/window_moe.py`
+    (pages that grow and a ring of window pages a request) in the phase's
+    own process, float32 on the CPU (the plain paths), held to the
+    definition; the longer prompts pass the window of 256."""
+    out = run("cs.serve_family_phase(cs.window_case(dict(cs.WINDOW_CFG, "
+              "head_dim=32, dtype='float32'), 0), platform='cpu', "
+              "streams=5, prompt_lens=(100, 600), new_tokens=6, slots=3, "
+              "seed=0)")
+    assert '"phase": "serve_window"' in out
+
+
 def test_train_phase_tiny_on_cpu():
     out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
               "steps=12, seed=0)")

@@ -1,0 +1,117 @@
+"""The paged kernel body with grouped key-value heads and a window
+(`ops/decode_attention.py`): the decode form and the chunk form against
+their plain `jax.numpy` references, over query heads a key-value head in
+{1, 16}, window on and off, and tables whose live pages do not start at
+column 0 (a window layer's ring: the columns the window has left name
+pages that hold later positions, or the trash page)."""
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import decode_attention as da
+
+BS, D, HKV, NB = 8, 16, 2, 40
+
+
+def _pools(key, hkv=HKV, layers=2):
+    kk, kv = jax.random.split(key)
+    shape = (layers, NB, hkv, BS, D)
+    return (jax.random.normal(kk, shape, jnp.float32),
+            jax.random.normal(kv, shape, jnp.float32))
+
+
+def _ring_tables(rng, pos_last, window, mb, ring):
+    """A table a stream: column `j` names ring page `j % ring` of the
+    stream's own pages for the columns that can still be live, 0 (the
+    trash page) for those the window has left whole and past the end."""
+    b = len(pos_last)
+    tables = np.zeros((b, mb), np.int32)
+    free = list(rng.permutation(np.arange(1, NB)))
+    for i, last in enumerate(pos_last):
+        pages = [free.pop() for _ in range(min(ring, last // BS + 1))]
+        lo = 0 if window is None else max(0, last - window - BS) // BS
+        for j in range(lo, last // BS + 1):
+            tables[i, j] = pages[j % len(pages)]
+    return jnp.asarray(tables)
+
+
+def _dense(q, k_pool, v_pool, tables, pos, window, layer):
+    """By the definition, from the pages the table names, position by
+    position: nothing shared with the code under test but the pool."""
+    q, kp, vp = (np.asarray(a, np.float64) for a in (q, k_pool, v_pool))
+    tables, pos = np.asarray(tables), np.asarray(pos)
+    b, w, hq, d = q.shape
+    g = hq // kp.shape[2]
+    out = np.zeros_like(q)
+    for i in range(b):
+        for t in range(w):
+            at = pos[i] + t
+            lo = 0 if window is None else max(0, at - window + 1)
+            js = np.arange(lo, at + 1)
+            for h in range(hq):
+                k = kp[layer, tables[i, js // BS], h // g, js % BS]
+                v = vp[layer, tables[i, js // BS], h // g, js % BS]
+                s = k @ q[i, t, h] * d ** -0.5
+                p = np.exp(s - s.max())
+                out[i, t, h] = (p / p.sum()) @ v
+    return out
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("g", [1, 16])
+def test_decode_form_matches_the_definition(g, window, impl):
+    rng = np.random.default_rng(3)
+    pos = np.array([0, 5, 37, 75, 130], np.int32)
+    mb = 20
+    k_pool, v_pool = _pools(jax.random.key(1))
+    # a ring of 5 pages holds a window of 20 and a page more
+    tables = _ring_tables(rng, pos, window, mb, 5 if window else mb)
+    # the positions a ring no longer holds are not in the pool at all:
+    # what the table's earlier columns name is later data or trash
+    q = jax.random.normal(jax.random.key(2), (len(pos), HKV * g, D))
+    got = da.gqa_decode_attention(q, k_pool, v_pool, tables, jnp.asarray(pos),
+                                  layer=1, window=window, impl=impl)
+    want = _dense(q[:, None], k_pool, v_pool, tables, pos, window, 1)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("window", [None, 20])
+@pytest.mark.parametrize("g", [1, 16])
+@pytest.mark.parametrize("start,c", [(0, 16), (13, 16), (64, 8), (91, 16)])
+def test_chunk_form_matches_the_definition(start, c, g, window, impl):
+    """A chunk's queries each keep their own window: the first pages are
+    cut for the later ones inside the mask, a chunk that straddles the
+    window's edge (start 13, window 20) among them."""
+    rng = np.random.default_rng(4)
+    mb = 20
+    k_pool, v_pool = _pools(jax.random.key(5))
+    last = start + c - 1
+    ring = (20 + c) // BS + 2 if window else mb
+    table = _ring_tables(rng, [last], window and window + c, mb, ring)[0]
+    q = jax.random.normal(jax.random.key(6), (c, HKV * g, D))
+    got = da.gqa_chunk_attention(q, k_pool, v_pool, table, jnp.int32(start),
+                                 layer=0, window=window, impl=impl)
+    want = _dense(q[None], k_pool, v_pool, table[None],
+                  np.array([start]), window, 0)[0]
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+def test_a_window_layer_never_reads_a_page_the_window_has_left():
+    """NaNs in every page before the window's first: the kernel's output
+    is finite, so none of them was multiplied in."""
+    window, pos = 16, np.array([100], np.int32)
+    k_pool, v_pool = _pools(jax.random.key(10), layers=1)
+    tables = jnp.arange(1, 21, dtype=jnp.int32)[None]
+    first_live = (100 - window + 1) // BS
+    dead = np.asarray(tables[0, :first_live])
+    k_pool = k_pool.at[0, dead].set(jnp.nan)
+    v_pool = v_pool.at[0, dead].set(jnp.nan)
+    q = jax.random.normal(jax.random.key(11), (1, HKV * 16, D))
+    got = da.gqa_decode_attention(q, k_pool, v_pool, tables,
+                                  jnp.asarray(pos), layer=0, window=window,
+                                  impl="pallas")
+    assert np.isfinite(np.asarray(got)).all()

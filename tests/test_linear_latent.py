@@ -158,11 +158,11 @@ def test_one_footprint_arithmetic_for_every_family(params):
 def test_the_allocator_keeps_two_free_lists_in_one_space_of_ids():
     a = BlockAllocator(8, n_state=2)
     assert (a.free_state, a.free, a.used) == (2, 5, 0)
-    s, p = a.alloc(state=True), a.alloc()
+    s, p = a.alloc(kind="state"), a.alloc()
     assert (s, p) == (1, 3) and a.used == 2
-    a.alloc(state=True)
+    a.alloc(kind="state")
     with pytest.raises(RuntimeError, match="out of"):
-        a.alloc(state=True)
+        a.alloc(kind="state")
     a.decref(s)
     a.decref(p)
     assert (a.free_state, a.free) == (1, 5)

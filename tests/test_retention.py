@@ -306,8 +306,10 @@ def test_only_a_family_of_state_blocks_says_so():
     for other in (gpt.GPTConfig().family, latent_sparse_moe.FAMILY):
         assert (other.state_blocks, other.paged, other.state_keys) == (
             0, True, ())
-    assert ServingFamily._fields[-3:] == ("state_blocks", "paged",
-                                          "state_keys")
+    assert ServingFamily._fields[-5:] == (
+        "state_blocks", "paged", "state_keys", "bounded_keys",
+        "bounded_tokens")
+    assert (fam.bounded_keys, fam.bounded_tokens) == ((), 0)
 
 
 def test_a_prefix_cache_is_refused(params):
