@@ -75,6 +75,7 @@ CONTEXT_BLOCK = 1024        # cached positions a step of the prefill loops
 # vector they return beside the logits; the held experts' loads follow
 COUNTS = ("index_scanned_tokens", "index_selected_tokens",
           "index_layer_runs", "index_layer_reuses",
+          "index_chunk_selections", "index_chunk_thresholds",
           "expert_tokens_here", "expert_tokens_routed")
 
 # training. The embedding's scale in `init_params`: not the 0.02 of the
@@ -493,19 +494,23 @@ def _feed_forward(x, lp, cfg, live=None,
     return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
 
 
-def _counts(cfg, pos, live, expert_counts):
+def _counts(cfg, pos, live, expert_counts, chunk: bool = False):
     """The int32 vector a program returns: `COUNTS`, then the held
     experts' loads. pos [N]: each query's position; live [N]: the rows
-    that count."""
+    that count. A prefill `chunk` adds its "full" layers to the chunk
+    selections, and to the chunk thresholds where `_prefill_select` took
+    one (its context is past the top-k); a decode step adds to neither."""
     n_full = sum(ix == "full" for _, ix in cfg.kinds)
+    topk = cfg.index_topk or 0
     scanned = jnp.sum(jnp.where(live, pos + 1, 0))
-    selected = jnp.sum(jnp.where(
-        live, jnp.minimum(pos + 1, cfg.index_topk or 0), 0))
+    selected = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, topk), 0))
+    past = jnp.max(jnp.where(live, pos, 0)) >= topk
     experts = sum(expert_counts) if expert_counts else jnp.zeros(
         (2 + cfg.held_count,), jnp.int32)
     return jnp.concatenate([
         jnp.stack([scanned * n_full, selected * n_full,
                    jnp.int32(n_full), jnp.int32(cfg.n_layers - n_full),
+                   jnp.int32(n_full * chunk), n_full * chunk * past,
                    experts[0], experts[1]]).astype(jnp.int32),
         experts[2:].astype(jnp.int32)])
 
@@ -532,17 +537,60 @@ def _unembed(x, params, cfg):
 # whole sequence (tests, and the share test)
 # ---------------------------------------------------------------------------
 
-def _select_dense(scores, valid, k: int):
+def _order_keys(scores):
+    """float32 -> the int32 whose signed order is the float's: a
+    negative's low 31 bits are flipped. -inf is the smallest key of a
+    number and -0.0 lies under +0.0, the order `jax.lax.top_k` sorts by."""
+    bits = jax.lax.bitcast_convert_type(scores, jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def _select_dense(scores, valid, k: int, block: int | None = None,
+                  n_blocks=1):
     """scores [N, S] f32 with -inf where not `valid` -> bool [N, S]: the
     k largest of each row (all valid ones while there are no more). Of
     equal scores at the threshold the earliest positions are taken, as
     `jax.lax.top_k` takes them in a decode step: the same set either
-    way."""
-    k = min(k, scores.shape[1])
-    kth = jax.lax.top_k(scores, k)[0][:, -1:]
-    above, ties = scores > kth, scores == kth
-    need = k - jnp.sum(above, -1, keepdims=True)
-    return valid & (above | (ties & (jnp.cumsum(ties, -1) <= need)))
+    way. Nothing past the first `n_blocks` blocks of `block` columns is
+    valid, and the mask is made for those blocks alone (one block of all
+    S where none is given).
+
+    The threshold is counted, not sorted for: the row's k-th largest key
+    is the largest t with count(key >= t) >= k, fixed from its top bit
+    down, one compare and one row sum a bit. It starts as the smallest
+    int32; the bit under trial is flipped (the sign bit to 0, every other
+    to 1) and stays so where k keys still reach it. 32 passes whatever
+    the data, exact, and no index is carried along. A pass reads the
+    whole width: on the chip the keys stay in VMEM across the loop (7 us
+    a pass of [512, 16384]), and a pass that looped over the live blocks
+    alone took 16 at a third of the width (PERF.md, PR 46)."""
+    n, s = scores.shape
+    block = block or s
+    k = min(k, s)
+    keys = _order_keys(scores)
+
+    def fix(i, kth):
+        trial = kth ^ (jnp.int32(1) << (31 - i))
+        reach = jnp.sum(keys >= trial, -1, keepdims=True, dtype=jnp.int32)
+        return jnp.where(reach >= k, trial, kth)
+
+    kth = jax.lax.fori_loop(
+        0, 32, fix, jnp.full((n, 1), jnp.iinfo(jnp.int32).min, jnp.int32))
+    need = k - jnp.sum(keys > kth, -1, keepdims=True, dtype=jnp.int32)
+
+    def pick(j, carry):
+        seen, out = carry
+        key = jax.lax.dynamic_slice_in_dim(keys, j * block, block, axis=1)
+        ties = key == kth
+        seen = seen + jnp.cumsum(ties, -1, dtype=jnp.int32)
+        took = jax.lax.dynamic_slice_in_dim(valid, j * block, block, axis=1) \
+            & ((key > kth) | (ties & (seen <= need)))
+        return seen[:, -1:], jax.lax.dynamic_update_slice_in_dim(
+            out, took, j * block, axis=1)
+
+    return jax.lax.fori_loop(
+        0, n_blocks, pick,
+        (jnp.zeros((n, 1), jnp.int32), jnp.zeros((n, s), bool)))[1]
 
 
 def attend_full(q_nope, q_rope, row, selected, lp, cfg):
@@ -783,30 +831,37 @@ def _context_block(s: int, bs: int) -> int:
 def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
     """A chunk's selection over the cached context: q_i [C, J, Di],
     w [C, J], index [L_full, nb, bs, Di] -> bool [C, S]. Plain
-    `jax.numpy`: the context's keys gathered through the table, scored a
-    block of positions at a time, only as far as the chunk's last
-    position."""
+    `jax.numpy`. A chunk whose last position is under the top-k selects
+    every live position and scores nothing. Any other gathers the
+    context's keys through the table, scores them a block of positions at
+    a time, only as far as the chunk's last position, and makes the mask
+    for those blocks alone."""
     from ray_tpu.ops.decode_attention import gather_kv_pages
     _, nb, bs, di = index.shape
-    keys = gather_kv_pages(index.reshape(-1, bs, di),
-                           table[None] + layer * nb)[0]     # [S, Di]
-    c, s = q_i.shape[0], keys.shape[0]
+    c, s = q_i.shape[0], table.shape[0] * bs
     sb = _context_block(s, bs)
     last = jnp.max(jnp.where(valid, positions, 0))
+    live = every_earlier(positions, valid, s)
 
-    def block(j, scores):
-        ks = jax.lax.dynamic_slice_in_dim(keys, j * sb, sb)
-        dots = sparse_latent.index_dots(q_i, ks, "qjd,sd->qjs")
-        return jax.lax.dynamic_update_slice_in_dim(
-            scores, jnp.einsum("qj,qjs->qs", w, jax.nn.relu(dots)),
-            j * sb, axis=1)
+    def over_the_context():
+        keys = gather_kv_pages(index.reshape(-1, bs, di),
+                               table[None] + layer * nb)[0]     # [S, Di]
 
-    scores = jax.lax.fori_loop(0, last // sb + 1, block,
-                               jnp.full((c, s), -jnp.inf, jnp.float32))
-    live = (jnp.arange(s, dtype=jnp.int32)[None, :] <= positions[:, None]) \
-        & valid[:, None]
-    return _select_dense(jnp.where(live, scores, -jnp.inf), live,
-                         cfg.index_topk)
+        def block(j, scores):
+            ks = jax.lax.dynamic_slice_in_dim(keys, j * sb, sb)
+            dots = sparse_latent.index_dots(q_i, ks, "qjd,sd->qjs")
+            return jax.lax.dynamic_update_slice_in_dim(scores, jnp.where(
+                jax.lax.dynamic_slice_in_dim(live, j * sb, sb, axis=1),
+                jnp.einsum("qj,qjs->qs", w, jax.nn.relu(dots)), -jnp.inf),
+                j * sb, axis=1)
+
+        n_blocks = last // sb + 1
+        scores = jax.lax.fori_loop(0, n_blocks, block,
+                                   jnp.full((c, s), -jnp.inf, jnp.float32))
+        return _select_dense(scores, live, cfg.index_topk, sb, n_blocks)
+
+    return jax.lax.cond(last < cfg.index_topk, lambda: live,
+                        over_the_context)
 
 
 def _prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
@@ -915,7 +970,7 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     x = _norm(x, params["final_ln_scale"], cfg)
     last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
     return (_unembed(last, params, cfg), _pool_of(latent, index),
-            _counts(cfg, positions, valid, expert_counts))
+            _counts(cfg, positions, valid, expert_counts, chunk=True))
 
 
 # ---------------------------------------------------------------------------

@@ -598,6 +598,37 @@ def test_experts_grouped_train_kernels_compile_under_their_names(topo):
         "experts_grouped_dw", "experts_grouped_dx", "experts_grouped_train"]
 
 
+_GLM_PROGRAMS = {}
+
+
+def _glm_program(topo, program):
+    """-> (the decode step or a 512-token prefill chunk of
+    `benchmarks/configs/glm-5.2.json` as the engine jits it, the pool
+    donated, compiled once a module; the pool's shapes)."""
+    if program not in _GLM_PROGRAMS:
+        from ray_tpu.models import latent_sparse_moe as lsm
+        config, cfg, ref = _glm()
+        described, arg = describers(topo)
+        params = described(jax.eval_shape(
+            lambda k: ref.init_params(k, config), jax.random.key(0)))
+        pool = described(jax.eval_shape(lambda: lsm.init_pool(cfg, LNB, BS)))
+        if program == "decode":
+            compiled = jax.jit(
+                lambda p, cache, tok, pos, tab: lsm.decode(
+                    p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+                params, pool, arg((LS,)), arg((LS,)),
+                arg((LS, LMB))).compile()
+        else:
+            compiled = jax.jit(
+                lambda p, tok, cache, tab, start, n: lsm.prefill(
+                    p, tok, cache, cfg, block_table=tab, start=start,
+                    length=n), donate_argnums=(2,)).lower(
+                params, arg((1, 512)), pool, arg((LMB,)), arg(()),
+                arg(())).compile()
+        _GLM_PROGRAMS[program] = compiled, pool
+    return _GLM_PROGRAMS[program]
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     """The decode step and a 512-token prefill chunk of
@@ -605,28 +636,11 @@ def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     donated): every kernel is there under its name, the pool is updated
     in place (no copy of it among the temporaries), and weights, pool and
     temporaries fit the chip."""
-    from ray_tpu.models import latent_sparse_moe as lsm
-    config, cfg, ref = _glm()
-    described, arg = describers(topo)
-    params = described(jax.eval_shape(
-        lambda k: ref.init_params(k, config), jax.random.key(0)))
-    pool = described(jax.eval_shape(lambda: lsm.init_pool(cfg, LNB, BS)))
-    if program == "decode":
-        compiled = jax.jit(
-            lambda p, cache, tok, pos, tab: lsm.decode(
-                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
-            params, pool, arg((LS,)), arg((LS,)), arg((LS, LMB))).compile()
-        want = {"latent_row_write": 6, "index_scores": 2,
-                "sparse_latent_decode": 6, "experts_grouped": 5}
-    else:
-        compiled = jax.jit(
-            lambda p, tok, cache, tab, start, n: lsm.prefill(
-                p, tok, cache, cfg, block_table=tab, start=start,
-                length=n), donate_argnums=(2,)).lower(
-            params, arg((1, 512)), pool, arg((LMB,)), arg(()),
-            arg(())).compile()
-        want = {"latent_row_write": 6, "latent_row_gather": 6,
-                "experts_grouped_prefill": 5}
+    compiled, pool = _glm_program(topo, program)
+    want = {"decode": {"latent_row_write": 6, "index_scores": 2,
+                       "sparse_latent_decode": 6, "experts_grouped": 5},
+            "prefill": {"latent_row_write": 6, "latent_row_gather": 6,
+                        "experts_grouped_prefill": 5}}[program]
     names = kernel_names(compiled.as_text())
     assert {n: names.count(n) for n in set(names)} == want
     mem = compiled.memory_analysis()
@@ -635,6 +649,22 @@ def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
     assert mem.temp_size_in_bytes < 1e9                 # and never copied
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+def test_latent_prefill_chunk_sorts_nothing_of_the_context(topo):
+    """A chunk's selection counts its threshold (`_select_dense`): at the
+    cell's shapes (a chunk of 512 over a table of 16,384 positions) the
+    compiled program's only sorts are the router's, and none has the
+    context's 16,384 columns, as the two `sort f32[512,16384]` of a
+    `jax.lax.top_k(scores, 2048)` had; the decode step keeps its two of
+    `[16, 16384]` (`select_rows` needs the positions)."""
+    def sorts(program):
+        return [line for line in _glm_program(
+            topo, program)[0].as_text().splitlines()
+            if re.search(r" sort\(", line)]
+    assert sorts("prefill")
+    assert not [line[:160] for line in sorts("prefill") if "16384" in line]
+    assert [line for line in sorts("decode") if "[16,16384]" in line]
 
 
 # -- the power-retention family at brumby-14b.docgen-closed24's shapes: 16

@@ -200,6 +200,133 @@ def test_selection_takes_every_position_while_there_are_no_more(params):
         np.testing.assert_allclose(lg, want[pos], rtol=0, atol=TOL)
 
 
+def _rows(case):
+    """-> (scores [N, S] f32 with -inf where not live, live bool [N, S], k,
+    block, n_blocks) of one case of the counted selection."""
+    rng = np.random.default_rng(11)
+    n, s, k, block, n_blocks = 6, 96, 20, None, 1
+    x = rng.normal(size=(n, s)).astype(np.float32)
+    live = np.ones((n, s), bool)
+    if case == "signs_and_zeros":
+        # positives, negatives and both zeros, the zeros across the k-th
+        # place: +0.0 is taken before -0.0 wherever either lies
+        x[:, ::3] = np.where(rng.random((n, s // 3)) < 0.5, 0.0, -0.0)
+        x[0] = -np.abs(x[0])
+        x[1] = np.abs(x[1])
+        x[2, 12:] = -0.0
+        x[2, 40:50] = 0.0
+    elif case == "ties_across_the_kth_place":
+        x = np.round(x * 2) / 2 + 0.0           # runs of equal scores
+        x[0] = 1.0                              # a row of one value
+        x[1, :15], x[1, 15:] = 3.0, 2.0         # 15 above, 81 tied for 5
+        x[2, -k:] = 9.0                         # exactly k above the rest
+    elif case == "fewer_live_than_k":
+        live = np.arange(s)[None, :] <= np.array(
+            [0, 3, 18, 19, 20, 50])[:, None]
+        x[3, 5] = -np.inf                       # a live score of -inf
+    elif case == "rows_of_minus_infinity":
+        live[1] = live[4] = False
+        live[2, 7:] = False
+        x[5] = -np.inf                          # live, and every score -inf
+    elif case == "ragged_live_width":
+        # a mask of three blocks of 16; the live width (43) ends inside
+        # the third
+        block, n_blocks = 16, 3
+        live = np.arange(s)[None, :] <= np.array(
+            [30, 36, 40, 41, 42, 42])[:, None]
+    elif case == "blocks_with_ties":
+        block, n_blocks, k = 32, 3, 40
+        x = np.round(x) + 0.0                   # no -0.0
+    else:
+        raise ValueError(case)
+    x = np.where(live, x, -np.inf).astype(np.float32)
+    return x, live, k, block, n_blocks
+
+
+@pytest.mark.parametrize("case", [
+    "signs_and_zeros", "ties_across_the_kth_place", "fewer_live_than_k",
+    "rows_of_minus_infinity", "ragged_live_width", "blocks_with_ties"])
+def test_counted_selection_is_top_k_s_set(case):
+    """`_select_dense` finds its threshold by counting; the set it takes is
+    the one `jax.lax.top_k`'s indices name (the earliest positions of equal
+    scores), bit for bit, on the rows a sort orders by more than `<`."""
+    x, live, k, block, n_blocks = _rows(case)
+    n, s = x.shape
+    _, idx = jax.lax.top_k(jnp.asarray(x), k)
+    want = np.zeros((n, s), bool)
+    want[np.arange(n)[:, None], np.asarray(idx)] = True
+    want &= live
+    assert block is None or not live[:, n_blocks * block:].any()
+    got = jax.jit(lsm._select_dense, static_argnums=(2, 3))(
+        jnp.asarray(x), jnp.asarray(live), k, block, n_blocks)
+    assert want.sum() > 0 and np.array_equal(np.asarray(got), want)
+    # the reference's mask is the same set wherever no -0.0 meets a +0.0
+    # at the threshold (it compares floats, and takes them as equal)
+    if case != "signs_and_zeros":
+        assert np.array_equal(
+            np.asarray(ref.top_k_mask(jnp.asarray(x), k)) & live, want)
+
+
+CROSSING = {"index_topk": 24}       # chunks of 16: under, across, past
+
+
+def test_chunks_across_the_top_k_select_what_forward_selects(params,
+                                                             monkeypatch):
+    """A prompt of 48 in chunks of 16 under a top-k of 24: the first chunk
+    lies wholly under it (every live position, no threshold), the second
+    straddles it, the third is past it; each "full" layer's mask is the
+    whole-sequence forward's rows, and nothing past the chunk is set."""
+    cfg = config(**CROSSING)
+    seq = prompt(48, 8)
+    want = []
+    lsm.forward(params, jnp.asarray(seq[None]), cfg, selections=want)
+    taken = []
+    real = lsm._prefill_select
+
+    def kept(*args):
+        taken.append(real(*args))
+        return taken[-1]
+
+    monkeypatch.setattr(lsm, "_prefill_select", kept)
+    got = paged_logits(params, cfg, seq, n_prompt=48)
+    assert len(taken) == 2 * 3
+    for chunk in range(3):
+        rows = slice(16 * chunk, 16 * chunk + 16)
+        for layer in range(2):
+            mask = np.asarray(taken[2 * chunk + layer])
+            assert np.array_equal(mask[:, :48], np.asarray(want[layer][rows]))
+            assert not mask[:, 48:].any()
+    assert int(want[0][15].sum()) == 16 and int(want[0][40].sum()) == 24
+    tiny = {**TINY, **CROSSING}
+    ref_logits = np.asarray(
+        ref.logits(params, jnp.asarray(seq[None]), tiny))[0]
+    for pos, lg in got.items():
+        np.testing.assert_allclose(lg, ref_logits[pos], rtol=0, atol=TOL)
+
+
+def test_stats_count_the_chunks_that_took_a_threshold(params):
+    """`index_chunk_selections`: the "full" layers of every prefill chunk;
+    `index_chunk_thresholds`: of the chunks whose context is past the
+    top-k; a decode step adds to neither."""
+    eng = InferenceEngine(params, config(**CROSSING), slots=2, max_len=128,
+                          cache_blocks=40, prefill_chunk=16)
+    eng.submit(prompt(48, 8), max_new_tokens=1)      # last: 15, 31, 47
+    eng.submit(prompt(20, 9), max_new_tokens=1)      # last: 15, 19
+    eng.run_until_idle()
+    s = eng.stats()
+    assert s["prefill_chunks"] == 5
+    assert s["index_chunk_selections"] == 2 * 5
+    assert s["index_chunk_thresholds"] == 2 * 2
+    rid = eng.submit(prompt(10, 10), max_new_tokens=6)
+    eng.run_until_idle()
+    assert len(list(eng.tokens_for(rid))) == 6
+    s = eng.stats()
+    assert s["decode_steps"] >= 5 and s["prefill_chunks"] == 6
+    assert s["index_chunk_selections"] == 2 * 6
+    assert s["index_chunk_thresholds"] == 2 * 2
+    assert s["index_layer_runs"] == 2 * (s["decode_steps"] + 6)
+
+
 # -- (d) the share test ------------------------------------------------------
 
 @pytest.mark.parametrize("n_shared", [1, 2])
