@@ -22,10 +22,13 @@ A run, in order (every part but the window is set-up, and shown in
             load; rates, gaps and first tokens are taken over it from
             the clients' clocks, the engine's counts are zeroed at its
             start and read at its end
-  end       every open request is cancelled (a drain of the longest
-            would last two minutes and show nothing the window's own
-            requests do not); the replica compares a sample of finished
-            requests with the reference; everything is shut down
+  end       the load runs on until the requests that are compared
+            (`traffic.check_plan`: the mix's, the same in every run) have
+            streamed the tokens compared of them, `CHECK_WAIT_S` at the
+            most; every other open request is cancelled (a drain of the
+            longest would last two minutes and show nothing the window's
+            own requests do not); the replica compares those requests
+            with the reference; everything is shut down
 
 An open-loop request is timed from when it was due, a closed-loop one
 from when its client sent it.
@@ -46,6 +49,7 @@ from benchmarks.harness import traffic as traffic_mod
 from benchmarks.harness.common import BenchFailure, program_seed
 
 CALL_TIMEOUT_S = 600
+CHECK_WAIT_S = 60       # past the window's close, for a compared request
 
 
 # ---------------------------------------------------------------------------
@@ -97,7 +101,8 @@ class Load:
             t.start()
 
     def _serve_one(self, req: dict, t_ref: float) -> None:
-        rec = {"t_ref": t_ref, "prompt": req["prompt"],
+        rec = {"index": req["index"], "t_ref": t_ref,
+               "prompt": req["prompt"],
                "prompt_tokens": len(req["prompt"]),
                "asked": req["max_new_tokens"], "arrivals": [],
                "tokens": [], "logprobs": [], "ended": None}
@@ -123,6 +128,23 @@ class Load:
         if rec["ended"] != "complete" and self.stopping.is_set():
             rec["ended"] = "cut"            # by the end of the load
 
+    def await_streamed(self, plan: list, deadline: float) -> None:
+        """Until every request of `plan` ([(index, tokens)]) has streamed
+        so many tokens or has ended without them, `deadline` (on
+        `time.perf_counter`) at the latest. The load runs on meanwhile:
+        a request that is compared is served beside as many others as
+        in the window."""
+        def ready() -> bool:
+            with self._lock:
+                by_index = {r["index"]: r for r in self.records}
+            return all(index in by_index
+                       and (len(by_index[index]["tokens"]) >= n
+                            or by_index[index]["ended"] is not None)
+                       for index, n in plan)
+
+        while not ready() and time.perf_counter() < deadline:
+            time.sleep(0.05)
+
     def stop(self, timeout_s: float = 60.0) -> None:
         self.stopping.set()
         deadline = time.monotonic() + timeout_s
@@ -135,16 +157,31 @@ class Load:
             raise BenchFailure(f"{alive} client threads did not end")
 
 
-def check_sample(records: list, n: int) -> list:
-    """`n` finished requests that span the range of lengths: evenly
-    spaced by rank of prompt + output, the shortest and the longest
-    among them."""
-    done = sorted((r for r in records if r["ended"] == "complete"),
-                  key=lambda r: r["prompt_tokens"] + r["asked"])
-    if len(done) > n:
-        done = [done[int(i)] for i in np.linspace(0, len(done) - 1, n)]
-    return [{"prompt": r["prompt"], "tokens": r["tokens"],
-             "logprobs": r["logprobs"]} for r in done]
+def check_sample(records: list, plan: list) -> tuple:
+    """(what the replica compares, problems): of each request of `plan`
+    ([(index, tokens)], `traffic.check_plan`) its prompt and its first
+    `tokens` streamed tokens with their logprobs. A request of the plan
+    that streamed fewer is a problem of its own and nothing takes its
+    place: another request would make another sample, and the mean is
+    weighted by tokens. `records` in any order (a closed loop's clients
+    append theirs after they let the generator's lock go)."""
+    by_index = {r["index"]: r for r in records}
+    samples, problems = [], []
+    for index, n in plan:
+        r = by_index.get(index)
+        if r is None or len(r["tokens"]) < n:
+            problems.append(
+                f"request {index} of the mix's first block is one of the "
+                f"{len(plan)} compared with the reference and "
+                + ("was never sent" if r is None else
+                   f"streamed {len(r['tokens'])} of the {n} tokens "
+                   f"compared of it ({r['ended']})")
+                + ": nothing is compared in its place")
+        else:
+            samples.append({"index": index, "prompt": r["prompt"],
+                            "tokens": r["tokens"][:n],
+                            "logprobs": r["logprobs"][:n]})
+    return samples, problems
 
 
 def warm_prompts(info: dict, rng) -> list:
@@ -168,7 +205,7 @@ def warm_prompts(info: dict, rng) -> list:
 
 def _ray_tpu_processes():
     """(pid, parent pid, command line) of every worker-side process of
-    ray_tpu on this host (after `chip_smoke._ray_tpu_processes`)."""
+    ray_tpu on this host."""
     for path in glob.glob("/proc/[0-9]*"):
         try:
             with open(path + "/cmdline", "rb") as f:
@@ -270,11 +307,15 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
         time.sleep(window_s)
         w1 = time.perf_counter()
         window = in_replica("window_stop")
+        plan = traffic_mod.check_plan(mix)
+        stopped = time.perf_counter()     # a traced window's stop: 40-50 s
+        load.await_streamed(plan, w1 + CHECK_WAIT_S)
+        waited_s = time.perf_counter() - stopped
         load.stopping.set()
         cancelled = in_replica("end_load")
         load.stop()
-        verdict = in_replica("check",
-                             check_sample(load.records, mix["check_requests"]))
+        samples, problems = check_sample(load.records, plan)
+        verdict = in_replica("check", samples)
     except BenchFailure as e:
         failure = e
     finally:
@@ -305,19 +346,17 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
          tol["logprob_max_abs"]],
         ["logprob_mean_abs", verdict["logprob_mean_abs"],
          tol["logprob_mean_abs"]],
-        ["requests_compared", verdict["requests"],
-         f">= {min(4, mix['check_requests'])}"],
+        ["requests_compared", verdict["requests"], f"== {len(plan)}"],
+        ["logprob_tokens_compared", verdict["tokens"],
+         f"== {sum(n for _, n in plan)}"],
         ["failed_requests", len(failed), 0],
         ["programs_in_window", window["programs_in_window"], 0],
         ["retraces_unexpected", engine["retraces_unexpected"], 0],
         ["generator_late_ms_p90", late_p90, late_limit],
     ]
-    problems = []
-    if verdict["requests"] < min(4, mix["check_requests"]):
-        problems.append(f"only {verdict['requests']} finished requests to "
-                        f"compare with the reference")
-    elif (verdict["logprob_max_abs"] > tol["logprob_max_abs"]
-          or verdict["logprob_mean_abs"] > tol["logprob_mean_abs"]):
+    if verdict["tokens"] and (
+            verdict["logprob_max_abs"] > tol["logprob_max_abs"]
+            or verdict["logprob_mean_abs"] > tol["logprob_mean_abs"]):
         problems.append(
             f"streamed logprobs are {verdict['logprob_max_abs']} (max) / "
             f"{verdict['logprob_mean_abs']} (mean) from the reference's, "
@@ -388,7 +427,11 @@ def run(cell: dict, config: dict, mix: dict, *, seed: int, seconds: float,
                       "logprob_mean_abs": verdict["logprob_mean_abs"],
                       "logprob_tokens_compared": verdict["tokens"],
                       "logprob_per_request_max":
-                          verdict["per_request_max"]},
+                          verdict["per_request_max"],
+                      "compared_index_prompt_tokens": [
+                          [s["index"], len(s["prompt"]), len(s["tokens"])]
+                          for s in samples],
+                      "waited_for_compared_s": waited_s},
             "engine": {**engine,
                        "slot_occupancy_pct": 100 * engine["slot_occupancy"]},
             "compile": {**verdict["compile"], "warm": warmed},

@@ -176,8 +176,9 @@ class BenchReplica(InferenceReplica):
     def check(self, samples) -> dict:
         """How far the logprobs the engine streamed (prefill, then
         decode through the paged cache) are from the reference's on
-        prompt + output, per sampled request; and the device as JAX
-        reports it here, after everything has run."""
+        prompt + the tokens compared, per request of the mix's plan
+        (`serve_cell.check_sample`); and the device as JAX reports it
+        here, after everything has run."""
         diffs = []
         for s in samples:
             seq = np.concatenate([s["prompt"], s["tokens"]]).astype(np.int32)

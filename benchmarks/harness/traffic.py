@@ -8,22 +8,34 @@ Training mix keys:
   driver "train", batch, seq_len, unroll, mesh {axis: size},
   token_distribution {"zipf_exponent": a}, prefetch_depth,
   warm_dispatches (before the window), check_sequences (sequences of the
-  first batch whose loss is compared with the reference's), trace_s (the
-  part of the window a `--trace 1` run traces), window_s (a cap on the
-  timed window: the cell measures for the smaller of it and `--seconds`)
+  first batch whose loss is compared with the reference's), check_by
+  (optional; "position": the comparison is made position by position and
+  the median of the absolute gaps is held to the configuration's
+  `loss_position_abs`, where the plain mean of the sample is held to
+  `loss_abs` without it: `train_cell.py`), trace_s (the part of the
+  window a `--trace 1` run traces), window_s (a cap on the timed window:
+  the cell measures for the smaller of it and `--seconds`)
 
 Serving mix keys (`serve_requests`):
   driver "serve", loop "open" (rate_per_s) | "closed" (clients),
   prompt_tokens / output_tokens {median, sigma, min, max} (lognormal,
   clipped), length_block (requests a block: every block holds the same
   multiset of lengths and of gaps), order_seed (the order and pairing
-  within each block: the mix's, not the run's), ramp_s (open loop: the load before the window; closed loop: the
-  longest the ramp may take), ramp_requests (closed loop: the window opens
-  when so many requests have been sent),
-  trace_s, check_requests (finished requests whose logprobs are compared
-  with the reference's), warm_new_tokens, request_timeout_s,
-  late_limit_ms (how late the open loop's generator may run, p99; one
+  within each block: the mix's, not the run's), ramp_s (open loop: the
+  load before the window; closed loop: the longest the ramp may take),
+  ramp_requests (closed loop: the window opens when so many requests have
+  been sent), trace_s, warm_new_tokens, request_timeout_s,
+  late_limit_ms (how late the open loop's generator may run, p90; one
   engine tick where the mix does not say)
+
+  What `correct` compares with the reference is the mix's too
+  (`check_plan`): `check_requests` requests of the mix's first block,
+  evenly spaced by rank of prompt + output over the whole block, the
+  same requests and every token of each in every run, whatever its
+  seed, its length or its speed. They are among the first requests of
+  the load, in a closed loop the wave sent at t = 0 and served while
+  the ramp lasts: what a request meets once slots and pages have been
+  freed and taken again is not compared (PERF.md, section 2).
 """
 
 from __future__ import annotations
@@ -60,14 +72,12 @@ def lognormal_quantiles(spec: dict, n: int) -> np.ndarray:
     return np.clip(x, spec["min"], spec["max"]).astype(np.int64)
 
 
-def serve_requests(mix: dict, seed: int, vocab_size: int):
-    """Endless requests {"prompt": int32 ids, "max_new_tokens": n,
-    "due_s": seconds after the load's start (open loop) or None}.
+def serve_blocks(mix: dict):
+    """Endless blocks of `length_block` requests in the order they are
+    sent, each (prompt tokens, output tokens, seconds since the request
+    before it or None): the mix's alone, no seed of a run in it.
 
-    The lengths, their order and the arrival times are the mix's, the
-    same for every seed; the seed draws the token ids (uniform: no two
-    prompts share a prefix). Requests come in blocks of `length_block`;
-    each block holds the same prompt lengths, the same output lengths
+    Each block holds the same prompt lengths, the same output lengths
     and (open loop) the same gaps between arrivals: the mid-quantiles of
     the two lognormals and of the exponential at the mix's rate,
     rescaled so that a block lasts exactly `length_block / rate_per_s`.
@@ -77,7 +87,6 @@ def serve_requests(mix: dict, seed: int, vocab_size: int):
     second by 9.5 % from seed to seed with nothing else changed; PERF.md,
     section 6, PR 30.)"""
     order = np.random.default_rng([mix["order_seed"], 7])
-    rng = np.random.default_rng([seed, 7])
     block = mix["length_block"]
     prompts = lognormal_quantiles(mix["prompt_tokens"], block)
     outputs = lognormal_quantiles(mix["output_tokens"], block)
@@ -86,23 +95,55 @@ def serve_requests(mix: dict, seed: int, vocab_size: int):
         q = (np.arange(block) + 0.5) / block
         gaps = -np.log1p(-q)
         gaps *= block / mix["rate_per_s"] / gaps.sum()
-    due = 0.0
     while True:
         p_order, o_order = order.permutation(block), order.permutation(block)
         g_order = order.permutation(block)
-        for i in range(block):
-            if gaps is not None:
-                due += float(gaps[g_order[i]])
-            yield {"prompt": rng.integers(
-                       0, vocab_size, int(prompts[p_order[i]]),
-                       dtype=np.int32),
-                   "max_new_tokens": int(outputs[o_order[i]]),
-                   "due_s": due if gaps is not None else None}
+        yield [(int(prompts[p_order[i]]), int(outputs[o_order[i]]),
+                None if gaps is None else float(gaps[g_order[i]]))
+               for i in range(block)]
+
+
+def serve_requests(mix: dict, seed: int, vocab_size: int):
+    """Endless requests {"index": its place in the generator's order,
+    "prompt": int32 ids, "max_new_tokens": n, "due_s": seconds after the
+    load's start (open loop) or None}. The lengths, their order and the
+    arrival times are `serve_blocks`', the same for every seed; the seed
+    draws the token ids (uniform: no two prompts share a prefix)."""
+    rng = np.random.default_rng([seed, 7])
+    index, due = 0, 0.0
+    for block in serve_blocks(mix):
+        for prompt_tokens, output_tokens, gap in block:
+            if gap is not None:
+                due += gap
+            yield {"index": index,
+                   "prompt": rng.integers(0, vocab_size, prompt_tokens,
+                                          dtype=np.int32),
+                   "max_new_tokens": output_tokens,
+                   "due_s": None if gap is None else due}
+            index += 1
+
+
+def check_plan(mix: dict) -> list:
+    """[(index, tokens)]: the requests whose streamed logprobs a run
+    compares with the reference's, and how many tokens of each: all it
+    asked for. Of the mix's first block, `check_requests` evenly spaced
+    by rank of prompt + output over the whole block, the shortest and
+    the longest among them. A function of the mix file alone, so two
+    runs compare like with like: the requests that happened to finish
+    differ with the seed, the window and the speed, and a mean weighted
+    by tokens moved with them (PERF.md, section 6, PR 48)."""
+    first = next(serve_blocks(mix))
+    ranked = sorted(range(len(first)),
+                    key=lambda i: first[i][0] + first[i][1])
+    n = mix["check_requests"]
+    if len(ranked) > n:
+        ranked = [ranked[int(i)] for i in np.linspace(0, len(ranked) - 1, n)]
+    return [(i, first[i][1]) for i in ranked]
 
 
 def percentile(values, p: float) -> float | None:
     """The value at rank int(p/100 * n) of the sorted sample (the
-    engine's and `chip_smoke.pct`'s rule); None of nothing."""
+    engine's rule); None of nothing."""
     values = sorted(values)
     if not values:
         return None

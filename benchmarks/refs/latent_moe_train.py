@@ -130,10 +130,10 @@ def logits(params, tokens, config: dict):
         tokens)
 
 
-def loss(params, inputs, targets, config: dict):
-    """Mean negative log-likelihood of targets [B, T] after inputs
-    [B, T], over the rows of the vocabulary held. The logits are made a
-    block of positions at a time."""
+def token_losses(params, inputs, targets, config: dict):
+    """The negative log-likelihood of every target [B, T] after inputs
+    [B, T], over the rows of the vocabulary held: -> float32 [B, T]. The
+    logits are made a block of positions at a time."""
     head = f32(params["head"])
 
     def one(pair):
@@ -150,4 +150,13 @@ def loss(params, inputs, targets, config: dict):
 
         return jax.lax.map(block, jnp.arange(t // tb)).reshape(-1)
 
-    return jnp.mean(jax.lax.map(one, (inputs, targets)))
+    return jax.lax.map(one, (inputs, targets))
+
+
+def loss(params, inputs, targets, config: dict, weights=None):
+    """Mean negative log-likelihood of targets [B, T] after inputs
+    [B, T]; with `weights` [B, T], the mean weighted by them."""
+    nll = token_losses(params, inputs, targets, config)
+    if weights is None:
+        return jnp.mean(nll)
+    return jnp.sum(nll * weights) / jnp.sum(weights)

@@ -274,7 +274,10 @@ def rehearse(spec, tmp_path):
 def test_the_serving_control_is_not_correct(tmp_path):
     """The control of `olmo-1b`'s serving cells, at the tiny size: the
     engine's own int8 weights and int8 cache in the program's place.
-    Every request still gets its tokens; the logprobs are what fails."""
+    Every request still gets its tokens; the logprobs are what fails.
+    The compared requests are the mix's and the seed is fixed: the mean
+    reads 0.0024651686 in ten runs of ten, the sound program 7.9e-8; the
+    tiny limit, 0.0005, stands between the two."""
     cell = CELLS["olmo-1b.chat-closed64"]
     config = tiny_config(cell["config"])
     spec = {"cell": cell, "config": merged(config, config["control"]),
@@ -286,6 +289,8 @@ def test_the_serving_control_is_not_correct(tmp_path):
         result["problems"][0]
     checks = {c[0]: c for c in result["checks"]}
     assert checks["logprob_mean_abs"][1] > 3 * checks["logprob_mean_abs"][2]
+    assert checks["requests_compared"][1:] == [4, "== 4"]
+    assert checks["logprob_tokens_compared"][1:] == [24, "== 24"]
 
 
 @pytest.mark.parametrize("cell_name", list(CELLS))

@@ -7,6 +7,7 @@ is `tests/test_latent_moe_train.py`.)"""
 import json
 import os
 
+import numpy as np
 import pytest
 
 from benchmarks import run as bench_run
@@ -69,6 +70,7 @@ def test_the_file_holds_the_published_widths_and_says_what_it_cut():
     assert set(c["program"]["train"]) == {"flash_block_q", "flash_block_kv"}
     assert "rematerialised" in c["program"]["what"]
     assert c["tolerances"]["loss_abs"] > 0 and c["tolerances"]["why"]
+    assert 0 < c["tolerances"]["loss_position_abs"] < 0.01
     assert 0 < c["tolerances"]["grad_rel_floor"] \
         < c["tolerances"]["grad_rel"] < 1
     assert c["tolerances"]["floor_leaves"] == ["we_gate", "we_up", "we_down"]
@@ -172,8 +174,28 @@ def test_the_tiny_cell_is_correct_and_its_control_is_not(tmp_path):
     assert len(result["problems"]) == 1 and "reference" in \
         result["problems"][0]
     checks = {c[0]: c for c in result["checks"]}
-    sample = checks["program_loss_minus_reference_on_sample"]
+    sample = checks["program_loss_minus_reference_median_by_position"]
     assert sample[1] > 3 * sample[2]
+
+
+def test_position_losses_reads_every_position_through_the_mask():
+    """A loss function that is a masked mean, as the program's is: one
+    call a block, the last block past the end trimmed."""
+    import jax.numpy as jnp
+    from benchmarks.harness.train_cell import position_losses
+    calls = []
+
+    def loss_fn(params, batch, cfg, mesh):
+        calls.append(1)
+        nll = params * batch["inputs"].astype(jnp.float32)
+        return jnp.sum(nll * batch["mask"]) / jnp.maximum(
+            jnp.sum(batch["mask"]), 1.0)
+
+    inputs = np.arange(2 * 7, dtype=np.int32).reshape(2, 7)
+    every = position_losses(loss_fn, None, None, inputs.shape, block=4)
+    got = every(jnp.float32(0.5), {"inputs": inputs})
+    assert got.shape == (2, 7) and (got == 0.5 * inputs).all()
+    assert len(calls) == 1                      # traced once, run 4 times
 
 
 def ctx_with(monkeypatch, kernels, modules, loop_stats):
