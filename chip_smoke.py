@@ -502,7 +502,7 @@ def hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
                            "experts_grouped": n_sparse},
         "prefill_kernels": {"kda_chunk": n_kda,
                             "latent_row_write": n_latent,
-                            "latent_row_gather": n_latent,
+                            "latent_chunk_attend": n_latent,
                             "experts_grouped_prefill": n_sparse},
         "counters": ("state_resets", "kda_tokens_live", "kda_tokens_padded",
                      "latent_rows_read", "expert_tokens_here",

@@ -69,13 +69,13 @@ from ray_tpu.models.family import ServingFamily
 from ray_tpu.ops import grouped_experts, sparse_latent
 
 NEG_INF = sparse_latent.NEG_INF
-CONTEXT_BLOCK = 1024        # cached positions a step of the prefill loops
 
 # what the prefill and decode programs count, in the order of the int32
 # vector they return beside the logits; the held experts' loads follow
 COUNTS = ("index_scanned_tokens", "index_selected_tokens",
           "index_layer_runs", "index_layer_reuses",
           "index_chunk_selections", "index_chunk_thresholds",
+          "chunk_attend_blocks",
           "expert_tokens_here", "expert_tokens_routed")
 
 # training. The embedding's scale in `init_params`: not the 0.02 of the
@@ -494,23 +494,30 @@ def _feed_forward(x, lp, cfg, live=None,
     return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
 
 
-def _counts(cfg, pos, live, expert_counts, chunk: bool = False):
+def _counts(cfg, pos, live, expert_counts, chunk_block: int = 0):
     """The int32 vector a program returns: `COUNTS`, then the held
     experts' loads. pos [N]: each query's position; live [N]: the rows
-    that count. A prefill `chunk` adds its "full" layers to the chunk
+    that count. A prefill chunk (`chunk_block`: the positions a step of
+    its loops over the context takes) adds its "full" layers to the chunk
     selections, and to the chunk thresholds where `_prefill_select` took
-    one (its context is past the top-k); a decode step adds to neither."""
+    one (its context is past the top-k), and counts the context blocks
+    that `latent_chunk_attend` walked, every layer's; a decode step adds
+    to none."""
+    chunk = chunk_block > 0
     n_full = sum(ix == "full" for _, ix in cfg.kinds)
     topk = cfg.index_topk or 0
     scanned = jnp.sum(jnp.where(live, pos + 1, 0))
     selected = jnp.sum(jnp.where(live, jnp.minimum(pos + 1, topk), 0))
-    past = jnp.max(jnp.where(live, pos, 0)) >= topk
+    last = _last(pos, live)
+    past = last >= topk
+    walked = last // chunk_block + 1 if chunk else 0
     experts = sum(expert_counts) if expert_counts else jnp.zeros(
         (2 + cfg.held_count,), jnp.int32)
     return jnp.concatenate([
         jnp.stack([scanned * n_full, selected * n_full,
                    jnp.int32(n_full), jnp.int32(cfg.n_layers - n_full),
                    jnp.int32(n_full * chunk), n_full * chunk * past,
+                   cfg.n_layers * walked,
                    experts[0], experts[1]]).astype(jnp.int32),
         experts[2:].astype(jnp.int32)])
 
@@ -821,13 +828,6 @@ def update_router_bias(params, counts, cfg: LatentSparseMoEConfig):
 # paged prefill of one chunk
 # ---------------------------------------------------------------------------
 
-def _context_block(s: int, bs: int) -> int:
-    b = max(bs, min(s, CONTEXT_BLOCK) // bs * bs)
-    while s % b:
-        b -= bs
-    return b
-
-
 def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
     """A chunk's selection over the cached context: q_i [C, J, Di],
     w [C, J], index [L_full, nb, bs, Di] -> bool [C, S]. Plain
@@ -839,8 +839,8 @@ def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
     from ray_tpu.ops.decode_attention import gather_kv_pages
     _, nb, bs, di = index.shape
     c, s = q_i.shape[0], table.shape[0] * bs
-    sb = _context_block(s, bs)
-    last = jnp.max(jnp.where(valid, positions, 0))
+    sb = sparse_latent.context_block(s, bs)
+    last = _last(positions, valid)
     live = every_earlier(positions, valid, s)
 
     def over_the_context():
@@ -866,50 +866,32 @@ def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
 
 def _prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
                     valid, selected, lp, cfg):
-    """Expanded-head attention of a chunk's queries over the selected
-    positions of the cached context: keys and values rebuilt from the
-    latent rows a block of positions at a time (online softmax), only as
-    far as the chunk's last position. -> [C, H * v]."""
+    """Absorbed latent attention of a chunk's queries, q_nope [C, H, nope]
+    and q_rope [C, H, rope], over the selected positions of the cached
+    context (`decode_attend`'s form: the queries through the key
+    up-projection, scored against the stored rows only as far as the
+    chunk's last position, the mix of rows through the value
+    up-projection). -> [C, H * v]."""
     adt = cfg.activation_dtype()
-    _, nb, bs, _, words = latent.shape
-    flat = latent.reshape(-1, 1, words)
-    c, nh = q_nope.shape[0], cfg.n_heads
-    s = table.shape[0] * bs
-    sb = _context_block(s, bs)
     up = _kv_up(lp, cfg, adt)
-    last = jnp.max(jnp.where(valid, positions, 0))
-    q_nope = q_nope * jnp.asarray(_sm_scale(cfg), adt)
-    q_rope = q_rope * jnp.asarray(_sm_scale(cfg), adt)
+    q_abs = jnp.einsum("qhd,chd->hqc", q_nope, up[..., :cfg.nope_dim],
+                       preferred_element_type=jnp.float32).astype(adt)
+    q = jnp.concatenate([q_abs, q_rope.transpose(1, 0, 2)], -1) \
+        * jnp.asarray(_sm_scale(cfg), adt)
+    mixed = sparse_latent.latent_chunk_attend(
+        q, latent, layer, table, selected, _last(positions, valid),
+        mixed=cfg.kv_rank, dtype=adt, impl=cfg.sparse_impl)
+    # heads first, then a transpose: asked for `qhd`, XLA's CPU dot picks
+    # its loop by the chunk's bucket and a live row's bits follow the
+    # padding
+    att = jnp.einsum("hqc,chd->hqd", mixed, up[..., cfg.nope_dim:],
+                     preferred_element_type=jnp.float32).astype(adt)
+    return att.transpose(1, 0, 2).reshape(-1, cfg.n_heads * cfg.v_dim)
 
-    def block(j, carry):
-        m, l, acc = carry
-        at = j * sb + jnp.arange(sb, dtype=jnp.int32)
-        rows = sparse_latent.unpack_rows(sparse_latent.gather_rows(
-            flat, (table[at // bs] + layer * nb) * bs + at % bs,
-            impl=cfg.sparse_impl), cfg.row_values, adt)
-        kv = jnp.einsum("sc,chd->shd", rows[:, :cfg.kv_rank], up,
-                        preferred_element_type=jnp.float32).astype(adt)
-        sc = (jnp.einsum("qhd,shd->hqs", q_nope, kv[..., :cfg.nope_dim],
-                         preferred_element_type=jnp.float32)
-              + jnp.einsum("qhd,sd->hqs", q_rope, rows[:, cfg.kv_rank:],
-                           preferred_element_type=jnp.float32))
-        live = jax.lax.dynamic_slice_in_dim(selected, j * sb, sb, axis=1)
-        sc = jnp.where(live[None], sc, NEG_INF)
-        m_new = jnp.maximum(m, jnp.max(sc, -1))
-        corr = jnp.exp(m - m_new)
-        p = jnp.exp(sc - m_new[..., None])
-        acc = acc * corr[..., None] + jnp.einsum(
-            "hqs,shd->hqd", p.astype(adt), kv[..., cfg.nope_dim:],
-            preferred_element_type=jnp.float32)
-        return m_new, l * corr + jnp.sum(p, -1), acc
 
-    m, l, acc = jax.lax.fori_loop(
-        0, last // sb + 1, block,
-        (jnp.full((nh, c), NEG_INF, jnp.float32),
-         jnp.zeros((nh, c), jnp.float32),
-         jnp.zeros((nh, c, cfg.v_dim), jnp.float32)))
-    att = acc / jnp.maximum(l, 1e-30)[..., None]
-    return att.astype(adt).transpose(1, 0, 2).reshape(c, nh * cfg.v_dim)
+def _last(positions, valid):
+    """A chunk's last live position."""
+    return jnp.max(jnp.where(valid, positions, 0))
 
 
 def every_earlier(positions, valid, s: int):
@@ -970,7 +952,8 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     x = _norm(x, params["final_ln_scale"], cfg)
     last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
     return (_unembed(last, params, cfg), _pool_of(latent, index),
-            _counts(cfg, positions, valid, expert_counts, chunk=True))
+            _counts(cfg, positions, valid, expert_counts,
+                    sparse_latent.context_block(table.shape[0] * bs, bs)))
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,6 @@
-"""Sparse attention over a paged latent cache: the two decode kernels and
-the row format they read, with pure-JAX paths of identical math.
+"""Sparse attention over a paged latent cache: the decode kernels, a
+prefill chunk's kernel and the row format they read, with pure-JAX paths
+of identical math.
 
 A latent-attention model caches one row a token a layer: the compressed
 key-value vector and the shared rotary key (`[c_kv | k_rope]`). A learned
@@ -15,13 +16,15 @@ words, `[n_blocks, block_size, 1, words]`, `words` a multiple of 128: the
 compiler tiles it (1, 128), a row is `words * 4` contiguous bytes, and
 one DMA moves exactly one. XLA's own gather and scatter would first copy
 the whole pool into the (8, 128) tiling, so on a TPU rows are also written
-(`write_rows`) and read back for prefill (`gather_rows`) by DMA, HBM to
-HBM. A row of 16-bit values is split into a first and a second half, and
-word j holds first[j] in its low and second[j] in its high 16 bits
-(`pack_rows`; the kernel unpacks with a shift and a mask, and a bfloat16
-widened to float32 is those 16 bits shifted left); a row of float32
-values is its words as they are. Either way a row is `parts` arrays of
-`words` lanes, and the query is laid out the same way (`split_query`).
+by DMA, HBM to HBM (`write_rows`), and a prefill chunk's kernel fetches
+whole pages. A row of 16-bit values is split into a first and a second
+half, and word j holds first[j] in its low and second[j] in its high 16
+bits (`pack_rows`; the kernel unpacks with a shift and a mask, and a
+bfloat16 widened to float32 is those 16 bits shifted left); a row of
+float32 values is its words as they are. Either way a row is `parts`
+arrays of `words` lanes, and a decode kernel's query is laid out the same
+way (`split_query`); the chunk's kernel joins the parts back into the
+row's own order and takes its query in that order.
 
 **`index_scores`** (decode): for every stream the indexer's score of each
 cached position, `I[b, s] = sum_j w[b, j] ReLU(q[b, j] . k[s])` in
@@ -40,6 +43,12 @@ softmax, and the weighted sum of the rows themselves, whose leading
 rows come in chunks, double-buffered, one DMA a row; chunks past
 `count[b]` are never fetched, so the bytes scale with min(context, top_k)
 and never with the context.
+
+**`latent_chunk_attend`** (prefill): the same absorbed attention of a
+chunk's queries, every head, over the cached context up to the chunk's
+last position, masked to each query's selection. Dense: every live page
+is fetched once a group of heads and every key scored, the score tile
+made, masked, exponentiated and multiplied into the accumulator in VMEM.
 """
 
 from __future__ import annotations
@@ -59,11 +68,14 @@ NEG_INF = -1e30
 # section 3, lists them. Each call sits in a `named_scope` of its own
 # name: see flash_attention.py.
 SPARSE_LATENT_DECODE, INDEX_SCORES = "sparse_latent_decode", "index_scores"
-LATENT_DECODE = "latent_decode"
+LATENT_DECODE, LATENT_CHUNK_ATTEND = "latent_decode", "latent_chunk_attend"
 
 LANES = 128
 ROW_CHUNK = 256         # cached rows a chunk of the two decode kernels
 INDEX_STEP_TOKENS = 512  # cached positions a grid step of `index_scores`
+CONTEXT_BLOCK = 1024    # cached positions a step of a prefill chunk's loops
+CHUNK_VMEM_LIMIT = 100 << 20    # `latent_chunk_attend`'s scoped VMEM
+CHUNK_VMEM_BUDGET = 80 << 20    # what its plan of heads a grid step fills
 
 
 def _up(n: int, to: int) -> int:
@@ -140,7 +152,7 @@ def join_parts(o, values: int):
 # rows in and out of the pool
 # ---------------------------------------------------------------------------
 
-ROW_WRITE, ROW_GATHER = "latent_row_write", "latent_row_gather"
+ROW_WRITE = "latent_row_write"
 
 
 def _row_copies(n: int, copy):
@@ -192,30 +204,6 @@ def write_rows(pool, rows, at, *, impl: str = "auto"):
             input_output_aliases={2: 0},
             interpret=backend.interpret(),
         )(at.astype(jnp.int32), rows[:, None, :], pool)
-
-
-def _gather_kernel(at_ref, pool_ref, o_ref, sem, *, n: int):
-    _row_copies(n, lambda i: pltpu.make_async_copy(
-        pool_ref.at[at_ref[i]], o_ref.at[i], sem))
-
-
-def gather_rows(pool, at, *, impl: str = "auto"):
-    """pool [n_rows, 1, words] uint32, at [N] i32 -> [N, words]."""
-    if resolve_impl(impl) != "pallas":
-        return pool[at, 0]
-    n = at.shape[0]
-    with jax.named_scope(ROW_GATHER):
-        return pl.pallas_call(
-            functools.partial(_gather_kernel, n=n), name=ROW_GATHER,
-            out_shape=jax.ShapeDtypeStruct((n, 1, pool.shape[-1]),
-                                           pool.dtype),
-            grid_spec=pltpu.PrefetchScalarGridSpec(
-                num_scalar_prefetch=1, grid=(1,),
-                in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
-                out_specs=pl.BlockSpec(memory_space=pl.ANY),
-                scratch_shapes=[pltpu.SemaphoreType.DMA(())]),
-            interpret=backend.interpret(),
-        )(at.astype(jnp.int32), pool)[:, 0]
 
 
 # ---------------------------------------------------------------------------
@@ -575,3 +563,221 @@ def latent_decode(q, pool, layer: int, tables, count, *, dtype,
     if resolve_impl(impl) == "pallas":
         return _latent_decode_pallas(q, pool, layer, tables, count, dtype)
     return reference_latent_decode(q, pool, layer, tables, count, dtype)
+
+
+# ---------------------------------------------------------------------------
+# a prefill chunk over its cached context
+# ---------------------------------------------------------------------------
+
+def context_block(s: int, bs: int) -> int:
+    """Cached positions a step of a prefill chunk's loops over a table of
+    `s` positions in pages of `bs`: whole pages, a divisor of `s`,
+    `CONTEXT_BLOCK` at the most."""
+    b = max(bs, min(s, CONTEXT_BLOCK) // bs * bs)
+    while s % b:
+        b -= bs
+    return b
+
+
+def reference_latent_chunk_attend(q, pool, layer: int, table, selected, last,
+                                  mixed: int, dtype):
+    """`latent_chunk_attend` in plain `jax.numpy`: the context's rows
+    gathered through the table a block of positions at a time, only as far
+    as position `last`, through an online softmax."""
+    h, c, values = q.shape
+    _, nb, bs, _, words = pool.shape
+    sb = context_block(table.shape[0] * bs, bs)
+    flat = pool.reshape(-1, words)
+
+    def block(j, carry):
+        m, l, acc = carry
+        at = j * sb + jnp.arange(sb, dtype=jnp.int32)
+        rows = unpack_rows(
+            flat[(table[at // bs] + layer * nb) * bs + at % bs], values,
+            dtype)
+        sc = jnp.einsum("hqr,sr->hqs", q, rows,
+                        preferred_element_type=jnp.float32)
+        live = jax.lax.dynamic_slice_in_dim(selected, j * sb, sb, axis=1)
+        sc = jnp.where(live[None], sc, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(sc, -1))
+        corr = jnp.exp(m - m_new)
+        p = jnp.exp(sc - m_new[..., None])
+        acc = acc * corr[..., None] + jnp.einsum(
+            "hqs,sm->hqm", p.astype(dtype), rows[:, :mixed],
+            preferred_element_type=jnp.float32)
+        return m_new, l * corr + jnp.sum(p, -1), acc
+
+    m, l, acc = jax.lax.fori_loop(
+        0, last // sb + 1, block,
+        (jnp.full((h, c), NEG_INF, jnp.float32),
+         jnp.zeros((h, c), jnp.float32),
+         jnp.zeros((h, c, mixed), jnp.float32)))
+    return (acc / jnp.maximum(l, 1e-30)[..., None]).astype(q.dtype)
+
+
+def _chunk_kernel(tbl_ref, last_ref, q_ref, mask_ref, pool_ref, o_ref, buf,
+                  mbuf, sem, k_scr, bias_scr, m_scr, l_scr, acc_scr, *,
+                  layer: int, pages: int, block_size: int, dtype):
+    heads, c, _ = q_ref.shape
+    sb, width = k_scr.shape
+    words = buf.shape[-1]
+    n_blocks = last_ref[0] // sb + 1
+
+    def page(j, slot, i):
+        # a page where it lies: its rows are contiguous, one DMA
+        return pltpu.make_async_copy(
+            pool_ref.at[layer, tbl_ref[j * pages + i]],
+            buf.at[slot, pl.ds(i * block_size, block_size)], sem.at[0, slot])
+
+    def mask(j, slot):
+        return pltpu.make_async_copy(
+            mask_ref.at[:, pl.ds(pl.multiple_of(j * sb, sb), sb)],
+            mbuf.at[slot], sem.at[1, slot])
+
+    def issue(j, slot):
+        mask(j, slot).start()
+
+        def start(i, _):
+            page(j, slot, i).start()
+            return _
+        jax.lax.fori_loop(0, pages, start, 0)
+
+    def wait(slot):
+        mask(0, slot).wait()
+
+        def one(i, _):
+            page(0, slot, i).wait()
+            return _
+        jax.lax.fori_loop(0, pages, one, 0)
+
+    m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+    l_scr[...] = jnp.zeros_like(l_scr)
+    acc_scr[...] = jnp.zeros_like(acc_scr)
+    issue(0, 0)
+
+    def block(j, _):
+        slot = j % 2
+
+        @pl.when(j + 1 < n_blocks)
+        def _next():
+            issue(j + 1, 1 - slot)
+
+        wait(slot)
+        # the block's keys in the rows' own order, and its mask as what is
+        # added to a score: made once, read by every head of the group
+        parts = _parts_of(buf[slot].reshape(sb, words), dtype)
+        for i, part in enumerate(parts):
+            lo, hi = i * words, min((i + 1) * words, width)
+            if lo < hi:
+                k_scr[:, lo:hi] = part[:, :hi - lo].astype(k_scr.dtype)
+        bias_scr[...] = jnp.where(mbuf[slot].astype(jnp.int32) != 0, 0.0,
+                                  NEG_INF)
+
+        def head(h, _):
+            s = jax.lax.dot_general(
+                q_ref[h], k_scr[...],
+                dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) + bias_scr[...]
+            m_prev = m_scr[h]                               # [C, LANES]
+            m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m_prev - m_new)
+            p = jnp.exp(s - m_new[:, :1])
+            l_scr[h] = l_scr[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+            m_scr[h] = m_new
+            acc_scr[h] = acc_scr[h] * corr[:, :1] + jax.lax.dot_general(
+                p.astype(k_scr.dtype), k_scr[:, :acc_scr.shape[-1]],
+                dimension_numbers=(((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)
+            return _
+
+        jax.lax.fori_loop(0, heads, head, 0)
+        return _
+
+    jax.lax.fori_loop(0, n_blocks, block, 0)
+
+    def norm(h, _):
+        o_ref[h] = (acc_scr[h] * (1.0 / jnp.maximum(l_scr[h], 1e-30))[:, :1]
+                    ).astype(o_ref.dtype)
+        return _
+    jax.lax.fori_loop(0, heads, norm, 0)
+
+
+def _chunk_heads(h: int, c: int, sb: int, width: int, out: int, words: int,
+                 itemsize: int) -> int:
+    """Heads a grid step of `latent_chunk_attend`: the most that divide
+    `h` and keep the step's VMEM under `CHUNK_VMEM_BUDGET`. A head costs
+    its queries and its output (both double-buffered), its accumulator and
+    its running maximum and sum; the step, the two page and mask buffers,
+    the keys, the mask's addends and a score tile's temporaries."""
+    head = (2 * c * (width + out) * itemsize + c * out * 4
+            + 2 * c * LANES * 4)
+    step = (2 * sb * words * 4 + 2 * c * sb + sb * width * itemsize
+            + 4 * c * sb * 4)
+    fit = max(1, (CHUNK_VMEM_BUDGET - step) // head)
+    return max(g for g in range(1, h + 1) if h % g == 0 and g <= fit)
+
+
+def _latent_chunk_attend_pallas(q, pool, layer: int, table, selected, last,
+                                mixed: int, dtype):
+    h, c, values = q.shape
+    bs, words = pool.shape[2], pool.shape[-1]
+    sb = context_block(table.shape[0] * bs, bs)
+    compute = jnp.bfloat16 if row_parts(dtype) == 2 else jnp.float32
+    width, out = _up(values, LANES), _up(mixed, LANES)
+    padded = jnp.pad(q.astype(compute),
+                     ((0, 0), (0, 0), (0, width - values)))
+    heads = _chunk_heads(h, c, sb, width, out, words,
+                         jnp.dtype(compute).itemsize)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2, grid=(h // heads,),
+        in_specs=[pl.BlockSpec((heads, c, width), lambda g, *_: (g, 0, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((heads, c, out), lambda g, *_: (g, 0, 0)),
+        scratch_shapes=[
+            pltpu.VMEM((2, sb, 1, words), jnp.uint32),
+            pltpu.VMEM((2, c, sb), jnp.int8),
+            pltpu.SemaphoreType.DMA((2, 2)),
+            pltpu.VMEM((sb, width), compute),           # the block's keys
+            pltpu.VMEM((c, sb), jnp.float32),           # 0 or NEG_INF
+            pltpu.VMEM((heads, c, LANES), jnp.float32),     # m
+            pltpu.VMEM((heads, c, LANES), jnp.float32),     # l
+            pltpu.VMEM((heads, c, out), jnp.float32),       # acc
+        ])
+    with jax.named_scope(LATENT_CHUNK_ATTEND):
+        return pl.pallas_call(
+            functools.partial(_chunk_kernel, layer=layer, pages=sb // bs,
+                              block_size=bs, dtype=dtype),
+            name=LATENT_CHUNK_ATTEND,
+            out_shape=jax.ShapeDtypeStruct((h, c, out), q.dtype),
+            grid_spec=grid_spec,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("arbitrary",),
+                vmem_limit_bytes=CHUNK_VMEM_LIMIT),
+            interpret=backend.interpret(),
+        )(table.astype(jnp.int32), jnp.reshape(last, (1,)).astype(jnp.int32),
+          padded, selected.astype(jnp.int8), pool)[..., :mixed]
+
+
+def latent_chunk_attend(q, pool, layer: int, table, selected, last, *,
+                        mixed: int, dtype, impl: str = "auto"):
+    """A chunk's queries, every head, over the cached context of their
+    sequence: dense absorbed latent attention under a mask.
+
+    q [H, C, values]: the absorbed query in a row's own order
+    (`[q_nope W_uk | q_rope]`), the softmax scale folded in. pool
+    [L, n_blocks, bs, 1, words] uint32: the latent pool where it lies;
+    `layer` (static) the layer read. table [max_blocks] i32: the
+    sequence's pages in order (0: the trash block). selected bool
+    [C, max_blocks * bs]: the positions each query attends to. last i32:
+    the chunk's last position; the context is walked in blocks of
+    `context_block` positions only as far as `last`'s, and pages past it
+    are never fetched.
+    -> [H, C, mixed] in q's type, accumulated in float32: the
+    softmax-weighted sum of the leading `mixed` values of the selected
+    rows (a query that selects nothing: finite)."""
+    if resolve_impl(impl) == "pallas":
+        return _latent_chunk_attend_pallas(q, pool, layer, table, selected,
+                                           last, mixed, dtype)
+    return reference_latent_chunk_attend(q, pool, layer, table, selected,
+                                         last, mixed, dtype)

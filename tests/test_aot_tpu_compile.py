@@ -548,6 +548,23 @@ LATENT_KERNELS = {
         [((LS, 32, 128), BF16), ((LS, 32), F32),
          ((2 * LNB, BS, 128), BF16), ((LS, LMB), I32), ((LS,), I32)],
         ("index_scores",)),
+    "latent_chunk_attend": (
+        lambda q, pool, table, selected, last:
+        sparse_latent.latent_chunk_attend(
+            q, pool, 3, table, selected, last, mixed=512, dtype=BF16,
+            impl="pallas"),
+        [((64, 512, 576), BF16), ((6, LNB, BS, 1, 384), U32), ((LMB,), I32),
+         ((512, LMB * BS), jnp.bool_), ((), I32)],
+        ("latent_chunk_attend",)),
+    # `chip_smoke.py`'s serve-hybrid phase: 2 heads, rows of 160 values
+    # in pages of 128, a table of 8 pages, the 128-token bucket
+    "latent_chunk_attend_smoke": (
+        lambda q, pool, table, selected, last:
+        sparse_latent.latent_chunk_attend(
+            q, pool, 0, table, selected, last, mixed=128, dtype=BF16,
+            impl="pallas"),
+        [((2, 128, 160), BF16), ((1, 64, 128, 1, 128), U32), ((8,), I32),
+         ((128, 1024), jnp.bool_), ((), I32)], ("latent_chunk_attend",)),
     "experts_grouped": (
         lambda x, c, w, g, u, d: grouped_experts.experts_grouped(
             x, c, w, g, u, d, held_from=0, impl="pallas"),
@@ -639,7 +656,7 @@ def test_latent_family_programs_compile_at_the_cells_shapes(topo, program):
     compiled, pool = _glm_program(topo, program)
     want = {"decode": {"latent_row_write": 6, "index_scores": 2,
                        "sparse_latent_decode": 6, "experts_grouped": 5},
-            "prefill": {"latent_row_write": 6, "latent_row_gather": 6,
+            "prefill": {"latent_row_write": 6, "latent_chunk_attend": 6,
                         "experts_grouped_prefill": 5}}[program]
     names = kernel_names(compiled.as_text())
     assert {n: names.count(n) for n in set(names)} == want
@@ -665,6 +682,18 @@ def test_latent_prefill_chunk_sorts_nothing_of_the_context(topo):
     assert sorts("prefill")
     assert not [line[:160] for line in sorts("prefill") if "16384" in line]
     assert [line for line in sorts("decode") if "[16,16384]" in line]
+
+
+def test_latent_prefill_chunk_keeps_its_score_tile_in_the_kernel(topo):
+    """A chunk attends in `latent_chunk_attend`: the compiled 512-token
+    chunk makes no array of a head's score tile over a block of context
+    (`f32[64,512,1024]`, which the online softmax in XLA wrote and read
+    seven times a layer a block), and rebuilds no keys and values
+    (`bf16[1024,64,448]`)."""
+    text = _glm_program(topo, "prefill")[0].as_text()
+    assert "latent_chunk_attend" in text
+    assert not [line[:160] for line in text.splitlines()
+                if "[64,512,1024]" in line or "[1024,64,448]" in line]
 
 
 # -- the power-retention family at brumby-14b.docgen-closed24's shapes: 16
@@ -845,7 +874,7 @@ def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
             params, arg((1, chunk)), pool, arg((1 + KMB,)), arg(()),
             arg(())).compile()
         want = {"kda_chunk": KL, "latent_row_write": 1,
-                "latent_row_gather": 1, "experts_grouped_prefill": 6}
+                "latent_chunk_attend": 1, "experts_grouped_prefill": 6}
     names = kernel_names(compiled.as_text())
     assert {n: names.count(n) for n in set(names)} == want
     mem = compiled.memory_analysis()
