@@ -57,9 +57,11 @@ class ServingFamily(NamedTuple):
     ..`; in a ring several columns name one page, and only the latest of
     them is true. A family reads no column that its own bound has left.
     `models/gpt.py` is (0, paged): pages alone. `models/retention.py` is
-    (1, not paged): a state alone. `models/linear_latent.py` is (1,
-    paged): both. `models/window_moe.py` is (0, paged, bounded): full
-    layers' pages that grow and window layers' pages that do not.
+    (1, not paged): a state alone. `models/linear_latent.py` and
+    `models/mamba_moe.py` are (1, paged): both (KDA states beside latent
+    rows; Mamba-2 states beside one attention layer's keys and values).
+    `models/window_moe.py` is (0, paged, bounded): full layers' pages
+    that grow and window layers' pages that do not.
 
     state_blocks: how many blocks of fixed size a request holds
     paged: whether it also holds pages that grow

@@ -1,0 +1,562 @@
+"""A decoder whose every layer is a mixer or a feed-forward part alone:
+Mamba-2 state-space layers, LatentMoE expert layers and grouped-head
+attention without positions, in the order a pattern string gives. The
+layer that `NVIDIA-Nemotron-3-Super-120B-A12B-BF16` names (`nemotron_h`):
+published layer `l` is what `hybrid_override_pattern[l]` says, `M` a
+state layer, `E` an expert layer, `*` an attention layer (40 : 40 : 8 of
+88). Every layer is `y = x + f(RMSNorm(x))` with one learned scale.
+
+`M`, the state layer (`n = RMSNorm(x)`; H heads of P channels, G groups,
+state size N, K convolution taps):
+
+    [z | xBC | dt] = W_in n          (H P | H P + 2 G N | H; no bias)
+    xBC_t <- SiLU(b_c + sum_{j<K} w_c[j] xBC_{t-K+1+j})   depthwise, causal
+    xBC -> x_t [H, P], B_t, C_t [G, N]; head h reads group h // (H / G)
+    d_t = softplus(dt_t + dt_bias);  a_t = exp(d_t A_h),  A_h = -exp(A_log_h)
+    S_t = a_t S_{t-1} + d_t x_t B_t^T;   y_t = S_t C_t + D_h x_t
+    f = W_out RMSNorm_groups(y * SiLU(z))   the gate first, then the mean
+        square over each of G groups of H P / G channels, one scale
+
+`ops/mamba2.py` has the recurrence's two kernels. `*`, the attention
+layer: q = W_q n (Hq heads of d), k, v (Hkv heads), query head `h` reads
+key-value head `h // (Hq / Hkv)`, causal, scale d^-1/2, no positional
+term, `f = W_o concat(heads)` (`ops/decode_attention.py`'s `gqa_full_*`).
+`E`, the expert layer: `s = sigmoid(W_r n)` over the router's published
+width, float32, on the full hidden; the k largest of `s + b` chosen,
+weights `scale x s_e / sum of the chosen s` (`latent_sparse_moe.routing`,
+one group); `u = W_down n` into the latent; the held experts' part `r =
+sum_e w_e W2_e relu(W1_e u)^2` there (`ops/grouped_experts.py`'s ungated
+form); `routed = W_up r`; `shared = W2_s relu(W1_s n)^2` on the full
+hidden; `f = routed + shared`. What the absent experts would add is left
+out. This module is new and `models/linear_latent.py` is not widened:
+that family's layer is a mixer and a feed-forward part both, under two
+norms; nothing but the pool's two kinds would be shared.
+
+**What the engine holds for this family**: one request, two kinds of
+block (`ServingFamily.state_blocks` 1 and `paged`), as
+`models/linear_latent.py`. Column 0 of its table names a state block:
+`"state" [L_m, blocks, H / 2, N, 2 P]` float32 (`ops/mamba2.py`'s
+layout), rewritten by every token, and `"conv" [L_m, blocks, K - 1, H P +
+2 G N]`, the last pre-convolution `xBC` (float32 bytes of activation
+values). The columns after it name pages of `"k"`, `"v"` `[L_a, pages,
+Hkv, block_size, d]`, head-major as `models/window_moe.py`'s. Prefill
+resets the state block on a sequence's first chunk (`start == 0`), a
+chunk bucket's padding leaves state, tail and pages bit for bit, and
+decode's idle rows (table all 0) rewrite the trash blocks of both kinds.
+
+Parameters: the tree `benchmarks/refs/mamba_moe.py` documents.
+`forward` is the whole-sequence form for tests; `prefill` and `decode`
+are what `ServingFamily` asks.
+"""
+
+from __future__ import annotations
+
+from dataclasses import dataclass
+
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models import gpt
+from ray_tpu.models import latent_sparse_moe as lsm
+from ray_tpu.models import window_moe
+from ray_tpu.models.family import ServingFamily
+from ray_tpu.ops import decode_attention as da
+from ray_tpu.ops import grouped_experts, mamba2
+
+# what the prefill and decode programs count, in the order of the int32
+# vector they return beside the logits; the held experts' loads follow
+COUNTS = ("mamba_tokens_live", "mamba_tokens_padded", "state_resets",
+          "attention_rows_read", "expert_tokens_here",
+          "expert_tokens_routed")
+STATE_KEYS = ("state", "conv")      # the pool's arrays of state blocks
+KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
+EMBED_INIT = 1.0
+
+
+@dataclass(frozen=True)
+class MambaMoEConfig:
+    vocab_size: int = 512
+    d_model: int = 64
+    n_layers: int = 3
+    # one character a published layer (`KINDS`); the layers that run are
+    # [first_layer, first_layer + n_layers)
+    pattern: str = "ME*"
+    first_layer: int = 0
+    mamba_heads: int = 4
+    mamba_head_dim: int = 16
+    n_groups: int = 2
+    state_size: int = 16
+    conv_size: int = 4
+    n_heads: int = 4
+    n_kv_heads: int = 2
+    head_dim: int = 16
+    latent_dim: int = 32
+    expert_ff: int = 48
+    shared_ff: int = 96
+    router_width: int = 8
+    experts_per_token: int = 3
+    held_from: int = 0
+    held_count: int = 8
+    routed_scale: float = 1.0
+    norm_topk: bool = True
+    eps: float = 1e-5
+    max_seq_len: int = 128
+    dtype: str = "bfloat16"
+    mamba_impl: str = "auto"         # auto | pallas | jax (both ops)
+    attn_impl: str = "auto"          # auto | pallas | jax (gqa_full_*)
+    sparse_impl: str = "auto"        # auto | pallas | jax (the experts)
+    # test-only, for the benchmark's control: "bfloat16" rounds the
+    # recurrence's state to bfloat16 at every write and keeps float32 bytes
+    state_round: str = "none"        # none | bfloat16
+
+    def __post_init__(self):
+        if set(self.pattern) - set(KINDS) \
+                or len(self.pattern) < self.first_layer + self.n_layers:
+            raise ValueError(f"a layer is one of {sorted(KINDS)}, and the "
+                             f"pattern names every layer that runs")
+        if self.mamba_heads % (2 * self.n_groups) \
+                or self.n_heads % self.n_kv_heads:
+            raise ValueError("a group's state heads lie in pairs, and the "
+                             "query heads divide over the key-value heads")
+        if self.state_round not in ("none", "bfloat16"):
+            raise ValueError(f"unknown state_round {self.state_round!r}")
+
+    @property
+    def kinds(self) -> tuple:
+        """"mamba", "attention" or "experts", one a layer that runs."""
+        lo = self.first_layer
+        return tuple(KINDS[c] for c in self.pattern[lo:lo + self.n_layers])
+
+    @property
+    def inner(self) -> int:
+        return self.mamba_heads * self.mamba_head_dim
+
+    @property
+    def conv_channels(self) -> int:
+        return self.inner + 2 * self.n_groups * self.state_size
+
+    def activation_dtype(self):
+        return jnp.dtype(self.dtype)
+
+    @property
+    def family(self):
+        return FAMILY
+
+
+def from_published(*, hidden_size, num_hidden_layers, hybrid_override_pattern,
+                   mamba_num_heads, mamba_head_dim, n_groups, ssm_state_size,
+                   conv_kernel, num_attention_heads, num_key_value_heads,
+                   head_dim, moe_latent_size, moe_intermediate_size,
+                   moe_shared_expert_intermediate_size, n_routed_experts,
+                   num_experts_per_tok, routed_scaling_factor, norm_topk_prob,
+                   layer_norm_epsilon, max_position_embeddings, layers_from=0,
+                   experts_held_from=0, published=None,
+                   **same) -> MambaMoEConfig:
+    """The configuration file's published keys -> `MambaMoEConfig`
+    (`benchmarks/configs/nemotron-3-super.json`, `program.constructor`).
+    `n_routed_experts` is how many experts are held here; the router's
+    width is `published["n_routed_experts"]` where a share is run."""
+    return MambaMoEConfig(
+        d_model=hidden_size, n_layers=num_hidden_layers,
+        pattern=hybrid_override_pattern, first_layer=layers_from,
+        mamba_heads=mamba_num_heads, mamba_head_dim=mamba_head_dim,
+        n_groups=n_groups, state_size=ssm_state_size, conv_size=conv_kernel,
+        n_heads=num_attention_heads, n_kv_heads=num_key_value_heads,
+        head_dim=head_dim, latent_dim=moe_latent_size,
+        expert_ff=moe_intermediate_size,
+        shared_ff=moe_shared_expert_intermediate_size,
+        router_width=(published or {}).get("n_routed_experts",
+                                           n_routed_experts),
+        experts_per_token=num_experts_per_tok, held_from=experts_held_from,
+        held_count=n_routed_experts,
+        routed_scale=float(routed_scaling_factor), norm_topk=norm_topk_prob,
+        eps=layer_norm_epsilon, max_seq_len=max_position_embeddings, **same)
+
+
+def init_params(key, cfg: MambaMoEConfig):
+    """Float32 leaves, for tests; the tree `benchmarks/refs/mamba_moe.py`
+    documents. A head's step and decay spread over the heads so that one
+    remembers a few positions and another some thousands; the embedding
+    at `EMBED_INIT`, so that a token's own vector and not its context's
+    mean decides its experts."""
+    d, inner = cfg.d_model, cfg.inner
+    h, ch = cfg.mamba_heads, cfg.conv_channels
+    hq, hkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
+    lat, f, fs = cfg.latent_dim, cfg.expert_ff, cfg.shared_ff
+    residual = float(cfg.n_layers) ** -0.5
+    keys = iter(jax.random.split(key, 2 + 8 * cfg.n_layers))
+
+    def normal(shape, scale):
+        return jax.random.normal(next(keys), shape, jnp.float32) * scale
+
+    def ones(n):
+        return jnp.ones((n,), jnp.float32)
+
+    layers = []
+    for kind in cfg.kinds:
+        lp = {"norm_scale": ones(d)}
+        if kind == "mamba":
+            step = jnp.exp(jnp.linspace(jnp.log(1e-3), jnp.log(1e-1), h))
+            lp.update(
+                w_in=normal((d, inner + ch + h), d ** -0.5),
+                conv_w=normal((cfg.conv_size, ch), cfg.conv_size ** -0.5),
+                conv_b=normal((ch,), 0.1),
+                dt_bias=step + jnp.log(-jnp.expm1(-step)),
+                a_log=jnp.log(jnp.linspace(1.0, 16.0, h)),
+                d_skip=ones(h), gate_norm_scale=ones(inner),
+                w_out=normal((inner, d), inner ** -0.5 * residual))
+        elif kind == "attention":
+            lp.update(
+                w_q=normal((d, hq * hd), d ** -0.5),
+                w_k=normal((d, hkv * hd), d ** -0.5),
+                w_v=normal((d, hkv * hd), d ** -0.5),
+                w_out=normal((hq * hd, d), (hq * hd) ** -0.5 * residual))
+        else:
+            lp.update(
+                router=normal((d, cfg.router_width), d ** -0.5),
+                router_bias=normal((cfg.router_width,), 0.01),
+                latent_down=normal((d, lat), d ** -0.5),
+                we_up=normal((cfg.held_count, f, lat), lat ** -0.5),
+                we_down=normal((cfg.held_count, f, lat), f ** -0.5),
+                latent_up=normal((lat, d), lat ** -0.5 * residual),
+                ws_up=normal((d, fs), d ** -0.5),
+                ws_down=normal((fs, d), fs ** -0.5 * residual))
+        layers.append(lp)
+    return {"embed": normal((cfg.vocab_size, d), EMBED_INIT),
+            "head": normal((cfg.vocab_size, d), d ** -0.5),
+            "final_norm_scale": ones(d), "layers": layers}
+
+
+# ---------------------------------------------------------------------------
+# the pool
+# ---------------------------------------------------------------------------
+
+def init_pool(cfg: MambaMoEConfig, n_blocks: int, block_size: int,
+              mesh=None, *, state_blocks: int):
+    """{"state", "conv"} with `state_blocks` blocks on axis 1 and {"k",
+    "v"} with `n_blocks` pages, zero-filled; block 0 of each the trash
+    block."""
+    if mesh is not None:
+        raise ValueError("this family's pool is not sharded over a mesh")
+    n_mamba = cfg.kinds.count("mamba")
+    n_attn = cfg.kinds.count("attention")
+
+    def pages():
+        return jnp.zeros((n_attn, n_blocks, cfg.n_kv_heads, block_size,
+                          cfg.head_dim), cfg.activation_dtype())
+
+    return {
+        "state": jnp.zeros((n_mamba, state_blocks, cfg.mamba_heads // 2,
+                            cfg.state_size, 2 * cfg.mamba_head_dim),
+                           jnp.float32),
+        "conv": jnp.zeros((n_mamba, state_blocks, cfg.conv_size - 1,
+                           cfg.conv_channels), jnp.float32),
+        "k": pages(), "v": pages(),
+    }
+
+
+# ---------------------------------------------------------------------------
+# pieces of the layers
+# ---------------------------------------------------------------------------
+
+def _in_proj(n, lp, cfg):
+    """Normed n [N, D] -> (z [N, H P] in the activation type, xBC [N, H P
+    + 2 G N] float32 values of the activation type, dt [N, H] float32)."""
+    adt = cfg.activation_dtype()
+    proj = jnp.einsum("nd,df->nf", n, lp["w_in"].astype(adt),
+                      preferred_element_type=jnp.float32)
+    inner, ch = cfg.inner, cfg.conv_channels
+    return (proj[:, :inner].astype(adt),
+            proj[:, inner:inner + ch].astype(adt).astype(jnp.float32),
+            proj[:, inner + ch:])
+
+
+def _conv_act(conved, lp):
+    """The taps' sum [N, channels] f32 -> SiLU(. + bias)."""
+    return jax.nn.silu(conved + lp["conv_b"].astype(jnp.float32))
+
+
+def _ssm_inputs(act, dt, lp, cfg):
+    """The convolution's output [N, channels] f32 and the raw steps
+    -> (x [N, H, P], B, C [N, G, N] in the activation type, d [N, H] f32
+    > 0, A [H] f32 < 0)."""
+    adt = cfg.activation_dtype()
+    rows, inner = act.shape[0], cfg.inner
+    g, ns = cfg.n_groups, cfg.state_size
+    act = act.astype(adt)
+    x = act[:, :inner].reshape(rows, cfg.mamba_heads, cfg.mamba_head_dim)
+    b = act[:, inner:inner + g * ns].reshape(rows, g, ns)
+    c = act[:, inner + g * ns:].reshape(rows, g, ns)
+    step = jax.nn.softplus(dt + lp["dt_bias"].astype(jnp.float32))
+    return x, b, c, step, -jnp.exp(lp["a_log"].astype(jnp.float32))
+
+
+def _mamba_out(y, x, z, lp, cfg):
+    """The recurrence's y [N, H, P] f32 with the skip, the gate, the
+    grouped norm and W_out: -> [N, D]."""
+    adt = cfg.activation_dtype()
+    rows = y.shape[0]
+    y = y + lp["d_skip"].astype(jnp.float32)[:, None] * x.astype(jnp.float32)
+    y = y.reshape(rows, -1) * jax.nn.silu(z.astype(jnp.float32))
+    grouped = y.reshape(rows, cfg.n_groups, -1)
+    grouped = grouped * jax.lax.rsqrt(
+        jnp.mean(grouped * grouped, -1, keepdims=True) + cfg.eps)
+    out = grouped.reshape(rows, -1) * lp["gate_norm_scale"].astype(
+        jnp.float32)
+    return lsm._mm(out.astype(adt), lp["w_out"], adt)
+
+
+def _qkv(n, lp, cfg):
+    """Normed n [N, D] -> q [N, Hq, d], k, v [N, Hkv, d]; no positional
+    term."""
+    adt = cfg.activation_dtype()
+    rows = n.shape[0]
+    return (lsm._mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads,
+                                               cfg.head_dim),
+            lsm._mm(n, lp["w_k"], adt).reshape(rows, cfg.n_kv_heads,
+                                               cfg.head_dim),
+            lsm._mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads,
+                                               cfg.head_dim))
+
+
+def _relu2_mlp(n, w_up, w_down, adt):
+    up = jnp.einsum("nd,df->nf", n, w_up.astype(adt),
+                    preferred_element_type=jnp.float32)
+    return lsm._mm(jnp.square(jax.nn.relu(up)).astype(adt), w_down, adt)
+
+
+def _experts(n, lp, cfg, live, kernel):
+    """-> (what the held experts, through the latent, and the shared one
+    add [N, D]; counts i32: pairs routed here, pairs routed anywhere,
+    then the pairs each held expert got; rows where `live` is false count
+    nothing)."""
+    adt = cfg.activation_dtype()
+    chosen, weights = lsm.routing(n, lp, cfg)
+    if live is not None:
+        chosen = jnp.where(live[:, None], chosen, -1)
+    with jax.named_scope("latent_projections"):
+        u = lsm._mm(n, lp["latent_down"], adt)
+    with jax.named_scope("routed_experts"):
+        r, load = grouped_experts.experts_grouped(
+            u, chosen, weights, None, lp["we_up"], lp["we_down"],
+            held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
+    with jax.named_scope("latent_projections"):
+        routed = lsm._mm(r.astype(adt), lp["latent_up"], adt)
+    with jax.named_scope("shared_experts"):
+        shared = _relu2_mlp(n, lp["ws_up"], lp["ws_down"], adt)
+    counts = jnp.concatenate([
+        jnp.stack([jnp.sum(load), jnp.sum(chosen >= 0, dtype=jnp.int32)]),
+        load])
+    return routed + shared, counts
+
+
+def _counts(cfg, head, expert_counts):
+    """`COUNTS`' first four, then the experts' two and their loads."""
+    experts = sum(expert_counts) if expert_counts else jnp.zeros(
+        (2 + cfg.held_count,), jnp.int32)
+    return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
+                            experts.astype(jnp.int32)])
+
+
+def summarize(cfg, totals) -> dict:
+    """`COUNTS` summed over a window (None: nothing ran yet) -> the
+    engine's `stats()` entries."""
+    if totals is None:
+        totals = [0] * (len(COUNTS) + cfg.held_count)
+    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
+    load = [int(v) for v in totals[len(COUNTS):]]
+    mean = sum(load) / max(len(load), 1)
+    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
+    return out
+
+
+def _unembed(x, params, cfg):
+    return jnp.einsum("...d,vd->...v", x,
+                      params["head"].astype(cfg.activation_dtype()),
+                      preferred_element_type=jnp.float32)
+
+
+# ---------------------------------------------------------------------------
+# whole sequence (tests)
+# ---------------------------------------------------------------------------
+
+def forward(params, tokens, cfg: MambaMoEConfig):
+    """tokens [B, T] -> logits [B, T, V] f32, by the definition: the
+    recurrence token by token, no state kept, no cache."""
+    adt = cfg.activation_dtype()
+    taps = cfg.conv_size
+
+    def one(seq):
+        t = seq.shape[0]
+        live = jnp.ones((t,), bool)
+        x = params["embed"].astype(adt)[seq]
+        for lp, kind in zip(params["layers"], cfg.kinds):
+            n = lsm._norm(x, lp["norm_scale"], cfg)
+            if kind == "mamba":
+                z, xbc, dt = _in_proj(n, lp, cfg)
+                pre = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
+                w = lp["conv_w"].astype(jnp.float32)
+                act = _conv_act(sum(w[i] * pre[i:i + t]
+                                    for i in range(taps)), lp)
+                xs, b, c, step, a = _ssm_inputs(act, dt, lp, cfg)
+                y = mamba2.mamba2_recurrent(xs, step, a, b, c)[0]
+                x = x + _mamba_out(y, xs, z, lp, cfg)
+            elif kind == "attention":
+                q, k, v = _qkv(n, lp, cfg)
+                att = da.reference_gqa_attention(
+                    q[None], k[None], v[None], jnp.zeros((1,), jnp.int32))[0]
+                x = x + lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
+            else:
+                x = x + _experts(n, lp, cfg, live,
+                                 grouped_experts.EXPERTS_GROUPED)[0]
+        return _unembed(lsm._norm(x, params["final_norm_scale"], cfg),
+                        params, cfg)
+
+    return jax.lax.map(one, tokens)
+
+
+# ---------------------------------------------------------------------------
+# what the engine calls
+# ---------------------------------------------------------------------------
+
+def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
+            block_table, start, length=None):
+    """One chunk of one sequence (`gpt.prefill_paged`'s contract): tokens
+    [1, C] at positions start .. start + length - 1; `block_table[0]` the
+    sequence's state block, the rest its pages. A chunk that starts the
+    sequence resets state and tail. -> (logits [1, V] f32 of the chunk's
+    last real position, cache, counts)."""
+    c = tokens.shape[1]
+    if tokens.shape[0] != 1:
+        raise ValueError(f"prefill wants tokens [1, C], got batch "
+                         f"{tokens.shape[0]}")
+    adt = cfg.activation_dtype()
+    taps = cfg.conv_size
+    cache = dict(cache)
+    start = jnp.asarray(start, jnp.int32)
+    length = jnp.asarray(c if length is None else length, jnp.int32)
+    table = jnp.asarray(block_table, jnp.int32)
+    block, pages = table[0], table[1:]
+    first = start == 0
+    offs = jnp.arange(c, dtype=jnp.int32)
+    positions = start + offs
+    valid = offs < length
+    x = params["embed"].astype(adt)[tokens[0]]
+    n_mamba = n_attn = 0
+    expert_counts = []
+    for lp, kind in zip(params["layers"], cfg.kinds):
+        n = lsm._norm(x, lp["norm_scale"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("mamba_layer"):
+                z, xbc, dt = _in_proj(n, lp, cfg)
+                tail = jnp.where(first, 0.0, cache["conv"][n_mamba, block])
+                pre = jnp.concatenate([tail, xbc])
+                w = lp["conv_w"].astype(jnp.float32)
+                act = _conv_act(sum(w[i] * pre[i:i + c]
+                                    for i in range(taps)), lp)
+                # the last live positions' xBC, whatever the padding
+                cache["conv"] = cache["conv"].at[n_mamba, block].set(
+                    jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
+                xs, b, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+                y, cache["state"] = mamba2.mamba2_chunk(
+                    xs, step, a, b, cc, cache["state"], n_mamba, block,
+                    first, length, state_round=cfg.state_round,
+                    impl=cfg.mamba_impl)
+                x = x + _mamba_out(y, xs, z, lp, cfg)
+            n_mamba += 1
+        elif kind == "attention":
+            with jax.named_scope("attention_layer"):
+                q, k, v = _qkv(n, lp, cfg)
+                cache["k"] = window_moe._write_chunk(
+                    cache["k"], n_attn, k, pages, start, length)
+                cache["v"] = window_moe._write_chunk(
+                    cache["v"], n_attn, v, pages, start, length)
+                att = da.gqa_chunk_attention(
+                    q, cache["k"], cache["v"], pages, start, layer=n_attn,
+                    impl=cfg.attn_impl)
+                x = x + lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
+            n_attn += 1
+        else:
+            ff, counts = _experts(n, lp, cfg, valid,
+                                  grouped_experts.EXPERTS_GROUPED_PREFILL)
+            expert_counts.append(counts)
+            x = x + ff
+    x = lsm._norm(x, params["final_norm_scale"], cfg)
+    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+    rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
+    return (_unembed(last, params, cfg), cache,
+            _counts(cfg, [length * n_mamba, (c - length) * n_mamba, first,
+                          rows], expert_counts))
+
+
+def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
+           mesh=None):
+    """One token for every slot (`gpt.decode_step_paged`'s contract):
+    tokens [B] at positions pos [B]; `tables[:, 0]` each row's state
+    block, the rest its pages. Idle rows name the trash blocks of both
+    kinds, rewrite them and count nothing.
+    -> (logits [B, V] f32, cache, counts)."""
+    adt = cfg.activation_dtype()
+    cache = dict(cache)
+    bs = cache["k"].shape[3]
+    b = tokens.shape[0]
+    pos = pos.astype(jnp.int32)
+    tables = tables.astype(jnp.int32)
+    blocks, pages = tables[:, 0], tables[:, 1:]
+    live = blocks > 0
+    cols = pages.shape[1]
+    page = jnp.minimum(pos // bs, cols - 1)[:, None]
+    widx = jnp.where(
+        pos < cols * bs,
+        jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
+        cache["k"].shape[1] * bs)
+    x = params["embed"].astype(adt)[tokens]
+    n_mamba = n_attn = 0
+    expert_counts = []
+    for lp, kind in zip(params["layers"], cfg.kinds):
+        n = lsm._norm(x, lp["norm_scale"], cfg)
+        if kind == "mamba":
+            with jax.named_scope("mamba_layer"):
+                z, xbc, dt = _in_proj(n, lp, cfg)
+                pre = jnp.concatenate(
+                    [cache["conv"][n_mamba, blocks], xbc[:, None]], 1)
+                act = _conv_act(jnp.einsum(
+                    "kc,bkc->bc", lp["conv_w"].astype(jnp.float32), pre), lp)
+                cache["conv"] = cache["conv"].at[n_mamba, blocks].set(
+                    pre[:, 1:])
+                xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+                y, cache["state"] = mamba2.mamba2_step(
+                    xs, step, a, bb, cc, cache["state"], n_mamba, blocks,
+                    state_round=cfg.state_round, impl=cfg.mamba_impl)
+                x = x + _mamba_out(y, xs, z, lp, cfg)
+            n_mamba += 1
+        elif kind == "attention":
+            with jax.named_scope("attention_layer"):
+                q, k, v = _qkv(n, lp, cfg)
+                cache["k"] = window_moe._write_rows(cache["k"], n_attn, k,
+                                                    widx)
+                cache["v"] = window_moe._write_rows(cache["v"], n_attn, v,
+                                                    widx)
+                att = da.gqa_decode_attention(
+                    q, cache["k"], cache["v"], pages, pos, layer=n_attn,
+                    impl=cfg.attn_impl)
+                x = x + lsm._mm(att.reshape(b, -1), lp["w_out"], adt)
+            n_attn += 1
+        else:
+            ff, counts = _experts(n, lp, cfg, live,
+                                  grouped_experts.EXPERTS_GROUPED)
+            expert_counts.append(counts)
+            x = x + ff
+    x = lsm._norm(x, params["final_norm_scale"], cfg)
+    n_live = jnp.sum(live, dtype=jnp.int32)
+    rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
+    return (_unembed(x, params, cfg), cache,
+            _counts(cfg, [n_live * n_mamba, (b - n_live) * n_mamba,
+                          jnp.int32(0), rows], expert_counts))
+
+
+FAMILY = ServingFamily(
+    init_pool=init_pool, prefill=prefill, decode=decode,
+    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
+    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
+    state_keys=STATE_KEYS)

@@ -5,17 +5,26 @@ this chip holds `held` of them (`held_from` .. `held_from + held - 1`) and
 computes their part of the result for the tokens routed to them, however
 many or few those are. `experts_grouped` sorts the (token, expert) pairs
 by expert, lays each held expert's tokens out as a group of whole row
-tiles, and runs one gated MLP (gate, up, down) a group:
+tiles, and runs one MLP a group, in one of two forms of an expert:
 
+    gated (w_gate given; gate, up, down):
     y[t] = sum over the held experts e that t chose of
            weight[t, e] * (silu(x[t] Wg_e^T) * (x[t] Wu_e^T)) Wd_e
+    ungated relu^2 (w_gate None; up, down):
+    y[t] = sum ... of weight[t, e] * relu(x[t] Wu_e^T)^2 Wd_e
+
+`glm-5.2`, `kanana-2-30b-a3b`, `ling-3.0-flash-vl` and `command-a-plus`
+run the gated form at the model's own width (`models/
+latent_sparse_moe.py:expert_layer`); `nemotron-3-super` runs the ungated
+one in its 1,024-wide latent (`models/mamba_moe.py`), forward only. Both
+are one kernel body under the same names, so the same metrics read them.
 
 The layout has a static size, the worst case (every pair held, every group
 with a ragged tile): `N * k + held * tile` rows. The kernel's grid walks
 (row tile, slice of the expert width); tiles past the last group are
 skipped and re-use the last live tile's blocks, so they move no bytes. An
-expert's three matrices are stored `[held, F, D]`, width first, so that a
-slice of the width is whole rows of D.
+expert's matrices (three, or two) are stored `[held, F, D]`, width first,
+so that a slice of the width is whole rows of D.
 
 The pure-JAX path is the plain loop over the held experts, each over every
 token; it differentiates as it is, and is what the kernels are compared
@@ -56,6 +65,7 @@ EXPERTS_GROUPED_DX = "experts_grouped_dx"
 EXPERTS_GROUPED_DW = "experts_grouped_dw"
 
 WIDTH_SLICE = 256           # rows of the expert width a grid step
+LANES = 128
 VMEM_LIMIT = 64 * 1024 * 1024
 
 # `dot_general` dimension numbers
@@ -67,18 +77,21 @@ _TN = (((0,), (0,)), ((), ()))      # [c, a] x [c, b] -> [a, b]
 def reference_experts_grouped(x, chosen, weights, w_gate, w_up, w_down,
                               held_from: int):
     """x [N, D]; chosen [N, k] i32 expert ids; weights [N, k] f32;
-    w_gate, w_up, w_down [held, F, D] -> [N, D] f32."""
-    held = w_gate.shape[0]
+    w_gate (None: the ungated relu^2 form), w_up, w_down [held, F, D]
+    -> [N, D] f32."""
+    held = w_up.shape[0]
 
     def expert(y, e):
         i, wg, wu, wd = e
         mine = jnp.sum(jnp.where(chosen == held_from + i, weights, 0.0), -1)
-        gate = jnp.einsum("nd,fd->nf", x, wg.astype(x.dtype),
-                          preferred_element_type=jnp.float32)
+        if wg is not None:
+            gate = jnp.einsum("nd,fd->nf", x, wg.astype(x.dtype),
+                              preferred_element_type=jnp.float32)
         up = jnp.einsum("nd,fd->nf", x, wu.astype(x.dtype),
                         preferred_element_type=jnp.float32)
-        out = jnp.einsum("nf,fd->nd",
-                         (jax.nn.silu(gate) * up).astype(x.dtype),
+        hidden = (jnp.square(jax.nn.relu(up)) if wg is None
+                  else jax.nn.silu(gate) * up)
+        out = jnp.einsum("nf,fd->nd", hidden.astype(x.dtype),
                          wd.astype(x.dtype),
                          preferred_element_type=jnp.float32)
         return y + mine[:, None] * out, None
@@ -133,8 +146,11 @@ def group_layout(chosen, held_from: int, held: int, tile: int):
             n_live.astype(jnp.int32), load)
 
 
-def _experts_kernel(expert_ref, block_ref, live_ref, x_ref, wg_ref, wu_ref,
-                    wd_ref, o_ref, acc):
+def _experts_kernel(expert_ref, block_ref, live_ref, x_ref, *refs):
+    """One row tile against one slice of its expert's width. `refs`: the
+    expert's matrices (gate, up, down; or up, down: the ungated relu^2
+    form), the result's tile and the accumulator."""
+    *w_in, wd_ref, o_ref, acc = refs
     t, f = pl.program_id(0), pl.program_id(1)
 
     @pl.when(t < live_ref[0])
@@ -144,12 +160,14 @@ def _experts_kernel(expert_ref, block_ref, live_ref, x_ref, wg_ref, wu_ref,
             acc[...] = jnp.zeros_like(acc)
 
         x = x_ref[...]
-        gate = jax.lax.dot_general(x, wg_ref[0], _NT,
-                                   preferred_element_type=jnp.float32)
-        up = jax.lax.dot_general(x, wu_ref[0], _NT,
+        if len(w_in) == 2:
+            gate = jax.lax.dot_general(x, w_in[0][0], _NT,
+                                       preferred_element_type=jnp.float32)
+        up = jax.lax.dot_general(x, w_in[-1][0], _NT,
                                  preferred_element_type=jnp.float32)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)   # [tile, slice]
-        acc[...] += jax.lax.dot_general(
+        hidden = (jax.nn.silu(gate) * up if len(w_in) == 2
+                  else jnp.square(jax.nn.relu(up))).astype(x.dtype)
+        acc[...] += jax.lax.dot_general(                    # [tile, slice]
             hidden, wd_ref[0], _NN, preferred_element_type=jnp.float32)
 
         @pl.when(f == pl.num_programs(1) - 1)
@@ -158,10 +176,17 @@ def _experts_kernel(expert_ref, block_ref, live_ref, x_ref, wg_ref, wu_ref,
 
 
 def _width_slice(width: int) -> tuple[int, int]:
-    """(rows of the expert width a grid step, steps)."""
+    """(rows of the expert width a grid step, steps): the largest divisor
+    of the width up to `WIDTH_SLICE`; where that is not whole lane tiles
+    and the width is (2,688 = 21 x 128 walks down to 224), the next
+    divisor above that is (384)."""
     fs = min(WIDTH_SLICE, width)
     while width % fs:
         fs -= 1
+    if fs % LANES and width % LANES == 0:
+        fs = WIDTH_SLICE + LANES
+        while width % fs:
+            fs += LANES
     return fs, width // fs
 
 
@@ -177,13 +202,14 @@ def _held_slice(nf: int):
 def _grouped_pallas(xs, tile_expert, tile_block, n_live, w_gate, w_up,
                     w_down, tile: int, name: str):
     m, d = xs.shape
-    fs, nf = _width_slice(w_gate.shape[1])
+    fs, nf = _width_slice(w_up.shape[1])
+    matrices = [w for w in (w_gate, w_up, w_down) if w is not None]
     rows = pl.BlockSpec((tile, d), lambda t, f, ex, blk, live: (blk[t], 0))
     weight = pl.BlockSpec((1, fs, d), _held_slice(nf))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(m // tile, nf),
-        in_specs=[rows, weight, weight, weight],
+        in_specs=[rows] + [weight] * len(matrices),
         out_specs=rows,
         scratch_shapes=[pltpu.VMEM((tile, d), jnp.float32)],
     )
@@ -196,7 +222,7 @@ def _grouped_pallas(xs, tile_expert, tile_block, n_live, w_gate, w_up,
                 dimension_semantics=("arbitrary", "arbitrary"),
                 vmem_limit_bytes=VMEM_LIMIT),
             interpret=backend.interpret(),
-        )(tile_expert, tile_block, n_live[None], xs, w_gate, w_up, w_down)
+        )(tile_expert, tile_block, n_live[None], xs, *matrices)
 
 
 # ---------------------------------------------------------------------------
@@ -365,8 +391,9 @@ def _grouped(name: str, tile: int, x, weights, w_gate, w_up, w_down, layout):
     tile_expert, tile_block, n_live, load) of `group_layout`."""
     dest, src, tile_expert, tile_block, n_live, _ = layout
     ys = _grouped_pallas(x[src], tile_expert, tile_block, n_live,
-                         w_gate.astype(x.dtype), w_up.astype(x.dtype),
-                         w_down.astype(x.dtype), tile, name)
+                         None if w_gate is None else w_gate.astype(x.dtype),
+                         w_up.astype(x.dtype), w_down.astype(x.dtype), tile,
+                         name)
     # op for op the forward as it was before it had a backward: the
     # serving programs lower to what they were
     picked = ys[jnp.maximum(dest, 0)].astype(jnp.float32)   # [N, k, D]
@@ -375,11 +402,15 @@ def _grouped(name: str, tile: int, x, weights, w_gate, w_up, w_down, layout):
 
 
 def _grouped_fwd(name, tile, x, weights, w_gate, w_up, w_down, layout):
-    """The forward where a backward follows (training). The same values
+    """The forward where a backward follows (training; the gated form
+    alone has one). The same values
     as `_grouped`; the rows are picked in their own type and widened
     inside the sum, which at 16,384 tokens x 6 keeps an [N, k, D] float32
     array out of HBM (8 ms a step of `kanana-2-30b-a3b.pretrain-8k`:
     PERF.md, PR 38)."""
+    if w_gate is None:
+        raise NotImplementedError("the ungated relu^2 form of "
+                                  "experts_grouped has no backward pass")
     dest, src, tile_expert, tile_block, n_live, _ = layout
     ys = _grouped_pallas(x[src], tile_expert, tile_block, n_live,
                          w_gate.astype(x.dtype), w_up.astype(x.dtype),
@@ -416,15 +447,16 @@ _grouped.defvjp(_grouped_fwd, _grouped_bwd)
 def experts_grouped(x, chosen, weights, w_gate, w_up, w_down, *,
                     held_from: int, impl: str = "auto",
                     name: str = EXPERTS_GROUPED):
-    """The held experts' part of a routed layer's result; differentiable
-    in x, weights and the three matrices on either path.
+    """The held experts' part of a routed layer's result; the gated form
+    differentiable in x, weights and the three matrices on either path.
 
     x [N, D] normed activations; chosen [N, k] i32: each token's experts
     (ids over the router's whole width); weights [N, k] f32: their
     weights; w_gate, w_up, w_down [held, F, D]: experts `held_from` ..
-    `held_from + held - 1`; name: the forward kernel's. -> ([N, D] f32,
-    load [held] i32: the pairs each held expert got)."""
-    held = w_gate.shape[0]
+    `held_from + held - 1`, `w_gate` None for experts without a gate
+    matrix (relu^2 of the one projection); name: the forward kernel's.
+    -> ([N, D] f32, load [held] i32: the pairs each held expert got)."""
+    held = w_up.shape[0]
     if resolve_impl(impl) != "pallas":
         return reference_experts_grouped(
             x, chosen, weights, w_gate, w_up, w_down,

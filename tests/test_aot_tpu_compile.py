@@ -1036,3 +1036,201 @@ def test_window_family_programs_compile_at_the_cells_shapes(topo, program):
     assert mem.temp_size_in_bytes < 256 << 20
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
     print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# -- the state-space-and-latent-experts family at
+# nemotron-3-super.chat-closed96's shapes: 64 slots, five state layers of
+# 128 heads of 64 x 128 in 65 state blocks, one attention layer of 32
+# query heads over 2 key-value heads of 128 in 5,121 pages of 128, tables
+# of 1 + 80 columns, chunks of 512 and 128, 128 held experts of width
+# 2,688 in a 1,024-wide latent under a 512-wide router, 22 a token,
+# vocabulary 32,768
+
+def _nemotron():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import mamba_moe as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "nemotron-3-super.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+MB_, MH, MG, MP, MN, ML, MNS = 64, 128, 8, 64, 128, 5, 65
+MPAGES, MBS, MCOLS = 5121, 128, 80
+MSTATE = [((ML, MNS, MH // 2, MN, 2 * MP), jnp.float32)]
+
+
+def _mamba2_chunk_case(c):
+    from ray_tpu.ops import mamba2
+    return (lambda x, dt, a, b, cc, s, block, first, n: mamba2.mamba2_chunk(
+        x, dt, a, b, cc, s, 3, block, first, n, impl="pallas"),
+        [((c, MH, MP), BF16), ((c, MH), jnp.float32), ((MH,), jnp.float32)]
+        + [((c, MG, MN), BF16)] * 2 + MSTATE + [((), I32)] * 3)
+
+
+def _mamba2_step_case():
+    from ray_tpu.ops import mamba2
+    return (lambda x, dt, a, b, cc, s, blocks: mamba2.mamba2_step(
+        x, dt, a, b, cc, s, 3, blocks, impl="pallas"),
+        [((MB_, MH, MP), BF16), ((MB_, MH), jnp.float32),
+         ((MH,), jnp.float32)] + [((MB_, MG, MN), BF16)] * 2 + MSTATE
+        + [((MB_,), I32)])
+
+
+def _ungated_experts_case(n, name):
+    return (lambda x, c, w, u, d: grouped_experts.experts_grouped(
+        x, c, w, None, u, d, held_from=0, impl="pallas", name=name),
+        [((n, 1024), BF16), ((n, 22), I32), ((n, 22), jnp.float32)]
+        + [((128, 2688, 1024), BF16)] * 2)
+
+
+MAMBA_KERNELS = {
+    "mamba2_chunk_512": (_mamba2_chunk_case(512), "mamba2_chunk"),
+    "mamba2_chunk_128": (_mamba2_chunk_case(128), "mamba2_chunk"),
+    "mamba2_step": (_mamba2_step_case(), "mamba2_step"),
+    "experts_ungated_decode": (_ungated_experts_case(
+        64, "experts_grouped"), "experts_grouped"),
+    "experts_ungated_chunk": (_ungated_experts_case(
+        512, "experts_grouped_prefill"), "experts_grouped_prefill"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(MAMBA_KERNELS))
+def test_mamba_family_kernels_compile_under_their_names(topo, case):
+    """One kernel a call, under its own name; the state pool reaches the
+    recurrence's kernels as the program's parameter and is written in
+    place (nothing of a pool's size among the temporaries)."""
+    (fn, args), name = MAMBA_KERNELS[case]
+    compiled = compiled_for(topo, fn, *args)
+    assert kernel_names(compiled.as_text()) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_mamba_family_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/nemotron-3-super.json` as the engine jits them
+    (the pool donated): every kernel is there under its name, a call a
+    layer of its kind; states, tails and pages are updated in place (no
+    copy of a pool among the temporaries: under the state array's 1.36
+    GB); weights, pool and temporaries fit the chip."""
+    from ray_tpu.models import mamba_moe
+    config, cfg, ref = _nemotron()
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(lambda: mamba_moe.init_pool(
+        cfg, MPAGES, MBS, state_blocks=MNS)))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: mamba_moe.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((MB_,)), arg((MB_,)),
+            arg((MB_, 1 + MCOLS))).compile()
+        want = {"mamba2_step": ML, "gqa_full_decode": 1,
+                "experts_grouped": 5}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: mamba_moe.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((1 + MCOLS,)), arg(()),
+            arg(())).compile()
+        want = {"mamba2_chunk": ML, "gqa_full_chunk": 1,
+                "experts_grouped_prefill": 5}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 1e9                 # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# sha256 (first 16 hex digits) of the jaxpr of `experts_grouped` at the
+# four shapes the benchmark ran it at before it took a second form of an
+# expert (tokens, model width, expert width, held experts, experts a
+# token; the gated form, which is what those cells run), and of
+# `gqa_full_*` at `command-a-plus.mixed-closed24`'s: taken on PR 49's
+# tree, the commit before `ops/grouped_experts.py` was given the ungated
+# relu^2 form and `_width_slice` its lane rule. A PR that means to change
+# what those cells run replaces them and says so; under another JAX the
+# test skips (`OLMO_PAGED_JAX`'s rule)
+EXPERT_SHAPES = {
+    "glm-5.2.decode": (16, 6144, 2048, 16, 8, "experts_grouped"),
+    "glm-5.2.prefill": (512, 6144, 2048, 16, 8, "experts_grouped_prefill"),
+    "kanana-2-30b-a3b.train": (16384, 2048, 768, 16, 6,
+                               "experts_grouped_train"),
+    "ling-3.0-flash-vl.decode": (64, 2560, 768, 128, 8, "experts_grouped"),
+    "ling-3.0-flash-vl.prefill": (512, 2560, 768, 128, 8,
+                                  "experts_grouped_prefill"),
+    "command-a-plus.decode": (16, 4096, 4096, 16, 8, "experts_grouped"),
+    "command-a-plus.prefill": (512, 4096, 4096, 16, 8,
+                               "experts_grouped_prefill"),
+}
+UNCHANGED = {
+    "glm-5.2.decode": "dcb9f56380bb3e07",
+    "glm-5.2.prefill": "0c1fdf0b9654763e",
+    "kanana-2-30b-a3b.train": "e0c7a0c0d8754e1e",
+    "ling-3.0-flash-vl.decode": "e736e8f0bbc7710a",
+    "ling-3.0-flash-vl.prefill": "444375cd53e7d8d6",
+    "command-a-plus.decode": "dc89806515198a63",
+    "command-a-plus.prefill": "d4e148bdc7421574",
+    "gqa_full_decode": "aea805d4770d2386",
+    "gqa_full_chunk_512": "dd1d92bec09f2ab6",
+    "gqa_full_chunk_128": "a50facbd2e63945d",
+}
+
+
+@pytest.mark.parametrize("case", sorted(UNCHANGED))
+def test_shared_kernels_trace_to_what_they_did(case):
+    """The gated form of `experts_grouped` at the four shapes the
+    benchmark's other cells run (forward, and for the training cell its
+    gradient) and `gqa_full_*` at `command-a-plus`'s trace, equation for
+    equation, to what they did before this family shared them."""
+    import hashlib
+    if jax.__version__ != OLMO_PAGED_JAX:
+        pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
+    f32 = jnp.float32
+    shape = jax.ShapeDtypeStruct
+
+    def fingerprint(fn, *args):
+        text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+        return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+    if case in EXPERT_SHAPES:
+        n, d, f, held, k, name = EXPERT_SHAPES[case]
+        args = (shape((n, d), BF16), shape((n, k), I32),
+                shape((n, k), f32)) + (shape((held, f, d), BF16),) * 3
+
+        def forward(x, c, w, g, u, dn):
+            return grouped_experts.experts_grouped(
+                x, c, w, g, u, dn, held_from=0, impl="pallas", name=name)
+
+        def fn(x, c, w, g, u, dn):
+            if not name.endswith("train"):
+                return forward(x, c, w, g, u, dn)
+            return jax.grad(
+                lambda x, w, g, u, dn: jnp.sum(forward(x, c, w, g, u, dn)[0]),
+                argnums=(0, 1, 2, 3, 4))(x, w, g, u, dn)
+    else:
+        pool = shape(*WFULL)
+        if case == "gqa_full_decode":
+            args = (shape((WB, WHQ, WD), BF16), pool, pool,
+                    shape((WB, WMB), I32), shape((WB,), I32))
+
+            def fn(q, k, v, t, p):
+                return da.gqa_decode_attention(q, k, v, t, p, layer=0,
+                                               window=None, impl="pallas")
+        else:
+            args = (shape((int(case.rsplit("_", 1)[1]), WHQ, WD), BF16),
+                    pool, pool, shape((WMB,), I32), shape((), I32))
+
+            def fn(q, k, v, t, p):
+                return da.gqa_chunk_attention(q, k, v, t, p, layer=0,
+                                              window=None, impl="pallas")
+    assert fingerprint(fn, *args) == UNCHANGED[case]
