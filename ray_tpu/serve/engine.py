@@ -544,6 +544,19 @@ class _ChunkInFlight:
     enqueued: float = 0.0         # perf_counter at the span's end
 
 
+@dataclass
+class _StepInFlight:
+    """A decode step between its enqueue and the read of its tokens."""
+    nxt: object                   # device int32[slots]: the next
+    # step's operand where it is chained behind this one
+    lps: object
+    counts: object
+    rows: dict                    # slot -> rid, the rows it decodes
+    chained: int                  # of them, marked FROM_STEP / FROM_CHUNK
+    version: int                  # params_version it was computed under
+    dispatch_s: float             # its `engine/decode_dispatch`
+
+
 # --- a program's host-built input: one int32 array, one transfer --------
 #
 # What the host builds for a device program of the tick crosses to the
@@ -554,11 +567,19 @@ class _ChunkInFlight:
 # float32 to the bit. The layouts follow from shapes the engine fixes at
 # construction (slots, max_blocks, the verify window, the chunk bucket).
 
+# A decode row's token field says where the row's token comes from: the
+# value itself (a row that joins from the host), or one of two marks for
+# a token that has not left the device: the row of the step before, or
+# the token of the prompt chunk enqueued before the step.
+FROM_STEP, FROM_CHUNK = -1, -2
+
+
 def pack_rows(tokens, pos, temps, tables, step) -> np.ndarray:
     """A decode, verify or propose step's input: a row a slot of
     ``[tokens (1 or W) | pos | temperature bits | block table]``, the
     rows flattened, then the step counter `_sample` folds into the key.
-    `tokens` is ``[S]`` (decode, propose) or ``[S, W]`` (verify)."""
+    `tokens` is ``[S]`` (decode, propose) or ``[S, W]`` (verify); a
+    decode row's may be `FROM_STEP` or `FROM_CHUNK`."""
     slots = len(pos)
     tokens = tokens.reshape(slots, -1)
     w = tokens.shape[1]
@@ -883,9 +904,16 @@ class InferenceEngine:
             tok, logp = _sample(logits, temp[None], key, step)
             return tok[0], logp[0], cache, counts
 
-        def _decode(params, cache, inputs, key):
+        def _decode(params, cache, inputs, key, prev, chunk_tok):
+            """`prev` is the step before's `tok` and `chunk_tok` the
+            `tok` of the chunk enqueued before this step, both as they
+            lie on the device; a row marked `FROM_STEP` / `FROM_CHUNK`
+            takes its token there, any other the packed value."""
             self.decode_traces += 1
             tokens, pos, temps, tables, step = unpack_rows(inputs, slots)
+            tokens = jnp.where(
+                tokens == FROM_STEP, prev,
+                jnp.where(tokens == FROM_CHUNK, chunk_tok, tokens))
             logits, cache, counts = fam.decode(
                 params, tokens, cache, pos, tables, cfg, mesh)
             tok, logp = _sample(logits, temps, key, step)
@@ -952,8 +980,18 @@ class InferenceEngine:
         # Cache donation: the [L, n_blocks, bs, H, D] pool is by far the
         # engine's biggest array; donating it lets XLA alias input to
         # output so every step updates the pool in place in HBM.
-        self._prefill_fn = jax.jit(_prefill, donate_argnums=(2,))
-        self._decode_fn = jax.jit(_decode, donate_argnums=(1,))
+        # The two `tok` are laid out as the packed input is, so a step
+        # takes the one compile whether its `prev` / `chunk_tok` came
+        # from a program or are the placeholders below.
+        tok_first = (self._io_sh, None, None, None)
+        self._prefill_fn = jax.jit(_prefill, donate_argnums=(2,),
+                                   out_shardings=tok_first)
+        self._decode_fn = jax.jit(_decode, donate_argnums=(1,),
+                                  out_shardings=tok_first)
+        # what a step that chains behind nothing takes for them (unread)
+        self._no_prev = jax.device_put(np.zeros(slots, np.int32),
+                                       self._io_sh)
+        self._no_chunk_tok = jax.device_put(np.int32(0), self._io_sh)
         self._copy_fn = jax.jit(fam.copy_block, donate_argnums=(0,))
         self._verify_fn = (jax.jit(_verify, donate_argnums=(1,))
                            if spec is not None else None)
@@ -1034,7 +1072,14 @@ class InferenceEngine:
         # `_done`, `_errors`, `_handoffs`). Held for a few dict and
         # deque operations, never across device work; taken after
         # `_lock` where both are held, never before it.
-        self._delivery = threading.Condition()
+        self._delivery_lock = threading.RLock()
+        self._delivery = threading.Condition(self._delivery_lock)
+        # Consumers asleep in `_await`, by the rid they wait for, each
+        # on a condition of its own over the delivery lock: woken when
+        # something is handed to that rid (`_wake`) or to take the pump
+        # over (`_wake_one`), so a tick wakes the streams it served and
+        # not every stream that waits for a slot.
+        self._sleepers: dict[int, threading.Condition] = {}
         self._rid = 0
         # submitted, not yet seen by a tick: `_take_inbox` (under
         # `_lock`) moves it into `_pending`
@@ -1104,6 +1149,13 @@ class InferenceEngine:
         # host->device upload in _place_tree happens OUTSIDE _lock.
         self._swap_mutex = threading.Lock()
         self._decode_steps = 0
+        # The decode step whose tokens no one has read yet: the plain
+        # tick leaves its newest step here and the next tick enqueues a
+        # step behind it before reading it (`_chain_tick`); `_rest`
+        # reads it for whoever needs the engine at rest.
+        self._flight: _StepInFlight | None = None
+        self._steps_chained = 0
+        self._chain_drains = 0
         # host-to-device transfers made for the programs' inputs
         self._host_puts = 0
         # what the family's programs count (`ServingFamily.counts`),
@@ -1509,6 +1561,7 @@ class InferenceEngine:
             self._errors[victim.rid] = OverloadedError(
                 f"engine overloaded: request (class {victim.priority}) "
                 f"shed from the queue for a class-{priority} admission")
+            self._wake(victim.rid)
         self._sheds += 1
         self._class_counter(victim.priority)["sheds"] += 1
         self._recorder.on_finish(victim.rid, "shed")
@@ -1531,6 +1584,11 @@ class InferenceEngine:
         anything was released."""
         with self._before_pump(), self._lock:
             self._take_inbox()
+            if self._flight is not None and \
+                    rid in self._flight.rows.values():
+                # its token in flight is read (and dropped with its
+                # queue) before its blocks go
+                self._rest()
             hit = False
             for i, req in enumerate(self._pending):
                 if req.rid == rid:
@@ -1559,35 +1617,67 @@ class InferenceEngine:
                 hit |= self._out.pop(rid, None) is not None
                 hit |= self._errors.pop(rid, None) is not None
                 self._done.discard(rid)
+                self._wake(rid)
             if hit:
                 self._cancelled += 1
                 self._recorder.on_finish(rid, "cancelled")
             return hit
 
-    def _await(self, take):
-        """What `tokens_for` and `handoff_for` block in. `take()` runs
-        under the delivery lock and returns what its request has ready,
-        or None. With nothing ready the caller either becomes the pump
-        (it gets the pump mutex without waiting, and runs ONE `step()`,
-        which takes `_lock` itself) or sleeps on the condition until
-        the pump has ticked. Nobody queues to become the pump and
-        nobody asks for `_lock` in order to pop, so N consumers cost a
-        tick nothing; a lone consumer is always the pump, and sees the
-        ticks it would see calling `step()` itself. A tick that raises
-        does so in the consumer that ran it, and in no other.
+    def _wake(self, rid: int) -> None:
+        """Under the delivery lock: something was handed to `rid` (a
+        token, its end, an error, a hand-off, a cancel); its consumer,
+        if it sleeps in `_await`, wakes."""
+        cond = self._sleepers.get(rid)
+        if cond is not None:
+            cond.notify_all()
 
-        The mutex is given up under the delivery lock, and the
-        sleepers woken in the same hold: one that found the mutex taken
-        is already waiting by then, and cannot miss the call. Before a
-        tick the pump lets `_before_pump`'s threads have `_lock`."""
+    def _wake_one(self) -> None:
+        """Under the delivery lock: wake the consumer asleep longest, to
+        take the pump if it is free."""
+        cond = next(iter(self._sleepers.values()), None)
+        if cond is not None:
+            cond.notify_all()
+
+    def _await(self, take, rid: int):
+        """What `tokens_for` and `handoff_for` block in. `take()` runs
+        under the delivery lock and returns what request `rid` has
+        ready, or None. With nothing ready the caller either becomes the
+        pump (it gets the pump mutex without waiting, and runs ONE
+        `step()`, which takes `_lock` itself) or sleeps, on a condition
+        of its own, until something is handed to its request (`_wake`)
+        or the pump wants a taker (`_wake_one`). Nobody queues to become
+        the pump and nobody asks for `_lock` in order to pop, so N
+        consumers cost a tick nothing; a lone consumer is always the
+        pump, and sees the ticks it would see calling `step()` itself.
+        A tick that raises does so in the consumer that ran it, and in
+        no other.
+
+        A tick wakes the consumers it handed something to, when it hands
+        it over, and at its end one more: the pump's own thread pumps on
+        while its request has nothing ready, and when it leaves with
+        something, or with the tick's exception, the one it woke takes
+        the mutex; one that leaves with something while the mutex is
+        free wakes the next. The mutex is given up under the delivery
+        lock, and a consumer that found it taken is in `_sleepers`
+        before it lets go of that lock, so it cannot miss the call.
+        Before a tick the pump lets `_before_pump`'s threads have
+        `_lock`."""
         while True:
             with self._delivery:
                 got = take()
                 if got is not None:
+                    if not self._pump.locked():
+                        self._wake_one()
                     return got
                 if not self._pump.acquire(blocking=False):
-                    with self._phases.phase("stream/wait"):
-                        self._delivery.wait()
+                    cond = self._sleepers.setdefault(
+                        rid, threading.Condition(self._delivery_lock))
+                    try:
+                        with self._phases.phase("stream/wait"):
+                            cond.wait()
+                    finally:
+                        if self._sleepers.get(rid) is cond:
+                            del self._sleepers[rid]
                     continue
                 while self._lock_waiters:
                     self._delivery.wait()
@@ -1596,7 +1686,7 @@ class InferenceEngine:
             finally:
                 with self._delivery:
                     self._pump.release()
-                    self._delivery.notify_all()
+                    self._wake_one()
 
     def tokens_for(self, rid: int):
         """Generator of generated tokens for one request — each yielded
@@ -1641,7 +1731,7 @@ class InferenceEngine:
 
         try:
             # yield OUTSIDE every lock: a generator suspends at yield
-            while (tok := self._await(take)) is not _END:
+            while (tok := self._await(take, rid)) is not _END:
                 yield tok
         finally:
             self.cancel(rid)
@@ -1665,6 +1755,7 @@ class InferenceEngine:
         sampled from the final prefill chunk HERE so the decode engine
         never re-runs prefill), the sampling state, and the weight
         version the KV was computed under."""
+        self._rest()
         s = self._slots[slot_idx]
         p = s.prompt.size
         n_written = self._written_blocks(p)
@@ -1723,6 +1814,7 @@ class InferenceEngine:
             self._handoffs[s.rid] = blob
             self._out.pop(s.rid, None)
             self._done.discard(s.rid)
+            self._wake(s.rid)
         self._release(slot_idx)
 
     def handoff_for(self, rid: int) -> dict:
@@ -1750,7 +1842,7 @@ class InferenceEngine:
                     f"unknown or cancelled handoff rid {rid}")
             return None
 
-        return self._await(take)
+        return self._await(take, rid)
 
     def take_handoff(self, rid: int) -> dict | None:
         """Non-blocking collect: pop `rid`'s parked blob if its prefill
@@ -2052,6 +2144,9 @@ class InferenceEngine:
                 self._place_tree(old_draft, draft_params, "draft_params")
                 if draft_params is not None else None)
             with self._before_pump(), self._lock:
+                # a step in flight ran under the old weights: its tokens
+                # are emitted with that version before the swap
+                self._rest()
                 self.params = self._swap_fn(old, placed)
                 if placed_draft is not None:
                     self.draft_params = self._swap_fn(
@@ -2244,8 +2339,14 @@ class InferenceEngine:
         share the paged attention math). With no tree (a family of
         state blocks keeps none: its block is rewritten by every token)
         nothing is published and the resume re-prefills from its first
-        token; the first chunk resets the block it is given."""
+        token; the first chunk resets the block it is given. A decode
+        step in flight is read first, so the resume holds every token
+        computed; a victim that ended with that token needs no more."""
+        rid = self._slots[slot_idx].rid
+        self._rest()
         s = self._slots[slot_idx]
+        if s.rid != rid:
+            return
         seq = [int(t) for t in s.prompt.tolist()] \
             + [int(t) for t in s.emitted]
         # KV written so far covers seq[:pos] in decode (the parked
@@ -2276,6 +2377,7 @@ class InferenceEngine:
     def _force_preempt(self) -> bool:
         """Fault-injected preemption (site ``engine.preempt``): evict
         the lowest-class active stream regardless of pressure."""
+        self._rest()
         victim = self._pick_victim(self.priority_classes)
         if victim is None:
             return False
@@ -2377,11 +2479,16 @@ class InferenceEngine:
             with phase("engine/prefill_chunk", tokens=clen, bucket=cap,
                        overlapped=int(overlapped), start=s.filled) as chunk:
                 with phase("engine/prefill_build", puts=1):
-                    # keyed by the count before this tick's decode step,
-                    # wherever in the tick the chunk is enqueued
+                    # keyed by the count before the tick's decode step:
+                    # in the speculative tick this chunk follows that
+                    # step and in the plain tick it goes before it, so
+                    # there by the step before it on the device
+                    step = self._decode_steps
+                    if overlapped and self.spec is None:
+                        step += (self._flight is not None) - 1
                     inputs = self._dev(pack_chunk(
                         s.prompt[s.filled:s.filled + clen], cap, s.table,
-                        s.filled, s.temperature, self._decode_steps))
+                        s.filled, s.temperature, step))
                 with phase("engine/prefill_dispatch"):
                     tok, lp, self.cache, counts = self._prefill_fn(
                         self.params, inputs, self.cache, self._base_key)
@@ -2412,7 +2519,7 @@ class InferenceEngine:
 
     def _read_chunk_token(self, flight: "_ChunkInFlight") -> None:
         with self._phases.phase("engine/prefill_sync"):
-            # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: its token must reach the host to park on the slot, and the tick ends with no result unread. A chunk alone in its tick waits at once, inside engine/prefill_chunk, which keeps the prefill timing honest; a chunk behind a decode step waits after engine/emit, so its build and the emit lie under device time and the tick's two waits never overlap
+            # graftlint: disable-next-line=R001,R004 the chunk's one deliberate sync: its token must reach the host to park on the slot, and the tick ends with nothing unread but its newest decode step. A chunk alone in its tick waits at once, inside engine/prefill_chunk, which keeps the prefill timing honest; a chunk among the decode steps waits after engine/emit, so its build and the emit lie under device time, the tick's two waits never overlap, and the step enqueued behind the chunk runs while the host ends the tick
             flight.tok = int(np.asarray(flight.tok))
 
     def _finish_chunk(self, slot_idx: int,
@@ -2512,25 +2619,31 @@ class InferenceEngine:
         return did
 
     def _decode_with_chunk(self, decoding: list, slot_idx: int) -> float:
-        """The tick that holds decoders and a prefilling slot: the decode
-        step (or the speculative tick's programs) is enqueued first, the
-        slot's chunk is built and enqueued while the device runs it, and
-        only then is anything read: the step's tokens, which are emitted
-        while the device runs the chunk, then the chunk's token. Both
-        programs donate and return `self.cache`, so the device runs them
-        in that order with no gap; nothing of the step reads what the
-        chunk writes, so the order changes no stream's tokens. A slot
-        whose prompt ends here emits its first token in this tick and
-        joins the decode batch of the next. Returns the seconds the
-        chunk kept the tick open past the step's emit."""
+        """The tick that holds decoders and a prefilling slot: the
+        slot's chunk is built and enqueued among the tick's decode
+        programs (`_chain_tick`; behind the speculative tick's, which
+        reads its own at once) and its token is read last, after the
+        tokens the tick reads are emitted. Every program donates and
+        returns `self.cache`, so the device runs them in the order of
+        their enqueue with no gap; no step reads what a chunk of another
+        slot writes, so the order changes no stream's tokens. A slot
+        whose prompt ends here emits its first token in this tick.
+        Returns the seconds the chunk kept the tick open past the
+        emit."""
         flights = []
-        tick = self._decode_tick if self.spec is None else self._spec_tick
+
+        def start_chunk():
+            flights.append(self._start_chunk(slot_idx, overlapped=True))
+            return flights[0]
+
         try:
-            tick(decoding, enqueued=lambda: flights.append(
-                self._start_chunk(slot_idx, overlapped=True)))
+            if self.spec is None:
+                self._chain_tick(slot_idx, start_chunk)
+            else:
+                self._spec_tick(decoding, enqueued=start_chunk)
         finally:
-            # whatever the emit raised (fault site `engine.emit`), no
-            # program's result is left unread when the tick ends
+            # whatever the emit raised (fault site `engine.emit`), the
+            # chunk's result is not left unread when the tick ends
             t_emitted = time.perf_counter()
             if flights:
                 self._finish_chunk(slot_idx, flights[0])
@@ -2562,6 +2675,7 @@ class InferenceEngine:
             self._out[s.rid].append(ev)
             if finished:
                 self._done.add(s.rid)
+            self._wake(s.rid)
         s.emitted.append(int(tok))
         cc = self._class_counter(s.priority)
         cc["decode_tokens"] += 1
@@ -2576,17 +2690,22 @@ class InferenceEngine:
     def step(self) -> bool:
         """One scheduler tick: admit pending requests into free slots,
         then the tick's device work, in the order that what it holds
-        allows. With sequences decoding and a prompt to absorb, the
-        decode step is enqueued first, ONE prefill chunk is built and
-        enqueued while the device runs it, and only then are the step's
-        tokens waited for and emitted, and after them the chunk's
-        (`_decode_with_chunk`). With nothing decoding, every pending
-        chunk runs, each waited for, and the sequences that thereby
-        start decoding take one step in the same tick; with nothing to
-        prefill, the step alone. Whatever the order, no program's result
-        is unread when the tick ends: `update_params`, `cancel`,
-        preemption and the watchdog find the engine between ticks with
-        nothing in flight. Returns True if any device work happened."""
+        allows. The decode step is chained on the device
+        (`_chain_tick`): the last tick left step t enqueued and unread,
+        and this one enqueues ONE prefill chunk if a prompt waits, then
+        step t+1, whose continuing rows take their tokens from step t's
+        output where it lies, and only then waits for step t's tokens,
+        emits them, and waits for the chunk's; so the host's work of a
+        tick runs while the device has a step to run. With nothing
+        decoding, every pending chunk runs, each waited for, and the
+        sequences that thereby start decoding have their first step
+        enqueued in the same tick. `step()` returns with AT MOST ONE
+        DECODE STEP enqueued and unread, and no other result:
+        `update_params`, `cancel`, preemption, a hand-off's export,
+        `check_invariants` and `run_until_idle`'s end read it first
+        (`_rest`). The speculative tick proposes from the tokens on the
+        host, so it reads its programs in the tick that enqueues them
+        and leaves nothing. Returns True if any device work happened."""
         with self._lock:
             t_tick = time.perf_counter()
             # watchdog window: seq first, then start ts, cleared in the
@@ -2614,6 +2733,11 @@ class InferenceEngine:
                         self._force_preempt()
                     had_decoders = any(
                         s.phase == "decode" for s in self._slots)
+                    if not had_decoders and self._flight is not None:
+                        # every row of it has ended since (on eos_id):
+                        # nothing to chain behind it, its tokens dropped
+                        flight, self._flight = self._flight, None
+                        self._read_step(flight)
                     with phase("engine/admit") as admit:
                         self._take_inbox()
                         seq = self._admit_seq
@@ -2624,9 +2748,9 @@ class InferenceEngine:
                     decoding = self._decoding()
                     slot_idx = self._next_prefilling()
                     # the order comes from what the tick holds: with
-                    # decoders and a prompt to absorb, the step goes
-                    # first and the chunk behind it; else as ever,
-                    # chunks then the step
+                    # decoders and a prompt to absorb, ONE chunk among
+                    # the decode programs; else as ever, chunks then
+                    # the step
                     overlap = bool(had_decoders and decoding
                                    and slot_idx is not None)
                     chunked = overlap or self._prefill_tick(had_decoders)
@@ -2657,7 +2781,7 @@ class InferenceEngine:
                     elif self.spec is not None:
                         self._spec_tick(decoding)
                     else:
-                        self._decode_tick(decoding)
+                        self._chain_tick()
                     self._sentinel.check()
                     return True
             finally:
@@ -2694,80 +2818,199 @@ class InferenceEngine:
         # graftlint: disable-next-line=R004 µs-scale host->device placement of one tiny per-program input; placing outside the lock would race slot state, and the transfer is async (no sync back)
         return self._jax.device_put(packed, self._io_sh)
 
-    def _batch_arrays(self):
-        """Per-slot decode inputs. Rows not decoding (idle or
+    def _goes_on(self, s: _Slot) -> bool:
+        """Whether a decoding slot whose next token is in flight decodes
+        past it: `_emit`'s verdict less `eos_id`, from what the host
+        knows before the token."""
+        return s.remaining > 1 and s.pos + 2 < self.max_len
+
+    def _batch_arrays(self, flight=None, joining=None):
+        """Per-slot decode inputs ``(tokens, pos, tables, temps)`` and
+        the step's rows (slot -> rid). Rows not decoding (idle or
         mid-prefill) point at the trash block with pos 0: their garbage
         write collides harmlessly there and their sampled token is
-        never read."""
-        zeros = np.zeros((self.max_blocks,), np.int32)
-        tokens = np.array(
-            [s.token if s.phase == "decode" else 0
-             for s in self._slots], np.int32)
-        pos = np.array(
-            [s.pos if s.phase == "decode" else 0
-             for s in self._slots], np.int32)
-        tables = np.stack(
-            [s.table if s.phase == "decode" else zeros
-             for s in self._slots])
+        never read.
+
+        With `flight`, the step enqueued and unread, this is the step
+        behind it, built from what the host knows without its values: a
+        row of `flight` that goes on is marked `FROM_STEP` at `pos + 1`,
+        one that ends with the token in flight by `remaining` or
+        `max_len` is left out (one that ends on `eos_id` is known a
+        step late: `_read_step`), and any other decoding slot joins from
+        the host as ever. `joining` is the slot whose prompt's last
+        chunk is enqueued and unread: its row is marked `FROM_CHUNK`."""
+        slots = self.num_slots
+        tokens = np.zeros((slots,), np.int32)
+        pos = np.zeros((slots,), np.int32)
+        tables = np.zeros((slots, self.max_blocks), np.int32)
+        rows = {}
+        for i, s in enumerate(self._slots):
+            if s.phase == "decode":
+                if flight is None or flight.rows.get(i) != s.rid:
+                    tokens[i], pos[i] = s.token, s.pos
+                elif self._goes_on(s):
+                    tokens[i], pos[i] = FROM_STEP, s.pos + 1
+                else:
+                    continue
+            elif i == joining:
+                tokens[i], pos[i] = FROM_CHUNK, s.prompt.size
+            else:
+                continue
+            tables[i] = s.table
+            rows[i] = s.rid
         temps = np.array([s.temperature for s in self._slots],
                          np.float32)
-        return tokens, pos, tables, temps
+        return (tokens, pos, tables, temps), rows
 
-    def _decode_inputs(self, host=None, *, window=None, tables=None):
-        """A step's packed input on the device (`pack_rows`), as one
-        span: the host's part of a step before its dispatch, its one
-        put a child span of its own. `host` is what `_batch_arrays`
-        returned where the caller built it already (the speculative
-        tick); `window` takes the tokens' place for verify, `tables` the
-        target pool's for the draft pool's propose."""
+    def _put_rows(self, tokens, pos, tables, temps, step: int):
+        """A step's packed input (`pack_rows`), put in a span of its
+        own inside the caller's `engine/decode_build`."""
+        packed = pack_rows(tokens, pos, temps, tables, step)
+        with self._phases.phase("engine/decode_put", puts=1):
+            return self._dev(packed)
+
+    def _decode_inputs(self, host, *, window=None, tables=None):
+        """A verify or propose step's packed input on the device, as
+        one span. `host` is `_batch_arrays`' four arrays; `window`
+        takes the tokens' place for verify, `tables` the target pool's
+        for the draft pool's propose."""
+        with self._phases.phase("engine/decode_build"):
+            tokens, pos, own_tables, temps = host
+            return self._put_rows(
+                tokens if window is None else window, pos,
+                own_tables if tables is None else tables, temps,
+                self._decode_steps)
+
+    def _enqueue_step(self, behind=None, joining=None, chunk=None,
+                      built=None) -> _StepInFlight:
+        """Build (`_batch_arrays`, or `built`: its result), put and
+        dispatch one decode step; nothing is waited for. `behind` is
+        the step enqueued and unread whose output the `FROM_STEP` rows
+        read (this step's key takes the counter after that step's) and
+        `chunk` the chunk in flight whose token the `joining` slot's
+        row reads."""
         phase = self._phases.phase
         with phase("engine/decode_build"):
-            tokens, pos, own_tables, temps = host or self._batch_arrays()
-            packed = pack_rows(
-                tokens if window is None else window, pos, temps,
-                own_tables if tables is None else tables,
-                self._decode_steps)
-            with phase("engine/decode_put", puts=1):
-                return self._dev(packed)
-
-    def _decode_tick(self, decoding: list, host=None, enqueued=None):
-        """One decode step for every slot. `host` is `_batch_arrays`'
-        result where the speculative tick, falling back, built it
-        already. `enqueued`, where given, is called once the step is in
-        the device's queue and before its tokens are waited for
-        (`_decode_with_chunk`)."""
-        phase = self._phases.phase
-        inputs = self._decode_inputs(host)
+            (tokens, pos, tables, temps), rows = (
+                built or self._batch_arrays(behind, joining))
+            inputs = self._put_rows(
+                tokens, pos, tables, temps,
+                self._decode_steps + (behind is not None))
         with phase("engine/decode_dispatch") as dispatch:
             nxt, lps, self.cache, counts = self._decode_fn(
-                self.params, self.cache, inputs, self._base_key)
+                self.params, self.cache, inputs, self._base_key,
+                self._no_prev if behind is None else behind.nxt,
+                self._no_chunk_tok if joining is None else chunk.tok)
             bound = self._family.bounded_tokens
             if bound and dispatch.is_enabled():
                 # what the step's bounded pages hold of each decoding
                 # stream: min(context, bound), which no sum of contexts
                 # gives; summed only where a profiler session reads it
                 dispatch.set(bounded_rows=sum(
-                    min(self._slots[i].pos + 1, bound) for i in decoding))
-        if enqueued is not None:
-            enqueued()
+                    min(int(pos[i]) + 1, bound) for i in rows))
+        return _StepInFlight(nxt, lps, counts, rows,
+                             int(np.count_nonzero(tokens < 0)),
+                             self._params_version, dispatch.seconds)
+
+    def _read_step(self, flight: _StepInFlight) -> None:
+        """Wait for a step's tokens and emit them, each stamped with the
+        `params_version` the step ran under. A row whose slot has gone
+        to another request since the enqueue ended on `eos_id` in the
+        step before: its token is thrown away (its write went to the
+        stream's own next position, and device order is enqueue order,
+        so whoever took the blocks writes after it)."""
+        phase = self._phases.phase
         with phase("engine/token_sync") as sync:
-            # graftlint: disable-next-line=R001,R004 the decode tick IS the scheduler's unit of work: it must sync on the sampled tokens to route them, and the lock is held for exactly one tick by design
-            nxt = np.asarray(nxt)    # device sync
+            # graftlint: disable-next-line=R001,R004 the decode tick IS the scheduler's unit of work: it must sync on the sampled tokens to route them, and the lock is held for exactly one tick by design. The plain tick waits here for the step the tick before enqueued, after it has enqueued the next one behind it, so the device has a step to run while the host routes these
+            nxt = np.asarray(flight.nxt)    # device sync
             # graftlint: disable-next-line=R001,R004 same sync as nxt above — lps arrives in the same device batch, so this is a no-cost host view
-            lps = np.asarray(lps)
-            self._add_counts(counts)
-        dt = dispatch.seconds + sync.seconds
+            lps = np.asarray(flight.lps)
+            self._add_counts(flight.counts)
+        live = [i for i, rid in flight.rows.items()
+                if self._slots[i].rid == rid]
+        dt = flight.dispatch_s + sync.seconds
         self._step_times.append(dt)
         self._decode_steps += 1
-        self._decode_tokens += len(decoding)
-        self._decode_slot_steps += len(decoding)
-        self._tok_window.append((dt, len(decoding)))
-        with phase("engine/emit", tokens=len(decoding)):
-            for i in decoding:
-                s = self._slots[i]
-                s.token, s.pos = int(nxt[i]), s.pos + 1
-                s.remaining -= 1
-                self._emit(s, i, s.token, float(lps[i]))
+        self._decode_tokens += len(live)
+        self._decode_slot_steps += len(live)
+        self._tok_window.append((dt, len(live)))
+        with phase("engine/emit", tokens=len(live)):
+            try:
+                for i in live:
+                    s = self._slots[i]
+                    s.token, s.pos = int(nxt[i]), s.pos + 1
+                    s.remaining -= 1
+                    self._emit(s, i, s.token, float(lps[i]),
+                               flight.version)
+            except BaseException:
+                # the rows not reached keep the host's token and
+                # position; a step chained behind this one took them for
+                # advanced, so it is dropped and the next joins from the
+                # host, as after any failed emit
+                self._flight = None
+                raise
+
+    def _rest(self) -> None:
+        """Under `_lock`: read the decode step in flight, if there is
+        one, so that nothing is enqueued and unread. For whoever must
+        find the engine at rest between two ticks (`chain_drains`)."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._chain_drains += 1
+            self._read_step(flight)
+
+    def _chain_tick(self, chunk_slot: int | None = None,
+                    start_chunk=None) -> None:
+        """The plain tick's decode programs: this tick's chunk where it
+        has one (`start_chunk` enqueues it), then the next decode step,
+        and only then the wait for the tokens of the step the last tick
+        left unread, and their emit. That next step is chained behind
+        the unread one inside `engine/decode_chain` (`_batch_arrays`:
+        its rows are the rows it would have had with the other read
+        first, the chunk's slot among them if its prompt ends here);
+        with nothing unread (the first step, the first after `_rest`)
+        its rows join from the host and the pipeline is full again. The
+        device runs chunk, step, chunk, step either way. The step is
+        left unread; the chunk's token is the caller's to read
+        (`_decode_with_chunk`)."""
+        behind = self._flight
+        chunk = joining = None
+        if start_chunk is not None:
+            chunk = start_chunk()
+            s = self._slots[chunk_slot]
+            # `_finish_chunk`'s verdict, from what the host knows now
+            if chunk is not None \
+                    and s.filled + chunk.tokens >= s.prompt.size \
+                    and s.remaining > 1 \
+                    and s.prompt.size + 1 < self.max_len:
+                joining = chunk_slot
+        if behind is None:
+            self._flight = self._enqueue_step(None, joining, chunk)
+            return
+        if joining is not None or any(
+                s.phase == "decode" and (behind.rows.get(i) != s.rid
+                                         or self._goes_on(s))
+                for i, s in enumerate(self._slots)):
+            with self._phases.phase("engine/decode_chain") as chain:
+                self._flight = self._enqueue_step(behind, joining, chunk)
+                self._steps_chained += 1
+                chain.set(rows=self._flight.chained)
+        else:
+            # every stream ends with the token in flight
+            self._flight = None
+        self._read_step(behind)
+
+    def _decode_tick(self, host, enqueued=None):
+        """One decode step for every decoding slot, read in the tick
+        that enqueues it: the speculative tick's fallback where nothing
+        is worth speculating on. `host` is what `_batch_arrays` gave
+        it; `enqueued`, where given, is called once the step is in the
+        device's queue and before its tokens are waited for
+        (`_decode_with_chunk`)."""
+        flight = self._enqueue_step(built=host)
+        if enqueued is not None:
+            enqueued()
+        self._read_step(flight)
 
     def _add_counts(self, counts) -> None:
         """Sum what a prefill or decode program counted (after the
@@ -2810,7 +3053,8 @@ class InferenceEngine:
                  if self._slots[i].remaining >= 2]
         proposals: dict[int, list] = {}
         with phase("engine/decode_build"):
-            tokens, *_ = host = self._batch_arrays()
+            built = self._batch_arrays()
+            tokens, *_ = host = built[0]
         with phase("engine/propose") as propose:
             if self.spec == "ngram":
                 for i in worth:
@@ -2839,7 +3083,7 @@ class InferenceEngine:
                 for i in worth:
                     proposals[i] = drafts[i].tolist()
         if not proposals:
-            self._decode_tick(decoding, host, enqueued)
+            self._decode_tick(built, enqueued)
             return
         window = np.concatenate([tokens[:, None], drafts], axis=1)
         inputs = self._decode_inputs(host, window=window)
@@ -2884,6 +3128,8 @@ class InferenceEngine:
                 busy = self._inbox or self._pending or any(
                     s.active for s in self._slots)
                 if not busy:
+                    # a row that ended on eos_id left a step behind it
+                    self._rest()
                     return
                 self.step()
 
@@ -2897,7 +3143,12 @@ class InferenceEngine:
         pool's scale arrays must additionally track their payload's
         block geometry exactly (one f32 scale per (position, head) row —
         refcounts need no separate audit because scales share the
-        payload's block axis and ride the same copy/evict/free paths)."""
+        payload's block axis and ride the same copy/evict/free paths).
+        Reads a decode step in flight first: the checks are of an engine
+        at rest."""
+        with self._lock:
+            self._rest()
+
         def _audit_scales(pool, label):
             if pool is None or "k_scale" not in pool:
                 return
@@ -2995,7 +3246,10 @@ class InferenceEngine:
         `stats()["params_version"]` must not see it rewind. The windowed
         `swaps` counter and `weight_swap_ms` DO reset."""
         with self._before_pump(), self._lock:
+            # a step in flight belongs to the window that enqueued it
+            self._rest()
             self._decode_steps = self._host_puts = 0
+            self._steps_chained = self._chain_drains = 0
             self._model_counts = None
             self._prefill_tokens = self._decode_tokens = 0
             self._phases.clear()
@@ -3053,7 +3307,18 @@ class InferenceEngine:
           ``slots`` / ``active`` / ``pending`` — slot capacity, occupied
           slots, queued (unadmitted) requests, those no tick has seen
           yet included.
-          ``decode_steps`` — device decode/verify ticks since reset.
+          ``decode_steps`` — decode/verify steps read since reset (the
+          plain tick reads a step in the tick after the one that
+          enqueued it; one enqueued and unread is not counted yet).
+          ``steps_chained`` — of the plain tick's steps, those enqueued
+          behind a step whose tokens were still unread, their continuing
+          rows fed from that step's output on the device (each inside an
+          `engine/decode_chain` span): every step but the pipeline's
+          fills, the first step and the first after each drain.
+          ``chain_drains`` — reads of the step in flight forced by
+          whoever needed the engine at rest between two ticks
+          (`update_params`, a `cancel` or a preemption of a live stream,
+          `check_invariants`, `run_until_idle`'s end).
           ``host_puts`` — host-to-device transfers made for the
           programs' inputs since reset: one a decode, verify, propose or
           prefill program (`pack_rows`, `pack_chunk`), so
@@ -3070,12 +3335,15 @@ class InferenceEngine:
           (below).
           ``prefill_chunks`` — chunked-admission device calls;
           ``chunks_overlapped`` — those enqueued behind a decode step of
-          the same tick and built while the device ran it (their
+          the device's queue and built while the device ran it (their
           `engine/prefill_chunk` carries ``overlapped=1``): every chunk
           of a tick that held a decoder, none of a tick that held none.
           ``slot_occupancy`` — mean fraction of slots active per tick.
-          ``p50_token_latency_ms`` / ``p99_token_latency_ms`` — decode
-          step-time percentiles over a 512-tick window.
+          ``p50_token_latency_ms`` / ``p99_token_latency_ms`` —
+          percentiles over a 512-step window of a step's dispatch plus
+          the wait for its tokens, which for a chained step lie in two
+          ticks with the host's work between them: no longer a tick's
+          length.
 
         Compile-once accounting (NEVER reset — identity, not rate):
           ``prefill_traces`` / ``decode_traces`` / ``verify_traces`` /
@@ -3345,6 +3613,8 @@ class InferenceEngine:
                 "active": sum(s.active for s in self._slots),
                 "pending": len(self._pending),
                 "decode_steps": self._decode_steps,
+                "steps_chained": self._steps_chained,
+                "chain_drains": self._chain_drains,
                 "host_puts": self._host_puts,
                 "prefill_tokens": self._prefill_tokens,
                 "decode_tokens": self._decode_tokens,

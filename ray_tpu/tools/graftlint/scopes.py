@@ -51,6 +51,16 @@ HOT_SCOPES: dict[str, frozenset[str]] = {
         "pack_chunk",
         "InferenceEngine._dev",
         "InferenceEngine._decode_inputs",
+        "InferenceEngine._put_rows",
+        # the chained decode step: enqueued behind a step whose tokens
+        # are unread (`_chain_tick`, `_enqueue_step`), which `_read_step`
+        # waits for a tick later, or `_rest` for whoever needs the
+        # engine at rest
+        "InferenceEngine._goes_on",
+        "InferenceEngine._enqueue_step",
+        "InferenceEngine._chain_tick",
+        "InferenceEngine._read_step",
+        "InferenceEngine._rest",
         "InferenceEngine._start_chunk",
         "InferenceEngine._read_chunk_token",
         "InferenceEngine._finish_chunk",

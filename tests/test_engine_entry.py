@@ -160,6 +160,33 @@ def test_eight_streams_one_in_step_and_each_served_every_tick(slow_ticks):
     eng.check_invariants()
 
 
+def test_a_tick_wakes_the_consumers_it_served_and_one_more(slow_ticks):
+    """Two slots, eight consumers: the six whose requests wait for a
+    slot sleep through the ticks that hand them nothing, but for the one
+    a tick's end wakes to take the pump if it is free. Every stream
+    still ends, whoever's thread pumped."""
+    eng = tiny_engine(slots=2)
+    slow_ticks(eng)
+    n_new = 10
+    rids = [eng.submit(prompt(i), max_new_tokens=n_new) for i in range(8)]
+    got = {rid: 0 for rid in rids}
+
+    def consume(rid):
+        def run():
+            for _ in eng.tokens_for(rid):
+                got[rid] += 1
+        return run
+
+    run_threads([consume(rid) for rid in rids])
+    assert all(n == n_new for n in got.values())
+    st = eng.stats()
+    # a tick woke the two it served and one more, not all seven asleep
+    # (with every sleeper woken every tick: over four a tick)
+    assert 30 <= st["ticks"] and st["stream_waits"] <= 3 * st["ticks"]
+    assert not eng._sleepers
+    eng.check_invariants()
+
+
 def test_a_tick_run_by_another_thread_than_the_last_is_a_handoff():
     """`pump_handoffs`: the short stream's consumer runs every tick
     until its stream ends, with the long stream decoding beside it; the

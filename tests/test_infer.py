@@ -205,8 +205,9 @@ class TestInferenceEngine:
 
 
 # ---------------------------------------------------------------------------
-# the tick that holds decoders and a prompt: decode step first, the chunk
-# behind it
+# the tick that holds decoders and a prompt: the chunk among the decode
+# steps, which are chained on the device (step t+1 enqueued before step
+# t's tokens are read)
 # ---------------------------------------------------------------------------
 
 LONG = list(range(1, 30))       # four chunks of 8
@@ -305,12 +306,16 @@ def test_chunks_overlapped_counts_the_chunks_behind_a_decode_step(family):
     assert st["chunks_overlapped"] == 0 < st["prefill_chunks"]   # the ramp
     eng.reset_stats()
     eng.submit(LONG, max_new_tokens=2)
+
+    def enqueued():                     # read, or in flight
+        return eng.stats()["decode_steps"] + (eng._flight is not None)
+
     for _ in range(4):
-        before = eng.stats()
+        before, steps = eng.stats(), enqueued()
         eng.step()
         st = eng.stats()
         assert st["chunks_overlapped"] - before["chunks_overlapped"] == 1
-        assert st["decode_steps"] - before["decode_steps"] == 1
+        assert enqueued() - steps == 1
     assert st["chunks_overlapped"] == st["prefill_chunks"] == 4
     assert st["prefill_time_s"] > st["prefill_build_s"] \
         + st["prefill_dispatch_s"] + st["prefill_sync_s"] > 0
@@ -399,10 +404,11 @@ def _swap(eng, rids, weights):
                          ids=["cancel", "preempt", "hot-swap"])
 def test_between_two_overlapped_ticks_the_engine_is_at_rest(
         family, alone, between):
-    """`step()` returns with no result unread, so what lands between two
-    ticks that each ran a chunk behind a decode step finds the engine as
-    it always did: a cancel frees its stream, a forced preemption and a
-    hot swap leave every stream token-identical, the books balance."""
+    """`step()` returns with at most one decode step enqueued and unread
+    and no other result, and whatever lands between two ticks reads that
+    step first (`_rest`), so it finds the engine as it always did: a
+    cancel frees its stream, a forced preemption and a hot swap leave
+    every stream token-identical, the books balance."""
     from ray_tpu.util import faults
     cfg, params, family_kw = family
     # a swap donates the tree the engine was built on: it gets its own
@@ -430,6 +436,204 @@ def test_between_two_overlapped_ticks_the_engine_is_at_rest(
     assert st["active"] == st["pending"] == 0
     assert st["preemptions"] == (between is _preempt)
     assert st["chunks_overlapped"] <= st["prefill_chunks"]
+
+
+# ---------------------------------------------------------------------------
+# the decode step chained on the device
+# ---------------------------------------------------------------------------
+
+def drained(eng):
+    """`eng.step()` followed by the read of what it left in flight: the
+    order of the engine before the chain, a step read in its own tick."""
+    with eng._lock:
+        did = eng.step()
+        eng._rest()
+    return did
+
+
+def drive(eng, tick, requests, late=()):
+    """Submit `requests` (prompt, new tokens, keywords), tick twice,
+    submit `late`, tick to the end: every stream as (token, logprob)
+    pairs, and the ticks it took."""
+    rids = [eng.submit(p, max_new_tokens=n, **kw) for p, n, kw in requests]
+    tick(eng)
+    tick(eng)
+    rids += [eng.submit(p, max_new_tokens=n, **kw) for p, n, kw in late]
+    ticks = 2
+    while eng.stats()["active"] or eng.stats()["pending"]:
+        tick(eng)
+        ticks += 1
+    with eng._lock:
+        eng._rest()
+    return [events(eng._out[r]) for r in rids], ticks
+
+
+MIXED = ([([3 + i, 5, 7], 12 + 5 * i, {"temperature": 0.9 * (i % 2)})
+          for i in range(3)],
+         [(LONG, 10, {"temperature": 0.7}), (LONG[:11], 6, {})])
+
+
+def test_streams_are_the_same_chained_or_drained_every_tick(family):
+    """Greedy and sampled streams, to the token and the logprob: a
+    chained step has the rows and the key the step would have had with
+    its predecessor read first, and every chunk the key of the step
+    before it."""
+    chained, drained_eng = overlap_engine(family), overlap_engine(family)
+    got, _ = drive(chained, lambda e: e.step(), *MIXED)
+    want, _ = drive(drained_eng, drained, *MIXED)
+    assert got == want
+    assert [len(g) for g in got] == [12, 17, 22, 10, 6]
+    a, b = chained.stats(), drained_eng.stats()
+    assert a["decode_steps"] == b["decode_steps"]
+    assert a["steps_chained"] == a["decode_steps"] - 1
+    assert a["chain_drains"] == 0
+    assert b["steps_chained"] == 0 and \
+        b["chain_drains"] == b["decode_steps"]
+    # both rows' sources were used: the step's output and a chunk's token
+    assert a["chunks_overlapped"] == b["chunks_overlapped"] > 0
+    chained.check_invariants()
+
+
+def test_a_stream_that_ends_on_eos_runs_one_row_step_more(family, alone):
+    """The end is known when the token is read, a tick after the step
+    behind it was enqueued with the row still in it: nothing past the
+    eos is emitted, the slot is free one tick later than a drained
+    engine frees it, the row-step's token is thrown away, and the
+    request admitted into the freed blocks behind it is untouched."""
+    base = [t for t, _ in alone[0]]
+    eos = base[6]
+    upto = base.index(eos) + 1
+    ticks = {}
+    for name, tick in (("chained", lambda e: e.step()),
+                       ("drained", drained)):
+        eng = overlap_engine(family, slots=2)
+        rid = eng.submit([3, 5, 7], max_new_tokens=30, eos_id=eos)
+        other = eng.submit([4, 5, 7], max_new_tokens=30)
+        n, held = 0, set()
+        while rid not in eng._done:
+            held = {b for s in eng._slots if s.rid == rid for b in s.blocks}
+            tick(eng)
+            n += 1
+        ticks[name] = n
+        assert [int(t) for t in eng._out[rid]] == base[:upto]
+        idle = next(i for i, s in enumerate(eng._slots) if not s.active)
+        if name == "chained":
+            # the step behind the eos still holds the row
+            assert eng._flight.rows[idle] == rid
+        # a new request takes the slot and blocks right behind it
+        new = eng.submit([5, 5, 7], max_new_tokens=30)
+        tick(eng)
+        assert eng._slots[idle].rid == new
+        assert held & set(eng._slots[idle].blocks)
+        eng.check_invariants()
+        assert [int(t) for t in eng._out[rid]] == base[:upto]
+        assert_same_stream(events(eng.tokens_for(new)), alone[2])
+        assert_same_stream(events(eng.tokens_for(other)), alone[1])
+        eng.run_until_idle()
+        eng.check_invariants()
+        assert eng._flight is None
+        st = eng.stats()
+        assert st["active"] == st["pending"] == 0
+    assert ticks["chained"] == ticks["drained"] + 1
+
+
+def test_steps_chained_and_chain_drains_count_the_pipeline(family):
+    """Every step is chained but the fills, the first and the first
+    after each drain; a drain is a read forced by a cancel, a forced
+    preemption or a hot swap that met a step in flight, and none where
+    there was none."""
+    from ray_tpu.util import faults
+    cfg, params, family_kw = family
+    eng = overlap_engine((cfg, jax.tree.map(jnp.copy, params), family_kw))
+    faults.clear()
+    try:
+        rids = beside_decoders(eng, new_tokens=40)
+        eng.submit(LONG, max_new_tokens=8)
+        eng.step()
+        assert eng._flight is not None and eng.stats()["chain_drains"] == 0
+        assert eng.cancel(rids[1])
+        assert eng.stats()["chain_drains"] == 1 and eng._flight is None
+        assert not eng.cancel(rids[1])          # nothing live: no read
+        eng.step()
+        eng.step()
+        faults.install(faults.FaultPlan(seed=1).fail(
+            "engine.preempt", at=0, times=1))
+        eng.step()
+        assert eng.stats()["chain_drains"] == 2
+        assert eng.stats()["preemptions"] == 1
+        eng.step()
+        assert eng._flight is not None
+        assert eng.update_params(params) == 1
+        assert eng.stats()["chain_drains"] == 3 and eng._flight is None
+        assert eng.update_params(params) == 2   # at rest: no read
+        assert eng.stats()["chain_drains"] == 3
+    finally:
+        faults.clear()
+    eng.run_until_idle()
+    st = eng.stats()
+    assert st["chain_drains"] == 3
+    assert st["steps_chained"] == st["decode_steps"] - 1 - 3 > 0
+    assert st["host_puts"] == st["decode_steps"] + st["prefill_chunks"]
+    assert "``steps_chained``" in type(eng).stats.__doc__
+    eng.check_invariants()
+
+
+def test_an_emit_that_fails_drops_the_step_chained_behind_it():
+    """A fault at `engine.emit` leaves the rows after it with the host's
+    old token and position, as ever; the step already enqueued behind
+    took them for advanced, so it is dropped and they decode on from
+    the host, token-identical (the row it struck loses that token, as
+    it did before the chain)."""
+    from ray_tpu.util import faults
+    family = dense_family()
+    want = [events(overlap_engine(family).generate(
+        [3 + i, 5, 7], max_new_tokens=12)) for i in range(3)]
+    eng = overlap_engine(family)
+    faults.clear()
+    try:
+        rids = beside_decoders(eng, new_tokens=12)
+        eng.step()
+        # three first tokens and two steps' emits so far: the next
+        # emit is the first row's, with a step chained behind it
+        faults.install(faults.FaultPlan(seed=1).fail(
+            "engine.emit", at=0, times=1))
+        with pytest.raises(faults.FaultInjected):
+            eng.step()
+        assert eng._flight is None
+    finally:
+        faults.clear()
+    eng.run_until_idle()
+    got = [events(eng._out[r]) for r in rids]
+    assert len(got[0]) == len(want[0]) - 1
+    assert_same_stream(got[1], want[1])
+    assert_same_stream(got[2], want[2])
+    eng.check_invariants()
+
+
+def test_one_decode_program_whatever_feeds_a_row(family):
+    """Rows that join from the host, from the step before and from a
+    chunk's token run the one compiled step: the marks ride in the packed
+    input and the two device operands keep their layout."""
+    eng = overlap_engine(family)
+    eng.generate([3, 5, 7], max_new_tokens=4)   # from the host, then chained
+    eng.generate(LONG, max_new_tokens=3)        # every chunk bucket
+    eng.arm_retrace_sentinel()
+    marks = set()
+    enqueue = eng._enqueue_step
+
+    def spy(behind=None, joining=None, chunk=None, built=None):
+        flight = enqueue(behind, joining, chunk, built)
+        marks.add((behind is not None, joining is not None))
+        return flight
+
+    eng._enqueue_step = spy
+    got, _ = drive(eng, lambda e: e.step(), *MIXED)
+    assert marks == {(False, False), (True, False), (True, True)}
+    assert [len(g) for g in got] == [12, 17, 22, 10, 6]
+    st = eng.stats()
+    assert st["decode_traces"] == 1 and st["retraces_unexpected"] == 0
+    assert eng._decode_fn._cache_size() == 1
+    eng.check_invariants()
 
 
 # ---------------------------------------------------------------------------

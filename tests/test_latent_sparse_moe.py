@@ -18,8 +18,9 @@ from benchmarks.refs import latent_sparse_moe as ref
 from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
 from ray_tpu.ops import grouped_experts, sparse_latent
-from ray_tpu.serve.engine import (InferenceEngine, pack_chunk, pack_rows,
-                                  unpack_chunk, unpack_rows)
+from ray_tpu.serve.engine import (FROM_CHUNK, FROM_STEP, InferenceEngine,
+                                  pack_chunk, pack_rows, unpack_chunk,
+                                  unpack_rows)
 from ray_tpu.util import faults
 
 # the published keys at a tiny size: two layers own an indexer, the top-k
@@ -610,8 +611,11 @@ def test_gpt_lowers_to_the_programs_it_lowered_to_before():
         tok, logp = _sample(logits, temp[None], key, step)
         return tok[0], logp[0], cache
 
-    def _decode(params, cache, inputs, key):
+    def _decode(params, cache, inputs, key, prev, chunk_tok):
         tokens, pos, temps, tables, step = unpack_rows(inputs, 4)
+        tokens = jnp.where(
+            tokens == FROM_STEP, prev,
+            jnp.where(tokens == FROM_CHUNK, chunk_tok, tokens))
         logits, cache = gpt.decode_step_paged(
             params, tokens, cache, pos, tables, cfg, None)
         tok, logp = _sample(logits, temps, key, step)
@@ -623,7 +627,8 @@ def test_gpt_lowers_to_the_programs_it_lowered_to_before():
     # (tests/test_packed_inputs.py holds the layouts to the bit)
     decode_args = (p, eng.cache, pack_rows(
         np.zeros(4, i32), np.zeros(4, i32), np.zeros(4, f32),
-        np.zeros((4, eng.max_blocks), i32), 0), key)
+        np.zeros((4, eng.max_blocks), i32), 0), key, np.zeros(4, i32),
+        i32(0))
     assert _sha(eng._decode_fn, *decode_args) == _sha(
         jax.jit(_decode, donate_argnums=(1,)), *decode_args)
     prefill_args = (p, pack_chunk(

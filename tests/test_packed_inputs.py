@@ -89,7 +89,10 @@ class FourArrayEngine(InferenceEngine):
     program is the old jitted function behind a shim that takes the
     array apart with numpy, puts tokens (or the window), positions,
     tables and temperatures one by one and hands `jit` the rest as host
-    scalars. Nothing of `unpack_rows` / `unpack_chunk` runs here."""
+    scalars. Nothing of `unpack_rows` / `unpack_chunk` runs here, and
+    no token stays on the device: a row marked `FROM_STEP` or
+    `FROM_CHUNK` gets its value on the host, from the step or the chunk
+    read there and then."""
 
     def __init__(self, params, cfg, **kw):
         super().__init__(params, cfg, **kw)
@@ -172,7 +175,13 @@ class FourArrayEngine(InferenceEngine):
                     put(np.ascontiguousarray(r[:, w + 1]).view(np.float32)),
                     np.int32(packed[-1]))
 
-        def decode_fn(params, cache, packed, key):
+        def decode_fn(params, cache, packed, key, prev, chunk_tok):
+            packed = packed.copy()
+            tokens = packed[:-1].reshape(slots, 3 + blocks)[:, 0]
+            tokens[:] = np.where(
+                tokens == engine_mod.FROM_STEP, np.asarray(prev),
+                np.where(tokens == engine_mod.FROM_CHUNK,
+                         np.asarray(chunk_tok), tokens))
             *arrays, step = rows(packed, 1)
             return decode(params, cache, *arrays, key, step)
 

@@ -606,13 +606,14 @@ class TestChunkedPrefill:
             self, setup):
         """While a long prompt is being admitted, every scheduler tick
         still advances the in-flight stream by one token and runs at
-        most ONE prefill chunk."""
+        most ONE prefill chunk. (A tick reads the decode step the tick
+        before enqueued, so the first tick's is counted by the second.)"""
         cfg, params = setup
         eng = make_engine(cfg, params, prefill_chunk=8,
                           prefix_cache=False)
         eng.submit(list(range(1, 5)), max_new_tokens=24)
         eng.step()                      # admit + drain tiny prefill
-        assert eng.stats()["decode_steps"] == 1
+        assert eng.stats()["decode_steps"] == 0 and eng._flight is not None
         # now a 24-token prompt arrives: 3 chunks of 8
         eng.submit(list(range(40, 64)), max_new_tokens=2)
         for tick in range(1, 4):
@@ -621,6 +622,7 @@ class TestChunkedPrefill:
             s = eng.stats()
             assert s["prefill_chunks"] - before["prefill_chunks"] == 1
             assert s["decode_steps"] - before["decode_steps"] == 1
+            assert s["decode_tokens"] - before["decode_tokens"] == 1
         assert s["prefill_chunks"] == 4     # 1 warm + 3 chunked
         assert s["max_admission_stall_ms"] > 0.0
         eng.run_until_idle()

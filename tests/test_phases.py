@@ -278,17 +278,23 @@ def traced_beside_a_decoder(tmp_path_factory):
         tokens = list(eng.tokens_for(rid))
     finally:
         jax.profiler.stop_trace()
+    with eng._lock:
+        eng._rest()             # the resident's step in flight: counted
     stats = eng.stats()
     eng.cancel(resident)
     return {"events": program_spans(out)[1], "stats": stats,
             "tokens": tokens}
 
 
-def test_a_tick_with_a_decoder_enqueues_the_step_then_the_chunk_and_reads_after(
+def test_a_tick_with_a_decoder_enqueues_the_chunk_then_the_step_and_reads_after(
         traced_beside_a_decoder):
-    """decode (build, put, enqueue), chunk (build, enqueue), the step's
-    tokens, the emit, the chunk's token: in that order inside one
-    `engine/tick`, every span under its old name, the chunk marked."""
+    """chunk (build, enqueue), the next decode step chained behind the
+    unread one (build, put, enqueue inside `engine/decode_chain`), the
+    unread step's tokens, the emit, the chunk's token: in that order
+    inside one `engine/tick`, every span under its old name, the chunk
+    marked. The session's first tick found the engine at rest
+    (`reset_stats`): its step joins from the host, outside any
+    `engine/decode_chain`, and it reads the chunk's token alone."""
     ev, st = (traced_beside_a_decoder[k] for k in ("events", "stats"))
     chunks = sorted(ev["engine/prefill_chunk"], key=lambda c: c[1])
     assert [c[3]["tokens"] for c in chunks] == [8, 8, 4]
@@ -300,28 +306,42 @@ def test_a_tick_with_a_decoder_enqueues_the_step_then_the_chunk_and_reads_after(
         assert len(got) == 1, (name, len(got))
         return got[0]
 
-    ticks = [t for t in ev["engine/tick"] if any(inside(c, [t])
-                                                 for c in chunks)]
+    ticks = sorted((t for t in ev["engine/tick"]
+                    if any(inside(c, [t]) for c in chunks)),
+                   key=lambda t: t[1])
     assert len(ticks) == 3
-    for tick in ticks:
-        chunk, build, dispatch, sync, d_build, d_put, d_dispatch, \
-            token_sync, emit = (within(f"engine/{n}", tick) for n in (
+    for n, tick in enumerate(ticks):
+        chunk, build, dispatch, sync, d_build, d_put, d_dispatch = (
+            within(f"engine/{n}", tick) for n in (
                 "prefill_chunk", "prefill_build", "prefill_dispatch",
                 "prefill_sync", "decode_build", "decode_put",
-                "decode_dispatch", "token_sync", "emit"))
+                "decode_dispatch"))
         assert tick[3]["decoding"] == 1 and tick[3]["prefilling"] == 1
         assert inside(d_put, [d_build]) and d_build[2] <= d_dispatch[1]
-        assert d_dispatch[2] <= build[1]        # the step is enqueued first
+        assert chunk[2] <= d_build[1]           # the chunk is enqueued first
         # the span holds the build and the enqueue, and ends before a wait
         assert inside(build, [chunk]) and inside(dispatch, [chunk])
-        assert build[2] <= dispatch[1] and chunk[2] <= token_sync[1]
-        assert dispatch[2] <= token_sync[2]     # both in flight, then read
-        assert token_sync[2] <= emit[1] and emit[2] <= sync[1]
+        assert build[2] <= dispatch[1]
         assert not inside(sync, [chunk])
-    # the prompt was in none of the three ticks' decode batches: each
-    # emitted the resident's token alone
+        if n == 0:                              # the pipeline's fill
+            assert not any(inside(e, [tick]) for name in (
+                "decode_chain", "token_sync", "emit")
+                for e in ev[f"engine/{name}"])
+            assert d_dispatch[2] <= sync[1]
+            continue
+        chain, token_sync, emit = (within(f"engine/{n}", tick) for n in (
+            "decode_chain", "token_sync", "emit"))
+        assert inside(d_build, [chain]) and inside(d_dispatch, [chain])
+        # the last row of the prompt's last chunk rides the chain too
+        assert chain[3]["rows"] == 1 + (n == 2)
+        assert chain[2] <= token_sync[1]        # both in flight, then read
+        assert token_sync[2] <= emit[1] and emit[2] <= sync[1]
+    # the step a tick reads was enqueued before the prompt's last chunk:
+    # the two that these ticks read emitted the resident's token alone
     assert [e[3]["tokens"] for e in sorted(ev["engine/emit"],
-                                           key=lambda e: e[1])][:3] == [1] * 3
+                                           key=lambda e: e[1])][:2] == [1] * 2
+    assert st["steps_chained"] == len(ev["engine/decode_chain"]) == \
+        st["decode_steps"] - 1
     assert len(traced_beside_a_decoder["tokens"]) == 3
 
 
@@ -365,8 +385,9 @@ def test_the_put_spans_say_one_put_each(session, request):
     assert sum(e[3]["puts"] for e in spans) == st["host_puts"]
     for tick in ev["engine/tick"]:
         inside_tick = [e for e in spans if inside(e, [tick])]
-        assert len(inside_tick) == (tick[3]["decoding"] > 0) + sum(
-            inside(c, [tick]) for c in ev["engine/prefill_chunk"])
+        assert len(inside_tick) == sum(
+            inside(c, [tick]) for name in ("decode_dispatch", "prefill_chunk")
+            for c in ev[f"engine/{name}"])
 
 
 def test_every_tick_says_how_long_after_the_last_it_began(traced):
