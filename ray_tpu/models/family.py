@@ -7,6 +7,21 @@ from __future__ import annotations
 
 from typing import Callable, NamedTuple
 
+# The parts of a step, the one vocabulary every family and both trainers
+# open as `jax.named_scope` where the work is traced, so that an HLO
+# instruction's `op_name` says whose it is (PERF.md, section 3):
+#   embed      the token table's gather and what precedes the first layer
+#   mixer      a layer's token mixing whole: norm, projections, the
+#              attention / state kernel, the cache's write, the residual
+#   ffn        a layer's feed-forward whole: norm, dense or routed and
+#              shared experts, a latent's projections, the residual
+#   head       the final norm, the output matrix, sampling, the loss
+#   optimizer  training: the update and whatever else walks the gradients
+# A part is the outermost scope of a layer's half; what a family opens
+# below it keeps its own name.
+PARTS = ("embed", "mixer", "ffn", "head", "optimizer")
+EMBED, MIXER, FFN, HEAD, OPTIMIZER = PARTS
+
 
 class ServingFamily(NamedTuple):
     """What `serve/engine.py` asks of a model family, found as the

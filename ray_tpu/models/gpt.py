@@ -30,7 +30,7 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention
 
 
@@ -281,23 +281,25 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
     MLP unless given. Returns ``(x, attend's kept, ffn's kept)``."""
     adt = cfg.activation_dtype()
     lead = x.shape[:-1]
-    h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-    q, k, v = (jnp.einsum("...d,dh->...h", h, _w(lp, name, adt),
-                          preferred_element_type=pet).astype(adt)
-               for name in ("wq", "wk", "wv"))
-    q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
-               for a in (q, k, v))
-    att, attend_kept = attend(q, k, v)
-    att = jnp.einsum("...h,hd->...d",
-                     att.reshape(*lead, cfg.n_heads * cfg.head_dim),
-                     _w(lp, "wo", adt),
-                     preferred_element_type=pet).astype(adt)
-    x = x + att
-    h = _rms_norm(x, lp["ln2_scale"].astype(adt))
-    if ffn is None:
-        ffn = partial(_gated_mlp, adt=adt, pet=pet)
-    ff, ffn_kept = ffn(h, lp)
-    return x + ff, attend_kept, ffn_kept
+    with jax.named_scope(MIXER):
+        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
+        q, k, v = (jnp.einsum("...d,dh->...h", h, _w(lp, name, adt),
+                              preferred_element_type=pet).astype(adt)
+                   for name in ("wq", "wk", "wv"))
+        q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
+                   for a in (q, k, v))
+        att, attend_kept = attend(q, k, v)
+        att = jnp.einsum("...h,hd->...d",
+                         att.reshape(*lead, cfg.n_heads * cfg.head_dim),
+                         _w(lp, "wo", adt),
+                         preferred_element_type=pet).astype(adt)
+        x = x + att
+    with jax.named_scope(FFN):
+        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
+        if ffn is None:
+            ffn = partial(_gated_mlp, adt=adt, pet=pet)
+        ff, ffn_kept = ffn(h, lp)
+        return x + ff, attend_kept, ffn_kept
 
 
 def _block(x, lp, cfg: GPTConfig, mesh: Mesh | None):
@@ -316,8 +318,9 @@ def forward_features(params, tokens, cfg: GPTConfig,
     consumes these directly so [B, T, vocab] logits never exist."""
     adt = cfg.activation_dtype()
     t = tokens.shape[1]
-    x = params["embed"].astype(adt)[tokens]
-    x = x + params["pos_embed"].astype(adt)[:t][None]
+    with jax.named_scope(EMBED):
+        x = params["embed"].astype(adt)[tokens]
+        x = x + params["pos_embed"].astype(adt)[:t][None]
 
     block = partial(_block, cfg=cfg, mesh=mesh)
     if cfg.remat:
@@ -343,7 +346,8 @@ def forward_features(params, tokens, cfg: GPTConfig,
         return block(x, lp), None
 
     x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    return _rms_norm(x, params["final_ln_scale"].astype(adt))
+    with jax.named_scope(HEAD):
+        return _rms_norm(x, params["final_ln_scale"].astype(adt))
 
 
 def forward(params, tokens, cfg: GPTConfig, mesh: Mesh | None = None):
@@ -351,9 +355,10 @@ def forward(params, tokens, cfg: GPTConfig, mesh: Mesh | None = None):
     (float32 by default)."""
     adt = cfg.activation_dtype()
     x = forward_features(params, tokens, cfg, mesh)
-    logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(adt),
-                        preferred_element_type=jnp.dtype(cfg.logits_dtype))
-    return logits
+    with jax.named_scope(HEAD):
+        return jnp.einsum(
+            "btd,vd->btv", x, params["embed"].astype(adt),
+            preferred_element_type=jnp.dtype(cfg.logits_dtype))
 
 
 def check_loss_impl(cfg: GPTConfig) -> str:
@@ -374,16 +379,18 @@ def loss_fn(params, batch, cfg: GPTConfig, mesh: Mesh | None = None):
     if check_loss_impl(cfg) == "fused":
         from ray_tpu.ops.fused_xent import fused_softmax_xent
         x = forward_features(params, tokens[:, :-1], cfg, mesh)
-        nll = fused_softmax_xent(
-            x, params["embed"].astype(cfg.activation_dtype()), targets,
-            vocab_chunk=cfg.loss_chunk, mesh=mesh)
-        return jnp.mean(nll)
+        with jax.named_scope(HEAD):
+            nll = fused_softmax_xent(
+                x, params["embed"].astype(cfg.activation_dtype()), targets,
+                vocab_chunk=cfg.loss_chunk, mesh=mesh)
+            return jnp.mean(nll)
     logits = forward(params, tokens[:, :-1], cfg, mesh)
-    # upcast before the softmax so logits_dtype="bfloat16" configs keep
-    # an f32 logsumexp (same guard as spmd.softmax_xent)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    return -jnp.mean(ll)
+    with jax.named_scope(HEAD):
+        # upcast before the softmax so logits_dtype="bfloat16" configs
+        # keep an f32 logsumexp (same guard as spmd.softmax_xent)
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        return -jnp.mean(ll)
 
 
 def completion_logprobs(params, tokens, start, width, cfg: GPTConfig,
@@ -403,19 +410,20 @@ def completion_logprobs(params, tokens, start, width, cfg: GPTConfig,
     top of this.
     """
     logits = forward(params, tokens, cfg, mesh)
-    logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-    t = tokens.shape[1]
-    start = jnp.asarray(start, jnp.int32)
-    # Absolute position of completion token j, clipped into range so
-    # padded tails index safely (caller masks them out).
-    idx = jnp.clip(start[:, None]
-                   + jnp.arange(width, dtype=jnp.int32)[None, :],
-                   1, t - 1)                                  # [B, W]
-    rows = jnp.take_along_axis(
-        logp, (idx - 1)[..., None], axis=1)                   # [B, W, V]
-    toks = jnp.take_along_axis(tokens, idx, axis=1)           # [B, W]
-    return jnp.take_along_axis(rows, toks[..., None],
-                               axis=-1)[..., 0]
+    with jax.named_scope(HEAD):
+        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+        t = tokens.shape[1]
+        start = jnp.asarray(start, jnp.int32)
+        # Absolute position of completion token j, clipped into range so
+        # padded tails index safely (caller masks them out).
+        idx = jnp.clip(start[:, None]
+                       + jnp.arange(width, dtype=jnp.int32)[None, :],
+                       1, t - 1)                              # [B, W]
+        rows = jnp.take_along_axis(
+            logp, (idx - 1)[..., None], axis=1)               # [B, W, V]
+        toks = jnp.take_along_axis(tokens, idx, axis=1)       # [B, W]
+        return jnp.take_along_axis(rows, toks[..., None],
+                                   axis=-1)[..., 0]
 
 
 # ---------------------------------------------------------------------------
@@ -678,7 +686,8 @@ def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
     (x, cache), _ = jax.lax.scan(
         body, (x, cache),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
-    return _rms_norm(x, params["final_ln_scale"].astype(adt)), cache
+    with jax.named_scope(HEAD):
+        return _rms_norm(x, params["final_ln_scale"].astype(adt)), cache
 
 
 def prefill_paged(params, tokens, cache, cfg: GPTConfig,
@@ -723,16 +732,17 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
     length = jnp.asarray(c if length is None else length, jnp.int32)
     table = jnp.asarray(block_table, jnp.int32)
 
-    offs = jnp.arange(c, dtype=jnp.int32)
-    positions = start + offs
-    valid = offs < length
-    # Physical flat write indices; padded tail rows scatter out of
-    # bounds and are dropped, so chunk garbage never lands in a block.
-    widx = jnp.where(valid, table[positions // bs] * bs + positions % bs,
-                     nb * bs)
-
-    x = params["embed"].astype(adt)[tokens[0]]
-    x = x + params["pos_embed"].astype(adt)[positions]      # [C, D]
+    with jax.named_scope(EMBED):
+        offs = jnp.arange(c, dtype=jnp.int32)
+        positions = start + offs
+        valid = offs < length
+        # Physical flat write indices; padded tail rows scatter out of
+        # bounds and are dropped, so chunk garbage never lands in a block.
+        widx = jnp.where(valid,
+                         table[positions // bs] * bs + positions % bs,
+                         nb * bs)
+        x = params["embed"].astype(adt)[tokens[0]]
+        x = x + params["pos_embed"].astype(adt)[positions]      # [C, D]
 
     def attend(q, cache, layer):        # q: [C, H, Dh]
         return paged_prefill_attention(
@@ -741,9 +751,10 @@ def prefill_paged(params, tokens, cache, cfg: GPTConfig,
             layer=layer, impl=cfg.prefill_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    logits = jnp.einsum("td,vd->tv", last, params["embed"].astype(adt),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(HEAD):
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        logits = jnp.einsum("td,vd->tv", last, params["embed"].astype(adt),
+                            preferred_element_type=jnp.float32)
     return logits, cache
 
 
@@ -771,16 +782,18 @@ def decode_step_paged(params, tokens, cache, pos, tables,
     mb = tables.shape[1]
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
-    # Positions past the table's reach (speculative draft steps can run a
-    # few past max_len) must DROP, not clamp — a clamped index would land
-    # the write inside the slot's own last block and corrupt real data.
-    blk = jnp.take_along_axis(
-        tables, jnp.minimum(pos // bs, mb - 1)[:, None], axis=1)[:, 0]
-    widx = jnp.where(pos < mb * bs, blk * bs + pos % bs,
-                     nb * bs)                    # [B] flat write index
-    x = params["embed"].astype(adt)[tokens]
-    x = x + params["pos_embed"].astype(adt)[
-        jnp.minimum(pos, cfg.max_seq_len - 1)]
+    with jax.named_scope(EMBED):
+        # Positions past the table's reach (speculative draft steps can
+        # run a few past max_len) must DROP, not clamp — a clamped index
+        # would land the write inside the slot's own last block and
+        # corrupt real data.
+        blk = jnp.take_along_axis(
+            tables, jnp.minimum(pos // bs, mb - 1)[:, None], axis=1)[:, 0]
+        widx = jnp.where(pos < mb * bs, blk * bs + pos % bs,
+                         nb * bs)                # [B] flat write index
+        x = params["embed"].astype(adt)[tokens]
+        x = x + params["pos_embed"].astype(adt)[
+            jnp.minimum(pos, cfg.max_seq_len - 1)]
 
     def attend(q, cache, layer):        # q: [B, H, Dh]
         return paged_decode_attention(q, cache["k"], cache["v"], tables,
@@ -790,8 +803,9 @@ def decode_step_paged(params, tokens, cache, pos, tables,
                                       impl=cfg.decode_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
-    logits = jnp.einsum("bd,vd->bv", x, params["embed"].astype(adt),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(HEAD):
+        logits = jnp.einsum("bd,vd->bv", x, params["embed"].astype(adt),
+                            preferred_element_type=jnp.float32)
     return logits, cache
 
 
@@ -831,15 +845,16 @@ def verify_step_paged(params, tokens, cache, pos, tables,
     mb = tables.shape[1]
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
-    positions = pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
-    blk = jnp.take_along_axis(tables, jnp.minimum(positions // bs,
-                                                  mb - 1), axis=1)
-    widx = jnp.where(positions < mb * bs,
-                     blk * bs + positions % bs,
-                     nb * bs).reshape(-1)         # [B*W] flat, drop OOB
-    x = params["embed"].astype(adt)[tokens]
-    x = x + params["pos_embed"].astype(adt)[
-        jnp.minimum(positions, cfg.max_seq_len - 1)]
+    with jax.named_scope(EMBED):
+        positions = pos[:, None] + jnp.arange(w, dtype=jnp.int32)[None, :]
+        blk = jnp.take_along_axis(tables, jnp.minimum(positions // bs,
+                                                      mb - 1), axis=1)
+        widx = jnp.where(positions < mb * bs,
+                         blk * bs + positions % bs,
+                         nb * bs).reshape(-1)     # [B*W] flat, drop OOB
+        x = params["embed"].astype(adt)[tokens]
+        x = x + params["pos_embed"].astype(adt)[
+            jnp.minimum(positions, cfg.max_seq_len - 1)]
 
     def attend(q, cache, layer):        # q: [B, W, H, Dh]
         return paged_verify_attention(q, cache["k"], cache["v"], tables,
@@ -849,8 +864,9 @@ def verify_step_paged(params, tokens, cache, pos, tables,
                                       impl=cfg.decode_attn_impl)
 
     x, cache = _paged_layers(params, x, cache, cfg, widx, attend)
-    logits = jnp.einsum("bwd,vd->bwv", x, params["embed"].astype(adt),
-                        preferred_element_type=jnp.float32)
+    with jax.named_scope(HEAD):
+        logits = jnp.einsum("bwd,vd->bwv", x, params["embed"].astype(adt),
+                            preferred_element_type=jnp.float32)
     return logits, cache
 
 

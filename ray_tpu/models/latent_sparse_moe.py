@@ -65,7 +65,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gpt
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import grouped_experts, sparse_latent
 
 NEG_INF = sparse_latent.NEG_INF
@@ -486,12 +486,13 @@ def _feed_forward(x, lp, cfg, live=None,
                   every_load: bool = False):
     """x += the layer's feed-forward; -> (x, expert counts or None)."""
     adt = cfg.activation_dtype()
-    h2 = _norm(x, lp["ffn_norm_scale"], cfg)
-    if "router" in lp:
-        routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel,
-                                              every_load)
-        return x + routed + shared, counts
-    return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
+    with jax.named_scope(FFN):
+        h2 = _norm(x, lp["ffn_norm_scale"], cfg)
+        if "router" in lp:
+            routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel,
+                                                  every_load)
+            return x + routed + shared, counts
+        return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
 
 
 def _counts(cfg, pos, live, expert_counts, chunk_block: int = 0):
@@ -628,23 +629,27 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
         t = seq.shape[0]
         pos = jnp.arange(t, dtype=jnp.int32)
         causal = pos[None, :] <= pos[:, None]
-        x = params["embed"].astype(adt)[seq]
+        with jax.named_scope(EMBED):
+            x = params["embed"].astype(adt)[seq]
         selected = None if cfg.has_indexer else causal
         for lp in params["layers"]:
-            h = _norm(x, lp["attn_norm_scale"], cfg)
-            q_nope, q_rope, row = _project(h, lp, pos, cfg)
-            if "wi_q" in lp:
-                q_i, k_i, w = _index_parts(x, lp, pos, cfg)
-                s = sparse_latent.index_dots(q_i, k_i, "qjd,kd->qjk")
-                scores = jnp.where(causal, jnp.einsum(
-                    "qj,qjk->qk", w, jax.nn.relu(s)), -jnp.inf)
-                selected = _select_dense(scores, causal, cfg.index_topk)
-                if selections is not None:
-                    selections.append(selected)
-            att = attend_full(q_nope, q_rope, row, selected, lp, cfg)
-            x = x + _mm(att.reshape(t, -1), lp["w_out"], adt)
+            with jax.named_scope(MIXER):
+                h = _norm(x, lp["attn_norm_scale"], cfg)
+                q_nope, q_rope, row = _project(h, lp, pos, cfg)
+                if "wi_q" in lp:
+                    q_i, k_i, w = _index_parts(x, lp, pos, cfg)
+                    s = sparse_latent.index_dots(q_i, k_i, "qjd,kd->qjk")
+                    scores = jnp.where(causal, jnp.einsum(
+                        "qj,qjk->qk", w, jax.nn.relu(s)), -jnp.inf)
+                    selected = _select_dense(scores, causal, cfg.index_topk)
+                    if selections is not None:
+                        selections.append(selected)
+                att = attend_full(q_nope, q_rope, row, selected, lp, cfg)
+                x = x + _mm(att.reshape(t, -1), lp["w_out"], adt)
             x, _ = _feed_forward(x, lp, cfg)
-        return _unembed(_norm(x, params["final_ln_scale"], cfg), params, cfg)
+        with jax.named_scope(HEAD):
+            return _unembed(_norm(x, params["final_ln_scale"], cfg), params,
+                            cfg)
 
     if selections is not None:          # the list is filled outside a map
         return jnp.stack([one(seq) for seq in tokens])
@@ -754,8 +759,9 @@ def _train_layer(x, lp, pos, cfg):
     """-> (x [B, T, D], the layer's expert counts [2 + router_width] i32,
     None for a dense layer)."""
     b, t, d = x.shape
-    x = x + _train_attention(_norm(x, lp["attn_norm_scale"], cfg), lp, pos,
-                             cfg)
+    with jax.named_scope(MIXER):
+        x = x + _train_attention(_norm(x, lp["attn_norm_scale"], cfg), lp,
+                                 pos, cfg)
     x, counts = _feed_forward(
         x.reshape(b * t, d), lp, cfg,
         kernel=grouped_experts.EXPERTS_GROUPED_TRAIN, every_load=True)
@@ -787,15 +793,17 @@ def forward_features(params, tokens, cfg: LatentSparseMoEConfig, mesh=None):
     layer = jax.checkpoint(
         lambda x, lp: _train_layer(x, lp, pos, cfg),
         policy=jax.checkpoint_policies.save_only_these_names(*SAVED_NAMES))
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        x = params["embed"].astype(adt)[tokens]
     counts = []
     for lp in params["layers"]:
         x, c = layer(x, lp)
         if c is not None:
             counts.append(c)
-    counts = jnp.stack(counts) if counts else jnp.zeros(
-        (0, 2 + cfg.router_width), jnp.int32)
-    return _norm(x, params["final_ln_scale"], cfg), counts
+    with jax.named_scope(HEAD):
+        counts = jnp.stack(counts) if counts else jnp.zeros(
+            (0, 2 + cfg.router_width), jnp.int32)
+        return _norm(x, params["final_ln_scale"], cfg), counts
 
 
 def update_router_bias(params, counts, cfg: LatentSparseMoEConfig):
@@ -922,38 +930,43 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     start = jnp.asarray(start, jnp.int32)
     length = jnp.asarray(c if length is None else length, jnp.int32)
     table = jnp.asarray(block_table, jnp.int32)
-    offs = jnp.arange(c, dtype=jnp.int32)
-    positions = start + offs
-    valid = offs < length
-    widx = jnp.where(valid, table[positions // bs] * bs + positions % bs,
-                     nb * bs)
     latent, index = cache["latent"], cache.get("index")
-    x = params["embed"].astype(adt)[tokens[0]]
-    selected = None if cfg.has_indexer else every_earlier(
-        positions, valid, table.shape[0] * bs)
+    with jax.named_scope(EMBED):
+        offs = jnp.arange(c, dtype=jnp.int32)
+        positions = start + offs
+        valid = offs < length
+        widx = jnp.where(valid,
+                         table[positions // bs] * bs + positions % bs,
+                         nb * bs)
+        x = params["embed"].astype(adt)[tokens[0]]
+        selected = None if cfg.has_indexer else every_earlier(
+            positions, valid, table.shape[0] * bs)
     full, expert_counts = 0, []
     for i, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm_scale"], cfg)
-        q_nope, q_rope, row = _project(h, lp, positions, cfg)
-        latent = _write_latent(latent, i, row, widx, cfg)
-        if "wi_q" in lp:
-            q_i, k_i, w = _index_parts(x, lp, positions, cfg)
-            index = _write_index(index, full, k_i, widx)
-            selected = _prefill_select(q_i, w, index, full, table,
-                                       positions, valid, cfg)
-            full += 1
-        att = _prefill_attend(q_nope, q_rope, latent, i, table, positions,
-                              valid, selected, lp, cfg)
-        x = x + _mm(att, lp["w_out"], adt)
+        with jax.named_scope(MIXER):
+            h = _norm(x, lp["attn_norm_scale"], cfg)
+            q_nope, q_rope, row = _project(h, lp, positions, cfg)
+            latent = _write_latent(latent, i, row, widx, cfg)
+            if "wi_q" in lp:
+                q_i, k_i, w = _index_parts(x, lp, positions, cfg)
+                index = _write_index(index, full, k_i, widx)
+                selected = _prefill_select(q_i, w, index, full, table,
+                                           positions, valid, cfg)
+                full += 1
+            att = _prefill_attend(q_nope, q_rope, latent, i, table,
+                                  positions, valid, selected, lp, cfg)
+            x = x + _mm(att, lp["w_out"], adt)
         x, counts = _feed_forward(
             x, lp, cfg, valid, grouped_experts.EXPERTS_GROUPED_PREFILL)
         if counts is not None:
             expert_counts.append(counts)
-    x = _norm(x, params["final_ln_scale"], cfg)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    return (_unembed(last, params, cfg), _pool_of(latent, index),
-            _counts(cfg, positions, valid, expert_counts,
-                    sparse_latent.context_block(table.shape[0] * bs, bs)))
+    with jax.named_scope(HEAD):
+        x = _norm(x, params["final_ln_scale"], cfg)
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        return (_unembed(last, params, cfg), _pool_of(latent, index),
+                _counts(cfg, positions, valid, expert_counts,
+                        sparse_latent.context_block(table.shape[0] * bs,
+                                                    bs)))
 
 
 # ---------------------------------------------------------------------------
@@ -1025,35 +1038,38 @@ def decode(params, tokens, cache, pos, tables,
     b = tokens.shape[0]
     pos = pos.astype(jnp.int32)
     tables = tables.astype(jnp.int32)
-    widx = decode_write_index(cache["latent"], tables, pos)
-    live = tables[:, 0] > 0
     latent, index = cache["latent"], cache.get("index")
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        widx = decode_write_index(cache["latent"], tables, pos)
+        live = tables[:, 0] > 0
+        x = params["embed"].astype(adt)[tokens]
     rows = count = None
     full, expert_counts = 0, []
     for i, lp in enumerate(params["layers"]):
-        h = _norm(x, lp["attn_norm_scale"], cfg)
-        q_nope, q_rope, row = _project(h, lp, pos, cfg)
-        latent = _write_latent(latent, i, row, widx, cfg)
-        if "wi_q" in lp:
-            q_i, k_i, w = _index_parts(x, lp, pos, cfg)
-            index = _write_index(index, full, k_i, widx)
-            scores = sparse_latent.index_scores(
-                q_i, w, index.reshape(-1, bs, cfg.index_dim),
-                tables + full * nb, pos, impl=cfg.sparse_impl)
-            rows, count, idx = select_rows(scores, tables, pos, cfg, bs)
-            if selections is not None:
-                selections.append(idx)
-            full += 1
-        att = decode_attend(q_nope, q_rope, latent, i, tables, pos, lp, cfg,
-                            rows, count)
-        x = x + _mm(att.reshape(b, -1), lp["w_out"], adt)
+        with jax.named_scope(MIXER):
+            h = _norm(x, lp["attn_norm_scale"], cfg)
+            q_nope, q_rope, row = _project(h, lp, pos, cfg)
+            latent = _write_latent(latent, i, row, widx, cfg)
+            if "wi_q" in lp:
+                q_i, k_i, w = _index_parts(x, lp, pos, cfg)
+                index = _write_index(index, full, k_i, widx)
+                scores = sparse_latent.index_scores(
+                    q_i, w, index.reshape(-1, bs, cfg.index_dim),
+                    tables + full * nb, pos, impl=cfg.sparse_impl)
+                rows, count, idx = select_rows(scores, tables, pos, cfg, bs)
+                if selections is not None:
+                    selections.append(idx)
+                full += 1
+            att = decode_attend(q_nope, q_rope, latent, i, tables, pos, lp,
+                                cfg, rows, count)
+            x = x + _mm(att.reshape(b, -1), lp["w_out"], adt)
         x, counts = _feed_forward(x, lp, cfg, live)
         if counts is not None:
             expert_counts.append(counts)
-    x = _norm(x, params["final_ln_scale"], cfg)
-    return (_unembed(x, params, cfg), _pool_of(latent, index),
-            _counts(cfg, pos, live, expert_counts))
+    with jax.named_scope(HEAD):
+        x = _norm(x, params["final_ln_scale"], cfg)
+        return (_unembed(x, params, cfg), _pool_of(latent, index),
+                _counts(cfg, pos, live, expert_counts))
 
 
 FAMILY = ServingFamily(

@@ -50,7 +50,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import grouped_experts, kda
 
 # what the prefill and decode programs count, in the order of the int32
@@ -319,7 +319,8 @@ def _feed_forward(x, lp, cfg, live, kernel):
     """`latent_sparse_moe._feed_forward` and this layer's counts (pairs
     here, pairs routed, groups kept here, the held experts' loads), None
     for a dense layer."""
-    groups = _groups_here(x, lp, cfg, live)
+    with jax.named_scope(FFN):
+        groups = _groups_here(x, lp, cfg, live)
     x, counts = lsm._feed_forward(x, lp, cfg, live, kernel)
     if counts is None:
         return x, None
@@ -353,24 +354,28 @@ def forward(params, tokens, cfg: LinearLatentConfig):
         pos = jnp.arange(t, dtype=jnp.int32)
         live = jnp.ones((t,), bool)
         causal = pos[None, :] <= pos[:, None]
-        x = params["embed"].astype(adt)[seq]
+        with jax.named_scope(EMBED):
+            x = params["embed"].astype(adt)[seq]
         for lp, mixer in zip(params["layers"], cfg.mixers):
-            h = lsm._norm(x, lp["attn_norm_scale"], cfg)
-            if mixer == "kda":
-                pre = jnp.pad(_pre_conv(h, lp, cfg), ((taps - 1, 0), (0, 0)))
-                w = _conv_taps(lp)
-                conved = sum(w[i] * pre[i:i + t] for i in range(taps))
-                q, k, v, g, beta = _heads(conved, h, lp, cfg)
-                x = x + _kda_out(kda.kda_recurrent(q, k, v, g, beta)[0], h,
-                                 lp, cfg)
-            else:
-                q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
-                x = x + _latent_out(lsm.attend_full(
-                    q_nope, q_rope, row, causal, lp, cfg), h, lp, cfg)
+            with jax.named_scope(MIXER):
+                h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+                if mixer == "kda":
+                    pre = jnp.pad(_pre_conv(h, lp, cfg),
+                                  ((taps - 1, 0), (0, 0)))
+                    w = _conv_taps(lp)
+                    conved = sum(w[i] * pre[i:i + t] for i in range(taps))
+                    q, k, v, g, beta = _heads(conved, h, lp, cfg)
+                    x = x + _kda_out(kda.kda_recurrent(q, k, v, g, beta)[0], h,
+                                     lp, cfg)
+                else:
+                    q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
+                    x = x + _latent_out(lsm.attend_full(
+                        q_nope, q_rope, row, causal, lp, cfg), h, lp, cfg)
             x, _ = _feed_forward(x, lp, cfg, live,
                                  grouped_experts.EXPERTS_GROUPED)
-        return lsm._unembed(lsm._norm(x, params["final_ln_scale"], cfg),
-                            params, cfg)
+        with jax.named_scope(HEAD):
+            return lsm._unembed(lsm._norm(x, params["final_ln_scale"], cfg),
+                                params, cfg)
 
     return jax.lax.map(one, tokens)
 
@@ -394,55 +399,58 @@ def prefill(params, tokens, cache, cfg: LinearLatentConfig, mesh=None, *,
     taps = cfg.conv_size
     state, conv, latent = cache["state"], cache["conv"], cache["latent"]
     nb, bs = latent.shape[1], latent.shape[2]
-    start = jnp.asarray(start, jnp.int32)
-    length = jnp.asarray(c if length is None else length, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    block, pages = table[0], table[1:]
-    first = start == 0
-    offs = jnp.arange(c, dtype=jnp.int32)
-    positions = start + offs
-    valid = offs < length
-    widx = jnp.where(valid, pages[positions // bs] * bs + positions % bs,
-                     nb * bs)
-    every = lsm.every_earlier(positions, valid, pages.shape[0] * bs)
-    x = params["embed"].astype(adt)[tokens[0]]
+    with jax.named_scope(EMBED):
+        start = jnp.asarray(start, jnp.int32)
+        length = jnp.asarray(c if length is None else length, jnp.int32)
+        table = jnp.asarray(block_table, jnp.int32)
+        block, pages = table[0], table[1:]
+        first = start == 0
+        offs = jnp.arange(c, dtype=jnp.int32)
+        positions = start + offs
+        valid = offs < length
+        widx = jnp.where(valid, pages[positions // bs] * bs + positions % bs,
+                         nb * bs)
+        every = lsm.every_earlier(positions, valid, pages.shape[0] * bs)
+        x = params["embed"].astype(adt)[tokens[0]]
     n_kda = n_latent = 0
     expert_counts = []
     for lp, mixer in zip(params["layers"], cfg.mixers):
-        h = lsm._norm(x, lp["attn_norm_scale"], cfg)
-        if mixer == "kda":
-            tail = jnp.where(first, 0.0, conv[n_kda, block])
-            pre = jnp.concatenate([tail, _pre_conv(h, lp, cfg)])
-            w = _conv_taps(lp)
-            conved = sum(w[i] * pre[i:i + c] for i in range(taps))
-            # the last live positions' projections, whatever the padding
-            conv = conv.at[n_kda, block].set(
-                jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
-            q, k, v, g, beta = _heads(conved, h, lp, cfg)
-            o, state = kda.kda_chunk(
-                q, k, v, g, beta, state, n_kda, block, first, length,
-                state_round=cfg.state_round, impl=cfg.kda_impl)
-            x = x + _kda_out(o, h, lp, cfg)
-            n_kda += 1
-        else:
-            q_nope, q_rope, row = lsm._project(h, lp, positions, cfg)
-            latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
-            att = lsm._prefill_attend(q_nope, q_rope, latent, n_latent,
-                                      pages, positions, valid, every, lp,
-                                      cfg)
-            x = x + _latent_out(att.reshape(c, cfg.n_heads, cfg.v_dim), h,
-                                lp, cfg)
-            n_latent += 1
+        with jax.named_scope(MIXER):
+            h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+            if mixer == "kda":
+                tail = jnp.where(first, 0.0, conv[n_kda, block])
+                pre = jnp.concatenate([tail, _pre_conv(h, lp, cfg)])
+                w = _conv_taps(lp)
+                conved = sum(w[i] * pre[i:i + c] for i in range(taps))
+                # the last live positions' projections, whatever the padding
+                conv = conv.at[n_kda, block].set(
+                    jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
+                q, k, v, g, beta = _heads(conved, h, lp, cfg)
+                o, state = kda.kda_chunk(
+                    q, k, v, g, beta, state, n_kda, block, first, length,
+                    state_round=cfg.state_round, impl=cfg.kda_impl)
+                x = x + _kda_out(o, h, lp, cfg)
+                n_kda += 1
+            else:
+                q_nope, q_rope, row = lsm._project(h, lp, positions, cfg)
+                latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
+                att = lsm._prefill_attend(q_nope, q_rope, latent, n_latent,
+                                          pages, positions, valid, every, lp,
+                                          cfg)
+                x = x + _latent_out(att.reshape(c, cfg.n_heads, cfg.v_dim), h,
+                                    lp, cfg)
+                n_latent += 1
         x, counts = _feed_forward(x, lp, cfg, valid,
                                   grouped_experts.EXPERTS_GROUPED_PREFILL)
         if counts is not None:
             expert_counts.append(counts)
-    x = lsm._norm(x, params["final_ln_scale"], cfg)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_latent
-    return (lsm._unembed(last, params, cfg),
-            {"state": state, "conv": conv, "latent": latent},
-            _counts(cfg, [length, c - length, first, rows], expert_counts))
+    with jax.named_scope(HEAD):
+        x = lsm._norm(x, params["final_ln_scale"], cfg)
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_latent
+        return (lsm._unembed(last, params, cfg),
+                {"state": state, "conv": conv, "latent": latent},
+                _counts(cfg, [length, c - length, first, rows], expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
@@ -456,45 +464,48 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
     taps = cfg.conv_size
     state, conv, latent = cache["state"], cache["conv"], cache["latent"]
     b = tokens.shape[0]
-    pos = pos.astype(jnp.int32)
-    tables = tables.astype(jnp.int32)
-    blocks, pages = tables[:, 0], tables[:, 1:]
-    live = blocks > 0
-    widx = lsm.decode_write_index(latent, pages, pos)
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        pos = pos.astype(jnp.int32)
+        tables = tables.astype(jnp.int32)
+        blocks, pages = tables[:, 0], tables[:, 1:]
+        live = blocks > 0
+        widx = lsm.decode_write_index(latent, pages, pos)
+        x = params["embed"].astype(adt)[tokens]
     n_kda = n_latent = 0
     expert_counts = []
     for lp, mixer in zip(params["layers"], cfg.mixers):
-        h = lsm._norm(x, lp["attn_norm_scale"], cfg)
-        if mixer == "kda":
-            pre = jnp.concatenate(
-                [conv[n_kda, blocks], _pre_conv(h, lp, cfg)[:, None]], 1)
-            conved = jnp.einsum("kc,bkc->bc", _conv_taps(lp), pre)
-            conv = conv.at[n_kda, blocks].set(pre[:, 1:])
-            q, k, v, g, beta = _heads(conved, h, lp, cfg)
-            o, state = kda.kda_step(
-                q, k, v, g, beta, state, n_kda, blocks,
-                state_round=cfg.state_round, impl=cfg.kda_impl)
-            x = x + _kda_out(o, h, lp, cfg)
-            n_kda += 1
-        else:
-            q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
-            latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
-            att = lsm.decode_attend(q_nope, q_rope, latent, n_latent, pages,
-                                    pos, lp, cfg)
-            x = x + _latent_out(att, h, lp, cfg)
-            n_latent += 1
+        with jax.named_scope(MIXER):
+            h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+            if mixer == "kda":
+                pre = jnp.concatenate(
+                    [conv[n_kda, blocks], _pre_conv(h, lp, cfg)[:, None]], 1)
+                conved = jnp.einsum("kc,bkc->bc", _conv_taps(lp), pre)
+                conv = conv.at[n_kda, blocks].set(pre[:, 1:])
+                q, k, v, g, beta = _heads(conved, h, lp, cfg)
+                o, state = kda.kda_step(
+                    q, k, v, g, beta, state, n_kda, blocks,
+                    state_round=cfg.state_round, impl=cfg.kda_impl)
+                x = x + _kda_out(o, h, lp, cfg)
+                n_kda += 1
+            else:
+                q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
+                latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
+                att = lsm.decode_attend(q_nope, q_rope, latent, n_latent,
+                                        pages, pos, lp, cfg)
+                x = x + _latent_out(att, h, lp, cfg)
+                n_latent += 1
         x, counts = _feed_forward(x, lp, cfg, live,
                                   grouped_experts.EXPERTS_GROUPED)
         if counts is not None:
             expert_counts.append(counts)
-    x = lsm._norm(x, params["final_ln_scale"], cfg)
-    rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_latent
-    zero = jnp.int32(0)
-    return (lsm._unembed(x, params, cfg),
-            {"state": state, "conv": conv, "latent": latent},
-            _counts(cfg, [jnp.sum(live, dtype=jnp.int32), b - jnp.sum(
-                live, dtype=jnp.int32), zero, rows], expert_counts))
+    with jax.named_scope(HEAD):
+        x = lsm._norm(x, params["final_ln_scale"], cfg)
+        rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_latent
+        zero = jnp.int32(0)
+        return (lsm._unembed(x, params, cfg),
+                {"state": state, "conv": conv, "latent": latent},
+                _counts(cfg, [jnp.sum(live, dtype=jnp.int32), b - jnp.sum(
+                    live, dtype=jnp.int32), zero, rows], expert_counts))
 
 
 FAMILY = ServingFamily(

@@ -59,7 +59,7 @@ import jax.numpy as jnp
 from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
 from ray_tpu.models import window_moe
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts, mamba2
 
@@ -325,6 +325,11 @@ def _relu2_mlp(n, w_up, w_down, adt):
     return lsm._mm(jnp.square(jax.nn.relu(up)).astype(adt), w_down, adt)
 
 
+def _part(kind: str) -> str:
+    """A layer is a mixer or a feed-forward part alone."""
+    return MIXER if kind in ("mamba", "attention") else FFN
+
+
 def _experts(n, lp, cfg, live, kernel):
     """-> (what the held experts, through the latent, and the shared one
     add [N, D]; counts i32: pairs routed here, pairs routed anywhere,
@@ -389,28 +394,32 @@ def forward(params, tokens, cfg: MambaMoEConfig):
     def one(seq):
         t = seq.shape[0]
         live = jnp.ones((t,), bool)
-        x = params["embed"].astype(adt)[seq]
+        with jax.named_scope(EMBED):
+            x = params["embed"].astype(adt)[seq]
         for lp, kind in zip(params["layers"], cfg.kinds):
-            n = lsm._norm(x, lp["norm_scale"], cfg)
-            if kind == "mamba":
-                z, xbc, dt = _in_proj(n, lp, cfg)
-                pre = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
-                w = lp["conv_w"].astype(jnp.float32)
-                act = _conv_act(sum(w[i] * pre[i:i + t]
-                                    for i in range(taps)), lp)
-                xs, b, c, step, a = _ssm_inputs(act, dt, lp, cfg)
-                y = mamba2.mamba2_recurrent(xs, step, a, b, c)[0]
-                x = x + _mamba_out(y, xs, z, lp, cfg)
-            elif kind == "attention":
-                q, k, v = _qkv(n, lp, cfg)
-                att = da.reference_gqa_attention(
-                    q[None], k[None], v[None], jnp.zeros((1,), jnp.int32))[0]
-                x = x + lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
-            else:
-                x = x + _experts(n, lp, cfg, live,
-                                 grouped_experts.EXPERTS_GROUPED)[0]
-        return _unembed(lsm._norm(x, params["final_norm_scale"], cfg),
-                        params, cfg)
+            with jax.named_scope(_part(kind)):
+                n = lsm._norm(x, lp["norm_scale"], cfg)
+                if kind == "mamba":
+                    z, xbc, dt = _in_proj(n, lp, cfg)
+                    pre = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
+                    w = lp["conv_w"].astype(jnp.float32)
+                    act = _conv_act(sum(w[i] * pre[i:i + t]
+                                        for i in range(taps)), lp)
+                    xs, b, c, step, a = _ssm_inputs(act, dt, lp, cfg)
+                    y = mamba2.mamba2_recurrent(xs, step, a, b, c)[0]
+                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                elif kind == "attention":
+                    q, k, v = _qkv(n, lp, cfg)
+                    att = da.reference_gqa_attention(
+                        q[None], k[None], v[None],
+                        jnp.zeros((1,), jnp.int32))[0]
+                    x = x + lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
+                else:
+                    x = x + _experts(n, lp, cfg, live,
+                                     grouped_experts.EXPERTS_GROUPED)[0]
+        with jax.named_scope(HEAD):
+            return _unembed(lsm._norm(x, params["final_norm_scale"], cfg),
+                            params, cfg)
 
     return jax.lax.map(one, tokens)
 
@@ -433,60 +442,63 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
     adt = cfg.activation_dtype()
     taps = cfg.conv_size
     cache = dict(cache)
-    start = jnp.asarray(start, jnp.int32)
-    length = jnp.asarray(c if length is None else length, jnp.int32)
-    table = jnp.asarray(block_table, jnp.int32)
-    block, pages = table[0], table[1:]
-    first = start == 0
-    offs = jnp.arange(c, dtype=jnp.int32)
-    positions = start + offs
-    valid = offs < length
-    x = params["embed"].astype(adt)[tokens[0]]
+    with jax.named_scope(EMBED):
+        start = jnp.asarray(start, jnp.int32)
+        length = jnp.asarray(c if length is None else length, jnp.int32)
+        table = jnp.asarray(block_table, jnp.int32)
+        block, pages = table[0], table[1:]
+        first = start == 0
+        offs = jnp.arange(c, dtype=jnp.int32)
+        positions = start + offs
+        valid = offs < length
+        x = params["embed"].astype(adt)[tokens[0]]
     n_mamba = n_attn = 0
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
-        n = lsm._norm(x, lp["norm_scale"], cfg)
-        if kind == "mamba":
-            with jax.named_scope("mamba_layer"):
-                z, xbc, dt = _in_proj(n, lp, cfg)
-                tail = jnp.where(first, 0.0, cache["conv"][n_mamba, block])
-                pre = jnp.concatenate([tail, xbc])
-                w = lp["conv_w"].astype(jnp.float32)
-                act = _conv_act(sum(w[i] * pre[i:i + c]
-                                    for i in range(taps)), lp)
-                # the last live positions' xBC, whatever the padding
-                cache["conv"] = cache["conv"].at[n_mamba, block].set(
-                    jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
-                xs, b, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
-                y, cache["state"] = mamba2.mamba2_chunk(
-                    xs, step, a, b, cc, cache["state"], n_mamba, block,
-                    first, length, state_round=cfg.state_round,
-                    impl=cfg.mamba_impl)
-                x = x + _mamba_out(y, xs, z, lp, cfg)
-            n_mamba += 1
-        elif kind == "attention":
-            with jax.named_scope("attention_layer"):
-                q, k, v = _qkv(n, lp, cfg)
-                cache["k"] = window_moe._write_chunk(
-                    cache["k"], n_attn, k, pages, start, length)
-                cache["v"] = window_moe._write_chunk(
-                    cache["v"], n_attn, v, pages, start, length)
-                att = da.gqa_chunk_attention(
-                    q, cache["k"], cache["v"], pages, start, layer=n_attn,
-                    impl=cfg.attn_impl)
-                x = x + lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
-            n_attn += 1
-        else:
-            ff, counts = _experts(n, lp, cfg, valid,
-                                  grouped_experts.EXPERTS_GROUPED_PREFILL)
-            expert_counts.append(counts)
-            x = x + ff
-    x = lsm._norm(x, params["final_norm_scale"], cfg)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
-    return (_unembed(last, params, cfg), cache,
-            _counts(cfg, [length * n_mamba, (c - length) * n_mamba, first,
-                          rows], expert_counts))
+        with jax.named_scope(_part(kind)):
+            n = lsm._norm(x, lp["norm_scale"], cfg)
+            if kind == "mamba":
+                with jax.named_scope("mamba_layer"):
+                    z, xbc, dt = _in_proj(n, lp, cfg)
+                    tail = jnp.where(first, 0.0, cache["conv"][n_mamba, block])
+                    pre = jnp.concatenate([tail, xbc])
+                    w = lp["conv_w"].astype(jnp.float32)
+                    act = _conv_act(sum(w[i] * pre[i:i + c]
+                                        for i in range(taps)), lp)
+                    # the last live positions' xBC, whatever the padding
+                    cache["conv"] = cache["conv"].at[n_mamba, block].set(
+                        jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
+                    xs, b, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+                    y, cache["state"] = mamba2.mamba2_chunk(
+                        xs, step, a, b, cc, cache["state"], n_mamba, block,
+                        first, length, state_round=cfg.state_round,
+                        impl=cfg.mamba_impl)
+                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                n_mamba += 1
+            elif kind == "attention":
+                with jax.named_scope("attention_layer"):
+                    q, k, v = _qkv(n, lp, cfg)
+                    cache["k"] = window_moe._write_chunk(
+                        cache["k"], n_attn, k, pages, start, length)
+                    cache["v"] = window_moe._write_chunk(
+                        cache["v"], n_attn, v, pages, start, length)
+                    att = da.gqa_chunk_attention(
+                        q, cache["k"], cache["v"], pages, start, layer=n_attn,
+                        impl=cfg.attn_impl)
+                    x = x + lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
+                n_attn += 1
+            else:
+                ff, counts = _experts(n, lp, cfg, valid,
+                                      grouped_experts.EXPERTS_GROUPED_PREFILL)
+                expert_counts.append(counts)
+                x = x + ff
+    with jax.named_scope(HEAD):
+        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
+        return (_unembed(last, params, cfg), cache,
+                _counts(cfg, [length * n_mamba, (c - length) * n_mamba, first,
+                              rows], expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
@@ -500,59 +512,63 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
     cache = dict(cache)
     bs = cache["k"].shape[3]
     b = tokens.shape[0]
-    pos = pos.astype(jnp.int32)
-    tables = tables.astype(jnp.int32)
-    blocks, pages = tables[:, 0], tables[:, 1:]
-    live = blocks > 0
-    cols = pages.shape[1]
-    page = jnp.minimum(pos // bs, cols - 1)[:, None]
-    widx = jnp.where(
-        pos < cols * bs,
-        jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
-        cache["k"].shape[1] * bs)
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        pos = pos.astype(jnp.int32)
+        tables = tables.astype(jnp.int32)
+        blocks, pages = tables[:, 0], tables[:, 1:]
+        live = blocks > 0
+        cols = pages.shape[1]
+        page = jnp.minimum(pos // bs, cols - 1)[:, None]
+        widx = jnp.where(
+            pos < cols * bs,
+            jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
+            cache["k"].shape[1] * bs)
+        x = params["embed"].astype(adt)[tokens]
     n_mamba = n_attn = 0
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
-        n = lsm._norm(x, lp["norm_scale"], cfg)
-        if kind == "mamba":
-            with jax.named_scope("mamba_layer"):
-                z, xbc, dt = _in_proj(n, lp, cfg)
-                pre = jnp.concatenate(
-                    [cache["conv"][n_mamba, blocks], xbc[:, None]], 1)
-                act = _conv_act(jnp.einsum(
-                    "kc,bkc->bc", lp["conv_w"].astype(jnp.float32), pre), lp)
-                cache["conv"] = cache["conv"].at[n_mamba, blocks].set(
-                    pre[:, 1:])
-                xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
-                y, cache["state"] = mamba2.mamba2_step(
-                    xs, step, a, bb, cc, cache["state"], n_mamba, blocks,
-                    state_round=cfg.state_round, impl=cfg.mamba_impl)
-                x = x + _mamba_out(y, xs, z, lp, cfg)
-            n_mamba += 1
-        elif kind == "attention":
-            with jax.named_scope("attention_layer"):
-                q, k, v = _qkv(n, lp, cfg)
-                cache["k"] = window_moe._write_rows(cache["k"], n_attn, k,
-                                                    widx)
-                cache["v"] = window_moe._write_rows(cache["v"], n_attn, v,
-                                                    widx)
-                att = da.gqa_decode_attention(
-                    q, cache["k"], cache["v"], pages, pos, layer=n_attn,
-                    impl=cfg.attn_impl)
-                x = x + lsm._mm(att.reshape(b, -1), lp["w_out"], adt)
-            n_attn += 1
-        else:
-            ff, counts = _experts(n, lp, cfg, live,
-                                  grouped_experts.EXPERTS_GROUPED)
-            expert_counts.append(counts)
-            x = x + ff
-    x = lsm._norm(x, params["final_norm_scale"], cfg)
-    n_live = jnp.sum(live, dtype=jnp.int32)
-    rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
-    return (_unembed(x, params, cfg), cache,
-            _counts(cfg, [n_live * n_mamba, (b - n_live) * n_mamba,
-                          jnp.int32(0), rows], expert_counts))
+        with jax.named_scope(_part(kind)):
+            n = lsm._norm(x, lp["norm_scale"], cfg)
+            if kind == "mamba":
+                with jax.named_scope("mamba_layer"):
+                    z, xbc, dt = _in_proj(n, lp, cfg)
+                    pre = jnp.concatenate(
+                        [cache["conv"][n_mamba, blocks], xbc[:, None]], 1)
+                    act = _conv_act(jnp.einsum(
+                        "kc,bkc->bc", lp["conv_w"].astype(jnp.float32),
+                        pre), lp)
+                    cache["conv"] = cache["conv"].at[n_mamba, blocks].set(
+                        pre[:, 1:])
+                    xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+                    y, cache["state"] = mamba2.mamba2_step(
+                        xs, step, a, bb, cc, cache["state"], n_mamba, blocks,
+                        state_round=cfg.state_round, impl=cfg.mamba_impl)
+                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                n_mamba += 1
+            elif kind == "attention":
+                with jax.named_scope("attention_layer"):
+                    q, k, v = _qkv(n, lp, cfg)
+                    cache["k"] = window_moe._write_rows(cache["k"], n_attn, k,
+                                                        widx)
+                    cache["v"] = window_moe._write_rows(cache["v"], n_attn, v,
+                                                        widx)
+                    att = da.gqa_decode_attention(
+                        q, cache["k"], cache["v"], pages, pos, layer=n_attn,
+                        impl=cfg.attn_impl)
+                    x = x + lsm._mm(att.reshape(b, -1), lp["w_out"], adt)
+                n_attn += 1
+            else:
+                ff, counts = _experts(n, lp, cfg, live,
+                                      grouped_experts.EXPERTS_GROUPED)
+                expert_counts.append(counts)
+                x = x + ff
+    with jax.named_scope(HEAD):
+        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        n_live = jnp.sum(live, dtype=jnp.int32)
+        rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
+        return (_unembed(x, params, cfg), cache,
+                _counts(cfg, [n_live * n_mamba, (b - n_live) * n_mamba,
+                              jnp.int32(0), rows], expert_counts))
 
 
 FAMILY = ServingFamily(

@@ -38,7 +38,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import gpt
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import power_retention
 
 # what the prefill program counts, in the order of the int32 vector it
@@ -189,9 +189,11 @@ def _project(h, lp, pos, cfg):
 
 def _mlp(x, lp, cfg):
     adt = cfg.activation_dtype()
-    m = _norm(x, lp["mlp_norm_scale"], cfg)
-    hidden = jax.nn.silu(_mm(m, lp["w_gate"], adt)) * _mm(m, lp["w_up"], adt)
-    return x + _mm(hidden, lp["w_down"], adt)
+    with jax.named_scope(FFN):
+        m = _norm(x, lp["mlp_norm_scale"], cfg)
+        hidden = (jax.nn.silu(_mm(m, lp["w_gate"], adt))
+                  * _mm(m, lp["w_up"], adt))
+        return x + _mm(hidden, lp["w_down"], adt)
 
 
 def _mixed(x, o, lp, cfg):
@@ -225,15 +227,19 @@ def forward(params, tokens, cfg: RetentionConfig):
 
     def one(seq):
         pos = jnp.arange(seq.shape[0])
-        x = params["embed"].astype(adt)[seq]
+        with jax.named_scope(EMBED):
+            x = params["embed"].astype(adt)[seq]
         for lp in params["layers"]:
-            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
-                                     lp, pos, cfg)
-            o = power_retention.retention_quadratic(
-                q, k, v, logg, eps=cfg.retention_eps)
-            x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
-        return _unembed(_norm(x, params["final_norm_scale"], cfg), params,
-                        cfg)
+            with jax.named_scope(MIXER):
+                q, k, v, logg = _project(
+                    _norm(x, lp["mix_norm_scale"], cfg), lp, pos, cfg)
+                o = power_retention.retention_quadratic(
+                    q, k, v, logg, eps=cfg.retention_eps)
+                x = _mixed(x, o, lp, cfg)
+            x = _mlp(x, lp, cfg)
+        with jax.named_scope(HEAD):
+            return _unembed(_norm(x, params["final_norm_scale"], cfg),
+                            params, cfg)
 
     return jax.lax.map(one, tokens)
 
@@ -260,19 +266,23 @@ def prefill(params, tokens, cache, cfg: RetentionConfig, mesh=None, *,
     first = start == 0
     positions = start + jnp.arange(c, dtype=jnp.int32)
     s, z = cache["s"], cache["z"]
-    x = params["embed"].astype(adt)[tokens[0]]
+    with jax.named_scope(EMBED):
+        x = params["embed"].astype(adt)[tokens[0]]
     for i, lp in enumerate(params["layers"]):
-        q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg), lp,
-                                 positions, cfg)
-        o, s, z = power_retention.retention_chunk(
-            q, k, v, logg, s, z, i, block, first, length,
-            eps=cfg.retention_eps, state_round=cfg.state_round,
-            impl=cfg.retention_impl)
-        x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
-    x = _norm(x, params["final_norm_scale"], cfg)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    counts = jnp.stack([length, c - length, first.astype(jnp.int32)])
-    return _unembed(last, params, cfg), {"s": s, "z": z}, counts
+        with jax.named_scope(MIXER):
+            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
+                                     lp, positions, cfg)
+            o, s, z = power_retention.retention_chunk(
+                q, k, v, logg, s, z, i, block, first, length,
+                eps=cfg.retention_eps, state_round=cfg.state_round,
+                impl=cfg.retention_impl)
+            x = _mixed(x, o, lp, cfg)
+        x = _mlp(x, lp, cfg)
+    with jax.named_scope(HEAD):
+        x = _norm(x, params["final_norm_scale"], cfg)
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        counts = jnp.stack([length, c - length, first.astype(jnp.int32)])
+        return _unembed(last, params, cfg), {"s": s, "z": z}, counts
 
 
 def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
@@ -284,16 +294,20 @@ def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
     adt = cfg.activation_dtype()
     blocks = tables.astype(jnp.int32)[:, 0]
     s, z = cache["s"], cache["z"]
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        x = params["embed"].astype(adt)[tokens]
     for i, lp in enumerate(params["layers"]):
-        q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg), lp,
-                                 pos.astype(jnp.int32), cfg)
-        o, s, z = power_retention.retention_step(
-            q, k, v, logg, s, z, i, blocks, eps=cfg.retention_eps,
-            state_round=cfg.state_round, impl=cfg.retention_impl)
-        x = _mlp(_mixed(x, o, lp, cfg), lp, cfg)
-    x = _norm(x, params["final_norm_scale"], cfg)
-    return _unembed(x, params, cfg), {"s": s, "z": z}, None
+        with jax.named_scope(MIXER):
+            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
+                                     lp, pos.astype(jnp.int32), cfg)
+            o, s, z = power_retention.retention_step(
+                q, k, v, logg, s, z, i, blocks, eps=cfg.retention_eps,
+                state_round=cfg.state_round, impl=cfg.retention_impl)
+            x = _mixed(x, o, lp, cfg)
+        x = _mlp(x, lp, cfg)
+    with jax.named_scope(HEAD):
+        x = _norm(x, params["final_norm_scale"], cfg)
+        return _unembed(x, params, cfg), {"s": s, "z": z}, None
 
 
 FAMILY = ServingFamily(

@@ -58,7 +58,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
-from ray_tpu.models.family import ServingFamily
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts, quant
 
@@ -367,19 +367,24 @@ def forward(params, tokens, cfg: WindowMoEConfig):
         t = seq.shape[0]
         pos = jnp.arange(t, dtype=jnp.int32)
         live = jnp.ones((t,), bool)
-        x = params["embed"].astype(adt)[seq]
+        with jax.named_scope(EMBED):
+            x = params["embed"].astype(adt)[seq]
         for lp, kind in zip(params["layers"], cfg.kinds):
-            n = _layer_norm(x, lp["norm_scale"], cfg.eps)
-            q, k, v = _qkv(n, lp, kind, pos, cfg)
-            att = da.reference_gqa_attention(
-                q[None], k[None], v[None], jnp.zeros((1,), jnp.int32),
-                _window_of(kind, cfg))[0]
-            a = lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
-            ff, _ = _experts(n, lp, cfg, live,
-                             grouped_experts.EXPERTS_GROUPED)
-            x = x + a + ff
-        return _unembed(_layer_norm(x, params["final_norm_scale"], cfg.eps),
-                        params, cfg)
+            with jax.named_scope(MIXER):
+                n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+                q, k, v = _qkv(n, lp, kind, pos, cfg)
+                att = da.reference_gqa_attention(
+                    q[None], k[None], v[None], jnp.zeros((1,), jnp.int32),
+                    _window_of(kind, cfg))[0]
+                a = lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
+            with jax.named_scope(FFN):
+                ff, _ = _experts(n, lp, cfg, live,
+                                 grouped_experts.EXPERTS_GROUPED)
+                x = x + a + ff
+        with jax.named_scope(HEAD):
+            return _unembed(
+                _layer_norm(x, params["final_norm_scale"], cfg.eps), params,
+                cfg)
 
     return jax.lax.map(one, tokens)
 
@@ -409,35 +414,39 @@ def prefill(params, tokens, cache, cfg: WindowMoEConfig, mesh=None, *,
     table = jnp.asarray(block_table, jnp.int32)
     half = table.shape[0] // 2
     tables = {"full": table[:half], "window": table[half:]}
-    offs = jnp.arange(c, dtype=jnp.int32)
-    positions = start + offs
-    valid = offs < length
-    x = params["embed"].astype(adt)[tokens[0]]
+    with jax.named_scope(EMBED):
+        offs = jnp.arange(c, dtype=jnp.int32)
+        positions = start + offs
+        valid = offs < length
+        x = params["embed"].astype(adt)[tokens[0]]
     at = {"window": 0, "full": 0}
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
-        n = _layer_norm(x, lp["norm_scale"], cfg.eps)
-        q, k, v = _qkv(n, lp, kind, positions, cfg)
-        kk, vk = POOLS[kind]
-        layer = at[kind]
-        cache[kk] = _write_chunk(cache[kk], layer, _stored(k, cfg),
-                                 tables[kind], start, length)
-        cache[vk] = _write_chunk(cache[vk], layer, _stored(v, cfg),
-                                 tables[kind], start, length)
-        with jax.named_scope(f"{kind}_attention"):
-            att = da.gqa_chunk_attention(
-                q, cache[kk], cache[vk], tables[kind], start, layer=layer,
-                window=_window_of(kind, cfg), impl=cfg.attn_impl)
-        a = lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
-        ff, counts = _experts(n, lp, cfg, valid,
-                              grouped_experts.EXPERTS_GROUPED_PREFILL)
-        expert_counts.append(counts)
-        x = x + a + ff
+        with jax.named_scope(MIXER):
+            n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+            q, k, v = _qkv(n, lp, kind, positions, cfg)
+            kk, vk = POOLS[kind]
+            layer = at[kind]
+            cache[kk] = _write_chunk(cache[kk], layer, _stored(k, cfg),
+                                     tables[kind], start, length)
+            cache[vk] = _write_chunk(cache[vk], layer, _stored(v, cfg),
+                                     tables[kind], start, length)
+            with jax.named_scope(f"{kind}_attention"):
+                att = da.gqa_chunk_attention(
+                    q, cache[kk], cache[vk], tables[kind], start, layer=layer,
+                    window=_window_of(kind, cfg), impl=cfg.attn_impl)
+            a = lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
+        with jax.named_scope(FFN):
+            ff, counts = _experts(n, lp, cfg, valid,
+                                  grouped_experts.EXPERTS_GROUPED_PREFILL)
+            expert_counts.append(counts)
+            x = x + a + ff
         at[kind] += 1
-    x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
-    last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-    return (_unembed(last, params, cfg), cache,
-            _counts(*_rows_read(positions, valid, cfg), expert_counts))
+    with jax.named_scope(HEAD):
+        x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
+        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
+        return (_unembed(last, params, cfg), cache,
+                _counts(*_rows_read(positions, valid, cfg), expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: WindowMoEConfig,
@@ -453,38 +462,42 @@ def decode(params, tokens, cache, pos, tables, cfg: WindowMoEConfig,
     tables = tables.astype(jnp.int32)
     half = tables.shape[1] // 2
     tabs = {"full": tables[:, :half], "window": tables[:, half:]}
-    live = jnp.any(tables > 0, -1)
-    page = jnp.minimum(pos // bs, half - 1)[:, None]
-    widx = {kind: jnp.where(
-        pos < half * bs,
-        jnp.take_along_axis(tab, page, 1)[:, 0] * bs + pos % bs,
-        cache[POOLS[kind][0]].shape[1] * bs)
-        for kind, tab in tabs.items()}
-    x = params["embed"].astype(adt)[tokens]
+    with jax.named_scope(EMBED):
+        live = jnp.any(tables > 0, -1)
+        page = jnp.minimum(pos // bs, half - 1)[:, None]
+        widx = {kind: jnp.where(
+            pos < half * bs,
+            jnp.take_along_axis(tab, page, 1)[:, 0] * bs + pos % bs,
+            cache[POOLS[kind][0]].shape[1] * bs)
+            for kind, tab in tabs.items()}
+        x = params["embed"].astype(adt)[tokens]
     at = {"window": 0, "full": 0}
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
-        n = _layer_norm(x, lp["norm_scale"], cfg.eps)
-        q, k, v = _qkv(n, lp, kind, pos, cfg)
-        kk, vk = POOLS[kind]
-        layer = at[kind]
-        cache[kk] = _write_rows(cache[kk], layer, _stored(k, cfg),
-                                widx[kind])
-        cache[vk] = _write_rows(cache[vk], layer, _stored(v, cfg),
-                                widx[kind])
-        with jax.named_scope(f"{kind}_attention"):
-            att = da.gqa_decode_attention(
-                q, cache[kk], cache[vk], tabs[kind], pos, layer=layer,
-                window=_window_of(kind, cfg), impl=cfg.attn_impl)
-        a = lsm._mm(att.reshape(att.shape[0], -1), lp["w_out"], adt)
-        ff, counts = _experts(n, lp, cfg, live,
-                              grouped_experts.EXPERTS_GROUPED)
-        expert_counts.append(counts)
-        x = x + a + ff
+        with jax.named_scope(MIXER):
+            n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+            q, k, v = _qkv(n, lp, kind, pos, cfg)
+            kk, vk = POOLS[kind]
+            layer = at[kind]
+            cache[kk] = _write_rows(cache[kk], layer, _stored(k, cfg),
+                                    widx[kind])
+            cache[vk] = _write_rows(cache[vk], layer, _stored(v, cfg),
+                                    widx[kind])
+            with jax.named_scope(f"{kind}_attention"):
+                att = da.gqa_decode_attention(
+                    q, cache[kk], cache[vk], tabs[kind], pos, layer=layer,
+                    window=_window_of(kind, cfg), impl=cfg.attn_impl)
+            a = lsm._mm(att.reshape(att.shape[0], -1), lp["w_out"], adt)
+        with jax.named_scope(FFN):
+            ff, counts = _experts(n, lp, cfg, live,
+                                  grouped_experts.EXPERTS_GROUPED)
+            expert_counts.append(counts)
+            x = x + a + ff
         at[kind] += 1
-    x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
-    return (_unembed(x, params, cfg), cache,
-            _counts(*_rows_read(pos, live, cfg), expert_counts))
+    with jax.named_scope(HEAD):
+        x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
+        return (_unembed(x, params, cfg), cache,
+                _counts(*_rows_read(pos, live, cfg), expert_counts))
 
 
 FAMILY = ServingFamily(

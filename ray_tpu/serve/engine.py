@@ -117,6 +117,7 @@ from dataclasses import dataclass, field
 import numpy as np
 
 from ray_tpu.exceptions import OverloadedError
+from ray_tpu.models.family import EMBED, HEAD
 from ray_tpu.util import faults as _faults
 
 logger = logging.getLogger("ray_tpu.serve")
@@ -881,23 +882,27 @@ class InferenceEngine:
             """Sample one token per row; also return the model's NATURAL
             (temperature-1) f32 log-likelihood of the sampled token —
             the per-token logprob the RL flywheel trains against."""
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            k = jax.random.fold_in(key, step)
-            safe = jnp.where(temps > 0, temps, 1.0)
-            sampled = jax.random.categorical(
-                k, logits.astype(jnp.float32) / safe[:, None]
-            ).astype(jnp.int32)
-            tok = jnp.where(temps > 0, sampled, greedy)
-            nat = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            logp = jnp.take_along_axis(nat, tok[:, None], axis=-1)[:, 0]
-            return tok, logp
+            with jax.named_scope(HEAD):
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                k = jax.random.fold_in(key, step)
+                safe = jnp.where(temps > 0, temps, 1.0)
+                sampled = jax.random.categorical(
+                    k, logits.astype(jnp.float32) / safe[:, None]
+                ).astype(jnp.int32)
+                tok = jnp.where(temps > 0, sampled, greedy)
+                nat = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                         axis=-1)
+                logp = jnp.take_along_axis(nat, tok[:, None],
+                                           axis=-1)[:, 0]
+                return tok, logp
 
         max_blocks = self.max_blocks
 
         def _prefill(params, inputs, cache, key):
             self.prefill_traces += 1
-            tokens, table, start, length, temp, step = unpack_chunk(
-                inputs, max_blocks)
+            with jax.named_scope(EMBED):
+                tokens, table, start, length, temp, step = unpack_chunk(
+                    inputs, max_blocks)
             logits, cache, counts = fam.prefill(
                 params, tokens, cache, cfg, mesh, block_table=table,
                 start=start, length=length)
@@ -910,10 +915,12 @@ class InferenceEngine:
             lie on the device; a row marked `FROM_STEP` / `FROM_CHUNK`
             takes its token there, any other the packed value."""
             self.decode_traces += 1
-            tokens, pos, temps, tables, step = unpack_rows(inputs, slots)
-            tokens = jnp.where(
-                tokens == FROM_STEP, prev,
-                jnp.where(tokens == FROM_CHUNK, chunk_tok, tokens))
+            with jax.named_scope(EMBED):
+                tokens, pos, temps, tables, step = unpack_rows(inputs,
+                                                               slots)
+                tokens = jnp.where(
+                    tokens == FROM_STEP, prev,
+                    jnp.where(tokens == FROM_CHUNK, chunk_tok, tokens))
             logits, cache, counts = fam.decode(
                 params, tokens, cache, pos, tables, cfg, mesh)
             tok, logp = _sample(logits, temps, key, step)
@@ -932,50 +939,52 @@ class InferenceEngine:
             future writes overwrite the stale K/V before any read.
             """
             self.verify_traces += 1
-            tokens, pos, temps, tables, step = unpack_rows(
-                inputs, slots, self.spec_window)
+            with jax.named_scope(EMBED):
+                tokens, pos, temps, tables, step = unpack_rows(
+                    inputs, slots, self.spec_window)
             logits, cache = fam.verify(
                 params, tokens, cache, pos, tables, cfg, mesh)
-            b, w = tokens.shape
-            drafts = tokens[:, 1:]                       # [B, W-1]
-            greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            k = jax.random.fold_in(key, step)
-            safe = jnp.where(temps > 0, temps, 1.0)
-            logp = jax.nn.log_softmax(
-                logits / safe[:, None, None], axis=-1)   # [B, W, V]
-            # Accept draft j iff it matches greedy (temp 0) or w.p.
-            # p_target(draft) (rejection sampling with the draft as a
-            # point-mass proposal — exact for ANY proposal, so padded /
-            # garbage drafts stay distribution-correct).
-            p_draft = jnp.exp(jnp.take_along_axis(
-                logp[:, :-1], drafts[..., None], axis=-1)[..., 0])
-            u = jax.random.uniform(jax.random.fold_in(k, 1),
-                                   drafts.shape)
-            match = jnp.where((temps > 0)[:, None], u < p_draft,
-                              drafts == greedy[:, :-1])
-            acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
-            accepted = jnp.sum(acc, axis=1)              # [B] in [0,W-1]
-            # Residual for the first rejected position: target dist with
-            # the rejected draft masked out. Col W-1 (the bonus token
-            # when everything is accepted) is sampled unmasked.
-            res = logp.at[jnp.arange(b)[:, None],
-                          jnp.arange(w - 1)[None, :], drafts].set(-1e30)
-            corr = jax.random.categorical(
-                jax.random.fold_in(k, 2), res, axis=-1).astype(jnp.int32)
-            corr = jnp.where((temps > 0)[:, None], corr, greedy)
-            drafts_pad = jnp.concatenate(
-                [drafts, jnp.zeros_like(drafts[:, :1])], axis=1)
-            cols = jnp.arange(w)[None, :]
-            out = jnp.where(cols < accepted[:, None], drafts_pad, corr)
-            # Natural (temperature-1) logprob of each emitted token:
-            # logits[:, j] is the next-token distribution after the
-            # prefix extended by out[:, :j], so column j's emitted token
-            # scores against column j's untempered log-softmax — same
-            # contract as the plain decode path.
-            nat = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            out_lp = jnp.take_along_axis(
-                nat, out[..., None], axis=-1)[..., 0]
-            return out, out_lp, accepted, cache
+            with jax.named_scope(HEAD):
+                b, w = tokens.shape
+                drafts = tokens[:, 1:]                       # [B, W-1]
+                greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+                k = jax.random.fold_in(key, step)
+                safe = jnp.where(temps > 0, temps, 1.0)
+                logp = jax.nn.log_softmax(
+                    logits / safe[:, None, None], axis=-1)   # [B, W, V]
+                # Accept draft j iff it matches greedy (temp 0) or w.p.
+                # p_target(draft) (rejection sampling with the draft as a
+                # point-mass proposal — exact for ANY proposal, so padded /
+                # garbage drafts stay distribution-correct).
+                p_draft = jnp.exp(jnp.take_along_axis(
+                    logp[:, :-1], drafts[..., None], axis=-1)[..., 0])
+                u = jax.random.uniform(jax.random.fold_in(k, 1),
+                                       drafts.shape)
+                match = jnp.where((temps > 0)[:, None], u < p_draft,
+                                  drafts == greedy[:, :-1])
+                acc = jnp.cumprod(match.astype(jnp.int32), axis=1)
+                accepted = jnp.sum(acc, axis=1)              # [B] in [0,W-1]
+                # Residual for the first rejected position: target dist with
+                # the rejected draft masked out. Col W-1 (the bonus token
+                # when everything is accepted) is sampled unmasked.
+                res = logp.at[jnp.arange(b)[:, None],
+                              jnp.arange(w - 1)[None, :], drafts].set(-1e30)
+                corr = jax.random.categorical(
+                    jax.random.fold_in(k, 2), res, axis=-1).astype(jnp.int32)
+                corr = jnp.where((temps > 0)[:, None], corr, greedy)
+                drafts_pad = jnp.concatenate(
+                    [drafts, jnp.zeros_like(drafts[:, :1])], axis=1)
+                cols = jnp.arange(w)[None, :]
+                out = jnp.where(cols < accepted[:, None], drafts_pad, corr)
+                # Natural (temperature-1) logprob of each emitted token:
+                # logits[:, j] is the next-token distribution after the
+                # prefix extended by out[:, :j], so column j's emitted token
+                # scores against column j's untempered log-softmax — same
+                # contract as the plain decode path.
+                nat = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+                out_lp = jnp.take_along_axis(
+                    nat, out[..., None], axis=-1)[..., 0]
+                return out, out_lp, accepted, cache
 
         # Cache donation: the [L, n_blocks, bs, H, D] pool is by far the
         # engine's biggest array; donating it lets XLA alias input to

@@ -267,8 +267,7 @@ class TrainLoop:
     def __init__(self, step_fn: Callable, *, unroll: int = 1,
                  metrics_interval: int = 10, metrics_lag: int = 2,
                  donate: bool = True, checkpointer=None,
-                 publisher: Callable | None = None,
-                 flops_per_step: float | None = None):
+                 publisher: Callable | None = None):
         self.unroll = max(1, int(unroll))
         self.metrics_interval = metrics_interval
         self.metrics_lag = metrics_lag
@@ -287,13 +286,11 @@ class TrainLoop:
         self.last_ring: MetricsRing | None = None
         # Step-time breakdown of the last run (the totals of the loop's
         # program spans, `self.phases` — host-side timers only, no device
-        # syncs beyond the ones already there), MFU/goodput derived from
-        # it, and the retrace sentinel.
+        # syncs beyond the ones already there), goodput derived from it,
+        # and the retrace sentinel.
         self.phases = _telemetry.Phases()
         self.last_breakdown: dict = {}
         self.last_step_totals: dict = {}
-        self.flops_per_step = flops_per_step
-        self.last_mfu = 0.0
         self.last_goodput = 0.0
         self.name = _telemetry.next_name("train")
         self.sentinel = _telemetry.RetraceSentinel(self.name)
@@ -402,20 +399,14 @@ class TrainLoop:
         }
         # Host goodput: fraction of wall time the host spends inside
         # device dispatch (i.e. not stalled on data, checkpoint or
-        # metrics plumbing). MFU needs the model's flop estimate.
+        # metrics plumbing).
         self.last_goodput = dispatch_s / denom
-        # MFU is a device metric: off an accelerator it stays 0.0 (not
-        # measured) instead of a CPU rate over a chip's peak.
-        if (self.flops_per_step and steps_run
-                and jax.default_backend() != "cpu"):
-            self.last_mfu = _telemetry.mfu(
-                self.flops_per_step * steps_run / denom)
         return state, out
 
     def stats(self) -> dict:
         """Telemetry-bridge stats dict (util.telemetry republishes these
         as train_* gauges at every /metrics scrape): the last run's
-        step-time breakdown plus MFU/goodput, the fused-dispatch
+        step-time breakdown plus goodput, the fused-dispatch
         compile-once accounting, and under their own names the totals of
         what its steps carried beside the loss (`step_totals`)."""
         return {
@@ -423,7 +414,6 @@ class TrainLoop:
             "dispatch_traces": self.dispatch_traces,
             "retraces_unexpected": self.sentinel.retraces_unexpected,
             "unroll": self.unroll,
-            "mfu": self.last_mfu,
             "goodput": self.last_goodput,
             **self.last_breakdown,
         }

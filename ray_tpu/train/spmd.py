@@ -20,6 +20,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec
 
+from ray_tpu.models.family import HEAD, OPTIMIZER
 from ray_tpu.parallel.sharding import (
     logical_to_spec,
     replicated,
@@ -167,12 +168,13 @@ def make_train_step(loss_fn: Callable,
             # running mean in f32 regardless of param/grad dtype: the
             # k-th increment is (x_k - mean)/k, so bf16 grads never
             # accumulate in their own (3-bit-mantissa-per-step) dtype
-            inv = 1.0 / (i + 1.0)
-            loss_mean = loss_mean + (loss.astype(jnp.float32)
-                                     - loss_mean) * inv
-            gmean = jax.tree.map(
-                lambda m, x: m + (x.astype(jnp.float32) - m) * inv,
-                gmean, g)
+            with jax.named_scope(OPTIMIZER):
+                inv = 1.0 / (i + 1.0)
+                loss_mean = loss_mean + (loss.astype(jnp.float32)
+                                         - loss_mean) * inv
+                gmean = jax.tree.map(
+                    lambda m, x: m + (x.astype(jnp.float32) - m) * inv,
+                    gmean, g)
             return (i + 1.0, loss_mean, gmean), None
 
         zeros = jax.tree.map(
@@ -180,8 +182,9 @@ def make_train_step(loss_fn: Callable,
         (_, loss, gmean), _ = jax.lax.scan(
             micro_step, (jnp.zeros(()), jnp.zeros(()), zeros),
             split_micro(batch))
-        grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
-                             gmean, params)
+        with jax.named_scope(OPTIMIZER):
+            grads = jax.tree.map(lambda g, p: g.astype(p.dtype),
+                                 gmean, params)
         return loss, grads
 
     def step(state: TrainState, batch):
@@ -189,19 +192,21 @@ def make_train_step(loss_fn: Callable,
         more = {}
         if aux_update is not None:
             loss, aux = loss
-        updates, opt_state = optimizer.update(
-            _trainable(grads, frozen), state.opt_state,
-            _trainable(state.params, frozen))
-        if frozen is None:
-            params = optax.apply_updates(state.params, updates)
-        else:
-            params = jax.tree.map(
-                lambda p, u: p if u is None else (p + u).astype(p.dtype),
-                state.params, updates, is_leaf=lambda x: x is None)
-        if aux_update is not None:
-            params, more = aux_update(params, aux)
-        gnorm = optax.global_norm(grads)
-        new_state = TrainState(params, opt_state, state.step + 1)
+        with jax.named_scope(OPTIMIZER):
+            updates, opt_state = optimizer.update(
+                _trainable(grads, frozen), state.opt_state,
+                _trainable(state.params, frozen))
+            if frozen is None:
+                params = optax.apply_updates(state.params, updates)
+            else:
+                params = jax.tree.map(
+                    lambda p, u: (p if u is None
+                                  else (p + u).astype(p.dtype)),
+                    state.params, updates, is_leaf=lambda x: x is None)
+            if aux_update is not None:
+                params, more = aux_update(params, aux)
+            gnorm = optax.global_norm(grads)
+            new_state = TrainState(params, opt_state, state.step + 1)
         return new_state, {"loss": loss, "grad_norm": gnorm,
                            "step": new_state.step, **more}
 
@@ -229,6 +234,14 @@ def softmax_xent(logits, targets):
     return jax.scipy.special.logsumexp(logits, axis=-1) - tgt
 
 
+def _mean_nll(nll, mask):
+    """The loss of per-token `nll`, over the mask's tokens where given."""
+    with jax.named_scope(HEAD):
+        if mask is None:
+            return jnp.mean(nll)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
 def gpt_loss_fn(params, batch, cfg, mesh: Mesh | None = None):
     """Cross entropy over pre-shifted inputs/targets [B, T].
 
@@ -241,16 +254,15 @@ def gpt_loss_fn(params, batch, cfg, mesh: Mesh | None = None):
     if gpt.check_loss_impl(cfg) == "fused":
         from ray_tpu.ops.fused_xent import fused_softmax_xent
         x = gpt.forward_features(params, batch["inputs"], cfg, mesh)
-        nll = fused_softmax_xent(
-            x, params["embed"].astype(cfg.activation_dtype()),
-            batch["targets"], vocab_chunk=cfg.loss_chunk, mesh=mesh)
+        with jax.named_scope(HEAD):
+            nll = fused_softmax_xent(
+                x, params["embed"].astype(cfg.activation_dtype()),
+                batch["targets"], vocab_chunk=cfg.loss_chunk, mesh=mesh)
     else:
         logits = gpt.forward(params, batch["inputs"], cfg, mesh)
-        nll = softmax_xent(logits, batch["targets"])
-    mask = batch.get("mask")
-    if mask is not None:
-        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
-    return jnp.mean(nll)
+        with jax.named_scope(HEAD):
+            nll = softmax_xent(logits, batch["targets"])
+    return _mean_nll(nll, batch.get("mask"))
 
 
 def make_gpt_trainer(cfg, mesh: Mesh, rng=None,
@@ -401,12 +413,11 @@ def latent_moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
     from ray_tpu.ops.fused_xent import fused_softmax_xent
 
     x, counts = lsm.forward_features(params, batch["inputs"], cfg, mesh)
-    nll = fused_softmax_xent(
-        x, params["head"].astype(cfg.activation_dtype()), batch["targets"],
-        mesh=mesh)
-    mask = batch.get("mask")
-    loss = (jnp.mean(nll) if mask is None
-            else jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0))
+    with jax.named_scope(HEAD):
+        nll = fused_softmax_xent(
+            x, params["head"].astype(cfg.activation_dtype()),
+            batch["targets"], mesh=mesh)
+    loss = _mean_nll(nll, batch.get("mask"))
     return (loss, counts) if with_counts else loss
 
 

@@ -819,52 +819,6 @@ def _publish_one(name: str, mname: str, key: str, num: float,
 
 
 # ---------------------------------------------------------------------------
-# MFU helpers
-# ---------------------------------------------------------------------------
-
-# Published peaks of one chip, keyed by `jax.Device.device_kind` as the
-# runtime reports it (a v5e chip says "TPU v5 lite"; "TPU v5e" is jax's
-# other name for the same part). Source: Google Cloud documentation,
-# "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s per chip. This is
-# the one table the benches and the MFU gauge read; a device that is not
-# in it is an error, never a default — add a row with its source.
-DEVICE_PEAKS = {
-    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-    "TPU v5e": {"bf16_flops": 197e12, "hbm_bytes_per_s": 819e9},
-}
-
-
-def device_peaks(device=None) -> dict:
-    """`DEVICE_PEAKS` row of `device` (default: jax.devices()[0])."""
-    if device is None:
-        import jax
-        device = jax.devices()[0]
-    kind = getattr(device, "device_kind", None)
-    if kind not in DEVICE_PEAKS:
-        raise ValueError(
-            f"no published peaks for device_kind {kind!r} in "
-            f"telemetry.DEVICE_PEAKS (known: {sorted(DEVICE_PEAKS)}); a "
-            "utilization against a guessed peak is not a measurement")
-    return DEVICE_PEAKS[kind]
-
-
-def device_peak_flops(device=None) -> float:
-    """Peak bf16 FLOPs/s of `device`; raises on an unknown kind."""
-    return device_peaks(device)["bf16_flops"]
-
-
-def mfu(flops_per_sec: float, n_devices: int | None = None,
-        device=None) -> float:
-    """Model FLOPs utilization: achieved model FLOPs/s over the
-    devices' aggregate peak."""
-    if n_devices is None:
-        import jax
-        n_devices = len(jax.devices())
-    return flops_per_sec / (device_peak_flops(device)
-                            * max(1, int(n_devices)))
-
-
-# ---------------------------------------------------------------------------
 # exports / self-test
 # ---------------------------------------------------------------------------
 

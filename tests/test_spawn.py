@@ -1,16 +1,13 @@
 """Who gets the chip: worker environments, the compile cache's place,
-the peaks table, and `chip_smoke.py` off the chip."""
+and `chip_smoke.py` off the chip."""
 
 import os
 import subprocess
 import sys
 import time
-import types
-
-import pytest
 
 from ray_tpu._private import spawn
-from ray_tpu.util import compile_cache, telemetry
+from ray_tpu.util import compile_cache
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -57,18 +54,6 @@ def test_compile_cache_defaults_to_a_fixed_path_in_the_checkout(
         assert compile_cache.enable_compile_cache() == path    # never moves
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
-
-
-def test_device_peaks_resolve_v5e_by_name_and_raise_on_unknown():
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    assert telemetry.device_peak_flops(v5e) == 197e12
-    assert telemetry.device_peaks(v5e)["hbm_bytes_per_s"] == 819e9
-    assert telemetry.mfu(197e12 * 4, n_devices=4, device=v5e) == \
-        pytest.approx(1.0)
-    for kind in ("cpu", "TPU v9", ""):
-        with pytest.raises(ValueError, match="no published peaks"):
-            telemetry.device_peak_flops(
-                types.SimpleNamespace(device_kind=kind))
 
 
 def test_unmeetable_tpu_request_fails_with_a_message():
