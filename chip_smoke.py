@@ -503,7 +503,7 @@ def retention_case(cfg_kwargs: dict, seed: int) -> dict:
         "decode_kernels": {"retention_step": cfg.n_layers},
         "prefill_kernels": {"retention_chunk": cfg.n_layers},
         "counters": ("state_resets", "retention_tokens_live",
-                     "retention_tokens_padded"),
+                     "retention_tokens_padded", "state_folds"),
         "tolerances": (RETENTION_LOGPROB_MAX_TOL,
                        RETENTION_LOGPROB_MEAN_TOL)}
 

@@ -18,13 +18,23 @@ the state form of the same numbers, the two kernels and the layout.
 
 **What the engine holds for this family**: one block a sequence, the
 state of every layer and key-value head (`s [L, blocks, Hkv, d, D]`,
-`z [L, blocks, Hkv, 1, D]`, float32), rewritten by every token. The
-family says so through `ServingFamily.state_blocks` (1, and not
-`paged`); the engine then gives a request one block whatever its length
-and keeps no prefix tree.
+`z [L, blocks, Hkv, 1, D]`, float32), the rings of the decode tokens that
+are not in it yet (`ring [L, blocks, Hkv, 3, RING, d]`: `k`, `v`, `log g`
+of up to `power_retention.RING` tokens) and how many the block's rings
+hold (`held [1, blocks]`, int32: one count a block, since all layers
+step together). A decode step reads a row's state once and writes its
+token into the rings; when they are full (every `RING`-th token of the
+sequence's decode, each row in its own turn) the step folds them into the
+state and writes it back. The family says what a request holds through
+`ServingFamily.state_blocks` (1, and not `paged`); the engine then gives
+a request one block whatever its length and keeps no prefix tree, and
+its block moves (copy, hand-off) carry all four arrays.
 Prefill resets the block on a sequence's first chunk (`start == 0`),
-leaves it untouched by a chunk bucket's padding, and decode's idle rows
-(table 0) rewrite the trash block.
+leaves it untouched by a chunk bucket's padding, reads no ring and leaves
+the block's count at 0 (a chunk runs only on a sequence that is not
+decoding, and a block that another sequence left must not hand its count
+on); decode's idle rows (table 0) move nothing, of the trash block
+either.
 
 `forward` is the whole-sequence form for tests; `prefill` and `decode`
 are what `ServingFamily` asks.
@@ -41,9 +51,11 @@ from ray_tpu.models import gpt
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import power_retention
 
-# what the prefill program counts, in the order of the int32 vector it
-# returns beside the logits (the decode program counts nothing)
-COUNTS = ("retention_tokens_live", "retention_tokens_padded", "state_resets")
+# what the programs count, in the order of the int32 vector each returns
+# beside the logits: the prefill program the first three, the decode
+# program the last (rows whose ring went into their state)
+COUNTS = ("retention_tokens_live", "retention_tokens_padded", "state_resets",
+          "state_folds")
 
 
 @dataclass(frozen=True)
@@ -133,16 +145,22 @@ def init_params(key, cfg: RetentionConfig):
 
 def init_pool(cfg: RetentionConfig, n_blocks: int, block_size: int,
               mesh=None, *, state_blocks: int | None = None):
-    """{"s", "z"}, zero-filled float32; blocks on axis 1 of both, a block
-    one sequence's state. The family holds no pages, so its one count is
-    of state blocks: `state_blocks` as the engine names it, `n_blocks`
-    for a caller that names no other; `block_size` sizes nothing."""
+    """{"s", "z", "ring"}, zero-filled float32, and {"held"}, int32
+    [1, blocks]: the entries a block's rings hold; blocks on axis 1 of
+    all, a block one sequence's state. The family holds no pages, so its
+    one count is of state blocks: `state_blocks` as the engine names it,
+    `n_blocks` for a caller that names no other; `block_size` sizes
+    nothing."""
     if mesh is not None:
         raise ValueError("this family's pool is not sharded over a mesh")
-    shape = (cfg.n_layers, state_blocks or n_blocks, cfg.n_kv_heads)
+    blocks = state_blocks or n_blocks
+    shape = (cfg.n_layers, blocks, cfg.n_kv_heads)
     return {"s": jnp.zeros(shape + (cfg.head_dim, cfg.feature_dim),
                            jnp.float32),
-            "z": jnp.zeros(shape + (1, cfg.feature_dim), jnp.float32)}
+            "z": jnp.zeros(shape + (1, cfg.feature_dim), jnp.float32),
+            "ring": jnp.zeros(shape + (3, power_retention.RING,
+                                       cfg.head_dim), jnp.float32),
+            "held": jnp.zeros((1, blocks), jnp.int32)}
 
 
 # ---------------------------------------------------------------------------
@@ -281,37 +299,51 @@ def prefill(params, tokens, cache, cfg: RetentionConfig, mesh=None, *,
     with jax.named_scope(HEAD):
         x = _norm(x, params["final_norm_scale"], cfg)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-        counts = jnp.stack([length, c - length, first.astype(jnp.int32)])
-        return _unembed(last, params, cfg), {"s": s, "z": z}, counts
+        counts = jnp.stack([length, c - length, first.astype(jnp.int32),
+                            jnp.zeros((), jnp.int32)])
+        # a chunk leaves its block's rings empty, whoever held it before
+        held = cache["held"].at[0, block].set(0)
+        return (_unembed(last, params, cfg),
+                {**cache, "s": s, "z": z, "held": held}, counts)
 
 
 def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
            mesh=None):
     """One token for every slot (`gpt.decode_step_paged`'s contract):
     tokens [B] at positions pos [B], each row's state in block
-    `tables[:, 0]`. Idle rows name the trash block and rewrite it.
-    -> (logits [B, V] f32, cache, None)."""
+    `tables[:, 0]`. A row's token goes into its block's rings, and the
+    rings into the state when they are full; idle rows name the trash
+    block and move nothing of it.
+    -> (logits [B, V] f32, cache, counts)."""
     adt = cfg.activation_dtype()
     blocks = tables.astype(jnp.int32)[:, 0]
-    s, z = cache["s"], cache["z"]
+    s, z, ring = cache["s"], cache["z"], cache["ring"]
+    held = cache["held"][0, blocks]
+    fold, after = power_retention.ring_after(blocks, held, cfg.state_round)
     with jax.named_scope(EMBED):
         x = params["embed"].astype(adt)[tokens]
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
             q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
                                      lp, pos.astype(jnp.int32), cfg)
-            o, s, z = power_retention.retention_step(
-                q, k, v, logg, s, z, i, blocks, eps=cfg.retention_eps,
-                state_round=cfg.state_round, impl=cfg.retention_impl)
+            o, s, z, ring = power_retention.retention_step(
+                q, k, v, logg, s, z, ring, i, blocks, held,
+                eps=cfg.retention_eps, state_round=cfg.state_round,
+                impl=cfg.retention_impl)
             x = _mixed(x, o, lp, cfg)
         x = _mlp(x, lp, cfg)
     with jax.named_scope(HEAD):
         x = _norm(x, params["final_norm_scale"], cfg)
-        return _unembed(x, params, cfg), {"s": s, "z": z}, None
+        counts = jnp.zeros((len(COUNTS),), jnp.int32).at[-1].set(
+            jnp.sum(fold, dtype=jnp.int32))
+        # idle rows all name block 0 and all leave its count as it was
+        held = cache["held"].at[0, blocks].set(after)
+        return (_unembed(x, params, cfg),
+                {"s": s, "z": z, "ring": ring, "held": held}, counts)
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
     copy_block=gpt.copy_block, gather_block=gpt.gather_block,
     scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
-    paged=False, state_keys=("s", "z"))
+    paged=False, state_keys=("s", "z", "ring", "held"))

@@ -712,6 +712,7 @@ def _brumby():
 
 RB, RL, RNB, RHQ, RHKV, RD, RBIG = 16, 8, 17, 40, 8, 128, 9216
 RPOOL = [((RL, RNB, RHKV, RD, RBIG), F32), ((RL, RNB, RHKV, 1, RBIG), F32)]
+RRING = [((RL, RNB, RHKV, 3, 16, RD), F32)]     # power_retention.RING
 
 
 def _retention_chunk_case(c):
@@ -724,11 +725,12 @@ def _retention_chunk_case(c):
 
 def _retention_step_case():
     from ray_tpu.ops import power_retention as pr
-    return (lambda q, k, v, g, s, z, blocks: pr.retention_step(
-        q, k, v, g, s, z, 3, blocks, eps=1e-6, impl="pallas"),
+    assert RRING[0][0][4] == pr.RING
+    return (lambda q, k, v, g, s, z, ring, blocks, held: pr.retention_step(
+        q, k, v, g, s, z, ring, 3, blocks, held, eps=1e-6, impl="pallas"),
         [((RB, RHQ, RD), BF16), ((RB, RHKV, RD), BF16),
-         ((RB, RHKV, RD), BF16), ((RB, RHKV), F32)] + RPOOL
-        + [((RB,), I32)])
+         ((RB, RHKV, RD), BF16), ((RB, RHKV), F32)] + RPOOL + RRING
+        + [((RB,), I32)] * 2)
 
 
 RETENTION_KERNELS = {
@@ -750,7 +752,10 @@ def test_retention_family_programs_compile_at_the_cells_shapes(topo, program):
     `benchmarks/configs/brumby-14b.json` as the engine jits them (the
     pool donated): one kernel a layer under its name, every sequence's
     state updated in place (no copy of the pool among the temporaries),
-    and weights, pool and temporaries fit the chip."""
+    weights, pool and temporaries fit the chip, and in the decode
+    program nothing but the kernels has the states' array for a result
+    (a row's state goes back by the kernel's own DMA, and only where
+    the row folds: no op of the program writes a whole `s`)."""
     from ray_tpu.models import retention
     config, cfg, ref = _brumby()
     described, arg = describers(topo)
@@ -774,8 +779,16 @@ def test_retention_family_programs_compile_at_the_cells_shapes(topo, program):
             params, arg((1, chunk)), pool, arg((1,)), arg(()),
             arg(())).compile()
         want = {"retention_chunk": RL}
-    names = kernel_names(compiled.as_text())
+    text = compiled.as_text()
+    names = kernel_names(text)
     assert {n: names.count(n) for n in set(names)} == want
+    if program == "decode":
+        whole = "f32[%d,%d,%d,%d,%d]" % RPOOL[0][0]
+        makes = [line.strip()[:120] for line in text.splitlines()
+                 if re.search(r" = \(?[^=]*" + re.escape(whole) + r"[^=]* "
+                              r"(?!parameter|custom-call|get-tuple-element|"
+                              r"tuple|bitcast)[a-z-]+\(", line)]
+        assert not makes, makes
     mem = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pool))

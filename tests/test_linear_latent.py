@@ -136,7 +136,7 @@ def test_one_footprint_arithmetic_for_every_family(params):
     assert {n: (f.state_blocks, f.paged, f.state_keys)
             for n, f in fams.items()} == {
         "gpt": (0, True, ()), "latent": (0, True, ()),
-        "retention": (1, False, ("s", "z")),
+        "retention": (1, False, ("s", "z", "ring", "held")),
         "hybrid": (1, True, ("state", "conv"))}
     eng = make_engine(params)
     # 3 slots x 1 state block + 3 x 96 / 16 pages; a table is the state
