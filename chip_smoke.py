@@ -26,10 +26,12 @@ block and growing latent pages in one pool, experts chosen by groups).
 Then the window-and-full family
 (`models/window_moe.py`, `--phase serve-window`: grouped key-value heads,
 pages that grow beside a ring of pages that do not, four `gqa_*` kernels),
-and last the state-space-and-latent-experts one (`models/mamba_moe.py`,
+then the state-space-and-latent-experts one (`models/mamba_moe.py`,
 `--phase serve-mamba`: a state block of Mamba-2 states and convolution
 tails beside one attention layer's pages, experts without a gate matrix
-in a latent).
+in a latent), and last the parallel-hybrid one (`models/parallel_hybrid.py`,
+`--phase serve-parallel-hybrid`: a state block and pages in every layer,
+a state head of 128 x 256 and a group of 5 query heads a key-value head).
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -489,6 +491,24 @@ MAMBA_LOGPROB_MAX_TOL = 5e-1
 MAMBA_LOGPROB_MEAN_TOL = 5e-2
 
 
+# `models/parallel_hybrid.py` at the published state head (128 x 256: a
+# head a lane tile over two tiles of rows) and attention group (5 query
+# heads a key-value head of 128 dims, pages of 128), and otherwise tiny;
+# multipliers off 1, so that each is in the program that runs
+PARALLEL_HYBRID_CFG = dict(
+    vocab_size=512, d_model=256, n_layers=3, mamba_heads=4,
+    mamba_head_dim=128, n_groups=2, state_size=256, n_heads=10,
+    n_kv_heads=2, head_dim=128, d_ff=512, max_seq_len=1024,
+    embedding_multiplier=2.0, ssm_in_multiplier=0.5,
+    ssm_multipliers=(0.7, 0.5, 0.7, 1.0, 0.7), ssm_out_multiplier=1.5,
+    attention_in_multiplier=1.0, key_multiplier=0.5,
+    attention_out_multiplier=1.5, mlp_multipliers=(0.5, 1.5),
+    lm_head_multiplier=0.5)
+# bfloat16 activations against the float32 definition, three layers
+PARALLEL_HYBRID_LOGPROB_MAX_TOL = 5e-1
+PARALLEL_HYBRID_LOGPROB_MEAN_TOL = 5e-2
+
+
 def retention_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
@@ -588,11 +608,35 @@ def mamba_case(cfg_kwargs: dict, seed: int) -> dict:
         "tolerances": (MAMBA_LOGPROB_MAX_TOL, MAMBA_LOGPROB_MEAN_TOL)}
 
 
+def parallel_hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import parallel_hybrid
+    cfg = parallel_hybrid.ParallelHybridConfig(**cfg_kwargs)
+    return {
+        "phase": "serve_parallel_hybrid", "family": parallel_hybrid,
+        "cfg": cfg,
+        "params": parallel_hybrid.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32", mamba_impl="jax",
+                                     attn_impl="jax"),
+        "engine": {"block_size": 128}, "table": 1 + 1024 // 128,
+        "decode_kernels": {"mamba2_step": cfg.n_layers,
+                           "gqa_full_decode": cfg.n_layers},
+        "prefill_kernels": {"mamba2_chunk": cfg.n_layers,
+                            "gqa_full_chunk": cfg.n_layers},
+        "counters": ("state_resets", "mamba_tokens_live",
+                     "mamba_tokens_padded", "attention_rows_read",
+                     "decode_rows_read_a_layer", "state_blocks"),
+        "tolerances": (PARALLEL_HYBRID_LOGPROB_MAX_TOL,
+                       PARALLEL_HYBRID_LOGPROB_MEAN_TOL)}
+
+
 def serve_family_phase(case: dict, *, platform: str, streams: int,
                        prompt_lens: tuple[int, int], new_tokens: int,
                        slots: int, seed: int) -> None:
     """The engine over a family that keeps more than pages that grow
-    (`retention_case`, `hybrid_case`, `mamba_case`: a state a sequence;
+    (`retention_case`, `hybrid_case`, `mamba_case`, `parallel_hybrid_case`:
+    a state a sequence;
     `window_case`: a ring of window pages), in this process: `streams` greedy
     requests over `slots` slots (so blocks are reused), chunked
     prefill in both buckets and then steps. Holds the streamed logprobs to
@@ -913,7 +957,8 @@ def main() -> int:
     ap.add_argument("--seed", type=int, default=0)
     ap.add_argument("--phase", choices=("train", "train4",
                                         "serve-retention", "serve-hybrid",
-                                        "serve-window", "serve-mamba"),
+                                        "serve-window", "serve-mamba",
+                                        "serve-parallel-hybrid"),
                     help="how a phase child is started")
     args = ap.parse_args()
 
@@ -928,7 +973,9 @@ def main() -> int:
     cases = {"serve-retention": (retention_case, RETENTION_CFG),
              "serve-hybrid": (hybrid_case, HYBRID_CFG),
              "serve-window": (window_case, WINDOW_CFG),
-             "serve-mamba": (mamba_case, MAMBA_CFG)}
+             "serve-mamba": (mamba_case, MAMBA_CFG),
+             "serve-parallel-hybrid": (parallel_hybrid_case,
+                                       PARALLEL_HYBRID_CFG)}
     if args.phase in cases:
         make, cfg_kwargs = cases[args.phase]
         case = make(cfg_kwargs, args.seed)
@@ -968,6 +1015,7 @@ def main() -> int:
             run_phase_child("serve-hybrid", args.seed)
             run_phase_child("serve-window", args.seed)
             run_phase_child("serve-mamba", args.seed)
+            run_phase_child("serve-parallel-hybrid", args.seed)
             run_phase_child("train", args.seed)
         else:
             run_phase_child("train4", args.seed)

@@ -72,9 +72,13 @@ class ServingFamily(NamedTuple):
     ..`; in a ring several columns name one page, and only the latest of
     them is true. A family reads no column that its own bound has left.
     `models/gpt.py` is (0, paged): pages alone. `models/retention.py` is
-    (1, not paged): a state alone. `models/linear_latent.py` and
-    `models/mamba_moe.py` are (1, paged): both (KDA states beside latent
-    rows; Mamba-2 states beside one attention layer's keys and values).
+    (1, not paged): a state alone. `models/linear_latent.py`,
+    `models/mamba_moe.py` and `models/parallel_hybrid.py` are (1, paged):
+    both (KDA states beside latent rows; Mamba-2 states beside one
+    attention layer's keys and values; and, new with the third, both
+    kinds in every layer: a layer's state branch and its attention
+    branch read one norm's output, so every array of the pool has a
+    layer of the model a layer).
     `models/window_moe.py` is (0, paged, bounded): full layers' pages
     that grow and window layers' pages that do not.
 

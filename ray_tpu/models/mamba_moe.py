@@ -114,7 +114,8 @@ class MambaMoEConfig:
                 or len(self.pattern) < self.first_layer + self.n_layers:
             raise ValueError(f"a layer is one of {sorted(KINDS)}, and the "
                              f"pattern names every layer that runs")
-        if self.mamba_heads % (2 * self.n_groups) \
+        if self.mamba_heads % (mamba2.tile_heads(self.mamba_head_dim)
+                               * self.n_groups) \
                 or self.n_heads % self.n_kv_heads:
             raise ValueError("a group's state heads lie in pairs, and the "
                              "query heads divide over the key-value heads")
@@ -245,13 +246,20 @@ def init_pool(cfg: MambaMoEConfig, n_blocks: int, block_size: int,
         return jnp.zeros((n_attn, n_blocks, cfg.n_kv_heads, block_size,
                           cfg.head_dim), cfg.activation_dtype())
 
+    return {**state_arrays(cfg, n_mamba, state_blocks),
+            "k": pages(), "v": pages()}
+
+
+def state_arrays(cfg, n_mamba: int, state_blocks: int) -> dict:
+    """`STATE_KEYS`' arrays for `n_mamba` state layers, zero-filled: the
+    states as `ops/mamba2.py` stores them and the convolution tails."""
+    t = mamba2.tile_heads(cfg.mamba_head_dim)
     return {
-        "state": jnp.zeros((n_mamba, state_blocks, cfg.mamba_heads // 2,
-                            cfg.state_size, 2 * cfg.mamba_head_dim),
+        "state": jnp.zeros((n_mamba, state_blocks, cfg.mamba_heads // t,
+                            cfg.state_size, t * cfg.mamba_head_dim),
                            jnp.float32),
         "conv": jnp.zeros((n_mamba, state_blocks, cfg.conv_size - 1,
                            cfg.conv_channels), jnp.float32),
-        "k": pages(), "v": pages(),
     }
 
 
@@ -259,13 +267,34 @@ def init_pool(cfg: MambaMoEConfig, n_blocks: int, block_size: int,
 # pieces of the layers
 # ---------------------------------------------------------------------------
 
-def _in_proj(n, lp, cfg):
+def _in_proj(n, lp, cfg, mup=None):
     """Normed n [N, D] -> (z [N, H P] in the activation type, xBC [N, H P
-    + 2 G N] float32 values of the activation type, dt [N, H] float32)."""
+    + 2 G N] float32 values of the activation type, dt [N, H] float32).
+    `mup`: a float32 factor a column of W_in's output, for a family whose
+    source scales z, x, B, C and dt each by a number of its own."""
     adt = cfg.activation_dtype()
-    proj = jnp.einsum("nd,df->nf", n, lp["w_in"].astype(adt),
-                      preferred_element_type=jnp.float32)
     inner, ch = cfg.inner, cfg.conv_channels
+    w = lp["w_in"].astype(adt)
+    if w.shape[1] % mamba2.LANES and n.shape[0] % mamba2.LANES == 0:
+        # a chunk's rows are whole lane tiles and the width is not (H = 32
+        # columns of dt after 9,216): the compiler then lays the product
+        # out with the positions on the lanes, the convolution's tail
+        # with it, and the whole pool of tails anew around every layer's
+        # read and write of one block's (1.02 GB a copy at a tail of 3 x
+        # 5,120: AOT for a v5e, PR 54). dt's columns in a product of
+        # their own leave z | xBC whole tiles wide. The single product
+        # below stays only so that the program of a width of whole tiles
+        # (18,560, which holds no such copy) is the one it was
+        proj = [jnp.einsum("nd,df->nf", n, part,
+                           preferred_element_type=jnp.float32)
+                for part in (w[:, :inner + ch], w[:, inner + ch:])]
+        if mup is not None:
+            proj = [proj[0] * mup[:inner + ch], proj[1] * mup[inner + ch:]]
+        return (proj[0][:, :inner].astype(adt),
+                proj[0][:, inner:].astype(adt).astype(jnp.float32), proj[1])
+    proj = jnp.einsum("nd,df->nf", n, w, preferred_element_type=jnp.float32)
+    if mup is not None:
+        proj = proj * mup
     return (proj[:, :inner].astype(adt),
             proj[:, inner:inner + ch].astype(adt).astype(jnp.float32),
             proj[:, inner + ch:])
@@ -304,6 +333,70 @@ def _mamba_out(y, x, z, lp, cfg):
     out = grouped.reshape(rows, -1) * lp["gate_norm_scale"].astype(
         jnp.float32)
     return lsm._mm(out.astype(adt), lp["w_out"], adt)
+
+
+def mamba_whole(n, lp, cfg, mup=None):
+    """The state layer of normed n [T, D] of one whole sequence by the
+    definition, through W_out: -> [T, D]."""
+    t, taps = n.shape[0], cfg.conv_size
+    z, xbc, dt = _in_proj(n, lp, cfg, mup)
+    pre = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
+    w = lp["conv_w"].astype(jnp.float32)
+    act = _conv_act(sum(w[i] * pre[i:i + t] for i in range(taps)), lp)
+    xs, b, c, step, a = _ssm_inputs(act, dt, lp, cfg)
+    y = mamba2.mamba2_recurrent(xs, step, a, b, c)[0]
+    return _mamba_out(y, xs, z, lp, cfg)
+
+
+def mamba_chunk(n, lp, cache, cfg, layer, block, first, length, mup=None):
+    """The state layer of a prompt chunk's normed n [C, D] against state
+    block `block` of state layer `layer`: `cache`'s "conv" and "state"
+    are replaced; a first chunk reads both as zeros, and the tail kept
+    is the last live positions' xBC, whatever the padding.
+    -> what W_out gives [C, D]."""
+    c, taps = n.shape[0], cfg.conv_size
+    z, xbc, dt = _in_proj(n, lp, cfg, mup)
+    tail = jnp.where(first, 0.0, cache["conv"][layer, block])
+    pre = jnp.concatenate([tail, xbc])
+    w = lp["conv_w"].astype(jnp.float32)
+    act = _conv_act(sum(w[i] * pre[i:i + c] for i in range(taps)), lp)
+    cache["conv"] = cache["conv"].at[layer, block].set(
+        jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
+    xs, b, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+    y, cache["state"] = mamba2.mamba2_chunk(
+        xs, step, a, b, cc, cache["state"], layer, block, first, length,
+        state_round=cfg.state_round, impl=cfg.mamba_impl)
+    return _mamba_out(y, xs, z, lp, cfg)
+
+
+def mamba_step(n, lp, cache, cfg, layer, blocks, mup=None):
+    """The state layer of one decode position a row, normed n [B, D], each
+    against its own state block: `cache`'s "conv" and "state" are
+    replaced. -> what W_out gives [B, D]."""
+    z, xbc, dt = _in_proj(n, lp, cfg, mup)
+    pre = jnp.concatenate([cache["conv"][layer, blocks], xbc[:, None]], 1)
+    act = _conv_act(jnp.einsum(
+        "kc,bkc->bc", lp["conv_w"].astype(jnp.float32), pre), lp)
+    cache["conv"] = cache["conv"].at[layer, blocks].set(pre[:, 1:])
+    xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
+    y, cache["state"] = mamba2.mamba2_step(
+        xs, step, a, bb, cc, cache["state"], layer, blocks,
+        state_round=cfg.state_round, impl=cfg.mamba_impl)
+    return _mamba_out(y, xs, z, lp, cfg)
+
+
+def row_index(pages, pos, pool):
+    """Where a decode step's rows go in a head-major pool `[L, n_blocks,
+    Hkv, bs, d]`: the flat position `page * bs + offset` of pos [B]
+    through each row's pages [B, columns]; past the table's reach,
+    `n_blocks * bs` (dropped by `window_moe._write_rows`)."""
+    n_blocks, bs = pool.shape[1], pool.shape[3]
+    cols = pages.shape[1]
+    page = jnp.minimum(pos // bs, cols - 1)[:, None]
+    return jnp.where(
+        pos < cols * bs,
+        jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
+        n_blocks * bs)
 
 
 def _qkv(n, lp, cfg):
@@ -389,7 +482,6 @@ def forward(params, tokens, cfg: MambaMoEConfig):
     """tokens [B, T] -> logits [B, T, V] f32, by the definition: the
     recurrence token by token, no state kept, no cache."""
     adt = cfg.activation_dtype()
-    taps = cfg.conv_size
 
     def one(seq):
         t = seq.shape[0]
@@ -400,14 +492,7 @@ def forward(params, tokens, cfg: MambaMoEConfig):
             with jax.named_scope(_part(kind)):
                 n = lsm._norm(x, lp["norm_scale"], cfg)
                 if kind == "mamba":
-                    z, xbc, dt = _in_proj(n, lp, cfg)
-                    pre = jnp.pad(xbc, ((taps - 1, 0), (0, 0)))
-                    w = lp["conv_w"].astype(jnp.float32)
-                    act = _conv_act(sum(w[i] * pre[i:i + t]
-                                        for i in range(taps)), lp)
-                    xs, b, c, step, a = _ssm_inputs(act, dt, lp, cfg)
-                    y = mamba2.mamba2_recurrent(xs, step, a, b, c)[0]
-                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                    x = x + mamba_whole(n, lp, cfg)
                 elif kind == "attention":
                     q, k, v = _qkv(n, lp, cfg)
                     att = da.reference_gqa_attention(
@@ -440,7 +525,6 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
         raise ValueError(f"prefill wants tokens [1, C], got batch "
                          f"{tokens.shape[0]}")
     adt = cfg.activation_dtype()
-    taps = cfg.conv_size
     cache = dict(cache)
     with jax.named_scope(EMBED):
         start = jnp.asarray(start, jnp.int32)
@@ -459,21 +543,8 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
             n = lsm._norm(x, lp["norm_scale"], cfg)
             if kind == "mamba":
                 with jax.named_scope("mamba_layer"):
-                    z, xbc, dt = _in_proj(n, lp, cfg)
-                    tail = jnp.where(first, 0.0, cache["conv"][n_mamba, block])
-                    pre = jnp.concatenate([tail, xbc])
-                    w = lp["conv_w"].astype(jnp.float32)
-                    act = _conv_act(sum(w[i] * pre[i:i + c]
-                                        for i in range(taps)), lp)
-                    # the last live positions' xBC, whatever the padding
-                    cache["conv"] = cache["conv"].at[n_mamba, block].set(
-                        jax.lax.dynamic_slice_in_dim(pre, length, taps - 1))
-                    xs, b, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
-                    y, cache["state"] = mamba2.mamba2_chunk(
-                        xs, step, a, b, cc, cache["state"], n_mamba, block,
-                        first, length, state_round=cfg.state_round,
-                        impl=cfg.mamba_impl)
-                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                    x = x + mamba_chunk(n, lp, cache, cfg, n_mamba, block,
+                                        first, length)
                 n_mamba += 1
             elif kind == "attention":
                 with jax.named_scope("attention_layer"):
@@ -510,19 +581,13 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
     -> (logits [B, V] f32, cache, counts)."""
     adt = cfg.activation_dtype()
     cache = dict(cache)
-    bs = cache["k"].shape[3]
     b = tokens.shape[0]
     with jax.named_scope(EMBED):
         pos = pos.astype(jnp.int32)
         tables = tables.astype(jnp.int32)
         blocks, pages = tables[:, 0], tables[:, 1:]
         live = blocks > 0
-        cols = pages.shape[1]
-        page = jnp.minimum(pos // bs, cols - 1)[:, None]
-        widx = jnp.where(
-            pos < cols * bs,
-            jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
-            cache["k"].shape[1] * bs)
+        widx = row_index(pages, pos, cache["k"])
         x = params["embed"].astype(adt)[tokens]
     n_mamba = n_attn = 0
     expert_counts = []
@@ -531,19 +596,7 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
             n = lsm._norm(x, lp["norm_scale"], cfg)
             if kind == "mamba":
                 with jax.named_scope("mamba_layer"):
-                    z, xbc, dt = _in_proj(n, lp, cfg)
-                    pre = jnp.concatenate(
-                        [cache["conv"][n_mamba, blocks], xbc[:, None]], 1)
-                    act = _conv_act(jnp.einsum(
-                        "kc,bkc->bc", lp["conv_w"].astype(jnp.float32),
-                        pre), lp)
-                    cache["conv"] = cache["conv"].at[n_mamba, blocks].set(
-                        pre[:, 1:])
-                    xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
-                    y, cache["state"] = mamba2.mamba2_step(
-                        xs, step, a, bb, cc, cache["state"], n_mamba, blocks,
-                        state_round=cfg.state_round, impl=cfg.mamba_impl)
-                    x = x + _mamba_out(y, xs, z, lp, cfg)
+                    x = x + mamba_step(n, lp, cache, cfg, n_mamba, blocks)
                 n_mamba += 1
             elif kind == "attention":
                 with jax.named_scope("attention_layer"):

@@ -78,6 +78,7 @@ VMEM, a turn of pages at a time, instead of round-tripping through HBM.
 from __future__ import annotations
 
 import functools
+import math
 from typing import NamedTuple
 
 import jax
@@ -191,6 +192,15 @@ def _query_tile(w: int, h: int) -> int:
     heads), else as many as fill `_PROGRAM_ROWS`, the rest in further
     programs of the grid's second axis."""
     return min(w, max(1, _PROGRAM_ROWS // h))
+
+
+def _gqa_query_tile(w: int, g: int) -> int:
+    """`_query_tile` for a key-value head's group of `g` query heads:
+    where the queries go to several programs, as many of them as make a
+    program's `wt * g` score rows whole sublane tiles (a group of 5: 200
+    queries, not 204; a group of 16: `_query_tile`'s 64)."""
+    wt = _query_tile(w, g)
+    return wt if wt == w else max(wt - wt % (8 // math.gcd(g, 8)), 1)
 
 
 class _DecodePlan(NamedTuple):
@@ -516,7 +526,8 @@ def _paged_call(name: str, q, k_pool, v_pool, tables, pos, layer, *,
     side, row `w * g + j`), `grid = (B, Hkv, tiles)`; `window` as
     `_paged_kernel` says."""
     b, total, lanes = q.shape[0], q.shape[-2], q.shape[-1]
-    qrows = _query_tile(queries, heads) * heads
+    qrows = (_gqa_query_tile if head_major else _query_tile)(
+        queries, heads) * heads
     quantized = ks is not None
     hbm = pl.BlockSpec(memory_space=pl.ANY)
     if head_major:
@@ -928,7 +939,9 @@ def _gqa_plan(bs: int, g: int, d: int, dtype, w: int,
     queries of each of `g` heads a program: pages a turn from `_DECODE_TURN`
     (one query a head: the turn is all DMA) or `_CHUNK_TURN` positions,
     halved until two turns of K and V, the score tiles and the running
-    state fit the default scope. None where a page is not whole tiles."""
+    state fit the default scope. None where a page or a program's `w * g`
+    score rows are not whole tiles (`gqa_attention` pads a group and
+    `_gqa_query_tile` cuts a tile so that the rows are)."""
     item = jnp.dtype(dtype).itemsize
     if d % 128 or bs % (8 * 4 // item) or (w * g) % 8:
         return None
@@ -977,8 +990,15 @@ def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
             f"{k_pool.shape}, {tables.shape}")
     hkv, bs = k_pool.shape[2:4]
     g = hq // hkv
-    wt = _query_tile(w, g)
-    plan = _gqa_plan(bs, g, d, k_pool.dtype, wt)
+    wt = _gqa_query_tile(w, g)
+    # rows a key-value head a program are whole sublane tiles: where the
+    # queries of one program are not (the decode step's one query of a
+    # group of 5), the group is padded with heads of zeros, whose rows
+    # score what the real ones fetched and are cut away
+    gp = g if (wt * g) % 8 == 0 else _up(g, 8)
+    if gp != g:
+        wt = _gqa_query_tile(w, gp)
+    plan = _gqa_plan(bs, gp, d, k_pool.dtype, wt)
     if impl == "auto":
         if plan is None:
             backend.note_fallback(name, f"block_size {bs}, {w} queries of "
@@ -998,16 +1018,18 @@ def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
     if w % wt:
         q = jnp.pad(q, ((0, 0), (0, -w % wt), (0, 0), (0, 0)))
     # a key-value head's g query heads side by side: [B, Hkv, W * g, D]
-    rows = q.reshape(b, -1, hkv, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, hkv, -1, d)
+    rows = q.reshape(b, -1, hkv, g, d)
+    if gp != g:
+        rows = jnp.pad(rows, ((0, 0),) * 3 + ((0, gp - g), (0, 0)))
+    rows = rows.transpose(0, 2, 1, 3, 4).reshape(b, hkv, -1, d)
     out = _paged_call(name, rows, k_pool, v_pool, tables.astype(jnp.int32),
                       pos.astype(jnp.int32),
                       jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
-                      block_size=bs, heads=g, queries=w, sm_scale=d ** -0.5,
+                      block_size=bs, heads=gp, queries=w, sm_scale=d ** -0.5,
                       interpret=backend.interpret(), window=window,
                       head_major=True)
-    return out.reshape(b, hkv, -1, g, d).transpose(0, 2, 1, 3, 4).reshape(
-        b, -1, hq, d)[:, :w]
+    return out.reshape(b, hkv, -1, gp, d)[:, :, :, :g].transpose(
+        0, 2, 1, 3, 4).reshape(b, -1, hq, d)[:, :w]
 
 
 def gqa_decode_attention(q, k_pool, v_pool, tables, pos, *, layer,

@@ -13,16 +13,19 @@ with `x_t [P]` the head's input and `B_t`, `C_t [N]` those of the head's
 group (head `h` reads group `h // (H / G)`). The skip `D_h x_t`, the
 gate and the grouped norm are the caller's.
 
-**The state as stored**: `[L, blocks, H / 2, N, 2 P]` float32, a block
+**The state as stored**: `[L, blocks, H / t, N, t P]` float32, a block
 one sequence's state and block 0 the engine's trash block
-(`ops/power_retention.py`'s conventions). Two heads of one group lie
-side by side on the lanes, each transposed: `pool[l, b, i, n, j * P + p]
-= S_{2 i + j}[p, n]`. At P = 64 that is one lane tile, so a token's
-`x` of a head pair is one row as the projection left it, `B` and `C` are
-columns shared by the pair, `y` comes out as a row, and every matmul of
-the chunk form is 128 wide. Both kernels take the whole pool, are told
-layer and block through scalar prefetch, and write the block in place
-(`input_output_aliases`).
+(`ops/power_retention.py`'s conventions). `t = tile_heads(P)` heads of
+one group lie side by side on the lanes, each transposed: `pool[l, b, i,
+n, j * P + p] = S_{t i + j}[p, n]`; two below a lane tile (a pair: at P =
+64 one lane tile), one where a head fills the lanes alone (P = 128). So a
+token's `x` of a tile's heads is one row as the projection left it, `B`
+and `C` are columns the tile shares, `y` comes out as a row, and every
+matmul of the chunk form is 128 wide; N counts the tile's rows (one
+lane tile square at 128, two tiles tall at 256). Both kernels take the
+whole pool, are told layer and block through scalar prefetch, and write
+the block in place (`input_output_aliases`). They have plans for P x N
+of 64 x 128 and 128 x 256 (`plan`).
 
 `mamba2_step` is decode's: one position of each of B sequences, each
 against its own block, float32 on the vector unit (a step is bound by the
@@ -37,8 +40,8 @@ themselves.
     Y = exp(c) * (C S_0)  +  (L * (C B^T)) (d x),   L[t, s] = exp(c_t - c_s), s <= t
     S_n = exp(c_n) S_0 + B^T (exp(c_n - c) * d x)
 
-A program is one head pair; `C B^T` is made once a group and kept in
-VMEM while the group's pairs follow one another. Every exponent is a
+A program is one lane tile of heads; `C B^T` is made once a group and
+kept in VMEM while the group's tiles follow one another. Every exponent is a
 difference taken forward in time, so no factor passes 1 however fast a
 head forgets. Matmul operands are bfloat16 with float32 accumulation; the
 state is read as a high and a low bfloat16 part and updated in float32.
@@ -77,6 +80,9 @@ ROWS = 8                    # sublanes of a float32 tile
 VMEM_LIMIT = 64 * 1024 * 1024
 MM_DTYPE = jnp.bfloat16     # what the chunk kernel feeds the MXU
 NEVER = -1e30               # an exponent that reads as a factor of 0
+# head widths P x state sizes N the kernels have plans for: a pair of
+# heads a lane tile over one tile of rows, one head a lane tile over two
+PLANNED = ((64, 128), (128, 256))
 _HIGHEST = jax.lax.Precision.HIGHEST
 _NT = (((1,), (1,)), ((), ()))      # [a, c] x [b, c] -> [a, b]
 _TN = (((0,), (0,)), ((), ()))      # [c, a] x [c, b] -> [a, b]
@@ -90,18 +96,29 @@ def _rounded(x, state_round: str):
     return x
 
 
+def tile_heads(p: int) -> int:
+    """Heads side by side on a row of lanes: a pair, or one where a head
+    fills the lanes alone."""
+    return 1 if p % LANES == 0 else 2
+
+
 def to_pairs(s):
-    """States by head `[..., H, P, N]` -> as stored `[..., H / 2, N, 2 P]`."""
+    """States by head `[..., H, P, N]` -> as stored `[..., H / t, N, t P]`,
+    `t = tile_heads(P)`."""
     *lead, h, p, n = s.shape
-    return jnp.moveaxis(s.reshape(*lead, h // 2, 2, p, n), -1, -3).reshape(
-        *lead, h // 2, n, 2 * p)
+    t = tile_heads(p)
+    return jnp.moveaxis(s.reshape(*lead, h // t, t, p, n), -1, -3).reshape(
+        *lead, h // t, n, t * p)
 
 
-def to_heads(s):
-    """As stored `[..., H / 2, N, 2 P]` -> by head `[..., H, P, N]`."""
-    *lead, hp, n, pp = s.shape
-    return jnp.moveaxis(s.reshape(*lead, hp, n, 2, pp // 2), -3, -1).reshape(
-        *lead, 2 * hp, pp // 2, n)
+def to_heads(s, p: int):
+    """As stored `[..., H / t, N, t P]` -> by head `[..., H, P, N]`, given
+    the head's P: the stored shape alone does not tell a pair of 64 from
+    one head of 128."""
+    *lead, ht, n, tp = s.shape
+    t = tile_heads(p)
+    return jnp.moveaxis(s.reshape(*lead, ht, n, t, tp // t), -3, -1).reshape(
+        *lead, t * ht, tp // t, n)
 
 
 def _by_head(a, heads: int):
@@ -184,10 +201,11 @@ def _chunk_plain(x, dt, a, b, c, s, *, state_round):
 def plan(heads: int, groups: int, p: int, n: int, c: int = SUB):
     """"" where the kernels have a plan for these widths (and, for the
     chunk kernel, a chunk of `c` positions), else why not."""
-    if 2 * p != LANES or n != LANES:
-        return (f"a head pair's state of {n} x {2 * p} is not one lane tile "
-                f"square ({LANES} x {LANES})")
-    if heads % groups or (heads // groups) % 2:
+    if (p, n) not in PLANNED:
+        return (f"a head's state of {p} x {n} is none of those the kernels "
+                f"have plans for ({', '.join(f'{a} x {b}' for a, b in PLANNED)}"
+                f": whole lane tiles)")
+    if heads % groups or (heads // groups) % tile_heads(p):
         return f"{heads} heads do not lie in pairs inside {groups} groups"
     if c % SUB:
         return f"a chunk of {c} positions is not whole sub-blocks of {SUB}"
@@ -202,13 +220,15 @@ def _step_kernel(blocks_ref, meta_ref, rows_ref, bc_ref, s_ref, y_ref, s_out,
                  *, pairs: int, state_round: str):
     del blocks_ref, meta_ref
     bc = bc_ref[0, 0]                                        # [ROWS, N]
-    n = bc.shape[1]
-    # B and C as columns: one square transpose a group
-    cols = jnp.concatenate(
-        [bc, jnp.zeros((n - ROWS, n), jnp.float32)], axis=0).T
+    # B and C as columns: one square transpose a lane tile of N, a group
+    cols = jnp.concatenate([
+        jnp.concatenate([bc[:, i:i + LANES],
+                         jnp.zeros((LANES - ROWS, LANES), jnp.float32)],
+                        axis=0).T
+        for i in range(0, bc.shape[1], LANES)], axis=0)
     b_col, c_col = cols[:, 0:1], cols[:, 1:2]
     for i in range(pairs):
-        xd = rows_ref[0, 0, pl.ds(i, 1), :]                  # [1, 2 P]
+        xd = rows_ref[0, 0, pl.ds(i, 1), :]                  # [1, LANES]
         decay = rows_ref[0, 0, pl.ds(pairs + i, 1), :]
         s = _rounded(s_ref[0, 0, i] * decay + b_col * xd, state_round)
         s_out[0, 0, i] = s
@@ -219,29 +239,29 @@ def _step_kernel(blocks_ref, meta_ref, rows_ref, bc_ref, s_ref, y_ref, s_out,
 def _step_pallas(x, dt, a, b, c, pool, layer, blocks, *, state_round):
     nb, h, p = x.shape
     g, n = b.shape[1:]
-    pairs = h // g // 2                 # head pairs a group
+    pairs = h // g // tile_heads(p)     # lane tiles of heads a group
     f32 = jnp.float32
     dt = dt.astype(f32)
-    xd = (x.astype(f32) * dt[..., None]).reshape(nb, g, pairs, 2 * p)
+    xd = (x.astype(f32) * dt[..., None]).reshape(nb, g, pairs, LANES)
     decay = jnp.broadcast_to(jnp.exp(dt * a.astype(f32))[..., None],
-                             (nb, h, p)).reshape(nb, g, pairs, 2 * p)
-    rows = jnp.concatenate([xd, decay], axis=2)              # [B, G, 2 pairs, 2P]
+                             (nb, h, p)).reshape(nb, g, pairs, LANES)
+    rows = jnp.concatenate([xd, decay], axis=2)              # [B, G, 2 pairs, LANES]
     bc = jnp.pad(jnp.stack([b.astype(f32), c.astype(f32)], axis=2),
                  ((0, 0), (0, 0), (0, ROWS - 2), (0, 0)))    # [B, G, ROWS, N]
 
     def state():
         return pl.BlockSpec(
-            (1, 1, pairs, n, 2 * p),
+            (1, 1, pairs, n, LANES),
             lambda i, j, blocks, meta: (meta[0], blocks[i], j, 0, 0))
 
-    def mine(rows_):
-        return pl.BlockSpec((1, 1, rows_, LANES),
+    def mine(rows_, lanes=LANES):
+        return pl.BlockSpec((1, 1, rows_, lanes),
                             lambda i, j, *_: (i, j, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(nb, g),
-        in_specs=[mine(2 * pairs), mine(ROWS), state()],
+        in_specs=[mine(2 * pairs), mine(ROWS, n), state()],
         out_specs=[mine(pairs), state()],
     )
     with jax.named_scope(MAMBA2_STEP):
@@ -249,7 +269,7 @@ def _step_pallas(x, dt, a, b, c, pool, layer, blocks, *, state_round):
             functools.partial(_step_kernel, pairs=pairs,
                               state_round=state_round),
             name=MAMBA2_STEP,
-            out_shape=[jax.ShapeDtypeStruct((nb, g, pairs, 2 * p), f32),
+            out_shape=[jax.ShapeDtypeStruct((nb, g, pairs, LANES), f32),
                        jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
             grid_spec=grid_spec,
             # operands count the two prefetched ones
@@ -268,8 +288,8 @@ def mamba2_step(x, dt, a, b, c, pool, layer, blocks, *,
     """One decode position of B sequences through one layer's recurrence.
 
     x [B, H, P]; dt [B, H] float32 (> 0); a [H] float32 (< 0); b, c
-    [B, G, N]; pool [L, blocks, H / 2, N, 2 P] float32; blocks [B] int32:
-    each row's state (idle rows: 0, the trash block).
+    [B, G, N]; pool [L, blocks, H / t, N, t P] float32 (`to_pairs`);
+    blocks [B] int32: each row's state (idle rows: 0, the trash block).
     -> (y [B, H, P] float32, pool)."""
     h, p = x.shape[1:]
     if resolve_impl(impl) == "pallas":
@@ -278,7 +298,8 @@ def mamba2_step(x, dt, a, b, c, pool, layer, blocks, *,
             return _step_pallas(x, dt, a, b, c, pool, layer, blocks,
                                 state_round=state_round)
         backend.note_fallback(MAMBA2_STEP, why)
-    y, s = _step_plain(x, dt, b, c, a, to_heads(pool[layer, blocks]),
+    y, s = _step_plain(x, dt, b, c, a,
+                       to_heads(pool[layer, blocks], p),
                        state_round=state_round)
     return y, pool.at[layer, blocks].set(to_pairs(s))
 
@@ -290,7 +311,8 @@ def mamba2_step(x, dt, a, b, c, pool, layer, blocks, *,
 def _chunk_kernel(meta_ref, b_ref, c_ref, xd_ref, cum_ref, row_ref, s_ref,
                   y_ref, s_out, cb, *, subs: int, state_round: str):
     f32 = jnp.float32
-    half = LANES // 2
+    heads = row_ref.shape[1]            # of this program's lane tile
+    half = LANES // heads
 
     @pl.when(pl.program_id(1) == 0)
     def _scores():                      # C B^T of the group's sub-blocks
@@ -305,7 +327,7 @@ def _chunk_kernel(meta_ref, b_ref, c_ref, xd_ref, cum_ref, row_ref, s_ref,
     lane = jax.lax.broadcasted_iota(jnp.int32, (SUB, LANES), 1)
     for j in range(subs):
         rows = slice(j * SUB, (j + 1) * SUB)
-        cum = cum_ref[0, rows, :]                            # [SUB, 2 P]
+        cum = cum_ref[0, rows, :]                            # [SUB, LANES]
         total = cum[SUB - 1:SUB, :]
         xd = xd_ref[0, rows, :].astype(f32)
         high = s.astype(MM_DTYPE)
@@ -313,14 +335,14 @@ def _chunk_kernel(meta_ref, b_ref, c_ref, xd_ref, cum_ref, row_ref, s_ref,
         cj = c_ref[0, rows, :]
         y = jnp.exp(cum) * (jnp.dot(cj, high, preferred_element_type=f32)
                             + jnp.dot(cj, low, preferred_element_type=f32))
-        for head in range(2):
+        for head in range(heads):
             col = cum[:, head * half:head * half + 1]        # [SUB, 1]
             row = row_ref[0, pl.ds(head, 1), rows]           # [1, SUB]
             lower = jnp.exp(jnp.where(t_idx >= s_idx, col - row, NEVER))
             mine = (lane >= half) if head else (lane < half)
             y += jnp.dot((lower * cb[j]).astype(MM_DTYPE),
-                         jnp.where(mine, xd, 0.0).astype(MM_DTYPE),
-                         preferred_element_type=f32)
+                         (xd if heads == 1 else jnp.where(mine, xd, 0.0)
+                          ).astype(MM_DTYPE), preferred_element_type=f32)
         y_ref[0, rows, :] = y
         s = jnp.exp(total) * s + jax.lax.dot_general(
             b_ref[0, rows, :], (xd * jnp.exp(total - cum)).astype(MM_DTYPE),
@@ -331,17 +353,18 @@ def _chunk_kernel(meta_ref, b_ref, c_ref, xd_ref, cum_ref, row_ref, s_ref,
 def _chunk_pallas(x, dt, a, b, c, pool, layer, block, first, *, state_round):
     n_c, h, p = x.shape
     g, n = b.shape[1:]
-    pairs = h // g // 2
+    t = tile_heads(p)
+    pairs = h // g // t                 # lane tiles of heads a group
     subs = n_c // SUB
     mm, f32 = MM_DTYPE, jnp.float32
 
-    def by_pair(v):                     # [C, H, P] -> [H / 2, C, 2 P]
-        return v.reshape(n_c, h // 2, 2 * p).swapaxes(0, 1)
+    def by_pair(v):                     # [C, H, P] -> [H / t, C, LANES]
+        return v.reshape(n_c, h // t, LANES).swapaxes(0, 1)
 
     cum = jnp.cumsum((dt * a).reshape(subs, SUB, h), axis=1).reshape(n_c, h)
     xd = by_pair(x.astype(f32) * dt[..., None]).astype(mm)
     cum_lanes = by_pair(jnp.broadcast_to(cum[..., None], (n_c, h, p)))
-    cum_rows = cum.T.reshape(h // 2, 2, n_c)
+    cum_rows = cum.T.reshape(h // t, t, n_c)
     meta = jnp.stack([jnp.asarray(layer, jnp.int32),
                       jnp.asarray(block, jnp.int32),
                       jnp.asarray(first, jnp.int32)])
@@ -357,15 +380,15 @@ def _chunk_pallas(x, dt, a, b, c, pool, layer, block, first, *, state_round):
 
     def state():
         return pl.BlockSpec(
-            (1, 1, 1, n, 2 * p),
+            (1, 1, 1, n, LANES),
             lambda i, j, meta: (meta[0], meta[1], i * pairs + j, 0, 0))
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(g, pairs),
-        in_specs=[group(n_c, n), group(n_c, n), pair(n_c, 2 * p),
-                  pair(n_c, 2 * p), pair(2, n_c), state()],
-        out_specs=[pair(n_c, 2 * p), state()],
+        in_specs=[group(n_c, n), group(n_c, n), pair(n_c, LANES),
+                  pair(n_c, LANES), pair(t, n_c), state()],
+        out_specs=[pair(n_c, LANES), state()],
         scratch_shapes=[pltpu.VMEM((subs, SUB, SUB), f32)],
     )
     with jax.named_scope(MAMBA2_CHUNK):
@@ -373,7 +396,7 @@ def _chunk_pallas(x, dt, a, b, c, pool, layer, block, first, *, state_round):
             functools.partial(_chunk_kernel, subs=subs,
                               state_round=state_round),
             name=MAMBA2_CHUNK,
-            out_shape=[jax.ShapeDtypeStruct((h // 2, n_c, 2 * p), f32),
+            out_shape=[jax.ShapeDtypeStruct((h // t, n_c, LANES), f32),
                        jax.ShapeDtypeStruct(pool.shape, pool.dtype)],
             grid_spec=grid_spec,
             # operands count the prefetched one
@@ -392,8 +415,8 @@ def mamba2_chunk(x, dt, a, b, c, pool, layer, block, first, length, *,
     """A prefill chunk of one sequence through one layer's recurrence.
 
     x [C, H, P]; dt [C, H] float32 (> 0); a [H] float32 (< 0); b, c
-    [C, G, N]; pool [L, blocks, H / 2, N, 2 P] float32; layer, block:
-    which state; first: the sequence's first chunk (the block is read as
+    [C, G, N]; pool [L, blocks, H / t, N, t P] float32 (`to_pairs`);
+    layer, block: which state; first: the sequence's first chunk (the block is read as
     zeros); length: the chunk's live positions.
     -> (y [C, H, P] float32, pool)."""
     n_c, h, p = x.shape
@@ -414,6 +437,6 @@ def mamba2_chunk(x, dt, a, b, c, pool, layer, block, first, length, *,
                        for v in (x, dt, b, c))
     y, s = _chunk_plain(
         x, dt, a, b, c,
-        jnp.where(first, 0.0, to_heads(pool[layer, block])),
+        jnp.where(first, 0.0, to_heads(pool[layer, block], p)),
         state_round=state_round)
     return y[:n_c], pool.at[layer, block].set(to_pairs(s))

@@ -1164,6 +1164,151 @@ def test_mamba_family_programs_compile_at_the_cells_shapes(topo, program):
     print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
 
 
+# ---------------------------------------------------------------------------
+# the parallel-hybrid family (`benchmarks/configs/falcon-h1-34b.json`): a
+# state of 128 x 256 a head, a group of 5 query heads a key-value head
+# ---------------------------------------------------------------------------
+
+def _falcon():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import parallel_hybrid as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "falcon-h1-34b.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+FH, FG, FP, FN, FL, FHQ, FHKV, FB = 32, 2, 128, 256, 6, 20, 4, 64
+FSTATE = [((FL, FB + 1, FH, FN, FP), jnp.float32)]
+FPAGES = [((FL, 1900, FHKV, 128, 128), BF16)] * 2
+
+
+def _wide_chunk_case(c):
+    from ray_tpu.ops import mamba2
+    return (lambda x, dt, a, b, cc, s, block, first, n: mamba2.mamba2_chunk(
+        x, dt, a, b, cc, s, 3, block, first, n, impl="pallas"),
+        [((c, FH, FP), BF16), ((c, FH), jnp.float32), ((FH,), jnp.float32)]
+        + [((c, FG, FN), BF16)] * 2 + FSTATE + [((), I32)] * 3)
+
+
+def _wide_step_case():
+    from ray_tpu.ops import mamba2
+    return (lambda x, dt, a, b, cc, s, blocks: mamba2.mamba2_step(
+        x, dt, a, b, cc, s, 3, blocks, impl="pallas"),
+        [((FB, FH, FP), BF16), ((FB, FH), jnp.float32),
+         ((FH,), jnp.float32)] + [((FB, FG, FN), BF16)] * 2 + FSTATE
+        + [((FB,), I32)])
+
+
+def _five_decode_case():
+    return (lambda q, k, v, t, pos: da.gqa_decode_attention(
+        q, k, v, t, pos, layer=3, impl="pallas"),
+        [((FB, FHQ, 128), BF16)] + FPAGES + [((FB, 128), I32),
+                                              ((FB,), I32)])
+
+
+def _five_chunk_case(c):
+    return (lambda q, k, v, t, start: da.gqa_chunk_attention(
+        q, k, v, t, start, layer=3, impl="pallas"),
+        [((c, FHQ, 128), BF16)] + FPAGES + [((128,), I32), ((), I32)])
+
+
+PARALLEL_KERNELS = {
+    "mamba2_chunk_512": (_wide_chunk_case(512), "mamba2_chunk"),
+    "mamba2_chunk_128": (_wide_chunk_case(128), "mamba2_chunk"),
+    "mamba2_step": (_wide_step_case(), "mamba2_step"),
+    "gqa_full_decode": (_five_decode_case(), "gqa_full_decode"),
+    "gqa_full_chunk_512": (_five_chunk_case(512), "gqa_full_chunk"),
+    "gqa_full_chunk_128": (_five_chunk_case(128), "gqa_full_chunk"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARALLEL_KERNELS))
+def test_parallel_hybrid_kernels_compile_under_their_names(topo, case):
+    """The recurrence's kernels at a head of 128 x 256 (a head a lane
+    tile, two tiles of rows) and the grouped-head kernels at a group of
+    5 (padded to a sublane tile in decode, a query tile of 200 in a
+    chunk): one kernel a call, under its own name, the pool in place."""
+    (fn, args), name = PARALLEL_KERNELS[case]
+    compiled = compiled_for(topo, fn, *args)
+    assert kernel_names(compiled.as_text()) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_parallel_hybrid_programs_compile_at_the_cells_shapes(topo, program):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/falcon-h1-34b.json` as the engine jits them (the
+    pool donated): both kernels of every layer under their names; states,
+    tails and pages are updated in place; weights, pool and temporaries
+    fit the chip."""
+    from ray_tpu.models import parallel_hybrid
+    config, cfg, ref = _falcon()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    slots, cols = serve["slots"], serve["max_len"] // kw["block_size"]
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = described(jax.eval_shape(lambda: parallel_hybrid.init_pool(
+        cfg, kw["cache_blocks"] + 1, kw["block_size"],
+        state_blocks=slots + 1)))
+    if program == "decode":
+        compiled = jax.jit(
+            lambda p, cache, tok, pos, tab: parallel_hybrid.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((slots,)), arg((slots,)),
+            arg((slots, 1 + cols))).compile()
+        want = {"mamba2_step": FL, "gqa_full_decode": FL}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: parallel_hybrid.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((1 + cols,)), arg(()),
+            arg(())).compile()
+        want = {"mamba2_chunk": FL, "gqa_full_chunk": FL}
+    names = kernel_names(compiled.as_text())
+    assert {n: names.count(n) for n in set(names)} == want
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 1e9                 # and never copied
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+def test_parallel_hybrid_reference_fits_beside_the_pool(topo):
+    """The cell's comparison runs `refs/parallel_hybrid.py` in the
+    replica, beside the weights and the whole pool, on a sequence padded
+    to `max_len`: what it holds at once (the float32 residual, 336 MB,
+    and a block's work) has to fit in what 64 slots and the pages leave
+    of the chip."""
+    from ray_tpu.models import parallel_hybrid
+    config, cfg, ref = _falcon()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = jax.eval_shape(lambda: parallel_hybrid.init_pool(
+        cfg, kw["cache_blocks"] + 1, kw["block_size"],
+        state_blocks=serve["slots"] + 1))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda p, s: ref.token_logprobs(p, s, config)).lower(
+            params, arg((1, serve["max_len"]))).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.temp_size_in_bytes < 0.6e9
+    assert (mem.argument_size_in_bytes + pool_bytes
+            + mem.temp_size_in_bytes) < 15.75e9
+
+
 # sha256 (first 16 hex digits) of the jaxpr of `experts_grouped` at the
 # four shapes the benchmark ran it at before it took a second form of an
 # expert (tokens, model width, expert width, held experts, experts a

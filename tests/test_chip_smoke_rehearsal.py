@@ -88,6 +88,19 @@ def test_serve_mamba_phase_tiny_on_cpu():
     assert '"phase": "serve_mamba"' in out
 
 
+def test_serve_parallel_hybrid_phase_tiny_on_cpu():
+    """The seventh family's part: an engine over
+    `models/parallel_hybrid.py` (a state block and pages in every layer a
+    request, a group of 5 query heads) in the phase's own process,
+    float32 on the CPU (the plain paths), held to the definition."""
+    out = run("cs.serve_family_phase(cs.parallel_hybrid_case(dict("
+              "cs.PARALLEL_HYBRID_CFG, mamba_head_dim=16, state_size=32, "
+              "head_dim=32, dtype='float32'), 0), platform='cpu', "
+              "streams=5, prompt_lens=(100, 300), new_tokens=6, slots=3, "
+              "seed=0)")
+    assert '"phase": "serve_parallel_hybrid"' in out
+
+
 def test_train_phase_tiny_on_cpu():
     out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
               "steps=12, seed=0)")

@@ -1,9 +1,10 @@
 """The paged kernel body with grouped key-value heads and a window
 (`ops/decode_attention.py`): the decode form and the chunk form against
 their plain `jax.numpy` references, over query heads a key-value head in
-{1, 16}, window on and off, and tables whose live pages do not start at
-column 0 (a window layer's ring: the columns the window has left name
-pages that hold later positions, or the trash page)."""
+{1, 5, 16} (5: a group that is no whole sublane tile), window on and
+off, and tables whose live pages do not start at column 0 (a window
+layer's ring: the columns the window has left name pages that hold later
+positions, or the trash page)."""
 
 import jax
 import jax.numpy as jnp
@@ -59,9 +60,13 @@ def _dense(q, k_pool, v_pool, tables, pos, window, layer):
     return out
 
 
+# a group of 5 without a window: no configuration pairs the two
+GROUPS = pytest.mark.parametrize(
+    "g,window", [(1, None), (1, 20), (5, None), (16, None), (16, 20)])
+
+
 @pytest.mark.parametrize("impl", ["jax", "pallas"])
-@pytest.mark.parametrize("window", [None, 20])
-@pytest.mark.parametrize("g", [1, 16])
+@GROUPS
 def test_decode_form_matches_the_definition(g, window, impl):
     rng = np.random.default_rng(3)
     pos = np.array([0, 5, 37, 75, 130], np.int32)
@@ -79,8 +84,7 @@ def test_decode_form_matches_the_definition(g, window, impl):
 
 
 @pytest.mark.parametrize("impl", ["jax", "pallas"])
-@pytest.mark.parametrize("window", [None, 20])
-@pytest.mark.parametrize("g", [1, 16])
+@GROUPS
 @pytest.mark.parametrize("start,c", [(0, 16), (13, 16), (64, 8), (91, 16)])
 def test_chunk_form_matches_the_definition(start, c, g, window, impl):
     """A chunk's queries each keep their own window: the first pages are
@@ -98,6 +102,28 @@ def test_chunk_form_matches_the_definition(start, c, g, window, impl):
     want = _dense(q[None], k_pool, v_pool, table[None],
                   np.array([start]), window, 0)[0]
     np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("start", [0, 37])
+def test_a_long_chunk_of_a_group_of_five_goes_in_whole_row_tiles(start):
+    """More queries than one program scores (1,024 rows / 5 heads): the
+    tile is cut to 200 queries so that its rows are whole sublane tiles,
+    and the chunk's 208 go to two programs."""
+    assert da._gqa_query_tile(208, 5) == 200
+    assert da._gqa_query_tile(512, 16) == da._query_tile(512, 16) == 64
+    assert da._gqa_query_tile(1, 5) == 1
+    c, g = 208, 5
+    rng = np.random.default_rng(7)
+    mb = (start + c) // BS + 1
+    k_pool, v_pool = _pools(jax.random.key(12), layers=1)
+    table = _ring_tables(rng, [start + c - 1], None, mb, mb)[0]
+    q = jax.random.normal(jax.random.key(13), (c, HKV * g, D))
+    got = da.gqa_chunk_attention(q, k_pool, v_pool, table, jnp.int32(start),
+                                 layer=0, impl="pallas")
+    want = da.reference_gqa_paged_attention(
+        q[None], k_pool[0], v_pool[0], table[None], jnp.array([start]))[0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=2e-5,
+                               rtol=2e-5)
 
 
 def test_a_window_layer_never_reads_a_page_the_window_has_left():
