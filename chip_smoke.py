@@ -513,6 +513,7 @@ def retention_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
     from ray_tpu.models import retention
+    from ray_tpu.ops import power_retention
     cfg = retention.RetentionConfig(**cfg_kwargs)
     return {
         "phase": "serve_retention", "family": retention, "cfg": cfg,
@@ -524,6 +525,7 @@ def retention_case(cfg_kwargs: dict, seed: int) -> dict:
         "prefill_kernels": {"retention_chunk": cfg.n_layers},
         "counters": ("state_resets", "retention_tokens_live",
                      "retention_tokens_padded", "state_folds"),
+        "ring": power_retention.RING,
         "tolerances": (RETENTION_LOGPROB_MAX_TOL,
                        RETENTION_LOGPROB_MEAN_TOL)}
 
@@ -586,6 +588,7 @@ def mamba_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
     from ray_tpu.models import mamba_moe
+    from ray_tpu.ops import mamba2
     cfg = mamba_moe.MambaMoEConfig(**cfg_kwargs)
     n = {kind: cfg.kinds.count(kind)
          for kind in ("mamba", "attention", "experts")}
@@ -604,7 +607,9 @@ def mamba_case(cfg_kwargs: dict, seed: int) -> dict:
         "counters": ("state_resets", "mamba_tokens_live",
                      "mamba_tokens_padded", "attention_rows_read",
                      "expert_tokens_here", "expert_tokens_routed",
-                     "expert_load_max_over_mean", "state_blocks"),
+                     "expert_load_max_over_mean", "state_blocks",
+                     "state_folds"),
+        "ring": mamba2.RING,
         "tolerances": (MAMBA_LOGPROB_MAX_TOL, MAMBA_LOGPROB_MEAN_TOL)}
 
 
@@ -612,6 +617,7 @@ def parallel_hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
     from ray_tpu.models import parallel_hybrid
+    from ray_tpu.ops import mamba2
     cfg = parallel_hybrid.ParallelHybridConfig(**cfg_kwargs)
     return {
         "phase": "serve_parallel_hybrid", "family": parallel_hybrid,
@@ -626,7 +632,9 @@ def parallel_hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
                             "gqa_full_chunk": cfg.n_layers},
         "counters": ("state_resets", "mamba_tokens_live",
                      "mamba_tokens_padded", "attention_rows_read",
-                     "decode_rows_read_a_layer", "state_blocks"),
+                     "decode_rows_read_a_layer", "state_blocks",
+                     "state_folds"),
+        "ring": mamba2.RING,
         "tolerances": (PARALLEL_HYBRID_LOGPROB_MAX_TOL,
                        PARALLEL_HYBRID_LOGPROB_MEAN_TOL)}
 
@@ -716,6 +724,13 @@ def serve_family_phase(case: dict, *, platform: str, streams: int,
     if "state_resets" in case["counters"]:
         check(stats["state_resets"] == streams,
               f"{stats['state_resets']} first chunks for {streams} requests")
+    if "state_folds" in case["counters"]:
+        # a stream's first token is its prefill's; the steps after it
+        # fill its ring, which goes into its state every `ring` of them
+        folds = streams * ((new_tokens - 1) // case["ring"])
+        check(stats["state_folds"] == folds,
+              f"{stats['state_folds']} rings folded into their states, "
+              f"{folds} wanted of {streams} streams of {new_tokens} tokens")
     if "window_rows_read" in case["counters"]:
         n_window = case["cfg"].kinds.count("window")
         check(0 < stats["window_rows_read"]

@@ -36,13 +36,19 @@ norms; nothing but the pool's two kinds would be shared.
 block (`ServingFamily.state_blocks` 1 and `paged`), as
 `models/linear_latent.py`. Column 0 of its table names a state block:
 `"state" [L_m, blocks, H / 2, N, 2 P]` float32 (`ops/mamba2.py`'s
-layout), rewritten by every token, and `"conv" [L_m, blocks, K - 1, H P +
-2 G N]`, the last pre-convolution `xBC` (float32 bytes of activation
-values). The columns after it name pages of `"k"`, `"v"` `[L_a, pages,
-Hkv, block_size, d]`, head-major as `models/window_moe.py`'s. Prefill
-resets the state block on a sequence's first chunk (`start == 0`), a
-chunk bucket's padding leaves state, tail and pages bit for bit, and
-decode's idle rows (table all 0) rewrite the trash blocks of both kinds.
+layout), `"conv" [L_m, blocks, K - 1, H P + 2 G N]`, the last
+pre-convolution `xBC` (float32 bytes of activation values), `"ring"`, the
+decode tokens that are not in the state yet (`mamba2.ring_array`: read
+by every token, folded into the state when it is full) and
+`"held" [1, blocks]` int32, the entries a block's rings hold: one count a
+block, since a sequence's state layers advance together, kept on the
+device because decode steps are chained. The columns after it name pages
+of `"k"`, `"v"` `[L_a, pages, Hkv, block_size, d]`, head-major as
+`models/window_moe.py`'s. Prefill resets the state block on a sequence's
+first chunk (`start == 0`) and leaves its rings empty, a chunk bucket's
+padding leaves state, tail and pages bit for bit, and decode's idle rows
+(table all 0) rewrite the trash blocks' tails and pages and move nothing
+of the trash state or its rings.
 
 Parameters: the tree `benchmarks/refs/mamba_moe.py` documents.
 `forward` is the whole-sequence form for tests; `prefill` and `decode`
@@ -66,9 +72,10 @@ from ray_tpu.ops import grouped_experts, mamba2
 # what the prefill and decode programs count, in the order of the int32
 # vector they return beside the logits; the held experts' loads follow
 COUNTS = ("mamba_tokens_live", "mamba_tokens_padded", "state_resets",
-          "attention_rows_read", "expert_tokens_here",
+          "attention_rows_read", "state_folds", "expert_tokens_here",
           "expert_tokens_routed")
-STATE_KEYS = ("state", "conv")      # the pool's arrays of state blocks
+# the pool's arrays of state blocks
+STATE_KEYS = ("state", "conv", "ring", "held")
 KINDS = {"M": "mamba", "*": "attention", "E": "experts"}
 EMBED_INIT = 1.0
 
@@ -252,7 +259,8 @@ def init_pool(cfg: MambaMoEConfig, n_blocks: int, block_size: int,
 
 def state_arrays(cfg, n_mamba: int, state_blocks: int) -> dict:
     """`STATE_KEYS`' arrays for `n_mamba` state layers, zero-filled: the
-    states as `ops/mamba2.py` stores them and the convolution tails."""
+    states as `ops/mamba2.py` stores them, the convolution tails, the
+    rings beside the states and how many entries a block's rings hold."""
     t = mamba2.tile_heads(cfg.mamba_head_dim)
     return {
         "state": jnp.zeros((n_mamba, state_blocks, cfg.mamba_heads // t,
@@ -260,6 +268,10 @@ def state_arrays(cfg, n_mamba: int, state_blocks: int) -> dict:
                            jnp.float32),
         "conv": jnp.zeros((n_mamba, state_blocks, cfg.conv_size - 1,
                            cfg.conv_channels), jnp.float32),
+        "ring": mamba2.ring_array(n_mamba, state_blocks, cfg.mamba_heads,
+                                  cfg.n_groups, cfg.mamba_head_dim,
+                                  cfg.state_size),
+        "held": jnp.zeros((1, state_blocks), jnp.int32),
     }
 
 
@@ -369,19 +381,41 @@ def mamba_chunk(n, lp, cache, cfg, layer, block, first, length, mup=None):
     return _mamba_out(y, xs, z, lp, cfg)
 
 
-def mamba_step(n, lp, cache, cfg, layer, blocks, mup=None):
+def rings_emptied(cache, block):
+    """`cache`'s "held" after a prefill chunk: the chunk leaves its
+    block's rings empty, whoever held the block before. (A chunk after
+    decode steps of the same sequence does not occur, so nothing waits in
+    them: a preempted stream prefills again from its first token.)"""
+    return cache["held"].at[0, block].set(0)
+
+
+def rings_stepped(cache, blocks, cfg):
+    """What a decode step does to its rows' rings (`mamba2.ring_after`):
+    -> (held [B] before the step, for every state layer's `mamba_step`;
+    `cache`'s "held" after it, written once, after the last state layer:
+    idle rows all name block 0 and all leave its count as it was; how
+    many rows fold)."""
+    held = cache["held"][0, blocks]
+    fold, after = mamba2.ring_after(blocks, held, cfg.state_round)
+    return (held, cache["held"].at[0, blocks].set(after),
+            jnp.sum(fold, dtype=jnp.int32))
+
+
+def mamba_step(n, lp, cache, cfg, layer, blocks, held, mup=None):
     """The state layer of one decode position a row, normed n [B, D], each
-    against its own state block: `cache`'s "conv" and "state" are
-    replaced. -> what W_out gives [B, D]."""
+    against its own state block, whose rings hold `held` [B] entries:
+    `cache`'s "conv", "state" and "ring" are replaced (a live row's token
+    goes into its ring, and the ring into the state when it is full).
+    -> what W_out gives [B, D]."""
     z, xbc, dt = _in_proj(n, lp, cfg, mup)
     pre = jnp.concatenate([cache["conv"][layer, blocks], xbc[:, None]], 1)
     act = _conv_act(jnp.einsum(
         "kc,bkc->bc", lp["conv_w"].astype(jnp.float32), pre), lp)
     cache["conv"] = cache["conv"].at[layer, blocks].set(pre[:, 1:])
     xs, bb, cc, step, a = _ssm_inputs(act, dt, lp, cfg)
-    y, cache["state"] = mamba2.mamba2_step(
-        xs, step, a, bb, cc, cache["state"], layer, blocks,
-        state_round=cfg.state_round, impl=cfg.mamba_impl)
+    y, cache["state"], cache["ring"] = mamba2.mamba2_step(
+        xs, step, a, bb, cc, cache["state"], cache["ring"], layer, blocks,
+        held, state_round=cfg.state_round, impl=cfg.mamba_impl)
     return _mamba_out(y, xs, z, lp, cfg)
 
 
@@ -449,7 +483,7 @@ def _experts(n, lp, cfg, live, kernel):
 
 
 def _counts(cfg, head, expert_counts):
-    """`COUNTS`' first four, then the experts' two and their loads."""
+    """`COUNTS`' first five, then the experts' two and their loads."""
     experts = sum(expert_counts) if expert_counts else jnp.zeros(
         (2 + cfg.held_count,), jnp.int32)
     return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
@@ -567,9 +601,10 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
         x = lsm._norm(x, params["final_norm_scale"], cfg)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
+        cache["held"] = rings_emptied(cache, block)
         return (_unembed(last, params, cfg), cache,
                 _counts(cfg, [length * n_mamba, (c - length) * n_mamba, first,
-                              rows], expert_counts))
+                              rows, jnp.int32(0)], expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
@@ -577,7 +612,7 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
     """One token for every slot (`gpt.decode_step_paged`'s contract):
     tokens [B] at positions pos [B]; `tables[:, 0]` each row's state
     block, the rest its pages. Idle rows name the trash blocks of both
-    kinds, rewrite them and count nothing.
+    kinds, rewrite their tails and pages and count nothing.
     -> (logits [B, V] f32, cache, counts)."""
     adt = cfg.activation_dtype()
     cache = dict(cache)
@@ -587,6 +622,7 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
         tables = tables.astype(jnp.int32)
         blocks, pages = tables[:, 0], tables[:, 1:]
         live = blocks > 0
+        held, held_after, folds = rings_stepped(cache, blocks, cfg)
         widx = row_index(pages, pos, cache["k"])
         x = params["embed"].astype(adt)[tokens]
     n_mamba = n_attn = 0
@@ -596,7 +632,8 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
             n = lsm._norm(x, lp["norm_scale"], cfg)
             if kind == "mamba":
                 with jax.named_scope("mamba_layer"):
-                    x = x + mamba_step(n, lp, cache, cfg, n_mamba, blocks)
+                    x = x + mamba_step(n, lp, cache, cfg, n_mamba, blocks,
+                                       held)
                 n_mamba += 1
             elif kind == "attention":
                 with jax.named_scope("attention_layer"):
@@ -619,9 +656,10 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
         x = lsm._norm(x, params["final_norm_scale"], cfg)
         n_live = jnp.sum(live, dtype=jnp.int32)
         rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
+        cache["held"] = held_after
         return (_unembed(x, params, cfg), cache,
                 _counts(cfg, [n_live * n_mamba, (b - n_live) * n_mamba,
-                              jnp.int32(0), rows], expert_counts))
+                              jnp.int32(0), rows, folds], expert_counts))
 
 
 FAMILY = ServingFamily(

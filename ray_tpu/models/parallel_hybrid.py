@@ -44,12 +44,16 @@ programs are as they were). Attention is `ops/decode_attention.py`'s
 block (`ServingFamily.state_blocks` 1 and `paged`), as
 `models/mamba_moe.py`, and here both in every layer: column 0 of its
 table names a state block, `"state" [L, blocks, H / t, N, t P]` float32
-(`ops/mamba2.py`'s layout) and `"conv" [L, blocks, K - 1, H P + 2 G N]`;
-the columns after it name pages of `"k"`, `"v"` `[L, pages, Hkv,
-block_size, d]`, all four with `L = n_layers`. Prefill resets the state
-block on a sequence's first chunk, a chunk bucket's padding leaves
-state, tail and pages bit for bit, and decode's idle rows (table all 0)
-rewrite the trash blocks of both kinds.
+(`ops/mamba2.py`'s layout), `"conv" [L, blocks, K - 1, H P + 2 G N]`,
+`"ring"`, the decode tokens that are not in the state yet, and
+`"held" [1, blocks]`, the entries a block's ring holds
+(`mamba_moe.state_arrays`); the columns after it name pages of `"k"`,
+`"v"` `[L, pages, Hkv, block_size, d]`, all but `"held"` with `L =
+n_layers`. Prefill resets the state block on a sequence's first chunk
+and leaves its rings empty, a chunk bucket's padding leaves state, tail
+and pages bit for bit, and decode's idle rows (table all 0) rewrite the
+trash blocks' tails and pages and move nothing of the trash state or its
+rings.
 
 `forward` is the whole-sequence form for tests; `prefill` and `decode`
 are what `ServingFamily` asks.
@@ -73,9 +77,10 @@ from ray_tpu.ops import mamba2
 # what the prefill and decode programs count, in the order of the int32
 # vector they return beside the logits: the first four as
 # `models/mamba_moe.py`'s (over the layers), then the cached rows one
-# layer's attention read in decode steps alone
+# layer's attention read in decode steps alone, then the rows whose rings
+# went into their states (a decode step's, once whatever the layers)
 COUNTS = ("mamba_tokens_live", "mamba_tokens_padded", "state_resets",
-          "attention_rows_read", "decode_rows_read_a_layer")
+          "attention_rows_read", "decode_rows_read_a_layer", "state_folds")
 STATE_KEYS = mamba_moe.STATE_KEYS
 
 
@@ -387,7 +392,9 @@ def prefill(params, tokens, cache, cfg: ParallelHybridConfig, mesh=None, *,
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(offs < length, positions + 1, 0)) * layers
         counts = jnp.stack([length * layers, (c - length) * layers,
-                            first.astype(jnp.int32), rows, jnp.int32(0)])
+                            first.astype(jnp.int32), rows, jnp.int32(0),
+                            jnp.int32(0)])
+        cache["held"] = mamba_moe.rings_emptied(cache, block)
         return _unembed(last, params, cfg), cache, counts.astype(jnp.int32)
 
 
@@ -396,7 +403,7 @@ def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
     """One token for every slot (`gpt.decode_step_paged`'s contract):
     tokens [B] at positions pos [B]; `tables[:, 0]` each row's state
     block, the rest its pages. Idle rows name the trash blocks of both
-    kinds, rewrite them and count nothing.
+    kinds, rewrite their tails and pages and count nothing.
     -> (logits [B, V] f32, cache, counts)."""
     cache = dict(cache)
     b = tokens.shape[0]
@@ -406,6 +413,7 @@ def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
         tables = tables.astype(jnp.int32)
         blocks, pages = tables[:, 0], tables[:, 1:]
         live = blocks > 0
+        held, held_after, folds = mamba_moe.rings_stepped(cache, blocks, cfg)
         widx = mamba_moe.row_index(pages, pos, cache["k"])
         mup = _mup(cfg)
         x = _embed(params, tokens, cfg)
@@ -415,7 +423,7 @@ def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
             with jax.named_scope("state_branch"):
                 m = mamba_moe.mamba_step(
                     _scaled(n, cfg.ssm_in_multiplier), lp, cache, cfg, i,
-                    blocks, mup)
+                    blocks, held, mup)
             with jax.named_scope("attention_branch"):
                 q, k, v = _qkv(n, pos, lp, cfg)
                 cache["k"] = window_moe._write_rows(cache["k"], i, k, widx)
@@ -430,7 +438,8 @@ def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
         n_live = jnp.sum(live, dtype=jnp.int32)
         rows = jnp.sum(jnp.where(live, pos + 1, 0))
         counts = jnp.stack([n_live * layers, (b - n_live) * layers,
-                            jnp.int32(0), rows * layers, rows])
+                            jnp.int32(0), rows * layers, rows, folds])
+        cache["held"] = held_after
         return _unembed(x, params, cfg), cache, counts.astype(jnp.int32)
 
 

@@ -80,25 +80,29 @@ def test_serve_mamba_phase_tiny_on_cpu():
     """The fifth family's part: an engine over `models/mamba_moe.py` (a
     state block of Mamba-2 states and tails beside one attention layer's
     pages a request) in the phase's own process, float32 on the CPU (the
-    plain paths), held to the definition."""
+    plain paths), held to the definition; every stream decodes past a
+    fold of its ring (the phase checks `state_folds`)."""
     out = run("cs.serve_family_phase(cs.mamba_case(dict(cs.MAMBA_CFG, "
               "mamba_head_dim=16, state_size=32, head_dim=32, "
               "dtype='float32'), 0), platform='cpu', streams=5, "
-              "prompt_lens=(100, 300), new_tokens=6, slots=3, seed=0)")
+              "prompt_lens=(100, 300), new_tokens=11, slots=3, seed=0)")
     assert '"phase": "serve_mamba"' in out
+    assert '"state_folds": 5' in out
 
 
 def test_serve_parallel_hybrid_phase_tiny_on_cpu():
     """The seventh family's part: an engine over
     `models/parallel_hybrid.py` (a state block and pages in every layer a
     request, a group of 5 query heads) in the phase's own process,
-    float32 on the CPU (the plain paths), held to the definition."""
+    float32 on the CPU (the plain paths), held to the definition; every
+    stream decodes past a fold of its ring."""
     out = run("cs.serve_family_phase(cs.parallel_hybrid_case(dict("
               "cs.PARALLEL_HYBRID_CFG, mamba_head_dim=16, state_size=32, "
               "head_dim=32, dtype='float32'), 0), platform='cpu', "
-              "streams=5, prompt_lens=(100, 300), new_tokens=6, slots=3, "
+              "streams=5, prompt_lens=(100, 300), new_tokens=11, slots=3, "
               "seed=0)")
     assert '"phase": "serve_parallel_hybrid"' in out
+    assert '"state_folds": 5' in out
 
 
 def test_train_phase_tiny_on_cpu():

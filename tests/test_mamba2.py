@@ -1,5 +1,8 @@
 """`ops/mamba2.py`: the step and chunk kernels (interpret mode) and their
-plain paths against the token-by-token recurrence."""
+plain paths against the token-by-token recurrence; the ring of decode
+tokens beside a state (what it holds between folds, rows that fold in
+different steps, idle rows, stale rings, the control, the fold against
+float64)."""
 
 import functools
 
@@ -36,6 +39,28 @@ def pool_with(key, h=H, p=P, n=N):
     return jax.random.normal(key, (L, NB, h // t, n, t * p), F32)
 
 
+def rings_with(key=None, h=H, g=G, p=P, n=N):
+    """Empty rings, or with `key` stale ones: whatever a freed block's
+    last sequence left in them."""
+    ring = mamba2.ring_array(L, NB, h, g, p, n)
+    return ring if key is None else jax.random.normal(key, ring.shape, F32)
+
+
+def state_with_its_ring(pool, ring, layer, block, held, h, p):
+    """What a block's state would be with its ring's `held` tokens folded
+    in, by head [H, P, N]: exp(l_t) S_t0 + sum_s exp(l_t - l_s) (d_s x_s)
+    B_s^T, in float32 from the arrays as stored."""
+    s = mamba2.to_heads(pool[layer, block], p)
+    if not held:
+        return s
+    xd, b, logs = mamba2._unpacked(ring[layer, block, :held], h, G, p,
+                                   s.shape[-1])
+    b = mamba2._by_head(b, h)                                # [held, H, N]
+    w = jnp.exp(logs[-1][None] - logs)                       # [held, H]
+    return jnp.exp(logs[-1])[:, None, None] * s + jnp.einsum(
+        "sh,shp,shn->hpn", w, xd, b, precision=jax.lax.Precision.HIGHEST)
+
+
 def test_pairs_and_heads_are_inverse():
     s = jax.random.normal(jax.random.key(0), (3, H, P, N), F32)
     stored = mamba2.to_pairs(s)
@@ -55,29 +80,141 @@ def test_a_head_that_fills_the_lanes_is_stored_alone():
 
 # y is a float32 sum over N: at 256 it is twice as long as at 128, and so
 # is the room for its order
-@pytest.mark.parametrize("p,n,atol", [(64, 128, 2e-5), (128, 256, 4e-5)],
+@pytest.mark.parametrize("p,n,atol", [(64, 128, 4e-5), (128, 256, 8e-5)],
                          ids=["64x128", "128x256"])
 @pytest.mark.parametrize("impl", ["jax", "pallas"])
-def test_step_matches_the_recurrence(impl, p, n, atol):
-    nb = 3
-    x, dt, a, b, c = draws(jax.random.key(1), nb, p=p, n=n)
-    pool = pool_with(jax.random.key(2), p=p, n=n)
+def test_steps_match_the_recurrence_token_by_token(impl, p, n, atol):
+    """2 RING + 3 decode steps of two sequences, the second of which
+    starts three steps after the first (its row idle until then: block
+    0), so the rows' rings fill in different steps, over rings that hold
+    a freed block's stale tokens: `y` at every step; the state after
+    every fold; at a step that does not fold the state as it was and,
+    with its ring's tokens, the recurrence's. The idle row moves
+    nothing, of the trash block either; no other layer and no other
+    block is touched."""
+    ring, late = mamba2.RING, 3
+    steps = 2 * ring + 3
     to_heads = functools.partial(mamba2.to_heads, p=p)
-    blocks = jnp.array([2, 0, 4], jnp.int32)
-    y, new = mamba2.mamba2_step(x, dt, a, b, c, pool, 1, blocks, impl=impl)
-    for i, blk in enumerate([2, 0, 4]):
-        if blk == 0:
-            continue
-        want_y, want_s = mamba2.mamba2_recurrent(
-            x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1],
-            to_heads(pool[1, blk]))
-        np.testing.assert_allclose(y[i], want_y[0], rtol=2e-5, atol=atol)
-        np.testing.assert_allclose(to_heads(new[1, blk]), want_s,
-                                   rtol=1e-6, atol=1e-6)
-    # no other layer and no other live block is touched
-    np.testing.assert_array_equal(new[0], pool[0])
-    np.testing.assert_array_equal(new[1, 1], pool[1, 1])
-    np.testing.assert_array_equal(new[1, 3], pool[1, 3])
+    x, dt, a, b, c = draws(jax.random.key(1), 3 * steps, p=p, n=n)
+    x, dt, b, c = (v.reshape((steps, 3) + v.shape[1:]) for v in (x, dt, b, c))
+    pool = first = pool_with(jax.random.key(2), p=p, n=n)
+    rings = stale = rings_with(jax.random.key(3), p=p, n=n)
+    held = jnp.zeros((3,), jnp.int32)
+    want = {}
+    for row, blk, since in ((0, 2, 0), (2, 4, late)):
+        want[row] = mamba2.mamba2_recurrent(
+            x[since:, row], dt[since:, row], a, b[since:, row],
+            c[since:, row], to_heads(pool[1, blk]))
+    # the recurrence's state after each of a row's tokens
+    states = {row: [mamba2.mamba2_recurrent(
+        x[since:t + 1, row], dt[since:t + 1, row], a, b[since:t + 1, row],
+        c[since:t + 1, row], to_heads(first[1, blk]))[1]
+        for t in range(since, steps)]
+        for row, blk, since in ((0, 2, 0), (2, 4, late))}
+    folds = 0
+    for t in range(steps):
+        blocks = jnp.array([2, 0, 4 if t >= late else 0], jnp.int32)
+        fold, after = mamba2.ring_after(blocks, held)
+        y, new, rings = mamba2.mamba2_step(
+            x[t], dt[t], a, b[t], c[t], pool, rings, 1, blocks, held,
+            impl=impl)
+        for row, blk, since in ((0, 2, 0), (2, 4, late)):
+            if t < since:
+                continue
+            np.testing.assert_allclose(y[row], want[row][0][t - since],
+                                       rtol=2e-5, atol=atol)
+            scale = float(jnp.max(jnp.abs(states[row][t - since])))
+            if bool(fold[row]):
+                folds += 1
+                np.testing.assert_allclose(
+                    to_heads(new[1, blk]), states[row][t - since],
+                    rtol=1e-5, atol=1e-6 * scale)
+            else:
+                np.testing.assert_array_equal(new[1, blk], pool[1, blk])
+                np.testing.assert_allclose(
+                    state_with_its_ring(new, rings, 1, blk, int(after[row]),
+                                        H, p),
+                    states[row][t - since], rtol=1e-5, atol=1e-6 * scale)
+        assert int(after[1]) == 0 and not bool(fold[1])
+        pool, held = new, after
+    assert folds == (steps // ring) + ((steps - late) // ring)
+    # rows of one batch folded in different steps
+    assert [int(h) for h in held] == [steps % ring, 0, (steps - late) % ring]
+    # the idle row, the other layer and the other blocks: as they were
+    np.testing.assert_array_equal(pool[0], first[0])
+    for blk in (0, 1, 3):
+        np.testing.assert_array_equal(pool[1, blk], first[1, blk])
+    np.testing.assert_array_equal(rings[0], stale[0])
+    for blk in (1, 3):
+        np.testing.assert_array_equal(rings[1, blk], stale[1, blk])
+    # the trash block's: what an entry holds of a token (the plain path
+    # packs an idle row's entries anew, padding and all)
+    for got, was in zip(mamba2._unpacked(rings[1, 0], H, G, p, n),
+                        mamba2._unpacked(stale[1, 0], H, G, p, n)):
+        np.testing.assert_array_equal(got, was)
+
+
+@WIDTHS
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+def test_a_first_chunk_then_steps_over_a_stale_ring(impl, p, n):
+    """A freed block's state and rings hold its last sequence's numbers;
+    a first chunk reads the state as zeros and its caller leaves the
+    block's rings empty (`held` 0), so the steps after it read as the
+    recurrence does over the whole sequence."""
+    t, steps = 128, mamba2.RING + 2
+    x, dt, a, b, c = draws(jax.random.key(11), t + steps, p=p, n=n)
+    pool = pool_with(jax.random.key(12), p=p, n=n)
+    rings = rings_with(jax.random.key(13), p=p, n=n)
+    want_y, want_s = mamba2.mamba2_recurrent(x, dt, a, b, c)
+    _, pool = mamba2.mamba2_chunk(x[:t], dt[:t], a, b[:t], c[:t], pool, 0, 3,
+                                  True, t, impl=impl)
+    blocks = jnp.array([3], jnp.int32)
+    held = jnp.zeros((1,), jnp.int32)       # what a chunk leaves
+    tol = 2e-4 if impl == "jax" else 6e-2   # the chunk kernel's bfloat16
+    scale = float(jnp.max(jnp.abs(want_y)))
+    for i in range(t, t + steps):
+        y, pool, rings = mamba2.mamba2_step(
+            x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], pool, rings,
+            0, blocks, held, impl=impl)
+        _, held = mamba2.ring_after(blocks, held)
+        np.testing.assert_allclose(y[0], want_y[i], atol=tol * scale)
+    np.testing.assert_allclose(
+        state_with_its_ring(pool, rings, 0, 3, int(held[0]), H, p), want_s,
+        atol=tol * float(jnp.max(jnp.abs(want_s))))
+
+
+@WIDTHS
+def test_the_fold_against_float64(p, n):
+    """A ring's tokens folded into a state by the kernel, beside the
+    per-token float32 multiply-add, both against the same sum in float64:
+    the reordered sum loses nothing (errors of one order)."""
+    ring = mamba2.RING
+    x, dt, a, b, c = draws(jax.random.key(21), ring, p=p, n=n)
+    pool = pool_with(jax.random.key(22), p=p, n=n)
+    s64 = np.asarray(mamba2.to_heads(pool[0, 1], p), np.float64)
+    a64 = np.asarray(a, np.float64)
+    for i in range(ring):
+        d = np.asarray(dt[i], np.float64)
+        bh = np.repeat(np.asarray(b[i], np.float64), H // G, axis=0)
+        s64 = np.exp(d * a64)[:, None, None] * s64 + (
+            np.asarray(x[i], np.float64) * d[:, None])[..., None] \
+            * bh[:, None, :]
+    plain = mamba2.mamba2_recurrent(x, dt, a, b, c,
+                                    mamba2.to_heads(pool[0, 1], p))[1]
+    rings, blocks = rings_with(p=p, n=n), jnp.array([1], jnp.int32)
+    held = jnp.zeros((1,), jnp.int32)
+    for i in range(ring):
+        _, pool, rings = mamba2.mamba2_step(
+            x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], pool, rings,
+            0, blocks, held, impl="pallas")
+        _, held = mamba2.ring_after(blocks, held)
+    assert int(held[0]) == 0                # folded with the last token
+    errs = [float(np.max(np.abs(np.asarray(v, np.float64) - s64)))
+            for v in (mamba2.to_heads(pool[0, 1], p), plain)]
+    print(f"fold against float64 at {p} x {n}: kernel {errs[0]:.3g}, "
+          f"per-token float32 {errs[1]:.3g}, on states of "
+          f"{np.max(np.abs(s64)):.3g}")
+    assert errs[0] < 4 * errs[1] + 1e-6
 
 
 @WIDTHS
@@ -139,31 +276,64 @@ def test_chunks_then_steps_carry_one_state():
     y, pool = mamba2.mamba2_chunk(x[16:37], dt[16:37], a, b[16:37], c[16:37],
                                   pool, 0, 1, False, 21, impl="jax")
     ys.append(y)
+    rings = mamba2.ring_array(1, 3, 4, 2, 8, 16)
+    blocks, held = jnp.array([1], jnp.int32), jnp.zeros((1,), jnp.int32)
     for i in range(37, t):
-        y, pool = mamba2.mamba2_step(x[i:i + 1], dt[i:i + 1], a, b[i:i + 1],
-                                     c[i:i + 1], pool, 0,
-                                     jnp.array([1], jnp.int32), impl="jax")
+        y, pool, rings = mamba2.mamba2_step(
+            x[i:i + 1], dt[i:i + 1], a, b[i:i + 1], c[i:i + 1], pool, rings,
+            0, blocks, held, impl="jax")
+        _, held = mamba2.ring_after(blocks, held)
         ys.append(y)
     want_y, want_s = mamba2.mamba2_recurrent(x, dt, a, b, c)
     np.testing.assert_allclose(jnp.concatenate(ys), want_y, rtol=2e-4,
                                atol=2e-4)
-    np.testing.assert_allclose(mamba2.to_heads(pool[0, 1], 8), want_s,
-                               rtol=2e-4, atol=2e-4)
+    # the three tokens wait in the ring
+    assert int(held[0]) == 3
+    np.testing.assert_allclose(
+        state_with_its_ring(pool, rings, 0, 1, 3, 4, 8), want_s, rtol=2e-4,
+        atol=2e-4)
+    # an entry keeps a token's d x, its B and its log-decays, each from
+    # a row of its own
+    assert mamba2._entry_rows(4, 2, 8, 16) == (1, 1, 2, 8)
+    assert mamba2._entry_rows(128, 8, 64, 128) == (64, 8, 2, 80)
+    assert mamba2._entry_rows(32, 2, 128, 256) == (32, 4, 1, 40)
 
 
 @WIDTHS
 @pytest.mark.parametrize("form", ["step", "chunk"])
 def test_state_round_rounds_every_write(form, p, n):
     """The control's field: the state that is written holds bfloat16
-    numbers in float32 bytes, and differs from the sound one."""
+    numbers in float32 bytes, and differs from the sound one. A step
+    under it folds its one token at once, whatever `RING` is, into the
+    state the read-modify-write gave: `bfloat16(exp(d A) S + d x B^T)`;
+    the sound step writes no state, its token waits in the ring."""
     x, dt, a, b, c = draws(jax.random.key(8), 128, p=p, n=n)
     pool = pool_with(jax.random.key(9), p=p, n=n)
     if form == "step":
+        blocks = jnp.array([1, 2], jnp.int32)
+        held = jnp.zeros((2,), jnp.int32)
+        assert mamba2.ring_entries("bfloat16") == 1 < mamba2.ring_entries(
+            "none") == mamba2.RING
+        fold, after = mamba2.ring_after(blocks, held, "bfloat16")
+        assert bool(fold.all()) and not bool(after.any())
+
         def call(rnd):
             return mamba2.mamba2_step(
-                x[:2], dt[:2], a, b[:2], c[:2], pool, 0,
-                jnp.array([1, 2], jnp.int32), state_round=rnd,
-                impl="pallas")[1][0, 1:3]
+                x[:2], dt[:2], a, b[:2], c[:2], pool, rings_with(p=p, n=n),
+                0, blocks, held, state_round=rnd, impl="pallas")[1][0, 1:3]
+
+        np.testing.assert_array_equal(call("none"), pool[0, 1:3])
+        s = mamba2.to_heads(pool[0, 1:3], p)
+        moved = jnp.exp(dt[:2] * a)[..., None, None] * s + (
+            x[:2] * dt[:2, :, None])[..., None] * mamba2._by_head(
+                b[:2], H)[:, :, None, :]
+        gave = mamba2.to_pairs(moved.astype(jnp.bfloat16).astype(F32))
+        # to the bit but where the two sums' last float32 bit lies on
+        # either side of a bfloat16 rounding
+        same = np.asarray(call("bfloat16") == gave)
+        assert same.mean() > 0.99
+        np.testing.assert_allclose(call("bfloat16"), gave, rtol=2 ** -7,
+                                   atol=1e-6)
     else:
         def call(rnd):
             return mamba2.mamba2_chunk(

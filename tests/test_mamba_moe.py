@@ -17,7 +17,7 @@ import pytest
 
 from benchmarks.refs import mamba_moe as ref
 from ray_tpu.models import latent_sparse_moe as lsm, mamba_moe
-from ray_tpu.ops import grouped_experts
+from ray_tpu.ops import grouped_experts, mamba2
 from ray_tpu.serve.engine import InferenceEngine
 from ray_tpu.util import faults
 
@@ -164,12 +164,39 @@ def test_a_slot_handed_on_starts_from_a_reset_state(params):
     eng.check_invariants()
 
 
+@pytest.mark.parametrize("slots", [1, 2], ids=["one_slot", "two_slots"])
+def test_a_block_freed_mid_ring_is_taken_by_a_new_sequence(params, slots):
+    """Requests that decode past a fold and end part of the way into a
+    ring, one after another on the same state blocks: the next sequence's
+    first chunk leaves the block's rings empty, so each streams what the
+    reference gives for it alone; on two slots the rows' rings fill in
+    different steps. `state_folds` counts the rows whose rings went into
+    their states: one every `RING` decode tokens of a request."""
+    eng = make_engine(params, slots=slots)
+    ring = mamba2.RING
+    news = (ring + 4, 2 * ring + 3, ring + 2, 5)
+    prompts = [prompt(n, 60 + i) for i, n in enumerate((20, 9, 37, 12))]
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    for p, rid in zip(prompts, rids):
+        got = stream(eng, rid)
+        np.testing.assert_allclose(
+            [x for _, x in got], reference_logprobs(params, p, got),
+            atol=TOL)
+    s = eng.stats()
+    # a request's first token is its prefill's
+    assert s["state_folds"] == sum((n - 1) // ring for n in news)
+    assert 0 <= s["decode_tokens"] / ring - s["state_folds"] < len(news)
+    assert not np.asarray(eng.cache["held"])[0, 0]
+    eng.check_invariants()
+
+
 # -- (b) two kinds of block under one allocator -------------------------------
 
 def test_what_the_engine_holds_for_the_family(params):
     fam = mamba_moe.FAMILY
     assert (fam.state_blocks, fam.paged, fam.state_keys, fam.verify) == \
-        (1, True, ("state", "conv"), None)
+        (1, True, ("state", "conv", "ring", "held"),
+         None)
     eng = make_engine(params)
     # 3 slots x 1 state block + 3 x 96 / 16 pages; a table is the state
     # block and six pages
@@ -177,6 +204,12 @@ def test_what_the_engine_holds_for_the_family(params):
     pool = eng.cache
     assert pool["state"].shape == (5, 4, 4, 16, 16)
     assert pool["conv"].shape == (5, 4, 3, 64 + 2 * 2 * 16)
+    # the rings beside the states: an entry a token's d x (64 numbers:
+    # a row of lanes), its B (2 x 16: a row) and its running log-decay a
+    # head on the lanes (2 rows), in whole sublane tiles; one count a
+    # block
+    assert pool["ring"].shape == (5, 4, mamba2.RING, 8, 128)
+    assert pool["held"].shape == (1, 4) and pool["held"].dtype == jnp.int32
     assert pool["k"].shape == pool["v"].shape == (1, 19, 2, BS, 16)
     with pytest.raises(ValueError, match="prefix_cache=False"):
         InferenceEngine(params, config(), slots=2, max_len=64)
@@ -251,8 +284,10 @@ def test_preempt_and_resume(params, at):
 
 def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
     """A chunk of 13 live positions in buckets of 16 and 32: state, tail
-    and the page's rows bit for bit the same; a decode step whose rows
-    are all idle rewrites the trash blocks and nothing else."""
+    and the page's rows bit for bit the same, and the block's rings left
+    empty whatever they held; a decode step whose rows are all idle
+    rewrites the trash blocks' tails and pages and nothing else: no
+    state, no ring entry, no count of either kind of block."""
     cfg = config()
     table = jnp.asarray([2, 3, 4, 0, 0, 0, 0], jnp.int32)
     pools = []
@@ -265,6 +300,7 @@ def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
             params, jnp.asarray(toks), pool, cfg, block_table=table,
             start=0, length=13)
         assert [int(c) for c in counts[:3]] == [5 * 13, 5 * (bucket - 13), 1]
+        assert [int(h) for h in pool["held"][0]] == [1, 1, 0, 1]
         pools.append(pool)
     # bit for bit: the pages, and the four state layers before the
     # attention layer. The fifth reads what the plain attention path
@@ -286,10 +322,19 @@ def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
     _, after, counts = mamba_moe.decode(
         params, jnp.zeros((2,), jnp.int32), before,
         jnp.zeros((2,), jnp.int32), jnp.zeros((2, 7), jnp.int32), cfg)
-    assert [int(c) for c in counts[:6]] == [0, 10, 0, 0, 0, 0]
+    assert [int(c) for c in counts[:7]] == [0, 10, 0, 0, 0, 0, 0]
     for key in before:
         np.testing.assert_array_equal(np.asarray(before[key][:, 1:]),
                                       np.asarray(after[key][:, 1:]))
+    for key in ("state", "held"):
+        np.testing.assert_array_equal(np.asarray(before[key]),
+                                      np.asarray(after[key]))
+    # what the trash block's ring holds of tokens (not an entry's padding)
+    shape = (cfg.mamba_heads, cfg.n_groups, cfg.mamba_head_dim,
+             cfg.state_size)
+    for was, now in zip(mamba2._unpacked(before["ring"], *shape),
+                        mamba2._unpacked(after["ring"], *shape)):
+        np.testing.assert_array_equal(np.asarray(was), np.asarray(now))
 
 
 def test_a_rounded_state_moves_the_logprobs(params):

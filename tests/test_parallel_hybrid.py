@@ -17,6 +17,7 @@ import pytest
 
 from benchmarks.refs import parallel_hybrid as ref
 from ray_tpu.models import mamba_moe, parallel_hybrid
+from ray_tpu.ops import mamba2
 from ray_tpu.serve.engine import InferenceEngine
 from ray_tpu.util import faults
 
@@ -175,12 +176,40 @@ def test_a_slot_handed_on_starts_from_a_reset_state(params):
     eng.check_invariants()
 
 
+@pytest.mark.parametrize("slots", [1, 2], ids=["one_slot", "two_slots"])
+def test_a_block_freed_mid_ring_is_taken_by_a_new_sequence(params, slots):
+    """Requests that decode past a fold and end part of the way into a
+    ring, one after another on the same state blocks: the next sequence's
+    first chunk leaves the block's rings empty, so each streams what the
+    reference gives for it alone; on two slots the rows' rings fill in
+    different steps. `state_folds` counts the rows whose rings went into
+    their states, once whatever the layers: one every `RING` decode
+    tokens of a request."""
+    eng = make_engine(params, slots=slots)
+    ring = mamba2.RING
+    news = (ring + 4, 2 * ring + 3, ring + 2, 5)
+    prompts = [prompt(n, 60 + i) for i, n in enumerate((20, 9, 37, 12))]
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    for p, rid in zip(prompts, rids):
+        got = stream(eng, rid)
+        np.testing.assert_allclose(
+            [x for _, x in got], reference_logprobs(params, p, got),
+            atol=TOL)
+    s = eng.stats()
+    # a request's first token is its prefill's
+    assert s["state_folds"] == sum((n - 1) // ring for n in news)
+    assert 0 <= s["decode_tokens"] / ring - s["state_folds"] < len(news)
+    assert not np.asarray(eng.cache["held"])[0, 0]
+    eng.check_invariants()
+
+
 # -- (b) a state block and pages in every layer -------------------------------
 
 def test_what_the_engine_holds_for_the_family(params):
     fam = parallel_hybrid.FAMILY
     assert (fam.state_blocks, fam.paged, fam.state_keys, fam.verify,
-            fam.load) == (1, True, ("state", "conv"), None, None)
+            fam.load) == (1, True, ("state", "conv", "ring", "held"), None,
+                         None)
     eng = make_engine(params)
     # 3 slots x 1 state block + 3 x 96 / 16 pages; a table is the state
     # block and six pages
@@ -189,6 +218,8 @@ def test_what_the_engine_holds_for_the_family(params):
     # every array has a layer of the model a layer
     assert pool["state"].shape == (LAYERS, 4, 2, 16, 32)
     assert pool["conv"].shape == (LAYERS, 4, 3, 64 + 2 * 2 * 16)
+    assert pool["ring"].shape == (LAYERS, 4, mamba2.RING, 8, 128)
+    assert pool["held"].shape == (1, 4) and pool["held"].dtype == jnp.int32
     assert pool["k"].shape == pool["v"].shape == (LAYERS, 19, 2, BS, 16)
     with pytest.raises(ValueError, match="prefix_cache=False"):
         InferenceEngine(params, config(), slots=2, max_len=64)
@@ -207,6 +238,9 @@ def test_a_head_that_fills_the_lanes_is_stored_alone():
     pool = parallel_hybrid.init_pool(cfg, 3, BS, state_blocks=2)
     assert pool["state"].shape == (LAYERS, 2, 2, 256, 128)
     assert pool["conv"].shape == (LAYERS, 2, 3, 256 + 2 * 2 * 256)
+    # an entry: a head a row of d x, a group's B two rows, a head a
+    # lane of one row of log-decays
+    assert pool["ring"].shape == (LAYERS, 2, mamba2.RING, 8, 128)
 
 
 def test_a_request_holds_a_state_block_and_its_pages(params):
@@ -278,8 +312,10 @@ def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
     layer's state, tail and pages bit for bit the same (a later layer
     reads what the plain attention path made of 16 and of 32 query rows,
     whose sums the CPU backend orders by the shape; the kernels' pair is
-    `tests/test_mamba2.py`'s); a decode step whose rows are all idle
-    rewrites the trash blocks and nothing else."""
+    `tests/test_mamba2.py`'s), and the block's rings left empty whatever
+    they held; a decode step whose rows are all idle rewrites the trash
+    blocks' tails and pages and nothing else: no state, no ring entry, no
+    count of either."""
     cfg = config()
     table = jnp.asarray([2, 3, 4, 0, 0, 0, 0], jnp.int32)
     pools = []
@@ -292,7 +328,9 @@ def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
             params, jnp.asarray(toks), pool, cfg, block_table=table,
             start=0, length=13)
         assert [int(c) for c in counts] == [
-            LAYERS * 13, LAYERS * (bucket - 13), 1, LAYERS * 13 * 14 // 2, 0]
+            LAYERS * 13, LAYERS * (bucket - 13), 1, LAYERS * 13 * 14 // 2, 0,
+            0]
+        assert [int(h) for h in pool["held"][0]] == [1, 1, 0, 1]
         pools.append(pool)
     for key in ("state", "conv", "k", "v"):
         np.testing.assert_array_equal(np.asarray(pools[0][key][0]),
@@ -307,10 +345,19 @@ def test_padding_and_idle_rows_leave_state_tails_and_pages(params):
     _, after, counts = parallel_hybrid.decode(
         params, jnp.zeros((2,), jnp.int32), before,
         jnp.zeros((2,), jnp.int32), jnp.zeros((2, 7), jnp.int32), cfg)
-    assert [int(c) for c in counts] == [0, 2 * LAYERS, 0, 0, 0]
+    assert [int(c) for c in counts] == [0, 2 * LAYERS, 0, 0, 0, 0]
     for key in before:
         np.testing.assert_array_equal(np.asarray(before[key][:, 1:]),
                                       np.asarray(after[key][:, 1:]))
+    for key in ("state", "held"):
+        np.testing.assert_array_equal(np.asarray(before[key]),
+                                      np.asarray(after[key]))
+    # what the trash block's ring holds of tokens (not an entry's padding)
+    shape = (cfg.mamba_heads, cfg.n_groups, cfg.mamba_head_dim,
+             cfg.state_size)
+    for was, now in zip(mamba2._unpacked(before["ring"], *shape),
+                        mamba2._unpacked(after["ring"], *shape)):
+        np.testing.assert_array_equal(np.asarray(was), np.asarray(now))
 
 
 def test_a_rounded_state_moves_the_logprobs(params):
