@@ -32,6 +32,11 @@ tails beside one attention layer's pages, experts without a gate matrix
 in a latent), and last the parallel-hybrid one (`models/parallel_hybrid.py`,
 `--phase serve-parallel-hybrid`: a state block and pages in every layer,
 a state head of 128 x 256 and a group of 5 query heads a key-value head).
+After the dense train phase it trains the window-and-full family
+(`models/window_moe_train.py`, `--phase train-window`: the banded and the
+full flash kernels at grouped heads, the expert kernels' backward pass at
+an expert width of 896) and fails if a kernel is missing or the first
+loss leaves the plain forward's.
 
 A chip belongs to one process at a time, so this process never
 initialises a JAX backend: every phase runs in one process of its own
@@ -762,17 +767,19 @@ def seeded_batch(cfg, batch: int, seed: int) -> dict:
 
 
 def train_run(cfg, mesh_spec, devices, *, batch: int, steps: int,
-              unroll: int, seed: int, watch: CompileWatch) -> dict:
+              unroll: int, seed: int, watch: CompileWatch,
+              trainer: str = "make_gpt_trainer",
+              weight: str = "w_up") -> dict:
     """`steps` steps of `cfg` on a mesh over `devices` through
-    `TrainLoop` with the prefetcher; returns what it measured for
-    `check_train_run`. The generator yields the same seeded batch every
-    step, so the loss has to fall strictly (fresh random tokens cannot
-    go below ln(vocab))."""
+    `TrainLoop` with the prefetcher, by `train.spmd`'s `trainer`; returns
+    what it measured for `check_train_run`. The generator yields the same
+    seeded batch every step, so the loss has to fall strictly (fresh
+    random tokens cannot go below ln(vocab))."""
     import jax
 
     from ray_tpu.train import loop, spmd
     mesh = mesh_spec.build(devices)
-    state, step_fn, _ = spmd.make_gpt_trainer(
+    state, step_fn, _ = getattr(spmd, trainer)(
         cfg, mesh, rng=jax.random.key(seed),
         optimizer=spmd.default_optimizer(warmup_steps=0))
     host_batch = seeded_batch(cfg, batch, seed)
@@ -784,7 +791,8 @@ def train_run(cfg, mesh_spec, devices, *, batch: int, steps: int,
     batches = loop.DevicePrefetcher(
         host_batches(), loop.make_placer(mesh, stacked=True), depth=2,
         group=unroll)
-    weight = state.params["layers"]["w_up"]
+    layers = state.params["layers"]
+    weight = (layers if isinstance(layers, dict) else layers[0])[weight]
     shard_devices = sorted(s.device.id for s in weight.addressable_shards)
 
     # The fused dispatch `TrainLoop` builds, lowered here to read it. The
@@ -899,6 +907,67 @@ def train_phase(cfg_kwargs: dict, *, platform: str, batch: int,
               "cache: its key moves")
 
 
+# `Mellum2-12B-A2.5B`'s layer at its published head, group, window, expert
+# and model widths, cut in what the kernels' plans do not depend on: two
+# layers (a window layer and a full one), 16 query heads over 2, 4 experts
+# held of a 16-wide router, sequences of 4,096: the band is a quarter of
+# the sequence, a q block of 1,024 rows touches two kv blocks of 2,048,
+# and an expert's 896 rows are one width slice.
+WINDOW_TRAIN_CFG = dict(
+    vocab_size=8192, d_model=2304, n_layers=2, n_heads=16, n_kv_heads=2,
+    head_dim=128, window=1024, layer_types=("window", "full"),
+    rope_window=(500000.0,),
+    rope_full=(500000.0, 16.0, 8192, 32.0, 1.0, 1.2772588722239782),
+    expert_ff=896, router_width=16, experts_per_token=4, held_count=4,
+    max_seq_len=4096, flash_block_q=1024, flash_block_kv=2048,
+    expert_chunk=2048)
+WINDOW_TRAIN_KERNELS = {
+    "flash_fwd_band", "flash_dq_band", "flash_dkv_band",
+    "experts_grouped_train", "experts_grouped_dx", "experts_grouped_dw"}
+
+
+def train_window_phase(cfg_kwargs: dict, *, platform: str, batch: int,
+                       steps: int, seed: int) -> None:
+    """One chip: `models/window_moe_train.py` through the training loop
+    (the banded and the full flash kernels at grouped heads, the expert
+    kernels' backward), its first loss against the whole-sequence plain
+    form's (`forward`: every score made and masked, the experts one by
+    one)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import window_moe_train as wmt
+    from ray_tpu.parallel import MeshSpec
+    from ray_tpu.train import spmd
+    from ray_tpu.util.compile_cache import enable_compile_cache
+    cache_dir = enable_compile_cache()
+    fallbacks = watch_op_fallbacks()
+    watch = CompileWatch()
+    device = device_report()
+    check(device["platform"] == platform,
+          f"train phase runs on {device['platform']}, not {platform}")
+    cfg = wmt.WindowMoETrainConfig(**cfg_kwargs)
+    plain = dataclasses.replace(cfg, sparse_impl="jax")
+    host = seeded_batch(cfg, batch, seed)
+    params = jax.jit(lambda k: wmt.init_params(k, cfg))(jax.random.key(seed))
+    ref_loss = float(jax.jit(lambda p, b: jnp.mean(spmd.softmax_xent(
+        wmt.forward(p, b["inputs"], plain), b["targets"])))(params, host))
+    del params
+    run = train_run(cfg, MeshSpec(data=1), jax.devices()[:1], batch=batch,
+                    steps=steps, unroll=2, seed=seed, watch=watch,
+                    trainer="make_window_moe_trainer", weight="we_up")
+    emit({"phase": "train_window", "device": device_report(), **run,
+          "first_loss_plain": ref_loss, "op_fallbacks": fallbacks,
+          "cache_dir": cache_dir, **compile_report(watch)})
+    on_tpu = platform == "tpu"
+    check_train_run(run, WINDOW_TRAIN_KERNELS | TRAIN_KERNELS | XENT_KERNELS
+                    if on_tpu else set())
+    check(abs(run["losses"][0] - ref_loss) <= LOSS_TOL,
+          f"first loss {run['losses'][0]} against {ref_loss} from the "
+          f"plain forward (tolerance {LOSS_TOL})")
+    check(not fallbacks, f"ops fell back to pure JAX: {fallbacks}")
+
+
 def train4_phase(cfg_kwargs: dict, *, platform: str, batch: int,
                  steps: int, seed: int) -> None:
     """Four chips: the same step on `MeshSpec(fsdp=2, tensor=2)` and on
@@ -970,7 +1039,7 @@ def main() -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--chips", type=int, choices=(1, 4), default=1)
     ap.add_argument("--seed", type=int, default=0)
-    ap.add_argument("--phase", choices=("train", "train4",
+    ap.add_argument("--phase", choices=("train", "train4", "train-window",
                                         "serve-retention", "serve-hybrid",
                                         "serve-window", "serve-mamba",
                                         "serve-parallel-hybrid"),
@@ -980,6 +1049,10 @@ def main() -> int:
     if args.phase == "train":
         train_phase(TRAIN_CFG, platform="tpu", batch=8, steps=12,
                     seed=args.seed)
+        return 0
+    if args.phase == "train-window":
+        train_window_phase(WINDOW_TRAIN_CFG, platform="tpu", batch=2,
+                           steps=8, seed=args.seed)
         return 0
     if args.phase == "train4":
         train4_phase(TRAIN_CFG, platform="tpu", batch=8, steps=8,
@@ -1032,6 +1105,7 @@ def main() -> int:
             run_phase_child("serve-mamba", args.seed)
             run_phase_child("serve-parallel-hybrid", args.seed)
             run_phase_child("train", args.seed)
+            run_phase_child("train-window", args.seed)
         else:
             run_phase_child("train4", args.seed)
             serve_phase(WIDTHS, platform="tpu", replicas=2, streams=6,

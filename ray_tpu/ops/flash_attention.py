@@ -77,6 +77,10 @@ NEG_INF = -1e30
 # the first scope it meets (`jvp(xent_fwd)` -> `%jvp_xent_fwd_.N`), so
 # every call sits in a `named_scope` of its own name that takes the wrap.
 FLASH_FWD, FLASH_DQ, FLASH_DKV = "flash_fwd", "flash_dq", "flash_dkv"
+# A banded call's three (`window` given and under T), so that a trace
+# tells a window layer from a full one.
+FLASH_FWD_BAND, FLASH_DQ_BAND, FLASH_DKV_BAND = (
+    "flash_fwd_band", "flash_dq_band", "flash_dkv_band")
 
 # `checkpoint_name`s of the two things only the forward kernel can make:
 # its output and its per-row logsumexp. A `jax.checkpoint` policy that
@@ -105,6 +109,27 @@ def _causal_mask(s, q_start, k_start):
     ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
              - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
     return jnp.where(ahead >= k_start - q_start, s, NEG_INF)
+
+
+def _band_mask(s, q_start, k_start, window: int):
+    # the band's lower edge: row i keeps the columns j > i - window
+    ahead = (jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
+             - jax.lax.broadcasted_iota(jnp.int32, s.shape, 1))
+    return jnp.where(ahead < window + k_start - q_start, s, NEG_INF)
+
+
+# What a walk tells a tile of its place: wholly inside (no mask), on the
+# diagonal, on the band's lower edge, or on both (a window narrower than
+# two tiles).
+_FREE, _DIAG, _EDGE, _BOTH = False, True, "edge", "both"
+
+
+def _masked(s, kind, q_start, k_start, window):
+    if kind in (_DIAG, _BOTH):
+        s = _causal_mask(s, q_start, k_start)
+    if kind in (_EDGE, _BOTH):
+        s = _band_mask(s, q_start, k_start, window)
+    return s
 
 
 def _lanes(x, n: int):
@@ -136,16 +161,46 @@ def _crossed(start, width, sub, block, n):
     return jnp.clip(lo, 0, n), jnp.clip(hi, 0, n)
 
 
-def executed_share(plan: _Plan, t: int, causal: bool) -> float:
+def _under_band(q_start, plan: _Plan, window: int, block, n):
+    """A q sub-block's rows against the resident kv block's `n`
+    sub-blocks and the band's lower edge (row i reads j > i - window):
+    the sub-blocks under `lo` lie wholly under the band and are skipped,
+    [lo, hi) are crossed by its edge."""
+    first = block * n
+    lo = (q_start - window + 1) // plan.sub_kv - first
+    hi = (q_start + plan.sub_q - 1 - window) // plan.sub_kv + 1 - first
+    return jnp.clip(lo, 0, n), jnp.clip(hi, 0, n)
+
+
+def _past_band(k_start, plan: _Plan, window: int, block, n, t: int):
+    """The same for a kv sub-block's columns against the resident q
+    block: the band's edge crosses q sub-blocks [lo, hi), and those from
+    `hi` on read none of these columns (or lie past the sequence's `t`
+    rows: a grid step held on the last q block)."""
+    first = block * n
+    lo = (k_start + window) // plan.sub_q - first
+    hi = jnp.minimum(
+        (k_start + plan.sub_kv + window + plan.sub_q - 2) // plan.sub_q,
+        t // plan.sub_q) - first
+    return jnp.clip(lo, 0, n), jnp.clip(hi, 0, n)
+
+
+def executed_share(plan: _Plan, t: int, causal: bool,
+                   window: int | None = None) -> float:
     """Score elements the kernels compute, as a share of the T x T
     square. A causal pass needs half; tiles the diagonal crosses are
-    computed whole. Counted with the walks' own spans: for each q
-    sub-block, the kv tiles up to where `_walk_kv` stops."""
+    computed whole, and with a `window` those its lower edge crosses,
+    while the tiles under the band are not visited. Counted with the
+    walks' own spans: for each q sub-block, the kv tiles from where
+    `_walk_kv` starts to where it stops."""
     if not causal:
         return 1.0
-    tiles = sum(int(_crossed(q0, plan.sub_q, plan.sub_kv, 0,
-                             t // plan.sub_kv)[1])
-                for q0 in range(0, t, plan.sub_q))
+    n = t // plan.sub_kv
+    tiles = 0
+    for q0 in range(0, t, plan.sub_q):
+        tiles += int(_crossed(q0, plan.sub_q, plan.sub_kv, 0, n)[1])
+        if window is not None:
+            tiles -= int(_under_band(q0, plan, window, 0, n)[0])
     return tiles * plan.sub_q * plan.sub_kv / (t * t)
 
 
@@ -162,38 +217,136 @@ def _walk(lo, hi, n, tile, *args):
         jax.lax.fori_loop(lo, hi, lambda i, _: tile(i, *args), None)
 
 
-def _walk_kv(tile, q_start, kv_block, *, causal: bool, plan: _Plan):
+def _on_diagonal(plan: _Plan, window: int | None):
+    """What the diagonal's tiles are told: the band's edge can cross one
+    of them only where the window is narrower than two tiles."""
+    if window is not None and window < plan.sub_q + plan.sub_kv - 1:
+        return _BOTH
+    return _DIAG
+
+
+def _walk_kv(tile, q_start, kv_block, *, causal: bool, plan: _Plan,
+             window: int | None = None):
     """Walk the resident kv block's sub-blocks for one sub-block of q
-    rows: unmasked below the diagonal, masked where it crosses."""
+    rows: unmasked below the diagonal, masked where it crosses. With a
+    `window` the walk starts at the first tile the band reaches and masks
+    those its lower edge crosses."""
     n = plan.block_kv // plan.sub_kv
     if not causal:
-        return _walk(0, n, n, tile, False)
+        return _walk(0, n, n, tile, _FREE)
     lo, hi = _crossed(q_start, plan.sub_q, plan.sub_kv, kv_block, n)
-    _walk(0, lo, n, tile, False)
-    _walk(lo, hi, n, tile, True)
+    if window is None:
+        _walk(0, lo, n, tile, _FREE)
+        _walk(lo, hi, n, tile, _DIAG)
+        return
+    first, inside = _under_band(q_start, plan, window, kv_block, n)
+    inside = jnp.minimum(inside, lo)
+    _walk(first, inside, n, tile, _EDGE)
+    _walk(inside, lo, n, tile, _FREE)
+    _walk(lo, hi, n, tile, _on_diagonal(plan, window))
 
 
-def _walk_q(tile, k_start, q_block, *, causal: bool, plan: _Plan):
+def _walk_q(tile, k_start, q_block, *, causal: bool, plan: _Plan,
+            window: int | None = None, t: int = 0):
     """The same for one sub-block of kv rows over the resident q block:
-    from the diagonal down."""
+    from the diagonal down, and with a `window` no further than the last
+    q tile that reads these columns."""
     n = plan.block_q // plan.sub_q
     if not causal:
-        return _walk(0, n, n, tile, False)
+        return _walk(0, n, n, tile, _FREE)
     lo, hi = _crossed(k_start, plan.sub_kv, plan.sub_q, q_block, n)
-    _walk(lo, hi, n, tile, True)
-    _walk(hi, n, n, tile, False)
+    if window is None:
+        _walk(lo, hi, n, tile, _DIAG)
+        _walk(hi, n, n, tile, _FREE)
+        return
+    edge, last = _past_band(k_start, plan, window, q_block, n, t)
+    last = jnp.maximum(last, hi)
+    edge = jnp.clip(edge, hi, last)
+    _walk(lo, hi, n, tile, _on_diagonal(plan, window))
+    _walk(hi, edge, n, tile, _FREE)
+    _walk(edge, last, n, tile, _EDGE)
+
+
+class _Span(NamedTuple):
+    """Which blocks of the sequential grid axis a step of the parallel
+    axes can need, as functions of that step's block on the other axis:
+    `first(i)` .. `last(i)`, and `steps`, how many the grid walks. The
+    kernel's block at step `s` is `first(i) + s`; its index map holds a
+    step past `last(i)` (or, counting from 0, before `first(i)`) on the
+    nearest block it does need, so that the step moves no bytes
+    (`grouped_experts._held_slice` does the same), and its body's walks
+    are empty there. `None` in place of a `_Span`: the axis is walked
+    whole, one block at a time, as a call of one block or a non-causal
+    one is."""
+    first: object
+    last: object
+    steps: int
+    offset: bool        # the grid's step 0 is `first(i)`, not block 0
+
+
+def _kv_span(t: int, plan: _Plan, causal: bool, window: int | None):
+    """The kv blocks a q block needs (forward and dQ)."""
+    n_q, n_kv = t // plan.block_q, t // plan.block_kv
+    if not causal or n_kv == 1:
+        return None
+    bq, bkv = plan.block_q, plan.block_kv
+
+    def last(i):
+        return ((i + 1) * bq - 1) // bkv
+
+    if window is None:
+        return _Span(lambda i: 0, last, n_kv, False)
+
+    def first(i):
+        return jnp.maximum(i * bq - window + 1, 0) // bkv
+
+    steps = max(((i + 1) * bq - 1) // bkv - max(i * bq - window + 1, 0) // bkv
+                for i in range(n_q)) + 1
+    return _Span(first, last, steps, True)
+
+
+def _q_span(t: int, plan: _Plan, causal: bool, window: int | None):
+    """The q blocks a kv block needs (dK/dV)."""
+    n_q, n_kv = t // plan.block_q, t // plan.block_kv
+    if not causal or n_q == 1:
+        return None
+    bq, bkv = plan.block_q, plan.block_kv
+
+    def first(j):
+        return (j * bkv) // bq
+
+    if window is None:
+        return _Span(first, lambda j: n_q - 1, n_q, False)
+
+    def last(j):
+        return jnp.minimum(((j + 1) * bkv + window - 2) // bq, n_q - 1)
+
+    steps = max(min(((j + 1) * bkv + window - 2) // bq, n_q - 1)
+                - (j * bkv) // bq for j in range(n_kv)) + 1
+    return _Span(first, last, steps, True)
+
+
+def _held(span: _Span | None, i, s):
+    """(the block the body of step `s` works on, the block its index map
+    names)."""
+    if span is None:
+        return s, s
+    at = span.first(i) + s if span.offset else s
+    return at, jnp.clip(at, span.first(i), span.last(i))
 
 
 def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
-                  causal: bool, plan: _Plan, with_lse: bool):
+                  causal: bool, plan: _Plan, with_lse: bool,
+                  window: int | None = None, span: _Span | None = None):
     if with_lse:
         lse_ref, m_scr, l_scr, acc_scr = rest
     else:
         m_scr, l_scr, acc_scr = rest
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    ki = _held(span, qi, step)[0]
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         m_scr[:] = jnp.full_like(m_scr, NEG_INF)
         l_scr[:] = jnp.zeros_like(l_scr)
@@ -210,9 +363,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
             s = jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)            # [sq, skv]
+            # a row whose columns of this tile are all masked adds
+            # garbage at the running maximum NEG_INF, which the first
+            # live column's correction exp(NEG_INF - m) = 0 wipes; every
+            # row has one, its own position
             if masked:
-                s = _causal_mask(
-                    s, q_start, ki * plan.block_kv + j * plan.sub_kv)
+                s = _masked(s, masked, q_start,
+                            ki * plan.block_kv + j * plan.sub_kv, window)
             m_prev = m_scr[rows, :]                            # [sq, 128]
             m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
             corr = jnp.exp(m_prev - m_new)
@@ -228,12 +385,12 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
             acc_scr[rows, :] = (acc_scr[rows, :]
                                 * _lanes(corr, pv.shape[1]) + pv)
 
-        _walk_kv(tile, q_start, ki, causal=causal, plan=plan)
+        _walk_kv(tile, q_start, ki, causal=causal, plan=plan, window=window)
 
     n_q = plan.block_q // plan.sub_q
     _walk(0, n_q, n_q, q_rows)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         o_ref[0] = (acc_scr[:] / _lanes(l_scr[:], acc_scr.shape[1])
                     ).astype(o_ref.dtype)
@@ -241,38 +398,55 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, *rest, sm_scale: float,
             lse_ref[0] = m_scr[:] + jnp.log(l_scr[:])
 
 
-def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, plan: _Plan,
-                interpret: bool, with_lse: bool):
-    """q, k: [BH, T, D], v: [BH, T, Dv] with T divisible by both block
-    sizes.
+def _q_side_specs(plan: _Plan, group: int, span: _Span | None, d: int,
+                  dv: int):
+    """Block specs of the grids that hold a q block while kv blocks go by
+    (forward, dQ): (a q block of width w, K, V). Query head `b` reads
+    key-value head `b // group`: K and V are never repeated in HBM."""
+    def q_rows(w):
+        return pl.BlockSpec((1, plan.block_q, w), lambda b, i, j: (b, i, 0))
 
-    Returns (out [BH, T, Dv], lse) where lse is [BH, T, 128] f32 (per-row
-    logsumexp broadcast across the lane tile) when with_lse, else None."""
+    if group == 1 and span is None:
+        def kv_at(b, i, j):
+            return b, j, 0
+    else:
+        def kv_at(b, i, j):
+            return b // group, _held(span, i, j)[1], 0
+    return (q_rows, pl.BlockSpec((1, plan.block_kv, d), kv_at),
+            pl.BlockSpec((1, plan.block_kv, dv), kv_at))
+
+
+def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, plan: _Plan,
+                interpret: bool, with_lse: bool, window: int | None = None):
+    """q [BHq, T, D]; k [BHkv, T, D], v [BHkv, T, Dv], BHq a multiple of
+    BHkv, with T divisible by both block sizes.
+
+    Returns (out [BHq, T, Dv], lse) where lse is [BHq, T, 128] f32
+    (per-row logsumexp broadcast across the lane tile) when with_lse,
+    else None."""
     bh, t, d = q.shape
     dv = v.shape[-1]
-    block_q, block_kv = plan.block_q, plan.block_kv
-    grid = (bh, t // block_q, t // block_kv)
+    block_q = plan.block_q
+    span = _kv_span(t, plan, causal, window)
+    grid = (bh, t // block_q, span.steps if span else t // plan.block_kv)
+    name = FLASH_FWD if window is None else FLASH_FWD_BAND
 
     kernel = functools.partial(
         _flash_kernel, sm_scale=sm_scale, causal=causal, plan=plan,
-        with_lse=with_lse)
+        with_lse=with_lse, window=window, span=span)
+    q_rows, kspec, vspec = _q_side_specs(plan, bh // k.shape[0], span, d, dv)
     out_shape = [jax.ShapeDtypeStruct((bh, t, dv), q.dtype)]
-    out_specs = [pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))]
+    out_specs = [q_rows(dv)]
     if with_lse:
         out_shape.append(jax.ShapeDtypeStruct((bh, t, 128), jnp.float32))
-        out_specs.append(
-            pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0)))
-    with jax.named_scope(FLASH_FWD):
+        out_specs.append(q_rows(128))
+    with jax.named_scope(name):
         res = pl.pallas_call(
             kernel,
-            name=FLASH_FWD,
+            name=name,
             out_shape=tuple(out_shape),
             grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0)),
-                pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0)),
-                pl.BlockSpec((1, block_kv, dv), lambda b, i, j: (b, j, 0)),
-            ],
+            in_specs=[q_rows(d), kspec, vspec],
             out_specs=tuple(out_specs),
             scratch_shapes=[
                 pltpu.VMEM((block_q, 128), jnp.float32),   # m, lanes alike
@@ -291,20 +465,19 @@ def _flash_bhtd(q, k, v, *, sm_scale: float, causal: bool, plan: _Plan,
 # ---------------------------------------------------------------------------
 
 def _recompute_p_ds(q, k, v, do, lse, delta, q_start, k_start, *,
-                    sm_scale: float, masked: bool):
+                    sm_scale: float, masked, window: int | None = None):
     """Rebuild one tile of the probabilities and of dS from saved
     lse/delta — the shared core of both backward kernels, so a
     masking/scaling change can never diverge between dQ and dK/dV.
     q, do: [sq, D] f32; k, v: [skv, D] f32; lse, delta: [sq, 128], every
     lane alike, as they arrive.
-    `masked` is static: the walk knows which tiles the diagonal
-    crosses."""
+    `masked` is static: the walk knows which tiles the diagonal and the
+    band's edge cross."""
     s = jax.lax.dot_general(
         q * sm_scale, k,
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)     # [sq, skv]
-    if masked:
-        s = _causal_mask(s, q_start, k_start)
+    s = _masked(s, masked, q_start, k_start, window)
     p = jnp.exp(s - _lanes(lse, s.shape[1]))    # [sq, skv]
     dp = jax.lax.dot_general(
         do, v,
@@ -315,11 +488,13 @@ def _recompute_p_ds(q, k, v, do, lse, delta, q_start, k_start, *,
 
 
 def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
-               dq_scr, *, sm_scale: float, causal: bool, plan: _Plan):
+               dq_scr, *, sm_scale: float, causal: bool, plan: _Plan,
+               window: int | None = None, span: _Span | None = None):
     qi = pl.program_id(1)
-    ki = pl.program_id(2)
+    step = pl.program_id(2)
+    ki = _held(span, qi, step)[0]
 
-    @pl.when(ki == 0)
+    @pl.when(step == 0)
     def _init():
         dq_scr[:] = jnp.zeros_like(dq_scr)
 
@@ -338,29 +513,35 @@ def _dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref, dq_ref,
             _, ds = _recompute_p_ds(
                 q, k, v, do, lse, delta, q_start,
                 ki * plan.block_kv + j * plan.sub_kv,
-                sm_scale=sm_scale, masked=masked)
+                sm_scale=sm_scale, masked=masked, window=window)
             dq_scr[rows, :] += sm_scale * jax.lax.dot_general(
                 ds, k,
                 dimension_numbers=(((1,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)     # [sq, D]
 
-        _walk_kv(tile, q_start, ki, causal=causal, plan=plan)
+        _walk_kv(tile, q_start, ki, causal=causal, plan=plan, window=window)
 
     n_q = plan.block_q // plan.sub_q
     _walk(0, n_q, n_q, q_rows)
 
-    @pl.when(ki == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dq_ref[0] = dq_scr[:].astype(dq_ref.dtype)
 
 
 def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dk_ref, dv_ref, dk_scr, dv_scr, *, sm_scale: float,
-                causal: bool, plan: _Plan):
+                causal: bool, plan: _Plan, window: int | None = None,
+                span: _Span | None = None, steps: int | None = None,
+                t: int = 0):
+    """The innermost grid axis walks, for each query head of the key-value
+    head's group in turn, the `steps` q blocks the kv block needs; dK and
+    dV are summed over all of them in VMEM and written once."""
     ki = pl.program_id(1)
-    qi = pl.program_id(2)
+    step = pl.program_id(2)
+    qi = _held(span, ki, step if steps is None else step % steps)[0]
 
-    @pl.when(qi == 0)
+    @pl.when(step == 0)
     def _init():
         dk_scr[:] = jnp.zeros_like(dk_scr)
         dv_scr[:] = jnp.zeros_like(dv_scr)
@@ -378,7 +559,7 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
             p, ds = _recompute_p_ds(
                 q, k, v, do, lse_ref[0, rows, :], delta_ref[0, rows, :],
                 qi * plan.block_q + i * plan.sub_q, k_start,
-                sm_scale=sm_scale, masked=masked)
+                sm_scale=sm_scale, masked=masked, window=window)
             dv_scr[cols, :] += jax.lax.dot_general(
                 p, do,
                 dimension_numbers=(((0,), (0,)), ((), ())),
@@ -388,59 +569,79 @@ def _dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 dimension_numbers=(((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)     # [skv, D]
 
-        _walk_q(tile, k_start, qi, causal=causal, plan=plan)
+        _walk_q(tile, k_start, qi, causal=causal, plan=plan, window=window,
+                t=t)
 
     n_kv = plan.block_kv // plan.sub_kv
     _walk(0, n_kv, n_kv, kv_rows)
 
-    @pl.when(qi == pl.num_programs(2) - 1)
+    @pl.when(step == pl.num_programs(2) - 1)
     def _finalize():
         dk_ref[0] = dk_scr[:].astype(dk_ref.dtype)
         dv_ref[0] = dv_scr[:].astype(dv_ref.dtype)
 
 
 def _flash_bwd_bhtd(q, k, v, do, lse, delta, *, sm_scale: float,
-                    causal: bool, plan: _Plan, interpret: bool):
-    """q, k [BH, T, D]; v, do [BH, T, Dv] (lse/delta [BH, T, 128] f32)
-    -> (dq, dk, dv)."""
+                    causal: bool, plan: _Plan, interpret: bool,
+                    window: int | None = None):
+    """q [BHq, T, D], do [BHq, T, Dv]; k [BHkv, T, D], v [BHkv, T, Dv]
+    (lse/delta [BHq, T, 128] f32) -> (dq [BHq, T, D], dk, dv as k, v:
+    summed over a key-value head's group of query heads)."""
     bh, t, d = q.shape
     dv = v.shape[-1]
+    group = bh // k.shape[0]
     block_q, block_kv = plan.block_q, plan.block_kv
-    common = dict(sm_scale=sm_scale, causal=causal, plan=plan)
+    common = dict(sm_scale=sm_scale, causal=causal, plan=plan, window=window)
+    banded = window is not None
+    name_dq, name_dkv = ((FLASH_DQ_BAND, FLASH_DKV_BAND) if banded
+                         else (FLASH_DQ, FLASH_DKV))
 
-    qspec = pl.BlockSpec((1, block_q, d), lambda b, i, j: (b, i, 0))
-    kspec = pl.BlockSpec((1, block_kv, d), lambda b, i, j: (b, j, 0))
-    vspec = pl.BlockSpec((1, block_kv, dv), lambda b, i, j: (b, j, 0))
-    dospec = pl.BlockSpec((1, block_q, dv), lambda b, i, j: (b, i, 0))
-    rowq = pl.BlockSpec((1, block_q, 128), lambda b, i, j: (b, i, 0))
-    with jax.named_scope(FLASH_DQ):
+    span = _kv_span(t, plan, causal, window)
+    q_rows, kspec, vspec = _q_side_specs(plan, group, span, d, dv)
+    with jax.named_scope(name_dq):
         dq = pl.pallas_call(
-            functools.partial(_dq_kernel, **common),
-            name=FLASH_DQ,
+            functools.partial(_dq_kernel, **common, span=span),
+            name=name_dq,
             out_shape=jax.ShapeDtypeStruct((bh, t, d), q.dtype),
-            grid=(bh, t // block_q, t // block_kv),
-            in_specs=[qspec, kspec, vspec, dospec, rowq, rowq],
-            out_specs=qspec,
+            grid=(bh, t // block_q, span.steps if span else t // block_kv),
+            in_specs=[q_rows(d), kspec, vspec, q_rows(dv), q_rows(128),
+                      q_rows(128)],
+            out_specs=q_rows(d),
             scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
         )(q, k, v, do, lse, delta)
 
-    # dKV grid: kv blocks parallel, q blocks innermost/sequential.
-    qspec2 = pl.BlockSpec((1, block_q, d), lambda b, j, i: (b, i, 0))
+    # dKV grid: kv blocks parallel, q blocks innermost/sequential, and
+    # with grouped heads the group's query heads around them.
+    span = _q_span(t, plan, causal, window)
+    steps = span.steps if span else t // block_q
+    if group == 1 and span is None:
+        steps = None            # the axis is the q blocks and no more
+
+        def q_at(b, j, i):
+            return b, i, 0
+    else:
+        def q_at(b, j, s):
+            return b * group + s // steps, _held(span, j, s % steps)[1], 0
+
+    def q_rows2(w):
+        return pl.BlockSpec((1, block_q, w), q_at)
+
     kspec2 = pl.BlockSpec((1, block_kv, d), lambda b, j, i: (b, j, 0))
     vspec2 = pl.BlockSpec((1, block_kv, dv), lambda b, j, i: (b, j, 0))
-    dospec2 = pl.BlockSpec((1, block_q, dv), lambda b, j, i: (b, i, 0))
-    rowq2 = pl.BlockSpec((1, block_q, 128), lambda b, j, i: (b, i, 0))
-    with jax.named_scope(FLASH_DKV):
+    with jax.named_scope(name_dkv):
         dk, dv = pl.pallas_call(
-            functools.partial(_dkv_kernel, **common),
-            name=FLASH_DKV,
-            out_shape=(jax.ShapeDtypeStruct((bh, t, d), k.dtype),
-                       jax.ShapeDtypeStruct((bh, t, dv), v.dtype)),
-            grid=(bh, t // block_kv, t // block_q),
-            in_specs=[qspec2, kspec2, vspec2, dospec2, rowq2, rowq2],
+            functools.partial(_dkv_kernel, **common, span=span, steps=steps,
+                              t=t),
+            name=name_dkv,
+            out_shape=(jax.ShapeDtypeStruct(k.shape, k.dtype),
+                       jax.ShapeDtypeStruct(v.shape, v.dtype)),
+            grid=(k.shape[0], t // block_kv,
+                  group * steps if steps else t // block_q),
+            in_specs=[q_rows2(d), kspec2, vspec2, q_rows2(dv), q_rows2(128),
+                      q_rows2(128)],
             out_specs=(kspec2, vspec2),
             scratch_shapes=[pltpu.VMEM((block_kv, d), jnp.float32),
                             pltpu.VMEM((block_kv, dv), jnp.float32)],
@@ -511,15 +712,30 @@ def _unbhtd(x, b: int, h: int, d: int):
     return x.reshape(b, h, *x.shape[1:]).transpose(0, 2, 1, 3)[..., :d]
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def flash_attention(q, k, v, causal: bool = False, block_q: int = 2048,
-                    block_kv: int = 2048):
-    """[B, T, H, D] attention; falls back to the XLA path on
-    TPU-unfriendly shapes. Fully differentiable: both directions are
-    Pallas kernels (backward = dQ + dKV kernels over saved lse). v may
-    have another width than q and k (latent attention: 192 against 128);
-    the scale is that of q's width, the output has v's, and neither is
-    padded to the other.
+                    block_kv: int = 2048, window: int | None = None):
+    """q [B, T, Hq, D], k, v [B, T, Hkv, D] attention; falls back to the
+    XLA path on TPU-unfriendly shapes. Fully differentiable: both
+    directions are Pallas kernels (backward = dQ + dKV kernels over saved
+    lse). v may have another width than q and k (latent attention: 192
+    against 128); the scale is that of q's width, the output has v's, and
+    neither is padded to the other.
+
+    Grouped heads: Hq a multiple of Hkv, query head `h` reads key-value
+    head `h // (Hq // Hkv)` through the kernels' index maps. K and V are
+    never repeated in HBM, and dK, dV are summed over a group's query
+    heads inside the dK/dV kernel, whose innermost grid axis walks them.
+
+    `window` (causal calls): position i reads `i - window < j <= i`. The
+    tile walks get a lower bound from the band as they have an upper one
+    from the diagonal, the tiles its lower edge crosses are masked, and
+    the grid's sequential axis is only as long as the blocks one step's
+    band can touch; the three kernels then run under the `_band` names. A
+    window of T or more is no band and runs the plain kernels. Where a
+    causal call's grid has more than one block on the sequential axis, a
+    step outside the diagonal (or the band) stays on the nearest block
+    it needs and fetches nothing.
 
     `block_q` / `block_kv` are upper bounds: blocks shrink to the largest
     divisor of T, so ragged sequence lengths stay on the kernel path. The
@@ -563,32 +779,81 @@ def flash_attention(q, k, v, causal: bool = False, block_q: int = 2048,
 
     The caller passes the bounds (`models/latent_sparse_moe.py`: 1024 x
     2048, 56.1 ms the three together against 56.4 and 60.2); the default
-    bounds and the (d, d) plan above are as they were."""
-    out, _ = _flash_forward_impl(q, k, v, causal, block_q, block_kv,
+    bounds and the (d, d) plan above are as they were.
+
+    Grouped heads at a long sequence, banded and full, swept the same way
+    (`benchmarks/tools/flash_group_sweep.py`, PERF.md, PR 57) at (Hq,
+    Hkv, T, D) = (32, 4, 32768, 128), B = 1, device ms a call, block_q x
+    block_kv, tile; `share`: the part of the square the walks compute
+    (the band itself is 0.0308 of it, the triangle 0.5000):
+
+        window 1,024             share    forward   dQ        dK/dV
+        2048 x 2048, 512 x 512   0.0461    6.326     8.133     9.953
+        1024 x 2048, 512 x 512   0.0461    6.469     9.440    10.180
+        1024 x 1024, 512 x 512   0.0461    6.419     8.158    10.047
+         512 x 1024, 512 x 512   0.0461    6.573     8.367    10.796
+        1024 x 2048, 256 x 256   0.0385   11.031    12.116    13.262
+        1024 x 1024, 256 x 256   0.0385   10.987    10.879    13.071
+         512 x  512, 256 x 256   0.0385   11.562    11.729    13.995
+        no window
+        2048 x 2048, 512 x 512   0.5078   61.254    85.864   104.446
+        1024 x 2048, 512 x 512   0.5078   62.033    86.805   107.286
+        2048 x 1024, 512 x 512   0.5078   63.811    91.794   105.578
+        1024 x 1024, 512 x 512   0.5078   65.216    93.567   109.554
+        1024 x 2048, 256 x 256   0.5039  133.197   135.951   166.268
+
+    At D = 128, 2048 x 2048 fits and is the quickest for both, so
+    `models/window_moe_train.py` passes those bounds: a window layer's
+    three kernels take 24.4 ms where a full layer's take 251.6. Under a
+    window of 1,024 a 512-row tile reads 1,536 columns (the edge's tile,
+    a whole one, the diagonal's) for the 1,024 it needs; a 256-wide tile
+    reads 1,280 and loses more than that to the cost of a tile. dK and dV
+    are summed over a group's 8 query heads in the kernel's scratch, one
+    write a key-value head: eight partial gradients of [32, T, 128]
+    summed outside would add 0.5 GB of traffic a layer and were not
+    built."""
+    out, _ = _flash_forward_impl(q, k, v, causal, block_q, block_kv, window,
                                  with_lse=False)
     return out
 
 
-def _flash_forward_impl(q, k, v, causal, block_q, block_kv, with_lse):
+def _band(window, causal: bool, t: int):
+    """The call's window as the kernels take it: None where it cuts
+    nothing off."""
+    if window is None:
+        return None
+    if not causal or window < 1:
+        raise ValueError("a window is a causal call's, of one position "
+                         "or more")
+    return None if window >= t else int(window)
+
+
+def _flash_forward_impl(q, k, v, causal, block_q, block_kv, window,
+                        with_lse):
     """Returns (out, lse|None). lse is None on the XLA fallback path or
     when with_lse=False (the inference variant, which skips the lse
     write entirely)."""
     b, t, h, d = q.shape
     dv = v.shape[-1]
+    if h % k.shape[2] or k.shape[2] != v.shape[2]:
+        raise ValueError(f"{h} query heads do not divide over "
+                         f"{k.shape[2]} key and {v.shape[2]} value heads")
+    window = _band(window, causal, t)
     plan = _plan_blocks(t, block_q, block_kv)
     if plan is None:
         backend.note_fallback("flash_attention", f"T={t}")
-        return reference_attention(q, k, v, causal=causal), None
+        return reference_attention(q, k, v, causal=causal,
+                                   window=window), None
     interpret = backend.interpret()
     out, lse = _flash_bhtd(_bhtd(q), _bhtd(k), _bhtd(v), sm_scale=d ** -0.5,
                            causal=causal, plan=plan, interpret=interpret,
-                           with_lse=with_lse)
+                           with_lse=with_lse, window=window)
     return _unbhtd(out, b, h, dv), lse
 
 
-def _flash_fwd(q, k, v, causal, block_q, block_kv):
+def _flash_fwd(q, k, v, causal, block_q, block_kv, window):
     out, lse = _flash_forward_impl(q, k, v, causal, block_q, block_kv,
-                                   with_lse=True)
+                                   window, with_lse=True)
     if lse is None:
         return out, (q, k, v, None, None)
     # The kernel writes its lse across a 128-lane tile; one lane of it is
@@ -600,15 +865,18 @@ def _flash_fwd(q, k, v, causal, block_q, block_kv):
     return out, (q, k, v, out, lse)
 
 
-def _flash_bwd(causal, block_q, block_kv, res, g):
+def _flash_bwd(causal, block_q, block_kv, window, res, g):
     q, k, v, out, lse = res
+    window = _band(window, causal, q.shape[1])
     if lse is None:   # XLA fallback path (static shape decision)
         _, vjp = jax.vjp(
-            lambda q, k, v: reference_attention(q, k, v, causal=causal),
+            lambda q, k, v: reference_attention(q, k, v, causal=causal,
+                                                window=window),
             q, k, v)
         return vjp(g)
 
     b, t, h, d = q.shape
+    hkv = k.shape[2]
     dv = v.shape[-1]
     plan = _plan_blocks(t, block_q, block_kv)
     interpret = backend.interpret()
@@ -620,9 +888,10 @@ def _flash_bwd(causal, block_q, block_kv, res, g):
     lse = jnp.broadcast_to(lse[..., None], (b * h, t, 128))
     dq, dk, dv_ = _flash_bwd_bhtd(
         _bhtd(q), _bhtd(k), _bhtd(v), _bhtd(g), lse, delta,
-        sm_scale=d ** -0.5, causal=causal, plan=plan, interpret=interpret)
-    return (_unbhtd(dq, b, h, d), _unbhtd(dk, b, h, d),
-            _unbhtd(dv_, b, h, dv))
+        sm_scale=d ** -0.5, causal=causal, plan=plan, interpret=interpret,
+        window=window)
+    return (_unbhtd(dq, b, h, d), _unbhtd(dk, b, hkv, d),
+            _unbhtd(dv_, b, hkv, dv))
 
 
 flash_attention.defvjp(_flash_fwd, _flash_bwd)

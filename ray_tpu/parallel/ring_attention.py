@@ -114,14 +114,22 @@ def ring_attention(q, k, v, mesh: Mesh, *, causal: bool = False,
     return fn(q, k, v)
 
 
-def reference_attention(q, k, v, *, causal: bool = False):
-    """O(T^2)-memory reference for tests."""
+def reference_attention(q, k, v, *, causal: bool = False,
+                        window: int | None = None):
+    """O(T^2)-memory reference for tests. k and v may have fewer heads
+    than q (query head `h` reads head `h // (Hq // Hkv)`); with `window`,
+    position i reads `i - window < j` only."""
     qf, kf, vf = (x.astype(jnp.float32) for x in (q, k, v))
+    if kf.shape[2] != qf.shape[2]:
+        kf, vf = (jnp.repeat(x, qf.shape[2] // x.shape[2], axis=2)
+                  for x in (kf, vf))
     scale = qf.shape[-1] ** -0.5
     score = jnp.einsum("bqhd,bkhd->bhqk", qf * scale, kf)
     if causal:
         t = q.shape[1]
         mask = jnp.tril(jnp.ones((t, t), bool))
+        if window is not None:
+            mask &= ~jnp.tril(jnp.ones((t, t), bool), -window)
         score = jnp.where(mask, score, NEG_INF)
     p = jax.nn.softmax(score, axis=-1)
     out = jnp.einsum("bhqk,bkhd->bqhd", p, vf)

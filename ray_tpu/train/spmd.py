@@ -312,7 +312,7 @@ def _make_lm_trainer(init_fn, logical_axes, loss_fn, mesh: Mesh, rng,
                      aux_update: Callable | None = None,
                      frozen: Callable | None = None):
     """Shared assembly behind make_gpt_trainer / make_moe_trainer /
-    make_latent_moe_trainer."""
+    make_latent_moe_trainer / make_window_moe_trainer."""
     rng = jax.random.key(0) if rng is None else rng
     optimizer = optimizer or default_optimizer()
     state = None
@@ -442,3 +442,41 @@ def make_latent_moe_trainer(cfg, mesh: Mesh, rng=None,
         mesh, rng, optimizer, rules, init_state=init_state,
         aux_update=partial(lsm.update_router_bias, cfg=cfg),
         frozen=lsm.is_router_bias)
+
+
+def window_moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
+                       with_counts: bool = False):
+    """`latent_moe_loss_fn` for `models.window_moe_train`: the mean
+    negative log-likelihood of pre-shifted inputs/targets [B, T] over the
+    rows of the vocabulary held, untied head, through
+    `fused_softmax_xent`; no auxiliary loss."""
+    from ray_tpu.models import window_moe_train as wmt
+    from ray_tpu.ops.fused_xent import fused_softmax_xent
+
+    x, counts = wmt.forward_features(params, batch["inputs"], cfg, mesh)
+    with jax.named_scope(HEAD):
+        nll = fused_softmax_xent(
+            x, params["head"].astype(cfg.activation_dtype()),
+            batch["targets"], mesh=mesh)
+    loss = _mean_nll(nll, batch.get("mask"))
+    return (loss, counts) if with_counts else loss
+
+
+def make_window_moe_trainer(cfg, mesh: Mesh, rng=None,
+                            optimizer: optax.GradientTransformation | None
+                            = None,
+                            rules: dict | None = None,
+                            init_state: bool = True):
+    """`make_gpt_trainer`'s assembly for `models.window_moe_train` (window
+    and full layers through the flash kernels, banded and not, at grouped
+    heads; this chip's share of the routed experts with their backward
+    kernels). Every leaf is the optimizer's and nothing moves outside it;
+    the step's metrics carry `expert_pairs_here`, `expert_pairs_routed`,
+    `expert_load_max` and `expert_load_mean`."""
+    from ray_tpu.models import window_moe_train as wmt
+
+    return _make_lm_trainer(
+        lambda key: wmt.init_params(key, cfg), wmt.param_logical_axes(cfg),
+        partial(window_moe_loss_fn, cfg=cfg, mesh=mesh, with_counts=True),
+        mesh, rng, optimizer, rules, init_state=init_state,
+        aux_update=partial(wmt.expert_metrics, cfg=cfg))

@@ -1487,3 +1487,135 @@ def test_shared_kernels_trace_to_what_they_did(case):
                 return da.gqa_chunk_attention(q, k, v, t, p, layer=0,
                                               window=None, impl="pallas")
     assert fingerprint(fn, *args) == UNCHANGED[case]
+
+
+# -- the window / full family on the training path, at
+# `mellum2-12b-a2.5b.longctx-32k`'s shapes: one sequence of 32,768, 32
+# query heads over 4 key-value heads of 128, a window of 1,024, 16 experts
+# of 896 x 2304 held of a 64-wide router, 8 a token, 8,192 tokens a chunk
+
+def _flash_32k(window):
+    def grad(q, k, v):
+        return jax.grad(lambda q, k, v: jnp.sum(flash_attention(
+            q, k, v, True, 2048, 2048, window).astype(jnp.float32)),
+            argnums=(0, 1, 2))(q, k, v)
+    q, kv = ((1, 32768, 32, 128), BF16), ((1, 32768, 4, 128), BF16)
+    return grad, [q, kv, kv]
+
+
+FLASH_32K = {
+    "band": (1024, ("flash_fwd_band", "flash_dq_band", "flash_dkv_band")),
+    "full": (None, ("flash_fwd", "flash_dq", "flash_dkv")),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FLASH_32K))
+def test_flash_kernels_compile_at_32k_under_their_names(topo, case):
+    """The banded and the full call, forward and backward, with grouped
+    heads: K and V stay 4 heads wide (no [.., 32, 128] key or value is
+    made), and dK, dV come out of the kernel at 4 heads."""
+    window, names = FLASH_32K[case]
+    fn, args = _flash_32k(window)
+    text = compiled_text(topo, fn, *args)
+    assert sorted(kernel_names(text)) == sorted(names)
+    assert re.search(rf"%{names[2]}\.\d+ = \(bf16\[4,32768,128\]", text)
+    # nothing repeats a key or a value to the query heads: no array of
+    # q's size comes out of a broadcast, a concatenation or a gather
+    # (but the test's own dO: the gradient of a sum, a constant spread)
+    assert not [line for line in arrays_made(text, BF16, 32 * 32768 * 128)
+                if re.search(r" (broadcast|concatenate|gather)\((?!%constant)",
+                             line)]
+
+
+def test_experts_grouped_train_kernels_compile_at_width_896(topo):
+    """The same cell's expert layer, forward, dx and dw, a chunk of 8,192
+    tokens: an expert's 896 rows are whole lane tiles and no multiple of
+    256, and go through in `_width_slice`'s one slice."""
+    assert grouped_experts._width_slice(896) == (896, 1)
+
+    def loss(x, w, g, u, d, c):
+        return jnp.sum(grouped_experts.experts_grouped(
+            x, c, w, g, u, d, held_from=0, impl="pallas",
+            name=grouped_experts.EXPERTS_GROUPED_TRAIN)[0])
+    text = compiled_text(
+        topo, jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4)),
+        ((8192, 2304), BF16), ((8192, 8), F32),
+        *[((16, 896, 2304), F32)] * 3, ((8192, 8), I32))
+    assert sorted(kernel_names(text)) == [
+        "experts_grouped_dw", "experts_grouped_dx", "experts_grouped_train"]
+
+
+@pytest.mark.parametrize("width,want", [
+    (896, (896, 1)), (2688, (384, 7)), (768, (256, 3)), (2048, (256, 8)),
+    (4096, (256, 16)), (1536, (256, 6)), (32, (32, 1))])
+def test_width_slice_keeps_every_accepted_cell_s_slice(width, want):
+    """mellum2 (896), nemotron (2,688), kanana and ling (768), glm
+    (2,048), command-a-plus (4,096), kanana's shared pair (1,536) and the
+    tests' tiny experts."""
+    assert grouped_experts._width_slice(width) == want
+
+
+def test_window_train_step_compiles_at_the_cells_shapes(topo):
+    """`mellum2-12b-a2.5b.longctx-32k`'s fused dispatch (two steps) at
+    its real shapes through the chip's compiler: every kernel under its
+    name, a window layer's forward once (its output and logsumexp are
+    saved), no key or value repeated to 32 heads, and arguments and
+    temporaries inside the chip's 16 GB."""
+    from unittest import mock
+
+    from benchmarks import run as bench_run
+    from benchmarks.harness import common
+    from ray_tpu.models import window_moe_train as wmt
+    from ray_tpu.parallel.sharding import (logical_to_spec, replicated,
+                                           tree_shardings)
+    from ray_tpu.train import loop, spmd
+
+    _, cell, config, mix = bench_run.load_cell(
+        "mellum2-12b-a2.5b.longctx-32k")
+    cfg = common.model_config(config, "train", **config["program"]["train"])
+    mesh = MeshSpec(**mix["mesh"]).build(topo.devices[:1])
+    opt = spmd.default_optimizer(**config["program"]["optimizer"])
+    _, step_fn, _ = spmd.make_window_moe_trainer(cfg, mesh, optimizer=opt,
+                                                 init_state=False)
+
+    def abstract(shapes, shardings):
+        return jax.tree.map(
+            lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype,
+                                               sharding=sh),
+            shapes, shardings)
+
+    p_sh = tree_shardings(mesh, wmt.param_logical_axes(cfg))
+    p_shape = jax.eval_shape(lambda k: wmt.init_params(k, cfg),
+                             jax.random.key(0))
+    state = spmd.TrainState(
+        abstract(p_shape, p_sh),
+        abstract(jax.eval_shape(opt.init, p_shape),
+                 spmd.opt_state_shardings(opt, p_shape, p_sh, mesh)),
+        jax.ShapeDtypeStruct((), I32, sharding=replicated(mesh)))
+    tok = jax.ShapeDtypeStruct(
+        (mix["unroll"], mix["batch"], mix["seq_len"]), I32,
+        sharding=NamedSharding(mesh, PartitionSpec(
+            None, *logical_to_spec(("batch",), None, mesh), None)))
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        compiled = loop.fuse_steps(step_fn, mix["unroll"]).lower(
+            state, {"inputs": tok, "targets": tok}).compile()
+    text = compiled.as_text()
+    kernels = kernel_names(text)
+    for name in ("flash_fwd_band", "flash_dq_band", "flash_dkv_band"):
+        assert kernels.count(name) == 3, kernels
+    for name in ("flash_fwd", "flash_dq", "flash_dkv", "xent_fwd",
+                 "xent_dx", "xent_de"):
+        assert kernels.count(name) == 1, kernels
+    for name in ("experts_grouped_train", "experts_grouped_dx",
+                 "experts_grouped_dw"):
+        assert kernels.count(name) == 4, kernels
+    # the lowered step holds no [1, 32768, 32, 128] key or value: the
+    # only arrays of that size are q, the attention's output, and their
+    # gradients
+    assert not [line for line in text.splitlines()
+                if re.search(r"bf16\[1,32768,32,128\]", line)
+                and re.search(r"w_k|w_v", line)
+                and " dot(" not in line and " fusion(" not in line]
+    mem = compiled.memory_analysis()
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
+        < 15.5 * 2**30

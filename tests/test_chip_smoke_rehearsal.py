@@ -174,3 +174,16 @@ def test_fused_train_dispatch_compiles_for_v5e(n, mesh):
     through the chip's compiler: kernels present, fits the 16 GB chip."""
     out = run(f"N, MESH = {n}, {mesh}\n" + AOT_TRAIN_STEP)
     assert "KERNELS" in out
+
+
+def test_train_window_phase_tiny_on_cpu():
+    """`--phase train-window` at a tiny size: the trainer over
+    `models/window_moe_train.py` through the loop, banded and full layers
+    in interpret mode, first loss against the plain forward's."""
+    out = run("cs.train_window_phase(dict(cs.WINDOW_TRAIN_CFG, "
+              "vocab_size=256, d_model=64, n_heads=4, n_kv_heads=2, "
+              "head_dim=16, window=40, expert_ff=32, max_seq_len=128, "
+              "rope_full=(10000.0, 4.0, 32, 32.0, 1.0, 1.1), "
+              "flash_block_q=128, flash_block_kv=128, expert_chunk=128, "
+              "dtype='float32'), platform='cpu', batch=2, steps=6, seed=0)")
+    assert '"phase": "train_window"' in out
