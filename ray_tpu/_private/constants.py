@@ -375,7 +375,8 @@ SERVE_HANDLE_REFRESH_S = define(
 SERVE_STREAM_BATCH = define(
     "SERVE_STREAM_BATCH", int, 16,
     "Streaming responses ship at most this many chunks per proxy "
-    "round-trip (a reply goes out with what is ready).")
+    "round-trip (a reply goes out with what is ready and the first "
+    "chunk it had to wait for).")
 
 SERVE_STREAM_IDLE_TTL_S = define(
     "SERVE_STREAM_IDLE_TTL_S", float, 300.0,

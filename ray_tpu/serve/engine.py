@@ -3759,13 +3759,14 @@ class InferenceReplica:
     """Serve deployment hosting one InferenceEngine; `__call__` returns
     a generator of token ids, which `serve.replica` automatically turns
     into a `next_chunks` stream — so `handle.stream(prompt)` yields
-    tokens as they are decoded (a reply carries the tokens that are
-    ready, not a full batch), and concurrent requests continuously
-    batch into the shared engine's slots: each stream's reply thread is
-    a consumer of `tokens_for`, one of them at a time runs the tick and
-    the rest sleep until it ends. A client that walks away mid-stream
-    closes the generator, which cancels the request and frees its cache
-    blocks.
+    tokens as they are decoded (a reply carries the tokens made while
+    its client was away and leaves with the first it had to wait for,
+    so the gap between a stream's tokens is the engine's tick), and
+    concurrent requests continuously batch into the shared engine's
+    slots: each stream's reply thread is a consumer of `tokens_for`,
+    one of them at a time runs the tick and the rest sleep until it
+    ends. A client that walks away mid-stream closes the generator,
+    which cancels the request and frees its cache blocks.
 
     Construction takes *config kwargs*, not arrays: params are
     initialized on the replica from `seed`, so nothing heavyweight rides

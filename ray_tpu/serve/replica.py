@@ -23,11 +23,14 @@ from ray_tpu._private.constants import (
     SERVE_STREAM_IDLE_TTL_S as _STREAM_IDLE_TTL_S,
 )
 
-# How long `_next_chunks_sync` goes on collecting once it holds a chunk.
-# Well under an engine tick and well over a list's `next()`: a generator
-# that makes a token a tick answers a token a call, a fast one still
-# fills `max_chunks` in one round trip.
-_REPLY_COLLECT_S = 0.02
+# A `next()` that took longer than this had to wait for its chunk, and
+# `_next_chunks_sync` ends the reply with it. Far over a ready chunk (a
+# list's `next()`, a queue's pop: microseconds) and far under the
+# shortest engine tick (5 ms): a generator that makes a token a tick
+# answers what it made while the client was away and the token of the
+# tick the call met; a ready one still fills `max_chunks` in one round
+# trip.
+_CHUNK_WAITED_S = 1e-3
 
 
 class StreamingResponse:
@@ -79,6 +82,7 @@ class Replica:
         # pin the generator + its closure for the replica's lifetime)
         self._streams: dict[int, list] = {}
         self._stream_ids = itertools.count(1)
+        self._reply_tokens = 0      # chunks handed back, all replies
         # Sync handlers and `next_chunks` pulls get a dedicated pool
         # sized to the concurrency the deployment declared (the actor
         # admits no more calls than that at once, so no pull waits for
@@ -235,8 +239,10 @@ class Replica:
                           max_chunks: int = _STREAM_BATCH):
         """Pull the next batch of chunks from a registered stream: what
         is ready, up to `max_chunks` — the reply goes out once it holds
-        that many, or holds at least one and has been collecting for
-        `_REPLY_COLLECT_S`. Returns (chunks, done); the stream is
+        that many, or with the first chunk it had to wait for (a
+        `next()` longer than `_CHUNK_WAITED_S`: the generator is slower
+        than its consumer, and one more wait would hold back everything
+        made before). Returns (chunks, done); the stream is
         dropped when done. An unknown/TTL-reaped id returns
         (None, True) — consumers must treat that as an ERROR, not a
         clean EOF, or a reaped stream looks like a complete (truncated)
@@ -258,19 +264,21 @@ class Replica:
                 return None, True
             it = entry[0]
             chunks = []
-            done = False
-            deadline = time.perf_counter() + _REPLY_COLLECT_S
+            done = waited = False
+            asked = time.perf_counter()
             try:
-                while len(chunks) < max_chunks:
+                while len(chunks) < max_chunks and not waited:
                     chunks.append(next(it))
-                    if time.perf_counter() > deadline:
-                        break
+                    now = time.perf_counter()
+                    waited = now - asked > _CHUNK_WAITED_S
+                    asked = now
             except StopIteration:
                 done = True
-            if done:
-                with self._lock:
+            with self._lock:
+                self._reply_tokens += len(chunks)
+                if done:
                     self._streams.pop(stream_id, None)
-            reply.set(tokens=len(chunks))
+            reply.set(tokens=len(chunks), waited=int(waited))
             return chunks, done
 
     def cancel_stream(self, stream_id: int) -> bool:
@@ -298,13 +306,17 @@ class Replica:
         telemetry bridge republishes as `replica_*` series. `replies` /
         `reply_s` count the `stream/reply` spans: `next_chunks` calls
         answered and the time they held a reply thread (a
-        `jax.profiler` trace of the replica shows each one)."""
+        `jax.profiler` trace of the replica shows each one, with its
+        `tokens` and whether it ended on a chunk it `waited` for);
+        `reply_tokens` is the chunks they carried, so tokens a reply is
+        `reply_tokens / replies`."""
         with self._lock:
             out = {"inflight": self._inflight, "total": self._total,
                    "streams": len(self._streams),
                    "uptime_s": time.time() - self._started,
                    "replies": self._phases.count("stream/reply"),
-                   "reply_s": self._phases.seconds("stream/reply")}
+                   "reply_s": self._phases.seconds("stream/reply"),
+                   "reply_tokens": self._reply_tokens}
         fn = getattr(self.callable, "stats", None)
         if callable(fn) and not self._is_function:
             try:

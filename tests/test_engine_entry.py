@@ -434,3 +434,106 @@ def test_replies_run_on_the_replicas_own_executor():
     assert asyncio.run(rep.next_chunks(sid)) == ([1], True)
     assert names[0].startswith("replica-entry-test")
     assert rep._executor._max_workers == 4
+
+
+@pytest.mark.parametrize("gap", [0.005, 0.012])
+def test_a_generator_a_few_ms_a_chunk_is_answered_a_chunk_a_call(gap):
+    """Gaps under the 20 ms a reply once went on collecting for: every
+    `next()` waits, so every reply leaves with the one chunk it waited
+    for, about a gap after the call."""
+    n = 8
+
+    def slow():
+        for i in range(n):
+            time.sleep(gap)
+            yield i
+
+    rep = make_replica()
+    sid = rep._register_stream(slow())
+    took = []
+    for i in range(n):
+        t0 = time.perf_counter()
+        assert rep._next_chunks_sync(sid, 16) == ([i], False)
+        took.append(time.perf_counter() - t0)
+    assert rep._next_chunks_sync(sid, 16) == ([], True)
+    assert gap * 0.9 <= statistics.median(took) < 2 * gap + 0.005, took
+    st = rep.stats()
+    assert st["reply_tokens"] == n and st["replies"] == n + 1
+
+
+@pytest.mark.parametrize("wait", [0.012, 0.03])
+def test_a_reply_ends_with_the_first_chunk_it_waited_for(wait):
+    def bursty():
+        yield from range(3)             # ready
+        for i in range(3, 6):
+            time.sleep(wait)
+            yield i
+
+    rep = make_replica()
+    sid = rep._register_stream(bursty())
+    t0 = time.perf_counter()
+    got, done = rep._next_chunks_sync(sid, 16)
+    took = time.perf_counter() - t0
+    # the three that were ready and the one waited for, after one wait
+    assert (got, done) == ([0, 1, 2, 3], False)
+    assert wait * 0.9 <= took < 2 * wait, took
+    assert rep._next_chunks_sync(sid, 16) == ([4], False)
+    assert rep._next_chunks_sync(sid, 16) == ([5], False)
+    assert rep._next_chunks_sync(sid, 16) == ([], True)
+    assert rep.stats()["reply_tokens"] == 6
+
+
+def pull_all(rep, sid, max_chunks, replies):
+    """A callable that drains stream `sid` as a handle does, each
+    reply's chunks appended to `replies`."""
+    def run():
+        done = False
+        while not done:
+            chunks, done = rep._next_chunks_sync(sid, max_chunks)
+            replies.append(chunks)
+    return run
+
+
+def test_two_engine_streams_get_every_token_once_and_in_order(slow_ticks):
+    """Two streams of one engine behind a `Replica`, each drained by its
+    own thread through `_next_chunks_sync` as a handle would: whichever
+    thread runs the tick, each reply holds its stream's next tokens."""
+    eng = tiny_engine()
+    n_new = 6
+    want = [eng.generate(prompt(i), max_new_tokens=n_new) for i in (1, 2)]
+    slow_ticks(eng)
+    rep = make_replica()
+    sids = [rep._register_stream(eng.tokens_for(
+                eng.submit(prompt(i), max_new_tokens=n_new)))
+            for i in (1, 2)]
+    replies = {sid: [] for sid in sids}
+    run_threads([pull_all(rep, sid, 16, replies[sid]) for sid in sids])
+    assert [[int(t) for r in replies[sid] for t in r]
+            for sid in sids] == want
+    st = rep.stats()
+    assert st["reply_tokens"] == 2 * n_new and st["streams"] == 0
+    assert st["replies"] == sum(len(r) for r in replies.values())
+    # a token a tick: a reply does not hold one back to wait for the next
+    assert statistics.median(
+        len(c) for r in replies.values() for c in r if c) == 1, replies
+    eng.check_invariants()
+
+
+def test_reply_tokens_loses_no_count_under_many_reply_threads():
+    """More reply threads than cores, a short switch interval: the count
+    is the chunks handed back, whichever thread added last."""
+    import sys
+    rep = make_replica()
+    n_threads, n_chunks = 24, 400
+    sids = [rep._register_stream(iter(range(n_chunks)))
+            for _ in range(n_threads)]
+    replies = {sid: [] for sid in sids}
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        run_threads([pull_all(rep, sid, 7, replies[sid]) for sid in sids])
+    finally:
+        sys.setswitchinterval(interval)
+    assert all(sum(r, []) == list(range(n_chunks)) for r in replies.values())
+    st = rep.stats()
+    assert st["reply_tokens"] == n_threads * n_chunks and st["streams"] == 0

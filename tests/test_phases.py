@@ -605,3 +605,79 @@ def test_replica_counts_its_replies():
     assert rep._next_chunks_sync(sid, 3) == (None, True)
     st = rep.stats()
     assert st["replies"] == 3 and st["reply_s"] > 0
+    assert st["reply_tokens"] == 5
+
+
+@pytest.fixture(scope="module")
+def traced_replies(tmp_path_factory):
+    """A third session: a replica's replies over a ready stream and over
+    one that makes its consumer wait, in call order."""
+    from ray_tpu.serve.replica import Replica
+
+    def slow():
+        yield 0
+        for i in (1, 2):
+            time.sleep(0.01)
+            yield i
+
+    rep = Replica({"callable": slow, "deployment_name": "phases-replies"})
+    out = str(tmp_path_factory.mktemp("trace-replies"))
+    start_trace(out)
+    try:
+        ready = rep._register_stream(iter(range(5)))
+        made = rep._register_stream(slow())
+        got = [rep._next_chunks_sync(sid, 3)
+               for sid in (ready, ready, made, made, made)]
+    finally:
+        jax.profiler.stop_trace()
+    path, events = program_spans(out)
+    return {"path": path, "got": got, "stats": rep.stats(),
+            "replies": sorted(events["stream/reply"], key=lambda e: e[1])}
+
+
+def test_a_reply_span_says_what_it_carried_and_how_it_ended(traced_replies):
+    assert traced_replies["got"] == [
+        ([0, 1, 2], False), ([3, 4], True),     # `max_chunks`; the end
+        ([0, 1], False), ([2], False),          # each ends on a wait
+        ([], True)]
+    assert [(r[3]["tokens"], r[3]["waited"])
+            for r in traced_replies["replies"]] == [
+        (3, 0), (2, 0), (2, 1), (1, 1), (0, 0)]
+    st = traced_replies["stats"]
+    assert (st["replies"], st["reply_tokens"]) == (5, 8)
+
+
+def test_the_reply_span_s_median_is_a_metric_of_the_benchmark(
+        traced_replies, monkeypatch):
+    """`stream_reply_ms`: a data file over the reducer that was there,
+    its entry in `BENCHMARK.json` the file's own fields, its cells those
+    that report the metric it moves."""
+    import statistics
+
+    from benchmarks import run as bench_run
+    from benchmarks.harness import spans
+
+    bench = bench_run.load_json("BENCHMARK.json")
+    entry = bench_run.by_name(bench["per_layer"], "stream_reply_ms",
+                              "per-layer metric")
+    spec = bench_run.load_json("benchmarks", "layer_metrics",
+                               "stream_reply_ms.json")
+    assert {k: spec[k] for k in entry} == entry
+    assert (spec["reducer"], spec["args"]) == (
+        "span_median_ms", {"span": "stream/reply"})
+    assert (entry["layer"], entry["moves"]) == ("engine host", "tpot_p90_ms")
+    assert entry["workloads"] == bench_run.by_name(
+        bench["end_to_end"], "tpot_p90_ms", "metric")["workloads"]
+
+    ctx = {"trace": {"busy_s": 0.0}}
+    monkeypatch.setattr(spans, "summary",
+                        lambda c: spans.reduce(traced_replies["path"]))
+    want = statistics.median(e - s for _, s, e, _ in
+                             traced_replies["replies"]) * 1e-6
+    assert bench_run.read_layer_metric("stream_reply_ms", ctx) == \
+        pytest.approx(want)
+    # a trace of a program without the span, and an untraced run
+    monkeypatch.setattr(spans, "summary", lambda c: {"spans": {}})
+    assert bench_run.read_layer_metric("stream_reply_ms", ctx) is None
+    monkeypatch.setattr(spans, "summary", lambda c: None)
+    assert bench_run.read_layer_metric("stream_reply_ms", ctx) is None
