@@ -1,7 +1,10 @@
-"""The interface between `serve/engine.py` and the model families it can
-serve. A family's module builds one `ServingFamily` from its own functions
-and its configuration object returns it as `cfg.family`; the engine and
-every family import this module and none imports another family for it."""
+"""The two ways out of `ray_tpu/models/`: the interface between
+`serve/engine.py` and the model families it can serve, and the one between
+`train/spmd.py` and those it can train. A family's module builds a
+`ServingFamily`, a `TrainingFamily` or both from its own functions and its
+configuration object returns them as `cfg.family` and `cfg.training`; the
+engine, the trainer and every family import this module and none imports
+another family for it."""
 
 from __future__ import annotations
 
@@ -112,3 +115,25 @@ class ServingFamily(NamedTuple):
     state_keys: tuple = ()
     bounded_keys: tuple = ()
     bounded_tokens: int = 0
+
+
+class TrainingFamily(NamedTuple):
+    """What `train/spmd.py` asks of a family that trains through its
+    features and the fused loss (`make_features_trainer`), found as the
+    `training` of the configuration object it is given.
+
+    init_params(key, cfg) -> the float32 masters
+    param_logical_axes(cfg) -> a tuple of logical axes a leaf
+    forward_features(params, tokens [B, T], cfg, mesh) -> (final-normed
+            activations [B, T, D], which the loss multiplies by the
+            leaf `head` [V, D]; what the forward counted)
+    aux_update(params, counts, cfg) -> (params, metrics) and
+    frozen(params) -> a tree of bools: `make_train_step`'s two hooks;
+            no `frozen`: every leaf is the optimizer's
+    """
+    init_params: Callable
+    param_logical_axes: Callable
+    forward_features: Callable
+    head: str
+    aux_update: Callable
+    frozen: Callable | None = None

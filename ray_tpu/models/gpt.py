@@ -30,6 +30,8 @@ import numpy as np
 from jax.ad_checkpoint import checkpoint_name
 from jax.sharding import Mesh
 
+from ray_tpu.models.blocks import (copy_block, gated_mlp, gather_block,
+                                   rms_norm, scatter_block, weight)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.parallel.ring_attention import reference_attention, ring_attention
 
@@ -182,16 +184,11 @@ def init_params(rng, cfg: GPTConfig):
     }
 
 
-def _rms_norm(x, scale, eps: float = 1e-6):
-    var = jnp.mean(jnp.square(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
-
-
 # ---------------------------------------------------------------------------
 # forward
 # ---------------------------------------------------------------------------
 
-def _attention(q, k, v, impl: str, mesh: Mesh | None):
+def attention(q, k, v, impl: str, mesh: Mesh | None):
     """Causal self-attention on [B, T, H, Dh] by `impl`, a config's
     `attn_impl`."""
     if impl == "auto":
@@ -230,37 +227,13 @@ def _attention(q, k, v, impl: str, mesh: Mesh | None):
     return checkpoint_name(out, "attn_out")
 
 
-def _w(lp, name, adt):
-    """Resolve one per-layer matmul weight: dequantize (f32 scale per
-    output channel, then cast to the activation dtype) when the layer
-    dict carries a ``"<name>_scale"`` sibling, plain cast otherwise —
-    a static dict-key check, so f32 configs trace byte-identical code."""
-    w = lp[name]
-    s = lp.get(name + "_scale")
-    if s is None:
-        return w.astype(adt)
-    return (w.astype(jnp.float32) * s[..., None, :]).astype(adt)
-
-
 def _matmul_out(cfg: GPTConfig):
     """Element type the layer's einsums emit (`cfg.matmul_out`)."""
     return (jnp.float32 if cfg.matmul_out == "float32"
             else cfg.activation_dtype())
 
 
-def _gated_mlp(h, lp, adt, pet):
-    """SwiGLU feed-forward on normed activations h [..., D], the layer's
-    default: -> (out [..., D], None)."""
-    up = jnp.einsum("...d,df->...f", h, _w(lp, "w_up", adt),
-                    preferred_element_type=pet).astype(adt)
-    gate = jnp.einsum("...d,df->...f", h, _w(lp, "w_gate", adt),
-                      preferred_element_type=pet).astype(adt)
-    ff = jax.nn.silu(gate) * up
-    return jnp.einsum("...f,fd->...d", ff, _w(lp, "w_down", adt),
-                      preferred_element_type=pet).astype(adt), None
-
-
-def _layer(x, lp, cfg, pet, attend, ffn=None):
+def layer(x, lp, cfg, pet, attend, ffn=None):
     """One transformer layer, the only spelling of it: norm, q/k/v,
     attention, output projection, residual, norm, feed-forward, residual.
 
@@ -268,9 +241,9 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
     training, [C, D] chunked prefill, [B, D] decode, [B, W, D] verify).
     lp: this layer's param slice (f32 masters cast here, leaves that
     `serving_params` cast read as they are; int8 leaves of
-    `quantize_params` dequantized by `_w`). cfg: any config with
-    `n_heads`, `head_dim` and `activation_dtype()`. pet: the einsums'
-    output element type, the caller's choice (`_matmul_out`).
+    `quantize_params` dequantized by `blocks.weight`). cfg: any config
+    with `n_heads`, `head_dim` and `activation_dtype()`. pet: the
+    einsums' output element type, the caller's choice (`_matmul_out`).
 
     The two parts that vary come in as arguments and return
     ``(output, kept)``, where `kept` is whatever the part makes besides
@@ -282,8 +255,9 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
     adt = cfg.activation_dtype()
     lead = x.shape[:-1]
     with jax.named_scope(MIXER):
-        h = _rms_norm(x, lp["ln1_scale"].astype(adt))
-        q, k, v = (jnp.einsum("...d,dh->...h", h, _w(lp, name, adt),
+        h = rms_norm(x, lp["ln1_scale"])
+        q, k, v = (jnp.einsum("...d,dh->...h", h,
+                              weight(lp, name, adt),
                               preferred_element_type=pet).astype(adt)
                    for name in ("wq", "wk", "wv"))
         q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
@@ -291,23 +265,23 @@ def _layer(x, lp, cfg, pet, attend, ffn=None):
         att, attend_kept = attend(q, k, v)
         att = jnp.einsum("...h,hd->...d",
                          att.reshape(*lead, cfg.n_heads * cfg.head_dim),
-                         _w(lp, "wo", adt),
+                         weight(lp, "wo", adt),
                          preferred_element_type=pet).astype(adt)
         x = x + att
     with jax.named_scope(FFN):
-        h = _rms_norm(x, lp["ln2_scale"].astype(adt))
+        h = rms_norm(x, lp["ln2_scale"])
         if ffn is None:
-            ffn = partial(_gated_mlp, adt=adt, pet=pet)
+            ffn = partial(gated_mlp, adt=adt, pet=pet)
         ff, ffn_kept = ffn(h, lp)
         return x + ff, attend_kept, ffn_kept
 
 
-def _block(x, lp, cfg: GPTConfig, mesh: Mesh | None):
+def block(x, lp, cfg: GPTConfig, mesh: Mesh | None):
     """One training block. x: [B, T, D] activations in cfg.dtype; lp:
     this layer's param slice."""
-    x, _, _ = _layer(
+    x, _, _ = layer(
         x, lp, cfg, _matmul_out(cfg),
-        lambda q, k, v: (_attention(q, k, v, cfg.attn_impl, mesh), None))
+        lambda q, k, v: (attention(q, k, v, cfg.attn_impl, mesh), None))
     return x
 
 
@@ -322,7 +296,7 @@ def forward_features(params, tokens, cfg: GPTConfig,
         x = params["embed"].astype(adt)[tokens]
         x = x + params["pos_embed"].astype(adt)[:t][None]
 
-    block = partial(_block, cfg=cfg, mesh=mesh)
+    run = partial(block, cfg=cfg, mesh=mesh)
     if cfg.remat:
         # Measured on the v5e (PERF.md, PR 25): under "dots", saving the
         # flash forward's output and lse halves `flash_fwd`'s time a step
@@ -340,14 +314,14 @@ def forward_features(params, tokens, cfg: GPTConfig,
             raise ValueError(
                 f"unknown remat_policy {cfg.remat_policy!r} "
                 "(expected 'nothing' | 'dots' | 'attn_out')")
-        block = jax.checkpoint(block, policy=policy)
+        run = jax.checkpoint(run, policy=policy)
 
     def scan_body(x, lp):
-        return block(x, lp), None
+        return run(x, lp), None
 
     x, _ = jax.lax.scan(scan_body, x, params["layers"])
     with jax.named_scope(HEAD):
-        return _rms_norm(x, params["final_ln_scale"].astype(adt))
+        return rms_norm(x, params["final_ln_scale"])
 
 
 def forward(params, tokens, cfg: GPTConfig, mesh: Mesh | None = None):
@@ -488,9 +462,9 @@ def serving_params(params, cfg: GPTConfig):
     `decode_step_paged` and `verify_step_paged` read out.
 
     `weight_dtype="int8"` quantizes the `QUANTIZED_WEIGHTS`
-    (`quantize_params`); their scales stay f32, since `_w` multiplies
-    in f32 before its cast. For every `weight_dtype`, every other
-    floating leaf (`embed`, `pos_embed`, the norm scales and, with
+    (`quantize_params`); their scales stay f32, since `blocks.weight`
+    multiplies in f32 before its cast. For every `weight_dtype`, every
+    other floating leaf (`embed`, `pos_embed`, the norm scales and, with
     `weight_dtype="f32"`, the matmul stacks) takes
     `cfg.activation_dtype()`, the dtype each step casts it to at use:
     the same rounding of the same numbers, done once, so the matmuls
@@ -564,50 +538,6 @@ def init_kv_pool(cfg: GPTConfig, n_blocks: int, block_size: int,
     return pool
 
 
-def copy_block(cache, src, dst):
-    """Copy physical block `src` onto `dst` in every entry of the pool —
-    the device half of copy-on-write prefix sharing. Iterates the cache
-    dict, so an int8 pool's scale rows travel with their payload and COW
-    semantics never depend on the dtype (the block axis is axis 1 for
-    payloads and scales alike). src/dst may be traced scalars, so one
-    jit (with the cache donated) serves every copy the engine ever
-    issues."""
-    out = {}
-    for name in cache:
-        blk = jax.lax.dynamic_slice_in_dim(cache[name], src, 1, axis=1)
-        out[name] = jax.lax.dynamic_update_slice_in_dim(
-            cache[name], blk, dst, axis=1)
-    return out
-
-
-def gather_block(cache, idx):
-    """Read physical block `idx` out of every entry of the pool — the
-    device half of KV-block export for disaggregated prefill/decode
-    serving. Returns a dict of [L, block_size, H, Dh] payload rows (and
-    [L, block_size, H] scale rows for an int8 pool — iterating the
-    cache dict means scales always travel with their payload, exactly
-    like `copy_block`). `idx` may be a traced scalar, so one jit serves
-    every block a prefill engine ever exports; the cache is NOT donated
-    (the pool must survive the read)."""
-    return {name: jax.lax.dynamic_index_in_dim(
-                cache[name], idx, axis=1, keepdims=False)
-            for name in cache}
-
-
-def scatter_block(cache, block, idx):
-    """Write one exported block's rows (the dict `gather_block`
-    returned, re-hosted on the importing engine) onto physical block
-    `idx` of this pool — the device half of KV-block import. Payload
-    and scale entries land through the same index, so an int8 pool's
-    quantized rows re-install byte-identical and the decode engine's
-    attention dequantizes exactly what the prefill engine wrote. `idx`
-    may be a traced scalar; donate the cache at jit time so imports
-    update the pool in place."""
-    return {name: jax.lax.dynamic_update_slice_in_dim(
-                cache[name], block[name][:, None], idx, axis=1)
-            for name in cache}
-
-
 def _scatter_kv(cache, layer, k, v, widx):
     """Write `k`/`v` [..., H, Dh] (activation dtype; N rows over the
     leading dims) into layer `layer` (a traced scalar) of the stacked
@@ -665,9 +595,9 @@ def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
     whole = reads_pool_where_it_lies(*cache["k"].shape[2:], cache["k"].dtype,
                                      "k_scale" in cache)
 
-    def body(carry, layer):
+    def body(carry, scanned):
         x, cache = carry                # cache["k"/"v"]: [L, nb, bs, H, Dh]
-        lp, li = layer
+        lp, li = scanned
 
         def write_then_attend(q, k, v):
             if whole:
@@ -680,14 +610,14 @@ def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
                 name: jax.lax.dynamic_update_slice_in_dim(
                     cache[name], one[name], li, 0) for name in cache}
 
-        x, cache, _ = _layer(x, lp, cfg, pet, write_then_attend)
+        x, cache, _ = layer(x, lp, cfg, pet, write_then_attend)
         return (x, cache), None
 
     (x, cache), _ = jax.lax.scan(
         body, (x, cache),
         (params["layers"], jnp.arange(cfg.n_layers, dtype=jnp.int32)))
     with jax.named_scope(HEAD):
-        return _rms_norm(x, params["final_ln_scale"].astype(adt)), cache
+        return rms_norm(x, params["final_ln_scale"]), cache
 
 
 def prefill_paged(params, tokens, cache, cfg: GPTConfig,

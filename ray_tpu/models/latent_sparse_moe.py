@@ -47,10 +47,11 @@ The pool is two kinds of state under one block table: `"latent"` uint32
 `[L, n_blocks, block_size, 1, words]` (`ops/sparse_latent.py`'s row format)
 for every layer and `"index"` `[L_full, n_blocks, block_size, index_dim]`
 for the layers that own an indexer. Blocks are axis 1 of both, so
-`gpt.copy_block` / `gather_block` / `scatter_block` move them together.
+`blocks.copy_block` / `gather_block` / `scatter_block` move them together.
 
-Rotary positions are interleaved pairs. The dense layer and the shared
-expert are `gpt._gated_mlp`; the norms `gpt._rms_norm`. Parameters: the
+Rotary positions are interleaved pairs. The dense layer, the shared
+expert, the norms, the router and the held experts are `models/blocks.py`'s
+(`gated_mlp`, `rms_norm`, `expert_layer` over `cfg.experts`). Parameters: the
 tree `benchmarks/refs/latent_sparse_moe.py` documents (a list of layer
 dicts; layers differ, so they are not stacked), leaves of any float type,
 cast at use. A layer without the bottleneck has `w_q` [D, H*(nope+rope)]
@@ -64,8 +65,12 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
-from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
+from ray_tpu.models.blocks import (Experts, copy_block, expert_layer,
+                                   expert_totals, gated_mlp, gather_block,
+                                   layer_norm, mm, rms_norm, rope_pairs,
+                                   scatter_block, summarize, unembed)
+from ray_tpu.models.family import (EMBED, FFN, HEAD, MIXER, ServingFamily,
+                                   TrainingFamily)
 from ray_tpu.ops import grouped_experts, sparse_latent
 
 NEG_INF = sparse_latent.NEG_INF
@@ -186,8 +191,19 @@ class LatentSparseMoEConfig:
         return jnp.dtype(self.dtype)
 
     @property
+    def experts(self) -> Experts:
+        return Experts(
+            self.router_width, self.experts_per_token, self.norm_topk,
+            self.held_from, self.n_group, self.topk_group, self.routed_scale,
+            self.expert_round, self.sparse_impl)
+
+    @property
     def family(self):
         return FAMILY
+
+    @property
+    def training(self):
+        return TRAINING
 
 
 def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
@@ -277,7 +293,7 @@ def _flat_at(pool, layer: int, widx):
                      n_layers * nb * bs)
 
 
-def _write_latent(latent, layer: int, row, widx, cfg):
+def write_latent(latent, layer: int, row, widx, cfg):
     """Cache rows row [N, kv_rank + rope] into a layer of the latent
     pool."""
     words = latent.shape[-1]
@@ -298,55 +314,23 @@ def _write_index(index, layer: int, keys, widx):
 # pieces of the layer
 # ---------------------------------------------------------------------------
 
-def _mm(x, w, adt):
-    return jnp.einsum("...d,df->...f", x, w.astype(adt),
-                      preferred_element_type=jnp.float32).astype(adt)
-
-
-def rope(x, pos, theta: float):
-    """Rotary embedding on the last axis of x [N, ..., d] at positions
-    pos [N], interleaved pairs; float32 inside."""
-    d = x.shape[-1]
-    inv = theta ** (-jnp.arange(0, d, 2, dtype=jnp.float32) / d)
-    ang = pos.astype(jnp.float32)[:, None] * inv
-    ang = ang.reshape(ang.shape[:1] + (1,) * (x.ndim - 2) + ang.shape[1:])
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., 0::2], xf[..., 1::2]
-    return jnp.stack([x1 * cos - x2 * sin, x1 * sin + x2 * cos],
-                     -1).reshape(x.shape).astype(x.dtype)
-
-
-def _norm(x, scale, cfg):
-    return gpt._rms_norm(x, scale.astype(x.dtype), cfg.eps)
-
-
-def _layer_norm(x, scale, bias, cfg):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + cfg.eps)
-            * scale.astype(jnp.float32)
-            + bias.astype(jnp.float32)).astype(x.dtype)
-
-
-def _project(h, lp, pos, cfg):
+def project(h, lp, pos, cfg):
     """Normed h [N, D] at positions pos [N] -> (q_nope [N, H, nope],
     q_rope [N, H, rope], row [N, kv_rank + rope]: the cache row
     `[c_kv | k_rope]`)."""
     adt = cfg.activation_dtype()
     n = h.shape[0]
     if "w_q" in lp:
-        q = _mm(h, lp["w_q"], adt)
+        q = mm(h, lp["w_q"], adt)
     else:
-        c_q = _norm(_mm(h, lp["wq_a"], adt), lp["q_norm_scale"], cfg)
-        q = _mm(c_q, lp["wq_b"], adt)
+        c_q = rms_norm(mm(h, lp["wq_a"], adt), lp["q_norm_scale"], cfg.eps)
+        q = mm(c_q, lp["wq_b"], adt)
     q = q.reshape(n, cfg.n_heads, -1)
     q_nope = q[..., :cfg.nope_dim]
-    q_rope = rope(q[..., cfg.nope_dim:], pos, cfg.rope_theta)
-    kv = _mm(h, lp["wkv_a"], adt)
-    c_kv = _norm(kv[:, :cfg.kv_rank], lp["kv_norm_scale"], cfg)
-    k_rope = rope(kv[:, cfg.kv_rank:], pos, cfg.rope_theta)
+    q_rope = rope_pairs(q[..., cfg.nope_dim:], pos, cfg.rope_theta)
+    kv = mm(h, lp["wkv_a"], adt)
+    c_kv = rms_norm(kv[:, :cfg.kv_rank], lp["kv_norm_scale"], cfg.eps)
+    k_rope = rope_pairs(kv[:, cfg.kv_rank:], pos, cfg.rope_theta)
     return q_nope, q_rope, _stored(
         jnp.concatenate([c_kv, k_rope], -1), cfg)
 
@@ -361,24 +345,24 @@ def _index_parts(x, lp, pos, cfg):
     f32 = jnp.float32
     n, rp = x.shape[0], cfg.rope_dim
 
-    def mm(a, w):
+    def mm32(a, w):
         # three bfloat16 passes on the MXU: 2^-16, far under the cached
         # key's own rounding
         return jnp.einsum("...d,df->...f", a, w.astype(f32),
                           precision=jax.lax.Precision.HIGH)
 
     def turned(a):
-        return jnp.concatenate([rope(a[..., :rp], pos, cfg.rope_theta),
+        return jnp.concatenate([rope_pairs(a[..., :rp], pos, cfg.rope_theta),
                                 a[..., rp:]], -1)
 
-    h = gpt._rms_norm(x.astype(f32), lp["attn_norm_scale"].astype(f32), cfg.eps)
-    c_q = gpt._rms_norm(mm(h, lp["wq_a"]), lp["q_norm_scale"].astype(f32),
-                        cfg.eps)
-    q_i = turned(mm(c_q, lp["wi_q"]).reshape(
+    h = rms_norm(x.astype(f32), lp["attn_norm_scale"], cfg.eps)
+    c_q = rms_norm(mm32(h, lp["wq_a"]), lp["q_norm_scale"], cfg.eps)
+    q_i = turned(mm32(c_q, lp["wi_q"]).reshape(
         n, cfg.index_heads, cfg.index_dim))
-    k_i = turned(_layer_norm(mm(h, lp["wi_k"]), lp["ik_norm_scale"],
-                             lp["ik_norm_bias"], cfg))
-    w = mm(h, lp["wi_w"]) * (cfg.index_heads ** -0.5 * cfg.index_dim ** -0.5)
+    k_i = turned(layer_norm(mm32(h, lp["wi_k"]), lp["ik_norm_scale"],
+                            cfg.eps, lp["ik_norm_bias"]))
+    w = mm32(h, lp["wi_w"]) * (cfg.index_heads ** -0.5
+                               * cfg.index_dim ** -0.5)
     return q_i, _stored(k_i, cfg).astype(cfg.activation_dtype()), w
 
 
@@ -392,107 +376,18 @@ def _sm_scale(cfg) -> float:
     return (cfg.nope_dim + cfg.rope_dim) ** -0.5
 
 
-def kept_groups(biased, cfg):
-    """The group-limited choice's first half: biased scores [N, E] ->
-    bool [N, n_group], true at the `topk_group` groups whose two largest
-    scores sum highest."""
-    n = biased.shape[0]
-    best = jax.lax.top_k(biased.reshape(n, cfg.n_group, -1), 2)[0]
-    _, keep = jax.lax.top_k(jnp.sum(best, -1), cfg.topk_group)
-    return jnp.any(keep[..., None] == jnp.arange(cfg.n_group), axis=1)
-
-
-def router_scores(h2, lp):
-    """-> (sigmoid scores [N, E], scores + the expert bias where the
-    layer has one), float32."""
-    g = jax.nn.sigmoid(jnp.einsum(
-        "nd,de->ne", h2.astype(jnp.float32),
-        lp["router"].astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    if "router_bias" not in lp:
-        return g, g
-    return g, g + lp["router_bias"].astype(jnp.float32)
-
-
-def routing(h2, lp, cfg):
-    """-> (chosen [N, k] i32, weights [N, k] f32), in float32: a choice
-    between two near-equal scores should not turn on the activations'
-    rounding more than it must. Of `cfg` it reads `router_width`,
-    `experts_per_token` and `norm_topk`, and, where the family's router
-    has them, `n_group` with `topk_group` (else one group) and
-    `routed_scale` (else 1)."""
-    g, biased = router_scores(h2, lp)
-    if getattr(cfg, "n_group", 1) > 1:
-        biased = jnp.where(jnp.repeat(
-            kept_groups(biased, cfg), cfg.router_width // cfg.n_group, 1),
-            biased, -jnp.inf)
-    _, chosen = jax.lax.top_k(biased, cfg.experts_per_token)
-    weights = jnp.take_along_axis(g, chosen, -1)
-    if cfg.norm_topk:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
-    return chosen.astype(jnp.int32), weights * getattr(
-        cfg, "routed_scale", 1.0)
-
-
-def _rounded(a, cfg):
-    """`cfg.expert_round`'s grid (float8_e4m3fn: three bits of mantissa,
-    at most 448; ties to even, the small exponents' coarser steps left
-    out), in a's own type; the gradient passes as through a cast. By
-    arithmetic on the bits and not by a cast there and back: the TPU
-    compiler drops a round trip through a type its chip has no unit for,
-    and the control then rounds nothing (PERF.md, PR 38)."""
-    if cfg.expert_round == "none":
-        return a
-    drop = 23 - 3                       # float32 mantissa bits to lose
-    bits = jax.lax.bitcast_convert_type(a.astype(jnp.float32), jnp.uint32)
-    bits = (bits + jnp.uint32((1 << (drop - 1)) - 1)
-            + ((bits >> drop) & jnp.uint32(1))) & jnp.uint32(
-                ~((1 << drop) - 1) & 0xFFFFFFFF)
-    grid = jnp.clip(jax.lax.bitcast_convert_type(bits, jnp.float32),
-                    -448.0, 448.0).astype(a.dtype)
-    return a + jax.lax.stop_gradient(grid - a)
-
-
-def expert_layer(h2, lp, cfg, live=None,
+def feed_forward(x, lp, cfg, live=None,
                  kernel: str = grouped_experts.EXPERTS_GROUPED,
                  every_load: bool = False):
-    """A sparse layer's two parts on normed h2 [N, D]: -> (routed: what
-    the held experts add, shared: the shared expert's, counts i32: pairs
-    routed here, pairs routed anywhere, then the pairs each held expert
-    got, or with `every_load` each expert of the router's whole width,
-    held or not; rows where `live` is false count nothing)."""
-    adt = cfg.activation_dtype()
-    chosen, weights = routing(h2, lp, cfg)
-    if live is not None:
-        chosen = jnp.where(live[:, None], chosen, -1)
-    routed, load = grouped_experts.experts_grouped(
-        _rounded(h2, cfg), chosen, weights, _rounded(lp["we_gate"], cfg),
-        _rounded(lp["we_up"], cfg), _rounded(lp["we_down"], cfg),
-        held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
-    shared, _ = gpt._gated_mlp(
-        h2, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
-             "w_down": lp["ws_down"]}, adt, jnp.float32)
-    here = jnp.sum(load)
-    if every_load:
-        load = jnp.sum(chosen[..., None] == jnp.arange(cfg.router_width),
-                       (0, 1), dtype=jnp.int32)
-    counts = jnp.concatenate([
-        jnp.stack([here, jnp.sum(chosen >= 0, dtype=jnp.int32)]), load])
-    return routed.astype(adt), shared, counts
-
-
-def _feed_forward(x, lp, cfg, live=None,
-                  kernel: str = grouped_experts.EXPERTS_GROUPED,
-                  every_load: bool = False):
     """x += the layer's feed-forward; -> (x, expert counts or None)."""
     adt = cfg.activation_dtype()
     with jax.named_scope(FFN):
-        h2 = _norm(x, lp["ffn_norm_scale"], cfg)
+        h2 = rms_norm(x, lp["ffn_norm_scale"], cfg.eps)
         if "router" in lp:
-            routed, shared, counts = expert_layer(h2, lp, cfg, live, kernel,
-                                                  every_load)
+            routed, shared, counts = expert_layer(
+                h2, lp, cfg.experts, adt, live, kernel, every_load)
             return x + routed + shared, counts
-        return x + gpt._gated_mlp(h2, lp, adt, jnp.float32)[0], None
+        return x + gated_mlp(h2, lp, adt, jnp.float32)[0], None
 
 
 def _counts(cfg, pos, live, expert_counts, chunk_block: int = 0):
@@ -512,8 +407,7 @@ def _counts(cfg, pos, live, expert_counts, chunk_block: int = 0):
     last = _last(pos, live)
     past = last >= topk
     walked = last // chunk_block + 1 if chunk else 0
-    experts = sum(expert_counts) if expert_counts else jnp.zeros(
-        (2 + cfg.held_count,), jnp.int32)
+    experts = expert_totals(expert_counts, 2 + cfg.held_count)
     return jnp.concatenate([
         jnp.stack([scanned * n_full, selected * n_full,
                    jnp.int32(n_full), jnp.int32(cfg.n_layers - n_full),
@@ -521,24 +415,6 @@ def _counts(cfg, pos, live, expert_counts, chunk_block: int = 0):
                    cfg.n_layers * walked,
                    experts[0], experts[1]]).astype(jnp.int32),
         experts[2:].astype(jnp.int32)])
-
-
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * (len(COUNTS) + cfg.held_count)
-    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
-    load = [int(v) for v in totals[len(COUNTS):]]
-    mean = sum(load) / max(len(load), 1)
-    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
-    return out
-
-
-def _unembed(x, params, cfg):
-    return jnp.einsum("...d,vd->...v", x,
-                      params["head"].astype(cfg.activation_dtype()),
-                      preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -634,8 +510,8 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
         selected = None if cfg.has_indexer else causal
         for lp in params["layers"]:
             with jax.named_scope(MIXER):
-                h = _norm(x, lp["attn_norm_scale"], cfg)
-                q_nope, q_rope, row = _project(h, lp, pos, cfg)
+                h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
+                q_nope, q_rope, row = project(h, lp, pos, cfg)
                 if "wi_q" in lp:
                     q_i, k_i, w = _index_parts(x, lp, pos, cfg)
                     s = sparse_latent.index_dots(q_i, k_i, "qjd,kd->qjk")
@@ -645,11 +521,11 @@ def forward(params, tokens, cfg: LatentSparseMoEConfig, selections=None):
                     if selections is not None:
                         selections.append(selected)
                 att = attend_full(q_nope, q_rope, row, selected, lp, cfg)
-                x = x + _mm(att.reshape(t, -1), lp["w_out"], adt)
-            x, _ = _feed_forward(x, lp, cfg)
+                x = x + mm(att.reshape(t, -1), lp["w_out"], adt)
+            x, _ = feed_forward(x, lp, cfg)
         with jax.named_scope(HEAD):
-            return _unembed(_norm(x, params["final_ln_scale"], cfg), params,
-                            cfg)
+            return unembed(rms_norm(x, params["final_ln_scale"], cfg.eps),
+                           params["head"], adt)
 
     if selections is not None:          # the list is filled outside a map
         return jnp.stack([one(seq) for seq in tokens])
@@ -741,7 +617,7 @@ def _train_attention(h, lp, pos, cfg):
     adt = cfg.activation_dtype()
     b, t, d = h.shape
     nh = cfg.n_heads
-    q_nope, q_rope, row = _project(h.reshape(b * t, d), lp, pos, cfg)
+    q_nope, q_rope, row = project(h.reshape(b * t, d), lp, pos, cfg)
     kv = jnp.einsum("sc,chd->shd", row[:, :cfg.kv_rank], _kv_up(lp, cfg, adt),
                     preferred_element_type=jnp.float32).astype(adt)
     k_rope = jnp.broadcast_to(row[:, None, cfg.kv_rank:],
@@ -752,7 +628,7 @@ def _train_attention(h, lp, pos, cfg):
     v = kv[..., cfg.nope_dim:].reshape(b, t, nh, cfg.v_dim)
     att = flash_attention(q, k, v, True, cfg.flash_block_q,
                           cfg.flash_block_kv)
-    return _mm(att.reshape(b, t, nh * cfg.v_dim), lp["w_out"], adt)
+    return mm(att.reshape(b, t, nh * cfg.v_dim), lp["w_out"], adt)
 
 
 def _train_layer(x, lp, pos, cfg):
@@ -760,9 +636,9 @@ def _train_layer(x, lp, pos, cfg):
     None for a dense layer)."""
     b, t, d = x.shape
     with jax.named_scope(MIXER):
-        x = x + _train_attention(_norm(x, lp["attn_norm_scale"], cfg), lp,
-                                 pos, cfg)
-    x, counts = _feed_forward(
+        x = x + _train_attention(
+            rms_norm(x, lp["attn_norm_scale"], cfg.eps), lp, pos, cfg)
+    x, counts = feed_forward(
         x.reshape(b * t, d), lp, cfg,
         kernel=grouped_experts.EXPERTS_GROUPED_TRAIN, every_load=True)
     return x.reshape(b, t, d), counts
@@ -803,7 +679,7 @@ def forward_features(params, tokens, cfg: LatentSparseMoEConfig, mesh=None):
     with jax.named_scope(HEAD):
         counts = jnp.stack(counts) if counts else jnp.zeros(
             (0, 2 + cfg.router_width), jnp.int32)
-        return _norm(x, params["final_ln_scale"], cfg), counts
+        return rms_norm(x, params["final_ln_scale"], cfg.eps), counts
 
 
 def update_router_bias(params, counts, cfg: LatentSparseMoEConfig):
@@ -872,8 +748,8 @@ def _prefill_select(q_i, w, index, layer: int, table, positions, valid, cfg):
                         over_the_context)
 
 
-def _prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
-                    valid, selected, lp, cfg):
+def prefill_attend(q_nope, q_rope, latent, layer: int, table, positions,
+                   valid, selected, lp, cfg):
     """Absorbed latent attention of a chunk's queries, q_nope [C, H, nope]
     and q_rope [C, H, rope], over the selected positions of the cached
     context (`decode_attend`'s form: the queries through the key
@@ -944,26 +820,26 @@ def prefill(params, tokens, cache, cfg: LatentSparseMoEConfig, mesh=None, *,
     full, expert_counts = 0, []
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            h = _norm(x, lp["attn_norm_scale"], cfg)
-            q_nope, q_rope, row = _project(h, lp, positions, cfg)
-            latent = _write_latent(latent, i, row, widx, cfg)
+            h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
+            q_nope, q_rope, row = project(h, lp, positions, cfg)
+            latent = write_latent(latent, i, row, widx, cfg)
             if "wi_q" in lp:
                 q_i, k_i, w = _index_parts(x, lp, positions, cfg)
                 index = _write_index(index, full, k_i, widx)
                 selected = _prefill_select(q_i, w, index, full, table,
                                            positions, valid, cfg)
                 full += 1
-            att = _prefill_attend(q_nope, q_rope, latent, i, table,
+            att = prefill_attend(q_nope, q_rope, latent, i, table,
                                   positions, valid, selected, lp, cfg)
-            x = x + _mm(att, lp["w_out"], adt)
-        x, counts = _feed_forward(
+            x = x + mm(att, lp["w_out"], adt)
+        x, counts = feed_forward(
             x, lp, cfg, valid, grouped_experts.EXPERTS_GROUPED_PREFILL)
         if counts is not None:
             expert_counts.append(counts)
     with jax.named_scope(HEAD):
-        x = _norm(x, params["final_ln_scale"], cfg)
+        x = rms_norm(x, params["final_ln_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-        return (_unembed(last, params, cfg), _pool_of(latent, index),
+        return (unembed(last, params["head"], adt), _pool_of(latent, index),
                 _counts(cfg, positions, valid, expert_counts,
                         sparse_latent.context_block(table.shape[0] * bs,
                                                     bs)))
@@ -1047,9 +923,9 @@ def decode(params, tokens, cache, pos, tables,
     full, expert_counts = 0, []
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            h = _norm(x, lp["attn_norm_scale"], cfg)
-            q_nope, q_rope, row = _project(h, lp, pos, cfg)
-            latent = _write_latent(latent, i, row, widx, cfg)
+            h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
+            q_nope, q_rope, row = project(h, lp, pos, cfg)
+            latent = write_latent(latent, i, row, widx, cfg)
             if "wi_q" in lp:
                 q_i, k_i, w = _index_parts(x, lp, pos, cfg)
                 index = _write_index(index, full, k_i, widx)
@@ -1062,17 +938,22 @@ def decode(params, tokens, cache, pos, tables,
                 full += 1
             att = decode_attend(q_nope, q_rope, latent, i, tables, pos, lp,
                                 cfg, rows, count)
-            x = x + _mm(att.reshape(b, -1), lp["w_out"], adt)
-        x, counts = _feed_forward(x, lp, cfg, live)
+            x = x + mm(att.reshape(b, -1), lp["w_out"], adt)
+        x, counts = feed_forward(x, lp, cfg, live)
         if counts is not None:
             expert_counts.append(counts)
     with jax.named_scope(HEAD):
-        x = _norm(x, params["final_ln_scale"], cfg)
-        return (_unembed(x, params, cfg), _pool_of(latent, index),
+        x = rms_norm(x, params["final_ln_scale"], cfg.eps)
+        return (unembed(x, params["head"], adt), _pool_of(latent, index),
                 _counts(cfg, pos, live, expert_counts))
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize)
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block,
+    counts=lambda cfg, totals: summarize(COUNTS, totals, cfg.held_count))
+TRAINING = TrainingFamily(
+    init_params=init_params, param_logical_axes=param_logical_axes,
+    forward_features=forward_features, head="head",
+    aux_update=update_router_bias, frozen=is_router_bias)

@@ -20,8 +20,8 @@ The KDA layer (`n = RMSNorm(x)`; H heads of d, keys and values alike):
     y_t = W_o concat_h( RMSNorm_head(o_t^h) x sigmoid(w_og^h . n_t) )
 
 `ops/kda.py` has the recurrence's two kernels. The latent layer is
-`latent_sparse_moe`'s without indexer or query bottleneck (`_project`, the
-row format, `decode_attend` over every cached row, `_prefill_attend`),
+`latent_sparse_moe`'s without indexer or query bottleneck (`project`, the
+row format, `decode_attend` over every cached row, `prefill_attend`),
 each head's output times the same head-wise gate `sigmoid(w_og^h . n)`.
 No rotary in a KDA layer: the decay carries position.
 
@@ -48,8 +48,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
 from ray_tpu.models import latent_sparse_moe as lsm
+from ray_tpu.models.blocks import (copy_block, expert_totals, gather_block,
+                                   kept_groups, mm, rms_norm, router_scores,
+                                   scatter_block, summarize, unembed)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import grouped_experts, kda
 
@@ -100,6 +102,8 @@ class LinearLatentConfig(lsm.LatentSparseMoEConfig):
     @property
     def family(self):
         return FAMILY
+
+    training = None     # `latent_sparse_moe`'s trainer is not this family's
 
 
 def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
@@ -242,8 +246,7 @@ def _pre_conv(h, lp, cfg):
     """Normed h [N, D] -> the three projections side by side [N, 3 H d],
     float32 values of the activation type."""
     adt = cfg.activation_dtype()
-    return jnp.concatenate([lsm._mm(h, lp[w], adt)
-                            for w in ("w_q", "w_k", "w_v")],
+    return jnp.concatenate([mm(h, lp[w], adt) for w in ("w_q", "w_k", "w_v")],
                            -1).astype(jnp.float32)
 
 
@@ -282,15 +285,15 @@ def _kda_out(o, h, lp, cfg):
     """The recurrence's output o [N, H, d] f32 through the head norm, the
     gate and W_o: -> [N, D]."""
     adt = cfg.activation_dtype()
-    o = gpt._rms_norm(o, lp["o_norm_scale"].astype(jnp.float32), cfg.eps)
-    return lsm._mm((o * _gate(h, lp, cfg)).astype(adt).reshape(
+    o = rms_norm(o, lp["o_norm_scale"], cfg.eps)
+    return mm((o * _gate(h, lp, cfg)).astype(adt).reshape(
         o.shape[0], -1), lp["w_out"], adt)
 
 
 def _latent_out(att, h, lp, cfg):
     """Latent attention's heads att [N, H, v] through the gate and W_o."""
     adt = cfg.activation_dtype()
-    return lsm._mm((att.astype(jnp.float32) * _gate(h, lp, cfg)).astype(
+    return mm((att.astype(jnp.float32) * _gate(h, lp, cfg)).astype(
         adt).reshape(att.shape[0], -1), lp["w_out"], adt)
 
 
@@ -302,41 +305,29 @@ def _groups_here(x, lp, cfg, live):
     lo, hi = -(-cfg.held_from // per), (cfg.held_from + cfg.held_count) // per
     if "router" not in lp or cfg.n_group == 1 or hi <= lo:
         return jnp.int32(0)
-    h2 = lsm._norm(x, lp["ffn_norm_scale"], cfg)
-    kept = lsm.kept_groups(lsm.router_scores(h2, lp)[1], cfg)[:, lo:hi]
+    h2 = rms_norm(x, lp["ffn_norm_scale"], cfg.eps)
+    kept = kept_groups(router_scores(h2, lp)[1], cfg.n_group,
+                       cfg.topk_group)[:, lo:hi]
     return jnp.sum(kept & live[:, None], dtype=jnp.int32)
 
 
 def _counts(cfg, head, expert_counts):
     """`COUNTS`' first four, then the experts' three and their loads."""
-    experts = sum(expert_counts) if expert_counts else jnp.zeros(
-        (3 + cfg.held_count,), jnp.int32)
+    experts = expert_totals(expert_counts, 3 + cfg.held_count)
     return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
                             experts.astype(jnp.int32)])
 
 
 def _feed_forward(x, lp, cfg, live, kernel):
-    """`latent_sparse_moe._feed_forward` and this layer's counts (pairs
+    """`latent_sparse_moe.feed_forward` and this layer's counts (pairs
     here, pairs routed, groups kept here, the held experts' loads), None
     for a dense layer."""
     with jax.named_scope(FFN):
         groups = _groups_here(x, lp, cfg, live)
-    x, counts = lsm._feed_forward(x, lp, cfg, live, kernel)
+    x, counts = lsm.feed_forward(x, lp, cfg, live, kernel)
     if counts is None:
         return x, None
     return x, jnp.concatenate([counts[:2], groups[None], counts[2:]])
-
-
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * (len(COUNTS) + cfg.held_count)
-    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
-    load = [int(v) for v in totals[len(COUNTS):]]
-    mean = sum(load) / max(len(load), 1)
-    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
-    return out
 
 
 # ---------------------------------------------------------------------------
@@ -358,7 +349,7 @@ def forward(params, tokens, cfg: LinearLatentConfig):
             x = params["embed"].astype(adt)[seq]
         for lp, mixer in zip(params["layers"], cfg.mixers):
             with jax.named_scope(MIXER):
-                h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+                h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
                 if mixer == "kda":
                     pre = jnp.pad(_pre_conv(h, lp, cfg),
                                   ((taps - 1, 0), (0, 0)))
@@ -368,14 +359,14 @@ def forward(params, tokens, cfg: LinearLatentConfig):
                     x = x + _kda_out(kda.kda_recurrent(q, k, v, g, beta)[0], h,
                                      lp, cfg)
                 else:
-                    q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
+                    q_nope, q_rope, row = lsm.project(h, lp, pos, cfg)
                     x = x + _latent_out(lsm.attend_full(
                         q_nope, q_rope, row, causal, lp, cfg), h, lp, cfg)
             x, _ = _feed_forward(x, lp, cfg, live,
                                  grouped_experts.EXPERTS_GROUPED)
         with jax.named_scope(HEAD):
-            return lsm._unembed(lsm._norm(x, params["final_ln_scale"], cfg),
-                                params, cfg)
+            return unembed(rms_norm(x, params["final_ln_scale"], cfg.eps),
+                           params["head"], adt)
 
     return jax.lax.map(one, tokens)
 
@@ -416,7 +407,7 @@ def prefill(params, tokens, cache, cfg: LinearLatentConfig, mesh=None, *,
     expert_counts = []
     for lp, mixer in zip(params["layers"], cfg.mixers):
         with jax.named_scope(MIXER):
-            h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+            h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
             if mixer == "kda":
                 tail = jnp.where(first, 0.0, conv[n_kda, block])
                 pre = jnp.concatenate([tail, _pre_conv(h, lp, cfg)])
@@ -432,9 +423,9 @@ def prefill(params, tokens, cache, cfg: LinearLatentConfig, mesh=None, *,
                 x = x + _kda_out(o, h, lp, cfg)
                 n_kda += 1
             else:
-                q_nope, q_rope, row = lsm._project(h, lp, positions, cfg)
-                latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
-                att = lsm._prefill_attend(q_nope, q_rope, latent, n_latent,
+                q_nope, q_rope, row = lsm.project(h, lp, positions, cfg)
+                latent = lsm.write_latent(latent, n_latent, row, widx, cfg)
+                att = lsm.prefill_attend(q_nope, q_rope, latent, n_latent,
                                           pages, positions, valid, every, lp,
                                           cfg)
                 x = x + _latent_out(att.reshape(c, cfg.n_heads, cfg.v_dim), h,
@@ -445,10 +436,10 @@ def prefill(params, tokens, cache, cfg: LinearLatentConfig, mesh=None, *,
         if counts is not None:
             expert_counts.append(counts)
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_ln_scale"], cfg)
+        x = rms_norm(x, params["final_ln_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_latent
-        return (lsm._unembed(last, params, cfg),
+        return (unembed(last, params["head"], adt),
                 {"state": state, "conv": conv, "latent": latent},
                 _counts(cfg, [length, c - length, first, rows], expert_counts))
 
@@ -475,7 +466,7 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
     expert_counts = []
     for lp, mixer in zip(params["layers"], cfg.mixers):
         with jax.named_scope(MIXER):
-            h = lsm._norm(x, lp["attn_norm_scale"], cfg)
+            h = rms_norm(x, lp["attn_norm_scale"], cfg.eps)
             if mixer == "kda":
                 pre = jnp.concatenate(
                     [conv[n_kda, blocks], _pre_conv(h, lp, cfg)[:, None]], 1)
@@ -488,8 +479,8 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
                 x = x + _kda_out(o, h, lp, cfg)
                 n_kda += 1
             else:
-                q_nope, q_rope, row = lsm._project(h, lp, pos, cfg)
-                latent = lsm._write_latent(latent, n_latent, row, widx, cfg)
+                q_nope, q_rope, row = lsm.project(h, lp, pos, cfg)
+                latent = lsm.write_latent(latent, n_latent, row, widx, cfg)
                 att = lsm.decode_attend(q_nope, q_rope, latent, n_latent,
                                         pages, pos, lp, cfg)
                 x = x + _latent_out(att, h, lp, cfg)
@@ -499,10 +490,10 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
         if counts is not None:
             expert_counts.append(counts)
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_ln_scale"], cfg)
+        x = rms_norm(x, params["final_ln_scale"], cfg.eps)
         rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_latent
         zero = jnp.int32(0)
-        return (lsm._unembed(x, params, cfg),
+        return (unembed(x, params["head"], adt),
                 {"state": state, "conv": conv, "latent": latent},
                 _counts(cfg, [jnp.sum(live, dtype=jnp.int32), b - jnp.sum(
                     live, dtype=jnp.int32), zero, rows], expert_counts))
@@ -510,6 +501,6 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
-    state_keys=STATE_KEYS)
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, state_blocks=1, state_keys=STATE_KEYS,
+    counts=lambda cfg, totals: summarize(COUNTS, totals, cfg.held_count))

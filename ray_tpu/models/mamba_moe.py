@@ -23,8 +23,8 @@ key-value head `h // (Hq / Hkv)`, causal, scale d^-1/2, no positional
 term, `f = W_o concat(heads)` (`ops/decode_attention.py`'s `gqa_full_*`).
 `E`, the expert layer: `s = sigmoid(W_r n)` over the router's published
 width, float32, on the full hidden; the k largest of `s + b` chosen,
-weights `scale x s_e / sum of the chosen s` (`latent_sparse_moe.routing`,
-one group); `u = W_down n` into the latent; the held experts' part `r =
+weights `scale x s_e / sum of the chosen s` (`blocks.routing`, one
+group); `u = W_down n` into the latent; the held experts' part `r =
 sum_e w_e W2_e relu(W1_e u)^2` there (`ops/grouped_experts.py`'s ungated
 form); `routed = W_up r`; `shared = W2_s relu(W1_s n)^2` on the full
 hidden; `f = routed + shared`. What the absent experts would add is left
@@ -62,9 +62,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
-from ray_tpu.models import latent_sparse_moe as lsm
-from ray_tpu.models import window_moe
+from ray_tpu.models.blocks import (Experts, copy_block, expert_totals,
+                                   gather_block, mm, rms_norm, routing,
+                                   row_index, scatter_block, summarize,
+                                   unembed, write_chunk, write_rows)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts, mamba2
@@ -145,6 +146,12 @@ class MambaMoEConfig:
 
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def experts(self) -> Experts:
+        return Experts(self.router_width, self.experts_per_token,
+                       self.norm_topk, self.held_from,
+                       routed_scale=self.routed_scale, impl=self.sparse_impl)
 
     @property
     def family(self):
@@ -344,7 +351,7 @@ def _mamba_out(y, x, z, lp, cfg):
         jnp.mean(grouped * grouped, -1, keepdims=True) + cfg.eps)
     out = grouped.reshape(rows, -1) * lp["gate_norm_scale"].astype(
         jnp.float32)
-    return lsm._mm(out.astype(adt), lp["w_out"], adt)
+    return mm(out.astype(adt), lp["w_out"], adt)
 
 
 def mamba_whole(n, lp, cfg, mup=None):
@@ -419,37 +426,20 @@ def mamba_step(n, lp, cache, cfg, layer, blocks, held, mup=None):
     return _mamba_out(y, xs, z, lp, cfg)
 
 
-def row_index(pages, pos, pool):
-    """Where a decode step's rows go in a head-major pool `[L, n_blocks,
-    Hkv, bs, d]`: the flat position `page * bs + offset` of pos [B]
-    through each row's pages [B, columns]; past the table's reach,
-    `n_blocks * bs` (dropped by `window_moe._write_rows`)."""
-    n_blocks, bs = pool.shape[1], pool.shape[3]
-    cols = pages.shape[1]
-    page = jnp.minimum(pos // bs, cols - 1)[:, None]
-    return jnp.where(
-        pos < cols * bs,
-        jnp.take_along_axis(pages, page, 1)[:, 0] * bs + pos % bs,
-        n_blocks * bs)
-
-
 def _qkv(n, lp, cfg):
     """Normed n [N, D] -> q [N, Hq, d], k, v [N, Hkv, d]; no positional
     term."""
     adt = cfg.activation_dtype()
     rows = n.shape[0]
-    return (lsm._mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads,
-                                               cfg.head_dim),
-            lsm._mm(n, lp["w_k"], adt).reshape(rows, cfg.n_kv_heads,
-                                               cfg.head_dim),
-            lsm._mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads,
-                                               cfg.head_dim))
+    return (mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads, cfg.head_dim),
+            mm(n, lp["w_k"], adt).reshape(rows, cfg.n_kv_heads, cfg.head_dim),
+            mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads, cfg.head_dim))
 
 
 def _relu2_mlp(n, w_up, w_down, adt):
     up = jnp.einsum("nd,df->nf", n, w_up.astype(adt),
                     preferred_element_type=jnp.float32)
-    return lsm._mm(jnp.square(jax.nn.relu(up)).astype(adt), w_down, adt)
+    return mm(jnp.square(jax.nn.relu(up)).astype(adt), w_down, adt)
 
 
 def _part(kind: str) -> str:
@@ -463,17 +453,17 @@ def _experts(n, lp, cfg, live, kernel):
     then the pairs each held expert got; rows where `live` is false count
     nothing)."""
     adt = cfg.activation_dtype()
-    chosen, weights = lsm.routing(n, lp, cfg)
+    chosen, weights = routing(n, lp, cfg.experts)
     if live is not None:
         chosen = jnp.where(live[:, None], chosen, -1)
     with jax.named_scope("latent_projections"):
-        u = lsm._mm(n, lp["latent_down"], adt)
+        u = mm(n, lp["latent_down"], adt)
     with jax.named_scope("routed_experts"):
         r, load = grouped_experts.experts_grouped(
             u, chosen, weights, None, lp["we_up"], lp["we_down"],
             held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
     with jax.named_scope("latent_projections"):
-        routed = lsm._mm(r.astype(adt), lp["latent_up"], adt)
+        routed = mm(r.astype(adt), lp["latent_up"], adt)
     with jax.named_scope("shared_experts"):
         shared = _relu2_mlp(n, lp["ws_up"], lp["ws_down"], adt)
     counts = jnp.concatenate([
@@ -484,28 +474,9 @@ def _experts(n, lp, cfg, live, kernel):
 
 def _counts(cfg, head, expert_counts):
     """`COUNTS`' first five, then the experts' two and their loads."""
-    experts = sum(expert_counts) if expert_counts else jnp.zeros(
-        (2 + cfg.held_count,), jnp.int32)
+    experts = expert_totals(expert_counts, 2 + cfg.held_count)
     return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
                             experts.astype(jnp.int32)])
-
-
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * (len(COUNTS) + cfg.held_count)
-    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
-    load = [int(v) for v in totals[len(COUNTS):]]
-    mean = sum(load) / max(len(load), 1)
-    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
-    return out
-
-
-def _unembed(x, params, cfg):
-    return jnp.einsum("...d,vd->...v", x,
-                      params["head"].astype(cfg.activation_dtype()),
-                      preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -524,7 +495,7 @@ def forward(params, tokens, cfg: MambaMoEConfig):
             x = params["embed"].astype(adt)[seq]
         for lp, kind in zip(params["layers"], cfg.kinds):
             with jax.named_scope(_part(kind)):
-                n = lsm._norm(x, lp["norm_scale"], cfg)
+                n = rms_norm(x, lp["norm_scale"], cfg.eps)
                 if kind == "mamba":
                     x = x + mamba_whole(n, lp, cfg)
                 elif kind == "attention":
@@ -532,13 +503,13 @@ def forward(params, tokens, cfg: MambaMoEConfig):
                     att = da.reference_gqa_attention(
                         q[None], k[None], v[None],
                         jnp.zeros((1,), jnp.int32))[0]
-                    x = x + lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
+                    x = x + mm(att.reshape(t, -1), lp["w_out"], adt)
                 else:
                     x = x + _experts(n, lp, cfg, live,
                                      grouped_experts.EXPERTS_GROUPED)[0]
         with jax.named_scope(HEAD):
-            return _unembed(lsm._norm(x, params["final_norm_scale"], cfg),
-                            params, cfg)
+            return unembed(rms_norm(x, params["final_norm_scale"], cfg.eps),
+                           params["head"], adt)
 
     return jax.lax.map(one, tokens)
 
@@ -574,7 +545,7 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
         with jax.named_scope(_part(kind)):
-            n = lsm._norm(x, lp["norm_scale"], cfg)
+            n = rms_norm(x, lp["norm_scale"], cfg.eps)
             if kind == "mamba":
                 with jax.named_scope("mamba_layer"):
                     x = x + mamba_chunk(n, lp, cache, cfg, n_mamba, block,
@@ -583,14 +554,14 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
             elif kind == "attention":
                 with jax.named_scope("attention_layer"):
                     q, k, v = _qkv(n, lp, cfg)
-                    cache["k"] = window_moe._write_chunk(
+                    cache["k"] = write_chunk(
                         cache["k"], n_attn, k, pages, start, length)
-                    cache["v"] = window_moe._write_chunk(
+                    cache["v"] = write_chunk(
                         cache["v"], n_attn, v, pages, start, length)
                     att = da.gqa_chunk_attention(
                         q, cache["k"], cache["v"], pages, start, layer=n_attn,
                         impl=cfg.attn_impl)
-                    x = x + lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
+                    x = x + mm(att.reshape(c, -1), lp["w_out"], adt)
                 n_attn += 1
             else:
                 ff, counts = _experts(n, lp, cfg, valid,
@@ -598,11 +569,11 @@ def prefill(params, tokens, cache, cfg: MambaMoEConfig, mesh=None, *,
                 expert_counts.append(counts)
                 x = x + ff
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
         cache["held"] = rings_emptied(cache, block)
-        return (_unembed(last, params, cfg), cache,
+        return (unembed(last, params["head"], adt), cache,
                 _counts(cfg, [length * n_mamba, (c - length) * n_mamba, first,
                               rows, jnp.int32(0)], expert_counts))
 
@@ -629,7 +600,7 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
         with jax.named_scope(_part(kind)):
-            n = lsm._norm(x, lp["norm_scale"], cfg)
+            n = rms_norm(x, lp["norm_scale"], cfg.eps)
             if kind == "mamba":
                 with jax.named_scope("mamba_layer"):
                     x = x + mamba_step(n, lp, cache, cfg, n_mamba, blocks,
@@ -638,14 +609,12 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
             elif kind == "attention":
                 with jax.named_scope("attention_layer"):
                     q, k, v = _qkv(n, lp, cfg)
-                    cache["k"] = window_moe._write_rows(cache["k"], n_attn, k,
-                                                        widx)
-                    cache["v"] = window_moe._write_rows(cache["v"], n_attn, v,
-                                                        widx)
+                    cache["k"] = write_rows(cache["k"], n_attn, k, widx)
+                    cache["v"] = write_rows(cache["v"], n_attn, v, widx)
                     att = da.gqa_decode_attention(
                         q, cache["k"], cache["v"], pages, pos, layer=n_attn,
                         impl=cfg.attn_impl)
-                    x = x + lsm._mm(att.reshape(b, -1), lp["w_out"], adt)
+                    x = x + mm(att.reshape(b, -1), lp["w_out"], adt)
                 n_attn += 1
             else:
                 ff, counts = _experts(n, lp, cfg, live,
@@ -653,17 +622,17 @@ def decode(params, tokens, cache, pos, tables, cfg: MambaMoEConfig,
                 expert_counts.append(counts)
                 x = x + ff
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         n_live = jnp.sum(live, dtype=jnp.int32)
         rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
         cache["held"] = held_after
-        return (_unembed(x, params, cfg), cache,
+        return (unembed(x, params["head"], adt), cache,
                 _counts(cfg, [n_live * n_mamba, (b - n_live) * n_mamba,
                               jnp.int32(0), rows, folds], expert_counts))
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
-    state_keys=STATE_KEYS)
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, state_blocks=1, state_keys=STATE_KEYS,
+    counts=lambda cfg, totals: summarize(COUNTS, totals, cfg.held_count))

@@ -30,7 +30,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.models.gpt import _attention, _layer, _rms_norm
+from ray_tpu.models.blocks import rms_norm
+from ray_tpu.models.gpt import attention, layer
 
 
 @dataclass(frozen=True)
@@ -188,12 +189,12 @@ def _moe_ffn(x, lp, cfg: MoEConfig):
 
 
 def _block(x, lp, cfg: MoEConfig, mesh: Mesh | None):
-    """`gpt._layer` with the experts as its feed-forward; every einsum
+    """`gpt.layer` with the experts as its feed-forward; every einsum
     emits float32 (this model never got `matmul_out`: ROADMAP D6).
     -> (x, this layer's aux loss)."""
-    x, _, aux = _layer(
+    x, _, aux = layer(
         x, lp, cfg, jnp.float32,
-        lambda q, k, v: (_attention(q, k, v, cfg.attn_impl, mesh), None),
+        lambda q, k, v: (attention(q, k, v, cfg.attn_impl, mesh), None),
         lambda h, lp: _moe_ffn(h, lp, cfg))
     return x, aux
 
@@ -216,7 +217,7 @@ def forward(params, tokens, cfg: MoEConfig, mesh: Mesh | None = None):
 
     (x, aux_sum), _ = jax.lax.scan(
         scan_body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
+    x = rms_norm(x, params["final_ln_scale"])
     logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(adt),
                         preferred_element_type=jnp.float32)
     return logits, aux_sum / cfg.n_layers

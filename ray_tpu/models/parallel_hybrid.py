@@ -38,7 +38,7 @@ configuration as they take that one's, for they read widths by name, and
 `mup` is the one argument they gained (None there: that family's
 programs are as they were). Attention is `ops/decode_attention.py`'s
 `gqa_full_*` kernels over head-major pages, written by
-`models/window_moe.py`'s page writes; rotary is `models/retention.py`'s.
+`models/blocks.py`'s page writes; rotary is its `rope_halves`.
 
 **What the engine holds for this family**: one request, two kinds of
 block (`ServingFamily.state_blocks` 1 and `paged`), as
@@ -66,11 +66,12 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
-from ray_tpu.models import latent_sparse_moe as lsm
-from ray_tpu.models import mamba_moe, window_moe
+from ray_tpu.models import mamba_moe
+from ray_tpu.models.blocks import (copy_block, gather_block, mm, rms_norm,
+                                   rope_halves, row_index, scatter_block,
+                                   summarize, unembed, write_chunk,
+                                   write_rows)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
-from ray_tpu.models.retention import rope
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import mamba2
 
@@ -263,19 +264,19 @@ def _qkv(n, pos, lp, cfg):
     adt = cfg.activation_dtype()
     rows = n.shape[0]
     n = _scaled(n, cfg.attention_in_multiplier)
-    q = lsm._mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads, cfg.head_dim)
-    k = _scaled(lsm._mm(n, lp["w_k"], adt), cfg.key_multiplier).reshape(
+    q = mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads, cfg.head_dim)
+    k = _scaled(mm(n, lp["w_k"], adt), cfg.key_multiplier).reshape(
         rows, cfg.n_kv_heads, cfg.head_dim)
-    v = lsm._mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads,
-                                           cfg.head_dim)
-    return rope(q, pos, cfg.rope_theta), rope(k, pos, cfg.rope_theta), v
+    v = mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads, cfg.head_dim)
+    return (rope_halves(q, pos, cfg.rope_theta),
+            rope_halves(k, pos, cfg.rope_theta), v)
 
 
 def _mixed(x, m, att, lp, cfg):
     """The residual after both branches: the state branch's m [N, D] and
     the attention's heads att [N, Hq, d], each with its multiplier."""
     adt = cfg.activation_dtype()
-    a = lsm._mm(att.reshape(att.shape[0], -1), lp["w_o"], adt)
+    a = mm(att.reshape(att.shape[0], -1), lp["w_o"], adt)
     return x + _scaled(m, cfg.ssm_out_multiplier) \
         + _scaled(a, cfg.attention_out_multiplier)
 
@@ -284,10 +285,10 @@ def _mlp(x, lp, cfg):
     adt = cfg.activation_dtype()
     gate_mult, down_mult = cfg.mlp_multipliers
     with jax.named_scope(FFN):
-        f = lsm._norm(x, lp["ffn_norm_scale"], cfg)
-        hidden = lsm._mm(f, lp["w_up"], adt) * jax.nn.silu(
-            _scaled(lsm._mm(f, lp["w_gate"], adt), gate_mult))
-        return x + _scaled(lsm._mm(hidden, lp["w_down"], adt), down_mult)
+        f = rms_norm(x, lp["ffn_norm_scale"], cfg.eps)
+        hidden = mm(f, lp["w_up"], adt) * jax.nn.silu(
+            _scaled(mm(f, lp["w_gate"], adt), gate_mult))
+        return x + _scaled(mm(hidden, lp["w_down"], adt), down_mult)
 
 
 def _embed(params, tokens, cfg):
@@ -295,20 +296,10 @@ def _embed(params, tokens, cfg):
                    cfg.embedding_multiplier)
 
 
-def _unembed(x, params, cfg):
+def _logits(x, params, cfg):
     """Final-normed x [..., D] -> logits [..., V] f32."""
-    logits = jnp.einsum("...d,vd->...v", x,
-                        params["head"].astype(cfg.activation_dtype()),
-                        preferred_element_type=jnp.float32)
-    return _scaled(logits, cfg.lm_head_multiplier)
-
-
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * len(COUNTS)
-    return {name: int(totals[i]) for i, name in enumerate(COUNTS)}
+    return _scaled(unembed(x, params["head"], cfg.activation_dtype()),
+                   cfg.lm_head_multiplier)
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +318,7 @@ def forward(params, tokens, cfg: ParallelHybridConfig):
             x = _embed(params, seq, cfg)
         for lp in params["layers"]:
             with jax.named_scope(MIXER):
-                n = lsm._norm(x, lp["norm_scale"], cfg)
+                n = rms_norm(x, lp["norm_scale"], cfg.eps)
                 m = mamba_moe.mamba_whole(
                     _scaled(n, cfg.ssm_in_multiplier), lp, cfg, mup)
                 q, k, v = _qkv(n, pos, lp, cfg)
@@ -336,8 +327,8 @@ def forward(params, tokens, cfg: ParallelHybridConfig):
                 x = _mixed(x, m, att, lp, cfg)
             x = _mlp(x, lp, cfg)
         with jax.named_scope(HEAD):
-            return _unembed(lsm._norm(x, params["final_norm_scale"], cfg),
-                            params, cfg)
+            return _logits(
+                rms_norm(x, params["final_norm_scale"], cfg.eps), params, cfg)
 
     return jax.lax.map(one, tokens)
 
@@ -371,31 +362,31 @@ def prefill(params, tokens, cache, cfg: ParallelHybridConfig, mesh=None, *,
         x = _embed(params, tokens[0], cfg)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            n = lsm._norm(x, lp["norm_scale"], cfg)
+            n = rms_norm(x, lp["norm_scale"], cfg.eps)
             with jax.named_scope("state_branch"):
                 m = mamba_moe.mamba_chunk(
                     _scaled(n, cfg.ssm_in_multiplier), lp, cache, cfg, i,
                     block, first, length, mup)
             with jax.named_scope("attention_branch"):
                 q, k, v = _qkv(n, positions, lp, cfg)
-                cache["k"] = window_moe._write_chunk(cache["k"], i, k, pages,
-                                                     start, length)
-                cache["v"] = window_moe._write_chunk(cache["v"], i, v, pages,
-                                                     start, length)
+                cache["k"] = write_chunk(cache["k"], i, k, pages, start,
+                                         length)
+                cache["v"] = write_chunk(cache["v"], i, v, pages, start,
+                                         length)
                 att = da.gqa_chunk_attention(
                     q, cache["k"], cache["v"], pages, start, layer=i,
                     impl=cfg.attn_impl)
             x = _mixed(x, m, att, lp, cfg)
         x = _mlp(x, lp, cfg)
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(offs < length, positions + 1, 0)) * layers
         counts = jnp.stack([length * layers, (c - length) * layers,
                             first.astype(jnp.int32), rows, jnp.int32(0),
                             jnp.int32(0)])
         cache["held"] = mamba_moe.rings_emptied(cache, block)
-        return _unembed(last, params, cfg), cache, counts.astype(jnp.int32)
+        return _logits(last, params, cfg), cache, counts.astype(jnp.int32)
 
 
 def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
@@ -414,37 +405,37 @@ def decode(params, tokens, cache, pos, tables, cfg: ParallelHybridConfig,
         blocks, pages = tables[:, 0], tables[:, 1:]
         live = blocks > 0
         held, held_after, folds = mamba_moe.rings_stepped(cache, blocks, cfg)
-        widx = mamba_moe.row_index(pages, pos, cache["k"])
+        widx = row_index(pages, pos, cache["k"])
         mup = _mup(cfg)
         x = _embed(params, tokens, cfg)
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            n = lsm._norm(x, lp["norm_scale"], cfg)
+            n = rms_norm(x, lp["norm_scale"], cfg.eps)
             with jax.named_scope("state_branch"):
                 m = mamba_moe.mamba_step(
                     _scaled(n, cfg.ssm_in_multiplier), lp, cache, cfg, i,
                     blocks, held, mup)
             with jax.named_scope("attention_branch"):
                 q, k, v = _qkv(n, pos, lp, cfg)
-                cache["k"] = window_moe._write_rows(cache["k"], i, k, widx)
-                cache["v"] = window_moe._write_rows(cache["v"], i, v, widx)
+                cache["k"] = write_rows(cache["k"], i, k, widx)
+                cache["v"] = write_rows(cache["v"], i, v, widx)
                 att = da.gqa_decode_attention(
                     q, cache["k"], cache["v"], pages, pos, layer=i,
                     impl=cfg.attn_impl)
             x = _mixed(x, m, att, lp, cfg)
         x = _mlp(x, lp, cfg)
     with jax.named_scope(HEAD):
-        x = lsm._norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         n_live = jnp.sum(live, dtype=jnp.int32)
         rows = jnp.sum(jnp.where(live, pos + 1, 0))
         counts = jnp.stack([n_live * layers, (b - n_live) * layers,
                             jnp.int32(0), rows * layers, rows, folds])
         cache["held"] = held_after
-        return _unembed(x, params, cfg), cache, counts.astype(jnp.int32)
+        return _logits(x, params, cfg), cache, counts.astype(jnp.int32)
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
-    state_keys=STATE_KEYS)
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, state_blocks=1, state_keys=STATE_KEYS,
+    counts=lambda cfg, totals: summarize(COUNTS, totals))

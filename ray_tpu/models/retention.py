@@ -13,8 +13,8 @@ key-value heads at the published widths, five a group):
     m = RMSNorm(h)
 
 `norm_q`, `norm_k`: RMSNorm over a head's dims with a learned scale;
-`rot`: rotary in halves (the Qwen3 layout). `ops/power_retention.py` has
-the state form of the same numbers, the two kernels and the layout.
+`rot`: rotary in halves (`blocks.rope_halves`). `ops/power_retention.py`
+has the state form of the same numbers, the two kernels and the layout.
 
 **What the engine holds for this family**: one block a sequence, the
 state of every layer and key-value head (`s [L, blocks, Hkv, d, D]`,
@@ -47,7 +47,9 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
+from ray_tpu.models.blocks import (copy_block, gather_block, mm, rms_norm,
+                                   rope_halves, scatter_block, summarize,
+                                   unembed)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import power_retention
 
@@ -167,39 +169,18 @@ def init_pool(cfg: RetentionConfig, n_blocks: int, block_size: int,
 # pieces of the layer
 # ---------------------------------------------------------------------------
 
-def _mm(x, w, adt):
-    return jnp.einsum("...d,df->...f", x, w.astype(adt),
-                      preferred_element_type=jnp.float32).astype(adt)
-
-
-def _norm(x, scale, cfg):
-    return gpt._rms_norm(x, scale.astype(x.dtype), cfg.eps)
-
-
-def rope(x, pos, theta: float):
-    """Rotary embedding on the last axis of x [N, H, d] at positions pos
-    [N], in halves: (x[i], x[i + d/2]) turned by pos * theta^(-2i/d);
-    float32 inside."""
-    half = x.shape[-1] // 2
-    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) / half)
-    ang = pos.astype(jnp.float32)[:, None, None] * inv
-    cos, sin = jnp.cos(ang), jnp.sin(ang)
-    xf = x.astype(jnp.float32)
-    x1, x2 = xf[..., :half], xf[..., half:]
-    return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin],
-                           -1).astype(x.dtype)
-
-
 def _project(h, lp, pos, cfg):
     """Normed h [N, D] at positions pos [N] -> (q [N, Hq, d], k, v
     [N, Hkv, d], log g [N, Hkv] float32)."""
     adt = cfg.activation_dtype()
     n = h.shape[0]
-    q = _mm(h, lp["w_q"], adt).reshape(n, cfg.n_heads, cfg.head_dim)
-    k = _mm(h, lp["w_k"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-    v = _mm(h, lp["w_v"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
-    q = rope(_norm(q, lp["q_norm_scale"], cfg), pos, cfg.rope_theta)
-    k = rope(_norm(k, lp["k_norm_scale"], cfg), pos, cfg.rope_theta)
+    q = mm(h, lp["w_q"], adt).reshape(n, cfg.n_heads, cfg.head_dim)
+    k = mm(h, lp["w_k"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+    v = mm(h, lp["w_v"], adt).reshape(n, cfg.n_kv_heads, cfg.head_dim)
+    q = rope_halves(rms_norm(q, lp["q_norm_scale"], cfg.eps), pos,
+                    cfg.rope_theta)
+    k = rope_halves(rms_norm(k, lp["k_norm_scale"], cfg.eps), pos,
+                    cfg.rope_theta)
     gate = jnp.einsum("nd,dj->nj", h, lp["w_g"].astype(adt),
                       preferred_element_type=jnp.float32)
     return q, k, v, jax.nn.log_sigmoid(gate + lp["b_g"].astype(jnp.float32))
@@ -208,30 +189,15 @@ def _project(h, lp, pos, cfg):
 def _mlp(x, lp, cfg):
     adt = cfg.activation_dtype()
     with jax.named_scope(FFN):
-        m = _norm(x, lp["mlp_norm_scale"], cfg)
-        hidden = (jax.nn.silu(_mm(m, lp["w_gate"], adt))
-                  * _mm(m, lp["w_up"], adt))
-        return x + _mm(hidden, lp["w_down"], adt)
+        m = rms_norm(x, lp["mlp_norm_scale"], cfg.eps)
+        hidden = jax.nn.silu(mm(m, lp["w_gate"], adt)) * mm(m, lp["w_up"], adt)
+        return x + mm(hidden, lp["w_down"], adt)
 
 
 def _mixed(x, o, lp, cfg):
     """The residual after the retention's output o [N, Hq, d] f32."""
     adt = cfg.activation_dtype()
-    return x + _mm(o.astype(adt).reshape(o.shape[0], -1), lp["w_o"], adt)
-
-
-def _unembed(x, params, cfg):
-    return jnp.einsum("...d,vd->...v", x,
-                      params["head"].astype(cfg.activation_dtype()),
-                      preferred_element_type=jnp.float32)
-
-
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * len(COUNTS)
-    return {name: int(totals[i]) for i, name in enumerate(COUNTS)}
+    return x + mm(o.astype(adt).reshape(o.shape[0], -1), lp["w_o"], adt)
 
 
 # ---------------------------------------------------------------------------
@@ -250,14 +216,14 @@ def forward(params, tokens, cfg: RetentionConfig):
         for lp in params["layers"]:
             with jax.named_scope(MIXER):
                 q, k, v, logg = _project(
-                    _norm(x, lp["mix_norm_scale"], cfg), lp, pos, cfg)
+                    rms_norm(x, lp["mix_norm_scale"], cfg.eps), lp, pos, cfg)
                 o = power_retention.retention_quadratic(
                     q, k, v, logg, eps=cfg.retention_eps)
                 x = _mixed(x, o, lp, cfg)
             x = _mlp(x, lp, cfg)
         with jax.named_scope(HEAD):
-            return _unembed(_norm(x, params["final_norm_scale"], cfg),
-                            params, cfg)
+            return unembed(rms_norm(x, params["final_norm_scale"], cfg.eps),
+                           params["head"], adt)
 
     return jax.lax.map(one, tokens)
 
@@ -288,8 +254,8 @@ def prefill(params, tokens, cache, cfg: RetentionConfig, mesh=None, *,
         x = params["embed"].astype(adt)[tokens[0]]
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
-                                     lp, positions, cfg)
+            q, k, v, logg = _project(
+                rms_norm(x, lp["mix_norm_scale"], cfg.eps), lp, positions, cfg)
             o, s, z = power_retention.retention_chunk(
                 q, k, v, logg, s, z, i, block, first, length,
                 eps=cfg.retention_eps, state_round=cfg.state_round,
@@ -297,13 +263,13 @@ def prefill(params, tokens, cache, cfg: RetentionConfig, mesh=None, *,
             x = _mixed(x, o, lp, cfg)
         x = _mlp(x, lp, cfg)
     with jax.named_scope(HEAD):
-        x = _norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         counts = jnp.stack([length, c - length, first.astype(jnp.int32),
                             jnp.zeros((), jnp.int32)])
         # a chunk leaves its block's rings empty, whoever held it before
         held = cache["held"].at[0, block].set(0)
-        return (_unembed(last, params, cfg),
+        return (unembed(last, params["head"], adt),
                 {**cache, "s": s, "z": z, "held": held}, counts)
 
 
@@ -324,8 +290,9 @@ def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
         x = params["embed"].astype(adt)[tokens]
     for i, lp in enumerate(params["layers"]):
         with jax.named_scope(MIXER):
-            q, k, v, logg = _project(_norm(x, lp["mix_norm_scale"], cfg),
-                                     lp, pos.astype(jnp.int32), cfg)
+            q, k, v, logg = _project(
+                rms_norm(x, lp["mix_norm_scale"], cfg.eps), lp,
+                pos.astype(jnp.int32), cfg)
             o, s, z, ring = power_retention.retention_step(
                 q, k, v, logg, s, z, ring, i, blocks, held,
                 eps=cfg.retention_eps, state_round=cfg.state_round,
@@ -333,17 +300,18 @@ def decode(params, tokens, cache, pos, tables, cfg: RetentionConfig,
             x = _mixed(x, o, lp, cfg)
         x = _mlp(x, lp, cfg)
     with jax.named_scope(HEAD):
-        x = _norm(x, params["final_norm_scale"], cfg)
+        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
         counts = jnp.zeros((len(COUNTS),), jnp.int32).at[-1].set(
             jnp.sum(fold, dtype=jnp.int32))
         # idle rows all name block 0 and all leave its count as it was
         held = cache["held"].at[0, blocks].set(after)
-        return (_unembed(x, params, cfg),
+        return (unembed(x, params["head"], adt),
                 {"s": s, "z": z, "ring": ring, "held": held}, counts)
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize, state_blocks=1,
-    paged=False, state_keys=("s", "z", "ring", "held"))
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, state_blocks=1, paged=False,
+    counts=lambda cfg, totals: summarize(COUNTS, totals),
+    state_keys=("s", "z", "ring", "held"))

@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh
 
-from ray_tpu.models.gpt import _layer, _rms_norm
+from ray_tpu.models.blocks import rms_norm
+from ray_tpu.models.gpt import layer
 
 
 @dataclass(frozen=True)
@@ -142,9 +143,9 @@ def _gelu_mlp(h, lp):
 
 
 def _block(x, lp, cfg: ViTConfig):
-    """`gpt._layer` with bidirectional attention and an ungated GELU
+    """`gpt.layer` with bidirectional attention and an ungated GELU
     feed-forward; every einsum emits float32."""
-    x, _, _ = _layer(x, lp, cfg, jnp.float32, _attend, _gelu_mlp)
+    x, _, _ = layer(x, lp, cfg, jnp.float32, _attend, _gelu_mlp)
     return x
 
 
@@ -164,7 +165,7 @@ def forward(params, images, cfg: ViTConfig, mesh: Mesh | None = None):
         return block(x, lp), None
 
     x, _ = jax.lax.scan(scan_body, x, params["layers"])
-    x = _rms_norm(x, params["final_ln_scale"].astype(adt))
+    x = rms_norm(x, params["final_ln_scale"])
     pooled = jnp.mean(x.astype(jnp.float32), axis=1)
     return pooled @ params["head"] + params["head_bias"]
 

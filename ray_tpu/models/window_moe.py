@@ -40,7 +40,7 @@ positions `j * bs ..`, wherever the engine keeps it. Prefill writes a
 chunk's rows before it attends, a chunk bucket's padding is written
 nowhere, and decode's idle rows (table all 0) rewrite the trash pages.
 
-Experts: `models/latent_sparse_moe.py`'s `routing` and `expert_layer`
+Experts: `models/blocks.py`'s `routing` and `expert_layer`
 (`ops/grouped_experts.py`) over the held experts, without groups or
 bias. What the absent experts would add is left out.
 
@@ -56,8 +56,10 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models import gpt
-from ray_tpu.models import latent_sparse_moe as lsm
+from ray_tpu.models.blocks import (Experts, copy_block, expert_layer,
+                                   gather_block, layer_norm, mm, rope_pairs,
+                                   scatter_block, summarize, unembed,
+                                   write_chunk, write_rows)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts, quant
@@ -102,7 +104,7 @@ class WindowMoEConfig:
     # keeps that value in the pool's type (an int8 pool's numbers, not
     # its bytes). `full_window` cuts the full layers at so many
     # positions, as if they were window layers: the window wrong.
-    # `expert_round` is `latent_sparse_moe.expert_layer`'s: the routed
+    # `expert_round` is `blocks.expert_layer`'s: the routed
     # experts' inputs and matrices on the float8_e4m3fn grid (a probe,
     # not one of the cell's controls: with an eighth of the experts held
     # it reads inside the sound runs' range, PERF.md section 6, PR 44)
@@ -129,6 +131,12 @@ class WindowMoEConfig:
 
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def experts(self) -> Experts:
+        return Experts(self.router_width, self.experts_per_token,
+                       self.norm_topk, self.held_from,
+                       expert_round=self.expert_round, impl=self.sparse_impl)
 
     @property
     def family(self):
@@ -229,75 +237,20 @@ def _stored(rows, cfg):
     return rows
 
 
-def _write_rows(pool, layer: int, rows, widx):
-    """A decode step's rows [B, Hkv, d], one position a stream, into layer
-    `layer` of a head-major pool at the flat positions widx [B] (`page *
-    bs + offset`; `n_blocks * bs` and beyond: dropped). The pool is seen
-    as rows of d, `(page * Hkv + head) * bs + offset`, so that what is
-    scattered is whole contiguous rows: a window of (head, d), which a
-    page does not hold side by side, makes XLA relayout the whole pool
-    around the scatter."""
-    layers, n_blocks, hkv, bs, d = pool.shape
-    heads = jnp.arange(hkv, dtype=jnp.int32)
-    at = ((widx // bs)[:, None] * hkv + heads) * bs + (widx % bs)[:, None]
-    at = jnp.where((widx < n_blocks * bs)[:, None], at, n_blocks * hkv * bs)
-    flat = pool.reshape(layers, n_blocks * hkv * bs, d)
-    flat = flat.at[layer, at.reshape(-1)].set(
-        rows.astype(pool.dtype).reshape(-1, d), mode="drop")
-    return flat.reshape(pool.shape)
-
-
-def _write_chunk(pool, layer: int, rows, table, start, length):
-    """A chunk's rows [C, Hkv, d] at positions start .. start + length -
-    1 into layer `layer` of a head-major pool, a page at a time: each of
-    the pages the chunk can touch is read, its rows that the chunk holds
-    are replaced, and it is written back where it lies (a slice update in
-    place; a page the chunk does not reach is written back as it was).
-    The bucket's padding past `length` is written nowhere."""
-    c, hkv, d = rows.shape
-    bs, cols = pool.shape[3], table.shape[0]
-    padded = jnp.pad(rows.astype(pool.dtype), ((bs, bs), (0, 0), (0, 0)))
-    offs = jnp.arange(bs, dtype=jnp.int32)
-    first = start // bs
-    for i in range(-(-c // bs) + 1):
-        page = first + i
-        # the page's row r is the chunk's row page * bs + r - start
-        lo = page * bs - start
-        mine = jax.lax.dynamic_slice_in_dim(padded, lo + bs, bs)
-        live = (lo + offs >= 0) & (lo + offs < length) & (page < cols)
-        blk = table[jnp.minimum(page, cols - 1)]
-        old = jax.lax.dynamic_slice(
-            pool, (layer, blk, 0, 0, 0), (1, 1, hkv, bs, d))
-        new = jnp.where(live[None, None, None, :, None],
-                        mine.swapaxes(0, 1)[None, None], old)
-        pool = jax.lax.dynamic_update_slice(pool, new, (layer, blk, 0, 0, 0))
-    return pool
-
-
 # ---------------------------------------------------------------------------
 # pieces of the layer
 # ---------------------------------------------------------------------------
-
-def _layer_norm(x, scale, eps):
-    xf = x.astype(jnp.float32)
-    mu = jnp.mean(xf, -1, keepdims=True)
-    var = jnp.mean((xf - mu) ** 2, -1, keepdims=True)
-    return ((xf - mu) * jax.lax.rsqrt(var + eps)
-            * scale.astype(jnp.float32)).astype(x.dtype)
-
 
 def _qkv(n, lp, kind, pos, cfg):
     """Normed n [N, D] at positions pos [N] -> q [N, Hq, d], k, v [N,
     Hkv, d] in the activation type, rotary applied in a window layer."""
     adt = cfg.activation_dtype()
     rows = n.shape[0]
-    q = lsm._mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads, cfg.head_dim)
-    k = lsm._mm(n, lp["w_k"], adt).reshape(rows, cfg.n_kv_heads,
-                                           cfg.head_dim)
-    v = lsm._mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads,
-                                           cfg.head_dim)
+    q = mm(n, lp["w_q"], adt).reshape(rows, cfg.n_heads, cfg.head_dim)
+    k = mm(n, lp["w_k"], adt).reshape(rows, cfg.n_kv_heads, cfg.head_dim)
+    v = mm(n, lp["w_v"], adt).reshape(rows, cfg.n_kv_heads, cfg.head_dim)
     if kind == "window":
-        q, k = (lsm.rope(a, pos, cfg.rope_theta) for a in (q, k))
+        q, k = (rope_pairs(a, pos, cfg.rope_theta) for a in (q, k))
     return q, k, v
 
 
@@ -306,7 +259,8 @@ def _experts(n, lp, cfg, live, kernel):
     The shared experts' matrices lie side by side, so their sum is one
     gated MLP and their mean a quarter of it."""
     with jax.named_scope("routed_experts"):
-        routed, shared, counts = lsm.expert_layer(n, lp, cfg, live, kernel)
+        routed, shared, counts = expert_layer(
+            n, lp, cfg.experts, cfg.activation_dtype(), live, kernel)
     with jax.named_scope("shared_experts"):
         shared = shared * (1.0 / cfg.shared_experts)
     return routed + shared.astype(routed.dtype), counts
@@ -314,12 +268,6 @@ def _experts(n, lp, cfg, live, kernel):
 
 def _window_of(kind, cfg):
     return cfg.window if kind == "window" else cfg.full_window
-
-
-def _unembed(x, params, cfg):
-    return cfg.logit_scale * jnp.einsum(
-        "...d,vd->...v", x, params["embed"].astype(cfg.activation_dtype()),
-        preferred_element_type=jnp.float32)
 
 
 def _counts(window_rows, full_rows, expert_counts):
@@ -342,18 +290,6 @@ def _rows_read(pos, live, cfg):
             jnp.sum(full) * (cfg.n_layers - n_window))
 
 
-def summarize(cfg, totals) -> dict:
-    """`COUNTS` summed over a window (None: nothing ran yet) -> the
-    engine's `stats()` entries."""
-    if totals is None:
-        totals = [0] * (len(COUNTS) + cfg.held_count)
-    out = {name: int(totals[i]) for i, name in enumerate(COUNTS)}
-    load = [int(v) for v in totals[len(COUNTS):]]
-    mean = sum(load) / max(len(load), 1)
-    out["expert_load_max_over_mean"] = max(load) / mean if mean else 0.0
-    return out
-
-
 # ---------------------------------------------------------------------------
 # whole sequence (tests)
 # ---------------------------------------------------------------------------
@@ -371,20 +307,20 @@ def forward(params, tokens, cfg: WindowMoEConfig):
             x = params["embed"].astype(adt)[seq]
         for lp, kind in zip(params["layers"], cfg.kinds):
             with jax.named_scope(MIXER):
-                n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+                n = layer_norm(x, lp["norm_scale"], cfg.eps)
                 q, k, v = _qkv(n, lp, kind, pos, cfg)
                 att = da.reference_gqa_attention(
                     q[None], k[None], v[None], jnp.zeros((1,), jnp.int32),
                     _window_of(kind, cfg))[0]
-                a = lsm._mm(att.reshape(t, -1), lp["w_out"], adt)
+                a = mm(att.reshape(t, -1), lp["w_out"], adt)
             with jax.named_scope(FFN):
                 ff, _ = _experts(n, lp, cfg, live,
                                  grouped_experts.EXPERTS_GROUPED)
                 x = x + a + ff
         with jax.named_scope(HEAD):
-            return _unembed(
-                _layer_norm(x, params["final_norm_scale"], cfg.eps), params,
-                cfg)
+            return cfg.logit_scale * unembed(
+                layer_norm(x, params["final_norm_scale"], cfg.eps),
+                params["embed"], adt)
 
     return jax.lax.map(one, tokens)
 
@@ -423,19 +359,19 @@ def prefill(params, tokens, cache, cfg: WindowMoEConfig, mesh=None, *,
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
         with jax.named_scope(MIXER):
-            n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+            n = layer_norm(x, lp["norm_scale"], cfg.eps)
             q, k, v = _qkv(n, lp, kind, positions, cfg)
             kk, vk = POOLS[kind]
             layer = at[kind]
-            cache[kk] = _write_chunk(cache[kk], layer, _stored(k, cfg),
-                                     tables[kind], start, length)
-            cache[vk] = _write_chunk(cache[vk], layer, _stored(v, cfg),
-                                     tables[kind], start, length)
+            cache[kk] = write_chunk(cache[kk], layer, _stored(k, cfg),
+                                    tables[kind], start, length)
+            cache[vk] = write_chunk(cache[vk], layer, _stored(v, cfg),
+                                    tables[kind], start, length)
             with jax.named_scope(f"{kind}_attention"):
                 att = da.gqa_chunk_attention(
                     q, cache[kk], cache[vk], tables[kind], start, layer=layer,
                     window=_window_of(kind, cfg), impl=cfg.attn_impl)
-            a = lsm._mm(att.reshape(c, -1), lp["w_out"], adt)
+            a = mm(att.reshape(c, -1), lp["w_out"], adt)
         with jax.named_scope(FFN):
             ff, counts = _experts(n, lp, cfg, valid,
                                   grouped_experts.EXPERTS_GROUPED_PREFILL)
@@ -443,9 +379,9 @@ def prefill(params, tokens, cache, cfg: WindowMoEConfig, mesh=None, *,
             x = x + a + ff
         at[kind] += 1
     with jax.named_scope(HEAD):
-        x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
+        x = layer_norm(x, params["final_norm_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-        return (_unembed(last, params, cfg), cache,
+        return (cfg.logit_scale * unembed(last, params["embed"], adt), cache,
                 _counts(*_rows_read(positions, valid, cfg), expert_counts))
 
 
@@ -475,19 +411,19 @@ def decode(params, tokens, cache, pos, tables, cfg: WindowMoEConfig,
     expert_counts = []
     for lp, kind in zip(params["layers"], cfg.kinds):
         with jax.named_scope(MIXER):
-            n = _layer_norm(x, lp["norm_scale"], cfg.eps)
+            n = layer_norm(x, lp["norm_scale"], cfg.eps)
             q, k, v = _qkv(n, lp, kind, pos, cfg)
             kk, vk = POOLS[kind]
             layer = at[kind]
-            cache[kk] = _write_rows(cache[kk], layer, _stored(k, cfg),
-                                    widx[kind])
-            cache[vk] = _write_rows(cache[vk], layer, _stored(v, cfg),
-                                    widx[kind])
+            cache[kk] = write_rows(cache[kk], layer, _stored(k, cfg),
+                                   widx[kind])
+            cache[vk] = write_rows(cache[vk], layer, _stored(v, cfg),
+                                   widx[kind])
             with jax.named_scope(f"{kind}_attention"):
                 att = da.gqa_decode_attention(
                     q, cache[kk], cache[vk], tabs[kind], pos, layer=layer,
                     window=_window_of(kind, cfg), impl=cfg.attn_impl)
-            a = lsm._mm(att.reshape(att.shape[0], -1), lp["w_out"], adt)
+            a = mm(att.reshape(att.shape[0], -1), lp["w_out"], adt)
         with jax.named_scope(FFN):
             ff, counts = _experts(n, lp, cfg, live,
                                   grouped_experts.EXPERTS_GROUPED)
@@ -495,13 +431,13 @@ def decode(params, tokens, cache, pos, tables, cfg: WindowMoEConfig,
             x = x + a + ff
         at[kind] += 1
     with jax.named_scope(HEAD):
-        x = _layer_norm(x, params["final_norm_scale"], cfg.eps)
-        return (_unembed(x, params, cfg), cache,
+        x = layer_norm(x, params["final_norm_scale"], cfg.eps)
+        return (cfg.logit_scale * unembed(x, params["embed"], adt), cache,
                 _counts(*_rows_read(pos, live, cfg), expert_counts))
 
 
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
-    copy_block=gpt.copy_block, gather_block=gpt.gather_block,
-    scatter_block=gpt.scatter_block, counts=summarize,
-    bounded_keys=BOUNDED_KEYS)
+    copy_block=copy_block, gather_block=gather_block,
+    scatter_block=scatter_block, bounded_keys=BOUNDED_KEYS,
+    counts=lambda cfg, totals: summarize(COUNTS, totals, cfg.held_count))

@@ -26,8 +26,8 @@ experts, a tied head, full layers without positions, served) is the
 period of layer kinds and the grouped heads, so this is a module of its
 own beside it and not that module at other switches: of the block's seven
 lines one would be common. The pieces that are common to the trained
-families are imported: `latent_sparse_moe`'s `_mm`, `_rounded` and the
-counting, `ops.grouped_experts`, `ops.flash_attention`.
+families are imported: `models/blocks.py`'s `mm`, `rms_norm` and
+`rounded`, `ops.grouped_experts`, `ops.flash_attention`.
 
 Training (`forward_features`): attention through the flash kernels, a
 window layer's under their `_band` names (`ops/flash_attention.py`: the
@@ -53,9 +53,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import gpt
-from ray_tpu.models import latent_sparse_moe as lsm
-from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER
+from ray_tpu.models.blocks import mm, rms_norm, rounded, unembed
+from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, TrainingFamily
 from ray_tpu.ops import grouped_experts
 
 EMBED_INIT = 1.0        # `latent_sparse_moe.EMBED_INIT`'s reason
@@ -165,8 +164,7 @@ class WindowMoETrainConfig:
     # which experts a common id's tokens choose is no longer a draw a
     # seed of how much of the work is this chip's (PERF.md, PR 57)
     router_tied_blocks: int = 1
-    # test-only, for the benchmark's control
-    # (`latent_sparse_moe.expert_layer`'s)
+    # test-only, for the benchmark's control (`blocks.rounded`'s grid)
     expert_round: str = "none"       # none | float8_e4m3fn
 
     def __post_init__(self):
@@ -190,6 +188,10 @@ class WindowMoETrainConfig:
 
     def activation_dtype(self):
         return jnp.dtype(self.dtype)
+
+    @property
+    def training(self):
+        return TRAINING
 
 
 def from_published(*, hidden_size, num_hidden_layers, num_attention_heads,
@@ -272,25 +274,19 @@ def param_logical_axes(cfg: WindowMoETrainConfig):
 # pieces of the layer
 # ---------------------------------------------------------------------------
 
-def _norm(x, scale, cfg):
-    return gpt._rms_norm(x, scale.astype(x.dtype), cfg.eps)
-
-
 def _qkv(n, lp, kind, pos, cfg):
     """Normed n [B, T, D] at positions pos [T] -> q [B, T, Hq, d], k, v
     [B, T, Hkv, d] in the activation type, rotary by the layer's kind."""
     adt = cfg.activation_dtype()
     b, t, _ = n.shape
     spec = cfg.rope_window if kind == "window" else cfg.rope_full
-    q = lsm._mm(n, lp["w_q"], adt).reshape(b, t, cfg.n_heads, cfg.head_dim)
-    k = lsm._mm(n, lp["w_k"], adt).reshape(b, t, cfg.n_kv_heads,
-                                           cfg.head_dim)
-    v = lsm._mm(n, lp["w_v"], adt).reshape(b, t, cfg.n_kv_heads,
-                                           cfg.head_dim)
+    q = mm(n, lp["w_q"], adt).reshape(b, t, cfg.n_heads, cfg.head_dim)
+    k = mm(n, lp["w_k"], adt).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
+    v = mm(n, lp["w_v"], adt).reshape(b, t, cfg.n_kv_heads, cfg.head_dim)
     return rotary(q, pos, spec), rotary(k, pos, spec), v
 
 
-def routing(m, lp, cfg):
+def softmax_routing(m, lp, cfg):
     """-> (chosen [N, k] i32, weights [N, k] f32): the `experts_per_token`
     largest of a softmax over the router's whole width, in float32,
     renormalised over the chosen where `norm_topk`."""
@@ -310,13 +306,13 @@ def _experts(m, lp, cfg, kernel: str):
     adt = cfg.activation_dtype()
     n, d = m.shape
     chunk = math.gcd(n, cfg.expert_chunk)
-    gate, up, down = (lsm._rounded(lp[name], cfg)
+    gate, up, down = (rounded(lp[name], cfg.expert_round)
                       for name in ("we_gate", "we_up", "we_down"))
 
     def some(rows):
-        chosen, weights = routing(rows, lp, cfg)
+        chosen, weights = softmax_routing(rows, lp, cfg)
         routed, load = grouped_experts.experts_grouped(
-            lsm._rounded(rows, cfg), chosen, weights, gate, up, down,
+            rounded(rows, cfg.expert_round), chosen, weights, gate, up, down,
             held_from=cfg.held_from, impl=cfg.sparse_impl, name=kernel)
         every = jnp.sum(chosen[..., None] == jnp.arange(cfg.router_width),
                         (0, 1), dtype=jnp.int32)
@@ -327,12 +323,6 @@ def _experts(m, lp, cfg, kernel: str):
         return some(m)
     routed, counts = jax.lax.map(some, m.reshape(n // chunk, chunk, d))
     return routed.reshape(n, d), jnp.sum(counts, 0)
-
-
-def _unembed(x, params, cfg):
-    return jnp.einsum("...d,vd->...v", x,
-                      params["head"].astype(cfg.activation_dtype()),
-                      preferred_element_type=jnp.float32)
 
 
 # ---------------------------------------------------------------------------
@@ -350,20 +340,20 @@ def forward(params, tokens, cfg: WindowMoETrainConfig):
         x = params["embed"].astype(adt)[tokens]
     for lp, kind in zip(params["layers"], cfg.kinds):
         with jax.named_scope(MIXER):
-            q, k, v = _qkv(_norm(x, lp["attn_norm_scale"], cfg), lp, kind,
-                           pos, cfg)
+            q, k, v = _qkv(rms_norm(x, lp["attn_norm_scale"], cfg.eps), lp,
+                           kind, pos, cfg)
             att = reference_attention(
                 q, k, v, causal=True,
                 window=cfg.window if kind == "window" else None)
-            x = x + lsm._mm(att.reshape(b, t, -1), lp["w_out"], adt)
+            x = x + mm(att.reshape(b, t, -1), lp["w_out"], adt)
         with jax.named_scope(FFN):
-            m = _norm(x, lp["ffn_norm_scale"], cfg)
+            m = rms_norm(x, lp["ffn_norm_scale"], cfg.eps)
             x = x + _experts(m.reshape(b * t, -1), lp, cfg,
                              grouped_experts.EXPERTS_GROUPED)[0].reshape(
                                  x.shape)
     with jax.named_scope(HEAD):
-        return _unembed(_norm(x, params["final_norm_scale"], cfg), params,
-                        cfg)
+        return unembed(rms_norm(x, params["final_norm_scale"], cfg.eps),
+                       params["head"], adt)
 
 
 # ---------------------------------------------------------------------------
@@ -376,16 +366,16 @@ def _train_layer(x, lp, kind, pos, cfg):
     adt = cfg.activation_dtype()
     b, t, d = x.shape
     with jax.named_scope(MIXER):
-        q, k, v = _qkv(_norm(x, lp["attn_norm_scale"], cfg), lp, kind, pos,
-                       cfg)
+        q, k, v = _qkv(rms_norm(x, lp["attn_norm_scale"], cfg.eps), lp, kind,
+                       pos, cfg)
         att = flash_attention(q, k, v, True, cfg.flash_block_q,
                               cfg.flash_block_kv,
                               cfg.window if kind == "window" else None)
-        x = x + lsm._mm(att.reshape(b, t, -1), lp["w_out"], adt)
+        x = x + mm(att.reshape(b, t, -1), lp["w_out"], adt)
     with jax.named_scope(FFN):
         routed, counts = _experts(
-            _norm(x, lp["ffn_norm_scale"], cfg).reshape(b * t, d), lp, cfg,
-            grouped_experts.EXPERTS_GROUPED_TRAIN)
+            rms_norm(x, lp["ffn_norm_scale"], cfg.eps).reshape(b * t, d), lp,
+            cfg, grouped_experts.EXPERTS_GROUPED_TRAIN)
         return x + routed.reshape(b, t, d), counts
 
 
@@ -414,7 +404,8 @@ def forward_features(params, tokens, cfg: WindowMoETrainConfig, mesh=None):
             policy=policy)(x, lp)
         counts.append(c)
     with jax.named_scope(HEAD):
-        return _norm(x, params["final_norm_scale"], cfg), jnp.stack(counts)
+        return (rms_norm(x, params["final_norm_scale"], cfg.eps),
+                jnp.stack(counts))
 
 
 def expert_metrics(params, counts, cfg: WindowMoETrainConfig):
@@ -429,3 +420,9 @@ def expert_metrics(params, counts, cfg: WindowMoETrainConfig):
         "expert_load_max": jnp.max(held),
         "expert_load_mean": jnp.mean(held.astype(jnp.float32)),
     }
+
+
+TRAINING = TrainingFamily(
+    init_params=init_params, param_logical_axes=param_logical_axes,
+    forward_features=forward_features, head="head",
+    aux_update=expert_metrics)

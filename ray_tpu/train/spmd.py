@@ -312,7 +312,7 @@ def _make_lm_trainer(init_fn, logical_axes, loss_fn, mesh: Mesh, rng,
                      aux_update: Callable | None = None,
                      frozen: Callable | None = None):
     """Shared assembly behind make_gpt_trainer / make_moe_trainer /
-    make_latent_moe_trainer / make_window_moe_trainer."""
+    make_features_trainer."""
     rng = jax.random.key(0) if rng is None else rng
     optimizer = optimizer or default_optimizer()
     state = None
@@ -347,6 +347,7 @@ def make_gpt_pipeline_trainer(cfg, mesh: Mesh, num_microbatches: int = 2,
     from jax.sharding import PartitionSpec as P
 
     from ray_tpu.models import gpt
+    from ray_tpu.models.blocks import rms_norm
     from ray_tpu.parallel.pipeline import pipeline_apply
 
     s_count = max(mesh.shape.get("pipe", 1), 1)
@@ -371,14 +372,14 @@ def make_gpt_pipeline_trainer(cfg, mesh: Mesh, num_microbatches: int = 2,
             def body(h, lp):
                 # mesh=None: attention stays local to the stage shard (no
                 # nested seq-axis collectives inside the pipe shard_map)
-                return gpt._block(h, lp, cfg, None), None
+                return gpt.block(h, lp, cfg, None), None
             out, _ = jax.lax.scan(body, xm, sp)
             return out
 
         x = pipeline_apply(stage_fn, per_stage, x, mesh=mesh,
                            num_microbatches=num_microbatches,
                            batch_spec=P(None, ("data", "fsdp")))
-        x = gpt._rms_norm(x, params["final_ln_scale"].astype(adt))
+        x = rms_norm(x, params["final_ln_scale"])
         logits = jnp.einsum("btd,vd->btv", x, params["embed"].astype(adt),
                             preferred_element_type=jnp.float32)
         return jnp.mean(softmax_xent(logits, batch["targets"]))
@@ -402,81 +403,47 @@ def make_moe_trainer(cfg, mesh: Mesh, rng=None,
         rules, accum=accum, init_state=init_state)
 
 
-def latent_moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
-                       with_counts: bool = False):
+def features_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
+                     with_counts: bool = False):
     """Mean negative log-likelihood of pre-shifted inputs/targets [B, T]
-    under `models.latent_sparse_moe` (layers without an indexer), over
-    the rows of the vocabulary held, untied head, through
-    `fused_softmax_xent`; no auxiliary loss. With `with_counts`, (loss, the
-    forward's expert counts)."""
-    from ray_tpu.models import latent_sparse_moe as lsm
+    under the family `cfg.training` (`models.family.TrainingFamily`):
+    its features against its output matrix, over the rows of the
+    vocabulary held, through `fused_softmax_xent`; no auxiliary loss. With
+    `with_counts`, (loss, what the forward counted)."""
     from ray_tpu.ops.fused_xent import fused_softmax_xent
 
-    x, counts = lsm.forward_features(params, batch["inputs"], cfg, mesh)
+    family = cfg.training
+    x, counts = family.forward_features(params, batch["inputs"], cfg, mesh)
     with jax.named_scope(HEAD):
         nll = fused_softmax_xent(
-            x, params["head"].astype(cfg.activation_dtype()),
+            x, params[family.head].astype(cfg.activation_dtype()),
             batch["targets"], mesh=mesh)
     loss = _mean_nll(nll, batch.get("mask"))
     return (loss, counts) if with_counts else loss
 
 
-def make_latent_moe_trainer(cfg, mesh: Mesh, rng=None,
-                            optimizer: optax.GradientTransformation | None
-                            = None,
-                            rules: dict | None = None,
-                            init_state: bool = True):
-    """`make_gpt_trainer`'s assembly for `models.latent_sparse_moe`
-    (layers without an indexer: latent attention through the flash
-    kernels, this chip's share of the routed experts with their backward
-    kernels). `router_bias` is no leaf of the optimizer, whose state has
-    nothing for it: the step moves it after the optimizer's update, by
-    the step's own expert counts (`update_router_bias`), and its metrics
-    carry `expert_pairs_here`, `expert_pairs_routed`, `expert_load_max`,
-    `expert_load_mean` and `router_bias_abs_max`."""
-    from ray_tpu.models import latent_sparse_moe as lsm
-
+def make_features_trainer(
+        cfg, mesh: Mesh, rng=None,
+        optimizer: optax.GradientTransformation | None = None,
+        rules: dict | None = None, init_state: bool = True):
+    """`make_gpt_trainer`'s assembly for the family `cfg.training`: its
+    parameters and their axes, `features_loss_fn`, and the two hooks of
+    `make_train_step`: `frozen` leaves, no part of the optimizer's state,
+    where the family has them, and the `aux_update` that ends the step on
+    the forward's counts and adds its metrics to the step's
+    (`models.latent_sparse_moe`: the router bias moves by the step's
+    expert loads; `models.window_moe_train`: the expert metrics alone)."""
+    family = cfg.training
     return _make_lm_trainer(
-        lambda key: lsm.init_params(key, cfg), lsm.param_logical_axes(cfg),
-        partial(latent_moe_loss_fn, cfg=cfg, mesh=mesh, with_counts=True),
+        lambda key: family.init_params(key, cfg),
+        family.param_logical_axes(cfg),
+        partial(features_loss_fn, cfg=cfg, mesh=mesh, with_counts=True),
         mesh, rng, optimizer, rules, init_state=init_state,
-        aux_update=partial(lsm.update_router_bias, cfg=cfg),
-        frozen=lsm.is_router_bias)
+        aux_update=partial(family.aux_update, cfg=cfg),
+        frozen=family.frozen)
 
 
-def window_moe_loss_fn(params, batch, cfg, mesh: Mesh | None = None,
-                       with_counts: bool = False):
-    """`latent_moe_loss_fn` for `models.window_moe_train`: the mean
-    negative log-likelihood of pre-shifted inputs/targets [B, T] over the
-    rows of the vocabulary held, untied head, through
-    `fused_softmax_xent`; no auxiliary loss."""
-    from ray_tpu.models import window_moe_train as wmt
-    from ray_tpu.ops.fused_xent import fused_softmax_xent
-
-    x, counts = wmt.forward_features(params, batch["inputs"], cfg, mesh)
-    with jax.named_scope(HEAD):
-        nll = fused_softmax_xent(
-            x, params["head"].astype(cfg.activation_dtype()),
-            batch["targets"], mesh=mesh)
-    loss = _mean_nll(nll, batch.get("mask"))
-    return (loss, counts) if with_counts else loss
-
-
-def make_window_moe_trainer(cfg, mesh: Mesh, rng=None,
-                            optimizer: optax.GradientTransformation | None
-                            = None,
-                            rules: dict | None = None,
-                            init_state: bool = True):
-    """`make_gpt_trainer`'s assembly for `models.window_moe_train` (window
-    and full layers through the flash kernels, banded and not, at grouped
-    heads; this chip's share of the routed experts with their backward
-    kernels). Every leaf is the optimizer's and nothing moves outside it;
-    the step's metrics carry `expert_pairs_here`, `expert_pairs_routed`,
-    `expert_load_max` and `expert_load_mean`."""
-    from ray_tpu.models import window_moe_train as wmt
-
-    return _make_lm_trainer(
-        lambda key: wmt.init_params(key, cfg), wmt.param_logical_axes(cfg),
-        partial(window_moe_loss_fn, cfg=cfg, mesh=mesh, with_counts=True),
-        mesh, rng, optimizer, rules, init_state=init_state,
-        aux_update=partial(wmt.expert_metrics, cfg=cfg))
+# The names `benchmarks/configs/kanana-2-30b-a3b.json` and
+# `mellum2-12b-a2.5b.json` call by `program.entry` (ROADMAP D18).
+latent_moe_loss_fn = window_moe_loss_fn = features_loss_fn
+make_latent_moe_trainer = make_window_moe_trainer = make_features_trainer

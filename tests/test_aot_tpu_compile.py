@@ -240,7 +240,7 @@ def test_flash_attention_compiles_on_a_four_device_mesh(topo):
         mesh, PartitionSpec(("data", "fsdp"), None, "tensor", None))
     qkv = jax.ShapeDtypeStruct((8, 1024, H, D), BF16, sharding=sharding)
     text = jax.jit(
-        lambda q, k, v: gpt._attention(q, k, v, cfg.attn_impl, mesh)
+        lambda q, k, v: gpt.attention(q, k, v, cfg.attn_impl, mesh)
     ).lower(qkv, qkv, qkv).compile().as_text()
     assert text.count('custom_call_target="tpu_custom_call"') == 1
 

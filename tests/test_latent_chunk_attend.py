@@ -99,7 +99,7 @@ def test_the_kernel_is_the_reference_and_the_softmax_by_hand(
         shape, mask, dtype, c, start, length):
     cfg, q_nope, q_rope, pool, table, positions, valid, selected, lp, rows \
         = case(shape, mask, dtype, c, start, length)
-    got, want = (np.asarray(lsm._prefill_attend(
+    got, want = (np.asarray(lsm.prefill_attend(
         q_nope, q_rope, pool, 1, table, positions, valid, selected, lp,
         dataclasses.replace(cfg, sparse_impl=impl)), np.float32)
         for impl in ("pallas", "jax"))

@@ -13,6 +13,7 @@ import numpy as np
 import pytest
 
 from benchmarks.refs import latent_moe_train as ref
+from ray_tpu.models import blocks
 from ray_tpu.models import latent_sparse_moe as lsm
 from ray_tpu.ops import grouped_experts
 from ray_tpu.ops.flash_attention import flash_attention
@@ -252,20 +253,20 @@ def test_the_control_s_rounding_moves_the_loss(params):
 
 @pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
 def test_the_control_s_rounding_is_the_float8_grid_by_arithmetic(dtype):
-    """`_rounded` works on the bits (the TPU compiler drops a cast to
+    """`rounded` works on the bits (the TPU compiler drops a cast to
     float8 and back): in the type's normal range it is the cast's value,
     ties to even, and the gradient passes as through a cast."""
     cfg = config(expert_round="float8_e4m3fn")
     a = (3 * jax.random.normal(jax.random.key(5), (4096,))).astype(dtype)
-    got = np.asarray(lsm._rounded(a, cfg), np.float32)
+    got = np.asarray(blocks.rounded(a, cfg.expert_round), np.float32)
     want = np.asarray(a.astype(jnp.float8_e4m3fn).astype(dtype), np.float32)
     normal = np.abs(np.asarray(a, np.float32)) >= 2.0 ** -6
     assert normal.sum() > 4000 and np.all(got[normal] == want[normal])
     assert len(np.unique(np.abs(got[normal]))) < 80
-    grad = jax.grad(lambda x: jnp.sum(lsm._rounded(x, cfg)
+    grad = jax.grad(lambda x: jnp.sum(blocks.rounded(x, cfg.expert_round)
                                       .astype(jnp.float32)))(a)
     assert np.all(np.asarray(grad, np.float32) == 1.0)
-    assert lsm._rounded(a, config()) is a
+    assert blocks.rounded(a, config().expert_round) is a
 
 
 # -- the two kernels' backward passes ---------------------------------------

@@ -15,7 +15,7 @@ import numpy as np
 import pytest
 
 from benchmarks.refs import latent_sparse_moe as ref
-from ray_tpu.models import gpt
+from ray_tpu.models import blocks, gpt
 from ray_tpu.models import latent_sparse_moe as lsm
 from ray_tpu.ops import grouped_experts, sparse_latent
 from ray_tpu.serve.engine import (FROM_CHUNK, FROM_STEP, InferenceEngine,
@@ -349,7 +349,8 @@ def test_the_shares_add_up_to_the_uncut_layer(n_shared):
             cfg = config("pallas", n_routed_experts=hi - lo,
                          experts_held_from=lo, n_shared_experts=n_shared)
             assert share["ws_gate"].shape[1] == 32 * n_shared
-            routed, shared, counts = lsm.expert_layer(h2, share, cfg)
+            routed, shared, counts = blocks.expert_layer(
+                h2, share, cfg.experts, cfg.activation_dtype())
             np.testing.assert_allclose(shared, ref.shared_part(h2, lp),
                                        rtol=0, atol=TOL)
             assert int(counts[1]) == 48 * 2     # every pair is routed

@@ -17,8 +17,8 @@ import numpy as np
 import pytest
 
 from benchmarks.refs import linear_latent as ref
-from ray_tpu.models import gpt, latent_sparse_moe as lsm, linear_latent, \
-    retention
+from ray_tpu.models import blocks, gpt, latent_sparse_moe as lsm, \
+    linear_latent, retention
 from ray_tpu.ops import sparse_latent
 from ray_tpu.serve.engine import BlockAllocator, InferenceEngine
 from ray_tpu.util import faults
@@ -401,7 +401,7 @@ def test_one_group_routes_as_before():
     cfg = lsm.LatentSparseMoEConfig(router_width=16, experts_per_token=4)
     assert (cfg.n_group, cfg.topk_group) == (1, 1)
     h2, lp = router_inputs(cfg)
-    chosen, weights = lsm.routing(h2, lp, cfg)
+    chosen, weights = blocks.routing(h2, lp, cfg.experts)
     g = jax.nn.sigmoid(h2 @ lp["router"])
     _, want = jax.lax.top_k(g + lp["router_bias"], 4)
     np.testing.assert_array_equal(np.asarray(chosen), np.asarray(want))
@@ -415,7 +415,7 @@ def test_one_group_routes_as_before():
 def test_groups_limit_the_choice_as_the_reference_s_do():
     cfg = config()
     h2, lp = router_inputs(cfg)
-    chosen, weights = lsm.routing(h2, lp, cfg)
+    chosen, weights = blocks.routing(h2, lp, cfg.experts)
     want, want_w = ref.routing(h2, lp, TINY)
     np.testing.assert_array_equal(np.sort(np.asarray(chosen), -1),
                                   np.sort(np.asarray(want), -1))
@@ -424,7 +424,7 @@ def test_groups_limit_the_choice_as_the_reference_s_do():
     # every token's experts lie in two of the four groups of four
     assert all(len(set(row // 4)) <= 2 for row in np.asarray(chosen))
     free = dataclasses.replace(cfg, n_group=1, topk_group=1)
-    assert (np.sort(np.asarray(lsm.routing(h2, lp, free)[0]), -1)
+    assert (np.sort(np.asarray(blocks.routing(h2, lp, free.experts)[0]), -1)
             != np.sort(np.asarray(chosen), -1)).any()
 
 
@@ -444,7 +444,8 @@ def test_four_shares_add_up_to_the_uncut_layer():
         cfg = config(experts_held_from=4 * share)
         mine = {**lp, **{k: lp[k][4 * share:4 * share + 4]
                          for k in ("we_gate", "we_up", "we_down")}}
-        routed, shared, counts = lsm.expert_layer(h2, mine, cfg)
+        routed, shared, counts = blocks.expert_layer(
+            h2, mine, cfg.experts, cfg.activation_dtype())
         total = total + routed
         # what one share gives is the reference's share of it
         np.testing.assert_allclose(

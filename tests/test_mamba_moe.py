@@ -16,7 +16,7 @@ import numpy as np
 import pytest
 
 from benchmarks.refs import mamba_moe as ref
-from ray_tpu.models import latent_sparse_moe as lsm, mamba_moe
+from ray_tpu.models import blocks, mamba_moe
 from ray_tpu.ops import grouped_experts, mamba2
 from ray_tpu.serve.engine import InferenceEngine
 from ray_tpu.util import faults
@@ -445,5 +445,5 @@ def test_four_shares_add_up_to_the_uncut_layer(params):
                                rtol=0, atol=TOL)
     # the mixers and attention are every chip's alike: nothing of them is
     # a share (the reference's layer is the routed sum and the shared one)
-    chosen, _ = lsm.routing(n, lp, config())
+    chosen, _ = blocks.routing(n, lp, config().experts)
     assert int(jnp.max(chosen)) > 3

@@ -1,5 +1,5 @@
 """One transformer layer: every model forward in `ray_tpu/models/` goes
-through `gpt._layer`, and the layer equations themselves are pinned
+through `gpt.layer`, and the layer equations themselves are pinned
 against plain loops over the layers written here."""
 
 import ast
@@ -86,25 +86,25 @@ def _readers():
 
 @pytest.mark.parametrize("entry", sorted(ENTRY_POINTS))
 def test_every_forward_goes_through_the_one_layer(monkeypatch, entry):
-    """Each entry point scans its layers through `gpt._layer`: the scan
+    """Each entry point scans its layers through `gpt.layer`: the scan
     traces its body once, so one call whatever the depth. And besides
-    parameter initialisation and the logical-axes tables, `_layer` is the
+    parameter initialisation and the logical-axes tables, `layer` is the
     only function in `ray_tpu/models/` that names the attention weights
     or the norm scales."""
     calls = []
-    layer = gpt._layer
+    layer = gpt.layer
 
     def counted(*args, **kwargs):
         calls.append(1)
         return layer(*args, **kwargs)
 
     for mod in (gpt, moe, vit):
-        monkeypatch.setattr(mod, "_layer", counted)
+        monkeypatch.setattr(mod, "layer", counted)
     ENTRY_POINTS[entry]()
     assert len(calls) == 1
     computing = {(f, fn) for f, fn in _readers()
                  if fn not in ("init_params", "param_logical_axes")}
-    assert computing == {("gpt.py", "_layer")}
+    assert computing == {("gpt.py", "layer")}
 
 
 # ---------------------------------------------------------------------------

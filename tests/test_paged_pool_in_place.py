@@ -17,7 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from ray_tpu.models import gpt
+from ray_tpu.models import blocks, gpt
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import quant
 
@@ -45,13 +45,13 @@ def scanned_pool_layers(params, x, cache, cfg, widx, attend):
             stack_of_one = {n: a[None] for n, a in written.items()}
             return attend(q, stack_of_one, 0), written
 
-        x, lc, _ = gpt._layer(x, lp, cfg, gpt._matmul_out(cfg),
-                              write_then_attend)
+        x, lc, _ = gpt.layer(x, lp, cfg, gpt._matmul_out(cfg),
+                             write_then_attend)
         return x, lc
 
     x, cache = jax.lax.scan(body, x, (params["layers"], cache))
     scale = params["final_ln_scale"].astype(cfg.activation_dtype())
-    return gpt._rms_norm(x, scale), cache
+    return blocks.rms_norm(x, scale), cache
 
 
 @functools.lru_cache(maxsize=None)

@@ -17,8 +17,8 @@ import numpy as np
 import pytest
 
 from benchmarks.refs import window_moe as ref
-from ray_tpu.models import gpt, latent_sparse_moe as lsm, linear_latent, \
-    retention, window_moe
+from ray_tpu.models import blocks, gpt, latent_sparse_moe as lsm, \
+    linear_latent, retention, window_moe
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.serve.engine import BlockAllocator, InferenceEngine
 from ray_tpu.util import faults
@@ -470,7 +470,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
     for lp, kind in ((layers[0], "window"), (layers[3], "full")):
         want = ref.layer(x, lp, kind, whole)
         cfg = config(num_experts=2)
-        n = window_moe._layer_norm(x, lp["norm_scale"], cfg.eps)
+        n = blocks.layer_norm(x, lp["norm_scale"], cfg.eps)
         q, k, v = window_moe._qkv(n, lp, kind, pos, cfg)
         att = da.reference_gqa_attention(
             q[None], k[None], v[None], jnp.zeros((1,), jnp.int32),
@@ -480,7 +480,8 @@ def test_eight_shares_add_up_to_the_uncut_layer():
             cfg = config(num_experts=2, experts_held_from=2 * share)
             mine = {**lp, **{key: lp[key][2 * share:2 * share + 2]
                              for key in ("we_gate", "we_up", "we_down")}}
-            routed, shared, counts = lsm.expert_layer(n, mine, cfg)
+            routed, shared, counts = blocks.expert_layer(
+                n, mine, cfg.experts, cfg.activation_dtype())
             total = total + routed
             np.testing.assert_allclose(
                 np.asarray(routed), np.asarray(ref.routed_part(
