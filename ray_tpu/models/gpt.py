@@ -12,6 +12,14 @@ Design choices that matter on TPU:
   column/row split), and SP (ring attention over the ``seq`` axis) purely by
   changing the MeshSpec.
 - **`jax.checkpoint` on the block** to trade FLOPs for HBM.
+- **Two trees of one model.** The masters (`init_params`: `embed`,
+  `pos_embed`, `final_ln_scale` and the layer stacks `ln1_scale`, `wq`,
+  `wk`, `wv`, `wo`, `ln2_scale`, `w_up`, `w_gate`, `w_down`, float32) are
+  what training reads and trainers publish. The served tree
+  (`serving_params`, made once at load) is what the paged forwards
+  read: every leaf in the dtype a step reads it in, `wq`, `wk` and `wv`
+  side by side in one stack `wqkv` [L, D, 3, H * Dh] (with
+  `weight_dtype="int8"`, int8 stacks and their `_scale` siblings).
 
 The reference has no model zoo of its own (models live in user code /
 RLlib's catalog, `rllib/models/catalog.py`); this model is the framework's
@@ -100,7 +108,8 @@ class GPTConfig:
     kv_dtype: str = "f32"            # f32 | int8
     # Weight precision for the paged inference forwards (prefill/decode/
     # verify — training always runs full precision). Both expect params
-    # through `serving_params`, the engine's load-time function: "f32"
+    # through `serving_params`, the engine's load-time function (which
+    # also lays `wq`, `wk`, `wv` side by side in one leaf, `wqkv`): "f32"
     # is the published values, each rounded to `dtype` once where a step
     # would round it at use; "int8" quantizes the matmul stacks besides
     # (per-output-channel scales; dequant folds into each matmul's rhs
@@ -233,6 +242,22 @@ def _matmul_out(cfg: GPTConfig):
             else cfg.activation_dtype())
 
 
+# The projections a served tree holds as one leaf, `wqkv`
+# (`serving_params`), in the order `layer` takes them apart again.
+QKV = ("wq", "wk", "wv")
+
+
+def _fused_weight(lp, adt):
+    """A served layer's `wqkv` [D, 3, H * Dh] in `adt`: `blocks.weight`
+    for a leaf with two output axes, an int8 one's `wqkv_scale`
+    [3, H * Dh] a scale an output channel."""
+    w = lp["wqkv"]
+    s = lp.get("wqkv_scale")
+    if s is None:
+        return w.astype(adt)
+    return (w.astype(jnp.float32) * s[None]).astype(adt)
+
+
 def layer(x, lp, cfg, pet, attend, ffn=None):
     """One transformer layer, the only spelling of it: norm, q/k/v,
     attention, output projection, residual, norm, feed-forward, residual.
@@ -245,6 +270,12 @@ def layer(x, lp, cfg, pet, attend, ffn=None):
     with `n_heads`, `head_dim` and `activation_dtype()`. pet: the
     einsums' output element type, the caller's choice (`_matmul_out`).
 
+    q, k and v are one projection where `lp` holds `wqkv` (a served
+    tree) and three where it holds `wq`, `wk` and `wv` (the masters:
+    training, `moe.py`, `vit.py`): a static dict-key check, as
+    `blocks.weight`'s for a scale, so a dict without the leaf traces
+    what it traced. The same numbers are multiplied either way.
+
     The two parts that vary come in as arguments and return
     ``(output, kept)``, where `kept` is whatever the part makes besides
     its output (the pool with the layer's rows written, the experts' aux
@@ -256,10 +287,15 @@ def layer(x, lp, cfg, pet, attend, ffn=None):
     lead = x.shape[:-1]
     with jax.named_scope(MIXER):
         h = rms_norm(x, lp["ln1_scale"])
-        q, k, v = (jnp.einsum("...d,dh->...h", h,
-                              weight(lp, name, adt),
-                              preferred_element_type=pet).astype(adt)
-                   for name in ("wq", "wk", "wv"))
+        if "wqkv" in lp:
+            q, k, v = jnp.unstack(jnp.einsum(
+                "...d,dch->...ch", h, _fused_weight(lp, adt),
+                preferred_element_type=pet).astype(adt), axis=-2)
+        else:
+            q, k, v = (jnp.einsum("...d,dh->...h", h,
+                                  weight(lp, name, adt),
+                                  preferred_element_type=pet).astype(adt)
+                       for name in QKV)
         q, k, v = (a.reshape(*lead, cfg.n_heads, cfg.head_dim)
                    for a in (q, k, v))
         att, attend_kept = attend(q, k, v)
@@ -436,7 +472,7 @@ def check_quant_cfg(cfg: GPTConfig) -> bool:
 # The per-layer matmul weights the int8 weight-only path quantizes.
 # Norm scales, embed and pos_embed are not quantized — they are O(d)
 # reads, not the bandwidth, and the unembed shares `embed`.
-QUANTIZED_WEIGHTS = ("wq", "wk", "wv", "wo", "w_up", "w_gate", "w_down")
+QUANTIZED_WEIGHTS = (*QKV, "wo", "w_up", "w_gate", "w_down")
 
 
 def quantize_params(params):
@@ -461,26 +497,45 @@ def serving_params(params, cfg: GPTConfig):
     published masters in, the tree that `prefill_paged`,
     `decode_step_paged` and `verify_step_paged` read out.
 
+    `wq`, `wk` and `wv` [L, D, H * Dh] come out side by side as one
+    leaf, `wqkv` [L, D, 3, H * Dh], which `layer` reads with one dot.
+    Held apart, each projection's reshape to heads made XLA give its
+    dot a head-major output and want the weight transposed, so every
+    layer of every step and chunk staged each [D, H * Dh] slice in VMEM,
+    transposed it there and copied the product back to row-major (at
+    `olmo-1b`, three slices and three copies of 8.4 MB a layer, a sixth
+    of the time the device worked in `olmo-1b.chat-steady`: PERF.md,
+    PR 60); the one dot
+    reads its slice of the stack where it lies, as `wo`'s and the
+    feed-forward's do. The third axis keeps the heads on the last one:
+    under a mesh that splits the heads, a shard holds its own heads'
+    q, k and v columns, and the chip stores the axis of 3 outside the
+    tiles, each projection contiguous as its master was. A tree that
+    already holds `wqkv` is a served tree: it is not fused or quantized
+    again, and in its dtype comes back leaf for leaf.
+
     `weight_dtype="int8"` quantizes the `QUANTIZED_WEIGHTS`
-    (`quantize_params`); their scales stay f32, since `blocks.weight`
-    multiplies in f32 before its cast. For every `weight_dtype`, every
-    other floating leaf (`embed`, `pos_embed`, the norm scales and, with
-    `weight_dtype="f32"`, the matmul stacks) takes
+    (`quantize_params`, the three projections each as before: a scale
+    an output channel, `wqkv_scale` [L, 3, H * Dh]); the scales stay f32,
+    since `blocks.weight` multiplies in f32 before its cast. For every
+    `weight_dtype`, every other floating leaf (`embed`, `pos_embed`, the
+    norm scales and, with `weight_dtype="f32"`, the matmul stacks) takes
     `cfg.activation_dtype()`, the dtype each step casts it to at use:
     the same rounding of the same numbers, done once, so the matmuls
     see bit-identical operands and a compiled step's `astype` of a leaf
     to its own dtype is no op. (Cast at use, XLA hoists the casts out
     of the layer loop and every run of a step converts the whole tree.)
-    A leaf already in that dtype is returned as it is, so a
-    `dtype="float32"` tree and a tree published in bf16 come back leaf
-    for leaf. Pure and jittable; the engine runs it at construction and
+    A leaf already in that dtype is returned as it is, so a fused
+    `dtype="float32"` tree and a fused tree in bf16 come back leaf for
+    leaf. Pure and jittable; the engine runs it at construction and
     on every `update_params`, so trainers go on publishing f32 masters.
     Training calls the model functions with the masters themselves and
     traces what it traced."""
     adt = cfg.activation_dtype()
-    if cfg.weight_dtype == "int8":
+    fused = "wqkv" in params["layers"]
+    if cfg.weight_dtype == "int8" and not fused:
         params = quantize_params(params)
-    scales = {name + "_scale" for name in QUANTIZED_WEIGHTS}
+    scales = {name + "_scale" for name in (*QUANTIZED_WEIGHTS, "wqkv")}
 
     def cast(x):
         return (x.astype(adt) if jnp.issubdtype(x.dtype, jnp.floating)
@@ -488,6 +543,11 @@ def serving_params(params, cfg: GPTConfig):
 
     layers = {name: leaf if name in scales else cast(leaf)
               for name, leaf in params["layers"].items()}
+    if not fused:       # side by side: the payloads, and int8's scales
+        for suffix in ("", "_scale"):
+            if QKV[0] + suffix in layers:
+                layers["wqkv" + suffix] = jnp.stack(
+                    [layers.pop(name + suffix) for name in QKV], axis=-2)
     return {**{name: cast(leaf) for name, leaf in params.items()
                if name != "layers"}, "layers": layers}
 
@@ -577,7 +637,9 @@ def _paged_layers(params, x, cache, cfg: GPTConfig, widx, attend):
     `li` of the written pool: the three paged kernels (decode, verify,
     the prefill chunk) take the stacked pool and the layer's number and
     fetch the live pages themselves, so no step slices or copies a
-    layer. (Scanning over the pool instead makes XLA
+    layer: of the pool, and over a served tree (`serving_params`) of
+    the weights either, each dot reading its slice of its stack where
+    it lies. (Scanning over the pool instead makes XLA
     slice every layer out, stack the written layers into a new buffer
     and copy that onto the donated one: three passes over K and over V a
     step.)

@@ -852,6 +852,10 @@ class InferenceEngine:
         self._load_target = _loader(cfg, params)
         self._load_draft = (_loader(draft_cfg, draft_params)
                             if spec == "draft" else None)
+        # a family's `load` reads leaves by name, so a swap holds the
+        # published tree to the structure of what was given here
+        self._given = (jax.tree.structure(params),
+                       jax.tree.structure(draft_params))
         if self._load_target is not None:
             self.params = self._load_target(self.params)
         if self._load_draft is not None:
@@ -2083,6 +2087,22 @@ class InferenceEngine:
             else jax.numpy.asarray(n)
             for o, n in zip(old_leaves, new_leaves)])
 
+    def _load_published(self, load, given, tree, what: str):
+        """A published `tree` through `load`, the family's jitted
+        load-time function (as it is where there is none, or no tree).
+        The function reads leaves by name, so a tree whose structure is
+        not `given`, that of the tree the engine was built from, is
+        refused here as `_place_tree` refuses one after it."""
+        if load is None or tree is None:
+            return tree
+        got = self._jax.tree.structure(tree)
+        if got != given:
+            raise ValueError(
+                f"update_params: {what} pytree structure changed "
+                f"({got} != {given}): publish what the engine was "
+                "built from")
+        return load(tree)
+
     def update_params(self, new_params, *, draft_params=None) -> int:
         """Hot-swap model weights into the live engine between ticks.
 
@@ -2144,10 +2164,11 @@ class InferenceEngine:
             # donated swap copies what the steps read. Shapes repeat, so
             # this hits the cached _load trace (load_traces is
             # sentinel-pinned).
-            if self._load_target is not None:
-                new_params = self._load_target(new_params)
-            if self._load_draft is not None and draft_params is not None:
-                draft_params = self._load_draft(draft_params)
+            new_params = self._load_published(
+                self._load_target, self._given[0], new_params, "params")
+            draft_params = self._load_published(
+                self._load_draft, self._given[1], draft_params,
+                "draft_params")
             placed = self._place_tree(old, new_params, "params")
             placed_draft = (
                 self._place_tree(old_draft, draft_params, "draft_params")

@@ -91,6 +91,23 @@ def arrays_made(text: str, dtype, size: int) -> list[str]:
             and math.prod(map(int, m.group(1).split(","))) >= size]
 
 
+def loop_body(text: str) -> str:
+    """The instructions of a program's `while` bodies themselves: the
+    lines of every computation a `while` names as its body, and not those
+    of the computations these call, where a slice or a dequantize inside
+    a dot's fusion is an array on paper only (`arrays_made` reads every
+    line of a text; give it this one)."""
+    bodies = set(re.findall(r" while\(.*?body=%?([\w.\-]+)", text))
+    lines, inside = [], False
+    for line in text.splitlines():
+        if not line.startswith((" ", "}")):     # a computation's heading
+            m = re.match(r"(?:ENTRY )?%?([\w.\-]+) \(", line)
+            inside = bool(m) and m.group(1) in bodies
+        elif inside and line.startswith(" "):
+            lines.append(line)
+    return "\n".join(lines)
+
+
 def state_arrays_made(text: str, pool: dict) -> list[str]:
     """The instructions of a program that make an array of the shape of
     the pool's "state" or "ring": a copy of either around a kernel
@@ -424,16 +441,24 @@ DENSE_KERNEL = {"decode": "paged_decode", "prefill": "paged_mq",
                 "verify": "paged_mq"}
 
 
-@functools.lru_cache(maxsize=None)   # two tests read the same programs
-def _dense_program(topo, program, cfg):
+@functools.lru_cache(maxsize=None)   # three tests read the same programs
+def _dense_program(topo, program, cfg, apart=False):
     """`program` of `models/gpt.py` at the cells' shapes as the engine
     jits it (the cache donated, the weights as its load-time function
-    leaves them), compiled for one described chip;
+    leaves them, or with `apart` the masters cast to the activation
+    dtype, `wq`, `wk` and `wv` a leaf each as every tree was before
+    PR 60), compiled for one described chip;
     returns (compiled, params, pool) with the arguments as described."""
     described, arg = describers(topo)
-    params = described(jax.eval_shape(
-        lambda k: gpt.serving_params(gpt.init_params(k, cfg), cfg),
-        jax.random.key(0)))
+
+    def load(k):
+        masters = gpt.init_params(k, cfg)
+        if apart:
+            return jax.tree.map(
+                lambda a: a.astype(cfg.activation_dtype()), masters)
+        return gpt.serving_params(masters, cfg)
+
+    params = described(jax.eval_shape(load, jax.random.key(0)))
     pool = described(jax.eval_shape(
         lambda: gpt.init_kv_pool(cfg, CELL_NB, BS)))
     if program == "prefill":
@@ -508,14 +533,122 @@ def test_dense_family_programs_convert_no_weight(topo, program,
     # of one layer of `wq`, which the int8 path does make, a layer a turn)
     weights = {"bf16[%s]" % ",".join(map(str, leaf.shape))
                for leaf in (masters["embed"], *(
-                   masters["layers"][name] for name in gpt.QUANTIZED_WEIGHTS))}
+                   masters["layers"][name] for name in gpt.QUANTIZED_WEIGHTS),
+                   params["layers"]["wqkv"])}
     assert weights == {"bf16[16,2048,8192]", "bf16[16,8192,2048]",
-                       "bf16[16,2048,2048]", "bf16[50304,2048]"}
+                       "bf16[16,2048,2048]", "bf16[50304,2048]",
+                       "bf16[16,2048,3,2048]"}
     made = [line.strip() for line in compiled.as_text().splitlines()
             for m in [re.search(r" = (bf16\[[\d,]+\])\S* convert\(", line)]
             if m and m.group(1) in weights]
     assert not made, made
     assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+
+
+LAYER_WEIGHT = 2048 * 2048        # elements of one of a layer's projections
+
+
+def _weights_made_a_layer(text):
+    """The instructions of the layer loop's own body that make a bf16 or
+    int8 array of a projection's size or more, the pool's in-place
+    scatters apart; and the copies of a projection's output
+    ([rows, 16, 128]) that stand before those scatters."""
+    body = loop_body(text)
+    made = [line for dtype in (BF16, jnp.int8)
+            for line in arrays_made(body, dtype, LAYER_WEIGHT)
+            if not ("scatter" in line and f"[16,{CELL_NB},{BS},{H},{CELL_D}]"
+                    in line.split(" fusion(")[0])]
+    copies = [line.strip() for line in body.splitlines() if re.search(
+        rf" = bf16\[\d+,{H},{CELL_D}\]\S* copy\(", line)]
+    return body, made, copies
+
+
+@pytest.mark.parametrize("weight_dtype", ["f32", "int8"])
+@pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
+def test_dense_family_programs_read_a_layers_weights_where_they_lie(
+        topo, program, weight_dtype):
+    """The same three programs over the tree `gpt.serving_params` makes:
+    a layer's q, k and v projections are one dot over the `wqkv` stack
+    and the layer's number, as `wo`'s and the feed-forward's are, so no
+    instruction of the loop's body but the pool's two scatters makes an
+    array of a projection's size, bf16 or int8, and no copy of a
+    projection's output stands before the scatters. Held apart
+    (`wq`, `wk`, `wv`; every tree before PR 60), the reshape to heads
+    behind each dot made XLA want that dot's weight transposed, and the
+    body of the decode step read, three times a layer (ledger, PR 59,
+    `olmo-1b.chat-steady`: 0.65 of the 3.84 s the device worked):
+
+      %constant_dynamic-slice_fusion.8 = bf16[1,2048,2048]{2,1,0:..S(1)}
+          fusion(<the [16,2048,2048] stack>, <layer>)   # HBM -> VMEM
+      %copy.42 = bf16[1,2048,2048]{1,2,0:..S(1)}
+          copy(%constant_dynamic-slice_fusion.8)        # transposed there
+      %fusion.127 = bf16[32,16,128]{2,0,1} fusion(%bitcast.113, x, ..)
+      %copy.43 = bf16[32,16,128]{2,1,0} copy(%fusion.127)
+
+    and with int8 weights a dequantized `bf16[2048,2048]` besides
+    (`test_the_loop_reader_sees_a_tree_held_apart` reads them off such a
+    tree). Verify, which no cell runs, keeps three copies of its
+    `[32,5,1,2048]` outputs (a window of 5 on the sublanes): activations,
+    0.65 MB each."""
+    cfg = _olmo(weight_dtype=weight_dtype)
+    compiled, params, _ = _dense_program(topo, program, cfg)
+    assert params["layers"]["wqkv"].shape == (16, 2048, 3, 2048)
+    body, made, copies = _weights_made_a_layer(compiled.as_text())
+    assert kernel_names(body) == [DENSE_KERNEL[program]]    # the layer loop
+    assert len([line for line in body.splitlines()
+                if "scatter" in line and " fusion(" in line]) >= 2
+    assert not made, made
+    assert not copies, copies
+    assert compiled.memory_analysis().temp_size_in_bytes < 2e6
+
+
+def test_the_loop_reader_sees_a_tree_held_apart(topo):
+    """The control of the test above: the decode step over masters cast
+    and held apart, the tree every engine read before PR 60, which
+    `gpt.layer` still takes (the key check). The reader finds the
+    listing's six weight-sized instructions, three slices into VMEM and
+    three transposed copies, and the three copies of `[32,16,128]`."""
+    compiled, params, _ = _dense_program(topo, "decode", _olmo(), True)
+    assert "wqkv" not in params["layers"]
+    _, made, copies = _weights_made_a_layer(compiled.as_text())
+    slices = [line for line in made if "dynamic-slice" in line.split("=")[0]]
+    moved = [line for line in made if " copy(" in line]
+    assert len(slices) == len(moved) == 3 and len(made) == 6, made
+    assert all("bf16[1,2048,2048]" in line and "S(1)" in line
+               for line in made), made
+    assert len(copies) == 3, copies
+
+
+# sha256 (first 16 hex digits) of the jaxpr of `gpt.loss_fn` with its
+# gradient over f32 masters at the two dense training cells' model
+# configurations and batch (8 x 2,049 tokens), taken on PR 59's tree,
+# the commit before `gpt.layer` learned to read a served tree's `wqkv`:
+# a dict without that leaf traces what it traced (`OLMO_PAGED_JAX`'s
+# rule for another JAX, and for a PR that means to change the step)
+DENSE_TRAIN = {"datadecide-300m": "cafa6bbd2fe12c06",
+               "olmo-1b": "f35be8d376119077"}
+
+
+@pytest.mark.parametrize("name", sorted(DENSE_TRAIN))
+def test_dense_training_traces_to_what_it_did(name):
+    import hashlib
+    import json
+    from benchmarks.harness import common
+    if jax.__version__ != OLMO_PAGED_JAX:
+        pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           name + ".json")) as f:
+        config = json.load(f)
+    cfg = common.model_config(config, "train", **config["program"]["train"])
+    masters = jax.eval_shape(lambda k: gpt.init_params(k, cfg),
+                             jax.random.key(0))
+    assert "wqkv" not in masters["layers"]
+    batch = {"tokens": jax.ShapeDtypeStruct((8, 2049), I32)}
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
+        jax.value_and_grad(lambda p, b: gpt.loss_fn(p, b, cfg)))(
+            masters, batch)))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+        DENSE_TRAIN[name]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
