@@ -29,9 +29,13 @@ pages that grow beside a ring of pages that do not, four `gqa_*` kernels),
 then the state-space-and-latent-experts one (`models/mamba_moe.py`,
 `--phase serve-mamba`: a state block of Mamba-2 states and convolution
 tails beside one attention layer's pages, experts without a gate matrix
-in a latent), and last the parallel-hybrid one (`models/parallel_hybrid.py`,
+in a latent), then the parallel-hybrid one (`models/parallel_hybrid.py`,
 `--phase serve-parallel-hybrid`: a state block and pages in every layer,
-a state head of 128 x 256 and a group of 5 query heads a key-value head).
+a state head of 128 x 256 and a group of 5 query heads a key-value head),
+and last the short-convolution one (`models/shortconv_moe.py`, `--phase
+serve-shortconv`: convolution tails beside pages whose rows hold two
+key-value heads of 64 side by side, every expert of the router held and
+none shared).
 After the dense train phase it trains the window-and-full family
 (`models/window_moe_train.py`, `--phase train-window`: the banded and the
 full flash kernels at grouped heads, the expert kernels' backward pass at
@@ -514,6 +518,25 @@ PARALLEL_HYBRID_LOGPROB_MAX_TOL = 5e-1
 PARALLEL_HYBRID_LOGPROB_MEAN_TOL = 5e-2
 
 
+# `models/shortconv_moe.py` at the published head (64 dims, two key-value
+# heads a row of 128 lanes in pages of 128, a group of 4 query heads, so
+# `gqa_full_*` run on pairs) and otherwise tiny: the published pattern's
+# first 8 layers (both dense layers, then sparse layers under attention and
+# under convolutions), every one of 8 experts held, none shared
+SHORTCONV_CFG = dict(
+    vocab_size=512, d_model=256, n_layers=8,
+    layer_types=("conv", "conv", "attention", "conv", "conv", "conv",
+                 "attention", "conv"),
+    n_heads=8, n_kv_heads=2, head_dim=64, d_ff=512, expert_ff=256,
+    router_width=8, held_count=8, experts_per_token=2, max_seq_len=1024)
+# bfloat16 activations against the float32 definition, eight layers
+# (chip runs, PR 61: 0.185 largest, 0.0158 mean over six streams; with
+# every residual gain at 1 and the stream built from a table of 0.05,
+# 0.780 and 0.0637: `shortconv_moe.init_params` says why it is not)
+SHORTCONV_LOGPROB_MAX_TOL = 5e-1
+SHORTCONV_LOGPROB_MEAN_TOL = 5e-2
+
+
 def retention_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
@@ -644,12 +667,37 @@ def parallel_hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
                        PARALLEL_HYBRID_LOGPROB_MEAN_TOL)}
 
 
+def shortconv_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import shortconv_moe
+    cfg = shortconv_moe.ShortConvMoEConfig(**cfg_kwargs)
+    n_attn = cfg.n_layers - cfg.n_conv
+    n_sparse = sum(ffn == "sparse" for _, ffn in cfg.kinds)
+    return {
+        "phase": "serve_shortconv", "family": shortconv_moe, "cfg": cfg,
+        "params": shortconv_moe.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32", attn_impl="jax",
+                                     sparse_impl="jax"),
+        "engine": {"block_size": 128}, "table": 1 + 1024 // 128,
+        "decode_kernels": {"gqa_full_decode": n_attn,
+                           "experts_grouped": n_sparse},
+        "prefill_kernels": {"gqa_full_chunk": n_attn,
+                            "experts_grouped_prefill": n_sparse},
+        "counters": ("state_resets", "conv_rows_live", "conv_rows_padded",
+                     "attention_rows_read", "expert_tokens_here",
+                     "expert_tokens_routed", "expert_load_max_over_mean",
+                     "state_blocks"),
+        "tolerances": (SHORTCONV_LOGPROB_MAX_TOL,
+                       SHORTCONV_LOGPROB_MEAN_TOL)}
+
+
 def serve_family_phase(case: dict, *, platform: str, streams: int,
                        prompt_lens: tuple[int, int], new_tokens: int,
                        slots: int, seed: int) -> None:
     """The engine over a family that keeps more than pages that grow
-    (`retention_case`, `hybrid_case`, `mamba_case`, `parallel_hybrid_case`:
-    a state a sequence;
+    (`retention_case`, `hybrid_case`, `mamba_case`, `parallel_hybrid_case`,
+    `shortconv_case`: a state a sequence;
     `window_case`: a ring of window pages), in this process: `streams` greedy
     requests over `slots` slots (so blocks are reused), chunked
     prefill in both buckets and then steps. Holds the streamed logprobs to
@@ -1042,7 +1090,8 @@ def main() -> int:
     ap.add_argument("--phase", choices=("train", "train4", "train-window",
                                         "serve-retention", "serve-hybrid",
                                         "serve-window", "serve-mamba",
-                                        "serve-parallel-hybrid"),
+                                        "serve-parallel-hybrid",
+                                        "serve-shortconv"),
                     help="how a phase child is started")
     args = ap.parse_args()
 
@@ -1063,7 +1112,8 @@ def main() -> int:
              "serve-window": (window_case, WINDOW_CFG),
              "serve-mamba": (mamba_case, MAMBA_CFG),
              "serve-parallel-hybrid": (parallel_hybrid_case,
-                                       PARALLEL_HYBRID_CFG)}
+                                       PARALLEL_HYBRID_CFG),
+             "serve-shortconv": (shortconv_case, SHORTCONV_CFG)}
     if args.phase in cases:
         make, cfg_kwargs = cases[args.phase]
         case = make(cfg_kwargs, args.seed)
@@ -1104,6 +1154,7 @@ def main() -> int:
             run_phase_child("serve-window", args.seed)
             run_phase_child("serve-mamba", args.seed)
             run_phase_child("serve-parallel-hybrid", args.seed)
+            run_phase_child("serve-shortconv", args.seed)
             run_phase_child("train", args.seed)
             run_phase_child("train-window", args.seed)
         else:

@@ -225,6 +225,7 @@ class Experts(NamedTuple):
     routed_scale: float = 1.0
     expert_round: str = "none"       # none | float8_e4m3fn (`rounded`)
     impl: str = "auto"               # auto | pallas | jax
+    norm_eps: float = 0.0            # added to the chosen scores' sum
 
 
 def kept_groups(biased, n_group: int, topk_group: int):
@@ -261,7 +262,10 @@ def routing(h2, lp, experts: Experts):
     _, chosen = jax.lax.top_k(biased, experts.experts_per_token)
     weights = jnp.take_along_axis(g, chosen, -1)
     if experts.norm_topk:
-        weights = weights / jnp.sum(weights, -1, keepdims=True)
+        total = jnp.sum(weights, -1, keepdims=True)
+        if experts.norm_eps:        # 0.0: the program it was, not one op more
+            total = total + experts.norm_eps
+        weights = weights / total
     return chosen.astype(jnp.int32), weights * experts.routed_scale
 
 
@@ -288,7 +292,8 @@ def expert_layer(h2, lp, experts: Experts, adt, live=None,
                  kernel: str = grouped_experts.EXPERTS_GROUPED,
                  every_load: bool = False):
     """A sparse layer's two parts on normed h2 [N, D]: -> (routed: what
-    the held experts add, shared: the shared expert's, counts i32: pairs
+    the held experts add, shared: the shared expert's, or None where the
+    layer's parameters hold no `ws_gate`, counts i32: pairs
     routed here, pairs routed anywhere, then the pairs each held expert
     got, or with `every_load` each expert of the router's whole width,
     held or not; rows where `live` is false count nothing)."""
@@ -300,9 +305,11 @@ def expert_layer(h2, lp, experts: Experts, adt, live=None,
         rounded(h2, grid), chosen, weights, rounded(lp["we_gate"], grid),
         rounded(lp["we_up"], grid), rounded(lp["we_down"], grid),
         held_from=experts.held_from, impl=experts.impl, name=kernel)
-    shared, _ = gated_mlp(
-        h2, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
-             "w_down": lp["ws_down"]}, adt, jnp.float32)
+    shared = None
+    if "ws_gate" in lp:
+        shared, _ = gated_mlp(
+            h2, {"w_gate": lp["ws_gate"], "w_up": lp["ws_up"],
+                 "w_down": lp["ws_down"]}, adt, jnp.float32)
     here = jnp.sum(load)
     if every_load:
         load = jnp.sum(chosen[..., None] == jnp.arange(experts.router_width),

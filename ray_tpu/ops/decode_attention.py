@@ -921,13 +921,45 @@ def gather_head_major(pool, tables):
         b, mb * bs, hkv, d)
 
 
+def gqa_pack(hkv: int, d: int) -> int:
+    """Key-value heads that lie side by side in a row of a head-major
+    page: 1 where a head fills whole lane tiles, `128 // d` where it is a
+    part of one that divides it and the heads make whole rows (8 heads of
+    64: 2, a page `[bs, 128]` of a pair). A family lays its pool out as
+    `[L, n_blocks, Hkv / pack, bs, pack * d]` and writes its rows
+    `[N, Hkv, d]` as `[N, Hkv / pack, pack * d]`, which is the same bytes
+    in the same order (`heads_side_by_side`)."""
+    pack = 128 // d if d < 128 and 128 % d == 0 else 1
+    return pack if hkv % pack == 0 else 1
+
+
+def heads_side_by_side(rows, pack: int):
+    """Key or value rows `[N, Hkv, d]` as a pool of `gqa_pack` = `pack`
+    stores them, `[N, Hkv / pack, pack * d]`: a free reshape."""
+    n, hkv, d = rows.shape
+    return rows.reshape(n, hkv // pack, pack * d)
+
+
+def _heads_apart(pool, pack: int):
+    """One layer's pool `[n_blocks, Hkv / pack, bs, pack * d]` as
+    `[n_blocks, Hkv, bs, d]`: a copy, for the gather path alone."""
+    if pack == 1:
+        return pool
+    nb, rows, bs, lanes = pool.shape
+    return pool.reshape(nb, rows, bs, pack, lanes // pack).transpose(
+        0, 1, 3, 2, 4).reshape(nb, rows * pack, bs, lanes // pack)
+
+
 def reference_gqa_paged_attention(q, k_pool, v_pool, tables, pos,
                                   window=None):
     """`reference_gqa_attention` over one layer's head-major pools
-    `[n_blocks, Hkv, bs, D]` gathered through `tables`."""
-    return reference_gqa_attention(q, gather_head_major(k_pool, tables),
-                                   gather_head_major(v_pool, tables), pos,
-                                   window)
+    `[n_blocks, Hkv, bs, D]` gathered through `tables`; pools whose rows
+    hold several heads side by side (`gqa_pack`: the last axis a multiple
+    of q's) are taken apart first."""
+    pack = k_pool.shape[-1] // q.shape[-1]
+    return reference_gqa_attention(
+        q, gather_head_major(_heads_apart(k_pool, pack), tables),
+        gather_head_major(_heads_apart(v_pool, pack), tables), pos, window)
 
 
 _DECODE_TURN, _CHUNK_TURN = 1024, 512   # cached positions a turn, at most
@@ -935,7 +967,9 @@ _DECODE_TURN, _CHUNK_TURN = 1024, 512   # cached positions a turn, at most
 
 def _gqa_plan(bs: int, g: int, d: int, dtype, w: int,
               vmem: int | None = None) -> _DecodePlan | None:
-    """`_decode_plan` for head-major pools `[.., Hkv, bs, d]` and `w`
+    """`_decode_plan` for head-major pools `[.., Hkv, bs, d]` (`d` a
+    page's row as stored: `gqa_pack` heads of 64 side by side are one
+    row of 128) and `w`
     queries of each of `g` heads a program: pages a turn from `_DECODE_TURN`
     (one query a head: the turn is all DMA) or `_CHUNK_TURN` positions,
     halved until two turns of K and V, the score tiles and the running
@@ -981,15 +1015,29 @@ def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
     is a stream, a key-value head and a tile of queries; its pages are
     fetched once for the head's whole group and, with a window, from the
     first page the window reaches. The jax path gathers the layer
-    through the whole table (`reference_gqa_paged_attention`)."""
+    through the whole table (`reference_gqa_paged_attention`).
+
+    A head smaller than a lane tile (`gqa_pack`: 64, two a tile) is read
+    where it lies too, from pools ``[L, n_blocks, Hkv / pack, bs, pack *
+    D]``: a page is `pack` key-value heads side by side, one DMA of whole
+    tiles, and a program is a stream, such a row of heads and the ``pack
+    * Hq / Hkv`` query heads that read them. A query row goes to the
+    kernel `pack * D` lanes wide with zeros outside its own head's lanes,
+    so its scores are its own head's; ``p . V`` then fills every lane and
+    the row's own are kept. The MXU multiplies `pack` times what is
+    needed, on tiles it would fill no better, and the bytes read are the
+    pool's own."""
     b, w, hq, d = q.shape
-    if k_pool.ndim != 5 or tables.ndim != 2 or hq % k_pool.shape[2]:
+    pack = k_pool.shape[-1] // d if k_pool.ndim == 5 else 0
+    if (not pack or tables.ndim != 2 or k_pool.shape[4] != pack * d
+            or hq % (k_pool.shape[2] * pack)):
         raise ValueError(
             f"gqa_attention wants q [B, W, Hq, D], pools [L, n_blocks, "
-            f"Hkv, bs, D] and tables [B, max_blocks]; got {q.shape}, "
-            f"{k_pool.shape}, {tables.shape}")
+            f"Hkv / pack, bs, pack * D] and tables [B, max_blocks]; got "
+            f"{q.shape}, {k_pool.shape}, {tables.shape}")
+    # `hkv`: rows of heads a page holds; `g`: the query heads that read one
     hkv, bs = k_pool.shape[2:4]
-    g = hq // hkv
+    g, lanes = hq // hkv, pack * d
     wt = _gqa_query_tile(w, g)
     # rows a key-value head a program are whole sublane tiles: where the
     # queries of one program are not (the decode step's one query of a
@@ -998,11 +1046,11 @@ def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
     gp = g if (wt * g) % 8 == 0 else _up(g, 8)
     if gp != g:
         wt = _gqa_query_tile(w, gp)
-    plan = _gqa_plan(bs, gp, d, k_pool.dtype, wt)
+    plan = _gqa_plan(bs, gp, lanes, k_pool.dtype, wt)
     if impl == "auto":
         if plan is None:
             backend.note_fallback(name, f"block_size {bs}, {w} queries of "
-                                  f"{hq} heads over {hkv} of {d}")
+                                  f"{hq} heads over {hkv} of {lanes}")
         impl = "pallas" if backend.on_tpu() and plan is not None else "jax"
     if impl == "jax":
         return reference_gqa_paged_attention(
@@ -1019,17 +1067,26 @@ def gqa_attention(name: str, q, k_pool, v_pool, tables, pos, *, layer,
         q = jnp.pad(q, ((0, 0), (0, -w % wt), (0, 0), (0, 0)))
     # a key-value head's g query heads side by side: [B, Hkv, W * g, D]
     rows = q.reshape(b, -1, hkv, g, d)
+    if pack > 1:
+        # query head j of a row of heads reads the head at lanes j // (g /
+        # pack) * d ..: its own lanes hold it, the others zeros
+        own = (jnp.arange(g)[:, None] // (g // pack)
+               == jnp.arange(lanes)[None, :] // d)              # [g, lanes]
+        rows = jnp.where(own, jnp.tile(rows, (1, 1, 1, 1, pack)), 0)
     if gp != g:
         rows = jnp.pad(rows, ((0, 0),) * 3 + ((0, gp - g), (0, 0)))
-    rows = rows.transpose(0, 2, 1, 3, 4).reshape(b, hkv, -1, d)
+    rows = rows.transpose(0, 2, 1, 3, 4).reshape(b, hkv, -1, lanes)
     out = _paged_call(name, rows, k_pool, v_pool, tables.astype(jnp.int32),
                       pos.astype(jnp.int32),
                       jnp.asarray(layer, jnp.int32).reshape(1), plan=plan,
                       block_size=bs, heads=gp, queries=w, sm_scale=d ** -0.5,
                       interpret=backend.interpret(), window=window,
                       head_major=True)
-    return out.reshape(b, hkv, -1, gp, d)[:, :, :, :g].transpose(
-        0, 2, 1, 3, 4).reshape(b, -1, hq, d)[:, :w]
+    out = out.reshape(b, hkv, -1, gp, lanes)[:, :, :, :g]
+    if pack > 1:
+        out = jnp.sum(jnp.where(own, out, 0).reshape(
+            out.shape[:-1] + (pack, d)), axis=-2)
+    return out.transpose(0, 2, 1, 3, 4).reshape(b, -1, hq, d)[:, :w]
 
 
 def gqa_decode_attention(q, k_pool, v_pool, tables, pos, *, layer,

@@ -1752,3 +1752,190 @@ def test_window_train_step_compiles_at_the_cells_shapes(topo):
     mem = compiled.memory_analysis()
     assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes) \
         < 15.5 * 2**30
+
+
+# ---------------------------------------------------------------------------
+# the short-convolution family (`benchmarks/configs/lfm2-8b-a1b.json`):
+# grouped heads of 64, two key-value heads a lane tile, every expert held
+# ---------------------------------------------------------------------------
+
+def _lfm2():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import shortconv_moe as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "lfm2-8b-a1b.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+LB, LHQ, LD = 128, 32, 64               # slots, query heads, a head
+LPAGES = [((3, 4993, 4, 128, 128), BF16)] * 2   # 8 heads of 64 in pairs
+
+
+def _pair_decode_case():
+    return (lambda q, k, v, t, pos: da.gqa_decode_attention(
+        q, k, v, t, pos, layer=2, impl="pallas"),
+        [((LB, LHQ, LD), BF16)] + LPAGES + [((LB, 39), I32), ((LB,), I32)])
+
+
+def _pair_chunk_case(c):
+    return (lambda q, k, v, t, start: da.gqa_chunk_attention(
+        q, k, v, t, start, layer=2, impl="pallas"),
+        [((c, LHQ, LD), BF16)] + LPAGES + [((39,), I32), ((), I32)])
+
+
+def _all_experts_case(n, name):
+    return (lambda x, c, w, g, u, dn: grouped_experts.experts_grouped(
+        x, c, w, g, u, dn, held_from=0, impl="pallas", name=name)[0],
+        [((n, 2048), BF16), ((n, 4), I32), ((n, 4), jnp.float32)]
+        + [((32, 1792, 2048), BF16)] * 3)
+
+
+SHORTCONV_KERNELS = {
+    "gqa_full_decode": (_pair_decode_case(), "gqa_full_decode"),
+    "gqa_full_chunk_512": (_pair_chunk_case(512), "gqa_full_chunk"),
+    "gqa_full_chunk_128": (_pair_chunk_case(128), "gqa_full_chunk"),
+    "experts_grouped": (_all_experts_case(128, "experts_grouped"),
+                        "experts_grouped"),
+    "experts_grouped_prefill": (
+        _all_experts_case(512, "experts_grouped_prefill"),
+        "experts_grouped_prefill"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SHORTCONV_KERNELS))
+def test_shortconv_family_kernels_compile_under_their_names(topo, case,
+                                                            caplog):
+    """The grouped-head kernels at a head of 64 (a page of two key-value
+    heads side by side, 8 query heads a program: one sublane tile in
+    decode, 128 queries a program in a chunk) and the expert kernels at
+    32 held experts of 1,792: one kernel a call, under its own name, no
+    fallback noted, the pool in place."""
+    (fn, args), name = SHORTCONV_KERNELS[case]
+    compiled = compiled_for(topo, fn, *args)
+    assert kernel_names(compiled.as_text()) == [name]
+    assert not fallbacks(caplog)
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+def _tails_made(text: str, pool: dict) -> list[str]:
+    """The instructions of a program that make an array of the shape of
+    the pool's tails (`arrays_made`'s rule for what costs nothing; a
+    scatter or an update in place into the donated array is the array
+    itself)."""
+    shape = "bf16[" + ",".join(map(str, pool["tail"].shape)) + "]"
+    return [line.strip() for line in text.splitlines()
+            if f" = {shape}" in line and not re.search(
+                r" (parameter|get-tuple-element|bitcast|scatter|"
+                r"dynamic-update-slice)\(", line)
+            and "fusion(" not in line]
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
+                                                               caplog):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/lfm2-8b-a1b.json` as the engine jits them (the
+    pool donated): the attention and expert kernels of every layer under
+    their names, no fallback; no gather of a layer's pages (nothing the
+    size of a layer of the pool is made) and no copy of the whole tails
+    array; tails and pages updated in place; weights, pool and
+    temporaries under the chip's 15.75 GB with the reference's
+    temporaries beside them; no weight converted in a step."""
+    from ray_tpu.models import shortconv_moe
+    config, cfg, ref = _lfm2()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    slots, cols = serve["slots"], serve["max_len"] // kw["block_size"]
+    described, arg = describers(topo)
+    drawn = jax.eval_shape(lambda k: ref.init_params(k, config),
+                           jax.random.key(0))
+    # the reference's draw is a served tree: the engine's load runs nothing
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), jax.eval_shape(
+        lambda p: shortconv_moe.load(p, cfg), drawn)) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), drawn)
+    params = described(drawn)
+    pool = described(jax.eval_shape(lambda: shortconv_moe.init_pool(
+        cfg, kw["cache_blocks"], kw["block_size"], state_blocks=slots + 1)))
+    assert pool["k"].shape == LPAGES[0][0]
+    assert pool["tail"].shape == (11, slots + 1, 2, 2048)
+    if program == "decode":
+        started = time.monotonic()
+        lowered = jax.jit(
+            lambda p, cache, tok, pos, tab: shortconv_moe.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((slots,)), arg((slots,)),
+            arg((slots, 1 + cols)))
+        assert time.monotonic() - started < TRACE_AND_LOWER_S
+        compiled = lowered.compile()
+        want = {"gqa_full_decode": 3, "experts_grouped": 12}
+    else:
+        chunk = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: shortconv_moe.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, chunk)), pool, arg((1 + cols,)), arg(()),
+            arg(())).compile()
+        want = {"gqa_full_chunk": 3, "experts_grouped_prefill": 12}
+    text = compiled.as_text()
+    names = kernel_names(text)
+    assert {n: names.count(n) for n in set(names)} == want
+    assert not fallbacks(caplog)
+    # a chunk updates its block's tails where they lie. The step's one
+    # gather and one scatter of 128 blocks' tails are made on the array
+    # staged whole in VMEM (the compiler's memory-space assignment: 11.6
+    # MB in for each, once out: 35 MB a step at HBM speed, 43 us), once a
+    # program and not once a layer (ROADMAP S23's 4-5 %); PERF.md has the
+    # measured share
+    assert len(_tails_made(text, pool)) <= (3 if program == "decode" else 0)
+    # a gather of a layer through the table, or a lay-out of one, would be
+    # an array of a layer's pages: 4,993 x 4 x 128 x 128
+    layer = math.prod(pool["k"].shape[1:])
+    assert not [line for line in arrays_made(text, BF16, layer)
+                if "tpu_custom_call" not in line
+                and not re.search(r" (scatter|dynamic-update-slice)\(", line)
+                and "fusion(" not in line]
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 0.5e9               # and never copied
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + LFM2_REFERENCE_BYTES) < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+# what `refs/shortconv_moe.py` holds at once beside weights and pool
+# (`test_shortconv_reference_fits_beside_the_pool`)
+LFM2_REFERENCE_BYTES = 0.9e9
+
+
+def test_shortconv_reference_fits_beside_the_pool(topo):
+    """The cell's comparison runs `refs/shortconv_moe.py` in the replica,
+    beside the weights and the whole pool, on a sequence padded to
+    `max_len`: what it holds at once has to fit in what 128 slots' pages
+    leave of the chip."""
+    from ray_tpu.models import shortconv_moe
+    config, cfg, ref = _lfm2()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = jax.eval_shape(lambda: shortconv_moe.init_pool(
+        cfg, kw["cache_blocks"], kw["block_size"],
+        state_blocks=serve["slots"] + 1))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda p, s: ref.token_logprobs(p, s, config)).lower(
+            params, arg((1, serve["max_len"]))).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    print("reference", mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+          pool_bytes)
+    assert mem.temp_size_in_bytes < LFM2_REFERENCE_BYTES
+    assert (mem.argument_size_in_bytes + pool_bytes
+            + mem.temp_size_in_bytes) < 15.75e9

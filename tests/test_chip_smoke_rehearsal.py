@@ -105,6 +105,20 @@ def test_serve_parallel_hybrid_phase_tiny_on_cpu():
     assert '"state_folds": 5' in out
 
 
+def test_serve_shortconv_phase_tiny_on_cpu():
+    """The eighth family's part: an engine over `models/shortconv_moe.py`
+    (convolution tails beside pages of paired heads, every expert held)
+    in the phase's own process, float32 on the CPU (the plain paths), held
+    to the definition."""
+    out = run("cs.serve_family_phase(cs.shortconv_case(dict("
+              "cs.SHORTCONV_CFG, d_model=64, n_heads=4, head_dim=16, "
+              "d_ff=96, expert_ff=48, dtype='float32'), 0), platform='cpu', "
+              "streams=5, prompt_lens=(100, 300), new_tokens=6, slots=3, "
+              "seed=0)")
+    assert '"phase": "serve_shortconv"' in out
+    assert '"state_resets": 5' in out
+
+
 def test_train_phase_tiny_on_cpu():
     out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
               "steps=12, seed=0)")

@@ -141,3 +141,72 @@ def test_a_window_layer_never_reads_a_page_the_window_has_left():
                                   jnp.asarray(pos), layer=0, window=window,
                                   impl="pallas")
     assert np.isfinite(np.asarray(got)).all()
+
+
+# ---------------------------------------------------------------------------
+# a head of half a lane tile: two key-value heads side by side in a page
+# ---------------------------------------------------------------------------
+
+D64, HKV64, G64 = 64, 8, 4      # LFM2's: 32 query heads over 8 of 64
+
+
+def _paired_pools(key, layers=2):
+    """(pools as a family of `gqa_pack` 2 stores them `[L, NB, 4, BS,
+    128]`, the same numbers head by head `[L, NB, 8, BS, 64]`)."""
+    k_pool, v_pool = (jax.random.normal(k, (layers, NB, HKV64, BS, D64),
+                                        jnp.float32)
+                      for k in jax.random.split(key))
+    pair = lambda p: p.reshape(layers, NB, HKV64 // 2, 2, BS, D64).transpose(
+        0, 1, 2, 4, 3, 5).reshape(layers, NB, HKV64 // 2, BS, 2 * D64)
+    return (pair(k_pool), pair(v_pool)), (k_pool, v_pool)
+
+
+def test_a_head_of_64_lies_two_to_a_lane_tile():
+    assert da.gqa_pack(8, 64) == 2 and da.gqa_pack(8, 128) == 1
+    assert da.gqa_pack(2, 32) == 1 and da.gqa_pack(8, 256) == 1
+    rows = jnp.arange(3 * 8 * 64).reshape(3, 8, 64)
+    side = da.heads_side_by_side(rows, 2)
+    assert side.shape == (3, 4, 128)
+    assert (side[:, 1, 64:] == rows[:, 3]).all()
+    # the plan the cell's shapes run at: whole tiles, the default scope
+    for w in (1, 128):
+        assert da._gqa_plan(128, 8, 128, jnp.bfloat16, w) is not None
+    assert da._gqa_plan(128, 4, 64, jnp.bfloat16, 1) is None
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("pos", [
+    (0, 5, 37, 75, 130),        # ragged, a last page part full
+    (7, 15, 16, 159, 0)])       # a last page just full, one just begun
+def test_decode_at_a_head_of_64_matches_the_definition(pos, impl):
+    rng = np.random.default_rng(21)
+    pos = np.array(pos, np.int32)
+    paired, apart = _paired_pools(jax.random.key(20))
+    tables = _ring_tables(rng, pos, None, 20, 20)
+    q = jax.random.normal(jax.random.key(22), (len(pos), HKV64 * G64, D64))
+    got = da.gqa_decode_attention(q, *paired, tables, jnp.asarray(pos),
+                                  layer=1, impl=impl)
+    want = _dense(q[:, None], *apart, tables, pos, None, 1)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
+    ref = da.reference_gqa_paged_attention(
+        q[:, None], paired[0][1], paired[1][1], tables, jnp.asarray(pos))
+    np.testing.assert_allclose(np.asarray(ref[:, 0]), want, atol=2e-5,
+                               rtol=2e-5)
+
+
+@pytest.mark.parametrize("impl", ["jax", "pallas"])
+@pytest.mark.parametrize("start,c", [(0, 16), (13, 16), (64, 8), (91, 16),
+                                     (8, 136)])
+def test_chunk_at_a_head_of_64_matches_the_definition(start, c, impl):
+    """(8, 136): 136 queries of the 8 heads that read a pair are more
+    than one program's 1,024 score rows: two programs, the second
+    padded."""
+    assert da._gqa_query_tile(136, 2 * G64) == 128
+    rng = np.random.default_rng(23)
+    paired, apart = _paired_pools(jax.random.key(24))
+    table = _ring_tables(rng, [start + c - 1], None, 20, 20)[0]
+    q = jax.random.normal(jax.random.key(25), (c, HKV64 * G64, D64))
+    got = da.gqa_chunk_attention(q, *paired, table, jnp.int32(start),
+                                 layer=0, impl=impl)
+    want = _dense(q[None], *apart, table[None], np.array([start]), None, 0)[0]
+    np.testing.assert_allclose(np.asarray(got), want, atol=2e-5, rtol=2e-5)
