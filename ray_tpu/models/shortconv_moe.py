@@ -73,10 +73,13 @@ from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts
 
 # what the prefill and decode programs count, in the order of the int32
-# vector they return beside the logits; the held experts' loads follow
+# vector they return beside the logits; the held experts' loads follow.
+# `expert_row_tiles` over `experts_reached` is how often a call read an
+# expert's matrices: 1 where every group fits a tile of `experts_grouped`'s
+# layout, and each tile over that is a second read (`row_tile`)
 COUNTS = ("conv_rows_live", "conv_rows_padded", "state_resets",
           "attention_rows_read", "expert_tokens_here",
-          "expert_tokens_routed")
+          "expert_tokens_routed", "expert_row_tiles", "experts_reached")
 STATE_KEYS = ("tail",)              # the pool's arrays of state blocks
 KINDS = {"conv": "conv", "full_attention": "attention"}
 # the leaves a step reads in float32 (`load`): the router's scores and the
@@ -368,7 +371,9 @@ def _qkv(n, lp, pos, cfg):
 
 def _ffn(h, lp, kind, cfg, live, kernel):
     """-> (what the feed-forward part adds to h [N, D], the expert
-    layer's counts or None)."""
+    layer's counts or None: `expert_layer`'s two, then the row tiles the
+    call's layout gave the held experts and the experts that got a pair,
+    then the loads)."""
     adt = cfg.activation_dtype()
     f = rms_norm(h, lp["ffn_norm_scale"], cfg.eps)
     if kind == "dense":
@@ -376,12 +381,17 @@ def _ffn(h, lp, kind, cfg, live, kernel):
     with jax.named_scope("routed_experts"):
         routed, _, counts = expert_layer(f, lp, cfg.experts, adt, live,
                                          kernel)
-    return routed, counts
+        load = counts[2:]
+        tile = grouped_experts.row_tile(
+            f.shape[0] * cfg.experts_per_token, cfg.held_count)
+        plan = jnp.stack([jnp.sum(-(-load // tile)),
+                          jnp.sum(load > 0, dtype=jnp.int32)])
+    return routed, jnp.concatenate([counts[:2], plan, load])
 
 
 def _counts(cfg, head, expert_counts):
-    """`COUNTS`' first four, then the experts' two and their loads."""
-    experts = expert_totals(expert_counts, 2 + cfg.held_count)
+    """`COUNTS`' first four, then the experts' four and their loads."""
+    experts = expert_totals(expert_counts, 4 + cfg.held_count)
     return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
                             experts.astype(jnp.int32)])
 
@@ -554,9 +564,18 @@ def decode(params, tokens, cache, pos, tables, cfg: ShortConvMoEConfig,
                               jnp.int32(0), rows], expert_counts))
 
 
+def _stats(cfg, totals) -> dict:
+    """`ServingFamily.counts`: `COUNTS` by name, the loads' largest over
+    their mean, and the row tiles an expert reached."""
+    out = summarize(COUNTS, totals, cfg.held_count)
+    out["row_tiles_per_expert_reached"] = (
+        out["expert_row_tiles"] / max(out["experts_reached"], 1))
+    return out
+
+
 FAMILY = ServingFamily(
     init_pool=init_pool, prefill=prefill, decode=decode,
     copy_block=copy_block, gather_block=gather_block,
     scatter_block=scatter_block, load=load, state_blocks=1,
     state_keys=STATE_KEYS,
-    counts=lambda cfg, totals: summarize(COUNTS, totals, cfg.held_count))
+    counts=_stats)

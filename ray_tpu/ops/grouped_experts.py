@@ -13,18 +13,24 @@ tiles, and runs one MLP a group, in one of two forms of an expert:
     ungated relu^2 (w_gate None; up, down):
     y[t] = sum ... of weight[t, e] * relu(x[t] Wu_e^T)^2 Wd_e
 
-`glm-5.2`, `kanana-2-30b-a3b`, `ling-3.0-flash-vl` and `command-a-plus`
-run the gated form at the model's own width (`models/
-latent_sparse_moe.py:expert_layer`); `nemotron-3-super` runs the ungated
-one in its 1,024-wide latent (`models/mamba_moe.py`), forward only. Both
-are one kernel body under the same names, so the same metrics read them.
+`glm-5.2`, `kanana-2-30b-a3b`, `ling-3.0-flash-vl`, `command-a-plus`,
+`mellum2-12b-a2.5b` and `lfm2-8b-a1b` run the gated form at the model's
+own width (`models/blocks.py:expert_layer`; `lfm2-8b-a1b` alone holds
+every expert of its router); `nemotron-3-super` runs the ungated one in
+its 1,024-wide latent (`models/mamba_moe.py`), forward only. Both are one
+kernel body under the same names, so the same metrics read them.
 
 The layout has a static size, the worst case (every pair held, every group
 with a ragged tile): `N * k + held * tile` rows. The kernel's grid walks
-(row tile, slice of the expert width); tiles past the last group are
-skipped and re-use the last live tile's blocks, so they move no bytes. An
-expert's matrices (three, or two) are stored `[held, F, D]`, width first,
-so that a slice of the width is whole rows of D.
+(row tile, slice of the expert width), so each row tile of a group fetches
+its expert's matrices again. `row_tile` therefore sizes the tile by what
+the call can see, `N * k` pairs over `held` experts: about twice the pairs
+an expert can expect, so that a group is one tile and its matrices are
+read once a call; a larger tile costs `held * tile` rows of padding and
+nothing else. Tiles past the last group are skipped and re-use the last
+live tile's blocks, so they move no bytes. An expert's matrices (three, or
+two) are stored `[held, F, D]`, width first, so that a slice of the width
+is whole rows of D.
 
 The pure-JAX path is the plain loop over the held experts, each over every
 token; it differentiates as it is, and is what the kernels are compared
@@ -371,10 +377,25 @@ def _grouped_backward(xs, dys, row_w, tile_expert, tile_block, n_live,
 # the op
 # ---------------------------------------------------------------------------
 
-def row_tile(n_pairs: int) -> int:
-    """Rows a tile: 128 where a chunk of a prompt is routed, the 16 of a
-    packed sublane tile for a decode step's few pairs."""
-    return 128 if n_pairs >= 1024 else 16
+ROW_TILES = (16, 32, 64, 128)     # whole packed sublane tiles of bfloat16
+
+
+def row_tile(n_pairs: int, held: int) -> int:
+    """Rows a tile, from the call's static shape: 128 where a chunk of a
+    prompt is routed (1,024 pairs and more); under that the smallest of
+    `ROW_TILES` with room for twice the pairs a held expert can expect.
+
+    Under uniform routing that is `n_pairs / held` at most (where every
+    expert of the router is held; less where a share is, since the other
+    experts' pairs never reach the layout). A group that passes its tile
+    has its expert's matrices fetched a second time, and the expectation
+    itself is passed by nearly half the groups: at 512 pairs over 32
+    experts (16 an expert) a tile of 16 is 1.434 tiles an expert reached,
+    and 32, four standard deviations over, is 1.0001."""
+    if n_pairs >= 1024:
+        return ROW_TILES[-1]
+    return next((t for t in ROW_TILES if t * held >= 2 * n_pairs),
+                ROW_TILES[-1])
 
 
 def _pick_rows(rows, dest):
@@ -462,7 +483,7 @@ def experts_grouped(x, chosen, weights, w_gate, w_up, w_down, *,
             x, chosen, weights, w_gate, w_up, w_down,
             held_from), held_pairs(chosen, held_from, held)[1]
     n, k = chosen.shape
-    tile = row_tile(n * k)
+    tile = row_tile(n * k, held)
     layout = group_layout(chosen, held_from, held, tile)
     return _grouped(name, tile, x, weights, w_gate, w_up, w_down,
                     layout), layout[-1]

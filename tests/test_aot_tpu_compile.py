@@ -9,6 +9,7 @@ or times. `chip_smoke.py` is the run.
 """
 
 import functools
+import hashlib
 import math
 import os
 import re
@@ -77,6 +78,22 @@ def kernel_names(text: str) -> list[str]:
             .rsplit(".", 1)[0]
             for line in text.splitlines()
             if 'custom_call_target="tpu_custom_call"' in line]
+
+
+def kernel_results(text: str, name: str) -> list[str]:
+    """The result's type (`bf16[1536,2048]`) of each Mosaic kernel `name`
+    in a program."""
+    return [m.group(1) for line in text.splitlines()
+            if 'custom_call_target="tpu_custom_call"' in line
+            for m in [re.search(rf"%{name}(?:\.\d+)? = (\w+\[[\d,]*\])",
+                                line)] if m]
+
+
+def jaxpr_digest(fn, *args) -> str:
+    """sha256 (first 16 hex digits) of `fn`'s jaxpr at `args` (shapes),
+    addresses cut."""
+    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
 
 
 def arrays_made(text: str, dtype, size: int) -> list[str]:
@@ -631,7 +648,6 @@ DENSE_TRAIN = {"datadecide-300m": "cafa6bbd2fe12c06",
 
 @pytest.mark.parametrize("name", sorted(DENSE_TRAIN))
 def test_dense_training_traces_to_what_it_did(name):
-    import hashlib
     import json
     from benchmarks.harness import common
     if jax.__version__ != OLMO_PAGED_JAX:
@@ -644,11 +660,9 @@ def test_dense_training_traces_to_what_it_did(name):
                              jax.random.key(0))
     assert "wqkv" not in masters["layers"]
     batch = {"tokens": jax.ShapeDtypeStruct((8, 2049), I32)}
-    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(
-        jax.value_and_grad(lambda p, b: gpt.loss_fn(p, b, cfg)))(
-            masters, batch)))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
-        DENSE_TRAIN[name]
+    assert jaxpr_digest(
+        jax.value_and_grad(lambda p, b: gpt.loss_fn(p, b, cfg)),
+        masters, batch) == DENSE_TRAIN[name]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill", "verify"])
@@ -1123,15 +1137,10 @@ def test_paged_kernels_at_olmo_1b_trace_to_what_they_did(case):
     to what they did before the body took grouped heads and a window
     (the kernel's serialized Mosaic module is not the same from one
     compile to the next, so the jaxpr is what is held)."""
-    import hashlib
     if jax.__version__ != OLMO_PAGED_JAX:
         pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
     pool = jax.ShapeDtypeStruct((16, CELL_NB, BS, H, CELL_D), BF16)
     scalar = jax.ShapeDtypeStruct((), I32)
-
-    def fingerprint(fn, *args):
-        text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
-        return hashlib.sha256(text.encode()).hexdigest()[:16]
 
     if case == "decode":
         args = (jax.ShapeDtypeStruct((CELL_SLOTS, H, CELL_D), BF16), pool,
@@ -1149,7 +1158,7 @@ def test_paged_kernels_at_olmo_1b_trace_to_what_they_did(case):
         def fn(q, k, v, t, p, layer):
             return da.paged_prefill_attention(q, k, v, t, p, layer=layer,
                                               impl="pallas")
-    assert fingerprint(fn, *args) == OLMO_PAGED[case]
+    assert jaxpr_digest(fn, *args) == OLMO_PAGED[case]
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
@@ -1525,15 +1534,13 @@ MAMBA2_DIGESTS = {
 
 @pytest.mark.parametrize("case", sorted(MAMBA2_DIGESTS))
 def test_mamba2_kernels_trace_to_what_they_did(case):
-    import hashlib
     if jax.__version__ != OLMO_PAGED_JAX:
         pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
     cell, kernel = case.split(".")
     cases = MAMBA_KERNELS if cell == "nemotron" else PARALLEL_KERNELS
     fn, args = cases[kernel][0]
-    text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(
-        *(jax.ShapeDtypeStruct(shape, dtype) for shape, dtype in args))))
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == \
+    assert jaxpr_digest(fn, *(jax.ShapeDtypeStruct(shape, dtype)
+                              for shape, dtype in args)) == \
         MAMBA2_DIGESTS[case]
 
 
@@ -1578,15 +1585,10 @@ def test_shared_kernels_trace_to_what_they_did(case):
     benchmark's other cells run (forward, and for the training cell its
     gradient) and `gqa_full_*` at `command-a-plus`'s trace, equation for
     equation, to what they did before this family shared them."""
-    import hashlib
     if jax.__version__ != OLMO_PAGED_JAX:
         pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
     f32 = jnp.float32
     shape = jax.ShapeDtypeStruct
-
-    def fingerprint(fn, *args):
-        text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
-        return hashlib.sha256(text.encode()).hexdigest()[:16]
 
     if case in EXPERT_SHAPES:
         n, d, f, held, k, name = EXPERT_SHAPES[case]
@@ -1619,7 +1621,30 @@ def test_shared_kernels_trace_to_what_they_did(case):
             def fn(q, k, v, t, p):
                 return da.gqa_chunk_attention(q, k, v, t, p, layer=0,
                                               window=None, impl="pallas")
-    assert fingerprint(fn, *args) == UNCHANGED[case]
+    assert jaxpr_digest(fn, *args) == UNCHANGED[case]
+
+
+# `jaxpr_digest` of `SHORTCONV_KERNELS`' three calls of `experts_grouped`,
+# `lfm2-8b-a1b.chat-closed192`'s (every expert of a router of
+# 32 held, 4 a token: a step's 128 rows and the 128 bucket's in row tiles
+# of 32, a 512 chunk in tiles of 128), taken on PR 63's tree, which gave
+# `row_tile` the held experts to plan by. Apart from `UNCHANGED`: a PR
+# that means to change the plan of these calls (the tile, the grid's
+# order) replaces these and says so, and leaves those alone
+LFM2_PLAN = {
+    "experts_grouped": "03dc07d4fdc5a8c3",
+    "experts_grouped_prefill": "a6cac6ac09270951",
+    "experts_grouped_prefill_128": "a10a1bf20c9a30e8",
+}
+
+
+@pytest.mark.parametrize("case", sorted(LFM2_PLAN))
+def test_lfm2_expert_calls_trace_to_their_plan(case):
+    if jax.__version__ != OLMO_PAGED_JAX:
+        pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
+    (fn, args), _ = SHORTCONV_KERNELS[case]
+    assert jaxpr_digest(fn, *(jax.ShapeDtypeStruct(shape, dtype)
+                              for shape, dtype in args)) == LFM2_PLAN[case]
 
 
 # -- the window / full family on the training path, at
@@ -1801,7 +1826,15 @@ SHORTCONV_KERNELS = {
     "experts_grouped_prefill": (
         _all_experts_case(512, "experts_grouped_prefill"),
         "experts_grouped_prefill"),
+    "experts_grouped_prefill_128": (
+        _all_experts_case(128, "experts_grouped_prefill"),
+        "experts_grouped_prefill"),
 }
+# rows of a call -> rows of the expert kernel's layout, the pairs and a
+# tile of padding an expert (`grouped_experts.row_tile`): a step's and
+# the 128 bucket's 512 pairs in tiles of 32, a 512 chunk's 2,048 in tiles
+# of 128; 48 tiles each
+LFM2_LAYOUT_ROWS = {128: 512 + 32 * 32, 512: 2048 + 32 * 128}
 
 
 @pytest.mark.parametrize("case", sorted(SHORTCONV_KERNELS))
@@ -1810,11 +1843,16 @@ def test_shortconv_family_kernels_compile_under_their_names(topo, case,
     """The grouped-head kernels at a head of 64 (a page of two key-value
     heads side by side, 8 query heads a program: one sublane tile in
     decode, 128 queries a program in a chunk) and the expert kernels at
-    32 held experts of 1,792: one kernel a call, under its own name, no
-    fallback noted, the pool in place."""
+    32 held experts of 1,792 (16 pairs an expert of a step's and of the
+    128 bucket's call, so row tiles of 32): one kernel a call, under its
+    own name, no fallback noted, the pool in place."""
     (fn, args), name = SHORTCONV_KERNELS[case]
     compiled = compiled_for(topo, fn, *args)
     assert kernel_names(compiled.as_text()) == [name]
+    if name.startswith("experts_grouped"):
+        rows = LFM2_LAYOUT_ROWS[args[0][0][0]]
+        assert kernel_results(compiled.as_text(), name) == [
+            f"bf16[{rows},2048]"]
     assert not fallbacks(caplog)
     assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
 
@@ -1883,6 +1921,11 @@ def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
     names = kernel_names(text)
     assert {n: names.count(n) for n in set(names)} == want
     assert not fallbacks(caplog)
+    # every sparse layer's call walks the layout `row_tile` plans: a step
+    # of 128 rows x 4 over 32 experts is `experts_grouped` over [1536, 2048]
+    experts = next(n for n in want if n.startswith("experts_grouped"))
+    rows = LFM2_LAYOUT_ROWS[slots if program == "decode" else chunk]
+    assert kernel_results(text, experts) == [f"bf16[{rows},2048]"] * 12
     # a chunk updates its block's tails where they lie. The step's one
     # gather and one scatter of 128 blocks' tails are made on the array
     # staged whole in VMEM (the compiler's memory-space assignment: 11.6

@@ -430,7 +430,7 @@ def test_index_scores_kernel(dtype, mb, positions):
             list(np.argsort(-c[:n + 1], kind="stable")[:16])
 
 
-@pytest.mark.parametrize("n", [6, 160])      # the 16-row and 128-row tiles
+@pytest.mark.parametrize("n", [6, 160])      # row tiles of 16 and of 128
 def test_experts_grouped_kernel(n):
     d, f, held, width, k = 64, 32, 4, 8, 2
     keys = jax.random.split(jax.random.key(4), 6)
@@ -456,6 +456,71 @@ def test_experts_grouped_with_no_token_here():
         x, jnp.full((4, 2), 7), jnp.ones((4, 2)), *ws, held_from=0,
         impl="pallas")
     assert int(load.sum()) == 0 and not np.any(np.asarray(got))
+
+
+# rows, experts a token, held experts -> row tile, at every expert call of
+# the benchmark's cells (`tests/test_aot_tpu_compile.py:EXPERT_SHAPES`'
+# seven, nemotron's step, lfm2's step and both its chunk buckets, mellum2's
+# training chunk). Only lfm2, which holds every expert of its router, hands
+# an expert more than 8 pairs of a call under 1,024
+ROW_TILES = {
+    "glm-5.2.decode": (16, 8, 16, 16),
+    "glm-5.2.prefill": (512, 8, 16, 128),
+    "kanana-2-30b-a3b.train": (16384, 6, 16, 128),
+    "ling-3.0-flash-vl.decode": (64, 8, 128, 16),
+    "ling-3.0-flash-vl.prefill": (512, 8, 128, 128),
+    "command-a-plus.decode": (16, 8, 16, 16),
+    "command-a-plus.prefill": (512, 8, 16, 128),
+    "nemotron-3-super.decode": (64, 22, 128, 128),
+    "mellum2-12b-a2.5b.train": (8192, 8, 16, 128),
+    "lfm2-8b-a1b.decode": (128, 4, 32, 32),
+    "lfm2-8b-a1b.prefill_512": (512, 4, 32, 128),
+    "lfm2-8b-a1b.prefill_128": (128, 4, 32, 32),
+}
+
+
+@pytest.mark.parametrize("case", sorted(ROW_TILES))
+def test_row_tile_at_the_benchmark_s_shapes(case):
+    """The tile is what it was (16 under 1,024 pairs, 128 from there) in
+    every call but lfm2's two of 512 pairs over 32 experts, where 32 holds
+    the 16 pairs an expert expects twice over."""
+    n, k, held, tile = ROW_TILES[case]
+    assert grouped_experts.row_tile(n * k, held) == tile
+    if n * k < 1024:
+        assert tile * held >= 2 * n * k
+        assert tile == 16 or (tile // 2) * held < 2 * n * k
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gated", "relu2"])
+def test_experts_grouped_at_a_tile_of_32(gated):
+    """64 rows x 2 over 8 held experts plans tiles of 32. One expert gets
+    no pair, one exactly a tile, one 37 (a second tile, which must be as
+    right as the first), the rest what is left; unheld ids beside them."""
+    n, d, f, held, k = 64, 64, 32, 8, 2
+    assert grouped_experts.row_tile(n * k, held) == 32
+    # held experts 3 .. 10 of a router of 12: expert 3 none, 4 a tile, 5 37
+    ids = np.concatenate([np.full(32, 4), np.full(37, 5),
+                          np.resize([6, 7, 8, 9, 10, 0, 11], 59)])
+    chosen = jnp.asarray(np.random.default_rng(0).permutation(ids)
+                         .reshape(n, k).astype(np.int32))
+    keys = jax.random.split(jax.random.key(5), 5)
+    x = jax.random.normal(keys[0], (n, d))
+    weights = jax.random.uniform(keys[1], (n, k))
+    w_gate, w_up, w_down = (jax.random.normal(kk, (held, f, d)) * d ** -0.5
+                            for kk in keys[2:])
+    if not gated:
+        w_gate = None
+    got, load = grouped_experts.experts_grouped(
+        x, chosen, weights, w_gate, w_up, w_down, held_from=3, impl="pallas")
+    want = grouped_experts.reference_experts_grouped(
+        x, chosen, weights, w_gate, w_up, w_down, held_from=3)
+    assert list(np.asarray(load)[:3]) == [0, 32, 37]
+    layout = grouped_experts.group_layout(chosen, 3, held, 32)
+    # expert 5's two tiles follow expert 4's one: a tile more than the
+    # seven experts that got a pair
+    assert list(np.asarray(layout[2])[:3]) == [1, 2, 2]
+    assert int(layout[4]) == 1 + int(np.sum(np.asarray(load) > 0)) == 8
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-4)
 
 
 # -- (e) the engine's invariants with the new family -------------------------

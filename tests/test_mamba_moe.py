@@ -369,8 +369,9 @@ def expert_inputs(n, d, f, held, k, width, seed=3, dtype=jnp.float32):
                          ids=["width_48", "width_384_tile_128", "width_96"])
 def test_ungated_experts_match_their_plain_loop(n, f):
     """relu^2 of one projection, no gate matrix, at widths that are no
-    multiple of 256, with a decode step's few pairs (row tiles of 16) and
-    a chunk's many (128); by hand for one token besides."""
+    multiple of 256, with a decode step's few pairs (16 an expert: row
+    tiles of 32) and a chunk's many (128); by hand for one token
+    besides."""
     x, chosen, weights, w_up, w_down = expert_inputs(n, 32, f, 4, 7, 16)
     got, load = grouped_experts.experts_grouped(
         x, chosen, weights, None, w_up, w_down, held_from=4, impl="pallas")

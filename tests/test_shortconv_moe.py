@@ -252,6 +252,46 @@ def test_a_request_holds_a_state_block_and_its_pages(params):
     eng.check_invariants()
 
 
+def test_row_tiles_an_expert_reached_come_through_stats(params):
+    """No group of the tiny programs passes its tile (a step's 9 pairs and
+    a chunk's 48 or 96 over 8 experts: tiles of 16, 16 and 32, and a token
+    gives an expert one pair), so every expert a call reached was one
+    tile, one read of its matrices."""
+    eng = make_engine(params)
+    assert eng.stats()["row_tiles_per_expert_reached"] == 0.0
+    rids = [eng.submit(prompt(n, 60 + n), max_new_tokens=5)
+            for n in (20, 37, 9, 50)]
+    for rid in rids:
+        stream(eng, rid)
+    s = eng.stats()
+    calls = 6 * (s["decode_steps"] + s["prefill_chunks"])
+    assert 0 < s["experts_reached"] <= 8 * calls
+    assert s["expert_row_tiles"] == s["experts_reached"]
+    assert s["row_tiles_per_expert_reached"] == 1.0
+
+
+def test_a_group_past_its_tile_counts_a_second_row_tile(params):
+    """A router that sends every row to expert 0: a step of 20 rows x 3
+    over 8 experts lays out in tiles of 16, so expert 0's 20 pairs are
+    two tiles in each of the six sparse layers and every other reached
+    expert's are one."""
+    cfg = config()
+    b = 20
+    assert grouped_experts.row_tile(b * 3, cfg.held_count) == 16
+    skewed = dict(params, layers=[
+        dict(lp, router_bias=lp["router_bias"].at[0].add(100.0))
+        if "router_bias" in lp else lp for lp in params["layers"]])
+    pool = shortconv_moe.init_pool(cfg, 1 + b, BS, state_blocks=1 + b)
+    tables = jnp.stack([jnp.arange(1, b + 1)] * 2, axis=1)
+    counts = np.asarray(shortconv_moe.decode(
+        skewed, jnp.asarray(prompt(b, 7)), pool,
+        jnp.zeros((b,), jnp.int32), tables, cfg)[2])
+    s = shortconv_moe.FAMILY.counts(cfg, counts)
+    assert s["expert_tokens_here"] == 6 * b * 3
+    assert s["expert_row_tiles"] == s["experts_reached"] + 6
+    assert 1.0 < s["row_tiles_per_expert_reached"] <= 1.0 + 6 / (6 * 3)
+
+
 @pytest.mark.parametrize("at", [2, 5])
 def test_preempt_and_resume(params, at):
     """Both kinds of block go back, the resume re-prefills prompt and
