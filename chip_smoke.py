@@ -562,6 +562,7 @@ def hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
     from ray_tpu.models import linear_latent
+    from ray_tpu.ops import kda
     cfg = linear_latent.LinearLatentConfig(**cfg_kwargs)
     n_kda = cfg.mixers.count("kda")
     n_latent, n_sparse = cfg.n_layers - n_kda, sum(
@@ -580,9 +581,10 @@ def hybrid_case(cfg_kwargs: dict, seed: int) -> dict:
                             "latent_chunk_attend": n_latent,
                             "experts_grouped_prefill": n_sparse},
         "counters": ("state_resets", "kda_tokens_live", "kda_tokens_padded",
-                     "latent_rows_read", "expert_tokens_here",
+                     "latent_rows_read", "state_folds", "expert_tokens_here",
                      "expert_tokens_routed", "expert_groups_kept_here",
                      "expert_load_max_over_mean", "state_blocks"),
+        "ring": kda.RING,
         "tolerances": (HYBRID_LOGPROB_MAX_TOL, HYBRID_LOGPROB_MEAN_TOL)}
 
 

@@ -27,14 +27,20 @@ No rotary in a KDA layer: the decay carries position.
 
 **What the engine holds for this family**: one request, two kinds of
 block (`ServingFamily.state_blocks` 1 and `paged`). Column 0 of its table
-names a state block: `"state" [L_kda, blocks, H, d, d]` float32, rewritten
-by every token, and `"conv" [L_kda, blocks, conv_size - 1, 3 H d]`, the
-last pre-convolution projections (float32 bytes of activation values).
-The columns after it name pages of `"latent" [L_latent, pages, block_size,
-1, words]`, one row a token. Prefill resets the state block on a
-sequence's first chunk (`start == 0`), a chunk bucket's padding leaves
-state and tail bit for bit, and decode's idle rows (table all 0) rewrite
-the trash blocks of both kinds.
+names a state block: `"state" [L_kda, blocks, H, d, d]` float32,
+`"conv" [L_kda, blocks, conv_size - 1, 3 H d]`, the last pre-convolution
+projections (float32 bytes of activation values), and the ring beside the
+state: `"ring"`, the decode tokens that are not in the state yet
+(`kda.ring_array`: read every step, folded into the state when a block's
+ring is full, so that a decode step reads a state once and writes it once
+a ring), and `"held" [1, blocks]` int32, the entries a block's rings
+hold: one count a block, since all layers step together. The columns
+after it name pages of `"latent" [L_latent, pages, block_size, 1,
+words]`, one row a token. Prefill resets the state block on a sequence's
+first chunk (`start == 0`) and leaves its rings empty, a chunk bucket's
+padding leaves state and tail bit for bit, and decode's idle rows (table
+all 0) rewrite the trash blocks' tails and pages and move nothing of the
+trash state or its rings.
 
 Parameters: the tree `benchmarks/refs/linear_latent.py` documents.
 `forward` is the whole-sequence form for tests; `prefill` and `decode`
@@ -58,9 +64,10 @@ from ray_tpu.ops import grouped_experts, kda
 # what the prefill and decode programs count, in the order of the int32
 # vector they return beside the logits; the held experts' loads follow
 COUNTS = ("kda_tokens_live", "kda_tokens_padded", "state_resets",
-          "latent_rows_read", "expert_tokens_here", "expert_tokens_routed",
-          "expert_groups_kept_here")
-STATE_KEYS = ("state", "conv")      # the pool's arrays of state blocks
+          "latent_rows_read", "state_folds", "expert_tokens_here",
+          "expert_tokens_routed", "expert_groups_kept_here")
+# the pool's arrays of state blocks
+STATE_KEYS = ("state", "conv", "ring", "held")
 NORM_EPS = 1e-6                     # of a head's q and k
 
 
@@ -216,9 +223,10 @@ def init_params(key, cfg: LinearLatentConfig):
 
 def init_pool(cfg: LinearLatentConfig, n_blocks: int, block_size: int,
               mesh=None, *, state_blocks: int):
-    """{"state", "conv"} with `state_blocks` blocks on axis 1 and
-    {"latent"} with `n_blocks` pages, zero-filled; block 0 of each the
-    trash block."""
+    """`STATE_KEYS`' arrays with `state_blocks` blocks on axis 1 (the
+    rings beside the states, and how many entries a block's rings hold)
+    and {"latent"} with `n_blocks` pages, zero-filled; block 0 of each
+    the trash block."""
     if mesh is not None:
         raise ValueError("this family's pool is not sharded over a mesh")
     n_kda = cfg.mixers.count("kda")
@@ -228,6 +236,8 @@ def init_pool(cfg: LinearLatentConfig, n_blocks: int, block_size: int,
                            jnp.float32),
         "conv": jnp.zeros((n_kda, state_blocks, cfg.conv_size - 1,
                            cfg.conv_channels), jnp.float32),
+        "ring": kda.ring_array(n_kda, state_blocks, cfg.n_heads, hd),
+        "held": jnp.zeros((1, state_blocks), jnp.int32),
         "latent": lsm.latent_pool(cfg, cfg.n_layers - n_kda, n_blocks,
                                   block_size),
     }
@@ -312,7 +322,7 @@ def _groups_here(x, lp, cfg, live):
 
 
 def _counts(cfg, head, expert_counts):
-    """`COUNTS`' first four, then the experts' three and their loads."""
+    """`COUNTS`' first five, then the experts' three and their loads."""
     experts = expert_totals(expert_counts, 3 + cfg.held_count)
     return jnp.concatenate([jnp.stack(head).astype(jnp.int32),
                             experts.astype(jnp.int32)])
@@ -439,9 +449,15 @@ def prefill(params, tokens, cache, cfg: LinearLatentConfig, mesh=None, *,
         x = rms_norm(x, params["final_ln_scale"], cfg.eps)
         last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
         rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_latent
+        # the chunk leaves its block's rings empty, whoever held the
+        # block before (a chunk after decode steps of the same sequence
+        # does not occur: a preempted stream prefills again from its
+        # first token)
         return (unembed(last, params["head"], adt),
-                {"state": state, "conv": conv, "latent": latent},
-                _counts(cfg, [length, c - length, first, rows], expert_counts))
+                {**cache, "state": state, "conv": conv, "latent": latent,
+                 "held": cache["held"].at[0, block].set(0)},
+                _counts(cfg, [length, c - length, first, rows, jnp.int32(0)],
+                        expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
@@ -449,17 +465,23 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
     """One token for every slot (`gpt.decode_step_paged`'s contract):
     tokens [B] at positions pos [B]; `tables[:, 0]` each row's state
     block, the rest its pages. Idle rows name the trash blocks of both
-    kinds, rewrite them and count nothing.
+    kinds, rewrite their tails and pages and count nothing. Every KDA
+    layer's step reads `held` as it was before the step; it is written
+    once, after the last (idle rows all name block 0 and all leave its
+    count as it was).
     -> (logits [B, V] f32, cache, counts)."""
     adt = cfg.activation_dtype()
     taps = cfg.conv_size
     state, conv, latent = cache["state"], cache["conv"], cache["latent"]
+    ring = cache["ring"]
     b = tokens.shape[0]
     with jax.named_scope(EMBED):
         pos = pos.astype(jnp.int32)
         tables = tables.astype(jnp.int32)
         blocks, pages = tables[:, 0], tables[:, 1:]
         live = blocks > 0
+        held = cache["held"][0, blocks]
+        fold, held_after = kda.ring_after(blocks, held, cfg.state_round)
         widx = lsm.decode_write_index(latent, pages, pos)
         x = params["embed"].astype(adt)[tokens]
     n_kda = n_latent = 0
@@ -473,8 +495,8 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
                 conved = jnp.einsum("kc,bkc->bc", _conv_taps(lp), pre)
                 conv = conv.at[n_kda, blocks].set(pre[:, 1:])
                 q, k, v, g, beta = _heads(conved, h, lp, cfg)
-                o, state = kda.kda_step(
-                    q, k, v, g, beta, state, n_kda, blocks,
+                o, state, ring = kda.kda_step(
+                    q, k, v, g, beta, state, ring, n_kda, blocks, held,
                     state_round=cfg.state_round, impl=cfg.kda_impl)
                 x = x + _kda_out(o, h, lp, cfg)
                 n_kda += 1
@@ -492,11 +514,14 @@ def decode(params, tokens, cache, pos, tables, cfg: LinearLatentConfig,
     with jax.named_scope(HEAD):
         x = rms_norm(x, params["final_ln_scale"], cfg.eps)
         rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_latent
-        zero = jnp.int32(0)
+        n_live = jnp.sum(live, dtype=jnp.int32)
         return (unembed(x, params["head"], adt),
-                {"state": state, "conv": conv, "latent": latent},
-                _counts(cfg, [jnp.sum(live, dtype=jnp.int32), b - jnp.sum(
-                    live, dtype=jnp.int32), zero, rows], expert_counts))
+                {"state": state, "conv": conv, "latent": latent,
+                 "ring": ring,
+                 "held": cache["held"].at[0, blocks].set(held_after)},
+                _counts(cfg, [n_live, b - n_live, jnp.int32(0), rows,
+                              jnp.sum(fold, dtype=jnp.int32)],
+                        expert_counts))
 
 
 FAMILY = ServingFamily(

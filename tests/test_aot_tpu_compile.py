@@ -978,6 +978,7 @@ def _ling():
 KB, KL, KNS, KH, KD = 64, 6, 65, 32, 128
 KPAGES, KBS, KMB, KWORDS = 5697, 128, 128, 384
 KSTATE = [((KL, KNS, KH, KD, KD), F32)]
+KRING = [((KL, KNS, 8, 3 * KH, 128), F32)]
 
 
 def _kda_chunk_case(c):
@@ -990,10 +991,10 @@ def _kda_chunk_case(c):
 
 def _kda_step_case():
     from ray_tpu.ops import kda
-    return (lambda q, k, v, g, beta, s, blocks: kda.kda_step(
-        q, k, v, g, beta, s, 3, blocks, impl="pallas"),
+    return (lambda q, k, v, g, beta, s, ring, blocks, held: kda.kda_step(
+        q, k, v, g, beta, s, ring, 3, blocks, held, impl="pallas"),
         [((KB, KH, KD), BF16)] * 3 + [((KB, KH, KD), F32), ((KB, KH), F32)]
-        + KSTATE + [((KB,), I32)])
+        + KSTATE + KRING + [((KB,), I32)] * 2)
 
 
 def _latent_decode_case():
@@ -1014,17 +1015,56 @@ HYBRID_KERNELS = {
 
 @pytest.mark.parametrize("case", sorted(HYBRID_KERNELS))
 def test_hybrid_kernels_compile_under_their_names(topo, case):
+    """One kernel a call, under its own name; the state pool and the ring
+    reach `kda_step` as the program's parameters and are written in place
+    (nothing of a pool's size among the temporaries)."""
     (fn, args), name = HYBRID_KERNELS[case]
-    assert kernel_names(compiled_text(topo, fn, *args)) == [name]
+    compiled = compiled_for(topo, fn, *args)
+    assert kernel_names(compiled.as_text()) == [name]
+    assert compiled.memory_analysis().temp_size_in_bytes < 256 << 20
+
+
+# `jaxpr_digest` of the KDA kernels' calls at the cell's shapes
+# (`HYBRID_KERNELS`): the chunk kernel's as PR 63's tree traced them (PR 64
+# gave the step kernel its ring and did not touch the chunk kernel), the
+# step kernel's as PR 64 left it. A PR that means to change what the cell
+# runs replaces them and says so; under another JAX the test skips
+# (`OLMO_PAGED_JAX`'s rule)
+KDA_DIGESTS = {
+    "kda_chunk_512": "1849b00662e90453",
+    "kda_chunk_128": "58b9d3173852774c",
+    "kda_step": "24538af26f05bc47",
+}
+
+
+@pytest.mark.parametrize("case", sorted(KDA_DIGESTS))
+def test_kda_kernels_trace_to_what_they_did(case):
+    if jax.__version__ != OLMO_PAGED_JAX:
+        pytest.skip(f"digests taken under jax {OLMO_PAGED_JAX}")
+    fn, args = HYBRID_KERNELS[case][0]
+    assert jaxpr_digest(fn, *(jax.ShapeDtypeStruct(shape, dtype)
+                              for shape, dtype in args)) == KDA_DIGESTS[case]
+
+
+# seconds to trace and lower the hybrid family's decode program here, on
+# the CPU: ten times what the parent's took (the read-modify-write
+# `kda_step`: 0.59 + 0.35 s, alone on the machine, in the minutes PR 64's
+# took 0.97 + 0.50 s); a kernel body unrolled in Python over a state's
+# heads and tiles takes many times this (PR 53: 19 s)
+HYBRID_TRACE_AND_LOWER_S = 10.0
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
-def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
+def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program,
+                                                            caplog):
     """The decode step and both prefill buckets of
     `benchmarks/configs/ling-3.0-flash-vl.json` as the engine jits them
-    (the pool donated): every kernel is there under its name, states,
-    tails and pages are updated in place (no copy of the pool among the
-    temporaries), and weights, pool and temporaries fit the chip."""
+    (the pool donated): every kernel is there under its name, a call a
+    layer of its kind, no fallback; states, rings, tails and pages are
+    updated in place (no copy of the pool among the temporaries, and no
+    array of the states' or the rings' shape made around a kernel);
+    weights, pool and temporaries fit the chip; the decode program traces
+    and lowers in seconds."""
     from ray_tpu.models import linear_latent
     config, cfg, ref = _ling()
     described, arg = describers(topo)
@@ -1032,12 +1072,18 @@ def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
         lambda k: ref.init_params(k, config), jax.random.key(0)))
     pool = described(jax.eval_shape(lambda: linear_latent.init_pool(
         cfg, KPAGES, KBS, state_blocks=KNS)))
+    assert [(pool[k].shape, pool[k].dtype) for k in ("state", "ring")] == \
+        KSTATE + KRING
     if program == "decode":
-        compiled = jax.jit(
+        started = time.monotonic()
+        lowered = jax.jit(
             lambda p, cache, tok, pos, tab: linear_latent.decode(
                 p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
             params, pool, arg((KB,)), arg((KB,)),
-            arg((KB, 1 + KMB))).compile()
+            arg((KB, 1 + KMB)))
+        # paid at every start of a process: no compile cache answers it
+        assert time.monotonic() - started < HYBRID_TRACE_AND_LOWER_S
+        compiled = lowered.compile()
         want = {"kda_step": KL, "latent_row_write": 1, "latent_decode": 1,
                 "experts_grouped": 6}
     else:
@@ -1052,6 +1098,11 @@ def test_hybrid_family_programs_compile_at_the_cells_shapes(topo, program):
                 "latent_chunk_attend": 1, "experts_grouped_prefill": 6}
     names = kernel_names(compiled.as_text())
     assert {n: names.count(n) for n in set(names)} == want
+    assert not fallbacks(caplog)
+    # neither the states nor the ring beside them are copied around a
+    # kernel, nor carried into VMEM and back (`ops/mamba2.py`'s ring in
+    # three arrays was, PR 56)
+    assert not state_arrays_made(compiled.as_text(), pool)
     mem = compiled.memory_analysis()
     pool_bytes = sum(a.size * a.dtype.itemsize
                      for a in jax.tree.leaves(pool))

@@ -19,7 +19,7 @@ import pytest
 from benchmarks.refs import linear_latent as ref
 from ray_tpu.models import blocks, gpt, latent_sparse_moe as lsm, \
     linear_latent, retention
-from ray_tpu.ops import sparse_latent
+from ray_tpu.ops import kda, sparse_latent
 from ray_tpu.serve.engine import BlockAllocator, InferenceEngine
 from ray_tpu.util import faults
 
@@ -137,7 +137,7 @@ def test_one_footprint_arithmetic_for_every_family(params):
             for n, f in fams.items()} == {
         "gpt": (0, True, ()), "latent": (0, True, ()),
         "retention": (1, False, ("s", "z", "ring", "held")),
-        "hybrid": (1, True, ("state", "conv"))}
+        "hybrid": (1, True, ("state", "conv", "ring", "held"))}
     eng = make_engine(params)
     # 3 slots x 1 state block + 3 x 96 / 16 pages; a table is the state
     # block and six pages
@@ -146,6 +146,11 @@ def test_one_footprint_arithmetic_for_every_family(params):
     assert eng._written_blocks(16) == 2 and eng._written_blocks(17) == 3
     pool = eng.cache
     assert pool["state"].shape[:2] == pool["conv"].shape[:2] == (6, 4)
+    # the rings beside the states: an entry a token's k, u and running G
+    # (4 heads x 16 numbers: a row of lanes each, in a sublane tile of
+    # its own); one count a block
+    assert pool["ring"].shape == (6, 4, kda.RING, 24, 128)
+    assert pool["held"].shape == (1, 4) and pool["held"].dtype == jnp.int32
     assert pool["latent"].shape[:3] == (1, 19, BS)
     with pytest.raises(ValueError, match="prefix_cache=False"):
         InferenceEngine(params, config(), slots=2, max_len=64)
@@ -196,8 +201,10 @@ def test_a_request_holds_a_state_block_and_its_pages(params):
     assert s["blocks_in_use"] == s["state_blocks_in_use"] == 0
     assert s["pool_bytes"] == sum(a.nbytes for a in eng.cache.values())
     # a page's row (128 words of 4 B: 40 values in whole lane tiles) a
-    # token, and a sequence's state and tails over its 96 positions
-    state = 6 * (4 * 16 * 16 + 3 * 3 * 4 * 16) * 4
+    # token, and a sequence's state, tails, rings (whole sublane tiles
+    # of lanes at this width: 24 rows an entry) and count over its 96
+    # positions
+    state = 6 * (4 * 16 * 16 + 3 * 3 * 4 * 16 + kda.RING * 24 * 128) * 4 + 4
     assert s["kv_bytes_per_token"] == pytest.approx(128 * 4 + state / 96)
     # counts: the family's, through `counts`
     assert s["state_resets"] == 5
@@ -220,10 +227,11 @@ def test_a_request_holds_a_state_block_and_its_pages(params):
 @pytest.mark.parametrize("at", [2, 4, 6])
 def test_preempt_and_resume(params, at):
     """Preempted after its first token, in the middle of its steps and
-    before its last: both kinds of block go back, the resume re-prefills
-    prompt and emitted tokens from the first token into a state block it
-    resets and pages it rewrites, and the stream is what an unpreempted
-    one is."""
+    before its last, each time with tokens waiting in its rings: both
+    kinds of block go back, the resume re-prefills prompt and emitted
+    tokens from the first token into a state block it resets (its rings
+    left empty) and pages it rewrites, and the stream is what an
+    unpreempted one is."""
     base_eng = make_engine(params)
     base = stream(base_eng, base_eng.submit(prompt(40, 50),
                                             max_new_tokens=9))
@@ -250,8 +258,9 @@ def test_handoff_carries_the_state_block_and_the_pages(params):
     rid = pre.submit(p, max_new_tokens=6)
     blob = pre.handoff_for(rid)
     assert blob["n_blocks"] == len(blob["payload"]) == 1 + 3
-    assert set(blob["payload"][0]) == {"state", "conv"}
+    assert set(blob["payload"][0]) == {"state", "conv", "ring", "held"}
     assert blob["payload"][0]["state"].shape == (6, 4, 16, 16)
+    assert blob["payload"][0]["ring"].shape == (6, kda.RING, 24, 128)
     assert [set(b) for b in blob["payload"][1:]] == [{"latent"}] * 3
     assert blob["payload"][1]["latent"].shape == (1, BS, 1, 128)
     assert pre.stats()["blocks_in_use"] == 0
@@ -260,6 +269,95 @@ def test_handoff_carries_the_state_block_and_the_pages(params):
     assert dec.stats()["blocks_in_use"] == 0
     dec.check_invariants()
     pre.check_invariants()
+
+
+@pytest.mark.parametrize("slots", [1, 2], ids=["one_slot", "two_slots"])
+def test_a_block_freed_mid_ring_is_taken_by_a_new_sequence(params, slots):
+    """Requests that decode past a fold and end part of the way into a
+    ring, one after another on the same state blocks: the next sequence's
+    first chunk leaves the block's rings empty, so each streams what the
+    reference gives for it alone; on two slots the rows' rings fill in
+    different steps. `state_folds` counts the rows whose rings went into
+    their states: one every `RING` decode tokens of a request."""
+    eng = make_engine(params, slots=slots)
+    ring = kda.RING
+    news = (ring + 4, 2 * ring + 3, ring + 2, 5)
+    prompts = [prompt(n, 60 + i) for i, n in enumerate((20, 9, 37, 12))]
+    rids = [eng.submit(p, max_new_tokens=n) for p, n in zip(prompts, news)]
+    for p, rid in zip(prompts, rids):
+        got = stream(eng, rid)
+        seq = np.concatenate([p, [t for t, _ in got]]).astype(np.int32)
+        lp = np.asarray(ref.token_logprobs(
+            params, jnp.asarray(seq)[None], TINY)[0])[len(p) - 1:]
+        np.testing.assert_allclose([x for _, x in got], lp, atol=TOL)
+    s = eng.stats()
+    # a request's first token is its prefill's
+    assert s["state_folds"] == sum((n - 1) // ring for n in news)
+    assert 0 <= s["decode_tokens"] / ring - s["state_folds"] < len(news)
+    assert not np.asarray(eng.cache["held"])[0, 0]
+    eng.check_invariants()
+
+
+def test_the_engine_counts_a_fold_a_ring_of_decode_tokens(params):
+    """`state_folds` beside `decode_tokens`: over a run of requests that
+    start in different steps the share is near `1 / RING` (short of it by
+    the requests that end part of the way into a ring), and 1 under the
+    control, whose every step folds its one token."""
+    news = (41, 26, 33, 50, 19)
+    for state_round, share in (("none", 1 / kda.RING), ("bfloat16", 1.0)):
+        eng = make_engine(params, config(state_round=state_round))
+        for i, n in enumerate(news):
+            eng.submit(prompt(7 + 5 * i, 90 + i), max_new_tokens=n)
+        eng.run_until_idle()
+        s = eng.stats()
+        assert s["decode_tokens"] == sum(news) - len(news)
+        if state_round == "none":
+            assert s["state_folds"] == sum((n - 1) // kda.RING for n in news)
+            assert 0.11 <= s["state_folds"] / s["decode_tokens"] <= share
+        else:
+            assert s["state_folds"] == s["decode_tokens"]
+        eng.check_invariants()
+
+
+def test_a_handed_off_block_carries_a_half_full_ring(params):
+    """A state block exported in the middle of a ring (`gather_block`'s
+    arrays: the ring's entries and its count beside state and tail) and
+    imported into another block of another pool goes on as it was."""
+    cfg = config()
+    fam = linear_latent.FAMILY
+    table = jnp.asarray([[2, 3, 4, 0, 0, 0, 0]], jnp.int32)
+    toks = prompt(20 + 5, 95)
+    pool = linear_latent.init_pool(cfg, 6, BS, state_blocks=4)
+    for start, n in ((0, 16), (16, 4)):
+        chunk = np.zeros((1, 16), np.int32)
+        chunk[0, :n] = toks[start:start + n]
+        _, pool, _ = linear_latent.prefill(
+            params, jnp.asarray(chunk), pool, cfg, block_table=table[0],
+            start=start, length=n)
+
+    def steps(pool, table, lo, hi):
+        logits = []
+        for i in range(lo, hi):
+            out, pool, _ = linear_latent.decode(
+                params, jnp.asarray(toks[i:i + 1]), pool,
+                jnp.asarray([i], jnp.int32), table, cfg)
+            logits.append(out)
+        return jnp.concatenate(logits), pool
+
+    _, pool = steps(pool, table, 20, 23)
+    assert int(pool["held"][0, 2]) == 3
+    want, _ = steps(pool, table, 23, 25)
+    # the state block into block 1 of a pool whose block 2 holds garbage
+    other = jax.tree.map(lambda a: a + jnp.ones((), a.dtype),
+                         linear_latent.init_pool(cfg, 6, BS, state_blocks=4))
+    mine = {key: pool[key] for key in fam.state_keys}
+    moved = {**fam.scatter_block({key: other[key] for key in fam.state_keys},
+                                 fam.gather_block(mine, 2), 1),
+             "latent": pool["latent"]}
+    assert int(moved["held"][0, 1]) == 3
+    got, _ = steps(moved, table.at[0, 0].set(1), 23, 25)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=TOL)
 
 
 def test_a_cancelled_request_frees_both_kinds_of_block(params):
