@@ -35,7 +35,9 @@ a state head of 128 x 256 and a group of 5 query heads a key-value head),
 and last the short-convolution one (`models/shortconv_moe.py`, `--phase
 serve-shortconv`: convolution tails beside pages whose rows hold two
 key-value heads of 64 side by side, every expert of the router held and
-none shared).
+none shared), and the shortcut one (`models/shortcut_moe.py`, `--phase
+serve-shortcut`: two latent blocks and two MLPs a layer with the experts
+beside them, a softmax router whose last outputs are identity experts).
 After the dense train phase it trains the window-and-full family
 (`models/window_moe_train.py`, `--phase train-window`: the banded and the
 full flash kernels at grouped heads, the expert kernels' backward pass at
@@ -537,6 +539,23 @@ SHORTCONV_LOGPROB_MAX_TOL = 5e-1
 SHORTCONV_LOGPROB_MEAN_TOL = 5e-2
 
 
+# `models/shortcut_moe.py` at the published latent row (512 + 64 values in
+# pages of 128, so the latent kernels run at the cell's row format) and
+# head (128 + 64 / 128) and otherwise tiny: two layers (four attention
+# blocks), 8 held experts of a router 48 wide whose last 16 outputs are
+# identity experts, 6 a token
+SHORTCUT_CFG = dict(
+    vocab_size=512, d_model=256, n_layers=2, n_heads=4, q_rank=128,
+    kv_rank=512, nope_dim=128, rope_dim=64, v_dim=128,
+    indexer_types=("none",) * 2, mlp_types=("sparse",) * 2, d_ff=512,
+    expert_ff=256, router_width=48, identity_experts=16, held_count=8,
+    experts_per_token=6, routed_scale=6.0, rope_theta=1e7,
+    max_seq_len=1024)
+# bfloat16 activations against the float32 definition, two double layers
+SHORTCUT_LOGPROB_MAX_TOL = 5e-1
+SHORTCUT_LOGPROB_MEAN_TOL = 5e-2
+
+
 def retention_case(cfg_kwargs: dict, seed: int) -> dict:
     import jax
 
@@ -694,13 +713,40 @@ def shortconv_case(cfg_kwargs: dict, seed: int) -> dict:
                        SHORTCONV_LOGPROB_MEAN_TOL)}
 
 
+def shortcut_case(cfg_kwargs: dict, seed: int) -> dict:
+    import jax
+
+    from ray_tpu.models import shortcut_moe
+    cfg = shortcut_moe.ShortcutMoEConfig(**cfg_kwargs)
+    return {
+        "phase": "serve_shortcut", "family": shortcut_moe, "cfg": cfg,
+        "params": shortcut_moe.init_params(jax.random.key(seed), cfg),
+        "plain": dataclasses.replace(cfg, dtype="float32",
+                                     sparse_impl="jax"),
+        "engine": {"block_size": 128}, "table": 1024 // 128,
+        "decode_kernels": {"latent_row_write": 2 * cfg.n_layers,
+                           "latent_decode": 2 * cfg.n_layers,
+                           "experts_grouped": cfg.n_layers},
+        "prefill_kernels": {"latent_row_write": 2 * cfg.n_layers,
+                            "latent_chunk_attend": 2 * cfg.n_layers,
+                            "experts_grouped_prefill": cfg.n_layers},
+        "counters": ("latent_rows_read", "decode_rows_live",
+                     "chunk_rows_live", "expert_tokens_here",
+                     "expert_tokens_routed", "identity_tokens",
+                     "rows_few_experts", "rows_many_experts",
+                     "expert_load_max_over_mean"),
+        "tolerances": (SHORTCUT_LOGPROB_MAX_TOL,
+                       SHORTCUT_LOGPROB_MEAN_TOL)}
+
+
 def serve_family_phase(case: dict, *, platform: str, streams: int,
                        prompt_lens: tuple[int, int], new_tokens: int,
                        slots: int, seed: int) -> None:
     """The engine over a family that keeps more than pages that grow
     (`retention_case`, `hybrid_case`, `mamba_case`, `parallel_hybrid_case`,
     `shortconv_case`: a state a sequence;
-    `window_case`: a ring of window pages), in this process: `streams` greedy
+    `window_case`: a ring of window pages), or whose layer is no chain
+    (`shortcut_case`), in this process: `streams` greedy
     requests over `slots` slots (so blocks are reused), chunked
     prefill in both buckets and then steps. Holds the streamed logprobs to
     the family's float32 definition over the same tokens, the two programs
@@ -786,6 +832,13 @@ def serve_family_phase(case: dict, *, platform: str, streams: int,
         check(stats["state_folds"] == folds,
               f"{stats['state_folds']} rings folded into their states, "
               f"{folds} wanted of {streams} streams of {new_tokens} tokens")
+    if "identity_tokens" in case["counters"]:
+        rows = stats["decode_rows_live"] + stats["chunk_rows_live"]
+        choices = rows * cfg.n_layers * cfg.experts_per_token
+        check(stats["expert_tokens_routed"] + stats["identity_tokens"]
+              == choices and 0 < stats["identity_tokens"] < choices,
+              f"{stats['expert_tokens_routed']} choices with an expert and "
+              f"{stats['identity_tokens']} without, of {choices}")
     if "window_rows_read" in case["counters"]:
         n_window = case["cfg"].kinds.count("window")
         check(0 < stats["window_rows_read"]
@@ -1093,7 +1146,8 @@ def main() -> int:
                                         "serve-retention", "serve-hybrid",
                                         "serve-window", "serve-mamba",
                                         "serve-parallel-hybrid",
-                                        "serve-shortconv"),
+                                        "serve-shortconv",
+                                        "serve-shortcut"),
                     help="how a phase child is started")
     args = ap.parse_args()
 
@@ -1115,7 +1169,8 @@ def main() -> int:
              "serve-mamba": (mamba_case, MAMBA_CFG),
              "serve-parallel-hybrid": (parallel_hybrid_case,
                                        PARALLEL_HYBRID_CFG),
-             "serve-shortconv": (shortconv_case, SHORTCONV_CFG)}
+             "serve-shortconv": (shortconv_case, SHORTCONV_CFG),
+             "serve-shortcut": (shortcut_case, SHORTCUT_CFG)}
     if args.phase in cases:
         make, cfg_kwargs = cases[args.phase]
         case = make(cfg_kwargs, args.seed)
@@ -1157,6 +1212,7 @@ def main() -> int:
             run_phase_child("serve-mamba", args.seed)
             run_phase_child("serve-parallel-hybrid", args.seed)
             run_phase_child("serve-shortconv", args.seed)
+            run_phase_child("serve-shortcut", args.seed)
             run_phase_child("train", args.seed)
             run_phase_child("train-window", args.seed)
         else:

@@ -92,6 +92,21 @@ def weight(lp, name, adt):
     return (w.astype(jnp.float32) * s[..., None, :]).astype(adt)
 
 
+def cast_leaves(params, adt, float32_leaves=()):
+    """A family's `load` where its steps read plain leaves: every
+    floating leaf in the activations' type `adt`, those named in
+    `float32_leaves` in float32. A leaf already there is returned as it
+    is."""
+    def cast(path, leaf):
+        if not jnp.issubdtype(leaf.dtype, jnp.floating):
+            return leaf
+        name = path[-1].key if hasattr(path[-1], "key") else None
+        want = jnp.float32 if name in float32_leaves else adt
+        return leaf if leaf.dtype == want else leaf.astype(want)
+
+    return jax.tree_util.tree_map_with_path(cast, params)
+
+
 def gated_mlp(h, lp, adt, pet):
     """SwiGLU feed-forward on normed activations h [..., D] over `lp`'s
     `w_up`, `w_gate` and `w_down`, einsums emitting `pet`:
@@ -226,6 +241,10 @@ class Experts(NamedTuple):
     expert_round: str = "none"       # none | float8_e4m3fn (`rounded`)
     impl: str = "auto"               # auto | pallas | jax
     norm_eps: float = 0.0            # added to the chosen scores' sum
+    score_func: str = "sigmoid"      # sigmoid | softmax (the whole width)
+    # outputs from here up have no expert behind them: each returns its
+    # input (a zero-computation expert); None: every output has weights
+    identity_from: int | None = None
 
 
 def kept_groups(biased, n_group: int, topk_group: int):
@@ -238,10 +257,12 @@ def kept_groups(biased, n_group: int, topk_group: int):
     return jnp.any(keep[..., None] == jnp.arange(n_group), axis=1)
 
 
-def router_scores(h2, lp):
-    """-> (sigmoid scores [N, E], scores + the expert bias where the
-    layer has one), float32."""
-    g = jax.nn.sigmoid(jnp.einsum(
+def router_scores(h2, lp, score_func: str = "sigmoid"):
+    """-> (scores [N, E]: a sigmoid an output, or a softmax over the
+    router's whole width; scores + the expert bias where the layer has
+    one), float32."""
+    score = {"sigmoid": jax.nn.sigmoid, "softmax": jax.nn.softmax}[score_func]
+    g = score(jnp.einsum(
         "nd,de->ne", h2.astype(jnp.float32),
         lp["router"].astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
@@ -251,10 +272,11 @@ def router_scores(h2, lp):
 
 
 def routing(h2, lp, experts: Experts):
-    """A sigmoid router's choice: -> (chosen [N, k] i32, weights [N, k]
-    f32), in float32: a choice between two near-equal scores should not
-    turn on the activations' rounding more than it must."""
-    g, biased = router_scores(h2, lp)
+    """A router's choice: -> (chosen [N, k] i32, weights [N, k] f32), in
+    float32: a choice between two near-equal scores should not turn on
+    the activations' rounding more than it must. Outputs without an
+    expert (`identity_from`) are chosen like any other."""
+    g, biased = router_scores(h2, lp, experts.score_func)
     if experts.n_group > 1:
         biased = jnp.where(jnp.repeat(
             kept_groups(biased, experts.n_group, experts.topk_group),
@@ -291,15 +313,23 @@ def rounded(a, grid: str):
 def expert_layer(h2, lp, experts: Experts, adt, live=None,
                  kernel: str = grouped_experts.EXPERTS_GROUPED,
                  every_load: bool = False):
-    """A sparse layer's two parts on normed h2 [N, D]: -> (routed: what
+    """A sparse layer's parts on normed h2 [N, D]: -> (routed: what
     the held experts add, shared: the shared expert's, or None where the
-    layer's parameters hold no `ws_gate`, counts i32: pairs
-    routed here, pairs routed anywhere, then the pairs each held expert
-    got, or with `every_load` each expert of the router's whole width,
-    held or not; rows where `live` is false count nothing)."""
+    layer's parameters hold no `ws_gate`, identity: `h2` times the sum of
+    a row's weights for outputs without an expert, which every chip adds
+    for its own rows, or None where `experts.identity_from` is, counts
+    i32: pairs routed here, pairs routed to an expert anywhere, then the
+    pairs each held expert got, or with `every_load` each output of the
+    router's whole width, held or not; rows where `live` is false count
+    nothing)."""
     chosen, weights = routing(h2, lp, experts)
     if live is not None:
         chosen = jnp.where(live[:, None], chosen, -1)
+    identity = None
+    if experts.identity_from is not None:
+        free = chosen >= experts.identity_from
+        identity = (jnp.sum(jnp.where(free, weights, 0.0), -1, keepdims=True)
+                    * h2.astype(jnp.float32)).astype(adt)
     grid = experts.expert_round
     routed, load = grouped_experts.experts_grouped(
         rounded(h2, grid), chosen, weights, rounded(lp["we_gate"], grid),
@@ -314,9 +344,12 @@ def expert_layer(h2, lp, experts: Experts, adt, live=None,
     if every_load:
         load = jnp.sum(chosen[..., None] == jnp.arange(experts.router_width),
                        (0, 1), dtype=jnp.int32)
+    to_expert = chosen >= 0
+    if identity is not None:
+        to_expert &= ~free
     counts = jnp.concatenate([
-        jnp.stack([here, jnp.sum(chosen >= 0, dtype=jnp.int32)]), load])
-    return routed.astype(adt), shared, counts
+        jnp.stack([here, jnp.sum(to_expert, dtype=jnp.int32)]), load])
+    return routed.astype(adt), shared, identity, counts
 
 
 # what the programs count

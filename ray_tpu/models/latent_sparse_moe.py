@@ -384,7 +384,7 @@ def feed_forward(x, lp, cfg, live=None,
     with jax.named_scope(FFN):
         h2 = rms_norm(x, lp["ffn_norm_scale"], cfg.eps)
         if "router" in lp:
-            routed, shared, counts = expert_layer(
+            routed, shared, _, counts = expert_layer(
                 h2, lp, cfg.experts, adt, live, kernel, every_load)
             return x + routed + shared, counts
         return x + gated_mlp(h2, lp, adt, jnp.float32)[0], None

@@ -63,11 +63,11 @@ from dataclasses import dataclass
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.blocks import (Experts, copy_block, expert_layer,
-                                   expert_totals, gated_mlp, gather_block,
-                                   mm, rms_norm, rope_halves, row_index,
-                                   scatter_block, summarize, unembed,
-                                   write_chunk, write_rows)
+from ray_tpu.models.blocks import (Experts, cast_leaves, copy_block,
+                                   expert_layer, expert_totals, gated_mlp,
+                                   gather_block, mm, rms_norm, rope_halves,
+                                   row_index, scatter_block, summarize,
+                                   unembed, write_chunk, write_rows)
 from ray_tpu.models.family import EMBED, FFN, HEAD, MIXER, ServingFamily
 from ray_tpu.ops import decode_attention as da
 from ray_tpu.ops import grouped_experts
@@ -260,16 +260,7 @@ def load(params, cfg: ShortConvMoEConfig):
     read it in, so that no step converts a weight: the router, its bias
     and the taps in float32 (`FLOAT32_LEAVES`), every other in the
     activations' type. A leaf already there is returned as it is."""
-    adt = cfg.activation_dtype()
-
-    def cast(path, leaf):
-        if not jnp.issubdtype(leaf.dtype, jnp.floating):
-            return leaf
-        name = path[-1].key if hasattr(path[-1], "key") else None
-        want = jnp.float32 if name in FLOAT32_LEAVES else adt
-        return leaf if leaf.dtype == want else leaf.astype(want)
-
-    return jax.tree_util.tree_map_with_path(cast, params)
+    return cast_leaves(params, cfg.activation_dtype(), FLOAT32_LEAVES)
 
 
 # ---------------------------------------------------------------------------
@@ -379,8 +370,8 @@ def _ffn(h, lp, kind, cfg, live, kernel):
     if kind == "dense":
         return gated_mlp(f, lp, adt, jnp.float32)
     with jax.named_scope("routed_experts"):
-        routed, _, counts = expert_layer(f, lp, cfg.experts, adt, live,
-                                         kernel)
+        routed, _, _, counts = expert_layer(f, lp, cfg.experts, adt,
+                                            live, kernel)
         load = counts[2:]
         tile = grouped_experts.row_tile(
             f.shape[0] * cfg.experts_per_token, cfg.held_count)

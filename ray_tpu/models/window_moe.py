@@ -259,7 +259,7 @@ def _experts(n, lp, cfg, live, kernel):
     The shared experts' matrices lie side by side, so their sum is one
     gated MLP and their mean a quarter of it."""
     with jax.named_scope("routed_experts"):
-        routed, shared, counts = expert_layer(
+        routed, shared, _, counts = expert_layer(
             n, lp, cfg.experts, cfg.activation_dtype(), live, kernel)
     with jax.named_scope("shared_experts"):
         shared = shared * (1.0 / cfg.shared_experts)

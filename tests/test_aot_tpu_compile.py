@@ -2033,3 +2033,127 @@ def test_shortconv_reference_fits_beside_the_pool(topo):
     assert mem.temp_size_in_bytes < LFM2_REFERENCE_BYTES
     assert (mem.argument_size_in_bytes + pool_bytes
             + mem.temp_size_in_bytes) < 15.75e9
+
+
+# -- the shortcut family at longcat-flash-chat.agent-closed96's shapes: 64
+# slots, eight attention blocks of 2,050 pages of 128 latent rows, tables
+# of 32 columns, chunks of 512 and 128, 16 held experts of a router 768
+# wide of which 256 outputs are identity experts, 12 a token, vocabulary
+# 16,384
+
+def _longcat():
+    import json
+    from benchmarks.harness import common
+    from benchmarks.refs import shortcut_moe as ref
+    with open(os.path.join(common.ROOT, "benchmarks", "configs",
+                           "longcat-flash-chat.json")) as f:
+        config = json.load(f)
+    return config, common.model_config(config, "serve"), ref
+
+
+# rows of the layout `experts_grouped` plans for a call: all `n * k`
+# choices and a tile an expert held (`grouped_experts.group_layout`),
+# though a third of the choices name no expert and 31 of 32 of the rest
+# another chip's: 16 live pairs a step, 128 a chunk (PERF.md, PR 65)
+LONGCAT_LAYOUT_ROWS = {64: 64 * 12 + 16 * 128, 128: 128 * 12 + 16 * 128,
+                       512: 512 * 12 + 16 * 128}
+# what `refs/shortcut_moe.py` holds at once beside weights and pool
+# (`test_shortcut_reference_fits_beside_the_pool`)
+LONGCAT_REFERENCE_BYTES = 1.2e9
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+def test_shortcut_family_programs_compile_at_the_cells_shapes(topo, program,
+                                                              caplog):
+    """The decode step and both prefill buckets of
+    `benchmarks/configs/longcat-flash-chat.json` as the engine jits them
+    (the pool donated): the latent and expert kernels of every block and
+    layer under their names, no fallback; both scopes of the shortcut in
+    the program; the pool updated in place; weights, pool and temporaries
+    under the chip's 15.75 GB with the reference's temporaries beside
+    them; no weight converted in a step."""
+    from ray_tpu.models import shortcut_moe
+    config, cfg, ref = _longcat()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    slots, cols = serve["slots"], serve["max_len"] // kw["block_size"]
+    described, arg = describers(topo)
+    drawn = jax.eval_shape(lambda k: ref.init_params(k, config),
+                           jax.random.key(0))
+    # the reference's draw is a served tree: the engine's load runs nothing
+    assert jax.tree.map(lambda a: (a.shape, a.dtype), jax.eval_shape(
+        lambda p: shortcut_moe.load(p, cfg), drawn)) == jax.tree.map(
+        lambda a: (a.shape, a.dtype), drawn)
+    params = described(drawn)
+    # the engine asks for `cache_blocks` pages and the trash page
+    pool = described(jax.eval_shape(lambda: shortcut_moe.init_pool(
+        cfg, kw["cache_blocks"] + 1, kw["block_size"])))
+    assert (pool["latent"].shape, pool["latent"].dtype) == (
+        (8, 2050, 128, 1, 384), jnp.uint32)
+    if program == "decode":
+        started = time.monotonic()
+        lowered = jax.jit(
+            lambda p, cache, tok, pos, tab: shortcut_moe.decode(
+                p, tok, cache, pos, tab, cfg), donate_argnums=(1,)).lower(
+            params, pool, arg((slots,)), arg((slots,)), arg((slots, cols)))
+        assert time.monotonic() - started < TRACE_AND_LOWER_S
+        compiled = lowered.compile()
+        want = {"latent_row_write": 8, "latent_decode": 8,
+                "experts_grouped": 4}
+        rows = slots
+    else:
+        rows = int(program.rsplit("_", 1)[1])
+        compiled = jax.jit(
+            lambda p, tok, cache, tab, start, n: shortcut_moe.prefill(
+                p, tok, cache, cfg, block_table=tab, start=start,
+                length=n), donate_argnums=(2,)).lower(
+            params, arg((1, rows)), pool, arg((cols,)), arg(()),
+            arg(())).compile()
+        want = {"latent_row_write": 8, "latent_chunk_attend": 8,
+                "experts_grouped_prefill": 4}
+    text = compiled.as_text()
+    names = kernel_names(text)
+    assert {n: names.count(n) for n in set(names)} == want
+    assert not fallbacks(caplog)
+    for scope in ("ffn/shortcut_experts", "ffn/shortcut_dense",
+                  "mixer/shortcut_dense"):
+        assert scope in text, scope
+    experts = next(n for n in want if n.startswith("experts_grouped"))
+    assert kernel_results(text, experts) == [
+        f"bf16[{LONGCAT_LAYOUT_ROWS[rows]},6144]"] * 4
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    assert mem.alias_size_in_bytes >= pool_bytes        # updated in place
+    assert mem.temp_size_in_bytes < 0.6e9               # and never copied
+    assert (mem.argument_size_in_bytes + mem.temp_size_in_bytes
+            + LONGCAT_REFERENCE_BYTES) < 15.75e9
+    print(program, mem.argument_size_in_bytes, mem.temp_size_in_bytes)
+
+
+def test_shortcut_reference_fits_beside_the_pool(topo):
+    """The cell's comparison runs `refs/shortcut_moe.py` in the replica,
+    beside the weights and the whole pool, on a sequence padded to
+    `max_len`: what it holds at once has to fit in what 64 slots' pages
+    leave of the chip."""
+    from ray_tpu.models import shortcut_moe
+    config, cfg, ref = _longcat()
+    serve = config["program"]["serve"]
+    kw = serve["engine_kwargs"]
+    described, arg = describers(topo)
+    params = described(jax.eval_shape(
+        lambda k: ref.init_params(k, config), jax.random.key(0)))
+    pool = jax.eval_shape(lambda: shortcut_moe.init_pool(
+        cfg, kw["cache_blocks"] + 1, kw["block_size"]))
+    with jax.default_matmul_precision("highest"):
+        compiled = jax.jit(
+            lambda p, s: ref.token_logprobs(p, s, config)).lower(
+            params, arg((1, serve["max_len"]))).compile()
+    mem = compiled.memory_analysis()
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pool))
+    print("reference", mem.argument_size_in_bytes, mem.temp_size_in_bytes,
+          pool_bytes)
+    assert mem.temp_size_in_bytes < LONGCAT_REFERENCE_BYTES
+    assert (mem.argument_size_in_bytes + pool_bytes
+            + mem.temp_size_in_bytes) < 15.75e9

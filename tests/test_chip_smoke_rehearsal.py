@@ -119,6 +119,22 @@ def test_serve_shortconv_phase_tiny_on_cpu():
     assert '"state_resets": 5' in out
 
 
+def test_serve_shortcut_phase_tiny_on_cpu():
+    """The ninth family's part: an engine over `models/shortcut_moe.py`
+    (two latent blocks and two MLPs a layer, the experts beside them, a
+    third of the router's outputs identity experts) in the phase's own
+    process, float32 on the CPU (the plain paths), held to the
+    definition; the phase checks that every choice is counted once, with
+    an expert or without."""
+    out = run("cs.serve_family_phase(cs.shortcut_case(dict("
+              "cs.SHORTCUT_CFG, d_model=64, q_rank=32, kv_rank=32, "
+              "nope_dim=16, rope_dim=8, v_dim=16, d_ff=96, expert_ff=48, "
+              "dtype='float32'), 0), platform='cpu', streams=5, "
+              "prompt_lens=(100, 300), new_tokens=6, slots=3, seed=0)")
+    assert '"phase": "serve_shortcut"' in out
+    assert '"identity_tokens"' in out
+
+
 def test_train_phase_tiny_on_cpu():
     out = run(f"cs.train_phase({TINY_TRAIN}, platform='cpu', batch=4, "
               "steps=12, seed=0)")

@@ -349,7 +349,7 @@ def test_the_shares_add_up_to_the_uncut_layer(n_shared):
             cfg = config("pallas", n_routed_experts=hi - lo,
                          experts_held_from=lo, n_shared_experts=n_shared)
             assert share["ws_gate"].shape[1] == 32 * n_shared
-            routed, shared, counts = blocks.expert_layer(
+            routed, shared, _, counts = blocks.expert_layer(
                 h2, share, cfg.experts, cfg.activation_dtype())
             np.testing.assert_allclose(shared, ref.shared_part(h2, lp),
                                        rtol=0, atol=TOL)

@@ -542,7 +542,7 @@ def test_four_shares_add_up_to_the_uncut_layer():
         cfg = config(experts_held_from=4 * share)
         mine = {**lp, **{k: lp[k][4 * share:4 * share + 4]
                          for k in ("we_gate", "we_up", "we_down")}}
-        routed, shared, counts = blocks.expert_layer(
+        routed, shared, _, counts = blocks.expert_layer(
             h2, mine, cfg.experts, cfg.activation_dtype())
         total = total + routed
         # what one share gives is the reference's share of it

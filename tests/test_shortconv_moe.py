@@ -422,7 +422,7 @@ def test_the_layer_without_a_shared_expert_costs_nothing_for_it(params):
     lp = params["layers"][2]
     f = jax.random.normal(jax.random.key(5), (24, 64))
     cfg = config()
-    routed, shared, counts = blocks.expert_layer(
+    routed, shared, _, counts = blocks.expert_layer(
         f, lp, cfg.experts, jnp.float32, None)
     assert shared is None and int(counts[0]) == int(counts[1]) == 24 * 3
     np.testing.assert_allclose(np.asarray(routed),
@@ -461,7 +461,7 @@ def test_shares_add_up_to_the_uncut_layer(cuts):
         assert (cfg.router_width, cfg.held_count) == (32, hi - lo)
         mine = {**lp, **{k: lp[k][lo:hi]
                          for k in ("we_gate", "we_up", "we_down")}}
-        routed, shared, counts = blocks.expert_layer(
+        routed, shared, _, counts = blocks.expert_layer(
             f, mine, cfg.experts, jnp.float32, live,
             grouped_experts.EXPERTS_GROUPED)
         assert shared is None and int(counts[1]) == 48 * 4

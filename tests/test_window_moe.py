@@ -480,7 +480,7 @@ def test_eight_shares_add_up_to_the_uncut_layer():
             cfg = config(num_experts=2, experts_held_from=2 * share)
             mine = {**lp, **{key: lp[key][2 * share:2 * share + 2]
                              for key in ("we_gate", "we_up", "we_down")}}
-            routed, shared, counts = blocks.expert_layer(
+            routed, shared, _, counts = blocks.expert_layer(
                 n, mine, cfg.experts, cfg.activation_dtype())
             total = total + routed
             np.testing.assert_allclose(
