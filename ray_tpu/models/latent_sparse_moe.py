@@ -891,7 +891,7 @@ def decode_attend(q_nope, q_rope, latent, layer: int, tables, pos, lp, cfg,
     if rows is None:
         out = sparse_latent.latent_decode(
             q, latent, layer, tables, pos + 1, dtype=adt,
-            impl=cfg.sparse_impl)
+            values=cfg.row_values, kv_rank=cfg.kv_rank, impl=cfg.sparse_impl)
     else:
         out = sparse_latent.sparse_latent_decode(
             q, latent.reshape(-1, 1, cfg.row_words), rows + layer * nb * bs,

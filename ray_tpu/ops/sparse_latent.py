@@ -40,9 +40,33 @@ the live ones first): scores against the whole row (the no-position part
 of the query already multiplied through the key up-projection), online
 softmax, and the weighted sum of the rows themselves, whose leading
 `kv_rank` values the caller takes through the value up-projection. The
-rows come in chunks, double-buffered, one DMA a row; chunks past
+rows come in chunks through a ring of `CHUNK_SLOTS` buffers, one DMA a
+row, the next chunks in flight while one is scored; chunks past
 `count[b]` are never fetched, so the bytes scale with min(context, top_k)
 and never with the context.
+
+**`latent_decode`**: the same attention over every cached row of a
+stream's pages, the first `count[b]` live: a chunk is `ROW_CHUNK` rows of
+whole pages, a page one DMA where it lies, through the same ring (on a
+v5e a chunk's fetch runs at 520 GB/s with one chunk in flight and bounds
+the kernel; with two, at 680: `PERF.md`, PR 66).
+
+**A chunk's step** (`_attend_chunk`, both decode kernels). The chunk
+buffer keeps the pool's tiling, `[chunk, 1, words]` tiled (1, 128), so
+that a row or a page lands by a plain DMA; it is never reshaped. The
+same bytes seen as `[chunk * words / 128, 128]` are tiled (8, 128), and a
+load with a sublane stride of `words / 128` takes one lane tile of eight
+rows as one vreg (a reshape of the loaded value costs about eighty vector
+operations a vreg instead: gathers, rotates, selects and repacks). Each
+part is shifted or masked into float32 and packed to the compute type
+once. In both products the cached rows are the MXU's stationary operand,
+a tile of 128 x 128 latched once and the heads streamed past it (`q
+[H, lanes] . part^T` for the scores, `p [H, chunk] . part` for the sum);
+the softmax runs along the lanes of `[H, chunk]`. With the row's `values`
+and `kv_rank` given, only the lane tiles that hold some of them are
+loaded, unpacked and multiplied: of a row of 512 + 64 values stored as
+two parts of 384 lanes, five tiles of six for the scores and four for
+the sum, whose other output tiles stay zero.
 
 **`latent_chunk_attend`** (prefill): the same absorbed attention of a
 chunk's queries, every head, over the cached context up to the chunk's
@@ -72,6 +96,7 @@ LATENT_DECODE, LATENT_CHUNK_ATTEND = "latent_decode", "latent_chunk_attend"
 
 LANES = 128
 ROW_CHUNK = 256         # cached rows a chunk of the two decode kernels
+CHUNK_SLOTS = 3         # their chunk buffers: one scored, the rest in flight
 INDEX_STEP_TOKENS = 512  # cached positions a grid step of `index_scores`
 CONTEXT_BLOCK = 1024    # cached positions a step of a prefill chunk's loops
 CHUNK_VMEM_LIMIT = 100 << 20    # `latent_chunk_attend`'s scoped VMEM
@@ -333,33 +358,68 @@ def index_scores(q, w, pool, tables, pos, *, impl: str = "auto"):
 # sparse latent decode
 # ---------------------------------------------------------------------------
 
-def reference_sparse_latent_decode(q, pool, rows, count, dtype):
+def reference_sparse_latent_decode(q, pool, rows, count, dtype, values=None,
+                                   kv_rank=None):
     """q [parts, B, H, words] (the scale folded in), pool
     [n_rows, 1, words] uint32, rows [B, K] i32, count [B] i32 ->
-    f32 [parts, B, H, words]."""
+    f32 [parts, B, H, words]. Scores over a row's first `values` values
+    and the sum of its first `kv_rank` (None: the whole row)."""
     k = rows.shape[1]
     picked = jnp.stack(_parts_of(pool[rows, 0], dtype))  # [parts,B,K,words]
-    s = jnp.einsum("pbhw,pbkw->bhk", q.astype(jnp.float32), picked,
+
+    def first(n):   # a row's value i * words + j is part i's lane j
+        if n is None:
+            return picked
+        at = jnp.arange(picked.shape[0] * picked.shape[-1])
+        return jnp.where(at.reshape(-1, 1, 1, picked.shape[-1]) < n, picked,
+                         0.0)
+
+    s = jnp.einsum("pbhw,pbkw->bhk", q.astype(jnp.float32), first(values),
                    preferred_element_type=jnp.float32)
     live = jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None]
     p = jax.nn.softmax(jnp.where(live[:, None], s, NEG_INF), axis=-1)
-    return jnp.einsum("bhk,pbkw->pbhw", p, picked,
+    return jnp.einsum("bhk,pbkw->pbhw", p, first(kv_rank),
                       preferred_element_type=jnp.float32)
 
 
-def _attend_chunk(q_ref, words, c, n, m_scr, l_scr, acc_scr, dtype):
-    """One chunk of cached rows (uint32 `words` [chunk, words], the c-th
-    chunk of a stream's `n` live rows) into the online softmax that both
-    decode kernels keep: scores of every head against whole rows, the
-    running maximum and sum, and the weighted sum of the rows."""
-    chunk = words.shape[0]
+def _live_tiles(n, words: int, parts: int):
+    """Lane tiles of each part that hold some of a row's first `n` values
+    (None: the whole row)."""
+    full = words // LANES
+    if n is None:
+        return (full,) * parts
+    return tuple(min(full, max(0, -(-(n - i * words) // LANES)))
+                 for i in range(parts))
+
+
+def _attend_chunk(q_ref, rows_ref, c, n, m_scr, l_scr, acc_scr, dtype, tiles):
+    """One chunk of cached rows (`rows_ref` uint32 [chunk, 1, words] as the
+    DMAs left them, the c-th chunk of a stream's `n` live rows) into the
+    online softmax that both decode kernels keep: scores of every head
+    against the rows, the running maximum and sum, and the weighted sum of
+    the rows. `tiles`: the lane tiles of each part that are scored and
+    that are summed (`_live_tiles`); no other is loaded, unpacked,
+    multiplied or accumulated.
+
+    The buffer is tiled (1, 128) like the pool, a row's lane tile a
+    sublane of its own. Seen as `[chunk * words / 128, 128]` it is the
+    same bytes tiled (8, 128), and a load with a sublane stride of
+    `words / 128` takes one lane tile of eight rows as the vreg the
+    products read: no row is moved after it has landed."""
+    chunk, _, words = rows_ref.shape
+    stride = words // LANES
     compute = jnp.bfloat16 if row_parts(dtype) == 2 else jnp.float32
-    parts = [p.astype(compute) for p in _parts_of(words, dtype)]
+    scored, summed = tiles
+    lanes = rows_ref.reshape(chunk * stride, LANES)
+    loaded = jnp.concatenate([lanes[pl.ds(t, chunk, stride=stride), :]
+                              for t in range(max(scored + summed))], axis=1)
+    rows = [_parts_of(loaded[:, :max(k, v) * LANES], dtype)[i].astype(compute)
+            for i, (k, v) in enumerate(zip(scored, summed))]
     s = sum(jax.lax.dot_general(
-        q_ref[i, 0].astype(compute), part,
+        q_ref[i, 0, :, :k * LANES].astype(compute), part[:, :k * LANES],
         dimension_numbers=(((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)
-        for i, part in enumerate(parts))                # [H, chunk]
+        for i, (part, k) in enumerate(zip(rows, scored)) if k)  # [H, chunk]
     col = c * chunk + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(col < n, s, NEG_INF)
     m_prev = m_scr[:, :1]
@@ -368,38 +428,45 @@ def _attend_chunk(q_ref, words, c, n, m_scr, l_scr, acc_scr, dtype):
     p = jnp.exp(s - m_new)
     l_scr[:, :1] = l_scr[:, :1] * corr + jnp.sum(p, axis=1, keepdims=True)
     m_scr[:, :1] = m_new
-    for i, part in enumerate(parts):
-        acc_scr[i] = acc_scr[i] * corr + jax.lax.dot_general(
-            p.astype(compute), part,
-            dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)         # [H, words]
+    p = p.astype(compute)
+    for i, (part, v) in enumerate(zip(rows, summed)):
+        if v:
+            acc_scr[i, :, :v * LANES] = acc_scr[i, :, :v * LANES] * corr \
+                + jax.lax.dot_general(
+                    p, part[:, :v * LANES],
+                    dimension_numbers=(((1,), (0,)), ((), ())),
+                    preferred_element_type=jnp.float32)     # [H, lanes]
 
 
 def _online_decode(n, chunk, issue, wait, q_ref, o_ref, buf, m_scr, l_scr,
-                   acc_scr, dtype):
+                   acc_scr, dtype, tiles):
     """The loop both decode kernels run for one stream: its `n` live rows
-    in chunks of `chunk`, double-buffered (`issue(c, slot)` starts chunk
-    c's DMAs into `buf[slot]`, `wait(slot)` waits for them), through the
-    online softmax, then the weighted sum of rows out."""
+    in chunks of `chunk` through a ring of `CHUNK_SLOTS` buffers
+    (`issue(c, slot)` starts chunk c's DMAs into `buf[slot]`,
+    `wait(slot)` waits for them; the chunks after the one being scored
+    are in flight), through the online softmax, then the weighted sum of
+    rows out."""
     n_chunks = (n + chunk - 1) // chunk
+    ahead = CHUNK_SLOTS - 1
     m_scr[...] = jnp.full_like(m_scr, NEG_INF)
     l_scr[...] = jnp.zeros_like(l_scr)
     acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(n_chunks > 0)
-    def _first():
-        issue(0, 0)
+    for c in range(ahead):
+        @pl.when(c < n_chunks)
+        def _first():
+            issue(c, c)
 
     def step(c, _):
-        slot = c % 2
+        slot = c % CHUNK_SLOTS
 
-        @pl.when(c + 1 < n_chunks)
+        @pl.when(c + ahead < n_chunks)
         def _next():
-            issue(c + 1, 1 - slot)
+            issue(c + ahead, (c + ahead) % CHUNK_SLOTS)
 
         wait(slot)
-        _attend_chunk(q_ref, buf[slot].reshape(chunk, buf.shape[-1]), c, n,
-                      m_scr, l_scr, acc_scr, dtype)
+        _attend_chunk(q_ref, buf.at[slot], c, n, m_scr, l_scr, acc_scr, dtype,
+                      tiles)
         return _
 
     jax.lax.fori_loop(0, n_chunks, step, 0)
@@ -408,7 +475,7 @@ def _online_decode(n, chunk, issue, wait, q_ref, o_ref, buf, m_scr, l_scr,
 
 
 def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
-                   m_scr, l_scr, acc_scr, *, chunk: int, dtype):
+                   m_scr, l_scr, acc_scr, *, chunk: int, dtype, tiles):
     b = pl.program_id(0)
 
     def issue(c, slot):
@@ -427,7 +494,7 @@ def _sparse_kernel(rows_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
                               sem.at[slot]).wait()
 
     _online_decode(count_ref[b], chunk, issue, wait, q_ref, o_ref, buf,
-                   m_scr, l_scr, acc_scr, dtype)
+                   m_scr, l_scr, acc_scr, dtype, tiles)
 
 
 def _decode_specs(parts: int, h: int, words: int, chunk: int):
@@ -435,8 +502,8 @@ def _decode_specs(parts: int, h: int, words: int, chunk: int):
     kernels take, after two prefetched scalars."""
     block = pl.BlockSpec((parts, 1, h, words), lambda i, *_: (0, i, 0, 0))
     return block, [
-        pltpu.VMEM((2, chunk, 1, words), jnp.uint32),
-        pltpu.SemaphoreType.DMA((2,)),
+        pltpu.VMEM((CHUNK_SLOTS, chunk, 1, words), jnp.uint32),
+        pltpu.SemaphoreType.DMA((CHUNK_SLOTS,)),
         pltpu.VMEM((h, LANES), jnp.float32),          # m (col 0 used)
         pltpu.VMEM((h, LANES), jnp.float32),          # l
         pltpu.VMEM((parts, h, words), jnp.float32),   # acc
@@ -456,7 +523,8 @@ def _sparse_latent_decode_pallas(q, pool, rows, count, dtype):
         out_specs=block, scratch_shapes=scratch)
     with jax.named_scope(SPARSE_LATENT_DECODE):
         return pl.pallas_call(
-            functools.partial(_sparse_kernel, chunk=chunk, dtype=dtype),
+            functools.partial(_sparse_kernel, chunk=chunk, dtype=dtype,
+                              tiles=(_live_tiles(None, words, parts),) * 2),
             name=SPARSE_LATENT_DECODE,
             out_shape=jax.ShapeDtypeStruct((parts, b, h, words),
                                            jnp.float32),
@@ -486,7 +554,8 @@ def sparse_latent_decode(q, pool, rows, count, *, dtype,
 # dense latent decode
 # ---------------------------------------------------------------------------
 
-def reference_latent_decode(q, pool, layer: int, tables, count, dtype):
+def reference_latent_decode(q, pool, layer: int, tables, count, dtype,
+                            values=None, kv_rank=None):
     """q [parts, B, H, words], pool [L, n_blocks, bs, 1, words] uint32,
     tables [B, max_blocks] i32, count [B] i32 -> f32 [parts, B, H, words]:
     `reference_sparse_latent_decode` over every row of the stream's
@@ -495,12 +564,13 @@ def reference_latent_decode(q, pool, layer: int, tables, count, dtype):
     at = jnp.arange(tables.shape[1] * bs, dtype=jnp.int32)
     rows = jnp.take(tables, at // bs, axis=1) * bs + at % bs
     return reference_sparse_latent_decode(
-        q, pool[layer].reshape(-1, 1, pool.shape[-1]), rows, count, dtype)
+        q, pool[layer].reshape(-1, 1, pool.shape[-1]), rows, count, dtype,
+        values, kv_rank)
 
 
 def _latent_kernel(tbl_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
                    m_scr, l_scr, acc_scr, *, layer: int, pages: int,
-                   block_size: int, dtype):
+                   block_size: int, dtype, tiles):
     b = pl.program_id(0)
 
     def copy(c, slot, i):
@@ -518,10 +588,11 @@ def _latent_kernel(tbl_ref, count_ref, q_ref, pool_ref, o_ref, buf, sem,
             copy(0, slot, i).wait()
 
     _online_decode(count_ref[b], pages * block_size, issue, wait, q_ref,
-                   o_ref, buf, m_scr, l_scr, acc_scr, dtype)
+                   o_ref, buf, m_scr, l_scr, acc_scr, dtype, tiles)
 
 
-def _latent_decode_pallas(q, pool, layer: int, tables, count, dtype):
+def _latent_decode_pallas(q, pool, layer: int, tables, count, dtype, values,
+                          kv_rank):
     parts, b, h, words = q.shape
     bs = pool.shape[2]
     pages = max(1, ROW_CHUNK // bs)
@@ -534,7 +605,9 @@ def _latent_decode_pallas(q, pool, layer: int, tables, count, dtype):
     with jax.named_scope(LATENT_DECODE):
         return pl.pallas_call(
             functools.partial(_latent_kernel, layer=layer, pages=pages,
-                              block_size=bs, dtype=dtype),
+                              block_size=bs, dtype=dtype,
+                              tiles=(_live_tiles(values, words, parts),
+                                     _live_tiles(kv_rank, words, parts))),
             name=LATENT_DECODE,
             out_shape=jax.ShapeDtypeStruct((parts, b, h, words),
                                            jnp.float32),
@@ -546,6 +619,7 @@ def _latent_decode_pallas(q, pool, layer: int, tables, count, dtype):
 
 
 def latent_decode(q, pool, layer: int, tables, count, *, dtype,
+                  values: int | None = None, kv_rank: int | None = None,
                   impl: str = "auto"):
     """One query a stream over every cached row of its context: dense
     absorbed latent attention.
@@ -555,14 +629,20 @@ def latent_decode(q, pool, layer: int, tables, count, *, dtype,
     latent pool where it lies; `layer` (static) the layer read. tables
     [B, max_blocks] i32: each stream's pages in order (0: the trash
     block). count [B] i32: the stream's live rows, its first `count[b]`
-    positions. A stream's live pages are streamed whole by DMA, a chunk of
-    pages ahead of the one being scored, so the bytes scale with its
-    context; pages past it are never fetched.
+    positions. A stream's live pages are streamed whole by DMA, two chunks
+    of pages ahead of the one being scored, so the bytes scale with its
+    context; pages past it are never fetched. `values` / `kv_rank`
+    (static; None: the whole row): the scores read a row's first `values`
+    values and the sum its first `kv_rank`, in whole lane tiles of each
+    part; what lies in the pool's other tiles is never loaded, and the
+    output's other tiles are zero.
     -> f32 [parts, B, H, words] (`join_parts` puts it back in the row's
     order)."""
     if resolve_impl(impl) == "pallas":
-        return _latent_decode_pallas(q, pool, layer, tables, count, dtype)
-    return reference_latent_decode(q, pool, layer, tables, count, dtype)
+        return _latent_decode_pallas(q, pool, layer, tables, count, dtype,
+                                     values, kv_rank)
+    return reference_latent_decode(q, pool, layer, tables, count, dtype,
+                                   values, kv_rank)
 
 
 # ---------------------------------------------------------------------------

@@ -997,12 +997,15 @@ def _kda_step_case():
         + KSTATE + KRING + [((KB,), I32)] * 2)
 
 
-def _latent_decode_case():
+def _latent_decode_case(heads=KH, layers=1, pages=KPAGES, mb=KMB):
+    """ling's call (32 heads over one layer's pages) or, with `heads` and
+    the pool's size given, longcat's; both rows hold 512 + 64 values."""
     return (lambda q, pool, tables, count: sparse_latent.latent_decode(
-        q, pool, 0, tables, count, dtype=BF16, impl="pallas"),
-        [((2, KB, KH, KWORDS), BF16), ((1, KPAGES, KBS, 1, KWORDS),
-                                       jnp.uint32),
-         ((KB, KMB), I32), ((KB,), I32)])
+        q, pool, layers - 1, tables, count, dtype=BF16, values=576,
+        kv_rank=512, impl="pallas"),
+        [((2, KB, heads, KWORDS), BF16), ((layers, pages, KBS, 1, KWORDS),
+                                          jnp.uint32),
+         ((KB, mb), I32), ((KB,), I32)])
 
 
 HYBRID_KERNELS = {
@@ -1010,6 +1013,9 @@ HYBRID_KERNELS = {
     "kda_chunk_128": (_kda_chunk_case(128), "kda_chunk"),
     "kda_step": (_kda_step_case(), "kda_step"),
     "latent_decode": (_latent_decode_case(), "latent_decode"),
+    # longcat-flash-chat.agent-closed96's: 64 heads over u32[8,2050,128,1,384]
+    "latent_decode_64": (_latent_decode_case(64, 8, 2050, 32),
+                         "latent_decode"),
 }
 
 

@@ -423,35 +423,70 @@ def test_a_rounded_state_moves_the_logprobs(params):
 
 # -- (c) the dense latent decode ----------------------------------------------
 
-@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
-def test_latent_decode_kernel_streams_a_stream_s_pages(dtype):
-    """Three streams over a pool of two layers and pages of 16 rows:
-    contexts of 1 row, of a whole number of chunks and of a ragged tail,
-    against the plain path and against softmax attention by hand."""
+def _latent_case(dtype, h, bs, values, seed=3):
+    """Six streams over layer 1 of a pool of two layers in pages of `bs`
+    rows: contexts of 1 row (an idle slot), of 255, 256 and 257 rows (a
+    chunk less one, whole, and one more), of a ragged last page, and of
+    every page of its table; the shorter tables are padded with the trash
+    block. -> (rows [2, n_blocks, bs, values], pool, q, tables, count)."""
     dt = jnp.dtype(dtype)
-    values, h, b = 40, 4, 3
     words = sparse_latent.row_words(values, dt)
-    ks = jax.random.split(jax.random.key(3), 3)
-    rows = jax.random.normal(ks[0], (2, 20, 16, values)).astype(dt)
+    count = np.asarray([1, 255, 256, 257, 259 + bs // 2, 384], np.int32)
+    mb = 384 // bs
+    ks = jax.random.split(jax.random.key(seed), 2)
+    rows = jax.random.normal(ks[0], (2, 6 * mb + 1, bs, values)).astype(dt)
     pool = sparse_latent.pack_rows(rows, words)[:, :, :, None, :]
     q = sparse_latent.split_query(
-        jax.random.normal(ks[1], (b, h, values)).astype(dt), words)
-    tables = jnp.asarray([[3, 0, 0, 0, 0, 0], [5, 9, 2, 7, 11, 13],
-                          [19, 1, 4, 6, 0, 0]], jnp.int32)
-    count = jnp.asarray([1, 96, 53], jnp.int32)
+        (2 * values ** -0.5 * jax.random.normal(ks[1], (6, h, values))
+         ).astype(dt), words)
+    tables = np.random.default_rng(seed).permutation(
+        np.arange(1, 6 * mb + 1)).reshape(6, mb).astype(np.int32)
+    tables[np.arange(mb)[None, :] * bs >= count[:, None]] = 0
+    return rows, pool, q, jnp.asarray(tables), jnp.asarray(count)
+
+
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+@pytest.mark.parametrize("bs", [16, 128])
+@pytest.mark.parametrize("h,values", [(4, 40), (32, 576), (64, 576)])
+def test_latent_decode_kernel_streams_a_stream_s_pages(dtype, bs, h, values):
+    """The kernel (interpreted here) against the plain path and against
+    softmax attention by hand, at ling's and longcat's heads and row."""
+    rows, pool, q, tables, count = _latent_case(dtype, h, bs, values)
     got = {impl: sparse_latent.join_parts(sparse_latent.latent_decode(
-        q, pool, 1, tables, count, dtype=dt, impl=impl), values)
+        q, pool, 1, tables, count, dtype=jnp.dtype(dtype), impl=impl), values)
         for impl in ("jax", "pallas")}
     np.testing.assert_allclose(np.asarray(got["pallas"]),
                                np.asarray(got["jax"]), rtol=0, atol=2e-2
                                if dtype == "bfloat16" else 1e-5)
     qf = sparse_latent.join_parts(q, values).astype(jnp.float32)
-    for i in range(b):
+    for i in range(len(count)):
         ctx = rows[1, tables[i]].reshape(-1, values)[:int(count[i])].astype(
             jnp.float32)
         p = jax.nn.softmax(qf[i] @ ctx.T, -1)
         np.testing.assert_allclose(np.asarray(got["jax"][i]),
                                    np.asarray(p @ ctx), rtol=0, atol=1e-4)
+
+
+def test_latent_decode_reads_only_the_tiles_that_hold_values():
+    """longcat's row, 512 + 64 values in 768 stored: with `values` and
+    `kv_rank` given, the last lane tile of a row's second part is never
+    read (NaN there reaches nothing), scores stop at `values`, and the
+    output's first `kv_rank` values are those of the whole-row call."""
+    values, kv_rank = 576, 512
+    rows, pool, q, tables, count = _latent_case("bfloat16", 64, 128, values)
+    whole = sparse_latent.join_parts(sparse_latent.latent_decode(
+        q, pool, 1, tables, count, dtype=jnp.bfloat16, impl="jax"), kv_rank)
+    nan = jnp.uint32(0x7FC00000)    # a bfloat16 NaN in a word's high half
+    poisoned = pool.at[..., 256:].set(pool[..., 256:] & 0xFFFF | nan)
+    for impl, tol in (("jax", 1e-5), ("pallas", 2e-2)):
+        out = sparse_latent.latent_decode(
+            q, poisoned, 1, tables, count, dtype=jnp.bfloat16, values=values,
+            kv_rank=kv_rank, impl=impl)
+        got = np.asarray(sparse_latent.join_parts(out, kv_rank))
+        assert np.isfinite(got).all(), impl
+        assert not np.asarray(sparse_latent.join_parts(out, 768))[
+            ..., kv_rank:].any(), impl
+        np.testing.assert_allclose(got, np.asarray(whole), rtol=0, atol=tol)
 
 
 def test_a_layer_without_an_indexer_is_served(params):
