@@ -966,17 +966,7 @@ class HostDaemon:
                 lst.close()
             except OSError:
                 pass
-        deadline = time.monotonic() + 2.0
-        for w in workers:
-            if w.proc is None:
-                continue
-            try:
-                while w.proc.poll() is None and time.monotonic() < deadline:
-                    time.sleep(0.02)
-                if w.proc.poll() is None:
-                    w.proc.kill()
-            except OSError:
-                pass
+        spawn.stop_procs([w.proc for w in workers])
         self.store.purge_spill()
         self.store.close()
         if os.environ.get("RAY_TPU_NODE_DIR") is None and \

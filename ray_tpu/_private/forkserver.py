@@ -35,16 +35,6 @@ def _proc_start(pid: int):
         return None
 
 
-def _reap(signum, frame):
-    try:
-        while True:
-            pid, _ = os.waitpid(-1, os.WNOHANG)
-            if pid == 0:
-                break
-    except ChildProcessError:
-        pass
-
-
 def _spawn_child(req: dict) -> int:
     import warnings
     with warnings.catch_warnings():
@@ -106,9 +96,16 @@ def main():
     # ~85ms of bytecode compilation per child without the preload.
     import asyncio  # noqa: F401
     from ray_tpu._private import worker_main  # noqa: F401
-    signal.signal(signal.SIGCHLD, _reap)
+    # The kernel reaps an ignored SIGCHLD's children itself. A handler
+    # cannot: SIGCHLD goes to the thread that forked (a `_serve` thread),
+    # Python runs handlers in the main thread, and that one sleeps in
+    # accept(), so a dead worker stayed a zombie, which `ForkedProc.poll`
+    # reads as alive, until the next spawner connected.
+    signal.signal(signal.SIGCHLD, signal.SIG_IGN)
+    # the spawner's pid as it gave it: one that died while this process
+    # was still importing has already handed it to another parent
     threading.Thread(target=_watch_parent,
-                     args=(os.getppid(), sock_path), daemon=True).start()
+                     args=(int(sys.argv[2]), sock_path), daemon=True).start()
     if os.path.exists(sock_path):
         os.unlink(sock_path)
     with connection.Listener(family="AF_UNIX", address=sock_path,
@@ -140,9 +137,9 @@ def _serve(conn):
             os._exit(0)
         try:
             pid = _spawn_child(req)
-            # start ticks = pid-reuse-proof identity (the factory reaps
-            # children on SIGCHLD, so a bare pid is recyclable the
-            # moment the child dies)
+            # start ticks = pid-reuse-proof identity (the factory ignores
+            # SIGCHLD, so a bare pid is recyclable the moment the child
+            # dies)
             conn.send({"pid": pid, "start": _proc_start(pid)})
         except BaseException as e:
             try:

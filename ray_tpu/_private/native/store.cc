@@ -25,6 +25,7 @@
 #include <cerrno>
 #include <cstdint>
 #include <cstring>
+#include <ctime>
 #include <fcntl.h>
 #include <pthread.h>
 #include <sys/mman.h>
@@ -187,7 +188,21 @@ void pin_sub(IndexSlot* s, uint32_t pid, uint32_t n) {
 }
 
 void lock(Handle* h) {
-  int rc = pthread_mutex_lock(&h->hdr->mutex);
+  // Never sleep on the mutex without a limit. The unlocker wakes one
+  // waiter; if that waiter is SIGKILLed before it takes the lock and the
+  // word changed hands meanwhile, the kernel's robust-list walk hands the
+  // wake-up to nobody, and the next waiter sleeps on a free mutex until
+  // somebody else contends: for ever, once the others have left (seen
+  // under load, one run of 25: word 0, the sleeper in futex_do_wait).
+  // A timed wait looks at the word again; a free mutex costs no clock.
+  int rc = pthread_mutex_trylock(&h->hdr->mutex);
+  while (rc == EBUSY || rc == ETIMEDOUT) {
+    struct timespec ts;
+    clock_gettime(CLOCK_REALTIME, &ts);
+    ts.tv_nsec += 20 * 1000 * 1000;
+    if (ts.tv_nsec >= 1000000000L) { ts.tv_sec += 1; ts.tv_nsec -= 1000000000L; }
+    rc = pthread_mutex_timedlock(&h->hdr->mutex, &ts);
+  }
   if (rc == EOWNERDEAD) {
     // A client died holding the lock, possibly mid-way through a
     // free-list/tag mutation. Recover the mutex but poison the allocator:

@@ -22,6 +22,7 @@ across processes stay wire-compatible.
 from __future__ import annotations
 
 import collections
+import os
 import socket
 import threading
 import time
@@ -98,6 +99,7 @@ class BatchedConnection:
         self._err: BaseException | None = None
         self._closed = False
         self._flushing = False   # a popped batch is still on the wire
+        self._reader = None      # ident of the one thread that calls recv()
         if self._coalesce:
             threading.Thread(target=self._flush_loop, daemon=True,
                              name="netaddr-flush").start()
@@ -105,8 +107,10 @@ class BatchedConnection:
     # ---- send side --------------------------------------------------------
 
     def send(self, msg) -> None:
+        """Thread-safe; callers hold no lock of their own across it."""
         if not self._coalesce:
-            self._raw.send(msg)
+            with self._wire_lock:
+                self._raw.send(msg)
             return
         direct = False
         with self._qcv:
@@ -126,7 +130,11 @@ class BatchedConnection:
             # flusher's wire->queue order, and FIFO holds: the wire
             # lock is taken while the queue is provably empty, so no
             # earlier logical message can be written after this one.
+            # Not for this channel's reader thread: a write can wait for
+            # the peer to read, and a peer in the mirror state waits for
+            # THIS thread to read; queued, the flusher waits in its place.
             if (not self._out and not self._flushing
+                    and threading.get_ident() != self._reader
                     and self._wire_lock.acquire(blocking=False)):
                 direct = True
             else:
@@ -225,6 +233,7 @@ class BatchedConnection:
     def recv(self):
         if self._in:
             return self._in.popleft()
+        self._reader = threading.get_ident()
         msg = self._raw.recv()
         if type(msg) is _Batch:
             self._in.extend(msg.msgs)
@@ -262,6 +271,24 @@ class BatchedConnection:
 
     def __getattr__(self, name):
         return getattr(self._raw, name)
+
+
+def hang_up(conn) -> None:
+    """Shut a channel's socket down in both directions without waiting
+    for the peer: its reader sees EOF, and a sender blocked on a pipe
+    the peer no longer drains (here or there) gets EPIPE. The reader
+    thread of `conn` still owns the close; None (a worker that has not
+    registered yet) is nothing to hang up."""
+    try:
+        s = socket.socket(fileno=os.dup(conn.fileno()))
+    except (OSError, ValueError, AttributeError):
+        return                     # never opened, or closed already
+    try:
+        s.shutdown(socket.SHUT_RDWR)
+    except OSError:
+        pass
+    finally:
+        s.close()
 
 
 class _BatchingListener:
@@ -321,7 +348,6 @@ def local_endpoint_host(conn) -> str | None:
     """The local IP of an established TCP connection — exactly the
     interface that routes to the remote side, so it's the right host for
     this machine to advertise back to it."""
-    import os
     try:
         fd = os.dup(conn.fileno())
         s = socket.socket(fileno=fd)

@@ -189,7 +189,6 @@ class _WorkerConn:
     idle: bool = True
     current: _TaskState | None = None
     known_functions: set = field(default_factory=set)
-    send_lock: threading.Lock = field(default_factory=threading.Lock)
     # resources temporarily released while the worker blocks in get()
     released: dict = field(default_factory=dict)
     alive: bool = True
@@ -209,8 +208,20 @@ class _WorkerConn:
     sub_nacked: bool = False
 
     def send(self, msg) -> bool:
-        # conn is None between spawn and registration
-        return protocol.safe_send(self.conn, self.send_lock, msg)
+        """False on a dead or absent peer. No lock around the channel's own
+        (`BatchedConnection.send` is thread-safe): held across a write that
+        waits for the worker to read, it kept this worker's reader thread,
+        which sends credits and replies, from reading what the worker was
+        itself blocked writing (`test_gbdt_trainer_multiworker_parity`
+        stood so)."""
+        conn = self.conn        # None between spawn and registration
+        if conn is None:
+            return False
+        try:
+            conn.send(msg)
+            return True
+        except (OSError, ValueError):
+            return False
 
 
 @dataclass
@@ -946,11 +957,16 @@ class NodeServer:
         self._reader_loop(w)
 
     def _reader_loop(self, w: _WorkerConn):
+        conn = w.conn
         while True:
             try:
-                msg = w.conn.recv()
+                msg = conn.recv()
             except (EOFError, OSError, TypeError):
                 self._on_worker_death(w)
+                try:
+                    conn.close()    # ends the channel's flusher thread too
+                except OSError:
+                    pass
                 return
             try:
                 self._handle(w, msg)
@@ -3583,6 +3599,8 @@ class NodeServer:
 
     def _on_worker_death(self, w: _WorkerConn):
         with self.lock:
+            if self._shutdown:
+                return      # nothing to recover, and the store is closing
             if w.kind == "attach":
                 # external CLI/monitoring connection: reap the entry, no
                 # task/actor state to recover
@@ -3883,12 +3901,22 @@ class NodeServer:
     # ------------------------------------------------------------------
 
     def shutdown(self):
-        with self.lock:
-            if self._shutdown:
-                return
-            self._shutdown = True
-            workers = list(self.workers.values())
-            nodes = list(self.nodes.values())
+        """End the session in a time bounded by constants, whatever it
+        still holds. No result has a reader any more, so a worker does not
+        get to finish: its channel is hung up, its reader sees EOF and the
+        process leaves at once (`exit_on_disconnect`), also from inside an
+        actor method; `spawn.stop_procs` signals what stays. No step waits
+        on a peer or on a lock without a limit."""
+        from ray_tpu._private import spawn
+        # a thread stuck under the lock must not hold the teardown
+        locked = self.lock.acquire(timeout=2.0)
+        already, self._shutdown = self._shutdown, True
+        workers = list(self.workers.values())
+        nodes = list(self.nodes.values())
+        if locked:
+            self.lock.release()
+        if already:
+            return
         try:
             self._usage_reporter.stop()
         except AttributeError:
@@ -3896,18 +3924,15 @@ class NodeServer:
         self._sched_event.set()   # release the scheduler thread
         for node in nodes:
             node.alive = False
-            node.send(protocol.KillNode())
-        for w in workers:
-            w.send(protocol.KillWorker())
-        for node in nodes:
-            if node.proc is not None:
-                try:
-                    node.proc.wait(2.0)
-                except Exception:
-                    try:
-                        node.proc.kill()
-                    except OSError:
-                        pass
+        # a daemon takes EOF for a head that restarts, so it is told: from
+        # a thread, since it may no longer read and its pipe may be full
+        told = threading.Thread(
+            target=lambda: [n.send(protocol.KillNode()) for n in nodes],
+            daemon=True)
+        told.start()
+        told.join(1.0)
+        for peer in workers + nodes:
+            netaddr.hang_up(peer.conn)
         for lst in (self._listener, self._tcp_listener):
             if lst is None:
                 continue
@@ -3915,21 +3940,7 @@ class NodeServer:
                 lst.close()
             except OSError:
                 pass
-        deadline = time.monotonic() + 3.0
-        for w in workers:
-            if w.proc is None:
-                continue
-            try:
-                while w.proc.poll() is None and time.monotonic() < deadline:
-                    time.sleep(0.02)
-                if w.proc.poll() is None:
-                    w.proc.terminate()
-                    try:
-                        w.proc.wait(1.0)
-                    except Exception:
-                        w.proc.kill()
-            except OSError:
-                pass
+        spawn.stop_procs([p.proc for p in workers + nodes])
         self.store.purge_spill()
         for node in nodes:
             # SIGKILLed daemons can't purge their own spill dirs

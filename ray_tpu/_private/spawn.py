@@ -107,9 +107,9 @@ def worker_log_file(log_dir: str | None, name: str):
 
 class ForkedProc:
     """Popen-compatible handle for a worker forked by the forkserver.
-    The factory reaps the child on SIGCHLD, so the bare pid is
-    recyclable the moment the child dies — every probe and signal is
-    therefore guarded by the start-ticks identity recorded at fork
+    The factory ignores SIGCHLD and the kernel reaps the child, so the
+    bare pid is recyclable the moment the child dies — every probe and
+    signal is therefore guarded by the start-ticks identity recorded at fork
     (signal-0 alone would report a recycled pid as alive forever and
     kill() could SIGKILL an unrelated process)."""
 
@@ -157,6 +157,31 @@ class ForkedProc:
         self._signal(_signal.SIGKILL)
 
 
+def stop_procs(procs, grace: float = 2.0) -> None:
+    """End every process in `procs` (Popen or ForkedProc) in at most
+    three waits of `grace` seconds, whatever their number: one wait for
+    all that were already asked to leave, SIGTERM to every survivor at
+    once and one wait, SIGKILL to what is left and one wait to reap.
+    libtpu's SIGTERM handler takes a second to re-raise, so a wait a
+    process would cost a session a second a worker."""
+    def wait_all(left):
+        deadline = _time.monotonic() + grace
+        while True:
+            left = [p for p in left if p.poll() is None]
+            if not left or _time.monotonic() >= deadline:
+                return left
+            _time.sleep(0.02)
+
+    left = wait_all([p for p in procs if p is not None])
+    for signal_all in ("terminate", "kill"):
+        for p in left:
+            try:
+                getattr(p, signal_all)()
+            except OSError:
+                pass
+        left = wait_all(left)
+
+
 class _ForkServerClient:
     """Lazy per-process handle on a forkserver child (forkserver.py).
     Thread-safe: requests are serialized over one connection."""
@@ -193,7 +218,7 @@ class _ForkServerClient:
             # drains would block a chatty worker at ~64KB
             self._proc = subprocess.Popen(
                 [sys.executable, "-m", "ray_tpu._private.forkserver",
-                 sock],
+                 sock, str(os.getpid())],
                 env=env, stdin=subprocess.DEVNULL)
             deadline = _time.monotonic() + 30.0
             while True:

@@ -276,6 +276,14 @@ class ServeController:
             self._graveyard.clear()
         for replicas in doomed:
             self._kill_replicas(replicas)
+        # A reconcile pass in flight finds its deployment gone and parks
+        # what it was starting in the graveyard, which no later pass will
+        # empty: wait for it, or that replica outlives the controller.
+        self._thread.join(timeout=30)
+        with self._lock:
+            parked, self._graveyard = self._graveyard, []
+        for replicas in parked:
+            self._kill_replicas(replicas)
         return True
 
     def ping(self) -> bool:

@@ -319,6 +319,13 @@ class GBDTTrainer(JaxTrainer):
             scaling_config=scaling_config or ScalingConfig(),
             run_config=run_config, datasets=datasets)
 
+    def fit(self):
+        from ray_tpu.util.collective import destroy_collective_group
+        try:
+            return super().fit()
+        finally:
+            destroy_collective_group(self.config["group_name"])
+
 
 def _lib_train_loop(config: dict):
     """XGBoost / LightGBM fit on the worker group (v1: each library's

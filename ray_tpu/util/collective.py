@@ -238,6 +238,18 @@ def init_collective_group(world_size: int, rank: int,
     return g
 
 
+def destroy_collective_group(group_name: str = "default") -> None:
+    """Forget a group and end its rendezvous actor (reference:
+    `collective.destroy_collective_group`); from one process, once every
+    rank is done. Left alone the actor holds its worker process until the
+    session ends."""
+    getattr(_local, "groups", {}).pop(group_name, None)
+    try:
+        ray_tpu.kill(ray_tpu.get_actor(f"_rtpu_collective:{group_name}"))
+    except ValueError:
+        pass        # never made, or destroyed already
+
+
 def _group(group_name: str) -> CollectiveGroup:
     groups = getattr(_local, "groups", {})
     if group_name not in groups:

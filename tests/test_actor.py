@@ -9,6 +9,9 @@ import ray_tpu
 from ray_tpu.actor import wait_for_actor_ready
 from ray_tpu.exceptions import ActorDiedError
 
+# these tests make actors and drop the handles
+pytestmark = pytest.mark.usefixtures("kills_its_actors")
+
 
 @ray_tpu.remote
 class Counter:

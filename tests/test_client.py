@@ -72,6 +72,7 @@ def test_client_driver_full_api(ray_session):
                 return self.n
         c = Counter.remote()
         assert ray_tpu.get(c.inc.remote(), timeout=120) == 1
+        ray_tpu.kill(c)
 
         # cluster state visible
         assert ray_tpu.cluster_resources().get("CPU", 0) > 0

@@ -2,8 +2,10 @@
 `python/ray/util/collective/tests/`)."""
 
 import numpy as np
+import pytest
 
 import ray_tpu
+from ray_tpu.util import collective as col
 
 
 def _rank_fn(rank, world):
@@ -26,6 +28,10 @@ def test_collective_allreduce_allgather_broadcast(ray_session):
         np.testing.assert_allclose(out, np.full(4, expect_sum))
         assert gathered == [0, 1, 2]
         assert bcast == 20.0
+    col.destroy_collective_group("g1")
+    with pytest.raises(ValueError):     # its rendezvous actor went with it
+        ray_tpu.get_actor("_rtpu_collective:g1")
+    col.destroy_collective_group("g1")  # and once more is no error
 
 
 def test_collective_send_recv(ray_session):
@@ -44,6 +50,7 @@ def test_collective_send_recv(ray_session):
     r = ray_tpu.remote(receiver).remote()
     assert ray_tpu.get(r, timeout=120) == 7.0
     assert ray_tpu.get(s, timeout=120)
+    col.destroy_collective_group("p2p")
 
 
 def test_named_group_create_race_converges(ray_session):
@@ -62,19 +69,17 @@ def test_named_group_create_race_converges(ray_session):
     refs = [fn.remote(r, world) for r in range(world)]
     ids = ray_tpu.get(refs, timeout=120)
     assert len(set(ids)) == 1, ids
+    col.destroy_collective_group("race")
 
 
 def test_collective_refuses_big_tensors(ray_session):
     """The host-side group is a control-plane funnel (one rendezvous
     actor); model-state-sized payloads must be refused with a pointer at
     the in-graph path, not silently bottlenecked."""
-    import numpy as np
-    import pytest
-
     from ray_tpu.exceptions import RayTpuError
-    from ray_tpu.util.collective import CollectiveGroup
 
-    g = CollectiveGroup("cap_test", world_size=1, rank=0)
+    g = col.CollectiveGroup("cap_test", world_size=1, rank=0)
     assert g.allreduce(np.ones(8)).sum() == 8.0          # small: fine
     with pytest.raises(RayTpuError, match="in-graph"):
         g.allreduce(np.zeros(80 << 20, np.uint8))        # 80MB: refused
+    col.destroy_collective_group("cap_test")

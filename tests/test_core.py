@@ -345,3 +345,99 @@ def test_blocked_worker_does_not_pin_pool_cap():
     assert r.returncode == 0, r.stdout + "\n" + r.stderr
     assert "RESULT 3" in r.stdout
     assert "RESULT2 1" in r.stdout
+
+
+def test_shutdown_is_bounded_by_a_constant_not_by_what_is_left():
+    """`ray_tpu.shutdown()` ends a session of sixteen actors in under 8 s
+    and leaves none of their processes: four were killed before (the
+    factory's zombies each cost the old teardown a second), one is inside a
+    method that sleeps 120 s, one more ignores SIGTERM in such a method,
+    one is stopped (it reads its connection no more and takes no SIGTERM).
+    The old per-worker terminate / wait(1.0) / kill loop needed 16 s and
+    more."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    child = textwrap.dedent("""
+        import os, signal, time
+        import ray_tpu
+        ray_tpu.init(num_cpus=4)
+
+        @ray_tpu.remote(num_cpus=0)
+        class A:
+            def pid(self):
+                return os.getpid()
+            def sleep(self, deaf):
+                if deaf:
+                    signal.signal(signal.SIGTERM, signal.SIG_IGN)
+                time.sleep(120)
+
+        actors = [A.remote() for _ in range(16)]
+        pids = ray_tpu.get([a.pid.remote() for a in actors], timeout=120)
+        for a in actors[3:7]:
+            ray_tpu.kill(a)
+        actors[0].sleep.remote(False)
+        actors[1].sleep.remote(True)
+        os.kill(pids[2], signal.SIGSTOP)
+        time.sleep(1.0)
+        t0 = time.monotonic()
+        ray_tpu.shutdown()
+        print("SHUTDOWN_S", time.monotonic() - t0)
+        def runs(pid):
+            try:
+                with open(f"/proc/{pid}/stat") as f:
+                    return f.read().rsplit(")", 1)[1].split()[0] != "Z"
+            except OSError:
+                return False
+        print("LEFT", [p for p in pids if runs(p)])
+    """)
+    env = dict(os.environ)
+    env.pop("XLA_FLAGS", None)
+    r = subprocess.run([sys.executable, "-c", child], env=env,
+                       capture_output=True, text=True, timeout=240)
+    assert r.returncode == 0, r.stdout + "\n" + r.stderr
+    took = float(r.stdout.split("SHUTDOWN_S")[1].split()[0])
+    assert took < 8.0, r.stdout
+    assert "LEFT []" in r.stdout, r.stdout
+
+
+def test_a_factory_whose_spawner_died_at_once_does_not_stay():
+    """The worker factory watches the pid its spawner gave it. It used to
+    ask `os.getppid()` once its imports were done: a driver that had died
+    by then had already handed it to another parent, and the factory
+    (with the stdio it inherited) lived on; two were left by a whole
+    tier-1 run."""
+    import os
+    import subprocess
+    import sys
+    import textwrap
+
+    child = textwrap.dedent("""
+        import os, threading, time
+        from ray_tpu._private import spawn
+        threading.Thread(target=spawn._forkserver._ensure,
+                         args=(b"k" * 16,), daemon=True).start()
+        time.sleep(0.15)        # the factory is still importing
+        print(os.getpid(), flush=True)
+        os._exit(0)
+    """)
+    r = subprocess.run([sys.executable, "-c", child], text=True,
+                       stdout=subprocess.PIPE, stderr=subprocess.DEVNULL,
+                       timeout=60)
+    sock = f"ray_tpu_fs_{int(r.stdout)}.sock"
+    deadline = time.monotonic() + 20
+    while time.monotonic() < deadline:
+        left = []
+        for pid in filter(str.isdigit, os.listdir("/proc")):
+            try:
+                with open(f"/proc/{pid}/cmdline", "rb") as f:
+                    if sock.encode() in f.read():
+                        left.append(pid)
+            except OSError:
+                pass
+        if not left:
+            return
+        time.sleep(0.2)
+    raise AssertionError(f"factory of a dead spawner still runs: {left}")

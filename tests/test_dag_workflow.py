@@ -66,7 +66,7 @@ def test_input_attribute_access(cluster):
     assert ray_tpu.get(dag.execute({"x": 9, "y": 4})) == 5
 
 
-def test_class_node_and_methods(cluster):
+def test_class_node_and_methods(cluster, kills_its_actors):
     @ray_tpu.remote
     class Accum:
         def __init__(self, start):

@@ -204,3 +204,39 @@ def test_batched_frame_faults_stay_per_logical_message(tmp_path):
         client.close()
         server.close()
         lst.close()
+
+
+def test_two_readers_that_answer_each_other_never_stop_reading(conn_pair):
+    """Each end's reader thread answers every message with one of 64 KiB,
+    and both ends start with a burst that fills the socket's buffers. A
+    reader that wrote to the wire itself would wait for its peer to read
+    while the peer, in the mirror state, waits for it (a tier-1 run stood
+    so for 270 s in `test_gbdt_trainer_multiworker_parity`: the head's
+    reader in `_on_pipelined_submit`, the worker's in its own send). The
+    channel hands its reader's sends to the flusher instead."""
+    blob, rounds = b"x" * (64 << 10), 60
+    got = {}
+
+    def reader(name, conn):
+        n = 0
+        while n < 2 * rounds:       # the peer's burst and its answers to ours
+            conn.recv()
+            n += 1
+            if n <= rounds:
+                conn.send(blob)
+        got[name] = n
+
+    ends = dict(zip(("client", "server"), conn_pair))
+    threads = [threading.Thread(target=reader, args=end, daemon=True)
+               for end in ends.items()]
+    for t in threads:
+        t.start()
+    for conn in ends.values():      # 60 x 64 KiB each way, nobody blocks here
+        burst = threading.Thread(
+            target=lambda c=conn: [c.send(blob) for _ in range(rounds)],
+            daemon=True)
+        burst.start()
+        threads.append(burst)
+    for t in threads:
+        t.join(timeout=30)
+    assert got == {"client": 2 * rounds, "server": 2 * rounds}

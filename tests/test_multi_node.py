@@ -398,3 +398,4 @@ class TestNodeFailure:
         # max_task_retries lets a call that raced the death be retried
         host = ray_tpu.get(svc.host.remote(), timeout=120)
         assert host == "head"
+        ray_tpu.kill(svc)       # it holds a CPU of the shared session

@@ -73,6 +73,7 @@ def test_list_actors_and_workers(cluster):
     assert isinstance(objs, list)
     nodes = state.list_nodes()
     assert nodes and nodes[0]["resources_total"].get("CPU", 0) > 0
+    ray_tpu.kill(a)
 
 
 def test_summary_and_timeline(cluster, tmp_path):

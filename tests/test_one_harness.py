@@ -95,3 +95,14 @@ def test_documented_commands_name_what_exists():
                 if phase not in phases:
                     wrong.append(f"{doc}: chip_smoke.py has no phase {phase}")
     assert not wrong, wrong
+
+
+# What a serving cell's `correct` compares, held to the mix in tier-1 too
+# (ISSUE 48): the benchmark's own cases, run from here as they stand.
+from benchmarks.tests.test_check_sample import (  # noqa: E402,F401
+    test_a_chosen_request_short_of_its_tokens_is_a_problem_not_a_resample,
+    test_the_generator_draws_what_it_drew_before_it_kept_the_index,
+    test_the_plan_is_the_mix_s_alone,
+    test_the_run_waits_for_a_compared_request_and_no_longer,
+    test_the_sample_is_the_same_whatever_finished,
+)

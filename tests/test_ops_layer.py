@@ -80,6 +80,9 @@ def test_dashboard_healthz_and_state(cluster, dashboard_port):
                   "/api/summary", "/api/jobs", "/api/logs",
                   "/api/serve/applications", "/api/metrics_snapshot"):
         _get(dashboard_port, route)
+    # the status route brings the Serve controller up
+    from ray_tpu import serve
+    serve.shutdown()
 
 
 def test_job_submit_success_and_logs(cluster):

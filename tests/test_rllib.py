@@ -192,8 +192,11 @@ def test_ppo_pendulum_continuous_runs():
     assert np.isfinite(r["policy_loss"])
 
 
-def test_algorithm_checkpoint_roundtrip(tmp_path):
+def test_algorithm_checkpoint_roundtrip(tmp_path, monkeypatch):
     from ray_tpu.rllib.algorithms.ppo import PPOConfig
+    # a Trainable built without a trial_dir saves under the working
+    # directory: `checkpoint_000001/` belongs in tmp_path, not the checkout
+    monkeypatch.chdir(tmp_path)
     algo = (PPOConfig().environment("CartPole-v1")
             .rollouts(num_envs_per_worker=2, rollout_fragment_length=16)
             .build())

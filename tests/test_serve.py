@@ -299,7 +299,7 @@ def test_proxy_concurrent_requests(serve_session):
     assert elapsed < 2.4, f"proxy serialized requests: {elapsed:.2f}s"
 
 
-def test_async_replica_soak_1k_concurrent(ray_session):
+def test_async_replica_soak_1k_concurrent(serve_session):
     """1000 concurrent slow requests overlap on ONE replica's event loop
     (reference: serve's async replica, `serve/_private/replica.py:429`).
     Thread-per-call would need 1000 threads; serialized execution would
