@@ -42,6 +42,27 @@ class ServingFamily(NamedTuple):
     verify(params, tokens [B, W], pool, pos, tables, cfg, mesh)
             -> (logits [B, W, V] f32, pool): speculative decoding; a
             family without it cannot be given `spec=`
+    tick(params, chunk_tokens [1, C], step_tokens [B], pool, pos, tables,
+         cfg, mesh, *, block_table, start, length)
+            -> (chunk logits [1, V] f32, step logits [B, V] f32, pool,
+            counts): `decode`'s step and `prefill`'s chunk as one
+            program, for a family whose weights' read is what a program
+            costs: each weight is read once for the rows of both. The
+            engine calls it in a tick that holds decoders and a chunk
+            that does not end its prompt; the chunk's sequence is then
+            none of the step's (its slot's row is idle), so the two
+            write disjoint blocks and pages and either order of them is
+            the two programs' result. The chunk's logits are for a
+            caller that reads them (a chunk that ends its prompt, which
+            the engine does not fuse yet): a program that drops them
+            pays nothing for them. The kernels it calls carry names
+            of their own: the benchmark's readers sum a kernel's seconds
+            by name over a whole trace and divide by the runs of one
+            program, so a kernel under the name it has in `prefill` or
+            `decode` would be counted into their metrics. `counts` is
+            the two programs' summed, where a count is of rows; a count
+            of the program's calls is its own. A family without it has
+            its chunk and its step enqueued as two programs
     load(params, cfg) -> params: the family's one load-time function,
             published masters in, the tree that prefill, decode and
             verify read out: every leaf in the dtype the steps would
@@ -108,6 +129,7 @@ class ServingFamily(NamedTuple):
     gather_block: Callable
     scatter_block: Callable
     verify: Callable | None = None
+    tick: Callable | None = None
     load: Callable | None = None
     counts: Callable | None = None
     state_blocks: int = 0

@@ -52,12 +52,15 @@ positions whatever the bucket's padding and writes the padding's rows
 nowhere; decode's idle rows (table all 0) rewrite the trash blocks.
 
 Parameters: the tree `benchmarks/refs/shortconv_moe.py` documents.
-`forward` is the whole-sequence form for tests; `prefill` and `decode`
-are what `ServingFamily` asks.
+`forward` is the whole-sequence form for tests; `prefill`, `decode` and
+`tick` (a step and another sequence's chunk as one program, each weight
+read once) are what `ServingFamily` asks, one layer loop (`_layers`) over
+the rows of a chunk, of a step or of both.
 """
 
 from __future__ import annotations
 
+import itertools
 from dataclasses import dataclass
 
 import jax
@@ -427,6 +430,164 @@ def forward(params, tokens, cfg: ShortConvMoEConfig):
 # what the engine calls
 # ---------------------------------------------------------------------------
 
+class _Rows:
+    """A set of rows on its way through the layers, opened inside `embed`
+    (its blocks' tails, every layer's, read once), `mix`ed a layer at a
+    time and closed inside `head` (every layer's new tail written once).
+    A subclass says what a convolution layer and an attention layer are
+    for its rows; `kernel` names the attention's."""
+
+    def __init__(self, cfg, kernel: str, x, positions, live, tails):
+        self.cfg, self.kernel = cfg, kernel
+        self.x, self.positions, self.live, self.tails = (x, positions, live,
+                                                         tails)
+        self.kept = []
+
+    def mix(self, lp, mixer, cache, n_conv, n_attn):
+        """A layer's mixer on these rows and its residual; an attention
+        layer writes the rows' keys and values into `cache` first."""
+        cfg = self.cfg
+        n = rms_norm(self.x, lp["operator_norm_scale"], cfg.eps)
+        if mixer == "conv":
+            out, tail = self.conv(n, lp, self.tails[n_conv])
+            self.kept.append(tail)
+        else:
+            q, k, v = _qkv(n, lp, self.positions, cfg)
+            for key, rows in (("k", k), ("v", v)):
+                cache[key] = self.write(
+                    cache[key], n_attn,
+                    da.heads_side_by_side(rows, cfg.kv_pack))
+            att = self.attend(q, cache["k"], cache["v"], n_attn)
+            out = mm(att.reshape(n.shape[0], -1), lp["w_out"],
+                     cfg.activation_dtype())
+        self.x = self.x + out
+
+    def attention_rows(self):
+        """Cached rows the attention layers read for the live rows."""
+        return jnp.sum(jnp.where(self.live, self.positions + 1, 0)) * (
+            self.cfg.n_layers - self.cfg.n_conv)
+
+
+class _ChunkRows(_Rows):
+    """One sequence's prompt chunk: tokens [1, C] at positions start ..
+    start + length - 1; `block_table[0]` the sequence's state block, the
+    rest its pages. A chunk that starts the sequence reads its tails as
+    zeros."""
+
+    def __init__(self, params, tokens, cache, cfg, block_table, start,
+                 length, kernel: str):
+        c = tokens.shape[1]
+        if tokens.shape[0] != 1:
+            raise ValueError(f"a chunk is tokens [1, C], got batch "
+                             f"{tokens.shape[0]}")
+        self.start = jnp.asarray(start, jnp.int32)
+        self.length = jnp.asarray(c if length is None else length, jnp.int32)
+        table = jnp.asarray(block_table, jnp.int32)
+        self.block, self.pages = table[0], table[1:]
+        self.first = self.start == 0
+        offs = jnp.arange(c, dtype=jnp.int32)
+        super().__init__(
+            cfg, kernel, params["embed"].astype(
+                cfg.activation_dtype())[tokens[0]],
+            self.start + offs, offs < self.length,
+            jax.lax.dynamic_index_in_dim(cache["tail"], self.block, 1, False))
+
+    def conv(self, n, lp, tail):
+        with jax.named_scope("short_conv_chunk"):
+            return conv_chunk(n, lp, tail, self.cfg, self.first, self.length)
+
+    def write(self, pool, layer, rows):
+        return write_chunk(pool, layer, rows, self.pages, self.start,
+                           self.length)
+
+    def attend(self, q, k_pool, v_pool, layer):
+        return da.gqa_attention(
+            self.kernel, q[None], k_pool, v_pool, self.pages[None],
+            self.start.reshape(1), layer=layer, impl=self.cfg.attn_impl)[0]
+
+    def close(self, params, cache):
+        """-> (the last live position's final-normed row [1, D], what the
+        chunk counted: `COUNTS`' first four)."""
+        c, n_conv = self.x.shape[0], self.cfg.n_conv
+        x = rms_norm(self.x, params["final_norm_scale"], self.cfg.eps)
+        last = jnp.take_along_axis(x, (self.length - 1)[None, None], axis=0)
+        rows = self.attention_rows()
+        if self.kept:
+            cache["tail"] = jax.lax.dynamic_update_slice_in_dim(
+                cache["tail"], jnp.stack(self.kept)[:, None], self.block, 1)
+        return last, [self.length * n_conv, (c - self.length) * n_conv,
+                      self.first, rows]
+
+
+class _StepRows(_Rows):
+    """One decode position for every slot: tokens [B] at positions pos
+    [B]; `tables[:, 0]` each row's state block, the rest its pages. Idle
+    rows name the trash blocks of both kinds, rewrite them (one scatter a
+    step: they all rewrite block 0) and count nothing."""
+
+    def __init__(self, params, tokens, cache, pos, tables, cfg, kernel: str):
+        pos = pos.astype(jnp.int32)
+        tables = tables.astype(jnp.int32)
+        self.blocks, self.pages = tables[:, 0], tables[:, 1:]
+        self.widx = row_index(self.pages, pos, cache["k"])
+        super().__init__(
+            cfg, kernel, params["embed"].astype(
+                cfg.activation_dtype())[tokens],
+            pos, self.blocks > 0,
+            cache["tail"][:, self.blocks])      # [L_conv, B, K - 1, D]
+
+    def conv(self, n, lp, tails):
+        with jax.named_scope("short_conv_step"):
+            return conv_step(n, lp, tails, self.cfg)
+
+    def write(self, pool, layer, rows):
+        return write_rows(pool, layer, rows, self.widx)
+
+    def attend(self, q, k_pool, v_pool, layer):
+        return da.gqa_attention(
+            self.kernel, q[:, None], k_pool, v_pool, self.pages,
+            self.positions, layer=layer, impl=self.cfg.attn_impl)[:, 0]
+
+    def close(self, params, cache):
+        """-> (every row final-normed [B, D], `COUNTS`' first four)."""
+        b, n_conv = self.x.shape[0], self.cfg.n_conv
+        x = rms_norm(self.x, params["final_norm_scale"], self.cfg.eps)
+        n_live = jnp.sum(self.live, dtype=jnp.int32)
+        rows = self.attention_rows()
+        if self.kept:
+            cache["tail"] = cache["tail"].at[:, self.blocks].set(
+                jnp.stack(self.kept))
+        return x, [n_live * n_conv, (b - n_live) * n_conv, jnp.int32(0),
+                   rows]
+
+
+def _layers(params, cache, cfg, sets, kernel):
+    """The layer loop of every program, over one set of rows or two: a
+    layer's mixer on each set as its own (different sequences: they write
+    disjoint blocks and pages of `cache`, a dict updated in place), its
+    feed-forward half once on all the rows laid one after the other,
+    through the expert kernel named `kernel`, so that two sets share one
+    read of the layer's weights. -> the sparse layers' counts."""
+    cuts = list(itertools.accumulate(s.x.shape[0] for s in sets))[:-1]
+    n_conv = n_attn = 0
+    expert_counts = []
+    for lp, (mixer, ffn) in zip(params["layers"], cfg.kinds):
+        with jax.named_scope(MIXER):
+            for s in sets:
+                s.mix(lp, mixer, cache, n_conv, n_attn)
+            n_conv += mixer == "conv"
+            n_attn += mixer != "conv"
+        with jax.named_scope(FFN):
+            x = jnp.concatenate([s.x for s in sets])
+            ff, counts = _ffn(x, lp, ffn, cfg,
+                              jnp.concatenate([s.live for s in sets]), kernel)
+            if counts is not None:
+                expert_counts.append(counts)
+            for s, part in zip(sets, jnp.split(x + ff, cuts)):
+                s.x = part
+    return expert_counts
+
+
 def prefill(params, tokens, cache, cfg: ShortConvMoEConfig, mesh=None, *,
             block_table, start, length=None):
     """One chunk of one sequence (`gpt.prefill_paged`'s contract): tokens
@@ -434,66 +595,16 @@ def prefill(params, tokens, cache, cfg: ShortConvMoEConfig, mesh=None, *,
     sequence's state block, the rest its pages. A chunk that starts the
     sequence reads its tails as zeros. -> (logits [1, V] f32 of the
     chunk's last real position, cache, counts)."""
-    c = tokens.shape[1]
-    if tokens.shape[0] != 1:
-        raise ValueError(f"prefill wants tokens [1, C], got batch "
-                         f"{tokens.shape[0]}")
-    adt = cfg.activation_dtype()
     cache = dict(cache)
-    pack = cfg.kv_pack
     with jax.named_scope(EMBED):
-        start = jnp.asarray(start, jnp.int32)
-        length = jnp.asarray(c if length is None else length, jnp.int32)
-        table = jnp.asarray(block_table, jnp.int32)
-        block, pages = table[0], table[1:]
-        first = start == 0
-        offs = jnp.arange(c, dtype=jnp.int32)
-        positions = start + offs
-        valid = offs < length
-        # the block's tails, every layer's, read once
-        tails = jax.lax.dynamic_index_in_dim(cache["tail"], block, 1, False)
-        x = params["embed"].astype(adt)[tokens[0]]
-    n_conv = n_attn = 0
-    kept, expert_counts = [], []
-    for lp, (mixer, ffn) in zip(params["layers"], cfg.kinds):
-        with jax.named_scope(MIXER):
-            n = rms_norm(x, lp["operator_norm_scale"], cfg.eps)
-            if mixer == "conv":
-                with jax.named_scope("short_conv_chunk"):
-                    out, tail = conv_chunk(n, lp, tails[n_conv], cfg, first,
-                                           length)
-                kept.append(tail)
-                x = x + out
-                n_conv += 1
-            else:
-                q, k, v = _qkv(n, lp, positions, cfg)
-                cache["k"] = write_chunk(
-                    cache["k"], n_attn, da.heads_side_by_side(k, pack), pages,
-                    start, length)
-                cache["v"] = write_chunk(
-                    cache["v"], n_attn, da.heads_side_by_side(v, pack), pages,
-                    start, length)
-                att = da.gqa_chunk_attention(
-                    q, cache["k"], cache["v"], pages, start, layer=n_attn,
-                    impl=cfg.attn_impl)
-                x = x + mm(att.reshape(c, -1), lp["w_out"], adt)
-                n_attn += 1
-        with jax.named_scope(FFN):
-            ff, counts = _ffn(x, lp, ffn, cfg, valid,
-                              grouped_experts.EXPERTS_GROUPED_PREFILL)
-            if counts is not None:
-                expert_counts.append(counts)
-            x = x + ff
+        chunk = _ChunkRows(params, tokens, cache, cfg, block_table, start,
+                           length, da.GQA_FULL_CHUNK)
+    expert_counts = _layers(params, cache, cfg, [chunk],
+                            grouped_experts.EXPERTS_GROUPED_PREFILL)
     with jax.named_scope(HEAD):
-        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
-        last = jnp.take_along_axis(x, (length - 1)[None, None], axis=0)
-        rows = jnp.sum(jnp.where(valid, positions + 1, 0)) * n_attn
-        if kept:        # every layer's new tail, written once
-            cache["tail"] = jax.lax.dynamic_update_slice_in_dim(
-                cache["tail"], jnp.stack(kept)[:, None], block, 1)
-        return (unembed(last, params["embed"], adt), cache,
-                _counts(cfg, [length * n_conv, (c - length) * n_conv, first,
-                              rows], expert_counts))
+        last, head = chunk.close(params, cache)
+        return (unembed(last, params["embed"], cfg.activation_dtype()),
+                cache, _counts(cfg, head, expert_counts))
 
 
 def decode(params, tokens, cache, pos, tables, cfg: ShortConvMoEConfig,
@@ -503,56 +614,46 @@ def decode(params, tokens, cache, pos, tables, cfg: ShortConvMoEConfig,
     block, the rest its pages. Idle rows name the trash blocks of both
     kinds, rewrite them and count nothing.
     -> (logits [B, V] f32, cache, counts)."""
-    adt = cfg.activation_dtype()
     cache = dict(cache)
-    b = tokens.shape[0]
-    pack = cfg.kv_pack
     with jax.named_scope(EMBED):
-        pos = pos.astype(jnp.int32)
-        tables = tables.astype(jnp.int32)
-        blocks, pages = tables[:, 0], tables[:, 1:]
-        live = blocks > 0
-        widx = row_index(pages, pos, cache["k"])
-        # every row's tails, every layer's, read once: [L_conv, B, K - 1, D]
-        tails = cache["tail"][:, blocks]
-        x = params["embed"].astype(adt)[tokens]
-    n_conv = n_attn = 0
-    kept, expert_counts = [], []
-    for lp, (mixer, ffn) in zip(params["layers"], cfg.kinds):
-        with jax.named_scope(MIXER):
-            n = rms_norm(x, lp["operator_norm_scale"], cfg.eps)
-            if mixer == "conv":
-                with jax.named_scope("short_conv_step"):
-                    out, tail = conv_step(n, lp, tails[n_conv], cfg)
-                kept.append(tail)
-                x = x + out
-                n_conv += 1
-            else:
-                q, k, v = _qkv(n, lp, pos, cfg)
-                cache["k"] = write_rows(
-                    cache["k"], n_attn, da.heads_side_by_side(k, pack), widx)
-                cache["v"] = write_rows(
-                    cache["v"], n_attn, da.heads_side_by_side(v, pack), widx)
-                att = da.gqa_decode_attention(
-                    q, cache["k"], cache["v"], pages, pos, layer=n_attn,
-                    impl=cfg.attn_impl)
-                x = x + mm(att.reshape(b, -1), lp["w_out"], adt)
-                n_attn += 1
-        with jax.named_scope(FFN):
-            ff, counts = _ffn(x, lp, ffn, cfg, live,
-                              grouped_experts.EXPERTS_GROUPED)
-            if counts is not None:
-                expert_counts.append(counts)
-            x = x + ff
+        step = _StepRows(params, tokens, cache, pos, tables, cfg,
+                         da.GQA_FULL_DECODE)
+    expert_counts = _layers(params, cache, cfg, [step],
+                            grouped_experts.EXPERTS_GROUPED)
     with jax.named_scope(HEAD):
-        x = rms_norm(x, params["final_norm_scale"], cfg.eps)
-        n_live = jnp.sum(live, dtype=jnp.int32)
-        rows = jnp.sum(jnp.where(live, pos + 1, 0)) * n_attn
-        if kept:        # one scatter a step: idle rows all rewrite block 0
-            cache["tail"] = cache["tail"].at[:, blocks].set(jnp.stack(kept))
-        return (unembed(x, params["embed"], adt), cache,
-                _counts(cfg, [n_live * n_conv, (b - n_live) * n_conv,
-                              jnp.int32(0), rows], expert_counts))
+        x, head = step.close(params, cache)
+        return (unembed(x, params["embed"], cfg.activation_dtype()), cache,
+                _counts(cfg, head, expert_counts))
+
+
+def tick(params, chunk_tokens, step_tokens, cache, pos, tables,
+         cfg: ShortConvMoEConfig, mesh=None, *, block_table, start, length):
+    """`ServingFamily.tick`: `decode`'s step and `prefill`'s chunk of
+    another sequence as one program, which reads every weight once: the
+    mixers as the two programs run them, each dense MLP, each sparse
+    layer's experts and the tied head once over the step's B rows and the
+    chunk's. The kernels run under names of their own
+    (`gqa_full_decode_tick`, `gqa_full_chunk_tick`, `experts_grouped_tick`).
+    -> (the chunk's logits [1, V] f32, the step's [B, V], cache, counts:
+    the two programs' summed, but the row tiles and the experts reached,
+    which are this program's one call's a layer)."""
+    cache = dict(cache)
+    with jax.named_scope(EMBED):
+        step = _StepRows(params, step_tokens, cache, pos, tables, cfg,
+                         da.GQA_FULL_DECODE_TICK)
+        chunk = _ChunkRows(params, chunk_tokens, cache, cfg, block_table,
+                           start, length, da.GQA_FULL_CHUNK_TICK)
+    expert_counts = _layers(params, cache, cfg, [step, chunk],
+                            grouped_experts.EXPERTS_GROUPED_TICK)
+    with jax.named_scope(HEAD):
+        rows, step_head = step.close(params, cache)
+        last, chunk_head = chunk.close(params, cache)
+        logits = unembed(jnp.concatenate([rows, last]), params["embed"],
+                         cfg.activation_dtype())
+        b = rows.shape[0]
+        return (logits[b:], logits[:b], cache,
+                _counts(cfg, [a + c for a, c in zip(step_head, chunk_head)],
+                        expert_counts))
 
 
 def _stats(cfg, totals) -> dict:
@@ -565,7 +666,7 @@ def _stats(cfg, totals) -> dict:
 
 
 FAMILY = ServingFamily(
-    init_pool=init_pool, prefill=prefill, decode=decode,
+    init_pool=init_pool, prefill=prefill, decode=decode, tick=tick,
     copy_block=copy_block, gather_block=gather_block,
     scatter_block=scatter_block, load=load, state_blocks=1,
     state_keys=STATE_KEYS,

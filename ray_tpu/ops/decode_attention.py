@@ -103,6 +103,11 @@ PAGED_DECODE, PAGED_MQ = "paged_decode", "paged_mq"
 # trace parts the four.
 GQA_WINDOW_DECODE, GQA_FULL_DECODE = "gqa_window_decode", "gqa_full_decode"
 GQA_WINDOW_CHUNK, GQA_FULL_CHUNK = "gqa_window_chunk", "gqa_full_chunk"
+# a full layer's two calls inside a program that holds a step and a chunk
+# (`ServingFamily.tick`): names of their own, since a reader divides a
+# name's seconds over the whole trace by the runs of one program
+GQA_FULL_DECODE_TICK = "gqa_full_decode_tick"
+GQA_FULL_CHUNK_TICK = "gqa_full_chunk_tick"
 
 
 # ---------------------------------------------------------------------------

@@ -63,9 +63,13 @@ from ray_tpu.ops.sparse_latent import resolve_impl
 # Kernel names in the compiled program and the profiler's trace; PERF.md,
 # section 3, lists them. The call sits in a `named_scope` of the same
 # name. A prefill chunk's call takes the second, so that a trace tells
-# the decode step's few tokens an expert from the chunk's many.
+# the decode step's few tokens an expert from the chunk's many; a call on
+# the rows of both (`ServingFamily.tick`) the third, so that the readers
+# of the first two, which divide a name's seconds by one program's runs,
+# keep reading the programs they name.
 EXPERTS_GROUPED = "experts_grouped"
 EXPERTS_GROUPED_PREFILL = "experts_grouped_prefill"
+EXPERTS_GROUPED_TICK = "experts_grouped_tick"
 EXPERTS_GROUPED_TRAIN = "experts_grouped_train"
 EXPERTS_GROUPED_DX = "experts_grouped_dx"
 EXPERTS_GROUPED_DW = "experts_grouped_dw"

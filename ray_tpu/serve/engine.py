@@ -106,6 +106,7 @@ condition (`_await`). Nothing waits for `_lock` to submit or to pop.
 from __future__ import annotations
 
 import collections
+import concurrent.futures
 import contextlib
 import functools
 import logging
@@ -575,6 +576,13 @@ class _StepInFlight:
 FROM_STEP, FROM_CHUNK = -1, -2
 
 
+def rows_size(slots: int, blocks: int, w: int = 1) -> int:
+    """Length of `pack_rows`' array: a row a slot of `w` tokens, the
+    position, the temperature and a table of `blocks`, then the step
+    counter."""
+    return slots * (w + 2 + blocks) + 1
+
+
 def pack_rows(tokens, pos, temps, tables, step) -> np.ndarray:
     """A decode, verify or propose step's input: a row a slot of
     ``[tokens (1 or W) | pos | temperature bits | block table]``, the
@@ -584,7 +592,7 @@ def pack_rows(tokens, pos, temps, tables, step) -> np.ndarray:
     slots = len(pos)
     tokens = tokens.reshape(slots, -1)
     w = tokens.shape[1]
-    packed = np.empty(slots * (w + 2 + tables.shape[1]) + 1, np.int32)
+    packed = np.empty(rows_size(slots, tables.shape[1], w), np.int32)
     rows = packed[:-1].reshape(slots, -1)
     rows[:, :w] = tokens
     rows[:, w] = pos
@@ -606,13 +614,19 @@ def unpack_rows(packed, slots: int, window: int | None = None):
     return tokens, rows[:, w], temps, rows[:, w + 2:], packed[-1]
 
 
+def chunk_size(cap: int, blocks: int) -> int:
+    """Length of `pack_chunk`'s array for the bucket `cap` and a table of
+    `blocks`."""
+    return cap + blocks + 4
+
+
 def pack_chunk(tokens, cap: int, table, start, temp, step) -> np.ndarray:
     """A prompt chunk's input: ``[tokens, zero-padded to the bucket
     `cap` | block table | start | length | temperature bits | step
     counter]``; `length` is `tokens`' own. One layout a chunk bucket, so
     the program compiles once a bucket."""
     length, blocks = len(tokens), len(table)
-    packed = np.zeros(cap + blocks + 4, np.int32)
+    packed = np.zeros(chunk_size(cap, blocks), np.int32)
     packed[:length] = tokens
     packed[cap:cap + blocks] = table
     packed[cap + blocks:] = (start, length,
@@ -629,6 +643,50 @@ def unpack_chunk(packed, max_blocks: int):
                                  for i in range(4))
     return (packed[None, :cap], packed[cap:cap + max_blocks], start,
             length, lax.bitcast_convert_type(temp, np.float32), step)
+
+
+def _compile_ahead(jitted, *args):
+    """`jitted` for a program that no request alone makes run, so that no
+    warm-up reaches it: traced, lowered and compiled for the shapes and
+    dtypes of `args` (and the shardings of those committed to one; an
+    array that is not is described without, as `jitted` called with it
+    would see it) in a thread started here, under the matmul precision,
+    default device and mesh of the thread that asks, which a thread of
+    its own would not inherit. -> the program as a callable: called with
+    arrays like `args` it waits for what is left of the compile, as a
+    jitted function's first call waits for its own, and runs; other
+    shapes raise where `jitted` would trace again. Its `compiled` is the
+    future of the executable. A compile that fails is logged when it does
+    and raised by every call. The thread is not a daemon (a process that
+    ends while XLA compiles waits for it) and ends with its one
+    compile."""
+    import jax
+    specs = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype,
+            sharding=a.sharding if getattr(a, "committed", False) else None),
+        args)
+    precision = jax.config.jax_default_matmul_precision
+    device = jax.config.jax_default_device
+    mesh = jax.sharding.get_mesh()
+    compiled = concurrent.futures.Future()
+
+    def work():
+        try:
+            with jax.default_matmul_precision(precision), \
+                    jax.default_device(device), jax.set_mesh(mesh):
+                compiled.set_result(jitted.lower(*specs).compile())
+        except BaseException as e:
+            logger.exception("compiling %s ahead of its first use failed",
+                             getattr(jitted, "__name__", jitted))
+            compiled.set_exception(e)
+
+    def program(*arrays):
+        return compiled.result()(*arrays)
+
+    program.compiled = compiled
+    threading.Thread(target=work, name="engine-compile-ahead").start()
+    return program
 
 
 class InferenceEngine:
@@ -812,6 +870,7 @@ class InferenceEngine:
         # across a whole multi-request run.
         self.prefill_traces = 0
         self.decode_traces = 0
+        self.tick_traces = 0
         self.verify_traces = 0
         self.draft_traces = 0
         self.draft_prefill_traces = 0
@@ -930,6 +989,27 @@ class InferenceEngine:
             tok, logp = _sample(logits, temps, key, step)
             return tok, logp, cache, counts
 
+        rows_len = rows_size(slots, max_blocks)
+
+        def _tick(params, cache, inputs, key, prev):
+            """`_decode` and `_prefill` as one program (`fam.tick`), for
+            a tick's step and its chunk of another sequence: `inputs` is
+            the step's packed rows, then the chunk's. The step samples
+            as in `_decode`; the chunk does not end its prompt, so its
+            logits are not asked for and XLA keeps nothing of them."""
+            self.tick_traces += 1
+            with jax.named_scope(EMBED):
+                tokens, pos, temps, tables, step = unpack_rows(
+                    inputs[:rows_len], slots)
+                tokens = jnp.where(tokens == FROM_STEP, prev, tokens)
+                chunk_tokens, table, start, length, _, _ = unpack_chunk(
+                    inputs[rows_len:], max_blocks)
+            _, logits, cache, counts = fam.tick(
+                params, chunk_tokens, tokens, cache, pos, tables, cfg, mesh,
+                block_table=table, start=start, length=length)
+            tok, logp = _sample(logits, temps, key, step)
+            return tok, logp, cache, counts
+
         def _verify(params, cache, inputs, key):
             """One batched W-token forward + in-jit accept/correct.
 
@@ -1005,6 +1085,24 @@ class InferenceEngine:
         self._no_prev = jax.device_put(np.zeros(slots, np.int32),
                                        self._io_sh)
         self._no_chunk_tok = jax.device_put(np.int32(0), self._io_sh)
+        # A family that can read a weight once for a step and a chunk:
+        # the one program that no request alone makes run (it takes
+        # decoders and another request's chunk in one tick), so a replica
+        # that warms up a request at a time would compile it under its
+        # first load, every stream waiting. It is compiled from here on
+        # (`_compile_ahead`), for the one shape it has (the full chunk),
+        # and called as the others are. A `role="prefill"` engine never
+        # decodes and has no use for it.
+        self._tick_fn = None
+        if fam.tick is not None and spec is None and role != "prefill":
+            tick_jit = jax.jit(_tick, donate_argnums=(1,),
+                               out_shardings=tok_first)
+            self._tick_fn = _compile_ahead(
+                tick_jit, self.params, self.cache, jax.device_put(
+                    np.zeros(rows_len + chunk_size(self.prefill_chunk,
+                                                   max_blocks), np.int32),
+                    self._io_sh),
+                self._base_key, self._no_prev)
         self._copy_fn = jax.jit(fam.copy_block, donate_argnums=(0,))
         self._verify_fn = (jax.jit(_verify, donate_argnums=(1,))
                            if spec is not None else None)
@@ -1184,6 +1282,8 @@ class InferenceEngine:
         # time from the end of `engine/prefill_chunk` to the token's read
         self._chunks_overlapped = 0
         self._chunk_tail_s = 0.0
+        # ticks whose chunk and step were one program (`_tick_fn`)
+        self._ticks_fused = 0
         self._prefix_hit_tokens = 0
         self._prompt_tokens = 0
         self._cow_copies = 0
@@ -1269,6 +1369,11 @@ class InferenceEngine:
         self._sentinel = _telemetry.RetraceSentinel(self.name)
         self._sentinel.watch("decode", lambda: self.decode_traces, cap=1,
                              registered=True)
+        if self._tick_fn is not None:
+            # a chunk that does not end its prompt is a full one: the
+            # fused program sees the largest bucket and no other
+            self._sentinel.watch("tick", lambda: self.tick_traces, cap=1,
+                                 registered=True)
         self._sentinel.watch("swap", lambda: self.swap_traces,
                              cap=2 if spec == "draft" else 1,
                              registered=True)
@@ -2658,8 +2763,16 @@ class InferenceEngine:
         their enqueue with no gap; no step reads what a chunk of another
         slot writes, so the order changes no stream's tokens. A slot
         whose prompt ends here emits its first token in this tick.
-        Returns the seconds the chunk kept the tick open past the
-        emit."""
+        Where the family offers `tick` and the chunk does not end its
+        prompt, chunk and step are ONE program (`_enqueue_fused`), which
+        reads every weight once: the chunk emits nothing, so nothing of
+        it is read and it keeps no tick open. Returns the seconds the
+        chunk kept the tick open past the emit."""
+        s = self._slots[slot_idx]
+        if self._tick_fn is not None and self._step_follows() \
+                and s.filled + self.prefill_chunk < s.prompt.size:
+            self._chain_tick(fused=slot_idx)
+            return 0.0
         flights = []
 
         def start_chunk():
@@ -2723,7 +2836,9 @@ class InferenceEngine:
         allows. The decode step is chained on the device
         (`_chain_tick`): the last tick left step t enqueued and unread,
         and this one enqueues ONE prefill chunk if a prompt waits, then
-        step t+1, whose continuing rows take their tokens from step t's
+        step t+1 (the two as one program where the family offers `tick`
+        and the chunk does not end its prompt: `_enqueue_fused`), whose
+        continuing rows take their tokens from step t's
         output where it lies, and only then waits for step t's tokens,
         emits them, and waits for the chunk's; so the host's work of a
         tick runs while the device has a step to run. With nothing
@@ -2892,10 +3007,14 @@ class InferenceEngine:
                          np.float32)
         return (tokens, pos, tables, temps), rows
 
-    def _put_rows(self, tokens, pos, tables, temps, step: int):
-        """A step's packed input (`pack_rows`), put in a span of its
-        own inside the caller's `engine/decode_build`."""
+    def _put_rows(self, tokens, pos, tables, temps, step: int,
+                  chunk=None):
+        """A step's packed input (`pack_rows`; with `chunk`, a packed
+        chunk of the same program behind it), put in a span of its own
+        inside the caller's `engine/decode_build`."""
         packed = pack_rows(tokens, pos, temps, tables, step)
+        if chunk is not None:
+            packed = np.concatenate([packed, chunk])
         with self._phases.phase("engine/decode_put", puts=1):
             return self._dev(packed)
 
@@ -2912,25 +3031,30 @@ class InferenceEngine:
                 self._decode_steps)
 
     def _enqueue_step(self, behind=None, joining=None, chunk=None,
-                      built=None) -> _StepInFlight:
+                      built=None, fused=None) -> _StepInFlight:
         """Build (`_batch_arrays`, or `built`: its result), put and
         dispatch one decode step; nothing is waited for. `behind` is
         the step enqueued and unread whose output the `FROM_STEP` rows
         read (this step's key takes the counter after that step's) and
         `chunk` the chunk in flight whose token the `joining` slot's
-        row reads."""
+        row reads. `fused` is a packed chunk (`pack_chunk`) that rides
+        in the step's program (`_enqueue_fused`)."""
         phase = self._phases.phase
         with phase("engine/decode_build"):
             (tokens, pos, tables, temps), rows = (
                 built or self._batch_arrays(behind, joining))
             inputs = self._put_rows(
                 tokens, pos, tables, temps,
-                self._decode_steps + (behind is not None))
+                self._decode_steps + (behind is not None), fused)
         with phase("engine/decode_dispatch") as dispatch:
-            nxt, lps, self.cache, counts = self._decode_fn(
-                self.params, self.cache, inputs, self._base_key,
-                self._no_prev if behind is None else behind.nxt,
-                self._no_chunk_tok if joining is None else chunk.tok)
+            prev = self._no_prev if behind is None else behind.nxt
+            if fused is None:
+                nxt, lps, self.cache, counts = self._decode_fn(
+                    self.params, self.cache, inputs, self._base_key, prev,
+                    self._no_chunk_tok if joining is None else chunk.tok)
+            else:
+                nxt, lps, self.cache, counts = self._tick_fn(
+                    self.params, self.cache, inputs, self._base_key, prev)
             bound = self._family.bounded_tokens
             if bound and dispatch.is_enabled():
                 # what the step's bounded pages hold of each decoding
@@ -2989,8 +3113,46 @@ class InferenceEngine:
             self._chain_drains += 1
             self._read_step(flight)
 
+    def _enqueue_fused(self, behind, slot_idx: int) -> _StepInFlight:
+        """The tick's decode step and the next chunk of `slot_idx`'s
+        prompt, which does not end it (so it is a full one), as one
+        program (`ServingFamily.tick`) inside `engine/tick_fused`. The
+        flight is the step's: the chunk emits nothing, so the program
+        samples no token for it (its temperature and counter ride as
+        zeros), its counts come with the step's, the tick ends with
+        nothing unread but the flight and the next chains behind it as
+        behind a step. What `_finish_chunk` does once a chunk's token is
+        read is done here, at the enqueue: device order is enqueue
+        order, so whatever follows finds the chunk written. The span
+        carries what `engine/prefill_chunk` does (`tokens`, `bucket`,
+        `start`: a reader of the chunk's attention needs them)."""
+        s = self._slots[slot_idx]
+        clen = self.prefill_chunk
+        with self._phases.phase("engine/tick_fused", tokens=clen,
+                                bucket=clen, start=s.filled) as span:
+            flight = self._enqueue_step(behind, fused=pack_chunk(
+                s.prompt[s.filled:s.filled + clen], clen, s.table, s.filled,
+                0.0, 0))
+        self._recorder.on_prefill_chunk(s.rid, clen, clen, span.seconds)
+        self._prefill_tokens += clen
+        self._prefill_chunks += 1
+        self._ticks_fused += 1
+        s.filled += clen
+        return flight
+
+    def _step_follows(self) -> bool:
+        """Whether a plain tick enqueues a decode step: where none is in
+        flight, a slot decodes; behind one in flight, where a decoding
+        slot joins or goes on past the token in flight (else every
+        stream ends with that token)."""
+        behind = self._flight
+        return behind is None or any(
+            s.phase == "decode" and (behind.rows.get(i) != s.rid
+                                     or self._goes_on(s))
+            for i, s in enumerate(self._slots))
+
     def _chain_tick(self, chunk_slot: int | None = None,
-                    start_chunk=None) -> None:
+                    start_chunk=None, fused: int | None = None) -> None:
         """The plain tick's decode programs: this tick's chunk where it
         has one (`start_chunk` enqueues it), then the next decode step,
         and only then the wait for the tokens of the step the last tick
@@ -3002,9 +3164,16 @@ class InferenceEngine:
         its rows join from the host and the pipeline is full again. The
         device runs chunk, step, chunk, step either way. The step is
         left unread; the chunk's token is the caller's to read
-        (`_decode_with_chunk`)."""
+        (`_decode_with_chunk`). `fused` is the slot whose chunk rides in
+        the step's own program instead (`_enqueue_fused`)."""
         behind = self._flight
         chunk = joining = None
+
+        def enqueue():
+            if fused is not None:
+                return self._enqueue_fused(behind, fused)
+            return self._enqueue_step(behind, joining, chunk)
+
         if start_chunk is not None:
             chunk = start_chunk()
             s = self._slots[chunk_slot]
@@ -3015,14 +3184,11 @@ class InferenceEngine:
                     and s.prompt.size + 1 < self.max_len:
                 joining = chunk_slot
         if behind is None:
-            self._flight = self._enqueue_step(None, joining, chunk)
+            self._flight = enqueue()
             return
-        if joining is not None or any(
-                s.phase == "decode" and (behind.rows.get(i) != s.rid
-                                         or self._goes_on(s))
-                for i, s in enumerate(self._slots)):
+        if joining is not None or self._step_follows():
             with self._phases.phase("engine/decode_chain") as chain:
-                self._flight = self._enqueue_step(behind, joining, chunk)
+                self._flight = enqueue()
                 self._steps_chained += 1
                 chain.set(rows=self._flight.chained)
         else:
@@ -3290,6 +3456,7 @@ class InferenceEngine:
             self._recorder.deliver_waits.clear()
             self._prefill_chunks = self._chunks_overlapped = 0
             self._chunk_tail_s = 0.0
+            self._ticks_fused = 0
             self._prefix_hit_tokens = self._prompt_tokens = 0
             self._cow_copies = self._evicted_blocks = 0
             self._bounded_reused = 0
@@ -3350,10 +3517,10 @@ class InferenceEngine:
           (`update_params`, a `cancel` or a preemption of a live stream,
           `check_invariants`, `run_until_idle`'s end).
           ``host_puts`` — host-to-device transfers made for the
-          programs' inputs since reset: one a decode, verify, propose or
-          prefill program (`pack_rows`, `pack_chunk`), so
-          ``decode_steps + prefill_chunks`` where nothing speculates
-          with a draft model.
+          programs' inputs since reset: one a decode, verify, propose,
+          prefill or fused program (`pack_rows`, `pack_chunk`), so
+          ``decode_steps + prefill_chunks - ticks_fused`` where nothing
+          speculates with a draft model.
           ``prefill_tokens`` / ``decode_tokens`` — tokens absorbed /
           emitted since reset; ``prefill_time_s`` / ``decode_time_s``
           the device time attributed to each: what the recorder is told
@@ -3367,7 +3534,15 @@ class InferenceEngine:
           ``chunks_overlapped`` — those enqueued behind a decode step of
           the device's queue and built while the device ran it (their
           `engine/prefill_chunk` carries ``overlapped=1``): every chunk
-          of a tick that held a decoder, none of a tick that held none.
+          of a tick that held a decoder, none of a tick that held none,
+          less the fused ones.
+          ``ticks_fused`` — ticks whose chunk and decode step were ONE
+          program (`ServingFamily.tick`, inside `engine/tick_fused`):
+          where the family offers it, every chunk of a tick that held a
+          decoder and did not end its prompt. Such a chunk is counted in
+          ``prefill_chunks`` and ``prefill_tokens`` at its enqueue, no
+          token is sampled for it, and its device time lies in the step's
+          (``decode_time_s``), not in ``prefill_time_s``.
           ``slot_occupancy`` — mean fraction of slots active per tick.
           ``p50_token_latency_ms`` / ``p99_token_latency_ms`` —
           percentiles over a 512-step window of a step's dispatch plus
@@ -3376,9 +3551,10 @@ class InferenceEngine:
           length.
 
         Compile-once accounting (NEVER reset — identity, not rate):
-          ``prefill_traces`` / ``decode_traces`` / ``verify_traces`` /
-          ``draft_traces`` / ``draft_prefill_traces`` — python traces of
-          each jitted path; tests pin decode/verify to 1 per lifetime.
+          ``prefill_traces`` / ``decode_traces`` / ``tick_traces`` /
+          ``verify_traces`` / ``draft_traces`` / ``draft_prefill_traces``
+          — python traces of each jitted path; tests pin decode/verify
+          to 1 per lifetime, and the fused tick sees one chunk bucket.
           ``swap_traces`` — traces of the hot-swap copy fn (once per
           distinct pytree: target and draft each trace once, ever).
           ``load_traces`` — traces of the family's load-time fn, which
@@ -3658,6 +3834,8 @@ class InferenceEngine:
                 "decode_traces": self.decode_traces,
                 "prefill_chunks": self._prefill_chunks,
                 "chunks_overlapped": self._chunks_overlapped,
+                "ticks_fused": self._ticks_fused,
+                "tick_traces": self.tick_traces,
                 "slot_occupancy": (sum(occ) / len(occ)) if occ else 0.0,
                 "p50_token_latency_ms": pct(50),
                 "p99_token_latency_ms": pct(99),

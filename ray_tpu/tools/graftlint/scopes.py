@@ -124,6 +124,9 @@ COMPILE_ONCE_JITS: dict[str, dict[str, str | None]] = {
     ENGINE: {
         "self._prefill_fn": "prefill",
         "self._decode_fn": "decode",
+        "tick_jit": "tick",     # a step and a chunk, one program;
+        # compiled ahead of its first use (`_compile_ahead`), so that no
+        # tick traces it
         "self._copy_fn": None,          # COW block copy; shapes fixed
         "self._verify_fn": "verify",
         "self._propose_fn": "draft",

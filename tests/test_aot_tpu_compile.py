@@ -1886,12 +1886,17 @@ SHORTCONV_KERNELS = {
     "experts_grouped_prefill_128": (
         _all_experts_case(128, "experts_grouped_prefill"),
         "experts_grouped_prefill"),
+    # a step's 128 rows and a chunk's 512 in one call (`shortconv_moe.tick`)
+    "experts_grouped_tick": (
+        _all_experts_case(640, "experts_grouped_tick"),
+        "experts_grouped_tick"),
 }
 # rows of a call -> rows of the expert kernel's layout, the pairs and a
 # tile of padding an expert (`grouped_experts.row_tile`): a step's and
 # the 128 bucket's 512 pairs in tiles of 32, a 512 chunk's 2,048 in tiles
-# of 128; 48 tiles each
-LFM2_LAYOUT_ROWS = {128: 512 + 32 * 32, 512: 2048 + 32 * 128}
+# of 128, 48 tiles each; a fused tick's 2,560 in tiles of 128, 52 tiles
+LFM2_LAYOUT_ROWS = {128: 512 + 32 * 32, 512: 2048 + 32 * 128,
+                    640: 2560 + 32 * 128}
 
 
 @pytest.mark.parametrize("case", sorted(SHORTCONV_KERNELS))
@@ -1927,13 +1932,16 @@ def _tails_made(text: str, pool: dict) -> list[str]:
             and "fusion(" not in line]
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128"])
+@pytest.mark.parametrize("program", ["decode", "prefill_512", "prefill_128",
+                                     "tick_512"])
 def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
                                                                caplog):
-    """The decode step and both prefill buckets of
+    """The decode step, both prefill buckets and the fused tick (a step
+    and a full chunk, one program) of
     `benchmarks/configs/lfm2-8b-a1b.json` as the engine jits them (the
     pool donated): the attention and expert kernels of every layer under
-    their names, no fallback; no gather of a layer's pages (nothing the
+    their names (the fused tick's under names of its own, one expert call
+    a sparse layer over the rows of both), no fallback; no gather of a layer's pages (nothing the
     size of a layer of the pool is made) and no copy of the whole tails
     array; tails and pages updated in place; weights, pool and
     temporaries under the chip's 15.75 GB with the reference's
@@ -1964,7 +1972,20 @@ def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
             arg((slots, 1 + cols)))
         assert time.monotonic() - started < TRACE_AND_LOWER_S
         compiled = lowered.compile()
+        chunk = None
         want = {"gqa_full_decode": 3, "experts_grouped": 12}
+    elif program == "tick_512":
+        chunk = 512
+        compiled = jax.jit(
+            lambda p, ctok, tok, cache, pos, tab, ctab, start, n:
+            shortconv_moe.tick(p, ctok, tok, cache, pos, tab, cfg,
+                               block_table=ctab, start=start, length=n),
+            donate_argnums=(3,)).lower(
+            params, arg((1, chunk)), arg((slots,)), pool, arg((slots,)),
+            arg((slots, 1 + cols)), arg((1 + cols,)), arg(()),
+            arg(())).compile()
+        want = {"gqa_full_decode_tick": 3, "gqa_full_chunk_tick": 3,
+                "experts_grouped_tick": 12}
     else:
         chunk = int(program.rsplit("_", 1)[1])
         compiled = jax.jit(
@@ -1981,7 +2002,8 @@ def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
     # every sparse layer's call walks the layout `row_tile` plans: a step
     # of 128 rows x 4 over 32 experts is `experts_grouped` over [1536, 2048]
     experts = next(n for n in want if n.startswith("experts_grouped"))
-    rows = LFM2_LAYOUT_ROWS[slots if program == "decode" else chunk]
+    rows = LFM2_LAYOUT_ROWS[{"decode": slots, "tick_512": slots + 512}.get(
+        program, chunk)]
     assert kernel_results(text, experts) == [f"bf16[{rows},2048]"] * 12
     # a chunk updates its block's tails where they lie. The step's one
     # gather and one scatter of 128 blocks' tails are made on the array
@@ -1989,7 +2011,8 @@ def test_shortconv_family_programs_compile_at_the_cells_shapes(topo, program,
     # MB in for each, once out: 35 MB a step at HBM speed, 43 us), once a
     # program and not once a layer (ROADMAP S23's 4-5 %); PERF.md has the
     # measured share
-    assert len(_tails_made(text, pool)) <= (3 if program == "decode" else 0)
+    assert len(_tails_made(text, pool)) <= (
+        3 if program in ("decode", "tick_512") else 0)
     # a gather of a layer through the table, or a lay-out of one, would be
     # an array of a layer's pages: 4,993 x 4 x 128 x 128
     layer = math.prod(pool["k"].shape[1:])

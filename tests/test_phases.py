@@ -390,6 +390,275 @@ def test_the_put_spans_say_one_put_each(session, request):
             for c in ev[f"engine/{name}"])
 
 
+# -- a step and a chunk as one program (`ServingFamily.tick`) -----------------
+
+def fusing_engine(**kw):
+    """An engine over the short-convolution family, which offers `tick`:
+    chunks of 8, five slots."""
+    from ray_tpu.models import shortconv_moe
+    cfg = shortconv_moe.ShortConvMoEConfig(
+        dtype="float32", attn_impl="jax", sparse_impl="jax")
+    params = shortconv_moe.init_params(jax.random.PRNGKey(0), cfg)
+    return InferenceEngine(params, cfg, slots=5, max_len=64, block_size=8,
+                           prefill_chunk=8, prefill_buckets=(8,),
+                           prefix_cache=False, **kw), params
+
+
+def tokens_of(n, seed):
+    return list(np.random.default_rng(seed).integers(0, 512, n))
+
+
+# prompts of one, two and five chunks of 8, greedy and at a temperature
+JOINERS = [(5, 0.0), (13, 0.8), (37, 0.0)]
+
+
+def mixed_run(eng, trace_to=None):
+    """Two streams decode, one of them at a temperature; then the three
+    `JOINERS` arrive at once, so that every one of their eight chunks
+    shares its tick with a decode step. -> (tokens a stream, stats)."""
+    rids = [eng.submit(tokens_of(6, 1), max_new_tokens=30),
+            eng.submit(tokens_of(4, 2), max_new_tokens=30, temperature=0.7)]
+    for _ in range(3):
+        eng.step()
+    eng.reset_stats()
+    if trace_to:
+        start_trace(trace_to)
+    try:
+        rids += [eng.submit(tokens_of(n, 10 + n), max_new_tokens=5,
+                            temperature=t) for n, t in JOINERS]
+        eng.run_until_idle()
+    finally:
+        if trace_to:
+            jax.profiler.stop_trace()
+    eng.check_invariants()
+    return [[(int(t), float(t.logprob)) for t in eng.tokens_for(r)]
+            for r in rids], eng.stats()
+
+
+@pytest.fixture(scope="module")
+def fused_and_not(tmp_path_factory):
+    """`mixed_run` through an engine whose family offers `tick`, traced,
+    and through one built on the same family without it."""
+    from ray_tpu.models import shortconv_moe
+    out = str(tmp_path_factory.mktemp("trace-fused"))
+    fused = mixed_run(fusing_engine()[0], out)
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(shortconv_moe, "FAMILY",
+                      shortconv_moe.FAMILY._replace(tick=None))
+        two_programs = mixed_run(fusing_engine()[0])
+    path, events = program_spans(out)
+    return {"fused": fused, "two_programs": two_programs, "events": events,
+            "path": path}
+
+
+def test_a_fused_tick_gives_the_two_programs_tokens(fused_and_not):
+    """The same tokens in the same order a stream, greedy and sampled;
+    every overlapped chunk that does not end its prompt is fused, the
+    three that do are not; a fused step is chained as a step is."""
+    (got, st), (want, two) = (fused_and_not[k] for k in (
+        "fused", "two_programs"))
+    for mine, theirs in zip(got, want):
+        assert [t for t, _ in mine] == [t for t, _ in theirs]
+        np.testing.assert_allclose([lp for _, lp in mine],
+                                   [lp for _, lp in theirs], rtol=0,
+                                   atol=1e-5)
+    ended = len(JOINERS)        # chunks that end a prompt, all overlapped
+    assert two["ticks_fused"] == two["tick_traces"] == 0
+    assert two["chunks_overlapped"] == two["prefill_chunks"] == 8
+    assert st["ticks_fused"] == two["chunks_overlapped"] - ended == 5
+    assert st["chunks_overlapped"] == ended and st["prefill_chunks"] == 8
+    assert st["tick_traces"] == 1 and st["retraces_unexpected"] == 0
+    for key in ("steps_chained", "decode_steps", "decode_tokens",
+                "prefill_tokens", "chain_drains"):
+        assert st[key] == two[key], key
+    # one transfer a program
+    assert st["host_puts"] == st["decode_steps"] + ended
+    assert two["host_puts"] == two["decode_steps"] + 8
+    # what the programs counted of rows is what two programs count
+    for key in ("conv_rows_live", "state_resets", "attention_rows_read",
+                "expert_tokens_here"):
+        assert st[key] == two[key], key
+    assert st["experts_reached"] < two["experts_reached"]
+
+
+def test_a_fused_tick_waits_for_no_chunk(fused_and_not):
+    """`engine/tick_fused` holds the step's build, put and dispatch and
+    lies inside the tick's `engine/decode_chain`; its tick opens no
+    `engine/prefill_chunk` and waits for no chunk's token
+    (`engine/prefill_sync`): what it reads is the step the tick before
+    left."""
+    ev, (_, st) = fused_and_not["events"], fused_and_not["fused"]
+    fused = sorted(ev["engine/tick_fused"], key=lambda e: e[1])
+    assert len(fused) == st["ticks_fused"] == 5
+    assert [(f[3]["tokens"], f[3]["bucket"]) for f in fused] == [(8, 8)] * 5
+    assert sorted(f[3]["start"] for f in fused) == [0, 0, 8, 16, 24]
+    ticks = [t for t in ev["engine/tick"] if any(inside(f, [t])
+                                                 for f in fused)]
+    assert len(ticks) == 5
+    assert all(inside(f, ev["engine/decode_chain"]) for f in fused)
+    for name in ("decode_build", "decode_put", "decode_dispatch"):
+        assert all(sum(inside(e, [f]) for e in ev[f"engine/{name}"]) == 1
+                   for f in fused), name
+    for name in ("prefill_chunk", "prefill_build", "prefill_sync"):
+        assert not any(inside(e, ticks) for e in ev[f"engine/{name}"]), name
+    for name in ("token_sync", "emit"):
+        assert all(sum(inside(e, [t]) for e in ev[f"engine/{name}"]) == 1
+                   for t in ticks), name
+    assert len(ev["engine/decode_chain"]) == st["steps_chained"]
+
+
+def test_the_fused_share_is_a_metric_of_the_benchmark(fused_and_not,
+                                                      monkeypatch):
+    from benchmarks import run as bench_run
+    from benchmarks.harness import spans
+    monkeypatch.setattr(spans, "summary",
+                        lambda ctx: spans.reduce(fused_and_not["path"]))
+    got = bench_run.read_layer_metric("ticks_fused_share",
+                                      {"trace": {"modules": {}}})
+    ev = fused_and_not["events"]
+    assert got == pytest.approx(100.0 * 5 / len(ev["engine/tick"]))
+
+
+def test_the_fused_program_is_compiled_before_a_load_and_nothing_under_it():
+    """A replica warms up a request at a time, which no fused tick ever
+    is: the program is compiled from the engine's construction on
+    (`_compile_ahead`) and is there when the first load fuses, and the
+    programs that take its outputs (the next step, the next chunk) see
+    the arrays they have always seen, so nothing is compiled under the
+    load: not `_tick`, and no second `_decode` or `_prefill`."""
+    from benchmarks.harness.common import CompileWatch
+    watch = CompileWatch()
+    eng, _ = fusing_engine()
+    for n in (5, 13):                       # the warm-up: both programs
+        list(eng.tokens_for(eng.submit(tokens_of(n, n), max_new_tokens=4)))
+    eng._tick_fn.compiled.result()
+    assert "jit(_tick)" in watch.names
+    warmed = watch.programs()
+    _, st = mixed_run(eng)
+    assert st["ticks_fused"] == 5
+    assert watch.programs() == warmed, watch.names
+    assert st["tick_traces"] == st["decode_traces"] == 1
+
+
+@pytest.mark.parametrize("context", ["precision", "mesh"])
+def test_a_program_compiled_ahead_is_its_caller_s_program(context):
+    """`_compile_ahead`'s thread traces under the matmul precision and
+    the mesh of the thread that asked: the executable is the one that
+    thread would compile itself, and an engine built inside a context
+    gets its fused program under it, as it gets the programs it traces
+    at their first call."""
+    from jax.sharding import PartitionSpec as P
+    from ray_tpu.serve import engine as engine_mod
+    x = jnp.ones((8, 8), jnp.float32)
+    if context == "precision":
+        fn = jax.jit(lambda a, b: a @ b)
+        inside = jax.default_matmul_precision("highest")
+    else:
+        fn = jax.jit(lambda a, b: jax.lax.with_sharding_constraint(
+            a @ b, P("x")))
+        inside = jax.set_mesh(jax.make_mesh(
+            (2,), ("x",), axis_types=(jax.sharding.AxisType.Auto,)))
+    outside = engine_mod._compile_ahead(
+        jax.jit(lambda a, b: a @ b), x, x).compiled.result().as_text()
+    with inside:
+        ahead = engine_mod._compile_ahead(fn, x, x)
+        want = fn.lower(x, x).compile().as_text()
+        direct = fn(x, x)
+        if context == "precision":
+            eng, _ = fusing_engine()
+    assert ahead.compiled.result().as_text() == want != outside
+    np.testing.assert_array_equal(ahead(x, x), direct)
+    if context == "precision":
+        assert "highest" in want.lower()
+        # the router's products ask for the highest themselves; under the
+        # context every product of the program does
+        at_default = fusing_engine()[0]._tick_fn.compiled.result().as_text()
+        assert eng._tick_fn.compiled.result().as_text().lower().count(
+            "highest") > at_default.lower().count("highest")
+
+
+FUSED_METRICS = ("ticks_fused_share", "tick_mixer_ms", "tick_ffn_ms",
+                 "tick_head_ms", "tick_compiler_ms", "lfm2_experts_tick_ms",
+                 "lfm2_experts_tick_roofline", "lfm2_gqa_decode_tick_ms",
+                 "lfm2_gqa_decode_tick_roofline", "lfm2_gqa_chunk_tick_ms",
+                 "lfm2_gqa_chunk_tick_roofline")
+
+
+@pytest.mark.parametrize("name", FUSED_METRICS)
+def test_a_fused_tick_s_metric_is_an_entry_with_a_file(name, fused_and_not,
+                                                       monkeypatch):
+    """Each metric the fused tick brought: `BENCHMARK.json`'s entry and
+    the file beside the readers say the same (the cell's list too), and
+    on a trace that holds no `jit__tick` (a family without `tick`, the
+    parent of the PR that brought them) the device readers find nothing
+    and do not raise."""
+    import json
+    from benchmarks import run as bench_run
+    from benchmarks.harness import spans
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry, = [m for m in json.load(f)["per_layer"] if m["name"] == name]
+    with open(os.path.join(root, "benchmarks", "layer_metrics",
+                           f"{name}.json")) as f:
+        spec = json.load(f)
+    assert {k: spec[k] for k in entry} == entry
+    assert entry["workloads"] == ["lfm2-8b-a1b.chat-closed192"]
+    assert entry["moves"] == "serve_tokens_per_s"
+    assert " over " in spec["what"]         # what it divides by what
+    if name != "ticks_fused_share":
+        assert spec["args"].get("module") == "jit__tick"
+        monkeypatch.setattr(spans, "summary", lambda ctx: spans.reduce(
+            fused_and_not["path"]))
+        assert bench_run.read_layer_metric(name, {
+            "trace": {"modules": {"jit__decode": [3, 0.1]}},
+            "stats": {"serve": {"decoding_context_tokens": 1.0},
+                      "engine": {"kv_bytes_per_token": 1}}}) is None
+
+
+def test_a_fused_tick_leaves_nothing_unread_but_its_flight():
+    """`update_params` and `cancel` in the tick after a fused one find
+    the chunk done at its enqueue, its tokens counted, and nothing unread
+    but the flight: the first reads it (`chain_drains`), as it reads a
+    step; the second, of the prefilling request, leaves it, since no row
+    of it is the request's. The stream beside them goes on to the tokens
+    of an undisturbed run."""
+    eng, params = fusing_engine()
+    resident = eng.submit(tokens_of(6, 1), max_new_tokens=12)
+    undisturbed = [int(t) for t in eng.tokens_for(resident)]
+    resident = eng.submit(tokens_of(6, 1), max_new_tokens=12)
+    for _ in range(3):
+        eng.step()
+    long = eng.submit(tokens_of(37, 47), max_new_tokens=4)
+    for fused_so_far in (1, 2):
+        before = eng.stats()
+        eng.step()
+        after = eng.stats()
+        assert after["ticks_fused"] == fused_so_far
+        assert after["prefill_tokens"] - before["prefill_tokens"] == 8
+        assert eng._flight is not None
+        slot, = [s for s in eng._slots if s.rid == long]
+        assert slot.filled == 8 * fused_so_far and slot.phase == "prefill"
+        assert list(eng._flight.rows.values()) == [resident]
+        if fused_so_far == 1:
+            eng.update_params(params)
+            assert eng._flight is None
+            assert eng.stats()["chain_drains"] == after["chain_drains"] + 1
+        else:
+            assert eng.cancel(long)
+            assert list(eng._flight.rows.values()) == [resident]
+            assert all(s.rid != long for s in eng._slots)
+            eng.check_invariants()      # reads the flight
+    assert [int(t) for t in eng.tokens_for(resident)] == undisturbed
+    assert eng.stats()["ticks_fused"] == 2
+
+
+def test_a_family_without_tick_never_fuses(traced_beside_a_decoder):
+    ev, st = (traced_beside_a_decoder[k] for k in ("events", "stats"))
+    assert "engine/tick_fused" not in ev
+    assert st["ticks_fused"] == st["tick_traces"] == 0
+    assert st["chunks_overlapped"] == 3
+
+
 def test_every_tick_says_how_long_after_the_last_it_began(traced):
     """`gap_us` and `carried` on each `engine/tick`: the engine's own
     reading of the time since the previous tick ended is the distance

@@ -374,6 +374,73 @@ def test_padding_and_idle_rows_leave_tails_and_pages(params):
                                       np.asarray(after[key][:, 1:]))
 
 
+@pytest.mark.parametrize("start,length,impl", [
+    (0, 16, "jax"), (0, 13, "jax"), (16, 16, "jax"), (16, 13, "jax"),
+    (16, 13, "pallas")],
+    ids=["first-full", "first-short", "later-full", "later-short",
+         "later-short-kernels"])
+def test_tick_is_prefill_then_decode_in_one_program(params, start, length,
+                                                    impl):
+    """`tick` against `prefill`, then `decode`, on the same pool and
+    inputs: a chunk of 16 that starts its sequence or follows its first
+    chunk, whole or 13 live positions long, beside a step of four rows of
+    which two decode (after prompts of 9 and 21) and two are idle, over
+    dense and sparse layers under both mixers. Both logits and every
+    array of the pool agree; the counts are the two programs' summed,
+    but the row tiles and the experts reached, which are the one call's
+    a layer that `tick` makes where the two programs make two."""
+    cfg = config(impl)
+    pool = shortconv_moe.init_pool(cfg, 12, BS, state_blocks=5)
+    # state block -> (the sequence's tokens, its pages, what this tick
+    # finds of it in the pool)
+    seqs = {1: (prompt(9, 1), [2], 9), 2: (prompt(21, 2), [3, 4], 21),
+            3: (prompt(32, 3), [5, 6, 7], start)}
+    tables = {b: jnp.asarray([b] + pages + [0] * (6 - len(pages)), jnp.int32)
+              for b, (_, pages, _) in seqs.items()}
+
+    def chunk_of(tokens):
+        padded = np.zeros((1, 16), np.int32)
+        padded[0, :len(tokens)] = tokens
+        return jnp.asarray(padded)
+
+    for b, (tokens, _, fed) in seqs.items():
+        for at in range(0, fed, 16):
+            n = min(16, fed - at)
+            _, pool, _ = shortconv_moe.prefill(
+                params, chunk_of(tokens[at:at + n]), pool, cfg,
+                block_table=tables[b], start=at, length=n)
+    chunk = chunk_of(seqs[3][0][start:start + length])
+    step_tokens = jnp.asarray([7, 0, 11, 0], jnp.int32)
+    pos = jnp.asarray([9, 0, 21, 0], jnp.int32)
+    rows = jnp.stack([tables[1], jnp.zeros(7, jnp.int32), tables[2],
+                      jnp.zeros(7, jnp.int32)])
+    want_chunk, after, chunk_counts = shortconv_moe.prefill(
+        params, chunk, pool, cfg, block_table=tables[3], start=start,
+        length=length)
+    want_step, after, step_counts = shortconv_moe.decode(
+        params, step_tokens, after, pos, rows, cfg)
+    got_chunk, got_step, got, counts = shortconv_moe.tick(
+        params, chunk, step_tokens, pool, pos, rows, cfg,
+        block_table=tables[3], start=start, length=length)
+    np.testing.assert_allclose(np.asarray(got_chunk), np.asarray(want_chunk),
+                               rtol=0, atol=TOL)
+    np.testing.assert_allclose(np.asarray(got_step), np.asarray(want_step),
+                               rtol=0, atol=TOL)
+    assert set(got) == set(after)
+    for key in after:
+        np.testing.assert_allclose(np.asarray(got[key]),
+                                   np.asarray(after[key]), rtol=0, atol=TOL,
+                                   err_msg=key)
+    counts, both = np.asarray(counts), np.asarray(chunk_counts + step_counts)
+    tiles, reached = (shortconv_moe.COUNTS.index(n) for n in (
+        "expert_row_tiles", "experts_reached"))
+    rows_counted = [i for i in range(len(both)) if i not in (tiles, reached)]
+    np.testing.assert_array_equal(counts[rows_counted], both[rows_counted])
+    assert max(chunk_counts[reached], step_counts[reached]) \
+        <= counts[reached] <= both[reached]
+    assert counts[reached] <= counts[tiles] <= both[tiles]
+
+
 def test_load_hands_back_a_served_tree_as_it_is(params):
     """Float32 masters into a bfloat16 program: every leaf in the type
     the steps read, the router, its bias and the taps in float32; a tree
