@@ -3497,8 +3497,18 @@ class InferenceEngine:
         """The engine's one stats contract — this dict feeds the serve
         autoscaler (`autoscaler.load_metrics.
         replica_demands_from_engine_stats`), the benchmark's serving
-        cells (`benchmarks/harness/serve_replica.ENGINE_STATS`), and the
-        RL flywheel's staleness accounting. Keys:
+        cells (`benchmarks/harness/serve_replica.ENGINE_STATS`), the RL
+        flywheel's staleness accounting, and a profile: under an open
+        `jax.profiler` session each call writes one `engine/counters`
+        span that carries every entry of this dict whose value is an int
+        or a float (no bool, string or nested dict: `role`, `spec`,
+        `prefix_cache`, `per_class` stay out) under its own key, what the
+        family's programs counted (`ServingFamily.counts`) among them:
+        a window's totals on the device planes' clock, and a series
+        where a controller polls. The rule is the value's type, so a new entry
+        needs no other edit; with no session on nothing is built
+        (`benchmarks/layer_metrics/span_counter_ratio.py` reads it).
+        Keys:
 
         Scheduler/throughput:
           ``slots`` / ``active`` / ``pending`` — slot capacity, occupied
@@ -3814,7 +3824,7 @@ class InferenceEngine:
                     return 0.0
                 return imp_ms[min(len(imp_ms) - 1,
                                   int(p / 100 * len(imp_ms)))]
-            return {
+            out = {
                 "slots": self.num_slots,
                 "active": sum(s.active for s in self._slots),
                 "pending": len(self._pending),
@@ -3952,6 +3962,12 @@ class InferenceEngine:
                 **(self._family.counts(self.cfg, self._model_counts)
                    if self._family.counts is not None else {}),
             }
+            with ph.phase("engine/counters") as span:
+                if span.is_enabled():
+                    span.set(**{k: v for k, v in out.items()
+                                if isinstance(v, (int, float))
+                                and not isinstance(v, bool)})
+            return out
 
 
 class InferenceReplica:

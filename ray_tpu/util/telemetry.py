@@ -26,7 +26,7 @@ built from five pieces:
     (`RAY_TPU_TELEMETRY_SAMPLE`, default 1.0) and cheap enough to leave
     on: the per-token hook is one dict lookup + an int increment, and an
     unsampled request costs a single failed lookup per hook.
-    Distills TTFT / TPOT / queue-wait into `util.metrics` histograms.
+    Distills TTFT and queue-wait into `util.metrics` histograms.
 
   * stats-dict metrics bridge — `register_stats_source(name, obj)`
     holds a weakref to anything with a `stats() -> dict` (engines,
@@ -465,13 +465,6 @@ class FlightRecorder:
                              {"rid": rid, "tokens": tr["tokens"]})
             dec["end_ns"] = now
             spans.append(dec)
-            if tr["tokens"] > 1:
-                h = _metric(_metrics.Histogram, "engine_tpot_ms",
-                            "inter-token latency after first token, ms",
-                            boundaries=_MS_BOUNDARIES)
-                if h is not None:
-                    h.observe((now - first) / 1e6 / (tr["tokens"] - 1),
-                              tags={"source": self.name})
         for s in spans:
             self._push(s)
 

@@ -6,6 +6,7 @@ step past the engine's edge (`first_yield`, `deliver_wait_ms_*`)."""
 import glob
 import os
 import re
+import sys
 import time
 
 import jax
@@ -950,3 +951,187 @@ def test_the_reply_span_s_median_is_a_metric_of_the_benchmark(
     assert bench_run.read_layer_metric("stream_reply_ms", ctx) is None
     monkeypatch.setattr(spans, "summary", lambda c: None)
     assert bench_run.read_layer_metric("stream_reply_ms", ctx) is None
+
+
+# -- the window's counters in the trace (`engine/counters`) -------------------
+
+COUNTERS = "engine/counters"
+MADE_UP = "made_up_rows"        # a counter a family adds tomorrow
+
+
+@pytest.fixture(scope="module")
+def traced_counters(tmp_path_factory):
+    """A fourth session, over the dense engine and one whose family has
+    `counts` (with one more name than its `COUNTS`, as a later PR would
+    add it): each decodes and reads `stats()`, decodes on and reads it
+    again, then resets and reads it a third time; the second decodes
+    once more, so the session's last snapshot holds counts.
+    {family: [the returned dicts]}, and the events in call order."""
+    from ray_tpu.models import shortconv_moe
+    family = shortconv_moe.FAMILY
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(shortconv_moe, "FAMILY", family._replace(
+            counts=lambda cfg, totals: {**family.counts(cfg, totals),
+                                        MADE_UP: 7}))
+        engines = {"dense": tiny_engine(), "counting": fusing_engine()[0]}
+    for eng in engines.values():
+        list(eng.tokens_for(eng.submit([5, 9, 3], max_new_tokens=2)))
+        eng.reset_stats()
+    out = str(tmp_path_factory.mktemp("trace-counters"))
+    returned = {}
+    start_trace(out)
+    try:
+        for name, eng in engines.items():
+            got = returned[name] = []
+            for prompt in ([7, 1, 2, 4], [3, 3, 8]):
+                list(eng.tokens_for(eng.submit(prompt, max_new_tokens=4)))
+                got.append(eng.stats())
+            eng.reset_stats()
+            got.append(eng.stats())
+        list(eng.tokens_for(eng.submit(tokens_of(11, 3), max_new_tokens=4)))
+        got.append(eng.stats())
+    finally:
+        jax.profiler.stop_trace()
+    path, events = program_spans(out)
+    events = sorted(events[COUNTERS], key=lambda e: e[1])
+    assert len(events) == 3 + 4
+    return {"path": path, "returned": returned,
+            "events": {"dense": events[:3], "counting": events[3:]}}
+
+
+@pytest.mark.parametrize("family", ["dense", "counting"])
+def test_stats_under_a_session_writes_the_dict_s_numbers(traced_counters,
+                                                         family):
+    """One `engine/counters` event a call, every int and float entry of
+    the returned dict on it at the returned value and nothing else; what
+    a family counts comes by the same rule, a name no list knows among
+    it."""
+    from ray_tpu.models import shortconv_moe
+    returned, events = (traced_counters[k][family]
+                        for k in ("returned", "events"))
+    assert len(returned) == len(events)
+    for st, ev in zip(returned, events):
+        attrs = dict(ev[3])
+        assert attrs == {k: v for k, v in st.items()
+                         if isinstance(v, (int, float))
+                         and not isinstance(v, bool)}
+        assert {type(attrs[k]) for k in attrs} == {int, float}
+        assert not {"role", "spec", "per_class", "prefix_cache"} & set(attrs)
+        counted = set(shortconv_moe.COUNTS) | {
+            "expert_load_max_over_mean", MADE_UP}
+        assert counted & set(attrs) == (
+            counted if family == "counting" else set())
+    mid = events[1][3]
+    # a prompt's first token comes from its prefill
+    assert mid["decode_tokens"] == 6 and mid["prefill_tokens"] == 7
+    if family == "counting":
+        assert mid[MADE_UP] == 7 and mid["expert_tokens_routed"] > 0
+
+
+@pytest.mark.parametrize("family", ["dense", "counting"])
+def test_counters_after_a_reset_are_zero(traced_counters, family):
+    _, before, after = (e[3] for e in traced_counters["events"][family][:3])
+    for key in ("decode_tokens", "prefill_tokens", "ticks", "host_puts",
+                "decode_steps", "submits", "tick_s"):
+        assert before[key] > 0 and after[key] == 0, key
+    if family == "counting":
+        assert after["expert_tokens_routed"] == after["conv_rows_live"] == 0
+        assert after["expert_load_max_over_mean"] == 0.0
+    # not reset: identity, not rate
+    assert after["decode_traces"] == before["decode_traces"] == 1
+
+
+def test_stats_with_no_session_builds_nothing_for_the_span(monkeypatch):
+    """No profiler session: `stats()` is the dict it was, and the span's
+    attributes are never gathered: `set` is not reached."""
+    eng = tiny_engine()
+    list(eng.tokens_for(eng.submit([5, 9, 3], max_new_tokens=3)))
+    want = eng.stats()
+
+    def set_(self, **attrs):
+        raise AssertionError(f"attributes built with no session: {attrs}")
+
+    monkeypatch.setattr(telemetry._phase_class(), "set", set_)
+    assert not telemetry._phase_class().is_enabled()
+    assert eng.stats() == want
+    assert eng._phases.count(COUNTERS) == 2
+
+
+def test_the_counters_reader_takes_the_last_snapshot(traced_counters, traced,
+                                                     monkeypatch):
+    """`span_counter_ratio.read`: the last event over an earlier one, a
+    list summed, and nothing for a missing name, a zero divisor, a trace
+    without the span or no trace."""
+    from benchmarks.layer_metrics import span_counter_ratio, tick_events
+    ctx = {"trace": {"modules": {}}}
+    monkeypatch.setattr(tick_events, "find",
+                        lambda c: traced_counters["path"])
+    dense, counting = (traced_counters["returned"][k]
+                       for k in ("dense", "counting"))
+    last = counting[-1]
+    read = span_counter_ratio.read
+    assert read(ctx, "slots") == last["slots"] == 5 != dense[-1]["slots"]
+    assert read(ctx, "blocks_free", "cache_blocks") == \
+        last["blocks_free"] / last["cache_blocks"]
+    assert read(ctx, ["slots", "cache_blocks"], ["block_size", "slots"]) \
+        == (5 + last["cache_blocks"]) / (last["block_size"] + 5)
+    assert read(ctx, "no_such_counter") is None
+    assert read(ctx, ["slots", "no_such_counter"]) is None
+    assert read(ctx, "slots", "no_such_counter") is None
+    assert last["cancelled"] == 0
+    assert read(ctx, "slots", "cancelled") is None
+    assert read(ctx, "decode_tokens") == 3  # since the reset, not the start
+    monkeypatch.setattr(tick_events, "find", lambda c: traced["path"])
+    assert read(ctx, "slots") is None       # a program before the span
+    monkeypatch.setattr(tick_events, "find", lambda c: None)
+    assert read(ctx, "slots") is None
+
+
+COUNTER_METRICS = (
+    "state_fold_share", "kv_rows_per_state_read",
+    "serve_expert_load_max_over_mean", "expert_pairs_here_share",
+    "expert_tiles_per_expert", "identity_choice_share")
+
+
+@pytest.mark.parametrize("name", COUNTER_METRICS)
+def test_a_counter_s_metric_reads_what_its_cells_families_count(
+        name, traced_counters, monkeypatch):
+    """Each metric over the counters: `BENCHMARK.json`'s entry and the
+    file beside the reader say the same, every listed cell reports what
+    it moves, and every attribute the file's `args` name is the engine's
+    own tally or in the `counts` of the family that the cell's
+    configuration runs."""
+    from benchmarks import run as bench_run
+    from benchmarks.harness import common
+    from benchmarks.layer_metrics import tick_events
+    bench = bench_run.load_json("BENCHMARK.json")
+    entry = bench_run.by_name(bench["per_layer"], name, "per-layer metric")
+    spec = bench_run.load_json("benchmarks", "layer_metrics", f"{name}.json")
+    assert {k: spec[k] for k in entry} == entry
+    of, per = ([side] if isinstance(side, str) else side
+               for side in (spec["args"]["of"], spec["args"].get("per", [])))
+    reads = {*of, *per}
+    engine_s = set(re.findall(r"``([a-z0-9_]+)``",
+                              InferenceEngine.stats.__doc__))
+    reporting = bench_run.by_name(bench["end_to_end"], entry["moves"],
+                                  "metric")["workloads"]
+    for cell_name in entry["workloads"]:
+        assert cell_name in reporting
+        _, cell, config, _ = bench_run.load_cell(cell_name)
+        cfg = common.model_config(config, "serve")
+        counted = set(cfg.family.counts(cfg, None))
+        assert counted >= set(sys.modules[type(cfg).__module__].COUNTS)
+        assert reads <= counted | engine_s, (cell_name, reads - counted)
+        assert reads & counted, cell_name
+    # on this trace: the last snapshot of a family that counts its
+    # experts' work and neither folds nor identity choices
+    monkeypatch.setattr(tick_events, "find",
+                        lambda c: traced_counters["path"])
+    last = traced_counters["returned"]["counting"][-1]
+    want = None
+    if reads <= set(last):
+        want = sum(last[n] for n in of) / (
+            sum(last[n] for n in per) if per else 1)
+    assert (want is not None) == ("expert" in name)
+    assert bench_run.read_layer_metric(
+        name, {"trace": {"modules": {}}}) == want
