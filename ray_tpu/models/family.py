@@ -49,13 +49,14 @@ class ServingFamily(NamedTuple):
             program, for a family whose weights' read is what a program
             costs: each weight is read once for the rows of both. The
             engine calls it in a tick that holds decoders and a chunk
-            that does not end its prompt; the chunk's sequence is then
-            none of the step's (its slot's row is idle), so the two
-            write disjoint blocks and pages and either order of them is
-            the two programs' result. The chunk's logits are for a
-            caller that reads them (a chunk that ends its prompt, which
-            the engine does not fuse yet): a program that drops them
-            pays nothing for them. The kernels it calls carry names
+            of the full bucket, whether or not it ends its prompt; the
+            chunk's sequence is none of the step's (its slot's row is
+            idle, and joins the next program's step where the prompt
+            ended), so the two write disjoint blocks and pages and
+            either order of them is the two programs' result. The
+            chunk's logits are its last live row's (by `length`): the
+            engine samples them in the same program and reads the token
+            where the chunk ended its prompt. The kernels it calls carry names
             of their own: the benchmark's readers sum a kernel's seconds
             by name over a whole trace and divide by the runs of one
             program, so a kernel under the name it has in `prefill` or

@@ -547,6 +547,16 @@ class _ChunkInFlight:
 
 
 @dataclass
+class _PromptEnded:
+    """The chunk that ended a prompt inside a step's program
+    (`_enqueue_fused`): its first token comes with the step's."""
+    slot: int
+    rid: int
+    tok: object                   # device scalars, as a chunk's are: the
+    lp: object                    # next program's `chunk_tok` operand
+
+
+@dataclass
 class _StepInFlight:
     """A decode step between its enqueue and the read of its tokens."""
     nxt: object                   # device int32[slots]: the next
@@ -557,6 +567,7 @@ class _StepInFlight:
     chained: int                  # of them, marked FROM_STEP / FROM_CHUNK
     version: int                  # params_version it was computed under
     dispatch_s: float             # its `engine/decode_dispatch`
+    ended: _PromptEnded | None = None   # a fused chunk's prompt
 
 
 # --- a program's host-built input: one int32 array, one transfer --------
@@ -991,24 +1002,29 @@ class InferenceEngine:
 
         rows_len = rows_size(slots, max_blocks)
 
-        def _tick(params, cache, inputs, key, prev):
+        def _tick(params, cache, inputs, key, prev, chunk_tok):
             """`_decode` and `_prefill` as one program (`fam.tick`), for
             a tick's step and its chunk of another sequence: `inputs` is
-            the step's packed rows, then the chunk's. The step samples
-            as in `_decode`; the chunk does not end its prompt, so its
-            logits are not asked for and XLA keeps nothing of them."""
+            the step's packed rows, then the chunk's. Each samples as in
+            its own program: the step's rows, resolved as `_decode`
+            resolves them (the tick before may have ended a prompt in
+            this program too), and the chunk's last live row, whose
+            token is read where the chunk ends its prompt."""
             self.tick_traces += 1
             with jax.named_scope(EMBED):
                 tokens, pos, temps, tables, step = unpack_rows(
                     inputs[:rows_len], slots)
-                tokens = jnp.where(tokens == FROM_STEP, prev, tokens)
-                chunk_tokens, table, start, length, _, _ = unpack_chunk(
-                    inputs[rows_len:], max_blocks)
-            _, logits, cache, counts = fam.tick(
+                tokens = jnp.where(
+                    tokens == FROM_STEP, prev,
+                    jnp.where(tokens == FROM_CHUNK, chunk_tok, tokens))
+                chunk_tokens, table, start, length, temp, chunk_step = \
+                    unpack_chunk(inputs[rows_len:], max_blocks)
+            last, logits, cache, counts = fam.tick(
                 params, chunk_tokens, tokens, cache, pos, tables, cfg, mesh,
                 block_table=table, start=start, length=length)
             tok, logp = _sample(logits, temps, key, step)
-            return tok, logp, cache, counts
+            ended, ended_lp = _sample(last, temp[None], key, chunk_step)
+            return tok, logp, cache, counts, ended[0], ended_lp[0]
 
         def _verify(params, cache, inputs, key):
             """One batched W-token forward + in-jit accept/correct.
@@ -1073,9 +1089,9 @@ class InferenceEngine:
         # Cache donation: the [L, n_blocks, bs, H, D] pool is by far the
         # engine's biggest array; donating it lets XLA alias input to
         # output so every step updates the pool in place in HBM.
-        # The two `tok` are laid out as the packed input is, so a step
-        # takes the one compile whether its `prev` / `chunk_tok` came
-        # from a program or are the placeholders below.
+        # Every `tok` (a fused program's two) is laid out as the packed
+        # input is, so a step takes the one compile whether its `prev` /
+        # `chunk_tok` came from a program or are the placeholders below.
         tok_first = (self._io_sh, None, None, None)
         self._prefill_fn = jax.jit(_prefill, donate_argnums=(2,),
                                    out_shardings=tok_first)
@@ -1090,19 +1106,19 @@ class InferenceEngine:
         # decoders and another request's chunk in one tick), so a replica
         # that warms up a request at a time would compile it under its
         # first load, every stream waiting. It is compiled from here on
-        # (`_compile_ahead`), for the one shape it has (the full chunk),
-        # and called as the others are. A `role="prefill"` engine never
-        # decodes and has no use for it.
+        # (`_compile_ahead`), for the one shape it has (the full bucket;
+        # `start` and `length` are data), and called as the others are.
+        # A `role="prefill"` engine never decodes and has no use for it.
         self._tick_fn = None
         if fam.tick is not None and spec is None and role != "prefill":
             tick_jit = jax.jit(_tick, donate_argnums=(1,),
-                               out_shardings=tok_first)
+                               out_shardings=(*tok_first, self._io_sh, None))
             self._tick_fn = _compile_ahead(
                 tick_jit, self.params, self.cache, jax.device_put(
                     np.zeros(rows_len + chunk_size(self.prefill_chunk,
                                                    max_blocks), np.int32),
                     self._io_sh),
-                self._base_key, self._no_prev)
+                self._base_key, self._no_prev, self._no_chunk_tok)
         self._copy_fn = jax.jit(fam.copy_block, donate_argnums=(0,))
         self._verify_fn = (jax.jit(_verify, donate_argnums=(1,))
                            if spec is not None else None)
@@ -1282,8 +1298,9 @@ class InferenceEngine:
         # time from the end of `engine/prefill_chunk` to the token's read
         self._chunks_overlapped = 0
         self._chunk_tail_s = 0.0
-        # ticks whose chunk and step were one program (`_tick_fn`)
-        self._ticks_fused = 0
+        # ticks whose chunk and step were one program (`_tick_fn`), and
+        # those of them whose chunk ended its prompt
+        self._ticks_fused = self._ticks_fused_last = 0
         self._prefix_hit_tokens = 0
         self._prompt_tokens = 0
         self._cow_copies = 0
@@ -1702,8 +1719,10 @@ class InferenceEngine:
         anything was released."""
         with self._before_pump(), self._lock:
             self._take_inbox()
-            if self._flight is not None and \
-                    rid in self._flight.rows.values():
+            flight = self._flight
+            if flight is not None and (
+                    rid in flight.rows.values()
+                    or flight.ended is not None and flight.ended.rid == rid):
                 # its token in flight is read (and dropped with its
                 # queue) before its blocks go
                 self._rest()
@@ -2693,14 +2712,7 @@ class InferenceEngine:
                 self._draft_alloc is not None
                 and s.draft_filled < s.prompt.size):
             return
-        # Prefill complete: publish the prompt's full blocks to the
-        # radix tree (decode writes only past them, so they are
-        # immutable), then join the decode batch. A slot admitted under
-        # an older params_version spanned a hot-swap mid-prefill — its
-        # K/V mixes weight versions and must NOT enter the prefix cache.
-        if self._tree is not None and s.prompt.size >= self.block_size \
-                and s.version == self._params_version:
-            self._tree.insert(s.prompt, s.blocks)
+        self._publish_prompt(s)
         if self.role == "prefill":
             # Disaggregated handoff: the first token is sampled (TTFT
             # closes HERE — the decode side never re-counts it), then
@@ -2714,6 +2726,22 @@ class InferenceEngine:
                 self._recorder.on_first_token(s.rid, wait)
             self._export_handoff(slot_idx)
             return
+        self._first_token(slot_idx)
+
+    def _publish_prompt(self, s: _Slot) -> None:
+        """Prefill complete: publish the prompt's full blocks to the
+        radix tree (decode writes only past them, so they are
+        immutable). A slot admitted under an older params_version
+        spanned a hot-swap mid-prefill — its K/V mixes weight versions
+        and must NOT enter the prefix cache."""
+        if self._tree is not None and s.prompt.size >= self.block_size \
+                and s.version == self._params_version:
+            self._tree.insert(s.prompt, s.blocks)
+
+    def _first_token(self, slot_idx: int) -> None:
+        """A slot whose prompt is absorbed and whose first token is
+        parked on it joins the decode batch and emits that token."""
+        s = self._slots[slot_idx]
         s.phase = "decode"
         s.pos = s.prompt.size
         s.remaining -= 1
@@ -2732,9 +2760,14 @@ class InferenceEngine:
                 if s.phase == "decode"]
 
     def _next_prefilling(self) -> int | None:
-        """The prefilling slot admitted first: whose chunk runs next."""
+        """The prefilling slot admitted first: whose chunk runs next.
+        Not the slot whose prompt ended in the program in flight
+        (`_enqueue_fused`): it has no chunk left and turns to `decode`
+        where that flight is read."""
+        ended = self._flight.ended if self._flight is not None else None
         return min((i for i, s in enumerate(self._slots)
-                    if s.phase == "prefill"),
+                    if s.phase == "prefill"
+                    and (ended is None or i != ended.slot)),
                    key=lambda i: self._slots[i].order, default=None)
 
     def _prefill_tick(self, had_decoders: bool) -> bool:
@@ -2763,14 +2796,18 @@ class InferenceEngine:
         their enqueue with no gap; no step reads what a chunk of another
         slot writes, so the order changes no stream's tokens. A slot
         whose prompt ends here emits its first token in this tick.
-        Where the family offers `tick` and the chunk does not end its
-        prompt, chunk and step are ONE program (`_enqueue_fused`), which
-        reads every weight once: the chunk emits nothing, so nothing of
-        it is read and it keeps no tick open. Returns the seconds the
-        chunk kept the tick open past the emit."""
+        Where the family offers `tick` and the chunk is of the full
+        bucket (the fused program's one shape), whether or not it ends
+        its prompt, chunk and step are ONE program (`_enqueue_fused`),
+        which reads every weight once: nothing of the chunk is read in
+        this tick and it keeps no tick open; the token of one that ends
+        its prompt comes with the step's, a tick later. Returns the
+        seconds the chunk kept the tick open past the emit."""
         s = self._slots[slot_idx]
         if self._tick_fn is not None and self._step_follows() \
-                and s.filled + self.prefill_chunk < s.prompt.size:
+                and self._chunk_bucket_for(min(
+                    self.prefill_chunk, s.prompt.size - s.filled)) \
+                == self.prefill_chunk:
             self._chain_tick(fused=slot_idx)
             return 0.0
         flights = []
@@ -2837,7 +2874,8 @@ class InferenceEngine:
         (`_chain_tick`): the last tick left step t enqueued and unread,
         and this one enqueues ONE prefill chunk if a prompt waits, then
         step t+1 (the two as one program where the family offers `tick`
-        and the chunk does not end its prompt: `_enqueue_fused`), whose
+        and the chunk is of the full bucket: `_enqueue_fused`; one that
+        ends its prompt there has its token read with step t+1's), whose
         continuing rows take their tokens from step t's
         output where it lies, and only then waits for step t's tokens,
         emits them, and waits for the chunk's; so the host's work of a
@@ -2845,7 +2883,8 @@ class InferenceEngine:
         decoding, every pending chunk runs, each waited for, and the
         sequences that thereby start decoding have their first step
         enqueued in the same tick. `step()` returns with AT MOST ONE
-        DECODE STEP enqueued and unread, and no other result:
+        DECODE STEP enqueued and unread (a fused chunk's token is an
+        output of that step's program), and no other result:
         `update_params`, `cancel`, preemption, a hand-off's export,
         `check_invariants` and `run_until_idle`'s end read it first
         (`_rest`). The speculative tick proposes from the tokens on the
@@ -2881,6 +2920,7 @@ class InferenceEngine:
                     if not had_decoders and self._flight is not None:
                         # every row of it has ended since (on eos_id):
                         # nothing to chain behind it, its tokens dropped
+                        # (a prompt that ended in it decodes from here)
                         flight, self._flight = self._flight, None
                         self._read_step(flight)
                     with phase("engine/admit") as admit:
@@ -2983,7 +3023,8 @@ class InferenceEngine:
         `max_len` is left out (one that ends on `eos_id` is known a
         step late: `_read_step`), and any other decoding slot joins from
         the host as ever. `joining` is the slot whose prompt's last
-        chunk is enqueued and unread: its row is marked `FROM_CHUNK`."""
+        chunk is enqueued and unread (a program of its own before this
+        step, or inside `flight`'s): its row is marked `FROM_CHUNK`."""
         slots = self.num_slots
         tokens = np.zeros((slots,), np.int32)
         pos = np.zeros((slots,), np.int32)
@@ -3031,14 +3072,16 @@ class InferenceEngine:
                 self._decode_steps)
 
     def _enqueue_step(self, behind=None, joining=None, chunk=None,
-                      built=None, fused=None) -> _StepInFlight:
+                      built=None, fused=None, ends=None) -> _StepInFlight:
         """Build (`_batch_arrays`, or `built`: its result), put and
         dispatch one decode step; nothing is waited for. `behind` is
         the step enqueued and unread whose output the `FROM_STEP` rows
         read (this step's key takes the counter after that step's) and
         `chunk` the chunk in flight whose token the `joining` slot's
         row reads. `fused` is a packed chunk (`pack_chunk`) that rides
-        in the step's program (`_enqueue_fused`)."""
+        in the step's program (`_enqueue_fused`), and `ends` the
+        (slot, rid) of the prompt it ends, if it ends one: the flight
+        then carries that chunk's token beside the step's."""
         phase = self._phases.phase
         with phase("engine/decode_build"):
             (tokens, pos, tables, temps), rows = (
@@ -3047,14 +3090,11 @@ class InferenceEngine:
                 tokens, pos, tables, temps,
                 self._decode_steps + (behind is not None), fused)
         with phase("engine/decode_dispatch") as dispatch:
-            prev = self._no_prev if behind is None else behind.nxt
-            if fused is None:
-                nxt, lps, self.cache, counts = self._decode_fn(
-                    self.params, self.cache, inputs, self._base_key, prev,
-                    self._no_chunk_tok if joining is None else chunk.tok)
-            else:
-                nxt, lps, self.cache, counts = self._tick_fn(
-                    self.params, self.cache, inputs, self._base_key, prev)
+            program = self._decode_fn if fused is None else self._tick_fn
+            nxt, lps, self.cache, counts, *last = program(
+                self.params, self.cache, inputs, self._base_key,
+                self._no_prev if behind is None else behind.nxt,
+                self._no_chunk_tok if joining is None else chunk.tok)
             bound = self._family.bounded_tokens
             if bound and dispatch.is_enabled():
                 # what the step's bounded pages hold of each decoding
@@ -3064,7 +3104,8 @@ class InferenceEngine:
                     min(int(pos[i]) + 1, bound) for i in rows))
         return _StepInFlight(nxt, lps, counts, rows,
                              int(np.count_nonzero(tokens < 0)),
-                             self._params_version, dispatch.seconds)
+                             self._params_version, dispatch.seconds,
+                             ends and _PromptEnded(*ends, *last))
 
     def _read_step(self, flight: _StepInFlight) -> None:
         """Wait for a step's tokens and emit them, each stamped with the
@@ -3080,6 +3121,11 @@ class InferenceEngine:
             # graftlint: disable-next-line=R001,R004 same sync as nxt above — lps arrives in the same device batch, so this is a no-cost host view
             lps = np.asarray(flight.lps)
             self._add_counts(flight.counts)
+            ended = flight.ended
+            if ended is not None:
+                # outputs of the program `nxt` waited for: casts, not a
+                # second round-trip
+                ended.tok, ended.lp = int(ended.tok), float(ended.lp)
         live = [i for i, rid in flight.rows.items()
                 if self._slots[i].rid == rid]
         dt = flight.dispatch_s + sync.seconds
@@ -3088,7 +3134,7 @@ class InferenceEngine:
         self._decode_tokens += len(live)
         self._decode_slot_steps += len(live)
         self._tok_window.append((dt, len(live)))
-        with phase("engine/emit", tokens=len(live)):
+        with phase("engine/emit", tokens=len(live) + (ended is not None)):
             try:
                 for i in live:
                     s = self._slots[i]
@@ -3103,53 +3149,98 @@ class InferenceEngine:
                 # host, as after any failed emit
                 self._flight = None
                 raise
+            finally:
+                # whatever the emit raised, the prompt that ended in
+                # this program is not left without its token: parked on
+                # its slot (which nothing releases before this read), it
+                # is emitted as `_finish_chunk` emits a chunk's
+                if ended is not None:
+                    s = self._slots[ended.slot]
+                    s.token, s.token_logp = ended.tok, ended.lp
+                    s.token_ver = flight.version
+                    self._first_token(ended.slot)
 
     def _rest(self) -> None:
         """Under `_lock`: read the decode step in flight, if there is
-        one, so that nothing is enqueued and unread. For whoever must
-        find the engine at rest between two ticks (`chain_drains`)."""
+        one (and with it the first token of a prompt that ended in its
+        program), so that nothing is enqueued and unread. For whoever
+        must find the engine at rest between two ticks
+        (`chain_drains`)."""
         flight, self._flight = self._flight, None
         if flight is not None:
             self._chain_drains += 1
             self._read_step(flight)
 
-    def _enqueue_fused(self, behind, slot_idx: int) -> _StepInFlight:
+    def _enqueue_fused(self, behind, slot_idx: int, joining=None,
+                       chunk=None) -> _StepInFlight:
         """The tick's decode step and the next chunk of `slot_idx`'s
-        prompt, which does not end it (so it is a full one), as one
-        program (`ServingFamily.tick`) inside `engine/tick_fused`. The
-        flight is the step's: the chunk emits nothing, so the program
-        samples no token for it (its temperature and counter ride as
-        zeros), its counts come with the step's, the tick ends with
-        nothing unread but the flight and the next chains behind it as
-        behind a step. What `_finish_chunk` does once a chunk's token is
-        read is done here, at the enqueue: device order is enqueue
-        order, so whatever follows finds the chunk written. The span
-        carries what `engine/prefill_chunk` does (`tokens`, `bucket`,
-        `start`: a reader of the chunk's attention needs them)."""
+        prompt, one of the full bucket, as one program
+        (`ServingFamily.tick`) inside `engine/tick_fused`. The flight is
+        the step's: the chunk's counts come with the step's, the tick
+        ends with nothing unread but the flight and the next chains
+        behind it as behind a step. What `_finish_chunk` does without a
+        chunk's token is done here, at the enqueue: device order is
+        enqueue order, so whatever follows finds the chunk written.
+        Where the chunk ends its prompt the flight carries its token
+        (`_PromptEnded`: sampled under the chunk's temperature and the
+        counter `_start_chunk` would give it): the slot stays out of
+        this program's rows, joins the next as `FROM_CHUNK`
+        (`_joining_behind`) and turns to `decode` where the flight is
+        read (`_read_step`), one tick after a chunk of its own program
+        would have. The span carries what `engine/prefill_chunk` does
+        (`tokens`, the live ones; `bucket`; `start`: a reader of the
+        chunk's attention needs them) and `ends_prompt`."""
         s = self._slots[slot_idx]
-        clen = self.prefill_chunk
+        clen = min(self.prefill_chunk, s.prompt.size - s.filled)
+        ends = s.filled + clen >= s.prompt.size
         with self._phases.phase("engine/tick_fused", tokens=clen,
-                                bucket=clen, start=s.filled) as span:
-            flight = self._enqueue_step(behind, fused=pack_chunk(
-                s.prompt[s.filled:s.filled + clen], clen, s.table, s.filled,
-                0.0, 0))
-        self._recorder.on_prefill_chunk(s.rid, clen, clen, span.seconds)
+                                bucket=self.prefill_chunk, start=s.filled,
+                                ends_prompt=int(ends)) as span:
+            flight = self._enqueue_step(
+                behind, joining, chunk,
+                fused=pack_chunk(
+                    s.prompt[s.filled:s.filled + clen], self.prefill_chunk,
+                    s.table, s.filled, s.temperature,
+                    self._decode_steps + (behind is not None) - 1),
+                ends=(slot_idx, s.rid) if ends else None)
+        self._recorder.on_prefill_chunk(s.rid, clen, self.prefill_chunk,
+                                        span.seconds)
         self._prefill_tokens += clen
         self._prefill_chunks += 1
         self._ticks_fused += 1
         s.filled += clen
+        if ends:
+            self._ticks_fused_last += 1
+            self._publish_prompt(s)
         return flight
+
+    def _joins(self, s: _Slot) -> bool:
+        """`_finish_chunk`'s verdict on a slot whose prompt's last chunk
+        is enqueued and unread, from what the host knows without its
+        token: whether the slot decodes past it."""
+        return s.remaining > 1 and s.prompt.size + 1 < self.max_len
+
+    def _joining_behind(self, behind) -> int | None:
+        """The slot whose prompt ended inside `behind`, the step in
+        flight, where it decodes on: the step enqueued behind it holds
+        its row."""
+        if behind is None or behind.ended is None \
+                or not self._joins(self._slots[behind.ended.slot]):
+            return None
+        return behind.ended.slot
 
     def _step_follows(self) -> bool:
         """Whether a plain tick enqueues a decode step: where none is in
         flight, a slot decodes; behind one in flight, where a decoding
         slot joins or goes on past the token in flight (else every
-        stream ends with that token)."""
+        stream ends with that token) or a prompt that ended in its
+        program decodes on."""
         behind = self._flight
-        return behind is None or any(
-            s.phase == "decode" and (behind.rows.get(i) != s.rid
-                                     or self._goes_on(s))
-            for i, s in enumerate(self._slots))
+        return behind is None \
+            or self._joining_behind(behind) is not None or any(
+                s.phase == "decode" and (behind.rows.get(i) != s.rid
+                                         or self._goes_on(s))
+                for i, s in enumerate(self._slots))
 
     def _chain_tick(self, chunk_slot: int | None = None,
                     start_chunk=None, fused: int | None = None) -> None:
@@ -3165,24 +3256,26 @@ class InferenceEngine:
         device runs chunk, step, chunk, step either way. The step is
         left unread; the chunk's token is the caller's to read
         (`_decode_with_chunk`). `fused` is the slot whose chunk rides in
-        the step's own program instead (`_enqueue_fused`)."""
+        the step's own program instead (`_enqueue_fused`). A step has
+        one `chunk_tok`: where a prompt ended inside the step in flight
+        its slot is the one that joins, and the slot of this tick's own
+        last chunk joins from the host a tick later, its token read."""
         behind = self._flight
-        chunk = joining = None
+        joining = self._joining_behind(behind)
+        chunk = behind.ended if joining is not None else None
 
         def enqueue():
             if fused is not None:
-                return self._enqueue_fused(behind, fused)
+                return self._enqueue_fused(behind, fused, joining, chunk)
             return self._enqueue_step(behind, joining, chunk)
 
         if start_chunk is not None:
-            chunk = start_chunk()
+            started = start_chunk()
             s = self._slots[chunk_slot]
-            # `_finish_chunk`'s verdict, from what the host knows now
-            if chunk is not None \
-                    and s.filled + chunk.tokens >= s.prompt.size \
-                    and s.remaining > 1 \
-                    and s.prompt.size + 1 < self.max_len:
-                joining = chunk_slot
+            if joining is None and started is not None \
+                    and s.filled + started.tokens >= s.prompt.size \
+                    and self._joins(s):
+                joining, chunk = chunk_slot, started
         if behind is None:
             self._flight = enqueue()
             return
@@ -3456,7 +3549,7 @@ class InferenceEngine:
             self._recorder.deliver_waits.clear()
             self._prefill_chunks = self._chunks_overlapped = 0
             self._chunk_tail_s = 0.0
-            self._ticks_fused = 0
+            self._ticks_fused = self._ticks_fused_last = 0
             self._prefix_hit_tokens = self._prompt_tokens = 0
             self._cow_copies = self._evicted_blocks = 0
             self._bounded_reused = 0
@@ -3545,14 +3638,18 @@ class InferenceEngine:
           the device's queue and built while the device ran it (their
           `engine/prefill_chunk` carries ``overlapped=1``): every chunk
           of a tick that held a decoder, none of a tick that held none,
-          less the fused ones.
+          less the fused ones: where the family offers `tick`, the last
+          chunks of a smaller bucket than the full one.
           ``ticks_fused`` — ticks whose chunk and decode step were ONE
           program (`ServingFamily.tick`, inside `engine/tick_fused`):
-          where the family offers it, every chunk of a tick that held a
-          decoder and did not end its prompt. Such a chunk is counted in
-          ``prefill_chunks`` and ``prefill_tokens`` at its enqueue, no
-          token is sampled for it, and its device time lies in the step's
-          (``decode_time_s``), not in ``prefill_time_s``.
+          where the family offers it, every chunk of the full bucket in
+          a tick that held a decoder. Such a chunk is counted in
+          ``prefill_chunks`` and ``prefill_tokens`` at its enqueue, and
+          its device time lies in the step's (``decode_time_s``), not in
+          ``prefill_time_s``. ``ticks_fused_last`` — those of them whose
+          chunk ended its prompt: its first token is read with the
+          step's tokens, a tick later, and its slot's first decode row
+          takes it where it lies (`FROM_CHUNK`).
           ``slot_occupancy`` — mean fraction of slots active per tick.
           ``p50_token_latency_ms`` / ``p99_token_latency_ms`` —
           percentiles over a 512-step window of a step's dispatch plus
@@ -3845,6 +3942,7 @@ class InferenceEngine:
                 "prefill_chunks": self._prefill_chunks,
                 "chunks_overlapped": self._chunks_overlapped,
                 "ticks_fused": self._ticks_fused,
+                "ticks_fused_last": self._ticks_fused_last,
                 "tick_traces": self.tick_traces,
                 "slot_occupancy": (sum(occ) / len(occ)) if occ else 0.0,
                 "p50_token_latency_ms": pct(50),

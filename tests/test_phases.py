@@ -393,15 +393,16 @@ def test_the_put_spans_say_one_put_each(session, request):
 
 # -- a step and a chunk as one program (`ServingFamily.tick`) -----------------
 
-def fusing_engine(**kw):
+def fusing_engine(buckets=(8,), **kw):
     """An engine over the short-convolution family, which offers `tick`:
-    chunks of 8, five slots."""
+    chunks of 8 (one bucket, so every chunk is of the full one), five
+    slots."""
     from ray_tpu.models import shortconv_moe
     cfg = shortconv_moe.ShortConvMoEConfig(
         dtype="float32", attn_impl="jax", sparse_impl="jax")
     params = shortconv_moe.init_params(jax.random.PRNGKey(0), cfg)
     return InferenceEngine(params, cfg, slots=5, max_len=64, block_size=8,
-                           prefill_chunk=8, prefill_buckets=(8,),
+                           prefill_chunk=8, prefill_buckets=buckets,
                            prefix_cache=False, **kw), params
 
 
@@ -416,7 +417,8 @@ JOINERS = [(5, 0.0), (13, 0.8), (37, 0.0)]
 def mixed_run(eng, trace_to=None):
     """Two streams decode, one of them at a temperature; then the three
     `JOINERS` arrive at once, so that every one of their eight chunks
-    shares its tick with a decode step. -> (tokens a stream, stats)."""
+    shares its tick with a decode step. -> ([(token, logprob)] a stream,
+    the two residents' first, stats)."""
     rids = [eng.submit(tokens_of(6, 1), max_new_tokens=30),
             eng.submit(tokens_of(4, 2), max_new_tokens=30, temperature=0.7)]
     for _ in range(3):
@@ -453,27 +455,46 @@ def fused_and_not(tmp_path_factory):
 
 
 def test_a_fused_tick_gives_the_two_programs_tokens(fused_and_not):
-    """The same tokens in the same order a stream, greedy and sampled;
-    every overlapped chunk that does not end its prompt is fused, the
-    three that do are not; a fused step is chained as a step is."""
+    """The same tokens in the same order a stream; with one bucket every
+    overlapped chunk is fused, the three that end a prompt too; a fused
+    step is chained as a step is. A sampled joiner decodes a step later
+    than behind a chunk of its own program, so under other keys: its
+    first token is the two programs' (the chunk's key is the same) and
+    every token's logprob is the plain forward's of its own stream."""
+    from ray_tpu.models import shortconv_moe
     (got, st), (want, two) = (fused_and_not[k] for k in (
         "fused", "two_programs"))
-    for mine, theirs in zip(got, want):
+    sampled = 2 + [t > 0 for _, t in JOINERS].index(True)
+    for n, (mine, theirs) in enumerate(zip(got, want)):
+        if n == sampled:
+            assert mine[0][0] == theirs[0][0] and len(mine) == len(theirs)
+            continue
         assert [t for t, _ in mine] == [t for t, _ in theirs]
         np.testing.assert_allclose([lp for _, lp in mine],
                                    [lp for _, lp in theirs], rtol=0,
                                    atol=1e-5)
+    eng, params = fusing_engine()
+    prompt = tokens_of(JOINERS[sampled - 2][0], 10 + JOINERS[sampled - 2][0])
+    stream = [t for t, _ in got[sampled]]
+    logits = shortconv_moe.forward(
+        params, jnp.asarray([prompt + stream], jnp.int32), eng.cfg)
+    natural = jax.nn.log_softmax(logits[0].astype(jnp.float32), axis=-1)
+    np.testing.assert_allclose(
+        [lp for _, lp in got[sampled]],
+        [natural[len(prompt) - 1 + i, t] for i, t in enumerate(stream)],
+        rtol=0, atol=1e-4)
     ended = len(JOINERS)        # chunks that end a prompt, all overlapped
     assert two["ticks_fused"] == two["tick_traces"] == 0
     assert two["chunks_overlapped"] == two["prefill_chunks"] == 8
-    assert st["ticks_fused"] == two["chunks_overlapped"] - ended == 5
-    assert st["chunks_overlapped"] == ended and st["prefill_chunks"] == 8
+    assert st["ticks_fused"] == st["prefill_chunks"] == 8
+    assert st["ticks_fused_last"] == ended and two["ticks_fused_last"] == 0
+    assert st["chunks_overlapped"] == 0
     assert st["tick_traces"] == 1 and st["retraces_unexpected"] == 0
     for key in ("steps_chained", "decode_steps", "decode_tokens",
                 "prefill_tokens", "chain_drains"):
         assert st[key] == two[key], key
     # one transfer a program
-    assert st["host_puts"] == st["decode_steps"] + ended
+    assert st["host_puts"] == st["decode_steps"]
     assert two["host_puts"] == two["decode_steps"] + 8
     # what the programs counted of rows is what two programs count
     for key in ("conv_rows_live", "state_resets", "attention_rows_read",
@@ -486,25 +507,36 @@ def test_a_fused_tick_waits_for_no_chunk(fused_and_not):
     """`engine/tick_fused` holds the step's build, put and dispatch and
     lies inside the tick's `engine/decode_chain`; its tick opens no
     `engine/prefill_chunk` and waits for no chunk's token
-    (`engine/prefill_sync`): what it reads is the step the tick before
-    left."""
+    (`engine/prefill_sync`), not where the chunk ended its prompt
+    either: what it reads is the step the tick before left. The span
+    says the chunk's live tokens, where it starts and whether it ends
+    its prompt."""
     ev, (_, st) = fused_and_not["events"], fused_and_not["fused"]
     fused = sorted(ev["engine/tick_fused"], key=lambda e: e[1])
-    assert len(fused) == st["ticks_fused"] == 5
-    assert [(f[3]["tokens"], f[3]["bucket"]) for f in fused] == [(8, 8)] * 5
-    assert sorted(f[3]["start"] for f in fused) == [0, 0, 8, 16, 24]
+    assert len(fused) == st["ticks_fused"] == 8
+    # the joiners in the order of their admission: 5, 13 and 37 tokens
+    assert [(f[3]["tokens"], f[3]["bucket"], f[3]["start"],
+             f[3]["ends_prompt"]) for f in fused] == [
+        (5, 8, 0, 1), (8, 8, 0, 0), (5, 8, 8, 1), (8, 8, 0, 0),
+        (8, 8, 8, 0), (8, 8, 16, 0), (8, 8, 24, 0), (5, 8, 32, 1)]
+    assert sum(f[3]["ends_prompt"] for f in fused) == st["ticks_fused_last"]
     ticks = [t for t in ev["engine/tick"] if any(inside(f, [t])
                                                  for f in fused)]
-    assert len(ticks) == 5
-    assert all(inside(f, ev["engine/decode_chain"]) for f in fused)
+    assert len(ticks) == 8
+    # the session's first tick found the engine at rest (`reset_stats`):
+    # its program chains behind nothing and the tick reads nothing
+    assert [inside(f, ev["engine/decode_chain"]) for f in fused] == [
+        False] + [True] * 7
     for name in ("decode_build", "decode_put", "decode_dispatch"):
         assert all(sum(inside(e, [f]) for e in ev[f"engine/{name}"]) == 1
                    for f in fused), name
     for name in ("prefill_chunk", "prefill_build", "prefill_sync"):
-        assert not any(inside(e, ticks) for e in ev[f"engine/{name}"]), name
+        assert not any(inside(e, ticks)
+                       for e in ev.get(f"engine/{name}", ())), name
+    ticks.sort(key=lambda t: t[1])
     for name in ("token_sync", "emit"):
-        assert all(sum(inside(e, [t]) for e in ev[f"engine/{name}"]) == 1
-                   for t in ticks), name
+        assert [sum(inside(e, [t]) for e in ev[f"engine/{name}"])
+                for t in ticks] == [0] + [1] * 7, name
     assert len(ev["engine/decode_chain"]) == st["steps_chained"]
 
 
@@ -517,7 +549,7 @@ def test_the_fused_share_is_a_metric_of_the_benchmark(fused_and_not,
     got = bench_run.read_layer_metric("ticks_fused_share",
                                       {"trace": {"modules": {}}})
     ev = fused_and_not["events"]
-    assert got == pytest.approx(100.0 * 5 / len(ev["engine/tick"]))
+    assert got == pytest.approx(100.0 * 8 / len(ev["engine/tick"]))
 
 
 def test_the_fused_program_is_compiled_before_a_load_and_nothing_under_it():
@@ -536,7 +568,7 @@ def test_the_fused_program_is_compiled_before_a_load_and_nothing_under_it():
     assert "jit(_tick)" in watch.names
     warmed = watch.programs()
     _, st = mixed_run(eng)
-    assert st["ticks_fused"] == 5
+    assert st["ticks_fused"] == 8 and st["ticks_fused_last"] == 3
     assert watch.programs() == warmed, watch.names
     assert st["tick_traces"] == st["decode_traces"] == 1
 
@@ -582,17 +614,20 @@ FUSED_METRICS = ("ticks_fused_share", "tick_mixer_ms", "tick_ffn_ms",
                  "tick_head_ms", "tick_compiler_ms", "lfm2_experts_tick_ms",
                  "lfm2_experts_tick_roofline", "lfm2_gqa_decode_tick_ms",
                  "lfm2_gqa_decode_tick_roofline", "lfm2_gqa_chunk_tick_ms",
-                 "lfm2_gqa_chunk_tick_roofline")
+                 "lfm2_gqa_chunk_tick_roofline", "chunks_fused_share")
+# read off the host's spans and counters, not off a program's ops
+HOST_METRICS = ("ticks_fused_share", "chunks_fused_share")
 
 
 @pytest.mark.parametrize("name", FUSED_METRICS)
 def test_a_fused_tick_s_metric_is_an_entry_with_a_file(name, fused_and_not,
                                                        monkeypatch):
     """Each metric the fused tick brought: `BENCHMARK.json`'s entry and
-    the file beside the readers say the same (the cell's list too), and
-    on a trace that holds no `jit__tick` (a family without `tick`, the
-    parent of the PR that brought them) the device readers find nothing
-    and do not raise."""
+    the file beside the readers say the same (the cell's list too), the
+    file's reducer is a module beside it, and on a trace that holds no
+    `jit__tick` (a family without `tick`, the parent of the PR that
+    brought them) the device readers find nothing and do not raise."""
+    import importlib
     import json
     from benchmarks import run as bench_run
     from benchmarks.harness import spans
@@ -606,7 +641,9 @@ def test_a_fused_tick_s_metric_is_an_entry_with_a_file(name, fused_and_not,
     assert entry["workloads"] == ["lfm2-8b-a1b.chat-closed192"]
     assert entry["moves"] == "serve_tokens_per_s"
     assert " over " in spec["what"]         # what it divides by what
-    if name != "ticks_fused_share":
+    assert callable(importlib.import_module(
+        f"benchmarks.layer_metrics.{spec['reducer']}").read)
+    if name not in HOST_METRICS:
         assert spec["args"].get("module") == "jit__tick"
         monkeypatch.setattr(spans, "summary", lambda ctx: spans.reduce(
             fused_and_not["path"]))
@@ -651,6 +688,178 @@ def test_a_fused_tick_leaves_nothing_unread_but_its_flight():
             eng.check_invariants()      # reads the flight
     assert [int(t) for t in eng.tokens_for(resident)] == undisturbed
     assert eng.stats()["ticks_fused"] == 2
+
+
+def alone(n, **kw):
+    """The greedy stream of `tokens_of(n, 10 + n)` with the engine to
+    itself: what a joiner's tokens have to be in any company."""
+    eng, _ = fusing_engine()
+    return [int(t) for t in eng.tokens_for(
+        eng.submit(tokens_of(n, 10 + n), **kw))]
+
+
+def beside_a_resident(eng):
+    """A stream that decodes for 40 tokens, two steps in."""
+    resident = eng.submit(tokens_of(6, 1), max_new_tokens=40)
+    for _ in range(3):
+        eng.step()
+    return resident
+
+
+@pytest.mark.parametrize("n, fused, fused_last, overlapped", [
+    (16, 2, 1, 0), (13, 2, 1, 0), (11, 1, 0, 1), (5, 1, 1, 0), (3, 0, 0, 1)],
+    ids=["two-full", "full-then-5-of-8", "full-then-3-of-4", "5-of-8",
+         "3-of-4"])
+def test_a_last_chunk_fuses_where_its_bucket_is_the_full_one(
+        n, fused, fused_last, overlapped):
+    """Buckets of 4 and 8: the fused program has the full bucket's shape
+    alone, so a prompt's last chunk rides in it where it pads to 8 and
+    goes before the step as a program of its own where it pads to 4;
+    either way the stream is the one the request gets alone."""
+    eng, _ = fusing_engine(buckets=(4, 8))
+    beside_a_resident(eng)
+    before = eng.stats()
+    rid = eng.submit(tokens_of(n, 10 + n), max_new_tokens=6)
+    got = [int(t) for t in eng.tokens_for(rid)]
+    after = eng.stats()
+    assert [after[k] - before[k] for k in (
+        "ticks_fused", "ticks_fused_last", "chunks_overlapped")] == [
+        fused, fused_last, overlapped]
+    assert after["tick_traces"] == 1 and after["retraces_unexpected"] == 0
+    assert got == alone(n, max_new_tokens=6)
+    eng.check_invariants()
+
+
+def fused_last_in_flight(n=5, **kw):
+    """An engine one tick after a prompt of `n` tokens ended inside a
+    step's program: -> (engine, params, resident's rid, joiner's rid)."""
+    eng, params = fusing_engine()
+    resident = beside_a_resident(eng)
+    rid = eng.submit(tokens_of(n, 10 + n), **kw)
+    eng.step()
+    ended = eng._flight.ended
+    assert ended.rid == rid and eng.stats()["ticks_fused_last"] == 1
+    slot = eng._slots[ended.slot]
+    # absorbed and counted at the enqueue; no row of this program, no
+    # chunk left, no token yet
+    assert slot.phase == "prefill" and slot.filled == n
+    assert rid not in eng._flight.rows.values()
+    assert eng._next_prefilling() is None
+    assert not eng._out[rid]
+    return eng, params, resident, rid
+
+
+def _cancel_it(eng, params, rid):
+    assert eng.cancel(rid)
+    assert all(s.rid != rid for s in eng._slots) and rid not in eng._out
+    return None
+
+
+def _swap(eng, params, rid):
+    # the same weights under a new version: the stream goes on as it was
+    assert eng.update_params(params) == 1
+    assert eng.stats()["chain_drains"] == 1
+    return [0] + [1] * 5
+
+
+def _preempt(eng, params, rid):
+    from ray_tpu.util import faults
+    faults.install(faults.FaultPlan(seed=1).fail(
+        "engine.preempt", at=0, times=1))
+    try:
+        eng.step()
+    finally:
+        faults.clear()
+    # the newest admission is the victim: its first token is out and the
+    # resume absorbs it with the prompt
+    assert eng.stats()["preemptions"] == 1
+    assert [int(t) for t in eng._out[rid]] == alone(5, max_new_tokens=6)[:1]
+    return [0] * 6
+
+
+def _check(eng, params, rid):
+    eng.check_invariants()
+    return [0] * 6
+
+
+@pytest.mark.parametrize("then", [_cancel_it, _swap, _preempt, _check])
+def test_whoever_needs_the_engine_at_rest_reads_a_fused_last_chunk_s_token(
+        then):
+    """A prompt ended inside the step in flight, then `cancel` of its
+    request, `update_params`, a forced preemption, `check_invariants`:
+    each reads the flight and with it the chunk's token (`_rest`), so
+    nothing is left unread, the first token is emitted once, under the
+    version of the program that computed it, and both streams are what
+    they are undisturbed."""
+    eng, params, resident, rid = fused_last_in_flight(max_new_tokens=6)
+    versions = then(eng, params, rid)
+    if then is not _preempt:    # whose tick went on and left its step
+        assert eng._flight is None
+    if versions is not None:
+        slot, = [s for s in eng._slots if s.rid == rid]
+        assert slot.phase == ("prefill" if then is _preempt else "decode")
+        got = list(eng.tokens_for(rid))
+        assert [int(t) for t in got] == alone(5, max_new_tokens=6)
+        assert [t.params_version for t in got] == versions
+    eng.run_until_idle()
+    want, _ = fusing_engine()
+    assert [int(t) for t in eng.tokens_for(resident)] == [
+        int(t) for t in want.tokens_for(
+            want.submit(tokens_of(6, 1), max_new_tokens=40))]
+    assert eng.stats()["ticks_fused_last"] >= 1
+    eng.check_invariants()
+
+
+@pytest.mark.parametrize("ends_by", ["max_new_tokens", "eos_id"])
+def test_a_stream_that_ends_with_a_fused_last_chunk_s_token(ends_by):
+    """A request of one new token has no row in the step behind its
+    chunk's program; one whose first token is its `eos_id` has, and that
+    row is thrown away where its step is read, as a row that ended on
+    `eos_id` a step late always is. Either stream is its first token."""
+    first = alone(5, max_new_tokens=1)
+    kw = ({"max_new_tokens": 1} if ends_by == "max_new_tokens"
+          else {"max_new_tokens": 6, "eos_id": first[0]})
+    eng, _, resident, rid = fused_last_in_flight(**kw)
+    slot = eng._flight.ended.slot
+    eng.step()
+    # the flight that carried the chunk is read: the stream is over
+    assert [int(t) for t in eng._out[rid]] == first and rid in eng._done
+    assert not eng._slots[slot].active
+    assert (slot in eng._flight.rows) == (ends_by == "eos_id")
+    before = eng.stats()["decode_tokens"]
+    eng.step()
+    assert eng.stats()["decode_tokens"] == before + 1   # the resident's
+    assert [int(t) for t in eng.tokens_for(rid)] == first
+    eng.run_until_idle()
+    assert len(list(eng.tokens_for(resident))) == 40
+    eng.check_invariants()
+
+
+def test_a_fused_tick_behind_a_fused_last_chunk_joins_its_row():
+    """Two prompts arrive at once: the tick after the one that fused the
+    first's only chunk fuses the second's first chunk, so the first's
+    row takes its token inside `jit__tick` (`FROM_CHUNK`), in the one
+    program the engine has."""
+    eng, _ = fusing_engine()
+    resident = beside_a_resident(eng)
+    a = eng.submit(tokens_of(5, 15), max_new_tokens=6)
+    b = eng.submit(tokens_of(13, 23), max_new_tokens=6)
+    eng.step()
+    assert eng._flight.ended.rid == a
+    eng.step()
+    st = eng.stats()
+    assert st["ticks_fused"] == 2 and st["ticks_fused_last"] == 1
+    assert set(eng._flight.rows.values()) == {resident, a}
+    assert eng._flight.chained == 2 and eng._flight.ended is None
+    eng.step()
+    assert eng._flight.ended.rid == b
+    assert [int(t) for t in eng.tokens_for(a)] == alone(5, max_new_tokens=6)
+    assert [int(t) for t in eng.tokens_for(b)] == alone(13, max_new_tokens=6)
+    st = eng.stats()
+    assert st["tick_traces"] == st["decode_traces"] == 1
+    assert st["chunks_overlapped"] == 0 and st["ticks_fused"] == 3
+    eng.run_until_idle()
+    eng.check_invariants()
 
 
 def test_a_family_without_tick_never_fuses(traced_beside_a_decoder):
